@@ -216,7 +216,7 @@ def mutant_replicated_forward(replicate=("wcls",)):
     from jax.sharding import PartitionSpec as P
 
     from ..parallel import tp as tp_mod
-    from ..utils.compat import shard_map as _shard_map
+    from ..parallel.tp import _shard_map
 
     def build(spec, mesh, scheme):
         n_slices = mesh.shape["tp"]
@@ -243,7 +243,7 @@ def mutant_replicated_forward(replicate=("wcls",)):
 def _user_frames(eqn):
     from jax._src import source_info_util
 
-    return list(source_info_util.user_frames(eqn.source_info))
+    return list(source_info_util.user_frames(eqn.source_info.traceback))
 
 
 def _dequant_site_filter():
@@ -273,7 +273,10 @@ def check_traced_sharding(closed_jaxpr, params, scheme: str, tp: int,
                              "jaxpr structure changed?")]
     rows = expected if expected is not None else \
         tp_mod.expected_shard_names(params, scheme)
-    in_names = sm.params["in_names"]
+    # the shard_map eqn records one PartitionSpec per operand, hoisted
+    # consts first
+    in_names = [tp_mod.spec_axis_names(spec)
+                for spec in sm.params["in_specs"]]
     if len(in_names) < len(rows):
         return [ShardFinding("J004", config,
                              f"{len(in_names)} traced operands < "

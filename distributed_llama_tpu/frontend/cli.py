@@ -195,6 +195,14 @@ def _weight_streaming(args, quiet: bool, allow_slices: bool = True):
     return server
 
 
+def _print_device_memory(when: str) -> None:
+    from ..utils.chip import memory_line
+
+    line = memory_line(when)
+    if line:
+        print(line, file=sys.stderr, flush=True)
+
+
 def _maybe_distributed(args) -> None:
     if args.coordinator:
         import jax
@@ -460,8 +468,13 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         from ..ops.linear import apply_q40_body_policy
 
         if wft == FloatType.Q40:
+            # rows per decode dispatch: the slot pool's width, the
+            # lockstep batch's, or one
+            rows = (1 if prompts is None else
+                    (args.slots or min(len(prompts), 8)) if args.continuous
+                    else len(prompts))
             apply_q40_body_policy(read_spec(args.model,
-                                            weights_float_type=wft))
+                                            weights_float_type=wft), rows)
         spec, params = load_model_packed(args.model, weights_float_type=wft,
                                          buffer_float_type=bft)
     if not quiet:
@@ -558,6 +571,7 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
                     fast_prefill=args.fast_prefill)
     if not quiet:
         print(f"⏩ Loaded model in {time.perf_counter() - t0:.1f}s")
+        _print_device_memory("loaded")
 
     tokenizer = Tokenizer(args.tokenizer, spec.vocab_size)
     seed = args.seed if args.seed is not None else int(time.time())
@@ -597,6 +611,8 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
                                   resume=resume,
                                   resume_prompt=(rest0 if resume else None),
                                   prefill_chunk=args.prefill_chunk)
+    if not quiet:
+        _print_device_memory("end")
     if args.profile and not quiet:
         print(f"⏩ Profiler trace written to {args.profile}")
         # the reference-shaped I/T split, profiler-derived (tools/it_split
@@ -959,7 +975,8 @@ def cmd_serve(argv: list[str]) -> int:
         from ..ops.linear import apply_q40_body_policy
 
         apply_q40_body_policy(read_spec(
-            args.model, weights_float_type=_FT[args.weights_float_type]))
+            args.model, weights_float_type=_FT[args.weights_float_type]),
+            rows=args.slots)
     spec, params = load(args.model,
                         weights_float_type=_FT[args.weights_float_type],
                         buffer_float_type=_FT[args.buffer_float_type])
@@ -1042,7 +1059,9 @@ def cmd_serve(argv: list[str]) -> int:
     if server.recovered:
         print(f"🌐 recovered {server.recovered} journaled requests "
               f"from {args.journal}")
+    _print_device_memory("loaded")
     server.serve_forever()
+    _print_device_memory("end")
     return 0
 
 

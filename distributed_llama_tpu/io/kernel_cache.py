@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from ..utils.compile_cache import cache_error
 from .loader import (Q40Kernel, Q40KernelI4PackedD, Q40KernelI4PackedNb,
                      Q40KernelNb, Q40Weight)
 
@@ -237,8 +238,7 @@ def load_packed(path: str, key: str) -> dict | None:
             tree[e["name"]] = fields[0] if cls is None else cls(*fields)
         return tree
     except (OSError, ValueError, KeyError, json.JSONDecodeError) as e:
-        print(f"kernel cache unreadable ({type(e).__name__}: {e}); "
-              f"rebuilding", file=sys.stderr)
+        cache_error("kcache", f"{path} unreadable, rebuilding", e)
         return None
 
 
@@ -300,8 +300,8 @@ def load_model_packed(path: str, spec=None, weights_float_type=None,
                   f"{time.perf_counter() - t0:.1f}s); next load skips "
                   f"re-tiling", file=sys.stderr)
         except OSError as e:
-            print(f"kernel cache not written ({e}); loads keep re-tiling",
-                  file=sys.stderr)
+            cache_error("kcache", f"{side} not written, loads keep "
+                                  f"re-tiling", e)
         finally:
             release_build_lock(lock)
     return spec, packed

@@ -216,7 +216,8 @@ def attention(spec: TransformerSpec, q: jax.Array, k_cache: jax.Array,
 
         if (attn_kernel_mode() == "pallas"
                 and supports_prefill(spec.seq_len, spec.head_size, t_len,
-                                     spec.kv_mul)):
+                                     spec.kv_mul, n_kv=k_cache.shape[1],
+                                     itemsize=k_cache.dtype.itemsize)):
             from ..ops.linear import matmul_mode
 
             out = prefill_attention(q, k_cache, v_cache, pos,

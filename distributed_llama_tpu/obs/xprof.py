@@ -221,7 +221,8 @@ def parse_xplane_capture(path: str, tokens: int) -> Attribution:
     it_split.summarize: the drift join is per-chip/token, matching
     comm_stats accounting.
     """
-    from ..utils.compat import profile_data_planes
+    from jax.profiler import ProfileData
+
     from ..utils.it_split import (_HLO_RE, _OP_RE, _SKIP_RE, _is_op_line,
                                   find_xplane)
 
@@ -230,7 +231,7 @@ def parse_xplane_capture(path: str, tokens: int) -> Attribution:
                          "tokens explicitly (tracecheck --tokens)")
     per_name: dict[str, list] = {}
     n_dev = 0
-    for plane in profile_data_planes(find_xplane(path)):
+    for plane in ProfileData.from_file(find_xplane(path)).planes:
         lines = list(plane.lines)
         has_xla_ops = any(ln.name == "XLA Ops" for ln in lines)
         plane_has_ops = False

@@ -289,14 +289,17 @@ def fuse_q40_layer_matmuls(params: dict) -> dict:
     return out
 
 
-def q40_body_policy(spec) -> tuple[str, str]:
+def q40_body_policy(spec, rows: int = 1) -> tuple[str, str]:
     """Resolve the single-chip Q40 decode-body policy: (policy, reason).
+    ``rows`` is how many rows one decode dispatch carries (1 for plain
+    ``inference``, the slot count for ``serve`` / ``--continuous``).
 
-    Promotes the bench's same-session A/B winner (BASELINE.md r5: 7B
-    9.645 ms/token with the int4-plane body on forced nb-major layout, vs
-    9.98-10.37 for the defaults) into the real CLI path — until now only
-    ``bench.py:_row_env`` applied it, so a plain ``inference`` run left
-    ~4% on the table (VERDICT round 5).
+    Promotes the bench's A/B winner into the real CLI path. On the attached
+    v5e at 7B (my chip run, PR 21; PERF.md): the fused chain runs 8.15
+    ms/token with the int4-plane body on forced nb-major layout against
+    8.60 d-major and 8.92 nb-major u8, and the per-token ``inference`` step
+    11.1 ms nb-major against 14.0 d-major (d-major's w2, nb = 344, is placed
+    transposed by the device client and copied row-major inside every step).
 
     Explicit ``DLLAMA_Q40_I4``/``DLLAMA_NB_MAJOR`` env wins over
     everything (including DLLAMA_Q40_BODY — nothing ever unsets a user
@@ -306,6 +309,10 @@ def q40_body_policy(spec) -> tuple[str, str]:
     the winning combo), ``d-major`` (keep the stock layout picks). auto
     picks ``i4-nb`` iff ALL of:
       * the Pallas kernel path is active (TPU; elsewhere layouts are moot),
+      * a decode dispatch is not 5..8 rows wide: the nb-major VPU body
+        serves T <= 4 and the MXU body T > 8, so in between EVERY matmul
+        takes the XLA dequantize-then-dot route — ``serve`` at 8 slots
+        decoded at 75 ms/token that way against 37.5 d-major (same run),
       * every matmul leaf places on the nb-major row tiler (the i4 body is
         nb-major-only — pad-free 7B-class shapes need the forced layout),
       * the packed weights leave conversion headroom: the in-chain i4
@@ -328,7 +335,12 @@ def q40_body_policy(spec) -> tuple[str, str]:
         return choice, "explicit DLLAMA_Q40_BODY"
     if q40_kernel_mode() != "pallas":
         return "d-major", "XLA matmul path (no Pallas kernels here)"
-    from .pallas_q40 import _pick_rows_nb
+    from .pallas_q40 import MULTI_T_MAX, NB_MULTI_T_MAX, _pick_rows_nb
+
+    if NB_MULTI_T_MAX < rows <= MULTI_T_MAX:
+        return "d-major", (f"{rows}-row decode dispatches: no nb-major "
+                           f"kernel serves T in {NB_MULTI_T_MAX + 1}.."
+                           f"{MULTI_T_MAX}, d-major has the multi-T body")
 
     shapes = [shape for _, shape in spec.layer_matmul_shapes()]
     shapes.append((spec.vocab_size, spec.dim))  # wcls
@@ -353,7 +365,7 @@ def q40_body_policy(spec) -> tuple[str, str]:
                      f"packed fits the i4 headroom gate")
 
 
-def apply_q40_body_policy(spec) -> str:
+def apply_q40_body_policy(spec, rows: int = 1) -> str:
     """Apply q40_body_policy by setting the layout env knobs the packers
     and the decode chain already read (DLLAMA_NB_MAJOR=force +
     DLLAMA_Q40_I4=on), BEFORE any pack/sidecar load — the kcache layout
@@ -363,7 +375,7 @@ def apply_q40_body_policy(spec) -> str:
     overridden."""
     import sys
 
-    policy, reason = q40_body_policy(spec)
+    policy, reason = q40_body_policy(spec, rows)
     if policy == "i4-nb":
         os.environ.setdefault("DLLAMA_NB_MAJOR", "force")
         os.environ.setdefault("DLLAMA_Q40_I4", "on")

@@ -357,14 +357,24 @@ def decode_attention(q, k_all, v_all, layer, pos, *, kv_mul: int,
 
 def _prefill_kernel(pos_ref, q_ref, k_hbm, v_hbm, out_ref, k_buf, v_buf,
                     sems, *, bq: int, bk: int, kv_mul: int, hs: int,
-                    bf16: bool):
-    """One (kv head g, q block qb) tile: flash walk over live KV blocks.
+                    bf16: bool, heads: int):
+    """One (kv-head group g, q block qb) tile: flash walk over live KV
+    blocks for ``heads`` kv heads.
 
-    q_ref/out_ref: (1, bq, kv_mul*hs) VMEM blocks of the group-major
+    q_ref/out_ref: (heads, bq, kv_mul*hs) VMEM blocks of the group-major
     (n_kv, T, kv_mul*hs) planes (the last two dims must be the blocked
     ones — Mosaic's (8, 128)-divisibility rule); k_hbm/v_hbm:
-    (S, n_kv, hs) in HBM; k/v_buf: (2, bk, hs) VMEM scratch; sems: (2, 2)
-    DMA semaphores (slot x {k, v}).
+    (S, n_kv, hs) in HBM; sems: (2, 2) DMA semaphores (slot x {k, v}).
+
+    f32 cache: heads == 1, the DMA slices one head and k/v_buf are
+    (2, bk, hs). A bf16 cache is tiled (8,128)(2,1) in HBM — heads 2r and
+    2r+1 share one 32-bit sublane — and the chip's compiler refuses a DMA
+    that slices fewer than 8 heads ("Slice shape along dimension 1 must be
+    aligned to tiling (8), but is 1"). So the bf16 walk takes a whole tile:
+    heads == 8, DMA'd as the 4 uint32 pair-rows of the bitcast
+    (S, n_kv/2, hs) view into (2, bk, 4, hs) uint32 buffers; each head
+    widens to f32 in VMEM (low half = even head, high half = odd; bf16 ->
+    f32 is a 16-bit shift, exact).
     """
     g = pl.program_id(0)
     qb = pl.program_id(1)
@@ -376,21 +386,42 @@ def _prefill_kernel(pos_ref, q_ref, k_hbm, v_hbm, out_ref, k_buf, v_buf,
     dn_pv = (((1,), (0,)), ((), ()))   # (bq, bk) @ (bk, hs)
     scale = 1.0 / jnp.sqrt(jnp.float32(hs))
 
+    if heads == 1:
+        def src(hbm, i):
+            return hbm.at[pl.ds(i * bk, bk), g]
+
+        def landed(buf, slot):
+            return [buf[slot]]                          # (bk, hs)
+    else:
+        rows = heads // 2
+        k_hbm, v_hbm = k_hbm.bitcast(jnp.uint32), v_hbm.bitcast(jnp.uint32)
+
+        def src(hbm, i):
+            return hbm.at[pl.ds(i * bk, bk), pl.ds(g * rows, rows)]
+
+        def landed(buf, slot):
+            out = []
+            for r in range(rows):
+                pair = buf[slot, :, r, :]               # (bk, hs) uint32
+                out.append(jax.lax.bitcast_convert_type(
+                    pair << 16, jnp.float32))
+                out.append(jax.lax.bitcast_convert_type(
+                    pair & jnp.uint32(0xFFFF0000), jnp.float32))
+            return out
+
     # causal bound: the deepest query row of this block sees keys
     # 0 .. pos + qb*bq + bq - 1 (the chunk's keys are already in the cache)
     n_blk = jnp.clip((pos + qb * bq + bq + bk - 1) // bk, 1, S // bk)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-    q_pos_rows = pos + qb * bq + rows                  # (bq, 1)
+    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    q_pos_rows = pos + qb * bq + rows_iota             # (bq, 1)
 
     def k_dma(slot, i):
-        return pltpu.make_async_copy(
-            k_hbm.at[pl.ds(i * bk, bk), g], k_buf.at[slot],
-            sems.at[slot, 0])
+        return pltpu.make_async_copy(src(k_hbm, i), k_buf.at[slot],
+                                     sems.at[slot, 0])
 
     def v_dma(slot, i):
-        return pltpu.make_async_copy(
-            v_hbm.at[pl.ds(i * bk, bk), g], v_buf.at[slot],
-            sems.at[slot, 1])
+        return pltpu.make_async_copy(src(v_hbm, i), v_buf.at[slot],
+                                     sems.at[slot, 1])
 
     k_dma(0, 0).start()
     v_dma(0, 0).start()
@@ -406,48 +437,60 @@ def _prefill_kernel(pos_ref, q_ref, k_hbm, v_hbm, out_ref, k_buf, v_buf,
 
         k_dma(slot, i).wait()
         v_dma(slot, i).wait()
-        k = k_buf[slot].astype(wdt)                    # (bk, hs)
-        v = v_buf[slot].astype(wdt)
+        ks, vs = landed(k_buf, slot), landed(v_buf, slot)
         key_pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         valid = key_pos <= q_pos_rows                  # (bq, bk)
 
         out = []
-        for j in range(kv_mul):
-            m_old, l_old, o_old = carry[j]
-            qj = q_ref[0, :, j * hs:(j + 1) * hs].astype(wdt)  # (bq, hs)
-            s = jax.lax.dot_general(qj, k, dn,
-                                    preferred_element_type=jnp.float32,
-                                    precision=prec) * scale
-            s = jnp.where(valid, s, NEG_INF)
-            # block 0 holds key 0, visible to every query row, so m is
-            # finite from the first walked block on (no -inf guard needed)
-            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)                     # (bq, bk)
-            corr = jnp.exp(m_old - m_new)              # (bq, 1)
-            l_new = l_old * corr + jnp.sum(p, axis=1, keepdims=True)
-            po = jax.lax.dot_general(p.astype(wdt), v, dn_pv,
-                                     preferred_element_type=jnp.float32,
-                                     precision=prec)
-            out.append((m_new, l_new, o_old * corr + po))
+        for h in range(heads):
+            k = ks[h].astype(wdt)                      # (bk, hs)
+            v = vs[h].astype(wdt)
+            for j in range(kv_mul):
+                m_old, l_old, o_old = carry[h * kv_mul + j]
+                qj = q_ref[h, :, j * hs:(j + 1) * hs].astype(wdt)  # (bq, hs)
+                s = jax.lax.dot_general(qj, k, dn,
+                                        preferred_element_type=jnp.float32,
+                                        precision=prec) * scale
+                s = jnp.where(valid, s, NEG_INF)
+                # block 0 holds key 0, visible to every query row, so m is
+                # finite from the first walked block on (no -inf guard)
+                m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)                 # (bq, bk)
+                corr = jnp.exp(m_old - m_new)          # (bq, 1)
+                l_new = l_old * corr + jnp.sum(p, axis=1, keepdims=True)
+                po = jax.lax.dot_general(p.astype(wdt), v, dn_pv,
+                                         preferred_element_type=jnp.float32,
+                                         precision=prec)
+                out.append((m_new, l_new, o_old * corr + po))
         return tuple(out)
 
     init = tuple((jnp.full((bq, 1), NEG_INF, jnp.float32),
                   jnp.zeros((bq, 1), jnp.float32),
                   jnp.zeros((bq, hs), jnp.float32))
-                 for _ in range(kv_mul))
+                 for _ in range(heads * kv_mul))
     final = jax.lax.fori_loop(0, n_blk, body, init)
-    for j in range(kv_mul):
-        _, l_j, o_j = final[j]
-        out_ref[0, :, j * hs:(j + 1) * hs] = o_j / l_j
+    for h in range(heads):
+        for j in range(kv_mul):
+            _, l_j, o_j = final[h * kv_mul + j]
+            out_ref[h, :, j * hs:(j + 1) * hs] = o_j / l_j
 
 
 # q-block rows: bounded so (bq, bk) score temporaries + q/out blocks stay
-# comfortably inside the 64 MB scoped-VMEM limit at kv_mul<=8
+# comfortably inside the 64 MB scoped-VMEM limit at 8 accumulators per tile
 _PREFILL_BQ_CAP = 1920
 
 
-def _pick_prefill_bq(t_len: int, kv_mul: int) -> int | None:
-    cap = min(_PREFILL_BQ_CAP, max(128, 245_760 // (kv_mul * 16)))
+def _prefill_heads(n_kv: int, itemsize: int) -> int | None:
+    """kv heads one kernel program walks: 1 for a 32-bit cache, a whole
+    8-head HBM tile for bf16 (see _prefill_kernel); None = unsupported."""
+    if itemsize == 4:
+        return 1
+    return 8 if itemsize == 2 and n_kv % 8 == 0 else None
+
+
+def _pick_prefill_bq(t_len: int, n_acc: int) -> int | None:
+    """``n_acc``: (m, l, o) accumulators live per program = heads*kv_mul."""
+    cap = min(_PREFILL_BQ_CAP, max(128, 245_760 // (n_acc * 16)))
     for cand in range(min(t_len, cap), 7, -1):
         if t_len % cand == 0 and cand % 8 == 0:
             return cand
@@ -462,9 +505,10 @@ def _pick_prefill_bk(seq_len: int) -> int | None:
 
 
 def supports_prefill(seq_len: int, head_size: int, t_len: int,
-                     kv_mul: int) -> bool:
-    return (t_len > 8 and head_size % 128 == 0
-            and _pick_prefill_bq(t_len, kv_mul) is not None
+                     kv_mul: int, n_kv: int = 8, itemsize: int = 4) -> bool:
+    heads = _prefill_heads(n_kv, itemsize)
+    return (t_len > 8 and head_size % 128 == 0 and heads is not None
+            and _pick_prefill_bq(t_len, heads * kv_mul) is not None
             and _pick_prefill_bk(seq_len) is not None)
 
 
@@ -483,31 +527,30 @@ def prefill_attention(q, k_cache, v_cache, pos, *, kv_mul: int,
     t_len, n_q, hs = q.shape
     S, n_kv, _ = k_cache.shape
     assert n_q == n_kv * kv_mul, (n_q, n_kv, kv_mul)
-    bq = _pick_prefill_bq(t_len, kv_mul)
+    heads = _prefill_heads(n_kv, k_cache.dtype.itemsize)
+    bq = _pick_prefill_bq(t_len, heads * kv_mul)
     bk = _pick_prefill_bk(S)
+    buf = (pltpu.VMEM((2, bk, hs), k_cache.dtype) if heads == 1
+           else pltpu.VMEM((2, bk, heads // 2, hs), jnp.uint32))
     # group-major carry: Mosaic blocks the LAST TWO dims, so the kv-head
     # axis must lead — (T, n_kv*kv_mul, hs) -> (n_kv, T, kv_mul*hs)
     qg = jnp.transpose(q.astype(jnp.float32)
                        .reshape(t_len, n_kv, kv_mul * hs), (1, 0, 2))
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, bq=bq, bk=bk, kv_mul=kv_mul,
-                          hs=hs, bf16=bf16),
-        grid=(n_kv, t_len // bq),
+                          hs=hs, bf16=bf16, heads=heads),
+        grid=(n_kv // heads, t_len // bq),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, kv_mul * hs), lambda g, qb: (g, qb, 0)),
+            pl.BlockSpec((heads, bq, kv_mul * hs), lambda g, qb: (g, qb, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, bq, kv_mul * hs),
+        out_specs=pl.BlockSpec((heads, bq, kv_mul * hs),
                                lambda g, qb: (g, qb, 0)),
         out_shape=jax.ShapeDtypeStruct((n_kv, t_len, kv_mul * hs),
                                        jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, bk, hs), k_cache.dtype),
-            pltpu.VMEM((2, bk, hs), k_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))],
         compiler_params=_VMEM64_PARAMS,
         interpret=interpret,
     )(jnp.asarray(pos, jnp.int32).reshape(1), qg, k_cache, v_cache)

@@ -84,9 +84,7 @@ _EPS = 1e-5
 # (768, 128) tile that the standalone matvec kernel runs fine). v5e has
 # 128 MB of physical VMEM — raise the limit rather than starving the tiles.
 _VMEM_LIMIT = 100 * 1024 * 1024
-from ..utils.compat import pallas_tpu_compiler_params as _compiler_params
-
-_PARAMS = _compiler_params(vmem_limit_bytes=_VMEM_LIMIT)
+_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def fusion_mode() -> str:

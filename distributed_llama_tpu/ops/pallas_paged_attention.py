@@ -21,10 +21,14 @@ positions 0..pos+i, the stacked causal windows of sequential decode).
 
 KV dtypes: f32/bf16 pages DMA raw planes; Q8 pages
 (``DLLAMA_KV_QUANT=q8``) DMA the int8 code planes PLUS the per-position
-f16 Q80 block-delta planes and dequantize inside the page loop — the
-same ``codes.astype(f32) * delta.astype(f32)`` value map as the XLA
-fallback's gather-side dequant (ops/quants.dequantize_q80_jax), so both
-routes see identical f32 K/V values.
+f16 Q80 block-delta planes and dequantize inside the page loop
+(``_dequant_q80_page``) — bit for bit the ``codes.astype(f32) *
+delta.astype(f32)`` value map of the XLA fallback's gather-side dequant
+(ops/quants.dequantize_q80_planes), so both routes see identical f32 K/V
+values. The chip shapes how: it takes no f16 kernel argument and has no
+f16 vectors, so the delta planes travel as their raw int16 bits and are
+widened by hand, and its layout pass refuses the (ps, nb, QK) reshape of
+the codes, so the deltas are spread to the codes' shape instead.
 
 Parity contract (tests/test_pallas_paged_attention.py): the kernel is
 INVARIANT to physical page placement — any permutation of the pool that
@@ -188,15 +192,18 @@ class _RawPages:
 
 
 class _Q8Pages:
-    """Q8 page reader: int8 code planes + f16 Q80 delta planes (4 DMAs per
-    page), dequantized on land with the exact XLA-fallback value map
-    (codes.astype(f32).reshape(ps, nb, QK) * d.astype(f32)[..., None])."""
+    """Q8 page reader: int8 code planes + Q80 delta planes as f16 bits
+    (4 DMAs per page), dequantized on land with the exact XLA-fallback
+    value map (_dequant_q80_page)."""
 
     def __init__(self, kq_hbm, kd_hbm, vq_hbm, vd_hbm, kq_buf, kd_buf,
                  vq_buf, vd_buf, sems):
         self.planes = ((kq_hbm, kq_buf, 0), (kd_hbm, kd_buf, 1),
                        (vq_hbm, vq_buf, 2), (vd_hbm, vd_buf, 3))
         self.sems = sems
+        # page-invariant: built once per program, not once per page
+        _, _, n_kv, hs = kq_buf.shape
+        self.masks = _q80_spread_masks(n_kv, hs)
 
     def __call__(self, slot, row):
         reader = self
@@ -215,12 +222,65 @@ class _Q8Pages:
         return _Quad()
 
     def landed(self, slot):
-        from ..ops.quants import dequantize_q80_planes
-
         (_, kq_buf, _), (_, kd_buf, _), (_, vq_buf, _), (_, vd_buf, _) = \
             self.planes
-        return (dequantize_q80_planes(kq_buf[slot], kd_buf[slot]),
-                dequantize_q80_planes(vq_buf[slot], vd_buf[slot]))
+        # the deltas land as raw f16 BITS (int16 planes, see
+        # paged_decode_attention_kernel_q8) and are widened by hand
+        return (_dequant_q80_page(kq_buf[slot], kd_buf[slot], self.masks),
+                _dequant_q80_page(vq_buf[slot], vd_buf[slot], self.masks))
+
+
+def _f16_bits_to_f32(bits):
+    """Exact f16 -> f32 from the 16 raw bits (any integer dtype), in
+    integer ops the chip's vector unit has. Normals move the exponent and
+    mantissa fields into f32 position (+112 exponent rebias); f16
+    subnormals are mantissa * 2^-24, built from the integer so no f32
+    subnormal is ever formed (the vector unit flushes those). inf/nan are
+    not mapped: a Q80 delta is amax/127 of finite cache values."""
+    h = bits.astype(jnp.int32)
+    mag = h & 0x7FFF
+    normal = jax.lax.bitcast_convert_type((mag << 13) + (112 << 23),
+                                          jnp.float32)
+    sub = (mag & 0x3FF).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    f = jnp.where(mag >> 10 == 0, sub, normal)
+    return jnp.where(h & 0x8000 != 0, -f, f)
+
+
+def _q80_spread_masks(n_kv: int, hs: int):
+    """The two selections _dequant_q80_page spreads deltas with, for a
+    (n_kv, hs) position row of nb = n_kv*hs/QK blocks: ``own`` (1, n_kv,
+    nb) keeps head h's blocks of the delta row, ``spread`` (nb, hs) f32
+    sends block j to the QK lanes it scales."""
+    nb, per_head = n_kv * hs // QK, hs // QK
+    head = jax.lax.broadcasted_iota(jnp.int32, (n_kv, nb), 0)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (n_kv, nb), 1)
+    j = jax.lax.broadcasted_iota(jnp.int32, (nb, hs), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (nb, hs), 1)
+    return ((blk // per_head == head)[None],
+            (j % per_head == lane // QK).astype(jnp.float32))
+
+
+def _dequant_q80_page(codes, d, masks=None):
+    """In-kernel Q80 page decode: codes (ps, n_kv, hs) int8, d (ps, nb)
+    f16 bits with nb = n_kv*hs/QK block deltas over the flattened
+    (n_kv, hs) row. Same values as quants.dequantize_q80_planes, which
+    reshapes the codes to (ps, nb, QK) — a minor-dim split the chip's
+    layout pass refuses ("infer-vector-layout: unsupported shape cast").
+    Here the deltas are spread to the codes' (ps, n_kv, hs) shape instead,
+    with casts the pass supports (``masks``, _q80_spread_masks): mask the
+    delta row per kv head, then one exact 0/1 selection dot sends delta j
+    to the QK lanes of its block. Every output is a single delta times
+    1.0, so HIGHEST keeps it bit-exact."""
+    ps, n_kv, hs = codes.shape
+    nb = d.shape[-1]
+    own, spread = masks or _q80_spread_masks(n_kv, hs)
+    d32 = _f16_bits_to_f32(d)                               # (ps, nb)
+    rows = jnp.where(own, d32[:, None, :], 0.0)             # (ps, n_kv, nb)
+    scale = jax.lax.dot_general(
+        rows.reshape(ps * n_kv, nb), spread, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+    return codes.astype(jnp.float32) * scale.reshape(ps, n_kv, hs)
 
 
 def _write_flash_out(final, out_ref, kv_mul: int):
@@ -252,8 +312,8 @@ def _kernel_paged_q8(layer_ref, pos_ref, table_ref, q_ref, kq_hbm, kd_hbm,
                      vq_hbm, vd_hbm, out_ref, kq_buf, kd_buf, vq_buf,
                      vd_buf, sems, *, page_size: int, kv_mul: int,
                      n_pages: int, t_len: int):
-    """_kernel_paged's Q8 twin: int8 code + f16 delta planes per page,
-    dequantized inside the page loop; sems (2, 4)."""
+    """_kernel_paged's Q8 twin: int8 code + int16 (f16 bits) delta planes
+    per page, dequantized inside the page loop; sems (2, 4)."""
     b = pl.program_id(0)
     reader = _Q8Pages(kq_hbm, kd_hbm, vq_hbm, vd_hbm, kq_buf, kd_buf,
                       vq_buf, vd_buf, sems)
@@ -329,6 +389,14 @@ def paged_decode_attention_kernel_q8(q, kq4, kd4, vq4, vd4, layer, pos,
     nb = n_kv * hs // QK
     B = q.shape[0]
     qg = q.reshape(B, t_len, n_kv, kv_mul, hs).astype(jnp.float32)
+
+    def f16_bits(d):
+        # the chip takes no f16 kernel argument ("Only arguments with ...
+        # bfloat16 or 32-bit element types are supported") and has no f16
+        # vectors: hand the delta planes over as their raw bits — a
+        # same-width bitcast, no copy
+        return jax.lax.bitcast_convert_type(d, jnp.int16)
+
     out = pl.pallas_call(
         functools.partial(_kernel_paged_q8, page_size=page_size,
                           kv_mul=kv_mul, n_pages=n_pages, t_len=t_len),
@@ -350,16 +418,17 @@ def paged_decode_attention_kernel_q8(q, kq4, kd4, vq4, vd4, layer, pos,
                                        jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((2, ps, n_kv, hs), jnp.int8),
-            pltpu.VMEM((2, ps, nb), jnp.float16),
+            pltpu.VMEM((2, ps, nb), jnp.int16),
             pltpu.VMEM((2, ps, n_kv, hs), jnp.int8),
-            pltpu.VMEM((2, ps, nb), jnp.float16),
+            pltpu.VMEM((2, ps, nb), jnp.int16),
             pltpu.SemaphoreType.DMA((2, 4)),
         ],
         compiler_params=_VMEM64_PARAMS,
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       jnp.asarray(pos, jnp.int32).reshape(B),
-      jnp.asarray(table, jnp.int32), qg, kq4, kd4, vq4, vd4)
+      jnp.asarray(table, jnp.int32), qg, kq4, f16_bits(kd4), vq4,
+      f16_bits(vd4))
     return out.reshape(B, t_len, n_kv * kv_mul * hs)
 
 

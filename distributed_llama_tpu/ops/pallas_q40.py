@@ -264,6 +264,11 @@ def _kernel_mxu_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
 
 
 MULTI_T_MAX = 8  # beyond this the per-row accumulators crowd VMEM; use MXU
+# the nb-major VPU multi body stops earlier (see _q40_matmul_nbmajor): T in
+# NB_MULTI_T_MAX+1..MULTI_T_MAX has NO nb-major kernel and takes the XLA
+# dequantize-then-dot route — ops/linear.q40_body_policy keeps dispatches of
+# that width off the nb-major layout
+NB_MULTI_T_MAX = 4
 
 # Raised scoped-VMEM limit for the T>1 kernels (MXU prefill bodies, the
 # unpack-once scratch kernels, and the T<=8 VPU multi bodies batched decode
@@ -271,9 +276,7 @@ MULTI_T_MAX = 8  # beyond this the per-row accumulators crowd VMEM; use MXU
 # tile sets at the default 16 MB (e.g. 22.6M at w2's nb=344/bt=32 prefill
 # tile, 26.3M at the 13B B=2 multi tile) though v5e has 128 MB physical.
 # Same approach as ops/pallas_layer._VMEM_LIMIT.
-from ..utils.compat import pallas_tpu_compiler_params as _compiler_params
-
-_VMEM64_PARAMS = _compiler_params(vmem_limit_bytes=64 * 1024 * 1024)
+_VMEM64_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
 def q40_i4_enabled() -> bool:
@@ -1313,8 +1316,9 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
         # the multi body carries t (nb, rows) f32 accumulators plus 16*t
         # unrolled broadcast temporaries; measured on v5e: t=4/rows=256
         # compiles, t=8 overflows scoped VMEM even at rows=128 — so the
-        # kernel serves t <= 4 and 5..8 take the dequant fallback below
-        if t > 4:
+        # kernel serves t <= NB_MULTI_T_MAX and 5..8 take the dequant
+        # fallback below
+        if t > NB_MULTI_T_MAX:
             rows = None
         else:
             cap = max(128, 300_000 // (t * nb))

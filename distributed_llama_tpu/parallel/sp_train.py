@@ -18,6 +18,7 @@ inputs/targets globally and shard_map splits both over sp.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -28,7 +29,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.llama import forward_seq
 from ..models.spec import TransformerSpec
 from .ring import ring_attention
-from ..utils.compat import shard_map as _shard_map
+
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _local_forward_seq(spec: TransformerSpec, params: dict[str, Any],

@@ -102,6 +102,7 @@ along their quantized input axis) or buffers are Q80.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -125,8 +126,11 @@ from ..obs.spans import (SCOPE_ATTN, SCOPE_EMBED, SCOPE_FFN, SCOPE_ICI_GATHER,
 from ..ops.linear import fake_quant_q80, matmul, rmsnorm, silu
 from ..ops.quants import (QK, FloatType, dequantize_q80_jax,
                           quantize_q80_jax)
-from ..utils.compat import shard_map as _shard_map
 from .comm_stats import tp_scheme
+
+# every program here is manual-axes code that does its own replication
+# bookkeeping; the varying-manual-axes check rejects it
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 # params tree -> PartitionSpec for the stacked arrays (layer axis leading).
 # Output-dim sharding = axis 1 for per-layer matmuls, axis 0 for wcls.
@@ -200,6 +204,14 @@ def param_specs(params: dict[str, Any],
 CACHE_SPEC = KVCache(P(None, "sp", "tp", None), P(None, "sp", "tp", None))
 
 
+def spec_axis_names(spec) -> dict:
+    """A PartitionSpec as ``{axis index: (mesh axes...)}``, unsharded axes
+    left out — the row shape of expected_shard_names, and what
+    analysis/shardcheck.py flattens a traced shard_map's in_specs to."""
+    return {i: tuple(ax) if isinstance(ax, (tuple, list)) else (ax,)
+            for i, ax in enumerate(spec) if ax is not None}
+
+
 def expected_shard_names(params: dict[str, Any], scheme: str | None = None):
     """The sharding contract as flat, machine-checkable rows: one
     ``(leaf_name, {axis_index: (mesh_axis, ...)})`` per leaf of the
@@ -218,9 +230,7 @@ def expected_shard_names(params: dict[str, Any], scheme: str | None = None):
     rows = []
     for path, spec in leaves_with_path:
         name = jax.tree_util.keystr(path)
-        names = {i: tuple(ax) if isinstance(ax, (tuple, list)) else (ax,)
-                 for i, ax in enumerate(spec) if ax is not None}
-        rows.append((name, names))
+        rows.append((name, spec_axis_names(spec)))
     return rows
 
 

@@ -6,8 +6,8 @@ Three independent pieces the crash-safe server composes:
 * ``StepWatchdog`` — a deadline on each device dispatch. The scheduler
   thread arms it just before launching a step and disarms it once the
   host outputs land; a monitor thread fires ``on_hang`` when a dispatch
-  overruns its deadline (a wedged device runtime, a hung collective, a
-  dead tunnel). Detection only: the watchdog cannot cancel device work —
+  overruns its deadline (a wedged device runtime, a hung collective).
+  Detection only: the watchdog cannot cancel device work —
   it marks the server DEGRADED and logs, and the ``--supervise`` wrapper
   (or the operator) decides whether to restart. A dispatch that
   eventually completes after tripping disarms normally and the health

@@ -7,22 +7,51 @@ TPU-native equivalent of "make main" is caching the compiled executable on
 disk: the first process pays the compile, every later process (including the
 driver's bench run) deserializes it in seconds.
 
-This wires up jax.config's persistent compilation cache with thresholds at
-zero (every executable is worth keeping for this workload). Callers:
-frontend/cli.py main(), bench.py, tools/*. The cache key includes the jax
-version, backend, and HLO — a changed model shape or kernel recompiles
-cleanly, it never serves stale artifacts.
+Where the cache lives is decided OUTSIDE the program when the standard
+``JAX_COMPILATION_CACHE_DIR`` is set: jax reads that variable itself, and this
+module then sets no directory in code (a ``jax.config.update`` would override
+it). Unset, the cache is ``.jax_cache/`` at the checkout root — a FIXED path,
+because the directory is part of the cache key's neighbourhood: a cache that
+moves never hits. The serialized-executable and shape-manifest stores
+(bench.py) live under the same resolved directory.
+
+Thresholds are zero either way (every executable is worth keeping for this
+workload). Callers: frontend/cli.py main(), bench.py, tools/*. The cache key
+includes the jax version, backend, and HLO — a changed model shape or kernel
+recompiles cleanly, it never serves stale artifacts.
 """
 
 from __future__ import annotations
 
 import os
+import sys
+
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+# store name -> errors seen by this process (cache_error)
+_ERRORS: dict[str, int] = {}
+
+
+def cache_error(store: str, what: str, err: BaseException) -> None:
+    """A cache that could not be read or written never kills a run — but
+    it is not swallowed either: every error is counted, and the first of
+    each store is reported on stderr. ``chip_smoke.py`` counts these lines
+    and fails on any; bench rows carry ``cache_error_count()``."""
+    n = _ERRORS[store] = _ERRORS.get(store, 0) + 1
+    if n == 1:
+        print(f"💡 cache error [{store}]: {what} ({type(err).__name__}: "
+              f"{err}); the run goes on without it — later errors of this "
+              f"store are counted, not printed", file=sys.stderr)
+
+
+def cache_error_count() -> int:
+    return sum(_ERRORS.values())
 
 
 def default_cache_dir() -> str:
-    """Env override, else `.jax_cache/` next to the package (the repo root in
-    a source checkout) — kept inside the project tree by design."""
-    env = os.environ.get("DLLAMA_JAX_CACHE_DIR")
+    """``$JAX_COMPILATION_CACHE_DIR`` if set, else ``.jax_cache/`` next to
+    the package (the repo root in a source checkout)."""
+    env = os.environ.get(ENV_DIR)
     if env:
         return env
     pkg_parent = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -30,17 +59,18 @@ def default_cache_dir() -> str:
     return os.path.join(pkg_parent, ".jax_cache")
 
 
-def enable_persistent_cache(cache_dir: str | None = None) -> str | None:
+def enable_persistent_cache() -> str | None:
     """Turn on the on-disk compile cache; returns the directory, or None if
     it could not be created (read-only install: degrade to no caching)."""
     import jax
 
-    cache_dir = cache_dir or default_cache_dir()
+    cache_dir = default_cache_dir()
     try:
         os.makedirs(cache_dir, exist_ok=True)
     except OSError:
         return None
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get(ENV_DIR):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache everything: even a 2-second compile beats a disk read loss, and
     # the big chain compiles are the whole point
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)

@@ -100,10 +100,10 @@ def parse_trace(path: str) -> dict[str, DeviceSplit]:
     Keys are 'plane-name[/line]' — one entry per device for TPU traces, one
     per virtual-device executor thread for CPU-mesh traces.
     """
-    from .compat import profile_data_planes
+    from jax.profiler import ProfileData
 
     out: dict[str, DeviceSplit] = {}
-    for plane in profile_data_planes(find_xplane(path)):
+    for plane in ProfileData.from_file(find_xplane(path)).planes:
         lines = list(plane.lines)
         has_xla_ops = any(ln.name == "XLA Ops" for ln in lines)
         for line in lines:

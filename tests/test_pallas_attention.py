@@ -170,16 +170,25 @@ def test_prefill_attention_matches_core(kv_mul, pos, t_len):
         rtol=1e-5, atol=1e-5)
 
 
-def test_prefill_attention_bf16_cache_and_mode():
+@pytest.mark.parametrize("n_kv,mxu_bf16", [(8, True), (16, True),
+                                           (16, False)])
+def test_prefill_attention_bf16_cache_and_mode(n_kv, mxu_bf16):
     """bf16 cache dtype + bf16 MXU mode stay within the fast-prefill
-    tolerance against the dense path run on the same bf16 cache."""
+    tolerance against the dense path run on the same bf16 cache. A bf16
+    cache walks whole 8-head HBM tiles as uint32 head pairs (the chip's
+    DMA cannot slice one bf16 head): 16 heads cover a second tile, and
+    fewer than 8 are gated off. With f32 MXU passes the widening must be
+    exact (flash reassociation only)."""
     import jax.numpy as jnp
 
     from distributed_llama_tpu.models.llama import (attention_core,
                                                     causal_cache_mask)
-    from distributed_llama_tpu.ops.pallas_attention import prefill_attention
+    from distributed_llama_tpu.ops.pallas_attention import (
+        prefill_attention, supports_prefill)
 
-    S, n_kv, hs, kv_mul, t_len, pos = 64, 2, 128, 2, 16, 24
+    S, hs, kv_mul, t_len, pos = 64, 128, 2, 16, 24
+    assert supports_prefill(S, hs, t_len, kv_mul, n_kv=n_kv, itemsize=2)
+    assert not supports_prefill(S, hs, t_len, kv_mul, n_kv=2, itemsize=2)
     n_q = n_kv * kv_mul
     rng = np.random.default_rng(3)
     k = jnp.asarray(rng.normal(size=(S, n_kv, hs))).astype(jnp.bfloat16)
@@ -189,11 +198,12 @@ def test_prefill_attention_bf16_cache_and_mode():
     want = attention_core(hs, kv_mul, q, k.astype(jnp.float32),
                           v.astype(jnp.float32),
                           causal_cache_mask(S, jnp.int32(pos), t_len))
-    got = prefill_attention(q, k, v, pos, kv_mul=kv_mul, bf16=True,
+    got = prefill_attention(q, k, v, pos, kv_mul=kv_mul, bf16=mxu_bf16,
                             interpret=True)
+    tol = 0.02 if mxu_bf16 else 1e-5
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want).reshape(t_len, n_q, hs),
-        rtol=0.02, atol=0.02)
+        rtol=tol, atol=tol)
 
 
 def test_prefill_attention_walks_only_live_blocks():

@@ -341,3 +341,47 @@ def _routing_case(tp, scheme, kv_quant, monkeypatch):
     xla = drive("xla")
     pallas = drive("pallas")
     np.testing.assert_allclose(pallas, xla, rtol=2e-5, atol=2e-5)
+
+
+def test_f16_bits_widen_exactly_for_every_finite_pattern():
+    """The q8 kernel reads its f16 deltas as raw bits (the chip takes no
+    f16 kernel argument and has no f16 vectors) and widens them by hand:
+    every finite f16 — zeros, subnormals, both signs — must come out the
+    f32 numpy gives, bit for bit."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_paged_attention import \
+        _f16_bits_to_f32
+
+    bits = np.arange(1 << 16, dtype=np.uint16)
+    f16 = bits.view(np.float16)
+    finite = np.isfinite(f16)
+    got = np.asarray(_f16_bits_to_f32(jnp.asarray(bits.view(np.int16))))
+    want = f16.astype(np.float32)
+    assert np.array_equal(got[finite].view(np.uint32),
+                          want[finite].view(np.uint32))
+
+
+def test_q80_page_dequant_matches_the_shared_value_map():
+    """_dequant_q80_page (the in-kernel decode, built from casts the chip's
+    layout pass accepts) is bit-equal to quants.dequantize_q80_planes (the
+    XLA routes' decode, whose (ps, nb, QK) reshape the chip refuses)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_paged_attention import \
+        _dequant_q80_page
+    from distributed_llama_tpu.ops.quants import QK, dequantize_q80_planes
+
+    rng = np.random.default_rng(2)
+    for ps, n_kv, hs in ((16, 32, 128), (8, 2, 128), (4, 8, 256)):
+        nb = n_kv * hs // QK
+        codes = jnp.asarray(rng.integers(-127, 128, (ps, n_kv, hs)),
+                            jnp.int8)
+        d = (rng.normal(size=(ps, nb)) * 0.01).astype(np.float16)
+        d[0, :6] = [0.0, -0.0, 6e-8, -3e-5, 6.1e-5, 65504.0]
+        d = jnp.asarray(d)
+        got = _dequant_q80_page(codes,
+                                jax.lax.bitcast_convert_type(d, jnp.int16))
+        assert np.array_equal(np.asarray(got),
+                              np.asarray(dequantize_q80_planes(codes, d)))

@@ -29,6 +29,22 @@ def test_auto_picks_i4_nb_for_7b_on_pallas(monkeypatch):
     assert "auto" in reason
 
 
+@pytest.mark.parametrize("rows,want", [(1, "i4-nb"), (4, "i4-nb"),
+                                       (5, "d-major"), (8, "d-major"),
+                                       (16, "i4-nb")])
+def test_auto_keeps_5_to_8_row_dispatches_off_nb_major(monkeypatch, rows,
+                                                       want):
+    """nb-major has a VPU body for T <= 4 and an MXU body for T > 8; a
+    5..8-row decode dispatch (serve's default 8 slots) would take the XLA
+    dequantize-then-dot route for every matmul — measured 75 vs 37.5
+    ms/token on the chip (PERF.md, PR 21)."""
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    policy, reason = q40_body_policy(llama2_7b_spec(), rows=rows)
+    assert policy == want, reason
+    if want == "d-major":
+        assert f"{rows}-row" in reason
+
+
 def test_auto_declines_13b_on_memory_headroom(monkeypatch):
     # the 13B i4 conversion OOMed a 16 GB chip (BASELINE.md r5): auto must
     # keep d-major there, and say why

@@ -104,11 +104,9 @@ def test_ring_attention_matches_dense():
         return ring_attention(head_size, kv_mul, qc, kc, vc, start, chunk,
                               axis_size=sp)
 
-    from distributed_llama_tpu.utils.compat import shard_map
-
-    fn = jax.jit(shard_map(
-        local, mesh=mesh,
-        in_specs=(P("sp"), P("sp"), P("sp")), out_specs=P("sp")))
+    fn = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P("sp"), P("sp"), P("sp")),
+        out_specs=P("sp"), check_vma=False))
     got = np.asarray(fn(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)))
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
 

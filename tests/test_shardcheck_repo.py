@@ -197,7 +197,7 @@ def test_const_hoisted_weight_reports_j004():
     from jax.sharding import PartitionSpec as P
 
     from distributed_llama_tpu.parallel import make_mesh
-    from distributed_llama_tpu.utils.compat import shard_map as _shard_map
+    from distributed_llama_tpu.parallel.tp import _shard_map
 
     mesh = make_mesh(tp=4, devices=jax.devices()[:4])
     big = jnp.ones((512, 512), jnp.float32)  # 1 MiB, closed over

@@ -146,7 +146,7 @@ def _name_stacks(jaxpr, out=None):
         for v in eqn.params.values():
             leaves = v if isinstance(v, (list, tuple)) else [v]
             for leaf in leaves:
-                if isinstance(leaf, jax.core.ClosedJaxpr):
+                if isinstance(leaf, jax.extend.core.ClosedJaxpr):
                     _name_stacks(leaf.jaxpr, out)
                 elif hasattr(leaf, "eqns"):  # raw Jaxpr
                     _name_stacks(leaf, out)

@@ -39,8 +39,8 @@ Usage:
       [--page-size 16] [--oversub 4] [--no-paged-compare]
       [--spec-k 4] [--no-spec-compare]
 
-On a remote/tunneled runtime, --block-steps 16 amortizes the per-dispatch
-round-trip; --block-steps 1 measures the per-step scheduling floor.
+--block-steps 16 amortizes the per-dispatch host round-trip;
+--block-steps 1 measures the per-step scheduling floor.
 """
 
 import argparse
@@ -430,8 +430,10 @@ def main():
     from distributed_llama_tpu.runtime.continuous import ContinuousEngine
     from distributed_llama_tpu.utils.fingerprint import env_fingerprint
 
-    print(f"backend: {jax.devices()[0].platform} x{len(jax.devices())}",
-          file=sys.stderr)
+    from distributed_llama_tpu.utils.chip import device_triple, require_tpu
+
+    dev = device_triple() if args.small else require_tpu()
+    print(f"backend: {dev}", file=sys.stderr)
     spec = small_bench_spec() if args.small else llama2_7b_spec()
     t0 = time.perf_counter()
     params = synth_q40_fast(spec)
