@@ -594,8 +594,11 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
             print(f"⏩ Resumed at pos {pos0} ({len(prev0)} tokens so far)")
     import contextlib
 
-    prof = (jax.profiler.trace(args.profile) if args.profile
-            else contextlib.nullcontext())
+    from ..obs.profiler import capture_options
+
+    prof = (jax.profiler.trace(args.profile,
+                               profiler_options=capture_options())
+            if args.profile else contextlib.nullcontext())
     prev = prev0 if args.resume_state else []
     with prof:
         if args.fast:

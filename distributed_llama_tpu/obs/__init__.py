@@ -15,6 +15,8 @@ instrument is one end-of-run benchmark line, tokenizer.cpp:381):
 * ``obs.spans`` — hierarchical span tracer (request → prefill/decode →
   layer → phase) + the canonical jax.named_scope names the tp forward
   emits; Chrome-trace/Perfetto + NDJSON exports (``GET /debug/timeline``);
+  ``host_phase`` puts the host's phases on the profiler's clock and
+  ``named_program`` names the programs a capture shows;
 * ``obs.xprof`` — profiler-capture loader: device events bucketed by
   named scope into per-phase ms/token and per-collective time/bytes;
 * ``obs.drift`` — the model-vs-measured reconciler behind

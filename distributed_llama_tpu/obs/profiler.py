@@ -35,6 +35,22 @@ def capture_active() -> str | None:
         return _active_dir
 
 
+def capture_options():
+    """The profiler options every capture of this program starts with:
+    the Python tracer OFF, host annotations (``obs/spans.host_phase``) on.
+    JAX's default records every Python call: on the chip that ran the
+    per-token loop at 24.9 instead of 12.0 ms/token and a capture then
+    read 59 % of the device idle (PERF.md section 6). The benchmark's
+    captures use the same levels, so an operator's capture shows the
+    same phases at the same cost."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    return opts
+
+
 def start_capture(trace_dir: str, seconds: float) -> None:
     """Start a jax.profiler trace into ``trace_dir`` and schedule its stop
     ``seconds`` from now on a daemon thread. Raises RuntimeError if a
@@ -54,7 +70,8 @@ def start_capture(trace_dir: str, seconds: float) -> None:
             raise RuntimeError(f"a profile capture into {_active_dir} is "
                                f"already running")
         os.makedirs(trace_dir, exist_ok=True)
-        jax.profiler.start_trace(trace_dir)
+        jax.profiler.start_trace(trace_dir,
+                                 profiler_options=capture_options())
         _active_dir = trace_dir
 
     def _stop():

@@ -11,7 +11,11 @@ rails that share ONE naming scheme:
 * **device scopes** — ``jax.named_scope`` annotations threaded through the
   tp forward (parallel/tp.py) using the canonical names below, so a
   jax.profiler capture carries per-phase and per-collective labels that
-  obs/xprof.py can bucket without guessing.
+  obs/xprof.py can bucket without guessing;
+* **host phases** — ``host_phase("serve.fetch")`` puts what the host is
+  doing on the PROFILER's clock, beside the device trace, so a capture's
+  idle gaps split by phase. The ring's ``perf_counter`` shares nothing
+  with the device trace; its decode spans have a twin here.
 
 The scope names are the contract between the forward (which emits them),
 the xprof loader (which buckets by them), and the drift reconciler
@@ -54,6 +58,40 @@ COLLECTIVE_SCOPE_KINDS = {
     SCOPE_ICI_SCATTER: "reduce_scatter",
     SCOPE_ICI_PPERMUTE: "ppermute",
 }
+
+# -- host phases on the profiler's clock -----------------------------------
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, resolved on first use
+
+
+def host_phase(name: str, **args):
+    """A host span in the profiler's own trace (a context manager), on the
+    clock the device trace uses. The ONE place the program names the
+    profiler's annotation: with no capture running it costs about what a
+    ``contextlib.nullcontext()`` does, so it has no switch. Names are a
+    contract with whoever reads a capture (PERF.md section 3 lists them);
+    none ends in ``.step``, which the benchmark's own spans use. ``args``
+    become the event's arguments (a request's trace id)."""
+    global _annotation
+    if _annotation is None:  # obs/ imports without JAX
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name, **args)
+
+
+def named_program(name: str, fn):
+    """``fn`` under a ``__name__`` of its own, for ``jax.jit`` to read: a
+    capture's "XLA Modules" line then shows each run as ``jit_<name>``,
+    where a ``functools.partial`` or a lambda reads ``jit__unknown`` and
+    two uses of one function read alike. A wrapper, because ``fn`` may be
+    shared (one forward is both the decode step and the prefill chunk)."""
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 @dataclasses.dataclass(frozen=True)

@@ -122,7 +122,8 @@ from ..models.spec import TransformerSpec
 # attribution contract lives THERE, the emission lives HERE
 from ..obs.spans import (SCOPE_ATTN, SCOPE_EMBED, SCOPE_FFN, SCOPE_ICI_GATHER,
                          SCOPE_ICI_PPERMUTE, SCOPE_ICI_PSUM,
-                         SCOPE_ICI_SCATTER, SCOPE_LAYER, SCOPE_LOGITS)
+                         SCOPE_ICI_SCATTER, SCOPE_LAYER, SCOPE_LOGITS,
+                         named_program)
 from ..ops.linear import fake_quant_q80, matmul, rmsnorm, silu
 from ..ops.quants import (QK, FloatType, dequantize_q80_jax,
                           quantize_q80_jax)
@@ -786,7 +787,7 @@ def make_local_step(spec: TransformerSpec, n_slices: int, n_sp: int,
 
 
 def make_sharded_forward(spec: TransformerSpec, mesh: Mesh,
-                         scheme: str | None = None):
+                         scheme: str | None = None, name: str = "wrap"):
     """Build the jitted tensor-parallel forward for this mesh.
 
     Returns fn(params, cache, tokens (T,), pos) -> (logits (T, vocab), cache).
@@ -794,6 +795,9 @@ def make_sharded_forward(spec: TransformerSpec, mesh: Mesh,
     single-chip program; parity across tp sizes is the stage-4 gate of
     SURVEY.md §7). ``scheme`` (default: the active DLLAMA_TP_SCHEME) is
     resolved ONCE here — the built program never re-reads the env.
+    ``name`` is what a profiler capture calls the program's runs
+    (``jit_<name>``): the one forward serves as decode step and as
+    prefill chunk, which a capture must tell apart.
     """
     n_slices = mesh.shape["tp"]
     n_sp = mesh.shape.get("sp", 1)
@@ -808,7 +812,7 @@ def make_sharded_forward(spec: TransformerSpec, mesh: Mesh,
                         out_specs=out_specs)
         return fn(params, cache, tokens, pos)
 
-    return jax.jit(wrap, donate_argnums=1)
+    return jax.jit(named_program(name, wrap), donate_argnums=1)
 
 
 # batched cache (L, B, S, n_kv, hs): sequence chunks over sp, kv heads
@@ -1028,7 +1032,8 @@ def make_sharded_forward_batch_paged(spec: TransformerSpec, mesh: Mesh,
                         out_specs=out_specs)
         return fn(params, cache, tokens, pos, table)
 
-    return jax.jit(wrap, donate_argnums=1)
+    return jax.jit(named_program("serve_decode_step", wrap),
+                   donate_argnums=1)
 
 
 def make_sharded_verify(spec: TransformerSpec, mesh: Mesh, page_size: int,
@@ -1328,4 +1333,5 @@ def make_sharded_forward_batch(spec: TransformerSpec, mesh: Mesh,
                         out_specs=out_specs)
         return fn(params, cache, tokens, pos)
 
-    return jax.jit(wrap, donate_argnums=1)
+    return jax.jit(named_program("serve_decode_step", wrap),
+                   donate_argnums=1)

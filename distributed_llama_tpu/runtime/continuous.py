@@ -26,6 +26,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any
@@ -36,6 +37,7 @@ from ..io.tokenizer import BOS
 from ..models.spec import TransformerSpec
 from ..obs import tracectx
 from ..obs.ledger import CensusRing, LedgerBook
+from ..obs.spans import host_phase, named_program
 from .sampling import Sampler
 
 
@@ -142,7 +144,10 @@ def _maybe_bf16(fn, enable: bool, jax_mod, jit: bool = False):
         from ..ops.linear import bf16_prefill
 
         fn = bf16_prefill(fn)
-    return jax_mod.jit(fn, donate_argnums=1) if jit else fn
+    if not jit:
+        return fn
+    return jax_mod.jit(named_program("serve_admit_prefill_chunk", fn),
+                       donate_argnums=1)
 
 
 _q8_fallback_warned = False
@@ -286,8 +291,6 @@ class ContinuousEngine:
                  kv_host_pages: int = 0, kv_disk_dir: str | None = None,
                  kv_disk_bytes: int = 0, kv_tier_async: bool = True,
                  remote_pages: bool = False, slo_priority: bool = False):
-        import functools
-
         import jax
         import jax.numpy as jnp
 
@@ -516,7 +519,9 @@ class ContinuousEngine:
                 self._prefill_fwd = _shared_program(
                     ("sh_prefill", spec, mesh, scheme, fast_prefill),
                     lambda: _maybe_bf16(
-                        make_sharded_forward(spec, mesh, scheme=scheme),
+                        make_sharded_forward(
+                            spec, mesh, scheme=scheme,
+                            name="serve_admit_prefill_chunk"),
                         fast_prefill, jax))
                 self._scratch_cache = lambda: shard_cache(
                     init_cache(spec, dtype), mesh)
@@ -532,8 +537,9 @@ class ContinuousEngine:
                 self._step = _shared_program(
                     ("step_paged", spec, page_size, kv_quant),
                     lambda: jax.jit(
-                        functools.partial(forward_batch_paged, spec,
-                                          page_size, kv_quant=kv_quant),
+                        named_program("serve_decode_step", functools.partial(
+                            forward_batch_paged, spec, page_size,
+                            kv_quant=kv_quant)),
                         donate_argnums=1))
                 if spec_k:
                     self._verify_base = _shared_program(
@@ -556,7 +562,8 @@ class ContinuousEngine:
                 self._step = _shared_program(
                     ("step_ragged", spec),
                     lambda: jax.jit(
-                        functools.partial(forward_batch_ragged, spec),
+                        named_program("serve_decode_step", functools.partial(
+                            forward_batch_ragged, spec)),
                         donate_argnums=1))
             if prefill_chunk > 1:
                 # admission prefill: single-sequence T=chunk forward into a
@@ -571,7 +578,9 @@ class ContinuousEngine:
             # donate only the batched cache (updated in place); the scratch
             # sequence cache can't alias the rank-5 output
             self._insert = _shared_program(
-                ("insert",), lambda: jax.jit(_insert, donate_argnums=0))
+                ("insert",), lambda: jax.jit(
+                    named_program("serve_admit_insert", _insert),
+                    donate_argnums=0))
             if self._alloc is not None:
                 # paged prefill plumbing: gather the slot's pages into a
                 # virtual contiguous sequence cache (shared prefix k/v
@@ -587,12 +596,15 @@ class ContinuousEngine:
                        else scatter_pages)
                 self._gather_pages = _shared_program(
                     ("gather", kv_quant, page_size),
-                    lambda: jax.jit(lambda c, t, gp=gp: gp(c, t,
-                                                           page_size)))
+                    lambda: jax.jit(named_program(
+                        "serve_admit_gather",
+                        lambda c, t, gp=gp: gp(c, t, page_size))))
                 self._scatter_pages = _shared_program(
                     ("scatter", kv_quant, page_size),
                     lambda: jax.jit(
-                        lambda c, s, t, sp_=sp_: sp_(c, s, t, page_size),
+                        named_program(
+                            "serve_admit_scatter",
+                            lambda c, s, t, sp_=sp_: sp_(c, s, t, page_size)),
                         donate_argnums=0))
         # KV tiering (ISSUE 12): bind the allocator's device I/O — the
         # demotion read (pool page planes -> host numpy, models/llama.
@@ -946,10 +958,10 @@ class ContinuousEngine:
         parity gates). Returns active slots after the iteration."""
         jnp = self.jnp
         T = self.dispatch_tokens
-        self._drain_remote_inbox()
-        self._sweep_cancelled()
+        self._intake()
         self._admit()
-        self._settle_promotions(quiet)
+        with host_phase("serve.grow_pages"):
+            self._settle_promotions(quiet)
         pool = self._pool
         # span assignment BEFORE page growth: every candidate decode row
         # wants 1 position; the slice row wants its span. Deferral
@@ -985,34 +997,36 @@ class ContinuousEngine:
                     # budget and takes the whole staging width
                     extra = min(len(s.forced), T - 1)
                 spans[win] = 1 + extra
-        paused = self._grow_pages(pool, 1, quiet, spans=spans)
+        with host_phase("serve.grow_pages"):
+            paused = self._grow_pages(pool, 1, quiet, spans=spans)
         if all(s.free for s in pool):
             self._journal_sync()  # cover sweep/admit records this iteration
             return self._n_outstanding()
-        blk = self._stage_mixed
-        greedy_only = True
-        for b, s in enumerate(pool):
-            span = 0 if (s.free or b in paused or b in deferred) \
-                else spans.get(b, 0)
-            spans[b] = span
-            blk[b, 0] = span
-            blk[b, 1] = s.pos
-            blk[b, 2:] = 0
-            if span <= 0:
-                continue
-            if s.sampler.temperature != 0.0:
-                greedy_only = False
-            blk[b, 2] = s.token
-            for i, t in enumerate(s.forced[:span - 1]):
-                blk[b, 3 + i] = t
-        n_active0 = sum(1 for v in spans.values() if v > 0)
-        total_span = sum(spans.values())
-        # virtual overrun charge: a healthy dispatch fits the budget
-        # (sum(span) <= T); the overrun-budget mutation does not, and the
-        # virtual clock must see the extra device time it would cost
-        self.stats.overrun_steps += max(0, -(-total_span // T) - 1)
-        table = self._stage_tables()
-        run = self._mixed_program(greedy_only)
+        with host_phase("serve.stage"):
+            blk = self._stage_mixed
+            greedy_only = True
+            for b, s in enumerate(pool):
+                span = 0 if (s.free or b in paused or b in deferred) \
+                    else spans.get(b, 0)
+                spans[b] = span
+                blk[b, 0] = span
+                blk[b, 1] = s.pos
+                blk[b, 2:] = 0
+                if span <= 0:
+                    continue
+                if s.sampler.temperature != 0.0:
+                    greedy_only = False
+                blk[b, 2] = s.token
+                for i, t in enumerate(s.forced[:span - 1]):
+                    blk[b, 3 + i] = t
+            n_active0 = sum(1 for v in spans.values() if v > 0)
+            total_span = sum(spans.values())
+            # virtual overrun charge: a healthy dispatch fits the budget
+            # (sum(span) <= T); the overrun-budget mutation does not, and the
+            # virtual clock must see the extra device time it would cost
+            self.stats.overrun_steps += max(0, -(-total_span // T) - 1)
+            table = self._stage_tables()
+            run = self._mixed_program(greedy_only)
         t0 = time.monotonic()  # census/ledger wall charges need it even
         #                        when the engine runs metrics-dark
         with self._span("mixed", "decode", budget=T, tokens=total_span,
@@ -1020,10 +1034,13 @@ class ContinuousEngine:
             if self._chaos is not None:
                 self._chaos.on_dispatch()  # inside the armed window (the
                 #   injected stall IS the hang the watchdog must detect)
-            out, cache = run(self.params, self.cache, jnp.asarray(blk),
-                             table)
-            self.cache = cache
-            out = np.asarray(out)  # dlint: allow[D001] host replay reads ids/logits
+            with host_phase("serve.stage"):
+                staged = jnp.asarray(blk)
+            with host_phase("serve.dispatch"):
+                out, self.cache = run(self.params, self.cache, staged,
+                                      table)
+            with host_phase("serve.fetch"):
+                out = np.asarray(out)  # dlint: allow[D001] host replay reads ids/logits
             if self._obs is not None:
                 # the sync flag additionally drains the donated cache
                 # write (obs/trace.sync_device_timing)
@@ -1034,32 +1051,34 @@ class ContinuousEngine:
                 self._obs.record_step(time.monotonic() - t0, n_active0)
                 if self._alloc is not None:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
-        self.stats.steps += 1
-        self.stats.sum_active += n_active0
-        self.stats.max_active = max(self.stats.max_active, n_active0)
-        self._census_dispatch("mixed", 1, paused, n_active0,
-                              time.monotonic() - t0, deferred=deferred)
-        # host replay: exactly step_once's per-token bookkeeping over each
-        # row's live window (forced pops first; the sampler is consulted
-        # only at the last position, where the fed inputs ran out)
-        for b, s in enumerate(pool):
-            if s.free:
-                continue
-            if s.req.cancelled:  # consumer vanished during the dispatch
-                self._retire(s, quiet)
-                continue
-            span = spans.get(b, 0)
-            if span <= 0:
-                continue
-            for i in range(span):
-                if s.forced:
-                    nxt, sampled = s.forced.pop(0), False
-                elif greedy_only:
-                    nxt, sampled = int(out[b, i]), True
-                else:
-                    nxt, sampled = int(s.sampler.sample(out[b, i])), True
-                if self._advance(s, nxt, quiet, sampled=sampled):
-                    break
+        with host_phase("serve.census"):
+            self.stats.steps += 1
+            self.stats.sum_active += n_active0
+            self.stats.max_active = max(self.stats.max_active, n_active0)
+            self._census_dispatch("mixed", 1, paused, n_active0,
+                                  time.monotonic() - t0, deferred=deferred)
+        with host_phase("serve.sample"):
+            # host replay: exactly step_once's per-token bookkeeping over each
+            # row's live window (forced pops first; the sampler is consulted
+            # only at the last position, where the fed inputs ran out)
+            for b, s in enumerate(pool):
+                if s.free:
+                    continue
+                if s.req.cancelled:  # consumer vanished during the dispatch
+                    self._retire(s, quiet)
+                    continue
+                span = spans.get(b, 0)
+                if span <= 0:
+                    continue
+                for i in range(span):
+                    if s.forced:
+                        nxt, sampled = s.forced.pop(0), False
+                    elif greedy_only:
+                        nxt, sampled = int(out[b, i]), True
+                    else:
+                        nxt, sampled = int(s.sampler.sample(out[b, i])), True
+                    if self._advance(s, nxt, quiet, sampled=sampled):
+                        break
         self._admit()
         self._journal_sync()
         return self._n_outstanding()
@@ -1090,58 +1109,59 @@ class ContinuousEngine:
         K = self.spec_k
         from .speculative import accept_or_resample, draft_tokens
 
-        self._drain_remote_inbox()
-        self._sweep_cancelled()
+        self._intake()
         self._admit()
-        self._settle_promotions(quiet)
-        self._resume_prefills()
         pool = self._pool
-        paused = self._grow_pages(pool, K, quiet)
+        with host_phase("serve.grow_pages"):
+            self._settle_promotions(quiet)
+            self._resume_prefills()
+            paused = self._grow_pages(pool, K, quiet)
         if all(s.free for s in pool):
             self._journal_sync()  # cover sweep/admit records this iteration
             return self._n_outstanding()
-        st = self._stage_spec
-        st_pos = self._stage_i32  # row 1 = per-slot positions, as ever
-        active0 = self._stage_active
-        kinds: list = [()] * self.slots  # window entry i (= input i+1):
-        #                                   'f' forced | 'd' drafted
-        greedy_only = True
-        for b, s in enumerate(pool):
-            active0[b] = not s.free and b not in paused
-            st[b, 0] = s.token
-            st[b, 1:] = 0
-            st_pos[1, b] = s.pos
-            if not active0[b]:
-                continue
-            if s.sampler.temperature != 0.0:
-                greedy_only = False
-            window = list(s.forced[:K - 1])
-            row_kinds = ["f"] * len(window)
-            room = K - 1 - len(window)
-            if room > 0 and not s.forced[K - 1:]:
-                # drafting starts only past the forced prompt; the lookup
-                # history is the emitted stream plus the forced tokens fed
-                # ahead of the drafts in THIS window
-                history = [s.req.tokens[0]] + s.req.out + window
-                drafts = draft_tokens(history, room, max_n=self.spec_ngram)
-                self.stats.spec_proposed += len(drafts)
-                if drafts:
-                    self._census.count_tokens("spec", len(drafts))
-                    if s.req.ledger is not None:
-                        s.req.ledger.charge_spec(len(drafts), 0)
-                if self._obs is not None:
-                    self._obs.spec_proposed.inc(len(drafts))
+        with host_phase("serve.stage"):
+            st = self._stage_spec
+            st_pos = self._stage_i32  # row 1 = per-slot positions, as ever
+            active0 = self._stage_active
+            kinds: list = [()] * self.slots  # window entry i (= input i+1):
+            #                                   'f' forced | 'd' drafted
+            greedy_only = True
+            for b, s in enumerate(pool):
+                active0[b] = not s.free and b not in paused
+                st[b, 0] = s.token
+                st[b, 1:] = 0
+                st_pos[1, b] = s.pos
+                if not active0[b]:
+                    continue
+                if s.sampler.temperature != 0.0:
+                    greedy_only = False
+                window = list(s.forced[:K - 1])
+                row_kinds = ["f"] * len(window)
+                room = K - 1 - len(window)
+                if room > 0 and not s.forced[K - 1:]:
+                    # drafting starts only past the forced prompt; the lookup
+                    # history is the emitted stream plus the forced tokens fed
+                    # ahead of the drafts in THIS window
+                    history = [s.req.tokens[0]] + s.req.out + window
+                    drafts = draft_tokens(history, room, max_n=self.spec_ngram)
+                    self.stats.spec_proposed += len(drafts)
                     if drafts:
-                        self._obs.count_dispatch_tokens("spec",
-                                                        len(drafts))
-                window += [int(t) for t in drafts]
-                row_kinds += ["d"] * len(drafts)
-            for i, t in enumerate(window):
-                st[b, 1 + i] = t
-            kinds[b] = tuple(row_kinds)
-        n_active0 = int(active0.sum())
-        table = self._stage_tables()
-        run = self._verify_program(greedy_only)
+                        self._census.count_tokens("spec", len(drafts))
+                        if s.req.ledger is not None:
+                            s.req.ledger.charge_spec(len(drafts), 0)
+                    if self._obs is not None:
+                        self._obs.spec_proposed.inc(len(drafts))
+                        if drafts:
+                            self._obs.count_dispatch_tokens("spec",
+                                                            len(drafts))
+                    window += [int(t) for t in drafts]
+                    row_kinds += ["d"] * len(drafts)
+                for i, t in enumerate(window):
+                    st[b, 1 + i] = t
+                kinds[b] = tuple(row_kinds)
+            n_active0 = int(active0.sum())
+            table = self._stage_tables()
+            run = self._verify_program(greedy_only)
         t0 = time.monotonic()  # census/ledger wall charges need it even
         #                        when the engine runs metrics-dark
         with self._span("verify", "decode", k=K, active=n_active0), \
@@ -1150,10 +1170,12 @@ class ContinuousEngine:
                 self._chaos.on_dispatch()  # inside the armed window: an
                 #   injected stall is device work as far as the watchdog
                 #   can tell — exactly the hang it must detect
-            out, cache = run(self.params, self.cache, jnp.asarray(st),
-                             jnp.asarray(st_pos[1]), table)
-            self.cache = cache
-            out = np.asarray(out)  # dlint: allow[D001] host replay reads ids/logits
+            with host_phase("serve.stage"):
+                staged = (jnp.asarray(st), jnp.asarray(st_pos[1]), table)
+            with host_phase("serve.dispatch"):
+                out, self.cache = run(self.params, self.cache, *staged)
+            with host_phase("serve.fetch"):
+                out = np.asarray(out)  # dlint: allow[D001] host replay reads ids/logits
             if self._obs is not None:
                 # the sync flag additionally drains the donated cache
                 # write (obs/trace.sync_device_timing)
@@ -1164,56 +1186,59 @@ class ContinuousEngine:
                 self._obs.record_step(time.monotonic() - t0, n_active0)
                 if self._alloc is not None:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
-        self.stats.steps += 1
-        self.stats.sum_active += n_active0
-        self.stats.max_active = max(self.stats.max_active, n_active0)
-        self._census_dispatch("spec", 1, paused, n_active0,
-                              time.monotonic() - t0)
-        # host replay: exactly step_once's per-position bookkeeping over
-        # the accepted prefix of each row's window
-        for b, s in enumerate(pool):
-            if s.free:
-                continue
-            if s.req.cancelled:  # consumer vanished during the dispatch
-                self._retire(s, quiet)
-                continue
-            if not active0[b]:
-                continue
-            row_kinds = kinds[b]
-            retired = False
-            for i in range(K):
-                accepted_draft = False
-                if s.forced:
-                    nxt, sampled = s.forced.pop(0), False
-                elif s.sampler.temperature == 0.0:
-                    nxt = (int(out[b, i]) if greedy_only
-                           else int(np.argmax(
-                               out[b, i][:self.spec.vocab_size])))
-                    sampled = True
-                    accepted_draft = (i < len(row_kinds)
-                                      and row_kinds[i] == "d"
-                                      and nxt == int(st[b, i + 1]))
-                elif i < len(row_kinds) and row_kinds[i] == "d":
-                    nxt, accepted_draft = accept_or_resample(
-                        out[b, i], int(st[b, i + 1]), s.sampler)
-                    sampled = True
-                else:  # no draft fed here: the plain sampler path
-                    nxt, sampled = int(s.sampler.sample(out[b, i])), True
-                if accepted_draft:
-                    self.stats.spec_accepted += 1
-                    if s.req.ledger is not None:
-                        s.req.ledger.charge_spec(0, 1)
-                    if self._obs is not None:
-                        self._obs.spec_accepted.inc()
-                if self._advance(s, nxt, quiet, sampled=sampled):
-                    retired = True
-                    break
-                if (i + 1 >= K or i >= len(row_kinds)
-                        or nxt != int(st[b, i + 1])):
-                    break  # window exhausted, or the fed input was wrong —
-                #            logits[i+1] were conditioned on a bad token
-            if not retired:
-                self._trim_pages(s)
+        with host_phase("serve.census"):
+            self.stats.steps += 1
+            self.stats.sum_active += n_active0
+            self.stats.max_active = max(self.stats.max_active, n_active0)
+            self._census_dispatch("spec", 1, paused, n_active0,
+                                  time.monotonic() - t0)
+        with host_phase("serve.sample"):
+            # host replay: exactly step_once's per-position bookkeeping over
+            # the accepted prefix of each row's window
+            for b, s in enumerate(pool):
+                if s.free:
+                    continue
+                if s.req.cancelled:  # consumer vanished during the dispatch
+                    self._retire(s, quiet)
+                    continue
+                if not active0[b]:
+                    continue
+                row_kinds = kinds[b]
+                retired = False
+                for i in range(K):
+                    accepted_draft = False
+                    if s.forced:
+                        nxt, sampled = s.forced.pop(0), False
+                    elif s.sampler.temperature == 0.0:
+                        nxt = (int(out[b, i]) if greedy_only
+                               else int(np.argmax(
+                                   out[b, i][:self.spec.vocab_size])))
+                        sampled = True
+                        accepted_draft = (i < len(row_kinds)
+                                          and row_kinds[i] == "d"
+                                          and nxt == int(st[b, i + 1]))
+                    elif i < len(row_kinds) and row_kinds[i] == "d":
+                        nxt, accepted_draft = accept_or_resample(
+                            out[b, i], int(st[b, i + 1]), s.sampler)
+                        sampled = True
+                    else:  # no draft fed here: the plain sampler path
+                        nxt, sampled = int(s.sampler.sample(out[b, i])), True
+                    if accepted_draft:
+                        self.stats.spec_accepted += 1
+                        if s.req.ledger is not None:
+                            s.req.ledger.charge_spec(0, 1)
+                        if self._obs is not None:
+                            self._obs.spec_accepted.inc()
+                    if self._advance(s, nxt, quiet, sampled=sampled):
+                        retired = True
+                        break
+                    if (i + 1 >= K or i >= len(row_kinds)
+                            or nxt != int(st[b, i + 1])):
+                        # window exhausted, or the fed input was wrong:
+                        # logits[i+1] were conditioned on a bad token
+                        break
+                if not retired:
+                    self._trim_pages(s)
         self._admit()
         self._journal_sync()
         return self._n_outstanding()
@@ -1412,48 +1437,50 @@ class ContinuousEngine:
         if k <= 1:
             return self.step_once(quiet=quiet)
         jnp = self.jnp
-        self._drain_remote_inbox()
-        self._sweep_cancelled()
+        self._intake()
         self._admit()
-        self._settle_promotions(quiet)
-        self._resume_prefills()
         pool = self._pool
-        paused = (self._grow_pages(pool, k, quiet)
-                  if self._alloc is not None else ())
+        with host_phase("serve.grow_pages"):
+            self._settle_promotions(quiet)
+            self._resume_prefills()
+            paused = (self._grow_pages(pool, k, quiet)
+                      if self._alloc is not None else ())
         if all(s.free for s in pool):
             self._journal_sync()  # cover sweep/admit records this iteration
             return self._n_outstanding()
-        B = self.slots
-        st_i32, st_f32 = self._stage_i32, self._stage_f32
-        active0 = self._stage_active
-        forced = np.full((k, B), -1, dtype=np.int32)
-        coins = np.zeros((k, B), dtype=np.float32)
-        for b, s in enumerate(pool):
-            active0[b] = not s.free and b not in paused
-            st_i32[0, b] = s.token
-            st_i32[1, b] = s.pos
-            st_i32[2, b] = 0 if s.free else s.budget
-            st_f32[0, b] = 0.0 if s.free else s.sampler.temperature
-            st_f32[1, b] = 0.9 if s.free else s.sampler.topp
-            if s.free:
-                continue
-            for i, t in enumerate(s.forced[:k]):
-                forced[i, b] = t
-            if s.sampler.temperature != 0.0:
-                # pre-draw on a THROWAWAY copy; the real stream advances
-                # during replay by exactly the coins the per-step loop
-                # would consume. Coin alignment: forced steps draw NO coin,
-                # so chain step i uses draw #(i - n_forced) — the stream
-                # position the per-step loop would be at
-                n_forced = min(len(s.forced), k)
-                if n_forced < k:
-                    coins[n_forced:, b] = s.sampler.rng.clone().f32_array(
-                        k - n_forced)
+        with host_phase("serve.stage"):
+            B = self.slots
+            st_i32, st_f32 = self._stage_i32, self._stage_f32
+            active0 = self._stage_active
+            forced = np.full((k, B), -1, dtype=np.int32)
+            coins = np.zeros((k, B), dtype=np.float32)
+            for b, s in enumerate(pool):
+                active0[b] = not s.free and b not in paused
+                st_i32[0, b] = s.token
+                st_i32[1, b] = s.pos
+                st_i32[2, b] = 0 if s.free else s.budget
+                st_f32[0, b] = 0.0 if s.free else s.sampler.temperature
+                st_f32[1, b] = 0.9 if s.free else s.sampler.topp
+                if s.free:
+                    continue
+                for i, t in enumerate(s.forced[:k]):
+                    forced[i, b] = t
+                if s.sampler.temperature != 0.0:
+                    # pre-draw on a THROWAWAY copy; the real stream
+                    # advances during replay by exactly the coins the
+                    # per-step loop would consume. Coin alignment: forced
+                    # steps draw NO coin, so chain step i uses draw
+                    # #(i - n_forced) — the stream position the per-step
+                    # loop would be at
+                    n_forced = min(len(s.forced), k)
+                    if n_forced < k:
+                        coins[n_forced:, b] = \
+                            s.sampler.rng.clone().f32_array(k - n_forced)
 
-        n_active0 = int(active0.sum())
-        table = (self._stage_tables() if self._alloc is not None
-                 else jnp.zeros((B, 0), jnp.int32))
-        run = self._chain(k, greedy_only=not st_f32[0].any())
+            n_active0 = int(active0.sum())
+            table = (self._stage_tables() if self._alloc is not None
+                     else jnp.zeros((B, 0), jnp.int32))
+            run = self._chain(k, greedy_only=not st_f32[0].any())
         t0 = time.monotonic()  # census/ledger wall charges need it even
         #                        when the engine runs metrics-dark
         with self._span("chain", "decode", steps=k, active=n_active0), \
@@ -1461,13 +1488,16 @@ class ContinuousEngine:
             if self._chaos is not None:
                 self._chaos.on_dispatch()  # inside the armed window (the
                 #   injected stall IS the hang the watchdog must detect)
-            cache, toks, acts = run(
-                self.params, self.cache, jnp.asarray(st_i32),
-                jnp.asarray(active0), jnp.asarray(forced),
-                jnp.asarray(coins), jnp.asarray(st_f32), table)
-            self.cache = cache
-            toks = np.asarray(toks)  # dlint: allow[D001] chain outputs drive
-            acts = np.asarray(acts)  # dlint: allow[D001] the host replay below
+            with host_phase("serve.stage"):
+                staged = (jnp.asarray(st_i32), jnp.asarray(active0),
+                          jnp.asarray(forced), jnp.asarray(coins),
+                          jnp.asarray(st_f32), table)
+            with host_phase("serve.dispatch"):
+                self.cache, toks, acts = run(self.params, self.cache,
+                                             *staged)
+            with host_phase("serve.fetch"):
+                toks = np.asarray(toks)  # dlint: allow[D001] chain outputs drive
+                acts = np.asarray(acts)  # dlint: allow[D001] the host replay below
             if self._obs is not None:
                 # toks/acts above already synced the chain's host outputs;
                 # the sync flag additionally drains the donated cache write
@@ -1481,42 +1511,62 @@ class ContinuousEngine:
                                       steps=k)
                 if self._alloc is not None:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
-        self.stats.steps += k
-        self.stats.sum_active += n_active0 * k
-        self.stats.max_active = max(self.stats.max_active, n_active0)
-        self._census_dispatch("decode", k, paused, n_active0,
-                              time.monotonic() - t0)
-        # host replay: apply the recorded per-step outcomes with exactly
-        # step_once's bookkeeping (forced pops, RNG draws, BOS/budget stops)
-        for b, s in enumerate(pool):
-            if s.free:
-                continue
-            if s.req.cancelled:  # consumer vanished during the chain
-                self._retire(s, quiet)  # paused rows free their pages too
-                continue
-            if not active0[b]:
-                continue
-            for i in range(k):
-                if not acts[i, b]:
-                    break
-                sampled = not s.forced
-                if s.forced:
-                    s.forced.pop(0)
-                elif s.sampler.temperature != 0.0:
-                    s.sampler.rng.f32()  # the coin the chain consumed
-                if self._advance(s, int(toks[i, b]), quiet, sampled=sampled):
-                    break
+        with host_phase("serve.census"):
+            self.stats.steps += k
+            self.stats.sum_active += n_active0 * k
+            self.stats.max_active = max(self.stats.max_active, n_active0)
+            self._census_dispatch("decode", k, paused, n_active0,
+                                  time.monotonic() - t0)
+        with host_phase("serve.sample"):
+            # host replay: apply the recorded per-step outcomes with
+            # exactly step_once's bookkeeping (forced pops, RNG draws,
+            # BOS/budget stops)
+            for b, s in enumerate(pool):
+                if s.free:
+                    continue
+                if s.req.cancelled:  # consumer vanished during the chain
+                    self._retire(s, quiet)  # paused rows free their pages too
+                    continue
+                if not active0[b]:
+                    continue
+                for i in range(k):
+                    if not acts[i, b]:
+                        break
+                    sampled = not s.forced
+                    if s.forced:
+                        s.forced.pop(0)
+                    elif s.sampler.temperature != 0.0:
+                        s.sampler.rng.f32()  # the coin the chain consumed
+                    if self._advance(s, int(toks[i, b]), quiet,
+                                     sampled=sampled):
+                        break
         self._admit()
         self._journal_sync()
         return self._n_outstanding()
 
-    def _span(self, name: str, cat: str, **meta):
-        """A timeline span when tracing is on; a free nullcontext when the
-        engine runs dark (the zero-calls-when-disabled contract covers the
-        span tracer too)."""
-        if self._spans is None:
-            return contextlib.nullcontext()
-        return self._spans.span(name, cat, **meta)
+    @contextlib.contextmanager
+    def _span(self, name: str, cat: str, phase: str | None = "serve.decode",
+              **meta):
+        """A span on both rails: the ring's ``name`` on ``perf_counter``
+        when tracing is on (a dark engine records none: the zero-calls-
+        when-disabled contract covers the span tracer too), and ALWAYS its
+        twin ``phase`` on the profiler's clock, so a capture of an engine
+        built without a registry shows phases too. A dispatch's twin is
+        ``serve.decode``, parent of its stage, dispatch and fetch phases;
+        the admission prefill has none of its own (``phase=None``: its
+        gather, chunk and scatter phases tile it)."""
+        null = contextlib.nullcontext()
+        ring = (self._spans.span(name, cat, **meta)
+                if self._spans is not None else null)
+        with host_phase(phase) if phase else null, ring:
+            yield
+
+    def _intake(self) -> None:
+        """An iteration's head: handoff pages and cancellations that
+        arrived since the last dispatch."""
+        with host_phase("serve.intake"):
+            self._drain_remote_inbox()
+            self._sweep_cancelled()
 
     def _watch(self):
         """Arm the step watchdog around a device dispatch (supervisor.
@@ -1531,8 +1581,9 @@ class ContinuousEngine:
         check. Called at the end of every step path."""
         if self._journal is None:
             return
-        self._journal.sync()
-        self._journal.maybe_compact()
+        with host_phase("serve.journal"):
+            self._journal.sync()
+            self._journal.maybe_compact()
 
     # -- cost accounting (ISSUE 16) -----------------------------------------
 
@@ -1924,14 +1975,14 @@ class ContinuousEngine:
         step (0 = idle: nothing queued, nothing in flight). Must be called
         from a single scheduler thread; submit() may race freely."""
         jnp = self.jnp
-        self._drain_remote_inbox()
-        self._sweep_cancelled()
+        self._intake()
         self._admit()
-        self._settle_promotions(quiet)
-        self._resume_prefills()
         pool = self._pool
-        paused = (self._grow_pages(pool, 1, quiet)
-                  if self._alloc is not None else ())
+        with host_phase("serve.grow_pages"):
+            self._settle_promotions(quiet)
+            self._resume_prefills()
+            paused = (self._grow_pages(pool, 1, quiet)
+                      if self._alloc is not None else ())
         if all(s.free for s in pool):
             self._journal_sync()  # cover sweep/admit records this iteration
             return self._n_outstanding()
@@ -1942,25 +1993,27 @@ class ContinuousEngine:
         t0 = time.monotonic()  # census/ledger wall charges need it even
         #                        when the engine runs metrics-dark
         st = self._stage_i32
-        for b, s in enumerate(pool):
-            st[0, b] = s.token
-            st[1, b] = s.pos
+        with host_phase("serve.stage"):
+            for b, s in enumerate(pool):
+                st[0, b] = s.token
+                st[1, b] = s.pos
         with self._span("step", "decode", active=active0), self._watch():
             if self._chaos is not None:
                 self._chaos.on_dispatch()  # inside the armed window (the
                 #   injected stall IS the hang the watchdog must detect)
-            # one staged upload; the row splits are lazy device-side
-            # slices, so the shared step program keeps its (tokens, pos)
-            # signature
-            staged = jnp.asarray(st[:2])
-            if self._alloc is not None:
-                logits, self.cache = self._step(
-                    self.params, self.cache, staged[0], staged[1],
-                    self._stage_tables())
-            else:
+            with host_phase("serve.stage"):
+                # one staged upload; the row splits are lazy device-side
+                # slices, so the shared step program keeps its
+                # (tokens, pos) signature
+                staged = jnp.asarray(st[:2])
+                rows = [staged[0], staged[1]]
+                if self._alloc is not None:
+                    rows.append(self._stage_tables())
+            with host_phase("serve.dispatch"):
                 logits, self.cache = self._step(self.params, self.cache,
-                                                staged[0], staged[1])
-            logits = np.asarray(logits)  # dlint: allow[D001] host sampler needs logits
+                                                *rows)
+            with host_phase("serve.fetch"):  # the wait and the transfer
+                logits = np.asarray(logits)  # dlint: allow[D001] host sampler needs logits
             if self._obs is not None:
                 # np.asarray synced the logits; the sync flag also drains
                 # the donated cache write (obs/trace.sync_device_timing)
@@ -1971,25 +2024,27 @@ class ContinuousEngine:
                 self._obs.record_step(time.monotonic() - t0, active0)
                 if self._alloc is not None:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
-        self.stats.steps += 1
-        self.stats.sum_active += active0
-        self.stats.max_active = max(self.stats.max_active, active0)
-        self._census_dispatch("decode", 1, paused, active0,
-                              time.monotonic() - t0)
-        for i, s in enumerate(pool):
-            if s.free:
-                continue
-            if s.req.cancelled:  # consumer gone: free the slot now
-                self._retire(s, quiet)
-                continue
-            if i in paused:  # starved of pages: frozen, retries next step
-                continue
-            if s.forced:
-                nxt = s.forced.pop(0)
-                self._advance(s, nxt, quiet)
-            else:
-                nxt = int(s.sampler.sample(logits[i]))
-                self._advance(s, nxt, quiet, sampled=True)
+        with host_phase("serve.census"):
+            self.stats.steps += 1
+            self.stats.sum_active += active0
+            self.stats.max_active = max(self.stats.max_active, active0)
+            self._census_dispatch("decode", 1, paused, active0,
+                                  time.monotonic() - t0)
+        with host_phase("serve.sample"):
+            for i, s in enumerate(pool):
+                if s.free:
+                    continue
+                if s.req.cancelled:  # consumer gone: free the slot now
+                    self._retire(s, quiet)
+                    continue
+                if i in paused:  # starved of pages: frozen until a retry
+                    continue
+                if s.forced:
+                    nxt = s.forced.pop(0)
+                    self._advance(s, nxt, quiet)
+                else:
+                    nxt = int(s.sampler.sample(logits[i]))
+                    self._advance(s, nxt, quiet, sampled=True)
         self._admit()
         self._journal_sync()
         return self._n_outstanding()
@@ -2133,52 +2188,65 @@ class ContinuousEngine:
         return "ok"
 
     def _admit(self):
-        spec = self.spec
+        """Fill free slots from the queue, each admission inside the host
+        phase ``serve.admit`` (with the request's trace identity as the
+        event's arguments)."""
         for slot_index, s in enumerate(self._pool):
             while s.free:
                 req = self._pop_request()
                 if req is None:
                     return
-                req.t_admit = time.monotonic()
-                s.req, s.pos = req, 0
-                s.token = req.tokens[0]
-                s.forced = list(req.tokens[1:])
-                s.budget = min(req.steps, spec.seq_len)
-                temp = (req.temperature if req.temperature is not None
-                        else self.temperature)
-                topp = req.topp if req.topp is not None else self.topp
-                seed = (req.seed if req.seed is not None
-                        else self.seed + req.index)
-                s.sampler = Sampler(spec.vocab_size, temp, topp, seed,
-                                    use_native=self.use_native_sampler)
-                if req.coin_cursor:
-                    # journal recovery: fast-forward the xorshift stream
-                    # past the coins a previous life already consumed —
-                    # the already-sampled tokens ride the forced window
-                    # (no draws), so the first NEW sample uses exactly
-                    # the coin the uninterrupted run would have
-                    s.sampler.rng.skip(req.coin_cursor)
-                if self._alloc is not None:
-                    if self._admit_paged(s) == "dry":
-                        self._requeue_front(s)
-                        return
-                    if self._alloc.pending_capable \
-                            and self._alloc.slot_pending(s.pages):
-                        # shared prefix promoting from host/disk (or
-                        # riding a DCN handoff upload): defer
-                        # admission prefill until the upload lands
-                        # (_settle_promotions) — gathering now would
-                        # read junk where the payload hasn't arrived
-                        s.await_promo = True
-                        break
-                self._maybe_prefill_slot(slot_index, s)
-                if s.req.cancelled:
-                    # consumer vanished during admission/prefill: free the
-                    # slot AND its pages NOW — a cancelled prefill must not
-                    # pin pool pages until the next chain boundary
-                    self._retire(s, quiet=True)
-                    continue
-                break  # slot filled
+                with host_phase("serve.admit",
+                                **tracectx.span_fields(req.trace)):
+                    if not self._place(slot_index, s, req):
+                        return  # pool dry: requeued at the head
+
+    def _place(self, slot_index: int, s: _Slot, req: Request) -> bool:
+        """Put ``req`` into the free slot ``s``: sampler, pages, admission
+        prefill. False = the page pool cannot serve it yet (requeued, and
+        admission stops); True otherwise, the slot then holds the request
+        unless its consumer vanished meanwhile (retired: the slot is free
+        again and ``_admit`` pops the next)."""
+        spec = self.spec
+        req.t_admit = time.monotonic()
+        s.req, s.pos = req, 0
+        s.token = req.tokens[0]
+        s.forced = list(req.tokens[1:])
+        s.budget = min(req.steps, spec.seq_len)
+        temp = (req.temperature if req.temperature is not None
+                else self.temperature)
+        topp = req.topp if req.topp is not None else self.topp
+        seed = (req.seed if req.seed is not None
+                else self.seed + req.index)
+        s.sampler = Sampler(spec.vocab_size, temp, topp, seed,
+                            use_native=self.use_native_sampler)
+        if req.coin_cursor:
+            # journal recovery: fast-forward the xorshift stream
+            # past the coins a previous life already consumed —
+            # the already-sampled tokens ride the forced window
+            # (no draws), so the first NEW sample uses exactly
+            # the coin the uninterrupted run would have
+            s.sampler.rng.skip(req.coin_cursor)
+        if self._alloc is not None:
+            if self._admit_paged(s) == "dry":
+                self._requeue_front(s)
+                return False
+            if self._alloc.pending_capable \
+                    and self._alloc.slot_pending(s.pages):
+                # shared prefix promoting from host/disk (or
+                # riding a DCN handoff upload): defer
+                # admission prefill until the upload lands
+                # (_settle_promotions) — gathering now would
+                # read junk where the payload hasn't arrived
+                s.await_promo = True
+                return True
+        self._maybe_prefill_slot(slot_index, s)
+        if s.req.cancelled:
+            # consumer vanished during admission/prefill: free the
+            # slot AND its pages NOW — a cancelled prefill must not
+            # pin pool pages until the next chain boundary
+            self._retire(s, quiet=True)
+        return True
 
     def _maybe_prefill_slot(self, slot_index: int, s: _Slot):
         """Admission prefill: fill the slot's cache rows for the prompt
@@ -2224,29 +2292,32 @@ class ContinuousEngine:
         hold = (self.prefill_hold
                 if paged and self.kv_quant == "f32" else None)
         end = n_pre
-        with self._span("prefill", "prefill", slot=slot_index,
+        with self._span("prefill", "prefill", phase=None, slot=slot_index,
                         tokens=n_pre - start,
                         **tracectx.span_fields(s.req.trace)):
-            if paged:
-                # seed a virtual contiguous sequence cache from the slot's
-                # pages: the unshared-suffix chunks attend over the shared
-                # prefix k/v, positions start.. are written before any
-                # later chunk reads them, and the scatter puts everything
-                # back in place (shared pages get byte-identical content)
-                from .paging import SCRAP_PAGE
+            with host_phase("serve.admit.gather"):
+                if paged:
+                    # seed a virtual contiguous sequence cache from the
+                    # slot's pages: the unshared-suffix chunks attend over
+                    # the shared prefix k/v, positions start.. are written
+                    # before any later chunk reads them, and the scatter
+                    # puts everything back in place (shared pages get
+                    # byte-identical content)
+                    from .paging import SCRAP_PAGE
 
-                tbl = np.full((self._max_pages,), SCRAP_PAGE, np.int32)
-                tbl[:len(s.pages)] = s.pages
-                tbl_dev = jnp.asarray(tbl)
-                cache_box = [self._gather_pages(self.cache, tbl_dev)]
-            else:
-                cache_box = [self._scratch_cache()]
+                    tbl = np.full((self._max_pages,), SCRAP_PAGE, np.int32)
+                    tbl[:len(s.pages)] = s.pages
+                    tbl_dev = jnp.asarray(tbl)
+                    cache_box = [self._gather_pages(self.cache, tbl_dev)]
+                else:
+                    cache_box = [self._scratch_cache()]
 
             def fwd(part, start_pos):
                 self.stats.prefill_chunks += 1
-                _, cache_box[0] = self._prefill_fwd(
-                    self.params, cache_box[0], jnp.asarray(part, jnp.int32),
-                    jnp.int32(start_pos))
+                with host_phase("serve.admit.prefill_chunk"):
+                    _, cache_box[0] = self._prefill_fwd(
+                        self.params, cache_box[0],
+                        jnp.asarray(part, jnp.int32), jnp.int32(start_pos))
 
             if hold is None:
                 run_chunked_prefill(fwd, tokens[start:n_pre], start, chunk,
@@ -2268,31 +2339,33 @@ class ContinuousEngine:
                             and hold(s)):
                         end = lo
                         break
-            if paged:
-                if self.kv_quant == "q8":
-                    # q8 scatter must NOT re-quantize pages whose bytes
-                    # were published by an EARLIER encode (quantize∘
-                    # dequantize moves bytes): the shared prefix keeps
-                    # its first publisher's encoding, and a preemption
-                    # resume keeps the pages its previous rounds already
-                    # wrote — their scatter entries park on the scrap
-                    # page. The gather above still reads them: suffix
-                    # chunks attend over the dequantized prefix.
-                    tbl_sc = tbl.copy()
-                    tbl_sc[:max(s.shared, start // self.page_size)] = \
-                        SCRAP_PAGE
-                    tbl_scatter = jnp.asarray(tbl_sc)
-                else:
+            with host_phase("serve.admit.scatter"):
+                if paged:
                     tbl_scatter = tbl_dev
-                self.cache = self._scatter_pages(self.cache, cache_box[0],
-                                                 tbl_scatter)
-                # publish the freshly prefilled full prompt pages NOW (not
-                # just at retire): a same-system-prompt request admitted
-                # into the next slot this very round already shares them
-                self._alloc.insert_prefix(tokens[:end], s.pages)
-            else:
-                self.cache = self._insert(self.cache, cache_box[0],
-                                          jnp.int32(slot_index))
+                    if self.kv_quant == "q8":
+                        # q8 scatter must NOT re-quantize pages whose
+                        # bytes were published by an EARLIER encode
+                        # (quantize∘dequantize moves bytes): the shared
+                        # prefix keeps its first publisher's encoding,
+                        # and a preemption resume keeps the pages its
+                        # previous rounds already wrote — their scatter
+                        # entries park on the scrap page. The gather
+                        # above still reads them: suffix chunks attend
+                        # over the dequantized prefix.
+                        tbl_sc = tbl.copy()
+                        tbl_sc[:max(s.shared, start // self.page_size)] \
+                            = SCRAP_PAGE
+                        tbl_scatter = jnp.asarray(tbl_sc)
+                    self.cache = self._scatter_pages(
+                        self.cache, cache_box[0], tbl_scatter)
+                    # publish the freshly prefilled full prompt pages NOW
+                    # (not just at retire): a same-system-prompt request
+                    # admitted into the next slot this very round already
+                    # shares them
+                    self._alloc.insert_prefix(tokens[:end], s.pages)
+                else:
+                    self.cache = self._insert(self.cache, cache_box[0],
+                                              jnp.int32(slot_index))
         # echo the prefilled prompt tokens into the output AND the token
         # count (the step loop both appends forced tokens and counts them —
         # "Generated tokens" must not change meaning with the toggle)

@@ -27,6 +27,7 @@ from ..models.llama import forward, init_cache
 from ..models.spec import TransformerSpec
 from ..obs.log import log_event
 from ..obs.metrics import summarize_values
+from ..obs.spans import host_phase, named_program
 from ..parallel.comm_stats import (CommStats, ici_all_gather_bytes,
                                    sp_lse_bytes, tp_scheme)
 from .sampling import Sampler
@@ -64,7 +65,8 @@ class Engine:
             self.params = shard_params(params, mesh, scheme=self.tp_scheme)
             self.cache = shard_cache(init_cache(spec, self.cache_dtype), mesh)
             self._fwd = make_sharded_forward(spec, mesh,
-                                             scheme=self.tp_scheme)
+                                             scheme=self.tp_scheme,
+                                             name="inference_step")
             self._step_raw = self._fwd  # shard_map wrapper; traceable in scan
         else:
             from ..models.llama import params_to_device
@@ -72,26 +74,32 @@ class Engine:
             self.params = params_to_device(params, spec=spec)
             self.cache = init_cache(spec, self.cache_dtype)
             self._step_raw = functools.partial(forward, spec)
-            self._fwd = jax.jit(self._step_raw, donate_argnums=1)
+            self._fwd = jax.jit(
+                named_program("inference_step", self._step_raw),
+                donate_argnums=1)
+        # a SECOND jit of the same forward for the T>8 prefill chunks, so
+        # that a capture tells a chunk's program run from a decode step's
+        # by name; decode and the T=1 prefill tail share ``_fwd``. Under
+        # fast_prefill it is traced with bf16 matmul precision
+        # (ops/linear.bf16_prefill). Documented tolerance:
+        # tests/test_prefill.py pins the prefilled-cache drift bound.
+        chunk_fwd = self._step_raw
         if fast_prefill:
-            # a SECOND compiled forward, traced under bf16 matmul precision
-            # (ops/linear.bf16_prefill) — used only for T>8 prefill chunks;
-            # decode and the T=1 prefill tail keep the parity program.
-            # Documented tolerance: tests/test_prefill.py pins the
-            # prefilled-cache drift bound.
             from ..ops.linear import bf16_prefill
 
-            self._fwd_prefill = jax.jit(bf16_prefill(self._step_raw),
-                                        donate_argnums=1)
-        else:
-            self._fwd_prefill = None
+            chunk_fwd = bf16_prefill(chunk_fwd)
+        self._fwd_prefill = jax.jit(
+            named_program("inference_prefill_chunk", chunk_fwd),
+            donate_argnums=1)
 
     def infer(self, token: int, pos: int) -> np.ndarray:
         """One decode step; returns f32 logits (vocab,). Blocks on device."""
-        tok = self.jnp.asarray([token], dtype=self.jnp.int32)
-        logits, self.cache = self._fwd(self.params, self.cache, tok,
-                                       self.jnp.int32(pos))
-        return np.asarray(logits[0])  # dlint: allow[D001] host sampler input
+        with host_phase("inference.dispatch"):
+            tok = self.jnp.asarray([token], dtype=self.jnp.int32)
+            logits, self.cache = self._fwd(self.params, self.cache, tok,
+                                           self.jnp.int32(pos))
+        with host_phase("inference.fetch"):  # the wait and the transfer
+            return np.asarray(logits[0])  # dlint: allow[D001] host sampler input
 
     def prefill(self, tokens: list[int], pos0: int = 0,
                 chunk: int = 128) -> None:
@@ -145,13 +153,14 @@ class Engine:
             return
 
         def fwd(part, start):
-            # fast-prefill (bf16) applies to the T>8 MXU-bound chunks only;
-            # the T=1 tail shares the decode parity program
-            f = (self._fwd_prefill if self._fwd_prefill is not None
-                 and len(part) > 8 else self._fwd)
-            _, self.cache = f(self.params, self.cache,
-                              jnp.asarray(part, jnp.int32),
-                              jnp.int32(start))
+            # the chunk program (bf16 under fast-prefill) runs the T>8
+            # MXU-bound chunks only; the T=1 tail shares the decode
+            # parity program
+            f = self._fwd_prefill if len(part) > 8 else self._fwd
+            with host_phase("inference.prefill_chunk"):
+                _, self.cache = f(self.params, self.cache,
+                                  jnp.asarray(part, jnp.int32),
+                                  jnp.int32(start))
 
         run_chunked_prefill(fwd, rest, rest_pos, chunk, seq_len)
 
@@ -345,42 +354,47 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
         if pos + 1 < len(prompt_tokens):
             next_token = prompt_tokens[pos + 1]
         else:
-            next_token = sampler.sample(logits)
+            with host_phase("inference.sampler"):
+                next_token = sampler.sample(logits)
         t2 = time.perf_counter()
 
-        gen_ms = (t2 - t0) * 1000
-        stats.tokens += 1
-        stats.total_ms += gen_ms
-        stats.infer_ms += (t1 - t0) * 1000
-        stats.host_ms += (t2 - t1) * 1000
-        stats.token_ms.append(gen_ms)
+        with host_phase("inference.emit"):
+            gen_ms = (t2 - t0) * 1000
+            stats.tokens += 1
+            stats.total_ms += gen_ms
+            stats.infer_ms += (t1 - t0) * 1000
+            stats.host_ms += (t2 - t1) * 1000
+            stats.token_ms.append(gen_ms)
 
-        pos += 1
-        stats.final_pos, stats.final_token = pos, int(next_token)
-        stats.prompt_rest = [t for t in prompt_tokens[pos + 1:] if t >= 0]
-        if next_token == BOS:
-            break  # reference stops on BOS before decoding it (tokenizer.cpp:376)
-        out_tokens.append(next_token)
-        piece = tokenizer.decode_piece(token, next_token)
-        if emit is not None:
-            emit(piece.decode("utf-8", errors="replace"))
-        if not quiet:
-            # the 🔶 reference stats line, or one NDJSON object per token
-            # with the same fields under DLLAMA_LOG_JSON=1 (obs/log.py)
-            log_event(
-                "decode.token",
-                f"🔶 G {gen_ms:7.2f} ms I {(t1 - t0) * 1000:7.2f} ms "
-                f"T {(t2 - t1) * 1000:7.2f} ms "
-                f"S {comm.sent_bytes / 1024:7.0f} kB "
-                f"R {comm.recv_bytes / 1024:7.0f} kB "
-                f"{piece.decode('utf-8', errors='replace')!r}",
-                pos=pos, token=int(next_token),
-                gen_ms=round(gen_ms, 3),
-                infer_ms=round((t1 - t0) * 1000, 3),
-                host_ms=round((t2 - t1) * 1000, 3),
-                sent_bytes=comm.sent_bytes, recv_bytes=comm.recv_bytes,
-                piece=piece.decode("utf-8", errors="replace"))
-        token = next_token
+            pos += 1
+            stats.final_pos, stats.final_token = pos, int(next_token)
+            stats.prompt_rest = [t for t in prompt_tokens[pos + 1:]
+                                 if t >= 0]
+            if next_token == BOS:
+                # reference stops on BOS before decoding it
+                # (tokenizer.cpp:376)
+                break
+            out_tokens.append(next_token)
+            piece = tokenizer.decode_piece(token, next_token)
+            if emit is not None:
+                emit(piece.decode("utf-8", errors="replace"))
+            if not quiet:
+                # the 🔶 reference stats line, or one NDJSON object per token
+                # with the same fields under DLLAMA_LOG_JSON=1 (obs/log.py)
+                log_event(
+                    "decode.token",
+                    f"🔶 G {gen_ms:7.2f} ms I {(t1 - t0) * 1000:7.2f} ms "
+                    f"T {(t2 - t1) * 1000:7.2f} ms "
+                    f"S {comm.sent_bytes / 1024:7.0f} kB "
+                    f"R {comm.recv_bytes / 1024:7.0f} kB "
+                    f"{piece.decode('utf-8', errors='replace')!r}",
+                    pos=pos, token=int(next_token),
+                    gen_ms=round(gen_ms, 3),
+                    infer_ms=round((t1 - t0) * 1000, 3),
+                    host_ms=round((t2 - t1) * 1000, 3),
+                    sent_bytes=comm.sent_bytes, recv_bytes=comm.recv_bytes,
+                    piece=piece.decode("utf-8", errors="replace"))
+            token = next_token
 
     if stats.tokens:
         # the SAME summary shape the serving metrics expose (/health,

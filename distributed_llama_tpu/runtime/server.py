@@ -73,6 +73,7 @@ from ..io.tokenizer import Tokenizer
 from ..models.spec import TransformerSpec
 from ..obs import tracectx
 from ..obs.log import log_event
+from ..obs.spans import host_phase
 from .continuous import ContinuousEngine, Request
 from .supervisor import HealthMonitor, StepWatchdog
 
@@ -1102,7 +1103,10 @@ class InferenceServer:
                 except ValueError:
                     pass  # drain/stop raced us: their state wins
             if active == 0:
-                time.sleep(_IDLE_SLEEP_S)
+                # idle for want of work, not for want of host speed: a
+                # capture must tell the two apart
+                with host_phase("serve.idle"):
+                    time.sleep(_IDLE_SLEEP_S)
 
     def _outstanding(self) -> int:
         with self.engine._lock:
