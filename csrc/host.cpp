@@ -13,6 +13,7 @@
 #include <cstdint>
 #include <cstring>
 #include <cmath>
+#include <functional>
 #include <string>
 #include <thread>
 #include <unordered_map>
@@ -47,10 +48,12 @@ static inline float f16_to_f32(uint16_t h) {
         if (m == 0) {
             bits = s;
         } else {  // subnormal
+            // m * 2^-24: after ``shift`` doublings bit 10 is the implicit
+            // one, so the exponent is -14 - shift
             int shift = 0;
             while (!(m & 0x400)) { m <<= 1; shift++; }
             m &= 0x3FF;
-            bits = s | ((127 - 15 - shift) << 23) | (m << 13);
+            bits = s | ((127 - 14 - shift) << 23) | (m << 13);
         }
     } else if (e == 31) {
         bits = s | 0x7F800000 | (m << 13);
@@ -187,29 +190,71 @@ static void tile_planes(const uint8_t* qs, uint8_t* qs_t,
     }
 }
 
-void q40_tile_kernel_layout(const uint8_t* qs, const uint16_t* d16,
-                            uint8_t* qs_t, float* scale, int64_t n_stacked,
-                            int64_t d, int64_t nb, int32_t n_threads) {
-    const int64_t work = n_stacked * 16;
+// [0, work) in n_threads contiguous ranges, one thread each; f(lo, hi).
+static void run_ranges(int64_t work, int32_t n_threads,
+                       const std::function<void(int64_t, int64_t)>& f) {
     if (n_threads < 1) n_threads = 1;
     if (n_threads > work) n_threads = (int32_t)work;
     std::vector<std::thread> ts;
     ts.reserve((size_t)n_threads);
-    for (int32_t t = 0; t < n_threads; t++) {
-        int64_t lo = work * t / n_threads, hi = work * (t + 1) / n_threads;
-        ts.emplace_back(tile_planes, qs, qs_t, d, nb, lo, hi);
-    }
+    for (int32_t t = 0; t < n_threads; t++)
+        ts.emplace_back(f, work * t / n_threads, work * (t + 1) / n_threads);
     for (auto& th : ts) th.join();
-    const int64_t ns = n_stacked * d * nb;  // scales: f16 -> f32, threaded
-    std::vector<std::thread> ss;
-    ss.reserve((size_t)n_threads);
-    for (int32_t t = 0; t < n_threads; t++) {
-        int64_t lo = ns * t / n_threads, hi = ns * (t + 1) / n_threads;
-        ss.emplace_back([=]() {
-            for (int64_t i = lo; i < hi; i++) scale[i] = f16_to_f32(d16[i]);
-        });
+}
+
+void q40_tile_kernel_layout(const uint8_t* qs, const uint16_t* d16,
+                            uint8_t* qs_t, float* scale, int64_t n_stacked,
+                            int64_t d, int64_t nb, int32_t n_threads) {
+    run_ranges(n_stacked * 16, n_threads, [=](int64_t lo, int64_t hi) {
+        tile_planes(qs, qs_t, d, nb, lo, hi);
+    });
+    // scales: f16 -> f32, threaded
+    run_ranges(n_stacked * d * nb, n_threads, [=](int64_t lo, int64_t hi) {
+        for (int64_t i = lo; i < hi; i++) scale[i] = f16_to_f32(d16[i]);
+    });
+}
+
+// nb-major sibling: (N, d, nb, 16) -> (N, 16, nb, d) codes and (N, d, nb) f16
+// -> (N, nb, d) f32 scales (io/loader.Q40KernelNb: the output dim d minor).
+// Work is cut into (stacked slice, band of TILE_I rows): a band's source
+// lines (one per row for four adjacent blocks) stay in L1 across the 16
+// nibble planes and the blocks that share them, so the source is read once
+// and every write is a run of TILE_I bytes — the plane-at-a-time loop above
+// reads the source 16 times.
+
+constexpr int64_t TILE_I = 128;
+
+static void tile_bands_nb(const uint8_t* qs, const uint16_t* d16,
+                          uint8_t* qs_t, float* scale, int64_t d, int64_t nb,
+                          int64_t lo, int64_t hi) {
+    const int64_t bands = (d + TILE_I - 1) / TILE_I;
+    for (int64_t w = lo; w < hi; w++) {
+        const int64_t s = w / bands, i0 = (w % bands) * TILE_I;
+        const int64_t i1 = i0 + TILE_I < d ? i0 + TILE_I : d;
+        const uint8_t* src_s = qs + s * d * nb * 16;
+        uint8_t* dst_s = qs_t + s * 16 * nb * d;
+        const uint16_t* ssrc = d16 + s * d * nb;
+        float* sdst = scale + s * nb * d;
+        for (int64_t b = 0; b < nb; b++) {
+            for (int64_t j = 0; j < 16; j++) {
+                const uint8_t* src = src_s + b * 16 + j;
+                uint8_t* dst = dst_s + (j * nb + b) * d;
+                for (int64_t i = i0; i < i1; i++) dst[i] = src[i * nb * 16];
+            }
+            for (int64_t i = i0; i < i1; i++)
+                sdst[b * d + i] = f16_to_f32(ssrc[i * nb + b]);
+        }
     }
-    for (auto& th : ss) th.join();
+}
+
+void q40_tile_kernel_layout_nb(const uint8_t* qs, const uint16_t* d16,
+                               uint8_t* qs_t, float* scale,
+                               int64_t n_stacked, int64_t d, int64_t nb,
+                               int32_t n_threads) {
+    run_ranges(n_stacked * ((d + TILE_I - 1) / TILE_I), n_threads,
+               [=](int64_t lo, int64_t hi) {
+                   tile_bands_nb(qs, d16, qs_t, scale, d, nb, lo, hi);
+               });
 }
 
 // ---- BPE tokenizer encode (reference src/tokenizer.cpp:84-204 semantics) ---

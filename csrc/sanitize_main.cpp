@@ -20,6 +20,10 @@ void q80_encode(const float* in, uint8_t* out, int64_t nb);
 void q40_tile_kernel_layout(const uint8_t* qs, const uint16_t* d16,
                             uint8_t* qs_t, float* scale, int64_t n_stacked,
                             int64_t d, int64_t nb, int32_t n_threads);
+void q40_tile_kernel_layout_nb(const uint8_t* qs, const uint16_t* d16,
+                               uint8_t* qs_t, float* scale,
+                               int64_t n_stacked, int64_t d, int64_t nb,
+                               int32_t n_threads);
 void* tok_create(const uint8_t* blob, const int64_t* offsets,
                  const float* scores, int32_t n);
 void tok_destroy(void* handle);
@@ -54,6 +58,19 @@ int main() {
                            ns, d, tnb, 64 /* > work: clamps */);
     q40_tile_kernel_layout(qs.data(), d16.data(), qs_t.data(), scale.data(),
                            1, 1, 1, 1);
+    // nb-major sibling: a row band cut short (d = 8 < 128) and a block
+    // tile cut short (nb = 4 < 8), then more than one band with a ragged
+    // last one (d = 200) and a ragged block tile (nb = 11)
+    q40_tile_kernel_layout_nb(qs.data(), d16.data(), qs_t.data(),
+                              scale.data(), ns, d, tnb, 64);
+    q40_tile_kernel_layout_nb(qs.data(), d16.data(), qs_t.data(),
+                              scale.data(), 1, 1, 1, 1);
+    const int64_t rd = 200, rnb = 11;
+    std::vector<uint8_t> rqs(2 * rd * rnb * 16), rqs_t(rqs.size());
+    std::vector<uint16_t> rd16(2 * rd * rnb, 0x3c00);
+    std::vector<float> rscale(2 * rd * rnb);
+    q40_tile_kernel_layout_nb(rqs.data(), rd16.data(), rqs_t.data(),
+                              rscale.data(), 2, rd, rnb, 3);
 
     // tokenizer: multi-byte UTF-8, byte fallback, and merge pressure
     const char* pieces[] = {"a", "b", "ab", "\xc3\xa9"};

@@ -452,8 +452,8 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
     t0 = time.perf_counter()
     if tp > 1 or args.sp > 1:
         # mesh runs keep the codec tree: tp-aware packing happens in
-        # parallel/tp.shard_params (the single-chip nb-major layout is
-        # rejected by the sharding specs)
+        # parallel/tp.shard_params, which picks each leaf's layout on its
+        # shard-local shape and says so on stderr
         spec, params = load_model(args.model, weights_float_type=wft,
                                   buffer_float_type=bft)
     else:

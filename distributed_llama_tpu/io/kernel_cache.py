@@ -251,9 +251,9 @@ def load_model_packed(path: str, spec=None, weights_float_type=None,
     """load_model + pack_q40_params + fuse_q40_layer_matmuls, with the
     sidecar shortcut: a valid `<model>.kcache` skips BOTH the .bin walk
     and the GB-scale re-tiling/fusion (the tree's leaves are memmap views
-    into the sidecar). Single-chip decode path only — the nb-major leaves
-    this packs are rejected by the shard_map sharding specs; mesh runs
-    keep load_model + tp-aware packing (parallel/tp.shard_params)."""
+    into the sidecar). Single-chip decode path only — mesh runs decide
+    each leaf's layout on its shard-local shape, so they keep load_model +
+    tp-aware packing (parallel/tp.shard_params)."""
     from ..ops.linear import (fuse_q40_layer_matmuls, pack_q40_params,
                               q40_kernel_mode)
     from ..ops.quants import FloatType

@@ -161,8 +161,16 @@ class Q40KernelI4PackedNb(NamedTuple):
 
 
 def to_kernel_layout_nb(w: Q40Weight) -> Q40KernelNb:
-    """(..., d, nb, 16) -> (..., 16, nb, d) with f32 scales (..., nb, d)."""
+    """(..., d, nb, 16) -> (..., 16, nb, d) with f32 scales (..., nb, d).
+    numpy inputs take the threaded native tiler, as ``to_kernel_layout``
+    does (the numpy strided transpose below is single-threaded)."""
     qs = w.qs
+    if isinstance(qs, np.ndarray) and isinstance(w.d16, np.ndarray):
+        from ..utils import native
+
+        tiled = native.q40_tile_kernel_layout(qs, w.d16, nb_major=True)
+        if tiled is not None:
+            return Q40KernelNb(*tiled)
     nd = qs.ndim
     perm = tuple(range(nd - 3)) + (nd - 1, nd - 2, nd - 3)
     qs_t = qs.transpose(perm)

@@ -179,16 +179,19 @@ def matmul(w, x: jax.Array, *, prefer_pallas: bool = False) -> jax.Array:
 
 def pack_q40_params(params: dict, enable: bool | None = None,
                     tp: int = 1, allow_nb_major: bool | None = None,
-                    input_sharded=()) -> dict:
+                    input_sharded=(), rows: int = 1) -> dict:
     """Re-tile every Q40Weight in a param tree to the kernel layout, once.
 
     ``enable=None`` means "iff the Pallas kernel will be used" — so CPU/test
     runs keep the codec layout and the golden-parity paths are untouched.
     ``tp`` is the tensor-parallel degree the weights will be sharded to:
-    kernel support is decided on the shard-LOCAL shape, since that is what
-    the kernel tiles inside shard_map. ``input_sharded`` names the keys the
-    fused tp scheme shards along the INPUT dim (wo/w2 — parallel/tp.py):
-    their local shape is (d, n/tp) instead of (d/tp, n).
+    kernel support AND the layout are decided on the shard-LOCAL shape,
+    since that is what the kernel tiles inside shard_map and what each chip
+    stores. ``input_sharded`` names the keys the fused tp scheme shards
+    along the INPUT dim (wo/w2 — parallel/tp.py): their local shape is
+    (d, n/tp) instead of (d/tp, n). ``rows`` is how many rows one decode
+    dispatch of the sharded engine carries (as q40_body_policy's): the
+    ``tp > 1`` rule reads it, ``allow_nb_major`` gates the ``tp == 1`` pick.
     Call this at load time, before device_put; never inside a jitted step.
     """
     if enable is None:
@@ -196,9 +199,9 @@ def pack_q40_params(params: dict, enable: bool | None = None,
     if not enable:
         return params
     if allow_nb_major is None:
-        # nb-major is UNSHARDED-only (the sharding specs reject it), and
-        # tp==1 does not imply unsharded (an sp>1 mesh packs with tp=1) —
-        # so the truly-single-chip callers must OPT IN explicitly
+        # tp==1 does not imply unsharded (an sp>1 mesh packs with tp=1), and
+        # the one-chip pick belongs to q40_body_policy — so the
+        # truly-single-chip callers must OPT IN explicitly
         # (params_to_device, shard_sim.rank_params_to_device, bench.py)
         allow_nb_major = False
     from .pallas_q40 import _pick_rows_nb, kernel_supports
@@ -216,23 +219,28 @@ def pack_q40_params(params: dict, enable: bool | None = None,
                 raise ValueError(
                     f"{k}: input-dim sharding needs n/tp to be a "
                     f"32-multiple, got n={n} tp={tp}")
-            if kernel_supports(d, n // tp):
-                return to_kernel_layout(v)
+            d_loc, n_loc = d, n // tp
+        elif d % tp:
             return v
-        if d % tp:
-            return v
+        else:
+            d_loc, n_loc = d // tp, n
         nb = n // 32
-        pad_ratio = (nb + (-nb % 128)) / nb  # TPU lane padding of nb-minor
-        # nb-major layout when the standard tiling would pad the packed
-        # bytes materially (13B: nb=160 -> 1.6x HBM and read inflation).
-        # DLLAMA_NB_MAJOR=force takes it for EVERY eligible leaf (the
-        # i4-formulation experiment arm: the int4 body exists only for
-        # nb-major, so pad-free shapes need the forced layout to reach it)
-        force_nb = os.environ.get("DLLAMA_NB_MAJOR", "") == "force"
-        if (allow_nb_major and tp == 1 and (pad_ratio > 1.25 or force_nb)
-                and _pick_rows_nb(d, nb) is not None):
+        if tp > 1:
+            nb_major = sharded_nb_major(d_loc, n_loc // 32, rows)
+        else:
+            pad_ratio = (nb + (-nb % 128)) / nb  # lane padding of nb-minor
+            # nb-major layout when the standard tiling would pad the packed
+            # bytes materially (13B: nb=160 -> 1.6x HBM and read inflation).
+            # DLLAMA_NB_MAJOR=force takes it for EVERY eligible leaf (the
+            # i4-formulation experiment arm: the int4 body exists only for
+            # nb-major, so pad-free shapes need the forced layout to reach
+            # it)
+            force_nb = os.environ.get("DLLAMA_NB_MAJOR", "") == "force"
+            nb_major = (allow_nb_major and (pad_ratio > 1.25 or force_nb)
+                        and _pick_rows_nb(d, nb) is not None)
+        if nb_major:
             return to_kernel_layout_nb(v)
-        if kernel_supports(d // tp, n):
+        if kernel_supports(d_loc, n_loc):
             return to_kernel_layout(v)
         # untileable dims stay codec-layout: they take the XLA fallback in
         # matmul(), which would otherwise pay a full re-transpose inside
@@ -240,6 +248,29 @@ def pack_q40_params(params: dict, enable: bool | None = None,
         return v
 
     return {k: pick(k, v) for k, v in params.items()}
+
+
+def sharded_nb_major(d_local: int, nb_local: int, rows: int = 1) -> bool:
+    """The layout rule of a SHARDED Q40 leaf, on its shard-local shape.
+
+    The chip stores an array whose minor dim is not a multiple of 128 with
+    the second-minor dim minor instead (no padding): a d-major shard
+    ``(16, d, nb)`` with ``nb % 128 != 0`` lies d-minor in HBM, the Pallas
+    call wants it row-major, and XLA copies (and pads) every such leaf at
+    the top of EVERY step program — 22.9 ms of a 37.2 ms step at Yi-34B
+    tp=4 (ledger, PR 24). Packed nb-major, the logical order IS that
+    physical order and nothing is copied. So: nb-major iff the shard-local
+    ``nb`` is off the 128 grid (on it, d-major is already row-major), the
+    shard-local ``d`` places on the nb-major row tiler, and no dispatch is
+    5..8 rows wide (no nb-major kernel serves those: every matmul would
+    take dequantize-then-dot, see q40_body_policy). The one-chip
+    ``pad_ratio`` test is the wrong one here: nb 224 pads by 1.14 and is
+    copied all the same."""
+    from .pallas_q40 import MULTI_T_MAX, NB_MULTI_T_MAX, _pick_rows_nb
+
+    return (nb_local % 128 != 0
+            and not NB_MULTI_T_MAX < rows <= MULTI_T_MAX
+            and _pick_rows_nb(d_local, nb_local) is not None)
 
 
 def fuse_q40_layer_matmuls(params: dict) -> dict:

@@ -109,7 +109,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..io.loader import Q40Kernel, Q40Weight
+from ..io.loader import Q40Kernel, Q40KernelNb, Q40Weight
 from ..models.llama import (KVCache, PagedKVQ8, attention_core,
                             batch_decode_attention, causal_cache_mask,
                             layer_view, mixed_attention, paged_attention_q8,
@@ -173,13 +173,6 @@ def param_specs(params: dict[str, Any],
             spec = _FUSED_OVERRIDES.get(name, spec)
         if spec is None:
             raise KeyError(f"unknown param {name}")
-        from ..io.loader import Q40KernelNb
-
-        if isinstance(val, Q40KernelNb):
-            raise TypeError(
-                f"{name}: nb-major kernel layout (Q40KernelNb) is "
-                f"single-chip only — pack_q40_params never selects it when "
-                f"tp > 1, so a fused/hand-built tree reached shard_params")
         if isinstance(val, Q40Weight):
             # qs (L, d, nb, 16) and d16 (L, d, nb) shard the same logical
             # axis the spec names — d (output bands) or, for the fused
@@ -196,6 +189,13 @@ def param_specs(params: dict[str, Any],
             qs_spec = P(*base[:-2], None, *base[-2:])
             d_spec = P(*base, *([None] * (len(val.scale.shape) - len(base))))
             specs[name] = Q40Kernel(qs_spec, d_spec)
+        elif isinstance(val, Q40KernelNb):
+            # qs_t (..., 16, nb, d) and scale (..., nb, d): the transpose
+            # carries the sharded axis with it — output bands on the LAST
+            # axis, the fused scheme's input bands on the nb axis
+            *lead, ax_d, ax_n = spec
+            specs[name] = Q40KernelNb(P(*lead, None, ax_n, ax_d),
+                                      P(*lead, ax_n, ax_d))
         else:
             specs[name] = spec
     return specs
@@ -235,19 +235,29 @@ def expected_shard_names(params: dict[str, Any], scheme: str | None = None):
     return rows
 
 
+# shard_params copies a shard at least this large on several threads
+_CUT_THREAD_BYTES = 1 << 26
+
+
 def shard_params(params: dict[str, Any], mesh: Mesh,
-                 scheme: str | None = None) -> dict[str, Any]:
+                 scheme: str | None = None, rows: int = 1) -> dict[str, Any]:
     """Place the param tree with the active scheme's shardings (ref:
     MatmulSlice output-dim bands everywhere; fused: wo/w2 input-dim bands).
 
     Q40 weights are re-tiled to the Pallas kernel layout first (host side,
-    once) when the Q40 fast path is active. Placement goes through
+    once) when the Q40 fast path is active; ``rows`` is the width of the
+    caller's decode dispatch, which the layout rule reads
+    (ops/linear.sharded_nb_major). Placement goes through
     ``make_array_from_callback``, not ``device_put``: each process
     materializes ONLY its addressable shards (a multi-host device_put both
     asserts bitwise-equal full values on every host — which slice-streamed
     weights deliberately violate, their unfetched bands being zeros — and
     would ship n_hosts copies of every tensor across the wire).
     """
+    import concurrent.futures
+    import os
+    import sys
+
     import numpy as np
 
     from ..ops.linear import pack_q40_params
@@ -265,19 +275,49 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
                     f"{v.qs.shape[-2]} Q40 blocks do not divide over "
                     f"tp={n_tp} (need input_dim/tp to be a 32-multiple)")
     params = pack_q40_params(
-        params, tp=n_tp,
+        params, tp=n_tp, rows=rows,
         input_sharded=(FUSED_INPUT_SHARDED
                        if scheme in _INPUT_SHARDED_SCHEMES else ()))
+    layouts = {label: [k for k, v in params.items() if isinstance(v, kind)]
+               for label, kind in (("nb-major", Q40KernelNb),
+                                   ("d-major", Q40Kernel),
+                                   ("codec", Q40Weight))}
+    if n_tp > 1 and (layouts["nb-major"] or layouts["d-major"]):
+        # unconditionally, as the one-chip policy note: a silent layout
+        # change would make runs incomparable
+        picks = "; ".join(f"{label}: {' '.join(keys)}"
+                          for label, keys in layouts.items() if keys)
+        print(f"💡 Q40 sharded layout: {picks} (tp={n_tp} {scheme}, "
+              f"{rows}-row decode dispatches; a shard-local block count "
+              f"off the 128 grid packs nb-major)", file=sys.stderr)
     specs = param_specs(params, scheme)
+    threads = min(16, os.cpu_count() or 1)
+
+    def cut(a, idx):
+        # host tree by contract (loader/synth/pack all emit numpy): this
+        # contiguous copy of one shard is the one conversion point. It is
+        # bound by first-touch page faults, not bandwidth (0.75 GB/s on one
+        # thread: 28 of Yi-34B's 38 s of placement), so bands of a large
+        # shard's leading axis go to threads
+        view = a[idx]
+        if view.ndim < 2 or view.nbytes < _CUT_THREAD_BYTES:
+            return np.ascontiguousarray(view)
+        out = np.empty(view.shape, view.dtype)
+        bands = np.linspace(0, len(view), threads + 1).astype(int)
+
+        def copy(lo, hi):
+            out[lo:hi] = view[lo:hi]
+
+        list(pool.map(copy, bands[:-1], bands[1:]))
+        return out
 
     def put(a, s):
-        # host tree by contract (loader/synth/pack all emit numpy): the
-        # callback's ascontiguousarray is the one conversion point
-        sh = NamedSharding(mesh, s)
         return jax.make_array_from_callback(
-            np.shape(a), sh, lambda idx, a=a: np.ascontiguousarray(a[idx]))
+            np.shape(a), NamedSharding(mesh, s),
+            lambda idx, a=a: cut(a, idx))
 
-    return jax.tree_util.tree_map(put, params, specs)
+    with concurrent.futures.ThreadPoolExecutor(threads) as pool:
+        return jax.tree_util.tree_map(put, params, specs)
 
 
 def shard_cache(cache: KVCache, mesh: Mesh) -> KVCache:

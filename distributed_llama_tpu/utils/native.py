@@ -56,11 +56,13 @@ def _load_locked():
         fn.restype = None
         fn.argtypes = [ctypes.POINTER(ctypes.c_float),
                        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64]
-    lib.q40_tile_kernel_layout.restype = None
-    lib.q40_tile_kernel_layout.argtypes = [
-        ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_uint16),
-        ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_float),
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32]
+    for name in ("q40_tile_kernel_layout", "q40_tile_kernel_layout_nb"):
+        fn = getattr(lib, name)
+        fn.restype = None
+        fn.argtypes = [
+            ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_uint16),
+            ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_float),
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32]
     lib.tok_create.restype = ctypes.c_void_p
     lib.tok_create.argtypes = [ctypes.POINTER(ctypes.c_uint8),
                                ctypes.POINTER(ctypes.c_int64),
@@ -115,11 +117,13 @@ def q40_decode_wire(buf: np.ndarray, nb: int) -> np.ndarray | None:
 
 
 def q40_tile_kernel_layout(qs: np.ndarray, d16: np.ndarray,
-                           n_threads: int | None = None):
+                           n_threads: int | None = None,
+                           nb_major: bool = False):
     """Threaded (..., d, nb, 16) -> (..., 16, d, nb) re-tiling + f16->f32
     scale upconvert — the load-time transform feeding the Pallas kernel
-    layout. Returns (qs_t, scale) or None when the native library is
-    unavailable (callers fall back to numpy)."""
+    layout; ``nb_major`` gives (..., 16, nb, d) codes and (..., nb, d)
+    scales instead (io/loader.Q40KernelNb). Returns (qs_t, scale) or None
+    when the native library is unavailable (callers fall back to numpy)."""
     lib = _load()
     if lib is None:
         return None
@@ -134,16 +138,18 @@ def q40_tile_kernel_layout(qs: np.ndarray, d16: np.ndarray,
     n_stacked = int(np.prod(lead)) if lead else 1
     qs_c = np.ascontiguousarray(qs)
     d16_c = np.ascontiguousarray(d16)
-    qs_t = np.empty((*lead, 16, d, nb), dtype=np.uint8)
-    scale = np.empty((*lead, d, nb), dtype=np.float32)
+    plane = (nb, d) if nb_major else (d, nb)
+    qs_t = np.empty((*lead, 16, *plane), dtype=np.uint8)
+    scale = np.empty((*lead, *plane), dtype=np.float32)
     if n_threads is None:
         n_threads = min(16, os.cpu_count() or 1)
-    lib.q40_tile_kernel_layout(
-        qs_c.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        d16_c.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
-        qs_t.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        scale.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        n_stacked, d, nb, n_threads)
+    tile = (lib.q40_tile_kernel_layout_nb if nb_major
+            else lib.q40_tile_kernel_layout)
+    tile(qs_c.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+         d16_c.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
+         qs_t.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+         scale.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+         n_stacked, d, nb, n_threads)
     return qs_t, scale
 
 

@@ -15,7 +15,10 @@ Nothing runs and nothing is timed: a compile that passes is not a chip run.
 Each case is one kernel entry at ``interpret=False`` on ShapeDtypeStructs
 pinned to one described v5e device, and asserts the Mosaic custom call is in
 the compiled program. Cases stay near a second each (tier-1 budget): no
-page-size-128 x t_len-4 paged case (~50 s).
+page-size-128 x t_len-4 paged case (~50 s). The two whole programs at the
+end (the tp=4 step and prefill chunk at Yi-34B's shard-local widths, about
+5 s each) guard what no single kernel shows: a weight-sized layout copy in
+the entry computation.
 """
 
 import functools
@@ -42,16 +45,15 @@ def _sd(shape, dtype):
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One device of a described v5e:2x2, with the persistent compile cache
-    off: an executable compiled for a described chip is written to the cache
-    but cannot be read back without a chip (it warns and recompiles)."""
+def topo():
+    """A described v5e:2x2, with the persistent compile cache off: an
+    executable compiled for a described chip is written to the cache but
+    cannot be read back without a chip (it warns and recompiles)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no libtpu / no topology support
         pytest.skip(f"cannot describe a v5e:2x2 topology here "
@@ -59,9 +61,17 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One device of the described v5e:2x2."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _q40(layout: str, leaf: str, t: int):
@@ -186,3 +196,86 @@ def test_kernel_compiles_for_v5e(chip, case):
         shapes)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert ("tpu_custom_call" in text) == kernel, case
+
+
+# ---- whole sharded programs: no weight-sized copy in a step ---------------
+
+YI = dict(dim=7168, hidden_dim=20480, n_heads=56, n_kv_heads=8,
+          vocab_size=64000)   # benchmark/configs/yi-34b-q40-tp4.json
+YI_TP, YI_LAYERS, YI_SEQ = 4, 2, 512    # a cut depth and context
+
+
+@pytest.fixture
+def chip_branch(monkeypatch):
+    """Code that asks for the backend takes its chip branch (Pallas kernels,
+    not interpret mode; the ``auto`` kernel modes), as
+    benchmark/tools/rehearse_compile.py arranges. Traces made so are dropped
+    afterwards: a jitted dispatch that resolved ``interpret=None`` to the
+    chip would otherwise be reused by a CPU test of the same shapes."""
+    for var in ("DLLAMA_Q40_KERNEL", "DLLAMA_ATTN_KERNEL", "DLLAMA_TP_SCHEME",
+                "DLLAMA_NB_MAJOR"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("t,kernels", [(1, 9), (128, 8)])
+def test_sharded_step_copies_no_weights(topo, chip_branch, t, kernels):
+    """The tp=4 decode step and T=128 chunk at Yi-34B's widths: every Q40
+    leaf reaches its Pallas call in the layout it is stored in. A d-major
+    shard whose block count is off the 128 grid is stored d-minor and
+    copied row-major (padded) at the top of every step: 6.6 GiB of
+    temporaries at 60 layers, 22.9 ms of a 37.2 ms step on the chip
+    (PERF.md, PR 25). ``kernels``: seven layer matmuls and the classifier,
+    plus decode attention at T=1 — one fewer means a matmul fell to
+    dequantize-then-dot."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_llama_tpu.io.loader import Q40Weight
+    from distributed_llama_tpu.models.llama import KVCache
+    from distributed_llama_tpu.models.spec import TransformerSpec
+    from distributed_llama_tpu.ops.linear import pack_q40_params
+    from distributed_llama_tpu.ops.quants import FloatType
+    from distributed_llama_tpu.parallel import tp
+
+    spec = TransformerSpec(**YI, n_layers=YI_LAYERS, seq_len=YI_SEQ,
+                           weights_float_type=FloatType.Q40,
+                           buffer_float_type=FloatType.F32)
+    mesh = Mesh(np.array(topo.devices[:YI_TP]).reshape(1, 1, YI_TP),
+                ("dp", "sp", "tp"))
+    L, dim = spec.n_layers, spec.dim
+
+    def q40(lead, d, n):
+        return Q40Weight(_sd((*lead, d, n // 32, 16), jnp.uint8),
+                         _sd((*lead, d, n // 32), jnp.float16))
+
+    tree = {"tok_embedding": _sd((spec.vocab_size, dim), jnp.float32),
+            "rms_att": _sd((L, dim), jnp.float32),
+            "rms_ffn": _sd((L, dim), jnp.float32),
+            "rms_final": _sd((dim,), jnp.float32),
+            "wcls": q40((), spec.vocab_size, dim),
+            **{k: q40((L,), *shape)
+               for k, shape in spec.layer_matmul_shapes()}}
+    packed = jax.eval_shape(
+        lambda w: pack_q40_params(w, tp=YI_TP,
+                                  input_sharded=tp.FUSED_INPUT_SHARDED), tree)
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        packed, tp.param_specs(packed, "fused"))
+    cache_sh = NamedSharding(mesh, tp.CACHE_SPEC.k)
+    cache = KVCache(*(jax.ShapeDtypeStruct(
+        (L, YI_SEQ, spec.n_kv_heads, spec.head_size), jnp.float32,
+        sharding=cache_sh) for _ in range(2)))
+    rep = NamedSharding(mesh, P())
+    compiled = tp.make_sharded_forward(spec, mesh, scheme="fused").lower(
+        params, cache, jax.ShapeDtypeStruct((t,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+    weights = sum(a.size * a.dtype.itemsize // YI_TP
+                  for k, v in packed.items() if k != "tok_embedding"
+                  for a in jax.tree_util.tree_leaves(v))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.05 * weights, (temp, weights)
+    assert compiled.as_text().count("tpu_custom_call") == kernels

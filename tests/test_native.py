@@ -136,6 +136,36 @@ def test_native_tile_kernel_layout_matches_numpy():
     np.testing.assert_array_equal(scale2, d16[0].astype(np.float32))
 
 
+@pytest.mark.parametrize("shape", [(3, 40, 5), (2, 300, 11), (1, 128, 8),
+                                   (256, 3)])
+def test_native_tile_kernel_layout_nb_matches_numpy(shape, monkeypatch):
+    """The threaded nb-major tiler against the numpy transpose it replaced
+    in ``to_kernel_layout_nb``: ragged row bands (d 40, 300), ragged block
+    tiles (nb 5, 11, 3), stacked and not; f16 subnormal deltas (the native
+    upconvert halved them) through both tilers."""
+    from distributed_llama_tpu.io.loader import (Q40Weight, to_kernel_layout,
+                                                 to_kernel_layout_nb)
+    from distributed_llama_tpu.utils import native
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    rng = np.random.default_rng(7)
+    qs = rng.integers(0, 256, (*shape, 16), dtype=np.uint8)
+    d16 = (rng.random(shape) * 0.1).astype(np.float16)
+    d16.reshape(-1)[:4] = [6e-8, 3e-5, -6.09e-5, 6.2e-5]  # around 2**-14
+    got = to_kernel_layout_nb(Q40Weight(qs, d16))
+    got_d = to_kernel_layout(Q40Weight(qs, d16))
+    monkeypatch.setattr(native, "q40_tile_kernel_layout",
+                        lambda *a, **k: None)      # the numpy fallback
+    want = to_kernel_layout_nb(Q40Weight(qs, d16))
+    np.testing.assert_array_equal(got_d.scale,
+                                  to_kernel_layout(Q40Weight(qs, d16)).scale)
+    assert got.qs_t.shape == (*shape[:-2], 16, shape[-1], shape[-2])
+    np.testing.assert_array_equal(got.qs_t, want.qs_t)
+    np.testing.assert_array_equal(got.scale, want.scale)
+    assert got.scale.dtype == np.float32 and got.qs_t.flags.c_contiguous
+
+
 def test_native_sampler_matches_numpy():
     """csrc sample_logits vs the numpy Sampler path on identical
     logits/coins, across strategies (argmax is numpy-only; multinomial and

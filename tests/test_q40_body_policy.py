@@ -6,6 +6,8 @@ pinned by tests/test_pallas_q40.py."""
 
 from __future__ import annotations
 
+import os
+
 import pytest
 
 from distributed_llama_tpu.models.synth import (llama2_7b_spec,
@@ -14,12 +16,20 @@ from distributed_llama_tpu.ops.linear import (apply_q40_body_policy,
                                               q40_body_policy)
 
 
+_KNOBS = ("DLLAMA_Q40_BODY", "DLLAMA_Q40_I4", "DLLAMA_NB_MAJOR",
+          "DLLAMA_Q40_BODY_MAX_GB", "DLLAMA_Q40_KERNEL")
+
+
 @pytest.fixture(autouse=True)
 def _clean_env(monkeypatch):
-    for var in ("DLLAMA_Q40_BODY", "DLLAMA_Q40_I4", "DLLAMA_NB_MAJOR",
-                "DLLAMA_Q40_BODY_MAX_GB", "DLLAMA_Q40_KERNEL"):
+    for var in _KNOBS:
         monkeypatch.delenv(var, raising=False)
     yield
+    # apply_q40_body_policy writes os.environ itself, and delenv of an
+    # absent variable registered no undo: drop what a test left behind
+    # (monkeypatch, torn down after this, puts back what was set before)
+    for var in _KNOBS:
+        os.environ.pop(var, None)
 
 
 def test_auto_picks_i4_nb_for_7b_on_pallas(monkeypatch):
