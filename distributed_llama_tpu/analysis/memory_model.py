@@ -67,8 +67,14 @@ def q40_codec_bytes(values: int) -> int:
 
 def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
     """Matmul-weight scalars per device: all 7 per-layer matmuls plus wcls
-    shard exactly 1/tp of their values in both schemes (tp.py)."""
-    per_layer = sum(d * n for _, (d, n) in spec.layer_matmul_shapes())
+    shard exactly 1/tp of their values in both schemes (tp.py). An expert
+    spec counts every expert's three tensors and holds them on ONE chip
+    (tp.py refuses it across ranks, and so does this model)."""
+    if spec.n_experts and n_slices > 1:
+        from ..ops.linear import MOE_TP_REFUSAL
+
+        raise ValueError(MOE_TP_REFUSAL)
+    per_layer = sum(c * d * n for (d, n), c in spec.matmul_shape_counts())
     total = spec.n_layers * per_layer + spec.vocab_size * spec.dim
     return total // n_slices
 
@@ -90,8 +96,10 @@ def replicated_device_bytes(spec: TransformerSpec) -> int:
     """Bytes every chip holds whole regardless of tp: the f32 embedding
     table and the rms norm vectors (2 per layer + final)."""
     embedding = spec.vocab_size * spec.dim * 4
-    norms = (2 * spec.n_layers + 1) * spec.dim * 4
-    return embedding + norms
+    norms = (spec.n_layers * sum(n for _, n in spec.layer_norm_shapes())
+             + spec.dim) * 4
+    routers = spec.n_layers * spec.n_experts * spec.dim * 4   # f32, whole
+    return embedding + norms + routers
 
 
 def kv_cache_device_bytes(spec: TransformerSpec, n_slices: int,

@@ -564,6 +564,12 @@ def check_config(entry: MatrixEntry, device: str = "v5e",
             f"unknown kv_quant {kv_quant!r} (expected f32|q8) — the "
             f"matrix declares a column the memory model cannot price"),
         ), kv_quant=kv_quant)
+    if spec.n_experts:
+        # tp.py refuses an expert spec; a check of its sharding has nothing
+        # to trace, and a footprint that skipped the experts would be wrong
+        from ..ops.linear import MOE_TP_REFUSAL
+
+        raise ValueError(f"shardcheck {config}: {MOE_TP_REFUSAL}")
     findings = check_uniform_shards(spec, entry.tp, entry.scheme, config)
     act_bytes = None
     if not findings and kv_quant == "q8":

@@ -670,6 +670,10 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     # speculation
     _m("dllama_spec_proposed_total"),
     _m("dllama_spec_accepted_total"),
+    # routed experts (an expert spec's decode dispatches)
+    _m("dllama_moe_routed_pairs_total"),
+    _m("dllama_moe_active_experts_total"),
+    _m("dllama_moe_expert_rows_total", labels=("expert",)),
     # journal / recovery / lifecycle
     _m("dllama_journal_records_total"),
     _m("dllama_recoveries_total"),

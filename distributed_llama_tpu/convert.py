@@ -11,6 +11,16 @@ Extensions beyond the reference:
 * ``--source hf``: convert a HuggingFace LlamaForCausalLM checkpoint
   (safetensors/pytorch), mapping q/k heads back from HF's permuted layout to
   Meta's interleaved RoPE layout.
+* ``--source hf`` on ``model_type: olmoe`` (OlmoeForCausalLM): the routed
+  experts (``mlp.gate.weight`` -> router, ``mlp.experts.{e}.gate_proj`` /
+  ``down_proj`` / ``up_proj`` -> w1 / w2 / w3 of expert e) and the q/k-norm
+  gains (``self_attn.q_norm`` / ``k_norm``), written with the extended
+  header (models/spec.py). The row permutation applied to wq / wk for
+  interleaved-pair RoPE is applied to the q/k-norm gains as well: the gains
+  act on the projection's outputs, so they move with its rows. That is the
+  one departure from the published rotate-half form, and it computes the
+  same attention scores (a fixed permutation inside each head of both q
+  and k leaves q.k unchanged; RMS is permutation-invariant).
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -49,7 +59,8 @@ _LAYER_TENSORS = [
 ]
 # Meta shards concatenate along dim=1 for these (converter.py:131-136)
 _AXIS1 = {"tok_embedding", "wo", "w2"}
-_ALWAYS_F32 = {"tok_embedding", "rms_att", "rms_ffn", "rms_final"}
+_ALWAYS_F32 = {"tok_embedding", "rms_att", "rms_ffn", "rms_final", "rms_q",
+               "rms_k", "moe_gate"}
 
 
 def _is_f32(name: str) -> bool:
@@ -134,14 +145,29 @@ class HFCheckpoint:
         return self._state
 
     def _unpermute(self, w: "np.ndarray", n_heads: int) -> np.ndarray:
-        d, n = w.shape
+        """Rows of a (d, n) projection, or the d gains that act on its
+        outputs, from rotate-half order to interleaved pairs."""
+        d = w.shape[0]
         hs = d // n_heads
-        return (w.reshape(n_heads, 2, hs // 2, n)
-                .transpose(0, 2, 1, 3).reshape(d, n))
+        return (w.reshape(n_heads, 2, hs // 2, *w.shape[1:])
+                .swapaxes(1, 2).reshape(w.shape))
 
     def spec(self, target: FloatType, seq_len: int) -> TransformerSpec:
         c = self.config
-        return TransformerSpec(
+        moe = {}
+        if getattr(c, "model_type", "") == "olmoe":
+            if getattr(c, "norm_topk_prob", False):
+                raise ValueError("olmoe with norm_topk_prob: the program "
+                                 "keeps the top-k probabilities as they are")
+            if getattr(c, "clip_qkv", None) is not None:
+                raise ValueError("olmoe with clip_qkv: not implemented")
+            moe = dict(n_experts=c.num_experts,
+                       n_active_experts=c.num_experts_per_tok, qk_norm=True)
+        return TransformerSpec(**moe, **self._base_sizes(target, seq_len))
+
+    def _base_sizes(self, target: FloatType, seq_len: int) -> dict:
+        c = self.config
+        return dict(
             dim=c.hidden_size, hidden_dim=c.intermediate_size,
             n_layers=c.num_hidden_layers, n_heads=c.num_attention_heads,
             n_kv_heads=getattr(c, "num_key_value_heads",
@@ -150,7 +176,9 @@ class HFCheckpoint:
             weights_float_type=target)
 
     def tensor_by_name(self, name: str, layer: int | None,
-                       spec: TransformerSpec) -> np.ndarray:
+                       spec: TransformerSpec,
+                       expert: int | None = None) -> np.ndarray:
+        experts = f"model.layers.{layer}.mlp.experts.{expert}"
         hf = {
             "tok_embedding": "model.embed_tokens.weight",
             "rms_final": "model.norm.weight",
@@ -164,11 +192,17 @@ class HFCheckpoint:
             "w1": f"model.layers.{layer}.mlp.gate_proj.weight",
             "w2": f"model.layers.{layer}.mlp.down_proj.weight",
             "w3": f"model.layers.{layer}.mlp.up_proj.weight",
+            "rms_q": f"model.layers.{layer}.self_attn.q_norm.weight",
+            "rms_k": f"model.layers.{layer}.self_attn.k_norm.weight",
+            "moe_gate": f"model.layers.{layer}.mlp.gate.weight",
+            "moe_w1": f"{experts}.gate_proj.weight",
+            "moe_w2": f"{experts}.down_proj.weight",
+            "moe_w3": f"{experts}.up_proj.weight",
         }[name]
         w = self.state[hf].to(self.torch.float32).numpy()
-        if name == "wq":
+        if name in ("wq", "rms_q"):
             w = self._unpermute(w, spec.n_heads)
-        elif name == "wk":
+        elif name in ("wk", "rms_k"):
             w = self._unpermute(w, spec.n_kv_heads)
         return w
 
@@ -211,9 +245,17 @@ def convert_hf(model_path: str, target: str, out: str | None = None,
         _write_tensor(f, spec, "tok_embedding",
                       ckpt.tensor_by_name("tok_embedding", None, spec))
         for i in range(spec.n_layers):
-            for name_, _ in _LAYER_TENSORS:
+            # file order (models/spec.py): norms, attention, router, experts
+            names = ([n for n, _ in spec.layer_norm_shapes()]
+                     + [n for n, _ in spec.layer_matmul_shapes()]
+                     + (["moe_gate"] if spec.n_experts else []))
+            for name_ in names:
                 _write_tensor(f, spec, name_,
                               ckpt.tensor_by_name(name_, i, spec))
+            for e in range(spec.n_experts):
+                for name_, _ in spec.expert_matmul_shapes():
+                    _write_tensor(f, spec, name_,
+                                  ckpt.tensor_by_name(name_, i, spec, e))
             print(f"🔶 wrote layer {i + 1}/{spec.n_layers}")
         _write_tensor(f, spec, "rms_final",
                       ckpt.tensor_by_name("rms_final", None, spec))

@@ -230,7 +230,11 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
     ap.add_argument("--topp", type=float, default=0.9)
     ap.add_argument("--seed", type=int, default=None)
     ap.add_argument("--tp", type=int, default=None,
-                    help="tensor-parallel ways (default: all local devices)")
+                    help="tensor-parallel ways (default: all local devices). "
+                         "An expert (mixture-of-experts) model, one whose "
+                         "header carries nExperts / nActiveExperts / qkNorm, "
+                         "runs on one chip only: pass --tp 1 for it (any "
+                         "other mesh is refused)")
     ap.add_argument("--tp-scheme", default=None,
                     choices=("ref", "fused", "overlap"),
                     help="tp collective schedule (= DLLAMA_TP_SCHEME): "
@@ -482,9 +486,18 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
               f"💡 nLayers: {spec.n_layers}\n💡 nHeads: {spec.n_heads}\n"
               f"💡 nKvHeads: {spec.n_kv_heads}\n"
               f"💡 vocabSize: {spec.vocab_size}\n💡 seqLen: {spec.seq_len}\n"
-              f"💡 nSlices: {tp} sp: {args.sp} scheme: "
+              + (f"💡 nExperts: {spec.n_experts}\n💡 nActiveExperts: "
+                 f"{spec.n_active_experts}\n💡 qkNorm: {int(spec.qk_norm)}\n"
+                 if spec.extended else "")
+              + f"💡 nSlices: {tp} sp: {args.sp} scheme: "
               f"{scheme if tp > 1 else '-'} ({n_dev} devices, "
               f"{jax.devices()[0].platform})")
+    if spec.n_experts and (tp > 1 or args.sp > 1):
+        from ..ops.linear import MOE_TP_REFUSAL
+
+        print(f"{MOE_TP_REFUSAL} (this run: tp={tp} sp={args.sp}; pass "
+              f"--tp 1)", file=sys.stderr)
+        return 2
     mesh = (make_mesh(sp=args.sp, tp=tp)
             if tp > 1 or args.sp > 1 else None)
     assumed = getattr(args, "_slice_tp_ranks", None)
@@ -705,7 +718,8 @@ def cmd_serve(argv: list[str]) -> int:
     ap.add_argument("--topp", type=float, default=0.9)
     ap.add_argument("--seed", type=int, default=None)
     ap.add_argument("--tp", type=int, default=None,
-                    help="tensor-parallel ways (default: single chip)")
+                    help="tensor-parallel ways (default: single chip; an "
+                         "expert model is refused under --tp > 1)")
     ap.add_argument("--tp-scheme", default=None,
                     choices=("ref", "fused", "overlap"),
                     help="tp collective schedule (= DLLAMA_TP_SCHEME; see "
@@ -984,6 +998,12 @@ def cmd_serve(argv: list[str]) -> int:
                         weights_float_type=_FT[args.weights_float_type],
                         buffer_float_type=_FT[args.buffer_float_type])
     tokenizer = Tokenizer(args.tokenizer, spec.vocab_size)
+    if spec.n_experts and sharded:
+        from ..ops.linear import MOE_TP_REFUSAL
+
+        print(f"{MOE_TP_REFUSAL} (this run: --tp {args.tp})",
+              file=sys.stderr)
+        return 2
     mesh = make_mesh(tp=args.tp) if args.tp and args.tp > 1 else None
     seed = args.seed if args.seed is not None else int(time.time())
     if journal is not None:

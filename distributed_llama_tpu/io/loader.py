@@ -17,7 +17,7 @@ from typing import NamedTuple
 
 import numpy as np
 
-from ..models.spec import HEADER_BYTES, TransformerSpec
+from ..models.spec import EXT_STRUCT, TransformerSpec
 from ..ops.quants import (
     FloatType,
     pack_q40_bytes,
@@ -231,7 +231,7 @@ def from_kernel_layout(w: Q40Kernel) -> Q40Weight:
 def read_spec(path: str, weights_float_type=FloatType.F32,
               buffer_float_type=FloatType.F32) -> TransformerSpec:
     with open(path, "rb") as f:
-        raw = f.read(HEADER_BYTES)
+        raw = f.read(EXT_STRUCT.size)  # a 28-byte header reads its first 28
     return TransformerSpec.from_header(raw, weights_float_type, buffer_float_type)
 
 
@@ -282,7 +282,7 @@ def load_model(path: str, spec: TransformerSpec | None = None,
         raise ValueError(
             f"file size mismatch: {path} has {mm.nbytes} bytes, "
             f"spec requires {expected}")
-    w = _Walker(mm, HEADER_BYTES)
+    w = _Walker(mm, spec.header_bytes)
 
     params: dict = {}
     params["tok_embedding"] = w.f32((spec.vocab_size, spec.dim))
@@ -291,27 +291,41 @@ def load_model(path: str, spec: TransformerSpec | None = None,
     # slot (avoids transiently holding list-of-layers + np.stack copies of
     # multi-GB tensors)
     shapes = spec.layer_matmul_shapes()
-    L = spec.n_layers
+    experts = spec.expert_matmul_shapes()
+    norms = spec.layer_norm_shapes()
+    L, E = spec.n_layers, spec.n_experts
     ft = spec.weights_float_type
-    params["rms_att"] = np.empty((L, spec.dim), np.float32)
-    params["rms_ffn"] = np.empty((L, spec.dim), np.float32)
-    for name, (dd, nn) in shapes:
-        if ft == FloatType.Q40:
-            params[name] = Q40Weight(np.empty((L, dd, nn // 32, 16), np.uint8),
-                                     np.empty((L, dd, nn // 32), np.float16))
-        else:
-            dtype = np.float32 if ft == FloatType.F32 else np.float16
-            params[name] = np.empty((L, dd, nn), dtype)
-    for layer in range(L):
-        params["rms_att"][layer] = w.f32((spec.dim,))
-        params["rms_ffn"][layer] = w.f32((spec.dim,))
-        for name, shape in shapes:
-            val = w.matmul(spec, shape)
-            if isinstance(val, Q40Weight):
-                params[name].qs[layer] = val.qs
-                params[name].d16[layer] = val.d16
+    for name, n in norms:
+        params[name] = np.empty((L, n), np.float32)
+    for lead, group in (((L,), shapes), ((L, E), experts)):
+        for name, (dd, nn) in group:
+            if ft == FloatType.Q40:
+                params[name] = Q40Weight(
+                    np.empty((*lead, dd, nn // 32, 16), np.uint8),
+                    np.empty((*lead, dd, nn // 32), np.float16))
             else:
-                params[name][layer] = val
+                dtype = np.float32 if ft == FloatType.F32 else np.float16
+                params[name] = np.empty((*lead, dd, nn), dtype)
+    if E:
+        params["moe_gate"] = np.empty((L, E, spec.dim), np.float32)
+
+    def place(name, at, val):
+        if isinstance(val, Q40Weight):
+            params[name].qs[at] = val.qs
+            params[name].d16[at] = val.d16
+        else:
+            params[name][at] = val
+
+    for layer in range(L):
+        for name, n in norms:
+            params[name][layer] = w.f32((n,))
+        for name, shape in shapes:
+            place(name, layer, w.matmul(spec, shape))
+        if E:
+            params["moe_gate"][layer] = w.f32((E, spec.dim))
+        for e in range(E):
+            for name, shape in experts:
+                place(name, (layer, e), w.matmul(spec, shape))
 
     params["rms_final"] = w.f32((spec.dim,))
     w.take(spec.rope_gap_bytes)  # legacy freq_cis region, skipped
@@ -344,7 +358,7 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
     same order as load_model; the total is asserted == spec.file_size().
     """
     out: list[TensorRange] = []
-    off = HEADER_BYTES
+    off = spec.header_bytes
 
     def add(name, layer, nbytes, rows=None):
         nonlocal off
@@ -353,11 +367,17 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
 
     add("tok_embedding", None, spec.vocab_size * spec.dim * 4)
     shapes = spec.layer_matmul_shapes()
+    experts = spec.expert_matmul_shapes()
     for layer in range(spec.n_layers):
-        add("rms_att", layer, spec.dim * 4)
-        add("rms_ffn", layer, spec.dim * 4)
+        for name, n in spec.layer_norm_shapes():
+            add(name, layer, n * 4)
         for name, shape in shapes:
             add(name, layer, spec.matmul_bytes(shape), rows=shape[0])
+        if experts:
+            add("moe_gate", layer, spec.n_experts * spec.dim * 4)
+        for _ in range(spec.n_experts):   # expert e's three, e ascending
+            for name, shape in experts:
+                add(name, layer, spec.matmul_bytes(shape), rows=shape[0])
     add("rms_final", None, spec.dim * 4)
     add("_rope_gap", None, spec.rope_gap_bytes)
     add("wcls", None, spec.matmul_bytes((spec.vocab_size, spec.dim)),
@@ -394,12 +414,17 @@ def write_model(path: str, spec: TransformerSpec, tensors: dict) -> None:
         f.write(np.ascontiguousarray(
             tensors["tok_embedding"], dtype=np.float32).tobytes())
         for layer in range(spec.n_layers):
-            f.write(np.ascontiguousarray(
-                tensors["rms_att"][layer], dtype=np.float32).tobytes())
-            f.write(np.ascontiguousarray(
-                tensors["rms_ffn"][layer], dtype=np.float32).tobytes())
+            for name, _ in spec.layer_norm_shapes():
+                f.write(np.ascontiguousarray(
+                    tensors[name][layer], dtype=np.float32).tobytes())
             for name, _ in spec.layer_matmul_shapes():
                 _write_matmul(f, spec, tensors[name][layer])
+            if spec.n_experts:
+                f.write(np.ascontiguousarray(
+                    tensors["moe_gate"][layer], dtype=np.float32).tobytes())
+            for e in range(spec.n_experts):
+                for name, _ in spec.expert_matmul_shapes():
+                    _write_matmul(f, spec, tensors[name][layer][e])
         f.write(np.ascontiguousarray(
             tensors["rms_final"], dtype=np.float32).tobytes())
         f.write(b"\x00" * spec.rope_gap_bytes)

@@ -255,6 +255,10 @@ def _qkv_proj(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
         q = matmul(lw["wq"], xb)
         k = matmul(lw["wk"], xb)
         v = matmul(lw["wv"], xb)
+    if spec.qk_norm:
+        # gains over the WHOLE projection (not per head), before RoPE
+        q = rmsnorm(q, lw["rms_q"])
+        k = rmsnorm(k, lw["rms_k"])
 
     def rot(a):
         return rope_rotate(a, positions, spec.head_size)
@@ -267,14 +271,22 @@ def _qkv_proj(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
 
 
 def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
-                    ao: jax.Array) -> jax.Array:
-    """Shared layer tail: wo + residual, then the SwiGLU ffn sub-block."""
+                    ao: jax.Array, moe_counts: bool = False):
+    """Shared layer tail: wo + residual, then the ffn sub-block: SwiGLU, or
+    for an expert spec the router and the routed experts (ops/pallas_moe).
+    ``moe_counts`` (expert specs only) also returns the (E,) int32 count of
+    rows routed to each expert: ``(x, counts)``."""
     with jax.named_scope(SCOPE_ATTN):
         ao = _maybe_q80(spec, ao)
         x = x + matmul(lw["wo"], ao)
     with jax.named_scope(SCOPE_FFN):
         xb = rmsnorm(x, lw["rms_ffn"])
         xb = _maybe_q80(spec, xb)
+        if spec.n_experts:
+            from ..ops.pallas_moe import moe_ffn
+
+            y, counts = moe_ffn(spec, lw, xb)
+            return (x + y, counts) if moe_counts else x + y
         if "w13" in lw:  # load-time fused kernel (linear.fuse_q40_layer_matmuls)
             h13 = matmul(lw["w13"], xb)
             hid = h13.shape[-1] // 2
@@ -287,10 +299,11 @@ def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
 
 def _layer(spec: TransformerSpec, x: jax.Array, lw: dict[str, Any],
            k_all: jax.Array, v_all: jax.Array, idx, pos: jax.Array,
-           positions: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+           positions: jax.Array, moe_counts: bool = False):
     """One transformer layer against the STACKED (L, S, n_kv, hs) caches,
     updated in place at layer ``idx``. This is the body `forward`'s layer
-    scan runs (and what the golden-parity test drives with L=1)."""
+    scan runs (and what the golden-parity test drives with L=1). Returns
+    (x, k_all, v_all); under ``moe_counts`` x is _post_attention's pair."""
     t_len = x.shape[0]
     with jax.named_scope(SCOPE_ATTN):
         q, k, v = _qkv_proj(spec, lw, x, positions)
@@ -319,13 +332,15 @@ def _layer(spec: TransformerSpec, x: jax.Array, lw: dict[str, Any],
             ao = attention(spec,
                            q.reshape(t_len, spec.n_heads, spec.head_size),
                            k_c, v_c, pos, t_len)
-    x = _post_attention(spec, lw, x, ao)
+    x = _post_attention(spec, lw, x, ao, moe_counts)
     return x, k_all, v_all
 
 
-LAYER_KEYS = ("rms_att", "rms_ffn", "wq", "wk", "wv", "wo", "w1", "w2", "w3")
+LAYER_KEYS = ("rms_att", "rms_ffn", "wq", "wk", "wv", "wo", "w1", "w2", "w3",
+              # an expert spec's: q/k-norm gains, router, expert stacks
+              "rms_q", "rms_k", "moe_gate", "moe_w1", "moe_w2", "moe_w3")
 # load-time fusions (ops/linear) + the megakernel's permuted wo
-FUSED_KEYS = ("wqkv", "w13", "wo_mega")
+FUSED_KEYS = ("wqkv", "w13", "wo_mega", "moe_w13")
 
 
 def split_layer_weights(params: dict[str, Any]):
@@ -421,18 +436,22 @@ def _forward_fused(spec: TransformerSpec, params: dict[str, Any],
 
 
 def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
-            tokens: jax.Array, pos: jax.Array) -> tuple[jax.Array, KVCache]:
+            tokens: jax.Array, pos: jax.Array, *, moe_counts: bool = False):
     """Run T tokens (at absolute positions pos..pos+T-1) through the model.
 
     Returns (logits (T, vocab) f32, updated cache). jit with spec static.
+    ``moe_counts`` (expert specs only; a Python-level switch, so a dense
+    spec traces the program it always did) adds a third result: the (L, E)
+    int32 count of rows routed to each expert in this dispatch.
     """
     t_len = tokens.shape[0]
     if t_len == 1:
         from ..ops import pallas_layer
 
-        if pallas_layer.fusion_enabled() and pallas_layer.supports(spec,
-                                                                   params):
-            return _forward_fused(spec, params, cache, tokens, pos)
+        if pallas_layer.fusion_enabled():
+            pallas_layer.refuse_expert_spec(spec)
+            if pallas_layer.supports(spec, params):
+                return _forward_fused(spec, params, cache, tokens, pos)
     positions = pos + jnp.arange(t_len)
     with jax.named_scope(SCOPE_EMBED):
         x = params["tok_embedding"][tokens].astype(jnp.float32)  # (T, dim)
@@ -450,16 +469,19 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
         idx, lw_slice = per_layer
         lw = layer_view(stacked, lw_slice, idx)
         x, k_all, v_all = _layer(spec, x, lw, k_all, v_all, idx, pos,
-                                 positions)
-        return (x, k_all, v_all), None
+                                 positions, moe_counts)
+        x, counts = x if moe_counts else (x, None)
+        return (x, k_all, v_all), counts
 
     idxs = jnp.arange(spec.n_layers, dtype=jnp.int32)
-    (x, k_new, v_new), _ = jax.lax.scan(scan_body, (x, cache.k, cache.v),
-                                        (idxs, scanned))
+    (x, k_new, v_new), counts = jax.lax.scan(
+        scan_body, (x, cache.k, cache.v), (idxs, scanned))
 
     with jax.named_scope(SCOPE_LOGITS):
         x = rmsnorm(x, params["rms_final"])
         logits = matmul(params["wcls"], x)
+    if moe_counts:
+        return logits, KVCache(k_new, v_new), counts
     return logits, KVCache(k_new, v_new)
 
 
@@ -816,8 +838,10 @@ def paged_decode_attention(head_size: int, kv_mul: int, page_size: int,
 def forward_batch_paged(spec: TransformerSpec, page_size: int,
                         params: dict[str, Any], cache,
                         tokens: jax.Array, pos_vec: jax.Array,
-                        table: jax.Array, *, kv_quant: str = "f32"):
+                        table: jax.Array, *, kv_quant: str = "f32",
+                        moe_counts: bool = False):
     """Decode one token per row against the PAGED page-pool cache.
+    ``moe_counts`` as in ``forward``: a third result, (L, E) int32.
 
     forward_batch_ragged's twin for the paged layout: cache planes are
     (L, P, page_size, n_kv, hs) pool pages (init_cache_paged), ``table``
@@ -865,13 +889,17 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
             ao, *kv = paged_decode_attention(
                 hs, kv_mul, page_size, P, q, k, v, *kv, idx, pos_vec,
                 table)
-        x = _post_attention(spec, lw, x, ao)
-        return (x, *kv), None
+        x = _post_attention(spec, lw, x, ao, moe_counts)
+        x, counts = x if moe_counts else (x, None)
+        return (x, *kv), counts
 
     idxs = jnp.arange(L, dtype=jnp.int32)
-    (x, *kv), _ = jax.lax.scan(scan_body, (x, *planes), (idxs, scanned))
+    (x, *kv), counts = jax.lax.scan(scan_body, (x, *planes),
+                                    (idxs, scanned))
     x = rmsnorm(x, params["rms_final"])
     logits = matmul(params["wcls"], x)
+    if moe_counts:
+        return logits, rebuild_paged_cache(tuple(kv), L), counts
     return logits, rebuild_paged_cache(tuple(kv), L)
 
 

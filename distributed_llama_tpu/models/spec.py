@@ -17,6 +17,25 @@ src/transformer.cpp:298-352:
 
 Matmul weights are stored row-major (d, n): out[i] = sum_j w[i, j] * x[j]
 (reference src/funcs.cpp:269-299 semantics).
+
+Expert models (``n_experts > 0``; OLMoE-style routed FFN, optional q/k-norm)
+carry a VERSIONED header extension that no 28-byte-header file has: three
+int32 {EXT_MAGIC (negative, where a 28-byte file holds its positive dim),
+EXT_VERSION, number of ints that follow}, then the seven base ints and
+{nExperts, nActiveExperts, qkNorm}: 52 bytes. A spec with all three at
+their defaults writes and reads the 28-byte header byte for byte as before.
+``hidden_dim`` is then the width of ONE expert, and the per-layer order is:
+
+  attention_norm, ffn_norm (F32 dim),
+  [q_norm (F32 dim), k_norm (F32 kvDim)   -- only if qkNorm]
+  wq, wk, wv, wo                          [weightsFloatType]
+  router (F32, nExperts x dim)
+  per expert e: w1_e (hidden x dim), w2_e (dim x hidden), w3_e (hidden x dim)
+                                          [weightsFloatType]
+
+In the param tree the expert tensors are stacked as ``moe_w1`` / ``moe_w2`` /
+``moe_w3`` (L, E, d, n), the router as ``moe_gate`` (L, E, dim) float32, the
+q/k-norm gains as ``rms_q`` (L, dim) and ``rms_k`` (L, kvDim).
 """
 
 from __future__ import annotations
@@ -28,6 +47,10 @@ from ..ops.quants import FloatType, batch_bytes
 
 HEADER_STRUCT = struct.Struct("<7i")
 HEADER_BYTES = HEADER_STRUCT.size  # 28
+# extended header: magic, version, count of the ints that follow
+EXT_MAGIC = -0x444C4D58   # "DLMX"; a 28-byte header starts with dim > 0
+EXT_VERSION = 2
+EXT_STRUCT = struct.Struct("<13i")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,6 +64,28 @@ class TransformerSpec:
     seq_len: int
     weights_float_type: FloatType = FloatType.F32
     buffer_float_type: FloatType = FloatType.F32
+    # routed experts (0 = a dense SwiGLU FFN), experts kept per token, and
+    # RMSNorm gains over the whole q / k projection before RoPE
+    n_experts: int = 0
+    n_active_experts: int = 0
+    qk_norm: bool = False
+
+    def __post_init__(self):
+        if bool(self.n_experts) != bool(self.n_active_experts) or not (
+                0 <= self.n_active_experts <= self.n_experts):
+            raise ValueError(
+                f"n_experts={self.n_experts} / n_active_experts="
+                f"{self.n_active_experts}: both 0 (dense FFN) or "
+                f"0 < active <= experts")
+
+    @property
+    def extended(self) -> bool:
+        """True when the file carries the 52-byte header extension."""
+        return bool(self.n_experts or self.qk_norm)
+
+    @property
+    def header_bytes(self) -> int:
+        return EXT_STRUCT.size if self.extended else HEADER_BYTES
 
     @property
     def head_size(self) -> int:
@@ -60,24 +105,70 @@ class TransformerSpec:
     @classmethod
     def from_header(cls, raw: bytes, weights_float_type=FloatType.F32,
                     buffer_float_type=FloatType.F32) -> "TransformerSpec":
-        dim, hidden, n_layers, n_heads, n_kv, vocab, seq = HEADER_STRUCT.unpack(
-            raw[:HEADER_BYTES])
+        ext = (0, 0, 0)
+        if struct.unpack_from("<i", raw)[0] == EXT_MAGIC:
+            if len(raw) < EXT_STRUCT.size:
+                raise ValueError("extended header truncated")
+            _, version, count, *ints = EXT_STRUCT.unpack(
+                raw[:EXT_STRUCT.size])
+            if version != EXT_VERSION or count != 10:
+                raise ValueError(f"unknown header extension version "
+                                 f"{version} ({count} ints)")
+            base, ext = ints[:7], ints[7:]
+        else:
+            base = HEADER_STRUCT.unpack(raw[:HEADER_BYTES])
+        dim, hidden, n_layers, n_heads, n_kv, vocab, seq = base
         # llama2.c-style exports flag a shared classifier with a negative
         # vocab size; the reference takes abs() (transformer.cpp:73)
         return cls(dim, hidden, n_layers, n_heads, n_kv, abs(vocab), seq,
-                   FloatType(weights_float_type), FloatType(buffer_float_type))
+                   FloatType(weights_float_type), FloatType(buffer_float_type),
+                   n_experts=ext[0], n_active_experts=ext[1],
+                   qk_norm=bool(ext[2]))
 
     def header(self) -> bytes:
-        return HEADER_STRUCT.pack(self.dim, self.hidden_dim, self.n_layers,
-                                  self.n_heads, self.n_kv_heads,
-                                  self.vocab_size, self.seq_len)
+        base = (self.dim, self.hidden_dim, self.n_layers, self.n_heads,
+                self.n_kv_heads, self.vocab_size, self.seq_len)
+        if not self.extended:
+            return HEADER_STRUCT.pack(*base)
+        return EXT_STRUCT.pack(EXT_MAGIC, EXT_VERSION, 10, *base,
+                               self.n_experts, self.n_active_experts,
+                               int(self.qk_norm))
 
     # -- per-tensor shapes (d, n) in file order ----------------------------
 
     def layer_matmul_shapes(self) -> list[tuple[str, tuple[int, int]]]:
+        """One layer's matmul tensors that exist ONCE a layer, in file
+        order: an expert spec has the four attention tensors here and its
+        FFN under ``expert_matmul_shapes``."""
         d, h, kv = self.dim, self.hidden_dim, self.kv_dim
-        return [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
-                ("wo", (d, d)), ("w1", (h, d)), ("w2", (d, h)), ("w3", (h, d))]
+        attn = [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
+                ("wo", (d, d))]
+        if self.n_experts:
+            return attn
+        return attn + [("w1", (h, d)), ("w2", (d, h)), ("w3", (h, d))]
+
+    def expert_matmul_shapes(self) -> list[tuple[str, tuple[int, int]]]:
+        """The tensors of ONE routed expert, in file order; a layer holds
+        ``n_experts`` of each, stacked (L, E, d, n) in the param tree.
+        Empty for a dense spec."""
+        if not self.n_experts:
+            return []
+        d, h = self.dim, self.hidden_dim
+        return [("moe_w1", (h, d)), ("moe_w2", (d, h)), ("moe_w3", (h, d))]
+
+    def matmul_shape_counts(self) -> list[tuple[tuple[int, int], int]]:
+        """((d, n), copies per layer) of every per-layer matmul tensor:
+        what a size or layout gate walks."""
+        return ([(shape, 1) for _, shape in self.layer_matmul_shapes()]
+                + [(shape, self.n_experts)
+                   for _, shape in self.expert_matmul_shapes()])
+
+    def layer_norm_shapes(self) -> list[tuple[str, int]]:
+        """One layer's float32 gain vectors, in file order."""
+        norms = [("rms_att", self.dim), ("rms_ffn", self.dim)]
+        if self.qk_norm:
+            norms += [("rms_q", self.dim), ("rms_k", self.kv_dim)]
+        return norms
 
     def matmul_bytes(self, shape: tuple[int, int]) -> int:
         dd, nn = shape
@@ -89,14 +180,15 @@ class TransformerSpec:
         return 2 * (self.seq_len * self.head_size // 2) * 4
 
     def block_bytes(self) -> int:
-        b = 2 * self.dim * 4  # rmsAtt + rmsFfn, always F32
-        for _, shape in self.layer_matmul_shapes():
-            b += self.matmul_bytes(shape)
+        b = sum(n * 4 for _, n in self.layer_norm_shapes())  # always F32
+        b += self.n_experts * self.dim * 4                   # router, F32
+        for shape, copies in self.matmul_shape_counts():
+            b += copies * self.matmul_bytes(shape)
         return b
 
     def file_size(self) -> int:
         """Byte-exact total, mirroring the check at transformer.cpp:344-348."""
-        b = HEADER_BYTES
+        b = self.header_bytes
         b += self.vocab_size * self.dim * 4          # tok_embeddings, F32
         b += self.n_layers * self.block_bytes()
         b += self.dim * 4                            # rmsFinal, F32

@@ -20,13 +20,21 @@ from .spec import TransformerSpec
 def _build_tree(spec: TransformerSpec, t, mm) -> dict:
     """Assemble the param tree from a dense builder ``t`` and a matmul-weight
     builder ``mm`` — the one place that knows the tree's key set."""
+    # draw order is part of the seed's meaning: a dense spec's tree is the
+    # one it always was (rms_att, rms_ffn before wcls)
     p = {"tok_embedding": t(spec.vocab_size, spec.dim),
-         "rms_final": 1 + t(spec.dim),
-         "rms_att": 1 + t(spec.n_layers, spec.dim),
-         "rms_ffn": 1 + t(spec.n_layers, spec.dim),
-         "wcls": mm(spec.vocab_size, spec.dim)}
+         "rms_final": 1 + t(spec.dim)}
+    for name, n in spec.layer_norm_shapes():
+        p[name] = 1 + t(spec.n_layers, n)
+    p["wcls"] = mm(spec.vocab_size, spec.dim)
     for name, shape in spec.layer_matmul_shapes():
         p[name] = mm(spec.n_layers, *shape)
+    for name, shape in spec.expert_matmul_shapes():
+        p[name] = mm(spec.n_layers, spec.n_experts, *shape)
+    if spec.n_experts:
+        # router rows ~N(0, 1/sqrt(dim)): t() draws at std 0.05
+        p["moe_gate"] = (t(spec.n_layers, spec.n_experts, spec.dim)
+                         * np.float32(20.0 / np.sqrt(spec.dim)))
     return p
 
 
@@ -231,10 +239,16 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
         f.write(spec.header())
         f.write(f32(spec.vocab_size, spec.dim))
         for _ in range(spec.n_layers):
-            f.write(f32(spec.dim, base=1.0, scale=0.05))   # rms_att
-            f.write(f32(spec.dim, base=1.0, scale=0.05))   # rms_ffn
+            for _, n in spec.layer_norm_shapes():   # rms_att, rms_ffn, ...
+                f.write(f32(n, base=1.0, scale=0.05))
             for _, (d, n) in spec.layer_matmul_shapes():
                 f.write(q40(d, n))
+            if spec.n_experts:                      # router rows, F32
+                f.write(f32(spec.n_experts, spec.dim,
+                            scale=1.0 / np.sqrt(spec.dim)))
+            for _ in range(spec.n_experts):
+                for _, (d, n) in spec.expert_matmul_shapes():
+                    f.write(q40(d, n))
         f.write(f32(spec.dim, base=1.0, scale=0.05))       # rms_final
         f.write(b"\x00" * spec.rope_gap_bytes)
         f.write(q40(spec.vocab_size, spec.dim, zero_row=BOS))

@@ -41,6 +41,9 @@ SCOPE_FFN = "ffn"          # ffn rmsnorm + swiglu + w2 (+ its combine)
 SCOPE_LOGITS = "logits"    # final norm + wcls + logits gather
 SCOPE_LAYER = "layer"      # the scanned layer body (parent of attn/ffn)
 PHASE_SCOPES = (SCOPE_EMBED, SCOPE_ATTN, SCOPE_FFN, SCOPE_LOGITS)
+# inside SCOPE_FFN of an expert spec (ops/pallas_moe.moe_ffn opens them)
+SCOPE_MOE_ROUTER = "moe.router"    # router matmul, softmax, top-k
+SCOPE_MOE_EXPERTS = "moe.experts"  # slot building, expert kernels, combine
 
 # collective scopes: one per _ici_* helper, named after the helper so a
 # trace event inside e.g. `ici_all_gather` is attributable to the exact

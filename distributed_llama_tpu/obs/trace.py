@@ -191,6 +191,19 @@ class EngineMetrics:
             "dllama_spec_accepted_total",
             "Draft tokens the verify forward accepted (greedy exact "
             "match, or the rejection-sampling accept at temperature > 0)")
+        # routed-expert instruments: an expert model's decode steps move
+        # them (models/llama.forward_batch_paged's per-dispatch (L, E)
+        # counts); dense engines expose the two totals flat at zero. The
+        # per-expert rows appear with the first counted dispatch
+        self.moe_pairs = c(
+            "dllama_moe_routed_pairs_total",
+            "(row, expert) pairs routed in decode dispatches, summed over "
+            "layers")
+        self.moe_active = c(
+            "dllama_moe_active_experts_total",
+            "Distinct experts a decode dispatch routed to, summed over "
+            "layers and dispatches: the expert tiles a step must read")
+        self._moe_rows: list = []
         # cost-ledger / scheduler-census series (ISSUE 16). The closed
         # vocabularies (token kinds, stall causes) pre-register so a
         # fresh scrape shows the full matrix at zero; per-class series
@@ -398,6 +411,20 @@ class EngineMetrics:
         for launches, moved, n, b in self._collectives:
             launches.inc(n * steps)
             moved.inc(b * steps)
+
+    def record_moe(self, counts) -> None:
+        """One decode dispatch's (L, E) rows-per-expert counts."""
+        self.moe_pairs.inc(int(counts.sum()))
+        self.moe_active.inc(int((counts > 0).sum()))
+        if not self._moe_rows:
+            self._moe_rows = [
+                self.registry.labeled_counter(
+                    "dllama_moe_expert_rows_total", {"expert": str(e)},
+                    "Rows routed to each expert in decode dispatches, "
+                    "summed over layers")
+                for e in range(counts.shape[1])]
+        for ctr, rows in zip(self._moe_rows, counts.sum(axis=0)):
+            ctr.inc(int(rows))
 
     def record_retire(self, req, now: float) -> None:
         """Derive the lifecycle histograms at retirement. Cancelled and

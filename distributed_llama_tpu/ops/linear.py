@@ -210,6 +210,18 @@ def pack_q40_params(params: dict, enable: bool | None = None,
         if not isinstance(v, Q40Weight):
             return v
         d, n = v.logical_shape[-2], v.logical_shape[-1]
+        if k.startswith("moe_"):
+            # routed-expert stacks (L, E, d, n): the grouped kernels
+            # (ops/pallas_moe) read the nb-major layout only; a shape the
+            # row tiler cannot place stays codec and takes the XLA scan
+            if tp > 1:
+                raise ValueError(MOE_TP_REFUSAL)
+            from .pallas_moe import shape_places
+
+            # w1 and w3 are fused along d afterwards: both widths must place
+            fused_ok = k == "moe_w2" or shape_places(2 * d, n // 32)
+            return (to_kernel_layout_nb(v)
+                    if fused_ok and shape_places(d, n // 32) else v)
         if k in input_sharded and tp > 1:
             # fused-scheme wo/w2: full output rows, 1/tp of the input
             # blocks per shard — the nb axis is the sharded one, so the
@@ -248,6 +260,12 @@ def pack_q40_params(params: dict, enable: bool | None = None,
         return v
 
     return {k: pick(k, v) for k, v in params.items()}
+
+
+MOE_TP_REFUSAL = (
+    "expert (mixture-of-experts) models run on one chip only: placing "
+    "experts across tensor-parallel ranks is not implemented, so --tp > 1 "
+    "(or any sharded mesh) refuses them")
 
 
 def sharded_nb_major(d_local: int, nb_local: int, rows: int = 1) -> bool:
@@ -302,12 +320,13 @@ def fuse_q40_layer_matmuls(params: dict) -> dict:
             if not kernel_supports(qs_t.shape[2], qs_t.shape[3] * 32):
                 return
             out[dst] = Q40Kernel(qs_t, scale)
-        elif all(isinstance(w, Q40KernelNb) and w.qs_t.ndim == 4
+        elif all(isinstance(w, Q40KernelNb) and w.qs_t.ndim in (4, 5)
                  for w in ws):
-            # nb-major: the output dim d is MINOR — concat along it
-            qs_t = np.concatenate([w.qs_t for w in ws], axis=3)
-            scale = np.concatenate([w.scale for w in ws], axis=2)
-            if _pick_rows_nb(qs_t.shape[3], qs_t.shape[2]) is None:
+            # nb-major: the output dim d is MINOR — concat along it (an
+            # expert stack has one more leading axis)
+            qs_t = np.concatenate([w.qs_t for w in ws], axis=-1)
+            scale = np.concatenate([w.scale for w in ws], axis=-1)
+            if _pick_rows_nb(qs_t.shape[-1], qs_t.shape[-2]) is None:
                 return
             out[dst] = Q40KernelNb(qs_t, scale)
         else:
@@ -317,6 +336,7 @@ def fuse_q40_layer_matmuls(params: dict) -> dict:
 
     fuse("wqkv", ("wq", "wk", "wv"))
     fuse("w13", ("w1", "w3"))
+    fuse("moe_w13", ("moe_w1", "moe_w3"))
     return out
 
 
@@ -373,13 +393,15 @@ def q40_body_policy(spec, rows: int = 1) -> tuple[str, str]:
                            f"kernel serves T in {NB_MULTI_T_MAX + 1}.."
                            f"{MULTI_T_MAX}, d-major has the multi-T body")
 
-    shapes = [shape for _, shape in spec.layer_matmul_shapes()]
+    counted = spec.matmul_shape_counts()     # a layer's, experts included
+    shapes = [shape for shape, _ in counted]
     shapes.append((spec.vocab_size, spec.dim))  # wcls
     bad = [(d, n) for d, n in shapes if _pick_rows_nb(d, n // 32) is None]
     if bad:
         return "d-major", (f"shape {bad[0]} has no nb-major row tiling "
                            f"(rows must divide by 128)")
-    packed_gb = (spec.n_layers * sum(d * (n // 32) * 18 for d, n in shapes[:-1])
+    packed_gb = (spec.n_layers * sum(c * d * (n // 32) * 18
+                                     for (d, n), c in counted)
                  + spec.vocab_size * (spec.dim // 32) * 18) / 1e9
     raw_gb = os.environ.get("DLLAMA_Q40_BODY_MAX_GB", "6")
     try:
@@ -392,6 +414,11 @@ def q40_body_policy(spec, rows: int = 1) -> tuple[str, str]:
                            f"{max_gb:.0f} GB i4-conversion headroom gate "
                            f"(DLLAMA_Q40_BODY_MAX_GB; 13B-class OOM, "
                            f"BASELINE.md r5)")
+    if spec.n_experts:
+        return "d-major", (f"expert spec, ~{packed_gb:.1f} GB packed: the "
+                           f"stock picks stand (expert stacks always pack "
+                           f"nb-major) and the i4 chain body has no expert "
+                           f"kernel")
     return "i4-nb", (f"auto: shapes place nb-major, ~{packed_gb:.1f} GB "
                      f"packed fits the i4 headroom gate")
 

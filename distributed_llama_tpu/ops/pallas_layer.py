@@ -169,6 +169,16 @@ def _plan(spec):
     return plan
 
 
+def refuse_expert_spec(spec) -> None:
+    """The fused-layer kernels hold a dense SwiGLU tail: an expert spec
+    under the fusion switch stops here rather than run another program."""
+    if spec.n_experts:
+        raise ValueError(
+            f"DLLAMA_LAYER_FUSION={fusion_mode()} cannot run an expert "
+            f"(mixture-of-experts) model: the fused-layer kernels have no "
+            f"routed-expert tail; unset it")
+
+
 def supports(spec, params) -> bool:
     """Fused path precondition: stacked d-major Q40Kernel weights for the
     whole layer chain (wqkv/w13 load-time fusions present) + plannable
@@ -814,6 +824,8 @@ def prepare_mega_params(spec, params: dict) -> dict:
     the sigma-permuted wo stack as ``wo_mega`` (the megakernel's attention-
     output plane layout — see wo_block_perm). ``wo`` stays for the T>1
     prefill path, which runs the unfused kernels."""
+    if fusion_enabled():
+        refuse_expert_spec(spec)   # at load: before any program is built
     if not (fusion_mode() == "on" and supports(spec, params)
             and _mega_shapes_ok(spec)):
         return params

@@ -1,0 +1,395 @@
+"""Routed experts (mixture-of-experts FFN) over Q40 weights.
+
+The FFN sub-block of an expert spec (``TransformerSpec.n_experts > 0``):
+
+  r = W_g x                      router logits, float32 at HIGHEST: it decides
+                                 WHICH experts run, so no Q40 and no bf16
+  p = softmax(r)                 over all E experts
+  keep the k largest p AS THEY ARE (no renormalisation)
+  y = sum_e p_e * w2_e( silu(w1_e x) * w3_e x )      over the kept e
+
+Expert weights stay Q40, stacked (L, E, ...) in the nb-major kernel layout
+(io/loader.Q40KernelNb: the output dim rides the lanes, so OLMoE's block
+counts 64 and 32 pad nothing and the chip stores the stack as it is packed;
+a d-major leaf of that shape would be copied at the top of every step,
+ops/linear.sharded_nb_major). ``w1`` and ``w3`` are fused at load into
+``moe_w13`` (ops/linear.fuse_q40_layer_matmuls).
+
+Two grouped matmuls, picked by the dispatch width T (static):
+
+* ``T <= MOE_SLOT_T_MAX`` (decode, verify, small mixed dispatches): the
+  step's (row, expert) pairs are grouped into SLOTS of one expert and up to
+  ``MOE_SLOT_ROWS`` rows; the grid walks the slots from a scalar-prefetched
+  list, experts ascending, so a distinct expert's tile is fetched ONCE (a
+  second slot of the same expert repeats the block index and Pallas skips
+  the copy), never all E and never once per pair. The slot count is a
+  static bound (every expert active, every capacity overflowing); the live
+  count is data and the steps past it are skipped. Body: ``_slot_body``,
+  the nb-major small-T VPU body of ops/pallas_q40 (``_matvec_body_multi_nb``)
+  with a slot's input planes packed on the lanes.
+* larger T (prefill chunks: every expert is hit): every expert runs every
+  row through ``_mxu_body_merged``, the nb-major MXU body of ops/pallas_q40
+  (``_matmul_body_nb``) with the nibble planes merged into the contraction,
+  and the rows it was not routed are weighted 0.
+
+Both are the float32 arithmetic of the dense Q40 kernels; neither
+dequantizes an expert to HBM. Off the Pallas path (codec or dense leaves:
+the CPU tests, F32 files) ``_experts_xla`` scans the experts one at a time.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
+from .linear import StackedQ40, matmul, matmul_mode, silu
+from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NB_MULTI_T_MAX,
+                         NJ, _pick_block_t, _pick_rows_nb)
+
+MOE_SLOT_ROWS = NB_MULTI_T_MAX   # rows one slot carries: the VPU body's cap
+MOE_SLOT_T_MAX = 32              # wider dispatches take the MXU body
+
+
+def route(gate: jax.Array, xb: jax.Array, k: int):
+    """Router of one layer over rows ``xb`` (T, dim): the k largest softmax
+    probabilities of each row as they are, (T, k) weights and expert ids."""
+    logits = jnp.einsum("ed,td->te", gate.astype(jnp.float32),
+                        xb.astype(jnp.float32),
+                        preferred_element_type=jnp.float32,
+                        precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+
+def max_slots(t: int, k: int, n_experts: int, cap: int) -> int:
+    """Static bound on the slots of a T-row dispatch: sum_e ceil(r_e / cap)
+    with sum r_e = T k pairs over at most min(E, T k) active experts."""
+    pairs = t * k
+    active = min(n_experts, pairs)
+    return min(pairs, -(-(pairs + active * (cap - 1)) // cap))
+
+
+def build_slots(topi: jax.Array, n_experts: int, cap: int):
+    """Group the (T, k) routed pairs by expert into slots of ``cap`` rows.
+
+    Returns ``slot_expert`` (A,) expert of each slot, ascending, the slots
+    past the live count repeating the last live expert (no new tile is
+    fetched for them); ``n_slots`` () live slots; ``slot_rows`` (A, cap) the
+    row each lane computes (lanes no pair fills compute row 0 and are never
+    read back); ``pair_slot`` / ``pair_lane`` (T, k) where each pair's
+    result lands; ``counts`` (E,) rows routed to each expert."""
+    t, k = topi.shape
+    a = max_slots(t, k, n_experts, cap)
+    flat = topi.reshape(-1).astype(jnp.int32)                    # (P,)
+    onehot = (flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32)
+              ).astype(jnp.int32)                                # (P, E)
+    counts = onehot.sum(axis=0)
+    # rank of a pair among its expert's pairs, in row order
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - onehot,
+                               flat[:, None], axis=1)[:, 0]
+    per = (counts + cap - 1) // cap                # slots of each expert
+    ends = jnp.cumsum(per)
+    n_slots = ends[-1]
+    slot = (ends - per)[flat] + rank // cap
+    lane = rank % cap
+    slot_expert = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(a, dtype=jnp.int32), side="right"),
+        jnp.max(flat)).astype(jnp.int32)
+    slot_rows = jnp.zeros((a, cap), jnp.int32).at[slot, lane].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k)
+    return (slot_expert, n_slots.astype(jnp.int32), slot_rows,
+            slot.reshape(t, k), lane.reshape(t, k), counts)
+
+
+# -- the slot kernel (VPU body) -------------------------------------------------
+
+def _slot_body(qs_ref, s, xp_ref, out_ref, c: int):
+    """``_matvec_body_multi_nb`` (ops/pallas_q40: unpack each nibble plane
+    once, one (nb, R) accumulator a row, sublane reduction, the -8 folded
+    into one xsum term) with the rows' input planes PACKED ON THE LANES:
+    ``xp_ref`` (nb, W) holds row ti's block b as 32 consecutive columns from
+    32 * ti (value j under the low nibbles at + j, under the high ones at
+    + 16 + j) and its block sum at 32 * c + ti. The 2-D kernels' (NJ, nb, t) planes put t
+    minor, which a slot's 1 to 4 rows pad to 128 lanes: a megabyte a slot,
+    more than the expert tile it multiplies."""
+    accs = [None] * c
+    for j in range(NJ):
+        q = qs_ref[j].astype(jnp.int32)              # (nb, R)
+        wlo = (q & 0xF).astype(jnp.float32)
+        whi = (q >> 4).astype(jnp.float32)
+        for ti in range(c):
+            a = (wlo * xp_ref[:, 32 * ti + j][:, None]
+                 + whi * xp_ref[:, 32 * ti + NJ + j][:, None])
+            accs[ti] = a if accs[ti] is None else accs[ti] + a
+    rows = []
+    for ti in range(c):
+        acc = accs[ti] - 8.0 * xp_ref[:, 32 * c + ti][:, None]
+        rows.append(jnp.sum(acc * s, axis=0, keepdims=True))    # (1, R)
+    out_ref[...] = jnp.concatenate(rows, axis=0)                # (c, R)
+
+
+def _kernel_moe_slots(layer_ref, sexp_ref, n_ref, qs_ref, scale_ref, xp_ref,
+                      out_ref):
+    del layer_ref, sexp_ref  # consumed by the index maps
+
+    @pl.when(pl.program_id(1) < n_ref[0])
+    def _():
+        _slot_body(qs_ref, scale_ref[...], xp_ref, out_ref, out_ref.shape[0])
+
+
+def _slot_planes(xs: jax.Array, nb: int) -> jax.Array:
+    """(A, C, n) rows -> (A, nb, W) packed planes of ``_slot_body``, W the
+    32 * C + C columns rounded up to whole 128-lane tiles. (No array here
+    has a minor dim under 32: the chip pads a minor dim to 128 lanes.)"""
+    a, c, _ = xs.shape
+    x4 = xs.astype(jnp.float32).reshape(a, c, nb, 32)
+    planes = jnp.transpose(x4, (0, 2, 1, 3)).reshape(a, nb, 32 * c)
+    xsum = jnp.transpose(jnp.sum(x4, axis=-1), (0, 2, 1))       # (A, nb, C)
+    width = 32 * c + c
+    return jnp.pad(jnp.concatenate([planes, xsum], axis=-1),
+                   ((0, 0), (0, 0), (0, -width % 128)))
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def moe_q40_slots(layer, slot_expert, n_slots, qs_t, scale, xs, *,
+                  block_rows, interpret):
+    """out[a, c] = dequant(w[layer, slot_expert[a]]) @ xs[a, c] for the live
+    slots a < n_slots (the rest of ``out`` is not written). ``qs_t``
+    (L, E, NJ, nb, d) / ``scale`` (L, E, nb, d) nb-major; ``xs`` (A, C, n)."""
+    nb, d = qs_t.shape[-2], qs_t.shape[-1]
+    a, c, _ = xs.shape
+    xp = _slot_planes(xs, nb)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        # slots innermost: the slots of one expert follow each other with
+        # the same weight block index, which is what skips the re-fetch
+        grid=(d // block_rows, a),
+        # leading dims are squeezed (None): the body sees (NJ, nb, rows)
+        # codes, (nb, rows) scales and one slot's (nb, W) planes
+        in_specs=[
+            pl.BlockSpec((None, None, NJ, nb, block_rows),
+                         lambda i, g, L, S, N: (L[0], S[g], 0, 0, i)),
+            pl.BlockSpec((None, None, nb, block_rows),
+                         lambda i, g, L, S, N: (L[0], S[g], 0, i)),
+            pl.BlockSpec((None, nb, xp.shape[-1]),
+                         lambda i, g, L, S, N: (g, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, c, block_rows),
+                               lambda i, g, L, S, N: (g, 0, i)),
+    )
+    return pl.pallas_call(
+        _kernel_moe_slots, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((a, c, d), jnp.float32),
+        compiler_params=_VMEM64_PARAMS, interpret=interpret,
+        name="moe_q40_slots",
+    )(layer, slot_expert, n_slots.reshape(1), qs_t, scale, xp)
+
+
+# -- the every-expert kernel (MXU body) ---------------------------------------
+
+def _mxu_body_merged(qs_ref, s, xlo_ref, xhi_ref, out_ref, bf16: bool):
+    """``_matmul_body_nb`` (ops/pallas_q40: dequantize the tile, float32
+    dots at HIGHEST, or bf16 under fast-prefill) with the 16 nibble planes
+    MERGED into the contraction: qs_ref (NJ, nb, R) codes, s (nb, R) scales,
+    xlo/xhi (bt, NJ * nb) with value j of block b at column j * nb + b; out
+    (bt, R). The 2-D kernels contract one plane at a time over nb, which is
+    64 or 32 for an expert: half or a quarter of an MXU pass's rows, 32
+    passes a tile. One (bt, NJ * nb) x (NJ * nb, R) dot per nibble half fills
+    them (measured on OLMoE's chunk: PERF.md section 6, PR 26)."""
+    nj, nb, r = qs_ref.shape
+    wdt = jnp.bfloat16 if bf16 else jnp.float32
+    prec = None if bf16 else jax.lax.Precision.HIGHEST
+    q = qs_ref[...].astype(jnp.int32)                # (NJ, nb, R)
+    dn = (((1,), (0,)), ((), ()))
+    acc = None
+    for x_ref, codes in ((xlo_ref, q & 0xF), (xhi_ref, q >> 4)):
+        w = ((codes - 8).astype(jnp.float32) * s[None]).astype(wdt)
+        a = jax.lax.dot_general(x_ref[...].astype(wdt),
+                                w.reshape(nj * nb, r), dn,
+                                preferred_element_type=jnp.float32,
+                                precision=prec)
+        acc = a if acc is None else acc + a
+    out_ref[...] = acc
+
+
+def _kernel_moe_mxu(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref, out_ref,
+                    *, bf16):
+    del layer_ref
+    _mxu_body_merged(qs_ref, scale_ref[...], xlo_ref, xhi_ref, out_ref, bf16)
+
+
+def _merged_planes(x: jax.Array, nb: int):
+    """(..., T, n) rows -> xlo, xhi (..., T, NJ * nb): value j (xhi: 16 + j)
+    of block b at column j * nb + b."""
+    x4 = x.astype(jnp.float32).reshape(*x.shape[:-1], nb, 2, NJ)
+    flat = jnp.swapaxes(x4, -3, -1)                  # (..., T, NJ, 2, nb)
+    return (flat[..., 0, :].reshape(*x.shape[:-1], NJ * nb),
+            flat[..., 1, :].reshape(*x.shape[:-1], NJ * nb))
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "block_t",
+                                             "interpret", "bf16"))
+def moe_q40_mxu(layer, qs_t, scale, x, *, block_rows, block_t, interpret,
+                bf16=False):
+    """out[e] = dequant(w[layer, e]) @ x for EVERY expert e: ``x`` (T, n)
+    is shared by the experts, or (E, T, n) holds each expert's own rows."""
+    n_exp, nb, d = qs_t.shape[1], qs_t.shape[-2], qs_t.shape[-1]
+    t = x.shape[-2]
+    xlo, xhi = _merged_planes(x, nb)
+    if x.ndim == 2:
+        x_spec = pl.BlockSpec((block_t, NJ * nb),
+                              lambda e, ti, i, L: (ti, 0))
+    else:
+        x_spec = pl.BlockSpec((None, block_t, NJ * nb),
+                              lambda e, ti, i, L: (e, ti, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_exp, t // block_t, d // block_rows),
+        in_specs=[
+            pl.BlockSpec((None, None, NJ, nb, block_rows),
+                         lambda e, ti, i, L: (L[0], e, 0, 0, i)),
+            pl.BlockSpec((None, None, nb, block_rows),
+                         lambda e, ti, i, L: (L[0], e, 0, i)),
+            x_spec, x_spec,
+        ],
+        out_specs=pl.BlockSpec((None, block_t, block_rows),
+                               lambda e, ti, i, L: (e, ti, i)),
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel_moe_mxu, bf16=bf16),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_exp, t, d), jnp.float32),
+        compiler_params=_VMEM64_PARAMS, interpret=interpret,
+        name="moe_q40_mxu",
+    )(layer, qs_t, scale, xlo, xhi)
+
+
+# -- the expert layer ---------------------------------------------------------
+
+def _slot_block_rows(d: int, nb: int, cap: int) -> int | None:
+    """Row tile of the slot kernel: _q40_matmul_nbmajor's small-T rule."""
+    rows = _pick_rows_nb(d, nb)
+    if rows is None or cap == 1:
+        return rows
+    limit = max(128, 300_000 // (cap * nb))
+    return next((r for r in range(min(rows, limit - limit % 128), 0, -128)
+                 if d % r == 0), None)
+
+
+def _mxu_block_rows(d: int, nb: int, block_t: int) -> int | None:
+    """Row tile of the MXU kernel: _q40_matmul_nbmajor's T > 8 rule."""
+    rows = _pick_rows_nb(d, nb)
+    if rows is None:
+        return None
+    limit = _MATMUL_ROWSXNB_CAP // nb
+    rows = next((r for r in range(min(rows, limit - limit % 128), 0, -128)
+                 if d % r == 0), None)
+    if rows is not None and block_t < 128 and rows > 256:
+        rows = 256 if d % 256 == 0 else (128 if d % 128 == 0 else None)
+    return rows
+
+
+def shape_places(d: int, nb: int) -> bool:
+    """True when both grouped kernels can tile a (d, nb * 32) expert
+    tensor: ops/linear.pack_q40_params packs an expert stack nb-major only
+    then (else it stays codec and takes ``_experts_xla``)."""
+    return (_slot_block_rows(d, nb, MOE_SLOT_ROWS) is not None
+            and _mxu_block_rows(d, nb, 8) is not None)
+
+
+def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret):
+    t, k = topi.shape
+    cap = min(MOE_SLOT_ROWS, t)
+    (slot_expert, n_slots, slot_rows, pair_slot, pair_lane,
+     counts) = build_slots(topi, n_experts, cap)
+
+    def call(w, xs):
+        nb, d = w.qs_t.shape[-2:]
+        return moe_q40_slots(layer, slot_expert, n_slots, w.qs_t, w.scale,
+                             xs, block_rows=_slot_block_rows(d, nb, cap),
+                             interpret=interpret)
+
+    h13 = call(w13, xb[slot_rows])                       # (A, C, 2 hidden)
+    hid = h13.shape[-1] // 2
+    out = call(w2, silu(h13[..., :hid]) * h13[..., hid:])   # (A, C, dim)
+    picked = out[pair_slot, pair_lane]                   # (T, k, dim)
+    return jnp.sum(picked * topw[..., None], axis=1), counts
+
+
+def _routing_mask(topw, topi, n_experts):
+    """(T, E) weight of each expert for each row (0 where not routed) and
+    the (E,) count of rows routed to each."""
+    onehot = topi[..., None] == jnp.arange(n_experts, dtype=topi.dtype)
+    return (jnp.sum(jnp.where(onehot, topw[..., None], 0.0), axis=1),
+            jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32))
+
+
+def _experts_mxu(layer, w13, w2, xb, topw, topi, n_experts, interpret, bf16):
+    wmask, counts = _routing_mask(topw, topi, n_experts)
+    t = xb.shape[0]
+    pad = (-t) % 8                       # the MXU body tiles T by eights
+    if pad:
+        xb = jnp.pad(xb, ((0, pad), (0, 0)))
+        wmask = jnp.pad(wmask, ((0, pad), (0, 0)))
+
+    def call(w, x):
+        nb, d = w.qs_t.shape[-2:]
+        block_t = _pick_block_t(t + pad, nb)
+        return moe_q40_mxu(layer, w.qs_t, w.scale, x,
+                           block_rows=_mxu_block_rows(d, nb, block_t),
+                           block_t=block_t, interpret=interpret, bf16=bf16)
+
+    h13 = call(w13, xb)                                  # (E, T, 2 hidden)
+    hid = h13.shape[-1] // 2
+    hb = silu(h13[..., :hid]) * h13[..., hid:] * wmask.T[:, :, None]
+    return jnp.sum(call(w2, hb), axis=0)[:t], counts
+
+
+def _experts_xla(lw, xb, topw, topi, n_experts):
+    """One expert at a time through ``ops/linear.matmul`` (codec Q40 or
+    dense leaves): every row through every expert, weighted 0 where it was
+    not routed. Holds one dequantized expert at a time."""
+    wmask, counts = _routing_mask(topw, topi, n_experts)
+
+    def body(acc, ws):
+        w1, w2, w3, m = ws
+        h = silu(matmul(w1, xb)) * matmul(w3, xb)
+        return acc + matmul(w2, h * m[:, None]), None
+
+    acc, _ = jax.lax.scan(body, jnp.zeros_like(xb, dtype=jnp.float32),
+                          (lw["moe_w1"], lw["moe_w2"], lw["moe_w3"], wmask.T))
+    return acc, counts
+
+
+def moe_ffn(spec, lw: dict, xb: jax.Array):
+    """The routed-expert FFN of one layer over normalised rows ``xb``
+    ((T, dim) or (B, T, dim)): returns (y like xb, counts (E,) int32 of
+    rows routed to each expert in this dispatch)."""
+    lead = xb.shape[:-1]
+    x2 = xb.reshape(-1, xb.shape[-1])
+    n_exp, k = spec.n_experts, spec.n_active_experts
+    with jax.named_scope(SCOPE_MOE_ROUTER):
+        topw, topi = route(lw["moe_gate"], x2, k)
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        w13, w2 = lw.get("moe_w13"), lw.get("moe_w2")
+        if isinstance(w13, StackedQ40) and isinstance(w2, StackedQ40):
+            interpret = jax.default_backend() != "tpu"
+            layer = jnp.asarray(w13.layer, dtype=jnp.int32).reshape(1)
+            if x2.shape[0] <= MOE_SLOT_T_MAX:
+                y, counts = _experts_slots(layer, w13.w, w2.w, x2, topw,
+                                           topi, n_exp, interpret)
+            else:
+                y, counts = _experts_mxu(layer, w13.w, w2.w, x2, topw, topi,
+                                         n_exp, interpret,
+                                         matmul_mode() == "bf16")
+        elif "moe_w1" not in lw or isinstance(lw["moe_w1"], StackedQ40):
+            raise NotImplementedError(
+                "expert stacks packed for the kernels without their fused "
+                "moe_w13 (ops/linear.fuse_q40_layer_matmuls)")
+        else:
+            y, counts = _experts_xla(lw, x2, topw, topi, n_exp)
+    return y.reshape(*lead, -1), counts

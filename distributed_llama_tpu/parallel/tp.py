@@ -166,6 +166,10 @@ _INPUT_SHARDED_SCHEMES = ("fused", "overlap")
 def param_specs(params: dict[str, Any],
                 scheme: str | None = None) -> dict[str, Any]:
     scheme = scheme or tp_scheme()
+    if any(name.startswith("moe_") for name in params):
+        from ..ops.linear import MOE_TP_REFUSAL
+
+        raise ValueError(MOE_TP_REFUSAL)  # refuse, never mis-shard an expert
     specs: dict[str, Any] = {}
     for name, val in params.items():
         spec = _MATMUL_SPECS.get(name) or _REPL_SPECS.get(name)
@@ -727,6 +731,10 @@ def validate_sharding(spec: TransformerSpec, mesh: Mesh,
     n_slices = mesh.shape["tp"]
     n_sp = mesh.shape.get("sp", 1)
     scheme = scheme or tp_scheme()
+    if spec.n_experts:
+        from ..ops.linear import MOE_TP_REFUSAL
+
+        raise ValueError(MOE_TP_REFUSAL)
     for req, name in ((spec.n_heads, "n_heads"),
                       (spec.n_kv_heads, "n_kv_heads"),
                       (spec.hidden_dim, "hidden_dim"),

@@ -253,6 +253,20 @@ class ContinuousStats:
     # charges it as real step time so loadcheck's gate catches the
     # overrun as inflated decode latency.
     overrun_steps: int = 0
+    # routed experts (an expert spec's decode steps; 0 / None otherwise):
+    # (row, expert) pairs routed, distinct experts summed over layers and
+    # steps (the expert tiles a step must read), and the rows each expert
+    # took summed over layers (an (E,) vector: how uneven the router is)
+    moe_pairs: int = 0
+    moe_active: int = 0
+    moe_load: Any = None
+
+    def count_moe(self, counts) -> None:
+        """One dispatch's (L, E) rows-per-expert counts."""
+        self.moe_pairs += int(counts.sum())
+        self.moe_active += int((counts > 0).sum())
+        load = counts.sum(axis=0, dtype=np.int64)
+        self.moe_load = load if self.moe_load is None else self.moe_load + load
 
     @property
     def tokens_per_s(self) -> float:
@@ -448,6 +462,7 @@ class ContinuousEngine:
         self.block_steps = block_steps  # >1: fused K-step chains (step_many)
         dtype = cache_dtype or jnp.float32
         self._cache_dtype = dtype
+        self._step_counts = None  # an expert spec's paged step_once program
         from ..models.llama import KVCache, forward, init_cache
 
         def _insert(cache_b, c1, b):
@@ -542,6 +557,18 @@ class ContinuousEngine:
                             forward_batch_paged, spec, page_size,
                             kv_quant=kv_quant)),
                         donate_argnums=1))
+                if spec.n_experts:
+                    # step_once's program for an expert spec: the same
+                    # step, with the (L, E) routed-rows counts beside the
+                    # logits (the chains keep the two-result ``_step``)
+                    self._step_counts = _shared_program(
+                        ("step_paged_counts", spec, page_size, kv_quant),
+                        lambda: jax.jit(
+                            named_program(
+                                "serve_decode_step", functools.partial(
+                                    forward_batch_paged, spec, page_size,
+                                    kv_quant=kv_quant, moe_counts=True)),
+                            donate_argnums=1))
                 if spec_k:
                     self._verify_base = _shared_program(
                         ("verify", spec, page_size, kv_quant),
@@ -2011,10 +2038,20 @@ class ContinuousEngine:
                 if self._alloc is not None:
                     rows.append(self._stage_tables())
             with host_phase("serve.dispatch"):
-                logits, self.cache = self._step(self.params, self.cache,
-                                                *rows)
+                if self._step_counts is not None:
+                    logits, self.cache, moe = self._step_counts(
+                        self.params, self.cache, *rows)
+                else:
+                    moe = None
+                    logits, self.cache = self._step(self.params, self.cache,
+                                                    *rows)
             with host_phase("serve.fetch"):  # the wait and the transfer
                 logits = np.asarray(logits)  # dlint: allow[D001] host sampler needs logits
+                if moe is not None:  # 4 KB beside the logits
+                    moe = np.asarray(moe)  # dlint: allow[D001] routed-rows counters
+                    self.stats.count_moe(moe)
+                    if self._obs is not None:
+                        self._obs.record_moe(moe)
             if self._obs is not None:
                 # np.asarray synced the logits; the sync flag also drains
                 # the donated cache write (obs/trace.sync_device_timing)

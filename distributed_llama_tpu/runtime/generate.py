@@ -57,6 +57,9 @@ class Engine:
         # stats line all describe the same collective schedule
         self.tp_scheme = tp_scheme()
         self._loops: dict = {}  # (temp, topp) -> compiled device loop
+        # routed (row, expert) pairs and distinct experts summed over layers
+        # and ``infer`` steps (expert specs; GenStats carries them)
+        self.moe_pairs = self.moe_active = 0
         if self.sharded:
             from ..parallel import (make_sharded_forward, shard_cache,
                                     shard_params, validate_sharding)
@@ -74,8 +77,13 @@ class Engine:
             self.params = params_to_device(params, spec=spec)
             self.cache = init_cache(spec, self.cache_dtype)
             self._step_raw = functools.partial(forward, spec)
+            # an expert spec's step also hands out the (L, E) count of rows
+            # routed to each expert (``infer`` fetches it with the logits);
+            # the loops and the prefill chunks keep the two-result forward
             self._fwd = jax.jit(
-                named_program("inference_step", self._step_raw),
+                named_program("inference_step", functools.partial(
+                    forward, spec, moe_counts=True) if spec.n_experts
+                    else self._step_raw),
                 donate_argnums=1)
         # a SECOND jit of the same forward for the T>8 prefill chunks, so
         # that a capture tells a chunk's program run from a decode step's
@@ -96,10 +104,15 @@ class Engine:
         """One decode step; returns f32 logits (vocab,). Blocks on device."""
         with host_phase("inference.dispatch"):
             tok = self.jnp.asarray([token], dtype=self.jnp.int32)
-            logits, self.cache = self._fwd(self.params, self.cache, tok,
-                                           self.jnp.int32(pos))
+            logits, self.cache, *moe = self._fwd(self.params, self.cache,
+                                                 tok, self.jnp.int32(pos))
         with host_phase("inference.fetch"):  # the wait and the transfer
-            return np.asarray(logits[0])  # dlint: allow[D001] host sampler input
+            out = np.asarray(logits[0])  # dlint: allow[D001] host sampler input
+            if moe:  # an expert spec on one chip: 4 KB beside the logits
+                counts = np.asarray(moe[0])  # dlint: allow[D001] routed-rows counters
+                self.moe_pairs += int(counts.sum())
+                self.moe_active += int((counts > 0).sum())
+            return out
 
     def prefill(self, tokens: list[int], pos0: int = 0,
                 chunk: int = 128) -> None:
@@ -158,9 +171,10 @@ class Engine:
             # parity program
             f = self._fwd_prefill if len(part) > 8 else self._fwd
             with host_phase("inference.prefill_chunk"):
-                _, self.cache = f(self.params, self.cache,
-                                  jnp.asarray(part, jnp.int32),
-                                  jnp.int32(start))
+                # an expert spec's ``_fwd`` has a third result (infer's)
+                _, self.cache, *_ = f(self.params, self.cache,
+                                      jnp.asarray(part, jnp.int32),
+                                      jnp.int32(start))
 
         run_chunked_prefill(fwd, rest, rest_pos, chunk, seq_len)
 
@@ -257,6 +271,8 @@ class GenStats:
     prompt_rest: list = dataclasses.field(default_factory=list)
     # ^ prompt tokens NOT yet consumed when the run ended (forced-token tail
     #   for a resumed continuation; empty once the prompt is exhausted)
+    moe_pairs: int = 0   # expert specs, per-step loop: routed (row, expert)
+    moe_active: int = 0  # pairs / distinct experts, summed over layers+steps
 
     @property
     def avg(self) -> tuple[float, float, float]:
@@ -345,6 +361,7 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
 
     comm = engine.comm_stats()
     stats = GenStats(final_pos=start_pos, final_token=token)
+    moe0 = (getattr(engine, "moe_pairs", 0), getattr(engine, "moe_active", 0))
     pos = start_pos
     while pos < steps:
         t0 = time.perf_counter()
@@ -396,6 +413,8 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                     piece=piece.decode("utf-8", errors="replace"))
             token = next_token
 
+    stats.moe_pairs = getattr(engine, "moe_pairs", 0) - moe0[0]
+    stats.moe_active = getattr(engine, "moe_active", 0) - moe0[1]
     if stats.tokens:
         # the SAME summary shape the serving metrics expose (/health,
         # bench.py rows): p50/p95/p99 over the per-token wall times plus
@@ -411,6 +430,11 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                   f"p95 {lat['p95']:.2f}  p99 {lat['p99']:.2f} | "
                   f"ICI S {comm.sent_bytes / 1024:.0f} kB "
                   f"R {comm.recv_bytes / 1024:.0f} kB /token")
+            if stats.moe_active:
+                print(f"Routed experts:      "
+                      f"{stats.moe_pairs / stats.moe_active:.2f} rows per "
+                      f"active expert, {stats.moe_active / stats.tokens:.1f} "
+                      f"active (summed over layers) per token")
         log_event("run.summary", None, tokens=stats.tokens,
                   avg_ms=round(stats.total_ms / stats.tokens, 3),
                   latency_ms={k: round(v, 3) for k, v in lat.items()},

@@ -159,6 +159,34 @@ def _paged_q8(ps: int = 16):
              _sd((b, SEQ // ps), jnp.int32)))
 
 
+def _moe(kind: str, leaf: str, rows: int):
+    """The routed-expert kernels (ops/pallas_moe) at OLMoE-1B-7B's widths:
+    64 experts a layer, ``w13`` (2 x 1024, 2048) and ``w2`` (2048, 1024),
+    nb-major. ``slots``: a decode dispatch of ``rows`` rows (8 experts a
+    row); ``mxu``: a prefill chunk of ``rows`` rows through every expert."""
+    from distributed_llama_tpu.ops import pallas_moe as pm
+
+    n_exp, k = 64, 8
+    d, n = {"w13": (2048, 2048), "w2": (2048, 1024)}[leaf]
+    nb = n // 32
+    qs_t = _sd((2, n_exp, 16, nb, d), jnp.uint8)
+    scale = _sd((2, n_exp, nb, d), jnp.float32)
+    layer = _sd((1,), jnp.int32)
+    if kind == "slots":
+        cap = min(pm.MOE_SLOT_ROWS, rows)
+        a = pm.max_slots(rows, k, n_exp, cap)
+        fn = functools.partial(
+            pm.moe_q40_slots, interpret=False,
+            block_rows=pm._slot_block_rows(d, nb, cap))
+        return fn, (layer, _sd((a,), jnp.int32), _sd((), jnp.int32), qs_t,
+                    scale, _sd((a, cap, n), jnp.float32))
+    block_t = pm._pick_block_t(rows, nb)
+    fn = functools.partial(pm.moe_q40_mxu, interpret=False, block_t=block_t,
+                           block_rows=pm._mxu_block_rows(d, nb, block_t))
+    x = (rows, n) if leaf == "w13" else (n_exp, rows, n)   # w2: own rows
+    return fn, (layer, qs_t, scale, _sd(x, jnp.float32))
+
+
 # kernel=False: the dispatch documents an XLA dequantize-then-dot route for
 # that shape (nb-major serves T <= 4, the int4 planes T == 1) — the case pins
 # the routing as well as the compile
@@ -184,6 +212,13 @@ CASES = {
     # type for load ... xf16" and "Only arguments with ... bfloat16 or 32-bit
     # element types are supported"
     "paged-q8-ps16-t1": (_paged_q8, True),
+    # was (first written with ``ref.at[0]`` views of blocks 1 to 4 lanes
+    # wide): "Slice shape along dimension 3 must be aligned to tiling (128),
+    # but is 4"
+    **{f"moe-{kind}-{leaf}-T{rows}":
+       (functools.partial(_moe, kind, leaf, rows), True)
+       for kind, rows in (("slots", 16), ("slots", 1), ("mxu", 128))
+       for leaf in ("w13", "w2")},
 }
 
 

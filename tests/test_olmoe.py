@@ -1,0 +1,541 @@
+"""An expert spec (OLMoE's block: routed experts, top-k kept as they are,
+q/k-norm) through the normal path against the plain float32 reference
+(models/reference_olmoe.py), on LOGITS, at a toy size on the CPU.
+
+Tolerance. Logits here are O(1) (max |logit| about 3). The program and the
+reference are both float32 and differ by accumulation order: 3e-6 measured.
+``TOL`` = 5e-5 leaves a factor of ten and is two orders under what a bf16
+router or a bf16 expert dot gives (1e-2: test_bf16_*_fails prove both fail).
+
+Near-ties. Top-k is discontinuous: where the router's k-th and (k+1)-th
+logits differ by less than the two implementations' rounding, they may keep
+different experts and every later position differs. So a sequence is
+compared only up to the first position whose smallest router margin over
+the layers (``r_(k) - r_(k+1)``, in the router's logits) is under
+``MARGIN_EPS`` (1e-4: over ten times the 3e-6 the two differ by); each test
+asserts that this is most of the sequence. The logit tolerance is never
+widened for a flipped expert.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from distributed_llama_tpu.io.loader import (Q40Weight, load_model,
+                                              read_spec, to_kernel_layout_nb,
+                                              write_model)
+from distributed_llama_tpu.models import reference_olmoe
+from distributed_llama_tpu.models.llama import (forward, init_cache,
+                                                params_to_device)
+from distributed_llama_tpu.models.spec import HEADER_BYTES, TransformerSpec
+from distributed_llama_tpu.models.synth import (synth_params,
+                                                write_synth_q40_model)
+from distributed_llama_tpu.ops import pallas_moe
+from distributed_llama_tpu.ops.quants import FloatType, dequantize_q40
+
+TOL = 5e-5
+MARGIN_EPS = 1e-4
+SEQ = 48
+
+
+def toy_spec(qk_norm=True, **kw):
+    base = dict(dim=256, hidden_dim=128, n_layers=4, n_heads=4, n_kv_heads=4,
+                vocab_size=512, seq_len=64,
+                weights_float_type=FloatType.Q40, n_experts=8,
+                n_active_experts=2, qk_norm=qk_norm)
+    base.update(kw)
+    return TransformerSpec(**base)
+
+
+SPEC = toy_spec()
+
+
+@pytest.fixture(scope="module")
+def tree():
+    return synth_params(SPEC, q40=True, seed=11)
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(5).integers(3, SPEC.vocab_size, SEQ)
+
+
+@pytest.fixture(scope="module")
+def want(tree, tokens):
+    """Reference logits, margins, routed ids of the test sequence."""
+    return reference_olmoe.forward(tree, SPEC, tokens)
+
+
+def compared(margins, at_least):
+    n = reference_olmoe.compared_positions(margins, MARGIN_EPS)
+    assert n >= at_least, (f"only {n} of {len(margins)} positions before a "
+                           f"router margin under {MARGIN_EPS}")
+    return n
+
+
+@pytest.fixture(params=["xla", "pallas"])
+def kernel_mode(request, monkeypatch):
+    """The XLA expert scan (codec leaves) and the grouped Pallas kernels in
+    interpret mode (nb-major leaves): the layout follows the mode."""
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", request.param)
+    return request.param
+
+
+# -- (i), (v): the whole sequence through ``forward``, q/k-norm on and off ----
+
+@pytest.mark.parametrize("qk_norm", [True, False])
+def test_forward_whole_sequence(kernel_mode, qk_norm):
+    spec = toy_spec(qk_norm=qk_norm)
+    tree = synth_params(spec, q40=True, seed=11)
+    toks = np.random.default_rng(5).integers(3, spec.vocab_size, SEQ)
+    ref, margins, _ = reference_olmoe.forward(tree, spec, toks)
+    params = params_to_device(tree, spec=spec)
+    assert ("moe_w13" in params) == (kernel_mode == "pallas")
+    assert ("rms_q" in params) == qk_norm
+    got, _ = jax.jit(lambda p, c, t: forward(spec, p, c, t, jnp.int32(0)))(
+        params, init_cache(spec), jnp.asarray(toks, jnp.int32))
+    n = compared(margins, SEQ * 3 // 4)   # T = 48 > 32: the MXU kernel
+    assert np.abs(np.asarray(got)[:n] - ref[:n]).max() < TOL
+
+
+def test_qk_norm_changes_the_logits(tree, tokens, want):
+    """The gains are in the computation: without them the logits move."""
+    off = toy_spec(qk_norm=False)
+    ref_off, _, _ = reference_olmoe.forward(
+        {k: v for k, v in tree.items() if k not in ("rms_q", "rms_k")},
+        off, tokens)
+    assert np.abs(ref_off - want[0]).max() > 100 * TOL
+
+
+# -- (ii): Engine.prefill in chunks, then Engine.infer through the cache -----
+
+def test_engine_prefill_then_infer(kernel_mode, tree, tokens, want):
+    from distributed_llama_tpu.runtime.generate import Engine
+
+    ref, margins, _ = want
+    eng = Engine(SPEC, tree)
+    n_pre = 40
+    eng.prefill([int(t) for t in tokens[:n_pre]], chunk=8)
+    n = compared(margins, SEQ * 3 // 4)
+    worst = 0.0
+    for pos in range(n_pre, n):
+        logits = eng.infer(int(tokens[pos]), pos)
+        worst = max(worst, float(np.abs(logits - ref[pos]).max()))
+    assert n > n_pre and worst < TOL
+    # (vi) T = 1: k distinct experts a layer, every step
+    steps = n - n_pre
+    k, L = SPEC.n_active_experts, SPEC.n_layers
+    assert eng.moe_pairs == eng.moe_active == steps * k * L
+
+
+# -- (iii), (vi): ContinuousEngine, paged, uneven lengths ---------------------
+
+def _served_rows(tree, slots, prompts, steps, **kw):
+    """Run ``prompts`` through a paged ContinuousEngine and record every
+    decode dispatch: (tokens, pos, the request in each slot, logits,
+    counts)."""
+    from distributed_llama_tpu.runtime.continuous import (ContinuousEngine,
+                                                          Request)
+
+    eng = ContinuousEngine(SPEC, tree, slots=slots, temperature=0.0,
+                           topp=0.9, seed=3, page_size=4, prefill_chunk=4,
+                           **kw)
+    seen, step = [], eng._step_counts
+    assert step is not None
+
+    def recording(params, cache, toks, pos, table):
+        logits, cache, counts = step(params, cache, toks, pos, table)
+        seen.append((np.asarray(toks), np.asarray(pos),
+                     [s.req for s in eng._pool], np.asarray(logits),
+                     np.asarray(counts)))
+        return logits, cache, counts
+
+    eng._step_counts = recording
+    reqs = [eng.submit(Request(tokens=list(p), steps=steps))
+            for p in prompts]
+    while eng.step_once():
+        pass
+    assert all(r.done.is_set() and r.error is None for r in reqs)
+    return eng, reqs, seen
+
+
+def test_continuous_paged_logits_and_counters(kernel_mode, tree):
+    from distributed_llama_tpu.obs.metrics import Registry
+
+    rng = np.random.default_rng(9)
+    prompts = [[1] + [int(t) for t in rng.integers(3, 500, n)]
+               for n in (2, 9, 5, 13, 4)]       # five requests, four slots
+    reg = Registry()
+    eng, reqs, seen = _served_rows(tree, 4, prompts, 24, metrics=reg)
+    k, L, E = SPEC.n_active_experts, SPEC.n_layers, SPEC.n_experts
+    # each request's sequence: its prompt, then the token fed at each later
+    # position
+    seqs = {id(r): {i: t for i, t in enumerate(r.tokens)} for r in reqs}
+    for toks, pos, owners, _, _ in seen:
+        for b, req in enumerate(owners):
+            if req is not None:
+                seqs[id(req)].setdefault(int(pos[b]), int(toks[b]))
+    refs = {}
+    for r in reqs:
+        seq = [seqs[id(r)][i] for i in range(len(seqs[id(r)]))]
+        logits, margins, routed = reference_olmoe.forward(tree, SPEC, seq)
+        refs[id(r)] = (logits, compared(margins, len(seq) * 3 // 4), routed)
+    worst, n_rows, n_count_steps = 0.0, 0, 0
+    for toks, pos, owners, logits, counts in seen:
+        assert counts.shape == (L, E) and counts.sum() == 4 * k * L
+        usable = all(req is not None and int(pos[b]) < refs[id(req)][1]
+                     for b, req in enumerate(owners))
+        if usable:   # every row known: the counts are the reference's
+            want = np.zeros((L, E), np.int64)
+            for b, req in enumerate(owners):
+                for layer in range(L):
+                    want[layer, refs[id(req)][2][int(pos[b]), layer]] += 1
+            assert (counts == want).all()
+            n_count_steps += 1
+        for b, req in enumerate(owners):
+            if req is None or int(pos[b]) >= refs[id(req)][1]:
+                continue
+            worst = max(worst, float(np.abs(
+                logits[b] - refs[id(req)][0][int(pos[b])]).max()))
+            n_rows += 1
+    assert n_rows > 60 and n_count_steps > 5 and worst < TOL
+    st = eng.stats
+    assert st.moe_pairs == st.steps * 4 * k * L          # rows x k x layers
+    assert st.moe_active == sum(int((c > 0).sum()) for *_, c in seen)
+    assert st.moe_load.sum() == st.moe_pairs and st.moe_load.shape == (E,)
+    assert reg.get("dllama_moe_routed_pairs_total").value == st.moe_pairs
+    assert reg.get("dllama_moe_active_experts_total").value == st.moe_active
+    text = reg.expose()
+    assert 'dllama_moe_expert_rows_total{expert="7"}' in text
+
+
+def test_dense_engine_exposes_moe_totals_at_zero():
+    from distributed_llama_tpu.obs.metrics import Registry
+    from distributed_llama_tpu.obs.trace import EngineMetrics
+
+    reg = Registry()
+    EngineMetrics(reg)
+    assert reg.get("dllama_moe_routed_pairs_total").value == 0
+    assert "dllama_moe_expert_rows_total" not in reg.expose()
+
+
+# -- a bf16 router and a bf16 expert dot both fail the tolerance -------------
+
+def test_bf16_expert_dot_fails(tree, tokens, want):
+    from distributed_llama_tpu.ops.linear import matmul_precision
+
+    params = params_to_device(tree, spec=SPEC)
+
+    def run(p, c, t):
+        with matmul_precision("bf16"):
+            return forward(SPEC, p, c, t, jnp.int32(0))
+
+    got, _ = jax.jit(run)(params, init_cache(SPEC),
+                          jnp.asarray(tokens, jnp.int32))
+    assert np.abs(np.asarray(got)[:4] - want[0][:4]).max() > 10 * TOL
+
+
+def test_bf16_router_fails(tree, tokens, want, monkeypatch):
+    def bf16_route(gate, xb, k):
+        logits = jnp.einsum("ed,td->te", gate.astype(jnp.bfloat16),
+                            xb.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
+        return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+    monkeypatch.setattr(pallas_moe, "route", bf16_route)
+    params = params_to_device(tree, spec=SPEC)
+    got, _ = jax.jit(lambda p, c, t: forward(SPEC, p, c, t, jnp.int32(0)))(
+        params, init_cache(SPEC), jnp.asarray(tokens, jnp.int32))
+    assert np.abs(np.asarray(got) - want[0]).max() > 10 * TOL
+
+
+# -- (iv): the grouped kernels, interpret mode, against a per-pair loop ------
+
+def _expert_stack(seed=3, L=2, E=8, hidden=128, dim=256):
+    rng = np.random.default_rng(seed)
+
+    def q40(d, n):
+        from distributed_llama_tpu.ops.quants import quantize_q40
+
+        return Q40Weight(*quantize_q40(
+            (rng.standard_normal((L, E, d, n)) / np.sqrt(n)
+             ).astype(np.float32)))
+
+    w1, w2, w3 = q40(hidden, dim), q40(dim, hidden), q40(hidden, dim)
+    nb1, nb3 = to_kernel_layout_nb(w1), to_kernel_layout_nb(w3)
+    w13 = type(nb1)(np.concatenate([nb1.qs_t, nb3.qs_t], -1),
+                    np.concatenate([nb1.scale, nb3.scale], -1))
+    dense = [dequantize_q40(w.qs, w.d16).astype(np.float64)
+             for w in (w1, w2, w3)]
+    return w13, to_kernel_layout_nb(w2), dense
+
+
+def _pair_loop(dense, layer, x, topw, topi):
+    w1, w2, w3 = (w[layer] for w in dense)
+    y = np.zeros((x.shape[0], w2.shape[1]))
+    for t in range(x.shape[0]):
+        for wgt, e in zip(topw[t], topi[t]):
+            g, u = w1[e] @ x[t], w3[e] @ x[t]
+            y[t] += wgt * (w2[e] @ (g / (1 + np.exp(-g)) * u))
+    return y
+
+
+def _routing(case, rows, k=2, n_experts=8, seed=0):
+    rng = np.random.default_rng(seed)
+    if case == "random":
+        topi = np.stack([rng.choice(n_experts, k, replace=False)
+                         for _ in range(rows)])
+    elif case == "rows_share_every_expert":
+        topi = np.tile(np.array([[5, 2]]), (rows, 1))
+    else:                                   # one expert takes every row
+        topi = np.stack([[3, (4 + t) % n_experts if (4 + t) % n_experts != 3
+                          else 0] for t in range(rows)])
+    return rng.random((rows, k)).astype(np.float32), topi.astype(np.int32)
+
+
+@pytest.mark.parametrize("case", ["random", "rows_share_every_expert",
+                                  "one_expert_takes_every_row"])
+@pytest.mark.parametrize("rows", [1, 3, 8, 16])
+def test_slot_kernel_matches_pair_loop(rows, case):
+    w13, w2, dense = _expert_stack()
+    x = np.random.default_rng(rows).standard_normal(
+        (rows, 256)).astype(np.float32)
+    topw, topi = _routing(case, rows)
+    layer = 1
+    got, counts = pallas_moe._experts_slots(
+        jnp.asarray([layer], jnp.int32), w13, w2, jnp.asarray(x),
+        jnp.asarray(topw), jnp.asarray(topi), 8, True)
+    want = _pair_loop(dense, layer, x.astype(np.float64), topw, topi)
+    assert np.abs(np.asarray(got) - want).max() < 1e-5
+    assert (np.asarray(counts) == np.bincount(topi.ravel(),
+                                              minlength=8)).all()
+
+
+@pytest.mark.parametrize("rows", [40, 13])   # 13: padded to the MXU's eights
+def test_every_expert_kernel_matches_pair_loop(rows):
+    w13, w2, dense = _expert_stack()
+    x = np.random.default_rng(rows).standard_normal(
+        (rows, 256)).astype(np.float32)
+    topw, topi = _routing("random", rows, seed=2)
+    got, counts = pallas_moe._experts_mxu(
+        jnp.asarray([0], jnp.int32), w13, w2, jnp.asarray(x),
+        jnp.asarray(topw), jnp.asarray(topi), 8, True, False)
+    want = _pair_loop(dense, 0, x.astype(np.float64), topw, topi)
+    assert np.abs(np.asarray(got) - want).max() < 1e-5
+    assert int(np.asarray(counts).sum()) == rows * 2
+
+
+@pytest.mark.parametrize("rows,k,n_experts,cap", [
+    (16, 8, 64, 4), (1, 8, 64, 1), (3, 2, 8, 3), (32, 8, 64, 4),
+    (16, 2, 8, 4)])
+def test_build_slots_places_every_pair_once(rows, k, n_experts, cap):
+    rng = np.random.default_rng(rows * k)
+    topi = np.stack([rng.choice(n_experts, k, replace=False)
+                     for _ in range(rows)]).astype(np.int32)
+    (slot_expert, n_slots, slot_rows, pair_slot, pair_lane,
+     counts) = map(np.asarray, pallas_moe.build_slots(
+         jnp.asarray(topi), n_experts, cap))
+    a = pallas_moe.max_slots(rows, k, n_experts, cap)
+    assert slot_expert.shape == (a,) and n_slots <= a
+    assert n_slots == sum(-(-c // cap) for c in counts)
+    assert (np.diff(slot_expert) >= 0).all()          # experts ascending
+    assert (slot_expert[n_slots:] == slot_expert[n_slots - 1]).all()
+    places = set()
+    for t in range(rows):
+        for j in range(k):
+            s, lane = pair_slot[t, j], pair_lane[t, j]
+            assert s < n_slots and slot_expert[s] == topi[t, j]
+            assert slot_rows[s, lane] == t
+            places.add((s, lane))
+    assert len(places) == rows * k                    # no two pairs collide
+
+
+def test_worst_case_routing_fits_the_static_slot_bound():
+    """Every row to the same k experts: the most slots one expert takes."""
+    topi = np.tile(np.arange(8, dtype=np.int32), (16, 1))
+    _, n_slots, *_ = pallas_moe.build_slots(jnp.asarray(topi), 64, 4)
+    assert int(n_slots) == 8 * 4 <= pallas_moe.max_slots(16, 8, 64, 4)
+
+
+# -- (vii): what refuses an expert spec --------------------------------------
+
+def test_tp_refuses_an_expert_spec(tree):
+    from distributed_llama_tpu.parallel import make_mesh
+    from distributed_llama_tpu.parallel.tp import (param_specs,
+                                                   validate_sharding)
+    from distributed_llama_tpu.runtime.generate import Engine
+
+    mesh = make_mesh(tp=2)
+    with pytest.raises(ValueError, match="one chip only"):
+        validate_sharding(SPEC, mesh)
+    with pytest.raises(ValueError, match="one chip only"):
+        param_specs(tree)
+    with pytest.raises(ValueError, match="one chip only"):
+        Engine(SPEC, tree, mesh=mesh)
+
+
+def test_cli_tp_refuses_an_expert_model(tmp_path, capsys):
+    from distributed_llama_tpu.frontend import cli
+    from distributed_llama_tpu.models.synth import write_synth_tokenizer
+
+    model, tok = str(tmp_path / "m.bin"), str(tmp_path / "t.bin")
+    write_synth_q40_model(model, SPEC, seed=1)
+    write_synth_tokenizer(tok, SPEC.vocab_size)
+    rc = cli.main(["inference", "--model", model, "--tokenizer", tok,
+                   "--prompt", "hi", "--steps", "4", "--tp", "2",
+                   "--weights-float-type", "q40"])
+    assert rc == 2 and "one chip only" in capsys.readouterr().err
+
+
+def test_fused_forward_switch_refuses_an_expert_spec(tree, monkeypatch):
+    from distributed_llama_tpu.runtime.generate import Engine
+
+    monkeypatch.setenv("DLLAMA_LAYER_FUSION", "on")
+    with pytest.raises(ValueError, match="DLLAMA_LAYER_FUSION"):
+        Engine(SPEC, tree)
+    params = params_to_device(tree)            # packed without the spec
+    with pytest.raises(ValueError, match="DLLAMA_LAYER_FUSION"):
+        forward(SPEC, params, init_cache(SPEC), jnp.asarray([5], jnp.int32),
+                jnp.int32(0))
+
+
+def test_spec_rejects_half_an_expert_description():
+    with pytest.raises(ValueError, match="n_active_experts"):
+        toy_spec(n_active_experts=0)
+    with pytest.raises(ValueError, match="n_active_experts"):
+        toy_spec(n_experts=2, n_active_experts=3)
+
+
+# -- (viii): the file format --------------------------------------------------
+
+@pytest.mark.parametrize("ftype", [FloatType.F32, FloatType.Q40])
+def test_write_load_round_trip(tmp_path, ftype):
+    spec = toy_spec(weights_float_type=ftype, n_layers=2)
+    dense = synth_params(spec, q40=False, seed=4)
+    path = str(tmp_path / "m.bin")
+    write_model(path, spec, dense)
+    assert read_spec(path, ftype) == spec
+    got_spec, got = load_model(path, weights_float_type=ftype)
+    assert got_spec == spec and set(got) == set(dense)
+    for name, val in dense.items():
+        have = got[name]
+        if isinstance(have, Q40Weight):
+            assert have.qs.shape[:-2] == val.shape[:-1]
+            have = dequantize_q40(have.qs, have.d16)
+            assert np.abs(have - val).max() < 0.03   # Q40 rounding: delta / 2
+        else:
+            assert np.array_equal(have, val)
+    ref, _, _ = reference_olmoe.forward(got, spec, [1, 7, 9])
+    assert np.isfinite(ref).all()
+
+
+def test_old_header_loads_byte_for_byte(tmp_path):
+    """A dense spec writes the 28-byte header and reads it back: nothing of
+    the extension reaches a file that has no experts."""
+    spec = TransformerSpec(64, 128, 2, 4, 2, 96, 32, FloatType.F32)
+    assert not spec.extended and len(spec.header()) == HEADER_BYTES == 28
+    assert spec.header() == np.array([64, 128, 2, 4, 2, 96, 32],
+                                     "<i4").tobytes()
+    path = str(tmp_path / "d.bin")
+    write_model(path, spec, synth_params(spec, q40=False, seed=2))
+    got_spec, got = load_model(path)
+    assert got_spec == spec and "moe_gate" not in got and "rms_q" not in got
+    with open(path, "rb") as fh:
+        assert fh.read(28) == spec.header()
+
+
+def test_extended_header_and_sizes():
+    ext = SPEC.header()
+    assert len(ext) == SPEC.header_bytes == 52
+    assert TransformerSpec.from_header(ext, FloatType.Q40) == SPEC
+    assert np.frombuffer(ext, "<i4")[0] < 0       # no dim reads as this
+    with pytest.raises(ValueError, match="version"):
+        TransformerSpec.from_header(
+            ext[:4] + np.array([9], "<i4").tobytes() + ext[8:])
+    # the published widths: 3.83 GB of Q40 matmul weights, 4.26 GB a file
+    olmoe = TransformerSpec(2048, 1024, 16, 16, 16, 50304, 4096,
+                            FloatType.Q40, n_experts=64, n_active_experts=8,
+                            qk_norm=True)
+    matmul = olmoe.n_layers * sum(
+        c * olmoe.matmul_bytes(s) for s, c in olmoe.matmul_shape_counts()
+    ) + olmoe.matmul_bytes((olmoe.vocab_size, olmoe.dim))
+    assert round(matmul / 1e9, 2) == 3.83
+    assert round(olmoe.file_size() / 1e9, 2) == 4.26
+
+
+def test_tensor_byte_ranges_cover_an_expert_file():
+    from distributed_llama_tpu.io.loader import tensor_byte_ranges
+
+    ranges = tensor_byte_ranges(SPEC)
+    assert ranges[0].offset == SPEC.header_bytes
+    assert sum(r.nbytes for r in ranges) + SPEC.header_bytes \
+        == SPEC.file_size()
+    per_layer = [r.name for r in ranges if r.layer == 0]
+    assert per_layer[:9] == ["rms_att", "rms_ffn", "rms_q", "rms_k", "wq",
+                             "wk", "wv", "wo", "moe_gate"]
+    assert per_layer[9:12] == ["moe_w1", "moe_w2", "moe_w3"]
+    assert len(per_layer) == 9 + 3 * SPEC.n_experts
+
+
+def test_synth_model_file_runs_through_the_cli(tmp_path, capsys):
+    from distributed_llama_tpu.frontend import cli
+    from distributed_llama_tpu.models.synth import write_synth_tokenizer
+
+    model, tok = str(tmp_path / "m.bin"), str(tmp_path / "t.bin")
+    size = write_synth_q40_model(model, SPEC, seed=1)
+    assert size == SPEC.file_size()
+    write_synth_tokenizer(tok, SPEC.vocab_size)
+    rc = cli.main(["inference", "--model", model, "--tokenizer", tok,
+                   "--prompt", "hello", "--steps", "12", "--tp", "1",
+                   "--temperature", "0", "--weights-float-type", "q40"])
+    out = capsys.readouterr().out
+    assert not rc and "Routed experts:" in out and "nExperts: 8" in out
+
+
+def test_converter_permutes_the_qk_gains_with_the_rows():
+    """convert.py's interleaving of wq / wk rows, applied to the gains: the
+    norm-then-rotate of the published rotate-half form and of this
+    program's interleaved pairs give the same attention scores."""
+    from distributed_llama_tpu.convert import HFCheckpoint
+
+    un = HFCheckpoint._unpermute
+    n_heads, hs, n = 2, 8, 16
+    rng = np.random.default_rng(0)
+    w, gain = rng.standard_normal((n_heads * hs, n)), rng.random(n_heads * hs)
+    x = rng.standard_normal(n)
+    published = (w @ x) * gain                     # rotate-half order
+    ours = (un(None, w, n_heads) @ x) * un(None, gain, n_heads)
+    assert np.allclose(un(None, published, n_heads), ours)
+    half = published.reshape(n_heads, 2, hs // 2)
+    pairs = ours.reshape(n_heads, hs // 2, 2)
+    assert np.allclose(half[:, 0], pairs[..., 0])
+    assert np.allclose(half[:, 1], pairs[..., 1])
+
+
+# -- the analysis tools count an expert spec or refuse it --------------------
+
+def test_memory_model_counts_the_experts():
+    from distributed_llama_tpu.analysis import memory_model
+
+    olmoe = TransformerSpec(2048, 1024, 16, 16, 16, 50304, 4096,
+                            FloatType.Q40, n_experts=64, n_active_experts=8,
+                            qk_norm=True)
+    got = memory_model.weights_device_bytes(olmoe, 1)
+    with pytest.raises(ValueError, match="one chip only"):
+        memory_model.weights_device_bytes(olmoe, 4)
+    assert 3.8e9 < got < 4.4e9
+
+
+def test_body_policy_counts_the_experts(monkeypatch):
+    from distributed_llama_tpu.ops.linear import q40_body_policy
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    olmoe = TransformerSpec(2048, 1024, 16, 16, 16, 50304, 4096,
+                            FloatType.Q40, n_experts=64, n_active_experts=8,
+                            qk_norm=True)
+    policy, reason = q40_body_policy(olmoe, rows=16)
+    assert policy == "d-major" and "3.8 GB" in reason
+    monkeypatch.setenv("DLLAMA_Q40_BODY_MAX_GB", "3")
+    assert "exceeds" in q40_body_policy(olmoe, rows=16)[1]
