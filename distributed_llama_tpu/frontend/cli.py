@@ -219,7 +219,17 @@ def _maybe_distributed(args) -> None:
 
 
 def cmd_inference(argv: list[str], quiet: bool = False) -> int:
-    ap = argparse.ArgumentParser(prog="dllama-tpu inference")
+    ap = argparse.ArgumentParser(
+        prog="dllama-tpu inference",
+        description="One prompt, token by token. At --temperature 0 the "
+                    "per-token loop runs one step ahead of the host: the "
+                    "step program returns the argmax of its logits and step "
+                    "n+1 is enqueued on it before step n's token is back "
+                    "(with a temperature the host samples from the logits). "
+                    "The summary's 'Steps run ahead: U used, D dropped' "
+                    "counts steps found already in flight (tokens - 1 after "
+                    "the prompt) and steps enqueued and thrown away (one, "
+                    "after a BOS stop).")
     ap.add_argument("--model", required=True, help=_MODEL_HELP)
     ap.add_argument("--tokenizer", required=True)
     ap.add_argument("--prompt", default=None)

@@ -6,11 +6,25 @@ reference's `Inference::infer(token, pos) -> logits` shape
 observable behavior: prompt tokens forced one at a time, sampling after the
 prompt, stop on BOS, per-token stats line and final averages.
 
+At temperature 0 the loop runs ONE STEP AHEAD of the host: the step program
+also returns the argmax of its logits (what ``Sampler.sample`` gives at
+temperature 0), and ``Engine.infer(..., pick=True)`` enqueues step n+1 on that
+token, still on the device, before it waits for step n's four bytes. The
+chip then never waits for the host between tokens. With a temperature the
+host samples from the logits as before: on the chip the device sampler of
+the ``--fast`` chain misses the host ``Sampler``'s token where the
+distribution is near uniform (PERF.md section 6, PR 27), and this loop's
+contract is the host's stream.
+
 Stats: the reference splits per-token time into I (inference) and T (transfer)
 via task-type timing (utils.cpp:104-106) and counts socket bytes. Under XLA
 the collectives are fused into the step, so we report:
-  I = device step time (jitted forward, block_until_ready)
-  T = host-side time (logits transfer + sampling + loop overhead)
+  I = the call of Engine.infer: launch and wait. Running ahead, the launch
+      is the NEXT step's and the wait is for this step's token (the step
+      has been running since the call before, so I is the device step less
+      the host's time between two calls)
+  T = host-side time between infer's return and the token being known: the
+      host sampler (0 where the device picks)
   S/R = analytic per-token collective bytes (parallel/comm_stats.py)
 """
 
@@ -31,6 +45,32 @@ from ..obs.spans import host_phase, named_program
 from ..parallel.comm_stats import (CommStats, ici_all_gather_bytes,
                                    sp_lse_bytes, tp_scheme)
 from .sampling import Sampler
+
+
+@dataclasses.dataclass
+class _Step:
+    """One enqueued run of the step program: its results, still on the
+    device, and what ``Engine.infer`` needs to hand it out or drop it."""
+    pos: int
+    logits: Any
+    picked: Any            # (1,) int32: the next step's input, never fetched
+    moe: list              # [(L, E) routed-row counts] of an expert spec
+    token: int | None = None  # input token, once the host knows it
+
+
+def _with_pick(step):
+    """``step`` (a forward: logits, cache[, moe counts]) AND the greedy pick
+    of the next token from row 0 of its logits (lowest index on a tie, as
+    the host's ``sample_argmax``), as one traceable function. The picked
+    token stays on the device as the next step's (1,) input."""
+    def step_and_pick(params, cache, tokens, pos):
+        import jax.numpy as jnp
+
+        logits, cache, *moe = step(params, cache, tokens, pos)
+        picked = jnp.argmax(logits[0]).astype(jnp.int32)
+        return (logits, picked[None], cache, *moe)
+
+    return step_and_pick
 
 
 class Engine:
@@ -60,17 +100,23 @@ class Engine:
         # routed (row, expert) pairs and distinct experts summed over layers
         # and ``infer`` steps (expert specs; GenStats carries them)
         self.moe_pairs = self.moe_active = 0
+        self._ahead: _Step | None = None  # the step enqueued ahead, if any
+        # steps found in flight and handed out / enqueued ahead and dropped
+        self.ahead_used = self.ahead_dropped = 0
+        tok_sharding = None  # one chip: the default device
         if self.sharded:
             from ..parallel import (make_sharded_forward, shard_cache,
                                     shard_params, validate_sharding)
 
             validate_sharding(spec, mesh)  # clear error before any device_put
+            tok_sharding = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec())
             self.params = shard_params(params, mesh, scheme=self.tp_scheme)
             self.cache = shard_cache(init_cache(spec, self.cache_dtype), mesh)
-            self._fwd = make_sharded_forward(spec, mesh,
-                                             scheme=self.tp_scheme,
-                                             name="inference_step")
-            self._step_raw = self._fwd  # shard_map wrapper; traceable in scan
+            # shard_map wrapper under a jit of its own; traceable in scan
+            # and inside the step program below
+            step = self._step_raw = make_sharded_forward(
+                spec, mesh, scheme=self.tp_scheme, name="inference_step")
         else:
             from ..models.llama import params_to_device
 
@@ -78,13 +124,19 @@ class Engine:
             self.cache = init_cache(spec, self.cache_dtype)
             self._step_raw = functools.partial(forward, spec)
             # an expert spec's step also hands out the (L, E) count of rows
-            # routed to each expert (``infer`` fetches it with the logits);
+            # routed to each expert (``infer`` fetches it with the token);
             # the loops and the prefill chunks keep the two-result forward
-            self._fwd = jax.jit(
-                named_program("inference_step", functools.partial(
-                    forward, spec, moe_counts=True) if spec.n_experts
-                    else self._step_raw),
-                donate_argnums=1)
+            step = (functools.partial(forward, spec, moe_counts=True)
+                    if spec.n_experts else self._step_raw)
+
+        # host tokens are placed as the step's own ``picked`` result is, or
+        # the mesh's step program would compile once for each of the two
+        self._put = functools.partial(jax.device_put, device=tok_sharding)
+        # ONE step program, (logits, picked, cache[, moe counts]): a caller
+        # that wants logits fetches those, ``pick`` callers four bytes
+        self._fwd = jax.jit(
+            named_program("inference_step", _with_pick(step)),
+            donate_argnums=1)
         # a SECOND jit of the same forward for the T>8 prefill chunks, so
         # that a capture tells a chunk's program run from a decode step's
         # by name; decode and the T=1 prefill tail share ``_fwd``. Under
@@ -100,18 +152,59 @@ class Engine:
             named_program("inference_prefill_chunk", chunk_fwd),
             donate_argnums=1)
 
-    def infer(self, token: int, pos: int) -> np.ndarray:
-        """One decode step; returns f32 logits (vocab,). Blocks on device."""
+    def _launch(self, tokens, pos: int) -> _Step:
+        """Enqueue one run of the step program (returns at once) on
+        ``tokens``: a list from the host, or a step's ``picked``."""
+        if isinstance(tokens, list):
+            tokens = self._put(np.array(tokens, np.int32))
+        logits, picked, self.cache, *moe = self._fwd(
+            self.params, self.cache, tokens, np.int32(pos))
+        return _Step(pos, logits, picked, moe)
+
+    def drop_ahead(self) -> None:
+        """Forget the step enqueued ahead, if there is one: the caller is
+        not going where ``infer`` assumed (a BOS stop, a forced token, a
+        jump, ``reset``). The cache slot it wrote is overwritten before
+        anything reads it (``prefill``'s invariant)."""
+        if self._ahead is not None:
+            self._ahead = None
+            self.ahead_dropped += 1
+
+    def infer(self, token: int, pos: int, pick: bool = False,
+              last: bool = False) -> np.ndarray | int:
+        """One decode step; returns f32 logits (vocab,). Blocks on device.
+
+        With ``pick`` it returns the id of the NEXT token instead, the
+        argmax of those logits taken on the device: what a ``Sampler`` at
+        temperature 0 gives. And unless ``last``, before it waits it
+        enqueues the step after this one, on the picked token (still on the
+        device) at ``pos + 1``; the next call finds that step in flight if
+        it is handed the token returned here and ``pos + 1``, and only
+        fetches four bytes. Any other call drops it (``drop_ahead``) and
+        runs as usual. Pass ``last`` where no such call will follow (the
+        step budget's end).
+        """
         with host_phase("inference.dispatch"):
-            tok = self.jnp.asarray([token], dtype=self.jnp.int32)
-            logits, self.cache, *moe = self._fwd(self.params, self.cache,
-                                                 tok, self.jnp.int32(pos))
+            step = self._ahead
+            if pick and step is not None and (step.token, step.pos) == (
+                    token, pos):
+                self._ahead = None
+                self.ahead_used += 1
+            else:
+                self.drop_ahead()
+                step = self._launch([token], pos)
+            if pick and not last and pos + 1 < self.spec.seq_len:
+                self._ahead = self._launch(step.picked, pos + 1)
         with host_phase("inference.fetch"):  # the wait and the transfer
-            out = np.asarray(logits[0])  # dlint: allow[D001] host sampler input
-            if moe:  # an expert spec on one chip: 4 KB beside the logits
-                counts = np.asarray(moe[0])  # dlint: allow[D001] routed-rows counters
+            if step.moe:  # an expert spec on one chip: 4 KB beside the rest
+                counts = np.asarray(step.moe[0])  # dlint: allow[D001] routed-rows counters
                 self.moe_pairs += int(counts.sum())
                 self.moe_active += int((counts > 0).sum())
+            if not pick:
+                return np.asarray(step.logits)[0]  # dlint: allow[D001] host sampler input
+            out = int(np.asarray(step.picked)[0])  # dlint: allow[D001] four bytes a token
+            if self._ahead is not None:
+                self._ahead.token = out
             return out
 
     def prefill(self, tokens: list[int], pos0: int = 0,
@@ -139,6 +232,7 @@ class Engine:
         """
         jnp = self.jnp
         seq_len = self.spec.seq_len
+        self.drop_ahead()
         if pos0 + len(tokens) > seq_len:
             # fail loudly before any cache write: past here the fused path
             # would raise an opaque numpy broadcast error and the unfused
@@ -168,13 +262,14 @@ class Engine:
         def fwd(part, start):
             # the chunk program (bf16 under fast-prefill) runs the T>8
             # MXU-bound chunks only; the T=1 tail shares the decode
-            # parity program
-            f = self._fwd_prefill if len(part) > 8 else self._fwd
+            # parity program (``_launch``: its logits and pick go unread)
             with host_phase("inference.prefill_chunk"):
-                # an expert spec's ``_fwd`` has a third result (infer's)
-                _, self.cache, *_ = f(self.params, self.cache,
-                                      jnp.asarray(part, jnp.int32),
-                                      jnp.int32(start))
+                if len(part) > 8:
+                    _, self.cache = self._fwd_prefill(
+                        self.params, self.cache,
+                        jnp.asarray(part, jnp.int32), jnp.int32(start))
+                else:
+                    self._launch(part, start)
 
         run_chunked_prefill(fwd, rest, rest_pos, chunk, seq_len)
 
@@ -223,6 +318,7 @@ class Engine:
         return self._loops[key]
 
     def reset(self):
+        self.drop_ahead()
         self.cache = init_cache(self.spec, self.cache_dtype)
         if self.sharded:
             from ..parallel import shard_cache
@@ -273,6 +369,8 @@ class GenStats:
     #   for a resumed continuation; empty once the prompt is exhausted)
     moe_pairs: int = 0   # expert specs, per-step loop: routed (row, expert)
     moe_active: int = 0  # pairs / distinct experts, summed over layers+steps
+    ahead_used: int = 0     # temperature 0: steps found already in flight
+    ahead_dropped: int = 0  # steps enqueued ahead and thrown away (BOS stop)
 
     @property
     def avg(self) -> tuple[float, float, float]:
@@ -361,18 +459,25 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
 
     comm = engine.comm_stats()
     stats = GenStats(final_pos=start_pos, final_token=token)
-    moe0 = (getattr(engine, "moe_pairs", 0), getattr(engine, "moe_active", 0))
+    moe0 = engine.moe_pairs, engine.moe_active
+    ahead0 = engine.ahead_used, engine.ahead_dropped
     pos = start_pos
     while pos < steps:
         t0 = time.perf_counter()
-        logits = engine.infer(token, pos)
+        forced = pos + 1 < len(prompt_tokens)
+        # at temperature 0 the device takes the argmax and runs one step
+        # ahead of this loop, but not past the budget: a step nobody wants
+        # would sit in front of the next generation's prefill
+        greedy = not forced and sampler.temperature == 0.0
+        out = engine.infer(token, pos, pick=greedy, last=pos + 1 >= steps)
         t1 = time.perf_counter()
-
-        if pos + 1 < len(prompt_tokens):
+        if forced:
             next_token = prompt_tokens[pos + 1]
+        elif greedy:
+            next_token = out
         else:
             with host_phase("inference.sampler"):
-                next_token = sampler.sample(logits)
+                next_token = sampler.sample(out)
         t2 = time.perf_counter()
 
         with host_phase("inference.emit"):
@@ -389,7 +494,9 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                                  if t >= 0]
             if next_token == BOS:
                 # reference stops on BOS before decoding it
-                # (tokenizer.cpp:376)
+                # (tokenizer.cpp:376); the step enqueued on it is the one
+                # step ever wasted
+                engine.drop_ahead()
                 break
             out_tokens.append(next_token)
             piece = tokenizer.decode_piece(token, next_token)
@@ -413,8 +520,10 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                     piece=piece.decode("utf-8", errors="replace"))
             token = next_token
 
-    stats.moe_pairs = getattr(engine, "moe_pairs", 0) - moe0[0]
-    stats.moe_active = getattr(engine, "moe_active", 0) - moe0[1]
+    stats.moe_pairs = engine.moe_pairs - moe0[0]
+    stats.moe_active = engine.moe_active - moe0[1]
+    stats.ahead_used = engine.ahead_used - ahead0[0]
+    stats.ahead_dropped = engine.ahead_dropped - ahead0[1]
     if stats.tokens:
         # the SAME summary shape the serving metrics expose (/health,
         # bench.py rows): p50/p95/p99 over the per-token wall times plus
@@ -430,6 +539,8 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                   f"p95 {lat['p95']:.2f}  p99 {lat['p99']:.2f} | "
                   f"ICI S {comm.sent_bytes / 1024:.0f} kB "
                   f"R {comm.recv_bytes / 1024:.0f} kB /token")
+            print(f"Steps run ahead:     {stats.ahead_used} used, "
+                  f"{stats.ahead_dropped} dropped")
             if stats.moe_active:
                 print(f"Routed experts:      "
                       f"{stats.moe_pairs / stats.moe_active:.2f} rows per "
@@ -439,7 +550,9 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                   avg_ms=round(stats.total_ms / stats.tokens, 3),
                   latency_ms={k: round(v, 3) for k, v in lat.items()},
                   sent_bytes_per_token=comm.sent_bytes,
-                  recv_bytes_per_token=comm.recv_bytes)
+                  recv_bytes_per_token=comm.recv_bytes,
+                  ahead_used=stats.ahead_used,
+                  ahead_dropped=stats.ahead_dropped)
     return out_tokens, stats
 
 

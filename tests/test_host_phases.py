@@ -29,8 +29,7 @@ SPEC = TransformerSpec(dim=64, hidden_dim=160, n_layers=2, n_heads=4,
 DRIVER_SPANS = {"inference.step", "inference.prefill", "inference.sample",
                 "serve.step", "bench.window"}
 INFERENCE_PHASES = {"inference.dispatch", "inference.fetch",
-                    "inference.prefill_chunk", "inference.sampler",
-                    "inference.emit"}
+                    "inference.prefill_chunk", "inference.emit"}
 # the phases one paged step_once iteration with an admission prefill runs
 SERVE_PHASES = {"serve.intake", "serve.admit", "serve.admit.gather",
                 "serve.admit.prefill_chunk", "serve.admit.scatter",
@@ -202,14 +201,40 @@ def test_inference_dispatch_and_fetch_tile_infer(inference_capture):
                if n.startswith("inference."))
 
 
-def test_inference_sampler_only_after_the_prompt(inference_capture):
+def test_inference_one_pair_a_token_and_no_host_sampler(inference_capture):
+    """At temperature 0 the device picks the token (PR 27): ``infer`` is
+    still one dispatch and one fetch a token, the step enqueued ahead under
+    the dispatch, and the host's sampler phase is not there."""
     spans = inference_capture
-    n = {k: sum(s[0] == k for s in spans) for k in INFERENCE_PHASES}
+    n = {k: sum(s[0] == k for s in spans)
+         for k in INFERENCE_PHASES | {"inference.sampler"}}
     # 12 prompt positions prefilled in one chunk, 6 sampled of 18 positions
     assert n["inference.prefill_chunk"] == 1
     assert n["inference.dispatch"] == n["inference.fetch"] == 6
-    assert n["inference.sampler"] == 6
+    assert n["inference.sampler"] == 0
     assert n["inference.emit"] == 6
+
+
+def test_inference_sampler_phase_with_a_temperature(params, tmp_path):
+    """With a temperature the host samples as before: one
+    ``inference.sampler`` a sampled token, after the prompt only."""
+    from distributed_llama_tpu.runtime.generate import Engine, generate
+    from distributed_llama_tpu.runtime.sampling import Sampler
+
+    eng = Engine(SPEC, params)
+
+    def run():
+        generate(eng, _IdTokenizer(),
+                 Sampler(SPEC.vocab_size, 0.8, 0.0, seed=3), "abcdefghijkl",
+                 18, emit=lambda piece: None, quiet=True, prefill_chunk=12)
+
+    spans = _capture(tmp_path, run)
+    n = {k: sum(s[0] == k for s in spans)
+         for k in INFERENCE_PHASES | {"inference.sampler"}}
+    sampled = n["inference.sampler"]        # 6, or fewer on a sampled BOS
+    assert 1 <= sampled <= 6
+    assert n["inference.dispatch"] == n["inference.fetch"] == sampled
+    assert n["inference.emit"] == sampled
 
 
 def test_inference_program_names(params):

@@ -242,15 +242,15 @@ def test_fused_prefill_loop_matches_per_chunk_dispatch():
 
     eng_b = Engine(spec, params)  # reference: windows dispatched one by one
     for lo in range(0, 36, 12):
-        _, eng_b.cache = eng_b._fwd(eng_b.params, eng_b.cache,
-                                    jnp.asarray(tokens[lo:lo + 12],
-                                                jnp.int32), jnp.int32(lo))
+        _, eng_b.cache = eng_b._fwd_prefill(
+            eng_b.params, eng_b.cache,
+            jnp.asarray(tokens[lo:lo + 12], jnp.int32), jnp.int32(lo))
     run_chunked_prefill(
         lambda part, start: setattr(
             eng_b, "cache",
-            eng_b._fwd(eng_b.params, eng_b.cache,
-                       jnp.asarray(part, jnp.int32),
-                       jnp.int32(start))[1]),
+            eng_b._fwd_prefill(eng_b.params, eng_b.cache,
+                               jnp.asarray(part, jnp.int32),
+                               jnp.int32(start))[1]),
         tokens[36:], 36, 12, spec.seq_len)
     lb = eng_b.infer(7, len(tokens))
 
