@@ -64,7 +64,8 @@ _PROC_T0 = time.perf_counter()  # warm-start accounting anchor
 _STARTUP: dict = {}
 
 
-def _tree_shapes_cached(spec, rank_tp: int, build, build_sig: str = ""):
+def _tree_shapes_cached(spec, rank_tp: int, build, build_sig: str = "",
+                        layout_label: str = ""):
     """Shape manifest for the packed host tree (synthetic benches only).
 
     The host-side prep for a synthetic bench — RNG synth + kernel re-tiling
@@ -93,15 +94,13 @@ def _tree_shapes_cached(spec, rank_tp: int, build, build_sig: str = ""):
     # layout, the matvec row cap feeds the layout picks, and builder
     # kwargs (e.g. the 70b rank tree's embed_dtype) change leaf
     # shapes/dtypes
-    from distributed_llama_tpu.ops.pallas_q40 import q40_i4_enabled
     from distributed_llama_tpu.parallel.comm_stats import tp_scheme
 
     # tp scheme is in the key: the fused scheme's rank trees slice wo/w2
     # along the INPUT dim, so a warm ref-scheme manifest has wrong shapes
     key = hashlib.sha256(
         f"v4|{spec!r}|{rank_tp}|{q40_kernel_mode()}|{fusion_cache_key()}"
-        f"|{_matvec_cap()}|i4={q40_i4_enabled()}"
-        f"|nbm={os.environ.get('DLLAMA_NB_MAJOR', '')}"
+        f"|{_matvec_cap()}|layout={layout_label}"
         f"|tpscheme={tp_scheme()}|{build_sig}"
         .encode()).hexdigest()[:16]
     path = os.path.join(default_cache_dir(), "shapes", f"tree_{key}.pkl")
@@ -205,8 +204,18 @@ def _bench(spec, params, samples: int, per_step: bool = False,
     # ONE pack+fuse recipe for both branches (kernel layout + wqkv/w13
     # fusion; band shapes are rank-local already on the rank_tp path, where
     # per-rank fusion is valid by construction — shard_sim)
-    from distributed_llama_tpu.ops.linear import (fuse_q40_layer_matmuls,
-                                                  pack_q40_params)
+    from distributed_llama_tpu.ops.linear import (Q40_STOCK,
+                                                  announce_q40_layout,
+                                                  fuse_q40_layer_matmuls,
+                                                  pack_q40_params,
+                                                  q40_body_policy)
+
+    # the whole-model row takes the layout an engine would resolve for it
+    # (7B: forced nb-major + the int4 chain body, 9.645 vs 9.98-10.37
+    # ms/token, BASELINE.md r5); a rank row is one band of a sharded model:
+    # the stock per-leaf picks, u8 bodies
+    layout = Q40_STOCK if rank_tp else q40_body_policy(spec, rows=1)
+    announce_q40_layout(layout)
 
     def prep():
         t0 = time.perf_counter()
@@ -224,8 +233,9 @@ def _bench(spec, params, samples: int, per_step: bool = False,
         # 1.19x) stays d-major. The fused scheme's wo/w2 bands slice the
         # INPUT dim (nb/S), which can move their pad ratio — the layout
         # the program actually ran is recorded in the row JSON either way
-        hp = fuse_q40_layer_matmuls(pack_q40_params(p, allow_nb_major=True))
-        # DLLAMA_Q40_I4=on needs NO host prep: the chain converts u8
+        hp = fuse_q40_layer_matmuls(pack_q40_params(
+            p, allow_nb_major=True, layout=layout))
+        # the int4 chain body needs NO host prep: the chain converts u8
         # nb-major leaves to int4 planes in-program (chain_weight_prep) —
         # the astype-produced s4 arrays get XLA-native layouts, which the
         # packed-u8-carrier + bitcast route does NOT (measured 4.7x rank
@@ -252,7 +262,8 @@ def _bench(spec, params, samples: int, per_step: bool = False,
                          f"|{sorted(getattr(params, 'keywords', {}).items())!r}")
         else:
             build_sig = ""
-        host_params = _tree_shapes_cached(spec, rank_tp, prep, build_sig)
+        host_params = _tree_shapes_cached(spec, rank_tp, prep, build_sig,
+                                          layout.label)
         t_gen = time.perf_counter()
         host_params = device_params_like(host_params)
         jax.block_until_ready(host_params)
@@ -264,18 +275,16 @@ def _bench(spec, params, samples: int, per_step: bool = False,
     # r4: rank rows pack with allow_nb_major=True — legal for the plain-jit
     # rank program, but the shard_map sharding specs reject nb-major, so a
     # deployed tp program would run d-major; the caveat must ride the JSON)
-    from distributed_llama_tpu.io.loader import (Q40KernelI4PackedD,
-                                                 Q40KernelI4PackedNb,
-                                                 Q40KernelNb)
+    from distributed_llama_tpu.io.loader import Q40KernelNb
 
-    _nbish = (Q40KernelNb, Q40KernelI4PackedNb)
-    _i4p = (Q40KernelI4PackedD, Q40KernelI4PackedNb)
     leaves = jax.tree_util.tree_leaves(
-        host_params, is_leaf=lambda x: isinstance(x, _nbish + _i4p))
-    has_nb = any(isinstance(x, _nbish) for x in leaves)
-    _STARTUP["q40_layout"] = (
-        ("i4-packed " if any(isinstance(x, _i4p) for x in leaves) else "")
-        + ("nb-major+d-major mix" if has_nb else "d-major"))
+        host_params, is_leaf=lambda x: isinstance(x, Q40KernelNb))
+    has_nb = any(isinstance(x, Q40KernelNb) for x in leaves)
+    _STARTUP["q40_layout"] = ("nb-major+d-major mix" if has_nb
+                              else "d-major")
+    # int4-plane chain conversion active? (nb-major leaves only — the
+    # layout label above reports the HOST tree, which stays u8)
+    _STARTUP["q40_i4"] = "on" if layout.i4_chain else "off"
     if rank_tp and has_nb:
         _STARTUP["rank_layout_caveat"] = (
             "rank measured with nb-major leaves (unsharded-plain-jit-only "
@@ -335,7 +344,8 @@ def _bench(spec, params, samples: int, per_step: bool = False,
     # the XLA compile AND the first-execution kernel-compile round-trips
     compile_and_place = make_decode_loop_aot(
         step, spec.seq_len, temperature=0.0, topp=0.9,
-        exe_cache_dir=os.path.join(default_cache_dir(), "aot"))
+        exe_cache_dir=os.path.join(default_cache_dir(), "aot"),
+        i4=layout.i4_chain)
     padded = np.full((spec.seq_len + 1,), -1, dtype=np.int32)
     padded[0] = 7
     if forced:  # fixed token stream: junk-argmax BOS can't truncate the chain
@@ -630,30 +640,6 @@ def _compact_summary(configs, rows, curve) -> dict:
     return out
 
 
-def _row_env(cfg: str, env: dict) -> dict:
-    """Per-row kernel-policy env for the --config all subprocesses —
-    every default here is a SAME-SESSION A/B winner (BASELINE.md r5);
-    explicit user env always wins.
-
-    * 13b-tp2/tp4: int4-plane body on the nb-major rank bands (tp2
-      10.68 vs 11.41, tp4 8.09 vs 8.46 — but tp8 7.41 vs 6.76: the
-      per-chain conversion tax beats the kernel gain at tp8 band sizes;
-      13B single-chip OOMs the transient copy).
-    * 7b: forced nb-major + int4 (9.645 vs 9.98-10.37; the i4 body is
-      nb-major-only, so the pad-free 7B shapes need the forced layout).
-      The 7b tp rows keep d-major: force+i4 measured a wash at tp4
-      (4.96 vs 5.00) and losses at tp2/tp8/70b-tp8 (6.74 vs 6.59,
-      4.66 vs 4.60, 19.67 vs 18.62).
-    """
-    if cfg in ("13b-tp2", "13b-tp4") and "DLLAMA_Q40_I4" not in env:
-        env["DLLAMA_Q40_I4"] = "on"
-    if cfg == "7b" and "DLLAMA_Q40_I4" not in env \
-            and "DLLAMA_NB_MAJOR" not in env:
-        env["DLLAMA_Q40_I4"] = "on"
-        env["DLLAMA_NB_MAJOR"] = "force"
-    return env
-
-
 def _run_all(args) -> int:
     """Default driver protocol (VERDICT r2 #1 + r3 #2): run the 7b, 13b,
     70b-tp8 configs plus the six {7b,13b}-tp{2,4,8} scaling rows — each in
@@ -683,7 +669,7 @@ def _run_all(args) -> int:
         cmd = [sys.executable, os.path.abspath(__file__),
                "--config", cfg, "--samples", str(args.samples)]
         print(f"=== bench --config {cfg} ===", file=sys.stderr)
-        env = _row_env(cfg, dict(os.environ))
+        env = dict(os.environ)
         prof = None
         if env.get("DLLAMA_BENCH_NO_PROFILE") != "1" \
                 and "DLLAMA_BENCH_PROFILE" not in env:
@@ -946,9 +932,6 @@ def main():
         # recorded here so the comparison basis is explicit)
         "kv_cache": ("bf16" if os.environ.get("DLLAMA_BENCH_KV_BF16")
                      else "f32"),
-        # int4-plane chain conversion active? (nb-major leaves only —
-        # the layout label above reports the HOST tree, which stays u8)
-        "q40_i4": os.environ.get("DLLAMA_Q40_I4", "off"),
         "device": dev,
         "cache_errors": cache_error_count(),
         **_STARTUP,

@@ -45,6 +45,38 @@ _MODEL_HELP = ("path to the reference-format .bin model. Single-chip Q40 "
                "mmap it instead of re-tiling for minutes; set "
                "DLLAMA_TILED_CACHE=0 to disable the sidecar read AND write")
 
+# --weights-float-type help of the modes that pack Q40 for one chip: how the
+# layout is picked, and where the pick is recorded
+_WFT_HELP = ("weight float type of the .bin. q40 on one chip: each weight's "
+             "kernel layout (nb-major, d-major) and the decode chain's body "
+             "(int4 planes or u8) are picked from the model's shapes, its "
+             "packed size and the width of a decode dispatch (--slots, else "
+             "the batch, else 1), with no knob; the pick is the "
+             "'Q40 body policy' line on stderr. With --tp > 1 each shard "
+             "is judged on its local shape ('Q40 sharded layout' line)")
+
+
+def _load_one_chip(model: str, wft, bft, rows: int):
+    """The single-chip load: sidecar-cached and pre-tiled (VERDICT r4 #7: a
+    warm <model>.kcache makes host prep an mmap, like the reference's
+    loader, transformer.cpp:280-296). The Q40 layout (bench-winning
+    i4-plane + nb-major where the device, the shapes and the dispatch width
+    ``rows`` support it) is resolved HERE, once, from the file's header and
+    announced on stderr; the same value keys the sidecar, packs the tree
+    and is returned for the engine. -> (spec, params, layout or None)."""
+    from ..io.kernel_cache import load_model_packed
+    from ..io.loader import read_spec
+    from ..ops.linear import announce_q40_layout, q40_body_policy
+
+    layout = None  # other float types: the engine's own, and moot
+    if wft == FloatType.Q40:
+        layout = q40_body_policy(read_spec(model, weights_float_type=wft),
+                                 rows)
+        announce_q40_layout(layout)
+    spec, params = load_model_packed(model, weights_float_type=wft,
+                                     buffer_float_type=bft, layout=layout)
+    return spec, params, layout
+
 
 def _obs_flags(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--log-json", action="store_true",
@@ -233,7 +265,8 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
     ap.add_argument("--model", required=True, help=_MODEL_HELP)
     ap.add_argument("--tokenizer", required=True)
     ap.add_argument("--prompt", default=None)
-    ap.add_argument("--weights-float-type", default="q40", choices=sorted(_FT))
+    ap.add_argument("--weights-float-type", default="q40", choices=sorted(_FT),
+                    help=_WFT_HELP)
     ap.add_argument("--buffer-float-type", default="f32", choices=sorted(_FT))
     ap.add_argument("--steps", type=int, default=64)
     ap.add_argument("--temperature", type=float, default=0.8)
@@ -464,6 +497,7 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
     else:
         tp = args.tp or max(1, n_dev // args.sp)
     t0 = time.perf_counter()
+    q40_layout = None  # mesh runs: the engine's own (the stock value)
     if tp > 1 or args.sp > 1:
         # mesh runs keep the codec tree: tp-aware packing happens in
         # parallel/tp.shard_params, which picks each leaf's layout on its
@@ -471,26 +505,12 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         spec, params = load_model(args.model, weights_float_type=wft,
                                   buffer_float_type=bft)
     else:
-        # single-chip: sidecar-cached pre-tiled load (VERDICT r4 #7) —
-        # a warm <model>.kcache makes host prep an mmap, like the
-        # reference's loader (transformer.cpp:280-296). The Q40 body
-        # policy (bench-winning i4-plane + nb-major layout where the
-        # device/shape supports it) must land BEFORE the load: the
-        # sidecar's layout key reads the env knobs it sets
-        from ..io.kernel_cache import load_model_packed
-        from ..io.loader import read_spec
-        from ..ops.linear import apply_q40_body_policy
-
-        if wft == FloatType.Q40:
-            # rows per decode dispatch: the slot pool's width, the
-            # lockstep batch's, or one
-            rows = (1 if prompts is None else
-                    (args.slots or min(len(prompts), 8)) if args.continuous
-                    else len(prompts))
-            apply_q40_body_policy(read_spec(args.model,
-                                            weights_float_type=wft), rows)
-        spec, params = load_model_packed(args.model, weights_float_type=wft,
-                                         buffer_float_type=bft)
+        # rows per decode dispatch: the slot pool's width, the lockstep
+        # batch's, or one
+        rows = (1 if prompts is None else
+                (args.slots or min(len(prompts), 8)) if args.continuous
+                else len(prompts))
+        spec, params, q40_layout = _load_one_chip(args.model, wft, bft, rows)
     if not quiet:
         print(f"💡 dim: {spec.dim}\n💡 hiddenDim: {spec.hidden_dim}\n"
               f"💡 nLayers: {spec.n_layers}\n💡 nHeads: {spec.n_heads}\n"
@@ -559,7 +579,7 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
                                 kv_disk_dir=args.kv_disk_dir,
                                 kv_disk_bytes=int(args.kv_disk_gb
                                                   * (1 << 30)),
-                                metrics=reg)
+                                metrics=reg, q40_layout=q40_layout)
             if reg is not None:
                 print(reg.expose(), file=sys.stderr, end="")
             return 0
@@ -588,10 +608,11 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
                   "(and quantized) KV pool", file=sys.stderr)
         generate_batch(spec, params, tokenizer, prompts, args.steps,
                        args.temperature, args.topp, seed,
-                       cache_dtype=cache_dtype, mesh=mesh, quiet=quiet)
+                       cache_dtype=cache_dtype, mesh=mesh, quiet=quiet,
+                       q40_layout=q40_layout)
         return 0
     engine = Engine(spec, params, mesh=mesh, cache_dtype=cache_dtype,
-                    fast_prefill=args.fast_prefill)
+                    fast_prefill=args.fast_prefill, q40_layout=q40_layout)
     if not quiet:
         print(f"⏩ Loaded model in {time.perf_counter() - t0:.1f}s")
         _print_device_memory("loaded")
@@ -716,7 +737,8 @@ def cmd_serve(argv: list[str]) -> int:
     ap = argparse.ArgumentParser(prog="dllama-tpu serve")
     ap.add_argument("--model", required=True, help=_MODEL_HELP)
     ap.add_argument("--tokenizer", required=True)
-    ap.add_argument("--weights-float-type", default="q40", choices=sorted(_FT))
+    ap.add_argument("--weights-float-type", default="q40", choices=sorted(_FT),
+                    help=_WFT_HELP)
     ap.add_argument("--buffer-float-type", default="f32", choices=sorted(_FT))
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=9990)
@@ -983,8 +1005,7 @@ def cmd_serve(argv: list[str]) -> int:
 
     import jax.numpy as jnp
 
-    from ..io.kernel_cache import load_model_packed
-    from ..io.loader import load_model, read_spec
+    from ..io.loader import load_model
     from ..io.tokenizer import Tokenizer
     from ..parallel import make_mesh
     from ..parallel.comm_stats import tp_scheme
@@ -994,19 +1015,15 @@ def cmd_serve(argv: list[str]) -> int:
         os.environ["DLLAMA_TP_SCHEME"] = args.tp_scheme
     tp_scheme()  # validate before the model load
     sharded = bool(args.tp and args.tp > 1)
-    load = (load_model if sharded  # mesh: tp-aware packing in shard_params
-            else load_model_packed)  # single-chip: sidecar
-    if not sharded and _FT[args.weights_float_type] == FloatType.Q40:
-        # same bench-winning layout policy as single-chip inference; must
-        # precede the load (sidecar layout key reads the env knobs)
-        from ..ops.linear import apply_q40_body_policy
-
-        apply_q40_body_policy(read_spec(
-            args.model, weights_float_type=_FT[args.weights_float_type]),
-            rows=args.slots)
-    spec, params = load(args.model,
-                        weights_float_type=_FT[args.weights_float_type],
-                        buffer_float_type=_FT[args.buffer_float_type])
+    wft = _FT[args.weights_float_type]
+    bft = _FT[args.buffer_float_type]
+    if sharded:  # mesh: tp-aware packing in shard_params
+        spec, params = load_model(args.model, weights_float_type=wft,
+                                  buffer_float_type=bft)
+        q40_layout = None  # the engine's own (the stock value)
+    else:
+        spec, params, q40_layout = _load_one_chip(args.model, wft, bft,
+                                                  rows=args.slots)
     tokenizer = Tokenizer(args.tokenizer, spec.vocab_size)
     if spec.n_experts and sharded:
         from ..ops.linear import MOE_TP_REFUSAL
@@ -1066,7 +1083,8 @@ def cmd_serve(argv: list[str]) -> int:
                                  page_channel_port=args.page_channel_port,
                                  handoff_min_pages=args.handoff_min_pages,
                                  flightrec_dir=args.flightrec,
-                                 watch_interval_s=args.watch_interval)
+                                 watch_interval_s=args.watch_interval,
+                                 q40_layout=q40_layout)
     except Exception as e:
         from ..runtime.journal import JournalConfigMismatch
 

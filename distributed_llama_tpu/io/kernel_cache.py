@@ -32,8 +32,7 @@ import time
 import numpy as np
 
 from ..utils.compile_cache import cache_error
-from .loader import (Q40Kernel, Q40KernelI4PackedD, Q40KernelI4PackedNb,
-                     Q40KernelNb, Q40Weight)
+from .loader import Q40Kernel, Q40KernelNb, Q40Weight
 
 MAGIC = b"DLKC0001"
 _ALIGN = 4096
@@ -43,8 +42,6 @@ _KINDS = {
     "q40w": (Q40Weight, 2),
     "q40k": (Q40Kernel, 2),
     "q40knb": (Q40KernelNb, 2),
-    "q40i4pd": (Q40KernelI4PackedD, 2),
-    "q40i4pnb": (Q40KernelI4PackedNb, 2),
 }
 
 
@@ -55,17 +52,15 @@ def _kind_of(v) -> str:
         return "q40k"
     if isinstance(v, Q40KernelNb):
         return "q40knb"
-    if isinstance(v, Q40KernelI4PackedD):
-        return "q40i4pd"
-    if isinstance(v, Q40KernelI4PackedNb):
-        return "q40i4pnb"
     return "dense"
 
 
 def layout_key(model_path: str | None = None, tp: int = 1,
-               weights_float_type=None, buffer_float_type=None) -> str:
-    """Everything that decides the packed tree's contents: the layout
-    knobs (mirroring the bench shape-manifest key), the float types the
+               weights_float_type=None, buffer_float_type=None,
+               layout=None) -> str:
+    """Everything that decides the packed tree's contents: the kernel
+    knobs and the model's resolved ``layout`` (an ops/linear.Q40Layout;
+    None = the stock picks), the float types the
     tree was decoded/packed under (a future packed form for another float
     type must not collide under the same key), AND the model file's
     identity (size + mtime) — overwriting the .bin with a new checkpoint
@@ -75,14 +70,14 @@ def layout_key(model_path: str | None = None, tp: int = 1,
     from ..ops.pallas_layer import fusion_cache_key
     from ..ops.pallas_q40 import _matvec_cap
 
-    # DLLAMA_Q40_I4 is deliberately NOT in this key: the sidecar stores
-    # the host u8 tree either way (i4 conversion is in-chain), and keying
-    # on it would rebuild the GB-scale sidecar on every flag flip
+    # the layout's i4 chain body is deliberately NOT in this key: the
+    # sidecar stores the host u8 tree either way (the conversion is
+    # in-chain)
     src = ""
     if model_path is not None:
         st = os.stat(model_path)
         src += f"|src={st.st_size}:{st.st_mtime_ns}"
-    nbm = os.environ.get("DLLAMA_NB_MAJOR", "auto") or "auto"
+    nbm = "force" if layout is not None and layout.force_nb_major else "auto"
     wf = getattr(weights_float_type, "name", weights_float_type) or "Q40"
     bf = getattr(buffer_float_type, "name", buffer_float_type) or "F32"
     return (f"v1|{q40_kernel_mode()}|{_matvec_cap()}|{fusion_cache_key()}"
@@ -247,15 +242,18 @@ def cache_enabled() -> bool:
 
 
 def load_model_packed(path: str, spec=None, weights_float_type=None,
-                      buffer_float_type=None):
+                      buffer_float_type=None, layout=None):
     """load_model + pack_q40_params + fuse_q40_layer_matmuls, with the
     sidecar shortcut: a valid `<model>.kcache` skips BOTH the .bin walk
     and the GB-scale re-tiling/fusion (the tree's leaves are memmap views
-    into the sidecar). Single-chip decode path only — mesh runs decide
-    each leaf's layout on its shard-local shape, so they keep load_model +
-    tp-aware packing (parallel/tp.shard_params)."""
-    from ..ops.linear import (fuse_q40_layer_matmuls, pack_q40_params,
-                              q40_kernel_mode)
+    into the sidecar). ``layout`` is the model's resolved Q40Layout
+    (ops/linear.q40_body_policy; None = the stock picks): it keys the
+    sidecar AND packs the tree, so the two cannot disagree. Single-chip
+    decode path only — mesh runs decide each leaf's layout on its
+    shard-local shape, so they keep load_model + tp-aware packing
+    (parallel/tp.shard_params)."""
+    from ..ops.linear import (Q40_STOCK, fuse_q40_layer_matmuls,
+                              pack_q40_params, q40_kernel_mode)
     from ..ops.quants import FloatType
     from .loader import load_model, read_spec
 
@@ -265,8 +263,9 @@ def load_model_packed(path: str, spec=None, weights_float_type=None,
     packing = wft == FloatType.Q40 and q40_kernel_mode() == "pallas"
     use_cache = cache_enabled() and packing
     side = sidecar_path(path)
+    layout = layout or Q40_STOCK
     key = layout_key(path, weights_float_type=wft,
-                     buffer_float_type=buffer_float_type)
+                     buffer_float_type=buffer_float_type, layout=layout)
     if use_cache and os.path.exists(side):
         t0 = time.perf_counter()
         if spec is None:
@@ -280,7 +279,7 @@ def load_model_packed(path: str, spec=None, weights_float_type=None,
     spec, params = load_model(path, spec=spec, weights_float_type=wft, **kw)
     t0 = time.perf_counter()
     packed = fuse_q40_layer_matmuls(
-        pack_q40_params(params, allow_nb_major=True))
+        pack_q40_params(params, allow_nb_major=True, layout=layout))
     dt = time.perf_counter() - t0
     if packing:
         print(f"kernel re-tile + fuse: {dt:.1f}s", file=sys.stderr)

@@ -126,40 +126,6 @@ class Q40KernelNbI4(NamedTuple):
                 self.scale.shape[-2] * 32)
 
 
-class Q40KernelI4PackedD(NamedTuple):
-    """RESIDENT uint8 carrier of the d-major int4 planes: qs_p uint8
-    (..., 32, d, nb/2) packs (code - 8) signed nibbles pairwise along the
-    minor dim, LOW nibble = even index — exactly XLA's S4 bit layout, so
-    the decode chain turns this into ``Q40KernelI4`` with ONE
-    bitcast_convert_type + minor reshape (a reinterpretation, not a
-    GB-scale compute pass, and no u8+i4 double residency — the fix for
-    the 13B OOM the in-chain conversion hit). uint8 because int4 arrays
-    cannot cross this runtime's jit/dispatch boundary. TESTS/EXPERIMENTS
-    ONLY: production repack_i4_packed emits only the Nb variant (the
-    d-major s4 body and the bitcast-materialized layout both measured as
-    hardware negatives — BASELINE.md r5)."""
-
-    qs_p: np.ndarray
-    scale: np.ndarray
-
-    @property
-    def logical_shape(self) -> tuple[int, ...]:
-        return (*self.scale.shape[:-1], self.scale.shape[-1] * 32)
-
-
-class Q40KernelI4PackedNb(NamedTuple):
-    """nb-major sibling of Q40KernelI4PackedD: qs_p uint8
-    (..., 32, nb, d/2), scale f32 (..., nb, d)."""
-
-    qs_p: np.ndarray
-    scale: np.ndarray
-
-    @property
-    def logical_shape(self) -> tuple[int, ...]:
-        return (*self.scale.shape[:-2], self.scale.shape[-1],
-                self.scale.shape[-2] * 32)
-
-
 def to_kernel_layout_nb(w: Q40Weight) -> Q40KernelNb:
     """(..., d, nb, 16) -> (..., 16, nb, d) with f32 scales (..., nb, d).
     numpy inputs take the threaded native tiler, as ``to_kernel_layout``

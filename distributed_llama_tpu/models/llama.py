@@ -31,8 +31,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelI4PackedD,
-                         Q40KernelI4PackedNb, Q40KernelNb, Q40KernelNbI4)
+from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelNb,
+                         Q40KernelNbI4)
 # the single-chip forward emits the SAME canonical trace scopes as the tp
 # forward (parallel/tp.py), so a --profile capture of either program
 # attributes through one obs/xprof.py vocabulary
@@ -351,9 +351,7 @@ def split_layer_weights(params: dict[str, Any]):
     keys = [k for k in LAYER_KEYS + FUSED_KEYS if k in params]
     stacked = {k: params[k] for k in keys
                if isinstance(params[k], (Q40Kernel, Q40KernelNb,
-                                         Q40KernelI4, Q40KernelNbI4,
-                                         Q40KernelI4PackedD,
-                                         Q40KernelI4PackedNb))}
+                                         Q40KernelI4, Q40KernelNbI4))}
     scanned = {k: params[k] for k in keys if k not in stacked}
     return stacked, scanned
 
@@ -1388,20 +1386,22 @@ def decode_step(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
 
 
 def params_to_device(params: dict[str, Any], dtype=None,
-                     spec: TransformerSpec | None = None) -> dict[str, Any]:
+                     spec: TransformerSpec | None = None,
+                     layout=None) -> dict[str, Any]:
     """Move a numpy param tree onto the default device as jax arrays.
 
     Q40 weights are re-tiled to the Pallas kernel layout here (once, host
-    side) when the Q40 fast path is active — see ops/linear.pack_q40_params.
-    With ``spec`` given, the megakernel's permuted-wo stack is prepared too
+    side) when the Q40 fast path is active — see ops/linear.pack_q40_params;
+    ``layout`` is the engine's resolved Q40Layout. With ``spec`` given, the
+    megakernel's permuted-wo stack is prepared too
     (ops/pallas_layer.prepare_mega_params) so T=1 decode can run one fused
     op per layer.
     """
     from ..io.loader import Q40Kernel, Q40Weight
     from ..ops.linear import fuse_q40_layer_matmuls, pack_q40_params
 
-    params = fuse_q40_layer_matmuls(pack_q40_params(params,
-                                                    allow_nb_major=True))
+    params = fuse_q40_layer_matmuls(pack_q40_params(
+        params, allow_nb_major=True, layout=layout))
     if spec is not None:
         from ..ops.pallas_layer import prepare_mega_params
 

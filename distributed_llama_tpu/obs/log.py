@@ -11,8 +11,8 @@ runtime/server.py, runtime/generate.py, and io/stream.py call
 nothing when text is None — a JSON-only event).
 
 Every NDJSON record additionally carries the run-config header
-(utils/fingerprint.run_stamp): ``tp_scheme``, the ``DLLAMA_Q40_BODY``
-policy, and the same ``env_fingerprint`` bench.py records per row — so a
+(utils/fingerprint.run_stamp): ``tp_scheme``, the resolved Q40 body
+policy's label, and the same ``env_fingerprint`` bench.py records per row — so a
 log stream is JOINABLE with BENCH_* rows and profiler captures by
 session basis. Explicit fields win over the stamp on key collision.
 """

@@ -8,8 +8,7 @@ ALLOWED to do that materialization:
 * ``ops/linear.dequantize_weight`` — the XLA dequantize-then-dot fallback
   (and the parity/test path on CPU). On the Pallas serving path the same
   values are produced in-kernel from VMEM tiles and never hit HBM.
-* ``ops/pallas_q40`` internals — in-kernel/per-tile dequant helpers and the
-  i4-carrier unpackers (layout reinterpretations of resident packed bytes).
+* ``ops/pallas_q40`` internals — in-kernel/per-tile dequant helpers.
 * ``parallel/tp._wire_gather`` / ``_wire`` and ``ops/linear.fake_quant_q80``
   — the Q80 *buffer* codec on activation vectors (dim-sized, not
   weight-sized; listed so the int8->f32 detector does not misread the wire
@@ -32,7 +31,6 @@ from __future__ import annotations
 ALLOWED_DEQUANT_SITES: tuple[tuple[str, str], ...] = (
     ("ops/linear.py", "dequantize_weight"),
     ("ops/linear.py", "fake_quant_q80"),
-    ("ops/pallas_q40.py", "unpack_i4_packed"),
     ("ops/pallas_q40.py", "_dequant_i4"),
     ("ops/pallas_q40.py", "_dequant_nb"),
     ("parallel/tp.py", "_wire_gather"),

@@ -27,8 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelI4PackedD,
-                         Q40KernelI4PackedNb, Q40KernelNb, Q40KernelNbI4,
+from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelNb, Q40KernelNbI4,
                          Q40Weight, from_kernel_layout, to_kernel_layout,
                          to_kernel_layout_nb)
 from .quants import dequantize_q40_jax, dequantize_q80_jax, quantize_q80_jax
@@ -104,10 +103,6 @@ def dequantize_weight(w) -> jax.Array:
     """Materialize any weight representation as f32 (d, n)."""
     if isinstance(w, StackedQ40):
         w = jax.tree_util.tree_map(lambda a: a[w.layer], w.w)
-    if isinstance(w, (Q40KernelI4PackedD, Q40KernelI4PackedNb)):
-        from .pallas_q40 import unpack_i4_packed
-
-        w = unpack_i4_packed(w)
     if isinstance(w, (Q40KernelI4, Q40KernelNbI4)):
         from .pallas_q40 import _dequant_i4
 
@@ -148,8 +143,7 @@ def matmul(w, x: jax.Array, *, prefer_pallas: bool = False) -> jax.Array:
         from .pallas_q40 import q40_matmul  # packing implies kernel support
 
         return q40_matmul(w.w, x, layer=w.layer)
-    if isinstance(w, (Q40KernelNb, Q40KernelI4, Q40KernelNbI4,
-                      Q40KernelI4PackedD, Q40KernelI4PackedNb)):
+    if isinstance(w, (Q40KernelNb, Q40KernelI4, Q40KernelNbI4)):
         from .pallas_q40 import q40_matmul  # dedicated dispatches
 
         return q40_matmul(w, x)
@@ -177,51 +171,131 @@ def matmul(w, x: jax.Array, *, prefer_pallas: bool = False) -> jax.Array:
                       precision=jax.lax.Precision.HIGHEST)
 
 
+class Q40Layout(NamedTuple):
+    """How one engine's Q40 leaves are laid out and which decode body its
+    fused chain runs: the ONE value ``q40_body_policy`` resolves for a
+    model and a dispatch width. Whoever builds an engine resolves it once
+    and hands it down as an argument (loader, sidecar key, packer, chain
+    builder); nothing reads it back from the process."""
+
+    label: str    # "i4-nb" | "d-major"
+    reason: str
+
+    @property
+    def force_nb_major(self) -> bool:
+        """Every leaf the nb-major row tiler places packs nb-major (the i4
+        body exists only there, so pad-free 7B-class shapes need it)."""
+        return self.label == "i4-nb"
+
+    @property
+    def i4_chain(self) -> bool:
+        """The fused decode chain converts nb-major leaves to signed-int4
+        planes at its start (ops/pallas_q40.chain_weight_prep)."""
+        return self.label == "i4-nb"
+
+
+# the stock per-leaf picks, u8 bodies: a sharded engine's value, and what a
+# packer gets that is handed nothing and finds no shim
+Q40_STOCK = Q40Layout("d-major", "stock per-leaf layout picks")
+
+MOE_TP_REFUSAL = (
+    "expert (mixture-of-experts) models run on one chip only: placing "
+    "experts across tensor-parallel ranks is not implemented, so --tp > 1 "
+    "(or any sharded mesh) refuses them")
+
+
+def nb_major_serves(rows: int) -> bool:
+    """Whether an nb-major kernel serves a decode dispatch ``rows`` wide.
+    The nb-major VPU body serves T <= 4 and the MXU body T > 8; in between
+    EVERY matmul of an nb-major leaf takes the XLA dequantize-then-dot
+    route: ``serve`` at 8 slots decoded at 75 ms/token that way against
+    37.5 d-major (my chip run, PR 21; PERF.md)."""
+    from .pallas_q40 import MULTI_T_MAX, NB_MULTI_T_MAX
+
+    return not NB_MULTI_T_MAX < rows <= MULTI_T_MAX
+
+
+def q40_leaf_layout(d: int, nb: int, *, tp: int = 1, rows: int = 1,
+                    layout: Q40Layout = Q40_STOCK,
+                    allow_nb_major: bool = True, key: str = "") -> str:
+    """THE layout rule of one Q40 leaf: ``"nb-major"``, ``"d-major"`` or
+    ``"codec"``, from its shard-LOCAL shape ``(d, nb)`` (what the kernel
+    tiles inside shard_map and what each chip stores), the tensor-parallel
+    degree, the decode dispatch width and the model's resolved ``layout``.
+
+    * A routed-expert stack (``key`` ``moe_*``): nb-major where both
+      grouped kernels (ops/pallas_moe) place it, else codec (the XLA scan);
+      ``moe_w1`` / ``moe_w3`` are fused along d afterwards, so twice their
+      width must place too.
+    * Sharded (``tp > 1``): nb-major iff the local ``nb`` is off the 128
+      grid and the dispatch width has an nb-major kernel. The chip stores an
+      array whose minor dim is not a multiple of 128 with the second-minor
+      dim minor instead: a d-major shard ``(16, d, nb)`` then lies d-minor
+      in HBM, the Pallas call wants it row-major, and XLA copies (and pads)
+      every such leaf at the top of EVERY step program, 22.9 ms of a
+      37.2 ms step at Yi-34B tp=4 (ledger, PR 24). Packed nb-major the
+      logical order IS that physical order. The one-chip padding test is
+      the wrong one here: nb 224 pads by 1.14 and is copied all the same.
+    * One chip: nb-major iff the model's layout forces it (its width was
+      judged there, once, for the whole model) or d-major's lane padding of
+      ``nb`` would inflate the packed bytes materially (13B: nb 160 -> 1.6x
+      HBM and reads). ``allow_nb_major=False`` is the caller saying that
+      its ``tp == 1`` is not one chip (an sp > 1 mesh): d-major or codec.
+
+    nb-major needs the row tiler to place ``d``; d-major needs the matvec
+    tiler; what neither places stays codec and takes the XLA fallback in
+    ``matmul`` (packed, it would pay a re-transpose inside every step)."""
+    from .pallas_q40 import _pick_rows_nb, kernel_supports
+
+    if key.startswith("moe_"):
+        from .pallas_moe import shape_places
+
+        fused_ok = key == "moe_w2" or shape_places(2 * d, nb)
+        return "nb-major" if fused_ok and shape_places(d, nb) else "codec"
+    if tp > 1:
+        wants_nb = nb % 128 != 0 and nb_major_serves(rows)
+    else:
+        pad_ratio = (nb + (-nb % 128)) / nb  # lane padding of nb-minor
+        wants_nb = allow_nb_major and (layout.force_nb_major
+                                       or pad_ratio > 1.25)
+    if wants_nb and _pick_rows_nb(d, nb) is not None:
+        return "nb-major"
+    return "d-major" if kernel_supports(d, nb * 32) else "codec"
+
+
 def pack_q40_params(params: dict, enable: bool | None = None,
-                    tp: int = 1, allow_nb_major: bool | None = None,
-                    input_sharded=(), rows: int = 1) -> dict:
+                    tp: int = 1, allow_nb_major: bool = False,
+                    input_sharded=(), rows: int = 1,
+                    layout: Q40Layout | None = None) -> dict:
     """Re-tile every Q40Weight in a param tree to the kernel layout, once.
 
     ``enable=None`` means "iff the Pallas kernel will be used" — so CPU/test
     runs keep the codec layout and the golden-parity paths are untouched.
-    ``tp`` is the tensor-parallel degree the weights will be sharded to:
-    kernel support AND the layout are decided on the shard-LOCAL shape,
-    since that is what the kernel tiles inside shard_map and what each chip
-    stores. ``input_sharded`` names the keys the fused tp scheme shards
-    along the INPUT dim (wo/w2 — parallel/tp.py): their local shape is
-    (d, n/tp) instead of (d/tp, n). ``rows`` is how many rows one decode
-    dispatch of the sharded engine carries (as q40_body_policy's): the
-    ``tp > 1`` rule reads it, ``allow_nb_major`` gates the ``tp == 1`` pick.
+    Each leaf's layout is ``q40_leaf_layout``'s answer on its shard-LOCAL
+    shape: ``tp`` is the tensor-parallel degree the weights will be sharded
+    to, ``input_sharded`` names the keys the fused tp scheme shards along
+    the INPUT dim (wo/w2 — parallel/tp.py: local shape (d, n/tp) instead of
+    (d/tp, n)), ``rows`` is how many rows one decode dispatch of the
+    sharded engine carries, ``layout`` the model's resolved value
+    (engines pass theirs; a by-hand packer that passes none gets what
+    ``apply_q40_body_policy`` last recorded, else the stock picks).
+    ``allow_nb_major`` defaults to off: tp == 1 does not imply one chip (an
+    sp > 1 mesh packs with tp=1), so the truly-single-chip callers opt in.
     Call this at load time, before device_put; never inside a jitted step.
     """
     if enable is None:
         enable = q40_kernel_mode() == "pallas"
     if not enable:
         return params
-    if allow_nb_major is None:
-        # tp==1 does not imply unsharded (an sp>1 mesh packs with tp=1), and
-        # the one-chip pick belongs to q40_body_policy — so the
-        # truly-single-chip callers must OPT IN explicitly
-        # (params_to_device, shard_sim.rank_params_to_device, bench.py)
-        allow_nb_major = False
-    from .pallas_q40 import _pick_rows_nb, kernel_supports
+    if layout is None:
+        layout = _APPLIED_LAYOUT or Q40_STOCK  # the shim's ONE reader
 
     def pick(k, v):
         if not isinstance(v, Q40Weight):
             return v
         d, n = v.logical_shape[-2], v.logical_shape[-1]
-        if k.startswith("moe_"):
-            # routed-expert stacks (L, E, d, n): the grouped kernels
-            # (ops/pallas_moe) read the nb-major layout only; a shape the
-            # row tiler cannot place stays codec and takes the XLA scan
-            if tp > 1:
-                raise ValueError(MOE_TP_REFUSAL)
-            from .pallas_moe import shape_places
-
-            # w1 and w3 are fused along d afterwards: both widths must place
-            fused_ok = k == "moe_w2" or shape_places(2 * d, n // 32)
-            return (to_kernel_layout_nb(v)
-                    if fused_ok and shape_places(d, n // 32) else v)
+        if k.startswith("moe_") and tp > 1:
+            raise ValueError(MOE_TP_REFUSAL)
         if k in input_sharded and tp > 1:
             # fused-scheme wo/w2: full output rows, 1/tp of the input
             # blocks per shard — the nb axis is the sharded one, so the
@@ -236,59 +310,14 @@ def pack_q40_params(params: dict, enable: bool | None = None,
             return v
         else:
             d_loc, n_loc = d // tp, n
-        nb = n // 32
-        if tp > 1:
-            nb_major = sharded_nb_major(d_loc, n_loc // 32, rows)
-        else:
-            pad_ratio = (nb + (-nb % 128)) / nb  # lane padding of nb-minor
-            # nb-major layout when the standard tiling would pad the packed
-            # bytes materially (13B: nb=160 -> 1.6x HBM and read inflation).
-            # DLLAMA_NB_MAJOR=force takes it for EVERY eligible leaf (the
-            # i4-formulation experiment arm: the int4 body exists only for
-            # nb-major, so pad-free shapes need the forced layout to reach
-            # it)
-            force_nb = os.environ.get("DLLAMA_NB_MAJOR", "") == "force"
-            nb_major = (allow_nb_major and (pad_ratio > 1.25 or force_nb)
-                        and _pick_rows_nb(d, nb) is not None)
-        if nb_major:
+        kind = q40_leaf_layout(d_loc, n_loc // 32, tp=tp, rows=rows,
+                               layout=layout, key=k,
+                               allow_nb_major=allow_nb_major)
+        if kind == "nb-major":
             return to_kernel_layout_nb(v)
-        if kernel_supports(d_loc, n_loc):
-            return to_kernel_layout(v)
-        # untileable dims stay codec-layout: they take the XLA fallback in
-        # matmul(), which would otherwise pay a full re-transpose inside
-        # the jitted step on every call
-        return v
+        return to_kernel_layout(v) if kind == "d-major" else v
 
     return {k: pick(k, v) for k, v in params.items()}
-
-
-MOE_TP_REFUSAL = (
-    "expert (mixture-of-experts) models run on one chip only: placing "
-    "experts across tensor-parallel ranks is not implemented, so --tp > 1 "
-    "(or any sharded mesh) refuses them")
-
-
-def sharded_nb_major(d_local: int, nb_local: int, rows: int = 1) -> bool:
-    """The layout rule of a SHARDED Q40 leaf, on its shard-local shape.
-
-    The chip stores an array whose minor dim is not a multiple of 128 with
-    the second-minor dim minor instead (no padding): a d-major shard
-    ``(16, d, nb)`` with ``nb % 128 != 0`` lies d-minor in HBM, the Pallas
-    call wants it row-major, and XLA copies (and pads) every such leaf at
-    the top of EVERY step program — 22.9 ms of a 37.2 ms step at Yi-34B
-    tp=4 (ledger, PR 24). Packed nb-major, the logical order IS that
-    physical order and nothing is copied. So: nb-major iff the shard-local
-    ``nb`` is off the 128 grid (on it, d-major is already row-major), the
-    shard-local ``d`` places on the nb-major row tiler, and no dispatch is
-    5..8 rows wide (no nb-major kernel serves those: every matmul would
-    take dequantize-then-dot, see q40_body_policy). The one-chip
-    ``pad_ratio`` test is the wrong one here: nb 224 pads by 1.14 and is
-    copied all the same."""
-    from .pallas_q40 import MULTI_T_MAX, NB_MULTI_T_MAX, _pick_rows_nb
-
-    return (nb_local % 128 != 0
-            and not NB_MULTI_T_MAX < rows <= MULTI_T_MAX
-            and _pick_rows_nb(d_local, nb_local) is not None)
 
 
 def fuse_q40_layer_matmuls(params: dict) -> dict:
@@ -340,106 +369,109 @@ def fuse_q40_layer_matmuls(params: dict) -> dict:
     return out
 
 
-def q40_body_policy(spec, rows: int = 1) -> tuple[str, str]:
-    """Resolve the single-chip Q40 decode-body policy: (policy, reason).
-    ``rows`` is how many rows one decode dispatch carries (1 for plain
-    ``inference``, the slot count for ``serve`` / ``--continuous``).
+# The in-chain i4 conversion transiently holds an extra ~half of the packed
+# bytes while the chain runs, which OOMed 13B on a 16 GB chip (PARITY.md
+# round-5 table): the i4 body is picked only for a model whose packed
+# weights stay under this many GB (between 7B's ~4.2 and 13B's ~7.8).
+Q40_I4_MAX_PACKED_GB = 6.0
 
-    Promotes the bench's A/B winner into the real CLI path. On the attached
-    v5e at 7B (my chip run, PR 21; PERF.md): the fused chain runs 8.15
-    ms/token with the int4-plane body on forced nb-major layout against
-    8.60 d-major and 8.92 nb-major u8, and the per-token ``inference`` step
-    11.1 ms nb-major against 14.0 d-major (d-major's w2, nb = 344, is placed
-    transposed by the device client and copied row-major inside every step).
 
-    Explicit ``DLLAMA_Q40_I4``/``DLLAMA_NB_MAJOR`` env wins over
-    everything (including DLLAMA_Q40_BODY — nothing ever unsets a user
-    knob), and the returned label then REPORTS what that env actually
-    engages rather than a policy nobody chose. Otherwise
-    ``DLLAMA_Q40_BODY`` overrides: ``auto`` (default), ``i4-nb`` (force
-    the winning combo), ``d-major`` (keep the stock layout picks). auto
-    picks ``i4-nb`` iff ALL of:
+def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
+    """Resolve the Q40 layout and decode body of a model: a pure function
+    of the spec, the width ``rows`` of one decode dispatch (1 for plain
+    ``inference``, the slot count for ``serve`` / ``--continuous``) and the
+    kernel mode. Unpacks as (label, reason). A ``sharded`` engine (a tp or
+    sp mesh) gets the stock value: ``q40_leaf_layout`` judges each of its
+    leaves on the shard-local shape, and the i4 chain body stays off.
+
+    On the attached v5e at 7B (my chip run, PR 21; PERF.md): the fused
+    chain runs 8.15 ms/token with the int4-plane body on forced nb-major
+    layout against 8.60 d-major and 8.92 nb-major u8, and the per-token
+    ``inference`` step 11.1 ms nb-major against 14.0 d-major (d-major's w2,
+    nb = 344, is placed transposed by the device client and copied
+    row-major inside every step).
+
+    ``i4-nb`` iff ALL of (else ``d-major``: the stock per-leaf picks, u8
+    bodies):
       * the Pallas kernel path is active (TPU; elsewhere layouts are moot),
-      * a decode dispatch is not 5..8 rows wide: the nb-major VPU body
-        serves T <= 4 and the MXU body T > 8, so in between EVERY matmul
-        takes the XLA dequantize-then-dot route — ``serve`` at 8 slots
-        decoded at 75 ms/token that way against 37.5 d-major (same run),
+      * an nb-major kernel serves the dispatch width (``nb_major_serves``),
       * every matmul leaf places on the nb-major row tiler (the i4 body is
         nb-major-only — pad-free 7B-class shapes need the forced layout),
-      * the packed weights leave conversion headroom: the in-chain i4
-        conversion transiently holds an extra ~half of the packed bytes
-        while the chain runs, which OOMed 13B on a 16 GB chip (PARITY.md
-        round-5 table) — gated at DLLAMA_Q40_BODY_MAX_GB packed (default
-        6.0, between 7B's ~4.2 and 13B's ~7.8).
+      * the packed weights leave the conversion its headroom
+        (``Q40_I4_MAX_PACKED_GB``),
+      * the spec has no routed experts (no expert kernel has an i4 body).
     """
-    choice = os.environ.get("DLLAMA_Q40_BODY", "auto")
-    if choice not in ("auto", "i4-nb", "d-major"):
-        raise ValueError(f"DLLAMA_Q40_BODY={choice!r}: expected "
-                         f"auto|i4-nb|d-major")
-    i4 = os.environ.get("DLLAMA_Q40_I4")
-    nbm = os.environ.get("DLLAMA_NB_MAJOR")
-    if i4 or nbm:
-        label = ("i4-nb" if i4 == "on" and nbm == "force"
-                 else f"env(i4={i4 or 'off'}, nb-major={nbm or 'auto'})")
-        return label, "explicit DLLAMA_Q40_I4/DLLAMA_NB_MAJOR env respected"
-    if choice != "auto":
-        return choice, "explicit DLLAMA_Q40_BODY"
+    if sharded:
+        return Q40_STOCK
     if q40_kernel_mode() != "pallas":
-        return "d-major", "XLA matmul path (no Pallas kernels here)"
+        return Q40Layout("d-major",
+                         "XLA matmul path (no Pallas kernels here)")
     from .pallas_q40 import MULTI_T_MAX, NB_MULTI_T_MAX, _pick_rows_nb
 
-    if NB_MULTI_T_MAX < rows <= MULTI_T_MAX:
-        return "d-major", (f"{rows}-row decode dispatches: no nb-major "
-                           f"kernel serves T in {NB_MULTI_T_MAX + 1}.."
-                           f"{MULTI_T_MAX}, d-major has the multi-T body")
+    if not nb_major_serves(rows):
+        return Q40Layout("d-major", (
+            f"{rows}-row decode dispatches: no nb-major kernel serves T in "
+            f"{NB_MULTI_T_MAX + 1}..{MULTI_T_MAX}, d-major has the multi-T "
+            f"body"))
 
     counted = spec.matmul_shape_counts()     # a layer's, experts included
     shapes = [shape for shape, _ in counted]
     shapes.append((spec.vocab_size, spec.dim))  # wcls
     bad = [(d, n) for d, n in shapes if _pick_rows_nb(d, n // 32) is None]
     if bad:
-        return "d-major", (f"shape {bad[0]} has no nb-major row tiling "
-                           f"(rows must divide by 128)")
+        return Q40Layout("d-major", (
+            f"shape {bad[0]} has no nb-major row tiling (rows must divide "
+            f"by 128)"))
     packed_gb = (spec.n_layers * sum(c * d * (n // 32) * 18
                                      for (d, n), c in counted)
                  + spec.vocab_size * (spec.dim // 32) * 18) / 1e9
-    raw_gb = os.environ.get("DLLAMA_Q40_BODY_MAX_GB", "6")
-    try:
-        max_gb = float(raw_gb)
-    except ValueError:
-        raise ValueError(f"DLLAMA_Q40_BODY_MAX_GB={raw_gb!r}: expected a "
-                         f"number of GB (e.g. 6)") from None
-    if packed_gb > max_gb:
-        return "d-major", (f"~{packed_gb:.1f} GB packed exceeds the "
-                           f"{max_gb:.0f} GB i4-conversion headroom gate "
-                           f"(DLLAMA_Q40_BODY_MAX_GB; 13B-class OOM, "
-                           f"BASELINE.md r5)")
+    if packed_gb > Q40_I4_MAX_PACKED_GB:
+        return Q40Layout("d-major", (
+            f"~{packed_gb:.1f} GB packed exceeds the "
+            f"{Q40_I4_MAX_PACKED_GB:.0f} GB i4-conversion headroom gate "
+            f"(13B-class OOM, BASELINE.md r5)"))
     if spec.n_experts:
-        return "d-major", (f"expert spec, ~{packed_gb:.1f} GB packed: the "
-                           f"stock picks stand (expert stacks always pack "
-                           f"nb-major) and the i4 chain body has no expert "
-                           f"kernel")
-    return "i4-nb", (f"auto: shapes place nb-major, ~{packed_gb:.1f} GB "
-                     f"packed fits the i4 headroom gate")
+        return Q40Layout("d-major", (
+            f"expert spec, ~{packed_gb:.1f} GB packed: the stock picks "
+            f"stand (expert stacks always pack nb-major) and the i4 chain "
+            f"body has no expert kernel"))
+    return Q40Layout("i4-nb", (
+        f"auto: shapes place nb-major, ~{packed_gb:.1f} GB packed fits the "
+        f"i4 headroom gate"))
+
+
+def announce_q40_layout(layout: Q40Layout) -> None:
+    """The record of a pick: one stderr line, printed unconditionally even
+    for quiet callers (a silent layout change would make runs
+    incomparable), and the label on every log record's run stamp."""
+    import sys
+
+    from ..utils.fingerprint import stamp_q40_body
+
+    stamp_q40_body(layout.label)
+    print(f"💡 Q40 body policy: {layout.label} ({layout.reason}; the i4 "
+          f"body engages on fused decode chains)", file=sys.stderr)
+
+
+# What apply_q40_body_policy last resolved, for packers that are handed no
+# layout. pack_q40_params is its one reader.
+_APPLIED_LAYOUT: Q40Layout | None = None
 
 
 def apply_q40_body_policy(spec, rows: int = 1) -> str:
-    """Apply q40_body_policy by setting the layout env knobs the packers
-    and the decode chain already read (DLLAMA_NB_MAJOR=force +
-    DLLAMA_Q40_I4=on), BEFORE any pack/sidecar load — the kcache layout
-    key includes DLLAMA_NB_MAJOR. Prints the chosen policy to stderr
-    unconditionally, even for quiet callers: a silent layout change would
-    make runs incomparable. setdefault only: explicit user env is never
-    overridden."""
-    import sys
+    """A SHIM for callers that pack by hand after it
+    (``pack_q40_params(tree, allow_nb_major=True)`` with no ``layout``, as
+    three tools under benchmark/tools/ do): resolves ``q40_body_policy``,
+    announces it, records it in ``_APPLIED_LAYOUT`` (overwritten by the
+    next call, not first-wins) and returns the label. Engines never depend
+    on it having been called: each resolves the same value from its own
+    spec and dispatch width, so a call before building one is redundant
+    and harmless. ROADMAP names its removal."""
+    global _APPLIED_LAYOUT
 
-    policy, reason = q40_body_policy(spec, rows)
-    if policy == "i4-nb":
-        os.environ.setdefault("DLLAMA_NB_MAJOR", "force")
-        os.environ.setdefault("DLLAMA_Q40_I4", "on")
-    print(f"💡 Q40 body policy: {policy} ({reason}; the i4 body "
-          f"engages on fused decode chains)", file=sys.stderr)
-    return policy
+    _APPLIED_LAYOUT = layout = q40_body_policy(spec, rows)
+    announce_q40_layout(layout)
+    return layout.label
 
 
 def fake_quant_q80(x: jax.Array) -> jax.Array:

@@ -12,7 +12,7 @@ Expert weights stay Q40, stacked (L, E, ...) in the nb-major kernel layout
 (io/loader.Q40KernelNb: the output dim rides the lanes, so OLMoE's block
 counts 64 and 32 pad nothing and the chip stores the stack as it is packed;
 a d-major leaf of that shape would be copied at the top of every step,
-ops/linear.sharded_nb_major). ``w1`` and ``w3`` are fused at load into
+ops/linear.q40_leaf_layout). ``w1`` and ``w3`` are fused at load into
 ``moe_w13`` (ops/linear.fuse_q40_layer_matmuls).
 
 Two grouped matmuls, picked by the dispatch width T (static):

@@ -43,8 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelI4PackedD,
-                         Q40KernelI4PackedNb, Q40KernelNb, Q40KernelNbI4,
+from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelNb, Q40KernelNbI4,
                          Q40Weight, to_kernel_layout)
 
 QK = 32
@@ -279,31 +278,6 @@ NB_MULTI_T_MAX = 4
 _VMEM64_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
-def q40_i4_enabled() -> bool:
-    """DLLAMA_Q40_I4=on routes the fused decode chain through signed-int4
-    weight planes (VERDICT r4 #2's second nb-major formulation).
-    NB-MAJOR LEAVES ONLY: d-major trees (7B/70B shapes) are a silent
-    no-op — their s4 body measured ~6x SLOWER on hardware (BASELINE.md
-    r5), so the flag only changes 13B-class nb-major leaves.
-
-    What it does: at CHAIN START (inside the jitted program — this
-    runtime cannot pass int4 across a jit boundary) every Q40KernelNb
-    leaf is re-expressed as (code - 8) int4 planes (to_i4_planes); the
-    T=1 matvec body then needs ONE convert + mul + add per plane instead
-    of convert/mask/shift/2xconvert/2xmul/2xadd — measured 701 GB/s vs
-    638 on the 13B w13 shape, against a 746 GB/s DMA floor
-    (tools/nb_probe.py). Cost: the conversion pass (~0.06 ms/token
-    amortized over a 64-step chain) and TRANSIENT extra HBM for the i4
-    copy while the chain runs (~+50% of the codes' bytes; the u8
-    originals remain the placed arguments). Exact same integers — parity
-    is bit-tight with the u8 bodies. Default off until the memory
-    headroom story is per-model; the bench flips it per config."""
-    mode = os.environ.get("DLLAMA_Q40_I4", "off")
-    if mode not in ("on", "off"):
-        raise ValueError(f"DLLAMA_Q40_I4={mode!r}: expected on|off")
-    return mode == "on"
-
-
 def to_i4_planes(tree):
     """Re-express every Q40Kernel / Q40KernelNb leaf of a param tree (or a
     single leaf) as its signed-int4 plane form. Jit-internal only — see
@@ -319,7 +293,7 @@ def to_i4_planes(tree):
         return jnp.concatenate([lo, hi], axis=-3)
 
     def conv(v):
-        # nb-major only in production (see repack_i4_packed); the d-major
+        # nb-major only in production (see chain_weight_prep); the d-major
         # planes exist for tests/experiments via the single-leaf form
         if isinstance(v, Q40KernelNb):
             return Q40KernelNbI4(planes(v.qs_t), v.scale)
@@ -332,77 +306,28 @@ def to_i4_planes(tree):
     return {k: conv(v) for k, v in tree.items()}
 
 
-def repack_i4_packed(tree):
-    """HOST-side: re-express u8 kernel leaves as the RESIDENT packed-i4
-    carrier (Q40KernelI4Packed*): (code - 8) signed nibbles, pairwise
-    along the minor dim, low nibble = even index (XLA S4 bit order).
-    (c - 8) & 0xF == c ^ 0x8 for 4-bit codes, so the repack is two XORs
-    and an interleave. Leaves whose minor dim is odd (tiny test specs)
-    stay u8 — the chain's legacy in-program conversion covers them."""
-    import numpy as np
+def chain_weight_prep(params, i4: bool):
+    """Decode-chain weight prep, run INSIDE the jitted chain (this runtime
+    cannot pass int4 across a jit boundary). With ``i4`` (the engine's
+    resolved ``Q40Layout.i4_chain``, ops/linear.q40_body_policy) every
+    Q40KernelNb leaf is re-expressed as (code - 8) signed-int4 planes
+    (to_i4_planes); the T=1 matvec body then needs ONE convert + mul + add
+    per plane instead of convert/mask/shift/2xconvert/2xmul/2xadd —
+    measured 701 GB/s vs 638 on the 13B w13 shape, against a 746 GB/s DMA
+    floor (tools/nb_probe.py). Cost: the conversion pass (~0.06 ms/token
+    amortized over a 64-step chain) and TRANSIENT extra HBM for the i4
+    copy while the chain runs (~+50% of the codes' bytes; the u8 originals
+    remain the placed arguments: fine at 7B, OOMs 13B). Exact same
+    integers — parity is bit-tight with the u8 bodies.
 
-    def pack(qs_t):
-        # qs_t is a host numpy plane stack (runs at load, after pack);
-        # the nibble ops above keep it numpy end to end
-        lo = (qs_t & 0xF) ^ 0x8
-        hi = (qs_t >> 4) ^ 0x8
-        pl = np.concatenate([lo, hi], axis=-3)
-        return (pl[..., 0::2] | (pl[..., 1::2] << 4)).astype(np.uint8)
-
-    def conv(v):
-        # nb-major ONLY: the d-major s4 body measured ~6x SLOWER than u8
-        # on hardware (64 vs 10.3 ms/token at 7B — Mosaic's s4->f32
-        # unpack on (rows, nb) tiles is pathological), while the nb-major
-        # body is the probe's 701 GB/s winner. Q40Kernel leaves stay u8.
-        if isinstance(v, Q40KernelNb) and v.qs_t.shape[-1] % 2 == 0:
-            return Q40KernelI4PackedNb(pack(v.qs_t), v.scale)
-        return v
-
-    return {k: conv(v) for k, v in tree.items()}
-
-
-def unpack_i4_packed(v):
-    """Jit-internal: the packed-u8 carrier -> int4 plane leaf. The
-    bitcast adds a trailing pair dim that the minor reshape collapses —
-    both are layout reinterpretations of the SAME packed bits (no second
-    copy of the weights). On jax builds whose u8->s4 bitcast does NOT
-    split pairs (int4 stored one byte per element, e.g. 0.4.37 CPU), the
-    nibbles unpack arithmetically instead — same values, the bitcast's
-    zero-copy property traded for a few VPU ops."""
-    q8 = v.qs_p
-    q4 = jax.lax.bitcast_convert_type(q8, jnp.int4)
-    if q4.shape == (*q8.shape, 2):                        # (..., X, Y/2, 2)
-        q4 = q4.reshape(*q4.shape[:-2], q4.shape[-2] * 2)  # (..., X, Y)
-    else:
-        # low nibble = even index (the repack_i4_packed layout); nibbles
-        # hold (c - 8) two's-complement: ((n + 8) & 0xF) - 8 re-signs
-        pairs = jnp.stack([q8 & 0xF, q8 >> 4], axis=-1)   # (..., Y/2, 2)
-        signed = ((pairs.astype(jnp.int32) + 8) & 0xF) - 8
-        q4 = signed.astype(jnp.int4).reshape(*q8.shape[:-1],
-                                             q8.shape[-1] * 2)
-    if isinstance(v, Q40KernelI4PackedD):
-        return Q40KernelI4(q4, v.scale)
-    return Q40KernelNbI4(q4, v.scale)
-
-
-def chain_weight_prep(params):
-    """Decode-chain weight prep, run INSIDE the jitted chain: packed-i4
-    carriers always unpack (they are unreadable otherwise); u8 kernel
-    leaves additionally convert to i4 planes when DLLAMA_Q40_I4=on (the
-    legacy double-residency path — fine at 7B, OOMs 13B)."""
-    i4 = q40_i4_enabled()
-
-    def conv(v):
-        if isinstance(v, (Q40KernelI4PackedD, Q40KernelI4PackedNb)):
-            return unpack_i4_packed(v)
-        # nb-major ONLY (the d-major s4 body is the documented ~6x
-        # negative; the single-leaf to_i4_planes form still converts
-        # d-major for tests, so gate HERE)
-        if i4 and isinstance(v, Q40KernelNb):
-            return to_i4_planes(v)
-        return v
-
-    return {k: conv(v) for k, v in params.items()}
+    NB-MAJOR LEAVES ONLY: the d-major s4 body measured ~6x SLOWER than u8
+    on hardware (64 vs 10.3 ms/token at 7B — Mosaic's s4->f32 unpack on
+    (rows, nb) tiles is pathological; BASELINE.md r5). The single-leaf
+    to_i4_planes form still converts d-major for tests, so gate HERE."""
+    if not i4:
+        return params
+    return {k: to_i4_planes(v) if isinstance(v, Q40KernelNb) else v
+            for k, v in params.items()}
 
 
 def _matvec_body_i4(qs4, s, x32_ref, out_ref):
@@ -1545,10 +1470,6 @@ def q40_matmul(w: Q40Kernel | Q40Weight, x: jax.Array,
     (L, 16, d, nb)) and the kernel DMAs layer ``layer`` directly out of the
     stack via scalar prefetch — the zero-copy path for lax.scan over layers.
     """
-    if isinstance(w, (Q40KernelI4PackedD, Q40KernelI4PackedNb)):
-        # callers outside a prepped chain (prefill, tests): unpack per
-        # call — the bitcast is a reinterpretation, not a weight copy
-        w = unpack_i4_packed(w)
     if isinstance(w, (Q40KernelI4, Q40KernelNbI4)):
         return _q40_matmul_i4(w, x, interpret, layer, block_rows)
     if isinstance(w, Q40KernelNb):

@@ -251,7 +251,7 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
     Q40 weights are re-tiled to the Pallas kernel layout first (host side,
     once) when the Q40 fast path is active; ``rows`` is the width of the
     caller's decode dispatch, which the layout rule reads
-    (ops/linear.sharded_nb_major). Placement goes through
+    (ops/linear.q40_leaf_layout). Placement goes through
     ``make_array_from_callback``, not ``device_put``: each process
     materializes ONLY its addressable shards (a multi-host device_put both
     asserts bitwise-equal full values on every host — which slice-streamed

@@ -304,7 +304,8 @@ class ContinuousEngine:
                  journal=None, watchdog=None, kv_quant: str = "f32",
                  kv_host_pages: int = 0, kv_disk_dir: str | None = None,
                  kv_disk_bytes: int = 0, kv_tier_async: bool = True,
-                 remote_pages: bool = False, slo_priority: bool = False):
+                 remote_pages: bool = False, slo_priority: bool = False,
+                 q40_layout=None):
         import jax
         import jax.numpy as jnp
 
@@ -476,8 +477,15 @@ class ContinuousEngine:
                 jax.lax.dynamic_update_slice(
                     cache_b.v, c1.v[:, None], (0, b, 0, 0, 0)))
 
-        if mesh is not None and (mesh.shape["tp"] > 1
-                                 or mesh.shape.get("sp", 1) > 1):
+        sharded = mesh is not None and (mesh.shape["tp"] > 1
+                                        or mesh.shape.get("sp", 1) > 1)
+        # how the Q40 leaves lie (ops/linear.Q40Layout), resolved once: one
+        # decode dispatch of this engine is ``slots`` rows wide
+        from ..ops.linear import q40_body_policy
+
+        self.q40_layout = q40_layout or q40_body_policy(
+            spec, rows=slots, sharded=sharded)
+        if sharded:
             # sharded step: same program as the lockstep batch path, driven
             # with a (B,) position vector
             from ..parallel import (make_sharded_forward,
@@ -542,7 +550,7 @@ class ContinuousEngine:
                 self._scratch_cache = lambda: shard_cache(
                     init_cache(spec, dtype), mesh)
         else:
-            self.params = params_to_device(params)
+            self.params = params_to_device(params, layout=self.q40_layout)
             if self._alloc is not None:
                 self.cache = (
                     init_cache_paged_q8(spec, self._alloc.n_pages + 1,
@@ -2600,7 +2608,7 @@ def generate_continuous(spec: TransformerSpec, params: dict[str, Any],
                         dispatch_tokens: int = 0,
                         kv_quant: str = "f32", kv_host_pages: int = 0,
                         kv_disk_dir: str | None = None,
-                        kv_disk_bytes: int = 0):
+                        kv_disk_bytes: int = 0, q40_layout=None):
     """CLI entry: encode prompts, stream them through a slot pool, print
     rows in the --prompts-file format ("[i] 'text'")."""
     reqs = [tokenizer.encode(p or "", bos=True, eos=False) for p in prompts]
@@ -2616,7 +2624,8 @@ def generate_continuous(spec: TransformerSpec, params: dict[str, Any],
                            dispatch_tokens=dispatch_tokens,
                            kv_quant=kv_quant, kv_host_pages=kv_host_pages,
                            kv_disk_dir=kv_disk_dir,
-                           kv_disk_bytes=kv_disk_bytes)
+                           kv_disk_bytes=kv_disk_bytes,
+                           q40_layout=q40_layout)
     outs, stats = eng.run(reqs, steps, quiet=quiet)
     for b, (req, row) in enumerate(zip(reqs, outs)):
         if not quiet:

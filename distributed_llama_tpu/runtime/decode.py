@@ -114,10 +114,14 @@ def greedy_verify_tokens(logits: jax.Array) -> jax.Array:
 
 
 def _make_decode_run(step_fn: StepFn, max_steps: int, temperature: float,
-                     topp: float):
+                     topp: float, i4: bool = False):
     """Build run(params, cache, prompt_padded, first_token, coins,
     start_pos, num_steps) -> (tokens (max_steps,), cache): the fused
     generation loop (raw traceable fn; make_decode_loop jits it).
+
+    ``i4``: the chain converts its nb-major Q40 leaves to int4 planes at
+    its start (the builder's resolved ``Q40Layout.i4_chain``,
+    ops/linear.q40_body_policy; ops/pallas_q40.chain_weight_prep).
 
     ``max_steps`` (typically seq_len) fixes the BUFFER shapes only; the
     actual step budget ``num_steps`` is a traced scalar bound of the
@@ -150,11 +154,9 @@ def _make_decode_run(step_fn: StepFn, max_steps: int, temperature: float,
         read as the terminator, so the host-side truncation is unchanged.
         """
         if isinstance(params, dict):
-            # packed-i4 carriers always unpack here (a bitcast, not a
-            # compute pass); u8 leaves convert iff DLLAMA_Q40_I4=on
             from ..ops.pallas_q40 import chain_weight_prep
 
-            params = chain_weight_prep(params)
+            params = chain_weight_prep(params, i4)
         toks0 = jnp.full((max_steps,), BOS, dtype=jnp.int32)
 
         def cond(carry):
@@ -182,15 +184,16 @@ def _make_decode_run(step_fn: StepFn, max_steps: int, temperature: float,
 
 
 def make_decode_loop(step_fn: StepFn, max_steps: int, temperature: float,
-                     topp: float):
+                     topp: float, i4: bool = False):
     """The fused generation loop, jitted (see _make_decode_run)."""
-    return jax.jit(_make_decode_run(step_fn, max_steps, temperature, topp),
-                   donate_argnums=1)
+    return jax.jit(_make_decode_run(step_fn, max_steps, temperature, topp,
+                                    i4), donate_argnums=1)
 
 
 def make_decode_loop_aot(step_fn: StepFn, max_steps: int,
                          temperature: float, topp: float,
-                         exe_cache_dir: str | None = None):
+                         exe_cache_dir: str | None = None,
+                         i4: bool = False):
     """make_decode_loop variant that AOT-compiles with the parameter layouts
     PINNED to what the placed arrays actually have, instead of letting the
     AOT compiler choose compact input layouts and convert them inside the
@@ -220,7 +223,7 @@ def make_decode_loop_aot(step_fn: StepFn, max_steps: int,
     """
     import numpy as np
 
-    run = _make_decode_run(step_fn, max_steps, temperature, topp)
+    run = _make_decode_run(step_fn, max_steps, temperature, topp, i4)
 
     def compile_and_place(params_host, *rest):
         def sds(a):

@@ -77,7 +77,8 @@ class Engine:
     """Owns params + cache + the jitted step; exposes infer(token, pos)."""
 
     def __init__(self, spec: TransformerSpec, params: dict[str, Any],
-                 mesh=None, cache_dtype=None, fast_prefill: bool = False):
+                 mesh=None, cache_dtype=None, fast_prefill: bool = False,
+                 q40_layout=None):
         import functools
 
         import jax
@@ -96,6 +97,12 @@ class Engine:
         # resolved ONCE: the engine's program, its comm accounting, and the
         # stats line all describe the same collective schedule
         self.tp_scheme = tp_scheme()
+        # likewise: how the Q40 leaves lie and which body the fused chain
+        # runs (ops/linear.Q40Layout); one dispatch here is one row wide
+        from ..ops.linear import q40_body_policy
+
+        self.q40_layout = q40_layout or q40_body_policy(
+            spec, rows=1, sharded=self.sharded)
         self._loops: dict = {}  # (temp, topp) -> compiled device loop
         # routed (row, expert) pairs and distinct experts summed over layers
         # and ``infer`` steps (expert specs; GenStats carries them)
@@ -120,7 +127,8 @@ class Engine:
         else:
             from ..models.llama import params_to_device
 
-            self.params = params_to_device(params, spec=spec)
+            self.params = params_to_device(params, spec=spec,
+                                           layout=self.q40_layout)
             self.cache = init_cache(spec, self.cache_dtype)
             self._step_raw = functools.partial(forward, spec)
             # an expert spec's step also hands out the (L, E) count of rows
@@ -314,7 +322,8 @@ class Engine:
         key = (temperature, topp)
         if key not in self._loops:
             self._loops[key] = make_decode_loop(
-                self._step_raw, self.spec.seq_len, temperature, topp)
+                self._step_raw, self.spec.seq_len, temperature, topp,
+                i4=self.q40_layout.i4_chain)
         return self._loops[key]
 
     def reset(self):
@@ -559,8 +568,8 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
 def generate_batch(spec: TransformerSpec, params: dict[str, Any],
                    tokenizer: Tokenizer, prompts: list[str], steps: int,
                    temperature: float, topp: float, seed: int,
-                   cache_dtype=None, mesh=None,
-                   quiet: bool = False) -> tuple[list[list[int]], GenStats]:
+                   cache_dtype=None, mesh=None, quiet: bool = False,
+                   q40_layout=None) -> tuple[list[list[int]], GenStats]:
     """Generate for B prompts in one fused lockstep batch.
 
     A capability extension (the reference is strictly batch=1): all rows
@@ -608,7 +617,11 @@ def generate_batch(spec: TransformerSpec, params: dict[str, Any],
         run = make_batch_decode_loop(spec, steps, temperature, topp,
                                      step_fn=step_fn)
     else:
-        dev_params = params_to_device(params)  # batch: T>1 paths, no mega prep
+        from ..ops.linear import q40_body_policy
+
+        # batch: T>1 paths, no mega prep; a dispatch is B rows wide
+        dev_params = params_to_device(
+            params, layout=q40_layout or q40_body_policy(spec, rows=B))
         cache0 = init_cache_batch(spec, B, dtype)
         run = make_batch_decode_loop(spec, steps, temperature, topp)
     t0 = time.perf_counter()

@@ -113,7 +113,7 @@ class InferenceServer:
                  disagg_peer: str | None = None,
                  page_channel_port: int = 0, handoff_min_pages: int = 2,
                  flightrec_dir: str | None = None,
-                 watch_interval_s: float = 0.0):
+                 watch_interval_s: float = 0.0, q40_layout=None):
         self.spec = spec
         self.tokenizer = tokenizer
         self.default_steps = steps
@@ -198,7 +198,8 @@ class InferenceServer:
                                            disagg_role == "decode"),
                                        slo_priority=(
                                            disagg_role == "prefill"
-                                           and slo is not None))
+                                           and slo is not None),
+                                       q40_layout=q40_layout)
         if disagg_role == "prefill":
             from .disagg import make_priority_hold
             from .page_channel import PageChannelServer

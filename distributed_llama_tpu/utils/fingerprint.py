@@ -50,6 +50,18 @@ def env_fingerprint() -> dict:
     return out
 
 
+# the Q40 layout label the process last announced
+# (ops/linear.announce_q40_layout); "unresolved" until one was
+_Q40_BODY = "unresolved"
+
+
+def stamp_q40_body(label: str) -> None:
+    """Record the resolved Q40 layout label for every later run stamp."""
+    global _Q40_BODY
+
+    _Q40_BODY = label
+
+
 def run_stamp() -> dict:
     """The joinability header: tp scheme + Q40 body policy + fingerprint.
 
@@ -68,7 +80,7 @@ def run_stamp() -> dict:
         stamp["tp_scheme"] = tp_scheme()
     except Exception:  # noqa: BLE001
         stamp["tp_scheme"] = os.environ.get("DLLAMA_TP_SCHEME", "?")
-    stamp["q40_body"] = os.environ.get("DLLAMA_Q40_BODY", "auto")
+    stamp["q40_body"] = _Q40_BODY
     key = "jax" in sys.modules
     if key not in _FP_CACHE:
         try:

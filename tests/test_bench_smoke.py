@@ -199,28 +199,3 @@ def test_bench_trials_env(monkeypatch):
 
     with pytest.raises(SystemExit):
         bench._bench_trials()
-
-
-def test_row_env_policy():
-    """The per-row kernel-policy envs are A/B-backed (BASELINE.md r5) and
-    must never override explicit user env."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod3", os.path.join(_ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    assert bench._row_env("13b-tp2", {})["DLLAMA_Q40_I4"] == "on"
-    assert bench._row_env("13b-tp4", {})["DLLAMA_Q40_I4"] == "on"
-    assert "DLLAMA_Q40_I4" not in bench._row_env("13b-tp8", {})
-    assert "DLLAMA_Q40_I4" not in bench._row_env("13b", {})
-    e7 = bench._row_env("7b", {})
-    assert e7 == {"DLLAMA_Q40_I4": "on", "DLLAMA_NB_MAJOR": "force"}
-    for cfg in ("7b-tp2", "7b-tp4", "7b-tp8", "70b-tp8"):
-        assert bench._row_env(cfg, {}) == {}
-    # explicit user env always wins
-    assert bench._row_env("7b", {"DLLAMA_Q40_I4": "off"}) == \
-        {"DLLAMA_Q40_I4": "off"}
-    assert bench._row_env("13b-tp2", {"DLLAMA_Q40_I4": "off"}) == \
-        {"DLLAMA_Q40_I4": "off"}

@@ -247,8 +247,7 @@ def chip_branch(monkeypatch):
     benchmark/tools/rehearse_compile.py arranges. Traces made so are dropped
     afterwards: a jitted dispatch that resolved ``interpret=None`` to the
     chip would otherwise be reused by a CPU test of the same shapes."""
-    for var in ("DLLAMA_Q40_KERNEL", "DLLAMA_ATTN_KERNEL", "DLLAMA_TP_SCHEME",
-                "DLLAMA_NB_MAJOR"):
+    for var in ("DLLAMA_Q40_KERNEL", "DLLAMA_ATTN_KERNEL", "DLLAMA_TP_SCHEME"):
         monkeypatch.delenv(var, raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     yield

@@ -155,16 +155,40 @@ def test_packed_tree_decodes_like_codec_tree(tmp_path, monkeypatch):
 
 
 def test_nb_major_force_invalidates(tmp_path, monkeypatch):
-    """DLLAMA_NB_MAJOR changes the packed layout, so it must re-key the
-    sidecar (a d-major sidecar served to a force run would silently
-    ignore the layout request)."""
+    """A layout that forces nb-major changes the packed tree, so it must
+    re-key the sidecar (a d-major sidecar served to a forced run would
+    silently ignore the layout request)."""
+    from distributed_llama_tpu.ops.linear import Q40Layout
+
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
     path = _model_file(tmp_path)
     kc.load_model_packed(path)
     side = kc.sidecar_path(path)
     assert kc.load_packed(side, kc.layout_key(path)) is not None
-    monkeypatch.setenv("DLLAMA_NB_MAJOR", "force")
-    assert kc.load_packed(side, kc.layout_key(path)) is None
+    forced = Q40Layout("i4-nb", "test")
+    assert kc.load_packed(side, kc.layout_key(path, layout=forced)) is None
+
+
+def test_layout_key_strings_are_the_parents(tmp_path, monkeypatch):
+    """The key for today's two cases is byte-equal to what the parent
+    built from DLLAMA_NB_MAJOR (unset -> ``nb=auto``, the i4-nb policy ->
+    ``nb=force``): no sidecar on disk is rebuilt by the change of
+    mechanism."""
+    from distributed_llama_tpu.ops.linear import Q40_STOCK, Q40Layout
+    from distributed_llama_tpu.ops.pallas_layer import fusion_cache_key
+    from distributed_llama_tpu.ops.pallas_q40 import _matvec_cap
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    path = _model_file(tmp_path)
+    st = os.stat(path)
+    tail = f"|tp=1|wf=Q40|bf=F32|src={st.st_size}:{st.st_mtime_ns}"
+    head = f"v1|pallas|{_matvec_cap()}|{fusion_cache_key()}"
+    assert kc.layout_key(path) == f"{head}|nb=auto{tail}"
+    assert kc.layout_key(path, layout=Q40_STOCK) == f"{head}|nb=auto{tail}"
+    assert kc.layout_key(path, layout=Q40Layout("d-major", "8 rows")) \
+        == f"{head}|nb=auto{tail}"
+    assert kc.layout_key(path, layout=Q40Layout("i4-nb", "auto")) \
+        == f"{head}|nb=force{tail}"
 
 
 def test_layout_key_folds_float_types(tmp_path, monkeypatch):

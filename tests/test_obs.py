@@ -700,9 +700,15 @@ def test_log_json_records_carry_run_stamp(capsys, monkeypatch):
     from distributed_llama_tpu.obs.log import log_event
     from distributed_llama_tpu.utils import fingerprint
 
+    from distributed_llama_tpu.ops.linear import (Q40Layout,
+                                                  announce_q40_layout)
+
     monkeypatch.setenv("DLLAMA_LOG_JSON", "1")
     monkeypatch.setenv("DLLAMA_TP_SCHEME", "ref")
-    monkeypatch.setenv("DLLAMA_Q40_BODY", "i4-nb")
+    # the stamp carries the label the process last announced, not a knob
+    monkeypatch.setattr(fingerprint, "_Q40_BODY", "unresolved")
+    announce_q40_layout(Q40Layout("i4-nb", "test"))
+    capsys.readouterr()
     fingerprint.reset_stamp_cache()
     try:
         log_event("decode.token", None, pos=1)

@@ -529,6 +529,7 @@ def test_memory_model_counts_the_experts():
 
 
 def test_body_policy_counts_the_experts(monkeypatch):
+    from distributed_llama_tpu.ops import linear
     from distributed_llama_tpu.ops.linear import q40_body_policy
 
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
@@ -537,5 +538,5 @@ def test_body_policy_counts_the_experts(monkeypatch):
                             qk_norm=True)
     policy, reason = q40_body_policy(olmoe, rows=16)
     assert policy == "d-major" and "3.8 GB" in reason
-    monkeypatch.setenv("DLLAMA_Q40_BODY_MAX_GB", "3")
+    monkeypatch.setattr(linear, "Q40_I4_MAX_PACKED_GB", 3.0)
     assert "exceeds" in q40_body_policy(olmoe, rows=16)[1]

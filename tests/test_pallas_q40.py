@@ -505,8 +505,8 @@ def test_multi_t_body_env_validation(monkeypatch):
 
 @pytest.mark.parametrize("layout", ["d", "nb"])
 def test_i4_planes_matvec_matches_u8(layout, monkeypatch):
-    """to_i4_planes + the int4 matvec bodies (DLLAMA_Q40_I4) compute the
-    exact same integers as the u8 kernels: parity is f32-tight."""
+    """to_i4_planes + the int4 matvec bodies (the i4 chain body) compute
+    the exact same integers as the u8 kernels: parity is f32-tight."""
     import jax
     import jax.numpy as jnp
 
@@ -558,9 +558,9 @@ def test_i4_planes_stacked_and_fallbacks(monkeypatch):
 
 
 def test_i4_decode_chain_parity(monkeypatch):
-    """DLLAMA_Q40_I4=on: the fused decode chain produces the same tokens
-    and cache as the u8 path (the conversion is inside the chain; same
-    integers end to end)."""
+    """A chain built with ``i4`` (a layout's ``i4_chain``) produces the
+    same tokens and cache as the u8 path (the conversion is inside the
+    chain; same integers end to end)."""
     import functools as ft
 
     import jax
@@ -578,19 +578,15 @@ def test_i4_decode_chain_parity(monkeypatch):
         synth_params(spec, q40=True), allow_nb_major=True))
     step = ft.partial(forward, spec)
 
-    def chain():
-        # a FRESH loop per arm: q40_i4_enabled() is read at trace time,
-        # and a shared jitted run would serve the first arm's trace to
-        # the second (cache hit on identical shapes)
-        run = make_decode_loop(step, 12, temperature=0.0, topp=0.9)
+    def chain(i4):
+        run = make_decode_loop(step, 12, temperature=0.0, topp=0.9, i4=i4)
         padded = jnp.full((13,), -1, jnp.int32).at[0].set(1)
         coins = jnp.zeros((12,), jnp.float32)
         toks, _ = run(params, init_cache(spec, jnp.float32), padded,
                       jnp.int32(1), coins, jnp.int32(0), jnp.int32(8))
         return np.asarray(toks)
 
-    base = chain()
-    monkeypatch.setenv("DLLAMA_Q40_I4", "on")
+    base = chain(False)
     # prove the i4 program actually traces: the conversion must appear
     # in the jaxpr of the enabled arm
     from distributed_llama_tpu.runtime.decode import _make_decode_run
@@ -598,78 +594,12 @@ def test_i4_decode_chain_parity(monkeypatch):
 
     padded = jnp.full((13,), -1, jnp.int32).at[0].set(1)
     eqns = walk_fn_eqns(
-        _make_decode_run(step, 12, 0.0, 0.9), params,
+        _make_decode_run(step, 12, 0.0, 0.9, True), params,
         init_cache(spec, jnp.float32), padded, jnp.int32(1),
         jnp.zeros((12,), jnp.float32), jnp.int32(0), jnp.int32(8))
     assert any(str(e.outvars[0].aval.dtype) == "int4" for e in eqns
                if e.outvars), "i4 conversion absent from the traced chain"
-    got = chain()
+    got = chain(True)
     np.testing.assert_array_equal(base, got)
 
 
-def test_i4_packed_carrier_roundtrip(monkeypatch):
-    """repack_i4_packed (host u8 carrier, nb-major-only in production —
-    the d-major s4 body measured ~6x slower on hardware) -> in-program
-    bitcast unpack -> matvec: same integers as the u8 kernel path, and
-    the resident carrier is plain uint8 at the SAME byte count."""
-    import jax
-    import jax.numpy as jnp
-
-    from distributed_llama_tpu.io.loader import (Q40KernelI4PackedNb,
-                                                 to_kernel_layout,
-                                                 to_kernel_layout_nb)
-    from distributed_llama_tpu.ops.pallas_q40 import (q40_matmul,
-                                                      repack_i4_packed)
-
-    d, n = 256, 512
-    w = _mk(d, n, seed=9)
-    # d-major leaves must pass through UNCHANGED (the documented negative)
-    kern_d = to_kernel_layout(w)
-    assert repack_i4_packed({"w": kern_d})["w"] is kern_d
-
-    kern = to_kernel_layout_nb(w)
-    tree = repack_i4_packed({"w": kern})
-    leaf = tree["w"]
-    assert isinstance(leaf, Q40KernelI4PackedNb)
-    assert leaf.qs_p.dtype == np.uint8
-    assert leaf.qs_p.nbytes == np.asarray(kern.qs_t).nbytes
-
-    rng = np.random.default_rng(11)
-    x = jnp.asarray(rng.standard_normal((1, n)).astype(np.float32))
-    want = np.asarray(q40_matmul(kern, x))
-    got = np.asarray(jax.jit(lambda l, xv: q40_matmul(l, xv))(leaf, x))
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
-
-def test_i4_packed_decode_chain_parity(monkeypatch):
-    """The fused chain over a packed-i4 tree (bitcast prep in-program)
-    emits the same tokens as the u8 tree."""
-    import functools as ft
-
-    import jax.numpy as jnp
-
-    from distributed_llama_tpu.models.llama import forward, init_cache
-    from distributed_llama_tpu.models.synth import (small_bench_spec,
-                                                    synth_params)
-    from distributed_llama_tpu.ops.linear import (fuse_q40_layer_matmuls,
-                                                  pack_q40_params)
-    from distributed_llama_tpu.ops.pallas_q40 import repack_i4_packed
-    from distributed_llama_tpu.runtime.decode import make_decode_loop
-
-    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
-    spec = small_bench_spec()
-    params = fuse_q40_layer_matmuls(pack_q40_params(
-        synth_params(spec, q40=True), allow_nb_major=True))
-    step = ft.partial(forward, spec)
-
-    def chain(tree):
-        run = make_decode_loop(step, 12, temperature=0.0, topp=0.9)
-        padded = jnp.full((13,), -1, jnp.int32).at[0].set(1)
-        coins = jnp.zeros((12,), jnp.float32)
-        toks, _ = run(tree, init_cache(spec, jnp.float32), padded,
-                      jnp.int32(1), coins, jnp.int32(0), jnp.int32(8))
-        return np.asarray(toks)
-
-    base = chain(params)
-    got = chain(repack_i4_packed(params))
-    np.testing.assert_array_equal(base, got)

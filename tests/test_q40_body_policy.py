@@ -1,42 +1,49 @@
-"""Q40 decode-body policy (ISSUE 3 satellite): the bench's A/B-winning
-i4-plane + nb-major combo must reach plain `inference` runs through ONE
-policy function, with DLLAMA_Q40_BODY as the explicit override and loud
-reasons either way. Decision logic only — the kernels themselves are
-pinned by tests/test_pallas_q40.py."""
+"""The Q40 layout of a model (ISSUE 29): ONE value, ``Q40Layout``, resolved
+by the pure ``q40_body_policy(spec, rows)`` and handed down as an argument;
+ONE per-leaf rule, ``q40_leaf_layout``, for one chip and for shards. No
+process environment carries any of it. Decision logic and plumbing only —
+the kernels themselves are pinned by tests/test_pallas_q40.py."""
 
 from __future__ import annotations
 
 import os
+import re
 
+import numpy as np
 import pytest
 
+from distributed_llama_tpu.io.loader import Q40Kernel, Q40KernelNb, Q40Weight
+from distributed_llama_tpu.models.spec import TransformerSpec
 from distributed_llama_tpu.models.synth import (llama2_7b_spec,
                                                 llama2_13b_spec)
-from distributed_llama_tpu.ops.linear import (apply_q40_body_policy,
-                                              q40_body_policy)
+from distributed_llama_tpu.ops import linear
+from distributed_llama_tpu.ops.linear import (Q40_STOCK, Q40Layout,
+                                              apply_q40_body_policy,
+                                              pack_q40_params,
+                                              q40_body_policy,
+                                              q40_leaf_layout)
+from distributed_llama_tpu.ops.quants import FloatType
 
-
-_KNOBS = ("DLLAMA_Q40_BODY", "DLLAMA_Q40_I4", "DLLAMA_NB_MAJOR",
-          "DLLAMA_Q40_BODY_MAX_GB", "DLLAMA_Q40_KERNEL")
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
-def _clean_env(monkeypatch):
-    for var in _KNOBS:
-        monkeypatch.delenv(var, raising=False)
-    yield
-    # apply_q40_body_policy writes os.environ itself, and delenv of an
-    # absent variable registered no undo: drop what a test left behind
-    # (monkeypatch, torn down after this, puts back what was set before)
-    for var in _KNOBS:
-        os.environ.pop(var, None)
+def _clean(monkeypatch):
+    """The kernel mode is each test's own; what ``apply_q40_body_policy``
+    records is put back afterwards (monkeypatch restores the attribute)."""
+    monkeypatch.delenv("DLLAMA_Q40_KERNEL", raising=False)
+    monkeypatch.setattr(linear, "_APPLIED_LAYOUT", None)
 
 
 def test_auto_picks_i4_nb_for_7b_on_pallas(monkeypatch):
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
-    policy, reason = q40_body_policy(llama2_7b_spec())
-    assert policy == "i4-nb"
-    assert "auto" in reason
+    layout = q40_body_policy(llama2_7b_spec())
+    policy, reason = layout                  # unpacks as (label, reason)
+    assert policy == "i4-nb" and "auto" in reason
+    assert layout.force_nb_major and layout.i4_chain
+    # a mesh engine: leaves are judged shard-locally, the i4 body stays off
+    assert q40_body_policy(llama2_7b_spec(), sharded=True) is Q40_STOCK
+    assert not (Q40_STOCK.force_nb_major or Q40_STOCK.i4_chain)
 
 
 @pytest.mark.parametrize("rows,want", [(1, "i4-nb"), (4, "i4-nb"),
@@ -49,10 +56,12 @@ def test_auto_keeps_5_to_8_row_dispatches_off_nb_major(monkeypatch, rows,
     dequantize-then-dot route for every matmul — measured 75 vs 37.5
     ms/token on the chip (PERF.md, PR 21)."""
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
-    policy, reason = q40_body_policy(llama2_7b_spec(), rows=rows)
-    assert policy == want, reason
+    layout = q40_body_policy(llama2_7b_spec(), rows=rows)
+    assert layout.label == want, layout.reason
+    assert layout.force_nb_major == layout.i4_chain == (want == "i4-nb")
+    assert linear.nb_major_serves(rows) == (want == "i4-nb")
     if want == "d-major":
-        assert f"{rows}-row" in reason
+        assert f"{rows}-row" in layout.reason
 
 
 def test_auto_declines_13b_on_memory_headroom(monkeypatch):
@@ -62,67 +71,284 @@ def test_auto_declines_13b_on_memory_headroom(monkeypatch):
     policy, reason = q40_body_policy(llama2_13b_spec())
     assert policy == "d-major"
     assert "headroom" in reason
-    # ... but a raised gate flips it (the knob the bench's tp2/tp4 rank
-    # rows effectively use at their smaller band sizes)
-    monkeypatch.setenv("DLLAMA_Q40_BODY_MAX_GB", "12")
+    # ... but a raised gate flips it (the gate is the module's constant)
+    monkeypatch.setattr(linear, "Q40_I4_MAX_PACKED_GB", 12.0)
     policy, _ = q40_body_policy(llama2_13b_spec())
     assert policy == "i4-nb"
 
 
 def test_auto_declines_off_pallas():
     # CPU / xla mode: layouts are moot, keep the stock picks
-    policy, reason = q40_body_policy(llama2_7b_spec())
-    assert policy == "d-major"
-    assert "Pallas" in reason or "XLA" in reason
+    layout = q40_body_policy(llama2_7b_spec())
+    assert layout.label == "d-major"
+    assert "Pallas" in layout.reason or "XLA" in layout.reason
+    assert not layout.force_nb_major and not layout.i4_chain
 
 
-def test_explicit_env_always_wins(monkeypatch):
-    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
-    monkeypatch.setenv("DLLAMA_Q40_I4", "off")
-    # the label reports what the env actually engages — never a policy
-    # nobody chose (a mislabel would defeat the comparability note)
-    policy, reason = q40_body_policy(llama2_7b_spec())
-    assert policy == "env(i4=off, nb-major=auto)" and "respected" in reason
+# ---- what replaced the environment's precedence ---------------------------
 
-    # direct env knobs beat DLLAMA_Q40_BODY too (nothing unsets user env)
-    monkeypatch.setenv("DLLAMA_Q40_BODY", "i4-nb")
-    policy, reason = q40_body_policy(llama2_7b_spec())
-    assert policy.startswith("env(") and "respected" in reason
-
-    # the exact winning combo set by hand reports as itself
-    monkeypatch.setenv("DLLAMA_Q40_I4", "on")
-    monkeypatch.setenv("DLLAMA_NB_MAJOR", "force")
-    assert q40_body_policy(llama2_7b_spec())[0] == "i4-nb"
-
-    monkeypatch.delenv("DLLAMA_Q40_I4")
-    monkeypatch.delenv("DLLAMA_NB_MAJOR")
-    policy, reason = q40_body_policy(llama2_7b_spec())
-    assert policy == "i4-nb" and "explicit DLLAMA_Q40_BODY" in reason
-
-    monkeypatch.setenv("DLLAMA_Q40_BODY", "nope")
-    with pytest.raises(ValueError, match="DLLAMA_Q40_BODY"):
-        q40_body_policy(llama2_7b_spec())
+# a width at which the stock picks and the forced layout differ (nb 128
+# pads nothing, so only a layout that forces it packs nb-major) and every
+# leaf places on the nb-major row tiler: q40_body_policy gives i4-nb at one
+# row and d-major at eight
+WIDE = dict(dim=4096, hidden_dim=4096, n_layers=1, n_heads=32,
+            n_kv_heads=32, vocab_size=256, seq_len=32,
+            weights_float_type=FloatType.Q40)
 
 
-def test_apply_sets_env_knobs_and_notes(monkeypatch, capsys):
-    import os
+def _wide():
+    from distributed_llama_tpu.models.synth import synth_q40_fast
+
+    spec = TransformerSpec(**WIDE)
+    return spec, synth_q40_fast(spec, seed=3)
+
+
+def _kinds(params) -> set:
+    return {type(v) for v in params.values()
+            if isinstance(v, (Q40Weight, Q40Kernel, Q40KernelNb))}
+
+
+def test_explicit_layout_wins_over_the_engines_own(monkeypatch):
+    """An engine resolves its layout itself, and one that is handed a
+    layout takes that: the value is an argument, nothing overrides it."""
+    from distributed_llama_tpu.runtime.generate import Engine
 
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    spec, tree = _wide()
+    own = Engine(spec, tree)
+    assert own.q40_layout.label == "i4-nb"
+    assert _kinds(own.params) == {Q40KernelNb}
+    stock = Q40Layout("d-major", "handed down")
+    handed = Engine(spec, tree, q40_layout=stock)
+    assert handed.q40_layout is stock
+    assert _kinds(handed.params) == {Q40Kernel}
+    # ... down to the chain: the loop converts to int4 planes iff the
+    # engine's layout says so
+    from distributed_llama_tpu.runtime import decode
+
+    seen = []
+    monkeypatch.setattr(decode, "make_decode_loop",
+                        lambda *a, i4=False: seen.append(i4))
+    own.decode_loop(0.0, 0.9)
+    handed.decode_loop(0.0, 0.9)
+    assert seen == [True, False]
+
+
+def test_apply_returns_label_notes_and_leaves_env_alone(monkeypatch, capsys):
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    before = dict(os.environ)
     assert apply_q40_body_policy(llama2_7b_spec()) == "i4-nb"
-    assert os.environ["DLLAMA_NB_MAJOR"] == "force"
-    assert os.environ["DLLAMA_Q40_I4"] == "on"
-    assert "Q40 body policy: i4-nb" in capsys.readouterr().err
+    assert dict(os.environ) == before
+    err = capsys.readouterr().err
+    # chip_smoke.py parses this line
+    m = re.search(r"💡 Q40 body policy: (.*)", err)
+    assert m and m.group(1).startswith("i4-nb (auto: ")
+    assert err.count("Q40 body policy") == 1
+    assert linear._APPLIED_LAYOUT == q40_body_policy(llama2_7b_spec())
 
 
-def test_apply_never_overrides_explicit_env(monkeypatch, capsys):
-    import os
+def test_apply_twice_the_second_stands(monkeypatch, capsys):
+    """Overwritten, not first-wins: a by-hand packer after the second call
+    packs the second call's layout."""
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    spec, tree = _wide()
+    assert apply_q40_body_policy(spec, rows=1) == "i4-nb"
+    assert _kinds(pack_q40_params(tree, allow_nb_major=True)) == {Q40KernelNb}
+    assert apply_q40_body_policy(spec, rows=8) == "d-major"
+    assert linear._APPLIED_LAYOUT.label == "d-major"
+    assert _kinds(pack_q40_params(tree, allow_nb_major=True)) == {Q40Kernel}
+    assert "8-row" in capsys.readouterr().err
+
+
+# ---- new with ISSUE 29 ----------------------------------------------------
+
+def test_two_engines_of_two_widths_each_get_their_own_layout(monkeypatch):
+    """One process, ``inference`` then ``serve`` at 8 slots, each preceded
+    by ``apply_q40_body_policy`` as the benchmark's drivers call it: the
+    one-row engine packs forced nb-major, the eight-row engine d-major (no
+    nb-major kernel serves 5..8 rows). Under the environment's
+    ``setdefault`` the first call won for both (75 against 37.5 ms/token
+    at 8 rows, PERF.md PR 21)."""
+    from distributed_llama_tpu.runtime.continuous import ContinuousEngine
+    from distributed_llama_tpu.runtime.generate import Engine
 
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
-    monkeypatch.setenv("DLLAMA_Q40_BODY", "i4-nb")  # forced policy...
-    monkeypatch.setenv("DLLAMA_Q40_I4", "off")      # ...but explicit knob
-    apply_q40_body_policy(llama2_7b_spec())
-    assert os.environ["DLLAMA_Q40_I4"] == "off"     # user env untouched
-    # an env-labeled outcome sets NOTHING (the user's partial config is
-    # not silently completed) and the note says what actually engages
-    assert "DLLAMA_NB_MAJOR" not in os.environ
-    assert "Q40 body policy: env(i4=off" in capsys.readouterr().err
+    spec, tree = _wide()
+    apply_q40_body_policy(spec, rows=1)
+    one = Engine(spec, tree)
+    apply_q40_body_policy(spec, rows=8)
+    eight = ContinuousEngine(spec, tree, 8, 0.0, 0.9, seed=1)
+    assert _kinds(one.params) == {Q40KernelNb}
+    assert _kinds(eight.params) == {Q40Kernel}
+    # and without the calls: engines resolve the same values themselves
+    assert Engine(spec, tree).q40_layout == one.q40_layout
+    sixteen = ContinuousEngine(spec, tree, 16, 0.0, 0.9, seed=1)
+    assert sixteen.q40_layout.label == "i4-nb"
+    assert _kinds(sixteen.params) == {Q40KernelNb}
+    assert eight.q40_layout.label == "d-major"
+
+
+def test_engine_ignores_the_shim_a_by_hand_packer_reads_it(monkeypatch):
+    from distributed_llama_tpu.runtime.generate import Engine
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    spec, tree = _wide()
+    apply_q40_body_policy(spec, rows=8)          # records d-major
+    assert _kinds(Engine(spec, tree).params) == {Q40KernelNb}
+    # the tools' contract (benchmark/tools/rehearse_compile.py and two
+    # more): apply, then pack by hand with no layout
+    assert _kinds(pack_q40_params(tree, allow_nb_major=True)) == {Q40Kernel}
+    apply_q40_body_policy(spec, rows=1)
+    assert _kinds(pack_q40_params(tree, allow_nb_major=True)) == {Q40KernelNb}
+    # a passed layout beats the shim
+    assert _kinds(pack_q40_params(tree, allow_nb_major=True,
+                                  layout=Q40_STOCK)) == {Q40Kernel}
+
+
+# The five cells' real leaf shapes (benchmark/configs/*.json) and what the
+# PARENT (PR 27's tree) packed for them, read off its own pack_q40_params
+# with the re-tilers stubbed: n = nb-major, d = d-major.
+def _dense(dim, hidden, heads, kv, vocab):
+    hs = dim // heads
+    return {"wq": (dim, dim), "wk": (kv * hs, dim), "wv": (kv * hs, dim),
+            "wo": (dim, dim), "w1": (hidden, dim), "w2": (dim, hidden),
+            "w3": (hidden, dim), "wcls": (vocab, dim)}
+
+
+MISTRAL = _dense(4096, 14336, 32, 8, 32000)
+YI = _dense(7168, 20480, 56, 8, 64000)
+OLMOE = {"wq": (2048, 2048), "wk": (2048, 2048), "wv": (2048, 2048),
+         "wo": (2048, 2048), "wcls": (50304, 2048), "moe_w1": (1024, 2048),
+         "moe_w2": (2048, 1024), "moe_w3": (1024, 2048)}
+MISTRAL_SPEC = dict(dim=4096, hidden_dim=14336, n_layers=32, n_heads=32,
+                    n_kv_heads=8, vocab_size=32000, seq_len=4096,
+                    weights_float_type=FloatType.Q40)
+OLMOE_SPEC = dict(dim=2048, hidden_dim=1024, n_layers=16, n_heads=16,
+                  n_kv_heads=16, vocab_size=50304, seq_len=4096,
+                  weights_float_type=FloatType.Q40, n_experts=64,
+                  n_active_experts=8, qk_norm=True)
+_ALL_NB = dict.fromkeys(MISTRAL, "nb-major")
+_ALL_D = dict.fromkeys(MISTRAL, "d-major")
+# (shapes, spec or None for a sharded tree, tp, rows) -> the parent's kinds
+PARENT_PACKED = {
+    # mistral7b.decode1: i4-nb forces every leaf
+    "mistral-tp1-rows1": (MISTRAL, MISTRAL_SPEC, 1, 1, _ALL_NB),
+    # mistral7b.serve-chat / serve-sat (8 slots): the stock picks, no pad
+    "mistral-tp1-rows8": (MISTRAL, MISTRAL_SPEC, 1, 8, _ALL_D),
+    "mistral-tp1-rows16": (MISTRAL, MISTRAL_SPEC, 1, 16, _ALL_NB),
+    # olmoe7b.gen-sat16: an expert spec keeps the stock picks at any width;
+    # nb 64 pads 2x d-major, so the dense leaves pack nb-major too
+    **{f"olmoe-tp1-rows{r}": (OLMOE, OLMOE_SPEC, 1, r,
+                              dict.fromkeys(OLMOE, "nb-major"))
+       for r in (1, 8, 16)},
+    # yi34b-tp4.decode1: shard-local nb 224 / 56 / 160, all off the grid
+    "yi-tp4-rows1": (YI, None, 4, 1, _ALL_NB),
+    "yi-tp4-rows8": (YI, None, 4, 8, _ALL_D),
+    "yi-tp4-rows16": (YI, None, 4, 16, _ALL_NB),
+    # Mistral sharded: nb 128 is on the grid, the input-sharded 32 / 112 not
+    **{f"mistral-tp4-rows{r}": (MISTRAL, None, 4, r,
+                                {**_ALL_D, "wo": "nb-major",
+                                 "w2": "nb-major"})
+       for r in (1, 16)},
+    "mistral-tp4-rows8": (MISTRAL, None, 4, 8, _ALL_D),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_PACKED))
+def test_leaf_rule_packs_the_cells_as_the_parent_did(case, monkeypatch):
+    """``q40_leaf_layout`` on each leaf's shard-local shape, under the
+    layout an engine of that width resolves, and ``pack_q40_params`` on
+    the abstract tree: both give the parent's packed kinds."""
+    import jax
+
+    from distributed_llama_tpu.parallel.tp import FUSED_INPUT_SHARDED
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    shapes, spec_kw, tp, rows, want = PARENT_PACKED[case]
+    layout = (q40_body_policy(TransformerSpec(**spec_kw), rows)
+              if spec_kw else Q40_STOCK)
+
+    def local(k, d, n):
+        if tp > 1 and k in FUSED_INPUT_SHARDED:
+            return d, n // tp // 32
+        return d // tp, n // 32
+
+    got = {k: q40_leaf_layout(*local(k, d, n), tp=tp, rows=rows,
+                              layout=layout, key=k)
+           for k, (d, n) in shapes.items()}
+    assert got == want
+
+    lead = {k: (1, 64) if k.startswith("moe_") else
+            () if k == "wcls" else (1,) for k in shapes}
+    tree = {k: Q40Weight(
+        jax.ShapeDtypeStruct((*lead[k], d, n // 32, 16), np.uint8),
+        jax.ShapeDtypeStruct((*lead[k], d, n // 32), np.float16))
+        for k, (d, n) in shapes.items()}
+    packed = jax.eval_shape(lambda t: pack_q40_params(
+        t, tp=tp, rows=rows, allow_nb_major=True, layout=layout,
+        input_sharded=FUSED_INPUT_SHARDED if tp > 1 else ()), tree)
+    names = {Q40KernelNb: "nb-major", Q40Kernel: "d-major",
+             Q40Weight: "codec"}
+    assert {k: names[type(v)] for k, v in packed.items()} == want
+
+
+def test_leaf_rule_corners():
+    forced = Q40Layout("i4-nb", "test")
+    # one chip: the pad pick (13B's nb 160 pads 1.6x), opted in or not
+    assert q40_leaf_layout(5120, 160) == "nb-major"
+    assert q40_leaf_layout(5120, 160, allow_nb_major=False) == "d-major"
+    assert q40_leaf_layout(5120, 432) == "d-major"            # pads 1.19x
+    assert q40_leaf_layout(5120, 432, layout=forced) == "nb-major"
+    # a d the nb-major row tiler cannot place stays d-major even forced
+    assert q40_leaf_layout(1376, 160, layout=forced) == "d-major"
+    # sharded: the layout's force is not consulted, the grid and width are
+    assert q40_leaf_layout(4096, 128, tp=4, layout=forced) == "d-major"
+    assert q40_leaf_layout(1792, 224, tp=4, rows=4) == "nb-major"
+    assert q40_leaf_layout(1792, 224, tp=4, rows=5) == "d-major"
+    # what neither tiler places stays codec
+    assert q40_leaf_layout(1000003, 128) == "codec"
+    # an expert stack: nb-major where the grouped kernels place it
+    assert q40_leaf_layout(1024, 64, key="moe_w1") == "nb-major"
+    assert q40_leaf_layout(100, 64, key="moe_w2") == "codec"
+
+
+_NAMES = ("DLLAMA_Q40_" + "BODY", "DLLAMA_Q40_" + "I4",
+          "DLLAMA_NB_" + "MAJOR")
+
+
+def _sources(*roots):
+    for root in roots:
+        path = os.path.join(_ROOT, root)
+        if os.path.isfile(path):
+            yield path
+            continue
+        for base, _, files in os.walk(path):
+            for f in files:
+                if f.endswith((".py", ".md")):
+                    yield os.path.join(base, f)
+
+
+def test_library_writes_no_environment_and_names_none_of_the_four():
+    """Outside frontend/ and analysis/ the package writes ``os.environ``
+    nowhere, and nothing that ships names the retired variables."""
+    write = re.compile(r"os\.environ\[[^\]]*\]\s*=[^=]|os\.environ\."
+                       r"(setdefault|update|pop)\(|os\.putenv\(|"
+                       r"del os\.environ")
+    offenders = []
+    for path in _sources("distributed_llama_tpu"):
+        rel = os.path.relpath(path, _ROOT)
+        if rel.split(os.sep)[1] in ("frontend", "analysis"):
+            continue
+        with open(path, encoding="utf-8") as fh:
+            for i, line in enumerate(fh, 1):
+                if write.search(line):
+                    offenders.append(f"{rel}:{i}: {line.strip()}")
+    assert offenders == []
+    named = []
+    for path in _sources("distributed_llama_tpu", "bench.py", "tools",
+                         "chip_smoke.py", "README.md"):
+        with open(path, encoding="utf-8") as fh:
+            text = fh.read()
+        named += [f"{os.path.relpath(path, _ROOT)}: {n}" for n in _NAMES
+                  if n in text]
+    assert named == []

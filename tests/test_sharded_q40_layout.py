@@ -65,10 +65,11 @@ PICKS = {
 
 
 @pytest.mark.parametrize("case", sorted(PICKS))
-def test_pack_rule_on_abstract_shapes(case, monkeypatch):
-    monkeypatch.delenv("DLLAMA_NB_MAJOR", raising=False)
+def test_pack_rule_on_abstract_shapes(case):
+    from distributed_llama_tpu.ops.linear import Q40_STOCK
+
     shapes, kw, want = PICKS[case]
-    assert _abstract_pick(shapes, **kw) == want
+    assert _abstract_pick(shapes, layout=Q40_STOCK, **kw) == want
 
 
 @pytest.mark.parametrize("scheme", ["ref", "fused", "overlap"])
