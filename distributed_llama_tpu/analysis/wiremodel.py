@@ -670,6 +670,9 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     # speculation
     _m("dllama_spec_proposed_total"),
     _m("dllama_spec_accepted_total"),
+    # step_once's run-ahead on device-resident picks
+    _m("dllama_serve_steps_ahead_total"),
+    _m("dllama_serve_rows_dropped_ahead_total"),
     # routed experts (an expert spec's decode dispatches)
     _m("dllama_moe_routed_pairs_total"),
     _m("dllama_moe_active_experts_total"),

@@ -204,6 +204,17 @@ class EngineMetrics:
             "Distinct experts a decode dispatch routed to, summed over "
             "layers and dispatches: the expert tiles a step must read")
         self._moe_rows: list = []
+        # step_once's run-ahead (ContinuousStats.steps_ahead /
+        # rows_dropped_ahead): how often the decode iteration engages
+        self.steps_ahead = c(
+            "dllama_serve_steps_ahead_total",
+            "Decode steps launched on the previous step's picks while "
+            "those were still on the device (all rows greedy)")
+        self.rows_dropped_ahead = c(
+            "dllama_serve_rows_dropped_ahead_total",
+            "Rows of steps launched ahead whose result was thrown away: "
+            "the row stopped on a token only the landing told, or was "
+            "cancelled meanwhile")
         # cost-ledger / scheduler-census series (ISSUE 16). The closed
         # vocabularies (token kinds, stall causes) pre-register so a
         # fresh scrape shows the full matrix at zero; per-class series

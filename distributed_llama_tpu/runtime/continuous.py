@@ -221,6 +221,49 @@ class _Slot:
 
 
 @dataclasses.dataclass
+class _Flight:
+    """One run of ``step_once``'s program that is launched and not yet
+    landed: its results are still on the device and the pool is as it was
+    before the step."""
+    rows: list      # per row: the _Slot that took part, else None
+    reqs: list      # ... and the request it held then (it may have left)
+    paused: Any     # slots that rode masked (a step launched from the host)
+    logits: Any     # (B, vocab) float32, fetched only for a temperature
+    picked: Any     # (B,) int32 argmax: the greedy rows' tokens
+    moe: Any        # an expert spec's (L, E) routed-rows counts, else None
+    t0: float       # when the device could start it (time.monotonic)
+    ahead: bool     # launched on the previous step's picks, unread
+
+    def rode(self) -> list:
+        """(row, slot) of the rows whose slot still holds the request it
+        took part for: the others stopped or were cancelled meanwhile and
+        their result is dropped."""
+        return [(b, s) for b, (s, r) in enumerate(zip(self.rows, self.reqs))
+                if s is not None and s.req is r]
+
+
+def _with_pick(step, paged: bool, vocab: int):
+    """``step_once``'s program around a decode forward ``step`` (logits,
+    cache[, moe counts]): the row inputs arrive as ONE staged int32 block
+    (B, 2[ + pages]) = [override | pos | page table], split here, and a
+    row's input token is its override or, where that is -1, the previous
+    step's pick, which never left the device. Beside the forward's results
+    it returns ``picked``, the argmax of each row's logits (lowest index
+    on a tie, as the host's ``sample_argmax``)."""
+    def run(params, cache, prev_picked, blk):
+        import jax.numpy as jnp
+
+        override = blk[:, 0]
+        tokens = jnp.where(override >= 0, override, prev_picked)
+        table = (blk[:, 2:],) if paged else ()
+        logits, cache, *moe = step(params, cache, tokens, blk[:, 1], *table)
+        picked = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
+        return (logits, picked, cache, *moe)
+
+    return run
+
+
+@dataclasses.dataclass
 class ContinuousStats:
     tokens: int = 0          # generated (emitted) tokens
     steps: int = 0           # device steps executed
@@ -260,6 +303,12 @@ class ContinuousStats:
     moe_pairs: int = 0
     moe_active: int = 0
     moe_load: Any = None
+    # step_once's run-ahead: steps launched on the previous step's picks
+    # while those were still on the device, and rows of such steps whose
+    # result was thrown away (the row had stopped on a token only the
+    # landing told, or was cancelled meanwhile)
+    steps_ahead: int = 0
+    rows_dropped_ahead: int = 0
 
     def count_moe(self, counts) -> None:
         """One dispatch's (L, E) rows-per-expert counts."""
@@ -463,7 +512,6 @@ class ContinuousEngine:
         self.block_steps = block_steps  # >1: fused K-step chains (step_many)
         dtype = cache_dtype or jnp.float32
         self._cache_dtype = dtype
-        self._step_counts = None  # an expert spec's paged step_once program
         from ..models.llama import KVCache, forward, init_cache
 
         def _insert(cache_b, c1, b):
@@ -510,6 +558,8 @@ class ContinuousEngine:
                     lambda: make_sharded_forward_batch_paged(
                         spec, mesh, page_size, scheme=scheme,
                         kv_quant=kv_quant))  # rejects sp>1
+                decode_key = ("sh_decode_paged", spec, mesh, page_size,
+                              scheme, kv_quant)
                 if spec_k:
                     self._verify_base = _shared_program(
                         ("sh_verify", spec, mesh, page_size, scheme,
@@ -537,6 +587,10 @@ class ContinuousEngine:
                     ("sh_step_batch", spec, mesh, scheme),
                     lambda: make_sharded_forward_batch(spec, mesh,
                                                        scheme=scheme))
+                decode_key = ("sh_decode_batch", spec, mesh, scheme)
+            # the jitted shard_map wrapper traces inline under step_once's
+            # program, which takes its name
+            decode_fwd = self._step
             if prefill_chunk > 1:
                 # admission prefill: the sharded single-sequence forward
                 # (T=chunk under sp/tp) fills a sharded scratch cache
@@ -565,18 +619,13 @@ class ContinuousEngine:
                             forward_batch_paged, spec, page_size,
                             kv_quant=kv_quant)),
                         donate_argnums=1))
-                if spec.n_experts:
-                    # step_once's program for an expert spec: the same
-                    # step, with the (L, E) routed-rows counts beside the
-                    # logits (the chains keep the two-result ``_step``)
-                    self._step_counts = _shared_program(
-                        ("step_paged_counts", spec, page_size, kv_quant),
-                        lambda: jax.jit(
-                            named_program(
-                                "serve_decode_step", functools.partial(
-                                    forward_batch_paged, spec, page_size,
-                                    kv_quant=kv_quant, moe_counts=True)),
-                            donate_argnums=1))
+                # step_once's forward: an expert spec's also hands out the
+                # (L, E) routed-rows counts (the chains keep the
+                # two-result ``_step``)
+                decode_key = ("decode_paged", spec, page_size, kv_quant)
+                decode_fwd = functools.partial(
+                    forward_batch_paged, spec, page_size, kv_quant=kv_quant,
+                    moe_counts=bool(spec.n_experts))
                 if spec_k:
                     self._verify_base = _shared_program(
                         ("verify", spec, page_size, kv_quant),
@@ -601,6 +650,8 @@ class ContinuousEngine:
                         named_program("serve_decode_step", functools.partial(
                             forward_batch_ragged, spec)),
                         donate_argnums=1))
+                decode_key = ("decode_ragged", spec)
+                decode_fwd = functools.partial(forward_batch_ragged, spec)
             if prefill_chunk > 1:
                 # admission prefill: single-sequence T=chunk forward into a
                 # scratch cache + plane insert
@@ -610,6 +661,27 @@ class ContinuousEngine:
                         functools.partial(forward, spec), fast_prefill,
                         jax, jit=True))
                 self._scratch_cache = lambda: init_cache(spec, dtype)
+        # step_once's ONE program (``_with_pick``): logits, picked, cache[,
+        # counts] from the previous step's picks and one staged block
+        paged = self._alloc is not None
+        self._decode = _shared_program(
+            decode_key, lambda: jax.jit(
+                named_program("serve_decode_step", _with_pick(
+                    decode_fwd, paged, spec.vocab_size)),
+                donate_argnums=1))
+        # columns of a launch's staged block: [override | pos | page table]
+        self._blk_cols = 2 + (self._max_pages if paged else 0)
+        # the newest launch's picks, the next launch's ``prev_picked``; the
+        # first is placed as a step's result is, or a mesh's program would
+        # compile once for each of the two placements
+        self._picked = jax.device_put(
+            np.zeros((slots,), np.int32),
+            jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            if sharded else None)
+        self._flight: _Flight | None = None  # launched, not landed
+        # rows that left the pool to land with the step in flight
+        # (_hand_over), until it has landed: fail_all must reach them
+        self._leaving: list[_Slot] = []
         if prefill_chunk > 1:
             # donate only the batched cache (updated in place); the scratch
             # sequence cache can't alias the rank-5 output
@@ -1473,6 +1545,7 @@ class ContinuousEngine:
         if k <= 1:
             return self.step_once(quiet=quiet)
         jnp = self.jnp
+        self._drain_flight(quiet)  # a step_once call before this one
         self._intake()
         self._admit()
         pool = self._pool
@@ -1624,7 +1697,7 @@ class ContinuousEngine:
     # -- cost accounting (ISSUE 16) -----------------------------------------
 
     def _census_dispatch(self, kind: str, k: int, paused, active: int,
-                         dt_s: float, deferred=()) -> None:
+                         dt_s: float, deferred=(), rode=None) -> None:
         """Charge BOTH accounting halves from one pool walk after a
         decode/spec dispatch: per-slot ledger charges (row steps, page
         steps, stalls by cause, pro-rated ICI bytes) and the whole-
@@ -1640,7 +1713,15 @@ class ContinuousEngine:
         pages_held = 0
         parked: dict = {}
         class_page_s: dict = {}
-        for b, s in enumerate(self._pool):
+        slots = list(enumerate(self._pool))
+        if rode is not None:
+            # step_once: the slots that rode. One that has left the pool
+            # (_hand_over) is charged with them; one admitted after the
+            # launch holds pages and rode nothing
+            in_pool = {id(s) for s in self._pool}
+            slots += [(-1, s) for s in rode if id(s) not in in_pool]
+            rode = {id(s) for s in rode}
+        for b, s in slots:
             if s.free:
                 continue
             led = s.req.ledger
@@ -1674,7 +1755,7 @@ class ContinuousEngine:
                 parked["budget_wait"] = parked.get("budget_wait", 0) + 1
                 if led is not None:
                     led.charge_stall("budget_wait", k, dt_s, reps)
-            elif led is not None:
+            elif led is not None and (rode is None or id(s) in rode):
                 led.charge_rows(k, dt_share, reps)
                 if self._ici_row_bytes:
                     led.charge_ici(self._ici_row_bytes * k, reps)
@@ -2006,94 +2087,258 @@ class ContinuousEngine:
         return sum(not s.free for s in self._pool) + queued
 
     def step_once(self, quiet: bool = True) -> int:
-        """Admit queued requests, run ONE device step over the pool, and
+        """Admit queued requests, land ONE device step over the pool, and
         retire finished rows. Returns the number of active slots after the
         step (0 = idle: nothing queued, nothing in flight). Must be called
-        from a single scheduler thread; submit() may race freely."""
-        jnp = self.jnp
+        from a single scheduler thread; submit() may race freely.
+
+        The iteration runs one step ahead of the host where the pool lets
+        it (``_runs_ahead``): with step n in flight it stages and launches
+        step n+1 on step n's picks, which are still on the device, and
+        only then lands step n (fetches its picks, advances, notifies and
+        retires its rows) while step n+1 runs. Where it does not, the step
+        is launched and landed here, on its logits: the iteration this
+        engine always had. Either way a call lands exactly one step."""
         self._intake()
+        flight, paused = self._flight, ()
+        if flight is not None:
+            self._hand_over(flight)
         self._admit()
         pool = self._pool
         with host_phase("serve.grow_pages"):
             self._settle_promotions(quiet)
             self._resume_prefills()
-            paused = (self._grow_pages(pool, 1, quiet)
-                      if self._alloc is not None else ())
-        if all(s.free for s in pool):
+            if flight is None and self._alloc is not None:
+                paused = self._grow_pages(pool, 1, quiet)
+        if flight is None and all(s.free for s in pool):
             self._journal_sync()  # cover sweep/admit records this iteration
             return self._n_outstanding()
-        # paused (page-starved) rows make no progress this step — exclude
-        # them from occupancy exactly as step_many's active mask does
-        active0 = sum(not s.free and b not in paused
-                      for b, s in enumerate(pool))
-        t0 = time.monotonic()  # census/ledger wall charges need it even
-        #                        when the engine runs metrics-dark
-        st = self._stage_i32
-        with host_phase("serve.stage"):
-            for b, s in enumerate(pool):
-                st[0, b] = s.token
-                st[1, b] = s.pos
-        with self._span("step", "decode", active=active0), self._watch():
-            if self._chaos is not None:
-                self._chaos.on_dispatch()  # inside the armed window (the
-                #   injected stall IS the hang the watchdog must detect)
-            with host_phase("serve.stage"):
-                # one staged upload; the row splits are lazy device-side
-                # slices, so the shared step program keeps its
-                # (tokens, pos) signature
-                staged = jnp.asarray(st[:2])
-                rows = [staged[0], staged[1]]
-                if self._alloc is not None:
-                    rows.append(self._stage_tables())
-            with host_phase("serve.dispatch"):
-                if self._step_counts is not None:
-                    logits, self.cache, moe = self._step_counts(
-                        self.params, self.cache, *rows)
-                else:
-                    moe = None
-                    logits, self.cache = self._step(self.params, self.cache,
-                                                    *rows)
-            with host_phase("serve.fetch"):  # the wait and the transfer
-                logits = np.asarray(logits)  # dlint: allow[D001] host sampler needs logits
-                if moe is not None:  # 4 KB beside the logits
-                    moe = np.asarray(moe)  # dlint: allow[D001] routed-rows counters
-                    self.stats.count_moe(moe)
-                    if self._obs is not None:
-                        self._obs.record_moe(moe)
-            if self._obs is not None:
-                # np.asarray synced the logits; the sync flag also drains
-                # the donated cache write (obs/trace.sync_device_timing)
-                if self._obs.sync:
-                    import jax
+        # the slots that ride the step to land: all that are occupied and
+        # not paused for one launched here
+        fresh = flight is None
+        riding = ({b: s for b, s in enumerate(pool)
+                   if not s.free and b not in paused} if fresh
+                  else dict(flight.rode()))
+        go_ahead = self._runs_ahead(riding, paused)
+        # the watchdog arms around the wait for a step: from the launch of
+        # one launched here, around the fetch of one already in flight
+        active0 = len(riding)
+        with self._span("step", "decode", active=active0), \
+                contextlib.ExitStack() as armed:
+            if fresh:
+                armed.enter_context(self._watch())
+                if self._chaos is not None:
+                    self._chaos.on_dispatch()  # inside the armed window
+                    #   (the injected stall IS the hang the watchdog must
+                    #   detect)
+                flight = self._launch(None, paused)
+            self._flight = self._launch(flight) if go_ahead else None
+            if not fresh:
+                armed.enter_context(self._watch())
+            out, on_host = self._fetch(flight)
+        self._land(flight, out, on_host, quiet)
+        self._leaving.clear()
+        self._admit()
+        ahead = self._flight
+        if ahead is not None and not ahead.rode():
+            # every row it was launched for stopped meanwhile: nothing to
+            # land (its dead writes are in pages that were those rows')
+            self._count_dropped(sum(r is not None for r in ahead.reqs))
+            self._flight = None
+        self._journal_sync()
+        return self._n_outstanding()
 
-                    jax.block_until_ready(self.cache)  # dlint: allow[D001] opt-in timing drain
-                self._obs.record_step(time.monotonic() - t0, active0)
-                if self._alloc is not None:
-                    self._obs.kv_pages_free.set(self._alloc.n_free)
+    def _hand_over(self, flight: _Flight) -> None:
+        """Before admission, with ``flight`` still on the device: a row it
+        stops for a reason the host knows already (its budget, a forced
+        BOS) leaves the pool now and lands with ``flight`` from the slot
+        object the flight keeps, so that its place is filled in THIS
+        iteration and the next launch carries the new row, as it would
+        were the step landed first. The leaving row's pages stay its own
+        until it retires at the landing; its full prompt pages are
+        published now (``_retire`` would, a moment too late for the
+        request that takes its place; ``flight`` writes the last of them
+        before any program enqueued from here on reads it)."""
+        for b, s in flight.rode():
+            if s is self._pool[b] and self._stops_known(s):
+                self._pool[b] = _Slot()
+                self._leaving.append(s)
+                if self._alloc is not None and not s.req.cancelled:
+                    n_ins = min(s.pos + 1, len(s.req.tokens))
+                    self._alloc.insert_prefix(s.req.tokens[:n_ins], s.pages)
+
+    @staticmethod
+    def _stops_known(s: _Slot) -> bool:
+        """Whether the step ``s`` rides in now is its last for a reason the
+        host knows before the token is back: its budget, a forced BOS."""
+        return s.pos + 1 >= s.budget or (bool(s.forced)
+                                         and s.forced[0] == BOS)
+
+    def _runs_ahead(self, riding: dict, paused) -> bool:
+        """Whether the step after the one ``riding`` ({row: the slot that
+        takes part in it}) may be launched before that one lands. The
+        rule reads the pool and nothing a user sets:
+        every occupied row is greedy (a row with a temperature needs its
+        logits on the host, where ``Sampler`` draws its coin), none is
+        paused (starved of pages, waiting on a promotion upload, parked
+        mid-prefill: those iterations mask rows and may fail one, and stay
+        the synchronous ones), the pool covers the positions the next step
+        writes, and no chaos monkey is counting dispatches."""
+        if self._chaos is not None or paused:
+            return False
+        alloc = self._alloc
+        for s in self._pool:
+            if s.free:
+                continue
+            if (s.sampler.temperature != 0.0 or s.await_promo
+                    or s.prefill_pending
+                    or (alloc is not None and alloc.pending_capable
+                        and alloc.slot_pending(s.pages))):
+                return False
+        if alloc is None:
+            return True
+        with host_phase("serve.grow_pages"):
+            for b, s in enumerate(self._pool):
+                # a riding row writes position pos + 1 next
+                ahead = 2 if riding.get(b) is s else 1
+                if not s.free and not self._ensure_pages(
+                        s, min(s.pos + ahead, s.budget)):
+                    return False
+        return True
+
+    def _launch(self, prev: _Flight | None, paused=()) -> _Flight | None:
+        """Stage and enqueue one run of the step program; returns at once.
+        Without ``prev`` every row rides on the host's token for it
+        (``paused`` rows too, masked: their result is not read). With
+        ``prev``, the step in flight, its rows ride at ``pos + 1`` on its
+        picks (override -1) or on the forced token the host knows; rows
+        admitted since ride on the host's token; a row of ``prev`` that
+        is known to stop there and is still in the pool (cancelled since
+        the sweep) rides masked like a free slot, on the scrap page. None
+        when no row would take part."""
+        from .paging import SCRAP_PAGE
+
+        t0 = time.monotonic()
+        # a NEW block per launch, shipped as one upload: the last one may
+        # still be in transfer (or, on a CPU backend, be the memory the
+        # step in flight reads)
+        blk = np.empty((self.slots, self._blk_cols), np.int32)
+        rows: list = [None] * self.slots
+        with host_phase("serve.stage"):
+            for b, s in enumerate(self._pool):
+                token, pos, pages = s.token, s.pos, s.pages
+                if (prev is not None and prev.rows[b] is s
+                        and prev.reqs[b] is s.req):
+                    if s.req.cancelled or self._stops_known(s):
+                        token, pos, pages = 0, 0, ()
+                    else:
+                        token = s.forced[0] if s.forced else -1
+                        pos += 1
+                        rows[b] = s
+                elif not s.free and b not in paused:
+                    rows[b] = s
+                row = blk[b]
+                row[0], row[1] = token, pos
+                row[2:2 + len(pages)] = pages
+                row[2 + len(pages):] = SCRAP_PAGE
+            if prev is not None and not any(r is not None for r in rows):
+                return None
+            staged = self.jnp.asarray(blk)
+        with host_phase("serve.dispatch"):
+            logits, picked, self.cache, *moe = self._decode(
+                self.params, self.cache, self._picked, staged)
+        self._picked = picked
+        if prev is not None:
+            self.stats.steps_ahead += 1
+            if self._obs is not None:
+                self._obs.steps_ahead.inc()
+        reqs = [None if s is None else s.req for s in rows]
+        return _Flight(rows, reqs, paused, logits, picked,
+                       moe[0] if moe else None, t0, prev is not None)
+
+    def _fetch(self, flight: _Flight):
+        """Wait for ``flight`` and bring back what its rows need: the
+        picks (4 bytes a row), or the logits where a row of it samples
+        with a temperature; an expert spec's counts beside them. Returns
+        (array, whether it holds logits)."""
+        on_host = any(s.sampler.temperature != 0.0
+                      for _, s in flight.rode())
+        with host_phase("serve.fetch"):  # the wait and the transfer
+            if on_host:
+                out = np.asarray(flight.logits)  # dlint: allow[D001] host sampler needs logits
+            else:
+                out = np.asarray(flight.picked)  # dlint: allow[D001] four bytes a row
+            if flight.moe is not None:  # 4 KB beside them
+                moe = np.asarray(flight.moe)  # dlint: allow[D001] routed-rows counters
+                self.stats.count_moe(moe)
+                if self._obs is not None:
+                    self._obs.record_moe(moe)
+        return out, on_host
+
+    def _land(self, flight: _Flight, out, on_host: bool, quiet: bool) -> None:
+        """The host's part of a step whose results ``_fetch`` brought back:
+        counters, census, and per row the token, ``_advance`` and
+        ``_retire``. A row of ``flight`` whose slot no longer holds its
+        request (stopped by the step before, cancelled and swept) is
+        dropped: the pool has moved on without it."""
+        pool = self._pool
+        now = time.monotonic()
+        dt = now - flight.t0  # landing to landing for a step run ahead
+        if self._flight is not None:
+            self._flight.t0 = now  # the device starts it as this one ends
+        rode = flight.rode()
+        if flight.ahead:
+            self._count_dropped(
+                sum(r is not None for r in flight.reqs) - len(rode)
+                + sum(s.req.cancelled for _, s in rode))
+        active0 = len(rode)
+        if self._obs is not None:
+            # the fetch synced the step; the sync flag also drains the
+            # donated cache write (obs/trace.sync_device_timing), unless
+            # that would wait for the step launched ahead
+            if self._obs.sync and self._flight is None:
+                import jax
+
+                jax.block_until_ready(self.cache)  # dlint: allow[D001] opt-in timing drain
+            self._obs.record_step(dt, active0)
+            if self._alloc is not None:
+                self._obs.kv_pages_free.set(self._alloc.n_free)
         with host_phase("serve.census"):
             self.stats.steps += 1
             self.stats.sum_active += active0
             self.stats.max_active = max(self.stats.max_active, active0)
-            self._census_dispatch("decode", 1, paused, active0,
-                                  time.monotonic() - t0)
+            self._census_dispatch("decode", 1, flight.paused, active0, dt,
+                                  rode=[s for _, s in rode])
         with host_phase("serve.sample"):
-            for i, s in enumerate(pool):
-                if s.free:
-                    continue
-                if s.req.cancelled:  # consumer gone: free the slot now
-                    self._retire(s, quiet)
-                    continue
-                if i in paused:  # starved of pages: frozen until a retry
+            for s in {id(s): s for s in (*flight.rows, *pool)
+                      if s is not None and not s.free
+                      and s.req.cancelled}.values():
+                self._retire(s, quiet)  # consumer gone: free the slot now
+            for b, s in rode:  # the others are paused, or admitted since
+                if s.free:     # (cancelled: retired above)
                     continue
                 if s.forced:
-                    nxt = s.forced.pop(0)
-                    self._advance(s, nxt, quiet)
+                    self._advance(s, s.forced.pop(0), quiet)
                 else:
-                    nxt = int(s.sampler.sample(logits[i]))
+                    nxt = int(s.sampler.sample(out[b]) if on_host
+                              else out[b])
                     self._advance(s, nxt, quiet, sampled=True)
-        self._admit()
-        self._journal_sync()
-        return self._n_outstanding()
+
+    def _count_dropped(self, n: int) -> None:
+        """``n`` rows of a step run ahead whose result is thrown away."""
+        if n:
+            self.stats.rows_dropped_ahead += n
+            if self._obs is not None:
+                self._obs.rows_dropped_ahead.inc(n)
+
+    def _drain_flight(self, quiet: bool) -> None:
+        """Land the step in flight, if any, launching nothing after it:
+        for a caller about to dispatch another program on the pool."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            with self._watch():
+                out, on_host = self._fetch(flight)
+            self._land(flight, out, on_host, quiet)
 
     def _advance(self, s: _Slot, nxt: int, quiet: bool,
                  sampled: bool = False) -> bool:
@@ -2526,7 +2771,9 @@ class ContinuousEngine:
 
     def fail_all(self, message: str):
         """Fail every queued and in-flight request (scheduler error path —
-        runtime/server.py): sets ``error`` then ``done`` so waiters wake."""
+        runtime/server.py): sets ``error`` then ``done`` so waiters wake.
+        A step in flight is forgotten unlanded, like one never run."""
+        self._flight = None
         with self._lock:
             pending = self._queue
             self._queue = []
@@ -2545,10 +2792,11 @@ class ContinuousEngine:
                                   failed=True)
             self._close_ledger(req.index, "failed")
             req.done.set()
-        for s in self._pool:
+        for s in (*self._pool, *self._leaving):
             if not s.free:
                 s.req.error = message
                 self._retire(s, quiet=True)
+        self._leaving.clear()
         if self._alloc is not None:
             # tear the radix tree down with the rest of the engine state:
             # a post-fault serving loop restarts from an empty, fully-free
@@ -2636,6 +2884,9 @@ def generate_continuous(spec: TransformerSpec, params: dict[str, Any],
         print(f"Avg generation time: "
               f"{stats.total_ms / max(1, stats.tokens):.2f} ms/token "
               f"({stats.tokens_per_s:.1f} tok/s)")
+        if stats.steps_ahead:
+            print(f"Steps run ahead:     {stats.steps_ahead} of "
+                  f"{stats.steps}, {stats.rows_dropped_ahead} rows dropped")
         if eng.allocator is not None:
             a = eng.allocator
             print(f"Paged KV:            {a.n_pages} pages x "

@@ -1273,6 +1273,15 @@ class InferenceServer:
         self.engine.close()  # KV-tier uploader thread (no-op untiered)
         if self._watchdog is not None:
             self._watchdog.close()
+        st = self.engine.stats
+        log_event("server.summary",
+                  f"🌐 served {st.tokens} tokens in {st.steps} steps "
+                  f"({st.avg_active:.2f} rows a step); {st.steps_ahead} "
+                  f"steps launched ahead on device-resident tokens, "
+                  f"{st.rows_dropped_ahead} rows of them dropped",
+                  file=sys.stderr, tokens=st.tokens, steps=st.steps,
+                  sum_active=st.sum_active, steps_ahead=st.steps_ahead,
+                  rows_dropped_ahead=st.rows_dropped_ahead)
         if self.journal is not None:
             self.journal.close()
         try:

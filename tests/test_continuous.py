@@ -251,13 +251,13 @@ def test_continuous_pos_never_reaches_seq_len(params):
     eng = ContinuousEngine(SPEC, params, slots=2, temperature=0.0, topp=0.9,
                            seed=3)
     seen = []
-    real_step = eng._step
+    real_step = eng._decode
 
-    def spy(params_, cache, tokens, pos_vec):
-        seen.append(np.asarray(pos_vec).max())
-        return real_step(params_, cache, tokens, pos_vec)
+    def spy(params_, cache, prev_picked, blk):
+        seen.append(np.asarray(blk)[:, 1].max())    # [override | pos]
+        return real_step(params_, cache, prev_picked, blk)
 
-    eng._step = spy
+    eng._decode = spy
     # steps == seq_len, desynced slots (one row retires early via its
     # shorter budget path while the other keeps going)
     reqs = [[1, 5, 9], [1, 22], [1, 7, 33, 2]]
@@ -342,3 +342,385 @@ def test_use_native_sampler_plumbed_to_slots(params):
     eng2.submit(Request(tokens=[1, 5], steps=4))
     eng2._admit()
     assert eng2._pool[0].sampler.use_native is True
+
+
+# -- step_once runs one step ahead of the host on greedy rows (PR 30) --------
+#
+# With every occupied row at temperature 0, step n+1 is launched on step n's
+# picks while they are still on the device, and step n lands while n+1 runs.
+# Scheduling stays invisible: each request's stream is the stream of the
+# SYNCHRONOUS iteration (every step launched and landed in one call), which
+# the same engine runs when ``_runs_ahead`` says no, and of the request run
+# alone on the host's argmax.
+
+AHEAD = TransformerSpec(dim=64, hidden_dim=160, n_layers=2, n_heads=4,
+                        n_kv_heads=2, vocab_size=128, seq_len=32)
+# greedy streams of AHEAD's seed-4 weights that stop on BOS, and one of
+# EXPERT's; the others below run to their budgets
+BOS_STOPS = {"dense": [1, 10, 60], "expert": [1, 171, 38]}
+
+
+@pytest.fixture(scope="module")
+def ahead_kinds():
+    """kind -> (spec, weights, engine keywords)."""
+    from distributed_llama_tpu.ops.quants import FloatType
+
+    expert = TransformerSpec(dim=128, hidden_dim=64, n_layers=2, n_heads=2,
+                             n_kv_heads=2, vocab_size=256, seq_len=32,
+                             weights_float_type=FloatType.Q40, n_experts=4,
+                             n_active_experts=2, qk_norm=True)
+    dense = synth_params(AHEAD, q40=False, seed=4, scale=0.3)
+    paged = dict(page_size=4, prefill_chunk=4)
+    return {"paged": (AHEAD, dense, paged),
+            "contiguous": (AHEAD, dense, dict(prefill_chunk=4)),
+            "expert": (expert, synth_params(expert, q40=True, seed=11),
+                       paged)}
+
+
+def _ahead_engine(kinds, kind, sync=False, slots=3, **kw):
+    from distributed_llama_tpu.runtime.continuous import ContinuousEngine
+
+    spec, tree, base = kinds[kind]
+    eng = ContinuousEngine(spec, tree, slots=slots, temperature=0.0,
+                           topp=0.9, seed=3, **{**base, **kw})
+    if sync:    # the rule says no: every step is launched and landed at once
+        eng._runs_ahead = lambda riding, paused: False
+    return eng
+
+
+def _requests(kind, n=7):
+    """``n`` greedy requests of uneven prompts and budgets: short prompts
+    that ride forced tokens, long ones that take the admission prefill,
+    one that stops on BOS."""
+    from distributed_llama_tpu.runtime.continuous import Request
+
+    rng = np.random.default_rng(30)
+    top = 250 if kind == "expert" else 120
+    reqs = [Request(tokens=list(BOS_STOPS["expert" if kind == "expert"
+                                          else "dense"]), steps=24)]
+    for i in range(n - 1):
+        plen = int(rng.integers(1, 12))
+        reqs.append(Request(
+            tokens=[1] + [int(t) for t in rng.integers(3, top, plen)],
+            steps=int(rng.integers(plen + 2, 30))))
+    return reqs
+
+
+def _drain(eng):
+    while eng.step_once():
+        pass
+    assert eng._flight is None
+
+
+def _mid_flight(eng, reqs):
+    """Three requests up front, the rest submitted one per two iterations,
+    while a step is in flight."""
+    for r in reqs[:3]:
+        eng.submit(r)
+    for r in reqs[3:]:
+        eng.step_once()
+        eng.step_once()
+        eng.submit(r)
+    _drain(eng)
+
+
+def _cancel(eng, reqs):
+    """The second request is cancelled between a launch and its landing."""
+    for r in reqs:
+        eng.submit(r)
+    for _ in range(5):
+        eng.step_once()
+    assert eng._flight is None or reqs[1] in eng._flight.reqs
+    eng.cancel(reqs[1])
+    _drain(eng)
+
+
+def _starved(eng, reqs):
+    """Two pages for the short request and a third page the long one must
+    wait for in a pool of four, then a request that takes the freed slot."""
+    from distributed_llama_tpu.runtime.continuous import Request
+
+    reqs[:] = [Request(tokens=[1, 22, 7], steps=6),
+               Request(tokens=[1, 5, 9], steps=12),
+               Request(tokens=[1, 60], steps=10)]
+    for r in reqs:
+        eng.submit(r)
+    _drain(eng)
+    assert eng.stats.pauses > 0     # a row did ride a step masked
+
+
+SCENARIOS = {
+    # name: (driver, engine keywords, kinds it applies to)
+    "admissions_mid_flight": (_mid_flight, {}, ("paged", "contiguous",
+                                                "expert")),
+    "cancel_in_flight": (_cancel, {}, ("paged", "contiguous", "expert")),
+    "page_starved_pause": (_starved, dict(slots=2, kv_pages=4,
+                                          prefix_share=False,
+                                          prefill_chunk=0),
+                           ("paged", "expert")),
+}
+
+
+@pytest.mark.parametrize("kind,scenario", [
+    (k, name) for name, (_, _, on) in SCENARIOS.items() for k in on])
+def test_run_ahead_streams_equal_the_synchronous_iteration(ahead_kinds, kind,
+                                                           scenario):
+    drive, kw, _ = SCENARIOS[scenario]
+    want, got = _requests(kind), _requests(kind)
+    sync = _ahead_engine(ahead_kinds, kind, sync=True, **kw)
+    drive(sync, want)
+    eng = _ahead_engine(ahead_kinds, kind, **kw)
+    drive(eng, got)
+    assert sync.stats.steps_ahead == 0 and eng.stats.steps_ahead > 0
+    for w, g in zip(want, got):
+        assert g.done.is_set() and g.error == w.error
+        if g.cancelled:     # a prefix of its stream, wherever it was cut
+            assert g.out == w.out[:len(g.out)] or w.out == g.out[:len(w.out)]
+        else:
+            assert g.out == w.out
+    assert len(got) == len(want)
+    if scenario == "admissions_mid_flight":
+        # the BOS stop is told by the token alone: its row of the step
+        # launched ahead is thrown away; budget stops hand their slot over
+        assert len(got[0].out) < 23 and eng.stats.rows_dropped_ahead >= 1
+        # the same row-steps, landed rows counted as ever; a request that
+        # arrives under a running step joins one step later
+        assert eng.stats.sum_active == sync.stats.sum_active
+        assert 0 <= eng.stats.steps - sync.stats.steps <= len(got) - 3
+    assert eng.audit_pages() == []
+
+
+@pytest.mark.parametrize("kind", ["paged", "contiguous"])
+def test_run_ahead_streams_equal_each_request_alone(ahead_kinds, kind,
+                                                    params_dev_ahead):
+    """The oracle that knows no scheduler: each request alone, token by
+    token, on the host's argmax of the single-sequence forward."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.models.llama import forward, init_cache
+
+    reqs = _requests(kind)
+    eng = _ahead_engine(ahead_kinds, kind)
+    _mid_flight(eng, reqs)
+    assert eng.stats.steps_ahead > 0.8 * eng.stats.steps
+    for r in reqs:
+        c, token, out = init_cache(AHEAD), r.tokens[0], []
+        for pos in range(min(r.steps, AHEAD.seq_len)):
+            lg, c = forward(AHEAD, params_dev_ahead, c,
+                            jnp.asarray([token], jnp.int32), jnp.int32(pos))
+            token = (r.tokens[pos + 1] if pos + 1 < len(r.tokens)
+                     else int(np.argmax(np.asarray(lg[0]))))
+            if token == 1:
+                break
+            out.append(token)
+        assert r.out == out
+
+
+@pytest.fixture(scope="module")
+def params_dev_ahead(ahead_kinds):
+    from distributed_llama_tpu.models.llama import params_to_device
+
+    return params_to_device(ahead_kinds["paged"][1])
+
+
+@pytest.mark.parametrize("kind", ["paged", "expert"])
+def test_prefix_published_at_retire_is_reused_when_run_ahead(ahead_kinds,
+                                                             kind):
+    """No admission prefill: a prompt's pages reach the radix tree when
+    its request retires. The request that takes the freed slot, same
+    prompt, must find them, run ahead or not, and read the same stream."""
+    from distributed_llama_tpu.runtime.continuous import Request
+
+    top = 250 if kind == "expert" else 120
+    prompt = [1] + [int(t) for t in
+                    np.random.default_rng(5).integers(3, top, 13)]
+
+    def run(sync):
+        eng = _ahead_engine(ahead_kinds, kind, sync=sync, slots=2,
+                            prefill_chunk=0)
+        reqs = [Request(tokens=list(prompt), steps=18),
+                Request(tokens=[1, 9, 17], steps=30),
+                Request(tokens=list(prompt) + [7], steps=24)]
+        for r in reqs:
+            eng.submit(r)
+        _drain(eng)
+        return eng, [r.out for r in reqs]
+
+    sync, want = run(True)
+    eng, got = run(False)
+    assert got == want and eng.stats.steps_ahead > 0
+    assert eng.allocator.prefix_hits == sync.allocator.prefix_hits == 1
+    assert eng.allocator.tokens_saved == sync.allocator.tokens_saved == 12
+
+
+@pytest.mark.parametrize("kind", ["paged", "contiguous", "expert"])
+def test_recovery_after_a_stop_with_a_step_in_flight(ahead_kinds, kind,
+                                                     tmp_path):
+    """The process dies with a step launched and not landed: that step is
+    not journaled, as one never run, and the next life's streams are the
+    uninterrupted run's."""
+    from distributed_llama_tpu.runtime.journal import RequestJournal
+
+    ref = _requests(kind, n=4)
+    plain = _ahead_engine(ahead_kinds, kind)
+    for r in ref:
+        plain.submit(r)
+    _drain(plain)
+
+    path = str(tmp_path / "requests.journal")
+    eng = _ahead_engine(ahead_kinds, kind, journal=RequestJournal(path))
+    first = _requests(kind, n=4)
+    for r in first:
+        eng.submit(r)
+    for _ in range(6):
+        eng.step_once()
+    assert eng._flight is not None and eng._flight.ahead   # and it "dies"
+    live = [r for r in first if not r.done.is_set()]
+    assert live and any(r.n_sampled for r in live)
+
+    eng2 = _ahead_engine(ahead_kinds, kind, journal=RequestJournal(path))
+    assert eng2.recover() == len(live)
+    with eng2._lock:
+        recovered = list(eng2._queue)
+    _drain(eng2)
+    want = {tuple(r.tokens): r.out for r in ref}
+    for r, old in zip(recovered, live):
+        assert r.out == want[tuple(old.tokens)]
+    assert eng2.audit_pages() == []
+
+
+class _NeverFetched:
+    """Stands in for a step's logits where no row needs them."""
+
+    def __array__(self, *a, **k):
+        raise AssertionError("the logits crossed to the host")
+
+
+@pytest.mark.parametrize("kind", ["paged", "contiguous", "expert"])
+def test_all_greedy_run_counts_its_steps_ahead_and_fetches_no_logits(
+        ahead_kinds, kind):
+    eng = _ahead_engine(ahead_kinds, kind)
+    real, launches = eng._decode, []
+
+    def no_logits(*args):
+        logits, *rest = real(*args)
+        launches.append(1)
+        return (_NeverFetched(), *rest)
+
+    eng._decode = no_logits
+    reqs = _requests(kind)[1:]          # budget stops only
+    for r in reqs:
+        eng.submit(r)
+    _drain(eng)
+    st = eng.stats
+    # one episode: the first step rides the host's tokens, every later one
+    # the picks still on the device, and one landing launches nothing
+    assert st.steps == len(launches) and st.steps_ahead == st.steps - 1
+    assert st.rows_dropped_ahead == 0
+
+
+def test_a_row_with_a_temperature_holds_the_iteration_synchronous(
+        ahead_kinds):
+    """While a row with a temperature is active no step is launched ahead
+    and it samples on the host from the logits, to the coin, as ``generate``
+    does alone; the greedy rows beside it read the streams they read
+    without it; when it has gone the iteration runs ahead again."""
+    from distributed_llama_tpu.obs.metrics import Registry
+    from distributed_llama_tpu.runtime.continuous import Request
+    from distributed_llama_tpu.runtime.generate import Engine, generate
+    from distributed_llama_tpu.runtime.sampling import Sampler
+
+    class _Tok:
+        def encode(self, text, bos=True, eos=False):
+            return [1] + [3 + b for b in text.encode()]
+
+        def decode_piece(self, prev, tok):
+            return b"?"
+
+    alone = _ahead_engine(ahead_kinds, "paged")
+    greedy = _requests("paged", n=4)
+    for r in greedy:
+        alone.submit(r)
+    _drain(alone)
+
+    reg = Registry()
+    eng = _ahead_engine(ahead_kinds, "paged", slots=4, metrics=reg)
+    warm = Request(tokens=_Tok().encode("hello"), steps=12, temperature=0.9,
+                   topp=0.9, seed=41)
+    beside = _requests("paged", n=4)
+    eng.submit(warm)
+    for r in beside:
+        eng.submit(r)
+    while not warm.done.is_set():
+        eng.step_once()
+        assert eng._flight is None
+    assert eng.stats.steps_ahead == 0 and eng.stats.steps >= 7
+    _drain(eng)
+    assert [r.out for r in beside] == [r.out for r in greedy]
+    assert 0 < eng.stats.steps_ahead < eng.stats.steps
+    want, _ = generate(Engine(AHEAD, ahead_kinds["paged"][1]), _Tok(),
+                       Sampler(AHEAD.vocab_size, 0.9, 0.9, 41), "hello", 12,
+                       quiet=True)
+    assert warm.out == want
+    # /metrics carries both counters
+    assert reg.get("dllama_serve_steps_ahead_total").value == \
+        eng.stats.steps_ahead
+    assert reg.get("dllama_serve_rows_dropped_ahead_total").value == \
+        eng.stats.rows_dropped_ahead
+    text = reg.expose()
+    assert "dllama_serve_steps_ahead_total" in text
+    assert "dllama_serve_rows_dropped_ahead_total" in text
+
+
+def test_a_chain_after_per_step_calls_lands_the_step_in_flight(ahead_kinds):
+    """``step_many(k > 1)`` on an engine that ``step_once`` left a step in
+    flight on lands it first; the streams are the per-step ones."""
+    want, got = _requests("paged"), _requests("paged")
+    ref = _ahead_engine(ahead_kinds, "paged")
+    for r in want:
+        ref.submit(r)
+    _drain(ref)
+    eng = _ahead_engine(ahead_kinds, "paged")
+    for r in got:
+        eng.submit(r)
+    for _ in range(4):
+        eng.step_once()
+    assert eng._flight is not None
+    while eng.step_many(3):
+        assert eng._flight is None
+    assert [r.out for r in got] == [r.out for r in want]
+
+
+def test_a_fault_under_a_handed_over_row_fails_it_with_the_rest(ahead_kinds):
+    """A row that has left the pool to land with the step in flight is
+    still the engine's to fail when the next launch raises: no client is
+    left waiting on a request no pool slot holds."""
+    from distributed_llama_tpu.runtime.continuous import Request
+
+    eng = _ahead_engine(ahead_kinds, "paged", slots=2)
+    reqs = [Request(tokens=[1, 5, 9], steps=6),
+            Request(tokens=[1, 22, 7], steps=20),
+            Request(tokens=[1, 60], steps=9)]
+    for r in reqs:
+        eng.submit(r)
+
+    def boom(*a, **k):
+        raise RuntimeError("injected device fault")
+
+    def about_to_hand_over():
+        flight = eng._flight
+        return flight is not None and any(eng._stops_known(s)
+                                          for _, s in flight.rode())
+
+    while not about_to_hand_over():
+        eng.step_once()
+    eng._decode = boom
+    with pytest.raises(RuntimeError, match="injected"):
+        eng.step_once()
+    assert eng._leaving and not reqs[0].done.is_set()
+    eng.fail_all("scheduler fault")
+    assert all(r.done.is_set() and r.error == "scheduler fault"
+               for r in reqs)
+    assert eng._flight is None and not eng._leaving
+    assert eng.audit_pages() == [] and eng.allocator.n_free == \
+        eng.allocator.n_pages

@@ -142,17 +142,19 @@ def _served_rows(tree, slots, prompts, steps, **kw):
     eng = ContinuousEngine(SPEC, tree, slots=slots, temperature=0.0,
                            topp=0.9, seed=3, page_size=4, prefill_chunk=4,
                            **kw)
-    seen, step = [], eng._step_counts
-    assert step is not None
+    seen, step = [], eng._decode
 
-    def recording(params, cache, toks, pos, table):
-        logits, cache, counts = step(params, cache, toks, pos, table)
-        seen.append((np.asarray(toks), np.asarray(pos),
-                     [s.req for s in eng._pool], np.asarray(logits),
-                     np.asarray(counts)))
-        return logits, cache, counts
+    def recording(params, cache, prev_picked, blk):
+        # rows are [override | pos | page table]; a row run ahead takes
+        # the previous step's pick where its override is -1
+        logits, picked, cache, counts = step(params, cache, prev_picked, blk)
+        blk = np.asarray(blk)
+        toks = np.where(blk[:, 0] >= 0, blk[:, 0], np.asarray(prev_picked))
+        seen.append((toks, blk[:, 1], [s.req for s in eng._pool],
+                     np.asarray(logits), np.asarray(counts)))
+        return logits, picked, cache, counts
 
-    eng._step_counts = recording
+    eng._decode = recording
     reqs = [eng.submit(Request(tokens=list(p), steps=steps))
             for p in prompts]
     while eng.step_once():

@@ -216,7 +216,7 @@ def test_server_scheduler_failure_returns_500(params):
     def boom(*a, **k):
         raise RuntimeError("injected device fault")
 
-    srv.engine._step = boom
+    srv.engine._decode = boom
     srv.start()
     try:
         _post(srv.port, {"prompt": "ab", "steps": 4})
