@@ -74,6 +74,10 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
         from ..ops.linear import MOE_TP_REFUSAL
 
         raise ValueError(MOE_TP_REFUSAL)
+    if spec.retention and n_slices > 1:
+        from ..ops.retention import TP_REFUSAL
+
+        raise ValueError(TP_REFUSAL)
     per_layer = sum(c * d * n for (d, n), c in spec.matmul_shape_counts())
     total = spec.n_layers * per_layer + spec.vocab_size * spec.dim
     return total // n_slices
@@ -99,14 +103,34 @@ def replicated_device_bytes(spec: TransformerSpec) -> int:
     norms = (spec.n_layers * sum(n for _, n in spec.layer_norm_shapes())
              + spec.dim) * 4
     routers = spec.n_layers * spec.n_experts * spec.dim * 4   # f32, whole
-    return embedding + norms + routers
+    gates = (spec.n_layers * spec.n_kv_heads * spec.dim * 4
+             if spec.retention else 0)                         # f32, whole
+    return embedding + norms + routers + gates
+
+
+def state_slot_bytes(spec: TransformerSpec) -> int:
+    """A retention spec's per-sequence memory on its one chip: the state
+    of every layer (ops/retention.state_bytes: float32, fixed whatever the
+    context). What ``kv_position_bytes`` x positions is to a softmax spec."""
+    from ..ops.retention import state_bytes
+
+    if not spec.retention:
+        raise ValueError("state_slot_bytes prices a retention spec's state")
+    return spec.n_layers * state_bytes(spec.n_kv_heads, spec.head_size)
 
 
 def kv_cache_device_bytes(spec: TransformerSpec, n_slices: int,
                           batch: int = 1, n_sp: int = 1,
                           cache_itemsize: int = 4) -> int:
     """K+V planes at max sequence: kv heads shard over tp, sequence chunks
-    over sp (tp.CACHE_SPEC / CACHE_SPEC_BATCH)."""
+    over sp (tp.CACHE_SPEC / CACHE_SPEC_BATCH). A retention spec holds
+    ``batch`` states instead (one chip only), of no length."""
+    if spec.retention:
+        if n_slices > 1 or n_sp > 1:
+            from ..ops.retention import TP_REFUSAL
+
+            raise ValueError(TP_REFUSAL)
+        return batch * state_slot_bytes(spec)
     return (2 * spec.n_layers * batch * (spec.seq_len // n_sp)
             * (spec.n_kv_heads // n_slices) * spec.head_size
             * cache_itemsize)
@@ -534,6 +558,12 @@ def device_footprint(spec: TransformerSpec, n_slices: int, scheme: str,
     if activation_bytes is None:
         activation_bytes = activation_bytes_analytic(spec, n_slices,
                                                      t_len=t_len)
+    if spec.retention and (kv_page_size or kv_quant != "f32" or spec_k
+                           or mixed_budget or tier_staging_pages):
+        raise ValueError(
+            "a retention spec's memory is `batch` states of fixed size: "
+            "pages, q8 pages, the verify window, the mixed budget and the "
+            "tier staging buffer do not apply (the engine refuses them)")
     if kv_page_size > 0:
         pages = (kv_pages if kv_pages is not None
                  else default_kv_pages(spec, batch, kv_page_size))

@@ -570,6 +570,12 @@ def check_config(entry: MatrixEntry, device: str = "v5e",
         from ..ops.linear import MOE_TP_REFUSAL
 
         raise ValueError(f"shardcheck {config}: {MOE_TP_REFUSAL}")
+    if spec.retention:
+        # likewise a retention spec: tp.py refuses it, and its memory is
+        # states of fixed size (memory_model.state_slot_bytes), not pages
+        from ..ops.retention import TP_REFUSAL
+
+        raise ValueError(f"shardcheck {config}: {TP_REFUSAL}")
     findings = check_uniform_shards(spec, entry.tp, entry.scheme, config)
     act_bytes = None
     if not findings and kv_quant == "q8":

@@ -677,6 +677,9 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_moe_routed_pairs_total"),
     _m("dllama_moe_active_experts_total"),
     _m("dllama_moe_expert_rows_total", labels=("expert",)),
+    # a retention spec's recurrent state
+    _m("dllama_state_bytes"),
+    _m("dllama_retention_min_normaliser"),
     # journal / recovery / lifecycle
     _m("dllama_journal_records_total"),
     _m("dllama_recoveries_total"),

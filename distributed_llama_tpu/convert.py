@@ -21,6 +21,16 @@ Extensions beyond the reference:
   one departure from the published rotate-half form, and it computes the
   same attention scores (a fixed permutation inside each head of both q
   and k leaves q.k unchanged; RMS is permutation-invariant).
+* ``--source hf`` on ``model_type: brumby`` (Brumby-14B: Qwen3's block with
+  power-retention layers): ``self_attn.q_norm`` / ``k_norm`` (ONE gain of
+  head size each, permuted inside the head as the rows of wq / wk are) ->
+  ``rms_q`` / ``rms_k``, and the retention gate ``self_attn.gate_proj.weight``
+  -> ``w_gate``, float32, written after ``wo`` under header extension 3
+  with ``rope_theta`` and ``rms_norm_eps`` from the config. The gate's
+  tensor name and its shape (n_kv_heads, hidden_size: one gate a KV head)
+  are this repo's reading of the publication, not of the checkpoint: a
+  checkpoint whose gate has another shape is REFUSED rather than guessed at
+  (``GATE_TENSOR`` names the tensor; there is no fallback).
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -60,7 +70,7 @@ _LAYER_TENSORS = [
 # Meta shards concatenate along dim=1 for these (converter.py:131-136)
 _AXIS1 = {"tok_embedding", "wo", "w2"}
 _ALWAYS_F32 = {"tok_embedding", "rms_att", "rms_ffn", "rms_final", "rms_q",
-               "rms_k", "moe_gate"}
+               "rms_k", "moe_gate", "w_gate"}
 
 
 def _is_f32(name: str) -> bool:
@@ -115,6 +125,11 @@ class MetaCheckpoint:
                 "rms_final": "norm.weight", "wcls": "output.weight"}
 
 
+GATE_TENSOR = "model.layers.{layer}.self_attn.gate_proj.weight"
+"""A ``brumby`` checkpoint's retention gate (assumed name; see the module
+docstring)."""
+
+
 class HFCheckpoint:
     """HuggingFace LlamaForCausalLM -> reference tensor layout.
 
@@ -163,6 +178,11 @@ class HFCheckpoint:
                 raise ValueError("olmoe with clip_qkv: not implemented")
             moe = dict(n_experts=c.num_experts,
                        n_active_experts=c.num_experts_per_tok, qk_norm=True)
+        if getattr(c, "model_type", "") == "brumby":
+            moe = dict(qk_norm=True, qk_norm_per_head=True,
+                       attn_kind="retention",
+                       rope_theta=float(c.rope_theta),
+                       norm_eps=float(c.rms_norm_eps))
         return TransformerSpec(**moe, **self._base_sizes(target, seq_len))
 
     def _base_sizes(self, target: FloatType, seq_len: int) -> dict:
@@ -194,13 +214,22 @@ class HFCheckpoint:
             "w3": f"model.layers.{layer}.mlp.up_proj.weight",
             "rms_q": f"model.layers.{layer}.self_attn.q_norm.weight",
             "rms_k": f"model.layers.{layer}.self_attn.k_norm.weight",
+            "w_gate": GATE_TENSOR.format(layer=layer),
             "moe_gate": f"model.layers.{layer}.mlp.gate.weight",
             "moe_w1": f"{experts}.gate_proj.weight",
             "moe_w2": f"{experts}.down_proj.weight",
             "moe_w3": f"{experts}.up_proj.weight",
         }[name]
         w = self.state[hf].to(self.torch.float32).numpy()
-        if name in ("wq", "rms_q"):
+        if name == "w_gate" and w.shape != spec.gate_shape:
+            raise ValueError(
+                f"{hf}: shape {w.shape}, expected (n_kv_heads, dim) = "
+                f"{spec.gate_shape}: one sigmoid gate a KV head is what the "
+                f"program runs; another gate form is not guessed at")
+        per_head = spec.qk_norm_per_head and name in ("rms_q", "rms_k")
+        if per_head:                      # one head's gains: permute inside
+            w = self._unpermute(w, 1)
+        elif name in ("wq", "rms_q"):
             w = self._unpermute(w, spec.n_heads)
         elif name in ("wk", "rms_k"):
             w = self._unpermute(w, spec.n_kv_heads)
@@ -249,6 +278,8 @@ def convert_hf(model_path: str, target: str, out: str | None = None,
             names = ([n for n, _ in spec.layer_norm_shapes()]
                      + [n for n, _ in spec.layer_matmul_shapes()]
                      + (["moe_gate"] if spec.n_experts else []))
+            if spec.retention:            # the gate follows wo
+                names.insert(names.index("wo") + 1, "w_gate")
             for name_ in names:
                 _write_tensor(f, spec, name_,
                               ckpt.tensor_by_name(name_, i, spec))

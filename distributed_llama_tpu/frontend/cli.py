@@ -45,6 +45,38 @@ _MODEL_HELP = ("path to the reference-format .bin model. Single-chip Q40 "
                "mmap it instead of re-tiling for minutes; set "
                "DLLAMA_TILED_CACHE=0 to disable the sidecar read AND write")
 
+def _refuses_retention(args, tp: int) -> bool:
+    """Print the lines ``runtime/continuous.retention_refusals`` has for the
+    flags of this run (``inference`` or ``serve``; a flag the mode does not
+    have reads as off); True if there was one."""
+    from ..runtime.continuous import retention_refusals
+
+    get = lambda name, off=0: getattr(args, name, off) or off  # noqa: E731
+    refused = retention_refusals(
+        tp=max(tp, get("sp", 1)), page_size=get("kv_page_size"),
+        kv_pages=get("kv_pages"), spec_k=get("spec_k"),
+        dispatch_tokens=get("dispatch_tokens"),
+        kv_quant=get("kv_quant", "f32"),
+        kv_host_pages=get("kv_host_pages"),
+        kv_disk_dir=get("kv_disk_dir", None), journal=bool(get("journal")),
+        disagg=bool(get("disagg_role")), block_steps=get("block_steps", 1),
+        kv_cache_dtype=get("kv_cache_dtype", "f32"))
+    for line in refused:
+        print(f"refused: {line}", file=sys.stderr)
+    return bool(refused)
+
+
+def _retention_line(spec, slots: int) -> str:
+    """What a retention spec keeps a sequence: a startup line."""
+    from ..ops.retention import state_bytes
+
+    per = spec.n_layers * state_bytes(spec.n_kv_heads, spec.head_size)
+    return (f"💡 attention: power retention (degree 2), no KV cache; "
+            f"state: {slots} "
+            f"slot{'s' if slots != 1 else ''} x {per / 2**20:.0f} MiB "
+            f"(fixed, whatever the context)")
+
+
 # --weights-float-type help of the modes that pack Q40 for one chip: how the
 # layout is picked, and where the pick is recorded
 _WFT_HELP = ("weight float type of the .bin. q40 on one chip: each weight's "
@@ -517,8 +549,12 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
               f"💡 nKvHeads: {spec.n_kv_heads}\n"
               f"💡 vocabSize: {spec.vocab_size}\n💡 seqLen: {spec.seq_len}\n"
               + (f"💡 nExperts: {spec.n_experts}\n💡 nActiveExperts: "
-                 f"{spec.n_active_experts}\n💡 qkNorm: {int(spec.qk_norm)}\n"
+                 f"{spec.n_active_experts}\n💡 qkNorm: "
+                 f"{int(spec.qk_norm) + spec.qk_norm_per_head}\n"
                  if spec.extended else "")
+              + (f"💡 attnKind: {spec.attn_kind}\n💡 ropeTheta: "
+                 f"{spec.rope_theta:g}\n💡 normEps: {spec.norm_eps:g}\n"
+                 if spec.header_version == 3 else "")
               + f"💡 nSlices: {tp} sp: {args.sp} scheme: "
               f"{scheme if tp > 1 else '-'} ({n_dev} devices, "
               f"{jax.devices()[0].platform})")
@@ -528,6 +564,11 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         print(f"{MOE_TP_REFUSAL} (this run: tp={tp} sp={args.sp}; pass "
               f"--tp 1)", file=sys.stderr)
         return 2
+    if spec.retention:
+        if _refuses_retention(args, tp):
+            return 2
+        if not quiet:
+            print(_retention_line(spec, rows))   # one chip: rows is set
     mesh = (make_mesh(sp=args.sp, tp=tp)
             if tp > 1 or args.sp > 1 else None)
     assumed = getattr(args, "_slice_tp_ranks", None)
@@ -1031,6 +1072,10 @@ def cmd_serve(argv: list[str]) -> int:
         print(f"{MOE_TP_REFUSAL} (this run: --tp {args.tp})",
               file=sys.stderr)
         return 2
+    if spec.retention:
+        if _refuses_retention(args, args.tp or 1):
+            return 2
+        print(_retention_line(spec, args.slots))
     mesh = make_mesh(tp=args.tp) if args.tp and args.tp > 1 else None
     seed = args.seed if args.seed is not None else int(time.time())
     if journal is not None:

@@ -17,7 +17,7 @@ from typing import NamedTuple
 
 import numpy as np
 
-from ..models.spec import EXT_STRUCT, TransformerSpec
+from ..models.spec import MAX_HEADER_BYTES, TransformerSpec
 from ..ops.quants import (
     FloatType,
     pack_q40_bytes,
@@ -197,7 +197,7 @@ def from_kernel_layout(w: Q40Kernel) -> Q40Weight:
 def read_spec(path: str, weights_float_type=FloatType.F32,
               buffer_float_type=FloatType.F32) -> TransformerSpec:
     with open(path, "rb") as f:
-        raw = f.read(EXT_STRUCT.size)  # a 28-byte header reads its first 28
+        raw = f.read(MAX_HEADER_BYTES)  # a shorter header reads its own
     return TransformerSpec.from_header(raw, weights_float_type, buffer_float_type)
 
 
@@ -274,6 +274,9 @@ def load_model(path: str, spec: TransformerSpec | None = None,
                 params[name] = np.empty((*lead, dd, nn), dtype)
     if E:
         params["moe_gate"] = np.empty((L, E, spec.dim), np.float32)
+    gate = spec.gate_shape   # a retention layer's, after wo
+    if gate:
+        params["w_gate"] = np.empty((L, *gate), np.float32)
 
     def place(name, at, val):
         if isinstance(val, Q40Weight):
@@ -287,6 +290,8 @@ def load_model(path: str, spec: TransformerSpec | None = None,
             params[name][layer] = w.f32((n,))
         for name, shape in shapes:
             place(name, layer, w.matmul(spec, shape))
+            if gate and name == "wo":
+                params["w_gate"][layer] = w.f32(gate)
         if E:
             params["moe_gate"][layer] = w.f32((E, spec.dim))
         for e in range(E):
@@ -339,6 +344,9 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
             add(name, layer, n * 4)
         for name, shape in shapes:
             add(name, layer, spec.matmul_bytes(shape), rows=shape[0])
+            if spec.retention and name == "wo":
+                add("w_gate", layer, 4 * spec.gate_shape[0]
+                    * spec.gate_shape[1])
         if experts:
             add("moe_gate", layer, spec.n_experts * spec.dim * 4)
         for _ in range(spec.n_experts):   # expert e's three, e ascending
@@ -385,6 +393,9 @@ def write_model(path: str, spec: TransformerSpec, tensors: dict) -> None:
                     tensors[name][layer], dtype=np.float32).tobytes())
             for name, _ in spec.layer_matmul_shapes():
                 _write_matmul(f, spec, tensors[name][layer])
+                if spec.retention and name == "wo":
+                    f.write(np.ascontiguousarray(
+                        tensors["w_gate"][layer], dtype=np.float32).tobytes())
             if spec.n_experts:
                 f.write(np.ascontiguousarray(
                     tensors["moe_gate"][layer], dtype=np.float32).tobytes())

@@ -47,12 +47,32 @@ class KVCache(NamedTuple):
     v: jax.Array
 
 
-def init_cache(spec: TransformerSpec, dtype=jnp.float32) -> KVCache:
+class StateCache(NamedTuple):
+    """A retention spec's per-sequence memory: a state of fixed size a
+    layer (ops/retention.py has the layout), whatever the context."""
+    s: jax.Array  # (n_layers, [B,] n_kv, n_off, hs, hs) f32: [o, value, key]
+    z: jax.Array  # (n_layers, [B,] n_kv, n_off, hs) f32: the normaliser's
+
+
+def init_state(spec: TransformerSpec, batch: int | None = None) -> StateCache:
+    """The empty state of one sequence, or of ``batch`` rows."""
+    from ..ops.retention import state_shapes
+
+    s, z = state_shapes(spec.n_kv_heads, spec.head_size)
+    lead = (spec.n_layers,) if batch is None else (spec.n_layers, batch)
+    return StateCache(jnp.zeros(lead + s, jnp.float32),
+                      jnp.zeros(lead + z, jnp.float32))
+
+
+def init_cache(spec: TransformerSpec, dtype=jnp.float32):
+    if spec.retention:  # float32 whatever ``dtype``: nothing scales with S
+        return init_state(spec)
     shape = (spec.n_layers, spec.seq_len, spec.n_kv_heads, spec.head_size)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
-def rope_rotate(x: jax.Array, positions: jax.Array, head_size: int) -> jax.Array:
+def rope_rotate(x: jax.Array, positions: jax.Array, head_size: int,
+                theta: float = 10000.0) -> jax.Array:
     """Interleaved-pair RoPE over the leading ``x.shape[-1]`` features.
 
     x: (T, n), positions: (T,). Pair p = features (2p, 2p+1); the angle uses
@@ -62,7 +82,7 @@ def rope_rotate(x: jax.Array, positions: jax.Array, head_size: int) -> jax.Array
     pairs = x.reshape(*x.shape[:-1], n // 2, 2)
     i = jnp.arange(0, n, 2, dtype=jnp.float32)  # feature index of each pair
     head_dim = jnp.mod(i, head_size)
-    freq = 1.0 / jnp.power(jnp.float32(10000.0), head_dim / head_size)
+    freq = 1.0 / jnp.power(jnp.float32(theta), head_dim / head_size)
     val = positions[:, None].astype(jnp.float32) * freq[None, :]  # (T, n/2)
     fcr, fci = jnp.cos(val), jnp.sin(val)
     v0, v1 = pairs[..., 0], pairs[..., 1]
@@ -243,7 +263,7 @@ def _qkv_proj(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
 
     Works on (T, dim) or batched (B, T, dim) activations.
     """
-    xb = rmsnorm(x, lw["rms_att"])
+    xb = rmsnorm(x, lw["rms_att"], spec.norm_eps)
     xb = _maybe_q80(spec, xb)
     if "wqkv" in lw:  # load-time fused kernel (ops/linear.fuse_q40_layer_matmuls)
         qkv = matmul(lw["wqkv"], xb)
@@ -255,13 +275,21 @@ def _qkv_proj(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
         q = matmul(lw["wq"], xb)
         k = matmul(lw["wk"], xb)
         v = matmul(lw["wv"], xb)
-    if spec.qk_norm:
+    if spec.qk_norm_per_head:
+        # ONE gain of head size, each head normed on its own, before RoPE
+        def per_head(a, gain):
+            heads = a.reshape(*a.shape[:-1], -1, spec.head_size)
+            return rmsnorm(heads, gain, spec.norm_eps).reshape(a.shape)
+
+        q = per_head(q, lw["rms_q"])
+        k = per_head(k, lw["rms_k"])
+    elif spec.qk_norm:
         # gains over the WHOLE projection (not per head), before RoPE
-        q = rmsnorm(q, lw["rms_q"])
-        k = rmsnorm(k, lw["rms_k"])
+        q = rmsnorm(q, lw["rms_q"], spec.norm_eps)
+        k = rmsnorm(k, lw["rms_k"], spec.norm_eps)
 
     def rot(a):
-        return rope_rotate(a, positions, spec.head_size)
+        return rope_rotate(a, positions, spec.head_size, spec.rope_theta)
 
     if x.ndim == 3:
         rot_fn = jax.vmap(rot)
@@ -280,7 +308,7 @@ def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
         ao = _maybe_q80(spec, ao)
         x = x + matmul(lw["wo"], ao)
     with jax.named_scope(SCOPE_FFN):
-        xb = rmsnorm(x, lw["rms_ffn"])
+        xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
         xb = _maybe_q80(spec, xb)
         if spec.n_experts:
             from ..ops.pallas_moe import moe_ffn
@@ -338,7 +366,8 @@ def _layer(spec: TransformerSpec, x: jax.Array, lw: dict[str, Any],
 
 LAYER_KEYS = ("rms_att", "rms_ffn", "wq", "wk", "wv", "wo", "w1", "w2", "w3",
               # an expert spec's: q/k-norm gains, router, expert stacks
-              "rms_q", "rms_k", "moe_gate", "moe_w1", "moe_w2", "moe_w3")
+              "rms_q", "rms_k", "moe_gate", "moe_w1", "moe_w2", "moe_w3",
+              "w_gate")   # a retention spec's gate
 # load-time fusions (ops/linear) + the megakernel's permuted wo
 FUSED_KEYS = ("wqkv", "w13", "wo_mega", "moe_w13")
 
@@ -428,9 +457,119 @@ def _forward_fused(spec: TransformerSpec, params: dict[str, Any],
     idxs = jnp.arange(spec.n_layers, dtype=jnp.int32)
     (x_col, k_new, v_new), _ = jax.lax.scan(
         scan_body, (x_col, cache.k, cache.v), (idxs, scanned))
-    x = rmsnorm(jnp.transpose(x_col), params["rms_final"])
+    x = rmsnorm(jnp.transpose(x_col), params["rms_final"], spec.norm_eps)
     logits = matmul(params["wcls"], x)
     return logits, KVCache(k_new, v_new)
+
+
+def _log_gate(lw: dict[str, Any], x: jax.Array, eps: float) -> jax.Array:
+    """log g = log sigmoid(W_g h), h = RMSNorm(x; rms_att): (..., n_kv),
+    float32 at HIGHEST precision (the gate sets how long a state
+    remembers; it is neither quantized nor taken in bf16)."""
+    h = rmsnorm(x, lw["rms_att"], eps)
+    return jax.nn.log_sigmoid(jnp.einsum(
+        "kd,...d->...k", lw["w_gate"], h,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32))
+
+
+def _merged_state(cache: StateCache):
+    """The stacked state as the kernels index it: every leading axis
+    (layer[, row], KV head) merged into one, a bitcast."""
+    return (cache.s.reshape(-1, *cache.s.shape[-3:]),
+            cache.z.reshape(-1, *cache.z.shape[-2:]))
+
+
+def forward_retention(spec: TransformerSpec, params: dict[str, Any],
+                      cache: StateCache, tokens: jax.Array, pos: jax.Array,
+                      n_valid=None, *, norm_min: bool = False):
+    """``forward`` for a retention spec: T tokens of ONE sequence at
+    positions pos..pos+T-1 through the state (L, n_kv, n_off, hs, hs).
+    A sequence's first position finds the state empty whatever it holds
+    (``pos == 0`` resets it). T = 1 is the recurrent step; T > 1 the
+    chunked form, of whose positions the first ``n_valid`` (default all)
+    are the sequence's and the rest padding that leaves the state alone.
+    ``norm_min`` adds the (L,) smallest normaliser phi(q).z of a T = 1
+    step (inf for a chunk: its own positions are not read through z)."""
+    from ..ops import retention
+
+    t_len = tokens.shape[0]
+    if t_len == 1:     # the batched step at one row: the same kernel call
+        return forward_batch_retention(spec, params, cache, tokens,
+                                       jnp.reshape(pos, (1,)),
+                                       norm_min=norm_min)
+    pad = -t_len % retention.SUBLANES
+    if pad:
+        tokens = jnp.concatenate([tokens, jnp.zeros((pad,), tokens.dtype)])
+    n_valid = t_len if n_valid is None else jnp.minimum(n_valid, t_len)
+    positions = pos + jnp.arange(t_len + pad)
+    with jax.named_scope(SCOPE_EMBED):
+        x = params["tok_embedding"][tokens].astype(jnp.float32)
+    stacked, scanned = split_layer_weights(params)
+
+    def scan_body(carry, per_layer):
+        x, s_all, z_all = carry
+        idx, lw_slice = per_layer
+        lw = layer_view(stacked, lw_slice, idx)
+        with jax.named_scope(SCOPE_ATTN):
+            q, k, v = _qkv_proj(spec, lw, x, positions)
+            ao, s_all, z_all = retention.chunk_attention(
+                spec.head_size, spec.kv_mul, q, k, v,
+                _log_gate(lw, x, spec.norm_eps), s_all, z_all, idx, pos == 0,
+                n_valid)
+        return (_post_attention(spec, lw, x, ao), s_all, z_all), None
+
+    idxs = jnp.arange(spec.n_layers, dtype=jnp.int32)
+    (x, s_all, z_all), _ = jax.lax.scan(
+        scan_body, (x, *_merged_state(cache)), (idxs, scanned))
+    with jax.named_scope(SCOPE_LOGITS):
+        x = rmsnorm(x[:t_len], params["rms_final"], spec.norm_eps)
+        logits = matmul(params["wcls"], x)
+    cache = StateCache(s_all.reshape(cache.s.shape),
+                       z_all.reshape(cache.z.shape))
+    if norm_min:
+        return logits, cache, jnp.full((spec.n_layers,), jnp.inf)
+    return logits, cache
+
+
+def forward_batch_retention(spec: TransformerSpec, params: dict[str, Any],
+                            cache: StateCache, tokens: jax.Array,
+                            pos_vec: jax.Array, active=None, *,
+                            norm_min: bool = False):
+    """``forward_batch_ragged`` for a retention spec: one token for each of
+    B rows at its own position, against the state (L, B, n_kv, n_off, hs,
+    hs). A row at position 0 finds its state empty; a row whose ``active``
+    ((B,), nonzero = takes part; default all) is 0 rides the step and
+    leaves its state as it is. ``norm_min`` adds the (L,) smallest
+    normaliser among the active rows."""
+    from ..ops import retention
+
+    B = tokens.shape[0]
+    x = params["tok_embedding"][tokens].astype(jnp.float32)
+    positions = pos_vec if jnp.ndim(pos_vec) == 1 else jnp.full((B,),
+                                                                pos_vec)
+    live = None if active is None else active != 0
+    stacked, scanned = split_layer_weights(params)
+
+    def scan_body(carry, per_layer):
+        x, s_all, z_all = carry
+        idx, lw_slice = per_layer
+        lw = layer_view(stacked, lw_slice, idx)
+        q, k, v = _qkv_proj(spec, lw, x, positions)
+        ao, s_all, z_all, low = retention.decode_attention(
+            spec.head_size, spec.kv_mul, q, k, v,
+            _log_gate(lw, x, spec.norm_eps), s_all, z_all, idx,
+            positions == 0, live)
+        return (_post_attention(spec, lw, x, ao), s_all, z_all), low
+
+    idxs = jnp.arange(spec.n_layers, dtype=jnp.int32)
+    (x, s_all, z_all), low = jax.lax.scan(
+        scan_body, (x, *_merged_state(cache)), (idxs, scanned))
+    x = rmsnorm(x, params["rms_final"], spec.norm_eps)
+    logits = matmul(params["wcls"], x)
+    cache = StateCache(s_all.reshape(cache.s.shape),
+                       z_all.reshape(cache.z.shape))
+    return (logits, cache, low) if norm_min else (logits, cache)
 
 
 def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
@@ -440,8 +579,11 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
     Returns (logits (T, vocab) f32, updated cache). jit with spec static.
     ``moe_counts`` (expert specs only; a Python-level switch, so a dense
     spec traces the program it always did) adds a third result: the (L, E)
-    int32 count of rows routed to each expert in this dispatch.
+    int32 count of rows routed to each expert in this dispatch. A
+    retention spec takes ``forward_retention`` (every position valid).
     """
+    if spec.retention:
+        return forward_retention(spec, params, cache, tokens, pos)
     t_len = tokens.shape[0]
     if t_len == 1:
         from ..ops import pallas_layer
@@ -476,7 +618,7 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
         scan_body, (x, cache.k, cache.v), (idxs, scanned))
 
     with jax.named_scope(SCOPE_LOGITS):
-        x = rmsnorm(x, params["rms_final"])
+        x = rmsnorm(x, params["rms_final"], spec.norm_eps)
         logits = matmul(params["wcls"], x)
     if moe_counts:
         return logits, KVCache(k_new, v_new), counts
@@ -894,7 +1036,7 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
     idxs = jnp.arange(L, dtype=jnp.int32)
     (x, *kv), counts = jax.lax.scan(scan_body, (x, *planes),
                                     (idxs, scanned))
-    x = rmsnorm(x, params["rms_final"])
+    x = rmsnorm(x, params["rms_final"], spec.norm_eps)
     logits = matmul(params["wcls"], x)
     if moe_counts:
         return logits, rebuild_paged_cache(tuple(kv), L), counts
@@ -1022,7 +1164,7 @@ def forward_batch_spec_paged(spec: TransformerSpec, page_size: int,
 
     idxs = jnp.arange(L, dtype=jnp.int32)
     (x, *kv), _ = jax.lax.scan(scan_body, (x, *planes), (idxs, scanned))
-    x = rmsnorm(x, params["rms_final"])
+    x = rmsnorm(x, params["rms_final"], spec.norm_eps)
     logits = matmul(params["wcls"], x)                     # (B*K, vocab)
     return logits.reshape(B, K, -1), rebuild_paged_cache(tuple(kv), L)
 
@@ -1156,7 +1298,7 @@ def forward_batch_mixed_paged(spec: TransformerSpec, page_size: int,
 
     idxs = jnp.arange(L, dtype=jnp.int32)
     (x, *kv), _ = jax.lax.scan(scan_body, (x, *planes), (idxs, scanned))
-    x = rmsnorm(x, params["rms_final"])
+    x = rmsnorm(x, params["rms_final"], spec.norm_eps)
     logits = matmul(params["wcls"], x)                     # (B*T, vocab)
     return logits.reshape(B, T, -1), rebuild_paged_cache(tuple(kv), L)
 
@@ -1245,10 +1387,13 @@ def scatter_pages_q8(cache: PagedKVQ8, seq_cache: KVCache,
 
 
 def init_cache_batch(spec: TransformerSpec, batch: int,
-                     dtype=jnp.float32) -> KVCache:
+                     dtype=jnp.float32):
     """Batched cache: (L, B, S, n_kv, hs) — each (b, layer) row has the same
     (S, n_kv, hs) layout as the single-sequence cache (forward_batch carries
-    it as a rank-4 (L*B, S, n_kv, hs) view; see there for why)."""
+    it as a rank-4 (L*B, S, n_kv, hs) view; see there for why). A retention
+    spec's is ``init_state(spec, batch)``: nothing in it scales with S."""
+    if spec.retention:
+        return init_state(spec, batch)
     shape = (spec.n_layers, batch, spec.seq_len, spec.n_kv_heads,
              spec.head_size)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
@@ -1279,6 +1424,8 @@ def forward_batch(spec: TransformerSpec, params: dict[str, Any],
     RoPE/GQA/softmax math (batched einsums over the head-major cache —
     see init_cache_batch for why the layout differs from the B=1 path).
     """
+    if spec.retention:
+        return forward_batch_retention(spec, params, cache, tokens, pos)
     B = tokens.shape[0]
     x = params["tok_embedding"][tokens].astype(jnp.float32)  # (B, dim)
     # each row rotates at its own clock (identical under the shared one)
@@ -1310,7 +1457,7 @@ def forward_batch(spec: TransformerSpec, params: dict[str, Any],
 
     idxs = jnp.arange(L, dtype=jnp.int32)
     (x, k4, v4), _ = jax.lax.scan(scan_body, (x, k4, v4), (idxs, scanned))
-    x = rmsnorm(x, params["rms_final"])
+    x = rmsnorm(x, params["rms_final"], spec.norm_eps)
     logits = matmul(params["wcls"], x)
     return logits, KVCache(k4.reshape(L, B, S, n_kv, hs),
                            v4.reshape(L, B, S, n_kv, hs))
@@ -1373,7 +1520,7 @@ def forward_seq(spec: TransformerSpec, params: dict[str, Any],
 
     idxs = jnp.arange(spec.n_layers, dtype=jnp.int32)
     x, _ = jax.lax.scan(body, x, (idxs, scanned))
-    x = rmsnorm(x, params["rms_final"])
+    x = rmsnorm(x, params["rms_final"], spec.norm_eps)
     return matmul(params["wcls"], x)
 
 

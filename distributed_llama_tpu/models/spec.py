@@ -36,6 +36,24 @@ their defaults writes and reads the 28-byte header byte for byte as before.
 In the param tree the expert tensors are stacked as ``moe_w1`` / ``moe_w2`` /
 ``moe_w3`` (L, E, d, n), the router as ``moe_gate`` (L, E, dim) float32, the
 q/k-norm gains as ``rms_q`` (L, dim) and ``rms_k`` (L, kvDim).
+
+Extension VERSION 3 (72 bytes: the thirteen ints of version 2, one more
+and two float64) is written only by a spec that needs one of its fields, so every file
+of versions 0 (28 bytes) and 2 still reads and writes byte for byte:
+{attnKind (0 softmax, 1 power retention of degree 2), ropeTheta,
+normEps}, and ``qkNorm`` may be 2: ONE
+gain of head size shared by all heads (Qwen3's per-head q/k-norm) where 1
+is a gain over the whole projection (OLMoE's). A retention spec (every
+layer the same kind; a per-layer list of kinds can take this field's
+place) keeps a recurrent state of fixed size in place of a KV cache
+(``ops/retention.py``) and carries one more tensor a layer, the gate:
+
+  attention_norm, ffn_norm, q_norm (F32 headSize), k_norm (F32 headSize),
+  wq, wk, wv, wo                          [weightsFloatType]
+  w_gate (F32, nKvHeads x dim)
+  w1, w2, w3                              [weightsFloatType]
+
+``w_gate`` is (L, nKvHeads, dim) float32 in the param tree.
 """
 
 from __future__ import annotations
@@ -51,6 +69,10 @@ HEADER_BYTES = HEADER_STRUCT.size  # 28
 EXT_MAGIC = -0x444C4D58   # "DLMX"; a 28-byte header starts with dim > 0
 EXT_VERSION = 2
 EXT_STRUCT = struct.Struct("<13i")
+EXT3_VERSION = 3
+EXT3_STRUCT = struct.Struct("<14i2d")   # ... attnKind, theta, eps
+MAX_HEADER_BYTES = EXT3_STRUCT.size
+ATTN_KINDS = ("softmax", "retention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,8 +91,25 @@ class TransformerSpec:
     n_experts: int = 0
     n_active_experts: int = 0
     qk_norm: bool = False
+    # ONE q/k-norm gain of head size shared by all heads (Qwen3) instead of
+    # a gain over the whole projection (OLMoE); implies qk_norm
+    qk_norm_per_head: bool = False
+    # what every layer's attention is: "softmax" over a KV cache, or power
+    # "retention" (degree 2: ops/retention.py) over a recurrent state
+    attn_kind: str = "softmax"
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
 
     def __post_init__(self):
+        if self.attn_kind not in ATTN_KINDS:
+            raise ValueError(f"attn_kind={self.attn_kind!r}: expected one "
+                             f"of {ATTN_KINDS}")
+        if self.qk_norm_per_head and not self.qk_norm:
+            raise ValueError("qk_norm_per_head says how qk_norm's gains "
+                             "lie: set qk_norm too")
+        if self.retention and (self.n_experts or self.head_size % 2):
+            raise ValueError("a retention spec has a dense FFN and an even "
+                             "head size")
         if bool(self.n_experts) != bool(self.n_active_experts) or not (
                 0 <= self.n_active_experts <= self.n_experts):
             raise ValueError(
@@ -79,13 +118,27 @@ class TransformerSpec:
                 f"0 < active <= experts")
 
     @property
+    def retention(self) -> bool:
+        """Whether the layers keep a recurrent state in place of a KV cache."""
+        return self.attn_kind == "retention"
+
+    @property
+    def header_version(self) -> int:
+        """0 (the 28-byte header), 2 or 3: the lowest that holds the spec."""
+        if (self.retention or self.qk_norm_per_head
+                or self.rope_theta != 10000.0 or self.norm_eps != 1e-5):
+            return EXT3_VERSION
+        return EXT_VERSION if (self.n_experts or self.qk_norm) else 0
+
+    @property
     def extended(self) -> bool:
-        """True when the file carries the 52-byte header extension."""
-        return bool(self.n_experts or self.qk_norm)
+        """True when the file carries a header extension."""
+        return self.header_version > 0
 
     @property
     def header_bytes(self) -> int:
-        return EXT_STRUCT.size if self.extended else HEADER_BYTES
+        return {0: HEADER_BYTES, EXT_VERSION: EXT_STRUCT.size,
+                EXT3_VERSION: EXT3_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
@@ -105,16 +158,25 @@ class TransformerSpec:
     @classmethod
     def from_header(cls, raw: bytes, weights_float_type=FloatType.F32,
                     buffer_float_type=FloatType.F32) -> "TransformerSpec":
-        ext = (0, 0, 0)
+        ext, more = (0, 0, 0), {}
         if struct.unpack_from("<i", raw)[0] == EXT_MAGIC:
-            if len(raw) < EXT_STRUCT.size:
-                raise ValueError("extended header truncated")
-            _, version, count, *ints = EXT_STRUCT.unpack(
-                raw[:EXT_STRUCT.size])
-            if version != EXT_VERSION or count != 10:
+            version, count = struct.unpack_from("<2i", raw, 4)
+            layout = {(EXT_VERSION, 10): EXT_STRUCT,
+                      (EXT3_VERSION, 13): EXT3_STRUCT}.get((version, count))
+            if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
-            base, ext = ints[:7], ints[7:]
+            if len(raw) < layout.size:
+                raise ValueError("extended header truncated")
+            _, _, _, *ints = layout.unpack(raw[:layout.size])
+            base, ext = ints[:7], ints[7:10]
+            if version == EXT3_VERSION:
+                kind, theta, eps = ints[10:]
+                if not 0 <= kind < len(ATTN_KINDS):
+                    raise ValueError(f"unknown attention kind {kind}")
+                more = dict(attn_kind=ATTN_KINDS[kind],
+                            qk_norm_per_head=ext[2] == 2,
+                            rope_theta=float(theta), norm_eps=float(eps))
         else:
             base = HEADER_STRUCT.unpack(raw[:HEADER_BYTES])
         dim, hidden, n_layers, n_heads, n_kv, vocab, seq = base
@@ -123,16 +185,21 @@ class TransformerSpec:
         return cls(dim, hidden, n_layers, n_heads, n_kv, abs(vocab), seq,
                    FloatType(weights_float_type), FloatType(buffer_float_type),
                    n_experts=ext[0], n_active_experts=ext[1],
-                   qk_norm=bool(ext[2]))
+                   qk_norm=bool(ext[2]), **more)
 
     def header(self) -> bytes:
         base = (self.dim, self.hidden_dim, self.n_layers, self.n_heads,
                 self.n_kv_heads, self.vocab_size, self.seq_len)
         if not self.extended:
             return HEADER_STRUCT.pack(*base)
-        return EXT_STRUCT.pack(EXT_MAGIC, EXT_VERSION, 10, *base,
-                               self.n_experts, self.n_active_experts,
-                               int(self.qk_norm))
+        if self.header_version == EXT_VERSION:
+            return EXT_STRUCT.pack(EXT_MAGIC, EXT_VERSION, 10, *base,
+                                   self.n_experts, self.n_active_experts,
+                                   int(self.qk_norm))
+        return EXT3_STRUCT.pack(
+            EXT_MAGIC, EXT3_VERSION, 13, *base, self.n_experts,
+            self.n_active_experts, int(self.qk_norm) + self.qk_norm_per_head,
+            ATTN_KINDS.index(self.attn_kind), self.rope_theta, self.norm_eps)
 
     # -- per-tensor shapes (d, n) in file order ----------------------------
 
@@ -166,9 +233,17 @@ class TransformerSpec:
     def layer_norm_shapes(self) -> list[tuple[str, int]]:
         """One layer's float32 gain vectors, in file order."""
         norms = [("rms_att", self.dim), ("rms_ffn", self.dim)]
-        if self.qk_norm:
+        if self.qk_norm_per_head:
+            norms += [("rms_q", self.head_size), ("rms_k", self.head_size)]
+        elif self.qk_norm:
             norms += [("rms_q", self.dim), ("rms_k", self.kv_dim)]
         return norms
+
+    @property
+    def gate_shape(self) -> tuple[int, int] | None:
+        """A retention layer's ``w_gate`` (float32, after ``wo`` in the
+        file): one row a KV head. None for a softmax spec."""
+        return (self.n_kv_heads, self.dim) if self.retention else None
 
     def matmul_bytes(self, shape: tuple[int, int]) -> int:
         dd, nn = shape
@@ -182,6 +257,8 @@ class TransformerSpec:
     def block_bytes(self) -> int:
         b = sum(n * 4 for _, n in self.layer_norm_shapes())  # always F32
         b += self.n_experts * self.dim * 4                   # router, F32
+        if self.retention:
+            b += self.n_kv_heads * self.dim * 4              # w_gate, F32
         for shape, copies in self.matmul_shape_counts():
             b += copies * self.matmul_bytes(shape)
         return b

@@ -31,6 +31,13 @@ def _build_tree(spec: TransformerSpec, t, mm) -> dict:
         p[name] = mm(spec.n_layers, *shape)
     for name, shape in spec.expert_matmul_shapes():
         p[name] = mm(spec.n_layers, spec.n_experts, *shape)
+    if spec.retention:
+        # gate rows ~N(0, 1/sqrt(dim)), no bias: g = sigmoid(.) sits near
+        # 0.5, so a seeded model remembers a few tokens (a step costs the
+        # same whatever g is; tests that compare a long memory hand the
+        # kernels their gates)
+        p["w_gate"] = (t(spec.n_layers, *spec.gate_shape)
+                       * np.float32(20.0 / np.sqrt(spec.dim)))
     if spec.n_experts:
         # router rows ~N(0, 1/sqrt(dim)): t() draws at std 0.05
         p["moe_gate"] = (t(spec.n_layers, spec.n_experts, spec.dim)
@@ -241,8 +248,11 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
         for _ in range(spec.n_layers):
             for _, n in spec.layer_norm_shapes():   # rms_att, rms_ffn, ...
                 f.write(f32(n, base=1.0, scale=0.05))
-            for _, (d, n) in spec.layer_matmul_shapes():
+            for name, (d, n) in spec.layer_matmul_shapes():
                 f.write(q40(d, n))
+                if spec.retention and name == "wo":     # gate rows, F32
+                    f.write(f32(*spec.gate_shape,
+                                scale=1.0 / np.sqrt(spec.dim)))
             if spec.n_experts:                      # router rows, F32
                 f.write(f32(spec.n_experts, spec.dim,
                             scale=1.0 / np.sqrt(spec.dim)))

@@ -215,6 +215,17 @@ class EngineMetrics:
             "Rows of steps launched ahead whose result was thrown away: "
             "the row stopped on a token only the landing told, or was "
             "cancelled meanwhile")
+        # a retention spec's state (ContinuousStats.state_bytes /
+        # min_normaliser); other engines expose them flat at zero
+        self.state_bytes = g(
+            "dllama_state_bytes",
+            "Resident bytes of the slots' recurrent states (a retention "
+            "model's per-sequence memory: fixed, whatever the context)")
+        self.retention_min_normaliser = g(
+            "dllama_retention_min_normaliser",
+            "Smallest normaliser phi(q).z any decode step has read among "
+            "its active rows and layers: near zero, a state has decayed "
+            "to nothing or a first position was read by cancellation")
         # cost-ledger / scheduler-census series (ISSUE 16). The closed
         # vocabularies (token kinds, stall causes) pre-register so a
         # fresh scrape shows the full matrix at zero; per-class series

@@ -84,15 +84,16 @@ class StackedQ40(NamedTuple):
     layer: Any   # traced scalar int32
 
 
-def rms_inv(x: jax.Array) -> jax.Array:
+def rms_inv(x: jax.Array, eps: float = RMS_EPS) -> jax.Array:
     """The reference's ``rms()``: inverse RMS with eps added after the mean."""
     ss = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
-    ss = ss / x.shape[-1] + RMS_EPS
+    ss = ss / x.shape[-1] + eps
     return jax.lax.rsqrt(ss)
 
 
-def rmsnorm(x: jax.Array, weight: jax.Array) -> jax.Array:
-    return (x * rms_inv(x)) * weight
+def rmsnorm(x: jax.Array, weight: jax.Array,
+            eps: float = RMS_EPS) -> jax.Array:
+    return (x * rms_inv(x, eps)) * weight
 
 
 def silu(x: jax.Array) -> jax.Array:

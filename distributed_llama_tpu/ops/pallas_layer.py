@@ -170,8 +170,14 @@ def _plan(spec):
 
 
 def refuse_expert_spec(spec) -> None:
-    """The fused-layer kernels hold a dense SwiGLU tail: an expert spec
-    under the fusion switch stops here rather than run another program."""
+    """The fused-layer kernels hold a dense SwiGLU tail and softmax
+    attention over a KV cache: an expert spec or a retention spec under the
+    fusion switch stops here rather than run another program."""
+    if spec.retention:
+        raise ValueError(
+            f"DLLAMA_LAYER_FUSION={fusion_mode()} cannot run a "
+            f"power-retention model: the fused-layer kernels attend over a "
+            f"KV cache; unset it")
     if spec.n_experts:
         raise ValueError(
             f"DLLAMA_LAYER_FUSION={fusion_mode()} cannot run an expert "
@@ -186,6 +192,8 @@ def supports(spec, params) -> bool:
     from ..ops.quants import FloatType
 
     if spec.buffer_float_type == FloatType.Q80:
+        return False
+    if spec.norm_eps != 1e-5 or spec.qk_norm:  # the kernels' own RMSNorm
         return False
     for key in ("wqkv", "wo", "w13", "w2"):
         w = params.get(key)
@@ -869,7 +877,7 @@ def rope_freq_cols(spec) -> tuple[np.ndarray, np.ndarray]:
     the first kv_dim rows (the pattern repeats per head)."""
     v = np.arange(spec.dim, dtype=np.float32)
     head_dim = np.mod(v - np.mod(v, 2), spec.head_size)
-    freq = (1.0 / np.power(np.float32(10000.0),
+    freq = (1.0 / np.power(np.float32(spec.rope_theta),
                            head_dim / spec.head_size)).reshape(-1, 1)
     even = (np.arange(spec.dim) % 2 == 0).astype(np.float32).reshape(-1, 1)
     return freq, even

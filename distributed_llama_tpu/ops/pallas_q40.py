@@ -942,6 +942,16 @@ def _pick_rows_nb(d: int, nb: int) -> int | None:
     return None
 
 
+def _matvec_nb_params(nb: int):
+    """The nb-major matvec's compiler parameters: the default scoped VMEM
+    up to 512 blocks a row (every shape it ran before PR 31: nothing about
+    their compile changes), the raised limit beyond. Its x planes lie
+    (nb, 1), a lane-padded 512 bytes a block, 32 planes of them: at
+    nb = 544 (hidden 17408) they alone pass the 16 MiB default, which the
+    chip's compiler refuses (``RESOURCE_EXHAUSTED ... vmem``)."""
+    return _VMEM64_PARAMS if nb > 512 else None
+
+
 def _dequant_nb(qs_t, scale):
     """jnp dequant of an nb-major (16, nb, d) plane set -> f32 (d, n)."""
     lo = ((qs_t & 0xF).astype(jnp.int8) - jnp.int8(8))
@@ -971,6 +981,7 @@ def _q40_matvec_nb_2d(qs_t, scale, x, *, block_rows, interpret):
         ],
         out_specs=pl.BlockSpec((1, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        compiler_params=_matvec_nb_params(nb),
         interpret=interpret,
     )(qs_t, scale, xlo, xhi, xsum)
     return out                                        # (1, d)
@@ -999,6 +1010,7 @@ def _q40_matvec_nb_stacked(layer, qs_t, scale, x, *, block_rows, interpret):
     out = pl.pallas_call(
         _kernel_matvec_nb_stacked, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        compiler_params=_matvec_nb_params(nb),
         interpret=interpret,
     )(layer, qs_t, scale, xlo, xhi, xsum)
     return out

@@ -170,6 +170,10 @@ def param_specs(params: dict[str, Any],
         from ..ops.linear import MOE_TP_REFUSAL
 
         raise ValueError(MOE_TP_REFUSAL)  # refuse, never mis-shard an expert
+    if "w_gate" in params:
+        from ..ops.retention import TP_REFUSAL
+
+        raise ValueError(TP_REFUSAL)      # nor a retention spec's state
     specs: dict[str, Any] = {}
     for name, val in params.items():
         spec = _MATMUL_SPECS.get(name) or _REPL_SPECS.get(name)
@@ -735,6 +739,16 @@ def validate_sharding(spec: TransformerSpec, mesh: Mesh,
         from ..ops.linear import MOE_TP_REFUSAL
 
         raise ValueError(MOE_TP_REFUSAL)
+    if spec.retention:
+        from ..ops.retention import TP_REFUSAL
+
+        raise ValueError(TP_REFUSAL)
+    if spec.header_version == 3:
+        raise ValueError(
+            "the sharded forward runs RoPE base 10000, RMSNorm eps 1e-5 "
+            "and q/k-norm gains over the whole projection only: this "
+            f"spec (rope_theta {spec.rope_theta}, norm_eps {spec.norm_eps}"
+            f", per-head q/k-norm {spec.qk_norm_per_head}) runs on one chip")
     for req, name in ((spec.n_heads, "n_heads"),
                       (spec.n_kv_heads, "n_kv_heads"),
                       (spec.hidden_dim, "hidden_dim"),

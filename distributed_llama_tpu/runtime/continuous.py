@@ -190,6 +190,63 @@ def _warn_q8_xla_fallback(spec: TransformerSpec, page_size: int,
           file=sys.stderr)
 
 
+def retention_refusals(*, tp: int = 1, page_size: int = 0, kv_pages: int = 0,
+                        prefix_share: bool = False, spec_k: int = 0,
+                        dispatch_tokens: int = 0, kv_quant: str = "f32",
+                        kv_host_pages: int = 0, kv_disk_dir=None,
+                        journal: bool = False, disagg: bool = False,
+                        block_steps: int = 1,
+                        kv_cache_dtype: str = "f32") -> list[str]:
+    """What a power-retention spec cannot run, one line for each feature
+    asked for, naming its flag and the reason. A sequence's memory is a
+    state of fixed size (ops/retention.py): it has no positions to page,
+    and it can be neither shared by page, nor rolled back, nor resumed
+    part-way without a snapshot, which nothing writes yet. The engine and
+    the CLI refuse with these lines; nothing stands in for the feature."""
+    from ..ops.retention import TP_REFUSAL
+
+    why = "a retention model keeps a recurrent state of fixed size, not a "\
+          "KV cache"
+    out = []
+    if tp > 1:
+        out.append(f"--tp {tp}: {TP_REFUSAL}")
+    if page_size or kv_pages:
+        out.append(f"--kv-page-size / --kv-pages: {why}; a sequence's "
+                   f"memory is one slot of fixed size, with no positions "
+                   f"to page")
+    if prefix_share:
+        out.append(f"prefix sharing (prefix_share): {why}; a state cannot "
+                   f"be shared by page, and a radix prefix cannot be "
+                   f"resumed from without a state snapshot")
+    if spec_k:
+        out.append(f"--spec-k {spec_k}: {why}; rejected drafts roll back "
+                   f"by truncating a page table, and a state cannot be "
+                   f"rolled back without a snapshot")
+    if dispatch_tokens:
+        out.append(f"--dispatch-tokens {dispatch_tokens}: {why}; the mixed "
+                   f"window writes through per-row page tables")
+    if kv_quant != "f32":
+        out.append(f"--kv-quant {kv_quant}: {why}; q8 quantizes KV pages, "
+                   f"and the state is float32 (bfloat16 fails the "
+                   f"reference's tolerance)")
+    if kv_host_pages or kv_disk_dir:
+        out.append(f"--kv-host-pages / --kv-disk-dir: {why}; the host and "
+                   f"disk tiers spill and promote KV pages")
+    if journal:
+        out.append(f"--journal: {why}; recovery resumes a sequence "
+                   f"part-way, which needs a state snapshot")
+    if disagg:
+        out.append(f"--disagg-role: {why}; the handoff ships prefilled KV "
+                   f"pages")
+    if block_steps > 1:
+        out.append(f"--block-steps {block_steps}: {why}; the fused chain "
+                   f"masks rows by parking their writes on a scrap page")
+    if kv_cache_dtype != "f32":
+        out.append(f"--kv-cache-dtype {kv_cache_dtype}: {why}; the state "
+                   f"is float32")
+    return out
+
+
 @dataclasses.dataclass
 class _Slot:
     req: Request | None = None   # None = free
@@ -231,6 +288,7 @@ class _Flight:
     logits: Any     # (B, vocab) float32, fetched only for a temperature
     picked: Any     # (B,) int32 argmax: the greedy rows' tokens
     moe: Any        # an expert spec's (L, E) routed-rows counts, else None
+    norm_min: Any   # a retention spec's (L,) smallest normaliser, else None
     t0: float       # when the device could start it (time.monotonic)
     ahead: bool     # launched on the previous step's picks, unread
 
@@ -242,20 +300,22 @@ class _Flight:
                 if s is not None and s.req is r]
 
 
-def _with_pick(step, paged: bool, vocab: int):
+def _with_pick(step, paged: bool, vocab: int, state: bool = False):
     """``step_once``'s program around a decode forward ``step`` (logits,
     cache[, moe counts]): the row inputs arrive as ONE staged int32 block
     (B, 2[ + pages]) = [override | pos | page table], split here, and a
     row's input token is its override or, where that is -1, the previous
     step's pick, which never left the device. Beside the forward's results
     it returns ``picked``, the argmax of each row's logits (lowest index
-    on a tie, as the host's ``sample_argmax``)."""
+    on a tie, as the host's ``sample_argmax``). A ``state`` (retention)
+    engine's block is [override | pos | takes part]: a row that does not
+    leaves its state as it is."""
     def run(params, cache, prev_picked, blk):
         import jax.numpy as jnp
 
         override = blk[:, 0]
         tokens = jnp.where(override >= 0, override, prev_picked)
-        table = (blk[:, 2:],) if paged else ()
+        table = (blk[:, 2:],) if paged else (blk[:, 2],) if state else ()
         logits, cache, *moe = step(params, cache, tokens, blk[:, 1], *table)
         picked = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
         return (logits, picked, cache, *moe)
@@ -309,6 +369,12 @@ class ContinuousStats:
     # landing told, or was cancelled meanwhile)
     steps_ahead: int = 0
     rows_dropped_ahead: int = 0
+    # a retention spec: resident bytes of the slots' states, and the
+    # smallest normaliser phi(q).z any decode step read among its active
+    # rows (a state decayed to nothing, or a first position read by
+    # cancellation, shows here)
+    state_bytes: int = 0
+    min_normaliser: float = float("inf")
 
     def count_moe(self, counts) -> None:
         """One dispatch's (L, E) rows-per-expert counts."""
@@ -368,6 +434,20 @@ class ContinuousEngine:
                                     scatter_pages_q8)
 
         self.spec = spec
+        self._state = spec.retention
+        if self._state:
+            refused = retention_refusals(
+                tp=mesh.shape["tp"] if mesh is not None else 1,
+                page_size=page_size, kv_pages=kv_pages,
+                prefix_share=bool(prefix_share and page_size),
+                spec_k=spec_k, dispatch_tokens=dispatch_tokens,
+                kv_quant=kv_quant, kv_host_pages=kv_host_pages,
+                kv_disk_dir=kv_disk_dir, journal=journal is not None,
+                disagg=remote_pages, block_steps=block_steps,
+                kv_cache_dtype="f32" if cache_dtype in (
+                    None, jnp.float32) else str(cache_dtype))
+            if refused:
+                raise ValueError("; ".join(refused))
         self.slots = slots
         self.temperature = temperature
         self.topp = topp
@@ -518,12 +598,12 @@ class ContinuousEngine:
             # write sequence-cache planes (L, S, kv, hs) into row b of the
             # batched (L, B, S, kv, hs) cache, in place (the sharded case
             # is pure per-shard work: the two caches share the S/kv-head
-            # sharding axes, and the batch axis is unsharded)
-            return KVCache(
+            # sharding axes, and the batch axis is unsharded); a retention
+            # spec's (L, kv, ...) state likewise into its (L, B, kv, ...)
+            return type(cache_b)(*(
                 jax.lax.dynamic_update_slice(
-                    cache_b.k, c1.k[:, None], (0, b, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(
-                    cache_b.v, c1.v[:, None], (0, b, 0, 0, 0)))
+                    whole, one[:, None], (0, b) + (0,) * (one.ndim - 1))
+                for whole, one in zip(cache_b, c1)))
 
         sharded = mesh is not None and (mesh.shape["tp"] > 1
                                         or mesh.shape.get("sp", 1) > 1)
@@ -652,13 +732,24 @@ class ContinuousEngine:
                         donate_argnums=1))
                 decode_key = ("decode_ragged", spec)
                 decode_fwd = functools.partial(forward_batch_ragged, spec)
+                if self._state:
+                    from ..models.llama import forward_batch_retention
+
+                    # step_once's forward also takes which rows take part
+                    # and hands out the (L,) smallest normaliser
+                    decode_fwd = functools.partial(
+                        forward_batch_retention, spec, norm_min=True)
             if prefill_chunk > 1:
                 # admission prefill: single-sequence T=chunk forward into a
-                # scratch cache + plane insert
+                # scratch cache + plane insert (a retention spec's chunk
+                # is also told how many of its positions are the prompt's)
+                from ..models.llama import forward_retention
+
                 self._prefill_fwd = _shared_program(
                     ("prefill", spec, fast_prefill),
                     lambda: _maybe_bf16(
-                        functools.partial(forward, spec), fast_prefill,
+                        functools.partial(forward_retention if self._state
+                                          else forward, spec), fast_prefill,
                         jax, jit=True))
                 self._scratch_cache = lambda: init_cache(spec, dtype)
         # step_once's ONE program (``_with_pick``): logits, picked, cache[,
@@ -667,10 +758,11 @@ class ContinuousEngine:
         self._decode = _shared_program(
             decode_key, lambda: jax.jit(
                 named_program("serve_decode_step", _with_pick(
-                    decode_fwd, paged, spec.vocab_size)),
+                    decode_fwd, paged, spec.vocab_size, self._state)),
                 donate_argnums=1))
         # columns of a launch's staged block: [override | pos | page table]
-        self._blk_cols = 2 + (self._max_pages if paged else 0)
+        # (a retention engine's: [override | pos | takes part])
+        self._blk_cols = 2 + (self._max_pages if paged else 0) + self._state
         # the newest launch's picks, the next launch's ``prev_picked``; the
         # first is placed as a step's result is, or a mesh's program would
         # compile once for each of the two placements
@@ -686,8 +778,9 @@ class ContinuousEngine:
             # donate only the batched cache (updated in place); the scratch
             # sequence cache can't alias the rank-5 output
             self._insert = _shared_program(
-                ("insert",), lambda: jax.jit(
-                    named_program("serve_admit_insert", _insert),
+                ("insert", self._state), lambda: jax.jit(
+                    named_program("serve_admit_state_insert" if self._state
+                                  else "serve_admit_insert", _insert),
                     donate_argnums=0))
             if self._alloc is not None:
                 # paged prefill plumbing: gather the slot's pages into a
@@ -806,6 +899,8 @@ class ContinuousEngine:
         self._submitted = 0 if journal is None else journal.next_id
         self._chains: dict = {}  # (k, greedy_only) -> fused chain program
         self.stats = ContinuousStats()
+        if self._state:
+            self.stats.state_bytes = sum(int(a.nbytes) for a in self.cache)
         # request-cost accounting + dispatch census (ISSUE 16, obs/
         # ledger.py): always on like stats and the SLOTracker — pure
         # host bookkeeping charged once per DISPATCH, not per token; the
@@ -822,6 +917,7 @@ class ContinuousEngine:
             from ..obs.trace import EngineMetrics
 
             self._obs = EngineMetrics(metrics)
+            self._obs.state_bytes.set(self.stats.state_bytes)
             if self._alloc is not None:
                 # a fresh paged server must scrape as fully free, not as
                 # exhausted (the gauge default 0)
@@ -2241,11 +2337,13 @@ class ContinuousEngine:
                 row[0], row[1] = token, pos
                 row[2:2 + len(pages)] = pages
                 row[2 + len(pages):] = SCRAP_PAGE
+                if self._state:
+                    row[2] = rows[b] is not None
             if prev is not None and not any(r is not None for r in rows):
                 return None
             staged = self.jnp.asarray(blk)
         with host_phase("serve.dispatch"):
-            logits, picked, self.cache, *moe = self._decode(
+            logits, picked, self.cache, *more = self._decode(
                 self.params, self.cache, self._picked, staged)
         self._picked = picked
         if prev is not None:
@@ -2253,8 +2351,10 @@ class ContinuousEngine:
             if self._obs is not None:
                 self._obs.steps_ahead.inc()
         reqs = [None if s is None else s.req for s in rows]
-        return _Flight(rows, reqs, paused, logits, picked,
-                       moe[0] if moe else None, t0, prev is not None)
+        beside = more[0] if more else None  # what the spec's kind counts
+        moe, norm_min = (None, beside) if self._state else (beside, None)
+        return _Flight(rows, reqs, paused, logits, picked, moe, norm_min,
+                       t0, prev is not None)
 
     def _fetch(self, flight: _Flight):
         """Wait for ``flight`` and bring back what its rows need: the
@@ -2268,6 +2368,12 @@ class ContinuousEngine:
                 out = np.asarray(flight.logits)  # dlint: allow[D001] host sampler needs logits
             else:
                 out = np.asarray(flight.picked)  # dlint: allow[D001] four bytes a row
+            if flight.norm_min is not None:  # (L,) floats
+                low = float(np.asarray(flight.norm_min).min())  # dlint: allow[D001] normaliser counter
+                if low < self.stats.min_normaliser:
+                    self.stats.min_normaliser = low
+                    if self._obs is not None:
+                        self._obs.retention_min_normaliser.set(low)
             if flight.moe is not None:  # 4 KB beside them
                 moe = np.asarray(flight.moe)  # dlint: allow[D001] routed-rows counters
                 self.stats.count_moe(moe)
@@ -2603,16 +2709,19 @@ class ContinuousEngine:
                 else:
                     cache_box = [self._scratch_cache()]
 
-            def fwd(part, start_pos):
+            def fwd(part, start_pos, *n_valid):
                 self.stats.prefill_chunks += 1
                 with host_phase("serve.admit.prefill_chunk"):
                     _, cache_box[0] = self._prefill_fwd(
                         self.params, cache_box[0],
-                        jnp.asarray(part, jnp.int32), jnp.int32(start_pos))
+                        jnp.asarray(part, jnp.int32), jnp.int32(start_pos),
+                        *(jnp.int32(n) for n in n_valid))
 
+            # a retention spec's chunk says how many of its positions are
+            # the prompt's (``valid``): a padded one must not reach a state
             if hold is None:
                 run_chunked_prefill(fwd, tokens[start:n_pre], start, chunk,
-                                    self.spec.seq_len)
+                                    self.spec.seq_len, valid=self._state)
             else:
                 # the same window schedule, one chunk at a time, yielding
                 # at PAGE-ALIGNED chunk boundaries when hold(s) says a
@@ -2624,13 +2733,14 @@ class ContinuousEngine:
                 while lo < n_pre:
                     hi = min(lo + chunk, n_pre)
                     run_chunked_prefill(fwd, tokens[lo:hi], lo, chunk,
-                                        self.spec.seq_len)
+                                        self.spec.seq_len, valid=self._state)
                     lo = hi
                     if (lo < n_pre and lo % self.page_size == 0
                             and hold(s)):
                         end = lo
                         break
-            with host_phase("serve.admit.scatter"):
+            with host_phase("serve.admit.state_insert" if self._state
+                            else "serve.admit.scatter"):
                 if paged:
                     tbl_scatter = tbl_dev
                     if self.kv_quant == "q8":

@@ -55,6 +55,7 @@ class _Step:
     logits: Any
     picked: Any            # (1,) int32: the next step's input, never fetched
     moe: list              # [(L, E) routed-row counts] of an expert spec
+    norm_min: list         # [(L,) smallest normaliser] of a retention spec
     token: int | None = None  # input token, once the host knows it
 
 
@@ -107,6 +108,11 @@ class Engine:
         # routed (row, expert) pairs and distinct experts summed over layers
         # and ``infer`` steps (expert specs; GenStats carries them)
         self.moe_pairs = self.moe_active = 0
+        # a retention spec: the smallest normaliser phi(q).z any ``infer``
+        # step read, and the position its state stands at. A state cannot
+        # be rewound: a step anywhere else than there or at 0 is refused
+        self.min_normaliser = float("inf")
+        self._state_pos = 0
         self._ahead: _Step | None = None  # the step enqueued ahead, if any
         # steps found in flight and handed out / enqueued ahead and dropped
         self.ahead_used = self.ahead_dropped = 0
@@ -136,6 +142,14 @@ class Engine:
             # the loops and the prefill chunks keep the two-result forward
             step = (functools.partial(forward, spec, moe_counts=True)
                     if spec.n_experts else self._step_raw)
+            if spec.retention:
+                from ..models.llama import forward_retention
+
+                # likewise the (L,) smallest normaliser; the chunk program
+                # is told how many of its positions are the sequence's
+                step = functools.partial(forward_retention, spec,
+                                         norm_min=True)
+                self._step_raw = functools.partial(forward_retention, spec)
 
         # host tokens are placed as the step's own ``picked`` result is, or
         # the mesh's step program would compile once for each of the two
@@ -165,15 +179,32 @@ class Engine:
         ``tokens``: a list from the host, or a step's ``picked``."""
         if isinstance(tokens, list):
             tokens = self._put(np.array(tokens, np.int32))
-        logits, picked, self.cache, *moe = self._fwd(
+        self._state_moves(pos, int(tokens.shape[0]))
+        logits, picked, self.cache, *more = self._fwd(
             self.params, self.cache, tokens, np.int32(pos))
-        return _Step(pos, logits, picked, moe)
+        if self.spec.retention:
+            return _Step(pos, logits, picked, [], more)
+        return _Step(pos, logits, picked, more, [])
+
+    def _state_moves(self, pos: int, n: int) -> None:
+        """A retention spec's state advances by ``n`` positions from
+        ``pos``: it has to stand there, or ``pos`` is 0 (a sequence's first
+        position finds the state empty whatever it holds)."""
+        if not self.spec.retention:
+            return
+        if pos not in (0, self._state_pos):
+            raise ValueError(
+                f"a retention state cannot be rewound or skipped ahead: it "
+                f"stands at position {self._state_pos}, asked for {pos} "
+                f"(start over at position 0)")
+        self._state_pos = pos + n
 
     def drop_ahead(self) -> None:
         """Forget the step enqueued ahead, if there is one: the caller is
         not going where ``infer`` assumed (a BOS stop, a forced token, a
         jump, ``reset``). The cache slot it wrote is overwritten before
-        anything reads it (``prefill``'s invariant)."""
+        anything reads it (``prefill``'s invariant); a retention spec's
+        state HAS moved by it, and only a start at position 0 follows."""
         if self._ahead is not None:
             self._ahead = None
             self.ahead_dropped += 1
@@ -204,6 +235,10 @@ class Engine:
             if pick and not last and pos + 1 < self.spec.seq_len:
                 self._ahead = self._launch(step.picked, pos + 1)
         with host_phase("inference.fetch"):  # the wait and the transfer
+            if step.norm_min:  # (L,) floats beside the rest
+                low = np.asarray(step.norm_min[0])  # dlint: allow[D001] normaliser counter
+                self.min_normaliser = min(self.min_normaliser,
+                                          float(low.min()))
             if step.moe:  # an expert spec on one chip: 4 KB beside the rest
                 counts = np.asarray(step.moe[0])  # dlint: allow[D001] routed-rows counters
                 self.moe_pairs += int(counts.sum())
@@ -249,6 +284,20 @@ class Engine:
                 f"prefill overflow: pos0={pos0} + {len(tokens)} tokens "
                 f"> seq_len={seq_len}")
         c = min(chunk, seq_len)
+        if self.spec.retention:
+            # a state has no slot to overwrite: a padded position must not
+            # reach it, so every chunk says how many of its positions count
+            def fwd_valid(part, start, n_valid):
+                with host_phase("inference.prefill_chunk"):
+                    self._state_moves(start, n_valid)
+                    _, self.cache = self._fwd_prefill(
+                        self.params, self.cache,
+                        jnp.asarray(part, jnp.int32), jnp.int32(start),
+                        jnp.int32(n_valid))
+
+            run_chunked_prefill(fwd_valid, tokens, pos0, c, seq_len,
+                                valid=True)
+            return
         n_full = len(tokens) // c
         rest, rest_pos = tokens, pos0
         if n_full >= 2 and c > 8:
@@ -328,6 +377,7 @@ class Engine:
 
     def reset(self):
         self.drop_ahead()
+        self._state_pos = 0
         self.cache = init_cache(self.spec, self.cache_dtype)
         if self.sharded:
             from ..parallel import shard_cache
@@ -342,18 +392,22 @@ class Engine:
 
 
 def run_chunked_prefill(fwd, tokens: list[int], pos0: int, chunk: int,
-                        seq_len: int) -> None:
+                        seq_len: int, valid: bool = False) -> None:
     """The ONE fixed-chunk prefill schedule, shared by Engine.prefill and
     the continuous engine's admission prefill: full T=chunk windows, a
     zero-padded partial window when it stays inside seq_len, and a per-token
     tail when padding would cross seq_len (dynamic_update_slice would clamp
     the start and shift writes over real positions). ``fwd(part, start)``
-    runs one forward pass and owns the cache state."""
+    runs one forward pass and owns the cache state. ``valid`` (a retention
+    spec: no position of a state is a slot to clamp or overwrite) pads
+    every partial window and calls ``fwd(part, start, n_valid)``."""
     chunk = min(chunk, seq_len)
     for lo in range(0, len(tokens), chunk):
         part = tokens[lo:lo + chunk]
         start = pos0 + lo
-        if len(part) == chunk:
+        if valid:
+            fwd(part + [0] * (chunk - len(part)), start, len(part))
+        elif len(part) == chunk:
             fwd(part, start)
         elif start + chunk <= seq_len:
             fwd(part + [0] * (chunk - len(part)), start)

@@ -94,6 +94,16 @@ class OversizedRequest(ValueError):
     series, distinct from malformed-payload bad_request."""
 
 
+class _BurstHTTPServer(ThreadingHTTPServer):
+    """``ThreadingHTTPServer`` with a listen backlog that holds a burst.
+    The stdlib's is 5: when a closed loop's 32 clients connect in the same
+    instant, connections past it are reset (``ConnectionResetError`` at
+    the client, a failed request: 1 of 8 runs of a 32-client cell on the
+    chip, PR 31; 2 of 25 five-second runs, PR 29)."""
+
+    request_queue_size = 256
+
+
 class InferenceServer:
     """Owns the engine, the HTTP listener, and the scheduler thread."""
 
@@ -679,7 +689,7 @@ class InferenceServer:
                         server._streams.discard(
                             threading.current_thread())
 
-        self.httpd = ThreadingHTTPServer((host, port), Handler)
+        self.httpd = _BurstHTTPServer((host, port), Handler)
         self._threads: list[threading.Thread] = []
 
     @property
@@ -1278,10 +1288,16 @@ class InferenceServer:
                   f"🌐 served {st.tokens} tokens in {st.steps} steps "
                   f"({st.avg_active:.2f} rows a step); {st.steps_ahead} "
                   f"steps launched ahead on device-resident tokens, "
-                  f"{st.rows_dropped_ahead} rows of them dropped",
+                  f"{st.rows_dropped_ahead} rows of them dropped"
+                  + (f"; state {st.state_bytes / 2**20:.0f} MiB resident, "
+                     f"smallest normaliser {st.min_normaliser:.3g}"
+                     if st.state_bytes else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
                   sum_active=st.sum_active, steps_ahead=st.steps_ahead,
-                  rows_dropped_ahead=st.rows_dropped_ahead)
+                  rows_dropped_ahead=st.rows_dropped_ahead,
+                  state_bytes=st.state_bytes,
+                  min_normaliser=(st.min_normaliser
+                                  if st.state_bytes else None))
         if self.journal is not None:
             self.journal.close()
         try:

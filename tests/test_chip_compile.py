@@ -187,6 +187,44 @@ def _moe(kind: str, leaf: str, rows: int):
     return fn, (layer, qs_t, scale, _sd(x, jnp.float32))
 
 
+def _q40_wide_w2():
+    """The stacked nb-major matvec at hidden 17408 -> dim 5120."""
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    d, nb = 5120, 17408 // 32
+    fn = functools.partial(pq._q40_matvec_nb_stacked, interpret=False,
+                           block_rows=pq._pick_rows_nb(d, nb))
+    return fn, (_sd((1,), jnp.int32), _sd((2, 16, nb, d), jnp.uint8),
+                _sd((2, nb, d), jnp.float32), _sd((1, nb * 32), jnp.float32))
+
+
+def _retention(kind: str):
+    """The power-retention kernels (ops/retention) at Brumby-14B's widths:
+    head size 128 (65 offsets a KV head), 8 KV heads, 5 query heads a
+    state, two layers stacked. ``decode``: a step of 16 rows; ``chunk``: a
+    128-token chunk of one sequence. The state is aliased in both."""
+    from distributed_llama_tpu.ops import retention as rt
+
+    d, m, n_kv, rows, t, layers = 128, 5, 8, 16, 128, 2
+    n_off = rt.n_offsets(d)
+    f32 = functools.partial(_sd, dtype=jnp.float32)
+    layer = _sd((1,), jnp.int32)
+    if kind == "decode":
+        r = rows * n_kv
+        fn = functools.partial(rt.retention_decode_step, interpret=False)
+        return fn, (layer, f32((layers * r, n_off, d, d)),
+                    f32((layers * r, n_off, d)), f32((r, m, n_off, d)),
+                    f32((r, n_off, d)), f32((r, 8, d)))
+    mt = m * t
+    fn = functools.partial(rt.retention_prefill_chunk, interpret=False)
+    return fn, (layer, f32((layers * n_kv, n_off, d, d)),
+                f32((layers * n_kv, n_off, d)), f32((n_kv, n_off, mt, d)),
+                f32((n_kv, n_off, t, d)), f32((n_kv, mt, d)),
+                f32((n_kv, t, d)), f32((n_kv, t, d)), f32((n_kv, d, t)),
+                f32((n_kv, 8, t)), f32((n_kv, mt, t)), f32((n_kv, mt, d)),
+                f32((n_kv, 8, d)))
+
+
 # kernel=False: the dispatch documents an XLA dequantize-then-dot route for
 # that shape (nb-major serves T <= 4, the int4 planes T == 1) — the case pins
 # the routing as well as the compile
@@ -219,6 +257,14 @@ CASES = {
        (functools.partial(_moe, kind, leaf, rows), True)
        for kind, rows in (("slots", 16), ("slots", 1), ("mxu", 128))
        for leaf in ("w13", "w2")},
+    # the state read and rewritten in place (whole-head 4.3 MB blocks under
+    # a raised scoped-VMEM limit), and the chunk's float32 MXU matmuls
+    "retention-decode-B16": (functools.partial(_retention, "decode"), True),
+    "retention-chunk-T128": (functools.partial(_retention, "chunk"), True),
+    # the T=1 nb-major matvec at Brumby's w2 (544 blocks a row): was
+    # "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem" under
+    # the default scoped limit (ops/pallas_q40._matvec_nb_params)
+    "q40-nb-w2-nb544-T1": (_q40_wide_w2, True),
 }
 
 

@@ -725,3 +725,28 @@ def test_server_watch_loop_ticks_periodically(params):
     finally:
         srv.stop()
     assert srv._watch_stop.is_set()
+
+
+def test_listener_holds_a_burst_of_connections(params):
+    """A closed loop's clients connect in the same instant, before the
+    accept loop has taken one: with the stdlib's backlog of 5 the seventh
+    connection of 64 waits for a retransmitted SYN or is reset (ROADMAP
+    S10: a failed request in 1 of 8 runs of a 32-client cell)."""
+    import socket
+
+    from distributed_llama_tpu.runtime.server import InferenceServer
+
+    srv = InferenceServer(SPEC, params, _IdTokenizer(), "127.0.0.1", 0,
+                          slots=2, steps=8, temperature=0.0, topp=0.9,
+                          seed=5, quiet=True)     # listening, not accepting
+    socks = []
+    try:
+        for _ in range(64):
+            socks.append(socket.create_connection(("127.0.0.1", srv.port),
+                                                  timeout=0.5))
+    finally:
+        for s in socks:
+            s.close()
+        srv.httpd.server_close()
+        srv.engine.close()
+    assert len(socks) == 64
