@@ -205,31 +205,22 @@ MOE_TP_REFUSAL = (
     "(or any sharded mesh) refuses them")
 
 
-def nb_major_serves(rows: int) -> bool:
-    """Whether an nb-major kernel serves a decode dispatch ``rows`` wide.
-    The nb-major VPU body serves T <= 4 and the MXU body T > 8; in between
-    EVERY matmul of an nb-major leaf takes the XLA dequantize-then-dot
-    route: ``serve`` at 8 slots decoded at 75 ms/token that way against
-    37.5 d-major (my chip run, PR 21; PERF.md)."""
-    from .pallas_q40 import MULTI_T_MAX, NB_MULTI_T_MAX
-
-    return not NB_MULTI_T_MAX < rows <= MULTI_T_MAX
-
-
-def q40_leaf_layout(d: int, nb: int, *, tp: int = 1, rows: int = 1,
+def q40_leaf_layout(d: int, nb: int, *, tp: int = 1,
                     layout: Q40Layout = Q40_STOCK,
                     allow_nb_major: bool = True, key: str = "") -> str:
     """THE layout rule of one Q40 leaf: ``"nb-major"``, ``"d-major"`` or
     ``"codec"``, from its shard-LOCAL shape ``(d, nb)`` (what the kernel
     tiles inside shard_map and what each chip stores), the tensor-parallel
-    degree, the decode dispatch width and the model's resolved ``layout``.
+    degree and the model's resolved ``layout``. The width of a decode
+    dispatch is no input: every width has an nb-major kernel
+    (ops/pallas_q40._q40_matmul_nbmajor).
 
     * A routed-expert stack (``key`` ``moe_*``): nb-major where both
       grouped kernels (ops/pallas_moe) place it, else codec (the XLA scan);
       ``moe_w1`` / ``moe_w3`` are fused along d afterwards, so twice their
       width must place too.
     * Sharded (``tp > 1``): nb-major iff the local ``nb`` is off the 128
-      grid and the dispatch width has an nb-major kernel. The chip stores an
+      grid. The chip stores an
       array whose minor dim is not a multiple of 128 with the second-minor
       dim minor instead: a d-major shard ``(16, d, nb)`` then lies d-minor
       in HBM, the Pallas call wants it row-major, and XLA copies (and pads)
@@ -254,7 +245,7 @@ def q40_leaf_layout(d: int, nb: int, *, tp: int = 1, rows: int = 1,
         fused_ok = key == "moe_w2" or shape_places(2 * d, nb)
         return "nb-major" if fused_ok and shape_places(d, nb) else "codec"
     if tp > 1:
-        wants_nb = nb % 128 != 0 and nb_major_serves(rows)
+        wants_nb = nb % 128 != 0
     else:
         pad_ratio = (nb + (-nb % 128)) / nb  # lane padding of nb-minor
         wants_nb = allow_nb_major and (layout.force_nb_major
@@ -266,7 +257,7 @@ def q40_leaf_layout(d: int, nb: int, *, tp: int = 1, rows: int = 1,
 
 def pack_q40_params(params: dict, enable: bool | None = None,
                     tp: int = 1, allow_nb_major: bool = False,
-                    input_sharded=(), rows: int = 1,
+                    input_sharded=(),
                     layout: Q40Layout | None = None) -> dict:
     """Re-tile every Q40Weight in a param tree to the kernel layout, once.
 
@@ -276,8 +267,7 @@ def pack_q40_params(params: dict, enable: bool | None = None,
     shape: ``tp`` is the tensor-parallel degree the weights will be sharded
     to, ``input_sharded`` names the keys the fused tp scheme shards along
     the INPUT dim (wo/w2 — parallel/tp.py: local shape (d, n/tp) instead of
-    (d/tp, n)), ``rows`` is how many rows one decode dispatch of the
-    sharded engine carries, ``layout`` the model's resolved value
+    (d/tp, n)), ``layout`` the model's resolved value
     (engines pass theirs; a by-hand packer that passes none gets what
     ``apply_q40_body_policy`` last recorded, else the stock picks).
     ``allow_nb_major`` defaults to off: tp == 1 does not imply one chip (an
@@ -311,9 +301,8 @@ def pack_q40_params(params: dict, enable: bool | None = None,
             return v
         else:
             d_loc, n_loc = d // tp, n
-        kind = q40_leaf_layout(d_loc, n_loc // 32, tp=tp, rows=rows,
-                               layout=layout, key=k,
-                               allow_nb_major=allow_nb_major)
+        kind = q40_leaf_layout(d_loc, n_loc // 32, tp=tp, layout=layout,
+                               key=k, allow_nb_major=allow_nb_major)
         if kind == "nb-major":
             return to_kernel_layout_nb(v)
         return to_kernel_layout(v) if kind == "d-major" else v
@@ -379,11 +368,14 @@ Q40_I4_MAX_PACKED_GB = 6.0
 
 def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     """Resolve the Q40 layout and decode body of a model: a pure function
-    of the spec, the width ``rows`` of one decode dispatch (1 for plain
-    ``inference``, the slot count for ``serve`` / ``--continuous``) and the
-    kernel mode. Unpacks as (label, reason). A ``sharded`` engine (a tp or
-    sp mesh) gets the stock value: ``q40_leaf_layout`` judges each of its
-    leaves on the shard-local shape, and the i4 chain body stays off.
+    of the spec and the kernel mode. Unpacks as (label, reason). ``rows``,
+    the width of one decode dispatch (1 for plain ``inference``, the slot
+    count for ``serve`` / ``--continuous``), is what every engine and
+    driver passes and decides nothing: an nb-major leaf has a kernel at
+    every width (ops/pallas_q40._q40_matmul_nbmajor), so ``serve`` at its
+    default 8 slots packs as ``inference`` does. A ``sharded`` engine (a tp
+    or sp mesh) gets the stock value: ``q40_leaf_layout`` judges each of
+    its leaves on the shard-local shape, and the i4 chain body stays off.
 
     On the attached v5e at 7B (my chip run, PR 21; PERF.md): the fused
     chain runs 8.15 ms/token with the int4-plane body on forced nb-major
@@ -395,7 +387,6 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     ``i4-nb`` iff ALL of (else ``d-major``: the stock per-leaf picks, u8
     bodies):
       * the Pallas kernel path is active (TPU; elsewhere layouts are moot),
-      * an nb-major kernel serves the dispatch width (``nb_major_serves``),
       * every matmul leaf places on the nb-major row tiler (the i4 body is
         nb-major-only — pad-free 7B-class shapes need the forced layout),
       * the packed weights leave the conversion its headroom
@@ -407,14 +398,9 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     if q40_kernel_mode() != "pallas":
         return Q40Layout("d-major",
                          "XLA matmul path (no Pallas kernels here)")
-    from .pallas_q40 import MULTI_T_MAX, NB_MULTI_T_MAX, _pick_rows_nb
+    from .pallas_q40 import _pick_rows_nb
 
-    if not nb_major_serves(rows):
-        return Q40Layout("d-major", (
-            f"{rows}-row decode dispatches: no nb-major kernel serves T in "
-            f"{NB_MULTI_T_MAX + 1}..{MULTI_T_MAX}, d-major has the multi-T "
-            f"body"))
-
+    del rows  # every dispatch width has an nb-major kernel
     counted = spec.matmul_shape_counts()     # a layer's, experts included
     shapes = [shape for shape, _ in counted]
     shapes.append((spec.vocab_size, spec.dim))  # wcls

@@ -25,8 +25,8 @@ Two grouped matmuls, picked by the dispatch width T (static):
   the copy), never all E and never once per pair. The slot count is a
   static bound (every expert active, every capacity overflowing); the live
   count is data and the steps past it are skipped. Body: ``_slot_body``,
-  the nb-major small-T VPU body of ops/pallas_q40 (``_matvec_body_multi_nb``)
-  with a slot's input planes packed on the lanes.
+  the nb-major small-T VPU body (one accumulator a row) with a slot's
+  input planes packed on the lanes.
 * larger T (prefill chunks: every expert is hit): every expert runs every
   row through ``_mxu_body_merged``, the nb-major MXU body of ops/pallas_q40
   (``_matmul_body_nb``) with the nibble planes merged into the contraction,
@@ -48,10 +48,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
 from .linear import StackedQ40, matmul, matmul_mode, silu
-from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NB_MULTI_T_MAX,
-                         NJ, _pick_block_t, _pick_rows_nb)
+from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ,
+                         _pick_block_t, _pick_rows_nb)
 
-MOE_SLOT_ROWS = NB_MULTI_T_MAX   # rows one slot carries: the VPU body's cap
+MOE_SLOT_ROWS = 4                # rows one slot carries: the VPU body's cap
 MOE_SLOT_T_MAX = 32              # wider dispatches take the MXU body
 
 
@@ -108,9 +108,9 @@ def build_slots(topi: jax.Array, n_experts: int, cap: int):
 # -- the slot kernel (VPU body) -------------------------------------------------
 
 def _slot_body(qs_ref, s, xp_ref, out_ref, c: int):
-    """``_matvec_body_multi_nb`` (ops/pallas_q40: unpack each nibble plane
-    once, one (nb, R) accumulator a row, sublane reduction, the -8 folded
-    into one xsum term) with the rows' input planes PACKED ON THE LANES:
+    """The nb-major small-T VPU body (unpack each nibble plane once, one
+    (nb, R) accumulator a row, sublane reduction, the -8 folded into one
+    xsum term) with the rows' input planes PACKED ON THE LANES:
     ``xp_ref`` (nb, W) holds row ti's block b as 32 consecutive columns from
     32 * ti (value j under the low nibbles at + j, under the high ones at
     + 16 + j) and its block sum at 32 * c + ti. The 2-D kernels' (NJ, nb, t) planes put t

@@ -194,45 +194,14 @@ def _kernel_matvec_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
                     out_ref)
 
 
-def _matvec_body_multi_nb(qs3, s, xlo_ref, xhi_ref, xsum_ref, out_ref):
-    """Small-T (2..8) nb-major body: qs3 (NJ, nb, R), s (nb, R), xlo/xhi
-    (NJ, nb, T), xsum (nb, T); out (T, R). The d-major multi body
-    transposed: unpack once per plane, one accumulator per batch row,
-    sublane reduction."""
-    t = xlo_ref.shape[2]
-    accs = [None] * t
-    for j in range(NJ):
-        q = qs3[j].astype(jnp.int32)                 # (nb, R)
-        wlo = (q & 0xF).astype(jnp.float32)
-        whi = (q >> 4).astype(jnp.float32)
-        for ti in range(t):
-            a = (wlo * xlo_ref[j, :, ti][:, None]
-                 + whi * xhi_ref[j, :, ti][:, None])
-            accs[ti] = a if accs[ti] is None else accs[ti] + a
-    rows = []
-    for ti in range(t):
-        acc = accs[ti] - 8.0 * xsum_ref[:, ti][:, None]
-        rows.append(jnp.sum(acc * s, axis=0, keepdims=True))   # (1, R)
-    out_ref[...] = jnp.concatenate(rows, axis=0)               # (T, R)
-
-
-def _kernel_multi_nb(qs_ref, scale_ref, xlo_ref, xhi_ref, xsum_ref, out_ref):
-    _matvec_body_multi_nb(qs_ref, scale_ref[...], xlo_ref, xhi_ref, xsum_ref,
-                          out_ref)
-
-
-def _kernel_multi_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
-                             xsum_ref, out_ref):
-    del layer_ref
-    _matvec_body_multi_nb(qs_ref[0], scale_ref[0], xlo_ref, xhi_ref,
-                          xsum_ref, out_ref)
-
-
 def _matmul_body_nb(qs3, s, xlo_ref, xhi_ref, out_ref, bf16=False):
-    """T>8 MXU body, nb-major: qs3 (NJ, nb, R), s (nb, R), xlo/xhi
+    """T>1 MXU body, nb-major: qs3 (NJ, nb, R), s (nb, R), xlo/xhi
     (NJ, bt, nb); out (bt, R). The contraction is a STANDARD (M,K)x(K,N)
     dot (x rows x nb against weights nb x R) — no minor-dim contraction
-    gymnastics; bf16 as in _matmul_body."""
+    gymnastics; bf16 as in _matmul_body. Its cost hardly depends on bt
+    under 128 rows (the unpack and the MXU's weight loads do not): on a v5e
+    an 8-row tile streams Mistral-7B's leaves at 265-275 GB/s, a 16-row
+    one at 240-255 (my chip run, PR 32; PERF.md section 7)."""
     dn = (((1,), (0,)), ((), ()))
     wdt = jnp.bfloat16 if bf16 else jnp.float32
     prec = None if bf16 else jax.lax.Precision.HIGHEST
@@ -262,12 +231,17 @@ def _kernel_mxu_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
     _matmul_body_nb(qs_ref[0], scale_ref[0], xlo_ref, xhi_ref, out_ref, bf16)
 
 
-MULTI_T_MAX = 8  # beyond this the per-row accumulators crowd VMEM; use MXU
-# the nb-major VPU multi body stops earlier (see _q40_matmul_nbmajor): T in
-# NB_MULTI_T_MAX+1..MULTI_T_MAX has NO nb-major kernel and takes the XLA
-# dequantize-then-dot route — ops/linear.q40_body_policy keeps dispatches of
-# that width off the nb-major layout
-NB_MULTI_T_MAX = 4
+# Where the bodies meet, chosen by T alone (what a dispatch observes).
+# d-major leaves: the VPU multi body (one accumulator a row) up to
+# MULTI_T_MAX rows, the MXU body beyond, where the per-row accumulators
+# crowd VMEM. nb-major leaves: the matvec at T = 1 and the MXU body
+# (_matmul_body_nb) for every T > 1, rows padded to a multiple of 8, so a
+# 2..8-row decode dispatch is ONE 8-row tile of the body a 16-row dispatch
+# and a prefill chunk run (_q40_matmul_nbmajor). An nb-major VPU multi body
+# served T = 2..4 until PR 32 and overflowed scoped VMEM at 8; on the chip
+# the MXU tile beat it at 3 and 4 rows on every 7B leaf (by 24-76 %) and at
+# 2 rows over a layer's four leaves (by 8 %), so it went (PERF.md section 7).
+MULTI_T_MAX = 8
 
 # Raised scoped-VMEM limit for the T>1 kernels (MXU prefill bodies, the
 # unpack-once scratch kernels, and the T<=8 VPU multi bodies batched decode
@@ -1016,64 +990,6 @@ def _q40_matvec_nb_stacked(layer, qs_t, scale, x, *, block_rows, interpret):
     return out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "interpret"))
-def _q40_multi_nb_2d(qs_t, scale, x, *, block_rows, interpret):
-    _, nb, d = qs_t.shape
-    t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)   # (NJ, t, nb)
-    xlo = jnp.transpose(xlo, (0, 2, 1))              # (NJ, nb, t)
-    xhi = jnp.transpose(xhi, (0, 2, 1))
-    xsum = jnp.sum(xlo + xhi, axis=0)                # (nb, t)
-    out = pl.pallas_call(
-        _kernel_multi_nb,
-        grid=(d // block_rows,),
-        in_specs=[
-            pl.BlockSpec((NJ, nb, block_rows), lambda i: (0, 0, i)),
-            pl.BlockSpec((nb, block_rows), lambda i: (0, i)),
-            pl.BlockSpec((NJ, nb, t), lambda i: (0, 0, 0)),
-            pl.BlockSpec((NJ, nb, t), lambda i: (0, 0, 0)),
-            pl.BlockSpec((nb, t), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((t, block_rows), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        # 13B batch shapes (wqkv d=15360 at t=2) measure 16.9M of scoped
-        # stack against the 16M default — raise like the MXU kernels
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(qs_t, scale, xlo, xhi, xsum)
-    return out                                        # (t, d)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "interpret"))
-def _q40_multi_nb_stacked(layer, qs_t, scale, x, *, block_rows, interpret):
-    _, _, nb, d = qs_t.shape
-    t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
-    xlo = jnp.transpose(xlo, (0, 2, 1))
-    xhi = jnp.transpose(xhi, (0, 2, 1))
-    xsum = jnp.sum(xlo + xhi, axis=0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(d // block_rows,),
-        in_specs=[
-            pl.BlockSpec((1, NJ, nb, block_rows),
-                         lambda i, L: (L[0], 0, 0, i)),
-            pl.BlockSpec((1, nb, block_rows), lambda i, L: (L[0], 0, i)),
-            pl.BlockSpec((NJ, nb, t), lambda i, L: (0, 0, 0)),
-            pl.BlockSpec((NJ, nb, t), lambda i, L: (0, 0, 0)),
-            pl.BlockSpec((nb, t), lambda i, L: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((t, block_rows), lambda i, L: (0, i)),
-    )
-    return pl.pallas_call(
-        _kernel_multi_nb_stacked, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        compiler_params=_VMEM64_PARAMS, interpret=interpret,
-    )(layer, qs_t, scale, xlo, xhi, xsum)
-
-
 def _kernel_scratch_nb(qs_ref, scale_ref, xlo_ref, xhi_ref, out_ref,
                        wlo_ref, whi_ref, *, bf16=False):
     _matmul_body_scratch(qs_ref, scale_ref[...], xlo_ref, xhi_ref,
@@ -1206,9 +1122,13 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                         interpret: bool | None,
                         layer: jax.Array | None,
                         block_rows: int | None = None) -> jax.Array:
-    """nb-major dispatch, all T regimes on kernels (T=1 matvec, 2..8 VPU
-    multi, >8 MXU with the standard (M,K)x(K,N) dot); the dequant fallback
-    remains only for tilings the rules can't place.
+    """nb-major dispatch: every T on a kernel, the body picked by T alone.
+    T = 1 the matvec, anything wider the MXU body with the standard
+    (M,K)x(K,N) dot (float32 at HIGHEST unless the caller traced under bf16
+    precision): rows are padded to a multiple of 8, so a 2..8-row decode
+    dispatch is ONE 8-row t-tile of the body a 16-row dispatch and a prefill
+    chunk run. The dequantize-then-dot fallback remains only for a ``d`` the
+    row tiler cannot place.
 
     ``block_rows`` overrides the auto-picked row tile (q40_matmul's tuning
     knob, plumbed through for nb-major too). Lane-riding rows must be a
@@ -1221,12 +1141,15 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     t = x2.shape[0]
-    if t > MULTI_T_MAX and t % 8 != 0:
+    if t > 1 and t % 8 != 0:
         pad = (-t) % 8
         out = _q40_matmul_nbmajor(w, jnp.pad(x2, ((0, pad), (0, 0))),
                                   interpret, layer, block_rows)
         return out[:t].reshape(*lead, d)
-    if t > MULTI_T_MAX and _prefill_matmul_mode() == "dequant":
+    # the prefill-ladder arms (DLLAMA_PREFILL_MATMUL) are about chunks:
+    # they never took a decode dispatch of up to MULTI_T_MAX rows
+    prefill = t > MULTI_T_MAX
+    if prefill and _prefill_matmul_mode() == "dequant":
         # prefill-ladder experiment arm — see q40_matmul
         if layer is not None:
             qs_t = qs_t[layer]
@@ -1249,27 +1172,14 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                              if d % r == 0), rows)
     else:
         rows = _pick_rows_nb(d, nb)
-    if rows is not None and 1 < t <= MULTI_T_MAX:
-        # the multi body carries t (nb, rows) f32 accumulators plus 16*t
-        # unrolled broadcast temporaries; measured on v5e: t=4/rows=256
-        # compiles, t=8 overflows scoped VMEM even at rows=128 — so the
-        # kernel serves t <= NB_MULTI_T_MAX and 5..8 take the dequant
-        # fallback below
-        if t > NB_MULTI_T_MAX:
-            rows = None
-        else:
-            cap = max(128, 300_000 // (t * nb))
-            rows = next((r for r in
-                         range(min(rows, cap - cap % 128), 0, -128)
-                         if d % r == 0), None)
-    if rows is not None and t > MULTI_T_MAX:
+    block_t = _pick_block_t(t, nb)
+    if rows is not None and t > 1:
         # the MXU body's f32 wlo/whi temporaries obey the same measured
         # rows*nb boundary as the d-major path (_MATMUL_ROWSXNB_CAP);
         # _pick_rows_nb's matvec budget is looser, so re-cap here
         cap = _MATMUL_ROWSXNB_CAP // nb
         rows = next((r for r in range(min(rows, cap - cap % 128), 0, -128)
                      if d % r == 0), None)
-        block_t = _pick_block_t(t, nb)
         if rows is not None and block_t < 128 and rows > 256:
             # same Mosaic small-t-tile VMEM behavior as the d-major MXU
             # path: shrink the row tile (see _pick_block_rows)
@@ -1278,36 +1188,27 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
         from .linear import matmul_mode
 
         bf16 = matmul_mode() == "bf16"
-        scratch = t > MULTI_T_MAX and _prefill_matmul_mode() == "scratch"
+        scratch = prefill and _prefill_matmul_mode() == "scratch"
         if layer is not None:
             lidx = jnp.asarray(layer, dtype=jnp.int32).reshape(1)
             if t == 1:
                 out = _q40_matvec_nb_stacked(lidx, qs_t, scale, x2,
                                              block_rows=rows,
                                              interpret=interpret)
-            elif t <= MULTI_T_MAX:
-                out = _q40_multi_nb_stacked(lidx, qs_t, scale, x2,
-                                            block_rows=rows,
-                                            interpret=interpret)
             else:
                 call = (_q40_mxu_nb_stacked_scratch if scratch
                         else _q40_mxu_nb_stacked)
                 out = call(lidx, qs_t, scale, x2, block_rows=rows,
-                           block_t=_pick_block_t(t, nb),
-                           interpret=interpret, bf16=bf16)
+                           block_t=block_t, interpret=interpret, bf16=bf16)
         else:
             if t == 1:
                 out = _q40_matvec_nb_2d(qs_t, scale, x2, block_rows=rows,
                                         interpret=interpret)
-            elif t <= MULTI_T_MAX:
-                out = _q40_multi_nb_2d(qs_t, scale, x2, block_rows=rows,
-                                       interpret=interpret)
             else:
                 call = (_q40_mxu_nb_2d_scratch if scratch
                         else _q40_mxu_nb_2d)
                 out = call(qs_t, scale, x2, block_rows=rows,
-                           block_t=_pick_block_t(t, nb),
-                           interpret=interpret, bf16=bf16)
+                           block_t=block_t, interpret=interpret, bf16=bf16)
         return out.reshape(*lead, d)
     if layer is not None:
         qs_t = qs_t[layer]

@@ -248,15 +248,14 @@ _CUT_THREAD_BYTES = 1 << 26
 
 
 def shard_params(params: dict[str, Any], mesh: Mesh,
-                 scheme: str | None = None, rows: int = 1) -> dict[str, Any]:
+                 scheme: str | None = None) -> dict[str, Any]:
     """Place the param tree with the active scheme's shardings (ref:
     MatmulSlice output-dim bands everywhere; fused: wo/w2 input-dim bands).
 
     Q40 weights are re-tiled to the Pallas kernel layout first (host side,
-    once) when the Q40 fast path is active; ``rows`` is the width of the
-    caller's decode dispatch, which the layout rule reads
-    (ops/linear.q40_leaf_layout). Placement goes through
-    ``make_array_from_callback``, not ``device_put``: each process
+    once) when the Q40 fast path is active, each leaf laid out by
+    ops/linear.q40_leaf_layout on its shard-local shape. Placement goes
+    through ``make_array_from_callback``, not ``device_put``: each process
     materializes ONLY its addressable shards (a multi-host device_put both
     asserts bitwise-equal full values on every host — which slice-streamed
     weights deliberately violate, their unfetched bands being zeros — and
@@ -283,7 +282,7 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
                     f"{v.qs.shape[-2]} Q40 blocks do not divide over "
                     f"tp={n_tp} (need input_dim/tp to be a 32-multiple)")
     params = pack_q40_params(
-        params, tp=n_tp, rows=rows,
+        params, tp=n_tp,
         input_sharded=(FUSED_INPUT_SHARDED
                        if scheme in _INPUT_SHARDED_SCHEMES else ()))
     layouts = {label: [k for k, v in params.items() if isinstance(v, kind)]
@@ -295,9 +294,9 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
         # change would make runs incomparable
         picks = "; ".join(f"{label}: {' '.join(keys)}"
                           for label, keys in layouts.items() if keys)
-        print(f"💡 Q40 sharded layout: {picks} (tp={n_tp} {scheme}, "
-              f"{rows}-row decode dispatches; a shard-local block count "
-              f"off the 128 grid packs nb-major)", file=sys.stderr)
+        print(f"💡 Q40 sharded layout: {picks} (tp={n_tp} {scheme}; a "
+              f"shard-local block count off the 128 grid packs nb-major)",
+              file=sys.stderr)
     specs = param_specs(params, scheme)
     threads = min(16, os.cpu_count() or 1)
 

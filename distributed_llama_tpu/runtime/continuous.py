@@ -628,8 +628,7 @@ class ContinuousEngine:
             scheme = tp_scheme()  # one resolution: decode + prefill +
             #                       params all run the same schedule
             validate_sharding(spec, mesh)
-            self.params = shard_params(params, mesh, scheme=scheme,
-                                       rows=slots)
+            self.params = shard_params(params, mesh, scheme=scheme)
             if self._alloc is not None:
                 # +1 physical page: the reserved scrap page 0
                 self._step = _shared_program(

@@ -665,7 +665,7 @@ def generate_batch(spec: TransformerSpec, params: dict[str, Any],
 
         scheme = tp_scheme()  # one resolution for program + params
         validate_sharding(spec, mesh)
-        dev_params = shard_params(params, mesh, scheme=scheme, rows=B)
+        dev_params = shard_params(params, mesh, scheme=scheme)
         cache0 = shard_cache_batch(init_cache_batch(spec, B, dtype), mesh)
         step_fn = make_sharded_forward_batch(spec, mesh, scheme=scheme)
         run = make_batch_decode_loop(spec, steps, temperature, topp,

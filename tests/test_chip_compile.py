@@ -37,7 +37,10 @@ N_KV, HS = 32, 128
 # (d, n) of the 7B matmul leaves as the single-chip tree holds them
 # (ops/linear.fuse_q40_layer_matmuls: wq stands for the d=4096 leaves)
 LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
-          "wcls": (VOCAB, DIM)}
+          "wcls": (VOCAB, DIM),
+          # Mistral-7B's FFN (hidden 14336: w2 has 448 blocks a row), the
+          # leaves the two 8-slot serving cells stream
+          "m-w13": (2 * 14336, DIM), "m-w2": (DIM, 14336)}
 
 
 def _sd(shape, dtype):
@@ -226,14 +229,22 @@ def _retention(kind: str):
 
 
 # kernel=False: the dispatch documents an XLA dequantize-then-dot route for
-# that shape (nb-major serves T <= 4, the int4 planes T == 1) — the case pins
-# the routing as well as the compile
+# that shape (the int4 planes serve T == 1 only) — the case pins the routing
+# as well as the compile. An nb-major leaf has a kernel at every T: from 2
+# rows to 8 (``serve``'s default 8 slots) it is the MXU body at ``block_t``
+# 8, whose small-t-tile row rule (256 rows) keeps it inside scoped VMEM
+_Q40_LEAVES = ("wq", "w13", "w2", "wcls")
 CASES = {
     **{f"q40-{layout}-{leaf}-T{t}":
        (functools.partial(_q40, layout, leaf, t),
-        layout == "d" or t == 1)
-       for layout in ("d", "nb", "i4") for leaf in LEAVES for t in (1, 8)},
+        layout != "i4" or t == 1)
+       for layout in ("d", "nb", "i4") for leaf in _Q40_LEAVES
+       for t in (1, 8)},
     "q40-nb-wq-T4": (functools.partial(_q40, "nb", "wq", 4), True),
+    "q40-nb-m-w13-T8": (functools.partial(_q40, "nb", "m-w13", 8), True),
+    "q40-nb-m-w2-T8": (functools.partial(_q40, "nb", "m-w2", 8), True),
+    # a 5-row dispatch pads to the same 8-row tile
+    "q40-nb-m-w2-T5": (functools.partial(_q40, "nb", "m-w2", 5), True),
     "decode-f32": (functools.partial(_decode, jnp.float32), True),
     "decode-bf16": (functools.partial(_decode, jnp.bfloat16), True),
     "decode-batch-f32": (_decode_batch, True),

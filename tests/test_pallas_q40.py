@@ -332,9 +332,8 @@ def test_nbmajor_matvec_matches_dequant():
     got = q40_matmul(wn, x, interpret=True)
     np.testing.assert_allclose(np.asarray(got), want.T, rtol=1e-4, atol=1e-3)
 
-    # the full dispatch ladder: T=2/4 (VPU multi-nb kernel — T=5..8 take
-    # the dequant fallback, whose scoped-VMEM footprint was measured to
-    # overflow), T=6 (that fallback), T=16 (MXU body), T=13 (pads to 16)
+    # the full dispatch ladder: T=2/4/6 (pad to one 8-row tile of the MXU
+    # body), T=16 (MXU body), T=13 (pads to 16)
     wd = dequantize_q40(np.asarray(w.qs), np.asarray(w.d16))
     for t in (2, 4, 6, 16, 13):
         xt = np.random.default_rng(t).standard_normal((t, 5120)).astype(
@@ -395,6 +394,76 @@ def test_nbmajor_pack_selection_and_forward_parity(monkeypatch):
     # callers: an sp>1 mesh packs with tp=1 but cannot carry Q40KernelNb)
     psh = pack_q40_params({"w2": _mk(128, 1280)})  # nb=40: 3.2x ratio
     assert isinstance(psh["w2"], Q40Kernel)
+
+
+def _nb_leaf(d, n, stacked, seed):
+    """An nb-major leaf (2-D, or three layers stacked) and its dense
+    float32 layers."""
+    from distributed_llama_tpu.io.loader import (Q40KernelNb,
+                                                 to_kernel_layout_nb)
+
+    ws = [_mk(d, n, seed=seed + i) for i in range(3 if stacked else 1)]
+    dense = [dequantize_q40(np.asarray(w.qs), np.asarray(w.d16)) for w in ws]
+    ks = [to_kernel_layout_nb(w) for w in ws]
+    if not stacked:
+        return ks[0], dense
+    return Q40KernelNb(np.stack([np.asarray(k.qs_t) for k in ks]),
+                       np.stack([np.asarray(k.scale) for k in ks])), dense
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["2d", "stacked"])
+@pytest.mark.parametrize("n", [4096, 5120], ids=["nb128", "nb160"])
+@pytest.mark.parametrize("t", [3, 5, 6, 7, 8])
+def test_nbmajor_up_to_8_rows_match_dequant_dot(t, n, stacked):
+    """A dispatch of up to 8 rows on an nb-major leaf (``serve`` at its
+    default 8 slots) pads to one 8-row tile of the MXU body: float32 parity
+    with the dequantize-then-dot reference, 2-D and stacked (the layer
+    scan's scalar-prefetch form), at a block count on the 128 grid and one
+    off it."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_q40 import q40_matmul
+
+    d = 256
+    w, dense = _nb_leaf(d, n, stacked, seed=40)
+    x = np.random.default_rng(t).standard_normal((t, n)).astype(np.float32)
+    for layer, wd in enumerate(dense):
+        got = q40_matmul(w, jnp.asarray(x), interpret=True,
+                         layer=jnp.int32(layer) if stacked else None)
+        assert got.shape == (t, d)
+        np.testing.assert_allclose(np.asarray(got), (wd @ x.T).T,
+                                   rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["2d", "stacked"])
+@pytest.mark.parametrize("t", [*range(1, 17), 128])
+def test_nbmajor_every_width_reaches_a_pallas_call(t, stacked):
+    """No silent XLA fallback: for every dispatch width an nb-major leaf the
+    row tiler places lowers to a Pallas call (T = 1 the matvec, wider the
+    MXU body), and one it cannot place (d not a multiple of 128) to none."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.io.loader import Q40KernelNb
+    from distributed_llama_tpu.ops.pallas_q40 import q40_matmul
+
+    def calls(d, nb):
+        lead = (2,) if stacked else ()
+        w = Q40KernelNb(
+            jax.ShapeDtypeStruct((*lead, 16, nb, d), jnp.uint8),
+            jax.ShapeDtypeStruct((*lead, nb, d), jnp.float32))
+        jaxpr = jax.make_jaxpr(lambda w, x, layer: q40_matmul(
+            w, x, interpret=True, layer=layer if stacked else None))(
+                w, jax.ShapeDtypeStruct((t, nb * 32), jnp.float32),
+                jax.ShapeDtypeStruct((), jnp.int32))
+        text = str(jaxpr)
+        return [name for name in ("_q40_matvec_nb", "_q40_mxu_nb")
+                if name in text]
+
+    want = "_q40_matvec_nb" if t == 1 else "_q40_mxu_nb"
+    for nb in (128, 160):
+        assert calls(256, nb) == [want], (t, nb)
+    assert calls(192, 160) == []       # no 128-multiple divides d
 
 
 @pytest.mark.parametrize("layout", ["d_major", "nb_major"])

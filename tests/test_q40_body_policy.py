@@ -46,22 +46,19 @@ def test_auto_picks_i4_nb_for_7b_on_pallas(monkeypatch):
     assert not (Q40_STOCK.force_nb_major or Q40_STOCK.i4_chain)
 
 
-@pytest.mark.parametrize("rows,want", [(1, "i4-nb"), (4, "i4-nb"),
-                                       (5, "d-major"), (8, "d-major"),
-                                       (16, "i4-nb")])
-def test_auto_keeps_5_to_8_row_dispatches_off_nb_major(monkeypatch, rows,
-                                                       want):
-    """nb-major has a VPU body for T <= 4 and an MXU body for T > 8; a
-    5..8-row decode dispatch (serve's default 8 slots) would take the XLA
-    dequantize-then-dot route for every matmul — measured 75 vs 37.5
-    ms/token on the chip (PERF.md, PR 21)."""
+@pytest.mark.parametrize("rows", [1, 4, 5, 8, 16])
+def test_auto_packs_every_dispatch_width_nb_major(monkeypatch, rows):
+    """Every width has an nb-major kernel since PR 32 (the matvec at one
+    row, the MXU body beyond: a 2..8-row dispatch is one 8-row tile), so the
+    width no longer keeps a tree d-major. Until then 5 and 8 rows (``serve``
+    at its default 8 slots) resolved ``d-major``: 36 ms a step against the
+    MXU body's 18.7 on the chip (PERF.md, PR 32)."""
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
     layout = q40_body_policy(llama2_7b_spec(), rows=rows)
-    assert layout.label == want, layout.reason
-    assert layout.force_nb_major == layout.i4_chain == (want == "i4-nb")
-    assert linear.nb_major_serves(rows) == (want == "i4-nb")
-    if want == "d-major":
-        assert f"{rows}-row" in layout.reason
+    assert layout.label == "i4-nb", layout.reason
+    assert layout.force_nb_major and layout.i4_chain
+    assert "row" not in layout.reason
+    assert layout == q40_body_policy(llama2_7b_spec())
 
 
 def test_auto_declines_13b_on_memory_headroom(monkeypatch):
@@ -89,8 +86,8 @@ def test_auto_declines_off_pallas():
 
 # a width at which the stock picks and the forced layout differ (nb 128
 # pads nothing, so only a layout that forces it packs nb-major) and every
-# leaf places on the nb-major row tiler: q40_body_policy gives i4-nb at one
-# row and d-major at eight
+# leaf places on the nb-major row tiler: q40_body_policy gives i4-nb at
+# every dispatch width
 WIDE = dict(dim=4096, hidden_dim=4096, n_layers=1, n_heads=32,
             n_kv_heads=32, vocab_size=256, seq_len=32,
             weights_float_type=FloatType.Q40)
@@ -149,26 +146,26 @@ def test_apply_returns_label_notes_and_leaves_env_alone(monkeypatch, capsys):
 
 def test_apply_twice_the_second_stands(monkeypatch, capsys):
     """Overwritten, not first-wins: a by-hand packer after the second call
-    packs the second call's layout."""
+    packs the second call's layout (here a model past the headroom gate
+    after one under it)."""
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
     spec, tree = _wide()
     assert apply_q40_body_policy(spec, rows=1) == "i4-nb"
     assert _kinds(pack_q40_params(tree, allow_nb_major=True)) == {Q40KernelNb}
-    assert apply_q40_body_policy(spec, rows=8) == "d-major"
+    assert apply_q40_body_policy(llama2_13b_spec(), rows=8) == "d-major"
     assert linear._APPLIED_LAYOUT.label == "d-major"
     assert _kinds(pack_q40_params(tree, allow_nb_major=True)) == {Q40Kernel}
-    assert "8-row" in capsys.readouterr().err
+    assert "headroom" in capsys.readouterr().err
 
 
 # ---- new with ISSUE 29 ----------------------------------------------------
 
 def test_two_engines_of_two_widths_each_get_their_own_layout(monkeypatch):
     """One process, ``inference`` then ``serve`` at 8 slots, each preceded
-    by ``apply_q40_body_policy`` as the benchmark's drivers call it: the
-    one-row engine packs forced nb-major, the eight-row engine d-major (no
-    nb-major kernel serves 5..8 rows). Under the environment's
-    ``setdefault`` the first call won for both (75 against 37.5 ms/token
-    at 8 rows, PERF.md PR 21)."""
+    by ``apply_q40_body_policy`` as the benchmark's drivers call it: each
+    engine resolves its own value from its own spec and width, and since
+    PR 32 (an nb-major kernel at every width) both pack forced nb-major.
+    A layout handed to the eight-row engine still wins over its own."""
     from distributed_llama_tpu.runtime.continuous import ContinuousEngine
     from distributed_llama_tpu.runtime.generate import Engine
 
@@ -179,13 +176,15 @@ def test_two_engines_of_two_widths_each_get_their_own_layout(monkeypatch):
     apply_q40_body_policy(spec, rows=8)
     eight = ContinuousEngine(spec, tree, 8, 0.0, 0.9, seed=1)
     assert _kinds(one.params) == {Q40KernelNb}
-    assert _kinds(eight.params) == {Q40Kernel}
+    assert _kinds(eight.params) == {Q40KernelNb}
     # and without the calls: engines resolve the same values themselves
     assert Engine(spec, tree).q40_layout == one.q40_layout
     sixteen = ContinuousEngine(spec, tree, 16, 0.0, 0.9, seed=1)
-    assert sixteen.q40_layout.label == "i4-nb"
+    assert sixteen.q40_layout.label == eight.q40_layout.label == "i4-nb"
     assert _kinds(sixteen.params) == {Q40KernelNb}
-    assert eight.q40_layout.label == "d-major"
+    stock = ContinuousEngine(spec, tree, 8, 0.0, 0.9, seed=1,
+                             q40_layout=Q40_STOCK)
+    assert _kinds(stock.params) == {Q40Kernel}
 
 
 def test_engine_ignores_the_shim_a_by_hand_packer_reads_it(monkeypatch):
@@ -193,7 +192,8 @@ def test_engine_ignores_the_shim_a_by_hand_packer_reads_it(monkeypatch):
 
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
     spec, tree = _wide()
-    apply_q40_body_policy(spec, rows=8)          # records d-major
+    apply_q40_body_policy(llama2_13b_spec(), rows=8)  # records d-major
+    assert linear._APPLIED_LAYOUT.label == "d-major"
     assert _kinds(Engine(spec, tree).params) == {Q40KernelNb}
     # the tools' contract (benchmark/tools/rehearse_compile.py and two
     # more): apply, then pack by hand with no layout
@@ -205,9 +205,11 @@ def test_engine_ignores_the_shim_a_by_hand_packer_reads_it(monkeypatch):
                                   layout=Q40_STOCK)) == {Q40Kernel}
 
 
-# The five cells' real leaf shapes (benchmark/configs/*.json) and what the
-# PARENT (PR 27's tree) packed for them, read off its own pack_q40_params
-# with the re-tilers stubbed: n = nb-major, d = d-major.
+# Real leaf shapes (benchmark/configs/*.json) and what is packed for them.
+# At 1 and 16 rows these are what PR 27's tree packed (read off its own
+# pack_q40_params with the re-tilers stubbed); at 8 rows that tree packed
+# the stock picks (no nb-major kernel served 5..8 rows before PR 32) and
+# the rows now read as their neighbours do.
 def _dense(dim, hidden, heads, kv, vocab):
     hs = dim // heads
     return {"wq": (dim, dim), "wk": (kv * hs, dim), "wv": (kv * hs, dim),
@@ -229,12 +231,12 @@ OLMOE_SPEC = dict(dim=2048, hidden_dim=1024, n_layers=16, n_heads=16,
                   n_active_experts=8, qk_norm=True)
 _ALL_NB = dict.fromkeys(MISTRAL, "nb-major")
 _ALL_D = dict.fromkeys(MISTRAL, "d-major")
-# (shapes, spec or None for a sharded tree, tp, rows) -> the parent's kinds
+# (shapes, spec or None for a sharded tree, tp, rows) -> the packed kinds
 PARENT_PACKED = {
     # mistral7b.decode1: i4-nb forces every leaf
     "mistral-tp1-rows1": (MISTRAL, MISTRAL_SPEC, 1, 1, _ALL_NB),
-    # mistral7b.serve-chat / serve-sat (8 slots): the stock picks, no pad
-    "mistral-tp1-rows8": (MISTRAL, MISTRAL_SPEC, 1, 8, _ALL_D),
+    # mistral7b.serve-chat / serve-sat (8 slots): as at 1 and 16 rows
+    "mistral-tp1-rows8": (MISTRAL, MISTRAL_SPEC, 1, 8, _ALL_NB),
     "mistral-tp1-rows16": (MISTRAL, MISTRAL_SPEC, 1, 16, _ALL_NB),
     # olmoe7b.gen-sat16: an expert spec keeps the stock picks at any width;
     # nb 64 pads 2x d-major, so the dense leaves pack nb-major too
@@ -242,15 +244,12 @@ PARENT_PACKED = {
                               dict.fromkeys(OLMOE, "nb-major"))
        for r in (1, 8, 16)},
     # yi34b-tp4.decode1: shard-local nb 224 / 56 / 160, all off the grid
-    "yi-tp4-rows1": (YI, None, 4, 1, _ALL_NB),
-    "yi-tp4-rows8": (YI, None, 4, 8, _ALL_D),
-    "yi-tp4-rows16": (YI, None, 4, 16, _ALL_NB),
+    **{f"yi-tp4-rows{r}": (YI, None, 4, r, _ALL_NB) for r in (1, 8, 16)},
     # Mistral sharded: nb 128 is on the grid, the input-sharded 32 / 112 not
     **{f"mistral-tp4-rows{r}": (MISTRAL, None, 4, r,
                                 {**_ALL_D, "wo": "nb-major",
                                  "w2": "nb-major"})
-       for r in (1, 16)},
-    "mistral-tp4-rows8": (MISTRAL, None, 4, 8, _ALL_D),
+       for r in (1, 8, 16)},
 }
 
 
@@ -258,7 +257,7 @@ PARENT_PACKED = {
 def test_leaf_rule_packs_the_cells_as_the_parent_did(case, monkeypatch):
     """``q40_leaf_layout`` on each leaf's shard-local shape, under the
     layout an engine of that width resolves, and ``pack_q40_params`` on
-    the abstract tree: both give the parent's packed kinds."""
+    the abstract tree: both give the table's kinds, whatever the width."""
     import jax
 
     from distributed_llama_tpu.parallel.tp import FUSED_INPUT_SHARDED
@@ -273,8 +272,7 @@ def test_leaf_rule_packs_the_cells_as_the_parent_did(case, monkeypatch):
             return d, n // tp // 32
         return d // tp, n // 32
 
-    got = {k: q40_leaf_layout(*local(k, d, n), tp=tp, rows=rows,
-                              layout=layout, key=k)
+    got = {k: q40_leaf_layout(*local(k, d, n), tp=tp, layout=layout, key=k)
            for k, (d, n) in shapes.items()}
     assert got == want
 
@@ -285,11 +283,68 @@ def test_leaf_rule_packs_the_cells_as_the_parent_did(case, monkeypatch):
         jax.ShapeDtypeStruct((*lead[k], d, n // 32), np.float16))
         for k, (d, n) in shapes.items()}
     packed = jax.eval_shape(lambda t: pack_q40_params(
-        t, tp=tp, rows=rows, allow_nb_major=True, layout=layout,
+        t, tp=tp, allow_nb_major=True, layout=layout,
         input_sharded=FUSED_INPUT_SHARDED if tp > 1 else ()), tree)
     names = {Q40KernelNb: "nb-major", Q40Kernel: "d-major",
              Q40Weight: "codec"}
     assert {k: names[type(v)] for k, v in packed.items()} == want
+
+
+# The six cells of BENCHMARK.json: (policy label, kinds) an engine of the
+# cell's width and tp resolves for the cell's own configuration file. PR 32
+# moved the two 8-row cells (were "d-major", every leaf d-major) and nothing
+# else: rows 1 and 16 and tp 4 read what PR 31's tree read.
+CELLS_PACKED = {
+    "mistral7b.decode1": ("i4-nb", _ALL_NB),
+    "mistral7b.serve-chat": ("i4-nb", _ALL_NB),
+    "mistral7b.serve-sat": ("i4-nb", _ALL_NB),
+    "yi34b-tp4.decode1": ("d-major", _ALL_NB),
+    "olmoe7b.gen-sat16": ("d-major", dict.fromkeys(OLMOE, "nb-major")),
+    "brumby14b.gen-sat16": ("i4-nb", _ALL_NB),
+}
+MOVED_BY_PR32 = {"mistral7b.serve-chat", "mistral7b.serve-sat"}
+PR31_PACKED = {**CELLS_PACKED,
+               **dict.fromkeys(MOVED_BY_PR32, ("d-major", _ALL_D))}
+
+
+@pytest.mark.parametrize("cell_name", sorted(CELLS_PACKED))
+def test_the_six_cells_policy_and_leaf_kinds(cell_name, monkeypatch):
+    """Each cell's configuration through its own harness module to the
+    program's spec, at the cell's dispatch width (1 / 8 / 16 rows) and tp
+    (1 / 4): the policy label and every leaf's packed kind."""
+    import jax
+
+    from benchmark.harness import cells, model, olmoe, retention
+    from distributed_llama_tpu.parallel.tp import FUSED_INPUT_SHARDED
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    cell = cells.load_cell(cell_name)
+    harness = {"olmoe": olmoe, "brumby": retention}.get(
+        cell.config.get("model_type"), model)
+    flags = cell.config["entries"][
+        "inference" if cell.traffic["entry"] == "inference" else "serve"]
+    rows, tp = int(flags.get("slots", 1)), int(flags.get("tp", 1))
+    assert (rows, tp) == {"mistral7b.decode1": (1, 1),
+                          "yi34b-tp4.decode1": (1, 4)}.get(
+        cell_name, (8 if cell_name in MOVED_BY_PR32 else 16, 1))
+    spec = harness.program_spec(harness.sizes_of(cell.config))
+    layout = q40_body_policy(spec, rows=rows, sharded=tp > 1)
+    shapes = dict(spec.layer_matmul_shapes() + spec.expert_matmul_shapes())
+    shapes["wcls"] = (spec.vocab_size, spec.dim)
+    lead = {k: (1, spec.n_experts) if k.startswith("moe_") else
+            () if k == "wcls" else (1,) for k in shapes}
+    tree = {k: Q40Weight(
+        jax.ShapeDtypeStruct((*lead[k], d, n // 32, 16), np.uint8),
+        jax.ShapeDtypeStruct((*lead[k], d, n // 32), np.float16))
+        for k, (d, n) in shapes.items()}
+    packed = jax.eval_shape(lambda t: pack_q40_params(
+        t, tp=tp, allow_nb_major=tp == 1, layout=layout,
+        input_sharded=FUSED_INPUT_SHARDED if tp > 1 else ()), tree)
+    names = {Q40KernelNb: "nb-major", Q40Kernel: "d-major",
+             Q40Weight: "codec"}
+    got = (layout.label, {k: names[type(v)] for k, v in packed.items()})
+    assert got == CELLS_PACKED[cell_name]
+    assert (got == PR31_PACKED[cell_name]) == (cell_name not in MOVED_BY_PR32)
 
 
 def test_leaf_rule_corners():
@@ -303,8 +358,8 @@ def test_leaf_rule_corners():
     assert q40_leaf_layout(1376, 160, layout=forced) == "d-major"
     # sharded: the layout's force is not consulted, the grid and width are
     assert q40_leaf_layout(4096, 128, tp=4, layout=forced) == "d-major"
-    assert q40_leaf_layout(1792, 224, tp=4, rows=4) == "nb-major"
-    assert q40_leaf_layout(1792, 224, tp=4, rows=5) == "d-major"
+    assert q40_leaf_layout(1792, 224, tp=4) == "nb-major"
+    assert q40_leaf_layout(1792, 256, tp=4) == "d-major"
     # what neither tiler places stays codec
     assert q40_leaf_layout(1000003, 128) == "codec"
     # an expert stack: nb-major where the grouped kernels place it

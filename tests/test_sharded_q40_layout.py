@@ -18,6 +18,14 @@ YI = {"wq": (7168, 7168), "wk": (1024, 7168), "wv": (1024, 7168),
       "wo": (7168, 7168), "w1": (20480, 7168), "w2": (7168, 20480),
       "w3": (20480, 7168), "wcls": (64000, 7168)}
 
+# ... of Mistral-7B's and Brumby-14B's (benchmark/configs/)
+MISTRAL = {"wq": (4096, 4096), "wk": (1024, 4096), "wv": (1024, 4096),
+           "wo": (4096, 4096), "w1": (14336, 4096), "w2": (4096, 14336),
+           "w3": (14336, 4096), "wcls": (32000, 4096)}
+BRUMBY = {"wq": (5120, 5120), "wk": (1024, 5120), "wv": (1024, 5120),
+          "wo": (5120, 5120), "w1": (17408, 5120), "w2": (5120, 17408),
+          "w3": (17408, 5120)}
+
 
 def _abstract_pick(shapes: dict, **kw) -> dict:
     """Leaf kinds ``pack_q40_params`` picks for (d, n) shapes, with no
@@ -48,10 +56,22 @@ PICKS = {
         {"wq": (4096, 4096), "wo": (4096, 16384), "w2": (4096, 14336)},
         dict(tp=4, input_sharded=FUSED),
         {"wq": Q40Kernel, "wo": Q40Kernel, "w2": Q40KernelNb}),
-    **{f"rows{r}": (YI, dict(tp=4, input_sharded=FUSED, rows=r),
-                    dict.fromkeys(YI, kind))
-       for r, kind in ((4, Q40KernelNb), (5, Q40Kernel), (8, Q40Kernel),
-                       (16, Q40KernelNb))},
+    # the width of a decode dispatch is no input of the rule since PR 32
+    # (every width has an nb-major kernel); what decides is each scheme's
+    # shard-local block count. Mistral at tp=2: nb 128 on the grid, fused
+    # wo 64 and w2 224 off it, ref w2 448 off it too
+    "mistral-tp2-fused": (MISTRAL, dict(tp=2, input_sharded=FUSED),
+                          {**dict.fromkeys(MISTRAL, Q40Kernel),
+                           "wo": Q40KernelNb, "w2": Q40KernelNb}),
+    "mistral-tp2-ref": (MISTRAL, dict(tp=2),
+                        {**dict.fromkeys(MISTRAL, Q40Kernel),
+                         "w2": Q40KernelNb}),
+    # Brumby's widths at tp=4: nb 160 off the grid (fused wo 40, w2 136)
+    "brumby-tp4-fused": (BRUMBY, dict(tp=4, input_sharded=FUSED),
+                         dict.fromkeys(BRUMBY, Q40KernelNb)),
+    # ... and under ref w2's d_local 1280 places, its nb 544 is off the grid
+    "brumby-tp4-ref": (BRUMBY, dict(tp=4),
+                       dict.fromkeys(BRUMBY, Q40KernelNb)),
     # d_local 1376 = 11008 / 8 has no 128-multiple divisor: today's pick
     "no-row-tiling": ({"w1": (11008, 5120)}, dict(tp=8), {"w1": Q40Kernel}),
     # tp == 1 is q40_body_policy's: nothing moves, opted in or not
