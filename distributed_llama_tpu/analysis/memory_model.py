@@ -79,8 +79,28 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
 
         raise ValueError(TP_REFUSAL)
     per_layer = sum(c * d * n for (d, n), c in spec.matmul_shape_counts())
+    if spec.latent:
+        # two kinds of layer, the experts HELD (not the router's width),
+        # and ``wkv_b`` apart: the device holds it as float32
+        # (``latent_absorbed_bytes``), not in the weights' float type
+        dense = sum(d * n for _, (d, n) in spec.dense_layer_matmul_shapes())
+        la = spec.latent
+        kvb = spec.n_heads * (la.nope_dim + la.v_dim) * la.kv_rank
+        return (spec.n_expert_layers * per_layer
+                + spec.n_dense_layers * dense - spec.n_layers * kvb
+                + spec.vocab_size * spec.dim)
     total = spec.n_layers * per_layer + spec.vocab_size * spec.dim
     return total // n_slices
+
+
+def latent_absorbed_bytes(spec: TransformerSpec) -> int:
+    """A latent spec's ``w_uk`` / ``w_uv`` (models/latent.py): ``wkv_b`` of
+    every layer dequantized to float32 for the absorbed products."""
+    if not spec.latent:
+        return 0
+    la = spec.latent
+    return (4 * spec.n_layers * spec.n_heads * (la.nope_dim + la.v_dim)
+            * la.kv_rank)
 
 
 def weights_device_bytes(spec: TransformerSpec, n_slices: int) -> int:
@@ -102,10 +122,12 @@ def replicated_device_bytes(spec: TransformerSpec) -> int:
     embedding = spec.vocab_size * spec.dim * 4
     norms = (spec.n_layers * sum(n for _, n in spec.layer_norm_shapes())
              + spec.dim) * 4
-    routers = spec.n_layers * spec.n_experts * spec.dim * 4   # f32, whole
+    expert_layers = spec.n_expert_layers if spec.latent else spec.n_layers
+    routers = expert_layers * spec.n_experts * (
+        spec.dim + spec.router.bias) * 4                       # f32, whole
     gates = (spec.n_layers * spec.n_kv_heads * spec.dim * 4
              if spec.retention else 0)                         # f32, whole
-    return embedding + norms + routers + gates
+    return embedding + norms + routers + gates + latent_absorbed_bytes(spec)
 
 
 def state_slot_bytes(spec: TransformerSpec) -> int:
@@ -131,6 +153,9 @@ def kv_cache_device_bytes(spec: TransformerSpec, n_slices: int,
 
             raise ValueError(TP_REFUSAL)
         return batch * state_slot_bytes(spec)
+    if spec.latent:
+        return batch * spec.seq_len * kv_position_bytes(spec, n_slices,
+                                                        cache_itemsize)
     return (2 * spec.n_layers * batch * (spec.seq_len // n_sp)
             * (spec.n_kv_heads // n_slices) * spec.head_size
             * cache_itemsize)
@@ -162,6 +187,14 @@ def kv_position_bytes(spec: TransformerSpec, n_slices: int,
     3.76x cut vs f32 (1.88x vs bf16). Exact, not approximate — the
     equal-HBM page multiplier the engine/bench use is derived from this
     number, and the shardcheck KV-quant column pins it."""
+    if spec.latent:
+        # ONE plane of latent.width values a position, stored in whole
+        # 128-lane tiles (models/latent.plane_width: 576 lies in 640)
+        if n_slices > 1 or kv_quant != "f32":
+            raise ValueError("a latent-attention spec's plane is float32 on "
+                             "one chip (runtime/continuous.latent_refusals)")
+        return (spec.n_layers * -(-spec.latent.width // 128) * 128
+                * cache_itemsize)
     kv_dim = (spec.n_kv_heads // n_slices) * spec.head_size
     if kv_quant == "q8":
         per = kv_dim + 2 * (kv_dim // QK)   # int8 codes + f16 deltas

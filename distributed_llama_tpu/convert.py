@@ -31,6 +31,22 @@ Extensions beyond the reference:
   are this repo's reading of the publication, not of the checkpoint: a
   checkpoint whose gate has another shape is REFUSED rather than guessed at
   (``GATE_TENSOR`` names the tensor; there is no fallback).
+* ``--source hf`` on ``model_type: deepseek_v3`` (DeepSeek-V3's block:
+  latent attention, leading dense layers, sigmoid group-limited routing with
+  a shared expert), from a BFLOAT16 checkpoint: ``self_attn.q_a_proj`` /
+  ``q_a_layernorm`` / ``q_b_proj`` / ``kv_a_proj_with_mqa`` /
+  ``kv_a_layernorm`` / ``kv_b_proj`` / ``o_proj``, ``mlp.gate.weight`` and
+  ``mlp.gate.e_score_correction_bias``, ``mlp.shared_experts.*`` and
+  ``mlp.experts.{e}.*`` (``LATENT_TENSORS``), written under header
+  extension 4 in ``TransformerSpec.layer_plans`` order. The rows are taken
+  as they are: the published weights give interleaved (pair) rotary
+  features, which the published code de-interleaves at run time and this
+  program rotates in place. The whole model is written: every layer and
+  every routed expert (a file that holds a share of the experts is a
+  benchmark's seeded tree, not a conversion). The released FP8 block-scaled
+  checkpoint (``quantization_config`` in its config) is NOT read: cast it
+  to bfloat16 with the publisher's script first. The multi-token-prediction
+  module (the checkpoint's last layer) is left out.
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -130,6 +146,74 @@ GATE_TENSOR = "model.layers.{layer}.self_attn.gate_proj.weight"
 docstring)."""
 
 
+_L = "model.layers.{layer}."
+LATENT_TENSORS = {
+    "rms_att": _L + "input_layernorm.weight",
+    "rms_ffn": _L + "post_attention_layernorm.weight",
+    "rms_q_a": _L + "self_attn.q_a_layernorm.weight",
+    "rms_kv_a": _L + "self_attn.kv_a_layernorm.weight",
+    "wq_a": _L + "self_attn.q_a_proj.weight",
+    "wq_b": _L + "self_attn.q_b_proj.weight",
+    "wkv_a": _L + "self_attn.kv_a_proj_with_mqa.weight",
+    "wkv_b": _L + "self_attn.kv_b_proj.weight",
+    "wo": _L + "self_attn.o_proj.weight",
+    "w1": _L + "mlp.gate_proj.weight",
+    "w2": _L + "mlp.down_proj.weight",
+    "w3": _L + "mlp.up_proj.weight",
+    "moe_gate": _L + "mlp.gate.weight",
+    "moe_bias": _L + "mlp.gate.e_score_correction_bias",
+    "sh_w1": _L + "mlp.shared_experts.gate_proj.weight",
+    "sh_w2": _L + "mlp.shared_experts.down_proj.weight",
+    "sh_w3": _L + "mlp.shared_experts.up_proj.weight",
+    "moe_w1": _L + "mlp.experts.{expert}.gate_proj.weight",
+    "moe_w2": _L + "mlp.experts.{expert}.down_proj.weight",
+    "moe_w3": _L + "mlp.experts.{expert}.up_proj.weight",
+}
+"""A ``deepseek_v3`` checkpoint's tensors by this repo's names, as the
+published bfloat16 state dict names them."""
+
+
+def latent_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
+    """The spec of a ``deepseek_v3`` config object ``c``: every layer and
+    every routed expert."""
+    from .models.spec import ExpertLayout, LatentAttn, RopeScaling, Router
+
+    if getattr(c, "quantization_config", None):
+        raise ValueError(
+            "an FP8 block-scaled checkpoint (quantization_config) is not "
+            "read: cast it to bfloat16 first (the publisher's "
+            "fp8_cast_bf16.py), then convert that")
+    if (c.scoring_func, c.topk_method, getattr(c, "moe_layer_freq", 1)) != (
+            "sigmoid", "noaux_tc", 1) or c.hidden_act != "silu":
+        raise ValueError("deepseek_v3: sigmoid scores with the noaux_tc "
+                         "choice, an expert layer after every leading "
+                         "dense one and SwiGLU are what the program runs")
+    rs = c.rope_scaling
+    if rs and rs.get("type", rs.get("rope_type")) != "yarn":
+        raise ValueError(f"rope_scaling {rs}: only yarn is implemented")
+    n_layers = c.num_hidden_layers
+    return TransformerSpec(
+        dim=c.hidden_size, hidden_dim=c.moe_intermediate_size,
+        n_layers=n_layers, n_heads=c.num_attention_heads,
+        n_kv_heads=c.num_key_value_heads, vocab_size=c.vocab_size,
+        seq_len=seq_len, weights_float_type=target,
+        n_experts=c.n_routed_experts,
+        n_active_experts=c.num_experts_per_tok,
+        rope_theta=float(c.rope_theta), norm_eps=float(c.rms_norm_eps),
+        latent=LatentAttn(c.q_lora_rank, c.kv_lora_rank, c.qk_nope_head_dim,
+                          c.qk_rope_head_dim, c.v_head_dim),
+        layout=ExpertLayout(min(c.first_k_dense_replace, n_layers - 1),
+                            c.intermediate_size, c.n_shared_experts),
+        router=Router("sigmoid", c.n_group, c.topk_group,
+                      bool(c.norm_topk_prob),
+                      float(c.routed_scaling_factor), True),
+        rope_scaling=RopeScaling(
+            float(rs["factor"]), int(rs["original_max_position_embeddings"]),
+            float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+            float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)))
+        if rs else None)
+
+
 class HFCheckpoint:
     """HuggingFace LlamaForCausalLM -> reference tensor layout.
 
@@ -170,6 +254,8 @@ class HFCheckpoint:
     def spec(self, target: FloatType, seq_len: int) -> TransformerSpec:
         c = self.config
         moe = {}
+        if getattr(c, "model_type", "") == "deepseek_v3":
+            return latent_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "olmoe":
             if getattr(c, "norm_topk_prob", False):
                 raise ValueError("olmoe with norm_topk_prob: the program "
@@ -198,6 +284,13 @@ class HFCheckpoint:
     def tensor_by_name(self, name: str, layer: int | None,
                        spec: TransformerSpec,
                        expert: int | None = None) -> np.ndarray:
+        if spec.latent:     # rows as they are: see the module docstring
+            key = LATENT_TENSORS.get(name) or {
+                "tok_embedding": "model.embed_tokens.weight",
+                "rms_final": "model.norm.weight",
+                "wcls": "lm_head.weight"}[name]
+            return self.state[key.format(layer=layer, expert=expert)].to(
+                self.torch.float32).numpy()
         experts = f"model.layers.{layer}.mlp.experts.{expert}"
         hf = {
             "tok_embedding": "model.embed_tokens.weight",
@@ -264,8 +357,8 @@ def convert_meta(model_path: str, target: str, out: str | None = None,
 
 
 def convert_hf(model_path: str, target: str, out: str | None = None,
-               seq_len: int = 2048) -> str:
-    ckpt = HFCheckpoint(model_path)
+               seq_len: int = 2048, ckpt=None) -> str:
+    ckpt = ckpt or HFCheckpoint(model_path)
     spec = ckpt.spec(_FT[target], seq_len)
     name = os.path.basename(os.path.normpath(model_path))
     out = out or f"dllama_{name}_{target}.bin"
@@ -273,7 +366,19 @@ def convert_hf(model_path: str, target: str, out: str | None = None,
         f.write(spec.header())
         _write_tensor(f, spec, "tok_embedding",
                       ckpt.tensor_by_name("tok_embedding", None, spec))
-        for i in range(spec.n_layers):
+        # a latent spec's layers, of two kinds, in the file's own order
+        for i, (_, _, entries) in enumerate(
+                spec.layer_plans() if spec.latent else ()):
+            for kind, name_, _, *e in entries:
+                arr = ckpt.tensor_by_name(name_, i, spec,
+                                          e[0] if e else None)
+                if kind == "f32":
+                    f.write(np.ascontiguousarray(
+                        arr, dtype=np.float32).tobytes())
+                else:
+                    _write_matmul(f, spec, arr)
+            print(f"🔶 wrote layer {i + 1}/{spec.n_layers}")
+        for i in range(0 if spec.latent else spec.n_layers):
             # file order (models/spec.py): norms, attention, router, experts
             names = ([n for n, _ in spec.layer_norm_shapes()]
                      + [n for n, _ in spec.layer_matmul_shapes()]
@@ -326,7 +431,10 @@ def main(argv=None):
     ap.add_argument("target", choices=sorted(_FT))
     ap.add_argument("--out")
     ap.add_argument("--seq-len", type=int, default=2048)
-    ap.add_argument("--source", choices=["meta", "hf"], default="meta")
+    ap.add_argument("--source", choices=["meta", "hf"], default="meta",
+                    help="hf: a HuggingFace checkpoint by its model_type "
+                         "(deepseek_v3: bfloat16 checkpoints only; the FP8 "
+                         "block-scaled release is not read)")
     ap.add_argument("--export-tokenizer", metavar="SP_MODEL",
                     help="also write tokenizer.bin from a sentencepiece model")
     args = ap.parse_args(argv)

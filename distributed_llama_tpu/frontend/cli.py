@@ -66,6 +66,36 @@ def _refuses_retention(args, tp: int) -> bool:
     return bool(refused)
 
 
+def _refuses_latent(args, tp: int, serve: bool) -> bool:
+    """``_refuses_retention`` for a latent-attention spec
+    (``runtime/continuous.latent_refusals``)."""
+    from ..runtime.continuous import latent_refusals
+
+    get = lambda name, off=0: getattr(args, name, off) or off  # noqa: E731
+    refused = latent_refusals(
+        tp=max(tp, get("sp", 1)), page_size=get("kv_page_size"),
+        spec_k=get("spec_k"), dispatch_tokens=get("dispatch_tokens"),
+        kv_quant=get("kv_quant", "f32"),
+        kv_host_pages=get("kv_host_pages"),
+        kv_disk_dir=get("kv_disk_dir", None),
+        disagg=bool(get("disagg_role")), block_steps=get("block_steps", 1),
+        kv_cache_dtype=get("kv_cache_dtype", "f32"), serve=serve)
+    for line in refused:
+        print(f"refused: {line}", file=sys.stderr)
+    return bool(refused)
+
+
+def _latent_line(spec) -> str:
+    """What a latent-attention spec caches and holds: a startup line."""
+    la, lay = spec.latent, spec.layout
+    return (f"💡 attention: latent (q rank {la.q_rank}, cache "
+            f"{la.width} values a position and layer: c_kv {la.kv_rank} + "
+            f"k_rope {la.rope_dim}); layers: {lay.dense_layers} dense + "
+            f"{spec.n_expert_layers} expert; experts held: "
+            f"{spec.n_experts_held} of {spec.n_experts} from {lay.offset}, "
+            f"{lay.shared} shared")
+
+
 def _retention_line(spec, slots: int) -> str:
     """What a retention spec keeps a sequence: a startup line."""
     from ..ops.retention import state_bytes
@@ -554,7 +584,7 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
                  if spec.extended else "")
               + (f"💡 attnKind: {spec.attn_kind}\n💡 ropeTheta: "
                  f"{spec.rope_theta:g}\n💡 normEps: {spec.norm_eps:g}\n"
-                 if spec.header_version == 3 else "")
+                 if spec.header_version >= 3 else "")
               + f"💡 nSlices: {tp} sp: {args.sp} scheme: "
               f"{scheme if tp > 1 else '-'} ({n_dev} devices, "
               f"{jax.devices()[0].platform})")
@@ -569,6 +599,13 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
             return 2
         if not quiet:
             print(_retention_line(spec, rows))   # one chip: rows is set
+    if spec.latent:
+        # batch prompts without --continuous run the lockstep batch, which
+        # has no latent cache: only one sequence or the paged pool
+        if _refuses_latent(args, tp, serve=prompts is not None):
+            return 2
+        if not quiet:
+            print(_latent_line(spec))
     mesh = (make_mesh(sp=args.sp, tp=tp)
             if tp > 1 or args.sp > 1 else None)
     assumed = getattr(args, "_slice_tp_ranks", None)
@@ -1076,6 +1113,10 @@ def cmd_serve(argv: list[str]) -> int:
         if _refuses_retention(args, args.tp or 1):
             return 2
         print(_retention_line(spec, args.slots))
+    if spec.latent:
+        if _refuses_latent(args, args.tp or 1, serve=True):
+            return 2
+        print(_latent_line(spec))
     mesh = make_mesh(tp=args.tp) if args.tp and args.tp > 1 else None
     seed = args.seed if args.seed is not None else int(time.time())
     if journal is not None:

@@ -252,6 +252,13 @@ def load_model(path: str, spec: TransformerSpec | None = None,
 
     params: dict = {}
     params["tok_embedding"] = w.f32((spec.vocab_size, spec.dim))
+    if spec.latent:
+        _load_planned_layers(spec, w, params)
+        params["rms_final"] = w.f32((spec.dim,))
+        params["wcls"] = w.matmul(spec, (spec.vocab_size, spec.dim))
+        if w.off != expected:
+            raise ValueError(f"missed {expected - w.off} bytes")
+        return spec, params
 
     # preallocate the stacked arrays and stream each layer straight into its
     # slot (avoids transiently holding list-of-layers + np.stack copies of
@@ -307,6 +314,40 @@ def load_model(path: str, spec: TransformerSpec | None = None,
     return spec, params
 
 
+def stack_of(params: dict, stack: str) -> dict:
+    """The dict a ``TransformerSpec.layer_plans`` stack's tensors live in:
+    the tree itself, or ``params["dense"]`` (made on first use)."""
+    return params.setdefault(stack, {}) if stack else params
+
+
+def _load_planned_layers(spec: TransformerSpec, w: _Walker,
+                         params: dict) -> None:
+    """The layers of a spec with two kinds of layer, in ``layer_plans``
+    order, each tensor streamed into its preallocated stack."""
+    q40 = spec.weights_float_type == FloatType.Q40
+    dtype = np.float16 if spec.weights_float_type == FloatType.F16 \
+        else np.float32
+    for stack, name, kind, shape in spec.stack_leaves():
+        dst = stack_of(params, stack)
+        if kind == "mm" and q40:
+            *lead, dd, nn = shape
+            dst[name] = Q40Weight(
+                np.empty((*lead, dd, nn // 32, 16), np.uint8),
+                np.empty((*lead, dd, nn // 32), np.float16))
+        else:
+            dst[name] = np.empty(shape, dtype if kind == "mm"
+                                 else np.float32)
+    for stack, at, entries in spec.layer_plans():
+        dst = stack_of(params, stack)
+        for kind, name, shape, *e in entries:
+            val = w.f32(shape) if kind == "f32" else w.matmul(spec, shape)
+            if isinstance(val, Q40Weight):
+                dst[name].qs[(at, *e)] = val.qs
+                dst[name].d16[(at, *e)] = val.d16
+            else:
+                dst[name][(at, *e)] = val
+
+
 class TensorRange(NamedTuple):
     """One tensor's byte placement in the .bin: ``rows`` is the output dim
     for matmul tensors (whose contiguous row bands are what MatmulSlice
@@ -339,7 +380,14 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
     add("tok_embedding", None, spec.vocab_size * spec.dim * 4)
     shapes = spec.layer_matmul_shapes()
     experts = spec.expert_matmul_shapes()
-    for layer in range(spec.n_layers):
+    for layer, (_, _, entries) in enumerate(
+            spec.layer_plans() if spec.latent else ()):
+        for kind, name, shape, *_ in entries:
+            if kind == "f32":
+                add(name, layer, 4 * int(np.prod(shape)))
+            else:
+                add(name, layer, spec.matmul_bytes(shape), rows=shape[0])
+    for layer in range(0 if spec.latent else spec.n_layers):
         for name, n in spec.layer_norm_shapes():
             add(name, layer, n * 4)
         for name, shape in shapes:
@@ -387,7 +435,17 @@ def write_model(path: str, spec: TransformerSpec, tensors: dict) -> None:
         f.write(spec.header())
         f.write(np.ascontiguousarray(
             tensors["tok_embedding"], dtype=np.float32).tobytes())
-        for layer in range(spec.n_layers):
+        for stack, at, entries in (spec.layer_plans() if spec.latent
+                                   else ()):
+            src = tensors[stack] if stack else tensors
+            for kind, name, _, *e in entries:
+                val = src[name][(at, *e)]
+                if kind == "f32":
+                    f.write(np.ascontiguousarray(
+                        val, dtype=np.float32).tobytes())
+                else:
+                    _write_matmul(f, spec, val)
+        for layer in range(0 if spec.latent else spec.n_layers):
             for name, _ in spec.layer_norm_shapes():
                 f.write(np.ascontiguousarray(
                     tensors[name][layer], dtype=np.float32).tobytes())
@@ -421,7 +479,9 @@ def densify_params(params: dict) -> dict:
 
     out = {}
     for name, val in params.items():
-        if isinstance(val, Q40Weight):
+        if isinstance(val, dict):       # a second stack of layers
+            out[name] = densify_params(val)
+        elif isinstance(val, Q40Weight):
             out[name] = dequantize_q40(val.qs, val.d16)
         elif isinstance(val, Q40Kernel):  # pre-tiled: go through the codec
             w = from_kernel_layout(val)

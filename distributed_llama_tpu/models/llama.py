@@ -67,6 +67,10 @@ def init_state(spec: TransformerSpec, batch: int | None = None) -> StateCache:
 def init_cache(spec: TransformerSpec, dtype=jnp.float32):
     if spec.retention:  # float32 whatever ``dtype``: nothing scales with S
         return init_state(spec)
+    if spec.latent:     # one plane [c_kv | k_rope] in place of K and V
+        from .latent import init_cache as init_latent
+
+        return init_latent(spec, dtype)
     shape = (spec.n_layers, spec.seq_len, spec.n_kv_heads, spec.head_size)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
@@ -298,11 +302,27 @@ def _qkv_proj(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
     return rot_fn(q), rot_fn(k), v
 
 
+def _swiglu(spec: TransformerSpec, lw: dict[str, Any], xb: jax.Array,
+            prefix: str = "") -> jax.Array:
+    """w2(silu(w1 xb) * w3 xb) of the leaves ``prefix + w1 | w2 | w3`` (or
+    their load-time fusion ``prefix + w13``: linear.fuse_q40_layer_matmuls)."""
+    if prefix + "w13" in lw:
+        h13 = matmul(lw[prefix + "w13"], xb)
+        hid = h13.shape[-1] // 2
+        hb = silu(h13[..., :hid]) * h13[..., hid:]
+    else:
+        hb = silu(matmul(lw[prefix + "w1"], xb)) * matmul(lw[prefix + "w3"],
+                                                          xb)
+    return matmul(lw[prefix + "w2"], _maybe_q80(spec, hb))
+
+
 def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
                     ao: jax.Array, moe_counts: bool = False):
     """Shared layer tail: wo + residual, then the ffn sub-block: SwiGLU, or
-    for an expert spec the router and the routed experts (ops/pallas_moe).
-    ``moe_counts`` (expert specs only) also returns the (E,) int32 count of
+    for an EXPERT LAYER (one whose weights hold a router: an expert spec's
+    leading dense layers hold none) the router and the routed experts
+    (ops/pallas_moe) plus the shared expert where the layer has one.
+    ``moe_counts`` (expert layers only) also returns the (E,) int32 count of
     rows routed to each expert: ``(x, counts)``."""
     with jax.named_scope(SCOPE_ATTN):
         ao = _maybe_q80(spec, ao)
@@ -310,19 +330,14 @@ def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
     with jax.named_scope(SCOPE_FFN):
         xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
         xb = _maybe_q80(spec, xb)
-        if spec.n_experts:
+        if "moe_gate" in lw:
             from ..ops.pallas_moe import moe_ffn
 
             y, counts = moe_ffn(spec, lw, xb)
+            if "sh_w2" in lw:
+                y = y + _swiglu(spec, lw, xb, "sh_")
             return (x + y, counts) if moe_counts else x + y
-        if "w13" in lw:  # load-time fused kernel (linear.fuse_q40_layer_matmuls)
-            h13 = matmul(lw["w13"], xb)
-            hid = h13.shape[-1] // 2
-            hb = silu(h13[..., :hid]) * h13[..., hid:]
-        else:
-            hb = silu(matmul(lw["w1"], xb)) * matmul(lw["w3"], xb)
-        hb = _maybe_q80(spec, hb)
-        return x + matmul(lw["w2"], hb)
+        return x + _swiglu(spec, lw, xb)
 
 
 def _layer(spec: TransformerSpec, x: jax.Array, lw: dict[str, Any],
@@ -367,9 +382,13 @@ def _layer(spec: TransformerSpec, x: jax.Array, lw: dict[str, Any],
 LAYER_KEYS = ("rms_att", "rms_ffn", "wq", "wk", "wv", "wo", "w1", "w2", "w3",
               # an expert spec's: q/k-norm gains, router, expert stacks
               "rms_q", "rms_k", "moe_gate", "moe_w1", "moe_w2", "moe_w3",
-              "w_gate")   # a retention spec's gate
+              "w_gate",   # a retention spec's gate
+              # a latent spec's: low-rank q, the latent row, the absorbed
+              # halves of wkv_b, the router's bias, the shared expert
+              "rms_q_a", "rms_kv_a", "wq_a", "wq_b", "wkv_a", "wkv_b",
+              "w_uk", "w_uv", "moe_bias", "sh_w1", "sh_w2", "sh_w3")
 # load-time fusions (ops/linear) + the megakernel's permuted wo
-FUSED_KEYS = ("wqkv", "w13", "wo_mega", "moe_w13")
+FUSED_KEYS = ("wqkv", "w13", "wo_mega", "moe_w13", "sh_w13")
 
 
 def split_layer_weights(params: dict[str, Any]):
@@ -584,6 +603,11 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
     """
     if spec.retention:
         return forward_retention(spec, params, cache, tokens, pos)
+    if spec.latent:
+        from .latent import forward_latent
+
+        return forward_latent(spec, params, cache, tokens, pos,
+                              moe_counts=moe_counts)
     t_len = tokens.shape[0]
     if t_len == 1:
         from ..ops import pallas_layer
@@ -693,6 +717,10 @@ def init_cache_paged(spec: TransformerSpec, n_pages: int, page_size: int,
     physical pages through an int32 page-table row, so the pool can be
     sized far below slots * seq_len (the HBM lever of vLLM's
     PagedAttention)."""
+    if spec.latent:     # ONE plane a page: models/latent.py
+        from .latent import init_cache_paged as init_latent_paged
+
+        return init_latent_paged(spec, n_pages, page_size, dtype)
     if spec.seq_len % page_size:
         raise ValueError(f"page_size={page_size} must divide "
                          f"seq_len={spec.seq_len}")
@@ -1001,6 +1029,12 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
     parity against f32 moves to distribution-pinned tolerance gates, the
     documented quantization contract.
     """
+    if spec.latent:
+        from .latent import forward_batch_latent_paged
+
+        return forward_batch_latent_paged(spec, page_size, params, cache,
+                                          tokens, pos_vec, table,
+                                          moe_counts=moe_counts)
     B = tokens.shape[0]
     x = params["tok_embedding"][tokens].astype(jnp.float32)  # (B, dim)
     positions = pos_vec if jnp.ndim(pos_vec) == 1 else jnp.full((B,),
@@ -1311,13 +1345,14 @@ def gather_pages(cache: KVCache, table: jax.Array,
     single-sequence prefill program expects a contiguous plane. ``table``
     is the slot's full (max_pages,) logical->physical row; entries beyond
     the live prefix gather scrap-page junk that prefill overwrites (its
-    chunk at position p writes p before any later chunk reads it)."""
+    chunk at position p writes p before any later chunk reads it). A
+    latent pool's one plane (models/latent.LatentCache) gathers alike."""
     def g(plane):
         L = plane.shape[0]
         got = jnp.take(plane, table, axis=1)  # (L, max_pages, ps, kv, hs)
         return got.reshape(L, table.shape[0] * page_size, *plane.shape[3:])
 
-    return KVCache(g(cache.k), g(cache.v))
+    return type(cache)(*(g(plane) for plane in cache))
 
 
 def scatter_pages(cache: KVCache, seq_cache: KVCache, table: jax.Array,
@@ -1334,7 +1369,8 @@ def scatter_pages(cache: KVCache, seq_cache: KVCache, table: jax.Array,
                                 *plane.shape[3:])
         return plane.at[:, table].set(upd)
 
-    return KVCache(s(cache.k, seq_cache.k), s(cache.v, seq_cache.v))
+    return type(cache)(*(s(plane, seq) for plane, seq in zip(cache,
+                                                             seq_cache)))
 
 
 def gather_pages_q8(cache: PagedKVQ8, table: jax.Array,
@@ -1547,9 +1583,16 @@ def params_to_device(params: dict[str, Any], dtype=None,
     from ..io.loader import Q40Kernel, Q40Weight
     from ..ops.linear import fuse_q40_layer_matmuls, pack_q40_params
 
+    if "wkv_b" in params:   # a latent spec's absorbed halves (float32)
+        from .latent import prepare_latent_params
+
+        if spec is None:
+            raise ValueError("a latent-attention tree needs its spec to be "
+                             "placed: params_to_device(params, spec=spec)")
+        params = prepare_latent_params(spec, params)
     params = fuse_q40_layer_matmuls(pack_q40_params(
         params, allow_nb_major=True, layout=layout))
-    if spec is not None:
+    if spec is not None and not spec.latent:
         from ..ops.pallas_layer import prepare_mega_params
 
         params = prepare_mega_params(spec, params)
@@ -1560,12 +1603,13 @@ def params_to_device(params: dict[str, Any], dtype=None,
             x = x.astype(dtype)
         return x
 
-    out = {}
-    for k, v in params.items():
-        if isinstance(v, (Q40Weight, Q40Kernel, Q40KernelNb)):
-            # quantized leaves keep their exact codec/kernel dtypes — the
-            # dtype knob is for dense weights only (scales must stay f32/f16)
-            out[k] = jax.tree_util.tree_map(jnp.asarray, v)
-        else:
-            out[k] = conv(v)
-    return out
+    def place(stack):
+        # quantized leaves keep their exact codec/kernel dtypes — the dtype
+        # knob is for dense weights only (scales must stay f32/f16); a
+        # nested dict is a second stack of layers ("dense")
+        return {k: place(v) if isinstance(v, dict)
+                else jax.tree_util.tree_map(jnp.asarray, v)
+                if isinstance(v, (Q40Weight, Q40Kernel, Q40KernelNb))
+                else conv(v) for k, v in stack.items()}
+
+    return place(params)

@@ -54,6 +54,34 @@ place) keeps a recurrent state of fixed size in place of a KV cache
   w1, w2, w3                              [weightsFloatType]
 
 ``w_gate`` is (L, nKvHeads, dim) float32 in the param tree.
+
+Extension VERSION 4 (the fourteen ints and two float64 of version 3, then
+sixteen ints and seven float64: ``EXT4_STRUCT``) is written only by a spec
+that sets one of the grouped records below, so files of versions 0, 2 and 3
+read and write byte for byte: {qRank, kvRank, nopeDim, ropeDim, vDim}
+(``LatentAttn``: low-rank q, and a latent KV plane of kvRank + ropeDim values
+a position in place of K and V), {denseLayers, denseHidden, sharedExperts,
+held, offset} (``ExpertLayout``: leading dense layers and their width,
+shared experts, and WHICH routed experts this file holds: a count and an
+offset into the router's width ``nExperts``), {scoring, groups, groupsKept,
+renormalise, bias; scale} (``Router``), {scaled; factor, originalPositions,
+betaFast, betaSlow, mscale, mscaleAllDim} (``RopeScaling``: YaRN). Such a
+file has no legacy rope gap, and its layers are of two kinds, dense first
+(``layer_plans`` is the file order, the one place that says it):
+
+  attention_norm, ffn_norm, q_a_norm (F32 qRank), kv_a_norm (F32 kvRank),
+  wq_a (qRank x dim), wq_b (nHeads (nope + rope) x qRank),
+  wkv_a ((kvRank + rope) x dim), wkv_b (nHeads (nope + v) x kvRank),
+  wo (dim x nHeads v)                     [weightsFloatType]
+  a dense layer:   w1, w2, w3 at denseHidden
+  an expert layer: router (F32, nExperts x dim), [router_bias (F32 nExperts)],
+                   sh_w1, sh_w2, sh_w3 at sharedExperts x hidden,
+                   per HELD expert e: w1_e, w2_e, w3_e
+
+In the param tree the expert layers' tensors are the top-level stacks (their
+leading axis counts expert layers only; ``moe_w*`` are (L_e, held, d, n),
+``moe_bias`` (L_e, nExperts)) and the leading dense layers' are the same
+keys under ``params["dense"]``.
 """
 
 from __future__ import annotations
@@ -71,8 +99,70 @@ EXT_VERSION = 2
 EXT_STRUCT = struct.Struct("<13i")
 EXT3_VERSION = 3
 EXT3_STRUCT = struct.Struct("<14i2d")   # ... attnKind, theta, eps
-MAX_HEADER_BYTES = EXT3_STRUCT.size
+EXT4_VERSION = 4
+EXT4_STRUCT = struct.Struct("<14i2d16i7d")
+MAX_HEADER_BYTES = EXT4_STRUCT.size
 ATTN_KINDS = ("softmax", "retention")
+ROUTER_SCORINGS = ("softmax", "sigmoid")
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttn:
+    """Latent attention: q through a low rank, and ONE cached plane of
+    ``kv_rank + rope_dim`` values a position that all heads read (its first
+    ``kv_rank`` columns are also the values). Head sizes are stated, not
+    derived from ``dim // n_heads``."""
+    q_rank: int
+    kv_rank: int
+    nope_dim: int    # a head's q / k part that carries no position
+    rope_dim: int    # ... and the part RoPE rotates (k's is shared by heads)
+    v_dim: int
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def width(self) -> int:
+        """Values cached a position and layer: [c_kv | k_rope]."""
+        return self.kv_rank + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayout:
+    """Which layers are dense and which experts live here: ``dense_layers``
+    leading layers have a SwiGLU of ``dense_hidden``; the rest route over
+    ``n_experts`` and add ``shared`` always-on experts (one SwiGLU of
+    ``shared * hidden_dim``); this file holds routed experts
+    ``offset .. offset + held - 1`` of the router's width (held 0 = all)."""
+    dense_layers: int = 0
+    dense_hidden: int = 0
+    shared: int = 0
+    held: int = 0
+    offset: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """How an expert layer scores and chooses (ops/pallas_moe.route)."""
+    scoring: str = "softmax"
+    groups: int = 1          # experts are split into this many groups ...
+    groups_kept: int = 1     # ... and chosen among the best so many
+    renormalise: bool = False
+    scale: float = 1.0
+    bias: bool = False       # a (n_experts,) bias added for the CHOICE only
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN: frequencies blended between f and f / factor over the
+    correction range, and the attention scale's m^2."""
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,6 +189,11 @@ class TransformerSpec:
     attn_kind: str = "softmax"
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
+    # grouped records of header version 4 (None / defaults: as before)
+    latent: LatentAttn | None = None
+    layout: ExpertLayout = ExpertLayout()
+    router: Router = Router()
+    rope_scaling: RopeScaling | None = None
 
     def __post_init__(self):
         if self.attn_kind not in ATTN_KINDS:
@@ -110,6 +205,34 @@ class TransformerSpec:
         if self.retention and (self.n_experts or self.head_size % 2):
             raise ValueError("a retention spec has a dense FFN and an even "
                              "head size")
+        lay = self.layout
+        if self.router.scoring not in ROUTER_SCORINGS:
+            raise ValueError(f"router scoring {self.router.scoring!r}: "
+                             f"expected one of {ROUTER_SCORINGS}")
+        if (lay != ExpertLayout() or self.router != Router()) and not (
+                self.n_experts):
+            raise ValueError("an expert layout or a router kind says how an "
+                             "expert spec's layers lie: set n_experts too")
+        if self.n_experts and (
+                not 0 <= lay.dense_layers < self.n_layers
+                or bool(lay.dense_layers) != bool(lay.dense_hidden)
+                or lay.offset + (lay.held or self.n_experts) > self.n_experts
+                or self.n_experts % self.router.groups
+                or not 0 < self.router.groups_kept <= self.router.groups):
+            raise ValueError(f"{lay} / {self.router} do not fit n_layers="
+                             f"{self.n_layers}, n_experts={self.n_experts}")
+        if self.latent and (self.retention or self.qk_norm
+                            or self.latent.rope_dim % 2):
+            raise ValueError("a latent-attention spec is softmax attention "
+                             "without q/k-norm, with an even rope_dim")
+        if not self.latent and (lay != ExpertLayout() or self.rope_scaling
+                                or self.router != Router()):
+            raise ValueError("an expert layout, a router kind and a RoPE "
+                             "scaling are run by the latent-attention "
+                             "forward only (models/latent.py): set latent")
+        if self.latent and not self.n_experts:
+            raise ValueError("a latent-attention spec has expert layers "
+                             "after its leading dense ones: set n_experts")
         if bool(self.n_experts) != bool(self.n_active_experts) or not (
                 0 <= self.n_active_experts <= self.n_experts):
             raise ValueError(
@@ -124,7 +247,11 @@ class TransformerSpec:
 
     @property
     def header_version(self) -> int:
-        """0 (the 28-byte header), 2 or 3: the lowest that holds the spec."""
+        """0 (the 28-byte header), 2, 3 or 4: the lowest that holds the
+        spec."""
+        if (self.latent or self.rope_scaling or self.layout != ExpertLayout()
+                or self.router != Router()):
+            return EXT4_VERSION
         if (self.retention or self.qk_norm_per_head
                 or self.rope_theta != 10000.0 or self.norm_eps != 1e-5):
             return EXT3_VERSION
@@ -138,11 +265,35 @@ class TransformerSpec:
     @property
     def header_bytes(self) -> int:
         return {0: HEADER_BYTES, EXT_VERSION: EXT_STRUCT.size,
-                EXT3_VERSION: EXT3_STRUCT.size}[self.header_version]
+                EXT3_VERSION: EXT3_STRUCT.size,
+                EXT4_VERSION: EXT4_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
-        return self.dim // self.n_heads
+        """A q / k head: derived, unless a latent spec states it."""
+        return self.latent.qk_dim if self.latent else self.dim // self.n_heads
+
+    @property
+    def n_experts_held(self) -> int:
+        """Routed experts whose weights this spec holds (all, unless the
+        layout says a share)."""
+        return self.layout.held or self.n_experts
+
+    @property
+    def held_columns(self) -> slice:
+        """The columns of an (L, E) routed-rows count (the router's full
+        width: ops/pallas_moe.moe_ffn) that belong to experts held here."""
+        off = self.layout.offset
+        return slice(off, off + self.n_experts_held)
+
+    @property
+    def n_dense_layers(self) -> int:
+        return self.layout.dense_layers
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.layout.dense_layers if self.n_experts \
+            else 0
 
     @property
     def kv_dim(self) -> int:
@@ -162,7 +313,8 @@ class TransformerSpec:
         if struct.unpack_from("<i", raw)[0] == EXT_MAGIC:
             version, count = struct.unpack_from("<2i", raw, 4)
             layout = {(EXT_VERSION, 10): EXT_STRUCT,
-                      (EXT3_VERSION, 13): EXT3_STRUCT}.get((version, count))
+                      (EXT3_VERSION, 13): EXT3_STRUCT,
+                      (EXT4_VERSION, 29): EXT4_STRUCT}.get((version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
@@ -170,11 +322,13 @@ class TransformerSpec:
                 raise ValueError("extended header truncated")
             _, _, _, *ints = layout.unpack(raw[:layout.size])
             base, ext = ints[:7], ints[7:10]
-            if version == EXT3_VERSION:
-                kind, theta, eps = ints[10:]
+            if version == EXT4_VERSION:
+                more = _read_ext4(ints[13:])
+            if version >= EXT3_VERSION:
+                kind, theta, eps = ints[10:13]
                 if not 0 <= kind < len(ATTN_KINDS):
                     raise ValueError(f"unknown attention kind {kind}")
-                more = dict(attn_kind=ATTN_KINDS[kind],
+                more = dict(more, attn_kind=ATTN_KINDS[kind],
                             qk_norm_per_head=ext[2] == 2,
                             rope_theta=float(theta), norm_eps=float(eps))
         else:
@@ -196,10 +350,24 @@ class TransformerSpec:
             return EXT_STRUCT.pack(EXT_MAGIC, EXT_VERSION, 10, *base,
                                    self.n_experts, self.n_active_experts,
                                    int(self.qk_norm))
-        return EXT3_STRUCT.pack(
-            EXT_MAGIC, EXT3_VERSION, 13, *base, self.n_experts,
-            self.n_active_experts, int(self.qk_norm) + self.qk_norm_per_head,
-            ATTN_KINDS.index(self.attn_kind), self.rope_theta, self.norm_eps)
+        v3 = (*base, self.n_experts, self.n_active_experts,
+              int(self.qk_norm) + self.qk_norm_per_head,
+              ATTN_KINDS.index(self.attn_kind), self.rope_theta,
+              self.norm_eps)
+        if self.header_version == EXT3_VERSION:
+            return EXT3_STRUCT.pack(EXT_MAGIC, EXT3_VERSION, 13, *v3)
+        la = self.latent or LatentAttn(0, 0, 0, 0, 0)
+        lay, ro = self.layout, self.router
+        rs = self.rope_scaling or RopeScaling(0.0, 0)
+        return EXT4_STRUCT.pack(
+            EXT_MAGIC, EXT4_VERSION, 29, *v3,
+            la.q_rank, la.kv_rank, la.nope_dim, la.rope_dim, la.v_dim,
+            lay.dense_layers, lay.dense_hidden, lay.shared, lay.held,
+            lay.offset, ROUTER_SCORINGS.index(ro.scoring), ro.groups,
+            ro.groups_kept, int(ro.renormalise), int(ro.bias),
+            int(self.rope_scaling is not None),
+            ro.scale, rs.factor, float(rs.original_positions), rs.beta_fast,
+            rs.beta_slow, rs.mscale, rs.mscale_all_dim)
 
     # -- per-tensor shapes (d, n) in file order ----------------------------
 
@@ -210,9 +378,29 @@ class TransformerSpec:
         d, h, kv = self.dim, self.hidden_dim, self.kv_dim
         attn = [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
                 ("wo", (d, d))]
+        if self.latent:
+            la, nh = self.latent, self.n_heads
+            attn = [("wq_a", (la.q_rank, d)),
+                    ("wq_b", (nh * la.qk_dim, la.q_rank)),
+                    ("wkv_a", (la.width, d)),
+                    ("wkv_b", (nh * (la.nope_dim + la.v_dim), la.kv_rank)),
+                    ("wo", (d, nh * la.v_dim))]
         if self.n_experts:
-            return attn
+            sh = self.layout.shared * h   # the shared experts: ONE SwiGLU
+            return attn + ([("sh_w1", (sh, d)), ("sh_w2", (d, sh)),
+                            ("sh_w3", (sh, d))] if sh else [])
         return attn + [("w1", (h, d)), ("w2", (d, h)), ("w3", (h, d))]
+
+    def dense_layer_matmul_shapes(self) -> list[tuple[str, tuple[int, int]]]:
+        """A LEADING DENSE layer's matmul tensors of an expert spec whose
+        layout has some (empty otherwise): the attention tensors and a
+        SwiGLU of ``layout.dense_hidden``."""
+        if not self.layout.dense_layers:
+            return []
+        d, h = self.dim, self.layout.dense_hidden
+        n_attn = 5 if self.latent else 4
+        return self.layer_matmul_shapes()[:n_attn] + [
+            ("w1", (h, d)), ("w2", (d, h)), ("w3", (h, d))]
 
     def expert_matmul_shapes(self) -> list[tuple[str, tuple[int, int]]]:
         """The tensors of ONE routed expert, in file order; a layer holds
@@ -227,12 +415,15 @@ class TransformerSpec:
         """((d, n), copies per layer) of every per-layer matmul tensor:
         what a size or layout gate walks."""
         return ([(shape, 1) for _, shape in self.layer_matmul_shapes()]
-                + [(shape, self.n_experts)
+                + [(shape, self.n_experts_held)
                    for _, shape in self.expert_matmul_shapes()])
 
     def layer_norm_shapes(self) -> list[tuple[str, int]]:
         """One layer's float32 gain vectors, in file order."""
         norms = [("rms_att", self.dim), ("rms_ffn", self.dim)]
+        if self.latent:
+            norms += [("rms_q_a", self.latent.q_rank),
+                      ("rms_kv_a", self.latent.kv_rank)]
         if self.qk_norm_per_head:
             norms += [("rms_q", self.head_size), ("rms_k", self.head_size)]
         elif self.qk_norm:
@@ -245,16 +436,62 @@ class TransformerSpec:
         file): one row a KV head. None for a softmax spec."""
         return (self.n_kv_heads, self.dim) if self.retention else None
 
+    def layer_plans(self):
+        """The file's layers in order, for a spec whose layers are of two
+        kinds: ``(stack, index, entries)`` a layer, ``stack`` "dense" (a
+        leading dense layer, in ``params["dense"]``) or "" (an expert
+        layer, the top-level stacks), ``index`` the layer's place in its
+        stack, and ``entries`` its tensors in file order: ("f32", name,
+        shape) or ("mm", name, (d, n)[, expert]). Per-layer kinds of any
+        pattern can take this list's place."""
+        norms = [("f32", n, (w,)) for n, w in self.layer_norm_shapes()]
+        dense = norms + [("mm", n, s)
+                         for n, s in self.dense_layer_matmul_shapes()]
+        shared = self.layer_matmul_shapes()
+        n_attn = len(shared) - (3 if self.layout.shared else 0)
+        expert = norms + [("mm", n, s) for n, s in shared[:n_attn]]
+        expert.append(("f32", "moe_gate", (self.n_experts, self.dim)))
+        if self.router.bias:
+            expert.append(("f32", "moe_bias", (self.n_experts,)))
+        expert += [("mm", n, s) for n, s in shared[n_attn:]]
+        expert += [("mm", n, s, e) for e in range(self.n_experts_held)
+                   for n, s in self.expert_matmul_shapes()]
+        k = self.layout.dense_layers
+        return ([("dense", i, dense) for i in range(k)]
+                + [("", i, expert) for i in range(self.n_layers - k)])
+
+    def stack_leaves(self):
+        """(stack, name, kind, stacked shape) of every leaf of the two layer
+        stacks ``layer_plans`` walks, once each: the leading axis counts the
+        stack's layers, an expert tensor's second axis the experts held."""
+        depth = {"dense": self.n_dense_layers, "": self.n_expert_layers}
+        seen, out = set(), []
+        for stack, _, entries in self.layer_plans():
+            for kind, name, shape, *e in entries:
+                if (stack, name) not in seen:
+                    seen.add((stack, name))
+                    lead = (depth[stack],
+                            *([self.n_experts_held] if e else []))
+                    out.append((stack, name, kind, (*lead, *shape)))
+        return out
+
     def matmul_bytes(self, shape: tuple[int, int]) -> int:
         dd, nn = shape
         return batch_bytes(self.weights_float_type, nn, dd)
 
     @property
     def rope_gap_bytes(self) -> int:
-        """Legacy freq_cis_real+imag region (transformer.cpp:338-339)."""
+        """Legacy freq_cis_real+imag region (transformer.cpp:338-339); a
+        version-4 file has none."""
+        if self.header_version == EXT4_VERSION:
+            return 0
         return 2 * (self.seq_len * self.head_size // 2) * 4
 
     def block_bytes(self) -> int:
+        """One layer's bytes in the file (an expert layer's, where a
+        version-4 spec has two kinds: ``file_size`` walks both)."""
+        if self.header_version == EXT4_VERSION:
+            return self._plan_bytes(self.layer_plans()[-1][2])
         b = sum(n * 4 for _, n in self.layer_norm_shapes())  # always F32
         b += self.n_experts * self.dim * 4                   # router, F32
         if self.retention:
@@ -263,12 +500,40 @@ class TransformerSpec:
             b += copies * self.matmul_bytes(shape)
         return b
 
+    def _plan_bytes(self, entries) -> int:
+        import math
+
+        return sum(4 * math.prod(e[2]) if e[0] == "f32"
+                   else self.matmul_bytes(e[2]) for e in entries)
+
     def file_size(self) -> int:
         """Byte-exact total, mirroring the check at transformer.cpp:344-348."""
         b = self.header_bytes
         b += self.vocab_size * self.dim * 4          # tok_embeddings, F32
-        b += self.n_layers * self.block_bytes()
+        if self.header_version == EXT4_VERSION:
+            b += sum(self._plan_bytes(e) for _, _, e in self.layer_plans())
+        else:
+            b += self.n_layers * self.block_bytes()
         b += self.dim * 4                            # rmsFinal, F32
         b += self.rope_gap_bytes
         b += self.matmul_bytes((self.vocab_size, self.dim))  # wcls
         return b
+
+
+def _read_ext4(vals) -> dict:
+    """The grouped records of a version-4 header from its sixteen ints and
+    seven float64 (``TransformerSpec.header`` has the order)."""
+    (q_rank, kv_rank, nope, rope, v_dim, dense_layers, dense_hidden, shared,
+     held, offset, scoring, groups, kept, renorm, bias, scaled,
+     scale, factor, orig, b_fast, b_slow, mscale, mscale_all) = vals
+    if not 0 <= scoring < len(ROUTER_SCORINGS):
+        raise ValueError(f"unknown router scoring {scoring}")
+    return dict(
+        latent=LatentAttn(q_rank, kv_rank, nope, rope, v_dim)
+        if kv_rank else None,
+        layout=ExpertLayout(dense_layers, dense_hidden, shared, held, offset),
+        router=Router(ROUTER_SCORINGS[scoring], groups, kept, bool(renorm),
+                      float(scale), bool(bias)),
+        rope_scaling=RopeScaling(float(factor), int(orig), float(b_fast),
+                                 float(b_slow), float(mscale),
+                                 float(mscale_all)) if scaled else None)

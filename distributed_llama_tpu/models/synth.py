@@ -20,6 +20,8 @@ from .spec import TransformerSpec
 def _build_tree(spec: TransformerSpec, t, mm) -> dict:
     """Assemble the param tree from a dense builder ``t`` and a matmul-weight
     builder ``mm`` — the one place that knows the tree's key set."""
+    if spec.latent:
+        return _build_planned_tree(spec, t, mm)
     # draw order is part of the seed's meaning: a dense spec's tree is the
     # one it always was (rms_att, rms_ffn before wcls)
     p = {"tok_embedding": t(spec.vocab_size, spec.dim),
@@ -42,6 +44,30 @@ def _build_tree(spec: TransformerSpec, t, mm) -> dict:
         # router rows ~N(0, 1/sqrt(dim)): t() draws at std 0.05
         p["moe_gate"] = (t(spec.n_layers, spec.n_experts, spec.dim)
                          * np.float32(20.0 / np.sqrt(spec.dim)))
+    return p
+
+
+def _build_planned_tree(spec: TransformerSpec, t, mm) -> dict:
+    """``_build_tree`` for a spec with two stacks of layers (the leading
+    dense ones under ``p["dense"]``): the keys and shapes are
+    ``spec.layer_plans``'s. Router rows ~N(0, 1/sqrt(dim)); its bias
+    ~N(0, 0.05), so that the choice (on s + b) and the weights (on s)
+    differ."""
+    from ..io.loader import stack_of
+
+    p = {"tok_embedding": t(spec.vocab_size, spec.dim),
+         "rms_final": 1 + t(spec.dim),
+         "wcls": mm(spec.vocab_size, spec.dim)}
+    for stack, name, kind, shape in spec.stack_leaves():
+        dst = stack_of(p, stack)
+        if kind == "mm":
+            dst[name] = mm(*shape)
+        elif name == "moe_gate":
+            dst[name] = t(*shape) * np.float32(20.0 / np.sqrt(spec.dim))
+        elif name == "moe_bias":
+            dst[name] = t(*shape)
+        else:
+            dst[name] = 1 + t(*shape)
     return p
 
 
@@ -245,7 +271,17 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
     with open(path, "wb") as f:
         f.write(spec.header())
         f.write(f32(spec.vocab_size, spec.dim))
-        for _ in range(spec.n_layers):
+        for _, _, entries in (spec.layer_plans() if spec.latent else ()):
+            for kind, name, shape, *_ in entries:
+                if kind == "mm":
+                    f.write(q40(*shape))
+                elif name == "moe_gate":
+                    f.write(f32(*shape, scale=1.0 / np.sqrt(spec.dim)))
+                elif name == "moe_bias":
+                    f.write(f32(*shape, scale=0.05))
+                else:
+                    f.write(f32(*shape, base=1.0, scale=0.05))
+        for _ in range(0 if spec.latent else spec.n_layers):
             for _, n in spec.layer_norm_shapes():   # rms_att, rms_ffn, ...
                 f.write(f32(n, base=1.0, scale=0.05))
             for name, (d, n) in spec.layer_matmul_shapes():

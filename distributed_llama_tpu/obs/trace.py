@@ -203,6 +203,14 @@ class EngineMetrics:
             "dllama_moe_active_experts_total",
             "Distinct experts a decode dispatch routed to, summed over "
             "layers and dispatches: the expert tiles a step must read")
+        self.moe_local_pairs = c(
+            "dllama_moe_local_pairs_total",
+            "Routed pairs that landed on an expert this engine holds (all "
+            "of them, unless the model file holds a share of the experts)")
+        self.latent_pages = g(
+            "dllama_latent_pages_in_use",
+            "Pool pages the sequences of a latent-attention model hold "
+            "(one plane of latent.width values a position and layer)")
         self._moe_rows: list = []
         # step_once's run-ahead (ContinuousStats.steps_ahead /
         # rows_dropped_ahead): how often the decode iteration engages
@@ -434,10 +442,12 @@ class EngineMetrics:
             launches.inc(n * steps)
             moved.inc(b * steps)
 
-    def record_moe(self, counts) -> None:
-        """One decode dispatch's (L, E) rows-per-expert counts."""
+    def record_moe(self, counts, held: slice = slice(None)) -> None:
+        """One decode dispatch's (L, E) rows-per-expert counts; ``held``
+        the columns of the experts the engine holds."""
         self.moe_pairs.inc(int(counts.sum()))
-        self.moe_active.inc(int((counts > 0).sum()))
+        self.moe_local_pairs.inc(int(counts[:, held].sum()))
+        self.moe_active.inc(int((counts[:, held] > 0).sum()))
         if not self._moe_rows:
             self._moe_rows = [
                 self.registry.labeled_counter(

@@ -179,14 +179,14 @@ class Q40Layout(NamedTuple):
     and hands it down as an argument (loader, sidecar key, packer, chain
     builder); nothing reads it back from the process."""
 
-    label: str    # "i4-nb" | "d-major"
+    label: str    # "i4-nb" | "nb-major" | "d-major"
     reason: str
 
     @property
     def force_nb_major(self) -> bool:
         """Every leaf the nb-major row tiler places packs nb-major (the i4
         body exists only there, so pad-free 7B-class shapes need it)."""
-        return self.label == "i4-nb"
+        return self.label in ("i4-nb", "nb-major")
 
     @property
     def i4_chain(self) -> bool:
@@ -282,6 +282,9 @@ def pack_q40_params(params: dict, enable: bool | None = None,
         layout = _APPLIED_LAYOUT or Q40_STOCK  # the shim's ONE reader
 
     def pick(k, v):
+        if isinstance(v, dict):     # a second stack of layers ("dense")
+            return pack_q40_params(v, enable, tp, allow_nb_major,
+                                   input_sharded, layout)
         if not isinstance(v, Q40Weight):
             return v
         d, n = v.logical_shape[-2], v.logical_shape[-1]
@@ -327,7 +330,8 @@ def fuse_q40_layer_matmuls(params: dict) -> dict:
     """
     from .pallas_q40 import _pick_rows_nb, kernel_supports
 
-    out = dict(params)
+    out = {k: fuse_q40_layer_matmuls(v) if isinstance(v, dict) else v
+           for k, v in params.items()}
 
     def fuse(dst, keys):
         # host numpy tree by contract (runs after pack_q40_params, before
@@ -356,6 +360,7 @@ def fuse_q40_layer_matmuls(params: dict) -> dict:
     fuse("wqkv", ("wq", "wk", "wv"))
     fuse("w13", ("w1", "w3"))
     fuse("moe_w13", ("moe_w1", "moe_w3"))
+    fuse("sh_w13", ("sh_w1", "sh_w3"))       # a shared expert's
     return out
 
 
@@ -384,6 +389,9 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     nb = 344, is placed transposed by the device client and copied
     row-major inside every step).
 
+    An expert spec gets ``nb-major`` (every dense leaf the row tiler
+    places forced nb-major, u8 bodies) where the stock picks would leave
+    one of its dense leaves d-major at an ``nb`` off the 128 grid. Else
     ``i4-nb`` iff ALL of (else ``d-major``: the stock per-leaf picks, u8
     bodies):
       * the Pallas kernel path is active (TPU; elsewhere layouts are moot),
@@ -404,6 +412,28 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     counted = spec.matmul_shape_counts()     # a layer's, experts included
     shapes = [shape for shape, _ in counted]
     shapes.append((spec.vocab_size, spec.dim))  # wcls
+    if spec.n_experts:
+        # no expert kernel has an i4 body, so an expert spec's label says
+        # only how its DENSE leaves pack. The stock picks leave a leaf
+        # d-major while lane padding costs it under a quarter (nb 224 pads
+        # to 256), but the chip stores a minor dim off the 128 grid
+        # transposed, and the step then copies such a leaf row-major every
+        # time it runs (nb 344: PR 21; nb 224 sharded: PR 24; nb 448: PR
+        # 32). A dense spec escapes that by i4-nb; an expert spec with such
+        # a leaf forces every dense leaf the row tiler places nb-major.
+        dense = [shape for _, shape in (spec.layer_matmul_shapes()
+                                        + spec.dense_layer_matmul_shapes())]
+        copied = [(d, n) for d, n in dense + shapes[-1:]
+                  if (n // 32) % 128 and _pick_rows_nb(d, n // 32) is not None
+                  and q40_leaf_layout(d, n // 32) == "d-major"]
+        if copied:
+            d, n = copied[0]
+            return Q40Layout("nb-major", (
+                f"expert spec: the stock picks would leave shape {(d, n)} "
+                f"d-major at nb {n // 32}, off the 128 grid, which the chip "
+                f"stores transposed and every step copies row-major: every "
+                f"dense leaf the row tiler places packs nb-major, u8 bodies "
+                f"(expert stacks always pack nb-major)"))
     bad = [(d, n) for d, n in shapes if _pick_rows_nb(d, n // 32) is None]
     if bad:
         return Q40Layout("d-major", (

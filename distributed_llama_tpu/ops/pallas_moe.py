@@ -8,6 +8,15 @@ The FFN sub-block of an expert spec (``TransformerSpec.n_experts > 0``):
   keep the k largest p AS THEY ARE (no renormalisation)
   y = sum_e p_e * w2_e( silu(w1_e x) * w3_e x )      over the kept e
 
+``TransformerSpec.router`` states the other kind (``route``): sigmoid
+scores, a choice on score + bias limited to the best groups, weights from
+the unbiased scores, renormalised and scaled. And the spec's layout may
+hold a SHARE of the experts (``n_experts_held`` from ``layout.offset``): the
+router keeps its full width, the layer computes the pairs that landed on
+the experts held here and drops the rest (no stand-in for the chips that
+hold the others, no exchange), and the counts returned keep the router's
+width, so the share of pairs that landed here can be read.
+
 Expert weights stay Q40, stacked (L, E, ...) in the nb-major kernel layout
 (io/loader.Q40KernelNb: the output dim rides the lanes, so OLMoE's block
 counts 64 and 32 pad nothing and the chip stores the stack as it is packed;
@@ -55,14 +64,38 @@ MOE_SLOT_ROWS = 4                # rows one slot carries: the VPU body's cap
 MOE_SLOT_T_MAX = 32              # wider dispatches take the MXU body
 
 
-def route(gate: jax.Array, xb: jax.Array, k: int):
-    """Router of one layer over rows ``xb`` (T, dim): the k largest softmax
-    probabilities of each row as they are, (T, k) weights and expert ids."""
+def route(gate: jax.Array, xb: jax.Array, k: int, router=None, bias=None):
+    """Router of one layer over rows ``xb`` (T, dim): (T, k) weights and
+    expert ids. ``router`` None (or the default record): the k largest
+    softmax probabilities as they are. Else (``models/spec.Router``):
+    s = sigmoid or softmax of the logits; the choice is on c = s + ``bias``;
+    with groups, a group's score is the sum of its two largest c, the
+    ``groups_kept`` best groups stay and every other expert's c is -inf;
+    the k largest c are chosen (``lax.top_k``: the lower index wins a tie);
+    their weights are the UNBIASED s, over their sum (+ 1e-20) if
+    ``renormalise``, times ``scale``."""
     logits = jnp.einsum("ed,td->te", gate.astype(jnp.float32),
                         xb.astype(jnp.float32),
                         preferred_element_type=jnp.float32,
                         precision=jax.lax.Precision.HIGHEST)
-    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if router is None or (router.scoring == "softmax" and router.groups == 1
+                          and not router.renormalise and bias is None
+                          and router.scale == 1.0):
+        return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    s = (jax.nn.sigmoid(logits) if router.scoring == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    c = s if bias is None else s + bias.astype(jnp.float32)
+    if router.groups > 1:
+        per = c.reshape(c.shape[0], router.groups, -1)
+        score = jnp.sum(jax.lax.top_k(per, min(2, per.shape[-1]))[0], axis=-1)
+        _, kept = jax.lax.top_k(score, router.groups_kept)
+        keep = jnp.any(kept[..., None] == jnp.arange(router.groups), axis=1)
+        c = jnp.where(keep[..., None], per, -jnp.inf).reshape(c.shape)
+    _, topi = jax.lax.top_k(c, k)
+    topw = jnp.take_along_axis(s, topi, axis=1)
+    if router.renormalise:
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20)
+    return topw * jnp.float32(router.scale), topi
 
 
 def max_slots(t: int, k: int, n_experts: int, cap: int) -> int:
@@ -75,6 +108,8 @@ def max_slots(t: int, k: int, n_experts: int, cap: int) -> int:
 
 def build_slots(topi: jax.Array, n_experts: int, cap: int):
     """Group the (T, k) routed pairs by expert into slots of ``cap`` rows.
+    A pair whose id is -1 (it landed on an expert held elsewhere) takes no
+    slot: its ``pair_slot`` is the out-of-range ``A``.
 
     Returns ``slot_expert`` (A,) expert of each slot, ascending, the slots
     past the live count repeating the last live expert (no new tile is
@@ -94,13 +129,13 @@ def build_slots(topi: jax.Array, n_experts: int, cap: int):
     per = (counts + cap - 1) // cap                # slots of each expert
     ends = jnp.cumsum(per)
     n_slots = ends[-1]
-    slot = (ends - per)[flat] + rank // cap
+    slot = jnp.where(flat >= 0, (ends - per)[flat] + rank // cap, a)
     lane = rank % cap
     slot_expert = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(a, dtype=jnp.int32), side="right"),
-        jnp.max(flat)).astype(jnp.int32)
+        jnp.maximum(jnp.max(flat), 0)).astype(jnp.int32)
     slot_rows = jnp.zeros((a, cap), jnp.int32).at[slot, lane].set(
-        jnp.arange(t * k, dtype=jnp.int32) // k)
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
     return (slot_expert, n_slots.astype(jnp.int32), slot_rows,
             slot.reshape(t, k), lane.reshape(t, k), counts)
 
@@ -317,6 +352,8 @@ def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret):
     hid = h13.shape[-1] // 2
     out = call(w2, silu(h13[..., :hid]) * h13[..., hid:])   # (A, C, dim)
     picked = out[pair_slot, pair_lane]                   # (T, k, dim)
+    # a pair that took no slot reads whatever lies at the clamped index
+    picked = jnp.where((topi >= 0)[..., None], picked, 0.0)
     return jnp.sum(picked * topw[..., None], axis=1), counts
 
 
@@ -368,12 +405,26 @@ def _experts_xla(lw, xb, topw, topi, n_experts):
 def moe_ffn(spec, lw: dict, xb: jax.Array):
     """The routed-expert FFN of one layer over normalised rows ``xb``
     ((T, dim) or (B, T, dim)): returns (y like xb, counts (E,) int32 of
-    rows routed to each expert in this dispatch)."""
+    rows routed to each expert in this dispatch, over the router's FULL
+    width). Where the spec holds a share of the experts, y is the part the
+    held experts give."""
     lead = xb.shape[:-1]
     x2 = xb.reshape(-1, xb.shape[-1])
-    n_exp, k = spec.n_experts, spec.n_active_experts
+    k, held = spec.n_active_experts, spec.n_experts_held
     with jax.named_scope(SCOPE_MOE_ROUTER):
-        topw, topi = route(lw["moe_gate"], x2, k)
+        # the Router record alone decides: the default one is the old path
+        topw, topi = route(lw["moe_gate"], x2, k, spec.router,
+                           lw.get("moe_bias"))
+        routed = None
+        if held != spec.n_experts:
+            routed = jnp.sum(topi[..., None] == jnp.arange(
+                spec.n_experts, dtype=topi.dtype), axis=(0, 1),
+                dtype=jnp.int32)
+            local = topi - spec.layout.offset
+            here = (local >= 0) & (local < held)
+            topi = jnp.where(here, local, -1)
+            topw = jnp.where(here, topw, 0.0)
+    n_exp = held
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         w13, w2 = lw.get("moe_w13"), lw.get("moe_w2")
         if isinstance(w13, StackedQ40) and isinstance(w2, StackedQ40):
@@ -392,4 +443,4 @@ def moe_ffn(spec, lw: dict, xb: jax.Array):
                 "moe_w13 (ops/linear.fuse_q40_layer_matmuls)")
         else:
             y, counts = _experts_xla(lw, x2, topw, topi, n_exp)
-    return y.reshape(*lead, -1), counts
+    return y.reshape(*lead, -1), counts if routed is None else routed

@@ -247,6 +247,53 @@ def retention_refusals(*, tp: int = 1, page_size: int = 0, kv_pages: int = 0,
     return out
 
 
+def latent_refusals(*, tp: int = 1, page_size: int = 0,
+                    spec_k: int = 0, dispatch_tokens: int = 0,
+                    kv_quant: str = "f32", kv_host_pages: int = 0,
+                    kv_disk_dir=None, disagg: bool = False,
+                    block_steps: int = 1, kv_cache_dtype: str = "f32",
+                    serve: bool = True) -> list[str]:
+    """What a latent-attention spec cannot run, one line for each feature
+    asked for, naming its flag and the reason. Its cache is ONE plane of
+    ``latent.width`` values a position (models/latent.py) behind the same
+    page tables as a KV pool: what works on page ids and token ids (prefix
+    sharing, the journal) runs; what reads or writes the two planes of a KV
+    page does not carry the one plane yet, and is refused by name here, in
+    the engine and in the CLI. Nothing stands in for a refused feature."""
+    why = "a latent-attention model caches one plane [c_kv | k_rope] a " \
+          "layer, not K and V"
+    out = []
+    if tp > 1:
+        out.append(f"--tp {tp}: {why}; neither the latent plane nor the "
+                   f"experts held here are placed over tensor-parallel "
+                   f"ranks")
+    if serve and not page_size:
+        out.append(f"serve without --kv-page-size: {why}; serve reads it "
+                   f"through pages only (pass --kv-page-size)")
+    if kv_quant != "f32":
+        out.append(f"--kv-quant {kv_quant}: {why}; q8 pages quantize a "
+                   f"(n_kv, head) row in K and V planes")
+    if kv_host_pages or kv_disk_dir:
+        out.append(f"--kv-host-pages / --kv-disk-dir: {why}; the host and "
+                   f"disk tiers spill and promote (k, v) page planes")
+    if disagg:
+        out.append(f"--disagg-role: {why}; the page wire and the handoff "
+                   f"ship (k, v) page planes")
+    if spec_k:
+        out.append(f"--spec-k {spec_k}: {why}; the verify window has no "
+                   f"latent attention")
+    if dispatch_tokens:
+        out.append(f"--dispatch-tokens {dispatch_tokens}: {why}; the mixed "
+                   f"window has no latent attention")
+    if block_steps > 1:
+        out.append(f"--block-steps {block_steps}: {why}; the fused chain "
+                   f"was not carried over to it")
+    if kv_cache_dtype != "f32":
+        out.append(f"--kv-cache-dtype {kv_cache_dtype}: {why}; the plane is "
+                   f"float32 (what the reference's tolerance was read on)")
+    return out
+
+
 @dataclasses.dataclass
 class _Slot:
     req: Request | None = None   # None = free
@@ -360,9 +407,22 @@ class ContinuousStats:
     # (row, expert) pairs routed, distinct experts summed over layers and
     # steps (the expert tiles a step must read), and the rows each expert
     # took summed over layers (an (E,) vector: how uneven the router is)
+    # Where the spec holds a SHARE of the experts the counts keep the
+    # router's width: ``moe_pairs`` and ``moe_load`` are of every pair
+    # routed, ``moe_local_pairs`` of those that landed on an expert held
+    # here, and ``moe_active`` counts held experts only (all of them, for a
+    # spec that holds every expert: local == pairs then)
     moe_pairs: int = 0
+    moe_local_pairs: int = 0
     moe_active: int = 0
     moe_load: Any = None
+    # a latent spec: pool pages in use (each page_size positions x
+    # latent.width x layers of ONE plane; pages the prefix tree keeps
+    # count), as of the last landed step; and the cached positions the
+    # launched decode steps' rows read, summed (a row at position p reads
+    # p + 1): what the latent decode kernel must move, a layer
+    latent_pages: int = 0
+    latent_positions: int = 0
     # step_once's run-ahead: steps launched on the previous step's picks
     # while those were still on the device, and rows of such steps whose
     # result was thrown away (the row had stopped on a token only the
@@ -376,10 +436,12 @@ class ContinuousStats:
     state_bytes: int = 0
     min_normaliser: float = float("inf")
 
-    def count_moe(self, counts) -> None:
-        """One dispatch's (L, E) rows-per-expert counts."""
+    def count_moe(self, counts, held: slice = slice(None)) -> None:
+        """One dispatch's (L, E) rows-per-expert counts; ``held`` the
+        columns of the experts held here."""
         self.moe_pairs += int(counts.sum())
-        self.moe_active += int((counts > 0).sum())
+        self.moe_local_pairs += int(counts[:, held].sum())
+        self.moe_active += int((counts[:, held] > 0).sum())
         load = counts.sum(axis=0, dtype=np.int64)
         self.moe_load = load if self.moe_load is None else self.moe_load + load
 
@@ -443,6 +505,18 @@ class ContinuousEngine:
                 spec_k=spec_k, dispatch_tokens=dispatch_tokens,
                 kv_quant=kv_quant, kv_host_pages=kv_host_pages,
                 kv_disk_dir=kv_disk_dir, journal=journal is not None,
+                disagg=remote_pages, block_steps=block_steps,
+                kv_cache_dtype="f32" if cache_dtype in (
+                    None, jnp.float32) else str(cache_dtype))
+            if refused:
+                raise ValueError("; ".join(refused))
+        if spec.latent:
+            refused = latent_refusals(
+                tp=max(mesh.shape["tp"], mesh.shape.get("sp", 1))
+                if mesh is not None else 1,
+                page_size=page_size, spec_k=spec_k,
+                dispatch_tokens=dispatch_tokens, kv_quant=kv_quant,
+                kv_host_pages=kv_host_pages, kv_disk_dir=kv_disk_dir,
                 disagg=remote_pages, block_steps=block_steps,
                 kv_cache_dtype="f32" if cache_dtype in (
                     None, jnp.float32) else str(cache_dtype))
@@ -683,7 +757,11 @@ class ContinuousEngine:
                 self._scratch_cache = lambda: shard_cache(
                     init_cache(spec, dtype), mesh)
         else:
-            self.params = params_to_device(params, layout=self.q40_layout)
+            # a latent spec's tree is prepared by its spec (the absorbed
+            # halves of wkv_b); no other serve tree takes the spec's extras
+            self.params = params_to_device(
+                params, layout=self.q40_layout,
+                spec=spec if spec.latent else None)
             if self._alloc is not None:
                 self.cache = (
                     init_cache_paged_q8(spec, self._alloc.n_pages + 1,
@@ -2340,6 +2418,10 @@ class ContinuousEngine:
                     row[2] = rows[b] is not None
             if prev is not None and not any(r is not None for r in rows):
                 return None
+            if self.spec.latent:
+                self.stats.latent_positions += sum(
+                    int(blk[b, 1]) + 1 for b, s in enumerate(rows)
+                    if s is not None)
             staged = self.jnp.asarray(blk)
         with host_phase("serve.dispatch"):
             logits, picked, self.cache, *more = self._decode(
@@ -2375,9 +2457,15 @@ class ContinuousEngine:
                         self._obs.retention_min_normaliser.set(low)
             if flight.moe is not None:  # 4 KB beside them
                 moe = np.asarray(flight.moe)  # dlint: allow[D001] routed-rows counters
-                self.stats.count_moe(moe)
+                held = self.spec.held_columns
+                self.stats.count_moe(moe, held)
                 if self._obs is not None:
-                    self._obs.record_moe(moe)
+                    self._obs.record_moe(moe, held)
+            if self.spec.latent:
+                self.stats.latent_pages = (self._alloc.n_pages
+                                           - self._alloc.n_free)
+                if self._obs is not None:
+                    self._obs.latent_pages.set(self.stats.latent_pages)
         return out, on_host
 
     def _land(self, flight: _Flight, out, on_host: bool, quiet: bool) -> None:

@@ -242,7 +242,8 @@ class Engine:
             if step.moe:  # an expert spec on one chip: 4 KB beside the rest
                 counts = np.asarray(step.moe[0])  # dlint: allow[D001] routed-rows counters
                 self.moe_pairs += int(counts.sum())
-                self.moe_active += int((counts > 0).sum())
+                self.moe_active += int(
+                    (counts[:, self.spec.held_columns] > 0).sum())
             if not pick:
                 return np.asarray(step.logits)[0]  # dlint: allow[D001] host sampler input
             out = int(np.asarray(step.picked)[0])  # dlint: allow[D001] four bytes a token

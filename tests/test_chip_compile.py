@@ -40,7 +40,12 @@ LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
           "wcls": (VOCAB, DIM),
           # Mistral-7B's FFN (hidden 14336: w2 has 448 blocks a row), the
           # leaves the two 8-slot serving cells stream
-          "m-w13": (2 * 14336, DIM), "m-w2": (DIM, 14336)}
+          "m-w13": (2 * 14336, DIM), "m-w2": (DIM, 14336),
+          # DeepSeek-V3's new leaf shapes (in 7168: 224 blocks a row): the
+          # latent row's projection padded from 576 to 640 outputs, q_b
+          # (48 blocks), wo (512) and the dense layer's w2 (576 blocks)
+          "ds-wkv_a": (640, 7168), "ds-wq_b": (24576, 1536),
+          "ds-wo": (7168, 16384), "ds-w2": (7168, 18432)}
 
 
 def _sd(shape, dtype):
@@ -162,15 +167,32 @@ def _paged_q8(ps: int = 16):
              _sd((b, SEQ // ps), jnp.int32)))
 
 
-def _moe(kind: str, leaf: str, rows: int):
+def _latent_paged(ps: int = 16):
+    """The latent decode kernel (ops/pallas_latent_attention) at
+    DeepSeek-V3's widths: 32 rows of 128 heads over pages of 16 positions
+    of ONE plane, 576 values in 640 lanes, two layers' pool."""
+    from distributed_llama_tpu.ops.pallas_latent_attention import (
+        latent_paged_decode)
+
+    b, pages, heads, plane = 32, 64, 128, 640
+    return (functools.partial(latent_paged_decode, page_size=ps,
+                              n_pages=pages, kv_rank=512, interpret=False),
+            (_sd((b, heads, plane), jnp.float32),
+             _sd((2 * pages, ps, plane), jnp.float32), _sd((), jnp.int32),
+             _sd((b,), jnp.int32), _sd((b, SEQ // ps), jnp.int32)))
+
+
+def _moe(kind: str, leaf: str, rows: int, model: str = "olmoe"):
     """The routed-expert kernels (ops/pallas_moe) at OLMoE-1B-7B's widths:
     64 experts a layer, ``w13`` (2 x 1024, 2048) and ``w2`` (2048, 1024),
-    nb-major. ``slots``: a decode dispatch of ``rows`` rows (8 experts a
-    row); ``mxu``: a prefill chunk of ``rows`` rows through every expert."""
+    nb-major (``model`` "ds": DeepSeek-V3's, 32 held experts of width 2048
+    on dim 7168). ``slots``: a decode dispatch of ``rows`` rows (8 experts
+    a row); ``mxu``: a prefill chunk of ``rows`` rows through every expert."""
     from distributed_llama_tpu.ops import pallas_moe as pm
 
-    n_exp, k = 64, 8
-    d, n = {"w13": (2048, 2048), "w2": (2048, 1024)}[leaf]
+    n_exp, k = (64, 8) if model == "olmoe" else (32, 8)
+    d, n = {"olmoe": {"w13": (2048, 2048), "w2": (2048, 1024)},
+            "ds": {"w13": (4096, 7168), "w2": (7168, 2048)}}[model][leaf]
     nb = n // 32
     qs_t = _sd((2, n_exp, 16, nb, d), jnp.uint8)
     scale = _sd((2, n_exp, nb, d), jnp.float32)
@@ -276,6 +298,21 @@ CASES = {
     # "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem" under
     # the default scoped limit (ops/pallas_q40._matvec_nb_params)
     "q40-nb-w2-nb544-T1": (_q40_wide_w2, True),
+    # DeepSeek-V3 (PR 33). The latent plane: was "Slice shape along
+    # dimension 2 must be aligned to tiling (128), but is 576" on
+    # memref<36873x16x640xf32> (the chip stores 576 values in 640 lanes
+    # whatever it is told: models/latent.plane_width pads the plane itself)
+    "latent-paged-ps16-B32": (_latent_paged, True),
+    # its new leaves at the cell's 32 rows, one row and the 128-row chunk;
+    # nb 576 runs under the raised scoped-VMEM limit at T = 1
+    **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)
+       for leaf in ("ds-wkv_a", "ds-wq_b", "ds-wo", "ds-w2")
+       for t in (1, 32)},
+    "q40-nb-ds-w2-T128": (functools.partial(_q40, "nb", "ds-w2", 128), True),
+    **{f"moe-ds-{kind}-{leaf}-T{rows}":
+       (functools.partial(_moe, kind, leaf, rows, "ds"), True)
+       for kind, rows in (("slots", 32), ("mxu", 128))
+       for leaf in ("w13", "w2")},
 }
 
 

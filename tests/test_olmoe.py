@@ -240,7 +240,7 @@ def test_bf16_expert_dot_fails(tree, tokens, want):
 
 
 def test_bf16_router_fails(tree, tokens, want, monkeypatch):
-    def bf16_route(gate, xb, k):
+    def bf16_route(gate, xb, k, router=None, bias=None):
         logits = jnp.einsum("ed,td->te", gate.astype(jnp.bfloat16),
                             xb.astype(jnp.bfloat16),
                             preferred_element_type=jnp.float32)
