@@ -679,6 +679,9 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_moe_expert_rows_total", labels=("expert",)),
     # ... of which on experts held here; a latent spec's pages in use
     _m("dllama_moe_local_pairs_total"),
+    # how the slot kernel engaged: live slots, and those of one row
+    _m("dllama_moe_slots_total"),
+    _m("dllama_moe_single_row_slots_total"),
     _m("dllama_latent_pages_in_use"),
     # a retention spec's recurrent state
     _m("dllama_state_bytes"),

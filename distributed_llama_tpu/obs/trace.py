@@ -207,6 +207,13 @@ class EngineMetrics:
             "dllama_moe_local_pairs_total",
             "Routed pairs that landed on an expert this engine holds (all "
             "of them, unless the model file holds a share of the experts)")
+        self.moe_slots = c(
+            "dllama_moe_slots_total",
+            "Live slots of the expert slot kernel in decode dispatches (a "
+            "held expert's rows in slots of up to 8), summed over layers")
+        self.moe_single_row_slots = c(
+            "dllama_moe_single_row_slots_total",
+            "Of those, slots that held one row (the kernel's one-row body)")
         self.latent_pages = g(
             "dllama_latent_pages_in_use",
             "Pool pages the sequences of a latent-attention model hold "
@@ -442,12 +449,16 @@ class EngineMetrics:
             launches.inc(n * steps)
             moved.inc(b * steps)
 
-    def record_moe(self, counts, held: slice = slice(None)) -> None:
+    def record_moe(self, counts, held: slice = slice(None),
+                   slots: tuple = (0, 0)) -> None:
         """One decode dispatch's (L, E) rows-per-expert counts; ``held``
-        the columns of the experts the engine holds."""
+        the columns of the experts the engine holds; ``slots`` its (live
+        slots, one-row slots) as the slot kernel saw them."""
         self.moe_pairs.inc(int(counts.sum()))
         self.moe_local_pairs.inc(int(counts[:, held].sum()))
         self.moe_active.inc(int((counts[:, held] > 0).sum()))
+        self.moe_slots.inc(slots[0])
+        self.moe_single_row_slots.inc(slots[1])
         if not self._moe_rows:
             self._moe_rows = [
                 self.registry.labeled_counter(

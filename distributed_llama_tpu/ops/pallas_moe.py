@@ -28,22 +28,32 @@ Two grouped matmuls, picked by the dispatch width T (static):
 
 * ``T <= MOE_SLOT_T_MAX`` (decode, verify, small mixed dispatches): the
   step's (row, expert) pairs are grouped into SLOTS of one expert and up to
-  ``MOE_SLOT_ROWS`` rows; the grid walks the slots from a scalar-prefetched
-  list, experts ascending, so a distinct expert's tile is fetched ONCE (a
-  second slot of the same expert repeats the block index and Pallas skips
-  the copy), never all E and never once per pair. The slot count is a
-  static bound (every expert active, every capacity overflowing); the live
-  count is data and the steps past it are skipped. Body: ``_slot_body``,
-  the nb-major small-T VPU body (one accumulator a row) with a slot's
-  input planes packed on the lanes.
+  ``MOE_SLOT_ROWS`` = 8 rows (one sublane tile; one row at T == 1); the
+  grid walks the slots from a scalar-prefetched list, experts ascending, so
+  a distinct expert's tile is fetched ONCE (a second slot of the same
+  expert repeats the block index and Pallas skips the copy), never all E
+  and never once per pair. The slot count is a static bound (every expert
+  active, every capacity overflowing); the live count is data, and the
+  steps past it are skipped and repeat the last live slot's block indices,
+  so they move nothing. Two bodies, and the slot's LIVE ROW COUNT (a fourth
+  prefetched list, ``fill``) alone picks between them: a slot of one row
+  takes ``_row_body`` (the nb-major matvec's arithmetic, 8 vector
+  operations a packed byte; at T == 1 the only body), any other the MXU
+  tile ``_mxu_body_merged`` over all 8 rows (the body of the chunk's
+  experts and of every dense leaf at T > 1; its time does not depend on
+  the rows it carries). On a v5e the tile streams an expert at 270-290
+  GB/s and the one-row body at 520-610 (PERF.md section 7, PR 34), so a
+  model whose experts see one or two rows (a share of DeepSeek-V3's) and
+  one whose experts see three (OLMoE) run the same kernel.
 * larger T (prefill chunks: every expert is hit): every expert runs every
   row through ``_mxu_body_merged``, the nb-major MXU body of ops/pallas_q40
   (``_matmul_body_nb``) with the nibble planes merged into the contraction,
   and the rows it was not routed are weighted 0.
 
-Both are the float32 arithmetic of the dense Q40 kernels; neither
-dequantizes an expert to HBM. Off the Pallas path (codec or dense leaves:
-the CPU tests, F32 files) ``_experts_xla`` scans the experts one at a time.
+All are the float32 arithmetic of the dense Q40 kernels (products at
+HIGHEST on the MXU, exact on the VPU); none dequantizes an expert to HBM.
+Off the Pallas path (codec or dense leaves: the CPU tests, F32 files)
+``_experts_xla`` scans the experts one at a time.
 """
 
 from __future__ import annotations
@@ -60,8 +70,9 @@ from .linear import StackedQ40, matmul, matmul_mode, silu
 from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ,
                          _pick_block_t, _pick_rows_nb)
 
-MOE_SLOT_ROWS = 4                # rows one slot carries: the VPU body's cap
-MOE_SLOT_T_MAX = 32              # wider dispatches take the MXU body
+MOE_SLOT_ROWS = 8                # rows one slot carries: one sublane tile
+MOE_SLOT_T_MAX = 32              # wider dispatches run every expert
+MOE_SLOT_TILE_MAX = 2048         # largest row tile measured (PERF.md section 7)
 
 
 def route(gate: jax.Array, xb: jax.Array, k: int, router=None, bias=None):
@@ -98,6 +109,23 @@ def route(gate: jax.Array, xb: jax.Array, k: int, router=None, bias=None):
     return topw * jnp.float32(router.scale), topi
 
 
+def slot_cap(t: int) -> int:
+    """Rows a slot of a T-row dispatch carries: one sublane tile, one at
+    T == 1; 0 where the dispatch is too wide for slots."""
+    return 0 if t > MOE_SLOT_T_MAX else (MOE_SLOT_ROWS if t > 1 else 1)
+
+
+def slot_census(counts, t: int) -> tuple[int, int]:
+    """(live slots, slots of one row) of T-row dispatches whose routed
+    rows per held expert are ``counts`` (any shape; numpy, on the host):
+    what ``build_slots`` builds from them, for the counters."""
+    cap = slot_cap(t)
+    if not cap:
+        return 0, 0
+    single = counts if cap == 1 else counts % cap == 1
+    return int((-(-counts // cap)).sum()), int(single.sum())
+
+
 def max_slots(t: int, k: int, n_experts: int, cap: int) -> int:
     """Static bound on the slots of a T-row dispatch: sum_e ceil(r_e / cap)
     with sum r_e = T k pairs over at most min(E, T k) active experts."""
@@ -113,10 +141,11 @@ def build_slots(topi: jax.Array, n_experts: int, cap: int):
 
     Returns ``slot_expert`` (A,) expert of each slot, ascending, the slots
     past the live count repeating the last live expert (no new tile is
-    fetched for them); ``n_slots`` () live slots; ``slot_rows`` (A, cap) the
-    row each lane computes (lanes no pair fills compute row 0 and are never
-    read back); ``pair_slot`` / ``pair_lane`` (T, k) where each pair's
-    result lands; ``counts`` (E,) rows routed to each expert."""
+    fetched for them); ``n_slots`` () live slots; ``fill`` (A,) the live
+    rows of each slot (1 to cap; 0 past the live count); ``slot_rows``
+    (A, cap) the row each lane computes (lanes no pair fills compute row 0
+    and are never read back); ``pair_slot`` / ``pair_lane`` (T, k) where
+    each pair's result lands; ``counts`` (E,) rows routed to each expert."""
     t, k = topi.shape
     a = max_slots(t, k, n_experts, cap)
     flat = topi.reshape(-1).astype(jnp.int32)                    # (P,)
@@ -134,94 +163,114 @@ def build_slots(topi: jax.Array, n_experts: int, cap: int):
     slot_expert = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(a, dtype=jnp.int32), side="right"),
         jnp.maximum(jnp.max(flat), 0)).astype(jnp.int32)
+    # rows left for a slot of its expert; past the live count the repeated
+    # expert has none left
+    fill = jnp.clip(counts[slot_expert] - cap * (
+        jnp.arange(a, dtype=jnp.int32) - (ends - per)[slot_expert]), 0, cap)
     slot_rows = jnp.zeros((a, cap), jnp.int32).at[slot, lane].set(
         jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
-    return (slot_expert, n_slots.astype(jnp.int32), slot_rows,
-            slot.reshape(t, k), lane.reshape(t, k), counts)
+    return (slot_expert, n_slots.astype(jnp.int32), fill.astype(jnp.int32),
+            slot_rows, slot.reshape(t, k), lane.reshape(t, k), counts)
 
 
-# -- the slot kernel (VPU body) -------------------------------------------------
+# -- the slot kernel (the MXU tile; a one-row body beside it) ------------------
 
-def _slot_body(qs_ref, s, xp_ref, out_ref, c: int):
-    """The nb-major small-T VPU body (unpack each nibble plane once, one
-    (nb, R) accumulator a row, sublane reduction, the -8 folded into one
-    xsum term) with the rows' input planes PACKED ON THE LANES:
-    ``xp_ref`` (nb, W) holds row ti's block b as 32 consecutive columns from
-    32 * ti (value j under the low nibbles at + j, under the high ones at
-    + 16 + j) and its block sum at 32 * c + ti. The 2-D kernels' (NJ, nb, t) planes put t
-    minor, which a slot's 1 to 4 rows pad to 128 lanes: a megabyte a slot,
-    more than the expert tile it multiplies."""
-    accs = [None] * c
+def _row_body(qs_ref, s, xp_ref, out_ref):
+    """ONE row against the tile: ``_matvec_body_nb``'s arithmetic
+    (ops/pallas_q40: unpack each nibble plane once, an (nb, R) accumulator,
+    the -8 folded into one xsum term, a sublane reduction; 8 vector
+    operations a packed byte) with the row's planes PACKED ON THE LANES:
+    ``xp_ref`` (nb, 128) holds block b's value j under the low nibbles at
+    column j, under the high ones at 16 + j, and the block's sum at 32.
+    (The 2-D matvec's (NJ, nb, 1) planes pad each value to 128 lanes:
+    megabytes a slot at an expert's nb, more than the tile they multiply.)
+    Writes row 0 of ``out_ref``."""
+    acc = None
     for j in range(NJ):
         q = qs_ref[j].astype(jnp.int32)              # (nb, R)
-        wlo = (q & 0xF).astype(jnp.float32)
-        whi = (q >> 4).astype(jnp.float32)
-        for ti in range(c):
-            a = (wlo * xp_ref[:, 32 * ti + j][:, None]
-                 + whi * xp_ref[:, 32 * ti + NJ + j][:, None])
-            accs[ti] = a if accs[ti] is None else accs[ti] + a
-    rows = []
-    for ti in range(c):
-        acc = accs[ti] - 8.0 * xp_ref[:, 32 * c + ti][:, None]
-        rows.append(jnp.sum(acc * s, axis=0, keepdims=True))    # (1, R)
-    out_ref[...] = jnp.concatenate(rows, axis=0)                # (c, R)
+        a = ((q & 0xF).astype(jnp.float32) * xp_ref[:, j][:, None]
+             + (q >> 4).astype(jnp.float32) * xp_ref[:, NJ + j][:, None])
+        acc = a if acc is None else acc + a
+    acc = acc - 8.0 * xp_ref[:, 2 * NJ][:, None]
+    out_ref[0:1, :] = jnp.sum(acc * s, axis=0, keepdims=True)   # (1, R)
 
 
-def _kernel_moe_slots(layer_ref, sexp_ref, n_ref, qs_ref, scale_ref, xp_ref,
-                      out_ref):
-    del layer_ref, sexp_ref  # consumed by the index maps
+def _row_planes(x: jax.Array, nb: int) -> jax.Array:
+    """(..., n) rows -> (..., nb, 128) packed planes of ``_row_body``."""
+    x3 = x.astype(jnp.float32).reshape(*x.shape[:-1], nb, 2 * NJ)
+    packed = jnp.concatenate([x3, jnp.sum(x3, axis=-1, keepdims=True)], -1)
+    return jnp.pad(packed, [(0, 0)] * (packed.ndim - 1)
+                   + [(0, 128 - packed.shape[-1])])
 
-    @pl.when(pl.program_id(1) < n_ref[0])
+
+def _kernel_moe_slots(layer_ref, sexp_ref, n_ref, fill_ref, qs_ref,
+                      scale_ref, *refs):
+    """One slot, its body picked by its live rows (``fill_ref``; 0 past the
+    live count: nothing runs). ``refs``: at 8 rows a slot the merged planes
+    xlo, xhi (8, NJ * nb) of the slot's rows; always the packed planes
+    (nb, 128) of its FIRST row; out."""
+    del layer_ref, sexp_ref, n_ref  # consumed by the index maps
+    *tile_refs, row_ref, out_ref = refs
+    rows = fill_ref[pl.program_id(1)]
+
+    @pl.when(rows == 1)
     def _():
-        _slot_body(qs_ref, scale_ref[...], xp_ref, out_ref, out_ref.shape[0])
+        _row_body(qs_ref, scale_ref[...], row_ref, out_ref)
 
-
-def _slot_planes(xs: jax.Array, nb: int) -> jax.Array:
-    """(A, C, n) rows -> (A, nb, W) packed planes of ``_slot_body``, W the
-    32 * C + C columns rounded up to whole 128-lane tiles. (No array here
-    has a minor dim under 32: the chip pads a minor dim to 128 lanes.)"""
-    a, c, _ = xs.shape
-    x4 = xs.astype(jnp.float32).reshape(a, c, nb, 32)
-    planes = jnp.transpose(x4, (0, 2, 1, 3)).reshape(a, nb, 32 * c)
-    xsum = jnp.transpose(jnp.sum(x4, axis=-1), (0, 2, 1))       # (A, nb, C)
-    width = 32 * c + c
-    return jnp.pad(jnp.concatenate([planes, xsum], axis=-1),
-                   ((0, 0), (0, 0), (0, -width % 128)))
+    if tile_refs:  # at T == 1 a slot never holds a second row
+        @pl.when(rows > 1)
+        def _():
+            _mxu_body_merged(qs_ref, scale_ref[...], *tile_refs, out_ref,
+                             False)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def moe_q40_slots(layer, slot_expert, n_slots, qs_t, scale, xs, *,
-                  block_rows, interpret):
-    """out[a, c] = dequant(w[layer, slot_expert[a]]) @ xs[a, c] for the live
-    slots a < n_slots (the rest of ``out`` is not written). ``qs_t``
-    (L, E, NJ, nb, d) / ``scale`` (L, E, nb, d) nb-major; ``xs`` (A, C, n)."""
+def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
+                  rows=None, *, block_rows, interpret):
+    """out[a, c] = dequant(w[layer, slot_expert[a]]) @ (row c of slot a) for
+    the live slots a < n_slots and their live rows c < fill[a] (the rest of
+    ``out`` is not written, or holds what a row never read back gave).
+    ``qs_t`` (L, E, NJ, nb, d) / ``scale`` (L, E, nb, d) nb-major. A slot's
+    rows: ``xs`` (A, C, n) holds them, or ``xs`` (T, n) is the dispatch's
+    rows and ``rows`` (A, C) says which each slot takes (the planes are
+    then built once a row and gathered, not once a slot lane); C is one row
+    (T == 1) or one sublane tile of 8."""
     nb, d = qs_t.shape[-2], qs_t.shape[-1]
-    a, c, _ = xs.shape
-    xp = _slot_planes(xs, nb)
+    a, c = xs.shape[:2] if rows is None else rows.shape
+
+    def at(g, N):
+        # a dead slot repeats the last live one's block indices: nothing
+        # is fetched for it and nothing written back
+        return jnp.maximum(jnp.minimum(g, N[0] - 1), 0)
+
+    planes = [] if c == 1 else [p if rows is None else p[rows]
+                                for p in _merged_planes(xs, nb)]
+    planes.append(_row_planes(xs[:, 0], nb) if rows is None
+                  else _row_planes(xs, nb)[rows[:, 0]])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         # slots innermost: the slots of one expert follow each other with
         # the same weight block index, which is what skips the re-fetch
         grid=(d // block_rows, a),
         # leading dims are squeezed (None): the body sees (NJ, nb, rows)
-        # codes, (nb, rows) scales and one slot's (nb, W) planes
+        # codes, (nb, rows) scales and one slot's planes
         in_specs=[
             pl.BlockSpec((None, None, NJ, nb, block_rows),
-                         lambda i, g, L, S, N: (L[0], S[g], 0, 0, i)),
+                         lambda i, g, L, S, N, F: (L[0], S[g], 0, 0, i)),
             pl.BlockSpec((None, None, nb, block_rows),
-                         lambda i, g, L, S, N: (L[0], S[g], 0, i)),
-            pl.BlockSpec((None, nb, xp.shape[-1]),
-                         lambda i, g, L, S, N: (g, 0, 0)),
-        ],
+                         lambda i, g, L, S, N, F: (L[0], S[g], 0, i)),
+        ] + [pl.BlockSpec((None,) + p.shape[1:],
+                          lambda i, g, L, S, N, F: (at(g, N), 0, 0))
+             for p in planes],
         out_specs=pl.BlockSpec((None, c, block_rows),
-                               lambda i, g, L, S, N: (g, 0, i)),
+                               lambda i, g, L, S, N, F: (at(g, N), 0, i)),
     )
     return pl.pallas_call(
         _kernel_moe_slots, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((a, c, d), jnp.float32),
         compiler_params=_VMEM64_PARAMS, interpret=interpret,
         name="moe_q40_slots",
-    )(layer, slot_expert, n_slots.reshape(1), qs_t, scale, xp)
+    )(layer, slot_expert, n_slots.reshape(1), fill, qs_t, scale, *planes)
 
 
 # -- the every-expert kernel (MXU body) ---------------------------------------
@@ -305,13 +354,17 @@ def moe_q40_mxu(layer, qs_t, scale, x, *, block_rows, block_t, interpret,
 
 # -- the expert layer ---------------------------------------------------------
 
-def _slot_block_rows(d: int, nb: int, cap: int) -> int | None:
-    """Row tile of the slot kernel: _q40_matmul_nbmajor's small-T rule."""
-    rows = _pick_rows_nb(d, nb)
-    if rows is None or cap == 1:
-        return rows
-    limit = max(128, 300_000 // (cap * nb))
-    return next((r for r in range(min(rows, limit - limit % 128), 0, -128)
+def _slot_block_rows(d: int, nb: int) -> int | None:
+    """Row tile of the slot kernel: the largest multiple of 128 dividing
+    ``d`` under the MXU body's measured rows x nb boundary
+    (``_MATMUL_ROWSXNB_CAP``) and ``MOE_SLOT_TILE_MAX``. Not the dense MXU
+    rule's 256 rows: an expert's tile is small (OLMoE's ``w13`` at 256 rows
+    is 328 KB, 1.2 us of the tile's time against a grid step's fixed 0.35),
+    and both bodies ran faster the larger the tile on all four expert
+    leaves of the benchmark (2048 / 2048 on OLMoE, 512 / 1792 on
+    DeepSeek-V3's share: my chip run, PR 34)."""
+    limit = min(_MATMUL_ROWSXNB_CAP // nb, MOE_SLOT_TILE_MAX)
+    return next((r for r in range(min(d, limit) // 128 * 128, 0, -128)
                  if d % r == 0), None)
 
 
@@ -332,23 +385,24 @@ def shape_places(d: int, nb: int) -> bool:
     """True when both grouped kernels can tile a (d, nb * 32) expert
     tensor: ops/linear.pack_q40_params packs an expert stack nb-major only
     then (else it stays codec and takes ``_experts_xla``)."""
-    return (_slot_block_rows(d, nb, MOE_SLOT_ROWS) is not None
+    return (_slot_block_rows(d, nb) is not None
             and _mxu_block_rows(d, nb, 8) is not None)
 
 
 def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret):
     t, k = topi.shape
-    cap = min(MOE_SLOT_ROWS, t)
-    (slot_expert, n_slots, slot_rows, pair_slot, pair_lane,
+    cap = slot_cap(t)
+    (slot_expert, n_slots, fill, slot_rows, pair_slot, pair_lane,
      counts) = build_slots(topi, n_experts, cap)
 
-    def call(w, xs):
+    def call(w, xs, rows=None):
         nb, d = w.qs_t.shape[-2:]
-        return moe_q40_slots(layer, slot_expert, n_slots, w.qs_t, w.scale,
-                             xs, block_rows=_slot_block_rows(d, nb, cap),
+        return moe_q40_slots(layer, slot_expert, n_slots, fill, w.qs_t,
+                             w.scale, xs, rows,
+                             block_rows=_slot_block_rows(d, nb),
                              interpret=interpret)
 
-    h13 = call(w13, xb[slot_rows])                       # (A, C, 2 hidden)
+    h13 = call(w13, xb, slot_rows)                       # (A, C, 2 hidden)
     hid = h13.shape[-1] // 2
     out = call(w2, silu(h13[..., :hid]) * h13[..., hid:])   # (A, C, dim)
     picked = out[pair_slot, pair_lane]                   # (T, k, dim)

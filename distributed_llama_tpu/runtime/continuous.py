@@ -416,6 +416,12 @@ class ContinuousStats:
     moe_local_pairs: int = 0
     moe_active: int = 0
     moe_load: Any = None
+    # how the slot kernel engaged (ops/pallas_moe): live slots of the
+    # counted dispatches (a held expert's rows in slots of ``slot_cap``
+    # rows: ceil(count / cap), summed over layers and steps), and those of
+    # them that held ONE row, which take the one-row body
+    moe_slots: int = 0
+    moe_single_row_slots: int = 0
     # a latent spec: pool pages in use (each page_size positions x
     # latent.width x layers of ONE plane; pages the prefix tree keeps
     # count), as of the last landed step; and the cached positions the
@@ -436,12 +442,16 @@ class ContinuousStats:
     state_bytes: int = 0
     min_normaliser: float = float("inf")
 
-    def count_moe(self, counts, held: slice = slice(None)) -> None:
+    def count_moe(self, counts, held: slice = slice(None),
+                  slots: tuple = (0, 0)) -> None:
         """One dispatch's (L, E) rows-per-expert counts; ``held`` the
-        columns of the experts held here."""
+        columns of the experts held here; ``slots`` the dispatch's
+        ``ops/pallas_moe.slot_census``."""
         self.moe_pairs += int(counts.sum())
         self.moe_local_pairs += int(counts[:, held].sum())
         self.moe_active += int((counts[:, held] > 0).sum())
+        self.moe_slots += slots[0]
+        self.moe_single_row_slots += slots[1]
         load = counts.sum(axis=0, dtype=np.int64)
         self.moe_load = load if self.moe_load is None else self.moe_load + load
 
@@ -2457,10 +2467,13 @@ class ContinuousEngine:
                         self._obs.retention_min_normaliser.set(low)
             if flight.moe is not None:  # 4 KB beside them
                 moe = np.asarray(flight.moe)  # dlint: allow[D001] routed-rows counters
+                from ..ops.pallas_moe import slot_census
+
                 held = self.spec.held_columns
-                self.stats.count_moe(moe, held)
+                census = slot_census(moe[:, held], self.slots)
+                self.stats.count_moe(moe, held, census)
                 if self._obs is not None:
-                    self._obs.record_moe(moe, held)
+                    self._obs.record_moe(moe, held, census)
             if self.spec.latent:
                 self.stats.latent_pages = (self._alloc.n_pages
                                            - self._alloc.n_free)

@@ -198,13 +198,14 @@ def _moe(kind: str, leaf: str, rows: int, model: str = "olmoe"):
     scale = _sd((2, n_exp, nb, d), jnp.float32)
     layer = _sd((1,), jnp.int32)
     if kind == "slots":
-        cap = min(pm.MOE_SLOT_ROWS, rows)
+        cap = pm.slot_cap(rows)
         a = pm.max_slots(rows, k, n_exp, cap)
         fn = functools.partial(
             pm.moe_q40_slots, interpret=False,
-            block_rows=pm._slot_block_rows(d, nb, cap))
-        return fn, (layer, _sd((a,), jnp.int32), _sd((), jnp.int32), qs_t,
-                    scale, _sd((a, cap, n), jnp.float32))
+            block_rows=pm._slot_block_rows(d, nb))
+        return fn, (layer, _sd((a,), jnp.int32), _sd((), jnp.int32),
+                    _sd((a,), jnp.int32), qs_t, scale,
+                    _sd((a, cap, n), jnp.float32))
     block_t = pm._pick_block_t(rows, nb)
     fn = functools.partial(pm.moe_q40_mxu, interpret=False, block_t=block_t,
                            block_rows=pm._mxu_block_rows(d, nb, block_t))
@@ -288,7 +289,8 @@ CASES = {
     # but is 4"
     **{f"moe-{kind}-{leaf}-T{rows}":
        (functools.partial(_moe, kind, leaf, rows), True)
-       for kind, rows in (("slots", 16), ("slots", 1), ("mxu", 128))
+       for kind, rows in (("slots", 16), ("slots", 32), ("slots", 1),
+                          ("mxu", 128))
        for leaf in ("w13", "w2")},
     # the state read and rewritten in place (whole-head 4.3 MB blocks under
     # a raised scoped-VMEM limit), and the chunk's float32 MXU matmuls
@@ -311,7 +313,8 @@ CASES = {
     "q40-nb-ds-w2-T128": (functools.partial(_q40, "nb", "ds-w2", 128), True),
     **{f"moe-ds-{kind}-{leaf}-T{rows}":
        (functools.partial(_moe, kind, leaf, rows, "ds"), True)
-       for kind, rows in (("slots", 32), ("mxu", 128))
+       for kind, rows in (("slots", 32), ("slots", 16), ("slots", 1),
+                          ("mxu", 128))
        for leaf in ("w13", "w2")},
 }
 

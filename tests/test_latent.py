@@ -333,14 +333,17 @@ def test_the_grouped_kernels_walk_held_experts_only(rows, monkeypatch):
 
 def test_build_slots_gives_a_pair_held_elsewhere_no_slot():
     topi = jnp.asarray([[0, -1], [-1, -1], [2, 0]], jnp.int32)
-    (slot_expert, n_slots, slot_rows, pair_slot, pair_lane,
+    (slot_expert, n_slots, fill, slot_rows, pair_slot, pair_lane,
      counts) = pallas_moe.build_slots(topi, 3, 2)
     assert int(n_slots) == 2 and list(np.asarray(counts)) == [2, 0, 1]
+    assert np.asarray(fill)[:2].tolist() == [2, 1] and \
+        not np.asarray(fill)[2:].any()
     a = slot_rows.shape[0]
     assert np.asarray(pair_slot).tolist() == [[0, a], [a, a], [1, 0]]
     assert np.asarray(slot_rows)[0].tolist() == [0, 2]
     none = pallas_moe.build_slots(jnp.full((2, 2), -1, jnp.int32), 3, 2)
     assert int(none[1]) == 0 and int(np.asarray(none[0]).min()) == 0
+    assert not np.asarray(none[2]).any()              # no slot holds a row
 
 
 # -- the file: header version 4, and the older versions byte for byte ---------
@@ -551,6 +554,13 @@ def test_engine_serves_with_prefix_sharing_and_counts_its_share(kernel_mode,
     assert st.latent_pages > 0 and st.latent_positions > st.steps
     assert reg.get("dllama_moe_local_pairs_total").value == \
         st.moe_local_pairs
+    # three rows a dispatch: a held expert's rows fit one slot, and a pair
+    # held elsewhere takes none
+    assert st.moe_slots == st.moe_active
+    assert 0 < st.moe_single_row_slots <= st.moe_slots
+    assert reg.get("dllama_moe_slots_total").value == st.moe_slots
+    assert reg.get("dllama_moe_single_row_slots_total").value == \
+        st.moe_single_row_slots
     assert "dllama_latent_pages_in_use" in reg.expose()
 
 

@@ -211,6 +211,13 @@ def test_continuous_paged_logits_and_counters(kernel_mode, tree):
     assert reg.get("dllama_moe_active_experts_total").value == st.moe_active
     text = reg.expose()
     assert 'dllama_moe_expert_rows_total{expert="7"}' in text
+    # how the slot kernel engaged: four rows a dispatch, slots of 8
+    assert st.moe_slots == st.moe_active     # no expert can take over 8 rows
+    assert st.moe_single_row_slots == sum(int((c == 1).sum())
+                                          for *_, c in seen) > 0
+    assert reg.get("dllama_moe_slots_total").value == st.moe_slots
+    assert reg.get("dllama_moe_single_row_slots_total").value == \
+        st.moe_single_row_slots
 
 
 def test_dense_engine_exposes_moe_totals_at_zero():
@@ -253,6 +260,50 @@ def test_bf16_router_fails(tree, tokens, want, monkeypatch):
     assert np.abs(np.asarray(got) - want[0]).max() > 10 * TOL
 
 
+@pytest.mark.parametrize("kind", ["expert", "dense"])
+def test_slot_counters_over_a_served_run(kind, tree):
+    """``moe_slots`` / ``moe_single_row_slots`` are the host's arithmetic on
+    the counts each step returns: sum(ceil(count / 8)) and the slots of one
+    row, at 12 rows a dispatch (an expert can take over 8); a dense engine
+    reads 0."""
+    from distributed_llama_tpu.obs.metrics import Registry
+
+    reg = Registry()
+    rng = np.random.default_rng(5)
+    prompts = [[1] + [int(t) for t in rng.integers(3, 500, 3)]
+               for _ in range(12)]
+    if kind == "dense":
+        from distributed_llama_tpu.runtime.continuous import (
+            ContinuousEngine, Request)
+
+        spec = toy_spec(qk_norm=False, n_experts=0, n_active_experts=0,
+                        weights_float_type=FloatType.F32)
+        eng = ContinuousEngine(spec, synth_params(spec, q40=False, seed=2),
+                               slots=2, temperature=0.0, topp=0.9, seed=3,
+                               page_size=4, prefill_chunk=4, metrics=reg)
+        for p in prompts[:2]:
+            eng.submit(Request(tokens=list(p), steps=8))
+        while eng.step_once():
+            pass
+        st = eng.stats
+        assert st.steps > 0
+        assert st.moe_slots == st.moe_single_row_slots == st.moe_pairs == 0
+        assert reg.get("dllama_moe_slots_total").value == 0
+        assert reg.get("dllama_moe_single_row_slots_total").value == 0
+        return
+    eng, _, seen = _served_rows(tree, 12, prompts, 10, metrics=reg)
+    st = eng.stats
+    counts = [c for *_, c in seen]
+    assert st.moe_slots == sum(int((-(-c // 8)).sum()) for c in counts)
+    assert st.moe_single_row_slots == sum(int((c % 8 == 1).sum())
+                                          for c in counts)
+    assert st.moe_slots > st.moe_active      # some expert took over 8 rows
+    assert 0 < st.moe_single_row_slots < st.moe_slots
+    assert reg.get("dllama_moe_slots_total").value == st.moe_slots
+    assert reg.get("dllama_moe_single_row_slots_total").value == \
+        st.moe_single_row_slots
+
+
 # -- (iv): the grouped kernels, interpret mode, against a per-pair loop ------
 
 def _expert_stack(seed=3, L=2, E=8, hidden=128, dim=256):
@@ -279,6 +330,8 @@ def _pair_loop(dense, layer, x, topw, topi):
     y = np.zeros((x.shape[0], w2.shape[1]))
     for t in range(x.shape[0]):
         for wgt, e in zip(topw[t], topi[t]):
+            if e < 0:                       # held elsewhere: contributes 0
+                continue
             g, u = w1[e] @ x[t], w3[e] @ x[t]
             y[t] += wgt * (w2[e] @ (g / (1 + np.exp(-g)) * u))
     return y
@@ -286,20 +339,35 @@ def _pair_loop(dense, layer, x, topw, topi):
 
 def _routing(case, rows, k=2, n_experts=8, seed=0):
     rng = np.random.default_rng(seed)
-    if case == "random":
+    if case in ("random", "held_share"):
         topi = np.stack([rng.choice(n_experts, k, replace=False)
                          for _ in range(rows)])
+        if case == "held_share":            # experts 5.. live on other chips
+            topi = np.where(topi < 5, topi, -1)
     elif case == "rows_share_every_expert":
         topi = np.tile(np.array([[5, 2]]), (rows, 1))
+    elif case.startswith("fill"):
+        # expert 5 takes exactly n rows (one slot up to 8, two at 9); the
+        # other choices go round the rest
+        n = min(int(case[4:]), rows)
+        others = [e for e in range(n_experts) if e != 5]
+        topi = np.array([[5 if t < n else others[(2 * t + 1) % 7],
+                          others[(2 * t) % 7]] for t in range(rows)])
     else:                                   # one expert takes every row
         topi = np.stack([[3, (4 + t) % n_experts if (4 + t) % n_experts != 3
                           else 0] for t in range(rows)])
-    return rng.random((rows, k)).astype(np.float32), topi.astype(np.int32)
+    topw = rng.random((rows, k)).astype(np.float32)
+    return np.where(topi >= 0, topw, 0).astype(np.float32), \
+        topi.astype(np.int32)
 
 
-@pytest.mark.parametrize("case", ["random", "rows_share_every_expert",
-                                  "one_expert_takes_every_row"])
-@pytest.mark.parametrize("rows", [1, 3, 8, 16])
+SLOT_CASES = ["random", "rows_share_every_expert",
+              "one_expert_takes_every_row", "held_share", "fill1", "fill2",
+              "fill7", "fill8", "fill9"]
+
+
+@pytest.mark.parametrize("case", SLOT_CASES)
+@pytest.mark.parametrize("rows", [1, 3, 8, 16])       # 1: cap 1, one-row body
 def test_slot_kernel_matches_pair_loop(rows, case):
     w13, w2, dense = _expert_stack()
     x = np.random.default_rng(rows).standard_normal(
@@ -311,8 +379,34 @@ def test_slot_kernel_matches_pair_loop(rows, case):
         jnp.asarray(topw), jnp.asarray(topi), 8, True)
     want = _pair_loop(dense, layer, x.astype(np.float64), topw, topi)
     assert np.abs(np.asarray(got) - want).max() < 1e-5
-    assert (np.asarray(counts) == np.bincount(topi.ravel(),
+    assert (np.asarray(counts) == np.bincount(topi[topi >= 0],
                                               minlength=8)).all()
+
+
+@pytest.mark.parametrize("fill", [1, 2, 8])
+@pytest.mark.parametrize("nb", [224, 64])     # DeepSeek-V3's w13 and w2
+def test_slot_call_matches_dense_at_deepseeks_block_counts(nb, fill):
+    """One call of the slot kernel on a leaf of ``nb`` blocks a row at a toy
+    ``d``: a one-row slot (its own body), a part-filled and a full tile, a
+    dead slot behind them."""
+    from distributed_llama_tpu.ops.quants import quantize_q40
+
+    rng = np.random.default_rng(nb + fill)
+    d, n, n_exp = 128, nb * 32, 3
+    w = Q40Weight(*quantize_q40((rng.standard_normal((1, n_exp, d, n))
+                                 / np.sqrt(n)).astype(np.float32)))
+    dense = dequantize_q40(w.qs, w.d16).astype(np.float64)[0]
+    leaf = to_kernel_layout_nb(w)
+    xs = rng.standard_normal((3, 8, n)).astype(np.float32)
+    slot_expert = jnp.asarray([0, 2, 2], jnp.int32)     # slot 2 is dead
+    fills = jnp.asarray([fill, 8, 0], jnp.int32)
+    got = np.asarray(pallas_moe.moe_q40_slots(
+        jnp.zeros((1,), jnp.int32), slot_expert, jnp.int32(2), fills,
+        leaf.qs_t, leaf.scale, jnp.asarray(xs), block_rows=128,
+        interpret=True))
+    for a, (e, live) in enumerate(((0, fill), (2, 8))):
+        want = xs[a, :live].astype(np.float64) @ dense[e].T
+        assert np.abs(got[a, :live] - want).max() < 1e-5
 
 
 @pytest.mark.parametrize("rows", [40, 13])   # 13: padded to the MXU's eights
@@ -331,12 +425,13 @@ def test_every_expert_kernel_matches_pair_loop(rows):
 
 @pytest.mark.parametrize("rows,k,n_experts,cap", [
     (16, 8, 64, 4), (1, 8, 64, 1), (3, 2, 8, 3), (32, 8, 64, 4),
-    (16, 2, 8, 4)])
+    (16, 2, 8, 4), (16, 8, 64, 8), (32, 8, 64, 8), (3, 2, 8, 8),
+    (16, 2, 8, 8), (32, 8, 32, 8)])
 def test_build_slots_places_every_pair_once(rows, k, n_experts, cap):
     rng = np.random.default_rng(rows * k)
     topi = np.stack([rng.choice(n_experts, k, replace=False)
                      for _ in range(rows)]).astype(np.int32)
-    (slot_expert, n_slots, slot_rows, pair_slot, pair_lane,
+    (slot_expert, n_slots, fill, slot_rows, pair_slot, pair_lane,
      counts) = map(np.asarray, pallas_moe.build_slots(
          jnp.asarray(topi), n_experts, cap))
     a = pallas_moe.max_slots(rows, k, n_experts, cap)
@@ -344,21 +439,44 @@ def test_build_slots_places_every_pair_once(rows, k, n_experts, cap):
     assert n_slots == sum(-(-c // cap) for c in counts)
     assert (np.diff(slot_expert) >= 0).all()          # experts ascending
     assert (slot_expert[n_slots:] == slot_expert[n_slots - 1]).all()
+    assert (fill[:n_slots] >= 1).all() and not fill[n_slots:].any()
+    assert (np.bincount(slot_expert[:n_slots], weights=fill[:n_slots],
+                        minlength=n_experts) == counts).all()
     places = set()
     for t in range(rows):
         for j in range(k):
             s, lane = pair_slot[t, j], pair_lane[t, j]
             assert s < n_slots and slot_expert[s] == topi[t, j]
-            assert slot_rows[s, lane] == t
+            assert slot_rows[s, lane] == t and lane < fill[s]
             places.add((s, lane))
     assert len(places) == rows * k                    # no two pairs collide
 
 
-def test_worst_case_routing_fits_the_static_slot_bound():
+@pytest.mark.parametrize("cap", [4, 8])
+def test_worst_case_routing_fits_the_static_slot_bound(cap):
     """Every row to the same k experts: the most slots one expert takes."""
     topi = np.tile(np.arange(8, dtype=np.int32), (16, 1))
-    _, n_slots, *_ = pallas_moe.build_slots(jnp.asarray(topi), 64, 4)
-    assert int(n_slots) == 8 * 4 <= pallas_moe.max_slots(16, 8, 64, 4)
+    _, n_slots, fill, *_ = pallas_moe.build_slots(jnp.asarray(topi), 64, cap)
+    assert int(n_slots) == 8 * (16 // cap) <= pallas_moe.max_slots(
+        16, 8, 64, cap)
+    assert (np.asarray(fill)[:int(n_slots)] == cap).all()
+
+
+@pytest.mark.parametrize("rows,slots", [(1, 1), (16, 8), (32, 8), (40, 0)])
+def test_slot_census_counts_what_build_slots_builds(rows, slots):
+    """The host's arithmetic for the counters against the device's slots."""
+    assert pallas_moe.slot_cap(rows) == slots
+    rng = np.random.default_rng(rows)
+    topi = np.stack([rng.choice(16, 4, replace=False) for _ in range(rows)])
+    counts = np.bincount(topi.ravel(), minlength=16)
+    live, single = pallas_moe.slot_census(counts, rows)
+    if not slots:                           # too wide: every expert runs
+        assert (live, single) == (0, 0)
+        return
+    _, n_slots, fill, *_ = pallas_moe.build_slots(
+        jnp.asarray(topi, jnp.int32), 16, slots)
+    assert live == int(n_slots) == sum(-(-c // slots) for c in counts)
+    assert single == int((np.asarray(fill) == 1).sum())
 
 
 # -- (vii): what refuses an expert spec --------------------------------------
