@@ -273,7 +273,7 @@ def d005_bare_time(ctx: ModuleContext) -> Iterator[Finding]:
     never calls block_until_ready measures only the async dispatch — the
     round-1 'TPU is infinitely fast' trap. (time.monotonic/perf_counter
     deltas with an explicit sync, or a blocking np.asarray, are the
-    sanctioned patterns — see obs/trace.sync_device_timing.)"""
+    sanctioned patterns.)"""
     funcs: dict[ast.AST, dict] = {}
     for node in ast.walk(ctx.tree):
         if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
@@ -478,14 +478,14 @@ def _calls_blocking_asarray(ctx: ModuleContext, func: ast.AST) -> bool:
 
 @rule("D008", "timed region wraps device work with neither a sync nor a span",
       "open a span (obs/spans.SpanTracer; the timeline then owns the "
-      "region) or drain with block_until_ready / the "
-      "obs/trace.sync_device_timing gate — otherwise the interval "
+      "region) or drain with block_until_ready / a blocking np.asarray "
+      "— otherwise the interval "
       "measures dispatch and /debug/timeline has a hole",
       scope=("runtime/", "parallel/"))
 def d008_span_hygiene(ctx: ModuleContext) -> Iterator[Finding]:
     """A ``time.monotonic()``/``time.perf_counter()`` delta in a function
-    that dispatches jax work but never syncs (block_until_ready, the
-    sync_device_timing gate, a blocking np.asarray) and never opens a
+    that dispatches jax work but never syncs (block_until_ready, a
+    blocking np.asarray) and never opens a
     span. D005 catches the time.time() spelling of the dispatch trap;
     this rule covers the monotonic clocks AND enforces that timed device
     regions appear in the span timeline (ISSUE 5)."""
@@ -495,7 +495,6 @@ def d008_span_hygiene(ctx: ModuleContext) -> Iterator[Finding]:
         if not ctx.function_calls_device(node):
             continue
         if (ctx.function_calls(node, "block_until_ready")
-                or ctx.function_calls(node, "sync_device_timing")
                 or _calls_span(ctx, node)
                 or _calls_blocking_asarray(ctx, node)):
             continue

@@ -673,6 +673,14 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     # step_once's run-ahead on device-resident picks
     _m("dllama_serve_steps_ahead_total"),
     _m("dllama_serve_rows_dropped_ahead_total"),
+    # the admission account: landing intervals, those behind admissions
+    _m("dllama_engine_land_seconds_total"),
+    _m("dllama_engine_land_behind_admit_seconds_total"),
+    _m("dllama_engine_lands_behind_admit_total"),
+    _m("dllama_admit_prefills_total"),
+    _m("dllama_admit_prefill_chunks_total"),
+    _m("dllama_engine_fetch_wait_seconds_total"),
+    _m("dllama_engine_fetch_wait_behind_admit_seconds_total"),
     # routed experts (an expert spec's decode dispatches)
     _m("dllama_moe_routed_pairs_total"),
     _m("dllama_moe_active_experts_total"),

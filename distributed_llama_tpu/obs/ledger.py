@@ -133,6 +133,11 @@ class RequestLedger:
 
     def charge_prefill(self, chunks: int, tokens: int,
                        dt_s: float) -> None:
+        """One admission's prefill: its chunks, its tokens, and the wall
+        time of ENQUEUEING its programs (``dt_s``: host work, a few ms).
+        It is not the device's time: the programs run after the call has
+        returned, and what they cost the decode rows is booked where the
+        steps land (``ContinuousStats.book_land``)."""
         self.prefill_chunks += chunks
         self.prefill_tokens += tokens
         self.prefill_s += dt_s

@@ -75,7 +75,13 @@ def host_phase(name: str, **args):
     ``contextlib.nullcontext()`` does, so it has no switch. Names are a
     contract with whoever reads a capture (PERF.md section 3 lists them);
     none ends in ``.step``, which the benchmark's own spans use. ``args``
-    become the event's arguments (a request's trace id)."""
+    become the event's arguments (a request's trace id).
+
+    Two of the names carry the scheduler's admission account into a
+    capture: ``serve.land`` opens at the instant a step's results are on
+    the host (parent of ``serve.census`` and ``serve.sample``), and holds
+    one empty ``serve.land.chunk`` per admission prefill chunk that stood
+    before that step on the device queue."""
     global _annotation
     if _annotation is None:  # obs/ imports without JAX
         from jax.profiler import TraceAnnotation

@@ -16,8 +16,6 @@ extra bookkeeping structure.
 
 from __future__ import annotations
 
-import os
-
 from .ledger import STALL_CAUSES, TOKEN_KINDS
 from .metrics import (COUNT_BUCKETS, LATENCY_BUCKETS, RATE_BUCKETS, Registry)
 
@@ -25,15 +23,6 @@ from .metrics import (COUNT_BUCKETS, LATENCY_BUCKETS, RATE_BUCKETS, Registry)
 # small model and tens of ms at 7B — both ends must resolve.
 STEP_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
-
-
-def sync_device_timing() -> bool:
-    """DLLAMA_METRICS_SYNC=1: block_until_ready the cache after each timed
-    step so step-duration histograms measure DEVICE time, not dispatch time.
-    Off by default — the host-side logits/tokens conversion already syncs
-    the step's outputs, and an extra sync point stops the host from
-    preparing the next step while the device runs."""
-    return os.environ.get("DLLAMA_METRICS_SYNC", "") not in ("", "0")
 
 
 class EngineMetrics:
@@ -45,7 +34,6 @@ class EngineMetrics:
 
     def __init__(self, registry: Registry):
         self.registry = registry
-        self.sync = sync_device_timing()
         h, c, g = registry.histogram, registry.counter, registry.gauge
         self.queue_wait = h(
             "dllama_request_queue_wait_seconds",
@@ -59,7 +47,9 @@ class EngineMetrics:
             buckets=STEP_BUCKETS)
         self.prefill = h(
             "dllama_request_prefill_seconds",
-            "Admission-prefill duration (chunked prompt fill)")
+            "Time from slot admission to the first sampled token: the wait "
+            "for the running step, the admission prefill's programs on the "
+            "device, and the first step (TTFT = queue wait + this)")
         self.tokens_per_s = h(
             "dllama_request_tokens_per_second",
             "Sampled tokens/s over a request's admit->finish window",
@@ -230,6 +220,40 @@ class EngineMetrics:
             "Rows of steps launched ahead whose result was thrown away: "
             "the row stopped on a token only the landing told, or was "
             "cancelled meanwhile")
+        # the admission account (ContinuousStats.book_land): the landing
+        # intervals of every dispatch, those of the dispatches that stood
+        # behind an admission's programs on the device queue (with the
+        # landing before each, which the enqueue can make late), and what
+        # the admissions enqueued. Stall = behind_seconds - behind_steps x
+        # (land_seconds - behind_seconds) / (steps - behind_steps)
+        self.land_seconds = c(
+            "dllama_engine_land_seconds_total",
+            "Sum of the landing intervals of the dispatches (a step run "
+            "ahead: landing to landing): the time the engine was stepping")
+        self.land_behind_admit_seconds = c(
+            "dllama_engine_land_behind_admit_seconds_total",
+            "... of the dispatches that stood behind at least one "
+            "admission's programs on the device queue, and of the landing "
+            "before each (late by what the next interval lacks)")
+        self.lands_behind_admit = c(
+            "dllama_engine_lands_behind_admit_total",
+            "Device steps of the dispatches booked behind admissions")
+        self.admit_prefills = c(
+            "dllama_admit_prefills_total",
+            "Admissions that enqueued device work (gather or scratch "
+            "state, prefill chunks, scatter or insert)")
+        self.admit_prefill_chunks = c(
+            "dllama_admit_prefill_chunks_total",
+            "Admission prefill chunks enqueued")
+        self.fetch_wait = c(
+            "dllama_engine_fetch_wait_seconds_total",
+            "Time the scheduler stood in the blocking read of a "
+            "dispatch's results (the device being busy, where it runs "
+            "ahead)")
+        self.fetch_wait_behind_admit = c(
+            "dllama_engine_fetch_wait_behind_admit_seconds_total",
+            "... of the dispatches booked behind admissions (the host's "
+            "own part of an iteration is read from the others)")
         # a retention spec's state (ContinuousStats.state_bytes /
         # min_normaliser); other engines expose them flat at zero
         self.state_bytes = g(
@@ -448,6 +472,18 @@ class EngineMetrics:
         for launches, moved, n, b in self._collectives:
             launches.inc(n * steps)
             moved.inc(b * steps)
+
+    def record_land(self, dt_s: float, wait_s: float,
+                    steps_behind_admit: int) -> None:
+        """One landed dispatch's interval and the part of it spent in the
+        blocking read; ``steps_behind_admit`` its device steps if it is
+        booked behind admissions (``ContinuousStats.book_land``), else 0."""
+        self.land_seconds.inc(dt_s)
+        self.fetch_wait.inc(wait_s)
+        if steps_behind_admit:
+            self.land_behind_admit_seconds.inc(dt_s)
+            self.lands_behind_admit.inc(steps_behind_admit)
+            self.fetch_wait_behind_admit.inc(wait_s)
 
     def record_moe(self, counts, held: slice = slice(None),
                    slots: tuple = (0, 0)) -> None:

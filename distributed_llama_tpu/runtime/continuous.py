@@ -338,6 +338,12 @@ class _Flight:
     norm_min: Any   # a retention spec's (L,) smallest normaliser, else None
     t0: float       # when the device could start it (time.monotonic)
     ahead: bool     # launched on the previous step's picks, unread
+    # what was enqueued between the launch before this one and this one,
+    # so stands before it on the device queue: admission prefill chunks,
+    # and admissions that enqueued device work
+    chunks_ahead: int = 0
+    admits_ahead: int = 0
+    wait: float = 0.0  # seconds ``_fetch`` stood in the blocking read of it
 
     def rode(self) -> list:
         """(row, slot) of the rows whose slot still holds the request it
@@ -441,6 +447,58 @@ class ContinuousStats:
     # cancellation, shows here)
     state_bytes: int = 0
     min_normaliser: float = float("inf")
+    # the admission account, kept for every landed dispatch, dark or not,
+    # on time.monotonic. ``land_s``: the sum of the landing intervals (a
+    # step run ahead: landing to landing, which with the device never idle
+    # is the device time of whatever was queued between the two steps; a
+    # step launched from the host: from where the device was free to run
+    # the admissions queued before it, else from its launch; a chain, mixed
+    # or verify dispatch: launch to landing), so the time the engine was
+    # stepping. ``lands_behind_admit`` / ``land_behind_admit_s``: the steps
+    # and the intervals of the dispatches that stood behind at least one
+    # admission's programs on the device queue, and of the landing before
+    # each of them (``book_land``: the host may land that one late, and
+    # the two intervals add up to the truth). ``admit_prefills``:
+    # admissions that enqueued device work (a gather or a scratch state,
+    # chunks, a scatter or an insert); ``prefill_chunks`` above counts
+    # their chunks. ``admits_back_to_back_max``: the most admissions ahead
+    # of one dispatch. ``fetch_wait_s``: the time the host stood in the
+    # blocking read of a dispatch's results, and
+    # ``fetch_wait_behind_admit_s`` that of the dispatches booked behind
+    # admissions (the host's own part of an iteration is read from the
+    # plain ones: behind a burst it also stands in held-up enqueues)
+    land_s: float = 0.0
+    lands_behind_admit: int = 0
+    land_behind_admit_s: float = 0.0
+    admit_prefills: int = 0
+    admits_back_to_back_max: int = 0
+    fetch_wait_s: float = 0.0
+    fetch_wait_behind_admit_s: float = 0.0
+
+    def book_land(self, dt_s: float, steps: int, chunks: int, admits: int,
+                  wait_s: float = 0.0, enqueued_since: bool = False) -> bool:
+        """One landed dispatch of ``steps`` device steps whose landing
+        interval is ``dt_s``, of which the host stood ``wait_s`` in the
+        blocking read of its results, with ``chunks`` admission prefill
+        chunks of ``admits`` admissions ahead of it on the device queue.
+        ``enqueued_since``: the host has enqueued admission programs since
+        the landing before this one (they stand before the NEXT step). The
+        runtime holds the host in an enqueue once enough programs are in
+        flight, so this landing can come long after its step ended (0.7 s
+        before a burst of 13 chunks, measured), and the next interval is
+        short by as much: both are booked behind the admission, so that
+        their sum, which does not move with the lateness, is what the
+        stall is read from. Returns whether it was booked behind one."""
+        self.land_s += dt_s
+        self.fetch_wait_s += wait_s
+        behind = bool(chunks or admits or enqueued_since)
+        if behind:
+            self.lands_behind_admit += steps
+            self.land_behind_admit_s += dt_s
+            self.fetch_wait_behind_admit_s += wait_s
+            if admits > self.admits_back_to_back_max:
+                self.admits_back_to_back_max = admits
+        return behind
 
     def count_moe(self, counts, held: slice = slice(None),
                   slots: tuple = (0, 0)) -> None:
@@ -470,6 +528,50 @@ class ContinuousStats:
     def spec_accept_rate(self) -> float:
         """Accepted / proposed drafts (0.0 before any proposal)."""
         return self.spec_accepted / max(self.spec_proposed, 1)
+
+    @property
+    def plain_step_ms(self) -> float:
+        """A step with no admission ahead of it, landing to landing."""
+        return 1e3 * (self.land_s - self.land_behind_admit_s) / max(
+            self.steps - self.lands_behind_admit, 1)
+
+    @property
+    def admit_stall_s(self) -> float:
+        """What the steps that stood behind admissions took beyond plain
+        steps: the time decode rows stood still for admissions."""
+        return max(0.0, self.land_behind_admit_s
+                   - self.lands_behind_admit * self.plain_step_ms / 1e3)
+
+    @property
+    def admit_stall_ms_per_chunk(self) -> float:
+        """... over the prefill chunks: a chunk with its admission's share
+        of gather and scatter (or state insert)."""
+        return 1e3 * self.admit_stall_s / max(self.prefill_chunks, 1)
+
+    @property
+    def admit_share(self) -> float:
+        """... over the time the engine was stepping."""
+        return self.admit_stall_s / max(self.land_s, 1e-9)
+
+    @property
+    def host_ms_per_step(self) -> float:
+        """A plain step's interval outside the blocking read of its
+        results: what the host itself takes of an iteration (hidden under
+        the step in flight where it runs ahead)."""
+        return 1e3 * (
+            (self.land_s - self.land_behind_admit_s)
+            - (self.fetch_wait_s - self.fetch_wait_behind_admit_s)) / max(
+                self.steps - self.lands_behind_admit, 1)
+
+    @property
+    def admission_clause(self) -> str:
+        """The account in one clause, for the summaries."""
+        return (f"admission {self.admit_share:.1%} of stepping time, "
+                f"{self.admit_stall_ms_per_chunk:.1f} ms a chunk over "
+                f"{self.prefill_chunks} chunks; plain step "
+                f"{self.plain_step_ms:.2f} ms; host "
+                f"{self.host_ms_per_step:.2f} ms a step; at most "
+                f"{self.admits_back_to_back_max} admissions back to back")
 
 
 class ContinuousEngine:
@@ -858,6 +960,12 @@ class ContinuousEngine:
             jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
             if sharded else None)
         self._flight: _Flight | None = None  # launched, not landed
+        # [prefill chunks, admissions with device work] enqueued since the
+        # last launch: what the next dispatch will stand behind; and since
+        # when the device has been free to run them (the first one's
+        # enqueue, or the landing after which nothing else was in flight)
+        self._ahead = [0, 0]
+        self._ahead_t0 = 0.0
         # rows that left the pool to land with the step in flight
         # (_hand_over), until it has landed: fail_all must reach them
         self._leaving: list[_Slot] = []
@@ -1330,16 +1438,14 @@ class ContinuousEngine:
             with host_phase("serve.dispatch"):
                 out, self.cache = run(self.params, self.cache, staged,
                                       table)
+            t_wait = time.monotonic()
             with host_phase("serve.fetch"):
                 out = np.asarray(out)  # dlint: allow[D001] host replay reads ids/logits
+            now = time.monotonic()
+            dt = now - t0
+            self._book_land(dt, 1, *self._queued_ahead(), now - t_wait)
             if self._obs is not None:
-                # the sync flag additionally drains the donated cache
-                # write (obs/trace.sync_device_timing)
-                if self._obs.sync:
-                    import jax
-
-                    jax.block_until_ready(self.cache)  # dlint: allow[D001] opt-in timing drain
-                self._obs.record_step(time.monotonic() - t0, n_active0)
+                self._obs.record_step(dt, n_active0)
                 if self._alloc is not None:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
         with host_phase("serve.census"):
@@ -1465,16 +1571,14 @@ class ContinuousEngine:
                 staged = (jnp.asarray(st), jnp.asarray(st_pos[1]), table)
             with host_phase("serve.dispatch"):
                 out, self.cache = run(self.params, self.cache, *staged)
+            t_wait = time.monotonic()
             with host_phase("serve.fetch"):
                 out = np.asarray(out)  # dlint: allow[D001] host replay reads ids/logits
+            now = time.monotonic()
+            dt = now - t0
+            self._book_land(dt, 1, *self._queued_ahead(), now - t_wait)
             if self._obs is not None:
-                # the sync flag additionally drains the donated cache
-                # write (obs/trace.sync_device_timing)
-                if self._obs.sync:
-                    import jax
-
-                    jax.block_until_ready(self.cache)  # dlint: allow[D001] opt-in timing drain
-                self._obs.record_step(time.monotonic() - t0, n_active0)
+                self._obs.record_step(dt, n_active0)
                 if self._alloc is not None:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
         with host_phase("serve.census"):
@@ -1787,20 +1891,15 @@ class ContinuousEngine:
             with host_phase("serve.dispatch"):
                 self.cache, toks, acts = run(self.params, self.cache,
                                              *staged)
+            t_wait = time.monotonic()
             with host_phase("serve.fetch"):
                 toks = np.asarray(toks)  # dlint: allow[D001] chain outputs drive
                 acts = np.asarray(acts)  # dlint: allow[D001] the host replay below
+            now = time.monotonic()
+            dt = now - t0
+            self._book_land(dt, k, *self._queued_ahead(), now - t_wait)
             if self._obs is not None:
-                # toks/acts above already synced the chain's host outputs;
-                # the sync flag additionally drains the donated cache write
-                # so the histogram sees pure device time
-                # (obs/trace.sync_device_timing)
-                if self._obs.sync:
-                    import jax
-
-                    jax.block_until_ready(self.cache)  # dlint: allow[D001] opt-in timing drain
-                self._obs.record_step(time.monotonic() - t0, n_active0,
-                                      steps=k)
+                self._obs.record_step(dt, n_active0, steps=k)
                 if self._alloc is not None:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
         with host_phase("serve.census"):
@@ -2327,6 +2426,9 @@ class ContinuousEngine:
             # every row it was launched for stopped meanwhile: nothing to
             # land (its dead writes are in pages that were those rows')
             self._count_dropped(sum(r is not None for r in ahead.reqs))
+            self._ahead[0] += ahead.chunks_ahead  # still ahead of the next
+            self._ahead[1] += ahead.admits_ahead
+            self._ahead_t0 = time.monotonic()
             self._flight = None
         self._journal_sync()
         return self._n_outstanding()
@@ -2401,7 +2503,10 @@ class ContinuousEngine:
         when no row would take part."""
         from .paging import SCRAP_PAGE
 
-        t0 = time.monotonic()
+        # a step launched from the host stands behind whatever admissions
+        # have enqueued since the device fell idle: its interval runs from
+        # there (the enqueue itself can hold the host for most of it)
+        t0 = self._ahead_t0 if any(self._ahead) else time.monotonic()
         # a NEW block per launch, shipped as one upload: the last one may
         # still be in transfer (or, on a CPU backend, be the memory the
         # step in flight reads)
@@ -2445,7 +2550,25 @@ class ContinuousEngine:
         beside = more[0] if more else None  # what the spec's kind counts
         moe, norm_min = (None, beside) if self._state else (beside, None)
         return _Flight(rows, reqs, paused, logits, picked, moe, norm_min,
-                       t0, prev is not None)
+                       t0, prev is not None, *self._queued_ahead())
+
+    def _queued_ahead(self) -> tuple[int, int]:
+        """(admission prefill chunks, admissions with device work) enqueued
+        since the last launch, for the dispatch launched now: both
+        ``_admit`` calls of an iteration and ``_resume_prefills`` come
+        before the next launch, so this is the device queue's order."""
+        chunks, admits = self._ahead
+        self._ahead = [0, 0]
+        return chunks, admits
+
+    def _book_land(self, dt: float, steps: int, chunks: int, admits: int,
+                   wait: float, enqueued_since: bool = False) -> None:
+        """The admission account of one landed dispatch
+        (``ContinuousStats.book_land``), and its ``/metrics`` twin."""
+        behind = self.stats.book_land(dt, steps, chunks, admits, wait,
+                                      enqueued_since)
+        if self._obs is not None:
+            self._obs.record_land(dt, wait, steps if behind else 0)
 
     def _fetch(self, flight: _Flight):
         """Wait for ``flight`` and bring back what its rows need: the
@@ -2455,10 +2578,12 @@ class ContinuousEngine:
         on_host = any(s.sampler.temperature != 0.0
                       for _, s in flight.rode())
         with host_phase("serve.fetch"):  # the wait and the transfer
+            t_wait = time.monotonic()
             if on_host:
                 out = np.asarray(flight.logits)  # dlint: allow[D001] host sampler needs logits
             else:
                 out = np.asarray(flight.picked)  # dlint: allow[D001] four bytes a row
+            flight.wait = time.monotonic() - t_wait
             if flight.norm_min is not None:  # (L,) floats
                 low = float(np.asarray(flight.norm_min).min())  # dlint: allow[D001] normaliser counter
                 if low < self.stats.min_normaliser:
@@ -2486,49 +2611,62 @@ class ContinuousEngine:
         counters, census, and per row the token, ``_advance`` and
         ``_retire``. A row of ``flight`` whose slot no longer holds its
         request (stopped by the step before, cancelled and swept) is
-        dropped: the pool has moved on without it."""
-        pool = self._pool
-        now = time.monotonic()
-        dt = now - flight.t0  # landing to landing for a step run ahead
-        if self._flight is not None:
-            self._flight.t0 = now  # the device starts it as this one ends
-        rode = flight.rode()
-        if flight.ahead:
-            self._count_dropped(
-                sum(r is not None for r in flight.reqs) - len(rode)
-                + sum(s.req.cancelled for _, s in rode))
-        active0 = len(rode)
-        if self._obs is not None:
-            # the fetch synced the step; the sync flag also drains the
-            # donated cache write (obs/trace.sync_device_timing), unless
-            # that would wait for the step launched ahead
-            if self._obs.sync and self._flight is None:
-                import jax
+        dropped: the pool has moved on without it.
 
-                jax.block_until_ready(self.cache)  # dlint: allow[D001] opt-in timing drain
-            self._obs.record_step(dt, active0)
-            if self._alloc is not None:
-                self._obs.kv_pages_free.set(self._alloc.n_free)
-        with host_phase("serve.census"):
-            self.stats.steps += 1
-            self.stats.sum_active += active0
-            self.stats.max_active = max(self.stats.max_active, active0)
-            self._census_dispatch("decode", 1, flight.paused, active0, dt,
-                                  rode=[s for _, s in rode])
-        with host_phase("serve.sample"):
-            for s in {id(s): s for s in (*flight.rows, *pool)
-                      if s is not None and not s.free
-                      and s.req.cancelled}.values():
-                self._retire(s, quiet)  # consumer gone: free the slot now
-            for b, s in rode:  # the others are paused, or admitted since
-                if s.free:     # (cancelled: retired above)
-                    continue
-                if s.forced:
-                    self._advance(s, s.forced.pop(0), quiet)
-                else:
-                    nxt = int(s.sampler.sample(out[b]) if on_host
-                              else out[b])
-                    self._advance(s, nxt, quiet, sampled=True)
+        All of it inside the host phase ``serve.land``, which opens at the
+        landing's instant and holds one empty ``serve.land.chunk`` per
+        admission prefill chunk the step stood behind: a capture pairs a
+        landing with the chunks it paid for by containment, on the host's
+        lines alone."""
+        with host_phase("serve.land"):
+            now = time.monotonic()
+            for _ in range(flight.chunks_ahead):
+                with host_phase("serve.land.chunk"):
+                    pass
+            pool = self._pool
+            dt = now - flight.t0  # landing to landing for a step run ahead
+            if self._flight is not None:
+                self._flight.t0 = now  # the device starts it as this one ends
+            rode = flight.rode()
+            if flight.ahead:
+                self._count_dropped(
+                    sum(r is not None for r in flight.reqs) - len(rode)
+                    + sum(s.req.cancelled for _, s in rode))
+            active0 = len(rode)
+            # what this iteration's admissions enqueued before the fetch
+            # stands before the step launched ahead, or waits for the next
+            # launch; either way this landing may be late by it
+            ahead = self._flight
+            if ahead is None:
+                self._ahead_t0 = now  # what is queued starts as this ends
+            self._book_land(
+                dt, 1, flight.chunks_ahead, flight.admits_ahead, flight.wait,
+                bool(ahead.chunks_ahead or ahead.admits_ahead)
+                if ahead is not None else any(self._ahead))
+            if self._obs is not None:
+                self._obs.record_step(dt, active0)
+                if self._alloc is not None:
+                    self._obs.kv_pages_free.set(self._alloc.n_free)
+            with host_phase("serve.census"):
+                self.stats.steps += 1
+                self.stats.sum_active += active0
+                self.stats.max_active = max(self.stats.max_active, active0)
+                self._census_dispatch("decode", 1, flight.paused, active0,
+                                      dt, rode=[s for _, s in rode])
+            with host_phase("serve.sample"):
+                for s in {id(s): s for s in (*flight.rows, *pool)
+                          if s is not None and not s.free
+                          and s.req.cancelled}.values():
+                    self._retire(s, quiet)  # consumer gone: free the slot
+                for b, s in rode:  # the others are paused, or admitted since
+                    if s.free:     # (cancelled: retired above)
+                        continue
+                    if s.forced:
+                        self._advance(s, s.forced.pop(0), quiet)
+                    else:
+                        nxt = int(s.sampler.sample(out[b]) if on_host
+                                  else out[b])
+                        self._advance(s, nxt, quiet, sampled=True)
 
     def _count_dropped(self, n: int) -> None:
         """``n`` rows of a step run ahead whose result is thrown away."""
@@ -2559,6 +2697,9 @@ class ContinuousEngine:
             s.req.n_sampled += 1
             if not s.req.t_first_token:
                 s.req.t_first_token = time.monotonic()
+                if self._obs is not None and s.req.t_admit:
+                    self._obs.prefill.observe(s.req.t_first_token
+                                              - s.req.t_admit)
         if nxt == BOS:  # reference stop: BOS before decoding it
             self._retire(s, quiet)
             return True
@@ -2777,6 +2918,10 @@ class ContinuousEngine:
         t0 = time.monotonic()  # census/ledger wall charges need it even
         #                        when the engine runs metrics-dark
         chunks0 = self.stats.prefill_chunks
+        self.stats.admit_prefills += 1
+        if not any(self._ahead):
+            self._ahead_t0 = t0
+        self._ahead[1] += 1
         jnp = self.jnp
         paged = self._alloc is not None
         # chunk-boundary preemption (ISSUE 14): paged f32 pools only —
@@ -2811,6 +2956,7 @@ class ContinuousEngine:
 
             def fwd(part, start_pos, *n_valid):
                 self.stats.prefill_chunks += 1
+                self._ahead[0] += 1
                 with host_phase("serve.admit.prefill_chunk"):
                     _, cache_box[0] = self._prefill_fwd(
                         self.params, cache_box[0],
@@ -2873,7 +3019,9 @@ class ContinuousEngine:
         s.req.out.extend(tokens[start + 1:end + 1])
         for t in tokens[start + 1:end + 1]:
             self._notify(s.req, t)
-        dt_prefill = time.monotonic() - t0
+        dt_prefill = time.monotonic() - t0  # the ENQUEUE of the programs:
+        #   the host's work, which the ledger bills; what they cost the
+        #   device is the admission account's (ContinuousStats.book_land)
         self.stats.tokens += end - start
         # prefill census record: steps=0 so the step/stall/page-step
         # conservation totals (decode/spec currency) are untouched — the
@@ -2888,7 +3036,9 @@ class ContinuousEngine:
                 dt_prefill)
         if self._obs is not None:
             self._obs.generated.inc(end - start)
-            self._obs.prefill.observe(dt_prefill)
+            self._obs.admit_prefills.inc()
+            self._obs.admit_prefill_chunks.inc(
+                self.stats.prefill_chunks - chunks0)
             self._obs.count_dispatch_tokens("prefill", end - start)
         s.pos = end
         s.token = tokens[end]
@@ -3097,6 +3247,7 @@ def generate_continuous(spec: TransformerSpec, params: dict[str, Any],
         if stats.steps_ahead:
             print(f"Steps run ahead:     {stats.steps_ahead} of "
                   f"{stats.steps}, {stats.rows_dropped_ahead} rows dropped")
+        print(f"Admission account:   {stats.admission_clause}")
         if eng.allocator is not None:
             a = eng.allocator
             print(f"Paged KV:            {a.n_pages} pages x "

@@ -1288,13 +1288,25 @@ class InferenceServer:
                   f"🌐 served {st.tokens} tokens in {st.steps} steps "
                   f"({st.avg_active:.2f} rows a step); {st.steps_ahead} "
                   f"steps launched ahead on device-resident tokens, "
-                  f"{st.rows_dropped_ahead} rows of them dropped"
+                  f"{st.rows_dropped_ahead} rows of them dropped; "
+                  f"{st.admission_clause}"
                   + (f"; state {st.state_bytes / 2**20:.0f} MiB resident, "
                      f"smallest normaliser {st.min_normaliser:.3g}"
                      if st.state_bytes else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
                   sum_active=st.sum_active, steps_ahead=st.steps_ahead,
                   rows_dropped_ahead=st.rows_dropped_ahead,
+                  land_s=st.land_s, lands_behind_admit=st.lands_behind_admit,
+                  land_behind_admit_s=st.land_behind_admit_s,
+                  prefill_chunks=st.prefill_chunks,
+                  admit_prefills=st.admit_prefills,
+                  admits_back_to_back_max=st.admits_back_to_back_max,
+                  fetch_wait_s=st.fetch_wait_s,
+                  fetch_wait_behind_admit_s=st.fetch_wait_behind_admit_s,
+                  admit_share=st.admit_share,
+                  admit_stall_ms_per_chunk=st.admit_stall_ms_per_chunk,
+                  plain_step_ms=st.plain_step_ms,
+                  host_ms_per_step=st.host_ms_per_step,
                   state_bytes=st.state_bytes,
                   min_normaliser=(st.min_normaliser
                                   if st.state_bytes else None))

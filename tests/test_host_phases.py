@@ -34,8 +34,9 @@ INFERENCE_PHASES = {"inference.dispatch", "inference.fetch",
 SERVE_PHASES = {"serve.intake", "serve.admit", "serve.admit.gather",
                 "serve.admit.prefill_chunk", "serve.admit.scatter",
                 "serve.grow_pages", "serve.stage", "serve.dispatch",
-                "serve.fetch", "serve.decode", "serve.sample",
-                "serve.census", "serve.journal"}
+                "serve.fetch", "serve.decode", "serve.land",
+                "serve.land.chunk", "serve.sample", "serve.census",
+                "serve.journal"}
 DECODE_CHILDREN = {"serve.stage", "serve.dispatch", "serve.fetch"}
 
 
@@ -310,6 +311,8 @@ def test_serve_children_lie_inside_their_parents(serve_capture):
             assert parent == "serve.decode", (name, parent)
         elif name == "serve.stage":
             assert parent in (None, "serve.decode")
+        elif name in ("serve.land.chunk", "serve.census", "serve.sample"):
+            assert parent == "serve.land", (name, parent)
         else:
             assert parent is None, (name, parent)
     inside = {n for n, p in parents if p == "serve.decode"}
