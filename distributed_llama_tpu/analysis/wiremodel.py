@@ -690,6 +690,8 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     # how the slot kernel engaged: live slots, and those of one row
     _m("dllama_moe_slots_total"),
     _m("dllama_moe_single_row_slots_total"),
+    _m("dllama_moe_chunk_pairs_total"),
+    _m("dllama_moe_chunk_slots_total"),
     _m("dllama_latent_pages_in_use"),
     # a retention spec's recurrent state
     _m("dllama_state_bytes"),

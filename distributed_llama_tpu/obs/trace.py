@@ -204,6 +204,14 @@ class EngineMetrics:
         self.moe_single_row_slots = c(
             "dllama_moe_single_row_slots_total",
             "Of those, slots that held one row (the kernel's one-row body)")
+        self.moe_chunk_pairs = c(
+            "dllama_moe_chunk_pairs_total",
+            "Routed pairs that landed on held experts in admission prefill "
+            "chunks, summed over layers")
+        self.moe_chunk_slots = c(
+            "dllama_moe_chunk_slots_total",
+            "Live slots of the expert slot kernel in admission prefill "
+            "chunks, at the chunk's rows a slot, summed over layers")
         self.latent_pages = g(
             "dllama_latent_pages_in_use",
             "Pool pages the sequences of a latent-attention model hold "
@@ -504,6 +512,12 @@ class EngineMetrics:
                 for e in range(counts.shape[1])]
         for ctr, rows in zip(self._moe_rows, counts.sum(axis=0)):
             ctr.inc(int(rows))
+
+    def record_moe_chunk(self, local_pairs: int, slots: int) -> None:
+        """One admission prefill chunk of an expert model: the pairs that
+        landed on held experts and the live slots they filled."""
+        self.moe_chunk_pairs.inc(local_pairs)
+        self.moe_chunk_slots.inc(slots)
 
     def record_retire(self, req, now: float) -> None:
         """Derive the lifecycle histograms at retirement. Cancelled and

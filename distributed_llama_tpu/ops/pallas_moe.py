@@ -24,36 +24,43 @@ a d-major leaf of that shape would be copied at the top of every step,
 ops/linear.q40_leaf_layout). ``w1`` and ``w3`` are fused at load into
 ``moe_w13`` (ops/linear.fuse_q40_layer_matmuls).
 
-Two grouped matmuls, picked by the dispatch width T (static):
+ONE grouped matmul at every dispatch width: only the (row, expert) pairs
+the router chose are computed. The dispatch's pairs are grouped into SLOTS
+of one expert and up to C rows; the grid walks the slots from a
+scalar-prefetched list, experts ascending, so a distinct expert's tile is
+fetched ONCE (a second slot of the same expert repeats the block index and
+Pallas skips the copy), never all E and never once per pair. The slot count
+is a static bound (every expert active, every capacity overflowing); the
+live count is data, and the steps past it are skipped and repeat the last
+live slot's block indices, so they move nothing. A full slot opens another:
+no pair is dropped.
 
-* ``T <= MOE_SLOT_T_MAX`` (decode, verify, small mixed dispatches): the
-  step's (row, expert) pairs are grouped into SLOTS of one expert and up to
-  ``MOE_SLOT_ROWS`` = 8 rows (one sublane tile; one row at T == 1); the
-  grid walks the slots from a scalar-prefetched list, experts ascending, so
-  a distinct expert's tile is fetched ONCE (a second slot of the same
-  expert repeats the block index and Pallas skips the copy), never all E
-  and never once per pair. The slot count is a static bound (every expert
-  active, every capacity overflowing); the live count is data, and the
-  steps past it are skipped and repeat the last live slot's block indices,
-  so they move nothing. Two bodies, and the slot's LIVE ROW COUNT (a fourth
-  prefetched list, ``fill``) alone picks between them: a slot of one row
-  takes ``_row_body`` (the nb-major matvec's arithmetic, 8 vector
-  operations a packed byte; at T == 1 the only body), any other the MXU
-  tile ``_mxu_body_merged`` over all 8 rows (the body of the chunk's
-  experts and of every dense leaf at T > 1; its time does not depend on
-  the rows it carries). On a v5e the tile streams an expert at 270-290
-  GB/s and the one-row body at 520-610 (PERF.md section 7, PR 34), so a
-  model whose experts see one or two rows (a share of DeepSeek-V3's) and
-  one whose experts see three (OLMoE) run the same kernel.
-* larger T (prefill chunks: every expert is hit): every expert runs every
-  row through ``_mxu_body_merged``, the nb-major MXU body of ops/pallas_q40
-  (``_matmul_body_nb``) with the nibble planes merged into the contraction,
-  and the rows it was not routed are weighted 0.
+C is read from the dispatch's static shape (``slot_cap``): one row at
+T == 1; one sublane tile, ``MOE_SLOT_ROWS`` = 8, up to ``MOE_SLOT_T_MAX``
+= 32 rows (decode, verify, small mixed dispatches: the call is named
+``moe_q40_slots`` in a capture); wider (a prefill chunk: ``moe_q40_grouped``)
+twice the rows an expert expects, T k / E, from 16 to 32, because the tile's
+time grows with its rows and an expert's second slot unpacks its tile again
+(OLMoE's 128-row chunk, 16 rows an expert and the busiest twice that: 32;
+a share of DeepSeek-V3's experts: 32 by its shape, 4 rows an expert in
+fact). Until PR 36 a dispatch wider than 32 rows ran EVERY held expert over
+EVERY row and weighted the rows an expert was not routed by 0: 8 and 32
+times the routed work of those two chunks.
+
+The slot's LIVE ROW COUNT (a fourth prefetched list, ``fill``) alone picks
+its body: a slot of one row takes ``_row_body`` (the nb-major matvec's
+arithmetic, 8 vector operations a packed byte; at T == 1 the only body),
+any other the MXU tile ``_mxu_body_merged`` (the body of every dense leaf at
+T > 1) over the smallest of 8 / 16 / 32 / C rows that holds it
+(``_tile_rows``: a part-filled slot of a wide dispatch pays for its rows,
+not for C). On a v5e the tile streams an expert at 270-290 GB/s at 8 rows
+and the one-row body at 520-610 (PERF.md section 7, PRs 34 and 36).
 
 All are the float32 arithmetic of the dense Q40 kernels (products at
-HIGHEST on the MXU, exact on the VPU); none dequantizes an expert to HBM.
-Off the Pallas path (codec or dense leaves: the CPU tests, F32 files)
-``_experts_xla`` scans the experts one at a time.
+HIGHEST on the MXU, exact on the VPU; under fast-prefill's
+``matmul_mode() == "bf16"`` the tile multiplies in bfloat16); none
+dequantizes an expert to HBM. Off the Pallas path (codec or dense leaves:
+the CPU tests, F32 files) ``_experts_xla`` scans the experts one at a time.
 """
 
 from __future__ import annotations
@@ -67,11 +74,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
 from .linear import StackedQ40, matmul, matmul_mode, silu
-from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ,
-                         _pick_block_t, _pick_rows_nb)
+from .pallas_q40 import _MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ
 
-MOE_SLOT_ROWS = 8                # rows one slot carries: one sublane tile
-MOE_SLOT_T_MAX = 32              # wider dispatches run every expert
+MOE_SLOT_ROWS = 8                # rows of a narrow dispatch's slot: one sublane tile
+MOE_SLOT_T_MAX = 32              # widest dispatch whose slots are one such tile
+MOE_WIDE_ROWS = 32               # most rows a wider one's slot is worth
 MOE_SLOT_TILE_MAX = 2048         # largest row tile measured (PERF.md section 7)
 
 
@@ -109,19 +116,34 @@ def route(gate: jax.Array, xb: jax.Array, k: int, router=None, bias=None):
     return topw * jnp.float32(router.scale), topi
 
 
-def slot_cap(t: int) -> int:
-    """Rows a slot of a T-row dispatch carries: one sublane tile, one at
-    T == 1; 0 where the dispatch is too wide for slots."""
-    return 0 if t > MOE_SLOT_T_MAX else (MOE_SLOT_ROWS if t > 1 else 1)
+def slot_cap(t: int, k: int, n_experts: int) -> int:
+    """Rows a slot carries, from what a dispatch shows statically: its T
+    rows, the k pairs a row, the ``n_experts`` held. One row at T == 1, one
+    sublane tile up to ``MOE_SLOT_T_MAX`` rows (decode, verify). A wider
+    dispatch (a prefill chunk): the multiple of 8 that holds TWICE the
+    rows an expert expects, T k / n_experts (a router is skewed: OLMoE's
+    busiest experts take twice the mean of a chunk's rows, and a second
+    slot unpacks the expert's tile again), from 16 to ``MOE_WIDE_ROWS``:
+    the tile's time grows with its rows, and past 32 faster than the rows
+    (PERF.md section 7, PR 36: 128 rows on 64 experts of OLMoE's ``w13``
+    0.64 ms at 32 rows a slot, 0.82 at 16, 1.07 at 64). A part-filled slot
+    pays for the rows it holds, not for the capacity (``_tile_rows``), so
+    a share of DeepSeek-V3's experts, which expect 32 rows by their shape
+    and see 4, lose nothing to it. Where most experts take every row
+    (k over half of them) one slot holds all T rows: the every-expert
+    kernel's work, at its price."""
+    if t <= MOE_SLOT_T_MAX:
+        return MOE_SLOT_ROWS if t > 1 else 1
+    expect = -(-t * k // n_experts)
+    if 2 * expect > t:
+        return -(-t // 8) * 8
+    return min(max(-(-2 * expect // 8) * 8, 16), MOE_WIDE_ROWS)
 
 
-def slot_census(counts, t: int) -> tuple[int, int]:
-    """(live slots, slots of one row) of T-row dispatches whose routed
-    rows per held expert are ``counts`` (any shape; numpy, on the host):
-    what ``build_slots`` builds from them, for the counters."""
-    cap = slot_cap(t)
-    if not cap:
-        return 0, 0
+def slot_census(counts, cap: int) -> tuple[int, int]:
+    """(live slots, slots of one row) of dispatches whose routed rows per
+    held expert are ``counts`` (any shape; numpy, on the host) at ``cap``
+    rows a slot: what ``build_slots`` builds from them, for the counters."""
     single = counts if cap == 1 else counts % cap == 1
     return int((-(-counts // cap)).sum()), int(single.sum())
 
@@ -203,12 +225,22 @@ def _row_planes(x: jax.Array, nb: int) -> jax.Array:
                    + [(0, 128 - packed.shape[-1])])
 
 
+def _tile_rows(c: int) -> tuple[int, ...]:
+    """Row counts the MXU tile is compiled at inside a slot of ``c`` rows:
+    a slot runs the smallest that holds its live rows. The tile's time
+    grows with the rows it multiplies (1 : 1.0 : 1.1 : 1.7 : 3.3 at 8 / 16
+    / 32 / 64 / 128 on DeepSeek-V3's ``w13``), and most slots of a wide
+    dispatch are part filled (PERF.md section 7, PR 36: a kernel with a
+    64-row body as well ran slower, not faster)."""
+    return tuple(r for r in (8, 16, 32) if r < c) + (c,)
+
+
 def _kernel_moe_slots(layer_ref, sexp_ref, n_ref, fill_ref, qs_ref,
-                      scale_ref, *refs):
+                      scale_ref, *refs, bf16):
     """One slot, its body picked by its live rows (``fill_ref``; 0 past the
-    live count: nothing runs). ``refs``: at 8 rows a slot the merged planes
-    xlo, xhi (8, NJ * nb) of the slot's rows; always the packed planes
-    (nb, 128) of its FIRST row; out."""
+    live count: nothing runs). ``refs``: at C > 1 rows a slot the merged
+    planes xlo, xhi (C, NJ * nb) of the slot's rows; always the packed
+    planes (nb, 128) of its FIRST row; out."""
     del layer_ref, sexp_ref, n_ref  # consumed by the index maps
     *tile_refs, row_ref, out_ref = refs
     rows = fill_ref[pl.program_id(1)]
@@ -217,16 +249,25 @@ def _kernel_moe_slots(layer_ref, sexp_ref, n_ref, fill_ref, qs_ref,
     def _():
         _row_body(qs_ref, scale_ref[...], row_ref, out_ref)
 
-    if tile_refs:  # at T == 1 a slot never holds a second row
-        @pl.when(rows > 1)
-        def _():
-            _mxu_body_merged(qs_ref, scale_ref[...], *tile_refs, out_ref,
-                             False)
+    if not tile_refs:  # at T == 1 a slot never holds a second row
+        return
+    sizes = _tile_rows(out_ref.shape[0])
+    for lo, hi in zip((1,) + sizes, sizes):
+        whole = hi == sizes[-1]
+
+        @pl.when(rows > lo if whole else (rows > lo) & (rows <= hi))
+        def _(hi=hi, whole=whole):
+            # the first ``hi`` rows of the slot (a multiple of 8: whole
+            # sublane tiles); the rows past them are never read back
+            cut = (lambda r: r) if whole else (lambda r: r.at[pl.ds(0, hi)])
+            _mxu_body_merged(qs_ref, scale_ref[...], *map(cut, tile_refs),
+                             cut(out_ref), bf16)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret",
+                                             "bf16"))
 def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
-                  rows=None, *, block_rows, interpret):
+                  rows=None, *, block_rows, interpret, bf16=False):
     """out[a, c] = dequant(w[layer, slot_expert[a]]) @ (row c of slot a) for
     the live slots a < n_slots and their live rows c < fill[a] (the rest of
     ``out`` is not written, or holds what a row never read back gave).
@@ -234,7 +275,11 @@ def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
     rows: ``xs`` (A, C, n) holds them, or ``xs`` (T, n) is the dispatch's
     rows and ``rows`` (A, C) says which each slot takes (the planes are
     then built once a row and gathered, not once a slot lane); C is one row
-    (T == 1) or one sublane tile of 8."""
+    (T == 1) or a multiple of 8 (``slot_cap``). ``bf16``: the tile's
+    products in bfloat16 (fast-prefill); the one-row body stays exact. In a
+    capture the call is ``moe_q40_slots`` up to one sublane tile a slot (the
+    decode steps' kernel, which the benchmark's roofline shares find by
+    that name) and ``moe_q40_grouped`` beyond (a chunk's)."""
     nb, d = qs_t.shape[-2], qs_t.shape[-1]
     a, c = xs.shape[:2] if rows is None else rows.shape
 
@@ -266,14 +311,15 @@ def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
                                lambda i, g, L, S, N, F: (at(g, N), 0, i)),
     )
     return pl.pallas_call(
-        _kernel_moe_slots, grid_spec=grid_spec,
+        functools.partial(_kernel_moe_slots, bf16=bf16),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((a, c, d), jnp.float32),
         compiler_params=_VMEM64_PARAMS, interpret=interpret,
-        name="moe_q40_slots",
+        name="moe_q40_slots" if c <= MOE_SLOT_ROWS else "moe_q40_grouped",
     )(layer, slot_expert, n_slots.reshape(1), fill, qs_t, scale, *planes)
 
 
-# -- the every-expert kernel (MXU body) ---------------------------------------
+# -- the MXU tile ---------------------------------------------------------------
 
 def _mxu_body_merged(qs_ref, s, xlo_ref, xhi_ref, out_ref, bf16: bool):
     """``_matmul_body_nb`` (ops/pallas_q40: dequantize the tile, float32
@@ -300,12 +346,6 @@ def _mxu_body_merged(qs_ref, s, xlo_ref, xhi_ref, out_ref, bf16: bool):
     out_ref[...] = acc
 
 
-def _kernel_moe_mxu(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref, out_ref,
-                    *, bf16):
-    del layer_ref
-    _mxu_body_merged(qs_ref, scale_ref[...], xlo_ref, xhi_ref, out_ref, bf16)
-
-
 def _merged_planes(x: jax.Array, nb: int):
     """(..., T, n) rows -> xlo, xhi (..., T, NJ * nb): value j (xhi: 16 + j)
     of block b at column j * nb + b."""
@@ -315,83 +355,38 @@ def _merged_planes(x: jax.Array, nb: int):
             flat[..., 1, :].reshape(*x.shape[:-1], NJ * nb))
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "block_t",
-                                             "interpret", "bf16"))
-def moe_q40_mxu(layer, qs_t, scale, x, *, block_rows, block_t, interpret,
-                bf16=False):
-    """out[e] = dequant(w[layer, e]) @ x for EVERY expert e: ``x`` (T, n)
-    is shared by the experts, or (E, T, n) holds each expert's own rows."""
-    n_exp, nb, d = qs_t.shape[1], qs_t.shape[-2], qs_t.shape[-1]
-    t = x.shape[-2]
-    xlo, xhi = _merged_planes(x, nb)
-    if x.ndim == 2:
-        x_spec = pl.BlockSpec((block_t, NJ * nb),
-                              lambda e, ti, i, L: (ti, 0))
-    else:
-        x_spec = pl.BlockSpec((None, block_t, NJ * nb),
-                              lambda e, ti, i, L: (e, ti, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_exp, t // block_t, d // block_rows),
-        in_specs=[
-            pl.BlockSpec((None, None, NJ, nb, block_rows),
-                         lambda e, ti, i, L: (L[0], e, 0, 0, i)),
-            pl.BlockSpec((None, None, nb, block_rows),
-                         lambda e, ti, i, L: (L[0], e, 0, i)),
-            x_spec, x_spec,
-        ],
-        out_specs=pl.BlockSpec((None, block_t, block_rows),
-                               lambda e, ti, i, L: (e, ti, i)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel_moe_mxu, bf16=bf16),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_exp, t, d), jnp.float32),
-        compiler_params=_VMEM64_PARAMS, interpret=interpret,
-        name="moe_q40_mxu",
-    )(layer, qs_t, scale, xlo, xhi)
-
-
 # -- the expert layer ---------------------------------------------------------
 
-def _slot_block_rows(d: int, nb: int) -> int | None:
-    """Row tile of the slot kernel: the largest multiple of 128 dividing
-    ``d`` under the MXU body's measured rows x nb boundary
-    (``_MATMUL_ROWSXNB_CAP``) and ``MOE_SLOT_TILE_MAX``. Not the dense MXU
-    rule's 256 rows: an expert's tile is small (OLMoE's ``w13`` at 256 rows
-    is 328 KB, 1.2 us of the tile's time against a grid step's fixed 0.35),
-    and both bodies ran faster the larger the tile on all four expert
-    leaves of the benchmark (2048 / 2048 on OLMoE, 512 / 1792 on
-    DeepSeek-V3's share: my chip run, PR 34)."""
-    limit = min(_MATMUL_ROWSXNB_CAP // nb, MOE_SLOT_TILE_MAX)
+def _slot_block_rows(d: int, nb: int, cap: int = MOE_SLOT_ROWS) -> int | None:
+    """Row tile of the slot kernel at ``cap`` rows a slot: the largest
+    multiple of 128 dividing ``d`` under the MXU body's measured rows x nb
+    boundary (``_MATMUL_ROWSXNB_CAP``) and ``MOE_SLOT_TILE_MAX``. Not the
+    dense MXU rule's 256 rows: an expert's tile is small (OLMoE's ``w13``
+    at 256 rows is 328 KB, 1.2 us of the tile's time against a grid step's
+    fixed 0.35), and both bodies ran faster the larger the tile on all four
+    expert leaves of the benchmark (2048 / 2048 on OLMoE, 512 / 1792 on
+    DeepSeek-V3's share: my chip run, PR 34). Past 16 rows a slot HALF that
+    boundary: at a mid-sized row count Mosaic keeps more of the unpacked
+    planes live (ops/pallas_q40._pick_block_rows met it at 32 rows), and
+    the chip's compiler once refused DeepSeek-V3's ``w13`` at 32 rows and
+    the full 512-row tile (76.8 MB of scoped VMEM; my chip run, PR 36)."""
+    budget = _MATMUL_ROWSXNB_CAP // (1 if cap <= 16 else 2)
+    limit = min(budget // nb, MOE_SLOT_TILE_MAX)
     return next((r for r in range(min(d, limit) // 128 * 128, 0, -128)
                  if d % r == 0), None)
 
 
-def _mxu_block_rows(d: int, nb: int, block_t: int) -> int | None:
-    """Row tile of the MXU kernel: _q40_matmul_nbmajor's T > 8 rule."""
-    rows = _pick_rows_nb(d, nb)
-    if rows is None:
-        return None
-    limit = _MATMUL_ROWSXNB_CAP // nb
-    rows = next((r for r in range(min(rows, limit - limit % 128), 0, -128)
-                 if d % r == 0), None)
-    if rows is not None and block_t < 128 and rows > 256:
-        rows = 256 if d % 256 == 0 else (128 if d % 128 == 0 else None)
-    return rows
-
-
 def shape_places(d: int, nb: int) -> bool:
-    """True when both grouped kernels can tile a (d, nb * 32) expert
-    tensor: ops/linear.pack_q40_params packs an expert stack nb-major only
-    then (else it stays codec and takes ``_experts_xla``)."""
-    return (_slot_block_rows(d, nb) is not None
-            and _mxu_block_rows(d, nb, 8) is not None)
+    """True when the slot kernel can tile a (d, nb * 32) expert tensor at
+    every capacity: ops/linear.pack_q40_params packs an expert stack
+    nb-major only then (else it stays codec and takes ``_experts_xla``)."""
+    return _slot_block_rows(d, nb, MOE_WIDE_ROWS) is not None
 
 
-def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret):
+def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret,
+                   bf16=False):
     t, k = topi.shape
-    cap = slot_cap(t)
+    cap = slot_cap(t, k, n_experts)
     (slot_expert, n_slots, fill, slot_rows, pair_slot, pair_lane,
      counts) = build_slots(topi, n_experts, cap)
 
@@ -399,8 +394,8 @@ def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret):
         nb, d = w.qs_t.shape[-2:]
         return moe_q40_slots(layer, slot_expert, n_slots, fill, w.qs_t,
                              w.scale, xs, rows,
-                             block_rows=_slot_block_rows(d, nb),
-                             interpret=interpret)
+                             block_rows=_slot_block_rows(d, nb, cap),
+                             interpret=interpret, bf16=bf16)
 
     h13 = call(w13, xb, slot_rows)                       # (A, C, 2 hidden)
     hid = h13.shape[-1] // 2
@@ -417,27 +412,6 @@ def _routing_mask(topw, topi, n_experts):
     onehot = topi[..., None] == jnp.arange(n_experts, dtype=topi.dtype)
     return (jnp.sum(jnp.where(onehot, topw[..., None], 0.0), axis=1),
             jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32))
-
-
-def _experts_mxu(layer, w13, w2, xb, topw, topi, n_experts, interpret, bf16):
-    wmask, counts = _routing_mask(topw, topi, n_experts)
-    t = xb.shape[0]
-    pad = (-t) % 8                       # the MXU body tiles T by eights
-    if pad:
-        xb = jnp.pad(xb, ((0, pad), (0, 0)))
-        wmask = jnp.pad(wmask, ((0, pad), (0, 0)))
-
-    def call(w, x):
-        nb, d = w.qs_t.shape[-2:]
-        block_t = _pick_block_t(t + pad, nb)
-        return moe_q40_mxu(layer, w.qs_t, w.scale, x,
-                           block_rows=_mxu_block_rows(d, nb, block_t),
-                           block_t=block_t, interpret=interpret, bf16=bf16)
-
-    h13 = call(w13, xb)                                  # (E, T, 2 hidden)
-    hid = h13.shape[-1] // 2
-    hb = silu(h13[..., :hid]) * h13[..., hid:] * wmask.T[:, :, None]
-    return jnp.sum(call(w2, hb), axis=0)[:t], counts
 
 
 def _experts_xla(lw, xb, topw, topi, n_experts):
@@ -484,13 +458,9 @@ def moe_ffn(spec, lw: dict, xb: jax.Array):
         if isinstance(w13, StackedQ40) and isinstance(w2, StackedQ40):
             interpret = jax.default_backend() != "tpu"
             layer = jnp.asarray(w13.layer, dtype=jnp.int32).reshape(1)
-            if x2.shape[0] <= MOE_SLOT_T_MAX:
-                y, counts = _experts_slots(layer, w13.w, w2.w, x2, topw,
-                                           topi, n_exp, interpret)
-            else:
-                y, counts = _experts_mxu(layer, w13.w, w2.w, x2, topw, topi,
-                                         n_exp, interpret,
-                                         matmul_mode() == "bf16")
+            y, counts = _experts_slots(layer, w13.w, w2.w, x2, topw, topi,
+                                       n_exp, interpret,
+                                       matmul_mode() == "bf16")
         elif "moe_w1" not in lw or isinstance(lw["moe_w1"], StackedQ40):
             raise NotImplementedError(
                 "expert stacks packed for the kernels without their fused "

@@ -343,6 +343,9 @@ class _Flight:
     # and admissions that enqueued device work
     chunks_ahead: int = 0
     admits_ahead: int = 0
+    # an expert spec's: (rows, (L, E) routed-rows counts, still on the
+    # device) of each of those chunks; complete once this step has landed
+    chunk_moe: tuple = ()
     wait: float = 0.0  # seconds ``_fetch`` stood in the blocking read of it
 
     def rode(self) -> list:
@@ -428,6 +431,13 @@ class ContinuousStats:
     # them that held ONE row, which take the one-row body
     moe_slots: int = 0
     moe_single_row_slots: int = 0
+    # the same of admission prefill chunks, which the counters above leave
+    # out: pairs that landed on held experts, and the live slots they
+    # filled at the chunk's capacity (``slot_cap`` of its rows). Pairs over
+    # slots x capacity is the fill; slots a chunk and layer is what the
+    # kernel walked
+    moe_chunk_pairs: int = 0
+    moe_chunk_slots: int = 0
     # a latent spec: pool pages in use (each page_size positions x
     # latent.width x layers of ONE plane; pages the prefix tree keeps
     # count), as of the last landed step; and the cached positions the
@@ -512,6 +522,12 @@ class ContinuousStats:
         self.moe_single_row_slots += slots[1]
         load = counts.sum(axis=0, dtype=np.int64)
         self.moe_load = load if self.moe_load is None else self.moe_load + load
+
+    def count_moe_chunk(self, local_pairs: int, slots: int) -> None:
+        """One admission prefill chunk: the pairs that landed on held
+        experts, summed over layers, and the live slots they filled."""
+        self.moe_chunk_pairs += local_pairs
+        self.moe_chunk_slots += slots
 
     @property
     def tokens_per_s(self) -> float:
@@ -934,12 +950,16 @@ class ContinuousEngine:
                 # is also told how many of its positions are the prompt's)
                 from ..models.llama import forward_retention
 
+                # an expert spec's chunk hands out its (L, E) routed-rows
+                # counts as its decode step does
+                chunk_fwd = (
+                    functools.partial(forward_retention, spec) if self._state
+                    else functools.partial(forward, spec,
+                                           moe_counts=bool(spec.n_experts)))
                 self._prefill_fwd = _shared_program(
                     ("prefill", spec, fast_prefill),
-                    lambda: _maybe_bf16(
-                        functools.partial(forward_retention if self._state
-                                          else forward, spec), fast_prefill,
-                        jax, jit=True))
+                    lambda: _maybe_bf16(chunk_fwd, fast_prefill, jax,
+                                        jit=True))
                 self._scratch_cache = lambda: init_cache(spec, dtype)
         # step_once's ONE program (``_with_pick``): logits, picked, cache[,
         # counts] from the previous step's picks and one staged block
@@ -966,6 +986,9 @@ class ContinuousEngine:
         # enqueue, or the landing after which nothing else was in flight)
         self._ahead = [0, 0]
         self._ahead_t0 = 0.0
+        # an expert spec's: those chunks' (rows, routed-rows counts), left
+        # on the device until the dispatch behind them has landed
+        self._chunk_moe: list = []
         # rows that left the pool to land with the step in flight
         # (_hand_over), until it has landed: fail_all must reach them
         self._leaving: list[_Slot] = []
@@ -1577,6 +1600,7 @@ class ContinuousEngine:
             now = time.monotonic()
             dt = now - t0
             self._book_land(dt, 1, *self._queued_ahead(), now - t_wait)
+            self._count_chunk_moe(self._take_chunk_moe())
             if self._obs is not None:
                 self._obs.record_step(dt, n_active0)
                 if self._alloc is not None:
@@ -1898,6 +1922,7 @@ class ContinuousEngine:
             now = time.monotonic()
             dt = now - t0
             self._book_land(dt, k, *self._queued_ahead(), now - t_wait)
+            self._count_chunk_moe(self._take_chunk_moe())
             if self._obs is not None:
                 self._obs.record_step(dt, n_active0, steps=k)
                 if self._alloc is not None:
@@ -2428,6 +2453,7 @@ class ContinuousEngine:
             self._count_dropped(sum(r is not None for r in ahead.reqs))
             self._ahead[0] += ahead.chunks_ahead  # still ahead of the next
             self._ahead[1] += ahead.admits_ahead
+            self._chunk_moe[:0] = ahead.chunk_moe
             self._ahead_t0 = time.monotonic()
             self._flight = None
         self._journal_sync()
@@ -2550,7 +2576,8 @@ class ContinuousEngine:
         beside = more[0] if more else None  # what the spec's kind counts
         moe, norm_min = (None, beside) if self._state else (beside, None)
         return _Flight(rows, reqs, paused, logits, picked, moe, norm_min,
-                       t0, prev is not None, *self._queued_ahead())
+                       t0, prev is not None, *self._queued_ahead(),
+                       self._take_chunk_moe())
 
     def _queued_ahead(self) -> tuple[int, int]:
         """(admission prefill chunks, admissions with device work) enqueued
@@ -2560,6 +2587,33 @@ class ContinuousEngine:
         chunks, admits = self._ahead
         self._ahead = [0, 0]
         return chunks, admits
+
+    def _take_chunk_moe(self) -> tuple:
+        """The routed-rows counts of the chunks enqueued since the last
+        dispatch (an expert spec's; still on the device), for the dispatch
+        launched now to read once it has landed."""
+        taken, self._chunk_moe = tuple(self._chunk_moe), []
+        return taken
+
+    def _slot_census(self, local, rows: int) -> tuple[int, int]:
+        """(live slots, slots of one row) of a ``rows``-row dispatch whose
+        (L, E held) routed-rows counts are ``local``, at the rows a slot
+        its shape gives (``ops/pallas_moe.slot_cap``)."""
+        from ..ops.pallas_moe import slot_cap, slot_census
+
+        return slot_census(local, slot_cap(
+            rows, self.spec.n_active_experts, local.shape[1]))
+
+    def _count_chunk_moe(self, chunk_moe) -> None:
+        """Count chunks whose programs are known to have run (a dispatch
+        enqueued behind them has landed): no wait, a few KB each."""
+        held = self.spec.held_columns
+        for rows, counts in chunk_moe:
+            local = np.asarray(counts)[:, held]  # dlint: allow[D001] complete: the step behind it landed
+            pairs, slots = int(local.sum()), self._slot_census(local, rows)[0]
+            self.stats.count_moe_chunk(pairs, slots)
+            if self._obs is not None:
+                self._obs.record_moe_chunk(pairs, slots)
 
     def _book_land(self, dt: float, steps: int, chunks: int, admits: int,
                    wait: float, enqueued_since: bool = False) -> None:
@@ -2592,13 +2646,12 @@ class ContinuousEngine:
                         self._obs.retention_min_normaliser.set(low)
             if flight.moe is not None:  # 4 KB beside them
                 moe = np.asarray(flight.moe)  # dlint: allow[D001] routed-rows counters
-                from ..ops.pallas_moe import slot_census
-
                 held = self.spec.held_columns
-                census = slot_census(moe[:, held], self.slots)
+                census = self._slot_census(moe[:, held], self.slots)
                 self.stats.count_moe(moe, held, census)
                 if self._obs is not None:
                     self._obs.record_moe(moe, held, census)
+            self._count_chunk_moe(flight.chunk_moe)
             if self.spec.latent:
                 self.stats.latent_pages = (self._alloc.n_pages
                                            - self._alloc.n_free)
@@ -2958,10 +3011,12 @@ class ContinuousEngine:
                 self.stats.prefill_chunks += 1
                 self._ahead[0] += 1
                 with host_phase("serve.admit.prefill_chunk"):
-                    _, cache_box[0] = self._prefill_fwd(
+                    _, cache_box[0], *moe = self._prefill_fwd(
                         self.params, cache_box[0],
                         jnp.asarray(part, jnp.int32), jnp.int32(start_pos),
                         *(jnp.int32(n) for n in n_valid))
+                if moe:  # read where the dispatch behind it lands
+                    self._chunk_moe.append((len(part), moe[0]))
 
             # a retention spec's chunk says how many of its positions are
             # the prompt's (``valid``): a padded one must not reach a state

@@ -182,12 +182,14 @@ def _latent_paged(ps: int = 16):
              _sd((b,), jnp.int32), _sd((b, SEQ // ps), jnp.int32)))
 
 
-def _moe(kind: str, leaf: str, rows: int, model: str = "olmoe"):
+def _moe(leaf: str, rows: int, model: str = "olmoe"):
     """The routed-expert kernels (ops/pallas_moe) at OLMoE-1B-7B's widths:
     64 experts a layer, ``w13`` (2 x 1024, 2048) and ``w2`` (2048, 1024),
     nb-major (``model`` "ds": DeepSeek-V3's, 32 held experts of width 2048
-    on dim 7168). ``slots``: a decode dispatch of ``rows`` rows (8 experts
-    a row); ``mxu``: a prefill chunk of ``rows`` rows through every expert."""
+    on dim 7168): the slot call of a dispatch of ``rows`` rows (8 experts
+    a row) at the capacity its shape gives: a decode step's, one row's, a
+    128-row prefill chunk's (``w13``: the dispatch's rows and each slot's
+    row list; ``w2``: each slot's own rows)."""
     from distributed_llama_tpu.ops import pallas_moe as pm
 
     n_exp, k = (64, 8) if model == "olmoe" else (32, 8)
@@ -196,21 +198,15 @@ def _moe(kind: str, leaf: str, rows: int, model: str = "olmoe"):
     nb = n // 32
     qs_t = _sd((2, n_exp, 16, nb, d), jnp.uint8)
     scale = _sd((2, n_exp, nb, d), jnp.float32)
-    layer = _sd((1,), jnp.int32)
-    if kind == "slots":
-        cap = pm.slot_cap(rows)
-        a = pm.max_slots(rows, k, n_exp, cap)
-        fn = functools.partial(
-            pm.moe_q40_slots, interpret=False,
-            block_rows=pm._slot_block_rows(d, nb))
-        return fn, (layer, _sd((a,), jnp.int32), _sd((), jnp.int32),
-                    _sd((a,), jnp.int32), qs_t, scale,
-                    _sd((a, cap, n), jnp.float32))
-    block_t = pm._pick_block_t(rows, nb)
-    fn = functools.partial(pm.moe_q40_mxu, interpret=False, block_t=block_t,
-                           block_rows=pm._mxu_block_rows(d, nb, block_t))
-    x = (rows, n) if leaf == "w13" else (n_exp, rows, n)   # w2: own rows
-    return fn, (layer, qs_t, scale, _sd(x, jnp.float32))
+    cap = pm.slot_cap(rows, k, n_exp)
+    a = pm.max_slots(rows, k, n_exp, cap)
+    fn = functools.partial(pm.moe_q40_slots, interpret=False,
+                           block_rows=pm._slot_block_rows(d, nb))
+    xs = ((_sd((rows, n), jnp.float32), _sd((a, cap), jnp.int32))
+          if leaf == "w13" and rows > 32 else
+          (_sd((a, cap, n), jnp.float32),))
+    return fn, (_sd((1,), jnp.int32), _sd((a,), jnp.int32),
+                _sd((), jnp.int32), _sd((a,), jnp.int32), qs_t, scale, *xs)
 
 
 def _q40_wide_w2():
@@ -287,10 +283,12 @@ CASES = {
     # was (first written with ``ref.at[0]`` views of blocks 1 to 4 lanes
     # wide): "Slice shape along dimension 3 must be aligned to tiling (128),
     # but is 4"
+    # (``wide``: a 128-row chunk's slots, at the rows a slot its shape
+    # gives: it was the every-expert call ``mxu`` until PR 36)
     **{f"moe-{kind}-{leaf}-T{rows}":
-       (functools.partial(_moe, kind, leaf, rows), True)
+       (functools.partial(_moe, leaf, rows), True)
        for kind, rows in (("slots", 16), ("slots", 32), ("slots", 1),
-                          ("mxu", 128))
+                          ("wide", 128))
        for leaf in ("w13", "w2")},
     # the state read and rewritten in place (whole-head 4.3 MB blocks under
     # a raised scoped-VMEM limit), and the chunk's float32 MXU matmuls
@@ -312,9 +310,9 @@ CASES = {
        for t in (1, 32)},
     "q40-nb-ds-w2-T128": (functools.partial(_q40, "nb", "ds-w2", 128), True),
     **{f"moe-ds-{kind}-{leaf}-T{rows}":
-       (functools.partial(_moe, kind, leaf, rows, "ds"), True)
+       (functools.partial(_moe, leaf, rows, "ds"), True)
        for kind, rows in (("slots", 32), ("slots", 16), ("slots", 1),
-                          ("mxu", 128))
+                          ("wide", 128))
        for leaf in ("w13", "w2")},
 }
 
@@ -328,6 +326,26 @@ def test_kernel_compiles_for_v5e(chip, case):
         shapes)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert ("tpu_custom_call" in text) == kernel, case
+
+
+@pytest.mark.parametrize("rows,name", [(32, "moe_q40_slots"),
+                                       (128, "moe_q40_grouped")])
+def test_expert_call_is_named_by_its_width(chip, rows, name):
+    """A capture names a Pallas call by its ``name=``. The benchmark's
+    expert roofline shares find the DECODE kernel by ``moe_q40_slots`` and
+    every expert kernel by ``moe_q40``: a chunk's wide call keeps the
+    second prefix and not the first, or a step whose span held an admission
+    would count the chunk's calls as a decode step's."""
+    fn, shapes = _moe("w2", rows)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        shapes)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.split("=")[0].split("%")[-1].strip()
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and calls[0].startswith(name), calls
+    assert calls[0].startswith("moe_q40")
+    assert (rows > 32) != calls[0].startswith("moe_q40_slots")
 
 
 # ---- whole sharded programs: no weight-sized copy in a step ---------------

@@ -306,7 +306,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_add_up_to_the_layer():
     assert np.abs(prog_sum - want).max() < 2e-5
 
 
-@pytest.mark.parametrize("rows", [3, 16, 40])     # slots, slots, every-expert
+@pytest.mark.parametrize("rows", [3, 16, 40])     # narrow slots twice, wide
 def test_the_grouped_kernels_walk_held_experts_only(rows, monkeypatch):
     """Pairs that land on experts held elsewhere take no slot and add
     nothing: the packed kernels against the XLA scan, on a share."""

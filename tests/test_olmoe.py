@@ -96,7 +96,7 @@ def test_forward_whole_sequence(kernel_mode, qk_norm):
     assert ("rms_q" in params) == qk_norm
     got, _ = jax.jit(lambda p, c, t: forward(spec, p, c, t, jnp.int32(0)))(
         params, init_cache(spec), jnp.asarray(toks, jnp.int32))
-    n = compared(margins, SEQ * 3 // 4)   # T = 48 > 32: the MXU kernel
+    n = compared(margins, SEQ * 3 // 4)   # T = 48 > 32: wide slots
     assert np.abs(np.asarray(got)[:n] - ref[:n]).max() < TOL
 
 
@@ -288,6 +288,8 @@ def test_slot_counters_over_a_served_run(kind, tree):
         st = eng.stats
         assert st.steps > 0
         assert st.moe_slots == st.moe_single_row_slots == st.moe_pairs == 0
+        assert st.prefill_chunks > 0
+        assert st.moe_chunk_pairs == st.moe_chunk_slots == 0
         assert reg.get("dllama_moe_slots_total").value == 0
         assert reg.get("dllama_moe_single_row_slots_total").value == 0
         return
@@ -302,6 +304,61 @@ def test_slot_counters_over_a_served_run(kind, tree):
     assert reg.get("dllama_moe_slots_total").value == st.moe_slots
     assert reg.get("dllama_moe_single_row_slots_total").value == \
         st.moe_single_row_slots
+
+
+def test_chunk_counters_are_read_at_a_landing_not_in_admit(tree, monkeypatch):
+    """``moe_chunk_pairs`` / ``moe_chunk_slots``: an admission chunk's
+    (L, E) counts stay on the device through ``_admit`` and are counted
+    where the scheduler waits anyway, at the landing of the step launched
+    behind the chunk; the decode counters leave chunks out. Chunks of 40
+    rows (the wide grid) and a per-token tail (one row a slot)."""
+    from distributed_llama_tpu.obs.metrics import Registry
+    from distributed_llama_tpu.runtime.continuous import (ContinuousEngine,
+                                                          Request)
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    reg = Registry()
+    eng = ContinuousEngine(SPEC, tree, slots=2, temperature=0.0, topp=0.9,
+                           seed=3, page_size=4, prefill_chunk=40, metrics=reg)
+    chunks, deferred, fwd, admit = [], [], eng._prefill_fwd, eng._admit
+
+    def recording(params, cache, part, *rest):
+        out = fwd(params, cache, part, *rest)
+        chunks.append((int(part.shape[0]), out[2]))
+        return out
+
+    def watched():
+        st, n = eng.stats, len(chunks)
+        before = (st.moe_chunk_pairs, st.moe_chunk_slots)
+        admit()
+        if len(chunks) > n:    # chunks enqueued: none of them is read yet
+            assert (st.moe_chunk_pairs, st.moe_chunk_slots) == before
+            assert len(eng._chunk_moe) >= len(chunks) - n
+            deferred.append(len(chunks) - n)
+
+    eng._prefill_fwd, eng._admit = recording, watched
+    rng = np.random.default_rng(3)
+    reqs = [eng.submit(Request(
+        tokens=[1] + [int(t) for t in rng.integers(3, 500, n)], steps=52))
+        for n in (44, 8, 41)]     # 40 + 4 x 1; one padded chunk; 40 + 1
+    while eng.step_once():
+        pass
+    assert all(r.done.is_set() and r.error is None for r in reqs)
+    st, k, L, E = eng.stats, SPEC.n_active_experts, SPEC.n_layers, \
+        SPEC.n_experts
+    assert sorted(t for t, _ in chunks) == [1] * 5 + [40] * 3
+    assert sum(deferred) == len(chunks) == st.prefill_chunks
+    assert not eng._chunk_moe
+    pairs = sum(int(np.asarray(c).sum()) for _, c in chunks)
+    slots = sum(pallas_moe.slot_census(
+        np.asarray(c), pallas_moe.slot_cap(t, k, E))[0] for t, c in chunks)
+    assert st.moe_chunk_pairs == pairs == (5 + 3 * 40) * k * L
+    assert st.moe_chunk_slots == slots
+    # a 40-row chunk fills fewer slots than pairs; a one-row one as many
+    assert 5 * k * L < slots < pairs
+    assert st.moe_pairs == st.steps * 2 * k * L       # decode steps alone
+    assert reg.get("dllama_moe_chunk_pairs_total").value == pairs
+    assert reg.get("dllama_moe_chunk_slots_total").value == slots
 
 
 # -- (iv): the grouped kernels, interpret mode, against a per-pair loop ------
@@ -347,9 +404,12 @@ def _routing(case, rows, k=2, n_experts=8, seed=0):
     elif case == "rows_share_every_expert":
         topi = np.tile(np.array([[5, 2]]), (rows, 1))
     elif case.startswith("fill"):
-        # expert 5 takes exactly n rows (one slot up to 8, two at 9); the
-        # other choices go round the rest
-        n = min(int(case[4:]), rows)
+        # expert 5 takes exactly n rows (one slot up to the capacity, two
+        # one past it; ``fillcap``: the dispatch's own capacity); the other
+        # choices go round the rest
+        cap = pallas_moe.slot_cap(rows, k, n_experts)
+        n = min({"cap": cap, "cap+1": cap + 1}.get(
+            case[4:]) or int(case[4:]), rows)
         others = [e for e in range(n_experts) if e != 5]
         topi = np.array([[5 if t < n else others[(2 * t + 1) % 7],
                           others[(2 * t) % 7]] for t in range(rows)])
@@ -364,10 +424,16 @@ def _routing(case, rows, k=2, n_experts=8, seed=0):
 SLOT_CASES = ["random", "rows_share_every_expert",
               "one_expert_takes_every_row", "held_share", "fill1", "fill2",
               "fill7", "fill8", "fill9"]
+# a dispatch wider than MOE_SLOT_T_MAX rows (a prefill chunk) takes the same
+# grid at the capacity its shape gives; 13 rows: the narrow grid, rows past
+# a sublane tile
+WIDE_CASES = ["random", "one_expert_takes_every_row", "held_share", "fill1",
+              "fillcap", "fillcap+1"]
 
 
-@pytest.mark.parametrize("case", SLOT_CASES)
-@pytest.mark.parametrize("rows", [1, 3, 8, 16])       # 1: cap 1, one-row body
+@pytest.mark.parametrize("rows,case", [
+    *((r, c) for r in (1, 3, 8, 16) for c in SLOT_CASES),  # 1: one-row body
+    *((r, c) for r in (13, 40, 128) for c in WIDE_CASES)])
 def test_slot_kernel_matches_pair_loop(rows, case):
     w13, w2, dense = _expert_stack()
     x = np.random.default_rng(rows).standard_normal(
@@ -409,18 +475,45 @@ def test_slot_call_matches_dense_at_deepseeks_block_counts(nb, fill):
         assert np.abs(got[a, :live] - want).max() < 1e-5
 
 
-@pytest.mark.parametrize("rows", [40, 13])   # 13: padded to the MXU's eights
-def test_every_expert_kernel_matches_pair_loop(rows):
+@pytest.mark.parametrize("rows", [40, 13])   # a wide dispatch, a narrow one
+def test_fast_prefill_mode_reaches_the_tile_at_every_width(rows):
+    """``bf16`` (fast-prefill's ``matmul_mode``) multiplies the tile in
+    bfloat16: off the float32 result by bfloat16's rounding and no more;
+    without it the same call is the float32 one."""
     w13, w2, dense = _expert_stack()
     x = np.random.default_rng(rows).standard_normal(
         (rows, 256)).astype(np.float32)
     topw, topi = _routing("random", rows, seed=2)
-    got, counts = pallas_moe._experts_mxu(
-        jnp.asarray([0], jnp.int32), w13, w2, jnp.asarray(x),
-        jnp.asarray(topw), jnp.asarray(topi), 8, True, False)
     want = _pair_loop(dense, 0, x.astype(np.float64), topw, topi)
-    assert np.abs(np.asarray(got) - want).max() < 1e-5
-    assert int(np.asarray(counts).sum()) == rows * 2
+    err = {}
+    for bf16 in (False, True):
+        got, counts = pallas_moe._experts_slots(
+            jnp.asarray([0], jnp.int32), w13, w2, jnp.asarray(x),
+            jnp.asarray(topw), jnp.asarray(topi), 8, True, bf16)
+        err[bf16] = np.abs(np.asarray(got) - want).max()
+        assert int(np.asarray(counts).sum()) == rows * 2
+    assert err[False] < 1e-5 < 1e-3 < err[True] < 5e-2
+
+
+def test_a_wide_dispatch_is_the_sum_of_its_rows(monkeypatch):
+    """``moe_ffn`` over a 128-row chunk against 128 one-row dispatches
+    (the T == 1 grid: one row a slot, the exact body)."""
+    from distributed_llama_tpu.models.llama import (layer_view,
+                                                    split_layer_weights)
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    params = params_to_device(synth_params(SPEC, q40=True, seed=11),
+                              spec=SPEC)
+    stacked, scanned = split_layer_weights(params)
+    lw = layer_view(stacked, {k: v[2] for k, v in scanned.items()}, 2)
+    h = jnp.asarray(np.random.default_rng(7).standard_normal(
+        (128, SPEC.dim)).astype(np.float32))
+    wide, counts = jax.jit(lambda h: pallas_moe.moe_ffn(SPEC, lw, h))(h)
+    one = jax.jit(lambda r: pallas_moe.moe_ffn(SPEC, lw, r))
+    rows = [one(h[t:t + 1]) for t in range(128)]
+    assert np.abs(np.asarray(wide) - np.concatenate(
+        [np.asarray(y) for y, _ in rows])).max() < 1e-5
+    assert (np.asarray(counts) == sum(np.asarray(c) for _, c in rows)).all()
 
 
 @pytest.mark.parametrize("rows,k,n_experts,cap", [
@@ -452,27 +545,48 @@ def test_build_slots_places_every_pair_once(rows, k, n_experts, cap):
     assert len(places) == rows * k                    # no two pairs collide
 
 
-@pytest.mark.parametrize("cap", [4, 8])
-def test_worst_case_routing_fits_the_static_slot_bound(cap):
-    """Every row to the same k experts: the most slots one expert takes."""
-    topi = np.tile(np.arange(8, dtype=np.int32), (16, 1))
-    _, n_slots, fill, *_ = pallas_moe.build_slots(jnp.asarray(topi), 64, cap)
-    assert int(n_slots) == 8 * (16 // cap) <= pallas_moe.max_slots(
-        16, 8, 64, cap)
+@pytest.mark.parametrize("rows,n_experts,cap", [
+    (16, 64, 4), (16, 64, 8),
+    # a 128-row chunk: OLMoE's 64 experts, DeepSeek-V3's 32 held
+    *((128, e, c) for e in (64, 32) for c in (8, 16, 32))])
+def test_worst_case_routing_fits_the_static_slot_bound(rows, n_experts, cap):
+    """Every row to the same k experts (the most slots one expert takes),
+    and every expert one row past a whole number of slots (the most
+    part-filled slots): both inside ``max_slots``."""
+    bound = pallas_moe.max_slots(rows, 8, n_experts, cap)
+    topi = np.tile(np.arange(8, dtype=np.int32), (rows, 1))
+    _, n_slots, fill, *_ = pallas_moe.build_slots(
+        jnp.asarray(topi), n_experts, cap)
+    assert int(n_slots) == 8 * (rows // cap) <= bound
     assert (np.asarray(fill)[:int(n_slots)] == cap).all()
+    # spread: row t takes experts 8 t .. 8 t + 7 (mod E)
+    topi = (8 * np.arange(rows)[:, None] + np.arange(8)) % n_experts
+    _, n_slots, *_ = pallas_moe.build_slots(
+        jnp.asarray(topi, jnp.int32), n_experts, cap)
+    per = rows * 8 // n_experts
+    assert int(n_slots) == n_experts * -(-per // cap) <= bound
 
 
-@pytest.mark.parametrize("rows,slots", [(1, 1), (16, 8), (32, 8), (40, 0)])
+def test_slot_cap_of_a_narrow_dispatch_is_pinned():
+    """T == 1 and T <= 32 lower to the programs they always did: one row,
+    one sublane tile, whatever k and E; wider, a multiple of 8."""
+    for k, e in ((8, 64), (8, 32), (2, 8)):
+        assert pallas_moe.slot_cap(1, k, e) == 1
+        assert {pallas_moe.slot_cap(t, k, e) for t in range(2, 33)} == {8}
+        for t in (33, 40, 128, 256):
+            cap = pallas_moe.slot_cap(t, k, e)
+            assert cap % 8 == 0 and 8 < cap <= -(-t // 8) * 8
+
+
+@pytest.mark.parametrize("rows,slots", [(1, 1), (16, 8), (32, 8),
+                                        (40, 24), (128, 32)])
 def test_slot_census_counts_what_build_slots_builds(rows, slots):
     """The host's arithmetic for the counters against the device's slots."""
-    assert pallas_moe.slot_cap(rows) == slots
+    assert pallas_moe.slot_cap(rows, 4, 16) == slots
     rng = np.random.default_rng(rows)
     topi = np.stack([rng.choice(16, 4, replace=False) for _ in range(rows)])
     counts = np.bincount(topi.ravel(), minlength=16)
-    live, single = pallas_moe.slot_census(counts, rows)
-    if not slots:                           # too wide: every expert runs
-        assert (live, single) == (0, 0)
-        return
+    live, single = pallas_moe.slot_census(counts, slots)
     _, n_slots, fill, *_ = pallas_moe.build_slots(
         jnp.asarray(topi, jnp.int32), 16, slots)
     assert live == int(n_slots) == sum(-(-c // slots) for c in counts)
