@@ -78,6 +78,12 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
         from ..ops.retention import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)
+    if spec.hybrid:
+        _refuse_sharded_hybrid(n_slices)
+        # each layer its kind's tensors, and the tied classifier's copy
+        return spec.vocab_size * spec.dim + sum(
+            e[2][0] * e[2][1] for _, _, entries in spec.layer_plans()
+            for e in entries if e[0] == "mm")
     per_layer = sum(c * d * n for (d, n), c in spec.matmul_shape_counts())
     if spec.latent:
         # two kinds of layer, the experts HELD (not the router's width),
@@ -91,6 +97,13 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
                 + spec.vocab_size * spec.dim)
     total = spec.n_layers * per_layer + spec.vocab_size * spec.dim
     return total // n_slices
+
+
+def _refuse_sharded_hybrid(n_slices: int, n_sp: int = 1) -> None:
+    if n_slices > 1 or n_sp > 1:
+        from ..ops.mamba import TP_REFUSAL
+
+        raise ValueError(TP_REFUSAL)
 
 
 def latent_absorbed_bytes(spec: TransformerSpec) -> int:
@@ -120,6 +133,12 @@ def replicated_device_bytes(spec: TransformerSpec) -> int:
     """Bytes every chip holds whole regardless of tp: the f32 embedding
     table and the rms norm vectors (2 per layer + final)."""
     embedding = spec.vocab_size * spec.dim * 4
+    if spec.hybrid:     # every float32 leaf of every layer, the final norm
+        import math
+
+        return embedding + 4 * (2 * spec.dim + sum(
+            math.prod(e[2]) for _, _, entries in spec.layer_plans()
+            for e in entries if e[0] == "f32"))
     norms = (spec.n_layers * sum(n for _, n in spec.layer_norm_shapes())
              + spec.dim) * 4
     expert_layers = spec.n_expert_layers if spec.latent else spec.n_layers
@@ -136,8 +155,17 @@ def state_slot_bytes(spec: TransformerSpec) -> int:
     context). What ``kv_position_bytes`` x positions is to a softmax spec."""
     from ..ops.retention import state_bytes
 
+    if spec.hybrid:
+        # a hybrid spec's slot: each Mamba layer's conv inputs and state,
+        # each window layer's ring of K and V, float32 (models/sambay.py);
+        # its full layer's K / V are pages (``kv_position_bytes``)
+        hy = spec.hybrid
+        return 4 * (hy.count("mamba") * hy.d_inner * (
+            hy.d_state + hy.d_conv - 1)
+            + hy.count("swa") * hy.window * 2 * spec.kv_dim)
     if not spec.retention:
-        raise ValueError("state_slot_bytes prices a retention spec's state")
+        raise ValueError("state_slot_bytes prices a retention or a hybrid "
+                         "spec's slot")
     return spec.n_layers * state_bytes(spec.n_kv_heads, spec.head_size)
 
 
@@ -156,6 +184,10 @@ def kv_cache_device_bytes(spec: TransformerSpec, n_slices: int,
     if spec.latent:
         return batch * spec.seq_len * kv_position_bytes(spec, n_slices,
                                                         cache_itemsize)
+    if spec.hybrid:     # ``batch`` slots, and ONE layer's contiguous K / V
+        _refuse_sharded_hybrid(n_slices, n_sp)
+        return batch * (state_slot_bytes(spec) + spec.seq_len
+                        * kv_position_bytes(spec, 1, cache_itemsize))
     return (2 * spec.n_layers * batch * (spec.seq_len // n_sp)
             * (spec.n_kv_heads // n_slices) * spec.head_size
             * cache_itemsize)
@@ -195,6 +227,12 @@ def kv_position_bytes(spec: TransformerSpec, n_slices: int,
                              "one chip (runtime/continuous.latent_refusals)")
         return (spec.n_layers * -(-spec.latent.width // 128) * 128
                 * cache_itemsize)
+    if spec.hybrid:     # the ONE full layer's K and V, float32, one chip
+        _refuse_sharded_hybrid(n_slices)
+        if kv_quant != "f32":
+            raise ValueError("a hybrid spec's pages are float32 "
+                             "(runtime/continuous.cache_refusals)")
+        return 2 * spec.kv_dim * cache_itemsize
     kv_dim = (spec.n_kv_heads // n_slices) * spec.head_size
     if kv_quant == "q8":
         per = kv_dim + 2 * (kv_dim // QK)   # int8 codes + f16 deltas
@@ -597,11 +635,20 @@ def device_footprint(spec: TransformerSpec, n_slices: int, scheme: str,
             "a retention spec's memory is `batch` states of fixed size: "
             "pages, q8 pages, the verify window, the mixed budget and the "
             "tier staging buffer do not apply (the engine refuses them)")
+    if spec.hybrid and (kv_quant != "f32" or spec_k or mixed_budget
+                        or tier_staging_pages):
+        raise ValueError(
+            "a hybrid spec's memory is `batch` slots of fixed size and "
+            "float32 pages of one layer: q8 pages, the verify window, the "
+            "mixed budget and the tier staging buffer do not apply (the "
+            "engine refuses them)")
     if kv_page_size > 0:
         pages = (kv_pages if kv_pages is not None
                  else default_kv_pages(spec, batch, kv_page_size))
         kv_bytes = kv_page_pool_bytes(spec, n_slices, pages, kv_page_size,
                                       kv_quant=kv_quant)
+        if spec.hybrid:     # the slots beside the pool
+            kv_bytes += batch * state_slot_bytes(spec)
     else:
         kv_bytes = kv_cache_device_bytes(spec, n_slices, batch=batch)
     return MemoryReport(

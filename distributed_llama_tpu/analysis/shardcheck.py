@@ -576,6 +576,11 @@ def check_config(entry: MatrixEntry, device: str = "v5e",
         from ..ops.retention import TP_REFUSAL
 
         raise ValueError(f"shardcheck {config}: {TP_REFUSAL}")
+    if spec.hybrid:
+        # and a hybrid spec: slots of fixed size and one layer's pages
+        from ..ops.mamba import TP_REFUSAL
+
+        raise ValueError(f"shardcheck {config}: {TP_REFUSAL}")
     findings = check_uniform_shards(spec, entry.tp, entry.scheme, config)
     act_bytes = None
     if not findings and kv_quant == "q8":

@@ -696,6 +696,14 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     # a retention spec's recurrent state
     _m("dllama_state_bytes"),
     _m("dllama_retention_min_normaliser"),
+    # a hybrid spec's window rings, shared K / V and cross-decoder skip
+    _m("dllama_window_bytes"),
+    _m("dllama_shared_kv_pages_in_use"),
+    _m("dllama_shared_kv_positions_total"),
+    _m("dllama_window_kv_positions_total"),
+    _m("dllama_prompt_positions_total"),
+    _m("dllama_xdec_positions_total"),
+    _m("dllama_ssm_min_decay"),
     # journal / recovery / lifecycle
     _m("dllama_journal_records_total"),
     _m("dllama_recoveries_total"),

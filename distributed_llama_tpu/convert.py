@@ -47,6 +47,14 @@ Extensions beyond the reference:
   checkpoint (``quantization_config`` in its config) is NOT read: cast it
   to bfloat16 with the publisher's script first. The multi-token-prediction
   module (the checkpoint's last layer) is left out.
+* ``model_type: phi4flash`` (Phi-4-mini-flash-reasoning, SambaY): its
+  ``config.json`` gives the spec (``hybrid_spec``: the per-layer list of
+  kinds from ``mb_per_layer`` and the depth, header extension 5; the
+  state-space sizes, which the config has no key for, are the family's
+  defaults), and the tensors are REFUSED: which checkpoint tensors are the
+  lambdas and the sub-norm, and which heads the checkpoint pairs, was not
+  checked against the checkpoint (no network here), and a converter that
+  guessed would write a file that runs and is wrong.
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -256,6 +264,8 @@ class HFCheckpoint:
         moe = {}
         if getattr(c, "model_type", "") == "deepseek_v3":
             return latent_spec(c, target, seq_len)
+        if getattr(c, "model_type", "") == "phi4flash":
+            return hybrid_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "olmoe":
             if getattr(c, "norm_topk_prob", False):
                 raise ValueError("olmoe with norm_topk_prob: the program "
@@ -284,6 +294,13 @@ class HFCheckpoint:
     def tensor_by_name(self, name: str, layer: int | None,
                        spec: TransformerSpec,
                        expert: int | None = None) -> np.ndarray:
+        if spec.hybrid:
+            raise ValueError(
+                "phi4flash: the checkpoint's tensor names (lambdas, "
+                "sub-norm) and its pairing of heads were not checked "
+                "against the checkpoint, so no tensor is converted (the "
+                "module docstring says why); models/synth.py writes a "
+                "seeded file of this spec")
         if spec.latent:     # rows as they are: see the module docstring
             key = LATENT_TENSORS.get(name) or {
                 "tok_embedding": "model.embed_tokens.weight",
@@ -356,6 +373,30 @@ def convert_meta(model_path: str, target: str, out: str | None = None,
     return out
 
 
+def hybrid_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
+    """The spec of a ``phi4flash`` config: Mamba at every ``mb_per_layer``-th
+    layer up to the middle, window attention between, the full layer after
+    the middle one, then GMUs and cross-attention (``sambay_kinds``). The
+    config has no key for the state-space sizes: d_state 16, d_conv 4,
+    expand 2 and dt_rank = ceil(hidden / 16), the family's defaults."""
+    from .models.spec import HybridLayers, sambay_kinds
+
+    if getattr(c, "mb_per_layer", 2) != 2 or c.num_hidden_layers % 2 \
+            or not getattr(c, "tie_word_embeddings", True):
+        raise ValueError("phi4flash: mb_per_layer 2, an even depth and a "
+                         "tied embedding are what the list of kinds and "
+                         "the file lay out")
+    return TransformerSpec(
+        dim=c.hidden_size, hidden_dim=c.intermediate_size,
+        n_layers=c.num_hidden_layers, n_heads=c.num_attention_heads,
+        n_kv_heads=c.num_key_value_heads, vocab_size=c.vocab_size,
+        seq_len=seq_len, weights_float_type=target,
+        norm_eps=float(getattr(c, "layer_norm_eps", 1e-5)),
+        hybrid=HybridLayers(sambay_kinds(c.num_hidden_layers),
+                            int(c.sliding_window), 2 * c.hidden_size, 16, 4,
+                            -(-c.hidden_size // 16)))
+
+
 def convert_hf(model_path: str, target: str, out: str | None = None,
                seq_len: int = 2048, ckpt=None) -> str:
     ckpt = ckpt or HFCheckpoint(model_path)
@@ -368,7 +409,7 @@ def convert_hf(model_path: str, target: str, out: str | None = None,
                       ckpt.tensor_by_name("tok_embedding", None, spec))
         # a latent spec's layers, of two kinds, in the file's own order
         for i, (_, _, entries) in enumerate(
-                spec.layer_plans() if spec.latent else ()):
+                spec.layer_plans() if spec.planned else ()):
             for kind, name_, _, *e in entries:
                 arr = ckpt.tensor_by_name(name_, i, spec,
                                           e[0] if e else None)
@@ -378,7 +419,7 @@ def convert_hf(model_path: str, target: str, out: str | None = None,
                 else:
                     _write_matmul(f, spec, arr)
             print(f"🔶 wrote layer {i + 1}/{spec.n_layers}")
-        for i in range(0 if spec.latent else spec.n_layers):
+        for i in range(0 if spec.planned else spec.n_layers):
             # file order (models/spec.py): norms, attention, router, experts
             names = ([n for n, _ in spec.layer_norm_shapes()]
                      + [n for n, _ in spec.layer_matmul_shapes()]

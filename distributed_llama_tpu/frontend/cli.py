@@ -45,14 +45,16 @@ _MODEL_HELP = ("path to the reference-format .bin model. Single-chip Q40 "
                "mmap it instead of re-tiling for minutes; set "
                "DLLAMA_TILED_CACHE=0 to disable the sidecar read AND write")
 
-def _refuses_retention(args, tp: int) -> bool:
-    """Print the lines ``runtime/continuous.retention_refusals`` has for the
-    flags of this run (``inference`` or ``serve``; a flag the mode does not
-    have reads as off); True if there was one."""
-    from ..runtime.continuous import retention_refusals
+def _refuses(spec, args, tp: int, serve: bool) -> bool:
+    """Print the lines ``runtime/continuous.cache_refusals`` has for what a
+    sequence of ``spec`` caches and the flags of this run (``inference`` or
+    ``serve``; a flag the mode does not have reads as off); True if there
+    was one."""
+    from ..runtime.continuous import cache_refusals, sequence_caches
 
     get = lambda name, off=0: getattr(args, name, off) or off  # noqa: E731
-    refused = retention_refusals(
+    refused = cache_refusals(
+        sequence_caches(spec),
         tp=max(tp, get("sp", 1)), page_size=get("kv_page_size"),
         kv_pages=get("kv_pages"), spec_k=get("spec_k"),
         dispatch_tokens=get("dispatch_tokens"),
@@ -60,29 +62,24 @@ def _refuses_retention(args, tp: int) -> bool:
         kv_host_pages=get("kv_host_pages"),
         kv_disk_dir=get("kv_disk_dir", None), journal=bool(get("journal")),
         disagg=bool(get("disagg_role")), block_steps=get("block_steps", 1),
-        kv_cache_dtype=get("kv_cache_dtype", "f32"))
-    for line in refused:
-        print(f"refused: {line}", file=sys.stderr)
-    return bool(refused)
-
-
-def _refuses_latent(args, tp: int, serve: bool) -> bool:
-    """``_refuses_retention`` for a latent-attention spec
-    (``runtime/continuous.latent_refusals``)."""
-    from ..runtime.continuous import latent_refusals
-
-    get = lambda name, off=0: getattr(args, name, off) or off  # noqa: E731
-    refused = latent_refusals(
-        tp=max(tp, get("sp", 1)), page_size=get("kv_page_size"),
-        spec_k=get("spec_k"), dispatch_tokens=get("dispatch_tokens"),
-        kv_quant=get("kv_quant", "f32"),
-        kv_host_pages=get("kv_host_pages"),
-        kv_disk_dir=get("kv_disk_dir", None),
-        disagg=bool(get("disagg_role")), block_steps=get("block_steps", 1),
         kv_cache_dtype=get("kv_cache_dtype", "f32"), serve=serve)
     for line in refused:
         print(f"refused: {line}", file=sys.stderr)
     return bool(refused)
+
+
+def _hybrid_line(spec, slots: int) -> str:
+    """What a hybrid spec keeps a sequence: a startup line."""
+    hy = spec.hybrid
+    state = hy.count("mamba") * hy.d_inner * (hy.d_state + hy.d_conv - 1) * 4
+    ring = hy.count("swa") * hy.window * 2 * spec.kv_dim * 4
+    return (f"💡 layers: {hy.count('mamba')} mamba, {hy.count('swa')} window "
+            f"({hy.window}), 1 full, {hy.count('gmu')} gmu, "
+            f"{hy.count('xattn')} cross (differential attention); a "
+            f"sequence keeps {state / 2**20:.1f} MiB of state and "
+            f"{ring / 2**20:.1f} MiB of window ring ({slots} "
+            f"slot{'s' if slots != 1 else ''}, fixed) and ONE layer's K / V: "
+            f"{2 * spec.kv_dim * 4} B a position")
 
 
 def _latent_line(spec) -> str:
@@ -594,18 +591,16 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         print(f"{MOE_TP_REFUSAL} (this run: tp={tp} sp={args.sp}; pass "
               f"--tp 1)", file=sys.stderr)
         return 2
-    if spec.retention:
-        if _refuses_retention(args, tp):
-            return 2
-        if not quiet:
-            print(_retention_line(spec, rows))   # one chip: rows is set
-    if spec.latent:
-        # batch prompts without --continuous run the lockstep batch, which
-        # has no latent cache: only one sequence or the paged pool
-        if _refuses_latent(args, tp, serve=prompts is not None):
-            return 2
-        if not quiet:
-            print(_latent_line(spec))
+    # batch prompts without --continuous run the lockstep batch, which has
+    # no latent cache: only one sequence or the paged pool
+    if _refuses(spec, args, tp, serve=prompts is not None):
+        return 2
+    if spec.retention and not quiet:
+        print(_retention_line(spec, rows))   # one chip: rows is set
+    if spec.latent and not quiet:
+        print(_latent_line(spec))
+    if spec.hybrid and not quiet:
+        print(_hybrid_line(spec, rows))
     mesh = (make_mesh(sp=args.sp, tp=tp)
             if tp > 1 or args.sp > 1 else None)
     assumed = getattr(args, "_slice_tp_ranks", None)
@@ -1109,14 +1104,14 @@ def cmd_serve(argv: list[str]) -> int:
         print(f"{MOE_TP_REFUSAL} (this run: --tp {args.tp})",
               file=sys.stderr)
         return 2
+    if _refuses(spec, args, args.tp or 1, serve=True):
+        return 2
     if spec.retention:
-        if _refuses_retention(args, args.tp or 1):
-            return 2
         print(_retention_line(spec, args.slots))
     if spec.latent:
-        if _refuses_latent(args, args.tp or 1, serve=True):
-            return 2
         print(_latent_line(spec))
+    if spec.hybrid:
+        print(_hybrid_line(spec, args.slots))
     mesh = make_mesh(tp=args.tp) if args.tp and args.tp > 1 else None
     seed = args.seed if args.seed is not None else int(time.time())
     if journal is not None:

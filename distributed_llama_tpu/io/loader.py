@@ -252,9 +252,11 @@ def load_model(path: str, spec: TransformerSpec | None = None,
 
     params: dict = {}
     params["tok_embedding"] = w.f32((spec.vocab_size, spec.dim))
-    if spec.latent:
+    if spec.planned:
         _load_planned_layers(spec, w, params)
         params["rms_final"] = w.f32((spec.dim,))
+        if spec.hybrid:
+            params["rms_final_b"] = w.f32((spec.dim,))
         params["wcls"] = w.matmul(spec, (spec.vocab_size, spec.dim))
         if w.off != expected:
             raise ValueError(f"missed {expected - w.off} bytes")
@@ -316,13 +318,14 @@ def load_model(path: str, spec: TransformerSpec | None = None,
 
 def stack_of(params: dict, stack: str) -> dict:
     """The dict a ``TransformerSpec.layer_plans`` stack's tensors live in:
-    the tree itself, or ``params["dense"]`` (made on first use)."""
+    the tree itself, or ``params[stack]`` ("dense", or a hybrid spec's
+    layer kind; made on first use)."""
     return params.setdefault(stack, {}) if stack else params
 
 
 def _load_planned_layers(spec: TransformerSpec, w: _Walker,
                          params: dict) -> None:
-    """The layers of a spec with two kinds of layer, in ``layer_plans``
+    """The layers of a spec with several kinds of layer, in ``layer_plans``
     order, each tensor streamed into its preallocated stack."""
     q40 = spec.weights_float_type == FloatType.Q40
     dtype = np.float16 if spec.weights_float_type == FloatType.F16 \
@@ -381,13 +384,13 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
     shapes = spec.layer_matmul_shapes()
     experts = spec.expert_matmul_shapes()
     for layer, (_, _, entries) in enumerate(
-            spec.layer_plans() if spec.latent else ()):
+            spec.layer_plans() if spec.planned else ()):
         for kind, name, shape, *_ in entries:
             if kind == "f32":
                 add(name, layer, 4 * int(np.prod(shape)))
             else:
                 add(name, layer, spec.matmul_bytes(shape), rows=shape[0])
-    for layer in range(0 if spec.latent else spec.n_layers):
+    for layer in range(0 if spec.planned else spec.n_layers):
         for name, n in spec.layer_norm_shapes():
             add(name, layer, n * 4)
         for name, shape in shapes:
@@ -401,6 +404,8 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
             for name, shape in experts:
                 add(name, layer, spec.matmul_bytes(shape), rows=shape[0])
     add("rms_final", None, spec.dim * 4)
+    if spec.hybrid:
+        add("rms_final_b", None, spec.dim * 4)
     add("_rope_gap", None, spec.rope_gap_bytes)
     add("wcls", None, spec.matmul_bytes((spec.vocab_size, spec.dim)),
         rows=spec.vocab_size)
@@ -435,7 +440,7 @@ def write_model(path: str, spec: TransformerSpec, tensors: dict) -> None:
         f.write(spec.header())
         f.write(np.ascontiguousarray(
             tensors["tok_embedding"], dtype=np.float32).tobytes())
-        for stack, at, entries in (spec.layer_plans() if spec.latent
+        for stack, at, entries in (spec.layer_plans() if spec.planned
                                    else ()):
             src = tensors[stack] if stack else tensors
             for kind, name, _, *e in entries:
@@ -445,7 +450,7 @@ def write_model(path: str, spec: TransformerSpec, tensors: dict) -> None:
                         val, dtype=np.float32).tobytes())
                 else:
                     _write_matmul(f, spec, val)
-        for layer in range(0 if spec.latent else spec.n_layers):
+        for layer in range(0 if spec.planned else spec.n_layers):
             for name, _ in spec.layer_norm_shapes():
                 f.write(np.ascontiguousarray(
                     tensors[name][layer], dtype=np.float32).tobytes())
@@ -462,6 +467,9 @@ def write_model(path: str, spec: TransformerSpec, tensors: dict) -> None:
                     _write_matmul(f, spec, tensors[name][layer][e])
         f.write(np.ascontiguousarray(
             tensors["rms_final"], dtype=np.float32).tobytes())
+        if spec.hybrid:
+            f.write(np.ascontiguousarray(
+                tensors["rms_final_b"], dtype=np.float32).tobytes())
         f.write(b"\x00" * spec.rope_gap_bytes)
         _write_matmul(f, spec, tensors["wcls"])
     # byte-exact invariant

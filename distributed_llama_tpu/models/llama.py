@@ -65,6 +65,10 @@ def init_state(spec: TransformerSpec, batch: int | None = None) -> StateCache:
 
 
 def init_cache(spec: TransformerSpec, dtype=jnp.float32):
+    if spec.hybrid:     # state, window rings and ONE layer's K / V
+        from .sambay import init_cache as init_hybrid
+
+        return init_hybrid(spec, dtype=dtype)
     if spec.retention:  # float32 whatever ``dtype``: nothing scales with S
         return init_state(spec)
     if spec.latent:     # one plane [c_kv | k_rope] in place of K and V
@@ -599,8 +603,13 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
     ``moe_counts`` (expert specs only; a Python-level switch, so a dense
     spec traces the program it always did) adds a third result: the (L, E)
     int32 count of rows routed to each expert in this dispatch. A
-    retention spec takes ``forward_retention`` (every position valid).
+    retention spec takes ``forward_retention`` (every position valid), a
+    hybrid spec ``models/sambay.forward_sambay``.
     """
+    if spec.hybrid:
+        from .sambay import forward_sambay
+
+        return forward_sambay(spec, params, cache, tokens, pos)
     if spec.retention:
         return forward_retention(spec, params, cache, tokens, pos)
     if spec.latent:
@@ -709,7 +718,7 @@ def batch_decode_attention(head_size: int, kv_mul: int, seq_len: int,
 
 
 def init_cache_paged(spec: TransformerSpec, n_pages: int, page_size: int,
-                     dtype=jnp.float32) -> KVCache:
+                     dtype=jnp.float32, slots: int = 0) -> KVCache:
     """Paged pool cache: (L, P, page_size, n_kv, hs) — physical page p of
     layer l is the (page_size, n_kv, hs) plane at [l, p]. ``n_pages`` is
     the TOTAL physical page count including the reserved scrap page 0
@@ -721,6 +730,10 @@ def init_cache_paged(spec: TransformerSpec, n_pages: int, page_size: int,
         from .latent import init_cache_paged as init_latent_paged
 
         return init_latent_paged(spec, n_pages, page_size, dtype)
+    if spec.hybrid:     # ``slots`` rows of state and ring beside the pool
+        from .sambay import init_cache_paged as init_hybrid_paged
+
+        return init_hybrid_paged(spec, slots, n_pages, page_size, dtype)
     if spec.seq_len % page_size:
         raise ValueError(f"page_size={page_size} must divide "
                          f"seq_len={spec.seq_len}")
@@ -1035,6 +1048,11 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
         return forward_batch_latent_paged(spec, page_size, params, cache,
                                           tokens, pos_vec, table,
                                           moe_counts=moe_counts)
+    if spec.hybrid:
+        from .sambay import forward_batch_sambay
+
+        return forward_batch_sambay(spec, params, cache, tokens, pos_vec,
+                                    table, page_size=page_size)
     B = tokens.shape[0]
     x = params["tok_embedding"][tokens].astype(jnp.float32)  # (B, dim)
     positions = pos_vec if jnp.ndim(pos_vec) == 1 else jnp.full((B,),
@@ -1430,6 +1448,10 @@ def init_cache_batch(spec: TransformerSpec, batch: int,
     spec's is ``init_state(spec, batch)``: nothing in it scales with S."""
     if spec.retention:
         return init_state(spec, batch)
+    if spec.hybrid:
+        from .sambay import init_cache as init_hybrid
+
+        return init_hybrid(spec, batch, dtype)
     shape = (spec.n_layers, batch, spec.seq_len, spec.n_kv_heads,
              spec.head_size)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
@@ -1462,6 +1484,10 @@ def forward_batch(spec: TransformerSpec, params: dict[str, Any],
     """
     if spec.retention:
         return forward_batch_retention(spec, params, cache, tokens, pos)
+    if spec.hybrid:
+        from .sambay import forward_batch_sambay
+
+        return forward_batch_sambay(spec, params, cache, tokens, pos)
     B = tokens.shape[0]
     x = params["tok_embedding"][tokens].astype(jnp.float32)  # (B, dim)
     # each row rotates at its own clock (identical under the shared one)
@@ -1592,7 +1618,7 @@ def params_to_device(params: dict[str, Any], dtype=None,
         params = prepare_latent_params(spec, params)
     params = fuse_q40_layer_matmuls(pack_q40_params(
         params, allow_nb_major=True, layout=layout))
-    if spec is not None and not spec.latent:
+    if spec is not None and not spec.planned:
         from ..ops.pallas_layer import prepare_mega_params
 
         params = prepare_mega_params(spec, params)

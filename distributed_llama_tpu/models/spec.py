@@ -82,6 +82,19 @@ In the param tree the expert layers' tensors are the top-level stacks (their
 leading axis counts expert layers only; ``moe_w*`` are (L_e, held, d, n),
 ``moe_bias`` (L_e, nExperts)) and the leading dense layers' are the same
 keys under ``params["dense"]``.
+
+Extension VERSION 5 (version 4's values, then eight ints {window, dInner,
+dState, dConv, dtRank, three reserved} and 128 bytes, one a layer: its index
+into ``LAYER_KINDS``, 255 past the last layer) is written only by a spec
+that sets ``hybrid`` (``HybridLayers``: a per-layer list of kinds in place
+of the one ``attnKind``), so files of versions 0, 2, 3 and 4 read and write
+byte for byte. Such a file has no rope gap (the model has no positional
+encoding), LayerNorm with gain AND bias where the others have RMSNorm, and
+``layer_plans`` says each kind's tensors in file order; in the param tree
+each kind is a stack of its own under ``params[kind]`` (its leading axis
+counts the layers of that kind), the final norm's gain is followed by its
+bias (``rms_final_b``), and the classifier is a copy of the embedding in
+the weights' float type (tied).
 """
 
 from __future__ import annotations
@@ -101,8 +114,17 @@ EXT3_VERSION = 3
 EXT3_STRUCT = struct.Struct("<14i2d")   # ... attnKind, theta, eps
 EXT4_VERSION = 4
 EXT4_STRUCT = struct.Struct("<14i2d16i7d")
-MAX_HEADER_BYTES = EXT4_STRUCT.size
+EXT5_VERSION = 5
+EXT5_STRUCT = struct.Struct("<14i2d16i7d8i128B")
+MAX_HEADER_BYTES = EXT5_STRUCT.size
 ATTN_KINDS = ("softmax", "retention")
+# what a layer of a ``HybridLayers`` spec mixes with, and what it caches for
+# one sequence: a recurrent state of fixed size, a ring of the last
+# ``window`` positions' K / V, every position's K / V (the ONE growing
+# cache, in pages under ``serve``), or nothing of its own
+LAYER_KINDS = ("mamba", "swa", "full", "gmu", "xattn")
+CACHE_OF_KIND = {"mamba": "state", "swa": "window", "full": "pages",
+                 "gmu": None, "xattn": None}
 ROUTER_SCORINGS = ("softmax", "sigmoid")
 
 
@@ -166,6 +188,55 @@ class RopeScaling:
 
 
 @dataclasses.dataclass(frozen=True)
+class HybridLayers:
+    """A per-layer list of kinds (SambaY's decoder-hybrid-decoder,
+    arXiv:2507.06607; models/sambay.py runs it, models/reference_sambay.py
+    states it). ``kinds[i]`` is layer i's mixer: "mamba" (selective state
+    space, Mamba-1), "swa" (differential attention over the last ``window``
+    positions, the current one included), "full" (differential attention,
+    causal over every position: its K / V are the model's only growing
+    cache), "gmu" (a gate on the memory ``m``: the scan output of the last
+    Mamba layer before the first GMU) and "xattn" (differential
+    cross-attention, own queries over the "full" layer's K / V). Every
+    layer after the "full" one is a "gmu" or an "xattn": they hold no state
+    of their own, so a prompt runs them at its last position alone."""
+    kinds: tuple
+    window: int
+    d_inner: int
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 0
+
+    def count(self, kind: str) -> int:
+        return sum(k == kind for k in self.kinds)
+
+    def layers_of(self, kind: str) -> tuple:
+        return tuple(i for i, k in enumerate(self.kinds) if k == kind)
+
+    @property
+    def full_layer(self) -> int:
+        return self.kinds.index("full")
+
+    @property
+    def memory_layer(self) -> int | None:
+        """The Mamba layer whose scan output the GMUs gate (None: no GMU)."""
+        if "gmu" not in self.kinds:
+            return None
+        return max(i for i in self.layers_of("mamba")
+                   if i < self.kinds.index("gmu"))
+
+
+def sambay_kinds(n_layers: int) -> tuple:
+    """The published pattern at ``n_layers`` (even, >= 8), L/2 = h: Mamba at
+    even i <= h, window attention at odd i < h, the full layer at h + 1,
+    then GMUs at even and cross-attention at odd i."""
+    h = n_layers // 2
+    return tuple("mamba" if i <= h and i % 2 == 0 else "swa" if i < h
+                 else "full" if i == h + 1 else "gmu" if i % 2 == 0
+                 else "xattn" for i in range(n_layers))
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerSpec:
     dim: int
     hidden_dim: int
@@ -194,8 +265,14 @@ class TransformerSpec:
     layout: ExpertLayout = ExpertLayout()
     router: Router = Router()
     rope_scaling: RopeScaling | None = None
+    # header version 5: a per-layer list of kinds (None: every layer is
+    # what ``attn_kind`` / ``latent`` / ``layout`` say, the list's trivial
+    # cases)
+    hybrid: HybridLayers | None = None
 
     def __post_init__(self):
+        if self.hybrid is not None:
+            self._check_hybrid()
         if self.attn_kind not in ATTN_KINDS:
             raise ValueError(f"attn_kind={self.attn_kind!r}: expected one "
                              f"of {ATTN_KINDS}")
@@ -240,15 +317,54 @@ class TransformerSpec:
                 f"{self.n_active_experts}: both 0 (dense FFN) or "
                 f"0 < active <= experts")
 
+    def _check_hybrid(self) -> None:
+        hy = self.hybrid
+        kinds = tuple(hy.kinds)
+        if (len(kinds) != self.n_layers or len(kinds) > 128
+                or any(k not in LAYER_KINDS for k in kinds)):
+            raise ValueError(f"hybrid.kinds: one of {LAYER_KINDS} for each "
+                             f"of n_layers={self.n_layers} (at most 128)")
+        if kinds.count("full") != 1 or any(
+                k not in ("gmu", "xattn")
+                for k in kinds[kinds.index("full") + 1:]) or any(
+                k in ("gmu", "xattn") for k in kinds[:kinds.index("full")]):
+            raise ValueError("a hybrid spec has ONE full-attention layer, "
+                             "state-bearing layers before it and only "
+                             "gmu / xattn layers after it")
+        if "gmu" in kinds and "mamba" not in kinds:
+            raise ValueError("a gmu layer gates a Mamba layer's memory")
+        if (self.retention or self.latent or self.n_experts or self.qk_norm
+                or self.n_heads % 2 or self.n_kv_heads % 2
+                or (self.n_heads // 2) % (self.n_kv_heads // 2)
+                or hy.window < 1 or min(hy.d_inner, hy.d_state, hy.dt_rank,
+                                        hy.d_conv - 1) < 1):
+            raise ValueError("a hybrid spec has a dense FFN, differential "
+                             "attention over PAIRS of heads (even head "
+                             "counts), and positive state-space sizes")
+
+    @property
+    def planned(self) -> bool:
+        """Whether ``layer_plans`` (and not the one-kind walk) says the
+        file's layers."""
+        return bool(self.latent or self.hybrid)
+
     @property
     def retention(self) -> bool:
         """Whether the layers keep a recurrent state in place of a KV cache."""
         return self.attn_kind == "retention"
 
     @property
+    def stateful(self) -> bool:
+        """Whether a sequence keeps something that a step rewrites and that
+        cannot be rewound (a recurrent state, a window ring)."""
+        return bool(self.retention or self.hybrid)
+
+    @property
     def header_version(self) -> int:
         """0 (the 28-byte header), 2, 3 or 4: the lowest that holds the
         spec."""
+        if self.hybrid:
+            return EXT5_VERSION
         if (self.latent or self.rope_scaling or self.layout != ExpertLayout()
                 or self.router != Router()):
             return EXT4_VERSION
@@ -266,7 +382,8 @@ class TransformerSpec:
     def header_bytes(self) -> int:
         return {0: HEADER_BYTES, EXT_VERSION: EXT_STRUCT.size,
                 EXT3_VERSION: EXT3_STRUCT.size,
-                EXT4_VERSION: EXT4_STRUCT.size}[self.header_version]
+                EXT4_VERSION: EXT4_STRUCT.size,
+                EXT5_VERSION: EXT5_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
@@ -314,7 +431,8 @@ class TransformerSpec:
             version, count = struct.unpack_from("<2i", raw, 4)
             layout = {(EXT_VERSION, 10): EXT_STRUCT,
                       (EXT3_VERSION, 13): EXT3_STRUCT,
-                      (EXT4_VERSION, 29): EXT4_STRUCT}.get((version, count))
+                      (EXT4_VERSION, 29): EXT4_STRUCT,
+                      (EXT5_VERSION, 165): EXT5_STRUCT}.get((version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
@@ -322,8 +440,11 @@ class TransformerSpec:
                 raise ValueError("extended header truncated")
             _, _, _, *ints = layout.unpack(raw[:layout.size])
             base, ext = ints[:7], ints[7:10]
-            if version == EXT4_VERSION:
-                more = _read_ext4(ints[13:])
+            if version == EXT5_VERSION:
+                more = _read_ext5(ints[36:], base[2])
+                ints = ints[:36]
+            if version >= EXT4_VERSION:
+                more = dict(_read_ext4(ints[13:]), **more)
             if version >= EXT3_VERSION:
                 kind, theta, eps = ints[10:13]
                 if not 0 <= kind < len(ATTN_KINDS):
@@ -359,8 +480,7 @@ class TransformerSpec:
         la = self.latent or LatentAttn(0, 0, 0, 0, 0)
         lay, ro = self.layout, self.router
         rs = self.rope_scaling or RopeScaling(0.0, 0)
-        return EXT4_STRUCT.pack(
-            EXT_MAGIC, EXT4_VERSION, 29, *v3,
+        v4 = (
             la.q_rank, la.kv_rank, la.nope_dim, la.rope_dim, la.v_dim,
             lay.dense_layers, lay.dense_hidden, lay.shared, lay.held,
             lay.offset, ROUTER_SCORINGS.index(ro.scoring), ro.groups,
@@ -368,6 +488,14 @@ class TransformerSpec:
             int(self.rope_scaling is not None),
             ro.scale, rs.factor, float(rs.original_positions), rs.beta_fast,
             rs.beta_slow, rs.mscale, rs.mscale_all_dim)
+        if self.header_version == EXT4_VERSION:
+            return EXT4_STRUCT.pack(EXT_MAGIC, EXT4_VERSION, 29, *v3, *v4)
+        hy = self.hybrid
+        kinds = [LAYER_KINDS.index(k) for k in hy.kinds]
+        return EXT5_STRUCT.pack(
+            EXT_MAGIC, EXT5_VERSION, 165, *v3, *v4, hy.window, hy.d_inner,
+            hy.d_state, hy.d_conv, hy.dt_rank, 0, 0, 0,
+            *kinds, *([255] * (128 - len(kinds))))
 
     # -- per-tensor shapes (d, n) in file order ----------------------------
 
@@ -376,6 +504,12 @@ class TransformerSpec:
         order: an expert spec has the four attention tensors here and its
         FFN under ``expert_matmul_shapes``."""
         d, h, kv = self.dim, self.hidden_dim, self.kv_dim
+        if self.hybrid:     # every distinct matmul tensor of any kind, once
+            seen = {}
+            for _, _, entries in self.layer_plans():
+                seen.update({(e[1], e[2]): None for e in entries
+                             if e[0] == "mm"})
+            return list(seen)
         attn = [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
                 ("wo", (d, d))]
         if self.latent:
@@ -444,6 +578,8 @@ class TransformerSpec:
         stack, and ``entries`` its tensors in file order: ("f32", name,
         shape) or ("mm", name, (d, n)[, expert]). Per-layer kinds of any
         pattern can take this list's place."""
+        if self.hybrid:
+            return self._hybrid_plans()
         norms = [("f32", n, (w,)) for n, w in self.layer_norm_shapes()]
         dense = norms + [("mm", n, s)
                          for n, s in self.dense_layer_matmul_shapes()]
@@ -460,11 +596,53 @@ class TransformerSpec:
         return ([("dense", i, dense) for i in range(k)]
                 + [("", i, expert) for i in range(self.n_layers - k)])
 
+    def _hybrid_plans(self):
+        """``layer_plans`` of a hybrid spec: ``stack`` is the layer's kind,
+        ``index`` its place among the layers of that kind. Every layer:
+        LayerNorm gains and biases, the mixer's tensors, then the FFN
+        (``w13`` = fc1, [gate | up] on its output rows; ``w2`` = fc2). Small
+        leaves are float32 whatever the weights' type: the biases, a Mamba
+        layer's conv taps (d_conv, d_inner), ``x_proj`` (dt_rank + 2
+        d_state, d_inner), ``dt_proj`` (d_inner, dt_rank), ``a_log``
+        (d_state, d_inner: the state index before the channel, as the state
+        is held) and ``d_skip``; an attention layer's four lambda vectors
+        ``lam`` (4, head) [lq1, lk1, lq2, lk2] and sub-norm gain ``subln``
+        (2 head)."""
+        hy, d, h = self.hybrid, self.dim, self.hidden_dim
+        kv, hs = self.kv_dim, self.head_size
+        di, ds, dr = hy.d_inner, hy.d_state, hy.dt_rank
+        f, m = (lambda n, *s: ("f32", n, s)), (lambda n, *s: ("mm", n, s))
+        norms = [f("ln1_g", d), f("ln1_b", d), f("ln2_g", d), f("ln2_b", d)]
+        ffn = [m("w13", 2 * h, d), m("w2", d, h)]
+        diff = [f("lam", 4, hs), f("subln", 2 * hs)]
+        out = [m("wo", d, d), f("bo", d)]
+        mixer = {
+            "mamba": [m("in_proj", 2 * di, d), f("conv_w", hy.d_conv, di),
+                      f("conv_b", di), f("x_proj", dr + 2 * ds, di),
+                      f("dt_proj", di, dr), f("dt_b", di),
+                      f("a_log", ds, di), f("d_skip", di),
+                      m("out_proj", d, di)],
+            "swa": [m("wqkv", d + 2 * kv, d), f("bqkv", d + 2 * kv)] + diff
+            + out,
+            "gmu": [m("in_proj", di, d), m("out_proj", d, di)],
+            "xattn": [m("wq", d, d), f("bq", d)] + diff + out,
+        }
+        mixer["full"] = mixer["swa"]
+        seen: dict = {}
+        plans = []
+        for kind in hy.kinds:
+            plans.append((kind, seen.get(kind, 0),
+                          norms + mixer[kind] + ffn))
+            seen[kind] = seen.get(kind, 0) + 1
+        return plans
+
     def stack_leaves(self):
         """(stack, name, kind, stacked shape) of every leaf of the two layer
         stacks ``layer_plans`` walks, once each: the leading axis counts the
         stack's layers, an expert tensor's second axis the experts held."""
         depth = {"dense": self.n_dense_layers, "": self.n_expert_layers}
+        if self.hybrid:
+            depth = {k: self.hybrid.count(k) for k in LAYER_KINDS}
         seen, out = set(), []
         for stack, _, entries in self.layer_plans():
             for kind, name, shape, *e in entries:
@@ -483,14 +661,14 @@ class TransformerSpec:
     def rope_gap_bytes(self) -> int:
         """Legacy freq_cis_real+imag region (transformer.cpp:338-339); a
         version-4 file has none."""
-        if self.header_version == EXT4_VERSION:
+        if self.header_version >= EXT4_VERSION:
             return 0
         return 2 * (self.seq_len * self.head_size // 2) * 4
 
     def block_bytes(self) -> int:
         """One layer's bytes in the file (an expert layer's, where a
         version-4 spec has two kinds: ``file_size`` walks both)."""
-        if self.header_version == EXT4_VERSION:
+        if self.header_version >= EXT4_VERSION:
             return self._plan_bytes(self.layer_plans()[-1][2])
         b = sum(n * 4 for _, n in self.layer_norm_shapes())  # always F32
         b += self.n_experts * self.dim * 4                   # router, F32
@@ -510,14 +688,27 @@ class TransformerSpec:
         """Byte-exact total, mirroring the check at transformer.cpp:344-348."""
         b = self.header_bytes
         b += self.vocab_size * self.dim * 4          # tok_embeddings, F32
-        if self.header_version == EXT4_VERSION:
+        if self.header_version >= EXT4_VERSION:
             b += sum(self._plan_bytes(e) for _, _, e in self.layer_plans())
         else:
             b += self.n_layers * self.block_bytes()
         b += self.dim * 4                            # rmsFinal, F32
+        if self.hybrid:
+            b += self.dim * 4                        # its bias (LayerNorm)
         b += self.rope_gap_bytes
         b += self.matmul_bytes((self.vocab_size, self.dim))  # wcls
         return b
+
+
+def _read_ext5(vals, n_layers: int) -> dict:
+    """``hybrid`` from a version-5 header's eight ints and 128 bytes."""
+    window, d_inner, d_state, d_conv, dt_rank, _, _, _, *kinds = vals
+    kinds = kinds[:n_layers]
+    if not 0 < n_layers <= 128 or any(k >= len(LAYER_KINDS) for k in kinds):
+        raise ValueError("unknown layer kind in a version-5 header")
+    return dict(hybrid=HybridLayers(
+        tuple(LAYER_KINDS[k] for k in kinds), window, d_inner, d_state,
+        d_conv, dt_rank))
 
 
 def _read_ext4(vals) -> dict:

@@ -17,11 +17,13 @@ from ..ops.quants import quantize_q40
 from .spec import TransformerSpec
 
 
-def _build_tree(spec: TransformerSpec, t, mm) -> dict:
+def _build_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
     """Assemble the param tree from a dense builder ``t`` and a matmul-weight
-    builder ``mm`` — the one place that knows the tree's key set."""
-    if spec.latent:
-        return _build_planned_tree(spec, t, mm)
+    builder ``mm`` — the one place that knows the tree's key set. ``tie``
+    (a hybrid spec's tied classifier) turns the embedding into a matmul
+    weight; without it the classifier is drawn like any other."""
+    if spec.planned:
+        return _build_planned_tree(spec, t, mm, tie)
     # draw order is part of the seed's meaning: a dense spec's tree is the
     # one it always was (rms_att, rms_ffn before wcls)
     p = {"tok_embedding": t(spec.vocab_size, spec.dim),
@@ -47,21 +49,53 @@ def _build_tree(spec: TransformerSpec, t, mm) -> dict:
     return p
 
 
-def _build_planned_tree(spec: TransformerSpec, t, mm) -> dict:
-    """``_build_tree`` for a spec with two stacks of layers (the leading
-    dense ones under ``p["dense"]``): the keys and shapes are
-    ``spec.layer_plans``'s. Router rows ~N(0, 1/sqrt(dim)); its bias
-    ~N(0, 0.05), so that the choice (on s + b) and the weights (on s)
-    differ."""
+def hybrid_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
+    """A hybrid spec's float32 leaf ``name`` (any leading layer axes in
+    ``shape``) from ``unit(*shape)`` ~ N(0, 1), as the family initialises
+    it, so that a seeded state neither dies in a token nor blows up:
+    ``a_log`` = log(1..d_state); ``dt_b`` such that softplus gives 1e-3 to
+    1e-1, spread log-evenly over the channels; ``d_skip`` = 1; lambdas
+    N(0, 0.1); ``x_proj`` rows ~N(0, 1/sqrt(d_inner)), ``dt_proj``
+    ~N(0, 1/sqrt(dt_rank)), conv taps ~N(0, 1/2); gains 1 +- 0.05, biases
+    +- 0.05."""
+    hy = spec.hybrid
+    if name == "a_log":
+        a = np.log(np.arange(1, hy.d_state + 1, dtype=np.float32))
+        return np.broadcast_to(a[:, None], shape).copy()
+    if name == "dt_b":
+        dt = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), shape[-1]))
+        return np.broadcast_to((dt + np.log(-np.expm1(-dt))).astype(
+            np.float32), shape).copy()
+    if name == "d_skip":
+        return np.ones(shape, np.float32)
+    x = unit(*shape)
+    scale = {"lam": 0.1, "x_proj": hy.d_inner ** -0.5,
+             "dt_proj": hy.dt_rank ** -0.5, "conv_w": 0.5}.get(name, 0.05)
+    x = x * np.float32(scale)
+    return x + np.float32(1) if name in ("ln1_g", "ln2_g", "subln") else x
+
+
+def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
+    """``_build_tree`` for a spec with several stacks of layers (an expert
+    spec's leading dense ones under ``p["dense"]``, a hybrid spec's kinds
+    each under its name): the keys and shapes are ``spec.layer_plans``'s.
+    Router rows ~N(0, 1/sqrt(dim)); its bias ~N(0, 0.05), so that the choice
+    (on s + b) and the weights (on s) differ."""
     from ..io.loader import stack_of
 
     p = {"tok_embedding": t(spec.vocab_size, spec.dim),
-         "rms_final": 1 + t(spec.dim),
-         "wcls": mm(spec.vocab_size, spec.dim)}
+         "rms_final": 1 + t(spec.dim)}
+    if spec.hybrid:
+        p["rms_final_b"] = t(spec.dim)
+    p["wcls"] = (tie(p["tok_embedding"]) if spec.hybrid and tie
+                 else mm(spec.vocab_size, spec.dim))
     for stack, name, kind, shape in spec.stack_leaves():
         dst = stack_of(p, stack)
         if kind == "mm":
             dst[name] = mm(*shape)
+        elif spec.hybrid:
+            dst[name] = hybrid_leaf(spec, name, shape,
+                                    lambda *s: t(*s) * np.float32(20.0))
         elif name == "moe_gate":
             dst[name] = t(*shape) * np.float32(20.0 / np.sqrt(spec.dim))
         elif name == "moe_bias":
@@ -164,7 +198,10 @@ def synth_params(spec: TransformerSpec, q40: bool, seed: int = 0,
         qs, d16 = quantize_q40(x)
         return Q40Weight(qs, d16)
 
-    return _build_tree(spec, t, mm)
+    def tie(x):
+        return Q40Weight(*quantize_q40(x)) if q40 else x
+
+    return _build_tree(spec, t, mm, tie)
 
 
 def llama2_7b_spec(**overrides) -> TransformerSpec:
@@ -271,17 +308,21 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
     with open(path, "wb") as f:
         f.write(spec.header())
         f.write(f32(spec.vocab_size, spec.dim))
-        for _, _, entries in (spec.layer_plans() if spec.latent else ()):
+        for _, _, entries in (spec.layer_plans() if spec.planned else ()):
             for kind, name, shape, *_ in entries:
                 if kind == "mm":
                     f.write(q40(*shape))
+                elif spec.hybrid:
+                    f.write(memoryview(np.ascontiguousarray(hybrid_leaf(
+                        spec, name, shape, lambda *s: rng.standard_normal(
+                            s, dtype=np.float32)))).cast("B"))
                 elif name == "moe_gate":
                     f.write(f32(*shape, scale=1.0 / np.sqrt(spec.dim)))
                 elif name == "moe_bias":
                     f.write(f32(*shape, scale=0.05))
                 else:
                     f.write(f32(*shape, base=1.0, scale=0.05))
-        for _ in range(0 if spec.latent else spec.n_layers):
+        for _ in range(0 if spec.planned else spec.n_layers):
             for _, n in spec.layer_norm_shapes():   # rms_att, rms_ffn, ...
                 f.write(f32(n, base=1.0, scale=0.05))
             for name, (d, n) in spec.layer_matmul_shapes():
@@ -296,6 +337,8 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
                 for _, (d, n) in spec.expert_matmul_shapes():
                     f.write(q40(d, n))
         f.write(f32(spec.dim, base=1.0, scale=0.05))       # rms_final
+        if spec.hybrid:
+            f.write(f32(spec.dim, scale=0.05))             # its bias
         f.write(b"\x00" * spec.rope_gap_bytes)
         f.write(q40(spec.vocab_size, spec.dim, zero_row=BOS))
     size = os.path.getsize(path)

@@ -273,6 +273,36 @@ class EngineMetrics:
             "Smallest normaliser phi(q).z any decode step has read among "
             "its active rows and layers: near zero, a state has decayed "
             "to nothing or a first position was read by cancellation")
+        # a hybrid spec's (ContinuousStats.window_bytes and below): its
+        # recurrent states are ``dllama_state_bytes``
+        self.window_bytes = g(
+            "dllama_window_bytes",
+            "Resident bytes of the slots' window rings (a hybrid model's "
+            "window-attention layers: fixed, whatever the context)")
+        self.shared_kv_pages = g(
+            "dllama_shared_kv_pages_in_use",
+            "Pool pages of a hybrid model's ONE full-attention layer in use")
+        self.shared_kv_positions = c(
+            "dllama_shared_kv_positions_total",
+            "Cached positions the launched decode steps' rows read in ONE "
+            "of the layers that read the full layer's K / V, summed")
+        self.window_kv_positions = c(
+            "dllama_window_kv_positions_total",
+            "Ring slots the launched decode steps' rows read in ONE window "
+            "layer, summed (min(position + 1, window) a row)")
+        self.prompt_positions = c(
+            "dllama_prompt_positions_total",
+            "Prompt positions a hybrid model admitted")
+        self.xdec_positions = c(
+            "dllama_xdec_positions_total",
+            "... of which ran the cross-decoder (1 a prompt where admission "
+            "chunks took the rest)")
+        self.ssm_min_decay = g(
+            "dllama_ssm_min_decay",
+            "Smallest mean decay of the slowest state any active row's "
+            "Mamba layer took in a decode step: near zero, a state forgets "
+            "everything in one token")
+        self._hybrid_seen = [0, 0, 0, 0]
         # cost-ledger / scheduler-census series (ISSUE 16). The closed
         # vocabularies (token kinds, stall causes) pre-register so a
         # fresh scrape shows the full matrix at zero; per-class series
@@ -518,6 +548,20 @@ class EngineMetrics:
         landed on held experts and the live slots they filled."""
         self.moe_chunk_pairs.inc(local_pairs)
         self.moe_chunk_slots.inc(slots)
+
+    def record_hybrid(self, st) -> None:
+        """A hybrid model's counters as of a landed step, from the engine's
+        ``ContinuousStats`` (the counters advance by what is new)."""
+        now = [st.shared_kv_positions, st.window_kv_positions,
+               st.prompt_positions, st.xdec_positions]
+        for ctr, new, old in zip(
+                (self.shared_kv_positions, self.window_kv_positions,
+                 self.prompt_positions, self.xdec_positions), now,
+                self._hybrid_seen):
+            ctr.inc(new - old)
+        self._hybrid_seen = now
+        self.shared_kv_pages.set(st.shared_kv_pages)
+        self.ssm_min_decay.set(st.ssm_min_decay)
 
     def record_retire(self, req, now: float) -> None:
         """Derive the lifecycle histograms at retirement. Cancelled and

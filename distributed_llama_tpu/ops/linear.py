@@ -409,6 +409,14 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     from .pallas_q40 import _pick_rows_nb
 
     del rows  # every dispatch width has an nb-major kernel
+    if spec.hybrid:
+        # nb is 80, 160 or 320 at the published widths, all off the 128
+        # grid (a d-major leaf would be stored transposed and copied every
+        # step: see the expert case below), and the i4 body runs in fused
+        # chains only, which a state refuses
+        return Q40Layout("nb-major", (
+            "hybrid spec: every leaf the row tiler places packs nb-major, "
+            "u8 bodies (block counts off the 128 grid)"))
     counted = spec.matmul_shape_counts()     # a layer's, experts included
     shapes = [shape for shape, _ in counted]
     shapes.append((spec.vocab_size, spec.dim))  # wcls

@@ -174,6 +174,10 @@ def param_specs(params: dict[str, Any],
         from ..ops.retention import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)      # nor a retention spec's state
+    if "mamba" in params:
+        from ..ops.mamba import TP_REFUSAL
+
+        raise ValueError(TP_REFUSAL)      # nor a hybrid spec's slot
     specs: dict[str, Any] = {}
     for name, val in params.items():
         spec = _MATMUL_SPECS.get(name) or _REPL_SPECS.get(name)
@@ -740,6 +744,10 @@ def validate_sharding(spec: TransformerSpec, mesh: Mesh,
         raise ValueError(MOE_TP_REFUSAL)
     if spec.retention:
         from ..ops.retention import TP_REFUSAL
+
+        raise ValueError(TP_REFUSAL)
+    if spec.hybrid:
+        from ..ops.mamba import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)
     if spec.header_version == 3:

@@ -190,108 +190,125 @@ def _warn_q8_xla_fallback(spec: TransformerSpec, page_size: int,
           file=sys.stderr)
 
 
-def retention_refusals(*, tp: int = 1, page_size: int = 0, kv_pages: int = 0,
-                        prefix_share: bool = False, spec_k: int = 0,
-                        dispatch_tokens: int = 0, kv_quant: str = "f32",
-                        kv_host_pages: int = 0, kv_disk_dir=None,
-                        journal: bool = False, disagg: bool = False,
-                        block_steps: int = 1,
-                        kv_cache_dtype: str = "f32") -> list[str]:
-    """What a power-retention spec cannot run, one line for each feature
-    asked for, naming its flag and the reason. A sequence's memory is a
-    state of fixed size (ops/retention.py): it has no positions to page,
-    and it can be neither shared by page, nor rolled back, nor resumed
-    part-way without a snapshot, which nothing writes yet. The engine and
-    the CLI refuse with these lines; nothing stands in for the feature."""
-    from ..ops.retention import TP_REFUSAL
+def sequence_caches(spec) -> frozenset:
+    """What one sequence of ``spec`` caches, by kind: "pages" (K and V of
+    every position, which page), "plane" (a latent spec's one plane a
+    position, behind the same page tables) and "state" (a slot of fixed
+    size that a step rewrites: a recurrent state, a window ring). A hybrid
+    spec keeps a state AND one layer's pages."""
+    if spec.hybrid:
+        return frozenset({"state", "pages"})
+    if spec.retention:
+        return frozenset({"state"})
+    return frozenset({"plane"} if spec.latent else {"pages"})
 
-    why = "a retention model keeps a recurrent state of fixed size, not a "\
-          "KV cache"
+
+_WHY = {
+    frozenset({"state"}): "a retention model keeps a recurrent state of "
+                          "fixed size, not a KV cache",
+    frozenset({"plane"}): "a latent-attention model caches one plane "
+                          "[c_kv | k_rope] a layer, not K and V",
+    frozenset({"state", "pages"}): "a hybrid model keeps a recurrent state "
+                                   "and a window ring of fixed size beside "
+                                   "one layer's KV pages",
+}
+
+
+def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
+                   kv_pages: int = 0, prefix_share: bool = False,
+                   spec_k: int = 0, dispatch_tokens: int = 0,
+                   kv_quant: str = "f32", kv_host_pages: int = 0,
+                   kv_disk_dir=None, journal: bool = False,
+                   disagg: bool = False, block_steps: int = 1,
+                   kv_cache_dtype: str = "f32",
+                   serve: bool = True) -> list[str]:
+    """What a spec whose sequences cache ``caches`` (``sequence_caches``)
+    cannot run, one line for each feature asked for, naming its flag and the
+    reason: THE list, of which a retention spec's, a latent spec's and a
+    hybrid spec's are cases. A "state" (ops/retention.py, ops/mamba.py, a
+    window ring) has no positions to page, and can be neither shared by
+    page, nor rolled back, nor resumed part-way, nor shipped without a
+    snapshot, which nothing writes yet; a "plane" (models/latent.py) sits
+    behind the same page tables as a KV pool, so what works on page ids and
+    token ids (prefix sharing, the journal) runs, and what reads or writes
+    the two planes of a KV page does not carry it. Plain KV pages refuse
+    nothing. The engine and the CLI refuse with these lines; nothing stands
+    in for a refused feature."""
+    why = _WHY.get(caches)
+    if why is None:
+        return []
+    state, plane = "state" in caches, "plane" in caches
+    paged = bool(caches & {"pages", "plane"})
     out = []
+
+    def refuse(flag: str, for_state: str | None, for_plane: str | None):
+        reason = for_state if state else for_plane if plane else None
+        if reason is not None:
+            out.append(f"{flag}: {why}; {reason}")
+
     if tp > 1:
-        out.append(f"--tp {tp}: {TP_REFUSAL}")
-    if page_size or kv_pages:
-        out.append(f"--kv-page-size / --kv-pages: {why}; a sequence's "
-                   f"memory is one slot of fixed size, with no positions "
-                   f"to page")
+        if state:
+            from ..ops import mamba, retention
+
+            out.append(f"--tp {tp}: "
+                       f"{(mamba if paged else retention).TP_REFUSAL}")
+        else:
+            refuse(f"--tp {tp}", None, "neither the latent plane nor the "
+                   "experts held here are placed over tensor-parallel ranks")
+    if (page_size or kv_pages) and not paged:
+        refuse("--kv-page-size / --kv-pages", "a sequence's memory is one "
+               "slot of fixed size, with no positions to page", None)
+    if serve and not page_size and paged:
+        refuse("serve without --kv-page-size", "serve reads the full "
+               "layer's K / V through pages only (pass --kv-page-size)",
+               "serve reads it through pages only (pass --kv-page-size)")
     if prefix_share:
-        out.append(f"prefix sharing (prefix_share): {why}; a state cannot "
-                   f"be shared by page, and a radix prefix cannot be "
-                   f"resumed from without a state snapshot")
+        refuse("prefix sharing (prefix_share)", "a state cannot be shared "
+               "by page, and a radix prefix cannot be resumed from without "
+               "a state snapshot", None)
     if spec_k:
-        out.append(f"--spec-k {spec_k}: {why}; rejected drafts roll back "
-                   f"by truncating a page table, and a state cannot be "
-                   f"rolled back without a snapshot")
+        refuse(f"--spec-k {spec_k}", "rejected drafts roll back by "
+               "truncating a page table, and a state cannot be rolled back "
+               "without a snapshot", "the verify window has no latent "
+               "attention")
     if dispatch_tokens:
-        out.append(f"--dispatch-tokens {dispatch_tokens}: {why}; the mixed "
-                   f"window writes through per-row page tables")
+        refuse(f"--dispatch-tokens {dispatch_tokens}", "the mixed window "
+               "writes through per-row page tables", "the mixed window has "
+               "no latent attention")
     if kv_quant != "f32":
-        out.append(f"--kv-quant {kv_quant}: {why}; q8 quantizes KV pages, "
-                   f"and the state is float32 (bfloat16 fails the "
-                   f"reference's tolerance)")
+        refuse(f"--kv-quant {kv_quant}", "q8 quantizes KV pages, and the "
+               "state is float32 (bfloat16 fails the reference's tolerance)",
+               "q8 pages quantize a (n_kv, head) row in K and V planes")
     if kv_host_pages or kv_disk_dir:
-        out.append(f"--kv-host-pages / --kv-disk-dir: {why}; the host and "
-                   f"disk tiers spill and promote KV pages")
+        refuse("--kv-host-pages / --kv-disk-dir", "the host and disk tiers "
+               "spill and promote KV pages", "the host and disk tiers spill "
+               "and promote (k, v) page planes")
     if journal:
-        out.append(f"--journal: {why}; recovery resumes a sequence "
-                   f"part-way, which needs a state snapshot")
+        refuse("--journal", "recovery resumes a sequence part-way, which "
+               "needs a state snapshot", None)
     if disagg:
-        out.append(f"--disagg-role: {why}; the handoff ships prefilled KV "
-                   f"pages")
+        refuse("--disagg-role", "the handoff ships prefilled KV pages",
+               "the page wire and the handoff ship (k, v) page planes")
     if block_steps > 1:
-        out.append(f"--block-steps {block_steps}: {why}; the fused chain "
-                   f"masks rows by parking their writes on a scrap page")
+        refuse(f"--block-steps {block_steps}", "the fused chain masks rows "
+               "by parking their writes on a scrap page", "the fused chain "
+               "was not carried over to it")
     if kv_cache_dtype != "f32":
-        out.append(f"--kv-cache-dtype {kv_cache_dtype}: {why}; the state "
-                   f"is float32")
+        refuse(f"--kv-cache-dtype {kv_cache_dtype}", "the state is float32",
+               "the plane is float32 (what the reference's tolerance was "
+               "read on)")
     return out
 
 
-def latent_refusals(*, tp: int = 1, page_size: int = 0,
-                    spec_k: int = 0, dispatch_tokens: int = 0,
-                    kv_quant: str = "f32", kv_host_pages: int = 0,
-                    kv_disk_dir=None, disagg: bool = False,
-                    block_steps: int = 1, kv_cache_dtype: str = "f32",
-                    serve: bool = True) -> list[str]:
-    """What a latent-attention spec cannot run, one line for each feature
-    asked for, naming its flag and the reason. Its cache is ONE plane of
-    ``latent.width`` values a position (models/latent.py) behind the same
-    page tables as a KV pool: what works on page ids and token ids (prefix
-    sharing, the journal) runs; what reads or writes the two planes of a KV
-    page does not carry the one plane yet, and is refused by name here, in
-    the engine and in the CLI. Nothing stands in for a refused feature."""
-    why = "a latent-attention model caches one plane [c_kv | k_rope] a " \
-          "layer, not K and V"
-    out = []
-    if tp > 1:
-        out.append(f"--tp {tp}: {why}; neither the latent plane nor the "
-                   f"experts held here are placed over tensor-parallel "
-                   f"ranks")
-    if serve and not page_size:
-        out.append(f"serve without --kv-page-size: {why}; serve reads it "
-                   f"through pages only (pass --kv-page-size)")
-    if kv_quant != "f32":
-        out.append(f"--kv-quant {kv_quant}: {why}; q8 pages quantize a "
-                   f"(n_kv, head) row in K and V planes")
-    if kv_host_pages or kv_disk_dir:
-        out.append(f"--kv-host-pages / --kv-disk-dir: {why}; the host and "
-                   f"disk tiers spill and promote (k, v) page planes")
-    if disagg:
-        out.append(f"--disagg-role: {why}; the page wire and the handoff "
-                   f"ship (k, v) page planes")
-    if spec_k:
-        out.append(f"--spec-k {spec_k}: {why}; the verify window has no "
-                   f"latent attention")
-    if dispatch_tokens:
-        out.append(f"--dispatch-tokens {dispatch_tokens}: {why}; the mixed "
-                   f"window has no latent attention")
-    if block_steps > 1:
-        out.append(f"--block-steps {block_steps}: {why}; the fused chain "
-                   f"was not carried over to it")
-    if kv_cache_dtype != "f32":
-        out.append(f"--kv-cache-dtype {kv_cache_dtype}: {why}; the plane is "
-                   f"float32 (what the reference's tolerance was read on)")
-    return out
+def retention_refusals(**flags) -> list[str]:
+    """``cache_refusals`` of a power-retention spec (``inference`` and
+    ``serve`` alike: it has no pages to ask for)."""
+    return cache_refusals(frozenset({"state"}), **flags)
+
+
+def latent_refusals(**flags) -> list[str]:
+    """``cache_refusals`` of a latent-attention spec."""
+    return cache_refusals(frozenset({"plane"}), **flags)
 
 
 @dataclasses.dataclass
@@ -363,15 +380,17 @@ def _with_pick(step, paged: bool, vocab: int, state: bool = False):
     row's input token is its override or, where that is -1, the previous
     step's pick, which never left the device. Beside the forward's results
     it returns ``picked``, the argmax of each row's logits (lowest index
-    on a tie, as the host's ``sample_argmax``). A ``state`` (retention)
-    engine's block is [override | pos | takes part]: a row that does not
-    leaves its state as it is."""
+    on a tie, as the host's ``sample_argmax``). A ``state`` engine's block
+    ends in one more column, [override | pos | (page table) | takes part]:
+    a row that does not leaves its state as it is."""
     def run(params, cache, prev_picked, blk):
         import jax.numpy as jnp
 
         override = blk[:, 0]
         tokens = jnp.where(override >= 0, override, prev_picked)
-        table = (blk[:, 2:],) if paged else (blk[:, 2],) if state else ()
+        table = ((blk[:, 2:-1], blk[:, -1]) if paged and state
+                 else (blk[:, 2:],) if paged else (blk[:, 2],) if state
+                 else ())
         logits, cache, *moe = step(params, cache, tokens, blk[:, 1], *table)
         picked = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
         return (logits, picked, cache, *moe)
@@ -457,6 +476,23 @@ class ContinuousStats:
     # cancellation, shows here)
     state_bytes: int = 0
     min_normaliser: float = float("inf")
+    # a hybrid spec: resident bytes of the slots' window rings (its
+    # recurrent states are ``state_bytes``); pool pages of its ONE full
+    # layer in use, as of the last landed step; the cached positions the
+    # launched decode steps' rows read in ONE of the layers that read that
+    # layer's K / V, summed (a row at position p reads p + 1); positions
+    # admitted (prompt tokens) and those of them at which the
+    # cross-decoder ran (a prompt that took admission chunks: its last
+    # token alone; one that crawled through decode steps: all of them);
+    # and the smallest decay exp(delta A) any active row's state took in a
+    # decode step (near 0: a seeded state that forgets everything at once)
+    window_bytes: int = 0
+    shared_kv_pages: int = 0
+    shared_kv_positions: int = 0
+    window_kv_positions: int = 0    # ... in ONE window layer: min(p + 1, W)
+    prompt_positions: int = 0
+    xdec_positions: int = 0
+    ssm_min_decay: float = 1.0
     # the admission account, kept for every landed dispatch, dark or not,
     # on time.monotonic. ``land_s``: the sum of the landing intervals (a
     # step run ahead: landing to landing, which with the device never idle
@@ -603,7 +639,7 @@ class ContinuousEngine:
                  block_steps: int = 1, use_native_sampler: bool = True,
                  fast_prefill: bool = False, metrics=None,
                  page_size: int = 0, kv_pages: int = 0,
-                 prefix_share: bool = True, spec_k: int = 0,
+                 prefix_share: bool | None = None, spec_k: int = 0,
                  spec_ngram: int = 3, dispatch_tokens: int = 0,
                  slo=None, chaos=None,
                  journal=None, watchdog=None, kv_quant: str = "f32",
@@ -624,32 +660,27 @@ class ContinuousEngine:
                                     scatter_pages_q8)
 
         self.spec = spec
-        self._state = spec.retention
-        if self._state:
-            refused = retention_refusals(
-                tp=mesh.shape["tp"] if mesh is not None else 1,
-                page_size=page_size, kv_pages=kv_pages,
-                prefix_share=bool(prefix_share and page_size),
-                spec_k=spec_k, dispatch_tokens=dispatch_tokens,
-                kv_quant=kv_quant, kv_host_pages=kv_host_pages,
-                kv_disk_dir=kv_disk_dir, journal=journal is not None,
-                disagg=remote_pages, block_steps=block_steps,
-                kv_cache_dtype="f32" if cache_dtype in (
-                    None, jnp.float32) else str(cache_dtype))
-            if refused:
-                raise ValueError("; ".join(refused))
-        if spec.latent:
-            refused = latent_refusals(
-                tp=max(mesh.shape["tp"], mesh.shape.get("sp", 1))
-                if mesh is not None else 1,
-                page_size=page_size, spec_k=spec_k,
-                dispatch_tokens=dispatch_tokens, kv_quant=kv_quant,
-                kv_host_pages=kv_host_pages, kv_disk_dir=kv_disk_dir,
-                disagg=remote_pages, block_steps=block_steps,
-                kv_cache_dtype="f32" if cache_dtype in (
-                    None, jnp.float32) else str(cache_dtype))
-            if refused:
-                raise ValueError("; ".join(refused))
+        caches = sequence_caches(spec)
+        # a slot of fixed size a sequence (a recurrent state, a window
+        # ring); a hybrid spec keeps one AND pages of its full layer
+        self._state = "state" in caches
+        self._hybrid = bool(spec.hybrid)
+        if prefix_share is None:    # where the spec's cache can be shared
+            prefix_share = not self._state
+        refused = cache_refusals(
+            caches,
+            tp=max(mesh.shape["tp"], mesh.shape.get("sp", 1))
+            if mesh is not None else 1,
+            page_size=page_size, kv_pages=kv_pages,
+            prefix_share=bool(prefix_share and page_size),
+            spec_k=spec_k, dispatch_tokens=dispatch_tokens,
+            kv_quant=kv_quant, kv_host_pages=kv_host_pages,
+            kv_disk_dir=kv_disk_dir, journal=journal is not None,
+            disagg=remote_pages, block_steps=block_steps,
+            kv_cache_dtype="f32" if cache_dtype in (
+                None, jnp.float32) else str(cache_dtype))
+        if refused:
+            raise ValueError("; ".join(refused))
         self.slots = slots
         self.temperature = temperature
         self.topp = topp
@@ -896,7 +927,7 @@ class ContinuousEngine:
                                         page_size)
                     if kv_quant == "q8" else
                     init_cache_paged(spec, self._alloc.n_pages + 1,
-                                     page_size, dtype))
+                                     page_size, dtype, slots=slots))
                 self._step = _shared_program(
                     ("step_paged", spec, page_size, kv_quant),
                     lambda: jax.jit(
@@ -911,6 +942,14 @@ class ContinuousEngine:
                 decode_fwd = functools.partial(
                     forward_batch_paged, spec, page_size, kv_quant=kv_quant,
                     moe_counts=bool(spec.n_experts))
+                if self._hybrid:
+                    from ..models.sambay import forward_batch_sambay
+
+                    # also takes which rows take part, and hands out the
+                    # smallest decay a state took
+                    decode_fwd = functools.partial(
+                        forward_batch_sambay, spec, page_size=page_size,
+                        health=True)
                 if spec_k:
                     self._verify_base = _shared_program(
                         ("verify", spec, page_size, kv_quant),
@@ -956,6 +995,13 @@ class ContinuousEngine:
                     functools.partial(forward_retention, spec) if self._state
                     else functools.partial(forward, spec,
                                            moe_counts=bool(spec.n_experts)))
+                if self._hybrid:
+                    from ..models.sambay import forward_sambay
+
+                    # the self-decoder alone: the cross-decoder runs where
+                    # the prompt's last token takes its decode step
+                    chunk_fwd = functools.partial(forward_sambay, spec,
+                                                  xdec=False)
                 self._prefill_fwd = _shared_program(
                     ("prefill", spec, fast_prefill),
                     lambda: _maybe_bf16(chunk_fwd, fast_prefill, jax,
@@ -995,12 +1041,20 @@ class ContinuousEngine:
         if prefill_chunk > 1:
             # donate only the batched cache (updated in place); the scratch
             # sequence cache can't alias the rank-5 output
+            if self._hybrid:
+                from ..models.sambay import insert_sequence
+
+                # state and rings into the row, the full layer's K / V
+                # into the row's pages: ONE program
+                _insert = functools.partial(insert_sequence,
+                                            page_size=page_size)
             self._insert = _shared_program(
-                ("insert", self._state), lambda: jax.jit(
+                ("insert", self._state, self._hybrid and page_size),
+                lambda: jax.jit(
                     named_program("serve_admit_state_insert" if self._state
                                   else "serve_admit_insert", _insert),
                     donate_argnums=0))
-            if self._alloc is not None:
+            if self._alloc is not None and not self._hybrid:
                 # paged prefill plumbing: gather the slot's pages into a
                 # virtual contiguous sequence cache (shared prefix k/v
                 # included — suffix chunks must attend over it), prefill
@@ -1117,7 +1171,12 @@ class ContinuousEngine:
         self._submitted = 0 if journal is None else journal.next_id
         self._chains: dict = {}  # (k, greedy_only) -> fused chain program
         self.stats = ContinuousStats()
-        if self._state:
+        if self._hybrid:
+            from ..models.sambay import state_bytes
+
+            self.stats.state_bytes, self.stats.window_bytes = state_bytes(
+                self.cache)
+        elif self._state:
             self.stats.state_bytes = sum(int(a.nbytes) for a in self.cache)
         # request-cost accounting + dispatch census (ISSUE 16, obs/
         # ledger.py): always on like stats and the SLOTracker — pure
@@ -1136,6 +1195,7 @@ class ContinuousEngine:
 
             self._obs = EngineMetrics(metrics)
             self._obs.state_bytes.set(self.stats.state_bytes)
+            self._obs.window_bytes.set(self.stats.window_bytes)
             if self._alloc is not None:
                 # a fresh paged server must scrape as fully free, not as
                 # exhausted (the gauge default 0)
@@ -2555,14 +2615,21 @@ class ContinuousEngine:
                 row[0], row[1] = token, pos
                 row[2:2 + len(pages)] = pages
                 row[2 + len(pages):] = SCRAP_PAGE
-                if self._state:
-                    row[2] = rows[b] is not None
+                if self._state:     # the block's last column
+                    row[-1] = rows[b] is not None
             if prev is not None and not any(r is not None for r in rows):
                 return None
-            if self.spec.latent:
-                self.stats.latent_positions += sum(
-                    int(blk[b, 1]) + 1 for b, s in enumerate(rows)
-                    if s is not None)
+            if self.spec.latent or self._hybrid:
+                # what each riding row reads: itself and what came before
+                depth = [int(blk[b, 1]) + 1 for b, s in enumerate(rows)
+                         if s is not None]
+                if self._hybrid:
+                    w = self.spec.hybrid.window
+                    self.stats.shared_kv_positions += sum(depth)
+                    self.stats.window_kv_positions += sum(
+                        min(d, w) for d in depth)
+                else:
+                    self.stats.latent_positions += sum(depth)
             staged = self.jnp.asarray(blk)
         with host_phase("serve.dispatch"):
             logits, picked, self.cache, *more = self._decode(
@@ -2640,7 +2707,10 @@ class ContinuousEngine:
             flight.wait = time.monotonic() - t_wait
             if flight.norm_min is not None:  # (L,) floats
                 low = float(np.asarray(flight.norm_min).min())  # dlint: allow[D001] normaliser counter
-                if low < self.stats.min_normaliser:
+                if self._hybrid:
+                    self.stats.ssm_min_decay = min(self.stats.ssm_min_decay,
+                                                   low)
+                elif low < self.stats.min_normaliser:
                     self.stats.min_normaliser = low
                     if self._obs is not None:
                         self._obs.retention_min_normaliser.set(low)
@@ -2657,6 +2727,11 @@ class ContinuousEngine:
                                            - self._alloc.n_free)
                 if self._obs is not None:
                     self._obs.latent_pages.set(self.stats.latent_pages)
+            if self._hybrid:
+                self.stats.shared_kv_pages = (self._alloc.n_pages
+                                              - self._alloc.n_free)
+                if self._obs is not None:
+                    self._obs.record_hybrid(self.stats)
         return out, on_host
 
     def _land(self, flight: _Flight, out, on_host: bool, quiet: bool) -> None:
@@ -2900,6 +2975,10 @@ class ContinuousEngine:
         again and ``_admit`` pops the next)."""
         spec = self.spec
         req.t_admit = time.monotonic()
+        if self._hybrid:    # every prompt token takes a decode step, and
+            #   with it the cross-decoder, unless admission chunks take it
+            self.stats.prompt_positions += len(req.tokens)
+            self.stats.xdec_positions += len(req.tokens)
         s.req, s.pos = req, 0
         s.token = req.tokens[0]
         s.forced = list(req.tokens[1:])
@@ -2976,7 +3055,9 @@ class ContinuousEngine:
             self._ahead_t0 = t0
         self._ahead[1] += 1
         jnp = self.jnp
-        paged = self._alloc is not None
+        # a hybrid spec prefills a scratch sequence from position 0 (no
+        # shared prefix to gather) and inserts state, rings and pages at once
+        paged = self._alloc is not None and not self._hybrid
         # chunk-boundary preemption (ISSUE 14): paged f32 pools only —
         # the contiguous path's fresh scratch cache cannot resume
         # mid-prompt, and a q8 pool quantizes at every scatter, so a
@@ -3065,6 +3146,14 @@ class ContinuousEngine:
                     # admitted into the next slot this very round already
                     # shares them
                     self._alloc.insert_prefix(tokens[:end], s.pages)
+                elif self._hybrid:
+                    from .paging import SCRAP_PAGE
+
+                    tbl = np.full((self._max_pages,), SCRAP_PAGE, np.int32)
+                    tbl[:len(s.pages)] = s.pages
+                    self.cache = self._insert(self.cache, cache_box[0],
+                                              jnp.int32(slot_index),
+                                              jnp.asarray(tbl))
                 else:
                     self.cache = self._insert(self.cache, cache_box[0],
                                               jnp.int32(slot_index))
@@ -3099,6 +3188,8 @@ class ContinuousEngine:
         s.token = tokens[end]
         s.forced = list(tokens[end + 1:]) if end < n_pre else []
         s.prefill_pending = end < n_pre
+        if self._hybrid:    # the chunks ran no cross-decoder
+            self.stats.xdec_positions -= end - start
 
     def _resume_prefills(self) -> None:
         """Continue chunk-preempted admission prefills (ISSUE 14): every

@@ -112,11 +112,13 @@ class Engine:
         # step read, and the position its state stands at. A state cannot
         # be rewound: a step anywhere else than there or at 0 is refused
         self.min_normaliser = float("inf")
+        self.ssm_min_decay = 1.0  # a hybrid spec's: smallest state decay
         self._state_pos = 0
         self._ahead: _Step | None = None  # the step enqueued ahead, if any
         # steps found in flight and handed out / enqueued ahead and dropped
         self.ahead_used = self.ahead_dropped = 0
         tok_sharding = None  # one chip: the default device
+        chunk_fwd = None     # the T>8 chunk's forward, where not the step's
         if self.sharded:
             from ..parallel import (make_sharded_forward, shard_cache,
                                     shard_params, validate_sharding)
@@ -150,6 +152,14 @@ class Engine:
                 step = functools.partial(forward_retention, spec,
                                          norm_min=True)
                 self._step_raw = functools.partial(forward_retention, spec)
+            if spec.hybrid:
+                from ..models.sambay import forward_sambay
+
+                # likewise the smallest decay a state took; a chunk runs
+                # the self-decoder alone (no logits are read of it)
+                step = functools.partial(forward_sambay, spec, health=True)
+                chunk_fwd = functools.partial(forward_sambay, spec,
+                                              xdec=False)
 
         # host tokens are placed as the step's own ``picked`` result is, or
         # the mesh's step program would compile once for each of the two
@@ -165,7 +175,7 @@ class Engine:
         # fast_prefill it is traced with bf16 matmul precision
         # (ops/linear.bf16_prefill). Documented tolerance:
         # tests/test_prefill.py pins the prefilled-cache drift bound.
-        chunk_fwd = self._step_raw
+        chunk_fwd = chunk_fwd or self._step_raw
         if fast_prefill:
             from ..ops.linear import bf16_prefill
 
@@ -182,7 +192,7 @@ class Engine:
         self._state_moves(pos, int(tokens.shape[0]))
         logits, picked, self.cache, *more = self._fwd(
             self.params, self.cache, tokens, np.int32(pos))
-        if self.spec.retention:
+        if self.spec.stateful:
             return _Step(pos, logits, picked, [], more)
         return _Step(pos, logits, picked, more, [])
 
@@ -190,11 +200,11 @@ class Engine:
         """A retention spec's state advances by ``n`` positions from
         ``pos``: it has to stand there, or ``pos`` is 0 (a sequence's first
         position finds the state empty whatever it holds)."""
-        if not self.spec.retention:
+        if not self.spec.stateful:
             return
         if pos not in (0, self._state_pos):
             raise ValueError(
-                f"a retention state cannot be rewound or skipped ahead: it "
+                f"a recurrent state cannot be rewound or skipped ahead: it "
                 f"stands at position {self._state_pos}, asked for {pos} "
                 f"(start over at position 0)")
         self._state_pos = pos + n
@@ -236,9 +246,11 @@ class Engine:
                 self._ahead = self._launch(step.picked, pos + 1)
         with host_phase("inference.fetch"):  # the wait and the transfer
             if step.norm_min:  # (L,) floats beside the rest
-                low = np.asarray(step.norm_min[0])  # dlint: allow[D001] normaliser counter
-                self.min_normaliser = min(self.min_normaliser,
-                                          float(low.min()))
+                low = float(np.asarray(step.norm_min[0]).min())  # dlint: allow[D001] normaliser counter
+                if self.spec.hybrid:
+                    self.ssm_min_decay = min(self.ssm_min_decay, low)
+                else:
+                    self.min_normaliser = min(self.min_normaliser, low)
             if step.moe:  # an expert spec on one chip: 4 KB beside the rest
                 counts = np.asarray(step.moe[0])  # dlint: allow[D001] routed-rows counters
                 self.moe_pairs += int(counts.sum())
@@ -285,7 +297,7 @@ class Engine:
                 f"prefill overflow: pos0={pos0} + {len(tokens)} tokens "
                 f"> seq_len={seq_len}")
         c = min(chunk, seq_len)
-        if self.spec.retention:
+        if self.spec.stateful:
             # a state has no slot to overwrite: a padded position must not
             # reach it, so every chunk says how many of its positions count
             def fwd_valid(part, start, n_valid):

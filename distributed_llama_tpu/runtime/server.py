@@ -1290,7 +1290,14 @@ class InferenceServer:
                   f"steps launched ahead on device-resident tokens, "
                   f"{st.rows_dropped_ahead} rows of them dropped; "
                   f"{st.admission_clause}"
-                  + (f"; state {st.state_bytes / 2**20:.0f} MiB resident, "
+                  + (f"; state {st.state_bytes / 2**20:.0f} MiB and window "
+                     f"rings {st.window_bytes / 2**20:.0f} MiB resident, "
+                     f"{st.shared_kv_pages} pages of the shared K / V in "
+                     f"use, the cross-decoder at {st.xdec_positions} of "
+                     f"{st.prompt_positions} prompt positions, smallest "
+                     f"state decay {st.ssm_min_decay:.3g}"
+                     if st.window_bytes else
+                     f"; state {st.state_bytes / 2**20:.0f} MiB resident, "
                      f"smallest normaliser {st.min_normaliser:.3g}"
                      if st.state_bytes else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
@@ -1308,8 +1315,16 @@ class InferenceServer:
                   plain_step_ms=st.plain_step_ms,
                   host_ms_per_step=st.host_ms_per_step,
                   state_bytes=st.state_bytes,
+                  window_bytes=st.window_bytes,
+                  shared_kv_pages=st.shared_kv_pages,
+                  shared_kv_positions=st.shared_kv_positions,
+                  prompt_positions=st.prompt_positions,
+                  xdec_positions=st.xdec_positions,
+                  ssm_min_decay=(st.ssm_min_decay
+                                 if st.window_bytes else None),
                   min_normaliser=(st.min_normaliser
-                                  if st.state_bytes else None))
+                                  if st.state_bytes and not st.window_bytes
+                                  else None))
         if self.journal is not None:
             self.journal.close()
         try:

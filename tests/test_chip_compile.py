@@ -45,7 +45,13 @@ LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
           # latent row's projection padded from 576 to 640 outputs, q_b
           # (48 blocks), wo (512) and the dense layer's w2 (576 blocks)
           "ds-wkv_a": (640, 7168), "ds-wq_b": (24576, 1536),
-          "ds-wo": (7168, 16384), "ds-w2": (7168, 18432)}
+          "ds-wo": (7168, 16384), "ds-w2": (7168, 18432),
+          # Phi-4-mini-flash's (dim 2560: 80 blocks a row; d_inner 5120:
+          # 160; FFN 10240: 320): a Mamba layer's in_proj and out_proj (a
+          # GMU's are the halves), wqkv, wo / wq, fc1 and fc2
+          "ph-in_proj": (10240, 2560), "ph-out_proj": (2560, 5120),
+          "ph-wqkv": (5120, 2560), "ph-wo": (2560, 2560),
+          "ph-w13": (20480, 2560), "ph-w2": (2560, 10240)}
 
 
 def _sd(shape, dtype):
@@ -247,6 +253,50 @@ def _retention(kind: str):
                 f32((n_kv, 8, d)))
 
 
+def _mamba(kind: str):
+    """The state-space kernels (ops/mamba) at Phi-4-mini-flash's widths:
+    d_inner 5120, d_state 16, nine layers' state stacked. ``decode``: a
+    step of 32 rows; ``chunk``: a 128-token chunk of one sequence. The
+    state is aliased in both."""
+    from distributed_llama_tpu.ops import mamba
+
+    di, ds, rows, t, layers = 5120, 16, 32, 128, 9
+    f32 = functools.partial(_sd, dtype=jnp.float32)
+    if kind == "decode":
+        fn = functools.partial(mamba.mamba_decode_step, interpret=False)
+        return fn, (_sd((1,), jnp.int32), f32((layers * rows, ds, di)),
+                    f32((ds, di)), f32((rows, 8, di)),
+                    f32((rows, 2 * ds, 128)))
+    fn = functools.partial(mamba.mamba_prefill_chunk, interpret=False)
+    return fn, (_sd((2,), jnp.int32), f32((layers, ds, di)), f32((ds, di)),
+                f32((t, di)), f32((t, di)), f32((t, ds, 128)),
+                f32((t, ds, 128)))
+
+
+def _diff_attention(kind: str):
+    """Differential attention through the head-major softmax kernels
+    (ops/pallas_head_major_attention) at Phi-4-mini-flash's widths: 40
+    padded query heads over 10 KV pairs of 128, 32 rows. ``window``: eight
+    layers' rings of 512 slots; ``rows``: one sequence's contiguous K / V
+    of 8,704 positions (``inference``); ``paged``: ONE layer's pool, 544
+    pages of 16 a row."""
+    from distributed_llama_tpu.ops import pallas_head_major_attention as hm
+
+    b, n_q, n_kv, hs = 32, 40, 10, 128
+    if kind in ("window", "rows"):
+        b, s = (b, 512) if kind == "window" else (1, 8704)
+        ring = _sd((8 * b, n_kv, s, hs), jnp.float32)
+        return (functools.partial(hm.rows_decode_attention, kv_mul=4,
+                                  interpret=False),
+                (_sd((b, n_q, hs), jnp.float32), ring, ring,
+                 _sd((), jnp.int32), _sd((b,), jnp.int32)))
+    pool = _sd((17153, n_kv, 16, hs), jnp.float32)
+    return (functools.partial(hm.paged_decode_attention, kv_mul=4,
+                              interpret=False),
+            (_sd((b, n_q, hs), jnp.float32), pool, pool,
+             _sd((b,), jnp.int32), _sd((b, 8704 // 16), jnp.int32)))
+
+
 # kernel=False: the dispatch documents an XLA dequantize-then-dot route for
 # that shape (the int4 planes serve T == 1 only) — the case pins the routing
 # as well as the compile. An nb-major leaf has a kernel at every T: from 2
@@ -309,6 +359,23 @@ CASES = {
        for leaf in ("ds-wkv_a", "ds-wq_b", "ds-wo", "ds-w2")
        for t in (1, 32)},
     "q40-nb-ds-w2-T128": (functools.partial(_q40, "nb", "ds-w2", 128), True),
+    # Phi-4-mini-flash (PR 37): the state read and rewritten in place a
+    # row, the chunk's recurrence a lane tile at a time, the head-major
+    # softmax kernels at 10 KV pairs of 128 (head-minor, the pool and the
+    # rings were stored 16 heads wide and copied around every call), and
+    # its leaves at 1, 32 and 128 rows
+    "mamba-decode-B32": (functools.partial(_mamba, "decode"), True),
+    "mamba-chunk-T128": (functools.partial(_mamba, "chunk"), True),
+    "diff-window-W512-B32": (functools.partial(_diff_attention, "window"),
+                             True),
+    "diff-rows-S8704-B1": (functools.partial(_diff_attention, "rows"),
+                           True),
+    "diff-paged-ps16-B32": (functools.partial(_diff_attention, "paged"),
+                            True),
+    **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)
+       for leaf in ("ph-in_proj", "ph-out_proj", "ph-wqkv", "ph-wo",
+                    "ph-w13", "ph-w2")
+       for t in (1, 32, 128)},
     **{f"moe-ds-{kind}-{leaf}-T{rows}":
        (functools.partial(_moe, leaf, rows, "ds"), True)
        for kind, rows in (("slots", 32), ("slots", 16), ("slots", 1),
