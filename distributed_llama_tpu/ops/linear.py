@@ -35,7 +35,9 @@ from .quants import dequantize_q40_jax, dequantize_q80_jax, quantize_q80_jax
 RMS_EPS = 1e-5
 
 # trace-time matmul precision mode. "parity" = f32 accumulation at HIGHEST
-# (the logit-parity contract); "bf16" = bf16 MXU passes with f32 accumulation
+# (the logit-parity contract; the Q40 MXU tile reaches the same products in
+# five bf16 passes, ops/pallas_q40._five_pass_dot); "bf16" = bf16 MXU passes
+# with f32 accumulation
 # — ~3-6x the matmul throughput at a documented tolerance, used for the
 # opt-in fast-prefill path (--fast-prefill) where T is large and the outputs
 # only seed the KV cache. Read when a program is TRACED, so the mode must be

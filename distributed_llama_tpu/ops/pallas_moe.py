@@ -53,12 +53,16 @@ arithmetic, 8 vector operations a packed byte; at T == 1 the only body),
 any other the MXU tile ``_mxu_body_merged`` (the body of every dense leaf at
 T > 1) over the smallest of 8 / 16 / 32 / C rows that holds it
 (``_tile_rows``: a part-filled slot of a wide dispatch pays for its rows,
-not for C). On a v5e the tile streams an expert at 270-290 GB/s at 8 rows
-and the one-row body at 520-610 (PERF.md section 7, PRs 34 and 36).
+not for C). On a v5e the tile streams an expert at 370-390 GB/s at 8 rows
+(270-290 until PR 38) and the one-row body at 520-610 (PERF.md section 7,
+PRs 34, 36 and 38).
 
-All are the float32 arithmetic of the dense Q40 kernels (products at
-HIGHEST on the MXU, exact on the VPU; under fast-prefill's
-``matmul_mode() == "bf16"`` the tile multiplies in bfloat16); none
+All are the float32 arithmetic of the dense Q40 kernels: exact on the VPU,
+and on the MXU the tile's five bf16 passes (``ops/pallas_q40._five_pass_dot``:
+a Q40 weight, code x scale, has 15 significant bits and IS two bf16 numbers,
+so it is split in two exactly and multiplied by the row's three pieces: what
+``Precision.HIGHEST``'s six passes add, without its three-way split of the
+weight; under fast-prefill's ``matmul_mode() == "bf16"`` one pass); none
 dequantizes an expert to HBM. Off the Pallas path (codec or dense leaves:
 the CPU tests, F32 files) ``_experts_xla`` scans the experts one at a time.
 """
@@ -74,7 +78,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
 from .linear import StackedQ40, matmul, matmul_mode, silu
-from .pallas_q40 import _MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ
+from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ,
+                         _five_pass_dot, _mask_pieces)
 
 MOE_SLOT_ROWS = 8                # rows of a narrow dispatch's slot: one sublane tile
 MOE_SLOT_T_MAX = 32              # widest dispatch whose slots are one such tile
@@ -275,11 +280,12 @@ def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
     rows: ``xs`` (A, C, n) holds them, or ``xs`` (T, n) is the dispatch's
     rows and ``rows`` (A, C) says which each slot takes (the planes are
     then built once a row and gathered, not once a slot lane); C is one row
-    (T == 1) or a multiple of 8 (``slot_cap``). ``bf16``: the tile's
-    products in bfloat16 (fast-prefill); the one-row body stays exact. In a
-    capture the call is ``moe_q40_slots`` up to one sublane tile a slot (the
-    decode steps' kernel, which the benchmark's roofline shares find by
-    that name) and ``moe_q40_grouped`` beyond (a chunk's)."""
+    (T == 1) or a multiple of 8 (``slot_cap``). The tile multiplies the
+    rows' three bf16 pieces by the weight's two (``_mxu_body_merged``);
+    ``bf16``: one piece a side (fast-prefill); the one-row body stays
+    exact. In a capture the call is ``moe_q40_slots`` up to one sublane
+    tile a slot (the decode steps' kernel, which the benchmark's roofline
+    shares find by that name) and ``moe_q40_grouped`` beyond (a chunk's)."""
     nb, d = qs_t.shape[-2], qs_t.shape[-1]
     a, c = xs.shape[:2] if rows is None else rows.shape
 
@@ -322,26 +328,35 @@ def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
 # -- the MXU tile ---------------------------------------------------------------
 
 def _mxu_body_merged(qs_ref, s, xlo_ref, xhi_ref, out_ref, bf16: bool):
-    """``_matmul_body_nb`` (ops/pallas_q40: dequantize the tile, float32
-    dots at HIGHEST, or bf16 under fast-prefill) with the 16 nibble planes
+    """``_matmul_body_nb`` (ops/pallas_q40: dequantize the tile to float32,
+    exact, and multiply by ``_five_pass_dot``: the weight as its TWO bf16
+    pieces against the rows' three, five single bf16 passes; one piece a
+    side, one pass, under fast-prefill's ``bf16``) with the 16 nibble planes
     MERGED into the contraction: qs_ref (NJ, nb, R) codes, s (nb, R) scales,
-    xlo/xhi (bt, NJ * nb) with value j of block b at column j * nb + b; out
-    (bt, R). The 2-D kernels contract one plane at a time over nb, which is
-    64 or 32 for an expert: half or a quarter of an MXU pass's rows, 32
-    passes a tile. One (bt, NJ * nb) x (NJ * nb, R) dot per nibble half fills
-    them (measured on OLMoE's chunk: PERF.md section 6, PR 26)."""
+    xlo/xhi (bt, NJ * nb) float32 with value j of block b at column
+    j * nb + b; out (bt, R). The 2-D kernels contract one plane at a time
+    over nb, which is 64 or 32 for an expert: half or a quarter of an MXU
+    pass's rows, 32 passes a tile. One (3 bt, NJ * nb) x (NJ * nb, R) dot
+    per nibble half and weight piece fills them (measured on OLMoE's chunk:
+    PERF.md section 6, PR 26). The rows are split HERE, once a row tile: a
+    slot has one to eight of them, and three pieces a slot gathered outside
+    cost XLA more than these four operations a value (PERF.md section 7,
+    PR 38)."""
     nj, nb, r = qs_ref.shape
     wdt = jnp.bfloat16 if bf16 else jnp.float32
-    prec = None if bf16 else jax.lax.Precision.HIGHEST
     q = qs_ref[...].astype(jnp.int32)                # (NJ, nb, R)
     dn = (((1,), (0,)), ((), ()))
     acc = None
     for x_ref, codes in ((xlo_ref, q & 0xF), (xhi_ref, q >> 4)):
         w = ((codes - 8).astype(jnp.float32) * s[None]).astype(wdt)
-        a = jax.lax.dot_general(x_ref[...].astype(wdt),
-                                w.reshape(nj * nb, r), dn,
-                                preferred_element_type=jnp.float32,
-                                precision=prec)
+        if bf16:
+            a = jax.lax.dot_general(x_ref[...].astype(wdt),
+                                    w.reshape(nj * nb, r), dn,
+                                    preferred_element_type=jnp.float32)
+        else:
+            w = w.reshape(nj * nb, r)
+            x3 = jnp.concatenate(_mask_pieces(x_ref[...], 3), axis=0)
+            a = _five_pass_dot(x3, w, out_ref.shape[0])
         acc = a if acc is None else acc + a
     out_ref[...] = acc
 

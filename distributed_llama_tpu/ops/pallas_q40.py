@@ -194,28 +194,114 @@ def _kernel_matvec_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
                     out_ref)
 
 
-def _matmul_body_nb(qs3, s, xlo_ref, xhi_ref, out_ref, bf16=False):
-    """T>1 MXU body, nb-major: qs3 (NJ, nb, R), s (nb, R), xlo/xhi
-    (NJ, bt, nb); out (bt, R). The contraction is a STANDARD (M,K)x(K,N)
-    dot (x rows x nb against weights nb x R) — no minor-dim contraction
-    gymnastics; bf16 as in _matmul_body. Its cost hardly depends on bt
-    under 128 rows (the unpack and the MXU's weight loads do not): on a v5e
-    an 8-row tile streams Mistral-7B's leaves at 265-275 GB/s, a 16-row
-    one at 240-255 (my chip run, PR 32; PERF.md section 7)."""
+# -- the T>1 tile's dot: five bf16 passes, exact on what a Q40 weight holds ---
+#
+# A Q40 weight is code x scale, code an integer in [-8, 7] and scale a
+# float16 upconverted exactly (io/loader): at most 4 + 11 = 15 significant
+# bits, so it IS the sum of two bf16 numbers, its top 8 bits and the rest.
+# A float32 activation is the sum of three. Precision.HIGHEST knows neither:
+# it splits BOTH operands three ways on the vector unit (7 operations a
+# weight, of a tile whose unpack costs 4.5) and runs six passes, three of
+# them against a weight piece that is always zero. The bodies below split
+# the weight in two (a mask and a subtraction), stack the activation's three
+# pieces along the rows so each weight piece is pushed to the MXU once, and
+# add x_hi w_hi + x_mid w_hi + x_lo w_hi + x_hi w_lo + x_mid w_lo in
+# float32: everything HIGHEST's six passes add (x_lo w_lo, 2^-24 of a
+# product, is what it drops too).
+#
+# How the pieces reach the MXU was settled on the chip (PERF.md section 7,
+# PR 38's table): a dense leaf's rows are split ONCE a call outside the
+# kernel (it walks d / 256 row tiles of the same rows) and stored bfloat16
+# (half the planes' bytes and vector registers); an expert slot's are split
+# in the kernel (one to eight row tiles a slot: gathering three pieces a
+# slot cost XLA more than the split costs the tile); the weight's stay
+# FLOAT32 holding bf16 values and the dot runs at DEFAULT precision,
+# Mosaic's one bf16 pass, whose rounding of an operand that is a bf16 number
+# already is exact and costs the vector unit nothing: 6.5 operations a
+# weight where HIGHEST spent 11.5 (the tile is bound by them up to 32 rows,
+# and by the MXU's five passes at a chunk's 128).
+
+def _mask_pieces(x: jax.Array, n: int):
+    """float32 -> ``n`` float32 arrays holding bf16 values, each the top 8
+    bits of what the ones before left (a mask: truncation, so what is left
+    keeps its sign and loses 8 bits a piece; no cast to bfloat16 and back,
+    which XLA may elide), the last one all of it: their sum is ``x``
+    exactly, and the last IS a bf16 number when ``x`` has at most 8 n
+    significant bits. Two pieces of a Q40 weight (15 bits at most), three of
+    any float32. Lowers in a kernel and outside one."""
+    pieces = []
+    for _ in range(n - 1):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        pieces.append(jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32))
+        x = x - pieces[-1]
+    return (*pieces, x)
+
+
+def _stack_rows(block_t: int) -> int:
+    """Rows one t-tile's three pieces take in the stacked planes: 3 bt,
+    rounded up to bfloat16's 16-row sublane tile (an 8-row dispatch: 32,
+    the last 8 zeros)."""
+    return -(-3 * block_t // 16) * 16
+
+
+def _stack_pieces(p: jax.Array, block_t: int) -> jax.Array:
+    """(..., t, k) float32 -> (..., t / bt * S, k) bfloat16, S =
+    ``_stack_rows(bt)``: for each t-tile of ``block_t`` rows its rows' hi
+    pieces, then mid, then lo, then the padding."""
+    *lead, t, k = p.shape
+    s = _stack_rows(block_t)
+    x = jnp.stack(_mask_pieces(p, 3), axis=-3)       # (..., 3, t, k)
+    x = x.reshape(*lead, 3, t // block_t, block_t, k)
+    x = jnp.moveaxis(x, -4, -3).reshape(*lead, t // block_t, 3 * block_t, k)
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, s - 3 * block_t), (0, 0)])
+    return x.reshape(*lead, t // block_t * s, k).astype(jnp.bfloat16)
+
+
+def _five_pass_dot(x3: jax.Array, w: jax.Array, rows: int) -> jax.Array:
+    """(rows, R) float32: ``rows`` activation rows against a float32 Q40
+    tile ``w`` (K, R) in five single bf16 passes, as two dots: ``x3``
+    (>= 3 rows, K) holds the rows' [hi; mid; lo] pieces (bf16 numbers, as
+    bfloat16 or float32), so each weight piece is pushed to the MXU once:
+    x3 . w_hi and [hi; mid] . w_lo. The five slabs are added the small
+    terms first."""
     dn = (((1,), (0,)), ((), ()))
+    w_hi, w_lo = _mask_pieces(w, 2)
+    a = jax.lax.dot_general(x3, w_hi, dn, preferred_element_type=jnp.float32)
+    b = jax.lax.dot_general(x3[:2 * rows], w_lo, dn,
+                            preferred_element_type=jnp.float32)
+    return ((a[2 * rows:3 * rows] + b[rows:])
+            + (a[rows:2 * rows] + b[:rows])) + a[:rows]
+
+
+def _matmul_body_nb(qs3, s, xlo_ref, xhi_ref, out_ref, bf16=False):
+    """T>1 MXU body, nb-major: qs3 (NJ, nb, R), s (nb, R); out (bt, R). The
+    contraction is a STANDARD (M,K)x(K,N) dot (x rows x nb against weights
+    nb x R), one per nibble plane. Parity mode (``bf16`` False): xlo/xhi
+    (NJ, S, nb) bfloat16 hold the rows' three pieces stacked
+    (``_stack_pieces``) and each plane is ``_five_pass_dot``: the tile is
+    dequantized to float32 (exact), split in TWO bf16 pieces (exact: a Q40
+    weight has 15 bits) and multiplied in five single bf16 passes, as close
+    to float64 as HIGHEST's six and without its three-way split of the
+    weight. ``bf16`` (fast-prefill): xlo/xhi (NJ, bt, nb) float32, one
+    piece a side, one pass. Its cost hardly depends on bt under 128 rows
+    (the unpack and the MXU's weight loads do not; PERF.md section 7)."""
+    dn = (((1,), (0,)), ((), ()))
+    bt = out_ref.shape[0]
     wdt = jnp.bfloat16 if bf16 else jnp.float32
-    prec = None if bf16 else jax.lax.Precision.HIGHEST
+
+    def dot(x, w):
+        if not bf16:
+            return _five_pass_dot(x, w, bt)
+        return jax.lax.dot_general(x.astype(wdt), w, dn,
+                                   preferred_element_type=jnp.float32)
+
     acc = None
     for j in range(NJ):
         q = qs3[j].astype(jnp.int32)                 # (nb, R)
         wlo = (((q & 0xF) - 8).astype(jnp.float32) * s).astype(wdt)
         whi = (((q >> 4) - 8).astype(jnp.float32) * s).astype(wdt)
-        a = jax.lax.dot_general(xlo_ref[j].astype(wdt), wlo, dn,
-                                preferred_element_type=jnp.float32,
-                                precision=prec)
-        a = a + jax.lax.dot_general(xhi_ref[j].astype(wdt), whi, dn,
-                                    preferred_element_type=jnp.float32,
-                                    precision=prec)
+        a = dot(xlo_ref[j], wlo) + dot(xhi_ref[j], whi)
         acc = a if acc is None else acc + a
     out_ref[...] = acc
 
@@ -1064,6 +1150,19 @@ def _q40_mxu_nb_stacked_scratch(layer, qs_t, scale, x, *, block_rows,
     )(layer, qs_t, scale, xlo, xhi)
 
 
+def _mxu_nb_planes(x, nb: int, block_t: int, bf16: bool):
+    """The nb-major MXU body's x planes and the rows of one t-tile in them:
+    (NJ, t, nb) float32 under ``bf16`` (fast-prefill: one piece, cast in the
+    kernel), else the three bf16 pieces of every row, stacked a t-tile
+    (``_stack_pieces``), split ONCE a call here and not once a row tile in
+    the kernel."""
+    xlo, xhi = _split_x(x.astype(jnp.float32), nb)   # (NJ, t, nb) — natural
+    if bf16:
+        return xlo, xhi, block_t
+    return (_stack_pieces(xlo, block_t), _stack_pieces(xhi, block_t),
+            _stack_rows(block_t))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "block_t", "interpret",
                                     "bf16"))
@@ -1071,7 +1170,7 @@ def _q40_mxu_nb_2d(qs_t, scale, x, *, block_rows, block_t, interpret,
                    bf16=False):
     _, nb, d = qs_t.shape
     t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)   # (NJ, t, nb) — natural
+    xlo, xhi, x_rows = _mxu_nb_planes(x, nb, block_t, bf16)
     out = pl.pallas_call(
         functools.partial(_kernel_mxu_nb, bf16=bf16),
         compiler_params=_VMEM64_PARAMS,
@@ -1079,8 +1178,8 @@ def _q40_mxu_nb_2d(qs_t, scale, x, *, block_rows, block_t, interpret,
         in_specs=[
             pl.BlockSpec((NJ, nb, block_rows), lambda ti, i: (0, 0, i)),
             pl.BlockSpec((nb, block_rows), lambda ti, i: (0, i)),
-            pl.BlockSpec((NJ, block_t, nb), lambda ti, i: (0, ti, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda ti, i: (0, ti, 0)),
+            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i: (0, ti, 0)),
+            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i: (0, ti, 0)),
         ],
         out_specs=pl.BlockSpec((block_t, block_rows), lambda ti, i: (ti, i)),
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
@@ -1096,7 +1195,7 @@ def _q40_mxu_nb_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
                         interpret, bf16=False):
     _, _, nb, d = qs_t.shape
     t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
+    xlo, xhi, x_rows = _mxu_nb_planes(x, nb, block_t, bf16)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(t // block_t, d // block_rows),
@@ -1104,8 +1203,8 @@ def _q40_mxu_nb_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
             pl.BlockSpec((1, NJ, nb, block_rows),
                          lambda ti, i, L: (L[0], 0, 0, i)),
             pl.BlockSpec((1, nb, block_rows), lambda ti, i, L: (L[0], 0, i)),
-            pl.BlockSpec((NJ, block_t, nb), lambda ti, i, L: (0, ti, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda ti, i, L: (0, ti, 0)),
+            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i, L: (0, ti, 0)),
+            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i, L: (0, ti, 0)),
         ],
         out_specs=pl.BlockSpec((block_t, block_rows),
                                lambda ti, i, L: (ti, i)),
@@ -1124,11 +1223,13 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                         block_rows: int | None = None) -> jax.Array:
     """nb-major dispatch: every T on a kernel, the body picked by T alone.
     T = 1 the matvec, anything wider the MXU body with the standard
-    (M,K)x(K,N) dot (float32 at HIGHEST unless the caller traced under bf16
-    precision): rows are padded to a multiple of 8, so a 2..8-row decode
-    dispatch is ONE 8-row t-tile of the body a 16-row dispatch and a prefill
-    chunk run. The dequantize-then-dot fallback remains only for a ``d`` the
-    row tiler cannot place.
+    (M,K)x(K,N) dot (``_five_pass_dot``: the weight's two bf16 pieces
+    against the rows' three, split once a call, exact on both sides and as
+    close to float64 as HIGHEST; one piece a side where the caller traced
+    under bf16 precision): rows are padded to a multiple of 8, so a 2..8-row
+    decode dispatch is ONE 8-row t-tile of the body a 16-row dispatch and a
+    prefill chunk run. The dequantize-then-dot fallback remains only for a
+    ``d`` the row tiler cannot place.
 
     ``block_rows`` overrides the auto-picked row tile (q40_matmul's tuning
     knob, plumbed through for nb-major too). Lane-riding rows must be a

@@ -51,7 +51,16 @@ LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
           # GMU's are the halves), wqkv, wo / wq, fc1 and fc2
           "ph-in_proj": (10240, 2560), "ph-out_proj": (2560, 5120),
           "ph-wqkv": (5120, 2560), "ph-wo": (2560, 2560),
-          "ph-w13": (20480, 2560), "ph-w2": (2560, 10240)}
+          "ph-w13": (20480, 2560), "ph-w2": (2560, 10240),
+          # Mistral-7B's fused wqkv; Yi-34B's tp-4 shards (dim 7168: wo's
+          # shard has 56 blocks a row, w2's 160; a KV projection 256 rows);
+          # Brumby-14B's classifier (151,936 rows, whose only row tile is
+          # 128: 1187 is prime)
+          "m-wqkv": (6144, DIM),
+          "yi-wq": (1792, 7168), "yi-wk": (256, 7168), "yi-wo": (7168, 1792),
+          "yi-w1": (5120, 7168), "yi-w2": (7168, 5120),
+          "yi-wcls": (16000, 7168),
+          "br-wcls": (151936, 5120)}
 
 
 def _sd(shape, dtype):
@@ -97,7 +106,7 @@ def _q40(layout: str, leaf: str, t: int):
 
     d, n = LEAVES[leaf]
     nb = n // 32
-    lead = () if leaf == "wcls" else (2,)
+    lead = () if leaf.endswith("wcls") else (2,)
     if layout == "d":
         w = Q40Kernel(_sd((*lead, 16, d, nb), jnp.uint8),
                       _sd((*lead, d, nb), jnp.float32))
@@ -376,6 +385,20 @@ CASES = {
        for leaf in ("ph-in_proj", "ph-out_proj", "ph-wqkv", "ph-wo",
                     "ph-w13", "ph-w2")
        for t in (1, 32, 128)},
+    # the T > 1 tile's five-pass dot (PR 38) at every (leaf, rows) pair a
+    # cell runs that no case above holds: Mistral's leaves at a step's 8
+    # rows and a chunk's 128 (the rows' pieces stacked in bfloat16 planes,
+    # 32 and 96 rows a t-tile: ops/pallas_q40._mxu_nb_planes), Yi's tp-4
+    # shards at its chunk's 128, Brumby's classifier at its 16 (the expert
+    # slots' merged tile: the moe-* cases, 8 / 16 / 32 rows a slot)
+    **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)
+       for leaf, ts in (("m-wqkv", (8, 128)), ("wq", (128,)),
+                        ("m-w13", (128,)), ("m-w2", (128,)),
+                        ("wcls", (128,)), ("yi-wq", (128,)),
+                        ("yi-wk", (128,)), ("yi-wo", (128,)),
+                        ("yi-w1", (128,)), ("yi-w2", (128,)),
+                        ("yi-wcls", (128,)), ("br-wcls", (16,)))
+       for t in ts},
     **{f"moe-ds-{kind}-{leaf}-T{rows}":
        (functools.partial(_moe, leaf, rows, "ds"), True)
        for kind, rows in (("slots", 32), ("slots", 16), ("slots", 1),

@@ -672,3 +672,160 @@ def test_i4_decode_chain_parity(monkeypatch):
     np.testing.assert_array_equal(base, got)
 
 
+# ---- the T>1 tile's dot: five bf16 passes (PR 38) --------------------------
+
+def _f16_scales():
+    """Every finite float16, as float32 (the loader's exact upconvert)."""
+    s = np.arange(1 << 16, dtype=np.uint16).view(np.float16)
+    return s[np.isfinite(s)].astype(np.float32)
+
+
+@pytest.mark.parametrize("code", range(-8, 8))
+def test_q40_weight_is_two_bf16_pieces(code):
+    """code x scale has at most 4 + 11 significant bits: for every finite
+    float16 scale the tile's two pieces add up to it bit for bit, and both
+    ARE bfloat16 numbers (they round-trip), so the MXU multiplies what the
+    weight holds exactly."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_q40 import _mask_pieces
+
+    w = np.float32(code) * _f16_scales()
+    assert np.all(w.astype(np.float64)
+                  == np.float64(code) * _f16_scales().astype(np.float64))
+    hi, lo = (np.asarray(p) for p in _mask_pieces(jnp.asarray(w), 2))
+    np.testing.assert_array_equal(hi + lo, w)
+    for piece in (hi, lo):      # float32-held: a cast to bfloat16 is exact
+        np.testing.assert_array_equal(
+            piece, piece.astype(jnp.bfloat16).astype(np.float32))
+
+
+def test_activation_is_three_bf16_pieces():
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_q40 import _mask_pieces
+
+    x = np.random.default_rng(5).standard_normal(1 << 16).astype(np.float32)
+    x[:4] = [0.0, 1.0, -3.0e-20, 65504.0]
+    # jitted: where XLA would be free to elide a cast to bfloat16 and back
+    pieces = [np.asarray(p) for p in
+              jax.jit(lambda v: _mask_pieces(v, 3))(jnp.asarray(x))]
+    for p in pieces:
+        np.testing.assert_array_equal(
+            p, np.asarray(jnp.asarray(p).astype(jnp.bfloat16)
+                          ).astype(np.float32))
+    np.testing.assert_array_equal((pieces[0] + pieces[1]) + pieces[2], x)
+    assert np.abs(pieces[1]).max() > 0 and np.abs(pieces[2]).max() > 0
+
+
+def _tile_inputs(nb, rows, d=128, seed=0):
+    """Seeded codes, float16-valued scales and N(0,1) rows of one nb-major
+    tile, with the float64 product."""
+    rng = np.random.default_rng(1000 * nb + rows + seed)
+    qs = rng.integers(0, 256, (16, nb, d), dtype=np.uint8)
+    scale = ((rng.random((nb, d), dtype=np.float32) + 0.5)
+             / (8 * np.sqrt(32 * nb))).astype(np.float16).astype(np.float32)
+    x = rng.standard_normal((rows, 32 * nb)).astype(np.float32)
+    q = qs.astype(np.int32)
+    codes = np.concatenate([(q & 0xF) - 8, q >> 4], 0)
+    codes[16:] -= 8
+    w = np.transpose(codes * scale.astype(np.float64)[None],
+                     (2, 1, 0)).reshape(d, -1)              # (d, n)
+    return qs, scale, x, w, x.astype(np.float64) @ w.T
+
+
+def _run_body_nb(qs, scale, x, highest: bool):
+    """One tile through ``_matmul_body_nb`` (interpret mode); ``highest``:
+    through the body it replaced, float32 dots at Precision.HIGHEST."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    rows, d, nb = x.shape[0], qs.shape[-1], qs.shape[-2]
+
+    def highest_body(qs_ref, s_ref, xlo_ref, xhi_ref, out_ref):
+        dn = (((1,), (0,)), ((), ()))
+        acc = None
+        for j in range(pq.NJ):
+            q = qs_ref[j].astype(jnp.int32)
+            for x_ref, c in ((xlo_ref, q & 0xF), (xhi_ref, q >> 4)):
+                a = jax.lax.dot_general(
+                    x_ref[j], (c - 8).astype(jnp.float32) * s_ref[...], dn,
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
+                acc = a if acc is None else acc + a
+        out_ref[...] = acc
+
+    def new_body(qs_ref, s_ref, xlo_ref, xhi_ref, out_ref):
+        pq._matmul_body_nb(qs_ref, s_ref[...], xlo_ref, xhi_ref, out_ref)
+
+    # the HIGHEST body takes the rows as they are, the new one their pieces
+    xlo, xhi = (pq._split_x(jnp.asarray(x), nb) if highest else
+                pq._mxu_nb_planes(jnp.asarray(x), nb, rows, False)[:2])
+    return np.asarray(pl.pallas_call(
+        highest_body if highest else new_body,
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        interpret=True)(jnp.asarray(qs), jnp.asarray(scale), xlo, xhi))
+
+
+@pytest.mark.parametrize("rows", [8, 16, 32, 128])
+@pytest.mark.parametrize("nb", [32, 56, 64, 80, 128, 160, 224, 320, 448])
+def test_five_passes_are_as_close_to_float64_as_highest(nb, rows):
+    """At every block count and row count a cell runs: the five-product sum
+    lies no farther from the float64 product than the HIGHEST body does on
+    the same tile (plus 1e-7 of the outputs' size: two float32 summation
+    orders), and the sums one step cheaper do NOT: four products (without
+    x_mid w_lo) and one bf16 pass fail the same bound. That pins why
+    five."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    qs, scale, x, w, want = _tile_inputs(nb, rows)
+    size = np.abs(want).max()
+
+    def dist(got):
+        return np.abs(got - want).max() / size
+
+    bound = dist(_run_body_nb(qs, scale, x, highest=True)) + 1e-7
+    assert dist(_run_body_nb(qs, scale, x, highest=False)) <= bound
+    # the controls, from the same pieces, summed in float64
+    xp = [np.asarray(p, np.float64) for p in pq._mask_pieces(jnp.asarray(x), 3)]
+    wp = [np.asarray(p, np.float64)
+          for p in pq._mask_pieces(jnp.asarray(w.astype(np.float32)), 2)]
+    five = (xp[0] + xp[1] + xp[2]) @ wp[0].T + (xp[0] + xp[1]) @ wp[1].T
+    assert dist(five) <= bound
+    assert dist(five - xp[1] @ wp[1].T) > bound        # four products
+    assert dist(xp[0] @ wp[0].T) > bound               # one pass
+
+
+@pytest.mark.parametrize("rows", [8, 32])
+@pytest.mark.parametrize("nb", [32, 64, 224])
+def test_dense_and_merged_tiles_agree(nb, rows):
+    """``_matmul_body_nb`` (one dot a nibble plane) and the expert slots'
+    ``_mxu_body_merged`` (the planes merged into the contraction) are the
+    same five-product arithmetic: the same array on the same tile, to the
+    two summation orders' float32 rounding."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from distributed_llama_tpu.ops import pallas_moe as pm
+
+    qs, scale, x, _, want = _tile_inputs(nb, rows, seed=7)
+    dense = _run_body_nb(qs, scale, x, highest=False)
+
+    def merged_body(qs_ref, s_ref, xlo_ref, xhi_ref, out_ref):
+        pm._mxu_body_merged(qs_ref, s_ref[...], xlo_ref, xhi_ref, out_ref,
+                            False)
+
+    planes = pm._merged_planes(jnp.asarray(x), nb)
+    merged = np.asarray(pl.pallas_call(
+        merged_body, out_shape=jax.ShapeDtypeStruct(dense.shape, jnp.float32),
+        interpret=True)(jnp.asarray(qs), jnp.asarray(scale), *planes))
+    size = np.abs(want).max()
+    np.testing.assert_allclose(merged, dense, rtol=0, atol=1e-6 * size)
+    assert np.abs(merged - want).max() <= 1e-6 * size
