@@ -33,6 +33,7 @@ the same fits/headroom numbers next to every multi-chip projection.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 from ..models.spec import TransformerSpec
 from ..ops.quants import QK, FloatType
@@ -116,6 +117,16 @@ def latent_absorbed_bytes(spec: TransformerSpec) -> int:
             * la.kv_rank)
 
 
+def hyper_bytes(spec: TransformerSpec) -> int:
+    """The float32 tensors of a residual path of several streams
+    (``TransformerSpec.hyper_shapes``: two projections of (2 n + n^2, n
+    dim), their gates and biases, a layer; the projection's 2 n + n^2 rows
+    lie in whole 8-row tiles on the device, which is what they are at
+    n = 4)."""
+    return 4 * spec.n_layers * sum(math.prod(shape)
+                                   for _, shape in spec.hyper_shapes())
+
+
 def weights_device_bytes(spec: TransformerSpec, n_slices: int) -> int:
     """Resident bytes of this device's matmul-weight shards."""
     values = weight_values_per_device(spec, n_slices)
@@ -146,7 +157,8 @@ def replicated_device_bytes(spec: TransformerSpec) -> int:
         spec.dim + spec.router.bias) * 4                       # f32, whole
     gates = (spec.n_layers * spec.n_kv_heads * spec.dim * 4
              if spec.retention else 0)                         # f32, whole
-    return embedding + norms + routers + gates + latent_absorbed_bytes(spec)
+    return (embedding + norms + routers + gates + latent_absorbed_bytes(spec)
+            + hyper_bytes(spec))
 
 
 def state_slot_bytes(spec: TransformerSpec) -> int:
@@ -391,7 +403,10 @@ def activation_bytes_analytic(spec: TransformerSpec, n_slices: int,
     where a jaxpr is available; both land within a few MB of each other at
     decode shapes — activations are a rounding error next to weights/KV."""
     s = n_slices
-    vecs = (4 * spec.dim                      # x, xb, gathered block outs
+    # a spec with n residual streams carries X and writes X' (n dim each)
+    # where the others carry x and write x + y
+    streams = 2 * (spec.hyper.streams - 1) * spec.dim if spec.hyper else 0
+    vecs = (4 * spec.dim + streams            # x, xb, gathered block outs
             + 2 * (spec.hidden_dim // s)      # swiglu bands
             + (spec.dim + 2 * spec.kv_dim) // s   # local q/k/v
             + spec.vocab_size + spec.vocab_size // s)  # logits full + band

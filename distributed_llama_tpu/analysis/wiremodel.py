@@ -693,6 +693,9 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_moe_chunk_pairs_total"),
     _m("dllama_moe_chunk_slots_total"),
     _m("dllama_latent_pages_in_use"),
+    # a spec with several residual streams
+    _m("dllama_hc_streams"),
+    _m("dllama_hc_sublayers_a_step"),
     # a retention spec's recurrent state
     _m("dllama_state_bytes"),
     _m("dllama_retention_min_normaliser"),

@@ -47,6 +47,11 @@ Extensions beyond the reference:
   checkpoint (``quantization_config`` in its config) is NOT read: cast it
   to bfloat16 with the publisher's script first. The multi-token-prediction
   module (the checkpoint's last layer) is left out.
+* ``model_type: xing4_0`` (Xing4.0-29B-A4B: the same block with a residual
+  path of ``hc_mult`` streams): as ``deepseek_v3``, under header extension
+  6, with six float32 tensors a layer (``HYPER_TENSORS``). Their names in
+  the checkpoint are a GUESS (``HYPER_TENSORS_NOTE``): no checkpoint was
+  seen, and one that names them otherwise is refused by a ``KeyError``.
 * ``model_type: phi4flash`` (Phi-4-mini-flash-reasoning, SambaY): its
   ``config.json`` gives the spec (``hybrid_spec``: the per-layer list of
   kinds from ``mb_per_layer`` and the depth, header extension 5; the
@@ -181,6 +186,22 @@ LATENT_TENSORS = {
 published bfloat16 state dict names them."""
 
 
+HYPER_TENSORS = {
+    f"hc_{sub}_{leaf}": _L + f"{module}.{name}"
+    for sub, module in (("att", "attn_hc"), ("ffn", "mlp_hc"))
+    for leaf, name in (("phi", "phi.weight"), ("gate", "alpha"),
+                       ("bias", "bias"))}
+HYPER_TENSORS_NOTE = (
+    "a guess: no xing4_0 checkpoint was seen. The map takes one module a "
+    "sub-layer (attn_hc, mlp_hc) whose phi.weight is (2 n + n^2, n hidden) "
+    "with rows [pre | post | res row-major], alpha the three gates and "
+    "bias b_pre | b_post | B_res; a checkpoint that names or splits them "
+    "otherwise fails with a KeyError on the first such tensor, and nothing "
+    "is guessed further")
+"""A ``xing4_0`` checkpoint's hyper-connection tensors by this repo's names
+(``TransformerSpec.hyper_shapes``); the rest are ``LATENT_TENSORS``."""
+
+
 def latent_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
     """The spec of a ``deepseek_v3`` config object ``c``: every layer and
     every routed expert."""
@@ -264,6 +285,18 @@ class HFCheckpoint:
         moe = {}
         if getattr(c, "model_type", "") == "deepseek_v3":
             return latent_spec(c, target, seq_len)
+        if getattr(c, "model_type", "") == "xing4_0":
+            import dataclasses
+
+            from .models.spec import HyperConnections
+
+            print(f"🔶 xing4_0 hyper-connection tensors: "
+                  f"{HYPER_TENSORS_NOTE}")
+            return dataclasses.replace(
+                latent_spec(c, target, seq_len), hyper=HyperConnections(
+                    int(c.hc_mult), int(c.hc_sinkhorn_iters),
+                    float(c.hc_eps), float(c.mhc_h_res_clamp_min),
+                    float(c.mhc_h_res_clamp_max)))
         if getattr(c, "model_type", "") == "phi4flash":
             return hybrid_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "olmoe":
@@ -302,7 +335,7 @@ class HFCheckpoint:
                 "module docstring says why); models/synth.py writes a "
                 "seeded file of this spec")
         if spec.latent:     # rows as they are: see the module docstring
-            key = LATENT_TENSORS.get(name) or {
+            key = LATENT_TENSORS.get(name) or HYPER_TENSORS.get(name) or {
                 "tok_embedding": "model.embed_tokens.weight",
                 "rms_final": "model.norm.weight",
                 "wcls": "lm_head.weight"}[name]

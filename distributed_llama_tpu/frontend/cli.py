@@ -85,12 +85,15 @@ def _hybrid_line(spec, slots: int) -> str:
 def _latent_line(spec) -> str:
     """What a latent-attention spec caches and holds: a startup line."""
     la, lay = spec.latent, spec.layout
+    streams = (f"; {spec.hyper.streams} residual streams mixed around every "
+               f"sub-layer ({spec.hyper.sinkhorn_iters} Sinkhorn rounds)"
+               if spec.hyper else "")
     return (f"💡 attention: latent (q rank {la.q_rank}, cache "
             f"{la.width} values a position and layer: c_kv {la.kv_rank} + "
             f"k_rope {la.rope_dim}); layers: {lay.dense_layers} dense + "
             f"{spec.n_expert_layers} expert; experts held: "
             f"{spec.n_experts_held} of {spec.n_experts} from {lay.offset}, "
-            f"{lay.shared} shared")
+            f"{lay.shared} shared{streams}")
 
 
 def _retention_line(spec, slots: int) -> str:

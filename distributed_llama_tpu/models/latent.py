@@ -4,6 +4,9 @@ V, k leading dense layers and then expert layers (two ``lax.scan``s over two
 stacks of weights: ``params["dense"]`` and the top-level keys; per-layer
 kinds of any pattern can take the two's place), a router of the spec's kind
 with a shared expert and a share of the routed experts (ops/pallas_moe).
+A spec with ``hyper`` carries n residual streams (n, R, dim) through both
+scans in place of the one (R, dim); ops/hyper.py has the residual function,
+which is the plain add for every other spec.
 ``models/reference_latent.py`` states the layer in full, EXPANDED (every
 position's keys and values formed from its latent row). Here every
 dispatch, decode step and prefill chunk alike, runs the ABSORBED schedule:
@@ -35,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.spans import SCOPE_ATTN, SCOPE_EMBED, SCOPE_LOGITS
+from ..ops.hyper import fan_out, fold_in, residual_in
 from ..ops.linear import matmul, rmsnorm
 from .spec import TransformerSpec
 
@@ -217,8 +221,10 @@ def _stacks(spec: TransformerSpec, params: dict[str, Any]):
 
 
 def _scan_layers(spec, params, carry, attend_layer, moe_counts):
-    """Both stacks' ``lax.scan``: ``attend_layer(lw, x, layer, *planes)``
-    -> (attention output (R, H * v), *planes). Returns (carry, the expert
+    """Both stacks' ``lax.scan``: ``attend_layer(lw, h, layer, *planes)``
+    -> (attention output (R, H * v), *planes), h the attention sub-layer's
+    input: the carry x (R, dim), or the mix of its streams (n, R, dim) that
+    a spec with ``hyper`` reads (ops/hyper.py). Returns (carry, the expert
     layers' (L_e, E) routed-rows counts or None)."""
     from .llama import _post_attention, layer_view
 
@@ -230,9 +236,10 @@ def _scan_layers(spec, params, carry, attend_layer, moe_counts):
             x, *planes = carry
             idx, lw_slice = per_layer
             lw = layer_view(stacked, lw_slice, idx)
+            h, coef = residual_in(spec, lw, "att", x)
             with jax.named_scope(SCOPE_ATTN):
-                ao, *planes = attend_layer(lw, x, idx + first, *planes)
-            x = _post_attention(spec, lw, x, ao, want)
+                ao, *planes = attend_layer(lw, h, idx + first, *planes)
+            x = _post_attention(spec, lw, x, ao, want, coef)
             x, c = x if want else (x, None)
             return (x, *planes), c
 
@@ -252,7 +259,7 @@ def forward_latent(spec: TransformerSpec, params: dict[str, Any],
     t_len = tokens.shape[0]
     positions = pos + jnp.arange(t_len)
     with jax.named_scope(SCOPE_EMBED):
-        x = params["tok_embedding"][tokens].astype(jnp.float32)
+        x = fan_out(spec, params["tok_embedding"][tokens].astype(jnp.float32))
     mask = causal_cache_mask(spec.seq_len, pos, t_len)
 
     def attend_layer(lw, x, layer, c_all):
@@ -265,7 +272,7 @@ def forward_latent(spec: TransformerSpec, params: dict[str, Any],
     (x, c_all), counts = _scan_layers(spec, params, (x, cache.c),
                                       attend_layer, moe_counts)
     with jax.named_scope(SCOPE_LOGITS):
-        x = rmsnorm(x, params["rms_final"], spec.norm_eps)
+        x = rmsnorm(fold_in(spec, x), params["rms_final"], spec.norm_eps)
         logits = matmul(params["wcls"], x)
     if moe_counts:
         return logits, LatentCache(c_all), counts
@@ -310,7 +317,7 @@ def forward_batch_latent_paged(spec: TransformerSpec, page_size: int,
     """``models/llama.forward_batch_paged`` for a latent spec: one token
     for each of B rows at its own position against the page pool."""
     B = tokens.shape[0]
-    x = params["tok_embedding"][tokens].astype(jnp.float32)
+    x = fan_out(spec, params["tok_embedding"][tokens].astype(jnp.float32))
     pos_b = jnp.broadcast_to(jnp.asarray(pos_vec, jnp.int32), (B,))
     L, P, ps, width = cache.c.shape
 
@@ -323,7 +330,7 @@ def forward_batch_latent_paged(spec: TransformerSpec, page_size: int,
     (x, c3), counts = _scan_layers(
         spec, params, (x, cache.c.reshape(L * P, ps, width)), attend_layer,
         moe_counts)
-    x = rmsnorm(x, params["rms_final"], spec.norm_eps)
+    x = rmsnorm(fold_in(spec, x), params["rms_final"], spec.norm_eps)
     logits = matmul(params["wcls"], x)
     cache = LatentCache(c3.reshape(L, P, ps, width))
     return (logits, cache, counts) if moe_counts else (logits, cache)

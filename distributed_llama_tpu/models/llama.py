@@ -37,6 +37,7 @@ from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelNb,
 # forward (parallel/tp.py), so a --profile capture of either program
 # attributes through one obs/xprof.py vocabulary
 from ..obs.spans import SCOPE_ATTN, SCOPE_EMBED, SCOPE_FFN, SCOPE_LOGITS
+from ..ops.hyper import residual_in, residual_out
 from ..ops.linear import StackedQ40, fake_quant_q80, matmul, rmsnorm, silu
 from ..ops.quants import FloatType
 from .spec import TransformerSpec
@@ -321,18 +322,22 @@ def _swiglu(spec: TransformerSpec, lw: dict[str, Any], xb: jax.Array,
 
 
 def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
-                    ao: jax.Array, moe_counts: bool = False):
+                    ao: jax.Array, moe_counts: bool = False, coef=None):
     """Shared layer tail: wo + residual, then the ffn sub-block: SwiGLU, or
     for an EXPERT LAYER (one whose weights hold a router: an expert spec's
     leading dense layers hold none) the router and the routed experts
     (ops/pallas_moe) plus the shared expert where the layer has one.
     ``moe_counts`` (expert layers only) also returns the (E,) int32 count of
-    rows routed to each expert: ``(x, counts)``."""
+    rows routed to each expert: ``(x, counts)``. Both residuals go through
+    ops/hyper's ``residual_in`` / ``residual_out``: the plain add, unless
+    the spec carries several streams (x is then (n, R, dim) and ``coef``
+    what ``residual_in`` gave the caller for the attention sub-layer)."""
     with jax.named_scope(SCOPE_ATTN):
         ao = _maybe_q80(spec, ao)
-        x = x + matmul(lw["wo"], ao)
+        x = residual_out(coef, x, matmul(lw["wo"], ao))
+    h, coef = residual_in(spec, lw, "ffn", x)
     with jax.named_scope(SCOPE_FFN):
-        xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
+        xb = rmsnorm(h, lw["rms_ffn"], spec.norm_eps)
         xb = _maybe_q80(spec, xb)
         if "moe_gate" in lw:
             from ..ops.pallas_moe import moe_ffn
@@ -340,8 +345,9 @@ def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
             y, counts = moe_ffn(spec, lw, xb)
             if "sh_w2" in lw:
                 y = y + _swiglu(spec, lw, xb, "sh_")
-            return (x + y, counts) if moe_counts else x + y
-        return x + _swiglu(spec, lw, xb)
+            x = residual_out(coef, x, y)
+            return (x, counts) if moe_counts else x
+        return residual_out(coef, x, _swiglu(spec, lw, xb))
 
 
 def _layer(spec: TransformerSpec, x: jax.Array, lw: dict[str, Any],
@@ -390,7 +396,10 @@ LAYER_KEYS = ("rms_att", "rms_ffn", "wq", "wk", "wv", "wo", "w1", "w2", "w3",
               # a latent spec's: low-rank q, the latent row, the absorbed
               # halves of wkv_b, the router's bias, the shared expert
               "rms_q_a", "rms_kv_a", "wq_a", "wq_b", "wkv_a", "wkv_b",
-              "w_uk", "w_uv", "moe_bias", "sh_w1", "sh_w2", "sh_w3")
+              "w_uk", "w_uv", "moe_bias", "sh_w1", "sh_w2", "sh_w3",
+              # a spec's with several residual streams (ops/hyper.py)
+              "hc_att_phi", "hc_att_gate", "hc_att_bias",
+              "hc_ffn_phi", "hc_ffn_gate", "hc_ffn_bias")
 # load-time fusions (ops/linear) + the megakernel's permuted wo
 FUSED_KEYS = ("wqkv", "w13", "wo_mega", "moe_w13", "sh_w13")
 

@@ -127,6 +127,12 @@ def _rope(x, freq, factor):
 
 def attention(spec, lw, x):
     """x + the latent-attention sub-block, expanded."""
+    return x + attention_out(spec, lw, x)
+
+
+def attention_out(spec, lw, x):
+    """The latent-attention sub-block of input x (which it norms), expanded,
+    without the residual (models/reference_hyper.py mixes its own)."""
     la, nh, eps = spec.latent, spec.n_heads, spec.norm_eps
     t = x.shape[0]
     freq, factor, scale = rope_frequencies(spec)
@@ -146,7 +152,7 @@ def attention(spec, lw, x):
     causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
     att = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
     ao = jnp.einsum("hts,shd->thd", att, v).reshape(t, nh * la.v_dim)
-    return x + ao @ _dense(lw["wo"]).T
+    return ao @ _dense(lw["wo"]).T
 
 
 def _swiglu(h, w1, w2, w3):
@@ -185,6 +191,12 @@ def experts(spec, lw, x, shared: bool = True):
     """(x + the expert sub-block, margin (T,), chosen ids (T, k)).
     ``shared`` False leaves the shared expert out (the share test counts
     it once over the shares)."""
+    y, margin, ids = experts_out(spec, lw, x, shared)
+    return x + y, margin, ids
+
+
+def experts_out(spec, lw, x, shared: bool = True):
+    """``experts`` without the residual: the sub-block's output."""
     h = _rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
     w, ids, margin = route(spec, lw["moe_gate"], lw.get("moe_bias"), h)
     held, off = spec.n_experts_held, spec.layout.offset
@@ -200,7 +212,7 @@ def experts(spec, lw, x, shared: bool = True):
         y = y + jnp.where(here, w[:, j], 0.0)[:, None] * out
     if shared and spec.layout.shared:
         y = y + _swiglu(h, lw["sh_w1"], lw["sh_w2"], lw["sh_w3"])
-    return x + y, margin, ids
+    return y, margin, ids
 
 
 def _layer_of(stack: dict, i: int) -> dict:

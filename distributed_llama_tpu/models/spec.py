@@ -95,6 +95,20 @@ each kind is a stack of its own under ``params[kind]`` (its leading axis
 counts the layers of that kind), the final norm's gain is followed by its
 bias (``rms_final_b``), and the classifier is a copy of the embedding in
 the weights' float type (tied).
+
+Extension VERSION 6 (version 4's values, then four ints {streams,
+sinkhornIters, two reserved} and three float64 {eps, clampMin, clampMax}:
+``HyperConnections``) is written only by a spec that sets ``hyper``, so
+files of versions 0, 2, 3, 4 and 5 read and write byte for byte. A layer's
+residual path is then ``streams`` parallel streams mixed by per-token
+coefficients (``ops/hyper.py``), and each layer carries, after its norms and
+before its matmul tensors, six float32 tensors, three a sub-layer
+(attention's, then the feed-forward's):
+
+  hc_att_phi ((2 n + n^2) x (n dim): rows [pre (n) | post (n) | res (n^2,
+             row-major)], one row an output as every matmul weight here),
+  hc_att_gate (3: a_pre, a_post, a_res), hc_att_bias (2 n + n^2: b_pre,
+             b_post, B_res row-major), hc_ffn_phi, hc_ffn_gate, hc_ffn_bias
 """
 
 from __future__ import annotations
@@ -116,7 +130,10 @@ EXT4_VERSION = 4
 EXT4_STRUCT = struct.Struct("<14i2d16i7d")
 EXT5_VERSION = 5
 EXT5_STRUCT = struct.Struct("<14i2d16i7d8i128B")
+EXT6_VERSION = 6
+EXT6_STRUCT = struct.Struct("<14i2d16i7d4i3d")
 MAX_HEADER_BYTES = EXT5_STRUCT.size
+HC_SUBLAYERS = ("att", "ffn")
 ATTN_KINDS = ("softmax", "retention")
 # what a layer of a ``HybridLayers`` spec mixes with, and what it caches for
 # one sequence: a recurrent state of fixed size, a ring of the last
@@ -226,6 +243,27 @@ class HybridLayers:
                    if i < self.kinds.index("gmu"))
 
 
+@dataclasses.dataclass(frozen=True)
+class HyperConnections:
+    """A residual path of ``streams`` parallel streams (manifold-constrained
+    hyper-connections, arXiv:2512.24880; ops/hyper.py computes it,
+    models/reference_hyper.py states it): each sub-layer reads a per-token
+    mix of the streams and writes back through a per-token matrix that
+    ``sinkhorn_iters`` alternating normalisations (each sum taken with
+    ``eps`` added) project onto the doubly stochastic ones, from logits
+    clamped to [clamp_min, clamp_max]."""
+    streams: int
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp_min: float = -30.0
+    clamp_max: float = 30.0
+
+    @property
+    def coefficients(self) -> int:
+        """Per-token coefficients of one sub-layer: n + n + n^2."""
+        return self.streams * (2 + self.streams)
+
+
 def sambay_kinds(n_layers: int) -> tuple:
     """The published pattern at ``n_layers`` (even, >= 8), L/2 = h: Mamba at
     even i <= h, window attention at odd i < h, the full layer at h + 1,
@@ -269,10 +307,22 @@ class TransformerSpec:
     # what ``attn_kind`` / ``latent`` / ``layout`` say, the list's trivial
     # cases)
     hybrid: HybridLayers | None = None
+    # header version 6: a residual path of more than one stream (None: the
+    # plain ``x + F(x)``)
+    hyper: HyperConnections | None = None
 
     def __post_init__(self):
         if self.hybrid is not None:
             self._check_hybrid()
+        if self.hyper is not None and (
+                not self.latent or self.hyper.streams < 2
+                or self.hyper.sinkhorn_iters < 1
+                or not self.hyper.clamp_min < self.hyper.clamp_max):
+            raise ValueError("a residual path of several streams is run by "
+                             "the latent-attention forward (models/latent."
+                             "py): set latent, at least 2 streams, at least "
+                             "one Sinkhorn iteration and clamp_min < "
+                             "clamp_max")
         if self.attn_kind not in ATTN_KINDS:
             raise ValueError(f"attn_kind={self.attn_kind!r}: expected one "
                              f"of {ATTN_KINDS}")
@@ -361,8 +411,10 @@ class TransformerSpec:
 
     @property
     def header_version(self) -> int:
-        """0 (the 28-byte header), 2, 3 or 4: the lowest that holds the
-        spec."""
+        """0 (the 28-byte header), 2, 3, 4, 5 or 6: the lowest that holds
+        the spec."""
+        if self.hyper:
+            return EXT6_VERSION
         if self.hybrid:
             return EXT5_VERSION
         if (self.latent or self.rope_scaling or self.layout != ExpertLayout()
@@ -383,7 +435,8 @@ class TransformerSpec:
         return {0: HEADER_BYTES, EXT_VERSION: EXT_STRUCT.size,
                 EXT3_VERSION: EXT3_STRUCT.size,
                 EXT4_VERSION: EXT4_STRUCT.size,
-                EXT5_VERSION: EXT5_STRUCT.size}[self.header_version]
+                EXT5_VERSION: EXT5_STRUCT.size,
+                EXT6_VERSION: EXT6_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
@@ -432,7 +485,8 @@ class TransformerSpec:
             layout = {(EXT_VERSION, 10): EXT_STRUCT,
                       (EXT3_VERSION, 13): EXT3_STRUCT,
                       (EXT4_VERSION, 29): EXT4_STRUCT,
-                      (EXT5_VERSION, 165): EXT5_STRUCT}.get((version, count))
+                      (EXT5_VERSION, 165): EXT5_STRUCT,
+                      (EXT6_VERSION, 36): EXT6_STRUCT}.get((version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
@@ -442,6 +496,11 @@ class TransformerSpec:
             base, ext = ints[:7], ints[7:10]
             if version == EXT5_VERSION:
                 more = _read_ext5(ints[36:], base[2])
+                ints = ints[:36]
+            if version == EXT6_VERSION:
+                streams, iters, _, _, eps, lo, hi = ints[36:]
+                more = dict(hyper=HyperConnections(
+                    streams, iters, float(eps), float(lo), float(hi)))
                 ints = ints[:36]
             if version >= EXT4_VERSION:
                 more = dict(_read_ext4(ints[13:]), **more)
@@ -490,6 +549,11 @@ class TransformerSpec:
             rs.beta_slow, rs.mscale, rs.mscale_all_dim)
         if self.header_version == EXT4_VERSION:
             return EXT4_STRUCT.pack(EXT_MAGIC, EXT4_VERSION, 29, *v3, *v4)
+        if self.header_version == EXT6_VERSION:
+            hc = self.hyper
+            return EXT6_STRUCT.pack(
+                EXT_MAGIC, EXT6_VERSION, 36, *v3, *v4, hc.streams,
+                hc.sinkhorn_iters, 0, 0, hc.eps, hc.clamp_min, hc.clamp_max)
         hy = self.hybrid
         kinds = [LAYER_KINDS.index(k) for k in hy.kinds]
         return EXT5_STRUCT.pack(
@@ -564,6 +628,16 @@ class TransformerSpec:
             norms += [("rms_q", self.dim), ("rms_k", self.kv_dim)]
         return norms
 
+    def hyper_shapes(self) -> list[tuple[str, tuple]]:
+        """One layer's float32 tensors of the residual path, in file order
+        (empty without ``hyper``): three a sub-layer."""
+        if not self.hyper:
+            return []
+        k, n = self.hyper.coefficients, self.hyper.streams
+        return [(f"hc_{sub}_{leaf}", shape) for sub in HC_SUBLAYERS
+                for leaf, shape in (("phi", (k, n * self.dim)),
+                                    ("gate", (3,)), ("bias", (k,)))]
+
     @property
     def gate_shape(self) -> tuple[int, int] | None:
         """A retention layer's ``w_gate`` (float32, after ``wo`` in the
@@ -581,6 +655,7 @@ class TransformerSpec:
         if self.hybrid:
             return self._hybrid_plans()
         norms = [("f32", n, (w,)) for n, w in self.layer_norm_shapes()]
+        norms += [("f32", n, s) for n, s in self.hyper_shapes()]
         dense = norms + [("mm", n, s)
                          for n, s in self.dense_layer_matmul_shapes()]
         shared = self.layer_matmul_shapes()

@@ -75,6 +75,24 @@ def hybrid_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
     return x + np.float32(1) if name in ("ln1_g", "ln2_g", "subln") else x
 
 
+def hyper_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
+    """A float32 leaf ``hc_<sub>_<phi|gate|bias>`` of a spec with several
+    residual streams (any leading layer axes in ``shape``) from
+    ``unit(*shape)`` ~ N(0, 1): ``phi`` rows N(0, 1/sqrt(n dim)), so that a
+    unit-RMS ``xhat`` projects to N(0, 1); gates 0.5, so that the per-token
+    part moves the coefficients and is no constant in disguise; ``b_pre``
+    and ``b_post`` N(0, 1); ``B_res`` 4 x identity + N(0, 1) (a mix that
+    keeps most of a stream and some of the others)."""
+    n = spec.hyper.streams
+    if name.endswith("_gate"):
+        return np.full(shape, 0.5, np.float32)
+    x = unit(*shape).astype(np.float32)
+    if name.endswith("_phi"):
+        return x * np.float32((n * spec.dim) ** -0.5)
+    x[..., 2 * n:] += np.float32(4.0) * np.eye(n, dtype=np.float32).reshape(-1)
+    return x
+
+
 def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
     """``_build_tree`` for a spec with several stacks of layers (an expert
     spec's leading dense ones under ``p["dense"]``, a hybrid spec's kinds
@@ -96,6 +114,9 @@ def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
         elif spec.hybrid:
             dst[name] = hybrid_leaf(spec, name, shape,
                                     lambda *s: t(*s) * np.float32(20.0))
+        elif name.startswith("hc_"):
+            dst[name] = hyper_leaf(spec, name, shape,
+                                   lambda *s: t(*s) * np.float32(20.0))
         elif name == "moe_gate":
             dst[name] = t(*shape) * np.float32(20.0 / np.sqrt(spec.dim))
         elif name == "moe_bias":
@@ -314,6 +335,10 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
                     f.write(q40(*shape))
                 elif spec.hybrid:
                     f.write(memoryview(np.ascontiguousarray(hybrid_leaf(
+                        spec, name, shape, lambda *s: rng.standard_normal(
+                            s, dtype=np.float32)))).cast("B"))
+                elif name.startswith("hc_"):
+                    f.write(memoryview(np.ascontiguousarray(hyper_leaf(
                         spec, name, shape, lambda *s: rng.standard_normal(
                             s, dtype=np.float32)))).cast("B"))
                 elif name == "moe_gate":

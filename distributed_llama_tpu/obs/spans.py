@@ -44,6 +44,10 @@ PHASE_SCOPES = (SCOPE_EMBED, SCOPE_ATTN, SCOPE_FFN, SCOPE_LOGITS)
 # inside SCOPE_FFN of an expert spec (ops/pallas_moe.moe_ffn opens them)
 SCOPE_MOE_ROUTER = "moe.router"    # router matmul, softmax, top-k
 SCOPE_MOE_EXPERTS = "moe.experts"  # slot building, expert kernels, combine
+# a spec with several residual streams (ops/hyper.py opens them, beside
+# SCOPE_ATTN / SCOPE_FFN: a sub-layer's coefficients, and its two mixes)
+SCOPE_HC_COEF = "hc.coef"   # flat norm, projection, sigmoids, Sinkhorn
+SCOPE_HC_MIX = "hc.mix"     # the sub-layer's input and the streams' update
 
 # collective scopes: one per _ici_* helper, named after the helper so a
 # trace event inside e.g. `ici_all_gather` is attributable to the exact

@@ -216,6 +216,16 @@ class EngineMetrics:
             "dllama_latent_pages_in_use",
             "Pool pages the sequences of a latent-attention model hold "
             "(one plane of latent.width values a position and layer)")
+        # a spec with several residual streams (ContinuousStats.hc_streams
+        # / hc_sublayers_a_step): fixed by the spec, 0 without streams
+        self.hc_streams = g(
+            "dllama_hc_streams",
+            "Residual streams the model's layers mix (manifold-constrained "
+            "hyper-connections; 0: the plain x + F(x))")
+        self.hc_sublayers_a_step = g(
+            "dllama_hc_sublayers_a_step",
+            "Sub-layers a decode step mixes the residual streams around "
+            "(two a layer; 0 without streams)")
         self._moe_rows: list = []
         # step_once's run-ahead (ContinuousStats.steps_ahead /
         # rows_dropped_ahead): how often the decode iteration engages

@@ -195,11 +195,15 @@ def sequence_caches(spec) -> frozenset:
     every position, which page), "plane" (a latent spec's one plane a
     position, behind the same page tables) and "state" (a slot of fixed
     size that a step rewrites: a recurrent state, a window ring). A hybrid
-    spec keeps a state AND one layer's pages."""
+    spec keeps a state AND one layer's pages. "streams" rides along where
+    the residual path is several streams (``spec.hyper``): nothing a
+    sequence caches, but the list below names it in its reasons."""
     if spec.hybrid:
         return frozenset({"state", "pages"})
     if spec.retention:
         return frozenset({"state"})
+    if spec.latent and spec.hyper:
+        return frozenset({"plane", "streams"})
     return frozenset({"plane"} if spec.latent else {"pages"})
 
 
@@ -208,6 +212,9 @@ _WHY = {
                           "fixed size, not a KV cache",
     frozenset({"plane"}): "a latent-attention model caches one plane "
                           "[c_kv | k_rope] a layer, not K and V",
+    frozenset({"plane", "streams"}): "a latent-attention model with several "
+                                     "residual streams caches one plane "
+                                     "[c_kv | k_rope] a layer, not K and V",
     frozenset({"state", "pages"}): "a hybrid model keeps a recurrent state "
                                    "and a window ring of fixed size beside "
                                    "one layer's KV pages",
@@ -253,8 +260,11 @@ def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
             out.append(f"--tp {tp}: "
                        f"{(mamba if paged else retention).TP_REFUSAL}")
         else:
-            refuse(f"--tp {tp}", None, "neither the latent plane nor the "
-                   "experts held here are placed over tensor-parallel ranks")
+            refuse(f"--tp {tp}", None, (
+                "neither the streams' carry and per-token mixes, "
+                if "streams" in caches else "neither ")
+                + "the latent plane nor the experts held here are placed "
+                "over tensor-parallel ranks")
     if (page_size or kv_pages) and not paged:
         refuse("--kv-page-size / --kv-pages", "a sequence's memory is one "
                "slot of fixed size, with no positions to page", None)
@@ -464,6 +474,11 @@ class ContinuousStats:
     # p + 1): what the latent decode kernel must move, a layer
     latent_pages: int = 0
     latent_positions: int = 0
+    # a spec with several residual streams (ops/hyper.py): how many, and
+    # the sub-layers a decode step mixes them around (two a layer); fixed
+    # by the spec, 0 without streams
+    hc_streams: int = 0
+    hc_sublayers_a_step: int = 0
     # step_once's run-ahead: steps launched on the previous step's picks
     # while those were still on the device, and rows of such steps whose
     # result was thrown away (the row had stopped on a token only the
@@ -1178,6 +1193,9 @@ class ContinuousEngine:
                 self.cache)
         elif self._state:
             self.stats.state_bytes = sum(int(a.nbytes) for a in self.cache)
+        if spec.hyper:
+            self.stats.hc_streams = spec.hyper.streams
+            self.stats.hc_sublayers_a_step = 2 * spec.n_layers
         # request-cost accounting + dispatch census (ISSUE 16, obs/
         # ledger.py): always on like stats and the SLOTracker — pure
         # host bookkeeping charged once per DISPATCH, not per token; the
@@ -1196,6 +1214,8 @@ class ContinuousEngine:
             self._obs = EngineMetrics(metrics)
             self._obs.state_bytes.set(self.stats.state_bytes)
             self._obs.window_bytes.set(self.stats.window_bytes)
+            self._obs.hc_streams.set(self.stats.hc_streams)
+            self._obs.hc_sublayers_a_step.set(self.stats.hc_sublayers_a_step)
             if self._alloc is not None:
                 # a fresh paged server must scrape as fully free, not as
                 # exhausted (the gauge default 0)
@@ -2452,6 +2472,17 @@ class ContinuousEngine:
         with self._lock:
             queued = len(self._queue) + len(self._remote_inbox)
         return sum(not s.free for s in self._pool) + queued
+
+    def decode_program_text(self) -> str:
+        """The compiled text of ``step_once``'s ONE program at this engine's
+        shapes. A capture names a device op by its instruction there, and an
+        instruction's ``op_name`` carries the ``jax.named_scope``s it was
+        traced under (``obs/spans.SCOPE_*``), which the capture does not:
+        this is how a trace reader tells a layer's ops by what they are.
+        Compiles, or reads the compile cache: not for a measured window."""
+        staged = self.jnp.zeros((self.slots, self._blk_cols), self.jnp.int32)
+        return self._decode.lower(self.params, self.cache, self._picked,
+                                  staged).compile().as_text()
 
     def step_once(self, quiet: bool = True) -> int:
         """Admit queued requests, land ONE device step over the pool, and

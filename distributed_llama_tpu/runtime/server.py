@@ -1299,7 +1299,13 @@ class InferenceServer:
                      if st.window_bytes else
                      f"; state {st.state_bytes / 2**20:.0f} MiB resident, "
                      f"smallest normaliser {st.min_normaliser:.3g}"
-                     if st.state_bytes else ""),
+                     if st.state_bytes else
+                     f"; {st.hc_streams} residual streams mixed around "
+                     f"{st.hc_sublayers_a_step} sub-layers a step, "
+                     f"{st.latent_positions} cached positions read over "
+                     f"{st.latent_pages} pages in use at the end, "
+                     f"{st.moe_pairs} routed pairs"
+                     if st.hc_streams else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
                   sum_active=st.sum_active, steps_ahead=st.steps_ahead,
                   rows_dropped_ahead=st.rows_dropped_ahead,
@@ -1314,6 +1320,12 @@ class InferenceServer:
                   admit_stall_ms_per_chunk=st.admit_stall_ms_per_chunk,
                   plain_step_ms=st.plain_step_ms,
                   host_ms_per_step=st.host_ms_per_step,
+                  hc_streams=st.hc_streams,
+                  hc_sublayers_a_step=st.hc_sublayers_a_step,
+                  latent_positions=st.latent_positions,
+                  latent_pages=st.latent_pages, moe_pairs=st.moe_pairs,
+                  moe_local_pairs=st.moe_local_pairs,
+                  moe_active=st.moe_active,
                   state_bytes=st.state_bytes,
                   window_bytes=st.window_bytes,
                   shared_kv_pages=st.shared_kv_pages,

@@ -52,6 +52,13 @@ LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
           "ph-in_proj": (10240, 2560), "ph-out_proj": (2560, 5120),
           "ph-wqkv": (5120, 2560), "ph-wo": (2560, 2560),
           "ph-w13": (20480, 2560), "ph-w2": (2560, 10240),
+          # Xing4.0-29B-A4B's (dim 3584: 112 blocks a row; q rank 768: 24;
+          # dense FFN 9216: 288; shared expert 1024: 32; wo's 4096: 128)
+          "x4-wq_a": (768, 3584), "x4-wq_b": (6144, 768),
+          "x4-wkv_a": (640, 3584), "x4-wo": (3584, 4096),
+          "x4-w13": (18432, 3584), "x4-w2": (3584, 9216),
+          "x4-sh_w13": (2048, 3584), "x4-sh_w2": (3584, 1024),
+          "x4-wcls": (131072, 3584),
           # Mistral-7B's fused wqkv; Yi-34B's tp-4 shards (dim 7168: wo's
           # shard has 56 blocks a row, w2's 160; a KV projection 256 rows);
           # Brumby-14B's classifier (151,936 rows, whose only row tile is
@@ -207,9 +214,11 @@ def _moe(leaf: str, rows: int, model: str = "olmoe"):
     row list; ``w2``: each slot's own rows)."""
     from distributed_llama_tpu.ops import pallas_moe as pm
 
-    n_exp, k = (64, 8) if model == "olmoe" else (32, 8)
+    n_exp, k = {"olmoe": (64, 8), "ds": (32, 8), "x4": (64, 4)}[model]
     d, n = {"olmoe": {"w13": (2048, 2048), "w2": (2048, 1024)},
-            "ds": {"w13": (4096, 7168), "w2": (7168, 2048)}}[model][leaf]
+            "ds": {"w13": (4096, 7168), "w2": (7168, 2048)},
+            # Xing4.0's: 64 experts of width 1024 on dim 3584, 4 a row
+            "x4": {"w13": (2048, 3584), "w2": (3584, 1024)}}[model][leaf]
     nb = n // 32
     qs_t = _sd((2, n_exp, 16, nb, d), jnp.uint8)
     scale = _sd((2, n_exp, nb, d), jnp.float32)
@@ -222,6 +231,18 @@ def _moe(leaf: str, rows: int, model: str = "olmoe"):
           (_sd((a, cap, n), jnp.float32),))
     return fn, (_sd((1,), jnp.int32), _sd((a,), jnp.int32),
                 _sd((), jnp.int32), _sd((a,), jnp.int32), qs_t, scale, *xs)
+
+
+def _hc_coefficients(rows: int):
+    """The residual path's coefficient stage (ops/hyper: plain XLA, no
+    kernel) at Xing4.0's widths: 4 streams of 3584, a decode step's 32
+    rows, one row, a chunk's 128."""
+    from distributed_llama_tpu.models.spec import HyperConnections
+    from distributed_llama_tpu.ops.hyper import coefficients
+
+    fn = functools.partial(coefficients, HyperConnections(4), 1e-6)
+    return fn, (_sd((24, 4 * 3584), jnp.float32), _sd((3,), jnp.float32),
+                _sd((24,), jnp.float32), _sd((4, rows, 3584), jnp.float32))
 
 
 def _q40_wide_w2():
@@ -403,6 +424,25 @@ CASES = {
        (functools.partial(_moe, leaf, rows, "ds"), True)
        for kind, rows in (("slots", 32), ("slots", 16), ("slots", 1),
                           ("wide", 128))
+       for leaf in ("w13", "w2")},
+    # Xing4.0-29B-A4B (PR 39): the residual path's coefficient stage (flat
+    # norm, HIGHEST projection (24, 14336) x (14336, rows), sigmoids and 20
+    # unrolled Sinkhorn rounds on (4, rows) tiles; XLA, no custom call) at a
+    # step's rows, one row and a chunk's; its leaves (input widths 768, 3584, 9216 and 1024 that
+    # no cell compiled before) at the cell's 32 rows, and the rest where a
+    # shape is new at that count; its experts (2.0 rows an expert, 4 a row)
+    **{f"hc-coefficients-T{t}": (functools.partial(_hc_coefficients, t), False)
+       for t in (1, 32, 128)},
+    **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)
+       for leaf, ts in (("x4-wq_a", (1, 32, 128)), ("x4-wq_b", (1, 32, 128)),
+                        ("x4-wkv_a", (32,)), ("x4-wo", (32,)),
+                        ("x4-w13", (32,)), ("x4-w2", (1, 32, 128)),
+                        ("x4-sh_w13", (32,)), ("x4-sh_w2", (1, 32, 128)),
+                        ("x4-wcls", (32,)))
+       for t in ts},
+    **{f"moe-x4-{kind}-{leaf}-T{rows}":
+       (functools.partial(_moe, leaf, rows, "x4"), True)
+       for kind, rows in (("slots", 32), ("slots", 1), ("wide", 128))
        for leaf in ("w13", "w2")},
 }
 
