@@ -11,13 +11,44 @@ order, copied the whole pool and every ring around each kernel call (the
 described-chip compile of the 32-row step showed 8.2 GiB of temporaries,
 PR 37). Head-major, the two minor dims are (positions, 128): whole tiles
 whatever the head count, the default layout is the one the kernels take, a
-page is one contiguous block, and the scores of a chunk lie (n_kv, chunk)
-with the positions along lanes.
+page is one contiguous block, and the scores of a chunk lie (group, chunk)
+a KV head with the positions along lanes.
 
-Both kernels walk a row's live chunks (or pages) with
+Both kernels walk a row's live positions a CHUNK at a time with
 ``pallas_attention._flash_walk``'s double-buffered DMA loop and keep the
-running (m, l, o) of each query head of a group; scores are scaled by
-1 / sqrt(hs). Every position up to a row's ``last`` is attended.
+running (m, l, o) of every query head; scores are scaled by 1 / sqrt(hs).
+Every position up to a row's ``last`` is attended.
+
+How a chunk is chosen (from the call's shapes alone: no flag, no argument).
+A plane is cut in the largest chunk of 512 positions or fewer that divides
+it and leaves AT LEAST FOUR a plane (``_chunk``: 128 for a 512-slot ring,
+512 for a sequence's 8,704): a walk is the first chunk's copy, then copies
+hidden behind folds, then the last chunk's fold, so the fewer chunks a
+plane has the more of it is exposed (a 512-slot ring alone on the chip, of
+the HBM roofline: one chunk of 512 58 %, two of 256 66 %, four of 128 70 %;
+the copies alone 78 %; PERF.md section 7). A page is smaller than the
+fold's tile (16 positions of the MXU's 128), so the paged kernel lands
+``_pages_a_turn`` pages a turn side by side in one (n_kv, G x page_size,
+hs) slot, G the pages that make 128 positions (8 at 16 a page: 81 % of the
+roofline at a depth of 1,700, where 4 read 75, 16 77 and 32 68). A chunk's
+pages past the row's last live one are that last live page copied again:
+the table's entries past it are never read, what a slot holds is always the
+row's own finite K / V, and their positions are masked (a masked position's
+weight is exactly 0, and 0 x NaN would be NaN).
+
+How a chunk is folded (``_fold``): both contractions on the MXU, K and V
+read once for all the query heads of a group. The configuration's
+precision is float32, so every product keeps all 24 bits of both operands:
+each operand is cut in three pieces that ARE bf16 numbers
+(``pallas_q40._mask_pieces``: a float32 is their sum exactly), the small
+operand's pieces (the queries, cut once a call outside the kernel; the
+chunk's weights p) are stacked along rows against each piece of K or V, so
+three dots give all NINE piece products (Precision.HIGHEST keeps six), each
+exact in the MXU's float32 accumulator, and the nine slabs are added the
+small ones first. The pieces stay float32 and the dots run at DEFAULT
+precision, Mosaic's one bf16 pass, which rounds an operand that is a bf16
+number already to itself (PERF.md section 7 has the candidates' times).
+Max, exp and the sums stay on the vector unit, on (n_kv, group, chunk).
 """
 
 from __future__ import annotations
@@ -30,83 +61,107 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_attention import _VMEM_BUDGET, NEG_INF, _flash_walk
-from .pallas_q40 import _VMEM64_PARAMS
+from .pallas_q40 import _VMEM64_PARAMS, _mask_pieces
 
 ROWS_KERNEL = "hm_attn_rows_decode"
 PAGED_KERNEL = "hm_attn_paged_decode"
+_TILE = 128         # positions the MXU holds still at once: the fold's tile
 
 
-def _fold(q, k, v, valid, carry, kv_mul: int):
-    """One landed chunk into the carry. q (n_kv, kv_mul, hs); k, v (n_kv,
-    C, hs); valid (n_kv, C); carry: per query head of a group (m (n_kv, 1),
-    l (n_kv, 1), o (n_kv, hs))."""
-    scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
-    out = []
-    for i in range(kv_mul):
-        m_old, l_old, o_old = carry[i]
-        s = jnp.sum(k * q[:, i, :][:, None, :], axis=-1) * scale
-        s = jnp.where(valid, s, NEG_INF)                    # (n_kv, C)
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_old - m_new)
-        l_new = l_old * corr + jnp.sum(p, axis=1, keepdims=True)
-        o_new = o_old * corr + jnp.sum(p[:, :, None] * v, axis=1)
-        out.append((m_new, l_new, o_new))
-    return tuple(out)
+def _group_rows(kv_mul: int) -> int:
+    """Rows a group's query heads take in the stacked pieces: ``kv_mul``
+    rounded up to float32's 8-row sublane tile, so each piece's slab is
+    whole vector registers."""
+    return -(-kv_mul // 8) * 8
 
 
-def _init(n_kv: int, hs: int, kv_mul: int):
-    return tuple((jnp.full((n_kv, 1), NEG_INF, jnp.float32),
-                  jnp.zeros((n_kv, 1), jnp.float32),
-                  jnp.zeros((n_kv, hs), jnp.float32))
-                 for _ in range(kv_mul))
+def _stack3(x):
+    """(..., R, K) float32 -> (..., 3 R, K): the three bf16 pieces of ``x``
+    along the rows, [hi; mid; lo]."""
+    return jnp.concatenate(_mask_pieces(x, 3), axis=-2)
 
 
-def _write_out(final, out_ref, kv_mul: int):
-    for i in range(kv_mul):
-        _, l_i, o_i = final[i]
-        out_ref[0, :, i, :] = o_i / l_i
+def _dot9(x3, w, contract: int):
+    """(n, R, N) float32: the product of x (n, R, K), handed over as its
+    stacked pieces ``x3`` (n, 3 R, K), and ``w`` over ``w``'s dim
+    ``contract`` (the other is N), batched over n, EXACT in every product:
+    one dot of x3 against each of w's three pieces gives the nine piece
+    products, each of two bf16 numbers, summed over K in float32; the slabs
+    are added in the order of their size, the smallest first."""
+    r = x3.shape[1] // 3
+    dn = (((2,), (contract,)), ((0,), (0,)))
+    hi, mid, lo = (jax.lax.dot_general(x3, p, dn,
+                                       preferred_element_type=jnp.float32)
+                   for p in _mask_pieces(w, 3))
+    at = lambda a, i: a[:, i * r:(i + 1) * r]               # noqa: E731
+    return ((((at(lo, 2) + (at(lo, 1) + at(mid, 2)))
+              + (at(lo, 0) + at(mid, 1) + at(hi, 2)))
+             + (at(mid, 0) + at(hi, 1))) + at(hi, 0))
 
 
-def _pair(k_src, v_src, k_buf, v_buf, sems, slot):
-    return (pltpu.make_async_copy(k_src, k_buf.at[slot], sems.at[slot, 0]),
-            pltpu.make_async_copy(v_src, v_buf.at[slot], sems.at[slot, 1]))
+def _fold(q3, k, v, valid, carry):
+    """One landed chunk into the carry. q3 (n_kv, 3 R, hs) the group's
+    queries in stacked pieces; k, v (n_kv, C, hs); valid (1, 1, C); carry
+    m, l (n_kv, R, 1) and o (n_kv, R, hs)."""
+    m_old, l_old, o_old = carry
+    scale = 1.0 / jnp.sqrt(jnp.float32(k.shape[-1]))
+    s = jnp.where(valid, _dot9(q3, k, 2) * scale, NEG_INF)  # (n_kv, R, C)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=2, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_old - m_new)
+    l_new = l_old * corr + jnp.sum(p, axis=2, keepdims=True)
+    o_new = o_old * corr + _dot9(_stack3(p), v, 1)
+    return m_new, l_new, o_new
 
 
-def _rows_kernel(layer_ref, last_ref, q_ref, k_hbm, v_hbm, out_ref, k_buf,
-                 v_buf, sems, *, chunk: int, kv_mul: int, batch: int):
+def _walk(n_chunks, copies, last, q3_ref, k_buf, v_buf, out_ref):
+    """Walk ``n_chunks`` chunks of a slot's size (``copies(slot, i)``: the
+    DMAs that land chunk i in k / v_buf[slot]) into a fresh carry, positions
+    past ``last`` masked, and write the group's heads out."""
+    q3 = q3_ref[0]
+    n_kv, r3, hs = q3.shape
+    rows, kv_mul, chunk = r3 // 3, out_ref.shape[2], k_buf.shape[2]
+    init = (jnp.full((n_kv, rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((n_kv, rows, 1), jnp.float32),
+            jnp.zeros((n_kv, rows, hs), jnp.float32))
+
+    def update(i, slot, carry):
+        pos = i * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, chunk),
+                                                   2)
+        return _fold(q3, k_buf[slot].astype(jnp.float32),
+                     v_buf[slot].astype(jnp.float32), pos <= last, carry)
+
+    _, l_fin, o_fin = _flash_walk(
+        n_chunks, lambda s, i: [c.start() for c in copies(s, i)],
+        lambda s, i: [c.wait() for c in copies(s, i)], update, init)
+    out_ref[0] = (o_fin / l_fin)[:, :kv_mul]
+
+
+def _rows_kernel(layer_ref, last_ref, q3_ref, k_hbm, v_hbm, out_ref, k_buf,
+                 v_buf, sems, *, chunk: int, batch: int):
     """grid=(B,): program b walks the live chunks of plane layer * B + b.
-    q_ref / out_ref (1, n_kv, kv_mul, hs); k / v_hbm (rows, n_kv, S, hs);
-    k / v_buf (2, n_kv, chunk, hs)."""
+    q3_ref (1, n_kv, 3 R, hs); out_ref (1, n_kv, kv_mul, hs); k / v_hbm
+    (rows, n_kv, S, hs); k / v_buf (2, n_kv, chunk, hs)."""
     b = pl.program_id(0)
     row, last = layer_ref[0] * batch + b, last_ref[b]
-    q = q_ref[0]
-    n_kv, _, hs = q.shape
 
     def copies(slot, i):
         at = pl.ds(i * chunk, chunk)
-        return _pair(k_hbm.at[row, :, at], v_hbm.at[row, :, at], k_buf,
-                     v_buf, sems, slot)
+        return (pltpu.make_async_copy(k_hbm.at[row, :, at], k_buf.at[slot],
+                                      sems.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[row, :, at], v_buf.at[slot],
+                                      sems.at[slot, 1]))
 
-    def update(i, slot, carry):
-        pos = i * chunk + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (n_kv, chunk), 1)
-        return _fold(q, k_buf[slot].astype(jnp.float32),
-                     v_buf[slot].astype(jnp.float32), pos <= last, carry,
-                     kv_mul)
-
-    final = _flash_walk(
-        last // chunk + 1, lambda s, i: [c.start() for c in copies(s, i)],
-        lambda s, i: [c.wait() for c in copies(s, i)], update,
-        _init(n_kv, hs, kv_mul))
-    _write_out(final, out_ref, kv_mul)
+    _walk(last // chunk + 1, copies, last, q3_ref, k_buf, v_buf, out_ref)
 
 
 def _chunk(seq_len: int, n_kv: int, hs: int, itemsize: int) -> int | None:
-    """The largest chunk of positions that divides ``seq_len`` with both
-    slots of K and V inside the scratch budget."""
+    """The largest chunk of positions that divides ``seq_len``, leaves at
+    least four a plane (copies behind folds; a plane under 32 is cut in
+    eights) and has both slots of K and V inside the scratch budget."""
     for c in (512, 256, 128, 64, 32, 16, 8):
-        if seq_len % c == 0 and 4 * c * n_kv * hs * itemsize <= _VMEM_BUDGET:
+        if (seq_len % c == 0 and (seq_len >= 4 * c or c == 8)
+                and 4 * c * n_kv * hs * itemsize <= _VMEM_BUDGET):
             return c
     return None
 
@@ -115,6 +170,37 @@ def supports(seq_len: int, n_kv: int, head_size: int,
              itemsize: int = 4) -> bool:
     return head_size % 128 == 0 and _chunk(seq_len, n_kv, head_size,
                                            itemsize) is not None
+
+
+def _call(kernel, name, B, n_kv, kv_mul, hs, slot, dtype, interpret):
+    """The ``pallas_call`` both kernels share: two scalar operands in SMEM,
+    the stacked queries a row, K and V left in HBM; two slots of ``slot``
+    positions each for K and for V."""
+    rows3 = 3 * _group_rows(kv_mul)
+    return pl.pallas_call(
+        kernel, grid=(B,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, n_kv, rows3, hs), lambda b: (b, 0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, n_kv, kv_mul, hs),
+                               lambda b: (b, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, kv_mul, hs), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, n_kv, slot, hs), dtype),
+                        pltpu.VMEM((2, n_kv, slot, hs), dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))],
+        compiler_params=_VMEM64_PARAMS, interpret=interpret, name=name)
+
+
+def _stacked_queries(q, n_kv: int, kv_mul: int, hs: int):
+    """q (B, n_kv * kv_mul [x] hs) -> (B, n_kv, 3 R, hs): each group's heads
+    padded to R rows (zeros: their scores are 0 and nothing reads them) and
+    cut in pieces, once a call."""
+    qg = q.reshape(q.shape[0], n_kv, kv_mul, hs).astype(jnp.float32)
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, _group_rows(kv_mul) - kv_mul),
+                      (0, 0)))
+    return _stack3(qg)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_mul", "interpret"))
@@ -130,60 +216,51 @@ def rows_decode_attention(q, k4, v4, layer, last, *, kv_mul: int,
     if chunk is None:
         raise ValueError(f"no chunking of S={S} fits VMEM at n_kv={n_kv}, "
                          f"hs={hs} (gate with supports())")
-    block = pl.BlockSpec((1, n_kv, kv_mul, hs), lambda b: (b, 0, 0, 0))
-    out = pl.pallas_call(
-        functools.partial(_rows_kernel, chunk=chunk, kv_mul=kv_mul, batch=B),
-        grid=(B,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM), block,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=block,
-        out_shape=jax.ShapeDtypeStruct((B, n_kv, kv_mul, hs), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((2, n_kv, chunk, hs), k4.dtype),
-                        pltpu.VMEM((2, n_kv, chunk, hs), k4.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2))],
-        compiler_params=_VMEM64_PARAMS, interpret=interpret,
-        name=ROWS_KERNEL,
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.broadcast_to(jnp.asarray(last, jnp.int32), (B,)),
-      q.reshape(B, n_kv, kv_mul, hs).astype(jnp.float32), k4, v4)
+    out = _call(functools.partial(_rows_kernel, chunk=chunk, batch=B),
+                ROWS_KERNEL, B, n_kv, kv_mul, hs, chunk, k4.dtype, interpret)(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.broadcast_to(jnp.asarray(last, jnp.int32), (B,)),
+        _stacked_queries(q, n_kv, kv_mul, hs), k4, v4)
     return out.reshape(B, -1)
 
 
-def _paged_kernel(pos_ref, table_ref, q_ref, k_hbm, v_hbm, out_ref, k_buf,
-                  v_buf, sems, *, page_size: int, kv_mul: int):
-    """grid=(B,): program b walks its live pages through the table. k /
-    v_hbm (P, n_kv, page_size, hs); k / v_buf (2, n_kv, page_size, hs)."""
+def _pages_a_turn(page_size: int) -> int:
+    """Pages landed side by side a loop turn: what makes the fold's tile."""
+    return max(1, _TILE // page_size)
+
+
+def _paged_kernel(pos_ref, table_ref, q3_ref, k_hbm, v_hbm, out_ref, k_buf,
+                  v_buf, sems, *, page_size: int, group: int):
+    """grid=(B,): program b walks its live pages through the table,
+    ``group`` a turn. k / v_hbm (P, n_kv, page_size, hs); k / v_buf (2,
+    n_kv, group * page_size, hs). A turn's copies share a semaphore a slot
+    and side (each wait takes its own copy's bytes off it)."""
     b = pl.program_id(0)
     last = jnp.minimum(pos_ref[b], table_ref.shape[1] * page_size - 1)
-    q = q_ref[0]
-    n_kv, _, hs = q.shape
+    last_page = last // page_size
 
     def copies(slot, i):
-        page = table_ref[b, i]
-        return _pair(k_hbm.at[page], v_hbm.at[page], k_buf, v_buf, sems,
-                     slot)
+        out = []
+        for g in range(group):
+            page = table_ref[b, jnp.minimum(i * group + g, last_page)]
+            at = pl.ds(g * page_size, page_size)
+            out += [pltpu.make_async_copy(k_hbm.at[page],
+                                          k_buf.at[slot, :, at],
+                                          sems.at[slot, 0]),
+                    pltpu.make_async_copy(v_hbm.at[page],
+                                          v_buf.at[slot, :, at],
+                                          sems.at[slot, 1])]
+        return out
 
-    def update(i, slot, carry):
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (n_kv, page_size), 1)
-        return _fold(q, k_buf[slot].astype(jnp.float32),
-                     v_buf[slot].astype(jnp.float32), pos <= last, carry,
-                     kv_mul)
-
-    final = _flash_walk(
-        last // page_size + 1,
-        lambda s, i: [c.start() for c in copies(s, i)],
-        lambda s, i: [c.wait() for c in copies(s, i)], update,
-        _init(n_kv, hs, kv_mul))
-    _write_out(final, out_ref, kv_mul)
+    _walk(last_page // group + 1, copies, last, q3_ref, k_buf, v_buf,
+          out_ref)
 
 
 def supports_paged(page_size: int, n_kv: int, head_size: int,
                    itemsize: int = 4) -> bool:
     return (head_size % 128 == 0 and page_size % 8 == 0
-            and 4 * page_size * n_kv * head_size * itemsize <= _VMEM_BUDGET)
+            and 4 * _pages_a_turn(page_size) * page_size * n_kv * head_size
+            * itemsize <= _VMEM_BUDGET)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_mul", "interpret"))
@@ -196,21 +273,10 @@ def paged_decode_attention(q, k4, v4, pos, table, *, kv_mul: int,
         interpret = jax.default_backend() != "tpu"
     _, n_kv, ps, hs = k4.shape
     B = q.shape[0]
-    block = pl.BlockSpec((1, n_kv, kv_mul, hs), lambda b: (b, 0, 0, 0))
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=ps, kv_mul=kv_mul),
-        grid=(B,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM), block,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=block,
-        out_shape=jax.ShapeDtypeStruct((B, n_kv, kv_mul, hs), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((2, n_kv, ps, hs), k4.dtype),
-                        pltpu.VMEM((2, n_kv, ps, hs), k4.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2))],
-        compiler_params=_VMEM64_PARAMS, interpret=interpret,
-        name=PAGED_KERNEL,
-    )(jnp.asarray(pos, jnp.int32).reshape(B), jnp.asarray(table, jnp.int32),
-      q.reshape(B, n_kv, kv_mul, hs).astype(jnp.float32), k4, v4)
+    group = _pages_a_turn(ps)
+    out = _call(functools.partial(_paged_kernel, page_size=ps, group=group),
+                PAGED_KERNEL, B, n_kv, kv_mul, hs, group * ps, k4.dtype,
+                interpret)(
+        jnp.asarray(pos, jnp.int32).reshape(B), jnp.asarray(table, jnp.int32),
+        _stacked_queries(q, n_kv, kv_mul, hs), k4, v4)
     return out.reshape(B, -1)

@@ -306,10 +306,13 @@ def _mamba(kind: str):
 def _diff_attention(kind: str):
     """Differential attention through the head-major softmax kernels
     (ops/pallas_head_major_attention) at Phi-4-mini-flash's widths: 40
-    padded query heads over 10 KV pairs of 128, 32 rows. ``window``: eight
-    layers' rings of 512 slots; ``rows``: one sequence's contiguous K / V
-    of 8,704 positions (``inference``); ``paged``: ONE layer's pool, 544
-    pages of 16 a row."""
+    padded query heads over 10 KV pairs of 128, 32 rows, the fold's two
+    contractions on the MXU in float32 pieces (PR 40). One case a chunk the
+    kernels pick from the shape: ``window``: eight layers' rings of 512
+    slots (four chunks of 128); ``rows``: one sequence's contiguous K / V of
+    8,704 positions (``inference``: chunks of 512); ``paged``: ONE layer's
+    pool, 544 pages of 16 a row (eight a turn); ``paged32``: the same pool
+    in pages of 32 (four a turn; no cell runs it)."""
     from distributed_llama_tpu.ops import pallas_head_major_attention as hm
 
     b, n_q, n_kv, hs = 32, 40, 10, 128
@@ -320,11 +323,12 @@ def _diff_attention(kind: str):
                                   interpret=False),
                 (_sd((b, n_q, hs), jnp.float32), ring, ring,
                  _sd((), jnp.int32), _sd((b,), jnp.int32)))
-    pool = _sd((17153, n_kv, 16, hs), jnp.float32)
+    ps = 32 if kind == "paged32" else 16
+    pool = _sd((17152 * 16 // ps + 1, n_kv, ps, hs), jnp.float32)
     return (functools.partial(hm.paged_decode_attention, kv_mul=4,
                               interpret=False),
             (_sd((b, n_q, hs), jnp.float32), pool, pool,
-             _sd((b,), jnp.int32), _sd((b, 8704 // 16), jnp.int32)))
+             _sd((b,), jnp.int32), _sd((b, 8704 // ps), jnp.int32)))
 
 
 # kernel=False: the dispatch documents an XLA dequantize-then-dot route for
@@ -401,6 +405,8 @@ CASES = {
     "diff-rows-S8704-B1": (functools.partial(_diff_attention, "rows"),
                            True),
     "diff-paged-ps16-B32": (functools.partial(_diff_attention, "paged"),
+                            True),
+    "diff-paged-ps32-B32": (functools.partial(_diff_attention, "paged32"),
                             True),
     **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)
        for leaf in ("ph-in_proj", "ph-out_proj", "ph-wqkv", "ph-wo",

@@ -258,40 +258,150 @@ def test_differential_attention_against_two_softmaxes(tree, window):
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-4
 
 
-@pytest.mark.parametrize("kind", ["rows", "paged"])
-def test_head_major_decode_kernels_against_the_einsum(kind):
+def _hm_inputs(rng, kind, B, S, ps, scale_k=1.0, n_kv=10, kv_mul=4, hs=128,
+               nan_past=None):
+    """Queries, what the kernel is handed (``rows``: a second layer's planes
+    and its index; ``paged``: a pool and a table of ``S // ps`` pages a row)
+    and each row's (n_kv, S, hs) K / V as it should see them. ``nan_past``
+    (each row's last position): every page of the pool but a row's live
+    ones, the rest of its table included, is NaN."""
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    q = f(B, n_kv * kv_mul, hs)
+    if kind == "rows":
+        k, v = f(2 * B, n_kv, S, hs) * scale_k, f(2 * B, n_kv, S, hs)
+        return q, (jnp.asarray(k), jnp.asarray(v), 1), k[B:], v[B:]
+    per_row = S // ps
+    n_pool = 2 * B * per_row + 1
+    pool_k, pool_v = f(n_pool, n_kv, ps, hs) * scale_k, f(n_pool, n_kv, ps, hs)
+    table = (rng.permutation(n_pool - 1)[:B * per_row] + 1).reshape(
+        B, per_row).astype(np.int32)
+    gather = lambda pool: np.swapaxes(  # noqa: E731
+        pool[table], 1, 2).reshape(B, n_kv, S, hs)
+    k_c, v_c = gather(pool_k), gather(pool_v)
+    if nan_past is not None:
+        live = np.zeros(n_pool, bool)
+        for b, last in enumerate(nan_past):
+            live[table[b, :last // ps + 1]] = True
+        pool_k[~live] = pool_v[~live] = np.nan
+    return q, (jnp.asarray(pool_k), jnp.asarray(pool_v),
+               jnp.asarray(table)), k_c, v_c
+
+
+def _hm_run(kind, q, held, last, kv_mul):
+    from distributed_llama_tpu.ops import pallas_head_major_attention as hm
+
+    if kind == "rows":
+        return hm.rows_decode_attention(jnp.asarray(q), *held, last,
+                                        kv_mul=kv_mul)
+    return hm.paged_decode_attention(jnp.asarray(q), held[0], held[1], last,
+                                     held[2], kv_mul=kv_mul)
+
+
+def _attention64(q, k, v, last, kv_mul):
+    """float64 grouped attention: q (B, n_q, hs), k / v (B, n_kv, S, hs),
+    positions 0 .. last[b]; ``q``, ``k``, ``v`` are taken as given (round
+    them first for a low-precision control)."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    out = np.zeros(q.shape)
+    for b in range(q.shape[0]):
+        n = int(last[b]) + 1
+        for h in range(q.shape[1]):
+            s = k[b, h // kv_mul, :n] @ q[b, h] / np.sqrt(q.shape[-1])
+            w = np.exp(s - s.max())
+            out[b, h] = (w / w.sum()) @ v[b, h // kv_mul, :n]
+    return out.reshape(q.shape[0], -1)
+
+
+# kind, S, page, each row's last position. Pages of 16 make a chunk of 128
+# (eight a turn); a 512-slot ring walks four chunks of 128
+_HM_CASES = {
+    "rows": ("rows", 48, None, [0, 17, 47]),
+    "paged": ("paged", 48, 8, [0, 17, 47]),
+    "paged16-depth-0": ("paged", 320, 16, [0, 77]),
+    "paged16-one-under-a-chunk": ("paged", 320, 16, [126, 254]),
+    "paged16-exactly-a-chunk": ("paged", 320, 16, [127, 255]),
+    "paged16-one-over-a-chunk": ("paged", 320, 16, [128, 256]),
+    "paged16-partial-last-page": ("paged", 320, 16, [200, 137]),
+    "paged16-full-table": ("paged", 320, 16, [319, 303]),
+    "paged16-nan-in-unreferenced-pages": ("paged", 320, 16, [0, 130, 200]),
+    "ring512-under-the-edge": ("rows", 512, None, [126, 382]),
+    "ring512-at-the-edge": ("rows", 512, None, [127, 383]),
+    "ring512-past-the-edge": ("rows", 512, None, [128, 384]),
+    "ring512-full": ("rows", 512, None, [511, 510]),
+}
+
+
+@pytest.mark.parametrize("case", list(_HM_CASES))
+def test_head_major_decode_kernels_against_the_einsum(case):
     """ops/pallas_head_major_attention (interpret mode) against
-    ``attention_core``: three rows at their own depths, ten KV heads of
-    128, four query heads a group; the second layer's planes of two."""
+    ``attention_core``: rows at their own depths, ten KV heads of 128, four
+    query heads a group; the second layer's planes of two. The depths sit
+    on, one under and one over a chunk's edge, in a partial page and at the
+    table's end; ``nan``: a row owns its live pages only and every other
+    page of the pool, the rest of its table included, is NaN: a chunk's
+    tail past the last live page must not reach the output."""
     from distributed_llama_tpu.models.llama import attention_core
     from distributed_llama_tpu.ops import pallas_head_major_attention as hm
 
+    kind, S, ps, depths = _HM_CASES[case]
     rng = np.random.default_rng(4)
-    B, n_kv, kv_mul, hs, S, ps = 3, 10, 4, 128, 48, 8
-    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa
-    q = f(B, n_kv * kv_mul, hs)
-    last = jnp.asarray([0, 17, 47], jnp.int32)
+    B, kv_mul = len(depths), 4
+    last = jnp.asarray(depths, jnp.int32)
+    q, held, k_c, v_c = _hm_inputs(
+        rng, kind, B, S, ps, nan_past=depths if "nan" in case else None)
+    got = _hm_run(kind, q, held, last, kv_mul)
     mask = jnp.arange(S)[None, None, :] <= last[:, None, None]
-    if kind == "rows":
-        k, v = f(2 * B, n_kv, S, hs), f(2 * B, n_kv, S, hs)
-        got = hm.rows_decode_attention(q, k, v, 1, last, kv_mul=kv_mul)
-        k_c, v_c = k[B:], v[B:]
-    else:
-        pool_k, pool_v = f(40, n_kv, ps, hs), f(40, n_kv, ps, hs)
-        table = jnp.asarray(rng.permutation(39)[:B * 6].reshape(B, 6) + 1,
-                            jnp.int32)
-        got = hm.paged_decode_attention(q, pool_k, pool_v, last, table,
-                                        kv_mul=kv_mul)
-        gather = lambda pool: jnp.swapaxes(  # noqa: E731
-            pool[table], 2, 3).reshape(B, S, n_kv, hs)
-        k_c, v_c = (jnp.swapaxes(gather(pool_k), 1, 2),
-                    jnp.swapaxes(gather(pool_v), 1, 2))
-    want = attention_core(hs, kv_mul, q.reshape(B, 1, n_kv * kv_mul, hs),
-                          jnp.swapaxes(k_c, 1, 2), jnp.swapaxes(v_c, 1, 2),
+    want = attention_core(128, kv_mul, jnp.asarray(q)[:, None],
+                          jnp.swapaxes(jnp.asarray(k_c), 1, 2),
+                          jnp.swapaxes(jnp.asarray(v_c), 1, 2),
                           mask).reshape(B, -1)
+    assert np.isfinite(np.asarray(got)).all()
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-4
     assert hm.supports(512, 10, 128) and hm.supports_paged(16, 10, 128)
     assert not hm.supports(512, 10, 64)
+
+
+def test_head_major_chunks_come_from_the_shapes():
+    """A ring is four chunks or more (copies behind folds), a turn's pages
+    make the fold's tile of 128 positions."""
+    from distributed_llama_tpu.ops import pallas_head_major_attention as hm
+
+    assert hm._chunk(512, 10, 128, 4) == 128
+    assert hm._chunk(8704, 10, 128, 4) == 512
+    assert hm._chunk(48, 10, 128, 4) == 8 and hm._chunk(8, 10, 128, 4) == 8
+    assert [hm._pages_a_turn(ps) for ps in (8, 16, 32, 128, 256)] == [
+        16, 8, 4, 1, 1]
+
+
+# max |kernel - float64| of the PARENT's fold (PR 39's tree, the vector
+# unit's multiply-and-reduce, interpret mode) on the same inputs, recorded
+# 2026-09-29: the MXU form may be no further than twice that
+_PARENT_D64 = {("rows", 1.0): 1.554e-7, ("rows", 30.0): 1.115e-5,
+               ("paged", 1.0): 1.147e-7, ("paged", 30.0): 1.758e-5}
+
+
+@pytest.mark.parametrize("kind,scale_k", list(_PARENT_D64))
+def test_head_major_fold_keeps_float32(kind, scale_k):
+    """Both kernels against a float64 attention, on standard-normal K and on
+    K of thirty times the norm (scores to +-1,000: one winner a row, where a
+    bf16 product moves the winner): no further from float64 than twice the
+    parent's vector-unit fold, and the same attention with every product's
+    operands rounded to bfloat16 is at least 100 times further: a fold that
+    is quietly three passes, or one, fails here."""
+    rng = np.random.default_rng(40)
+    depths, kv_mul = [300, 511], 4
+    S, ps = 512, 16
+    q, held, k_c, v_c = _hm_inputs(rng, kind, len(depths), S, ps,
+                                   scale_k=scale_k)
+    got = _hm_run(kind, q, held, jnp.asarray(depths, jnp.int32), kv_mul)
+    want = _attention64(q, k_c, v_c, depths, kv_mul)
+    d = np.abs(np.asarray(got, np.float64) - want).max()
+    bf = lambda a: np.asarray(jax.lax.reduce_precision(  # noqa: E731
+        jnp.asarray(a), exponent_bits=8, mantissa_bits=7))
+    control = np.abs(_attention64(bf(q), bf(k_c), bf(v_c), depths, kv_mul)
+                     - want).max()
+    assert d <= 2 * _PARENT_D64[kind, scale_k], (d, _PARENT_D64)
+    assert control >= 100 * d, (control, d)
 
 
 def test_decode_through_the_kernels_matches_the_einsum_route(tree, tokens,
