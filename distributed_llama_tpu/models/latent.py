@@ -198,6 +198,71 @@ def attend(spec: TransformerSpec, q: jax.Array, plane: jax.Array,
                       preferred_element_type=jnp.float32)
 
 
+def chunk_attn_block(seq_len: int, t_len: int) -> int | None:
+    """The block in which a chunk of ``t_len`` queries walks the plane
+    (``attend_live``), from the call's shapes alone: T itself where it
+    divides ``seq_len`` (a chunk at pos = k T walks exactly k + 1 blocks),
+    else ``models/llama._pick_attn_block``'s. None is the whole plane
+    (``attend``): T <= 8, the rule of ``models/llama.attention``, or a
+    ``seq_len`` no block divides."""
+    from .llama import _pick_attn_block
+
+    if t_len <= 8:
+        return None
+    return t_len if seq_len % t_len == 0 else _pick_attn_block(seq_len)
+
+
+def chunk_walked_positions(seq_len: int, pos: int, t_len: int) -> int:
+    """Positions of the plane that the attention of a chunk of ``t_len``
+    rows at ``pos`` reads, a layer: the host's count of what the walk
+    does (``ContinuousStats.chunk_walked_positions``)."""
+    block = chunk_attn_block(seq_len, t_len)
+    if block is None:
+        return seq_len
+    return min(-(-(pos + t_len) // block) * block, seq_len)
+
+
+def attend_live(spec: TransformerSpec, q: jax.Array, plane: jax.Array,
+                pos: jax.Array, block: int) -> jax.Array:
+    """``attend`` for a chunk of ONE sequence, q (T, H, width) scaled at
+    positions pos .. pos + T - 1 against plane (S, width): a walk over the
+    blocks 0 .. (pos + T - 1) // block that a query of the chunk can see,
+    with a running (m, l, o) (``parallel.ring._lse_merge``). A block past
+    them is never read; a position inside them that no query sees weighs
+    exactly 0, as under ``attend``'s mask. The products are ``attend``'s
+    (float32, HIGHEST): only the order of the softmax's sums differs."""
+    from ..parallel.ring import _lse_merge
+
+    t_len, n_heads, _ = q.shape
+    rank = spec.latent.kv_rank
+    q_pos = pos + jnp.arange(t_len)
+    n_live = jnp.minimum((pos + t_len + block - 1) // block,
+                         plane.shape[0] // block)
+
+    def body(carry):
+        b, m, l, o = carry
+        blk = jax.lax.dynamic_slice_in_dim(plane, b * block, block,
+                                           0).astype(jnp.float32)
+        s = jnp.einsum("thw,sw->ths", q, blk, precision=HIGHEST,
+                       preferred_element_type=jnp.float32)
+        seen = (b * block + jnp.arange(block))[None, :] <= q_pos[:, None]
+        s = jnp.where(seen[:, None, :], s, -jnp.inf)
+        pm = jnp.max(s, axis=-1, keepdims=True)
+        # a row that sees nothing of this block: exp(-inf - 0) = 0
+        p = jnp.exp(s - jnp.where(jnp.isfinite(pm), pm, 0.0))
+        po = jnp.einsum("ths,sc->thc", p, blk[:, :rank], precision=HIGHEST,
+                        preferred_element_type=jnp.float32)
+        return (b + 1, *_lse_merge(m, l, o, pm,
+                                   jnp.sum(p, axis=-1, keepdims=True), po))
+
+    init = (jnp.int32(0),
+            jnp.full((t_len, n_heads, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((t_len, n_heads, 1), jnp.float32),
+            jnp.zeros((t_len, n_heads, rank), jnp.float32))
+    _, _, l, o = jax.lax.while_loop(lambda c: c[0] < n_live, body, init)
+    return o / l        # every query sees position 0: l > 0
+
+
 def attention_out(spec: TransformerSpec, lw: dict[str, Any],
                   o_lat: jax.Array) -> jax.Array:
     """(R, H, kv_rank) -> (R, H * v_dim): each head's W_UV."""
@@ -253,21 +318,27 @@ def forward_latent(spec: TransformerSpec, params: dict[str, Any],
                    cache: LatentCache, tokens: jax.Array, pos: jax.Array, *,
                    moe_counts: bool = False):
     """``models/llama.forward`` for a latent spec: T tokens of ONE sequence
-    at positions pos..pos+T-1 against the contiguous (L, S, width) cache."""
+    at positions pos..pos+T-1 against the contiguous (L, S, width) cache.
+    A chunk (T > 8) attends the blocks up to pos + T only
+    (``attend_live``); a step scores the whole plane."""
     from .llama import causal_cache_mask
 
     t_len = tokens.shape[0]
     positions = pos + jnp.arange(t_len)
     with jax.named_scope(SCOPE_EMBED):
         x = fan_out(spec, params["tok_embedding"][tokens].astype(jnp.float32))
-    mask = causal_cache_mask(spec.seq_len, pos, t_len)
+    block = chunk_attn_block(spec.seq_len, t_len)
+    if block is None:
+        mask = causal_cache_mask(spec.seq_len, pos, t_len)
 
     def attend_layer(lw, x, layer, c_all):
         q, row = latent_qkv(spec, lw, x, positions)
         c_all = jax.lax.dynamic_update_slice(
             c_all, row[None].astype(c_all.dtype), (layer, pos, 0))
         plane = jax.lax.dynamic_index_in_dim(c_all, layer, 0, keepdims=False)
-        return attention_out(spec, lw, attend(spec, q, plane, mask)), c_all
+        o_lat = (attend(spec, q, plane, mask) if block is None
+                 else attend_live(spec, q, plane, pos, block))
+        return attention_out(spec, lw, o_lat), c_all
 
     (x, c_all), counts = _scan_layers(spec, params, (x, cache.c),
                                       attend_layer, moe_counts)

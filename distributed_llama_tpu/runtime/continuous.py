@@ -474,6 +474,12 @@ class ContinuousStats:
     # p + 1): what the latent decode kernel must move, a layer
     latent_pages: int = 0
     latent_positions: int = 0
+    # ... and of its admission chunks: the positions of the gathered plane
+    # a chunk's attention read, a layer (models/latent.attend_live walks
+    # the blocks up to start + T; a T <= 8 chunk reads them all), and the
+    # positions of the plane, both summed over chunks
+    chunk_walked_positions: int = 0
+    chunk_plane_positions: int = 0
     # a spec with several residual streams (ops/hyper.py): how many, and
     # the sub-layers a decode step mixes them around (two a layer); fixed
     # by the spec, 0 without streams
@@ -629,6 +635,12 @@ class ContinuousStats:
             (self.land_s - self.land_behind_admit_s)
             - (self.fetch_wait_s - self.fetch_wait_behind_admit_s)) / max(
                 self.steps - self.lands_behind_admit, 1)
+
+    @property
+    def chunk_walk_share(self) -> float:
+        """The share of the plane a latent spec's chunks walked."""
+        return self.chunk_walked_positions / max(self.chunk_plane_positions,
+                                                 1)
 
     @property
     def admission_clause(self) -> str:
@@ -3076,6 +3088,7 @@ class ContinuousEngine:
                 or BOS in tokens[1:]):
             s.prefill_pending = False
             return
+        from ..models.latent import chunk_walked_positions
         from .generate import run_chunked_prefill
 
         t0 = time.monotonic()  # census/ledger wall charges need it even
@@ -3129,6 +3142,11 @@ class ContinuousEngine:
                         *(jnp.int32(n) for n in n_valid))
                 if moe:  # read where the dispatch behind it lands
                     self._chunk_moe.append((len(part), moe[0]))
+                if self.spec.latent:
+                    self.stats.chunk_walked_positions += \
+                        chunk_walked_positions(self.spec.seq_len, start_pos,
+                                               len(part))
+                    self.stats.chunk_plane_positions += self.spec.seq_len
 
             # a retention spec's chunk says how many of its positions are
             # the prompt's (``valid``): a padded one must not reach a state

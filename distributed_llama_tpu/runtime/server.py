@@ -1305,7 +1305,9 @@ class InferenceServer:
                      f"{st.latent_positions} cached positions read over "
                      f"{st.latent_pages} pages in use at the end, "
                      f"{st.moe_pairs} routed pairs"
-                     if st.hc_streams else ""),
+                     if st.hc_streams else "")
+                  + (f"; chunks walked {st.chunk_walk_share:.1%} of the "
+                     f"plane" if st.chunk_plane_positions else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
                   sum_active=st.sum_active, steps_ahead=st.steps_ahead,
                   rows_dropped_ahead=st.rows_dropped_ahead,
@@ -1323,7 +1325,10 @@ class InferenceServer:
                   hc_streams=st.hc_streams,
                   hc_sublayers_a_step=st.hc_sublayers_a_step,
                   latent_positions=st.latent_positions,
-                  latent_pages=st.latent_pages, moe_pairs=st.moe_pairs,
+                  latent_pages=st.latent_pages,
+                  chunk_walked_positions=st.chunk_walked_positions,
+                  chunk_plane_positions=st.chunk_plane_positions,
+                  moe_pairs=st.moe_pairs,
                   moe_local_pairs=st.moe_local_pairs,
                   moe_active=st.moe_active,
                   state_bytes=st.state_bytes,

@@ -154,6 +154,35 @@ def test_bfloat16_products_fail_the_tolerance(tree, tokens, want):
     assert np.abs(np.asarray(got) - want[0][:24]).max() > 5 * TOL
 
 
+def test_a_chunk_of_four_streams_walks_its_live_blocks_only(tree, want,
+                                                            monkeypatch):
+    """The walk of tests/test_latent.py under the four streams (they are
+    mixed outside the attention): 16 rows at 16 of 64 walk two blocks of
+    16, agree with the whole plane and with the reference, and read
+    nothing of the NaN planted past them."""
+    from distributed_llama_tpu.models import latent
+
+    params, toks = params_to_device(tree, spec=SPEC), jnp.asarray(
+        np.random.default_rng(5).integers(3, SPEC.vocab_size, SEQ)[:32])
+    assert latent.chunk_walked_positions(SPEC.seq_len, 16, 16) == 32
+
+    def second_chunk(dead):
+        # traced anew each call: the whole-plane form is chosen at trace
+        step = jax.jit(lambda p, c, t, pos: forward(SPEC, p, c, t, pos))
+        _, cache = step(params, init_cache(SPEC), toks[:16], jnp.int32(0))
+        return step(params, latent.LatentCache(cache.c.at[:, 32:].set(dead)),
+                    toks[16:], jnp.int32(16))
+
+    walk, over_nan = second_chunk(0.0), second_chunk(jnp.nan)
+    monkeypatch.setattr(latent, "chunk_attn_block", lambda *_: None)
+    whole = second_chunk(0.0)
+    assert np.abs(np.asarray(walk[0]) - want[0][16:32]).max() < TOL
+    assert 0 < np.abs(np.asarray(walk[0] - whole[0])).max() < TOL / 10
+    assert np.array_equal(np.asarray(over_nan[0]), np.asarray(walk[0]))
+    assert np.array_equal(np.asarray(over_nan[1].c[:, :32]),
+                          np.asarray(walk[1].c[:, :32]))
+
+
 # -- the residual function ------------------------------------------------------
 
 def _plain_specs():

@@ -154,6 +154,78 @@ def test_a_stale_page_reused_by_another_sequence_decodes_as_a_fresh_one(
         assert np.abs(outs[0] - outs[1]).max() < 1e-6
 
 
+# -- a chunk walks its live blocks only ------------------------------------------
+
+WALK_SEQ = 192      # T = 16 divides it (the block is T); T = 20 does not (64)
+WALK_TOL = TOL / 10
+
+
+@pytest.fixture(scope="module", params=[128, 32])
+def walk_model(request):
+    """(spec, device params) at the two cells' head counts, toy widths."""
+    spec = toy_spec(n_heads=request.param, n_kv_heads=request.param,
+                    n_layers=2, seq_len=WALK_SEQ,
+                    latent=LatentAttn(64, 64, 16, 16, 16))
+    return spec, params_to_device(synth_params(spec, q40=True, seed=7),
+                                  spec=spec)
+
+
+def chunk_both_ways(spec, params, t_len, pos, monkeypatch, seed=0):
+    """One chunk of t_len rows at pos over a plane of seeded rows, through
+    the walk and through the whole-plane ``attend`` (the plain reference:
+    no block at all), once more with NaN in every block the walk must not
+    read. -> (walk, whole plane, walk over the NaN) as (logits, plane)."""
+    rng = np.random.default_rng(seed)
+    toks = jnp.asarray(rng.integers(3, spec.vocab_size, t_len), jnp.int32)
+    c = np.zeros(latent.init_cache(spec).c.shape, np.float32)
+    c[:, :, :spec.latent.width] = 0.5 * rng.standard_normal(
+        c[:, :, :spec.latent.width].shape)
+    walked = latent.chunk_walked_positions(spec.seq_len, pos, t_len)
+    dead = c.copy()
+    dead[:, walked:] = np.nan
+
+    def run(plane):
+        got, cache = jax.jit(lambda p, c, t, at: forward(spec, p, c, t, at))(
+            params, latent.LatentCache(jnp.asarray(plane)), toks,
+            jnp.int32(pos))
+        return np.asarray(got), np.asarray(cache.c)
+
+    walk, over_nan = run(c), run(dead)
+    monkeypatch.setattr(latent, "chunk_attn_block", lambda *_: None)
+    return walk, run(c), over_nan, walked
+
+
+@pytest.mark.parametrize("t_len,pos,blocks", [
+    (16, 0, 1), (16, 48, 4), (16, WALK_SEQ - 16, 12),     # the block is T
+    (20, 0, 1), (20, 70, 2), (20, WALK_SEQ - 20, 3)])     # the fallback, 64
+def test_a_chunk_attends_its_live_blocks_and_reads_no_other(
+        walk_model, t_len, pos, blocks, monkeypatch):
+    spec, params = walk_model
+    block = latent.chunk_attn_block(spec.seq_len, t_len)
+    assert block == (t_len if t_len == 16 else 64)
+    walk, whole, over_nan, walked = chunk_both_ways(
+        spec, params, t_len, pos, monkeypatch)
+    assert walked == blocks * block
+    # logits up to 3.8 wide, two layers deep: 2.5e-6 to 3.5e-6 apart, with
+    # ONE block as with twelve (the order of the softmax's sums, a dozen
+    # float32 ulps at that size), a tenth of what the reference is held to
+    assert 0 < np.abs(walk[0] - whole[0]).max() < WALK_TOL
+    assert np.abs(walk[1] - whole[1]).max() < WALK_TOL    # the rows written
+    # NaN past the walk reaches neither the logits nor a written row
+    assert np.array_equal(over_nan[0], walk[0])
+    assert np.array_equal(over_nan[1][:, :walked], walk[1][:, :walked])
+
+
+def test_a_step_keeps_the_whole_plane():
+    assert latent.chunk_attn_block(2048, 8) is None
+    assert latent.chunk_attn_block(2048, 128) == 128
+    assert latent.chunk_attn_block(2048, 100) == 512
+    assert latent.chunk_attn_block(100, 24) is None     # no block divides
+    assert latent.chunk_walked_positions(2048, 300, 1) == 2048
+    assert latent.chunk_walked_positions(2048, 256, 128) == 384
+    assert latent.chunk_walked_positions(2048, 2000, 100) == 2048
+
+
 # -- absorbed against expanded, and the published numbers -------------------
 
 def test_absorbed_attention_agrees_with_expanded(tree):
@@ -562,6 +634,96 @@ def test_engine_serves_with_prefix_sharing_and_counts_its_share(kernel_mode,
     assert reg.get("dllama_moe_single_row_slots_total").value == \
         st.moe_single_row_slots
     assert "dllama_latent_pages_in_use" in reg.expose()
+
+
+# -- chunks that walk, through both entries, and what the engine counts -------
+
+def _two_prompts():
+    rng = np.random.default_rng(23)     # no router near-tie inside either
+    return [[1] + [int(t) for t in rng.integers(3, 500, n)] for n in (39, 19)]
+
+
+def test_chunks_then_steps_through_serve_are_inferences_and_counted(tree):
+    """Two prompts of 40 and 20 tokens admitted in chunks of 16 (the block:
+    16 divides the 64 positions), then decode steps: every pick is the
+    reference's maximum, ``inference``'s logits after the same chunks agree
+    with the reference, and the counters add up what the chunks walked."""
+    from distributed_llama_tpu.runtime.continuous import (ContinuousEngine,
+                                                          Request)
+    from distributed_llama_tpu.runtime.generate import Engine
+
+    eng = ContinuousEngine(SPEC, tree, slots=2, temperature=0.0, topp=0.9,
+                           seed=3, page_size=8, prefill_chunk=16)
+    prompts = _two_prompts()
+    reqs = [eng.submit(Request(tokens=list(p), steps=len(p) + 8))
+            for p in prompts]
+    while eng.step_once():
+        pass
+    assert all(r.done.is_set() and r.error is None for r in reqs)
+    one = Engine(SPEC, tree)
+    for r, p in zip(reqs, prompts):
+        seq = [p[0]] + list(r.out)
+        ref, margins, _ = reference_latent.forward(tree, SPEC, seq[:-1])
+        low = np.nonzero(margins.min(axis=1) < MARGIN_EPS)[0]
+        stop = int(low[0]) if low.size else len(seq)
+        assert stop > len(p), "a near-tie inside the prompt: pick a seed"
+        one.reset()
+        one.prefill(p[:-1], chunk=16)
+        for pos in range(len(p) - 1, min(stop, len(seq) - 1)):
+            assert ref[pos].max() - ref[pos][seq[pos + 1]] < TOL
+            got = one.infer(seq[pos], pos)
+            assert np.abs(np.asarray(got) - ref[pos]).max() < TOL
+    st = eng.stats
+    # a prompt's last token goes through a step: 39 and 19 rows in chunks
+    starts = [0, 16, 32, 0, 16]
+    assert st.prefill_chunks == len(starts)
+    assert st.chunk_walked_positions == sum(s + 16 for s in starts) == 144
+    assert st.chunk_plane_positions == len(starts) * SPEC.seq_len
+    assert st.chunk_walk_share == 144 / 320
+
+
+def test_a_chunk_of_eight_or_fewer_counts_the_whole_plane(tree):
+    from distributed_llama_tpu.runtime.continuous import Request
+
+    eng = _engine()                 # prefill_chunk=8: today's whole plane
+    r = eng.submit(Request(tokens=_two_prompts()[1], steps=22))
+    while eng.step_once():
+        pass
+    assert r.error is None and eng.stats.prefill_chunks == 3
+    assert eng.stats.chunk_walk_share == 1.0
+
+
+def test_the_servers_summary_says_what_share_of_the_plane_chunks_walked(
+        tree, capsys):
+    import json
+    import urllib.request
+
+    from distributed_llama_tpu.runtime.server import InferenceServer
+
+    class Tok:
+        def encode(self, text, bos=True, eos=False):
+            return [1] + [3 + b for b in text.encode()]
+
+        def decode_piece(self, prev, tok):
+            return b"<%d>" % tok
+
+    srv = InferenceServer(SPEC, tree, Tok(), "127.0.0.1", 0, slots=2,
+                          steps=4, temperature=0.0, topp=0.9, seed=5,
+                          quiet=True, metrics=False, page_size=8,
+                          prefill_chunk=16)
+    srv.start()
+    try:
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.port}/generate",
+            data=json.dumps({"prompt": "a" * 35, "steps": 40}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as r:
+            assert json.loads(r.read())["tokens"]
+    finally:
+        srv.stop()
+    # 36 tokens: 35 rows in chunks at 0, 16 and 32 of 64 positions
+    assert srv.engine.stats.chunk_walked_positions == 16 + 32 + 48
+    assert "chunks walked 50.0% of the plane" in capsys.readouterr().err
 
 
 # -- the converter, on a toy dict of the published names ---------------------
