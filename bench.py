@@ -84,14 +84,14 @@ def _tree_shapes_cached(spec, rank_tp: int, build, build_sig: str = "",
 
     from distributed_llama_tpu.ops.linear import q40_kernel_mode
     from distributed_llama_tpu.ops.pallas_layer import fusion_cache_key
-    from distributed_llama_tpu.ops.pallas_q40 import _matvec_cap
+    from distributed_llama_tpu.ops.pallas_q40 import _TILE_ROWS_CAP
     from distributed_llama_tpu.utils.compile_cache import (cache_error,
                                                            default_cache_dir)
 
     # every knob that changes the packed tree's CONTENTS must be in the
     # key: layer fusion adds the wo_mega stack only in 'mega' mode
     # (prepare_mega_params), the kernel mode decides kernel-vs-codec
-    # layout, the matvec row cap feeds the layout picks, and builder
+    # layout, the tile-row cap feeds the layout picks, and builder
     # kwargs (e.g. the 70b rank tree's embed_dtype) change leaf
     # shapes/dtypes
     from distributed_llama_tpu.parallel.comm_stats import tp_scheme
@@ -100,7 +100,7 @@ def _tree_shapes_cached(spec, rank_tp: int, build, build_sig: str = "",
     # along the INPUT dim, so a warm ref-scheme manifest has wrong shapes
     key = hashlib.sha256(
         f"v4|{spec!r}|{rank_tp}|{q40_kernel_mode()}|{fusion_cache_key()}"
-        f"|{_matvec_cap()}|layout={layout_label}"
+        f"|{_TILE_ROWS_CAP}|layout={layout_label}"
         f"|tpscheme={tp_scheme()}|{build_sig}"
         .encode()).hexdigest()[:16]
     path = os.path.join(default_cache_dir(), "shapes", f"tree_{key}.pkl")
@@ -378,8 +378,7 @@ def _bench(spec, params, samples: int, per_step: bool = False,
 
     prof_dir = os.environ.get("DLLAMA_BENCH_PROFILE")
     if prof_dir:
-        # op-time attribution of ONE timed chain (the in-situ analog of
-        # tools/prefill_ladder's op-family split): per-token device op ms
+        # op-time attribution of ONE timed chain: per-token device op ms
         # by kernel family, printed to stderr next to the wall number.
         # Also derives the reference-shaped I/T split (utils.cpp:104-106,
         # README.md:50): I = device compute op time, T = collective op

@@ -17,7 +17,7 @@ kinds: dense (1 array), q40w (qs, d16), q40k (qs_t, scale),
        q40knb (qs_t, scale).
 
 The layout key captures everything that changes the packed tree's
-CONTENTS (kernel mode, matvec row cap, nb-major policy, fusion mode,
+CONTENTS (kernel mode, tile-row cap, nb-major policy, fusion mode,
 format version); a mismatch falls back to a rebuild, never to silently
 wrong layouts. DLLAMA_TILED_CACHE=0 disables both read and write.
 """
@@ -68,7 +68,7 @@ def layout_key(model_path: str | None = None, tp: int = 1,
     the old weights."""
     from ..ops.linear import q40_kernel_mode
     from ..ops.pallas_layer import fusion_cache_key
-    from ..ops.pallas_q40 import _matvec_cap
+    from ..ops.pallas_q40 import _TILE_ROWS_CAP
 
     # the layout's i4 chain body is deliberately NOT in this key: the
     # sidecar stores the host u8 tree either way (the conversion is
@@ -80,7 +80,7 @@ def layout_key(model_path: str | None = None, tp: int = 1,
     nbm = "force" if layout is not None and layout.force_nb_major else "auto"
     wf = getattr(weights_float_type, "name", weights_float_type) or "Q40"
     bf = getattr(buffer_float_type, "name", buffer_float_type) or "F32"
-    return (f"v1|{q40_kernel_mode()}|{_matvec_cap()}|{fusion_cache_key()}"
+    return (f"v1|{q40_kernel_mode()}|{_TILE_ROWS_CAP}|{fusion_cache_key()}"
             f"|nb={nbm}|tp={tp}|wf={wf}|bf={bf}{src}")
 
 

@@ -89,10 +89,10 @@ class Q40KernelNb(NamedTuple):
                 self.scale.shape[-2] * 32)
 
 
-class Q40KernelI4(NamedTuple):
-    """Signed-int4 plane form of ``Q40Kernel``: qs4 int4 (..., 32, d, nb)
+class Q40KernelNbI4(NamedTuple):
+    """Signed-int4 plane form of ``Q40KernelNb``: qs4 int4 (..., 32, nb, d)
     holding (code - 8) directly (range -8..7 fits int4 exactly — planes
-    0..15 are the low nibbles, 16..31 the high), scale f32 (..., d, nb).
+    0..15 are the low nibbles, 16..31 the high), scale f32 (..., nb, d).
 
     DEVICE-ONLY and chain-internal: this runtime cannot pass int4 arrays
     across a jit boundary (dispatch-layer recursion), so the fused decode
@@ -101,21 +101,10 @@ class Q40KernelI4(NamedTuple):
     stays the placed argument. Why it exists: the T=1 matvec body drops
     from ~9 to ~3 VPU ops per packed byte (no mask, no shift, one convert,
     no xsum correction) — measured 701 GB/s vs 638 on the 13B w13 shape
-    against a 746 GB/s DMA floor (tools/nb_probe.py).
+    against a 746 GB/s DMA floor (probe since deleted; runtime of round
+    5). A d-major leaf has no such form: its s4 body measured ~6x slower
+    than u8 (ops/pallas_q40.chain_weight_prep).
     """
-
-    qs4: np.ndarray
-    scale: np.ndarray
-
-    @property
-    def logical_shape(self) -> tuple[int, ...]:
-        return (*self.scale.shape[:-1], self.scale.shape[-1] * 32)
-
-
-class Q40KernelNbI4(NamedTuple):
-    """Signed-int4 plane form of ``Q40KernelNb``: qs4 int4 (..., 32, nb, d),
-    scale f32 (..., nb, d). See Q40KernelI4 for the why and the
-    device-only caveat."""
 
     qs4: np.ndarray
     scale: np.ndarray

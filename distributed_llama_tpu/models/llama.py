@@ -31,8 +31,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelNb,
-                         Q40KernelNbI4)
+from ..io.loader import Q40Kernel, Q40KernelNb, Q40KernelNbI4
 # the single-chip forward emits the SAME canonical trace scopes as the tp
 # forward (parallel/tp.py), so a --profile capture of either program
 # attributes through one obs/xprof.py vocabulary
@@ -412,7 +411,7 @@ def split_layer_weights(params: dict[str, Any]):
     keys = [k for k in LAYER_KEYS + FUSED_KEYS if k in params]
     stacked = {k: params[k] for k in keys
                if isinstance(params[k], (Q40Kernel, Q40KernelNb,
-                                         Q40KernelI4, Q40KernelNbI4))}
+                                         Q40KernelNbI4))}
     scanned = {k: params[k] for k in keys if k not in stacked}
     return stacked, scanned
 

@@ -170,11 +170,9 @@ def segments(kinds: tuple) -> list:
 
 
 def _is_packed(v) -> bool:
-    from ..io.loader import Q40Kernel, Q40KernelNb
-    from ..ops.linear import Q40KernelI4, Q40KernelNbI4
+    from ..io.loader import Q40Kernel, Q40KernelNb, Q40KernelNbI4
 
-    return isinstance(v, (Q40Kernel, Q40KernelNb, Q40KernelI4,
-                          Q40KernelNbI4))
+    return isinstance(v, (Q40Kernel, Q40KernelNb, Q40KernelNbI4))
 
 
 def _layer_weights(stack: dict, first: int, reps: int):

@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelNb, Q40KernelNbI4,
-                         Q40Weight, from_kernel_layout, to_kernel_layout,
+from ..io.loader import (Q40Kernel, Q40KernelNb, Q40KernelNbI4, Q40Weight,
+                         from_kernel_layout, to_kernel_layout,
                          to_kernel_layout_nb)
 from .quants import dequantize_q40_jax, dequantize_q80_jax, quantize_q80_jax
 
@@ -106,7 +106,7 @@ def dequantize_weight(w) -> jax.Array:
     """Materialize any weight representation as f32 (d, n)."""
     if isinstance(w, StackedQ40):
         w = jax.tree_util.tree_map(lambda a: a[w.layer], w.w)
-    if isinstance(w, (Q40KernelI4, Q40KernelNbI4)):
+    if isinstance(w, Q40KernelNbI4):
         from .pallas_q40 import _dequant_i4
 
         return _dequant_i4(w)
@@ -146,7 +146,7 @@ def matmul(w, x: jax.Array, *, prefer_pallas: bool = False) -> jax.Array:
         from .pallas_q40 import q40_matmul  # packing implies kernel support
 
         return q40_matmul(w.w, x, layer=w.layer)
-    if isinstance(w, (Q40KernelNb, Q40KernelI4, Q40KernelNbI4)):
+    if isinstance(w, (Q40KernelNb, Q40KernelNbI4)):
         from .pallas_q40 import q40_matmul  # dedicated dispatches
 
         return q40_matmul(w, x)

@@ -334,9 +334,9 @@ def decode_attention(q, k_all, v_all, layer, pos, *, kv_mul: int,
 # builds its flash partials from XLA einsums: every KV block materializes a
 # (T, n_q, block) score plane plus separate m/l/o merge traffic through HBM,
 # and the surrounding reshapes/transposes land in the profiler's layout
-# bucket (~38% of chunk-1920 op time is attention + glue + layout,
-# tools/prefill_floor.py). This kernel runs the whole online-softmax walk
-# in VMEM: grid over (kv head, q block), and per invocation an in-kernel
+# bucket (~38% of chunk-1920 op time is attention + glue + layout; probe
+# since deleted; runtime of round 5). This kernel runs the whole
+# online-softmax walk in VMEM: grid over (kv head, q block), and per invocation an in-kernel
 # double-buffered DMA loop (the decode kernel's machinery, _flash_over_row's
 # pattern) walks ONLY the live KV blocks. Scores never touch HBM; the causal
 # bound clamps the walk exactly like blockwise_chunk_partials' n_live.

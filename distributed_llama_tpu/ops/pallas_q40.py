@@ -36,54 +36,17 @@ with the XLA path is bit-tight at f32.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..io.loader import (Q40Kernel, Q40KernelI4, Q40KernelNb, Q40KernelNbI4,
-                         Q40Weight, to_kernel_layout)
+from ..io.loader import (Q40Kernel, Q40KernelNb, Q40KernelNbI4, Q40Weight,
+                         to_kernel_layout)
 
 QK = 32
 NJ = 16  # nibble positions per block byte-plane
-
-
-def _prefill_matmul_mode() -> str:
-    """T>8 (prefill-chunk) matmul strategy — DLLAMA_PREFILL_MATMUL:
-
-    * 'dequant': unpack the packed weight once per chunk into an HBM
-      bf16/f32 temp and run a plain XLA dot.
-    * 'scratch': d-outer grid, unpack-once-to-VMEM-scratch MXU kernel —
-      the packed tile is DMA'd and unpacked exactly once per chunk
-      (_matmul_body_scratch), but every x tile re-streams once per d tile.
-    * 'legacy': the original (t/bt, d/rows) grid, which re-fetches and
-      re-unpacks every weight tile t/bt times per chunk.
-    * 'auto' (default): 'dequant' under the bf16 fast-prefill precision,
-      'legacy' in f32 parity mode.
-
-    The arms are the prefill ladder (tools/prefill_ladder.py, VERDICT r2
-    #6). Measured on v5e at 7B (tok/s at chunk 480/960/1920): dequant
-    3255/4055/4487 beats scratch 2623/3685/3761 beats legacy
-    2408/3565/4249 in bf16 — the Pallas grids re-stream one of the two
-    operands t/bt or d/rows times, while XLA's dense dot tiles both ways
-    and the one-time dequant temp costs less than either re-stream. In f32
-    parity mode the dense path triples MXU passes (HIGHEST) on 4x the temp
-    bytes, so the packed kernel stays ahead there (BASELINE.md r3 ladder).
-    Read at trace time, like the precision contextvar — programs already
-    traced (an existing Engine's cached jits) keep the mode they were
-    traced with; construct a new Engine to change it. Unknown values
-    raise (a typo would otherwise silently run a slower path)."""
-    mode = os.environ.get("DLLAMA_PREFILL_MATMUL") or "auto"  # '' = unset
-    if mode not in ("auto", "dequant", "scratch", "legacy"):
-        raise ValueError(f"DLLAMA_PREFILL_MATMUL={mode!r}: "
-                         f"expected auto|dequant|scratch|legacy")
-    if mode == "auto":
-        from .linear import matmul_mode
-
-        return "dequant" if matmul_mode() == "bf16" else "legacy"
-    return mode
 
 
 def _matvec_body(qs3, s, xlo_ref, xhi_ref, xsum_ref, out_ref):
@@ -329,19 +292,20 @@ def _kernel_mxu_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
 # 2 rows over a layer's four leaves (by 8 %), so it went (PERF.md section 7).
 MULTI_T_MAX = 8
 
-# Raised scoped-VMEM limit for the T>1 kernels (MXU prefill bodies, the
-# unpack-once scratch kernels, and the T<=8 VPU multi bodies batched decode
-# uses): Mosaic's conservative stack accounting rejects several measured-fine
-# tile sets at the default 16 MB (e.g. 22.6M at w2's nb=344/bt=32 prefill
-# tile, 26.3M at the 13B B=2 multi tile) though v5e has 128 MB physical.
+# Raised scoped-VMEM limit for the T>1 kernels (MXU prefill bodies and the
+# T<=8 VPU multi bodies batched decode uses): Mosaic's conservative stack
+# accounting rejects several measured-fine tile sets at the default 16 MB
+# (e.g. 22.6M at w2's nb=344/bt=32 prefill tile, 26.3M at the 13B B=2 multi
+# tile) though v5e has 128 MB physical.
 # Same approach as ops/pallas_layer._VMEM_LIMIT.
 _VMEM64_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
 def to_i4_planes(tree):
-    """Re-express every Q40Kernel / Q40KernelNb leaf of a param tree (or a
-    single leaf) as its signed-int4 plane form. Jit-internal only — see
-    Q40KernelI4's device-only caveat."""
+    """Re-express every Q40KernelNb leaf of a param tree (or a single leaf)
+    as its signed-int4 plane form; every other leaf stays as it is (see
+    chain_weight_prep for why a d-major leaf has no such form). Jit-internal
+    only — see Q40KernelNbI4's device-only caveat."""
     def planes(qs_t):
         # cast each nibble plane to int4 BEFORE the concat: an int32
         # intermediate of the whole concat is 8x the packed bytes and
@@ -353,15 +317,11 @@ def to_i4_planes(tree):
         return jnp.concatenate([lo, hi], axis=-3)
 
     def conv(v):
-        # nb-major only in production (see chain_weight_prep); the d-major
-        # planes exist for tests/experiments via the single-leaf form
         if isinstance(v, Q40KernelNb):
             return Q40KernelNbI4(planes(v.qs_t), v.scale)
         return v
 
     if isinstance(tree, (Q40Kernel, Q40KernelNb)):
-        if isinstance(tree, Q40Kernel):
-            return Q40KernelI4(planes(tree.qs_t), tree.scale)
         return conv(tree)
     return {k: conv(v) for k, v in tree.items()}
 
@@ -374,48 +334,24 @@ def chain_weight_prep(params, i4: bool):
     (to_i4_planes); the T=1 matvec body then needs ONE convert + mul + add
     per plane instead of convert/mask/shift/2xconvert/2xmul/2xadd —
     measured 701 GB/s vs 638 on the 13B w13 shape, against a 746 GB/s DMA
-    floor (tools/nb_probe.py). Cost: the conversion pass (~0.06 ms/token
-    amortized over a 64-step chain) and TRANSIENT extra HBM for the i4
-    copy while the chain runs (~+50% of the codes' bytes; the u8 originals
-    remain the placed arguments: fine at 7B, OOMs 13B). Exact same
+    floor (probe since deleted; runtime of round 5). Cost: the conversion
+    pass (~0.06 ms/token amortized over a 64-step chain) and TRANSIENT
+    extra HBM for the i4 copy while the chain runs (~+50% of the codes'
+    bytes; the u8 originals remain the placed arguments: fine at 7B, OOMs
+    13B). Exact same
     integers — parity is bit-tight with the u8 bodies.
 
     NB-MAJOR LEAVES ONLY: the d-major s4 body measured ~6x SLOWER than u8
     on hardware (64 vs 10.3 ms/token at 7B — Mosaic's s4->f32 unpack on
-    (rows, nb) tiles is pathological; BASELINE.md r5). The single-leaf
-    to_i4_planes form still converts d-major for tests, so gate HERE."""
-    if not i4:
-        return params
-    return {k: to_i4_planes(v) if isinstance(v, Q40KernelNb) else v
-            for k, v in params.items()}
-
-
-def _matvec_body_i4(qs4, s, x32_ref, out_ref):
-    """T=1 d-major int4 body: qs4 (32, R, nb) signed planes (code-8
-    pre-applied), s (R, nb) f32, x32 (32, 1, nb) f32 plane-split inputs
-    (lo planes then hi). One convert + broadcast-mul + add per plane —
-    no mask, no shift, no xsum correction."""
-    acc = None
-    for j in range(2 * NJ):
-        w = qs4[j].astype(jnp.float32)               # (R, nb)
-        a = w * x32_ref[j]                           # (1, nb) bcast over R
-        acc = a if acc is None else acc + a
-    out_ref[...] = jnp.sum(acc * s, axis=1, keepdims=True)  # (R, 1)
-
-
-def _kernel_matvec_i4_stacked(layer_ref, qs_ref, scale_ref, x32_ref,
-                              out_ref):
-    del layer_ref  # consumed by the index maps
-    _matvec_body_i4(qs_ref[0], scale_ref[0], x32_ref, out_ref)
-
-
-def _kernel_matvec_i4(qs_ref, scale_ref, x32_ref, out_ref):
-    _matvec_body_i4(qs_ref, scale_ref[...], x32_ref, out_ref)
+    (rows, nb) tiles is pathological; BASELINE.md r5), so it went (PR 43)
+    and a d-major leaf passes through as it is."""
+    return to_i4_planes(params) if i4 else params
 
 
 def _matvec_body_nb_i4(qs4, s, x32_ref, out_ref):
     """T=1 nb-major int4 body: qs4 (32, nb, R), s (nb, R), x32 (32, nb, 1);
-    out (1, R). The tools/nb_probe.py 'i4' winner verbatim."""
+    out (1, R). The 'i4' winner of the nb-major body probe (probe since
+    deleted; runtime of round 5) verbatim."""
     acc = None
     for j in range(2 * NJ):
         w = qs4[j].astype(jnp.float32)               # (nb, R)
@@ -432,190 +368,6 @@ def _kernel_matvec_nb_i4_stacked(layer_ref, qs_ref, scale_ref, x32_ref,
 
 def _kernel_matvec_nb_i4(qs_ref, scale_ref, x32_ref, out_ref):
     _matvec_body_nb_i4(qs_ref, scale_ref[...], x32_ref, out_ref)
-
-
-def _multi_t_body() -> str:
-    """T in (2..MULTI_T_MAX) body — DLLAMA_MULTI_T_BODY:
-
-    * 'vpu' (default): the shared-unpack VPU accumulate body
-      (_matvec_body_multi). Exact f32 math; per-row MAC work scales with
-      T (the continuous-batching step-floor term, BASELINE.md r4:
-      23.9 ms of the 8-slot 31 ms op floor).
-    * 'dequant': one-dot MXU body (VERDICT r4 #6's "new formulation"):
-      unpack each weight tile ONCE into a flat (rows, 32*nb) bf16
-      scratch, then a single long dot (T, 32*nb) x (rows, 32*nb)^T —
-      per-row work rides the otherwise-idle MXU instead of the VPU.
-      bf16 multiply with f32 accumulation: a DOCUMENTED TOLERANCE on
-      batched decode logits (same contract as --fast-prefill), so it is
-      opt-in. Read at trace time.
-
-    Unknown values raise (a typo would silently run the default)."""
-    mode = os.environ.get("DLLAMA_MULTI_T_BODY") or "vpu"  # '' = unset
-    if mode not in ("vpu", "dequant"):
-        raise ValueError(f"DLLAMA_MULTI_T_BODY={mode!r}: "
-                         f"expected vpu|dequant")
-    return mode
-
-
-def _multi_body_dequant(qs3, s, xp_ref, out_ref, w_ref):
-    """T<=8 one-dot body: qs3 (NJ, R, nb) d-major codes, s (R, nb) f32,
-    xp (T, 32*nb) bf16 in PLANE order (xp[t, j*nb + b] = x[t, b*32 + j]
-    for j < 16, x[t, b*32 + j] for the hi planes at j-16 >= 0 shifted by
-    +16), w_ref (R, 32*nb) bf16 scratch; out (R, T) — R minor-most rides
-    the legal (8,128) block tiling (a (T, R) block with T=8 rows would
-    need R % 128, which small-d leaves can't give).
-
-    The VPU pays ~13 unpack ops/byte ONCE per tile (vs 5 + 4*T for the
-    accumulate body); the T-proportional MAC work becomes one MXU dot
-    with K = 32*nb — long enough to pipeline, M = T wasted rows accepted
-    (the MXU is idle in this phase anyway)."""
-    nb = s.shape[-1]
-    for j in range(NJ):
-        q = qs3[j].astype(jnp.int32)
-        w_ref[:, j * nb:(j + 1) * nb] = \
-            (((q & 0xF) - 8).astype(jnp.float32) * s).astype(jnp.bfloat16)
-        w_ref[:, (NJ + j) * nb:(NJ + j + 1) * nb] = \
-            (((q >> 4) - 8).astype(jnp.float32) * s).astype(jnp.bfloat16)
-    out_ref[...] = jax.lax.dot_general(
-        w_ref[...], xp_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def _kernel_multi_dequant(qs_ref, scale_ref, xp_ref, out_ref, w_ref):
-    _multi_body_dequant(qs_ref, scale_ref[...], xp_ref, out_ref, w_ref)
-
-
-def _kernel_multi_dequant_stacked(layer_ref, qs_ref, scale_ref, xp_ref,
-                                  out_ref, w_ref):
-    del layer_ref  # consumed by the index maps
-    _multi_body_dequant(qs_ref[0], scale_ref[0], xp_ref, out_ref, w_ref)
-
-
-def _x_planes(x: jax.Array, nb: int) -> jax.Array:
-    """(T, n) f32 -> (T, 32*nb) bf16 in the _multi_body_dequant plane
-    order (lo planes 0..15 then hi planes 16..31, each nb wide)."""
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)     # (NJ, T, nb)
-    xp = jnp.concatenate([xlo, xhi], axis=0)           # (32, T, nb)
-    t = x.shape[0]
-    return jnp.transpose(xp, (1, 0, 2)).reshape(t, 2 * NJ * nb) \
-        .astype(jnp.bfloat16)
-
-
-def _matmul_body_scratch(qs3, s, xlo_ref, xhi_ref, out_ref, wlo_ref, whi_ref,
-                         bf16=False, nb_major=False):
-    """T>8 MXU body, d-OUTER grid, unpack-once: grid is (d/rows, t/bt) with
-    the t tiles innermost, so each packed weight tile is DMA'd and unpacked
-    exactly ONCE (at ti == 0, into the wlo/whi VMEM scratch) and every t
-    tile dots against the resident unpacked planes.
-
-    The legacy body (_matmul_body) runs on a (t/bt, d/rows) grid where the
-    weight tile is re-fetched and re-unpacked for EVERY t tile — t/bt = 15x
-    the packed bytes and VPU work at a 1920-token chunk (the prefill-ladder
-    finding, BASELINE.md r3). Decode (t == 1) is unaffected: one t tile
-    means the two schedules are identical, so the matvec path keeps its
-    tuned shape.
-
-    ``nb_major``: the planes are (nb, R) instead of (R, nb) — the ONLY
-    difference is which weight dim the x (bt, nb) tiles contract against,
-    so one body serves both layouts via the dot dimension numbers.
-    """
-    dn = ((((1,), (0,)) if nb_major else ((1,), (1,))), ((), ()))
-    wdt = jnp.bfloat16 if bf16 else jnp.float32
-    prec = None if bf16 else jax.lax.Precision.HIGHEST
-
-    @pl.when(pl.program_id(1) == 0)
-    def _unpack():
-        for j in range(NJ):
-            q = qs3[j].astype(jnp.int32)
-            wlo_ref[j, :, :] = ((((q & 0xF) - 8).astype(jnp.float32))
-                                * s).astype(wdt)
-            whi_ref[j, :, :] = ((((q >> 4) - 8).astype(jnp.float32))
-                                * s).astype(wdt)
-
-    acc = None
-    for j in range(NJ):
-        a = jax.lax.dot_general(xlo_ref[j].astype(wdt), wlo_ref[j], dn,
-                                preferred_element_type=jnp.float32,
-                                precision=prec)
-        a = a + jax.lax.dot_general(xhi_ref[j].astype(wdt), whi_ref[j], dn,
-                                    preferred_element_type=jnp.float32,
-                                    precision=prec)
-        acc = a if acc is None else acc + a
-    out_ref[...] = acc
-
-
-def _kernel_scratch(qs_ref, scale_ref, xlo_ref, xhi_ref, out_ref,
-                    wlo_ref, whi_ref, *, bf16=False):
-    _matmul_body_scratch(qs_ref, scale_ref[...], xlo_ref, xhi_ref, out_ref,
-                         wlo_ref, whi_ref, bf16)
-
-
-def _kernel_scratch_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
-                            out_ref, wlo_ref, whi_ref, *, bf16=False):
-    del layer_ref  # consumed by the index maps
-    _matmul_body_scratch(qs_ref[0], scale_ref[0], xlo_ref, xhi_ref, out_ref,
-                         wlo_ref, whi_ref, bf16)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16"))
-def _q40_matmul_2d_scratch(qs_t, scale, x, *, block_rows, block_t,
-                           interpret, bf16=False):
-    _, d, nb = qs_t.shape
-    t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
-    wdt = jnp.bfloat16 if bf16 else jnp.float32
-    out = pl.pallas_call(
-        functools.partial(_kernel_scratch, bf16=bf16),
-        grid=(d // block_rows, t // block_t),
-        in_specs=[
-            pl.BlockSpec((NJ, block_rows, nb), lambda i, ti: (0, i, 0)),
-            pl.BlockSpec((block_rows, nb), lambda i, ti: (i, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti: (0, ti, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti: (0, ti, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_rows), lambda i, ti: (ti, i)),
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((NJ, block_rows, nb), wdt),
-                        pltpu.VMEM((NJ, block_rows, nb), wdt)],
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(qs_t, scale, xlo, xhi)
-    return out
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16"))
-def _q40_matmul_stacked_scratch(layer, qs_t, scale, x, *, block_rows,
-                                block_t, interpret, bf16=False):
-    _, _, d, nb = qs_t.shape
-    t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
-    wdt = jnp.bfloat16 if bf16 else jnp.float32
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(d // block_rows, t // block_t),
-        in_specs=[
-            pl.BlockSpec((1, NJ, block_rows, nb),
-                         lambda i, ti, L: (L[0], 0, i, 0)),
-            pl.BlockSpec((1, block_rows, nb), lambda i, ti, L: (L[0], i, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti, L: (0, ti, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti, L: (0, ti, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_rows),
-                               lambda i, ti, L: (ti, i)),
-        scratch_shapes=[pltpu.VMEM((NJ, block_rows, nb), wdt),
-                        pltpu.VMEM((NJ, block_rows, nb), wdt)],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel_scratch_stacked, bf16=bf16),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(layer, qs_t, scale, xlo, xhi)
 
 
 def _matmul_body(qs3, s, xlo_ref, xhi_ref, out_ref, bf16=False):
@@ -673,9 +425,9 @@ def _split_x(x: jax.Array, nb: int) -> tuple[jax.Array, jax.Array]:
 
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16", "multi_body"))
+                                    "bf16"))
 def _q40_matmul_2d(qs_t, scale, x, *, block_rows, block_t, interpret,
-                   bf16=False, multi_body="vpu"):
+                   bf16=False):
     _, d, nb = qs_t.shape
     t = x.shape[0]
     xlo, xhi = _split_x(x.astype(jnp.float32), nb)
@@ -697,23 +449,6 @@ def _q40_matmul_2d(qs_t, scale, x, *, block_rows, block_t, interpret,
         )(qs_t, scale, xlo, xhi, xsum)
         return out.reshape(1, d)
     if t <= MULTI_T_MAX:
-        if multi_body == "dequant":
-            out = pl.pallas_call(
-                _kernel_multi_dequant,
-                grid=(d // block_rows,),
-                in_specs=[
-                    pl.BlockSpec((NJ, block_rows, nb), lambda i: (0, i, 0)),
-                    pl.BlockSpec((block_rows, nb), lambda i: (i, 0)),
-                    pl.BlockSpec((t, 2 * NJ * nb), lambda i: (0, 0)),
-                ],
-                out_specs=pl.BlockSpec((block_rows, t), lambda i: (i, 0)),
-                out_shape=jax.ShapeDtypeStruct((d, t), jnp.float32),
-                scratch_shapes=[
-                    pltpu.VMEM((block_rows, 2 * NJ * nb), jnp.bfloat16)],
-                compiler_params=_VMEM64_PARAMS,
-                interpret=interpret,
-            )(qs_t, scale, _x_planes(x, nb))
-            return jnp.transpose(out)                # (t, d)
         xsum = jnp.sum(xlo + xhi, axis=0)            # (t, nb)
         out = pl.pallas_call(
             _kernel_multi,
@@ -753,9 +488,9 @@ def _q40_matmul_2d(qs_t, scale, x, *, block_rows, block_t, interpret,
 
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16", "multi_body"))
+                                    "bf16"))
 def _q40_matmul_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
-                        interpret, bf16=False, multi_body="vpu"):
+                        interpret, bf16=False):
     _, _, d, nb = qs_t.shape
     t = x.shape[0]
     xlo, xhi = _split_x(x.astype(jnp.float32), nb)
@@ -781,28 +516,6 @@ def _q40_matmul_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
         )(layer, qs_t, scale, xlo, xhi, xsum)
         return out.reshape(1, d)
     if t <= MULTI_T_MAX:
-        if multi_body == "dequant":
-            grid_spec = pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(d // block_rows,),
-                in_specs=[
-                    pl.BlockSpec((1, NJ, block_rows, nb),
-                                 lambda i, L: (L[0], 0, i, 0)),
-                    pl.BlockSpec((1, block_rows, nb),
-                                 lambda i, L: (L[0], i, 0)),
-                    pl.BlockSpec((t, 2 * NJ * nb), lambda i, L: (0, 0)),
-                ],
-                out_specs=pl.BlockSpec((block_rows, t),
-                                       lambda i, L: (i, 0)),
-                scratch_shapes=[
-                    pltpu.VMEM((block_rows, 2 * NJ * nb), jnp.bfloat16)],
-            )
-            out = pl.pallas_call(
-                _kernel_multi_dequant_stacked, grid_spec=grid_spec,
-                out_shape=jax.ShapeDtypeStruct((d, t), jnp.float32),
-                compiler_params=_VMEM64_PARAMS, interpret=interpret,
-            )(layer, qs_t, scale, _x_planes(x, nb))
-            return jnp.transpose(out)                # (t, d)
         xsum = jnp.sum(xlo + xhi, axis=0)            # (t, nb)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -848,6 +561,20 @@ def _q40_matmul_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
 # 16MB limit at 7B shapes (observed: 512x344 -> 16.9M)
 _MATMUL_ROWSXNB_CAP = 131072
 
+# 2..MULTI_T_MAX rows, d-major VPU multi body: rows*nb*t words a tile. The
+# compiler keeps several unrolled-plane temporaries live next to the t
+# accumulators; 300k was sized against the old 16 MB scoped limit, and the
+# multi kernels now run with the raised _VMEM64_PARAMS (wide-nb shapes
+# measured ~26M), so it is a tile-size heuristic, not a hard ceiling:
+# measured flat 300k/600k/1200k at 13B B=2 (probe since deleted; runtime of
+# round 5) — tile granularity is not that path's limiter
+_MULTI_ROWSXNBXT_CAP = 300_000
+
+# Most rows a tile takes: the tuned d-major pick, and the nb-major matvec's.
+# More rows trade grid steps for longer per-tile DMAs; the scoped-VMEM word
+# budgets apply on top. io/kernel_cache.layout_key writes it into the key.
+_TILE_ROWS_CAP = 768
+
 
 def _pick_block_rows(d: int, t: int = 1, nb: int = 128,
                      block_t: int | None = None) -> int | None:
@@ -873,22 +600,7 @@ def _pick_block_rows(d: int, t: int = 1, nb: int = 128,
         # an uncapped 512-row tile measured 17.5 MB and failed to compile)
         step, cap = 8, max(8, 360_000 // nb)
     elif t <= MULTI_T_MAX:
-        # the compiler keeps several unrolled-plane temporaries live next to
-        # the t accumulators; the 300k rows*nb*t cap was sized against the
-        # old 16MB scoped limit — the multi kernels now run with the raised
-        # _VMEM64_PARAMS (wide-nb shapes measured ~26M), so the cap is a
-        # tile-size heuristic, not a hard ceiling. DLLAMA_MULTI_CAP
-        # overrides it (tile-size experiments via tools/batch_bench.py;
-        # measured flat 300k/600k/1200k at 13B B=2 — tile granularity is
-        # not that path's limiter)
-        raw = os.environ.get("DLLAMA_MULTI_CAP", "")
-        try:
-            cap_words = int(raw) if raw else 300_000
-        except ValueError:
-            raise ValueError(
-                f"DLLAMA_MULTI_CAP={raw!r}: expected a plain integer "
-                f"(rows*nb*t word budget, e.g. 600000)") from None
-        step, cap = 8, max(8, cap_words // (t * nb))
+        step, cap = 8, max(8, _MULTI_ROWSXNBXT_CAP // (t * nb))
     else:
         # MXU path. With a FULL 128-row t-tile Mosaic pipelines the
         # unrolled-plane f32 temporaries within the budget; at smaller
@@ -902,13 +614,12 @@ def _pick_block_rows(d: int, t: int = 1, nb: int = 128,
             step, cap = 128, _MATMUL_ROWSXNB_CAP // nb
         else:
             step, cap = 128, 256
-    top_rows = _matvec_cap() if t == 1 else 768
-    top = (min(d, top_rows, cap) // step) * step
+    top = (min(d, _TILE_ROWS_CAP, cap) // step) * step
     for cand in range(top, 0, -step):
         if d % cand == 0:
             return cand
     # small odd dims: a full-d block is legal when it fits the same budget
-    return d if d <= min(top_rows, cap) else None
+    return d if d <= min(_TILE_ROWS_CAP, cap) else None
 
 
 def kernel_supports(d: int, n: int) -> bool:
@@ -966,36 +677,13 @@ def _precision_dot(wf, x2):
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def _matvec_cap() -> int:
-    """T=1 matvec row-tile cap — DLLAMA_MATVEC_CAP, default 768 (the
-    tuned d-major pick). Raising it trades grid-step count for longer
-    per-tile DMAs (tile-size experiments on the real bench; the scoped-
-    VMEM word budget still applies on top)."""
-    raw = os.environ.get("DLLAMA_MATVEC_CAP", "")
-    if not raw:
-        return 768
-    try:
-        cap = int(raw)
-    except ValueError:
-        raise ValueError(f"DLLAMA_MATVEC_CAP={raw!r}: expected a plain "
-                         f"integer row cap (e.g. 1536)") from None
-    if cap < 128:
-        # below the nb-major lane minimum the cap would silently drop
-        # leaves off the kernel layout (a LAYOUT change, not a tile
-        # change) — refuse rather than measure the wrong code path
-        raise ValueError(f"DLLAMA_MATVEC_CAP={cap} < 128: the nb-major "
-                         f"row tile needs a multiple of 128")
-    return cap
-
-
 def _pick_rows_nb(d: int, nb: int) -> int | None:
     """Row tile for the nb-major matvec: rows ride the LANES, so they must
     be a multiple of 128 — a d with no multiple-of-128 divisor (including
     every d < 128) returns None and the caller routes to the dequant
     fallback; rows*nb stays under the same ~(16+4)-bytes-per-word
-    scoped-VMEM budget as the d-major matvec (DLLAMA_MATVEC_CAP lifts the
-    768-row default for tile experiments)."""
-    top = min(d, _matvec_cap(), max(128, 360_000 // nb))
+    scoped-VMEM budget as the d-major matvec."""
+    top = min(d, _TILE_ROWS_CAP, max(128, 360_000 // nb))
     for cand in range(top - top % 128, 0, -128):
         if d % cand == 0:
             return cand
@@ -1076,80 +764,6 @@ def _q40_matvec_nb_stacked(layer, qs_t, scale, x, *, block_rows, interpret):
     return out
 
 
-def _kernel_scratch_nb(qs_ref, scale_ref, xlo_ref, xhi_ref, out_ref,
-                       wlo_ref, whi_ref, *, bf16=False):
-    _matmul_body_scratch(qs_ref, scale_ref[...], xlo_ref, xhi_ref,
-                         out_ref, wlo_ref, whi_ref, bf16, nb_major=True)
-
-
-def _kernel_scratch_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref,
-                               xhi_ref, out_ref, wlo_ref, whi_ref, *,
-                               bf16=False):
-    del layer_ref
-    _matmul_body_scratch(qs_ref[0], scale_ref[0], xlo_ref, xhi_ref,
-                         out_ref, wlo_ref, whi_ref, bf16, nb_major=True)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16"))
-def _q40_mxu_nb_2d_scratch(qs_t, scale, x, *, block_rows, block_t,
-                           interpret, bf16=False):
-    _, nb, d = qs_t.shape
-    t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
-    wdt = jnp.bfloat16 if bf16 else jnp.float32
-    return pl.pallas_call(
-        functools.partial(_kernel_scratch_nb, bf16=bf16),
-        grid=(d // block_rows, t // block_t),
-        in_specs=[
-            pl.BlockSpec((NJ, nb, block_rows), lambda i, ti: (0, 0, i)),
-            pl.BlockSpec((nb, block_rows), lambda i, ti: (0, i)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti: (0, ti, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti: (0, ti, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_rows), lambda i, ti: (ti, i)),
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((NJ, nb, block_rows), wdt),
-                        pltpu.VMEM((NJ, nb, block_rows), wdt)],
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(qs_t, scale, xlo, xhi)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16"))
-def _q40_mxu_nb_stacked_scratch(layer, qs_t, scale, x, *, block_rows,
-                                block_t, interpret, bf16=False):
-    _, _, nb, d = qs_t.shape
-    t = x.shape[0]
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
-    wdt = jnp.bfloat16 if bf16 else jnp.float32
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(d // block_rows, t // block_t),
-        in_specs=[
-            pl.BlockSpec((1, NJ, nb, block_rows),
-                         lambda i, ti, L: (L[0], 0, 0, i)),
-            pl.BlockSpec((1, nb, block_rows), lambda i, ti, L: (L[0], 0, i)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti, L: (0, ti, 0)),
-            pl.BlockSpec((NJ, block_t, nb), lambda i, ti, L: (0, ti, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_rows),
-                               lambda i, ti, L: (ti, i)),
-        scratch_shapes=[pltpu.VMEM((NJ, nb, block_rows), wdt),
-                        pltpu.VMEM((NJ, nb, block_rows), wdt)],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel_scratch_nb_stacked, bf16=bf16),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(layer, qs_t, scale, xlo, xhi)
-
-
 def _mxu_nb_planes(x, nb: int, block_t: int, bf16: bool):
     """The nb-major MXU body's x planes and the rows of one t-tile in them:
     (NJ, t, nb) float32 under ``bf16`` (fast-prefill: one piece, cast in the
@@ -1219,8 +833,7 @@ def _q40_mxu_nb_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
 
 def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                         interpret: bool | None,
-                        layer: jax.Array | None,
-                        block_rows: int | None = None) -> jax.Array:
+                        layer: jax.Array | None) -> jax.Array:
     """nb-major dispatch: every T on a kernel, the body picked by T alone.
     T = 1 the matvec, anything wider the MXU body with the standard
     (M,K)x(K,N) dot (``_five_pass_dot``: the weight's two bf16 pieces
@@ -1228,13 +841,10 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
     close to float64 as HIGHEST; one piece a side where the caller traced
     under bf16 precision): rows are padded to a multiple of 8, so a 2..8-row
     decode dispatch is ONE 8-row t-tile of the body a 16-row dispatch and a
-    prefill chunk run. The dequantize-then-dot fallback remains only for a
-    ``d`` the row tiler cannot place.
+    prefill chunk run. Dequantize-then-dot serves a chunk traced under bf16
+    precision (see q40_matmul) and a ``d`` the row tiler cannot place."""
+    from .linear import matmul_mode
 
-    ``block_rows`` overrides the auto-picked row tile (q40_matmul's tuning
-    knob, plumbed through for nb-major too). Lane-riding rows must be a
-    multiple of 128 dividing d; the T-path VMEM caps below still apply, so
-    an oversized override is shrunk, not obeyed blindly."""
     qs_t, scale = w.qs_t, w.scale
     nb, d = qs_t.shape[-2], qs_t.shape[-1]
     if interpret is None:
@@ -1245,34 +855,12 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
     if t > 1 and t % 8 != 0:
         pad = (-t) % 8
         out = _q40_matmul_nbmajor(w, jnp.pad(x2, ((0, pad), (0, 0))),
-                                  interpret, layer, block_rows)
+                                  interpret, layer)
         return out[:t].reshape(*lead, d)
-    # the prefill-ladder arms (DLLAMA_PREFILL_MATMUL) are about chunks:
-    # they never took a decode dispatch of up to MULTI_T_MAX rows
-    prefill = t > MULTI_T_MAX
-    if prefill and _prefill_matmul_mode() == "dequant":
-        # prefill-ladder experiment arm — see q40_matmul
-        if layer is not None:
-            qs_t = qs_t[layer]
-            scale = scale[layer]
-        return _precision_dot(_dequant_nb(qs_t, scale),
-                              x2).reshape(*lead, d)
-    if block_rows is not None:
-        if block_rows % 128 or d % block_rows:
-            raise ValueError(
-                f"nb-major block_rows={block_rows} must be a multiple of "
-                f"128 dividing d={d}")
-        rows = block_rows
-        if t == 1:
-            # same scoped-VMEM budget the auto pick enforces — an oversized
-            # override is shrunk, not obeyed blindly (the t>1 branches below
-            # re-cap for themselves)
-            cap = max(128, 360_000 // nb)
-            if rows > cap:
-                rows = next((r for r in range(cap - cap % 128, 0, -128)
-                             if d % r == 0), rows)
-    else:
-        rows = _pick_rows_nb(d, nb)
+    bf16 = matmul_mode() == "bf16"
+    # a chunk under bf16 precision dequantizes once and dots (q40_matmul
+    # says why); a decode dispatch of up to MULTI_T_MAX rows never does
+    rows = None if bf16 and t > MULTI_T_MAX else _pick_rows_nb(d, nb)
     block_t = _pick_block_t(t, nb)
     if rows is not None and t > 1:
         # the MXU body's f32 wlo/whi temporaries obey the same measured
@@ -1286,10 +874,6 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
             # path: shrink the row tile (see _pick_block_rows)
             rows = 256 if d % 256 == 0 else (128 if d % 128 == 0 else None)
     if rows is not None:
-        from .linear import matmul_mode
-
-        bf16 = matmul_mode() == "bf16"
-        scratch = prefill and _prefill_matmul_mode() == "scratch"
         if layer is not None:
             lidx = jnp.asarray(layer, dtype=jnp.int32).reshape(1)
             if t == 1:
@@ -1297,19 +881,17 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                                              block_rows=rows,
                                              interpret=interpret)
             else:
-                call = (_q40_mxu_nb_stacked_scratch if scratch
-                        else _q40_mxu_nb_stacked)
-                out = call(lidx, qs_t, scale, x2, block_rows=rows,
-                           block_t=block_t, interpret=interpret, bf16=bf16)
+                out = _q40_mxu_nb_stacked(lidx, qs_t, scale, x2,
+                                          block_rows=rows, block_t=block_t,
+                                          interpret=interpret, bf16=bf16)
         else:
             if t == 1:
                 out = _q40_matvec_nb_2d(qs_t, scale, x2, block_rows=rows,
                                         interpret=interpret)
             else:
-                call = (_q40_mxu_nb_2d_scratch if scratch
-                        else _q40_mxu_nb_2d)
-                out = call(qs_t, scale, x2, block_rows=rows,
-                           block_t=block_t, interpret=interpret, bf16=bf16)
+                out = _q40_mxu_nb_2d(qs_t, scale, x2, block_rows=rows,
+                                     block_t=block_t, interpret=interpret,
+                                     bf16=bf16)
         return out.reshape(*lead, d)
     if layer is not None:
         qs_t = qs_t[layer]
@@ -1318,57 +900,35 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
     return _precision_dot(wf, x2).reshape(*lead, d)
 
 
-def _dequant_i4(w) -> jax.Array:
+def _dequant_i4(w: Q40KernelNbI4) -> jax.Array:
     """f32 dense weight from int4 planes (the T>1 / untileable fallback):
     plane index IS the in-block value position (0..31)."""
-    qs4, scale = w.qs4, w.scale
-    vals = qs4.astype(jnp.float32)
-    if isinstance(w, Q40KernelNbI4):
-        # (..., 32, nb, d) -> (..., d, nb, 32)
-        vals = jnp.moveaxis(jnp.moveaxis(vals, -3, -1), -3, -2)
-        scale = jnp.swapaxes(scale, -1, -2)
-    else:
-        vals = jnp.moveaxis(vals, -3, -1)          # (..., d, nb, 32)
+    vals = w.qs4.astype(jnp.float32)
+    # (..., 32, nb, d) -> (..., d, nb, 32)
+    vals = jnp.moveaxis(jnp.moveaxis(vals, -3, -1), -3, -2)
+    scale = jnp.swapaxes(w.scale, -1, -2)
     w_f = vals * scale[..., None]
     return w_f.reshape(*w_f.shape[:-2], w_f.shape[-2] * 32)
 
 
-def _pick_rows_i4(d: int, nb: int) -> int | None:
-    """Row tile for the d-major int4 matvec: int4 operands carry a
-    (64, 128) native tile, so the second-minor block dim (rows) must be a
-    multiple of 64 (Mosaic: 'has tiling (64, 128)'), under the same
-    VMEM-word budget as the u8 picker."""
-    top = min(d, _matvec_cap(), max(64, 360_000 // nb))
-    for cand in range(top - top % 64, 0, -64):
-        if d % cand == 0:
-            return cand
-    return None
-
-
-def _q40_matmul_i4(w, x, interpret, layer, block_rows):
-    """Dispatch for the int4-plane layouts (chain-internal, T=1 hot path;
+def _q40_matmul_i4(w: Q40KernelNbI4, x, interpret, layer):
+    """Dispatch for the int4-plane layout (chain-internal, T=1 hot path;
     anything else takes the dequantize-then-dot fallback)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    nb_major = isinstance(w, Q40KernelNbI4)
     d = w.logical_shape[-2]
-    nb = (w.scale.shape[-2] if nb_major else w.scale.shape[-1])
+    nb = w.scale.shape[-2]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     if x2.shape[0] == 1:
-        if nb_major:
-            rows = block_rows or _pick_rows_nb(d, nb)
-        else:
-            rows = block_rows or _pick_rows_i4(d, nb)
+        rows = _pick_rows_nb(d, nb)
         if rows:
             if layer is not None:
-                out = (_q40_matvec_nb_i4_stacked if nb_major
-                       else _q40_matvec_i4_stacked)(
+                out = _q40_matvec_nb_i4_stacked(
                     jnp.asarray(layer, jnp.int32).reshape(1), w.qs4,
                     w.scale, x2, block_rows=rows, interpret=interpret)
             else:
-                out = (_q40_matvec_nb_i4_2d if nb_major
-                       else _q40_matvec_i4_2d)(
+                out = _q40_matvec_nb_i4_2d(
                     w.qs4, w.scale, x2, block_rows=rows,
                     interpret=interpret)
             return out.reshape(*lead, d)
@@ -1379,50 +939,6 @@ def _q40_matmul_i4(w, x, interpret, layer, block_rows):
                       preferred_element_type=jnp.float32,
                       precision=jax.lax.Precision.HIGHEST) \
         .reshape(*lead, d)
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def _q40_matvec_i4_2d(qs4, scale, x, *, block_rows, interpret):
-    nj2, d, nb = qs4.shape
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)   # (NJ, 1, nb)
-    x32 = jnp.concatenate([xlo, xhi], axis=0)        # (32, 1, nb)
-    out = pl.pallas_call(
-        _kernel_matvec_i4,
-        grid=(d // block_rows,),
-        in_specs=[
-            pl.BlockSpec((nj2, block_rows, nb), lambda i: (0, i, 0)),
-            pl.BlockSpec((block_rows, nb), lambda i: (i, 0)),
-            pl.BlockSpec((nj2, 1, nb), lambda i: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((d, 1), jnp.float32),
-        compiler_params=_VMEM64_PARAMS, interpret=interpret,
-    )(qs4, scale, x32)
-    return out.reshape(1, d)
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def _q40_matvec_i4_stacked(layer, qs4, scale, x, *, block_rows, interpret):
-    _, nj2, d, nb = qs4.shape
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
-    x32 = jnp.concatenate([xlo, xhi], axis=0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(d // block_rows,),
-        in_specs=[
-            pl.BlockSpec((1, nj2, block_rows, nb),
-                         lambda i, L: (L[0], 0, i, 0)),
-            pl.BlockSpec((1, block_rows, nb), lambda i, L: (L[0], i, 0)),
-            pl.BlockSpec((nj2, 1, nb), lambda i, L: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, 1), lambda i, L: (i, 0)),
-    )
-    out = pl.pallas_call(
-        _kernel_matvec_i4_stacked, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((d, 1), jnp.float32),
-        compiler_params=_VMEM64_PARAMS, interpret=interpret,
-    )(layer, qs4, scale, x32)
-    return out.reshape(1, d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -1470,24 +986,43 @@ def _q40_matvec_nb_i4_stacked(layer, qs4, scale, x, *, block_rows,
     )(layer, qs4, scale, x32)
 
 
-def q40_matmul(w: Q40Kernel | Q40Weight, x: jax.Array,
-               block_rows: int | None = None,
-               interpret: bool | None = None,
+def q40_matmul(w: Q40Kernel | Q40KernelNb | Q40KernelNbI4 | Q40Weight,
+               x: jax.Array, interpret: bool | None = None,
                layer: jax.Array | None = None) -> jax.Array:
     """out[..., d] = dequant(w)(d, n) @ x[..., n], packed weights end to end.
 
     x may be (n,) or (..., n); leading dims are flattened into T for the
-    kernel and restored after. ``w`` should be a pre-tiled Q40Kernel on the
-    hot path; a Q40Weight is accepted and re-tiled per call (tests only).
+    kernel and restored after. ``w`` should be a pre-tiled leaf on the hot
+    path; a Q40Weight is accepted and re-tiled per call (tests only).
 
     ``layer``: when given, ``w`` holds stacked per-layer weights (qs_t
     (L, 16, d, nb)) and the kernel DMAs layer ``layer`` directly out of the
     stack via scalar prefetch — the zero-copy path for lax.scan over layers.
-    """
-    if isinstance(w, (Q40KernelI4, Q40KernelNbI4)):
-        return _q40_matmul_i4(w, x, interpret, layer, block_rows)
+
+    The body is picked by the leaf's layout and T alone, each with a
+    ``_2d`` and a ``_stacked`` wrapper; the row tile by the pickers:
+
+    ============== ================== ============== ================
+    leaf           T = 1              2..8 rows      more rows
+    ============== ================== ============== ================
+    Q40KernelNb    _q40_matvec_nb     _q40_mxu_nb    _q40_mxu_nb
+    Q40KernelNbI4  _q40_matvec_nb_i4  dequant + dot  dequant + dot
+    Q40Kernel      _kernel_matvec     _kernel_multi  _kernel
+    ============== ================== ============== ================
+
+    Two exceptions, both dequantize-then-dot in XLA: a ``d`` no tiler
+    places, and a chunk (T > 8) traced under bf16 precision
+    (``--fast-prefill``), where unpacking the weight ONCE into an HBM temp
+    and letting XLA tile a dense dot both ways beat the grids, which
+    re-stream one operand t/bt or d/rows times (7B on v5e, tok/s at chunk
+    480/960/1920: 3255/4055/4487 against the plain grid's 2408/3565/4249;
+    BASELINE.md r3, probe since deleted; runtime of round 5). In float32
+    parity the dense dot's HIGHEST passes run on 4x the temp bytes and the
+    packed grid stays ahead, so it keeps the chunk."""
+    if isinstance(w, Q40KernelNbI4):
+        return _q40_matmul_i4(w, x, interpret, layer)
     if isinstance(w, Q40KernelNb):
-        return _q40_matmul_nbmajor(w, x, interpret, layer, block_rows)
+        return _q40_matmul_nbmajor(w, x, interpret, layer)
     if isinstance(w, Q40Weight):
         w = to_kernel_layout(w)
     qs_t, scale = w.qs_t, w.scale
@@ -1504,11 +1039,7 @@ def q40_matmul(w: Q40Kernel | Q40Weight, x: jax.Array,
     n = x.shape[-1]
     x2 = x.reshape(-1, n)
     t = x2.shape[0]
-    if t > MULTI_T_MAX and _prefill_matmul_mode() == "dequant":
-        # prefill-ladder experiment arm (tools/prefill_ladder.py): unpack the
-        # weight ONCE into a bf16/f32 HBM temp and let XLA drive a plain MXU
-        # dot, instead of the Pallas grid re-unpacking the weight tile per
-        # T-tile. Decode (t==1) never takes this.
+    if t > MULTI_T_MAX and bf16:
         return _dequant_matmul(w, x2, layer).reshape(*lead, d)
     if t > MULTI_T_MAX and t % 8 != 0:
         # pad to a multiple of 8 so the MXU path always has an under-cap
@@ -1516,34 +1047,24 @@ def q40_matmul(w: Q40Kernel | Q40Weight, x: jax.Array,
         # scoped-VMEM plane budget); the pad rows are zeros, sliced off below
         pad = (-t) % 8
         out = q40_matmul(w, jnp.pad(x2, ((0, pad), (0, 0))),
-                         block_rows=block_rows, interpret=interpret,
-                         layer=layer)
+                         interpret=interpret, layer=layer)
         return out[:t].reshape(*lead, d)
     block_t = _pick_block_t(t, nb)
+    block_rows = _pick_block_rows(d, t, nb, block_t)
     if block_rows is None:
-        block_rows = _pick_block_rows(d, t, nb, block_t)
-        if block_rows is None:
-            # this (d, t) combo has no legal tiling (e.g. TP-shard dims with
-            # no multiple-of-128 divisor at MXU T): dequantize-then-dot on
-            # the packed weight — correctness everywhere, kernel speed on
-            # the shapes that matter
-            return _dequant_matmul(w, x2, layer).reshape(*lead, d)
-    scratch = t > MULTI_T_MAX and _prefill_matmul_mode() == "scratch"
-    # like bf16 above: the T<=8 body mode must be read at the CALLER's
-    # trace point and threaded as a static arg, or a cached inner trace
-    # silently serves the other body after the env flips
-    extra = {} if scratch else {"multi_body": _multi_t_body()
-                                if t <= MULTI_T_MAX else "vpu"}
+        # this (d, t) combo has no legal tiling (e.g. TP-shard dims with
+        # no multiple-of-128 divisor at MXU T): dequantize-then-dot on
+        # the packed weight — correctness everywhere, kernel speed on
+        # the shapes that matter
+        return _dequant_matmul(w, x2, layer).reshape(*lead, d)
     if layer is not None:
         if qs_t.ndim != 4:
             raise ValueError("layer= requires stacked (L, 16, d, nb) weights")
         lidx = jnp.asarray(layer, dtype=jnp.int32).reshape(1)
-        call = _q40_matmul_stacked_scratch if scratch else _q40_matmul_stacked
-        out = call(lidx, qs_t, scale, x2,
-                   block_rows=block_rows, block_t=block_t,
-                   interpret=interpret, bf16=bf16, **extra)
+        out = _q40_matmul_stacked(lidx, qs_t, scale, x2,
+                                  block_rows=block_rows, block_t=block_t,
+                                  interpret=interpret, bf16=bf16)
     else:
-        call = _q40_matmul_2d_scratch if scratch else _q40_matmul_2d
-        out = call(qs_t, scale, x2, block_rows=block_rows,
-                   block_t=block_t, interpret=interpret, bf16=bf16, **extra)
+        out = _q40_matmul_2d(qs_t, scale, x2, block_rows=block_rows,
+                             block_t=block_t, interpret=interpret, bf16=bf16)
     return out.reshape(*lead, d)

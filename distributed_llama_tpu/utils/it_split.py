@@ -140,8 +140,7 @@ def parse_trace(path: str) -> dict[str, DeviceSplit]:
 def bucket_ops(trace_dir: str, denom: int = 1) -> dict[str, float]:
     """Op time from a trace grouped by kernel family, in ms (divided by
     ``denom``, e.g. steps or tokens) — THE one copy of the family
-    classifier used by bench.py, tools/prefill_ladder.py and
-    tools/continuous_bench.py (the buckets are a measurement contract
+    classifier used by bench.py and tools/continuous_bench.py (the buckets are a measurement contract
     cited in BASELINE.md).
 
     Known blind spot: the match is by HLO instruction NAME. Pallas custom

@@ -88,10 +88,9 @@ def test_key_mismatch_rebuilds(tmp_path, monkeypatch):
     assert kc.load_packed(side, kc.layout_key(path)) is not None
     assert kc.load_packed(side, "v1|other|key") is None
 
-    # a different matvec cap changes the key -> rebuild instead of reuse
-    monkeypatch.setenv("DLLAMA_MATVEC_CAP", "1536")
-    assert kc.load_packed(side, kc.layout_key(path)) is None
-    monkeypatch.delenv("DLLAMA_MATVEC_CAP")
+    # the tile-row cap is a constant since PR 43 and still in the key, where
+    # it stood: sidecars written before stay valid
+    assert "|768|" in kc.layout_key(path)
 
     # overwriting the model .bin (same path, new contents) invalidates:
     # the key carries the source file's size+mtime
@@ -176,13 +175,14 @@ def test_layout_key_strings_are_the_parents(tmp_path, monkeypatch):
     mechanism."""
     from distributed_llama_tpu.ops.linear import Q40_STOCK, Q40Layout
     from distributed_llama_tpu.ops.pallas_layer import fusion_cache_key
-    from distributed_llama_tpu.ops.pallas_q40 import _matvec_cap
 
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
     path = _model_file(tmp_path)
     st = os.stat(path)
     tail = f"|tp=1|wf=Q40|bf=F32|src={st.st_size}:{st.st_mtime_ns}"
-    head = f"v1|pallas|{_matvec_cap()}|{fusion_cache_key()}"
+    # 768: the tile-row cap, spelled out (a constant since PR 43; the parent
+    # wrote its environment knob's default here)
+    head = f"v1|pallas|768|{fusion_cache_key()}"
     assert kc.layout_key(path) == f"{head}|nb=auto{tail}"
     assert kc.layout_key(path, layout=Q40_STOCK) == f"{head}|nb=auto{tail}"
     assert kc.layout_key(path, layout=Q40Layout("d-major", "8 rows")) \

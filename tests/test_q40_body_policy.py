@@ -6,6 +6,7 @@ the kernels themselves are pinned by tests/test_pallas_q40.py."""
 
 from __future__ import annotations
 
+import functools
 import os
 import re
 
@@ -290,10 +291,12 @@ def test_leaf_rule_packs_the_cells_as_the_parent_did(case, monkeypatch):
     assert {k: names[type(v)] for k, v in packed.items()} == want
 
 
-# The six cells of BENCHMARK.json: (policy label, kinds) an engine of the
+# The nine cells of BENCHMARK.json: (policy label, kinds) an engine of the
 # cell's width and tp resolves for the cell's own configuration file. PR 32
 # moved the two 8-row cells (were "d-major", every leaf d-major) and nothing
-# else: rows 1 and 16 and tp 4 read what PR 31's tree read.
+# else: rows 1 and 16 and tp 4 read what PR 31's tree read. The three 32-row
+# cells (PR 33, 37, 39) joined with PR 43; their kinds are "nb-major" for
+# every leaf the spec names, a leading dense layer's included.
 CELLS_PACKED = {
     "mistral7b.decode1": ("i4-nb", _ALL_NB),
     "mistral7b.serve-chat": ("i4-nb", _ALL_NB),
@@ -301,50 +304,84 @@ CELLS_PACKED = {
     "yi34b-tp4.decode1": ("d-major", _ALL_NB),
     "olmoe7b.gen-sat16": ("d-major", dict.fromkeys(OLMOE, "nb-major")),
     "brumby14b.gen-sat16": ("i4-nb", _ALL_NB),
+    "deepseekv3.gen-sat32": ("nb-major", None),
+    "phi4flash.reason-sat32": ("nb-major", None),
+    "xing4.gen-sat32": ("nb-major", None),
 }
 MOVED_BY_PR32 = {"mistral7b.serve-chat", "mistral7b.serve-sat"}
+SINCE_PR31 = {"deepseekv3.gen-sat32", "phi4flash.reason-sat32",
+              "xing4.gen-sat32"}
 PR31_PACKED = {**CELLS_PACKED,
                **dict.fromkeys(MOVED_BY_PR32, ("d-major", _ALL_D))}
+_HARNESS = {"olmoe": "olmoe", "brumby": "retention", "deepseek_v3": "latent",
+            "phi4flash": "hybrid", "xing4_0": "hyper"}
 
 
 @pytest.mark.parametrize("cell_name", sorted(CELLS_PACKED))
-def test_the_six_cells_policy_and_leaf_kinds(cell_name, monkeypatch):
+def test_the_nine_cells_policy_and_leaf_kinds(cell_name, monkeypatch):
     """Each cell's configuration through its own harness module to the
-    program's spec, at the cell's dispatch width (1 / 8 / 16 rows) and tp
-    (1 / 4): the policy label and every leaf's packed kind."""
+    program's spec, at the cell's dispatch width (1 / 8 / 16 / 32 rows) and
+    tp (1 / 4): the policy label and every leaf's packed kind. NO cell packs
+    a d-major leaf: the ledger cannot say what the d-major bodies cost
+    (ROADMAP D19 rests on this)."""
+    import importlib
+
     import jax
 
-    from benchmark.harness import cells, model, olmoe, retention
+    from benchmark.harness import cells
     from distributed_llama_tpu.parallel.tp import FUSED_INPUT_SHARDED
 
     monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
     cell = cells.load_cell(cell_name)
-    harness = {"olmoe": olmoe, "brumby": retention}.get(
-        cell.config.get("model_type"), model)
+    harness = importlib.import_module("benchmark.harness." + _HARNESS.get(
+        cell.config.get("model_type"), "model"))
     flags = cell.config["entries"][
         "inference" if cell.traffic["entry"] == "inference" else "serve"]
     rows, tp = int(flags.get("slots", 1)), int(flags.get("tp", 1))
     assert (rows, tp) == {"mistral7b.decode1": (1, 1),
                           "yi34b-tp4.decode1": (1, 4)}.get(
-        cell_name, (8 if cell_name in MOVED_BY_PR32 else 16, 1))
+        cell_name, (8 if cell_name in MOVED_BY_PR32 else
+                    32 if cell_name in SINCE_PR31 else 16, 1))
     spec = harness.program_spec(harness.sizes_of(cell.config))
     layout = q40_body_policy(spec, rows=rows, sharded=tp > 1)
-    shapes = dict(spec.layer_matmul_shapes() + spec.expert_matmul_shapes())
-    shapes["wcls"] = (spec.vocab_size, spec.dim)
-    lead = {k: (1, spec.n_experts) if k.startswith("moe_") else
-            () if k == "wcls" else (1,) for k in shapes}
-    tree = {k: Q40Weight(
-        jax.ShapeDtypeStruct((*lead[k], d, n // 32, 16), np.uint8),
-        jax.ShapeDtypeStruct((*lead[k], d, n // 32), np.float16))
-        for k, (d, n) in shapes.items()}
+
+    def leaves(shapes, lead):
+        shapes = dict(shapes)
+        if spec.latent:     # as models/latent.prepare_latent_params leaves it
+            from distributed_llama_tpu.models.latent import plane_width
+
+            del shapes["wkv_b"]     # float32 w_uk / w_uv from here on
+            shapes["wkv_a"] = (plane_width(spec), spec.dim)
+        return {k: Q40Weight(
+            jax.ShapeDtypeStruct((*lead(k), d, n // 32, 16), np.uint8),
+            jax.ShapeDtypeStruct((*lead(k), d, n // 32), np.float16))
+            for k, (d, n) in shapes.items()}
+
+    tree = leaves(spec.layer_matmul_shapes() + spec.expert_matmul_shapes(),
+                  lambda k: (1, spec.n_experts_held)
+                  if k.startswith("moe_") else (1,))
+    tree["wcls"] = Q40Weight(
+        jax.ShapeDtypeStruct((spec.vocab_size, spec.dim // 32, 16), np.uint8),
+        jax.ShapeDtypeStruct((spec.vocab_size, spec.dim // 32), np.float16))
+    if spec.dense_layer_matmul_shapes():
+        tree["dense"] = leaves(spec.dense_layer_matmul_shapes(),
+                               lambda k: (1,))
     packed = jax.eval_shape(lambda t: pack_q40_params(
         t, tp=tp, allow_nb_major=tp == 1, layout=layout,
         input_sharded=FUSED_INPUT_SHARDED if tp > 1 else ()), tree)
     names = {Q40KernelNb: "nb-major", Q40Kernel: "d-major",
              Q40Weight: "codec"}
-    got = (layout.label, {k: names[type(v)] for k, v in packed.items()})
-    assert got == CELLS_PACKED[cell_name]
-    assert (got == PR31_PACKED[cell_name]) == (cell_name not in MOVED_BY_PR32)
+    dense = packed.pop("dense", {})
+    kinds = {k: names[type(v)] for k, v in packed.items()}
+    label, want = CELLS_PACKED[cell_name]
+    if want is None:
+        want = dict.fromkeys(kinds, "nb-major")
+    assert (layout.label, kinds) == (label, want)
+    assert {*kinds.values(), *(names[type(v)] for v in dense.values())} \
+        == {"nb-major"}
+    if cell_name not in SINCE_PR31:
+        assert ((label, kinds) == PR31_PACKED[cell_name]) == (
+            cell_name not in MOVED_BY_PR32)
 
 
 def test_leaf_rule_corners():
@@ -367,8 +404,13 @@ def test_leaf_rule_corners():
     assert q40_leaf_layout(100, 64, key="moe_w2") == "codec"
 
 
+# retired names, spelled in halves so that this file holds none of them:
+# three layout variables (ISSUE 29), then four Q40 tile and body knobs and
+# the d-major int4 leaf they could reach (ISSUE 43)
 _NAMES = ("DLLAMA_Q40_" + "BODY", "DLLAMA_Q40_" + "I4",
-          "DLLAMA_NB_" + "MAJOR")
+          "DLLAMA_NB_" + "MAJOR", "DLLAMA_PREFILL_" + "MATMUL",
+          "DLLAMA_MULTI_T_" + "BODY", "DLLAMA_MULTI_" + "CAP",
+          "DLLAMA_MATVEC_" + "CAP", "Q40Kernel" + "I4")
 
 
 def _sources(*roots):
@@ -383,9 +425,9 @@ def _sources(*roots):
                     yield os.path.join(base, f)
 
 
-def test_library_writes_no_environment_and_names_none_of_the_four():
+def test_library_writes_no_environment():
     """Outside frontend/ and analysis/ the package writes ``os.environ``
-    nowhere, and nothing that ships names the retired variables."""
+    nowhere."""
     write = re.compile(r"os\.environ\[[^\]]*\]\s*=[^=]|os\.environ\."
                        r"(setdefault|update|pop)\(|os\.putenv\(|"
                        r"del os\.environ")
@@ -399,11 +441,29 @@ def test_library_writes_no_environment_and_names_none_of_the_four():
                 if write.search(line):
                     offenders.append(f"{rel}:{i}: {line.strip()}")
     assert offenders == []
-    named = []
+
+
+@functools.lru_cache(maxsize=None)
+def _shipped_text() -> dict:
+    """Relative path -> text of everything that ships, read once."""
+    out = {}
     for path in _sources("distributed_llama_tpu", "bench.py", "tools",
                          "chip_smoke.py", "README.md"):
         with open(path, encoding="utf-8") as fh:
-            text = fh.read()
-        named += [f"{os.path.relpath(path, _ROOT)}: {n}" for n in _NAMES
-                  if n in text]
-    assert named == []
+            out[os.path.relpath(path, _ROOT)] = fh.read()
+    return out
+
+
+@pytest.mark.parametrize("name", _NAMES)
+def test_nothing_that_ships_names_a_retired_variable(name):
+    assert [p for p, text in _shipped_text().items() if name in text] == []
+
+
+def test_the_package_names_eleven_variables():
+    """ROADMAP D5 counts them; a new ``DLLAMA_*`` name is a decision, not a
+    side effect."""
+    found = set()
+    for path, text in _shipped_text().items():
+        if path.startswith("distributed_llama_tpu" + os.sep):
+            found.update(re.findall(r"DLLAMA_[A-Z0-9_]+", text))
+    assert len(found) == 11, sorted(found)
