@@ -1,0 +1,27 @@
+"""The full layers' paged decode attention kernel's share of the HBM
+roofline: the bytes of K and V a decode step must read ONCE (8,192 B a cached
+position a row reads, position + 1 of them, in each of the four full layers'
+pools: ``harness/laguna.full_step_bytes`` over the program's
+``shared_kv_positions`` counter a step, across the TRACED seconds) over the
+device time of the ``hm_attn_paged_decode`` calls in the median decode step
+of the traced window, over 819 GB/s. It cannot pass 100 % unless the kernel
+skips a position. None for a program or a trace without the kernel or the counter."""
+
+from benchmark.harness import laguna
+from benchmark.harness.cells import load_reader
+
+LAYER = "kernels"
+UNIT = "%"
+MOVES = "out_tokens_per_s"
+SOURCE = "device_trace"
+
+_ring = load_reader("layer_metrics", "lag_ring_attn_roofline")
+
+
+def read(run):
+    positions = _ring.a_step(run, "shared_kv_positions")
+    if not positions:
+        return None
+    return _ring.share(run, laguna.full_step_bytes(
+        laguna.sizes_of(run.cell.config), positions),
+        _ring.step_seconds(run, "paged"))

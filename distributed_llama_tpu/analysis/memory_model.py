@@ -79,9 +79,10 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
         from ..ops.retention import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)
-    if spec.hybrid:
-        _refuse_sharded_hybrid(n_slices)
-        # each layer its kind's tensors, and the tied classifier's copy
+    if spec.slotted:
+        _refuse_sharded_slotted(spec, n_slices)
+        # each layer its kind's tensors (a held expert's counted once
+        # each), and the classifier (a hybrid spec's: the tied copy)
         return spec.vocab_size * spec.dim + sum(
             e[2][0] * e[2][1] for _, _, entries in spec.layer_plans()
             for e in entries if e[0] == "mm")
@@ -100,11 +101,14 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
     return total // n_slices
 
 
-def _refuse_sharded_hybrid(n_slices: int, n_sp: int = 1) -> None:
+def _refuse_sharded_slotted(spec, n_slices: int, n_sp: int = 1) -> None:
+    """A hybrid or a mixer-kinds spec over more than one chip: refused by
+    name, as parallel/tp.py refuses it."""
     if n_slices > 1 or n_sp > 1:
+        from ..ops.linear import MIXERS_TP_REFUSAL
         from ..ops.mamba import TP_REFUSAL
 
-        raise ValueError(TP_REFUSAL)
+        raise ValueError(MIXERS_TP_REFUSAL if spec.mixers else TP_REFUSAL)
 
 
 def latent_absorbed_bytes(spec: TransformerSpec) -> int:
@@ -144,10 +148,10 @@ def replicated_device_bytes(spec: TransformerSpec) -> int:
     """Bytes every chip holds whole regardless of tp: the f32 embedding
     table and the rms norm vectors (2 per layer + final)."""
     embedding = spec.vocab_size * spec.dim * 4
-    if spec.hybrid:     # every float32 leaf of every layer, the final norm
+    if spec.slotted:    # every float32 leaf of every layer, the final norm
         import math
 
-        return embedding + 4 * (2 * spec.dim + sum(
+        return embedding + 4 * ((2 if spec.hybrid else 1) * spec.dim + sum(
             math.prod(e[2]) for _, _, entries in spec.layer_plans()
             for e in entries if e[0] == "f32"))
     norms = (spec.n_layers * sum(n for _, n in spec.layer_norm_shapes())
@@ -167,6 +171,11 @@ def state_slot_bytes(spec: TransformerSpec) -> int:
     context). What ``kv_position_bytes`` x positions is to a softmax spec."""
     from ..ops.retention import state_bytes
 
+    if spec.mixers:
+        # a mixer-kinds spec's slot: each sliding layer's ring of K and V,
+        # float32 (models/laguna.py); its full layers' K / V are pages
+        mx = spec.mixers
+        return 4 * mx.count("sliding") * mx.window * 2 * spec.kv_dim
     if spec.hybrid:
         # a hybrid spec's slot: each Mamba layer's conv inputs and state,
         # each window layer's ring of K and V, float32 (models/sambay.py);
@@ -176,8 +185,8 @@ def state_slot_bytes(spec: TransformerSpec) -> int:
             hy.d_state + hy.d_conv - 1)
             + hy.count("swa") * hy.window * 2 * spec.kv_dim)
     if not spec.retention:
-        raise ValueError("state_slot_bytes prices a retention or a hybrid "
-                         "spec's slot")
+        raise ValueError("state_slot_bytes prices a retention, a hybrid or "
+                         "a mixer-kinds spec's slot")
     return spec.n_layers * state_bytes(spec.n_kv_heads, spec.head_size)
 
 
@@ -196,8 +205,8 @@ def kv_cache_device_bytes(spec: TransformerSpec, n_slices: int,
     if spec.latent:
         return batch * spec.seq_len * kv_position_bytes(spec, n_slices,
                                                         cache_itemsize)
-    if spec.hybrid:     # ``batch`` slots, and ONE layer's contiguous K / V
-        _refuse_sharded_hybrid(n_slices, n_sp)
+    if spec.slotted:    # ``batch`` slots, and the full layers' K / V
+        _refuse_sharded_slotted(spec, n_slices, n_sp)
         return batch * (state_slot_bytes(spec) + spec.seq_len
                         * kv_position_bytes(spec, 1, cache_itemsize))
     return (2 * spec.n_layers * batch * (spec.seq_len // n_sp)
@@ -239,12 +248,14 @@ def kv_position_bytes(spec: TransformerSpec, n_slices: int,
                              "one chip (runtime/continuous.latent_refusals)")
         return (spec.n_layers * -(-spec.latent.width // 128) * 128
                 * cache_itemsize)
-    if spec.hybrid:     # the ONE full layer's K and V, float32, one chip
-        _refuse_sharded_hybrid(n_slices)
+    if spec.slotted:    # the full layers' K and V alone (a hybrid spec
+        #                     has ONE), float32, one chip
+        _refuse_sharded_slotted(spec, n_slices)
         if kv_quant != "f32":
-            raise ValueError("a hybrid spec's pages are float32 "
-                             "(runtime/continuous.cache_refusals)")
-        return 2 * spec.kv_dim * cache_itemsize
+            raise ValueError("a hybrid or mixer-kinds spec's pages are "
+                             "float32 (runtime/continuous.cache_refusals)")
+        full = spec.mixers.count("full") if spec.mixers else 1
+        return 2 * full * spec.kv_dim * cache_itemsize
     kv_dim = (spec.n_kv_heads // n_slices) * spec.head_size
     if kv_quant == "q8":
         per = kv_dim + 2 * (kv_dim // QK)   # int8 codes + f16 deltas
@@ -650,11 +661,11 @@ def device_footprint(spec: TransformerSpec, n_slices: int, scheme: str,
             "a retention spec's memory is `batch` states of fixed size: "
             "pages, q8 pages, the verify window, the mixed budget and the "
             "tier staging buffer do not apply (the engine refuses them)")
-    if spec.hybrid and (kv_quant != "f32" or spec_k or mixed_budget
-                        or tier_staging_pages):
+    if spec.slotted and (kv_quant != "f32" or spec_k or mixed_budget
+                         or tier_staging_pages):
         raise ValueError(
-            "a hybrid spec's memory is `batch` slots of fixed size and "
-            "float32 pages of one layer: q8 pages, the verify window, the "
+            "a hybrid or mixer-kinds spec's memory is `batch` slots of "
+            "fixed size and float32 pages of its full layers: q8 pages, the verify window, the "
             "mixed budget and the tier staging buffer do not apply (the "
             "engine refuses them)")
     if kv_page_size > 0:
@@ -662,7 +673,7 @@ def device_footprint(spec: TransformerSpec, n_slices: int, scheme: str,
                  else default_kv_pages(spec, batch, kv_page_size))
         kv_bytes = kv_page_pool_bytes(spec, n_slices, pages, kv_page_size,
                                       kv_quant=kv_quant)
-        if spec.hybrid:     # the slots beside the pool
+        if spec.slotted:    # the slots beside the pool
             kv_bytes += batch * state_slot_bytes(spec)
     else:
         kv_bytes = kv_cache_device_bytes(spec, n_slices, batch=batch)

@@ -581,6 +581,11 @@ def check_config(entry: MatrixEntry, device: str = "v5e",
         from ..ops.mamba import TP_REFUSAL
 
         raise ValueError(f"shardcheck {config}: {TP_REFUSAL}")
+    if spec.mixers:
+        # and a mixer-kinds spec: rings and its full layers' pages
+        from ..ops.linear import MIXERS_TP_REFUSAL
+
+        raise ValueError(f"shardcheck {config}: {MIXERS_TP_REFUSAL}")
     findings = check_uniform_shards(spec, entry.tp, entry.scheme, config)
     act_bytes = None
     if not findings and kv_quant == "q8":

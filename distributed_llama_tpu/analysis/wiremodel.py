@@ -707,6 +707,10 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_prompt_positions_total"),
     _m("dllama_xdec_positions_total"),
     _m("dllama_ssm_min_decay"),
+    # a mixer-kinds spec's per-head output gate (its rings, pages and
+    # positions are the hybrid series above)
+    _m("dllama_attn_gate_min"),
+    _m("dllama_attn_gate_mean"),
     # journal / recovery / lifecycle
     _m("dllama_journal_records_total"),
     _m("dllama_recoveries_total"),

@@ -60,6 +60,15 @@ Extensions beyond the reference:
   lambdas and the sub-norm, and which heads the checkpoint pairs, was not
   checked against the checkpoint (no network here), and a converter that
   guessed would write a file that runs and is wrong.
+* ``model_type: laguna`` (Laguna-XS.2: window and full grouped-query
+  attention layers, each kind with its own head count and RoPE, a per-head
+  output gate, a dense layer before expert layers): ``laguna_spec`` reads
+  the config (``layer_types``, ``num_attention_heads_per_layer``, the two
+  ``rope_parameters``, ``gating``, the expert keys; header extension 7). The
+  tensor names (``LAGUNA_TENSORS``) are a GUESS (``LAGUNA_TENSORS_NOTE``):
+  no checkpoint was seen, and one that names them otherwise is refused by a
+  ``KeyError``. ``q_proj`` / ``k_proj`` rows go from rotate-half order to
+  interleaved pairs over a kind's ROTATED dimensions only.
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -182,6 +191,21 @@ LATENT_TENSORS = {
     "moe_w2": _L + "mlp.experts.{expert}.down_proj.weight",
     "moe_w3": _L + "mlp.experts.{expert}.up_proj.weight",
 }
+LAGUNA_TENSORS = dict(
+    {k: LATENT_TENSORS[k] for k in (
+        "rms_att", "rms_ffn", "wo", "w1", "w2", "w3", "moe_gate",
+        "moe_w1", "moe_w2", "moe_w3")},
+    wq=_L + "self_attn.q_proj.weight", wk=_L + "self_attn.k_proj.weight",
+    wv=_L + "self_attn.v_proj.weight",
+    w_hgate=_L + "self_attn.g_proj.weight",
+    sh_w1=_L + "mlp.shared_expert.gate_proj.weight",
+    sh_w2=_L + "mlp.shared_expert.down_proj.weight",
+    sh_w3=_L + "mlp.shared_expert.up_proj.weight")
+LAGUNA_TENSORS_NOTE = (
+    "the names of a laguna checkpoint's tensors (self_attn.g_proj for the "
+    "per-head gate, mlp.shared_expert.*, mlp.experts.{e}.*) are a guess: "
+    "no checkpoint was seen; one that names them otherwise fails with a "
+    "KeyError and nothing is written wrong")
 """A ``deepseek_v3`` checkpoint's tensors by this repo's names, as the
 published bfloat16 state dict names them."""
 
@@ -299,6 +323,9 @@ class HFCheckpoint:
                     float(c.mhc_h_res_clamp_max)))
         if getattr(c, "model_type", "") == "phi4flash":
             return hybrid_spec(c, target, seq_len)
+        if getattr(c, "model_type", "") == "laguna":
+            print(f"🔶 laguna tensors: {LAGUNA_TENSORS_NOTE}")
+            return laguna_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "olmoe":
             if getattr(c, "norm_topk_prob", False):
                 raise ValueError("olmoe with norm_topk_prob: the program "
@@ -334,6 +361,18 @@ class HFCheckpoint:
                 "against the checkpoint, so no tensor is converted (the "
                 "module docstring says why); models/synth.py writes a "
                 "seeded file of this spec")
+        if spec.mixers:
+            key = LAGUNA_TENSORS.get(name) or {
+                "tok_embedding": "model.embed_tokens.weight",
+                "rms_final": "model.norm.weight",
+                "wcls": "lm_head.weight"}[name]
+            w = self.state[key.format(layer=layer, expert=expert)].to(
+                self.torch.float32).numpy()
+            if name in ("wq", "wk"):
+                kind = spec.mixers.kinds[layer]
+                w = unpermute_rotary(w, spec.head_size,
+                                     spec.mixers.rotary(kind))
+            return w
         if spec.latent:     # rows as they are: see the module docstring
             key = LATENT_TENSORS.get(name) or HYPER_TENSORS.get(name) or {
                 "tok_embedding": "model.embed_tokens.weight",
@@ -406,6 +445,78 @@ def convert_meta(model_path: str, target: str, out: str | None = None,
     return out
 
 
+def unpermute_rotary(w: np.ndarray, head_size: int, rotary: int):
+    """Rows of a (heads x head_size, n) projection: each head's first
+    ``rotary`` rows from rotate-half order (i pairs with i + rotary / 2) to
+    interleaved pairs, the other rows as they are."""
+    heads = w.reshape(-1, head_size, *w.shape[1:])
+    turned = heads[:, :rotary].reshape(
+        heads.shape[0], 2, rotary // 2, *w.shape[1:]).swapaxes(1, 2)
+    return np.concatenate(
+        [turned.reshape(heads.shape[0], rotary, *w.shape[1:]),
+         heads[:, rotary:]], axis=1).reshape(w.shape)
+
+
+def laguna_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
+    """The spec of a ``laguna`` config: the per-layer list of kinds and head
+    counts, each kind's RoPE from ``rope_parameters``, the gate flag, and
+    the expert layout (leading dense layers from ``mlp_layer_types``).
+    What the config has no key for is ``assumed`` (models/reference_laguna
+    .py): sigmoid scores, renormalised top-k, the gate's form."""
+    from .models.spec import (ExpertLayout, MixerKind, MixerKinds,
+                              RopeScaling, Router)
+
+    kinds = tuple("full" if t == "full_attention" else "sliding"
+                  for t in c.layer_types)
+    heads = {k: {h for h, kk in zip(c.num_attention_heads_per_layer, kinds)
+                 if kk == k} for k in ("full", "sliding")}
+    if any(len(h) > 1 for h in heads.values()):
+        raise ValueError("laguna: one head count a layer KIND is what the "
+                         f"spec holds, the config gives {heads}")
+    mlp = list(getattr(c, "mlp_layer_types", []))
+    dense = len(mlp) - len([m for m in mlp if m == "sparse"])
+    if mlp[:dense] != ["dense"] * dense:
+        raise ValueError("laguna: dense layers lead, expert layers follow")
+
+    def kind_of(name, key):
+        rp = c.rope_parameters[key]
+        n_heads = (heads[name] or {c.num_attention_heads}).pop()
+        rot = int(round(c.head_dim * float(
+            rp.get("partial_rotary_factor", 1.0))))
+        yarn = None
+        if rp.get("rope_type", "default") == "yarn":
+            import math
+
+            m = 0.1 * math.log(rp["factor"]) + 1.0
+            if abs(float(rp.get("attention_factor", m)) - m) > 1e-4:
+                raise ValueError("laguna: an attention_factor other than "
+                                 "0.1 ln(factor) + 1 has no field")
+            yarn = RopeScaling(
+                float(rp["factor"]),
+                int(rp["original_max_position_embeddings"]),
+                float(rp.get("beta_fast", 32.0)),
+                float(rp.get("beta_slow", 1.0)), 1.0, 0.0)
+        return MixerKind(n_heads, float(rp["rope_theta"]),
+                         0 if rot == c.head_dim else rot, yarn)
+
+    full = kind_of("full", "full_attention")
+    return TransformerSpec(
+        dim=c.hidden_size, hidden_dim=c.moe_intermediate_size,
+        n_layers=c.num_hidden_layers, n_heads=full.heads,
+        n_kv_heads=c.num_key_value_heads, vocab_size=c.vocab_size,
+        seq_len=seq_len, weights_float_type=target,
+        n_experts=c.num_experts, n_active_experts=c.num_experts_per_tok,
+        norm_eps=float(c.rms_norm_eps),
+        layout=ExpertLayout(dense, c.intermediate_size if dense else 0,
+                            c.shared_expert_intermediate_size
+                            // c.moe_intermediate_size),
+        router=Router("sigmoid", 1, 1, True,
+                      float(c.moe_routed_scaling_factor)),
+        mixers=MixerKinds(kinds, int(c.sliding_window), int(c.head_dim),
+                          full, kind_of("sliding", "sliding_attention"),
+                          bool(getattr(c, "gating", False))))
+
+
 def hybrid_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
     """The spec of a ``phi4flash`` config: Mamba at every ``mb_per_layer``-th
     layer up to the middle, window attention between, the full layer after
@@ -441,8 +552,11 @@ def convert_hf(model_path: str, target: str, out: str | None = None,
         _write_tensor(f, spec, "tok_embedding",
                       ckpt.tensor_by_name("tok_embedding", None, spec))
         # a latent spec's layers, of two kinds, in the file's own order
-        for i, (_, _, entries) in enumerate(
-                spec.layer_plans() if spec.planned else ()):
+        i = -1
+        for stack, _, entries in (spec.layer_plans() if spec.planned
+                                  else ()):
+            # a mixer-kinds spec has two runs a layer: its FFN's follows
+            i += not (spec.mixers and stack in ("", "dense"))
             for kind, name_, _, *e in entries:
                 arr = ckpt.tensor_by_name(name_, i, spec,
                                           e[0] if e else None)

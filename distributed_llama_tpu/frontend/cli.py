@@ -82,6 +82,27 @@ def _hybrid_line(spec, slots: int) -> str:
             f"{2 * spec.kv_dim * 4} B a position")
 
 
+def _mixers_line(spec, slots: int) -> str:
+    """What a mixer-kinds spec keeps a sequence: a startup line."""
+    mx, lay = spec.mixers, spec.layout
+    ring = mx.count("sliding") * mx.window * 2 * spec.kv_dim * 4
+    ffn = (f"{lay.dense_layers} dense + {spec.n_expert_layers} expert "
+           f"FFNs ({spec.n_experts_held} experts held, "
+           f"{spec.n_active_experts} a token, {lay.shared} shared)"
+           if spec.n_experts else "dense FFNs")
+    return (f"💡 layers: {mx.count('full')} full ({mx.full.heads} heads, "
+            f"RoPE {mx.rotary('full')} of {mx.head_size} at theta "
+            f"{mx.full.rope_theta:g}"
+            f"{', YaRN' if mx.full.rope_scaling else ''}), "
+            f"{mx.count('sliding')} sliding ({mx.sliding.heads} heads, "
+            f"window {mx.window}, theta {mx.sliding.rope_theta:g}) over "
+            f"{spec.n_kv_heads} KV heads"
+            f"{', per-head output gate' if mx.gate else ''}; {ffn}; a "
+            f"sequence keeps {ring / 2**20:.1f} MiB of window ring ({slots} "
+            f"slot{'s' if slots != 1 else ''}, fixed) and its full layers' "
+            f"K / V: {2 * mx.count('full') * spec.kv_dim * 4} B a position")
+
+
 def _latent_line(spec) -> str:
     """What a latent-attention spec caches and holds: a startup line."""
     la, lay = spec.latent, spec.layout
@@ -604,6 +625,8 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         print(_latent_line(spec))
     if spec.hybrid and not quiet:
         print(_hybrid_line(spec, rows))
+    if spec.mixers and not quiet:
+        print(_mixers_line(spec, rows))
     mesh = (make_mesh(sp=args.sp, tp=tp)
             if tp > 1 or args.sp > 1 else None)
     assumed = getattr(args, "_slice_tp_ranks", None)
@@ -1115,6 +1138,8 @@ def cmd_serve(argv: list[str]) -> int:
         print(_latent_line(spec))
     if spec.hybrid:
         print(_hybrid_line(spec, args.slots))
+    if spec.mixers:
+        print(_mixers_line(spec, args.slots))
     mesh = make_mesh(tp=args.tp) if args.tp and args.tp > 1 else None
     seed = args.seed if args.seed is not None else int(time.time())
     if journal is not None:

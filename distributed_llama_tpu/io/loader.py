@@ -372,8 +372,10 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
     add("tok_embedding", None, spec.vocab_size * spec.dim * 4)
     shapes = spec.layer_matmul_shapes()
     experts = spec.expert_matmul_shapes()
-    for layer, (_, _, entries) in enumerate(
-            spec.layer_plans() if spec.planned else ()):
+    layer = -1
+    for stack, _, entries in (spec.layer_plans() if spec.planned else ()):
+        # a mixer-kinds spec has two runs a layer: its FFN's follows
+        layer += not (spec.mixers and stack in ("", "dense"))
         for kind, name, shape, *_ in entries:
             if kind == "f32":
                 add(name, layer, 4 * int(np.prod(shape)))

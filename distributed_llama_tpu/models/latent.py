@@ -75,33 +75,42 @@ def init_cache_paged(spec: TransformerSpec, n_pages: int, page_size: int,
         (spec.n_layers, n_pages, page_size, plane_width(spec)), dtype))
 
 
-def rope_frequencies(spec: TransformerSpec):
-    """(frequencies (rope_dim / 2,) float32, cos / sin factor, attention
-    scale): plain RoPE, or YaRN's blend of f and f / factor over the
-    correction range with the scale's m^2 (the reference states both and
-    keeps its own copy: the tests hold the two together and pin the
-    published model's numbers by hand)."""
+def rope_table(rope_dim: int, theta: float, rs):
+    """(frequencies (rope_dim / 2,) float32, YaRN's m(mscale), its
+    m(mscale_all_dim)) of a RoPE over ``rope_dim`` dimensions at base
+    ``theta``: plain (``rs`` None: both m are 1), or YaRN's blend of f and
+    f / factor over the correction range."""
     import math
 
-    la, rs = spec.latent, spec.rope_scaling
-    half = la.rope_dim // 2
-    freq = np.power(float(spec.rope_theta),
-                    -np.arange(half, dtype=np.float64) / half)
-    scale = 1.0 / math.sqrt(la.qk_dim)
+    half = rope_dim // 2
+    freq = np.power(float(theta), -np.arange(half, dtype=np.float64) / half)
     if rs is None:
-        return freq.astype(np.float32), 1.0, scale
+        return freq.astype(np.float32), 1.0, 1.0
     # the pair whose wavelength makes `turns` rotations over the original
     # positions: pairs below `low` keep f, above `high` take f / factor
-    edge = [la.rope_dim * math.log(rs.original_positions / (2 * math.pi * n))
-            / (2 * math.log(spec.rope_theta))
+    edge = [rope_dim * math.log(rs.original_positions / (2 * math.pi * n))
+            / (2 * math.log(theta))
             for n in (rs.beta_fast, rs.beta_slow)]
     low = max(math.floor(edge[0]), 0)
-    high = min(math.ceil(edge[1]), la.rope_dim - 1)
+    high = min(math.ceil(edge[1]), rope_dim - 1)
     slow = np.clip((np.arange(half) - low) / (high - low or 1e-3), 0.0, 1.0)
     freq = freq * (1.0 - slow) + freq / rs.factor * slow
     m = [0.1 * a * math.log(rs.factor) + 1.0 if rs.factor > 1 else 1.0
          for a in (rs.mscale, rs.mscale_all_dim)]
-    return freq.astype(np.float32), m[0] / m[1], scale * m[1] * m[1]
+    return freq.astype(np.float32), m[0], m[1]
+
+
+def rope_frequencies(spec: TransformerSpec):
+    """(frequencies (rope_dim / 2,) float32, cos / sin factor, attention
+    scale): plain RoPE, or YaRN's blend with the scale's m^2 (the reference
+    states both and keeps its own copy: the tests hold the two together and
+    pin the published model's numbers by hand)."""
+    import math
+
+    la = spec.latent
+    freq, m, m_all = rope_table(la.rope_dim, spec.rope_theta,
+                                spec.rope_scaling)
+    return freq, m / m_all, m_all * m_all / math.sqrt(la.qk_dim)
 
 
 def _rope(x: jax.Array, positions: jax.Array, freq, factor) -> jax.Array:

@@ -64,11 +64,31 @@ def init_state(spec: TransformerSpec, batch: int | None = None) -> StateCache:
                       jnp.zeros(lead + z, jnp.float32))
 
 
-def init_cache(spec: TransformerSpec, dtype=jnp.float32):
-    if spec.hybrid:     # state, window rings and ONE layer's K / V
-        from .sambay import init_cache as init_hybrid
+def slot_model(spec: TransformerSpec):
+    """The module that runs a spec whose sequences keep a slot of fixed
+    size AND pages (``spec.slotted``): ``models/sambay`` (a hybrid spec) or
+    ``models/laguna`` (a mixer-kinds spec). Both give ``init_cache``,
+    ``init_cache_paged``, ``insert_sequence``, ``state_bytes``,
+    ``forward_batch`` and ``forward_chunk``."""
+    if spec.mixers:
+        from . import laguna
 
-        return init_hybrid(spec, dtype=dtype)
+        return laguna
+    from . import sambay
+
+    return sambay
+
+
+def slot_counts(spec: TransformerSpec) -> dict:
+    """The keyword with which a slotted spec's forwards also hand out an
+    expert spec's (L_e, E) routed-rows counts (a mixer-kinds spec's alone:
+    a hybrid spec has a dense FFN), for the engines' ``functools.partial``."""
+    return {"moe_counts": True} if spec.mixers and spec.n_experts else {}
+
+
+def init_cache(spec: TransformerSpec, dtype=jnp.float32):
+    if spec.slotted:    # state / window rings and the full layers' K / V
+        return slot_model(spec).init_cache(spec, dtype=dtype)
     if spec.retention:  # float32 whatever ``dtype``: nothing scales with S
         return init_state(spec)
     if spec.latent:     # one plane [c_kv | k_rope] in place of K and V
@@ -618,6 +638,11 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
         from .sambay import forward_sambay
 
         return forward_sambay(spec, params, cache, tokens, pos)
+    if spec.mixers:
+        from .laguna import forward_chunk
+
+        return forward_chunk(spec, params, cache, tokens, pos,
+                             moe_counts=moe_counts)
     if spec.retention:
         return forward_retention(spec, params, cache, tokens, pos)
     if spec.latent:
@@ -738,10 +763,9 @@ def init_cache_paged(spec: TransformerSpec, n_pages: int, page_size: int,
         from .latent import init_cache_paged as init_latent_paged
 
         return init_latent_paged(spec, n_pages, page_size, dtype)
-    if spec.hybrid:     # ``slots`` rows of state and ring beside the pool
-        from .sambay import init_cache_paged as init_hybrid_paged
-
-        return init_hybrid_paged(spec, slots, n_pages, page_size, dtype)
+    if spec.slotted:    # ``slots`` rows of state and ring beside the pool
+        return slot_model(spec).init_cache_paged(spec, slots, n_pages,
+                                                 page_size, dtype)
     if spec.seq_len % page_size:
         raise ValueError(f"page_size={page_size} must divide "
                          f"seq_len={spec.seq_len}")
@@ -1061,6 +1085,12 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
 
         return forward_batch_sambay(spec, params, cache, tokens, pos_vec,
                                     table, page_size=page_size)
+    if spec.mixers:
+        from .laguna import forward_batch as forward_batch_mixers
+
+        return forward_batch_mixers(spec, params, cache, tokens, pos_vec,
+                                    table, page_size=page_size,
+                                    moe_counts=moe_counts)
     B = tokens.shape[0]
     x = params["tok_embedding"][tokens].astype(jnp.float32)  # (B, dim)
     positions = pos_vec if jnp.ndim(pos_vec) == 1 else jnp.full((B,),

@@ -64,8 +64,9 @@ import jax.numpy as jnp
 
 from ..obs.spans import SCOPE_ATTN, SCOPE_EMBED, SCOPE_FFN, SCOPE_LOGITS
 from ..ops import mamba as ssm_ops
-from ..ops.linear import StackedQ40, matmul, silu
-from .spec import LAYER_KINDS, TransformerSpec
+from ..ops.linear import matmul, silu
+from .kindscan import insert_sequence, merge_lead, run_layers  # noqa: F401
+from .spec import TransformerSpec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -122,71 +123,6 @@ def state_bytes(cache: HybridCache) -> tuple[int, int]:
     """(recurrent state, window rings) resident bytes."""
     return (int(cache.conv.nbytes + cache.ssm.nbytes),
             int(cache.wk.nbytes + cache.wv.nbytes))
-
-
-def insert_sequence(cache: HybridCache, one: HybridCache, row,
-                    table: jax.Array, page_size: int) -> HybridCache:
-    """Put a sequence's cache (``init_cache(spec)``, prefilled) into row
-    ``row`` of the paged cache: its state and rings whole, its K / V page by
-    page through ``table`` (max_pages,) (entries past the sequence's pages
-    point at the scrap page)."""
-    def put(whole, part):
-        return jax.lax.dynamic_update_slice(
-            whole, part[:, None].astype(whole.dtype),
-            (0, row) + (0,) * (part.ndim - 1))
-
-    def pages(pool, seq):
-        n_kv, _, hs = seq.shape[1:]
-        paged = seq[0].reshape(n_kv, table.shape[0], page_size, hs)
-        return pool.at[0, table].set(
-            jnp.swapaxes(paged, 0, 1).astype(pool.dtype))
-
-    return HybridCache(put(cache.conv, one.conv), put(cache.ssm, one.ssm),
-                       put(cache.wk, one.wk), put(cache.wv, one.wv),
-                       pages(cache.k, one.k), pages(cache.v, one.v))
-
-
-# -- the list of kinds as scans ------------------------------------------------
-
-def segments(kinds: tuple) -> list:
-    """[(first layer, unit, repeats, {kind: its first index in its stack})]:
-    the list cut into repeating units of one or two kinds."""
-    out, i = [], 0
-    seen = {k: 0 for k in LAYER_KINDS}
-    while i < len(kinds):
-        unit, reps = kinds[i:i + 2], 1
-        if len(unit) == 2 and unit[0] != unit[1]:
-            while kinds[i + 2 * reps:i + 2 * reps + 2] == unit:
-                reps += 1
-        if len(unit) < 2 or unit[0] == unit[1] or reps < 2:
-            unit, reps = kinds[i:i + 1], 1
-            while kinds[i + reps:i + reps + 1] == unit:
-                reps += 1
-        out.append((i, unit, reps, {k: seen[k] for k in unit}))
-        for k in unit:
-            seen[k] += reps
-        i += len(unit) * reps
-    return out
-
-
-def _is_packed(v) -> bool:
-    from ..io.loader import Q40Kernel, Q40KernelNb, Q40KernelNbI4
-
-    return isinstance(v, (Q40Kernel, Q40KernelNb, Q40KernelNbI4))
-
-
-def _layer_weights(stack: dict, first: int, reps: int):
-    """A stack's leaves for layers first .. first + reps - 1: packed Q40
-    stacks stay whole outside the scan (the kernel indexes them: ops/linear
-    .StackedQ40), the rest is sliced to be scanned."""
-    packed = {k: v for k, v in stack.items() if _is_packed(v)}
-    scanned = {k: jax.tree_util.tree_map(lambda a: a[first:first + reps], v)
-               for k, v in stack.items() if k not in packed}
-    return packed, scanned
-
-
-def _view(packed: dict, sliced: dict, idx) -> dict:
-    return dict(sliced, **{k: StackedQ40(v, idx) for k, v in packed.items()})
 
 
 # -- pieces of a layer -----------------------------------------------------------
@@ -273,15 +209,16 @@ def _write_rows(plane, new, rows, cols):
     return plane
 
 
-def _attend_rows(spec, q, k_all, v_all, idx, last):
+def _attend_rows(shape, q, k_all, v_all, idx, last):
     """Row b's query over slots 0 .. last[b] of its plane idx * B + b of
-    the contiguous (rows, n, S, h) caches: the flash-decode kernel on the
-    chip, a masked einsum elsewhere."""
+    the contiguous (rows, n, S, h) caches, ``shape`` the attention's (query
+    heads, KV heads, head size): the flash-decode kernel on the chip, a
+    masked einsum elsewhere."""
     from ..ops import pallas_head_major_attention as hm
     from ..ops.pallas_attention import attn_kernel_mode
     from .llama import attention_core
 
-    n_q, n_kv, hs = pair_shape(spec)
+    n_q, n_kv, hs = shape
     B, S = q.shape[0], k_all.shape[2]
     if attn_kernel_mode() == "pallas" and hm.supports(
             S, n_kv, hs, k_all.dtype.itemsize):
@@ -295,15 +232,15 @@ def _attend_rows(spec, q, k_all, v_all, idx, last):
                           mask).reshape(B, -1)
 
 
-def _attend_pages(spec, page_size, q, k_all, v_all, pos_b, table):
+def _attend_pages(shape, page_size, q, k_all, v_all, pos_b, table):
     """Row b's query over positions 0 .. pos_b[b] of its pages in the pool
-    (pages, n, page_size, h): the paged kernel on the chip, a gather of the
-    row's virtual plane elsewhere."""
+    (pages, n, page_size, h), ``shape`` as in ``_attend_rows``: the paged
+    kernel on the chip, a gather of the row's virtual plane elsewhere."""
     from ..ops import pallas_head_major_attention as hm
     from ..ops.pallas_attention import attn_kernel_mode
     from .llama import attention_core
 
-    n_q, n_kv, hs = pair_shape(spec)
+    n_q, n_kv, hs = shape
     B = q.shape[0]
     if attn_kernel_mode() == "pallas" and hm.supports_paged(
             page_size, n_kv, hs, k_all.dtype.itemsize):
@@ -336,25 +273,12 @@ class _Carry(NamedTuple):
 
 def _run(spec, params, carry: _Carry, layer_fn, upto: int | None = None):
     """Every layer (or those before layer ``upto``) through ``layer_fn(kind,
-    lw, carry, layer, idx)``, a repeating unit of the list a scan."""
-    for first, unit, reps, start in segments(spec.hybrid.kinds):
-        if upto is not None and first >= upto:
-            break
-        split = {k: _layer_weights(params[k], start[k], reps) for k in unit}
-
-        def body(carry, per, first=first, unit=unit, start=start,
-                 split=split):
-            j, sliced = per
-            for u, kind in enumerate(unit):
-                lw = _view(split[kind][0], sliced[kind], start[kind] + j)
-                carry = layer_fn(kind, lw, carry,
-                                 first + j * len(unit) + u, start[kind] + j)
-            return carry, None
-
-        carry, _ = jax.lax.scan(
-            body, carry, (jnp.arange(reps, dtype=jnp.int32),
-                          {k: split[k][1] for k in unit}))
-    return carry
+    lw, carry, layer, idx)``, a repeating unit of the list a scan
+    (``models/kindscan.py``: a layer's one stack is its kind's)."""
+    return run_layers(
+        [(k,) for k in spec.hybrid.kinds], params.__getitem__, carry,
+        lambda sig, lw, c, layer, idx: layer_fn(sig[0], lw, c, layer,
+                                                idx[sig[0]]), upto)
 
 
 def _logits(spec, params, x):
@@ -362,10 +286,6 @@ def _logits(spec, params, x):
         x = layernorm(x, params["rms_final"], params["rms_final_b"],
                       spec.norm_eps)
         return matmul(params["wcls"], x)
-
-
-def _merge(a, n_lead: int):
-    return a.reshape(-1, *a.shape[n_lead:])
 
 
 # -- the decode step ---------------------------------------------------------------
@@ -395,6 +315,7 @@ def forward_batch_sambay(spec: TransformerSpec, params: dict[str, Any],
     paged = table is not None
     x = params["tok_embedding"][tokens].astype(jnp.float32)
     dt = cache.k.dtype
+    shape = pair_shape(spec)
 
     def layer_fn(kind, lw, c: _Carry, layer, idx):
         u = layernorm(c.x, lw["ln1_g"], lw["ln1_b"], spec.norm_eps)
@@ -435,7 +356,7 @@ def forward_batch_sambay(spec: TransformerSpec, params: dict[str, Any],
                     wk = _write_rows(c.wk, k, idx * B + rows, pos_b % W)
                     wv = _write_rows(c.wv, v, idx * B + rows, pos_b % W)
                     c = c._replace(wk=wk, wv=wv)
-                    ao = _attend_rows(spec, q, wk, wv, idx,
+                    ao = _attend_rows(shape, q, wk, wv, idx,
                                       jnp.minimum(pos_b, W - 1))
                 elif paged:
                     if kind == "full":
@@ -445,21 +366,22 @@ def forward_batch_sambay(spec: TransformerSpec, params: dict[str, Any],
                         c = c._replace(
                             k=_write_rows(c.k, k, page, pos_b % page_size),
                             v=_write_rows(c.v, v, page, pos_b % page_size))
-                    ao = _attend_pages(spec, page_size, q, c.k, c.v, pos_b,
+                    ao = _attend_pages(shape, page_size, q, c.k, c.v, pos_b,
                                        table)
                 else:
                     if kind == "full":
                         c = c._replace(k=_write_rows(c.k, k, rows, pos_b),
                                        v=_write_rows(c.v, v, rows, pos_b))
-                    ao = _attend_rows(spec, q, c.k, c.v, 0, pos_b)
+                    ao = _attend_rows(shape, q, c.k, c.v, 0, pos_b)
                 mix = matmul(lw["wo"], diff_combine(spec, lw, layer, ao)) \
                     + lw["bo"]
         return c._replace(x=_ffn(spec, lw, c.x + mix))
 
     carry = _Carry(x, jnp.zeros((B, hy.d_inner), jnp.float32),
-                   _merge(cache.conv, 2), _merge(cache.ssm, 2),
-                   _merge(cache.wk, 2), _merge(cache.wv, 2),
-                   _merge(cache.k, 2), _merge(cache.v, 2), jnp.float32(1.0))
+                   merge_lead(cache.conv, 2), merge_lead(cache.ssm, 2),
+                   merge_lead(cache.wk, 2), merge_lead(cache.wv, 2),
+                   merge_lead(cache.k, 2), merge_lead(cache.v, 2),
+                   jnp.float32(1.0))
     carry = _run(spec, params, carry, layer_fn)
     logits = _logits(spec, params, carry.x)
     out = HybridCache(*(new.reshape(old.shape) for new, old in zip(
@@ -474,6 +396,25 @@ def _ring_positions(W: int, pos):
     ``pos`` (negative: none yet)."""
     s = jnp.arange(W)
     return pos - 1 - (pos - 1 - s) % W
+
+
+def ring_plan(W: int, pos, n_valid, T: int):
+    """What a chunk of T positions from ``pos`` (the first ``n_valid`` the
+    sequence's) reads of a window layer and leaves in its ring: the (T, W +
+    T) mask over [ring as it stands | the chunk's own keys], and (from_chunk
+    (1, W, 1), take (W,)): slot s takes the chunk's row ``take[s]``, the
+    newest valid position congruent to s, where ``from_chunk`` says the
+    chunk has one."""
+    positions = pos + jnp.arange(T)
+    ring_pos = _ring_positions(W, pos)
+    see_ring = (ring_pos[None, :] >= 0) & (
+        positions[:, None] - ring_pos[None, :] < W)
+    t = jnp.arange(T)
+    see_own = (t[None, :] <= t[:, None]) & (t[:, None] - t[None, :] < W)
+    last = pos + n_valid - 1
+    newest = last - (last - jnp.arange(W)) % W
+    return (jnp.concatenate([see_ring, see_own], axis=1),
+            (newest >= pos)[None, :, None], jnp.clip(newest - pos, 0, T - 1))
 
 
 def forward_sambay(spec: TransformerSpec, params: dict[str, Any],
@@ -511,19 +452,9 @@ def forward_sambay(spec: TransformerSpec, params: dict[str, Any],
     dt = cache.k.dtype
     with jax.named_scope(SCOPE_EMBED):
         x = params["tok_embedding"][tokens].astype(jnp.float32)
-    # a window layer's keys: the ring as it stands, then the chunk's own
-    ring_pos = _ring_positions(W, pos)
-    see_ring = (ring_pos[None, :] >= 0) & (
-        positions[:, None] - ring_pos[None, :] < W)
-    t = jnp.arange(T)
-    see_own = (t[None, :] <= t[:, None]) & (t[:, None] - t[None, :] < W)
-    win_mask = jnp.concatenate([see_ring, see_own], axis=1)
-    # ... and the ring after it: slot s takes the newest valid position
-    # congruent to s, if the chunk has one
-    last = pos + n_valid - 1
-    newest = last - (last - jnp.arange(W)) % W
-    from_chunk = (newest >= pos)[None, :, None]
-    take = jnp.clip(newest - pos, 0, T - 1)
+    # a window layer's keys (the ring as it stands, then the chunk's own)
+    # and the ring after it
+    win_mask, from_chunk, take = ring_plan(W, pos, n_valid, T)
     kv_at = jnp.where(valid, positions, S)     # padding is dropped
     heads_first = lambda a: jnp.swapaxes(a, 0, 1)  # noqa: E731
 
@@ -600,3 +531,8 @@ def forward_sambay(spec: TransformerSpec, params: dict[str, Any],
     else:
         logits = jnp.zeros((0, spec.vocab_size), jnp.float32)
     return (logits, out, carry.low[None]) if health else (logits, out)
+
+
+# the names ``models/llama.slot_model`` gives both slot-and-pages forwards
+forward_batch = forward_batch_sambay
+forward_chunk = forward_sambay

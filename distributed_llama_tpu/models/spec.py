@@ -109,6 +109,28 @@ before its matmul tensors, six float32 tensors, three a sub-layer
              row-major)], one row an output as every matmul weight here),
   hc_att_gate (3: a_pre, a_post, a_res), hc_att_bias (2 n + n^2: b_pre,
              b_post, B_res row-major), hc_ffn_phi, hc_ffn_gate, hc_ffn_bias
+
+Extension VERSION 7 (version 4's values, then nine ints {window, headSize,
+gate, fullHeads, slidingHeads, fullRotary, slidingRotary, fullScaled,
+slidingScaled}, fourteen float64 {fullTheta, slidingTheta,
+then each kind's YaRN six: factor, originalPositions, betaFast, betaSlow,
+mscale, mscaleAllDim} and 128 bytes, one a layer: its index into
+``MIXER_KINDS``, 255 past the last layer) is written only by a spec that
+sets ``mixers`` (``MixerKinds``: a per-layer list of grouped-query attention
+kinds, each with a head count and a RoPE of its own, COMBINED with version
+4's ``ExpertLayout`` / ``Router``), so files of every earlier version read
+and write byte for byte. Such a file has no rope gap, and a layer is two
+runs of tensors, its mixer's (``params[kind]``: a stack a kind, so ``wq`` /
+``wo`` may differ in shape by kind) and then its FFN's (the leading dense
+layers' under ``params["dense"]``, the others' the top-level stacks, as in
+version 4):
+
+  attention_norm (F32 dim), wq (heads_k head x dim), wk, wv (kvHeads head x
+  dim), wo (dim x heads_k head)          [weightsFloatType]
+  [w_hgate (F32, heads_k x dim)           -- only if gate]
+  ffn_norm (F32 dim), then a dense layer's w1, w2, w3 at denseHidden, or an
+  expert layer's router ... experts as in version 4 (or, with no experts,
+  w1, w2, w3 at hiddenDim)
 """
 
 from __future__ import annotations
@@ -132,7 +154,9 @@ EXT5_VERSION = 5
 EXT5_STRUCT = struct.Struct("<14i2d16i7d8i128B")
 EXT6_VERSION = 6
 EXT6_STRUCT = struct.Struct("<14i2d16i7d4i3d")
-MAX_HEADER_BYTES = EXT5_STRUCT.size
+EXT7_VERSION = 7
+EXT7_STRUCT = struct.Struct("<14i2d16i7d9i14d128B")
+MAX_HEADER_BYTES = EXT7_STRUCT.size
 HC_SUBLAYERS = ("att", "ffn")
 ATTN_KINDS = ("softmax", "retention")
 # what a layer of a ``HybridLayers`` spec mixes with, and what it caches for
@@ -143,6 +167,9 @@ LAYER_KINDS = ("mamba", "swa", "full", "gmu", "xattn")
 CACHE_OF_KIND = {"mamba": "state", "swa": "window", "full": "pages",
                  "gmu": None, "xattn": None}
 ROUTER_SCORINGS = ("softmax", "sigmoid")
+# what a layer of a ``MixerKinds`` spec attends over: every position (K / V
+# of its own, in pages under ``serve``) or the last ``window`` (a ring)
+MIXER_KINDS = ("full", "sliding")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -264,6 +291,46 @@ class HyperConnections:
         return self.streams * (2 + self.streams)
 
 
+@dataclasses.dataclass(frozen=True)
+class MixerKind:
+    """One kind of grouped-query attention layer: its query heads (over the
+    spec's ``n_kv_heads``) and its RoPE: base, how many leading dimensions
+    of a head it rotates (0: all of them), and YaRN or none."""
+    heads: int
+    rope_theta: float = 10000.0
+    rotary_dim: int = 0
+    rope_scaling: RopeScaling | None = None
+
+
+@dataclasses.dataclass(frozen=True)
+class MixerKinds:
+    """A per-layer list of grouped-query softmax attention kinds (Laguna's
+    layout; models/laguna.py runs it, models/reference_laguna.py states
+    it). ``kinds[i]`` is layer i's mixer: "full" (causal over every
+    position, K / V of ITS OWN) or "sliding" (the last ``window``
+    positions, the current one included). A kind has its own head count
+    and RoPE; the head SIZE is one, stated here (``dim // n_heads`` says
+    nothing where the head count is a kind's). ``gate``: each head's output
+    is multiplied by a sigmoid of the normed layer input (``w_hgate``)
+    before ``wo``. The FFN of a layer is ``TransformerSpec.layout``'s and
+    ``router``'s, as for a latent spec."""
+    kinds: tuple
+    window: int
+    head_size: int
+    full: MixerKind
+    sliding: MixerKind
+    gate: bool = False
+
+    def count(self, kind: str) -> int:
+        return sum(k == kind for k in self.kinds)
+
+    def of(self, kind: str) -> MixerKind:
+        return self.full if kind == "full" else self.sliding
+
+    def rotary(self, kind: str) -> int:
+        return self.of(kind).rotary_dim or self.head_size
+
+
 def sambay_kinds(n_layers: int) -> tuple:
     """The published pattern at ``n_layers`` (even, >= 8), L/2 = h: Mamba at
     even i <= h, window attention at odd i < h, the full layer at h + 1,
@@ -310,10 +377,16 @@ class TransformerSpec:
     # header version 6: a residual path of more than one stream (None: the
     # plain ``x + F(x)``)
     hyper: HyperConnections | None = None
+    # header version 7: a per-layer list of grouped-query attention kinds,
+    # each with a head count and RoPE of its own, beside ``layout`` and
+    # ``router`` (None: every layer is what the fields above say)
+    mixers: MixerKinds | None = None
 
     def __post_init__(self):
         if self.hybrid is not None:
             self._check_hybrid()
+        if self.mixers is not None:
+            self._check_mixers()
         if self.hyper is not None and (
                 not self.latent or self.hyper.streams < 2
                 or self.hyper.sinkhorn_iters < 1
@@ -352,11 +425,14 @@ class TransformerSpec:
                             or self.latent.rope_dim % 2):
             raise ValueError("a latent-attention spec is softmax attention "
                              "without q/k-norm, with an even rope_dim")
-        if not self.latent and (lay != ExpertLayout() or self.rope_scaling
-                                or self.router != Router()):
+        if not (self.latent or self.mixers) and (
+                lay != ExpertLayout() or self.rope_scaling
+                or self.router != Router()):
             raise ValueError("an expert layout, a router kind and a RoPE "
                              "scaling are run by the latent-attention "
-                             "forward only (models/latent.py): set latent")
+                             "forward (models/latent.py) and the mixer-"
+                             "kinds forward (models/laguna.py) only: set "
+                             "latent or mixers")
         if self.latent and not self.n_experts:
             raise ValueError("a latent-attention spec has expert layers "
                              "after its leading dense ones: set n_experts")
@@ -366,6 +442,32 @@ class TransformerSpec:
                 f"n_experts={self.n_experts} / n_active_experts="
                 f"{self.n_active_experts}: both 0 (dense FFN) or "
                 f"0 < active <= experts")
+
+    def _check_mixers(self) -> None:
+        mx = self.mixers
+        kinds = tuple(mx.kinds)
+        if (len(kinds) != self.n_layers or len(kinds) > 128
+                or any(k not in MIXER_KINDS for k in kinds)):
+            raise ValueError(f"mixers.kinds: one of {MIXER_KINDS} for each "
+                             f"of n_layers={self.n_layers} (at most 128)")
+        if (self.hybrid or self.latent or self.hyper or self.retention
+                or self.qk_norm or self.rope_scaling):
+            raise ValueError("a mixer-kinds spec is softmax grouped-query "
+                             "attention without q/k-norm: its RoPE and "
+                             "scaling are a kind's (MixerKind), and it "
+                             "combines with layout / router only")
+        for kind in MIXER_KINDS:
+            k = mx.of(kind)
+            rot = mx.rotary(kind)
+            if (k.heads < 1 or k.heads % self.n_kv_heads or rot % 2
+                    or not 0 < rot <= mx.head_size):
+                raise ValueError(
+                    f"mixers.{kind}: {k.heads} heads must be a multiple of "
+                    f"n_kv_heads={self.n_kv_heads}, and its rotary_dim even "
+                    f"and at most head_size={mx.head_size}")
+        if mx.window < 1 or mx.head_size < 2 or self.n_heads != mx.full.heads:
+            raise ValueError("mixers: a positive window and head_size, and "
+                             "n_heads the full kind's head count")
 
     def _check_hybrid(self) -> None:
         hy = self.hybrid
@@ -396,7 +498,7 @@ class TransformerSpec:
     def planned(self) -> bool:
         """Whether ``layer_plans`` (and not the one-kind walk) says the
         file's layers."""
-        return bool(self.latent or self.hybrid)
+        return bool(self.latent or self.hybrid or self.mixers)
 
     @property
     def retention(self) -> bool:
@@ -407,12 +509,21 @@ class TransformerSpec:
     def stateful(self) -> bool:
         """Whether a sequence keeps something that a step rewrites and that
         cannot be rewound (a recurrent state, a window ring)."""
-        return bool(self.retention or self.hybrid)
+        return bool(self.retention or self.hybrid or self.mixers)
+
+    @property
+    def slotted(self) -> bool:
+        """Whether a sequence keeps a slot of fixed size AND pages (a
+        hybrid spec's, a mixer-kinds spec's): what ``models/llama.
+        slot_model`` runs."""
+        return bool(self.hybrid or self.mixers)
 
     @property
     def header_version(self) -> int:
-        """0 (the 28-byte header), 2, 3, 4, 5 or 6: the lowest that holds
-        the spec."""
+        """0 (the 28-byte header), 2, 3, 4, 5, 6 or 7: the lowest that
+        holds the spec."""
+        if self.mixers:
+            return EXT7_VERSION
         if self.hyper:
             return EXT6_VERSION
         if self.hybrid:
@@ -436,11 +547,15 @@ class TransformerSpec:
                 EXT3_VERSION: EXT3_STRUCT.size,
                 EXT4_VERSION: EXT4_STRUCT.size,
                 EXT5_VERSION: EXT5_STRUCT.size,
-                EXT6_VERSION: EXT6_STRUCT.size}[self.header_version]
+                EXT6_VERSION: EXT6_STRUCT.size,
+                EXT7_VERSION: EXT7_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
-        """A q / k head: derived, unless a latent spec states it."""
+        """A q / k head: derived, unless a latent or a mixer-kinds spec
+        states it."""
+        if self.mixers:
+            return self.mixers.head_size
         return self.latent.qk_dim if self.latent else self.dim // self.n_heads
 
     @property
@@ -467,6 +582,8 @@ class TransformerSpec:
 
     @property
     def kv_dim(self) -> int:
+        if self.mixers:
+            return self.n_kv_heads * self.mixers.head_size
         return (self.dim * self.n_kv_heads) // self.n_heads
 
     @property
@@ -486,7 +603,8 @@ class TransformerSpec:
                       (EXT3_VERSION, 13): EXT3_STRUCT,
                       (EXT4_VERSION, 29): EXT4_STRUCT,
                       (EXT5_VERSION, 165): EXT5_STRUCT,
-                      (EXT6_VERSION, 36): EXT6_STRUCT}.get((version, count))
+                      (EXT6_VERSION, 36): EXT6_STRUCT,
+                      (EXT7_VERSION, 187): EXT7_STRUCT}.get((version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
@@ -496,6 +614,9 @@ class TransformerSpec:
             base, ext = ints[:7], ints[7:10]
             if version == EXT5_VERSION:
                 more = _read_ext5(ints[36:], base[2])
+                ints = ints[:36]
+            if version == EXT7_VERSION:
+                more = _read_ext7(ints[36:], base[2])
                 ints = ints[:36]
             if version == EXT6_VERSION:
                 streams, iters, _, _, eps, lo, hi = ints[36:]
@@ -554,6 +675,22 @@ class TransformerSpec:
             return EXT6_STRUCT.pack(
                 EXT_MAGIC, EXT6_VERSION, 36, *v3, *v4, hc.streams,
                 hc.sinkhorn_iters, 0, 0, hc.eps, hc.clamp_min, hc.clamp_max)
+        if self.header_version == EXT7_VERSION:
+            mx = self.mixers
+            kinds = [MIXER_KINDS.index(k) for k in mx.kinds]
+            yarn = []
+            for k in (mx.full, mx.sliding):
+                r = k.rope_scaling or RopeScaling(0.0, 0)
+                yarn += [r.factor, float(r.original_positions), r.beta_fast,
+                         r.beta_slow, r.mscale, r.mscale_all_dim]
+            return EXT7_STRUCT.pack(
+                EXT_MAGIC, EXT7_VERSION, 187, *v3, *v4, mx.window,
+                mx.head_size, int(mx.gate), mx.full.heads, mx.sliding.heads,
+                mx.full.rotary_dim, mx.sliding.rotary_dim,
+                int(mx.full.rope_scaling is not None),
+                int(mx.sliding.rope_scaling is not None),
+                mx.full.rope_theta, mx.sliding.rope_theta, *yarn,
+                *kinds, *([255] * (128 - len(kinds))))
         hy = self.hybrid
         kinds = [LAYER_KINDS.index(k) for k in hy.kinds]
         return EXT5_STRUCT.pack(
@@ -568,11 +705,13 @@ class TransformerSpec:
         order: an expert spec has the four attention tensors here and its
         FFN under ``expert_matmul_shapes``."""
         d, h, kv = self.dim, self.hidden_dim, self.kv_dim
-        if self.hybrid:     # every distinct matmul tensor of any kind, once
+        if self.hybrid or self.mixers:
+            # every distinct matmul tensor of any kind, once (a routed
+            # expert's are ``expert_matmul_shapes``)
             seen = {}
             for _, _, entries in self.layer_plans():
                 seen.update({(e[1], e[2]): None for e in entries
-                             if e[0] == "mm"})
+                             if e[0] == "mm" and len(e) == 3})
             return list(seen)
         attn = [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
                 ("wo", (d, d))]
@@ -593,8 +732,8 @@ class TransformerSpec:
         """A LEADING DENSE layer's matmul tensors of an expert spec whose
         layout has some (empty otherwise): the attention tensors and a
         SwiGLU of ``layout.dense_hidden``."""
-        if not self.layout.dense_layers:
-            return []
+        if not self.layout.dense_layers or self.mixers:
+            return []   # a mixer-kinds spec's are among its distinct ones
         d, h = self.dim, self.layout.dense_hidden
         n_attn = 5 if self.latent else 4
         return self.layer_matmul_shapes()[:n_attn] + [
@@ -654,6 +793,8 @@ class TransformerSpec:
         pattern can take this list's place."""
         if self.hybrid:
             return self._hybrid_plans()
+        if self.mixers:
+            return self._mixer_plans()
         norms = [("f32", n, (w,)) for n, w in self.layer_norm_shapes()]
         norms += [("f32", n, s) for n, s in self.hyper_shapes()]
         dense = norms + [("mm", n, s)
@@ -711,6 +852,45 @@ class TransformerSpec:
             seen[kind] = seen.get(kind, 0) + 1
         return plans
 
+    def _mixer_plans(self):
+        """``layer_plans`` of a mixer-kinds spec: TWO entries a layer, its
+        mixer's (``stack`` the kind, ``index`` its place among the layers
+        of that kind) and then its FFN's (``stack`` "dense" or "", as for
+        a latent spec's two kinds of FFN)."""
+        mx, d, h = self.mixers, self.dim, self.hidden_dim
+        kv, lay = self.kv_dim, self.layout
+        f, m = (lambda n, *s: ("f32", n, s)), (lambda n, *s: ("mm", n, s))
+        ffn_dense = [f("rms_ffn", d), m("w1", lay.dense_hidden, d),
+                     m("w2", d, lay.dense_hidden),
+                     m("w3", lay.dense_hidden, d)]
+        ffn = [f("rms_ffn", d)]
+        if self.n_experts:
+            ffn.append(f("moe_gate", self.n_experts, d))
+            if self.router.bias:
+                ffn.append(f("moe_bias", self.n_experts))
+            sh = lay.shared * h
+            if sh:
+                ffn += [m("sh_w1", sh, d), m("sh_w2", d, sh),
+                        m("sh_w3", sh, d)]
+            ffn += [("mm", n, s, e) for e in range(self.n_experts_held)
+                    for n, s in self.expert_matmul_shapes()]
+        else:
+            ffn += [m("w1", h, d), m("w2", d, h), m("w3", h, d)]
+        seen = {k: 0 for k in MIXER_KINDS}
+        plans = []
+        for i, kind in enumerate(mx.kinds):
+            q = mx.of(kind).heads * mx.head_size
+            mixer = [f("rms_att", d), m("wq", q, d), m("wk", kv, d),
+                     m("wv", kv, d), m("wo", d, q)]
+            if mx.gate:
+                mixer.append(f("w_hgate", mx.of(kind).heads, d))
+            plans.append((kind, seen[kind], mixer))
+            seen[kind] += 1
+            k = lay.dense_layers
+            plans.append(("dense", i, ffn_dense) if i < k
+                         else ("", i - k, ffn))
+        return plans
+
     def stack_leaves(self):
         """(stack, name, kind, stacked shape) of every leaf of the two layer
         stacks ``layer_plans`` walks, once each: the leading axis counts the
@@ -718,6 +898,10 @@ class TransformerSpec:
         depth = {"dense": self.n_dense_layers, "": self.n_expert_layers}
         if self.hybrid:
             depth = {k: self.hybrid.count(k) for k in LAYER_KINDS}
+        if self.mixers:
+            depth = {"dense": self.n_dense_layers,
+                     "": self.n_layers - self.n_dense_layers,
+                     **{k: self.mixers.count(k) for k in MIXER_KINDS}}
         seen, out = set(), []
         for stack, _, entries in self.layer_plans():
             for kind, name, shape, *e in entries:
@@ -773,6 +957,28 @@ class TransformerSpec:
         b += self.rope_gap_bytes
         b += self.matmul_bytes((self.vocab_size, self.dim))  # wcls
         return b
+
+
+def _read_ext7(vals, n_layers: int) -> dict:
+    """``mixers`` from a version-7 header's nine ints, fourteen float64
+    and 128 bytes."""
+    (window, head, gate, f_heads, s_heads, f_rot, s_rot, f_scaled, s_scaled,
+     f_theta, s_theta, *rest) = vals
+    yarn, kinds = rest[:12], rest[12:][:n_layers]
+    if not 0 < n_layers <= 128 or any(k >= len(MIXER_KINDS) for k in kinds):
+        raise ValueError("unknown layer kind in a version-7 header")
+
+    def scaling(on, six):
+        factor, orig, *more = six
+        return RopeScaling(float(factor), int(orig),
+                           *(float(x) for x in more)) if on else None
+
+    return dict(mixers=MixerKinds(
+        tuple(MIXER_KINDS[k] for k in kinds), window, head,
+        MixerKind(f_heads, float(f_theta), f_rot,
+                  scaling(f_scaled, yarn[:6])),
+        MixerKind(s_heads, float(s_theta), s_rot,
+                  scaling(s_scaled, yarn[6:])), bool(gate)))
 
 
 def _read_ext5(vals, n_layers: int) -> dict:

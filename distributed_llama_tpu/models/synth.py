@@ -117,7 +117,9 @@ def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
         elif name.startswith("hc_"):
             dst[name] = hyper_leaf(spec, name, shape,
                                    lambda *s: t(*s) * np.float32(20.0))
-        elif name == "moe_gate":
+        elif name in ("moe_gate", "w_hgate"):
+            # rows ~N(0, 1/sqrt(dim)): a head's gate sigmoid(.) then
+            # spreads around 0.5 and no seeded head is shut or idle
             dst[name] = t(*shape) * np.float32(20.0 / np.sqrt(spec.dim))
         elif name == "moe_bias":
             dst[name] = t(*shape)
@@ -341,7 +343,7 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
                     f.write(memoryview(np.ascontiguousarray(hyper_leaf(
                         spec, name, shape, lambda *s: rng.standard_normal(
                             s, dtype=np.float32)))).cast("B"))
-                elif name == "moe_gate":
+                elif name in ("moe_gate", "w_hgate"):
                     f.write(f32(*shape, scale=1.0 / np.sqrt(spec.dim)))
                 elif name == "moe_bias":
                     f.write(f32(*shape, scale=0.05))

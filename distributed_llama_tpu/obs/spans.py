@@ -48,6 +48,13 @@ SCOPE_MOE_EXPERTS = "moe.experts"  # slot building, expert kernels, combine
 # SCOPE_ATTN / SCOPE_FFN: a sub-layer's coefficients, and its two mixes)
 SCOPE_HC_COEF = "hc.coef"   # flat norm, projection, sigmoids, Sinkhorn
 SCOPE_HC_MIX = "hc.mix"     # the sub-layer's input and the streams' update
+# a mixer-kinds spec's (models/laguna.py opens them inside SCOPE_ATTN): the
+# per-head output gate, and each kind's own RoPE
+SCOPE_ATTN_GATE = "attn.gate"
+
+
+def scope_rope(kind: str) -> str:
+    return f"rope.{kind}"
 
 # collective scopes: one per _ici_* helper, named after the helper so a
 # trace event inside e.g. `ici_all_gather` is attributable to the exact

@@ -313,6 +313,16 @@ class EngineMetrics:
             "Mamba layer took in a decode step: near zero, a state forgets "
             "everything in one token")
         self._hybrid_seen = [0, 0, 0, 0]
+        # a mixer-kinds spec's (ContinuousStats.gate_min and below): its
+        # rings, full layers' pages and the positions its decode steps
+        # read are the hybrid gauges and counters above
+        self.attn_gate_min = g(
+            "dllama_attn_gate_min",
+            "Smallest per-head output gate any decode step applied over "
+            "its layers, active rows and heads: near zero, a head is shut")
+        self.attn_gate_mean = g(
+            "dllama_attn_gate_mean",
+            "Mean per-head output gate over the decode steps so far")
         # cost-ledger / scheduler-census series (ISSUE 16). The closed
         # vocabularies (token kinds, stall causes) pre-register so a
         # fresh scrape shows the full matrix at zero; per-class series
@@ -572,6 +582,9 @@ class EngineMetrics:
         self._hybrid_seen = now
         self.shared_kv_pages.set(st.shared_kv_pages)
         self.ssm_min_decay.set(st.ssm_min_decay)
+        if st.gate_steps:
+            self.attn_gate_min.set(st.gate_min)
+            self.attn_gate_mean.set(st.gate_mean)
 
     def record_retire(self, req, now: float) -> None:
         """Derive the lifecycle histograms at retirement. Cancelled and

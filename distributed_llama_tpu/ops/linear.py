@@ -207,6 +207,14 @@ MOE_TP_REFUSAL = (
     "(or any sharded mesh) refuses them")
 
 
+MIXERS_TP_REFUSAL = (
+    "a mixer-kinds model (window and full grouped-query attention layers, "
+    "each kind with its own head count; models/laguna.py) runs on one chip "
+    "only: neither its rings, its two shapes of wq / wo nor its full "
+    "layers' pools are placed over tensor-parallel ranks, so --tp > 1 (or "
+    "any sharded mesh) refuses it")
+
+
 def q40_leaf_layout(d: int, nb: int, *, tp: int = 1,
                     layout: Q40Layout = Q40_STOCK,
                     allow_nb_major: bool = True, key: str = "") -> str:
@@ -391,6 +399,8 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     nb = 344, is placed transposed by the device client and copied
     row-major inside every step).
 
+    A slot-and-pages spec (``spec.slotted``) gets ``nb-major`` where any of
+    its leaves has a block count off the 128 grid, else the stock picks.
     An expert spec gets ``nb-major`` (every dense leaf the row tiler
     places forced nb-major, u8 bodies) where the stock picks would leave
     one of its dense leaves d-major at an ``nb`` off the 128 grid. Else
@@ -411,17 +421,26 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
     from .pallas_q40 import _pick_rows_nb
 
     del rows  # every dispatch width has an nb-major kernel
-    if spec.hybrid:
-        # nb is 80, 160 or 320 at the published widths, all off the 128
-        # grid (a d-major leaf would be stored transposed and copied every
-        # step: see the expert case below), and the i4 body runs in fused
-        # chains only, which a state refuses
-        return Q40Layout("nb-major", (
-            "hybrid spec: every leaf the row tiler places packs nb-major, "
-            "u8 bodies (block counts off the 128 grid)"))
     counted = spec.matmul_shape_counts()     # a layer's, experts included
     shapes = [shape for shape, _ in counted]
     shapes.append((spec.vocab_size, spec.dim))  # wcls
+    if spec.slotted:
+        # the i4 body runs in fused chains only, which a slot's state
+        # refuses: a slot-and-pages spec packs u8 bodies, and ONE layout,
+        # nb-major, as soon as a leaf has a block count off the 128 grid (a
+        # d-major leaf there would be stored transposed and copied every
+        # step: see the expert case below). 80, 160 and 320 blocks a row at
+        # a hybrid spec's published widths; 16, 64 and 192 beside 256 at a
+        # mixer-kinds spec's
+        off = sorted({n // 32 for _, n in shapes if (n // 32) % 128})
+        if off:
+            return Q40Layout("nb-major", (
+                f"slot-and-pages spec with leaves of {off} blocks a row, "
+                f"off the 128 grid: every leaf the row tiler places packs "
+                f"nb-major, u8 bodies"))
+        return Q40Layout("d-major", (
+            "slot-and-pages spec, every block count on the 128 grid: the "
+            "stock picks, u8 bodies"))
     if spec.n_experts:
         # no expert kernel has an i4 body, so an expert spec's label says
         # only how its DENSE leaves pack. The stock picks leave a leaf

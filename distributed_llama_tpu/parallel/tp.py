@@ -178,6 +178,10 @@ def param_specs(params: dict[str, Any],
         from ..ops.mamba import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)      # nor a hybrid spec's slot
+    if "sliding" in params:
+        from ..ops.linear import MIXERS_TP_REFUSAL
+
+        raise ValueError(MIXERS_TP_REFUSAL)   # nor a mixer-kinds spec's
     specs: dict[str, Any] = {}
     for name, val in params.items():
         spec = _MATMUL_SPECS.get(name) or _REPL_SPECS.get(name)
@@ -750,6 +754,10 @@ def validate_sharding(spec: TransformerSpec, mesh: Mesh,
         from ..ops.mamba import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)
+    if spec.mixers:
+        from ..ops.linear import MIXERS_TP_REFUSAL
+
+        raise ValueError(MIXERS_TP_REFUSAL)
     if spec.header_version == 3:
         raise ValueError(
             "the sharded forward runs RoPE base 10000, RMSNorm eps 1e-5 "

@@ -195,9 +195,13 @@ def sequence_caches(spec) -> frozenset:
     every position, which page), "plane" (a latent spec's one plane a
     position, behind the same page tables) and "state" (a slot of fixed
     size that a step rewrites: a recurrent state, a window ring). A hybrid
-    spec keeps a state AND one layer's pages. "streams" rides along where
-    the residual path is several streams (``spec.hyper``): nothing a
-    sequence caches, but the list below names it in its reasons."""
+    spec keeps a state AND one layer's pages, a mixer-kinds spec rings AND
+    its full layers' pages ("rings" rides along: its slot is window rings
+    alone). "streams" rides along where the residual path is several
+    streams (``spec.hyper``): nothing a sequence caches, but the list below
+    names it in its reasons."""
+    if spec.mixers:
+        return frozenset({"state", "pages", "rings"})
     if spec.hybrid:
         return frozenset({"state", "pages"})
     if spec.retention:
@@ -218,6 +222,10 @@ _WHY = {
     frozenset({"state", "pages"}): "a hybrid model keeps a recurrent state "
                                    "and a window ring of fixed size beside "
                                    "one layer's KV pages",
+    frozenset({"state", "pages", "rings"}): "a mixer-kinds model keeps a "
+                                            "window ring of fixed size a "
+                                            "sliding layer beside its "
+                                            "full layers' KV pages",
 }
 
 
@@ -254,7 +262,11 @@ def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
             out.append(f"{flag}: {why}; {reason}")
 
     if tp > 1:
-        if state:
+        if "rings" in caches:
+            out.append(f"--tp {tp}: {why}; neither the rings, the kinds' "
+                       f"head counts nor the experts held here are placed "
+                       f"over tensor-parallel ranks")
+        elif state:
             from ..ops import mamba, retention
 
             out.append(f"--tp {tp}: "
@@ -514,6 +526,16 @@ class ContinuousStats:
     prompt_positions: int = 0
     xdec_positions: int = 0
     ssm_min_decay: float = 1.0
+    # a mixer-kinds spec (its rings are ``window_bytes``, its full layers'
+    # pool pages ``shared_kv_pages``, and ``window_kv_positions`` /
+    # ``shared_kv_positions`` count ONE sliding / ONE full layer's reads):
+    # the smallest value any decode step's per-head output gate took over
+    # its layers, active rows and heads, and the steps' mean gate summed
+    # (``gate_mean`` divides by ``gate_steps``): a gate near 0 shuts a
+    # head, and a mean near 0 or 1 says the seeded gates saturate
+    gate_min: float = 1.0
+    gate_mean_sum: float = 0.0
+    gate_steps: int = 0
     # the admission account, kept for every landed dispatch, dark or not,
     # on time.monotonic. ``land_s``: the sum of the landing intervals (a
     # step run ahead: landing to landing, which with the device never idle
@@ -579,6 +601,16 @@ class ContinuousStats:
         self.moe_single_row_slots += slots[1]
         load = counts.sum(axis=0, dtype=np.int64)
         self.moe_load = load if self.moe_load is None else self.moe_load + load
+
+    def count_gate(self, low: float, mean: float) -> None:
+        """One decode step's smallest and mean per-head gate value."""
+        self.gate_min = min(self.gate_min, low)
+        self.gate_mean_sum += mean
+        self.gate_steps += 1
+
+    @property
+    def gate_mean(self) -> float:
+        return self.gate_mean_sum / max(self.gate_steps, 1)
 
     def count_moe_chunk(self, local_pairs: int, slots: int) -> None:
         """One admission prefill chunk: the pairs that landed on held
@@ -691,7 +723,7 @@ class ContinuousEngine:
         # a slot of fixed size a sequence (a recurrent state, a window
         # ring); a hybrid spec keeps one AND pages of its full layer
         self._state = "state" in caches
-        self._hybrid = bool(spec.hybrid)
+        self._hybrid = spec.slotted
         if prefix_share is None:    # where the spec's cache can be shared
             prefix_share = not self._state
         refused = cache_refusals(
@@ -970,13 +1002,15 @@ class ContinuousEngine:
                     forward_batch_paged, spec, page_size, kv_quant=kv_quant,
                     moe_counts=bool(spec.n_experts))
                 if self._hybrid:
-                    from ..models.sambay import forward_batch_sambay
+                    from ..models.llama import slot_counts, slot_model
 
                     # also takes which rows take part, and hands out the
-                    # smallest decay a state took
+                    # smallest decay a state took (a mixer-kinds spec: its
+                    # gate gauges, and an expert spec's counts after them)
                     decode_fwd = functools.partial(
-                        forward_batch_sambay, spec, page_size=page_size,
-                        health=True)
+                        slot_model(spec).forward_batch, spec,
+                        page_size=page_size, health=True,
+                        **slot_counts(spec))
                 if spec_k:
                     self._verify_base = _shared_program(
                         ("verify", spec, page_size, kv_quant),
@@ -1023,12 +1057,14 @@ class ContinuousEngine:
                     else functools.partial(forward, spec,
                                            moe_counts=bool(spec.n_experts)))
                 if self._hybrid:
-                    from ..models.sambay import forward_sambay
+                    from ..models.llama import slot_counts, slot_model
 
                     # the self-decoder alone: the cross-decoder runs where
-                    # the prompt's last token takes its decode step
-                    chunk_fwd = functools.partial(forward_sambay, spec,
-                                                  xdec=False)
+                    # the prompt's last token takes its decode step (a
+                    # mixer-kinds spec's chunk: every layer, no classifier)
+                    chunk_fwd = functools.partial(
+                        slot_model(spec).forward_chunk, spec, xdec=False,
+                        **slot_counts(spec))
                 self._prefill_fwd = _shared_program(
                     ("prefill", spec, fast_prefill),
                     lambda: _maybe_bf16(chunk_fwd, fast_prefill, jax,
@@ -1069,14 +1105,15 @@ class ContinuousEngine:
             # donate only the batched cache (updated in place); the scratch
             # sequence cache can't alias the rank-5 output
             if self._hybrid:
-                from ..models.sambay import insert_sequence
+                from ..models.llama import slot_model
 
-                # state and rings into the row, the full layer's K / V
+                # state and rings into the row, the full layers' K / V
                 # into the row's pages: ONE program
-                _insert = functools.partial(insert_sequence,
+                _insert = functools.partial(slot_model(spec).insert_sequence,
                                             page_size=page_size)
             self._insert = _shared_program(
-                ("insert", self._state, self._hybrid and page_size),
+                ("insert", self._state, self._hybrid and page_size,
+                 bool(spec.mixers)),
                 lambda: jax.jit(
                     named_program("serve_admit_state_insert" if self._state
                                   else "serve_admit_insert", _insert),
@@ -1199,10 +1236,10 @@ class ContinuousEngine:
         self._chains: dict = {}  # (k, greedy_only) -> fused chain program
         self.stats = ContinuousStats()
         if self._hybrid:
-            from ..models.sambay import state_bytes
+            from ..models.llama import slot_model
 
-            self.stats.state_bytes, self.stats.window_bytes = state_bytes(
-                self.cache)
+            self.stats.state_bytes, self.stats.window_bytes = slot_model(
+                spec).state_bytes(self.cache)
         elif self._state:
             self.stats.state_bytes = sum(int(a.nbytes) for a in self.cache)
         if spec.hyper:
@@ -2667,7 +2704,7 @@ class ContinuousEngine:
                 depth = [int(blk[b, 1]) + 1 for b, s in enumerate(rows)
                          if s is not None]
                 if self._hybrid:
-                    w = self.spec.hybrid.window
+                    w = (self.spec.hybrid or self.spec.mixers).window
                     self.stats.shared_kv_positions += sum(depth)
                     self.stats.window_kv_positions += sum(
                         min(d, w) for d in depth)
@@ -2683,8 +2720,10 @@ class ContinuousEngine:
             if self._obs is not None:
                 self._obs.steps_ahead.inc()
         reqs = [None if s is None else s.req for s in rows]
-        beside = more[0] if more else None  # what the spec's kind counts
-        moe, norm_min = (None, beside) if self._state else (beside, None)
+        # what the spec's kind counts: a state's health reading, an
+        # expert spec's routed-rows counts, or both in that order
+        norm_min = more.pop(0) if more and self._state else None
+        moe = more[0] if more else None
         return _Flight(rows, reqs, paused, logits, picked, moe, norm_min,
                        t0, prev is not None, *self._queued_ahead(),
                        self._take_chunk_moe())
@@ -2749,8 +2788,11 @@ class ContinuousEngine:
                 out = np.asarray(flight.picked)  # dlint: allow[D001] four bytes a row
             flight.wait = time.monotonic() - t_wait
             if flight.norm_min is not None:  # (L,) floats
-                low = float(np.asarray(flight.norm_min).min())  # dlint: allow[D001] normaliser counter
-                if self._hybrid:
+                health = np.asarray(flight.norm_min)  # dlint: allow[D001] normaliser counter
+                low = float(health.min())
+                if self.spec.mixers:    # (2,): smallest and mean gate
+                    self.stats.count_gate(low, float(health[1]))
+                elif self._hybrid:
                     self.stats.ssm_min_decay = min(self.stats.ssm_min_decay,
                                                    low)
                 elif low < self.stats.min_normaliser:

@@ -113,6 +113,7 @@ class Engine:
         # be rewound: a step anywhere else than there or at 0 is refused
         self.min_normaliser = float("inf")
         self.ssm_min_decay = 1.0  # a hybrid spec's: smallest state decay
+        self.gate_min = 1.0       # a mixer-kinds spec's: smallest head gate
         self._state_pos = 0
         self._ahead: _Step | None = None  # the step enqueued ahead, if any
         # steps found in flight and handed out / enqueued ahead and dropped
@@ -152,14 +153,17 @@ class Engine:
                 step = functools.partial(forward_retention, spec,
                                          norm_min=True)
                 self._step_raw = functools.partial(forward_retention, spec)
-            if spec.hybrid:
-                from ..models.sambay import forward_sambay
+            if spec.slotted:
+                from ..models.llama import slot_counts, slot_model
 
-                # likewise the smallest decay a state took; a chunk runs
-                # the self-decoder alone (no logits are read of it)
-                step = functools.partial(forward_sambay, spec, health=True)
-                chunk_fwd = functools.partial(forward_sambay, spec,
-                                              xdec=False)
+                # likewise the smallest decay a state took (a mixer-kinds
+                # spec: its gate gauges, then an expert spec's counts); a
+                # chunk runs the self-decoder alone (no logits are read
+                # of it)
+                fwd = slot_model(spec).forward_chunk
+                step = functools.partial(fwd, spec, health=True,
+                                         **slot_counts(spec))
+                chunk_fwd = functools.partial(fwd, spec, xdec=False)
 
         # host tokens are placed as the step's own ``picked`` result is, or
         # the mesh's step program would compile once for each of the two
@@ -192,8 +196,8 @@ class Engine:
         self._state_moves(pos, int(tokens.shape[0]))
         logits, picked, self.cache, *more = self._fwd(
             self.params, self.cache, tokens, np.int32(pos))
-        if self.spec.stateful:
-            return _Step(pos, logits, picked, [], more)
+        if self.spec.stateful:    # its health reading, then any counts
+            return _Step(pos, logits, picked, more[1:], more[:1])
         return _Step(pos, logits, picked, more, [])
 
     def _state_moves(self, pos: int, n: int) -> None:
@@ -247,7 +251,9 @@ class Engine:
         with host_phase("inference.fetch"):  # the wait and the transfer
             if step.norm_min:  # (L,) floats beside the rest
                 low = float(np.asarray(step.norm_min[0]).min())  # dlint: allow[D001] normaliser counter
-                if self.spec.hybrid:
+                if self.spec.mixers:    # (smallest gate, mean gate)
+                    self.gate_min = min(self.gate_min, low)
+                elif self.spec.hybrid:
                     self.ssm_min_decay = min(self.ssm_min_decay, low)
                 else:
                     self.min_normaliser = min(self.min_normaliser, low)

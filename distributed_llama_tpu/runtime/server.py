@@ -1290,7 +1290,15 @@ class InferenceServer:
                   f"steps launched ahead on device-resident tokens, "
                   f"{st.rows_dropped_ahead} rows of them dropped; "
                   f"{st.admission_clause}"
-                  + (f"; state {st.state_bytes / 2**20:.0f} MiB and window "
+                  + (f"; window rings {st.window_bytes / 2**20:.0f} MiB "
+                     f"resident, {st.shared_kv_pages} pages of the full "
+                     f"layers' K / V in use, {st.window_kv_positions} ring "
+                     f"slots and {st.shared_kv_positions} cached positions "
+                     f"read a sliding and a full layer, {st.moe_pairs} "
+                     f"routed pairs, per-head gate smallest "
+                     f"{st.gate_min:.3g} mean {st.gate_mean:.3g}"
+                     if st.gate_steps else
+                     f"; state {st.state_bytes / 2**20:.0f} MiB and window "
                      f"rings {st.window_bytes / 2**20:.0f} MiB resident, "
                      f"{st.shared_kv_pages} pages of the shared K / V in "
                      f"use, the cross-decoder at {st.xdec_positions} of "
@@ -1335,10 +1343,14 @@ class InferenceServer:
                   window_bytes=st.window_bytes,
                   shared_kv_pages=st.shared_kv_pages,
                   shared_kv_positions=st.shared_kv_positions,
+                  window_kv_positions=st.window_kv_positions,
+                  gate_min=st.gate_min if st.gate_steps else None,
+                  gate_mean=st.gate_mean if st.gate_steps else None,
                   prompt_positions=st.prompt_positions,
                   xdec_positions=st.xdec_positions,
                   ssm_min_decay=(st.ssm_min_decay
-                                 if st.window_bytes else None),
+                                 if st.window_bytes and not st.gate_steps
+                                 else None),
                   min_normaliser=(st.min_normaliser
                                   if st.state_bytes and not st.window_bytes
                                   else None))

@@ -622,7 +622,8 @@ NEW_METRICS = {
     "admission_window_share": ("%", 30.0)}
 SAT_CELLS = ["mistral7b.serve-sat", "olmoe7b.gen-sat16",
              "brumby14b.gen-sat16", "deepseekv3.gen-sat32",
-             "phi4flash.reason-sat32", "xing4.gen-sat32"]
+             "phi4flash.reason-sat32", "xing4.gen-sat32",
+             "laguna.mix-sat32"]
 
 
 @pytest.mark.parametrize("family", ["chat_", "sat_"])

@@ -67,7 +67,16 @@ LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
           "yi-wq": (1792, 7168), "yi-wk": (256, 7168), "yi-wo": (7168, 1792),
           "yi-w1": (5120, 7168), "yi-w2": (7168, 5120),
           "yi-wcls": (16000, 7168),
-          "br-wcls": (151936, 5120)}
+          "br-wcls": (151936, 5120),
+          # Laguna-XS.2's (dim 2048: 64 blocks a row; a full layer's 48
+          # heads and a sliding layer's 64 over 8 KV heads of 128: wqkv of
+          # 8192 and 10240 rows, wo of 192 and 256 blocks; the dense FFN
+          # 8192; the shared expert 512: 16 blocks)
+          "lg-f-wqkv": (8192, 2048), "lg-s-wqkv": (10240, 2048),
+          "lg-f-wo": (2048, 6144), "lg-s-wo": (2048, 8192),
+          "lg-w13": (16384, 2048), "lg-w2": (2048, 8192),
+          "lg-sh_w13": (1024, 2048), "lg-sh_w2": (2048, 512),
+          "lg-wcls": (100352, 2048)}
 
 
 def _sd(shape, dtype):
@@ -214,11 +223,14 @@ def _moe(leaf: str, rows: int, model: str = "olmoe"):
     row list; ``w2``: each slot's own rows)."""
     from distributed_llama_tpu.ops import pallas_moe as pm
 
-    n_exp, k = {"olmoe": (64, 8), "ds": (32, 8), "x4": (64, 4)}[model]
+    n_exp, k = {"olmoe": (64, 8), "ds": (32, 8), "x4": (64, 4),
+                "lg": (256, 8)}[model]
     d, n = {"olmoe": {"w13": (2048, 2048), "w2": (2048, 1024)},
             "ds": {"w13": (4096, 7168), "w2": (7168, 2048)},
             # Xing4.0's: 64 experts of width 1024 on dim 3584, 4 a row
-            "x4": {"w13": (2048, 3584), "w2": (3584, 1024)}}[model][leaf]
+            "x4": {"w13": (2048, 3584), "w2": (3584, 1024)},
+            # Laguna-XS.2's: 256 experts of width 512 on dim 2048, 8 a row
+            "lg": {"w13": (1024, 2048), "w2": (2048, 512)}}[model][leaf]
     nb = n // 32
     qs_t = _sd((2, n_exp, 16, nb, d), jnp.uint8)
     scale = _sd((2, n_exp, nb, d), jnp.float32)
@@ -329,6 +341,32 @@ def _diff_attention(kind: str):
                               interpret=False),
             (_sd((b, n_q, hs), jnp.float32), pool, pool,
              _sd((b,), jnp.int32), _sd((b, 8704 // ps), jnp.int32)))
+
+
+def _gqa_attention(kind: str, heads: int):
+    """Grouped-query attention through the head-major kernels at
+    Laguna-XS.2's widths: 8 KV heads of 128 under 64 query heads (a sliding
+    layer: groups of 8) or 48 (a full layer: groups of 6, padded to 8 rows),
+    32 rows. ``window``: twelve layers' rings of 512 slots; ``rows``: one
+    sequence's contiguous planes of 5,120 positions, four full layers;
+    ``paged``: four layers' pools of 5,121 pages of 16 merged, the table
+    offset a layer."""
+    from distributed_llama_tpu.ops import pallas_head_major_attention as hm
+
+    b, n_kv, hs = 32, 8, 128
+    kv_mul = heads // n_kv
+    if kind in ("window", "rows"):
+        layers, b, s = (12, b, 512) if kind == "window" else (4, 1, 5120)
+        ring = _sd((layers * b, n_kv, s, hs), jnp.float32)
+        return (functools.partial(hm.rows_decode_attention, kv_mul=kv_mul,
+                                  interpret=False),
+                (_sd((b, heads, hs), jnp.float32), ring, ring,
+                 _sd((), jnp.int32), _sd((b,), jnp.int32)))
+    pool = _sd((4 * 5121, n_kv, 16, hs), jnp.float32)
+    return (functools.partial(hm.paged_decode_attention, kv_mul=kv_mul,
+                              interpret=False),
+            (_sd((b, heads, hs), jnp.float32), pool, pool,
+             _sd((b,), jnp.int32), _sd((b, 5120 // 16), jnp.int32)))
 
 
 # kernel=False: the dispatch documents an XLA dequantize-then-dot route for
@@ -450,6 +488,30 @@ CASES = {
        (functools.partial(_moe, leaf, rows, "x4"), True)
        for kind, rows in (("slots", 32), ("slots", 1), ("wide", 128))
        for leaf in ("w13", "w2")},
+    # Laguna-XS.2 (PR 44): the head-major kernels at 8 KV heads of 128 and
+    # TWO group sizes (8 heads a group in a sliding layer, 6 padded to 8 in
+    # a full one), its leaves at the cell's 32 rows, one row and a chunk's
+    # 512, and its experts (256 held, 8 a row: 1.6 rows an expert at 32
+    # rows, ``w2`` at 16 blocks a row)
+    "gqa-window-W512-B32-H64": (functools.partial(_gqa_attention, "window",
+                                                  64), True),
+    "gqa-rows-S5120-B1-H48": (functools.partial(_gqa_attention, "rows", 48),
+                              True),
+    "gqa-rows-S5120-B1-H64": (functools.partial(_gqa_attention, "rows", 64),
+                              True),
+    "gqa-paged-ps16-B32-H48": (functools.partial(_gqa_attention, "paged",
+                                                 48), True),
+    **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)
+       for leaf, ts in (("lg-f-wqkv", (1, 32, 512)), ("lg-s-wqkv", (32,)),
+                        ("lg-f-wo", (1, 32, 512)), ("lg-s-wo", (1, 32, 512)),
+                        ("lg-w13", (32,)), ("lg-w2", (1, 32, 512)),
+                        ("lg-sh_w13", (32,)), ("lg-sh_w2", (1, 32, 512)),
+                        ("lg-wcls", (32,)))
+       for t in ts},
+    **{f"moe-lg-{kind}-{leaf}-T{rows}":
+       (functools.partial(_moe, leaf, rows, "lg"), True)
+       for kind, rows in (("slots", 32), ("slots", 1), ("wide", 512))
+       for leaf in ("w13", "w2")},
 }
 
 
@@ -564,3 +626,56 @@ def test_sharded_step_copies_no_weights(topo, chip_branch, t, kernels):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 0.05 * weights, (temp, weights)
     assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+def test_laguna_step_holds_what_the_memory_model_counts(chip):
+    """The compiled ``serve`` decode step of ``laguna-xs2-q40`` (32 rows,
+    the configuration's pool, the rings and pools aliased) takes as
+    arguments what ``analysis/memory_model`` counts for the spec on one chip
+    (weights in the kernels' layout, the float32 leaves, rings for the
+    sliding layers, pages over the full layers only, every expert held), to
+    1 %, and copies neither an expert stack, a ring nor a pool around a
+    kernel call: what it needs beside its arguments stays under 0.25 GiB."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmark.harness import laguna as harness
+    from benchmark.tools.rehearse_laguna import shape_params
+    from distributed_llama_tpu.analysis import memory_model as mm
+    from distributed_llama_tpu.models import laguna
+
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-xs2-q40.json"), encoding="utf-8") as fh:
+        config = json.load(fh)
+    flags = config["entries"]["serve"]
+    sizes = harness.sizes_of(config)
+    spec = harness.program_spec(sizes)
+    b, ps, pages = (int(flags[k]) for k in ("slots", "kv_page_size",
+                                            "kv_pages"))
+    saved, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        params, _, kinds = shape_params(sizes, spec, b, chip)
+        assert all(v != "Q40Weight" for v in kinds.values())   # all packed
+        sds = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+        pool = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+                lambda: laguna.init_cache_paged(spec, b, pages + 1, ps)))
+        compiled = jax.jit(functools.partial(
+            laguna.forward_batch, spec, page_size=ps, health=True,
+            moe_counts=True), donate_argnums=1).lower(
+                params, pool, sds((b,), jnp.int32), sds((b,), jnp.int32),
+                sds((b, spec.seq_len // ps), jnp.int32),
+                sds((b,), jnp.int32)).compile()
+    finally:
+        jax.default_backend = saved
+    m = compiled.memory_analysis()
+    rep = mm.device_footprint(spec, 1, "ref", batch=b, kv_page_size=ps,
+                              kv_pages=pages)
+    counted = rep.weights_bytes + rep.replicated_bytes + rep.kv_cache_bytes
+    assert abs(m.argument_size_in_bytes - counted) < 0.01 * counted, (
+        m.argument_size_in_bytes, counted)
+    assert rep.kv_cache_bytes == (pages + 1) * 524288 + b * 12 * 512 * 8192
+    assert m.temp_size_in_bytes < 0.25 * 2 ** 30
+    assert compiled.as_text().count("tpu_custom_call") >= 20

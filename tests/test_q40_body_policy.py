@@ -291,7 +291,7 @@ def test_leaf_rule_packs_the_cells_as_the_parent_did(case, monkeypatch):
     assert {k: names[type(v)] for k, v in packed.items()} == want
 
 
-# The nine cells of BENCHMARK.json: (policy label, kinds) an engine of the
+# The ten cells of BENCHMARK.json (``laguna.mix-sat32`` joined with PR 44): (policy label, kinds) an engine of the
 # cell's width and tp resolves for the cell's own configuration file. PR 32
 # moved the two 8-row cells (were "d-major", every leaf d-major) and nothing
 # else: rows 1 and 16 and tp 4 read what PR 31's tree read. The three 32-row
@@ -307,14 +307,15 @@ CELLS_PACKED = {
     "deepseekv3.gen-sat32": ("nb-major", None),
     "phi4flash.reason-sat32": ("nb-major", None),
     "xing4.gen-sat32": ("nb-major", None),
+    "laguna.mix-sat32": ("nb-major", None),
 }
 MOVED_BY_PR32 = {"mistral7b.serve-chat", "mistral7b.serve-sat"}
 SINCE_PR31 = {"deepseekv3.gen-sat32", "phi4flash.reason-sat32",
-              "xing4.gen-sat32"}
+              "xing4.gen-sat32", "laguna.mix-sat32"}
 PR31_PACKED = {**CELLS_PACKED,
                **dict.fromkeys(MOVED_BY_PR32, ("d-major", _ALL_D))}
 _HARNESS = {"olmoe": "olmoe", "brumby": "retention", "deepseek_v3": "latent",
-            "phi4flash": "hybrid", "xing4_0": "hyper"}
+            "phi4flash": "hybrid", "xing4_0": "hyper", "laguna": "laguna"}
 
 
 @pytest.mark.parametrize("cell_name", sorted(CELLS_PACKED))
@@ -382,6 +383,31 @@ def test_the_nine_cells_policy_and_leaf_kinds(cell_name, monkeypatch):
     if cell_name not in SINCE_PR31:
         assert ((label, kinds) == PR31_PACKED[cell_name]) == (
             cell_name not in MOVED_BY_PR32)
+
+
+@pytest.mark.parametrize("dim,label", [(2048, "nb-major"),
+                                       (4096, "d-major")])
+def test_a_slotted_spec_is_judged_by_its_block_counts(dim, label,
+                                                      monkeypatch):
+    """A slot-and-pages spec packs ONE layout, and which follows from its
+    leaves' block counts, not from the record it carries: a leaf off the
+    128 grid (dim 2048: 64 blocks a row) makes it nb-major, and with every
+    count on the grid (dim, heads x head and hidden all multiples of 4096)
+    the stock picks stand; never the i4 body, which a slot's state
+    refuses."""
+    from distributed_llama_tpu.models.spec import (MixerKind, MixerKinds,
+                                                   TransformerSpec)
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    kind = MixerKind(32, 1e4, 128, None)
+    spec = TransformerSpec(
+        dim=dim, hidden_dim=8192, n_layers=4, n_heads=32, n_kv_heads=8,
+        vocab_size=32768, seq_len=1024, mixers=MixerKinds(
+            ("full", "sliding") * 2, 512, 128, kind, kind, True))
+    assert spec.slotted
+    layout = q40_body_policy(spec, rows=32)
+    assert layout.label == label and ("off the 128 grid" in layout.reason) \
+        == (label == "nb-major")
 
 
 def test_leaf_rule_corners():

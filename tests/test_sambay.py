@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from distributed_llama_tpu.io.loader import (load_model, tensor_byte_ranges,
                                              write_model)
 from distributed_llama_tpu.models import reference_sambay as ref
-from distributed_llama_tpu.models import sambay
+from distributed_llama_tpu.models import kindscan, sambay
 from distributed_llama_tpu.models.llama import (forward, init_cache,
                                                 params_to_device)
 from distributed_llama_tpu.models.spec import (ExpertLayout, HybridLayers,
@@ -78,11 +78,12 @@ def test_the_pattern_and_its_scans():
     assert kinds[16] == "mamba" and kinds[17] == "full"
     hy = HybridLayers(kinds, 512, 5120, 16, 4, 160)
     assert hy.memory_layer == 16 and hy.full_layer == 17
-    segs = sambay.segments(kinds)
-    assert [(f, u, r) for f, u, r, _ in segs] == [
+    segs = kindscan.segments([(k,) for k in kinds])
+    assert [(f, tuple(s for s, in u), r) for f, u, r in segs] == [
         (0, ("mamba", "swa"), 8), (16, ("mamba",), 1), (17, ("full",), 1),
         (18, ("gmu", "xattn"), 7)]
-    assert segs[1][3] == {"mamba": 8}
+    # a prefill stops after the full layer: the same scans, cut there
+    assert kindscan.segments([(k,) for k in kinds[:18]]) == segs[:3]
     assert sambay_kinds(8) == ("mamba", "swa", "mamba", "swa", "mamba",
                                "full", "gmu", "xattn")
 
