@@ -1393,85 +1393,133 @@ def forward_batch_mixed_paged(spec: TransformerSpec, page_size: int,
     return logits.reshape(B, T, -1), rebuild_paged_cache(tuple(kv), L)
 
 
-def gather_pages(cache: KVCache, table: jax.Array,
-                 page_size: int) -> KVCache:
+def _page_range(table: jax.Array, start, stop):
+    """``[start, stop)`` of a slot's table as ``fori_loop`` bounds: the
+    whole table where a bound is None."""
+    return (0 if start is None else start,
+            table.shape[0] if stop is None else stop)
+
+
+def gather_pages(cache: KVCache, table: jax.Array, page_size: int, *,
+                 into: KVCache | None = None, stop=None) -> KVCache:
     """Materialize one slot's virtual (L, S, n_kv, hs) sequence cache from
     its pool pages — the admission-prefill seed: chunked prefill of an
     UNSHARED suffix must attend over the shared prefix k/v, and the
     single-sequence prefill program expects a contiguous plane. ``table``
-    is the slot's full (max_pages,) logical->physical row; entries beyond
-    the live prefix gather scrap-page junk that prefill overwrites (its
-    chunk at position p writes p before any later chunk reads it). A
-    latent pool's one plane (models/latent.LatentCache) gathers alike."""
-    def g(plane):
-        L = plane.shape[0]
-        got = jnp.take(plane, table, axis=1)  # (L, max_pages, ps, kv, hs)
-        return got.reshape(L, table.shape[0] * page_size, *plane.shape[3:])
+    is the slot's full (max_pages,) logical->physical row; pages
+    ``[0, stop)`` of it (``stop`` a traced count; None: the whole table)
+    are copied over ``into``, a sequence cache whose other positions stay
+    as they are (None: zeros). What lies past the copied pages is never
+    read before prefill overwrites it (its chunk at position p writes p
+    before any later chunk reads it), so an admission gathers the pages
+    below its start and no others. jit with ``into`` donated: the copy is
+    in place. A latent pool's one plane (models/latent.LatentCache)
+    gathers alike."""
+    lo, hi = _page_range(table, None, stop)
 
-    return type(cache)(*(g(plane) for plane in cache))
+    def g(plane, seq):
+        L = plane.shape[0]
+        if seq is None:
+            seq = jnp.zeros((L, table.shape[0] * page_size,
+                             *plane.shape[3:]), plane.dtype)
+
+        def page(i, seq):
+            got = jax.lax.dynamic_index_in_dim(plane, table[i], axis=1,
+                                               keepdims=False)
+            return jax.lax.dynamic_update_slice_in_dim(
+                seq, got, i * page_size, axis=1)
+
+        return jax.lax.fori_loop(lo, hi, page, seq)
+
+    return type(cache)(*(g(plane, seq) for plane, seq in zip(
+        cache, into if into is not None else (None,) * len(cache))))
 
 
 def scatter_pages(cache: KVCache, seq_cache: KVCache, table: jax.Array,
-                  page_size: int) -> KVCache:
+                  page_size: int, *, start=None, stop=None) -> KVCache:
     """Write a prefilled virtual sequence cache back into the pool at the
     slot's physical pages — gather_pages' inverse (admission-prefill
-    insert). Shared prefix pages receive byte-identical content (the seed
-    copied them out and prefill never touches positions below its start),
-    and table entries parked on the scrap page absorb the junk tail.
-    jit with the POOL cache donated: the scatter updates in place."""
+    insert), over pages ``[start, stop)`` of the table (traced counts;
+    None: the table's first / last). Every pool page outside the range
+    keeps its bytes: an admission writes the pages its chunks filled and
+    no others. Over the whole table, shared prefix pages receive
+    byte-identical content (the seed copied them out and prefill never
+    touches positions below its start), and table entries parked on the
+    scrap page absorb the junk tail. jit with the POOL cache donated: the
+    scatter updates in place."""
+    lo, hi = _page_range(table, start, stop)
+
     def s(plane, seq_plane):
-        L = plane.shape[0]
-        upd = seq_plane.reshape(L, table.shape[0], page_size,
-                                *plane.shape[3:])
-        return plane.at[:, table].set(upd)
+        def page(i, plane):
+            upd = jax.lax.dynamic_slice_in_dim(seq_plane, i * page_size,
+                                               page_size, axis=1)
+            return jax.lax.dynamic_update_index_in_dim(plane, upd, table[i],
+                                                       axis=1)
+
+        return jax.lax.fori_loop(lo, hi, page, plane)
 
     return type(cache)(*(s(plane, seq) for plane, seq in zip(cache,
                                                              seq_cache)))
 
 
-def gather_pages_q8(cache: PagedKVQ8, table: jax.Array,
-                    page_size: int) -> KVCache:
+def gather_pages_q8(cache: PagedKVQ8, table: jax.Array, page_size: int, *,
+                    into: KVCache | None = None, stop=None) -> KVCache:
     """gather_pages' Q8 twin: materialize one slot's virtual (L, S, n_kv,
     hs) sequence cache FROM the quantized pool, dequantized to f32 — the
     admission-prefill seed (the single-sequence prefill program computes
     in f32 and must attend over the shared prefix's dequantized k/v, the
-    same values decode reads)."""
-    from ..ops.quants import QK, dequantize_q80_planes
+    same values decode reads). ``into`` / ``stop`` as gather_pages'."""
+    from ..ops.quants import dequantize_q80_planes
 
-    L, _, ps, n_kv, hs = cache.kq.shape
-    nb = n_kv * hs // QK
-    S = table.shape[0] * page_size
+    L, _, _, n_kv, hs = cache.kq.shape
+    lo, hi = _page_range(table, None, stop)
 
-    def g(codes, d):
-        qc = jnp.take(codes, table, axis=1).reshape(L, S, n_kv, hs)
-        dc = jnp.take(d, table, axis=1).reshape(L, S, nb)
-        return dequantize_q80_planes(qc, dc)
+    def g(codes, d, seq):
+        if seq is None:
+            seq = jnp.zeros((L, table.shape[0] * page_size, n_kv, hs),
+                            jnp.float32)
 
-    return KVCache(g(cache.kq, cache.kd), g(cache.vq, cache.vd))
+        def page(i, seq):
+            qc, dc = (jax.lax.dynamic_index_in_dim(p, table[i], axis=1,
+                                                   keepdims=False)
+                      for p in (codes, d))
+            return jax.lax.dynamic_update_slice_in_dim(
+                seq, dequantize_q80_planes(qc, dc), i * page_size, axis=1)
+
+        return jax.lax.fori_loop(lo, hi, page, seq)
+
+    k, v = into if into is not None else (None, None)
+    return KVCache(g(cache.kq, cache.kd, k), g(cache.vq, cache.vd, v))
 
 
 def scatter_pages_q8(cache: PagedKVQ8, seq_cache: KVCache,
-                     table: jax.Array, page_size: int) -> PagedKVQ8:
+                     table: jax.Array, page_size: int, *, start=None,
+                     stop=None) -> PagedKVQ8:
     """scatter_pages' Q8 twin: Q80-quantize the prefilled virtual plane
     per position and write codes + block deltas back into the pool at the
-    slot's physical pages. UNLIKE the f32 scatter, re-writing a SHARED
-    prefix page is not byte-idempotent (quantize∘dequantize moves codes
-    whose block max shrank), so the engine passes a table whose shared
-    entries are redirected to the scrap page — shared pages keep the
-    bytes their first prefiller wrote, and every reader sees one
-    deterministic encoding. jit with the POOL cache donated."""
-    from ..ops.quants import QK, quantize_q80_jax
+    slot's physical pages, over pages ``[start, stop)`` of the table.
+    UNLIKE the f32 scatter, re-writing a SHARED prefix page is not
+    byte-idempotent (quantize∘dequantize moves codes whose block max
+    shrank), so the engine's range starts past the pages an earlier
+    encode published — shared pages keep the bytes their first prefiller
+    wrote, and every reader sees one deterministic encoding. jit with the
+    POOL cache donated."""
+    from ..ops.quants import quantize_q80_jax
 
-    L, _, ps, n_kv, hs = cache.kq.shape
-    nb = n_kv * hs // QK
-    n_pages_tbl = table.shape[0]
+    L, _, _, n_kv, hs = cache.kq.shape
+    lo, hi = _page_range(table, start, stop)
 
     def s(codes_plane, d_plane, seq_plane):
-        qs, d = quantize_q80_jax(seq_plane.reshape(L, -1, n_kv * hs))
-        codes = qs.reshape(L, n_pages_tbl, page_size, n_kv, hs)
-        deltas = d.reshape(L, n_pages_tbl, page_size, nb)
-        return (codes_plane.at[:, table].set(codes),
-                d_plane.at[:, table].set(deltas))
+        def page(i, planes):
+            rows = jax.lax.dynamic_slice_in_dim(seq_plane, i * page_size,
+                                                page_size, axis=1)
+            qs, d = quantize_q80_jax(rows.reshape(L, page_size, n_kv * hs))
+            return tuple(
+                jax.lax.dynamic_update_index_in_dim(p, u, table[i], axis=1)
+                for p, u in zip(planes, (
+                    qs.reshape(L, page_size, n_kv, hs), d)))
+
+        return jax.lax.fori_loop(lo, hi, page, (codes_plane, d_plane))
 
     kq, kd = s(cache.kq, cache.kd, seq_cache.k)
     vq, vd = s(cache.vq, cache.vd, seq_cache.v)

@@ -555,7 +555,14 @@ class ContinuousStats:
     # blocking read of a dispatch's results, and
     # ``fetch_wait_behind_admit_s`` that of the dispatches booked behind
     # admissions (the host's own part of an iteration is read from the
-    # plain ones: behind a burst it also stands in held-up enqueues)
+    # plain ones: behind a burst it also stands in held-up enqueues).
+    # ``admit_pages_moved`` / ``admit_pages_table``: a paged pool's
+    # admissions: the pages, a layer, they gathered into and scattered
+    # from the scratch sequence (the pages below the prompt's start, and
+    # those its chunks filled), against twice the slot's table an
+    # admission, which is what moving the whole table both ways took;
+    # ``admit_gathers``: the admissions that ran a gather at all (a shared
+    # prefix or a resumed prompt: an unshared prompt has nothing to read)
     land_s: float = 0.0
     lands_behind_admit: int = 0
     land_behind_admit_s: float = 0.0
@@ -563,6 +570,9 @@ class ContinuousStats:
     admits_back_to_back_max: int = 0
     fetch_wait_s: float = 0.0
     fetch_wait_behind_admit_s: float = 0.0
+    admit_pages_moved: int = 0
+    admit_pages_table: int = 0
+    admit_gathers: int = 0
 
     def book_land(self, dt_s: float, steps: int, chunks: int, admits: int,
                   wait_s: float = 0.0, enqueued_since: bool = False) -> bool:
@@ -675,6 +685,11 @@ class ContinuousStats:
                                                  1)
 
     @property
+    def admit_pages_share(self) -> float:
+        """The share of their tables a paged pool's admissions moved."""
+        return self.admit_pages_moved / max(self.admit_pages_table, 1)
+
+    @property
     def admission_clause(self) -> str:
         """The account in one clause, for the summaries."""
         return (f"admission {self.admit_share:.1%} of stepping time, "
@@ -682,7 +697,11 @@ class ContinuousStats:
                 f"{self.prefill_chunks} chunks; plain step "
                 f"{self.plain_step_ms:.2f} ms; host "
                 f"{self.host_ms_per_step:.2f} ms a step; at most "
-                f"{self.admits_back_to_back_max} admissions back to back")
+                f"{self.admits_back_to_back_max} admissions back to back"
+                + (f"; {self.admit_pages_moved} of {self.admit_pages_table} "
+                   f"table pages moved ({self.admit_pages_share:.1%}), "
+                   f"{self.admit_gathers} admissions gathered"
+                   if self.admit_pages_table else ""))
 
 
 class ContinuousEngine:
@@ -1089,6 +1108,9 @@ class ContinuousEngine:
             jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
             if sharded else None)
         self._flight: _Flight | None = None  # launched, not landed
+        # a paged pool's admissions: the scratch sequence their chunks fill,
+        # kept from one to the next (_maybe_prefill_slot)
+        self._admit_scratch = None
         # [prefill chunks, admissions with device work] enqueued since the
         # last launch: what the next dispatch will stand behind; and since
         # when the device has been free to run them (the first one's
@@ -1119,29 +1141,38 @@ class ContinuousEngine:
                                   else "serve_admit_insert", _insert),
                     donate_argnums=0))
             if self._alloc is not None and not self._hybrid:
-                # paged prefill plumbing: gather the slot's pages into a
-                # virtual contiguous sequence cache (shared prefix k/v
-                # included — suffix chunks must attend over it), prefill
-                # into that, scatter back into the pool in place. Q8
-                # pools dequantize on gather and re-quantize on scatter
-                # (the engine redirects SHARED entries of the scatter
-                # table to the scrap page — quantize∘dequantize is not
-                # byte-idempotent, and a shared page must keep the bytes
-                # its first prefiller published).
+                # paged prefill plumbing: a virtual contiguous sequence
+                # cache the engine keeps from one admission to the next
+                # (``_admit_scratch``; an engine with no row left drops it:
+                # ``_retire``), the pages below the prompt's start
+                # gathered into it (a shared prefix, or what a preempted
+                # prompt already holds: suffix chunks must attend over
+                # them), prefill into that, and the pages the chunks
+                # filled scattered back into the pool in place. Q8 pools
+                # dequantize on gather and re-quantize on scatter (the
+                # scatter's range starts past the pages an EARLIER encode
+                # published — quantize∘dequantize is not byte-idempotent,
+                # and a shared page must keep the bytes its first
+                # prefiller wrote). Both take the whole table where the
+                # range is left out.
                 gp = gather_pages_q8 if kv_quant == "q8" else gather_pages
                 sp_ = (scatter_pages_q8 if kv_quant == "q8"
                        else scatter_pages)
                 self._gather_pages = _shared_program(
                     ("gather", kv_quant, page_size),
-                    lambda: jax.jit(named_program(
-                        "serve_admit_gather",
-                        lambda c, t, gp=gp: gp(c, t, page_size))))
+                    lambda: jax.jit(
+                        named_program(
+                            "serve_admit_gather",
+                            lambda c, t, into=None, stop=None, gp=gp: gp(
+                                c, t, page_size, into=into, stop=stop)),
+                        donate_argnames="into"))
                 self._scatter_pages = _shared_program(
                     ("scatter", kv_quant, page_size),
                     lambda: jax.jit(
                         named_program(
                             "serve_admit_scatter",
-                            lambda c, s, t, sp_=sp_: sp_(c, s, t, page_size)),
+                            lambda c, s, t, start=None, stop=None, sp_=sp_:
+                            sp_(c, s, t, page_size, start=start, stop=stop)),
                         donate_argnums=0))
         # KV tiering (ISSUE 12): bind the allocator's device I/O — the
         # demotion read (pool page planes -> host numpy, models/llama.
@@ -3159,18 +3190,32 @@ class ContinuousEngine:
                         **tracectx.span_fields(s.req.trace)):
             with host_phase("serve.admit.gather"):
                 if paged:
-                    # seed a virtual contiguous sequence cache from the
-                    # slot's pages: the unshared-suffix chunks attend over
-                    # the shared prefix k/v, positions start.. are written
-                    # before any later chunk reads them, and the scatter
-                    # puts everything back in place (shared pages get
-                    # byte-identical content)
+                    # the scratch sequence the engine keeps while it has
+                    # rows (else zeros: a gather of no page). The chunks
+                    # write positions start.. before any later chunk reads
+                    # them and what lies past them is an earlier
+                    # sequence's finite K / V under the causal mask, so
+                    # only the pages below ``start`` are gathered: a shared
+                    # prefix, or what a preempted prompt already holds (the
+                    # scratch may have served another slot meanwhile)
                     from .paging import SCRAP_PAGE
 
+                    ps = self.page_size
                     tbl = np.full((self._max_pages,), SCRAP_PAGE, np.int32)
                     tbl[:len(s.pages)] = s.pages
                     tbl_dev = jnp.asarray(tbl)
-                    cache_box = [self._gather_pages(self.cache, tbl_dev)]
+                    seq = self._admit_scratch
+                    if seq is None:
+                        seq = self._gather_pages(self.cache, tbl_dev,
+                                                 stop=np.int32(0))
+                    n_gather = -(-start // ps)
+                    if n_gather:
+                        seq = self._gather_pages(self.cache, tbl_dev,
+                                                 into=seq,
+                                                 stop=np.int32(n_gather))
+                        self.stats.admit_gathers += 1
+                    self._admit_scratch = None  # the chunks donate it
+                    cache_box = [seq]
                 else:
                     cache_box = [self._scratch_cache()]
 
@@ -3215,23 +3260,17 @@ class ContinuousEngine:
             with host_phase("serve.admit.state_insert" if self._state
                             else "serve.admit.scatter"):
                 if paged:
-                    tbl_scatter = tbl_dev
-                    if self.kv_quant == "q8":
-                        # q8 scatter must NOT re-quantize pages whose
-                        # bytes were published by an EARLIER encode
-                        # (quantize∘dequantize moves bytes): the shared
-                        # prefix keeps its first publisher's encoding,
-                        # and a preemption resume keeps the pages its
-                        # previous rounds already wrote — their scatter
-                        # entries park on the scrap page. The gather
-                        # above still reads them: suffix chunks attend
-                        # over the dequantized prefix.
-                        tbl_sc = tbl.copy()
-                        tbl_sc[:max(s.shared, start // self.page_size)] \
-                            = SCRAP_PAGE
-                        tbl_scatter = jnp.asarray(tbl_sc)
+                    # the pages the chunks filled and no others: the
+                    # range's lower bound is also the q8 rule (a page an
+                    # EARLIER encode published, the shared prefix's or a
+                    # resumed prompt's own, is not quantized twice)
+                    lo, hi = start // ps, -(-end // ps)
                     self.cache = self._scatter_pages(
-                        self.cache, cache_box[0], tbl_scatter)
+                        self.cache, cache_box[0], tbl_dev,
+                        start=np.int32(lo), stop=np.int32(hi))
+                    self._admit_scratch = cache_box[0]
+                    self.stats.admit_pages_moved += n_gather + hi - lo
+                    self.stats.admit_pages_table += 2 * self._max_pages
                     # publish the freshly prefilled full prompt pages NOW
                     # (not just at retire): a same-system-prompt request
                     # admitted into the next slot this very round already
@@ -3327,6 +3366,11 @@ class ContinuousEngine:
                 self._obs.kv_pages_free.set(self._alloc.n_free)
                 self._update_tier_obs()
         s.prefill_pending = False
+        if all(t.free or t is s for t in (*self._pool, *self._leaving)):
+            # the last row leaves: an idle engine holds no scratch sequence
+            # (4,096 positions of a 7B model's K / V are 1 GB; the next
+            # admission's is a fill of zeros)
+            self._admit_scratch = None
         s.req.t_finish = time.monotonic()
         if self._journal is not None and not self._suspending:
             # a drain-suspended request writes NO retirement: its admit +

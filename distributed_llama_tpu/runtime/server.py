@@ -1324,6 +1324,9 @@ class InferenceServer:
                   prefill_chunks=st.prefill_chunks,
                   admit_prefills=st.admit_prefills,
                   admits_back_to_back_max=st.admits_back_to_back_max,
+                  admit_pages_moved=st.admit_pages_moved,
+                  admit_pages_table=st.admit_pages_table,
+                  admit_gathers=st.admit_gathers,
                   fetch_wait_s=st.fetch_wait_s,
                   fetch_wait_behind_admit_s=st.fetch_wait_behind_admit_s,
                   admit_share=st.admit_share,

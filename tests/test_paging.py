@@ -302,6 +302,133 @@ def test_gather_scatter_pages_round_trip(params):
     np.testing.assert_array_equal(np.asarray(back.v), np.asarray(cache.v))
 
 
+def _pool_of(kind: str, n_pages: int, ps: int, seed: int):
+    """A pool of ``kind`` with random content, and the gather / scatter
+    that serve it: f32 K / V, q8 codes and deltas, ONE latent plane."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.models import llama
+    from distributed_llama_tpu.models.latent import LatentCache
+
+    rng = np.random.default_rng(seed)
+    L, kv, hs = 2, SPEC.n_kv_heads, 32
+    if kind == "q8":
+        codes, deltas = (L, n_pages, ps, kv, hs), (L, n_pages, ps, kv)
+        pool = llama.PagedKVQ8(*(
+            jnp.asarray(rng.integers(-127, 128, codes), jnp.int8) if i % 2 == 0
+            else jnp.asarray(rng.uniform(0.01, 0.1, deltas), jnp.float16)
+            for i in range(4)))
+        return pool, llama.gather_pages_q8, llama.scatter_pages_q8
+    shape = (L, n_pages, ps, 24) if kind == "latent" else (L, n_pages, ps,
+                                                          kv, hs)
+    planes = [jnp.asarray(rng.standard_normal(shape), jnp.float32)
+              for _ in range(1 if kind == "latent" else 2)]
+    pool = LatentCache(*planes) if kind == "latent" else llama.KVCache(
+        *planes)
+    return pool, llama.gather_pages, llama.scatter_pages
+
+
+def _seq_like(seq, seed: int):
+    """A sequence cache of ``seq``'s shape with other random content."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    return type(seq)(*(jnp.asarray(rng.standard_normal(p.shape), p.dtype)
+                       for p in seq))
+
+
+def _planes_equal(a, b, pages=None):
+    for x, y in zip(a, b):
+        x, y = np.asarray(x), np.asarray(y)
+        if pages is not None:
+            x, y = x[:, pages], y[:, pages]
+        np.testing.assert_array_equal(x, y)
+
+
+POOL_KINDS = ["f32", "q8", "latent"]
+
+
+@pytest.mark.parametrize("kind", POOL_KINDS)
+def test_gather_scatter_defaults_move_the_whole_table(kind):
+    """With no range the two are what they were before they took one: the
+    take of the table's pages, and the set of all of them."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.quants import (dequantize_q80_planes,
+                                                  quantize_q80_jax)
+
+    ps, n = 4, 6
+    pool, gather, scatter = _pool_of(kind, n + 1, ps, seed=1)
+    table = jnp.asarray(1 + np.arange(n, dtype=np.int32)[::-1])
+    seq = gather(pool, table, ps)
+
+    def taken(plane):
+        got = jnp.take(plane, table, axis=1)
+        return got.reshape(got.shape[0], n * ps, *got.shape[3:])
+
+    if kind == "q8":
+        want = [dequantize_q80_planes(taken(pool.kq), taken(pool.kd)),
+                dequantize_q80_planes(taken(pool.vq), taken(pool.vd))]
+    else:
+        want = [taken(plane) for plane in pool]
+    _planes_equal(seq, want)
+    new = _seq_like(seq, seed=2)
+    back = scatter(pool, new, table, ps)
+
+    def paged(plane):
+        return plane.reshape(plane.shape[0], n, ps, *plane.shape[2:])
+
+    if kind == "q8":
+        want = []
+        for plane in new:
+            qs, d = quantize_q80_jax(plane.reshape(*plane.shape[:2], -1))
+            want += [paged(qs.reshape(plane.shape)), paged(d)]
+    else:
+        want = [paged(plane) for plane in new]
+    _planes_equal(back, [p.at[:, table].set(w) for p, w in zip(pool, want)])
+
+
+@pytest.mark.parametrize("kind", POOL_KINDS)
+def test_ranged_scatter_writes_its_pages_only(kind):
+    """Pages ``[start, stop)`` of the table read as the whole-table scatter
+    leaves them; every other page of the pool keeps its bytes."""
+    import jax.numpy as jnp
+
+    ps, n = 4, 6
+    pool, gather, scatter = _pool_of(kind, n + 3, ps, seed=3)
+    table = np.asarray([5, 2, 7, 1, 8, 3], np.int32)
+    seq = _seq_like(gather(pool, jnp.asarray(table), ps), seed=4)
+    whole = scatter(pool, seq, jnp.asarray(table), ps)
+    for lo, hi in ((0, 2), (1, 4), (3, 6), (2, 2)):
+        got = scatter(pool, seq, jnp.asarray(table), ps,
+                      start=jnp.int32(lo), stop=jnp.int32(hi))
+        inside = table[lo:hi]
+        outside = np.setdiff1d(np.arange(n + 3), inside)
+        _planes_equal(got, whole, pages=inside)
+        _planes_equal(got, pool, pages=outside)
+
+
+@pytest.mark.parametrize("kind", POOL_KINDS)
+def test_ranged_gather_brings_the_pages_below_its_stop(kind):
+    """Positions under ``stop`` pages read as the whole-table gather's; the
+    rest of ``into`` stays as it was."""
+    import jax.numpy as jnp
+
+    ps, n = 4, 6
+    pool, gather, _ = _pool_of(kind, n + 3, ps, seed=5)
+    table = jnp.asarray([5, 2, 7, 1, 8, 3], jnp.int32)
+    whole = gather(pool, table, ps)
+    junk = _seq_like(whole, seed=6)
+    for stop in (0, 1, 3, 6):
+        got = gather(pool, table, ps, into=junk, stop=jnp.int32(stop))
+        for g, w, j in zip(got, whole, junk):
+            g = np.asarray(g)
+            np.testing.assert_array_equal(g[:, :stop * ps],
+                                          np.asarray(w)[:, :stop * ps])
+            np.testing.assert_array_equal(g[:, stop * ps:],
+                                          np.asarray(j)[:, stop * ps:])
+
+
 # -- engine behavior --------------------------------------------------------
 
 
@@ -347,6 +474,159 @@ def test_paged_streams_match_over_tp_mesh(params, scheme, monkeypatch):
     _, got, _ = _run(params, REQS[:3], 8, mesh=make_mesh(tp=2),
                      page_size=4, prefill_chunk=2, block_steps=3)
     assert got == ref
+
+
+def _admission_engine(params, kv_quant: str, hold_once: bool, **kw):
+    """A one-slot paged engine that snapshots each request's live pool
+    positions as it retires (a page freed is soon another request's)."""
+    import jax
+
+    from distributed_llama_tpu.runtime.continuous import ContinuousEngine
+
+    eng = ContinuousEngine(SPEC, params, slots=1, temperature=0.0, topp=0.9,
+                           seed=3, page_size=4, prefill_chunk=4,
+                           kv_quant=kv_quant, **kw)
+    if hold_once:
+        fired = []
+
+        def hold(slot):     # park at the first boundary, then resume
+            fired.append(slot)
+            return len(fired) == 1
+
+        eng.prefill_hold = hold
+    eng.live = []
+    retire = eng._retire
+
+    def snapshot(s, quiet):
+        pages = np.asarray(s.pages, np.int32)
+        eng.live.append([
+            np.asarray(p)[:, pages].reshape(p.shape[0], -1,
+                                            *p.shape[3:])[:, :s.pos]
+            for p in jax.tree.leaves(eng.cache)])
+        return retire(s, quiet)
+
+    eng._retire = snapshot
+    return eng
+
+
+def _as_before_the_ranges(eng):
+    """The admission the ranges replaced: a scratch of its own, the slot's
+    whole table gathered into it and scattered from it, and a q8 pool's
+    pages below the start parked on the scrap page for the scatter."""
+    gather, scatter, prefill = (eng._gather_pages, eng._scatter_pages,
+                                eng._maybe_prefill_slot)
+
+    def whole_gather(cache, table, into=None, stop=None):
+        return gather(cache, table)
+
+    def whole_scatter(cache, seq, table, start=None, stop=None):
+        if eng.kv_quant == "q8":
+            table = table.at[:start].set(SCRAP_PAGE)
+        return scatter(cache, seq, table)
+
+    def fresh_scratch(slot_index, s):
+        eng._admit_scratch = None
+        return prefill(slot_index, s)
+
+    eng._gather_pages, eng._scatter_pages = whole_gather, whole_scatter
+    eng._maybe_prefill_slot = fresh_scratch
+
+
+def _serve(eng, reqs, steps):
+    from distributed_llama_tpu.runtime.continuous import Request
+
+    sent = [Request(tokens=list(r), steps=steps) for r in reqs]
+    for r in sent:
+        eng.submit(r)
+    while eng.step_many(1, quiet=True):
+        pass
+    return [r.out for r in sent]
+
+
+_SYS = [1] + list(range(20, 28))      # 9 tokens: two full pages at 4 a page
+_LONG = [1] + [5 + (i % 20) for i in range(12)]     # 12 positions: 3 chunks
+
+
+@pytest.mark.parametrize("kv_quant,reqs,hold,gathers,moved", [
+    # nothing shared: no gather, and the pages the prompts cover scattered
+    ("f32", [[1, 5, 9, 14, 3, 8, 2], _LONG, [1, 60, 61, 62]], False, 0,
+     2 + 3 + 1),
+    ("q8", [[1, 5, 9, 14, 3, 8, 2], _LONG], False, 0, 2 + 3),
+    # the second prompt finds the first's two pages: they are gathered, and
+    # the page its own chunk filled scattered
+    ("f32", [_SYS + [40, 41], _SYS + [50, 51]], False, 1, 3 + 2 + 1),
+    ("q8", [_SYS + [40, 41], _SYS + [50, 51]], False, 1, 3 + 2 + 1),
+    # parked after its first chunk and resumed: the page it already holds
+    # is gathered again (the scratch may have served another slot)
+    ("f32", [_LONG], True, 1, 1 + 1 + 2),
+], ids=["unshared", "unshared-q8", "shared", "shared-q8", "resumed"])
+def test_admission_moves_the_pages_its_prompt_covers(params, kv_quant, reqs,
+                                                     hold, gathers, moved):
+    """Over a kept scratch full of large finite junk (no NaN: a masked
+    position weighs 0, and 0 x NaN is NaN) the streams and every live pool
+    position are what the whole-table admission leaves."""
+    import jax
+    import jax.numpy as jnp
+
+    ref = _admission_engine(params, kv_quant, hold)
+    _as_before_the_ranges(ref)
+    want = _serve(ref, reqs, 16)
+    eng = _admission_engine(params, kv_quant, hold)
+    junk = jax.tree.map(lambda p: np.full_like(p, 3e4), eng._gather_pages(
+        eng.cache, jnp.zeros((eng._max_pages,), jnp.int32)))
+    prefill = eng._maybe_prefill_slot
+
+    def junk_scratch(slot_index, s):    # as if it had served another slot
+        eng._admit_scratch = jax.tree.map(jnp.asarray, junk)
+        return prefill(slot_index, s)
+
+    eng._maybe_prefill_slot = junk_scratch
+    assert _serve(eng, reqs, 16) == want
+    assert len(eng.live) == len(ref.live) == len(reqs)
+    for got, exp in zip(eng.live, ref.live):
+        for g, e in zip(got, exp):
+            np.testing.assert_array_equal(g, e)
+    st = eng.stats
+    assert st.admit_prefills == len(reqs) + hold
+    assert (st.admit_gathers, st.admit_pages_moved) == (gathers, moved)
+    assert st.admit_pages_table == 2 * eng._max_pages * st.admit_prefills
+    assert (f"{moved} of {st.admit_pages_table} table pages moved"
+            in st.admission_clause)
+    assert f"{gathers} admissions gathered" in st.admission_clause
+    assert ref.stats.admit_gathers == gathers     # the same admissions
+    assert eng.audit_pages() == []
+
+
+def test_admission_scratch_is_kept_while_rows_remain(params):
+    """Admissions share ONE scratch sequence while the engine has rows (a
+    burst allocates one, not one each), and an idle engine holds none."""
+    from distributed_llama_tpu.runtime.continuous import (ContinuousEngine,
+                                                          Request)
+
+    eng = ContinuousEngine(SPEC, params, slots=2, temperature=0.0, topp=0.9,
+                           seed=3, page_size=4, prefill_chunk=4)
+    gather, fills = eng._gather_pages, []
+
+    def counted(cache, table, into=None, stop=None):
+        fills.append(into is None)
+        return gather(cache, table, into=into, stop=stop)
+
+    eng._gather_pages = counted
+    reqs = [Request(tokens=list(r), steps=n)    # the second ends first
+            for r, n in (([1, 5, 9, 14, 3, 8, 2], 16),
+                         ([1, 60, 61, 62, 63, 64], 9), (_LONG, 16))]
+    for r in reqs:
+        eng.submit(r)
+    eng.step_many(1, quiet=True)        # two admissions in one round
+    assert fills == [True] and eng._admit_scratch is not None
+    while eng.step_many(1, quiet=True):
+        pass
+    assert fills == [True]              # the third took the kept one
+    assert eng._admit_scratch is None and eng.stats.admit_prefills == 3
+    eng.submit(Request(tokens=[1, 70, 71, 72, 73, 74, 75], steps=16))
+    while eng.step_many(1, quiet=True):
+        pass
+    assert fills == [True, True]        # from idle: zeros again
 
 
 def test_fail_all_clears_tree_and_frees_pool(params):
