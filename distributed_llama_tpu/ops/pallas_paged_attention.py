@@ -1,45 +1,77 @@
 """Pallas TPU kernel: flash-decode attention over the PAGED page-pool KV
-cache (ISSUE 11 — the vLLM/PagedAttention move, Kwon et al. SOSP'23).
+cache, read through the page table (the vLLM / PagedAttention move, Kwon et
+al. SOSP'23): no gathered copy of a row's cache, HBM traffic proportional to
+the live positions.
 
-PR 6 made the paged pool the production layout but left it on the slowest
-attention path: ``models/llama.paged_decode_attention`` falls back to an
-XLA gather that materializes the row's whole virtual (B, S, n_kv, hs)
-plane in HBM every token (``jnp.take`` over the pool), because the
-contiguous flash kernel (ops/pallas_attention.py) assumes one contiguous
-cache row. This kernel walks the page table DIRECTLY: block = page is the
-natural tiling, and the DMA loop indexes each K/V page plane through the
-per-row int32 table — page i+1 prefetches while page i reduces, riding
-the SAME double-buffered machinery as the contiguous kernel
-(``pallas_attention._flash_walk``) with flash-decoding-style (Dao et al.)
-split-KV (m, l, o) accumulation. HBM traffic becomes pos-proportional
-again (live pages only) and the gather copy disappears.
+One kernel covers both paged shapes: the one-token decode step (t_len 1,
+``forward_batch_paged``) and the speculative-verify window (t_len 2..8,
+``forward_batch_spec_paged``; query i of a row sees positions 0..pos+i). The
+pool keeps its layout, (L*P, page_size, n_kv, hs): a page's positions
+outermost, the KV heads second-minor. grid=(B,): program b walks row b's live
+pages with ``pallas_attention._flash_walk``'s double-buffered DMA loop and
+keeps the running (m, l, o) of every query row; scores are scaled by
+1 / sqrt(hs).
 
-Shapes: ONE kernel covers both hot paged shapes — single-token decode
-(t_len=1, the forward_batch_paged step) and the (B, K) speculative-verify
-window (t_len=K, forward_batch_spec_paged; query i of a row sees virtual
-positions 0..pos+i, the stacked causal windows of sequential decode).
+Pages a turn. A page is smaller than the fold's tile (16 positions of the
+MXU's 128), so a loop turn lands ``_pages_a_turn`` pages (the head-major
+kernels' rule: 8 at 16 a page, one at 128) one after the other in a slot of
+C = G x page_size positions, (C, n_kv, hs), each page its own copy through
+the table, a turn's copies sharing a semaphore a slot and side. A turn's
+pages past the row's last live one are NOT copied (one loop over the turn's
+live pages starts the copies, one waits for them): the table's entries past
+it are never read, their place in the slot keeps what an earlier turn or row
+left there, and their positions are masked. A masked position's weight is
+exactly 0 and 0 x NaN would be NaN, so what is left there must be finite on
+the V side: the V slots start a call as zeros (``clear``) and only pool pages
+ever land in them; a NaN score from stale K is masked like any other.
 
-KV dtypes: f32/bf16 pages DMA raw planes; Q8 pages
-(``DLLAMA_KV_QUANT=q8``) DMA the int8 code planes PLUS the per-position
-f16 Q80 block-delta planes and dequantize inside the page loop
-(``_dequant_q80_page``) — bit for bit the ``codes.astype(f32) *
-delta.astype(f32)`` value map of the XLA fallback's gather-side dequant
-(ops/quants.dequantize_q80_planes), so both routes see identical f32 K/V
-values. The chip shapes how: it takes no f16 kernel argument and has no
-f16 vectors, so the delta planes travel as their raw int16 bits and are
-widened by hand, and its layout pass refuses the (ps, nb, QK) reshape of
-the codes, so the deltas are spread to the codes' shape instead.
+The fold is the head-major kernels' (``pallas_head_major_attention._fold``,
+as it lies): both contractions on the MXU, K and V read once for all the
+query rows of a group (a KV head's ``kv_mul`` query heads x ``t_len`` queries,
+padded to a sublane tile). Every product keeps all 24 bits of both operands
+(that module's docstring has the argument): each operand is cut in its three
+bf16 pieces, the small operand's pieces (the queries, cut once a call outside
+the kernel; the turn's weights p) stacked along the rows against each piece
+of K or V, the nine piece products each exact in the MXU's float32
+accumulator and added the small ones first. It wants K and V head-major,
+(n_kv, C, hs); the pool's heads are second-minor, and ``_heads`` is the
+bridge: where they are whole sublane tiles (n_kv % 8 == 0) the landed slot
+IS (C x n_kv, hs) with a position's heads on consecutive rows, so a KV
+head's K or V is ONE strided read of it (every n_kv-th row: a register a
+load, as many as reading the slot whole) and nothing is shuffled; a head
+count that is not whole tiles (a tp rank's) takes one relayout of the loaded
+slot. Alone on the chip (PERF.md section 7) the strided reads are within 7 to
+13 % of the copies alone; the same reads feeding a product a head 2 to 6 %
+ahead of that, at seven times the program's tracing (3 s of a serving
+cell's set-up on the chip's host); the relayout 1 to 5 % behind; a head
+INDEXED out of the slot (``k[:, h, :]``, of the ref or of its value) 1.7 to
+2.8 times slower: Mosaic gathers it a row at a time. What differs between
+OLMoE (16 KV heads x 1), Mistral (8 x 4), a verify window and a ``serve
+--tp`` rank (n_kv / tp heads) is the shape the kernel is traced with: no
+flag, no environment variable.
+
+KV dtypes: f32/bf16 pages DMA raw planes, and a float32 slot is read where
+it landed (the chip has no strided load of 16-bit rows: a bf16 slot is
+widened into one float32 slot a side first); Q8 pages
+(``DLLAMA_KV_QUANT=q8``) DMA the int8 code planes PLUS the per-position f16
+Q80 block-delta planes and dequantize a landed slot into the same float32
+slots (``_dequant_q80_page``), bit for bit the
+``codes.astype(f32) * delta.astype(f32)`` value map of the XLA fallback's
+gather-side dequant (ops/quants.dequantize_q80_planes), so both routes see
+identical f32 K/V values and the one fold sees float32. The chip shapes how:
+it takes no f16 kernel argument and has no f16 vectors, so the delta planes
+travel as their raw int16 bits and are widened by hand, and its layout pass
+refuses the (ps, nb, QK) reshape of the codes, so the deltas are spread to
+the codes' shape instead.
 
 Parity contract (tests/test_pallas_paged_attention.py): the kernel is
-INVARIANT to physical page placement — any permutation of the pool that
-updates the table produces bitwise-identical output — and element-level
-equal to the XLA gather path at the documented flash tolerance (the
-split-KV accumulation reassociates the softmax sums across page
-boundaries; the reduction-order deltas are ~1e-7 at f32, the same
-reassociation-only contract as the prefill flash kernel). The XLA gather
-fallback itself stays BITWISE equal to the contiguous cache — the PR 6
-gate — and is what CPU engines run (``attn_kernel_mode()`` auto-selects
-'xla' off-TPU, exactly like the contiguous kernel's gate).
+INVARIANT to physical page placement (any permutation of the pool that
+updates the table produces bitwise-identical output) and element-level
+equal to the XLA gather path at the flash tolerance (the split-KV
+accumulation reassociates the softmax sums across turns); its distance from
+a float64 attention is the float32 sums' alone. The XLA gather fallback
+stays BITWISE equal to the contiguous cache and is what CPU engines run
+(``attn_kernel_mode()`` auto-selects 'xla' off-TPU).
 """
 
 from __future__ import annotations
@@ -54,6 +86,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..ops.quants import QK
 from .pallas_attention import (_VMEM64_PARAMS, _VMEM_BUDGET, NEG_INF,
                                _flash_walk, attn_kernel_mode)
+from .pallas_head_major_attention import (_fold, _group_rows, _pages_a_turn,
+                                          _stack3)
 
 KV_QUANTS = ("f32", "q8")  # the --kv-quant vocabulary (f32 = cache dtype)
 
@@ -75,18 +109,23 @@ def kv_quant_mode() -> str:
 
 def _paged_scratch_bytes(page_size: int, n_kv: int, hs: int,
                          itemsize: int, q8: bool) -> int:
-    """2 slots x {K, V} page planes, plus the Q8 scale planes (f16, one
-    delta per QK values of the flattened (n_kv, hs) position row)."""
-    planes = 2 * 2 * page_size * n_kv * hs * itemsize
+    """2 slots x {K, V} planes of a turn's ``_pages_a_turn`` pages each, plus
+    the Q8 scale planes (f16, one delta per QK values of the flattened
+    (n_kv, hs) position row), plus, where a page is not float32 as it lies,
+    the one float32 slot a side a landed turn is widened into."""
+    positions = _pages_a_turn(page_size) * page_size
+    planes = 2 * 2 * positions * n_kv * hs * itemsize
     if q8:
-        planes += 2 * 2 * page_size * (n_kv * hs // QK) * 2
+        planes += 2 * 2 * positions * (n_kv * hs // QK) * 2
+    if itemsize != 4:
+        planes += 2 * positions * n_kv * hs * 4
     return planes
 
 
 def supports_paged(page_size: int, n_kv: int, head_size: int, t_len: int,
                    itemsize: int = 4, q8: bool = False) -> bool:
     """The kernel handles decode/verify windows up to 8 queries with
-    lane-width head_size and a page plane whose double-buffered scratch
+    lane-width head_size and a turn's pages whose double-buffered scratch
     fits the VMEM budget; Q8 pages additionally need the flattened
     (n_kv, hs) row to divide into Q80 blocks. Callers take the XLA gather
     fallback otherwise — same gating contract as the contiguous
@@ -98,136 +137,146 @@ def supports_paged(page_size: int, n_kv: int, head_size: int, t_len: int,
                                      q8) <= _VMEM_BUDGET)
 
 
-def _flash_pages(b, pos, q, table_ref, layer_ref, read_page, *,
-                 page_size: int, n_pages: int, max_pages: int, kv_mul: int,
-                 t_len: int):
-    """The paged flash walk for one batch row: double-buffered page DMA
-    through the table (``_flash_walk`` — the contiguous kernel's loop),
-    (m, l, o) accumulation widened to t_len queries. ``read_page`` is the
-    dtype hook: (slot, i, row) -> (start, wait) where wait(slot) returns
-    the landed page as f32 (k, v) planes — raw planes for f32/bf16 pages,
-    in-loop Q80 dequant for q8 pages. q: (t_len, n_kv, kv_mul, hs)."""
-    n_kv, hs = q.shape[1], q.shape[3]
-    scale = 1.0 / jnp.sqrt(jnp.float32(hs))
-    s_virt = max_pages * page_size
-    # live pages: the deepest query's position, clamped into the virtual
-    # plane (a budget-edge verify window walks every mapped page; its
-    # beyond-plane dead writes went to the scrap page and are never read)
-    last = jnp.minimum(pos + t_len - 1, s_virt - 1)
-    n_live = last // page_size + 1
-    q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (t_len, 1, 1), 0)
+def _heads(w, n_kv: int):
+    """A landed slot, a float32 ref (C, n_kv, hs), as the value (n_kv, C, hs)
+    the head-major fold takes.
 
-    def row_of(i):
-        # the page-table indirection: logical page i of row b lives at
-        # physical plane table[b, i] of layer layer_ref[0]
-        return layer_ref[0] * n_pages + table_ref[b, i]
-
-    def start_dma(slot, i):
-        read_page(slot, row_of(i)).start()
-
-    def wait_dma(slot, i):
-        read_page(slot, row_of(i)).wait()
-
-    def update(i, slot, carry):
-        k, v = read_page.landed(slot)                # (ps, n_kv, hs) f32
-        key_pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, n_kv), 0)
-        valid = key_pos[None] <= q_pos               # (t, ps, n_kv)
-        out = []
-        for mqi in range(kv_mul):
-            m_old, l_old, o_old = carry[mqi]         # (t,n_kv),(t,n_kv),
-            #                                          (t,n_kv,hs)
-            qm = q[:, :, mqi, :]                     # (t, n_kv, hs)
-            s = jnp.sum(k[None] * qm[:, None], axis=-1) * scale
-            s = jnp.where(valid, s, NEG_INF)         # (t, ps, n_kv)
-            m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])          # (t, ps, n_kv)
-            corr = jnp.exp(m_old - m_new)            # (t, n_kv)
-            l_new = l_old * corr + jnp.sum(p, axis=1)
-            po = jnp.sum(p[..., None] * v[None], axis=1)   # (t, n_kv, hs)
-            o_new = o_old * corr[..., None] + po
-            out.append((m_new, l_new, o_new))
-        return tuple(out)
-
-    init = tuple((jnp.full((t_len, n_kv), NEG_INF, jnp.float32),
-                  jnp.zeros((t_len, n_kv), jnp.float32),
-                  jnp.zeros((t_len, n_kv, hs), jnp.float32))
-                 for _ in range(kv_mul))
-    return _flash_walk(n_live, start_dma, wait_dma, update, init)
+    Where a position's heads are whole sublane tiles (n_kv % 8 == 0: every
+    single-chip pool) the slot is (C x n_kv, hs) as it lies, a position's
+    heads on consecutive rows, so a head's K or V is ONE strided read of it
+    (every n_kv-th row) and nothing is shuffled. Any other head count (a tp
+    rank's 2 or 10) is padded to a tile in the slot, which is then NOT those
+    rows (the chip compiles the view and reads wrong rows at 10 heads:
+    distance 0.59, PERF.md section 7): one relayout of the loaded slot."""
+    chunk, _, hs = w.shape
+    if n_kv % 8:
+        return jnp.swapaxes(w[...], 0, 1)
+    rows = w.reshape(chunk * n_kv, hs)
+    return jnp.stack([rows[pl.ds(h, chunk, stride=n_kv), :]
+                      for h in range(n_kv)])
 
 
-class _RawPages:
-    """f32/bf16 page reader: one K + one V plane DMA per page."""
+def _flash_pages(pos_ref, table_ref, layer_ref, q3_ref, out_ref, reader, *,
+                 page_size: int, n_pages: int, kv_mul: int, t_len: int):
+    """grid=(B,): program b walks row b's live pages through the table,
+    ``_pages_a_turn`` a turn (``_flash_walk``: the contiguous kernel's
+    double-buffered loop), folds each landed slot into the running (m, l, o)
+    of its query rows and writes them out normalised. q3_ref (1, n_kv, 3 R,
+    hs): a KV head's R rows are (query, head of the group), padded;
+    out_ref (1, n_kv, t_len * kv_mul, hs). ``reader`` is the dtype hook:
+    ``copies(slot, g, row)`` are the DMAs that land pool plane ``row`` as
+    page g of a slot, ``landed(slot)`` the slot's (k, v) as float32 refs
+    (C, n_kv, hs): the raw planes themselves for f32 pages, bf16 pages
+    widened and q8 pages dequantized into ``wide``."""
+    b = pl.program_id(0)
+    q3 = q3_ref[0]
+    n_kv, rows3, hs = q3.shape
+    rows, live = rows3 // 3, t_len * kv_mul
+    group = _pages_a_turn(page_size)
+    chunk = group * page_size
+    # the deepest query's position, clamped into the virtual plane (a
+    # budget-edge verify window walks every mapped page; its beyond-plane
+    # dead writes went to the scrap page and are never read)
+    last = jnp.minimum(pos_ref[b] + t_len - 1,
+                       table_ref.shape[1] * page_size - 1)
+    last_page = last // page_size
+    # the last position a query row attends: its own, never past the plane
+    # (a padded row: the deepest query's)
+    r = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
+    limit = jnp.minimum(pos_ref[b] + r // kv_mul, last)
+    key = jax.lax.broadcasted_iota(jnp.int32, (1, 1, chunk), 2)
 
-    def __init__(self, k_hbm, v_hbm, k_buf, v_buf, sems):
-        self.k_hbm, self.v_hbm = k_hbm, v_hbm
-        self.k_buf, self.v_buf = k_buf, v_buf
-        self.sems = sems
+    def each_copy(slot, i, act):
+        # a turn's pages past the row's last live one are not copied: their
+        # place in the slot keeps what an earlier turn (or an earlier row)
+        # left there, and their positions are masked. ONE loop over the
+        # turn's live pages, traced once (a ``pl.when`` a page is a traced
+        # branch a page and call site: 3 s of a serving cell's set-up on
+        # the chip's host, PERF.md section 7)
+        def page(g, _):
+            # the page-table indirection: logical page i of row b lives at
+            # physical plane table[b, i] of layer layer_ref[0]
+            row = layer_ref[0] * n_pages + table_ref[b, i * group + g]
+            for c in reader.copies(slot, g, row):
+                act(c)
 
-    def __call__(self, slot, row):
-        reader = self
+        jax.lax.fori_loop(0, jnp.minimum(group, last_page + 1 - i * group),
+                          page, None)
 
-        class _Pair:
-            def start(self):
-                pltpu.make_async_copy(reader.k_hbm.at[row],
-                                      reader.k_buf.at[slot],
-                                      reader.sems.at[slot, 0]).start()
-                pltpu.make_async_copy(reader.v_hbm.at[row],
-                                      reader.v_buf.at[slot],
-                                      reader.sems.at[slot, 1]).start()
+    # what a masked position leaves in a slot must be finite on the V side
+    # (its weight is exactly 0, and 0 x NaN would be NaN; a NaN score is
+    # masked): the slots start the call as zeros and only pages land there
+    pl.when(b == 0)(reader.clear)
 
-            def wait(self):
-                pltpu.make_async_copy(reader.k_hbm.at[row],
-                                      reader.k_buf.at[slot],
-                                      reader.sems.at[slot, 0]).wait()
-                pltpu.make_async_copy(reader.v_hbm.at[row],
-                                      reader.v_buf.at[slot],
-                                      reader.sems.at[slot, 1]).wait()
+    init = (jnp.full((n_kv, rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((n_kv, rows, 1), jnp.float32),
+            jnp.zeros((n_kv, rows, hs), jnp.float32))
+    _, l_fin, o_fin = _flash_walk(
+        last_page // group + 1,
+        lambda slot, i: each_copy(slot, i, lambda c: c.start()),
+        lambda slot, i: each_copy(slot, i, lambda c: c.wait()),
+        lambda i, slot, carry: _fold(
+            q3, *(_heads(w, n_kv) for w in reader.landed(slot)),
+            i * chunk + key <= limit, carry),
+        init)
+    out_ref[0] = (o_fin / l_fin)[:, :live]
 
-        return _Pair()
+
+class _Pages:
+    """What the two page readers share: ``planes``, the (pool plane in HBM,
+    its slots (2, C, ...) in VMEM) pairs a page is copied through, one
+    semaphore a slot and plane; ``wide``, the (k, v) float32 slots (C, n_kv,
+    hs) a landed turn is made float32 in where it does not land so."""
+
+    def __init__(self, planes, sems, wide, page_size):
+        self.planes, self.sems = planes, sems
+        self.wide, self.ps = wide, page_size
+
+    def copies(self, slot, g, row):
+        at = pl.ds(g * self.ps, self.ps)
+        return [pltpu.make_async_copy(hbm.at[row], buf.at[slot, at],
+                                      self.sems.at[slot, j])
+                for j, (hbm, buf) in enumerate(self.planes)]
+
+    def clear(self):
+        """Zero what scales a slot's V: the last plane's slots."""
+        buf = self.planes[-1][1]
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+
+class _RawPages(_Pages):
+    """f32/bf16 page reader: one K + one V plane DMA per page into slots
+    k / v_buf (2, C, n_kv, hs); sems (2, 2): slot x {k, v}. f32 pages are
+    read where they land (no ``wide``); bf16 pages are widened."""
 
     def landed(self, slot):
-        return (self.k_buf[slot].astype(jnp.float32),
-                self.v_buf[slot].astype(jnp.float32))
+        if not self.wide:
+            return tuple(buf.at[slot] for _, buf in self.planes)
+        for (_, buf), w in zip(self.planes, self.wide):
+            w[...] = buf[slot].astype(jnp.float32)
+        return self.wide
 
 
-class _Q8Pages:
+class _Q8Pages(_Pages):
     """Q8 page reader: int8 code planes + Q80 delta planes as f16 bits
-    (4 DMAs per page), dequantized on land with the exact XLA-fallback
-    value map (_dequant_q80_page)."""
+    (4 DMAs per page: K codes, K deltas, V codes, V deltas) into slots
+    (2, C, n_kv, hs) and (2, C, nb), dequantized on land with the exact
+    XLA-fallback value map (_dequant_q80_page); sems (2, 4). ``clear``
+    zeroes V's deltas: zeros scale whatever codes a slot holds to 0.0."""
 
-    def __init__(self, kq_hbm, kd_hbm, vq_hbm, vd_hbm, kq_buf, kd_buf,
-                 vq_buf, vd_buf, sems):
-        self.planes = ((kq_hbm, kq_buf, 0), (kd_hbm, kd_buf, 1),
-                       (vq_hbm, vq_buf, 2), (vd_hbm, vd_buf, 3))
-        self.sems = sems
-        # page-invariant: built once per program, not once per page
-        _, _, n_kv, hs = kq_buf.shape
-        self.masks = _q80_spread_masks(n_kv, hs)
-
-    def __call__(self, slot, row):
-        reader = self
-
-        class _Quad:
-            def start(self):
-                for hbm, buf, j in reader.planes:
-                    pltpu.make_async_copy(hbm.at[row], buf.at[slot],
-                                          reader.sems.at[slot, j]).start()
-
-            def wait(self):
-                for hbm, buf, j in reader.planes:
-                    pltpu.make_async_copy(hbm.at[row], buf.at[slot],
-                                          reader.sems.at[slot, j]).wait()
-
-        return _Quad()
+    def __init__(self, planes, sems, wide, page_size):
+        super().__init__(planes, sems, wide, page_size)
+        # page-invariant: built once per program, not once per turn
+        self.masks = _q80_spread_masks(*planes[0][1].shape[2:])
 
     def landed(self, slot):
-        (_, kq_buf, _), (_, kd_buf, _), (_, vq_buf, _), (_, vd_buf, _) = \
-            self.planes
         # the deltas land as raw f16 BITS (int16 planes, see
         # paged_decode_attention_kernel_q8) and are widened by hand
-        return (_dequant_q80_page(kq_buf[slot], kd_buf[slot], self.masks),
-                _dequant_q80_page(vq_buf[slot], vd_buf[slot], self.masks))
+        (_, kq_buf), (_, kd_buf), (_, vq_buf), (_, vd_buf) = self.planes
+        for w, q_buf, d_buf in zip(self.wide, (kq_buf, vq_buf),
+                                   (kd_buf, vd_buf)):
+            w[...] = _dequant_q80_page(q_buf[slot], d_buf[slot], self.masks)
+        return self.wide
 
 
 def _f16_bits_to_f32(bits):
@@ -283,45 +332,60 @@ def _dequant_q80_page(codes, d, masks=None):
     return codes.astype(jnp.float32) * scale.reshape(ps, n_kv, hs)
 
 
-def _write_flash_out(final, out_ref, kv_mul: int):
-    """THE (m, l, o) -> output normalization epilogue, shared by the f32
-    and q8 kernels so a change to the finalization cannot drift between
-    the two routes (they differ ONLY in how pages land in VMEM)."""
-    for mqi in range(kv_mul):
-        _, l_i, o_i = final[mqi]
-        out_ref[0, :, :, mqi, :] = o_i / l_i[..., None]
+def _kernel_paged(layer_ref, pos_ref, table_ref, q3_ref, k_hbm, v_hbm,
+                  out_ref, k_buf, v_buf, sems, *wide, page_size: int,
+                  **walk):
+    """k/v_hbm: (L*P, ps, n_kv, hs) pool planes in HBM."""
+    _flash_pages(pos_ref, table_ref, layer_ref, q3_ref, out_ref,
+                 _RawPages(((k_hbm, k_buf), (v_hbm, v_buf)), sems, wide,
+                           page_size),
+                 page_size=page_size, **walk)
 
 
-def _kernel_paged(layer_ref, pos_ref, table_ref, q_ref, k_hbm, v_hbm,
-                  out_ref, k_buf, v_buf, sems, *, page_size: int,
-                  kv_mul: int, n_pages: int, t_len: int):
-    """grid=(B,): program b flash-walks its live pages through the table.
-    q_ref/out_ref: per-b (1, t_len, n_kv, kv_mul, hs) VMEM blocks;
-    k/v_hbm: (L*P, ps, n_kv, hs) pool planes in HBM; k/v_buf: (2, ps,
-    n_kv, hs) VMEM scratch; sems (2, 2) DMA semaphores (slot x {k, v})."""
-    b = pl.program_id(0)
-    reader = _RawPages(k_hbm, v_hbm, k_buf, v_buf, sems)
-    final = _flash_pages(b, pos_ref[b], q_ref[0], table_ref, layer_ref,
-                         reader, page_size=page_size, n_pages=n_pages,
-                         max_pages=table_ref.shape[1], kv_mul=kv_mul,
-                         t_len=t_len)
-    _write_flash_out(final, out_ref, kv_mul)
-
-
-def _kernel_paged_q8(layer_ref, pos_ref, table_ref, q_ref, kq_hbm, kd_hbm,
+def _kernel_paged_q8(layer_ref, pos_ref, table_ref, q3_ref, kq_hbm, kd_hbm,
                      vq_hbm, vd_hbm, out_ref, kq_buf, kd_buf, vq_buf,
-                     vd_buf, sems, *, page_size: int, kv_mul: int,
-                     n_pages: int, t_len: int):
+                     vd_buf, sems, *wide, page_size: int, **walk):
     """_kernel_paged's Q8 twin: int8 code + int16 (f16 bits) delta planes
-    per page, dequantized inside the page loop; sems (2, 4)."""
-    b = pl.program_id(0)
-    reader = _Q8Pages(kq_hbm, kd_hbm, vq_hbm, vd_hbm, kq_buf, kd_buf,
-                      vq_buf, vd_buf, sems)
-    final = _flash_pages(b, pos_ref[b], q_ref[0], table_ref, layer_ref,
-                         reader, page_size=page_size, n_pages=n_pages,
-                         max_pages=table_ref.shape[1], kv_mul=kv_mul,
-                         t_len=t_len)
-    _write_flash_out(final, out_ref, kv_mul)
+    per page, dequantized a landed slot."""
+    _flash_pages(pos_ref, table_ref, layer_ref, q3_ref, out_ref,
+                 _Q8Pages(((kq_hbm, kq_buf), (kd_hbm, kd_buf),
+                           (vq_hbm, vq_buf), (vd_hbm, vd_buf)), sems, wide,
+                          page_size),
+                 page_size=page_size, **walk)
+
+
+def _paged_call(kernel, q, planes, scratch, layer, pos, table, *,
+                page_size: int, n_pages: int, kv_mul: int, t_len: int,
+                interpret: bool | None):
+    """The ``pallas_call`` both kernels share: layer, clocks and table in
+    SMEM, a row's queries a KV head (``t_len x kv_mul`` rows, padded to a
+    sublane tile and cut in stacked pieces here, once a call), the pool
+    planes left in HBM. Returns (B, t_len, n_q * hs)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    B, live = q.shape[0], t_len * kv_mul
+    n_kv, hs = planes[0].shape[2:]
+    qg = q.reshape(B, t_len, n_kv, kv_mul, hs).astype(jnp.float32)
+    qg = jnp.swapaxes(qg, 1, 2).reshape(B, n_kv, live, hs)
+    q3 = _stack3(jnp.pad(qg, ((0, 0), (0, 0),
+                              (0, _group_rows(live) - live), (0, 0))))
+    out = pl.pallas_call(
+        functools.partial(kernel, page_size=page_size, n_pages=n_pages,
+                          kv_mul=kv_mul, t_len=t_len),
+        grid=(B,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
+        + [pl.BlockSpec((1, *q3.shape[1:]), lambda b: (b, 0, 0, 0))]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(planes),
+        out_specs=pl.BlockSpec((1, n_kv, live, hs), lambda b: (b, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, live, hs), jnp.float32),
+        scratch_shapes=scratch,
+        compiler_params=_VMEM64_PARAMS,
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.asarray(pos, jnp.int32).reshape(B),
+      jnp.asarray(table, jnp.int32), q3, *planes)
+    out = jnp.swapaxes(out.reshape(B, n_kv, t_len, kv_mul, hs), 1, 2)
+    return out.reshape(B, t_len, n_kv * kv_mul * hs)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "n_pages",
@@ -337,39 +401,16 @@ def paged_decode_attention_kernel(q, k4, v4, layer, pos, table, *,
     q: (B, t_len, n_q*hs) f32; pos: (B,) per-row clocks; table:
     (B, max_pages) int32 physical page ids in logical order. Returns
     (B, t_len, n_q * hs) f32. Gate with supports_paged()."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    LP, ps, n_kv, hs = k4.shape
-    B = q.shape[0]
-    qg = q.reshape(B, t_len, n_kv, kv_mul, hs).astype(jnp.float32)
-    out = pl.pallas_call(
-        functools.partial(_kernel_paged, page_size=page_size,
-                          kv_mul=kv_mul, n_pages=n_pages, t_len=t_len),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t_len, n_kv, kv_mul, hs),
-                         lambda b: (b, 0, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, t_len, n_kv, kv_mul, hs),
-                               lambda b: (b, 0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, t_len, n_kv, kv_mul, hs),
-                                       jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, ps, n_kv, hs), k4.dtype),
-            pltpu.VMEM((2, ps, n_kv, hs), k4.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.asarray(pos, jnp.int32).reshape(B),
-      jnp.asarray(table, jnp.int32), qg, k4, v4)
-    return out.reshape(B, t_len, n_kv * kv_mul * hs)
+    _, ps, n_kv, hs = k4.shape
+    positions = _pages_a_turn(ps) * ps
+    slot = pltpu.VMEM((2, positions, n_kv, hs), k4.dtype)
+    wide = [] if k4.dtype == jnp.float32 else [
+        pltpu.VMEM((positions, n_kv, hs), jnp.float32)] * 2
+    return _paged_call(
+        _kernel_paged, q, (k4, v4),
+        [slot, slot, pltpu.SemaphoreType.DMA((2, 2)), *wide],
+        layer, pos, table, page_size=page_size, n_pages=n_pages,
+        kv_mul=kv_mul, t_len=t_len, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "n_pages",
@@ -383,12 +424,8 @@ def paged_decode_attention_kernel_q8(q, kq4, kd4, vq4, vd4, layer, pos,
     """Q8 twin of paged_decode_attention_kernel: pool planes are the Q80
     int8 codes (L*P, ps, n_kv, hs) plus f16 block deltas (L*P, ps, nb),
     dequantized inside the kernel's page loop."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    LP, ps, n_kv, hs = kq4.shape
-    nb = n_kv * hs // QK
-    B = q.shape[0]
-    qg = q.reshape(B, t_len, n_kv, kv_mul, hs).astype(jnp.float32)
+    _, ps, n_kv, hs = kq4.shape
+    positions = _pages_a_turn(ps) * ps
 
     def f16_bits(d):
         # the chip takes no f16 kernel argument ("Only arguments with ...
@@ -397,39 +434,15 @@ def paged_decode_attention_kernel_q8(q, kq4, kd4, vq4, vd4, layer, pos,
         # same-width bitcast, no copy
         return jax.lax.bitcast_convert_type(d, jnp.int16)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel_paged_q8, page_size=page_size,
-                          kv_mul=kv_mul, n_pages=n_pages, t_len=t_len),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t_len, n_kv, kv_mul, hs),
-                         lambda b: (b, 0, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, t_len, n_kv, kv_mul, hs),
-                               lambda b: (b, 0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, t_len, n_kv, kv_mul, hs),
-                                       jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, ps, n_kv, hs), jnp.int8),
-            pltpu.VMEM((2, ps, nb), jnp.int16),
-            pltpu.VMEM((2, ps, n_kv, hs), jnp.int8),
-            pltpu.VMEM((2, ps, nb), jnp.int16),
-            pltpu.SemaphoreType.DMA((2, 4)),
-        ],
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.asarray(pos, jnp.int32).reshape(B),
-      jnp.asarray(table, jnp.int32), qg, kq4, f16_bits(kd4), vq4,
-      f16_bits(vd4))
-    return out.reshape(B, t_len, n_kv * kv_mul * hs)
+    codes = pltpu.VMEM((2, positions, n_kv, hs), jnp.int8)
+    deltas = pltpu.VMEM((2, positions, n_kv * hs // QK), jnp.int16)
+    wide = pltpu.VMEM((positions, n_kv, hs), jnp.float32)
+    return _paged_call(
+        _kernel_paged_q8, q, (kq4, f16_bits(kd4), vq4, f16_bits(vd4)),
+        [codes, deltas, codes, deltas, pltpu.SemaphoreType.DMA((2, 4)),
+         wide, wide],
+        layer, pos, table, page_size=page_size, n_pages=n_pages,
+        kv_mul=kv_mul, t_len=t_len, interpret=interpret)
 
 
 def would_use_paged_kernel(page_size: int, n_kv: int, head_size: int,

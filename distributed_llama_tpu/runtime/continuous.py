@@ -486,6 +486,10 @@ class ContinuousStats:
     # p + 1): what the latent decode kernel must move, a layer
     latent_pages: int = 0
     latent_positions: int = 0
+    # a plain KV page pool (a Llama-family spec's): the cached positions the
+    # launched decode steps' rows read in ONE layer, summed the same way:
+    # what ``paged_decode_attention_kernel`` must move, a layer
+    paged_kv_positions: int = 0
     # ... and of its admission chunks: the positions of the gathered plane
     # a chunk's attention read, a layer (models/latent.attend_live walks
     # the blocks up to start + T; a T <= 8 chunk reads them all), and the
@@ -2730,7 +2734,8 @@ class ContinuousEngine:
                     row[-1] = rows[b] is not None
             if prev is not None and not any(r is not None for r in rows):
                 return None
-            if self.spec.latent or self._hybrid:
+            if (self.spec.latent or self._hybrid
+                    or self._alloc is not None):
                 # what each riding row reads: itself and what came before
                 depth = [int(blk[b, 1]) + 1 for b, s in enumerate(rows)
                          if s is not None]
@@ -2739,8 +2744,10 @@ class ContinuousEngine:
                     self.stats.shared_kv_positions += sum(depth)
                     self.stats.window_kv_positions += sum(
                         min(d, w) for d in depth)
-                else:
+                elif self.spec.latent:
                     self.stats.latent_positions += sum(depth)
+                else:
+                    self.stats.paged_kv_positions += sum(depth)
             staged = self.jnp.asarray(blk)
         with host_phase("serve.dispatch"):
             logits, picked, self.cache, *more = self._decode(

@@ -1313,7 +1313,10 @@ class InferenceServer:
                      f"{st.latent_positions} cached positions read over "
                      f"{st.latent_pages} pages in use at the end, "
                      f"{st.moe_pairs} routed pairs"
-                     if st.hc_streams else "")
+                     if st.hc_streams else
+                     f"; {st.paged_kv_positions} cached positions read a "
+                     f"layer of the KV page pool"
+                     if st.paged_kv_positions else "")
                   + (f"; chunks walked {st.chunk_walk_share:.1%} of the "
                      f"plane" if st.chunk_plane_positions else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
@@ -1337,6 +1340,7 @@ class InferenceServer:
                   hc_sublayers_a_step=st.hc_sublayers_a_step,
                   latent_positions=st.latent_positions,
                   latent_pages=st.latent_pages,
+                  paged_kv_positions=st.paged_kv_positions,
                   chunk_walked_positions=st.chunk_walked_positions,
                   chunk_plane_positions=st.chunk_plane_positions,
                   moe_pairs=st.moe_pairs,

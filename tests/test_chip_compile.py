@@ -14,8 +14,9 @@ Nothing runs and nothing is timed: a compile that passes is not a chip run.
 
 Each case is one kernel entry at ``interpret=False`` on ShapeDtypeStructs
 pinned to one described v5e device, and asserts the Mosaic custom call is in
-the compiled program. Cases stay near a second each (tier-1 budget): no
-page-size-128 x t_len-4 paged case (~50 s). The two whole programs at the
+the compiled program. Cases stay near a second each (tier-1 budget; the
+page-size-128 x t_len-4 paged case took ~50 s on the vector-unit fold and was
+left out until PR 47: seconds on the MXU fold). The two whole programs at the
 end (the tp=4 step and prefill chunk at Yi-34B's shard-local widths, about
 5 s each) guard what no single kernel shows: a weight-sized layout copy in
 the entry computation.
@@ -169,32 +170,34 @@ def _prefill(dtype):
              _sd((), jnp.int32)))
 
 
-def _paged(dtype, t_len: int, ps: int = 16):
+def _paged(dtype, t_len: int, ps: int = 16, n_kv: int = N_KV,
+           kv_mul: int = 1, b: int = 8):
     from distributed_llama_tpu.ops.pallas_paged_attention import (
         paged_decode_attention_kernel)
 
-    b, pages = 8, 64
-    pool = _sd((2 * pages, ps, N_KV, HS), dtype)
+    pages = 64
+    pool = _sd((2 * pages, ps, n_kv, HS), dtype)
     return (functools.partial(paged_decode_attention_kernel, page_size=ps,
-                              n_pages=pages, kv_mul=1, t_len=t_len,
+                              n_pages=pages, kv_mul=kv_mul, t_len=t_len,
                               interpret=False),
-            (_sd((b, t_len, N_KV * HS), jnp.float32), pool, pool,
+            (_sd((b, t_len, n_kv * kv_mul * HS), jnp.float32), pool, pool,
              _sd((), jnp.int32), _sd((b,), jnp.int32),
              _sd((b, SEQ // ps), jnp.int32)))
 
 
-def _paged_q8(ps: int = 16):
+def _paged_q8(ps: int = 16, t_len: int = 1, n_kv: int = N_KV,
+              kv_mul: int = 1):
     from distributed_llama_tpu.ops.pallas_paged_attention import (
         paged_decode_attention_kernel_q8)
 
     b, pages = 8, 64
-    codes = _sd((2 * pages, ps, N_KV, HS), jnp.int8)
-    deltas = _sd((2 * pages, ps, N_KV * HS // 32), jnp.float16)
+    codes = _sd((2 * pages, ps, n_kv, HS), jnp.int8)
+    deltas = _sd((2 * pages, ps, n_kv * HS // 32), jnp.float16)
     return (functools.partial(paged_decode_attention_kernel_q8, page_size=ps,
-                              n_pages=pages, kv_mul=1, t_len=1,
+                              n_pages=pages, kv_mul=kv_mul, t_len=t_len,
                               interpret=False),
-            (_sd((b, 1, N_KV * HS), jnp.float32), codes, deltas, codes,
-             deltas, _sd((), jnp.int32), _sd((b,), jnp.int32),
+            (_sd((b, t_len, n_kv * kv_mul * HS), jnp.float32), codes, deltas,
+             codes, deltas, _sd((), jnp.int32), _sd((b,), jnp.int32),
              _sd((b, SEQ // ps), jnp.int32)))
 
 
@@ -402,6 +405,33 @@ CASES = {
     # type for load ... xf16" and "Only arguments with ... bfloat16 or 32-bit
     # element types are supported"
     "paged-q8-ps16-t1": (_paged_q8, True),
+    # PR 47: a turn lands the pages that make 128 positions in one slot and
+    # folds it on the MXU, a KV head a strided read of the slot: at OLMoE's
+    # shape (16 KV heads x 1, 16 rows) and Mistral's (8 x 4, 8 rows), decode
+    # and a verify window of 4 and of 8; a bf16 pool (widened a slot); pages
+    # of 128 (one a turn); a tp-4 rank's two KV heads (Mistral's or Yi's)
+    # and 13B's ten; the q8 twin's window and its pages of 128
+    **{f"paged-f32-ps16-kv{n}x{m}-b{b}-t{t}":
+       (functools.partial(_paged, jnp.float32, t, n_kv=n, kv_mul=m, b=b),
+        True)
+       for n, m, b in ((16, 1, 16), (8, 4, 8)) for t in (1, 4)},
+    "paged-f32-ps16-kv8x4-t8":
+        (functools.partial(_paged, jnp.float32, 8, n_kv=8, kv_mul=4), True),
+    "paged-bf16-ps16-kv8x4-t1":
+        (functools.partial(_paged, jnp.bfloat16, 1, n_kv=8, kv_mul=4), True),
+    "paged-f32-ps128-t4": (functools.partial(_paged, jnp.float32, 4, 128),
+                           True),
+    "paged-f32-ps128-kv8x4-t4":
+        (functools.partial(_paged, jnp.float32, 4, 128, n_kv=8, kv_mul=4),
+         True),
+    "paged-f32-ps16-kv2x4-t1":
+        (functools.partial(_paged, jnp.float32, 1, n_kv=2, kv_mul=4), True),
+    "paged-f32-ps16-kv2x4-t8":
+        (functools.partial(_paged, jnp.float32, 8, n_kv=2, kv_mul=4), True),
+    "paged-f32-ps16-kv10x1-t1":
+        (functools.partial(_paged, jnp.float32, 1, n_kv=10), True),
+    "paged-q8-ps16-t4": (functools.partial(_paged_q8, t_len=4), True),
+    "paged-q8-ps128-t1": (functools.partial(_paged_q8, 128), True),
     # was (first written with ``ref.at[0]`` views of blocks 1 to 4 lanes
     # wide): "Slice shape along dimension 3 must be aligned to tiling (128),
     # but is 4"
@@ -524,6 +554,26 @@ def test_kernel_compiles_for_v5e(chip, case):
         shapes)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert ("tpu_custom_call" in text) == kernel, case
+
+
+@pytest.mark.parametrize("n_kv,kv_mul,t_len", [(8, 4, 1), (8, 4, 4),
+                                               (16, 1, 1), (16, 1, 4)])
+def test_q8_pages_under_128_blocks_a_position_are_refused(chip, n_kv, kv_mul,
+                                                          t_len):
+    """What the q8 twin cannot do yet, held still so that a repair shows: at
+    Mistral's and OLMoE's head counts a position's Q80 deltas are 32 and 64
+    values, the chip stores a plane's minor dim in 128 lanes, and Mosaic
+    refuses the copy of a page's delta plane ("Slice shape along dimension
+    2 must be aligned to tiling (128)"). The kernel before PR 47 was refused
+    the same way (the plane is the same whatever a turn lands); a 32-head
+    pool (128 deltas a position) compiles, above. ``supports_paged`` does
+    not know (ROADMAP S4): ``--kv-quant q8`` has no cell."""
+    fn, shapes = _paged_q8(t_len=t_len, n_kv=n_kv, kv_mul=kv_mul)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        shapes)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(fn).lower(*args).compile()
 
 
 @pytest.mark.parametrize("rows,name", [(32, "moe_q40_slots"),

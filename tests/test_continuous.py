@@ -619,6 +619,37 @@ def test_all_greedy_run_counts_its_steps_ahead_and_fetches_no_logits(
     assert st.rows_dropped_ahead == 0
 
 
+@pytest.mark.parametrize("kind", ["paged", "contiguous", "expert"])
+def test_paged_steps_count_the_positions_their_rows_read(ahead_kinds, kind):
+    """``ContinuousStats.paged_kv_positions``: position + 1 a riding row of
+    every launched step of a plain KV page pool, read back here from each
+    launch's staged block (a row that does not take part rides on the scrap
+    page alone); nothing without pages, and nothing on the counters of the
+    other pools (tests/test_latent.py, test_sambay.py and test_laguna.py
+    hold it at 0 on theirs)."""
+    from distributed_llama_tpu.runtime.paging import SCRAP_PAGE
+
+    eng = _ahead_engine(ahead_kinds, kind)
+    real, read = eng._decode, []
+
+    def staged(*args):
+        blk = np.asarray(args[3])
+        rides = (blk[:, 2:] != SCRAP_PAGE).any(axis=1)
+        read.append(int((blk[rides, 1] + 1).sum()))
+        return real(*args)
+
+    eng._decode = staged
+    for r in _requests(kind):
+        eng.submit(r)
+    _drain(eng)
+    st = eng.stats
+    if kind == "contiguous":
+        assert st.paged_kv_positions == 0
+        return
+    assert st.paged_kv_positions == sum(read) > st.sum_active
+    assert st.latent_positions == st.shared_kv_positions == 0
+
+
 def test_a_row_with_a_temperature_holds_the_iteration_synchronous(
         ahead_kinds):
     """While a row with a temperature is active no step is launched ahead

@@ -373,6 +373,7 @@ def test_serve_more_requests_than_slots(tree, tokens, want):
     assert st.moe_pairs == st.steps * 2 * 2 * 8 == st.moe_local_pairs
     assert st.moe_load.shape == (8,) and st.moe_chunk_pairs > 0
     assert st.shared_kv_positions > st.window_kv_positions > 0
+    assert st.paged_kv_positions == 0     # a plain KV pool's counter
     assert st.shared_kv_pages >= 0
 
 

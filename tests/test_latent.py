@@ -624,6 +624,7 @@ def test_engine_serves_with_prefix_sharing_and_counts_its_share(kernel_mode,
     assert st.moe_active <= st.moe_local_pairs
     assert st.moe_load.shape == (16,)
     assert st.latent_pages > 0 and st.latent_positions > st.steps
+    assert st.paged_kv_positions == 0     # a plain KV pool's counter
     assert reg.get("dllama_moe_local_pairs_total").value == \
         st.moe_local_pairs
     # three rows a dispatch: a held expert's rows fit one slot, and a pair

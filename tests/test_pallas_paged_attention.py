@@ -1,4 +1,10 @@
-"""Paged flash-decode Pallas kernel (ISSUE 11), interpret-mode gates.
+"""Paged flash-decode Pallas kernel, interpret-mode gates.
+
+A loop turn lands the pages that make 128 positions (8 at 16 a page) and
+folds them on the MXU in exact float32 pieces, a KV head a strided read of
+the slot, so the cases sit at positions that cross a page and a turn, at
+Mistral's (8 KV heads x 4), OLMoE's (16 x 1) and a tp rank's (2 x 4) head
+counts.
 
 Contract layers:
 
@@ -13,6 +19,9 @@ Contract layers:
   reach the output;
 * Q8 pages: the in-kernel dequant agrees with the XLA fallback's
   gather-side dequant (identical value map, flash-tolerance reduction);
+* float32 kept: the fold is no further from a float64 attention than the
+  vector-unit fold it replaced was on the same inputs, and a bfloat16
+  attention is far;
 * routing: the ONE maybe_paged_flash_decode gate drives the kernel
   through models/llama.paged_decode_attention + spec_verify_attention
   and both tp factories — pinned over tp x scheme x kv-quant with the
@@ -58,6 +67,183 @@ def _xla_reference(q, k4, v4, layer, pos, table, ps, P, kv_mul, t_len):
     return np.asarray(attention_core(
         hs, kv_mul, jnp.asarray(q).reshape(B, t_len, n_kv * kv_mul, hs),
         k_c, v_c, mask)).reshape(B, t_len, -1)
+
+
+# (n_kv, kv_mul): Mistral's pool, OLMoE's, a 7B pool's heads with one query
+# head each, and a tp-4 rank of Mistral's (heads that are not a sublane tile)
+HEADS = [(8, 4), (16, 1), (8, 1), (2, 4)]
+# pages of 16, ten a row: a turn is 8 pages = 128 positions. A row each at
+# depth 0, a page's last position, the next page's first, one under, at and
+# one over a turn's edge, and at the last position of the plane (a verify
+# window's last queries lie past it: the budget edge)
+PS, MAX_PAGES = 16, 10
+EDGES = [0, 15, 16, 127, 128, 129, MAX_PAGES * PS - 1]
+
+
+def _edge_case(n_kv, kv_mul, t_len, seed, pos=EDGES):
+    """One call's operands with a row at each of ``pos``: (q, k4, v4, pos,
+    table, P), two layers' pools."""
+    B = len(pos)
+    P = B * MAX_PAGES + 1
+    k4, v4 = _pool(2, P, PS, n_kv, 128, seed=seed)
+    table = _scrambled_table(B, MAX_PAGES, P, seed=seed)
+    q = np.random.default_rng(seed).normal(
+        size=(B, t_len, n_kv * kv_mul * 128)).astype(np.float32)
+    return q, k4, v4, np.asarray(pos, np.int32), table, P
+
+
+def _run(q, k4, v4, pos, table, P, kv_mul, t_len, layer=1):
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_paged_attention import \
+        paged_decode_attention_kernel
+
+    return np.asarray(paged_decode_attention_kernel(
+        jnp.asarray(q), jnp.asarray(k4), jnp.asarray(v4), layer, pos,
+        jnp.asarray(table), page_size=k4.shape[1], n_pages=P, kv_mul=kv_mul,
+        t_len=t_len, interpret=True))
+
+
+@pytest.mark.parametrize("t_len", [1, 4])
+@pytest.mark.parametrize("n_kv,kv_mul", HEADS)
+def test_paged_walk_matches_xla_gather_across_pages_and_turns(n_kv, kv_mul,
+                                                              t_len):
+    """Decode (t 1) and a verify window (t 4) against the XLA gather path,
+    a row at each of ``EDGES``: a group's heads and a window's queries are
+    rows of one product, each with its own causal bound."""
+    q, k4, v4, pos, table, P = _edge_case(n_kv, kv_mul, t_len,
+                                          seed=n_kv + t_len)
+    got = _run(q, k4, v4, pos, table, P, kv_mul, t_len)
+    want = _xla_reference(q, k4, v4, 1, pos, table, PS, P, kv_mul, t_len)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t_len", [1, 4])
+@pytest.mark.parametrize("n_kv,kv_mul", HEADS)
+def test_a_turn_reads_the_rows_own_live_pages_only(n_kv, kv_mul, t_len):
+    """A turn's pages past a row's last live one are neither copied nor
+    seen: with the scrap page and every page no live position maps (the
+    rest of each row's table included) poisoned with NaN, and the junk past
+    each clock inside its last live page (which lands whole and is masked)
+    made huge, the output is bit for bit the clean one. The rows come
+    shallow after deep, so a slot still holds an earlier row's pages where
+    this row's turn has none."""
+    pos = [141, 0, 128, 15, 127, 16]
+    q, k4, v4, pos, table, P = _edge_case(n_kv, kv_mul, t_len, seed=3,
+                                          pos=pos)
+    clean = _run(q, k4, v4, pos, table, P, kv_mul, t_len, layer=0)
+    k4p, v4p = k4.copy(), v4.copy()
+    live = np.zeros(2 * P, bool)
+    for b, p in enumerate(pos):
+        last = min(int(p) + t_len - 1, MAX_PAGES * PS - 1)
+        live[table[b, :last // PS + 1]] = True
+        page = table[b, last // PS]
+        k4p[page, last % PS + 1:], v4p[page, last % PS + 1:] = 1e9, -1e9
+    k4p[~live], v4p[~live] = np.nan, np.nan     # never read
+    poisoned = _run(q, k4p, v4p, pos, table, P, kv_mul, t_len, layer=0)
+    assert np.isfinite(clean).all()
+    np.testing.assert_array_equal(clean, poisoned)
+
+
+@pytest.mark.parametrize("n_kv,kv_mul,t_len", [
+    *((n, m, 1) for n, m in HEADS), (8, 4, 4), (2, 4, 4)])
+def test_placement_invariance_is_bitwise_across_turns(n_kv, kv_mul, t_len):
+    """The paged invariant (below, at pages of 8 in one turn) where a row
+    takes two turns and ends inside one: the pool's pages permuted and the
+    table remapped, bit for bit the same output."""
+    q, k4, v4, pos, table, P = _edge_case(n_kv, kv_mul, t_len, seed=5,
+                                          pos=[15, 127, 128, 141])
+    base = _run(q, k4, v4, pos, table, P, kv_mul, t_len)
+    perm = np.concatenate([[0], 1 + np.random.default_rng(5).permutation(
+        P - 1)])
+    k5, v5 = (a.reshape(2, P, PS, n_kv, 128) for a in (k4, v4))
+    k5p, v5p = np.empty_like(k5), np.empty_like(v5)
+    k5p[:, perm], v5p[:, perm] = k5, v5
+    moved = _run(q, k5p.reshape(k4.shape), v5p.reshape(v4.shape), pos,
+                 perm[table].astype(np.int32), P, kv_mul, t_len)
+    np.testing.assert_array_equal(base, moved)
+
+
+def _attention64(q, k, v, pos, kv_mul):
+    """float64 grouped attention of one-query rows: q (B, n_q * hs), k / v
+    (B, S, n_kv, hs), positions 0 .. pos[b]; operands taken as given."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    B, _, n_kv, hs = k.shape
+    q = q.reshape(B, n_kv, kv_mul, hs)
+    out = np.zeros(q.shape)
+    for b in range(B):
+        n = int(pos[b]) + 1
+        s = np.einsum("hmd,shd->hms", q[b], k[b, :n]) / np.sqrt(hs)
+        w = np.exp(s - s.max(axis=-1, keepdims=True))
+        out[b] = np.einsum("hms,shd->hmd", w / w.sum(axis=-1, keepdims=True),
+                           v[b, :n])
+    return out.reshape(B, -1)
+
+
+def _float64_distance(n_kv, kv_mul, scale_k):
+    """(max, root mean square) of |kernel - float64 attention| over eight
+    one-query rows around the page and turn edges, K scaled by ``scale_k``,
+    and the max of the same attention with every product's operands rounded
+    to bfloat16 (the control)."""
+    import jax
+    import jax.numpy as jnp
+
+    pos = [141, 158, 127, 128, 100, 64, 159, 31]
+    q, k4, v4, pos, table, P = _edge_case(n_kv, kv_mul, 1, seed=46, pos=pos)
+    k4 = k4 * np.float32(scale_k)
+    got = _run(q, k4, v4, pos, table, P, kv_mul, 1).astype(np.float64)[:, 0]
+    gathered = lambda a: a[P + table].reshape(  # noqa: E731
+        len(pos), -1, n_kv, 128)
+    k_c, v_c = gathered(k4), gathered(v4)
+    want = _attention64(q[:, 0], k_c, v_c, pos, kv_mul)
+    bf = lambda a: np.asarray(jax.lax.reduce_precision(  # noqa: E731
+        jnp.asarray(a), exponent_bits=8, mantissa_bits=7))
+    control = _attention64(bf(q[:, 0]), bf(k_c), bf(v_c), pos, kv_mul)
+    err = np.abs(got - want)
+    return (float(err.max()), float(np.sqrt((err ** 2).mean())),
+            float(np.abs(control - want).max()))
+
+
+# (max, rms) of ``_float64_distance`` in interpret mode on the CPU: of the
+# vector-unit fold this kernel had until PR 47 (a page a turn, ``jnp.sum(k *
+# q)`` a query head: commit f17418d run on THIS file's inputs), and of the
+# fold as PR 47 left it. Interpret mode multiplies in float32 whatever the
+# pieces, so what differs HERE is the order of the float32 sums alone, 128
+# positions a turn for 16: one to three units in the last place of an output
+# either way. On the chip, where the piece products are exact and the MXU
+# accumulates, the fold read UNDER the vector-unit fold on every shape
+# timed (4.43e-7 for 6.16e-7 at OLMoE's, 7.08e-7 for 9.75e-7 at Mistral's:
+# PERF.md section 7)
+PARENT_DISTANCE = {(8, 4, 1.0): (1.971e-07, 2.905e-08),
+                   (8, 4, 30.0): (1.172e-05, 6.379e-07),
+                   (16, 1, 1.0): (2.415e-07, 2.982e-08),
+                   (16, 1, 30.0): (9.020e-06, 6.007e-07)}
+FOLD_DISTANCE = {(8, 4, 1.0): (3.840e-07, 3.083e-08),
+                 (8, 4, 30.0): (1.226e-05, 7.642e-07),
+                 (16, 1, 1.0): (3.535e-07, 3.099e-08),
+                 (16, 1, 30.0): (1.614e-05, 7.902e-07)}
+
+
+@pytest.mark.parametrize("scale_k", [1.0, 30.0])
+@pytest.mark.parametrize("n_kv,kv_mul", [(8, 4), (16, 1)])
+def test_the_fold_keeps_float32(n_kv, kv_mul, scale_k):
+    """The fold at Mistral's and OLMoE's head counts against a float64
+    attention, on standard-normal K and on K of thirty times the norm
+    (scores to +-1,000: one winner a row, where a bf16 product moves the
+    winner), beside the vector-unit fold's reading on the SAME inputs
+    (``PARENT_DISTANCE`` against ``FOLD_DISTANCE``, both stated above): the
+    root mean square within a quarter of it (1.03 to 1.06 times at
+    standard-normal K, 1.2 to 1.3 at thirty times the norm), the max of the
+    eight rows within twice (it goes either way with the rows drawn). The
+    same attention with every product's operands rounded to bfloat16 is at
+    least 100 times further: a fold that is quietly three passes, or one,
+    fails here."""
+    worst, rms, control = _float64_distance(n_kv, kv_mul, scale_k)
+    was_worst, was_rms = PARENT_DISTANCE[n_kv, kv_mul, scale_k]
+    assert worst <= 2 * was_worst and rms <= 1.35 * was_rms, (worst, rms)
+    assert (worst, rms) == pytest.approx(
+        FOLD_DISTANCE[n_kv, kv_mul, scale_k], rel=0.25)
+    assert control >= 100 * worst, (control, worst)
 
 
 @pytest.mark.parametrize("kv_mul,pos", [(1, [0, 5, 31]), (2, [7, 30, 16]),

@@ -481,6 +481,7 @@ def test_serve_more_requests_than_slots(tree, tokens, want):
     assert st.prompt_positions == sum(len(p) for p in prompts)
     assert st.xdec_positions == 4 + 2 and st.admit_prefills == 4
     assert st.shared_kv_positions > 0 and st.shared_kv_pages >= 0
+    assert st.paged_kv_positions == 0     # a plain KV pool's counter
 
 
 def test_a_stale_row_decodes_as_an_empty_one(tree):
