@@ -175,7 +175,8 @@ def state_slot_bytes(spec: TransformerSpec) -> int:
         # a mixer-kinds spec's slot: each sliding layer's ring of K and V,
         # float32 (models/laguna.py); its full layers' K / V are pages
         mx = spec.mixers
-        return 4 * mx.count("sliding") * mx.window * 2 * spec.kv_dim
+        return (4 * mx.count("sliding") * mx.window
+                * spec.kv_cached("sliding"))
     if spec.hybrid:
         # a hybrid spec's slot: each Mamba layer's conv inputs and state,
         # each window layer's ring of K and V, float32 (models/sambay.py);
@@ -254,8 +255,10 @@ def kv_position_bytes(spec: TransformerSpec, n_slices: int,
         if kv_quant != "f32":
             raise ValueError("a hybrid or mixer-kinds spec's pages are "
                              "float32 (runtime/continuous.cache_refusals)")
-        full = spec.mixers.count("full") if spec.mixers else 1
-        return 2 * full * spec.kv_dim * cache_itemsize
+        if spec.mixers:     # the kind's KV heads, K's and V's head sizes
+            return (spec.mixers.count("full") * spec.kv_cached("full")
+                    * cache_itemsize)
+        return 2 * spec.kv_dim * cache_itemsize
     kv_dim = (spec.n_kv_heads // n_slices) * spec.head_size
     if kv_quant == "q8":
         per = kv_dim + 2 * (kv_dim // QK)   # int8 codes + f16 deltas

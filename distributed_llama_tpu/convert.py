@@ -69,6 +69,14 @@ Extensions beyond the reference:
   no checkpoint was seen, and one that names them otherwise is refused by a
   ``KeyError``. ``q_proj`` / ``k_proj`` rows go from rotate-half order to
   interleaved pairs over a kind's ROTATED dimensions only.
+* ``model_type: mimo_v2_flash`` (MiMo-V2-Flash: window layers with a
+  learned softmax sink beside full layers, a KV head count a layer kind, K
+  heads of 192 beside V heads of 128, the attention output scaled):
+  ``mimo_spec`` reads the config (``hybrid_layer_pattern``, the ``swa_*``
+  keys, ``v_head_dim``, ``attention_value_scale``, the two sink flags,
+  ``moe_layer_freq`` and the router's keys; header extension 8). The tensor
+  names are ``LAGUNA_TENSORS`` with ``MIMO_TENSORS``' two, a GUESS as
+  theirs; the multi-token-prediction layers are not read.
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -201,6 +209,10 @@ LAGUNA_TENSORS = dict(
     sh_w1=_L + "mlp.shared_expert.gate_proj.weight",
     sh_w2=_L + "mlp.shared_expert.down_proj.weight",
     sh_w3=_L + "mlp.shared_expert.up_proj.weight")
+MIMO_TENSORS = {"sink": _L + "self_attn.attention_sink_bias",
+                "moe_bias": LATENT_TENSORS["moe_bias"]}
+"""What a ``mimo_v2_flash`` checkpoint has beside ``LAGUNA_TENSORS`` (a
+guess, as those)."""
 LAGUNA_TENSORS_NOTE = (
     "the names of a laguna checkpoint's tensors (self_attn.g_proj for the "
     "per-head gate, mlp.shared_expert.*, mlp.experts.{e}.*) are a guess: "
@@ -326,6 +338,10 @@ class HFCheckpoint:
         if getattr(c, "model_type", "") == "laguna":
             print(f"🔶 laguna tensors: {LAGUNA_TENSORS_NOTE}")
             return laguna_spec(c, target, seq_len)
+        if getattr(c, "model_type", "") == "mimo_v2_flash":
+            print(f"🔶 mimo_v2_flash tensors, likewise: "
+                  f"{LAGUNA_TENSORS_NOTE}")
+            return mimo_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "olmoe":
             if getattr(c, "norm_topk_prob", False):
                 raise ValueError("olmoe with norm_topk_prob: the program "
@@ -362,7 +378,7 @@ class HFCheckpoint:
                 "module docstring says why); models/synth.py writes a "
                 "seeded file of this spec")
         if spec.mixers:
-            key = LAGUNA_TENSORS.get(name) or {
+            key = LAGUNA_TENSORS.get(name) or MIMO_TENSORS.get(name) or {
                 "tok_embedding": "model.embed_tokens.weight",
                 "rms_final": "model.norm.weight",
                 "wcls": "lm_head.weight"}[name]
@@ -515,6 +531,59 @@ def laguna_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
         mixers=MixerKinds(kinds, int(c.sliding_window), int(c.head_dim),
                           full, kind_of("sliding", "sliding_attention"),
                           bool(getattr(c, "gating", False))))
+
+
+def mimo_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
+    """The spec of a ``mimo_v2_flash`` config: the kinds from
+    ``hybrid_layer_pattern`` (0 full, 1 sliding), each kind's KV heads, RoPE
+    base and sink flag, K's and V's head sizes, the value scale, and the
+    expert layout (leading dense layers from ``moe_layer_freq``; no shared
+    expert). The rotated dimensions are ``int(head_dim x
+    partial_rotary_factor)`` as published (64 of 192), the same in both
+    kinds."""
+    from .models.spec import ExpertLayout, MixerKind, MixerKinds, Router
+
+    kinds = tuple("sliding" if k else "full" for k in c.hybrid_layer_pattern)
+    freq = list(c.moe_layer_freq)
+    dense = freq.index(1) if 1 in freq else len(freq)
+    if any(f != 1 for f in freq[dense:]) or len(freq) != len(kinds):
+        raise ValueError("mimo_v2_flash: dense layers lead, expert layers "
+                         "follow, one entry a layer")
+    if (c.swa_num_attention_heads != c.num_attention_heads
+            or c.swa_head_dim != c.head_dim
+            or c.swa_v_head_dim != c.v_head_dim
+            or c.scoring_func != "sigmoid" or c.topk_method != "noaux_tc"
+            or getattr(c, "n_shared_experts", None)
+            or getattr(c, "attention_bias", False)):
+        raise ValueError("mimo_v2_flash: one head count and one head size "
+                         "for both kinds, sigmoid scores with a choice bias, "
+                         "no shared expert and no attention bias are what "
+                         "the spec holds")
+    rot = int(c.head_dim * float(c.partial_rotary_factor))
+    rot -= rot % 2
+    rot = 0 if rot == c.head_dim else rot
+    heads = c.num_attention_heads
+    return TransformerSpec(
+        dim=c.hidden_size, hidden_dim=c.moe_intermediate_size,
+        n_layers=c.num_hidden_layers, n_heads=heads,
+        n_kv_heads=c.num_key_value_heads, vocab_size=c.vocab_size,
+        seq_len=seq_len, weights_float_type=target,
+        n_experts=c.n_routed_experts,
+        n_active_experts=c.num_experts_per_tok,
+        norm_eps=float(c.layernorm_epsilon),
+        layout=ExpertLayout(dense, c.intermediate_size if dense else 0, 0),
+        router=Router("sigmoid", int(c.n_group), int(c.topk_group),
+                      bool(c.norm_topk_prob),
+                      float(c.routed_scaling_factor or 1.0), True),
+        mixers=MixerKinds(
+            kinds, int(c.sliding_window), int(c.head_dim),
+            MixerKind(heads, float(c.rope_theta), rot, None, 0,
+                      bool(c.add_full_attention_sink_bias)),
+            MixerKind(heads, float(c.swa_rope_theta), rot, None,
+                      int(c.swa_num_key_value_heads),
+                      bool(c.add_swa_attention_sink_bias)),
+            False, 0 if c.v_head_dim == c.head_dim else int(c.v_head_dim),
+            float(c.attention_value_scale)))
 
 
 def hybrid_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:

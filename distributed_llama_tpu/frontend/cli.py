@@ -85,7 +85,15 @@ def _hybrid_line(spec, slots: int) -> str:
 def _mixers_line(spec, slots: int) -> str:
     """What a mixer-kinds spec keeps a sequence: a startup line."""
     mx, lay = spec.mixers, spec.layout
-    ring = mx.count("sliding") * mx.window * 2 * spec.kv_dim * 4
+    ring = mx.count("sliding") * mx.window * spec.kv_cached("sliding") * 4
+    (n_f, hk, hv), n_s = spec.kv_shape("full"), spec.kv_shape("sliding")[0]
+    kv = (f"{n_f} KV heads" if n_f == n_s
+          else f"{n_f} and {n_s} KV heads") + (
+        f" (K {hk}, V {hv})" if hk != hv else "")
+    sink = "".join(f", a softmax sink a {k} head" for k in ("full", "sliding")
+                   if mx.of(k).sink)
+    scale = (f", output scaled by {mx.value_scale:g}"
+             if mx.value_scale != 1.0 else "")
     ffn = (f"{lay.dense_layers} dense + {spec.n_expert_layers} expert "
            f"FFNs ({spec.n_experts_held} experts held, "
            f"{spec.n_active_experts} a token, {lay.shared} shared)"
@@ -96,11 +104,12 @@ def _mixers_line(spec, slots: int) -> str:
             f"{', YaRN' if mx.full.rope_scaling else ''}), "
             f"{mx.count('sliding')} sliding ({mx.sliding.heads} heads, "
             f"window {mx.window}, theta {mx.sliding.rope_theta:g}) over "
-            f"{spec.n_kv_heads} KV heads"
+            f"{kv}{sink}{scale}"
             f"{', per-head output gate' if mx.gate else ''}; {ffn}; a "
             f"sequence keeps {ring / 2**20:.1f} MiB of window ring ({slots} "
             f"slot{'s' if slots != 1 else ''}, fixed) and its full layers' "
-            f"K / V: {2 * mx.count('full') * spec.kv_dim * 4} B a position")
+            f"K / V: {mx.count('full') * spec.kv_cached('full') * 4} B a "
+            f"position")
 
 
 def _latent_line(spec) -> str:

@@ -1,8 +1,11 @@
 """The forward pass of a mixer-kinds spec (``TransformerSpec.mixers``:
-Laguna-XS.2's layout): grouped-query softmax attention whose KIND is a
-layer's ("full": causal over every position, K / V of its own; "sliding":
-the last ``window`` positions), each kind with a head count and a RoPE of
-its own and a per-head sigmoid gate on its output, around the FFN that
+Laguna-XS.2's layout and MiMo-V2-Flash's): grouped-query softmax attention
+whose KIND is a layer's ("full": causal over every position, K / V of its
+own; "sliding": the last ``window`` positions), each kind with a head count,
+a KV head count and a RoPE of its own, V heads that may be narrower than K
+heads, a learned softmax sink a query head where the kind has one
+(``lw["sink"]``: one more column, no value), the output scaled by
+``value_scale``, and a per-head sigmoid gate on it, around the FFN that
 ``spec.layout`` says (a leading dense SwiGLU, then routed experts with a
 shared one: ``models/llama._post_attention`` and ``ops/pallas_moe.moe_ffn``,
 as every expert spec). ``models/reference_laguna.py`` states every layer in
@@ -18,8 +21,11 @@ full; this module runs the same function through the caches:
 
 A sequence's cache (``init_cache(spec)``) is wk / wv (W, KV heads, window,
 head), k / v (F, KV heads, seq_len, head), W and F the counts of sliding and
-full layers; ``batch`` rows add an axis after the first; the pool is k / v
-(F, pages, KV heads, page_size, head) beside ``slots`` rows of rings. K and V
+full layers, KV heads the KIND's and head K's or V's size
+(``spec.kv_shape``); ``batch`` rows add an axis after the first; the pool is
+k / v (F, pages, KV heads, page_size, head) beside ``slots`` rows of rings. A
+head wider than one 128-lane tile is held in whole tiles, zeros past its
+values (``spec.cache_lanes``: K of 192 in 256). K and V
 are held HEAD-MAJOR and read by the kernels of
 ``ops/pallas_head_major_attention.py`` that a hybrid spec's attention takes
 (``models/sambay._attend_rows`` / ``_attend_pages``: the flash-decode
@@ -47,13 +53,13 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..obs.spans import (SCOPE_ATTN, SCOPE_ATTN_GATE, SCOPE_EMBED,
-                         SCOPE_LOGITS, scope_rope)
+from ..obs.spans import (SCOPE_ATTN, SCOPE_ATTN_GATE, SCOPE_ATTN_SCALE,
+                         SCOPE_EMBED, SCOPE_LOGITS, scope_rope)
 from ..ops.linear import matmul, rmsnorm
 from .kindscan import insert_sequence, merge_lead, run_layers  # noqa: F401
 from .latent import _rope, chunk_attn_block, rope_table
 from .sambay import _attend_pages, _attend_rows, _write_rows, ring_plan
-from .spec import TransformerSpec
+from .spec import TransformerSpec, cache_lanes
 
 HIGHEST = jax.lax.Precision.HIGHEST
 TOP_LEVEL = ("tok_embedding", "rms_final", "wcls")
@@ -66,22 +72,25 @@ class MixCache(NamedTuple):
     v: jax.Array      # (F, pages, KV heads, page_size, head)
 
 
-def _zeros(spec: TransformerSpec, lead: tuple, kv: tuple, dtype):
+def _zeros(spec: TransformerSpec, lead: tuple, kv_lead: tuple, kv: int,
+           dtype):
+    """Rings (W, *lead, KV heads, window, head) and K / V (F, *kv_lead, KV
+    heads, ``kv`` positions, head), each kind's KV heads, K's and V's head."""
     mx = spec.mixers
-    ring = (mx.count("sliding"), *lead, spec.n_kv_heads, mx.window,
-            mx.head_size)
-    z = jnp.zeros
-    return MixCache(z(ring, dtype), z(ring, dtype), z(kv, dtype),
-                    z(kv, dtype))
+    n_s, k_s, v_s = spec.kv_shape("sliding")
+    n_f, k_f, v_f = spec.kv_shape("full")
+    ring = (mx.count("sliding"), *lead, n_s, mx.window)
+    full = (mx.count("full"), *kv_lead, n_f, kv)
+    return MixCache(*(jnp.zeros((*shape, cache_lanes(head)), dtype)
+                      for shape, head in ((ring, k_s), (ring, v_s),
+                                          (full, k_f), (full, v_f))))
 
 
 def init_cache(spec: TransformerSpec, batch: int | None = None,
                dtype=jnp.float32) -> MixCache:
     """One sequence's cache, or ``batch`` rows' (contiguous K / V)."""
     lead = () if batch is None else (batch,)
-    mx = spec.mixers
-    return _zeros(spec, lead, (mx.count("full"), *lead, spec.n_kv_heads,
-                               spec.seq_len, mx.head_size), dtype)
+    return _zeros(spec, lead, lead, spec.seq_len, dtype)
 
 
 def init_cache_paged(spec: TransformerSpec, slots: int, n_pages: int,
@@ -91,10 +100,7 @@ def init_cache_paged(spec: TransformerSpec, slots: int, n_pages: int,
     if spec.seq_len % page_size:
         raise ValueError(f"page_size={page_size} must divide "
                          f"seq_len={spec.seq_len}")
-    mx = spec.mixers
-    return _zeros(spec, (slots,), (mx.count("full"), n_pages,
-                                   spec.n_kv_heads, page_size, mx.head_size),
-                  dtype)
+    return _zeros(spec, (slots,), (n_pages,), page_size, dtype)
 
 
 def state_bytes(cache: MixCache) -> tuple[int, int]:
@@ -156,9 +162,11 @@ def _rotate(x, positions, table):
     return jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
 
-def _qkv(spec, lw, heads: int, h):
-    """h (R, dim) normed -> q (R, heads, head), k, v (R, KV heads, head)."""
-    hs, n_kv = spec.head_size, spec.n_kv_heads
+def _qkv(spec, lw, kind: str, h):
+    """h (R, dim) normed -> q (R, heads, head), k (R, KV heads, head), v
+    (R, KV heads, V's head) of a ``kind`` layer."""
+    heads = spec.mixers.of(kind).heads
+    n_kv, hs, hv = spec.kv_shape(kind)
     if "wqkv" in lw:    # load-time fusion (ops/linear)
         qkv = matmul(lw["wqkv"], h)
         q, k, v = jnp.split(qkv, [heads * hs, (heads + n_kv) * hs], axis=-1)
@@ -166,7 +174,29 @@ def _qkv(spec, lw, heads: int, h):
         q, k, v = (matmul(lw[n], h) for n in ("wq", "wk", "wv"))
     r = h.shape[0]
     return (q.reshape(r, heads, hs), k.reshape(r, n_kv, hs),
-            v.reshape(r, n_kv, hs))
+            v.reshape(r, n_kv, hv))
+
+
+def _held(x, dtype):
+    """x (..., head) as the cache holds a head: ``dtype``, and zeros up to
+    ``cache_lanes``."""
+    pad = cache_lanes(x.shape[-1]) - x.shape[-1]
+    x = x.astype(dtype)
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def _values(a, head: int):
+    """A held K or V (..., lanes) back at its ``head`` values."""
+    return a if a.shape[-1] == head else a[..., :head]
+
+
+def _scaled(spec, ao):
+    """The attention output times ``value_scale`` (on V or on the sum, the
+    same number)."""
+    if spec.mixers.value_scale == 1.0:
+        return ao
+    with jax.named_scope(SCOPE_ATTN_SCALE):
+        return ao * jnp.float32(spec.mixers.value_scale)
 
 
 def head_gate(lw, h):
@@ -249,7 +279,7 @@ def forward_batch(spec: TransformerSpec, params: dict[str, Any],
     pos_b = jnp.broadcast_to(jnp.asarray(pos_vec, jnp.int32), (B,))
     live = jnp.ones((B,), bool) if active is None else active != 0
     rows = jnp.arange(B)
-    W, n_kv, hs = mx.window, spec.n_kv_heads, spec.head_size
+    W = mx.window
     paged = table is not None
     n_pool = cache.k.shape[1]
     tables = rope_tables(spec)
@@ -258,20 +288,21 @@ def forward_batch(spec: TransformerSpec, params: dict[str, Any],
 
     def layer_fn(kind, lw, c: _Carry, idx, fidx):
         heads = mx.of(kind).heads
-        shape = (heads, n_kv, hs)
+        shape = (heads, *spec.kv_shape(kind)[:2])
+        sink = lw.get("sink")       # (heads,) where the kind has one
         h = rmsnorm(c.x, lw["rms_att"], spec.norm_eps)
         with jax.named_scope(SCOPE_ATTN):
-            q, k, v = _qkv(spec, lw, heads, h)
+            q, k, v = _qkv(spec, lw, kind, h)
             with jax.named_scope(scope_rope(kind)):
                 q = _rotate(q, pos_b, tables[kind]).reshape(B, -1)
                 k = _rotate(k, pos_b, tables[kind])
-            k, v = k[:, :, None].astype(dt), v[:, :, None].astype(dt)
+            k, v = _held(k[:, :, None], dt), _held(v[:, :, None], dt)
             if kind == "sliding":
                 wk = _write_rows(c.wk, k, idx * B + rows, pos_b % W)
                 wv = _write_rows(c.wv, v, idx * B + rows, pos_b % W)
                 c = c._replace(wk=wk, wv=wv)
                 ao = _attend_rows(shape, q, wk, wv, idx,
-                                  jnp.minimum(pos_b, W - 1))
+                                  jnp.minimum(pos_b, W - 1), sink)
             elif paged:
                 own = table + idx * n_pool      # this layer's pool
                 page = jnp.take_along_axis(
@@ -279,12 +310,14 @@ def forward_batch(spec: TransformerSpec, params: dict[str, Any],
                 c = c._replace(
                     k=_write_rows(c.k, k, page, pos_b % page_size),
                     v=_write_rows(c.v, v, page, pos_b % page_size))
-                ao = _attend_pages(shape, page_size, q, c.k, c.v, pos_b, own)
+                ao = _attend_pages(shape, page_size, q, c.k, c.v, pos_b, own,
+                                   sink)
             else:
                 c = c._replace(k=_write_rows(c.k, k, idx * B + rows, pos_b),
                                v=_write_rows(c.v, v, idx * B + rows, pos_b))
-                ao = _attend_rows(shape, q, c.k, c.v, idx, pos_b)
-            ao, lo, mean = _gated(spec, lw, h, ao, heads, live)
+                ao = _attend_rows(shape, q, c.k, c.v, idx, pos_b, sink)
+            ao, lo, mean = _gated(spec, lw, h, _scaled(spec, ao), heads,
+                                  live)
         x, counts = _tail(spec, lw, c.x, ao, c.counts, fidx)
         return c._replace(x=x, counts=counts, gmin=jnp.minimum(c.gmin, lo),
                           gsum=c.gsum + mean)
@@ -306,13 +339,16 @@ def forward_batch(spec: TransformerSpec, params: dict[str, Any],
 
 # -- a chunk of one sequence ---------------------------------------------------------
 
-def _attend_live(kv_mul: int, q, k_plane, v_plane, pos, block: int):
+def _attend_live(kv_mul: int, hv: int, q, k_plane, v_plane, pos, block: int,
+                 sink=None):
     """q (T, heads, head) at positions pos .. pos + T - 1 over the
     head-major planes (KV heads, S, head): a walk over the blocks 0 ..
     (pos + T - 1) // block that a query of the chunk can see, with a
     running (m, l, o) (``parallel.ring._lse_merge``), as a latent spec's
     chunk walks its plane (``models/latent.attend_live``). The products
-    are ``attention_core``'s (float32, HIGHEST)."""
+    are ``attention_core``'s (float32, HIGHEST); with ``sink`` (heads,) the
+    walk starts at m = sink, l = 1: the column is folded before any
+    block."""
     from ..parallel.ring import _lse_merge
 
     t_len, heads, hs = q.shape
@@ -323,10 +359,10 @@ def _attend_live(kv_mul: int, q, k_plane, v_plane, pos, block: int):
 
     def body(carry):
         b, m, l, o = carry
-        kb = jax.lax.dynamic_slice_in_dim(k_plane, b * block, block,
-                                          1).astype(jnp.float32)
-        vb = jax.lax.dynamic_slice_in_dim(v_plane, b * block, block,
-                                          1).astype(jnp.float32)
+        kb = _values(jax.lax.dynamic_slice_in_dim(
+            k_plane, b * block, block, 1), hs).astype(jnp.float32)
+        vb = _values(jax.lax.dynamic_slice_in_dim(
+            v_plane, b * block, block, 1), hv).astype(jnp.float32)
         s = jnp.einsum("tgmd,gsd->tgms", qg, kb, precision=HIGHEST,
                        preferred_element_type=jnp.float32)
         seen = (b * block + jnp.arange(block))[None, :] <= q_pos[:, None]
@@ -340,12 +376,17 @@ def _attend_live(kv_mul: int, q, k_plane, v_plane, pos, block: int):
                                    jnp.sum(p, axis=-1, keepdims=True), po))
 
     lead = (t_len, n_kv, kv_mul)
+    if sink is None:
+        m0 = jnp.full((*lead, 1), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((*lead, 1), jnp.float32)
+    else:
+        m0 = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            n_kv, kv_mul, 1), (*lead, 1))
+        l0 = jnp.ones((*lead, 1), jnp.float32)
     _, _, l, o = jax.lax.while_loop(
         lambda c: c[0] < n_live, body,
-        (jnp.int32(0), jnp.full((*lead, 1), -jnp.inf, jnp.float32),
-         jnp.zeros((*lead, 1), jnp.float32),
-         jnp.zeros((*lead, hs), jnp.float32)))
-    return (o / l).reshape(t_len, heads * hs)
+        (jnp.int32(0), m0, l0, jnp.zeros((*lead, hv), jnp.float32)))
+    return (o / l).reshape(t_len, heads * hv)
 
 
 def forward_chunk(spec: TransformerSpec, params: dict[str, Any],
@@ -375,7 +416,7 @@ def forward_chunk(spec: TransformerSpec, params: dict[str, Any],
     positions = pos + jnp.arange(T)
     valid = jnp.arange(T) < n_valid
     W, S = mx.window, spec.seq_len
-    n_kv, hs = spec.n_kv_heads, spec.head_size
+    hs = spec.head_size
     dt = cache.k.dtype
     tables = rope_tables(spec)
     block = chunk_attn_block(S, T)
@@ -389,20 +430,23 @@ def forward_chunk(spec: TransformerSpec, params: dict[str, Any],
 
     def layer_fn(kind, lw, c: _Carry, idx, fidx):
         heads = mx.of(kind).heads
+        n_kv, _, hv = spec.kv_shape(kind)
+        sink = lw.get("sink")
         h = rmsnorm(c.x, lw["rms_att"], spec.norm_eps)
         with jax.named_scope(SCOPE_ATTN):
-            q, k, v = _qkv(spec, lw, heads, h)
+            q, k, v = _qkv(spec, lw, kind, h)
             with jax.named_scope(scope_rope(kind)):
                 q = _rotate(q, positions, tables[kind])
-                k = _rotate(k, positions, tables[kind]).astype(dt)
-            v = v.astype(dt)
+                k = _held(_rotate(k, positions, tables[kind]), dt)
+            v = _held(v, dt)
             if kind == "sliding":
                 wk = jax.lax.dynamic_index_in_dim(c.wk, idx, 0, False)
                 wv = jax.lax.dynamic_index_in_dim(c.wv, idx, 0, False)
                 ao = attention_core(
                     hs, heads // n_kv, q,
-                    jnp.concatenate([heads_first(wk), k]),
-                    jnp.concatenate([heads_first(wv), v]), win_mask)
+                    _values(jnp.concatenate([heads_first(wk), k]), hs),
+                    _values(jnp.concatenate([heads_first(wv), v]), hv),
+                    win_mask, sink)
                 c = c._replace(
                     wk=jax.lax.dynamic_update_slice_in_dim(
                         c.wk, jnp.where(from_chunk, heads_first(k[take]),
@@ -418,11 +462,14 @@ def forward_chunk(spec: TransformerSpec, params: dict[str, Any],
                 v_p = jax.lax.dynamic_index_in_dim(c.v, idx, 0, False)
                 if block is None:
                     ao = attention_core(hs, heads // n_kv, q,
-                                        heads_first(k_p), heads_first(v_p),
-                                        causal_cache_mask(S, pos, T))
+                                        _values(heads_first(k_p), hs),
+                                        _values(heads_first(v_p), hv),
+                                        causal_cache_mask(S, pos, T), sink)
                 else:
-                    ao = _attend_live(heads // n_kv, q, k_p, v_p, pos, block)
-            ao, lo, mean = _gated(spec, lw, h, ao, heads, valid)
+                    ao = _attend_live(heads // n_kv, hv, q, k_p, v_p, pos,
+                                      block, sink)
+            ao, lo, mean = _gated(spec, lw, h, _scaled(spec, ao), heads,
+                                  valid)
         x, counts = _tail(spec, lw, c.x, ao, c.counts, fidx)
         return c._replace(x=x, counts=counts, gmin=jnp.minimum(c.gmin, lo),
                           gsum=c.gsum + mean)

@@ -35,7 +35,8 @@ from ..io.loader import Q40Kernel, Q40KernelNb, Q40KernelNbI4
 # the single-chip forward emits the SAME canonical trace scopes as the tp
 # forward (parallel/tp.py), so a --profile capture of either program
 # attributes through one obs/xprof.py vocabulary
-from ..obs.spans import SCOPE_ATTN, SCOPE_EMBED, SCOPE_FFN, SCOPE_LOGITS
+from ..obs.spans import (SCOPE_ATTN, SCOPE_ATTN_SINK, SCOPE_EMBED, SCOPE_FFN,
+                         SCOPE_LOGITS)
 from ..ops.hyper import residual_in, residual_out
 from ..ops.linear import StackedQ40, fake_quant_q80, matmul, rmsnorm, silu
 from ..ops.quants import FloatType
@@ -125,12 +126,16 @@ def _maybe_q80(spec: TransformerSpec, x: jax.Array) -> jax.Array:
 
 
 def attention_core(head_size: int, kv_mul: int, q: jax.Array, k: jax.Array,
-                   v: jax.Array, mask: jax.Array) -> jax.Array:
+                   v: jax.Array, mask: jax.Array,
+                   sink: jax.Array | None = None) -> jax.Array:
     """Grouped-GQA causal attention — THE attention math, shared by the
     single-chip, sequence (training), and tensor-parallel paths.
 
-    q: (..., T, n_q, hs) reshaped to kv groups; k/v: (..., S, n_kv, hs);
-    mask: (T, S) True where key position is visible. Query head h = g*kv_mul+m
+    q: (..., T, n_q, hs) reshaped to kv groups; k/v: (..., S, n_kv, hs) (v's
+    last dim may be another: the output's is v's); mask: (T, S) True where
+    key position is visible; ``sink`` (n_q,) or None: a score a query head
+    that joins its softmax as one more column and carries no value
+    (a mixer-kinds spec's: models/laguna.py). Query head h = g*kv_mul+m
     attends kv head g = h//kv_mul (transformer-tasks.cpp:214), via einsum
     against the unexpanded cache (no materialized kv_mul-fold repeat).
     Masking with -inf before the max-subtracted softmax reproduces the
@@ -152,11 +157,19 @@ def attention_core(head_size: int, kv_mul: int, q: jax.Array, k: jax.Array,
                         preferred_element_type=jnp.float32,
                         precision=prec) * scale
     scores = jnp.where(mask[..., None, None, :, :], scores, -jnp.inf)
-    att = jax.nn.softmax(scores, axis=-1)
+    if sink is None:
+        att = jax.nn.softmax(scores, axis=-1)
+    else:
+        with jax.named_scope(SCOPE_ATTN_SINK):
+            col = jnp.broadcast_to(
+                sink.astype(jnp.float32).reshape(n_kv, kv_mul, 1, 1),
+                (*scores.shape[:-1], 1))
+            att = jax.nn.softmax(jnp.concatenate([scores, col], axis=-1),
+                                 axis=-1)[..., :-1]
     out = jnp.einsum("...gmts,...sgd->...tgmd", att, v,
                      preferred_element_type=jnp.float32,
                      precision=prec)
-    return out.reshape(*lead, t_len, n_q * head_size)
+    return out.reshape(*lead, t_len, n_q * v.shape[-1])
 
 
 def causal_cache_mask(seq_len: int, pos: jax.Array, t_len: int) -> jax.Array:

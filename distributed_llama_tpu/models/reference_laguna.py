@@ -1,5 +1,6 @@
 """The plain reference of a mixer-kinds model (``TransformerSpec.mixers``:
-Laguna-XS.2's layout, https://huggingface.co/poolside/Laguna-XS.2): the whole
+Laguna-XS.2's layout, https://huggingface.co/poolside/Laguna-XS.2, and
+MiMo-V2-Flash's, https://huggingface.co/XiaomiMiMo/MiMo-V2-Flash): the whole
 forward at every position in straightforward ``jax.numpy``, float32,
 ``highest`` matmul precision, with no kernels, no cache, nothing carried
 between calls and no batching, a layer at a time. It takes the loader's codec
@@ -11,10 +12,13 @@ dequantizes by the codec's own definition. The tests compare the program
 (``models/laguna.py``: decode step, chunked prefill, ``serve``) with it on
 logits.
 
-Layer l has kind k (``full`` or ``sliding``) with H_k query heads, ``n_kv``
-KV heads and one head size d, no biases. x (T, dim) at positions 0..T-1:
+Layer l has kind k (``full`` or ``sliding``) with H_k query heads, G_k KV
+heads (the spec's ``n_kv_heads`` unless the kind states its own), a K head
+size d and a V head size d_v (d unless stated), no biases. x (T, dim) at
+positions 0..T-1:
 
-  h = RMSNorm(x);  q = h Wq_k (H_k x d),  key = h Wk,  v = h Wv (n_kv x d)
+  h = RMSNorm(x);  q = h Wq_k (H_k x d),  key = h Wk (G_k x d),
+    v = h Wv (G_k x d_v)
   RoPE on q and key BY KIND: the first ``rotary_dim`` dimensions of a head in
     interleaved pairs (2p, 2p + 1) (the converter's permutation of the
     checkpoint's half-split pairs) at frequency theta_k^(-2p / rotary_dim),
@@ -25,12 +29,20 @@ KV heads and one head size d, no biases. x (T, dim) at positions 0..T-1:
     other dimensions pass unrotated
   scores = q . key / sqrt d, causal; a ``sliding`` layer reads the last
     ``window`` positions, the current one among them; softmax; head j reads
-    KV head j // (H_k / n_kv)
+    KV head j // (H_k / G_k). A kind with a SINK has a learned float32 score
+    s_j a query head, which joins head j's softmax as one more column and
+    is then dropped (it has no value): a row's weights sum to less than 1
+  the head's output times ``value_scale`` (1 unless stated)
   g = sigmoid(h Wg_k), one value a query head (float32); head j's output is
-    multiplied by g_j; then y = x + concat(o) Wo_k
+    multiplied by g_j (where the spec has the gate); then
+    y = x + concat(o) Wo_k
   h2 = RMSNorm(y).  A leading dense layer: y + SwiGLU_dense(h2).  The others:
-    s = sigmoid (or softmax) (h2 Wr), the k largest, w = scale s_sel
-    [/ sum(s_sel)], y + sum_e w_e SwiGLU^e(h2) + SwiGLU^shared(h2)
+    s = sigmoid (or softmax) (h2 Wr), the k largest (of s + b where the
+    router has a choice bias b), w = scale s_sel [/ sum(s_sel)],
+    y + sum_e w_e SwiGLU^e(h2) [+ SwiGLU^shared(h2)]; where the file holds
+    a SHARE of the experts (``layout.held`` from ``layout.offset``), the
+    sum runs over the chosen experts held here, at the weights the whole
+    router gave them: one chip's partial sum of an expert-parallel group
 
 then the final RMSNorm and the untied classifier.
 
@@ -40,7 +52,12 @@ What is assumed beyond the published ``config.json`` (each also under
 sibling Laguna-S-2.1 says ``per_head``); sigmoid router scores with no choice
 bias and renormalised top-k weights (the sibling's ``norm_topk_prob``); no
 q / k norm; that the window counts the current position; weights are the
-file's Q40 values dequantized, not bfloat16.
+file's Q40 values dequantized, not bfloat16. For MiMo-V2-Flash (each also
+under ``assumed`` in ``benchmark/configs/mimo-v2-flash-q40-ep8.json``):
+rotary pairs interleaved under the converter's permutation; the window
+counts the current position; the multi-token-prediction layers are left
+out; the checkpoint's tensor names are a guess (``convert.py`` converts no
+tensor of it).
 """
 
 from __future__ import annotations
@@ -110,13 +127,18 @@ def _rope(x, freq, factor):
     return jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
 
-def attention(spec, lw, kind: str, x, gate: bool = True, rope: bool = True):
+def attention(spec, lw, kind: str, x, gate: bool = True, rope: bool = True,
+              drop=()):
     """x + the attention sub-block of a ``kind`` layer. ``gate`` False
     leaves the per-head gate out and ``rope`` False the kind's RoPE (plain
-    RoPE over the whole head at theta 10,000 instead): what the tests show
-    to matter."""
-    mx, n_kv, eps = spec.mixers, spec.n_kv_heads, spec.norm_eps
-    mk, d = mx.of(kind), mx.head_size
+    RoPE over the whole head at theta 10,000 instead); ``drop`` names what
+    else to leave out: "sink" (the softmax's extra column), "value_scale",
+    "kv_heads" (the layer reads the FULL kind's count of its KV heads, the
+    first ones: the grouping a spec with one KV head count would apply):
+    what the tests show to matter."""
+    mx, eps = spec.mixers, spec.norm_eps
+    mk = mx.of(kind)
+    n_kv, d, d_v = spec.kv_shape(kind)
     t = x.shape[0]
     if rope:
         freq, factor = rope_table(mk, d)
@@ -125,15 +147,27 @@ def attention(spec, lw, kind: str, x, gate: bool = True, rope: bool = True):
     h = _rmsnorm(x, lw["rms_att"], eps)
     q = _rope((h @ _dense(lw["wq"]).T).reshape(t, mk.heads, d), freq, factor)
     k = _rope((h @ _dense(lw["wk"]).T).reshape(t, n_kv, d), freq, factor)
-    v = (h @ _dense(lw["wv"]).T).reshape(t, n_kv, d)
+    v = (h @ _dense(lw["wv"]).T).reshape(t, n_kv, d_v)
+    if "kv_heads" in drop:
+        n_kv = spec.kv_shape("full")[0]
+        k, v = k[:, :n_kv], v[:, :n_kv]
     qg = q.reshape(t, n_kv, mk.heads // n_kv, d)
     scores = jnp.einsum("tgmd,sgd->gmts", qg, k) / math.sqrt(d)
     back = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
     see = back >= 0
     if kind == "sliding":
         see = see & (back < mx.window)
-    att = jax.nn.softmax(jnp.where(see, scores, -jnp.inf), axis=-1)
-    o = jnp.einsum("gmts,sgd->tgmd", att, v).reshape(t, mk.heads, d)
+    scores = jnp.where(see, scores, -jnp.inf)
+    if mk.sink and "sink" not in drop:
+        # the sink as the column it is: one more key, with no value
+        col = jnp.broadcast_to(jnp.asarray(lw["sink"], jnp.float32).reshape(
+            n_kv, -1, 1, 1), (*scores.shape[:-1], 1))
+        att = jax.nn.softmax(jnp.concatenate([scores, col], -1), -1)[..., :-1]
+    else:
+        att = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum("gmts,sgd->tgmd", att, v).reshape(t, mk.heads, d_v)
+    if "value_scale" not in drop:
+        o = o * jnp.float32(mx.value_scale)
     if mx.gate and gate:
         g = jax.nn.sigmoid(h @ jnp.asarray(lw["w_hgate"], jnp.float32).T)
         o = o * g[..., None]
@@ -164,17 +198,21 @@ def route(spec, gate, bias, h):
 
 
 def experts(spec, lw, x):
-    """(x + the expert sub-block, margin (T,), chosen ids (T, k))."""
+    """(x + the expert sub-block, margin (T,), chosen ids (T, k)). The
+    stacks hold experts ``layout.offset .. + n_experts_held - 1``: a chosen
+    expert that is not among them adds nothing here."""
     h = _rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
     w, ids, margin = route(spec, lw["moe_gate"], lw.get("moe_bias"), h)
     w1, w2, w3 = (_dense(lw[n]) for n in ("moe_w1", "moe_w2", "moe_w3"))
     y = jnp.zeros_like(x)
+    off, held = spec.layout.offset, spec.n_experts_held
     for j in range(spec.n_active_experts):   # a row's j-th expert, in turn
-        e = ids[:, j]
+        here = (ids[:, j] >= off) & (ids[:, j] < off + held)
+        e = jnp.where(here, ids[:, j] - off, 0)
         g = jnp.einsum("thd,td->th", w1[e], h)
         u = jnp.einsum("thd,td->th", w3[e], h)
-        y = y + w[:, j, None] * jnp.einsum("tdh,th->td", w2[e],
-                                           jax.nn.silu(g) * u)
+        y = y + jnp.where(here, w[:, j], 0.0)[:, None] * jnp.einsum(
+            "tdh,th->td", w2[e], jax.nn.silu(g) * u)
     if spec.layout.shared:
         y = y + _swiglu(h, lw["sh_w1"], lw["sh_w2"], lw["sh_w3"])
     return x + y, margin, ids
@@ -186,9 +224,11 @@ def _layer_of(stack: dict, i: int) -> dict:
             and k not in ("tok_embedding", "rms_final", "wcls")}
 
 
-def forward(tree: dict, spec, tokens, gate: bool = True, rope: bool = True):
+def forward(tree: dict, spec, tokens, gate: bool = True, rope: bool = True,
+            drop=()):
     """Logits (T, vocab), router margins (T, expert layers) and chosen
-    expert ids (T, expert layers, k) of one sequence ``tokens`` (T,)."""
+    expert ids (T, expert layers, k) of one sequence ``tokens`` (T,);
+    ``gate``, ``rope`` and ``drop`` as ``attention`` takes them."""
     tokens = np.asarray(tokens)
     seen = {"full": 0, "sliding": 0}
     margins, routed = [], []
@@ -196,7 +236,7 @@ def forward(tree: dict, spec, tokens, gate: bool = True, rope: bool = True):
         x = jnp.asarray(tree["tok_embedding"], jnp.float32)[tokens]
         for i, kind in enumerate(spec.mixers.kinds):
             x = attention(spec, _layer_of(tree[kind], seen[kind]), kind, x,
-                          gate, rope)
+                          gate, rope, drop)
             seen[kind] += 1
             if i < spec.n_dense_layers:
                 lw = _layer_of(tree["dense"], i)

@@ -209,11 +209,13 @@ def _write_rows(plane, new, rows, cols):
     return plane
 
 
-def _attend_rows(shape, q, k_all, v_all, idx, last):
+def _attend_rows(shape, q, k_all, v_all, idx, last, sink=None):
     """Row b's query over slots 0 .. last[b] of its plane idx * B + b of
     the contiguous (rows, n, S, h) caches, ``shape`` the attention's (query
-    heads, KV heads, head size): the flash-decode kernel on the chip, a
-    masked einsum elsewhere."""
+    heads, KV heads, head size; K's lanes past it are zeros and V's last
+    dim is ``v_all``'s), ``sink`` a
+    score a query head or None (``attention_core``): the flash-decode
+    kernel on the chip, a masked einsum elsewhere."""
     from ..ops import pallas_head_major_attention as hm
     from ..ops.pallas_attention import attn_kernel_mode
     from .llama import attention_core
@@ -221,21 +223,25 @@ def _attend_rows(shape, q, k_all, v_all, idx, last):
     n_q, n_kv, hs = shape
     B, S = q.shape[0], k_all.shape[2]
     if attn_kernel_mode() == "pallas" and hm.supports(
-            S, n_kv, hs, k_all.dtype.itemsize):
-        return hm.rows_decode_attention(q, k_all, v_all, idx, last,
+            S, n_kv, k_all.shape[-1], k_all.dtype.itemsize, v_all.shape[-1]):
+        return hm.rows_decode_attention(q, k_all, v_all, idx, last, sink,
                                         kv_mul=n_q // n_kv)
     k_c = jax.lax.dynamic_slice_in_dim(k_all, idx * B, B, 0)
+    if k_c.shape[-1] != hs:     # a head held in whole lane tiles
+        k_c = k_c[..., :hs]
     v_c = jax.lax.dynamic_slice_in_dim(v_all, idx * B, B, 0)
     mask = jnp.arange(S)[None, None, :] <= last[:, None, None]
     return attention_core(hs, n_q // n_kv, q.reshape(B, 1, n_q, hs),
                           jnp.swapaxes(k_c, 1, 2), jnp.swapaxes(v_c, 1, 2),
-                          mask).reshape(B, -1)
+                          mask, sink).reshape(B, -1)
 
 
-def _attend_pages(shape, page_size, q, k_all, v_all, pos_b, table):
+def _attend_pages(shape, page_size, q, k_all, v_all, pos_b, table,
+                  sink=None):
     """Row b's query over positions 0 .. pos_b[b] of its pages in the pool
-    (pages, n, page_size, h), ``shape`` as in ``_attend_rows``: the paged
-    kernel on the chip, a gather of the row's virtual plane elsewhere."""
+    (pages, n, page_size, h), ``shape`` and ``sink`` as in
+    ``_attend_rows``: the paged kernel on the chip, a gather of the row's
+    virtual plane elsewhere."""
     from ..ops import pallas_head_major_attention as hm
     from ..ops.pallas_attention import attn_kernel_mode
     from .llama import attention_core
@@ -243,20 +249,24 @@ def _attend_pages(shape, page_size, q, k_all, v_all, pos_b, table):
     n_q, n_kv, hs = shape
     B = q.shape[0]
     if attn_kernel_mode() == "pallas" and hm.supports_paged(
-            page_size, n_kv, hs, k_all.dtype.itemsize):
-        return hm.paged_decode_attention(q, k_all, v_all, pos_b, table,
+            page_size, n_kv, k_all.shape[-1], k_all.dtype.itemsize,
+            v_all.shape[-1]):
+        return hm.paged_decode_attention(q, k_all, v_all, pos_b, table, sink,
                                          kv_mul=n_q // n_kv)
     s_virt = table.shape[1] * page_size
 
     def plane(pool):
         pages = jnp.take(pool, table.reshape(-1), axis=0).reshape(
-            B, table.shape[1], n_kv, page_size, hs)
-        return jnp.swapaxes(pages, 2, 3).reshape(B, s_virt, n_kv, hs)
+            B, table.shape[1], n_kv, page_size, pool.shape[-1])
+        return jnp.swapaxes(pages, 2, 3).reshape(B, s_virt, n_kv,
+                                                 pool.shape[-1])
 
     k_c, v_c = plane(k_all), plane(v_all)
+    if k_c.shape[-1] != hs:
+        k_c = k_c[..., :hs]
     mask = jnp.arange(s_virt)[None, None, :] <= pos_b[:, None, None]
     return attention_core(hs, n_q // n_kv, q.reshape(B, 1, n_q, hs),
-                          k_c, v_c, mask).reshape(B, -1)
+                          k_c, v_c, mask, sink).reshape(B, -1)
 
 
 class _Carry(NamedTuple):

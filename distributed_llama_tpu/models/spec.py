@@ -131,6 +131,16 @@ version 4):
   ffn_norm (F32 dim), then a dense layer's w1, w2, w3 at denseHidden, or an
   expert layer's router ... experts as in version 4 (or, with no experts,
   w1, w2, w3 at hiddenDim)
+
+Extension VERSION 8 (version 7's values, then five ints {valueHeadSize,
+fullKvHeads, slidingKvHeads, fullSink, slidingSink} and one float64
+{valueScale}) is written only by a mixer-kinds spec that sets one of them
+(MiMo-V2-Flash's layout: V heads narrower than K heads, a KV head count a
+layer kind, a learned softmax sink a query head, the attention output
+scaled), so a version-7 file reads and writes byte for byte. A kind's ``wk``
+is (kvHeads_k head x dim), ``wv`` (kvHeads_k valueHead x dim), ``wo`` (dim x
+heads_k valueHead), and a kind with a sink has, after ``wo`` (and
+``w_hgate``), ``sink`` (F32, heads_k).
 """
 
 from __future__ import annotations
@@ -156,7 +166,9 @@ EXT6_VERSION = 6
 EXT6_STRUCT = struct.Struct("<14i2d16i7d4i3d")
 EXT7_VERSION = 7
 EXT7_STRUCT = struct.Struct("<14i2d16i7d9i14d128B")
-MAX_HEADER_BYTES = EXT7_STRUCT.size
+EXT8_VERSION = 8
+EXT8_STRUCT = struct.Struct("<14i2d16i7d9i14d128B5i1d")
+MAX_HEADER_BYTES = EXT8_STRUCT.size
 HC_SUBLAYERS = ("att", "ffn")
 ATTN_KINDS = ("softmax", "retention")
 # what a layer of a ``HybridLayers`` spec mixes with, and what it caches for
@@ -293,13 +305,17 @@ class HyperConnections:
 
 @dataclasses.dataclass(frozen=True)
 class MixerKind:
-    """One kind of grouped-query attention layer: its query heads (over the
-    spec's ``n_kv_heads``) and its RoPE: base, how many leading dimensions
-    of a head it rotates (0: all of them), and YaRN or none."""
+    """One kind of grouped-query attention layer: its query heads, its KV
+    heads (0: the spec's ``n_kv_heads``), its RoPE: base, how many leading
+    dimensions of a head it rotates (0: all of them), and YaRN or none; and
+    whether each query head has a learned ``sink``: one more column of its
+    softmax that carries no value, so a row's weights sum to less than 1."""
     heads: int
     rope_theta: float = 10000.0
     rotary_dim: int = 0
     rope_scaling: RopeScaling | None = None
+    kv_heads: int = 0
+    sink: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -312,14 +328,30 @@ class MixerKinds:
     and RoPE; the head SIZE is one, stated here (``dim // n_heads`` says
     nothing where the head count is a kind's). ``gate``: each head's output
     is multiplied by a sigmoid of the normed layer input (``w_hgate``)
-    before ``wo``. The FFN of a layer is ``TransformerSpec.layout``'s and
-    ``router``'s, as for a latent spec."""
+    before ``wo``. ``v_head_size``: a V head's size where it is not a K
+    head's (0: ``head_size``); ``value_scale``: what the attention output is
+    multiplied by before ``wo``. The FFN of a layer is
+    ``TransformerSpec.layout``'s and ``router``'s, as for a latent spec."""
     kinds: tuple
     window: int
     head_size: int
     full: MixerKind
     sliding: MixerKind
     gate: bool = False
+    v_head_size: int = 0
+    value_scale: float = 1.0
+
+    @property
+    def v_size(self) -> int:
+        return self.v_head_size or self.head_size
+
+    @property
+    def widened(self) -> bool:
+        """Whether the spec states what a version-7 header has no field
+        for."""
+        return bool(self.v_head_size or self.value_scale != 1.0
+                    or any(k.kv_heads or k.sink
+                           for k in (self.full, self.sliding)))
 
     def count(self, kind: str) -> int:
         return sum(k == kind for k in self.kinds)
@@ -329,6 +361,15 @@ class MixerKinds:
 
     def rotary(self, kind: str) -> int:
         return self.of(kind).rotary_dim or self.head_size
+
+
+def cache_lanes(head: int) -> int:
+    """The last dim a mixer-kinds spec's cache gives a K or V head of
+    ``head`` values: itself up to one 128-lane tile, whole tiles past it
+    (192 lies in 256, the rest zeros). The chip tiles a last dim in 128
+    lanes whatever the shape says, and the decode kernels copy whole tiles
+    only (ops/pallas_head_major_attention.py)."""
+    return head if head <= 128 else -(-head // 128) * 128
 
 
 def sambay_kinds(n_layers: int) -> tuple:
@@ -459,15 +500,19 @@ class TransformerSpec:
         for kind in MIXER_KINDS:
             k = mx.of(kind)
             rot = mx.rotary(kind)
-            if (k.heads < 1 or k.heads % self.n_kv_heads or rot % 2
+            n_kv = self.kv_shape(kind)[0]
+            if (k.heads < 1 or k.heads % n_kv or rot % 2
                     or not 0 < rot <= mx.head_size):
                 raise ValueError(
                     f"mixers.{kind}: {k.heads} heads must be a multiple of "
-                    f"n_kv_heads={self.n_kv_heads}, and its rotary_dim even "
-                    f"and at most head_size={mx.head_size}")
-        if mx.window < 1 or mx.head_size < 2 or self.n_heads != mx.full.heads:
+                    f"n_kv_heads={n_kv} (the kind's), and its rotary_dim "
+                    f"even and at most head_size={mx.head_size}")
+        if (mx.window < 1 or mx.head_size < 2 or mx.v_head_size < 0
+                or self.n_heads != mx.full.heads
+                or mx.full.kv_heads not in (0, self.n_kv_heads)):
             raise ValueError("mixers: a positive window and head_size, and "
-                             "n_heads the full kind's head count")
+                             "n_heads the full kind's head count (n_kv_heads "
+                             "its KV heads)")
 
     def _check_hybrid(self) -> None:
         hy = self.hybrid
@@ -520,10 +565,10 @@ class TransformerSpec:
 
     @property
     def header_version(self) -> int:
-        """0 (the 28-byte header), 2, 3, 4, 5, 6 or 7: the lowest that
+        """0 (the 28-byte header), 2, 3, 4, 5, 6, 7 or 8: the lowest that
         holds the spec."""
         if self.mixers:
-            return EXT7_VERSION
+            return EXT8_VERSION if self.mixers.widened else EXT7_VERSION
         if self.hyper:
             return EXT6_VERSION
         if self.hybrid:
@@ -548,7 +593,8 @@ class TransformerSpec:
                 EXT4_VERSION: EXT4_STRUCT.size,
                 EXT5_VERSION: EXT5_STRUCT.size,
                 EXT6_VERSION: EXT6_STRUCT.size,
-                EXT7_VERSION: EXT7_STRUCT.size}[self.header_version]
+                EXT7_VERSION: EXT7_STRUCT.size,
+                EXT8_VERSION: EXT8_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
@@ -586,6 +632,19 @@ class TransformerSpec:
             return self.n_kv_heads * self.mixers.head_size
         return (self.dim * self.n_kv_heads) // self.n_heads
 
+    def kv_shape(self, kind: str) -> tuple[int, int, int]:
+        """(KV heads, a K head's size, a V head's size) of a mixer-kinds
+        spec's layers of ``kind``."""
+        mx = self.mixers
+        return (mx.of(kind).kv_heads or self.n_kv_heads, mx.head_size,
+                mx.v_size)
+
+    def kv_cached(self, kind: str) -> int:
+        """Values a position takes in the cache of ONE layer of ``kind``:
+        its K and V heads as held (``cache_lanes``)."""
+        n_kv, k_size, v_size = self.kv_shape(kind)
+        return n_kv * (cache_lanes(k_size) + cache_lanes(v_size))
+
     @property
     def kv_mul(self) -> int:
         """GQA group size: queries per kv head (reference transformer-tasks.cpp:214)."""
@@ -604,7 +663,8 @@ class TransformerSpec:
                       (EXT4_VERSION, 29): EXT4_STRUCT,
                       (EXT5_VERSION, 165): EXT5_STRUCT,
                       (EXT6_VERSION, 36): EXT6_STRUCT,
-                      (EXT7_VERSION, 187): EXT7_STRUCT}.get((version, count))
+                      (EXT7_VERSION, 187): EXT7_STRUCT,
+                      (EXT8_VERSION, 193): EXT8_STRUCT}.get((version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
@@ -615,7 +675,7 @@ class TransformerSpec:
             if version == EXT5_VERSION:
                 more = _read_ext5(ints[36:], base[2])
                 ints = ints[:36]
-            if version == EXT7_VERSION:
+            if version in (EXT7_VERSION, EXT8_VERSION):
                 more = _read_ext7(ints[36:], base[2])
                 ints = ints[:36]
             if version == EXT6_VERSION:
@@ -675,7 +735,7 @@ class TransformerSpec:
             return EXT6_STRUCT.pack(
                 EXT_MAGIC, EXT6_VERSION, 36, *v3, *v4, hc.streams,
                 hc.sinkhorn_iters, 0, 0, hc.eps, hc.clamp_min, hc.clamp_max)
-        if self.header_version == EXT7_VERSION:
+        if self.mixers:
             mx = self.mixers
             kinds = [MIXER_KINDS.index(k) for k in mx.kinds]
             yarn = []
@@ -683,14 +743,19 @@ class TransformerSpec:
                 r = k.rope_scaling or RopeScaling(0.0, 0)
                 yarn += [r.factor, float(r.original_positions), r.beta_fast,
                          r.beta_slow, r.mscale, r.mscale_all_dim]
-            return EXT7_STRUCT.pack(
-                EXT_MAGIC, EXT7_VERSION, 187, *v3, *v4, mx.window,
-                mx.head_size, int(mx.gate), mx.full.heads, mx.sliding.heads,
-                mx.full.rotary_dim, mx.sliding.rotary_dim,
-                int(mx.full.rope_scaling is not None),
-                int(mx.sliding.rope_scaling is not None),
-                mx.full.rope_theta, mx.sliding.rope_theta, *yarn,
-                *kinds, *([255] * (128 - len(kinds))))
+            v7 = (*v3, *v4, mx.window,
+                  mx.head_size, int(mx.gate), mx.full.heads, mx.sliding.heads,
+                  mx.full.rotary_dim, mx.sliding.rotary_dim,
+                  int(mx.full.rope_scaling is not None),
+                  int(mx.sliding.rope_scaling is not None),
+                  mx.full.rope_theta, mx.sliding.rope_theta, *yarn,
+                  *kinds, *([255] * (128 - len(kinds))))
+            if self.header_version == EXT7_VERSION:
+                return EXT7_STRUCT.pack(EXT_MAGIC, EXT7_VERSION, 187, *v7)
+            return EXT8_STRUCT.pack(
+                EXT_MAGIC, EXT8_VERSION, 193, *v7, mx.v_head_size,
+                mx.full.kv_heads, mx.sliding.kv_heads, int(mx.full.sink),
+                int(mx.sliding.sink), mx.value_scale)
         hy = self.hybrid
         kinds = [LAYER_KINDS.index(k) for k in hy.kinds]
         return EXT5_STRUCT.pack(
@@ -858,7 +923,7 @@ class TransformerSpec:
         of that kind) and then its FFN's (``stack`` "dense" or "", as for
         a latent spec's two kinds of FFN)."""
         mx, d, h = self.mixers, self.dim, self.hidden_dim
-        kv, lay = self.kv_dim, self.layout
+        lay = self.layout
         f, m = (lambda n, *s: ("f32", n, s)), (lambda n, *s: ("mm", n, s))
         ffn_dense = [f("rms_ffn", d), m("w1", lay.dense_hidden, d),
                      m("w2", d, lay.dense_hidden),
@@ -879,11 +944,15 @@ class TransformerSpec:
         seen = {k: 0 for k in MIXER_KINDS}
         plans = []
         for i, kind in enumerate(mx.kinds):
-            q = mx.of(kind).heads * mx.head_size
-            mixer = [f("rms_att", d), m("wq", q, d), m("wk", kv, d),
-                     m("wv", kv, d), m("wo", d, q)]
+            heads = mx.of(kind).heads
+            n_kv, k_size, v_size = self.kv_shape(kind)
+            mixer = [f("rms_att", d), m("wq", heads * k_size, d),
+                     m("wk", n_kv * k_size, d), m("wv", n_kv * v_size, d),
+                     m("wo", d, heads * v_size)]
             if mx.gate:
-                mixer.append(f("w_hgate", mx.of(kind).heads, d))
+                mixer.append(f("w_hgate", heads, d))
+            if mx.of(kind).sink:
+                mixer.append(f("sink", heads))
             plans.append((kind, seen[kind], mixer))
             seen[kind] += 1
             k = lay.dense_layers
@@ -961,10 +1030,13 @@ class TransformerSpec:
 
 def _read_ext7(vals, n_layers: int) -> dict:
     """``mixers`` from a version-7 header's nine ints, fourteen float64
-    and 128 bytes."""
+    and 128 bytes, and a version-8 header's five ints and one float64
+    after them."""
     (window, head, gate, f_heads, s_heads, f_rot, s_rot, f_scaled, s_scaled,
      f_theta, s_theta, *rest) = vals
     yarn, kinds = rest[:12], rest[12:][:n_layers]
+    v_head, f_kv, s_kv, f_sink, s_sink, v_scale = rest[140:] or (
+        0, 0, 0, 0, 0, 1.0)
     if not 0 < n_layers <= 128 or any(k >= len(MIXER_KINDS) for k in kinds):
         raise ValueError("unknown layer kind in a version-7 header")
 
@@ -976,9 +1048,10 @@ def _read_ext7(vals, n_layers: int) -> dict:
     return dict(mixers=MixerKinds(
         tuple(MIXER_KINDS[k] for k in kinds), window, head,
         MixerKind(f_heads, float(f_theta), f_rot,
-                  scaling(f_scaled, yarn[:6])),
+                  scaling(f_scaled, yarn[:6]), f_kv, bool(f_sink)),
         MixerKind(s_heads, float(s_theta), s_rot,
-                  scaling(s_scaled, yarn[6:])), bool(gate)))
+                  scaling(s_scaled, yarn[6:]), s_kv, bool(s_sink)),
+        bool(gate), v_head, float(v_scale)))
 
 
 def _read_ext5(vals, n_layers: int) -> dict:

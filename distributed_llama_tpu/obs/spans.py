@@ -49,8 +49,14 @@ SCOPE_MOE_EXPERTS = "moe.experts"  # slot building, expert kernels, combine
 SCOPE_HC_COEF = "hc.coef"   # flat norm, projection, sigmoids, Sinkhorn
 SCOPE_HC_MIX = "hc.mix"     # the sub-layer's input and the streams' update
 # a mixer-kinds spec's (models/laguna.py opens them inside SCOPE_ATTN): the
-# per-head output gate, and each kind's own RoPE
+# per-head output gate, each kind's own RoPE, the output's ``value_scale``
+# (an op of its own after the decode kernels' fold and the chunk's) and the
+# softmax's sink column where XLA computes it (``models/llama.
+# attention_core``; inside the decode kernels it is the walk's first carry
+# and no op)
 SCOPE_ATTN_GATE = "attn.gate"
+SCOPE_ATTN_SCALE = "attn.scale"
+SCOPE_ATTN_SINK = "attn.sink"
 
 
 def scope_rope(kind: str) -> str:

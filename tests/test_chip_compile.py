@@ -372,6 +372,34 @@ def _gqa_attention(kind: str, heads: int):
              _sd((b,), jnp.int32), _sd((b, 5120 // 16), jnp.int32)))
 
 
+def _kv_attention(kind: str, sink: bool):
+    """The head-major kernels at MiMo-V2-Flash's widths: 64 query heads, K
+    heads of 192 held in 256 lanes beside V heads of 128, 32 rows.
+    ``window``: nine sliding layers' rings of 128 slots, 8 KV heads (groups
+    of 8), a sink a head; ``rows``: one sequence's contiguous planes of
+    14,336 positions, three full layers, 4 KV heads (groups of 16);
+    ``paged``: three layers' pools of 16,385 pages of 16 merged."""
+    from distributed_llama_tpu.ops import pallas_head_major_attention as hm
+
+    b, heads, hk, hv = 32, 64, 256, 128
+    sinks = [_sd((heads,), jnp.float32)] if sink else [None]
+    q = _sd((b, heads, 192), jnp.float32)
+    if kind in ("window", "rows"):
+        layers, rows, n_kv, s = ((9, b, 8, 128) if kind == "window"
+                                 else (3, 1, 4, 14336))
+        q = _sd((rows, heads, 192), jnp.float32)
+        return (functools.partial(hm.rows_decode_attention,
+                                  kv_mul=heads // n_kv, interpret=False),
+                (q, _sd((layers * rows, n_kv, s, hk), jnp.float32),
+                 _sd((layers * rows, n_kv, s, hv), jnp.float32),
+                 _sd((), jnp.int32), _sd((rows,), jnp.int32), *sinks))
+    return (functools.partial(hm.paged_decode_attention, kv_mul=16,
+                              interpret=False),
+            (q, _sd((3 * 16385, 4, 16, hk), jnp.float32),
+             _sd((3 * 16385, 4, 16, hv), jnp.float32),
+             _sd((b,), jnp.int32), _sd((b, 14336 // 16), jnp.int32), *sinks))
+
+
 # kernel=False: the dispatch documents an XLA dequantize-then-dot route for
 # that shape (the int4 planes serve T == 1 only) — the case pins the routing
 # as well as the compile. An nb-major leaf has a kernel at every T: from 2
@@ -531,6 +559,20 @@ CASES = {
                               True),
     "gqa-paged-ps16-B32-H48": (functools.partial(_gqa_attention, "paged",
                                                  48), True),
+    # MiMo-V2-Flash (PR 48): K 192 in 256 lanes / V 128, 8 and 4 KV heads,
+    # groups of 8 and 16, a sink in the first carry (a copy 192 lanes wide
+    # was refused: "Slice shape along dimension 3 must be aligned to tiling
+    # (128), but is 192")
+    "kv-window-W128-B32-K192-sink": (functools.partial(
+        _kv_attention, "window", True), True),
+    "kv-window-W128-B32-K192": (functools.partial(
+        _kv_attention, "window", False), True),
+    "kv-rows-S14336-B1-K192": (functools.partial(
+        _kv_attention, "rows", False), True),
+    "kv-paged-ps16-B32-K192": (functools.partial(
+        _kv_attention, "paged", False), True),
+    "kv-paged-ps16-B32-K192-sink": (functools.partial(
+        _kv_attention, "paged", True), True),
     **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)
        for leaf, ts in (("lg-f-wqkv", (1, 32, 512)), ("lg-s-wqkv", (32,)),
                         ("lg-f-wo", (1, 32, 512)), ("lg-s-wo", (1, 32, 512)),
