@@ -215,7 +215,7 @@ def _bench(spec, params, samples: int, per_step: bool = False,
     # ms/token, BASELINE.md r5); a rank row is one band of a sharded model:
     # the stock per-leaf picks, u8 bodies
     layout = Q40_STOCK if rank_tp else q40_body_policy(spec, rows=1)
-    announce_q40_layout(layout)
+    announce_q40_layout(layout, None if rank_tp else spec)
 
     def prep():
         t0 = time.perf_counter()

@@ -162,9 +162,9 @@ def _load_one_chip(model: str, wft, bft, rows: int):
 
     layout = None  # other float types: the engine's own, and moot
     if wft == FloatType.Q40:
-        layout = q40_body_policy(read_spec(model, weights_float_type=wft),
-                                 rows)
-        announce_q40_layout(layout)
+        spec = read_spec(model, weights_float_type=wft)
+        layout = q40_body_policy(spec, rows)
+        announce_q40_layout(layout, spec)
     spec, params = load_model_packed(model, weights_float_type=wft,
                                      buffer_float_type=bft, layout=layout)
     return spec, params, layout

@@ -486,17 +486,40 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
         f"i4 headroom gate"))
 
 
-def announce_q40_layout(layout: Q40Layout) -> None:
+def t1_bodies(spec, layout: Q40Layout) -> str:
+    """``t1 mxu M/N``: of a one-chip model's N dense matmul tensors (a
+    layer's as the file has them, which fusing along ``d`` does not change,
+    and the classifier; routed experts run the expert kernels), the M whose
+    one-row dispatch takes the MXU matvec body
+    (ops/pallas_q40._matvec_body_nb_mxu: an nb-major leaf whose block count
+    is a multiple of 8); the others take a vector body or the XLA fallback.
+    Static, like the body itself: shapes decide at trace time."""
+    from .pallas_q40 import _t1_mxu
+
+    shapes = [shape for _, shape in (spec.layer_matmul_shapes()
+                                     + spec.dense_layer_matmul_shapes())]
+    shapes.append((spec.vocab_size, spec.dim))
+    mxu = sum(q40_leaf_layout(d, n // 32, layout=layout) == "nb-major"
+              and _t1_mxu(n // 32) for d, n in shapes)
+    return f"t1 mxu {mxu}/{len(shapes)}"
+
+
+def announce_q40_layout(layout: Q40Layout, spec=None) -> None:
     """The record of a pick: one stderr line, printed unconditionally even
     for quiet callers (a silent layout change would make runs
-    incomparable), and the label on every log record's run stamp."""
+    incomparable), and the label on every log record's run stamp. With the
+    ``spec`` both also carry ``t1_bodies``' count (where the Pallas kernels
+    run at all)."""
     import sys
 
     from ..utils.fingerprint import stamp_q40_body
 
-    stamp_q40_body(layout.label)
-    print(f"💡 Q40 body policy: {layout.label} ({layout.reason}; the i4 "
-          f"body engages on fused decode chains)", file=sys.stderr)
+    t1 = (t1_bodies(spec, layout)
+          if spec is not None and q40_kernel_mode() == "pallas" else "")
+    stamp_q40_body(f"{layout.label} {t1}".rstrip())
+    print(f"💡 Q40 body policy: {layout.label} ({layout.reason}; "
+          f"{t1 and t1 + '; '}the i4 body engages on fused decode chains)",
+          file=sys.stderr)
 
 
 # What apply_q40_body_policy last resolved, for packers that are handed no
@@ -516,7 +539,7 @@ def apply_q40_body_policy(spec, rows: int = 1) -> str:
     global _APPLIED_LAYOUT
 
     _APPLIED_LAYOUT = layout = q40_body_policy(spec, rows)
-    announce_q40_layout(layout)
+    announce_q40_layout(layout, spec)
     return layout.label
 
 

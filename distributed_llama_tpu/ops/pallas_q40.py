@@ -31,6 +31,19 @@ unrolled in the body — the packed bytes of a whole tile arrive as one big
 DMA that Pallas double-buffers across grid steps. Non-TPU backends run in
 interpret mode (tests); the numerics are the exact Q40 value map, so parity
 with the XLA path is bit-tight at f32.
+
+That is the d-major layout (``Q40Kernel``), whose one-row body multiplies
+and adds on the vector unit and is bound by it, not by HBM. Every benchmark
+cell packs the nb-MAJOR layout (``Q40KernelNb``: qs_t (16, nb, d), the
+output dim minor), and there both contractions run on the MXU in exact
+bf16 pieces of what the operands hold: a one-row dispatch pushes each raw
+code once against the row's three pieces laid block-diagonal and scales a
+block's product (``_matvec_body_nb_mxu``, PR 49: level with its tile's
+DMAs on Mistral-7B's ``w13`` and 9 to 19 % over them on a layer's other
+leaves, 72 % of the HBM roofline over the step where the vector body read
+63), a wider one
+dequantizes the tile and multiplies its two pieces by the rows' three
+(``_five_pass_dot``, PR 38). ``q40_matmul``'s docstring has the dispatch.
 """
 
 from __future__ import annotations
@@ -127,12 +140,15 @@ def _kernel_multi_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
 
 
 def _matvec_body_nb(qs3, s, xlo_ref, xhi_ref, xsum_ref, out_ref):
-    """T=1 body for the nb-MAJOR layout (io.loader.Q40KernelNb): qs3
+    """T=1 VECTOR body for the nb-MAJOR layout (io.loader.Q40KernelNb): qs3
     (NJ, nb, R) codes, s (nb, R) f32 scales, xlo/xhi (NJ, nb, 1), xsum
     (nb, 1). Same math as _matvec_body with the tile transposed: the
     output dim R rides the LANES (128-aligned for every Llama d), so
     awkward nb values (160 at 13B) cost no tile padding. The reduction
-    runs over sublanes (axis 0) instead of lanes."""
+    runs over sublanes (axis 0) instead of lanes. Since PR 49 it serves a
+    block count that is no multiple of 8 only (no model's: the tests');
+    every other leaf takes ``_matvec_body_nb_mxu`` below, which beat it on
+    every leaf timed (PERF.md section 7)."""
     acc = None
     for j in range(NJ):
         q = qs3[j].astype(jnp.int32)                 # (nb, R)
@@ -155,6 +171,139 @@ def _kernel_matvec_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
     del layer_ref  # consumed by the index maps
     _matvec_body_nb(qs_ref[0], scale_ref[0], xlo_ref, xhi_ref, xsum_ref,
                     out_ref)
+
+
+# -- the T=1 nb-major body: raw codes on the MXU, the row block-diagonal -----
+#
+# The vector body above spends 4.5 vector operations a weight, 2 of them the
+# multiply and the add, and is bound by them (62 % of the HBM roofline on
+# Mistral-7B's tree). They stay off the MXU there only because a Q40 scale
+# belongs to a (block, output row) pair, so a contraction over a row's blocks
+# mixes scales. At ONE row the contraction can be split by block for nothing:
+# for a group of 8 blocks (one float32 sublane tile) the 32 nibble slabs
+# (8, R) of the group, laid one under the other, are a (256, R) right-hand
+# side whose row v 8 + g is code v of block g (a renumbering of whole vector
+# registers), and the row's activation laid BLOCK-DIAGONAL,
+#   L[p 8 + g, v 8 + g'] = piece_p(x[block g, v]) where g == g', else 0
+# (p the three bf16 pieces of a float32, ``_mask_pieces``), is a (24, 256)
+# left-hand side. ONE dot L @ codes gives, in row p 8 + g, piece p's part of
+# block g's UNSCALED sum for each of the R outputs: every weight is pushed
+# to the MXU once, as its raw code 0..15 (a bf16 number, float32-held, so
+# nothing is cast on the vector unit); a code times a piece is exact in
+# float32 and the MXU adds in float32. The vector unit is left the unpack
+# (widen, mask or shift, convert: 2.5 operations a weight) and, a GROUP and
+# not a weight, the three slabs' sum, the ``- 8`` fold and the scale.
+#
+# L is built IN the kernel, at the first row tile, into scratch that the
+# later tiles read (a TPU grid runs in order on one core): outside it cost
+# XLA 5 to 16 us a call in copies, as the vector body's planes did and do
+# (its x planes lie (nb, 1), lane-padded: 7 MB of DMA a call at 448 blocks
+# a row). The row arrives as it is, (n / 128, 128). PERF.md section 7 has
+# the table that chose the group, the row tile and where L is made.
+
+_T1_CHUNK = 32   # blocks a load: one uint8 sublane tile (a tail may be less)
+_T1_GROUP = 8    # blocks a dot: one float32 sublane tile
+
+
+def _diag_planes_nb(x_ref, l_scr, xs_scr, nb: int):
+    """Fill ``l_scr`` (nb / 8, 24, 256) with the block-diagonal planes of the
+    row and ``xs_scr`` (nb, 1) with 8 x each block's sum (the ``- 8`` fold).
+    ``x_ref`` (n / 128, 128): row q holds blocks 4 q .. 4 q + 3, so a group
+    of 8 blocks is two rows. Each block's 32 values are spread to lanes
+    v 8 + g by a product with a 0 / 1 matrix (one term a column: exact on
+    bf16 pieces), four groups a product."""
+    f32 = jnp.float32
+    g = _T1_GROUP
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_T1_CHUNK, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_T1_CHUNK, 128), 1)
+    own = (lane // QK) == (sub % 4)              # a block's lanes of its row
+    low = sub[:g] < 4                            # a group's first row of two
+    spread = (jax.lax.broadcasted_iota(jnp.int32, (128, 256), 0) % QK
+              == jax.lax.broadcasted_iota(jnp.int32, (128, 256), 1)
+              // g).astype(f32)
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (g, 256), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (g, 256), 1) % g)
+    dn = (((1,), (0,)), ((), ()))
+
+    def chunk(x8, g0, start, groups):
+        # whole chunks at a time, as the body's turn and for its reason
+        n = g * groups
+        row = [jnp.broadcast_to(jax.lax.slice_in_dim(x8, r, r + 1), (g, 128))
+               for r in range(2 * groups)]
+        a = jnp.where(own[:n], jnp.concatenate(
+            [jnp.where(low, row[2 * k], row[2 * k + 1])
+             for k in range(groups)]), 0.0)      # (n, 128): a block a row
+        xs_scr[pl.ds(start, n), :] = 8.0 * jnp.sum(a, axis=1, keepdims=True)
+        planes = jax.lax.dot_general(jnp.concatenate(_mask_pieces(a, 3)),
+                                     spread, dn, preferred_element_type=f32)
+        for p in range(3):                       # rows: piece, group, block
+            piece = jax.lax.slice_in_dim(planes, p * n, (p + 1) * n)
+            l_scr[pl.ds(g0, groups), pl.ds(p * g, g), :] = jnp.where(
+                diag, piece.reshape(groups, g, 256), 0.0)
+
+    full, tail = divmod(nb, _T1_CHUNK)
+
+    def loop(c, carry):
+        chunk(x_ref[pl.ds(pl.multiple_of(c * 8, 8), 8), :], c * 4,
+              pl.multiple_of(c * _T1_CHUNK, _T1_CHUNK), 4)
+        return carry
+
+    if full:
+        jax.lax.fori_loop(0, full, loop, 0)
+    if tail:
+        chunk(x_ref[full * 8:full * 8 + tail // 4, :], full * 4,
+              full * _T1_CHUNK, tail // _T1_GROUP)
+
+
+def _matvec_body_nb_mxu(qs_ref, s_ref, x_ref, out_ref, l_scr, xs_scr):
+    """T=1 body for the nb-major layout, nb a multiple of 8: qs_ref
+    (NJ, nb, R) uint8 codes and s_ref (nb, R) f32 scales (refs: the groups
+    are walked with ``fori_loop``, 32 blocks a turn, so a leaf of 544 blocks
+    a row traces as fast as one of 56), x_ref (n / 128, 128) the row, out
+    (1, R); l_scr / xs_scr as ``_diag_planes_nb`` fills them at the first
+    row tile. See the section comment above."""
+    nb, rows = s_ref.shape
+    f32 = jnp.float32
+    dn = (((1,), (0,)), ((), ()))
+    g = _T1_GROUP
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        _diag_planes_nb(x_ref, l_scr, xs_scr, nb)
+
+    def turn(start, blocks, g0, acc):
+        # whole planes at a time: an operation traced is set-up time on
+        # every run, and a slab a plane a group made 300 of them a turn
+        q = qs_ref[:, pl.ds(start, blocks), :].astype(jnp.int32)
+        codes = jnp.concatenate([(q & 0xF).astype(f32),
+                                 (q >> 4).astype(f32)])  # (32, blocks, R)
+        for k in range(blocks // g):
+            rhs = jax.lax.slice_in_dim(codes, k * g, (k + 1) * g, axis=1)
+            p3 = jax.lax.dot_general(
+                l_scr[g0 + k], rhs.reshape(2 * NJ * g, rows), dn,
+                preferred_element_type=f32).reshape(3, g, rows)
+            r = pl.ds(start + k * g, g)
+            blk = (p3[2] + p3[1]) + p3[0]               # small pieces first
+            acc = acc + (blk - xs_scr[r, :]) * s_ref[r, :]
+        return acc
+
+    full, tail = divmod(nb, _T1_CHUNK)
+    acc = jnp.zeros((g, rows), f32)
+    if full:
+        acc = jax.lax.fori_loop(
+            0, full, lambda c, acc: turn(
+                pl.multiple_of(c * _T1_CHUNK, _T1_CHUNK), _T1_CHUNK, c * 4,
+                acc), acc)
+    if tail:
+        acc = turn(full * _T1_CHUNK, tail, full * 4, acc)
+    out_ref[...] = jnp.sum(acc, axis=0, keepdims=True)           # (1, R)
+
+
+def _kernel_matvec_nb_mxu_stacked(layer_ref, qs_ref, scale_ref, x_ref,
+                                  out_ref, l_scr, xs_scr):
+    del layer_ref  # consumed by the index maps
+    _matvec_body_nb_mxu(qs_ref.at[0], scale_ref.at[0], x_ref, out_ref, l_scr,
+                        xs_scr)
 
 
 # -- the T>1 tile's dot: five bf16 passes, exact on what a Q40 weight holds ---
@@ -293,10 +442,12 @@ def _kernel_mxu_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
 MULTI_T_MAX = 8
 
 # Raised scoped-VMEM limit for the T>1 kernels (MXU prefill bodies and the
-# T<=8 VPU multi bodies batched decode uses): Mosaic's conservative stack
-# accounting rejects several measured-fine tile sets at the default 16 MB
-# (e.g. 22.6M at w2's nb=344/bt=32 prefill tile, 26.3M at the 13B B=2 multi
-# tile) though v5e has 128 MB physical.
+# T<=8 VPU multi bodies batched decode uses) and the nb-major matvecs (a
+# 5.9 MB tile twice buffered beside 1.7 MB of planes at 544 blocks a row;
+# the vector body's lane-padded x planes alone passed 16 MiB there, PR 31):
+# Mosaic's conservative stack accounting rejects several measured-fine tile
+# sets at the default 16 MB (e.g. 22.6M at w2's nb=344/bt=32 prefill tile,
+# 26.3M at the 13B B=2 multi tile) though v5e has 128 MB physical.
 # Same approach as ops/pallas_layer._VMEM_LIMIT.
 _VMEM64_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
@@ -570,7 +721,8 @@ _MATMUL_ROWSXNB_CAP = 131072
 # round 5) — tile granularity is not that path's limiter
 _MULTI_ROWSXNBXT_CAP = 300_000
 
-# Most rows a tile takes: the tuned d-major pick, and the nb-major matvec's.
+# Most rows a tile takes: the tuned d-major pick, and the nb-major VECTOR
+# matvec's, T > 1 tile's and int4 body's (the MXU matvec: _pick_rows_t1).
 # More rows trade grid steps for longer per-tile DMAs; the scoped-VMEM word
 # budgets apply on top. io/kernel_cache.layout_key writes it into the key.
 _TILE_ROWS_CAP = 768
@@ -677,27 +829,46 @@ def _precision_dot(wf, x2):
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def _pick_rows_nb(d: int, nb: int) -> int | None:
-    """Row tile for the nb-major matvec: rows ride the LANES, so they must
+def _pick_rows_nb(d: int, nb: int, words: int = 360_000,
+                  most: int = _TILE_ROWS_CAP) -> int | None:
+    """Row tile of an nb-major kernel: rows ride the LANES, so they must
     be a multiple of 128 — a d with no multiple-of-128 divisor (including
     every d < 128) returns None and the caller routes to the dequant
-    fallback; rows*nb stays under the same ~(16+4)-bytes-per-word
-    scoped-VMEM budget as the d-major matvec."""
-    top = min(d, _TILE_ROWS_CAP, max(128, 360_000 // nb))
+    fallback. The defaults are the VECTOR matvec's budget, sized for its
+    whole-plane temporaries (rows*nb under the ~(16+4)-bytes-per-word
+    scoped-VMEM budget the d-major matvec has, at most 768 rows); the T > 1
+    tile and the int4 chain body start from them too. The MXU matvec
+    (``_pick_rows_t1``) asks for its own."""
+    top = min(d, most, max(128, words // nb))
     for cand in range(top - top % 128, 0, -128):
         if d % cand == 0:
             return cand
     return None
 
 
-def _matvec_nb_params(nb: int):
-    """The nb-major matvec's compiler parameters: the default scoped VMEM
-    up to 512 blocks a row (every shape it ran before PR 31: nothing about
-    their compile changes), the raised limit beyond. Its x planes lie
-    (nb, 1), a lane-padded 512 bytes a block, 32 planes of them: at
-    nb = 544 (hidden 17408) they alone pass the 16 MiB default, which the
-    chip's compiler refuses (``RESOURCE_EXHAUSTED ... vmem``)."""
-    return _VMEM64_PARAMS if nb > 512 else None
+def _t1_mxu(nb: int) -> bool:
+    """Whether a T = 1 nb-major leaf of ``nb`` blocks a row takes the MXU
+    body (``_matvec_body_nb_mxu``): its groups are 8 blocks. The vector
+    body keeps the rest (no model's leaf: an input width off the 256 grid).
+    Nothing else decides: on the chip the MXU body won on every leaf of
+    both decode cells, by 1.13 times (Mistral-7B's classifier) to 2.6
+    (Yi-34B's 256-row ``wk`` shard, whose call was its XLA copies), PERF.md
+    section 7."""
+    return nb % _T1_GROUP == 0
+
+
+def _pick_rows_t1(d: int, nb: int) -> int | None:
+    """Row tile of the T = 1 nb-major matvec. The MXU body holds 32 blocks
+    of a tile at a time, so its tile is bounded by the pipeline and not by
+    temporaries: up to 1024 rows and 288k words (a 5.9 MB tile of codes and
+    scales, twice buffered). A grid step costs about 0.4 us and the first
+    tile's DMA and the last tile's products overlap nothing, so on the chip
+    Mistral-7B's ``w13`` (128 blocks a row) ran 115 us at 512 rows and 103
+    at 1024 or 2048, its ``w2`` (448) 64 / 60 / 62 at 256 / 512 / 1024
+    (PERF.md section 7)."""
+    if _t1_mxu(nb):
+        return _pick_rows_nb(d, nb, words=288 * 1024, most=1024)
+    return _pick_rows_nb(d, nb)
 
 
 def _dequant_nb(qs_t, scale):
@@ -710,58 +881,69 @@ def _dequant_nb(qs_t, scale):
     return jnp.transpose(w, (2, 1, 0)).reshape(d, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def _q40_matvec_nb_2d(qs_t, scale, x, *, block_rows, interpret):
-    _, nb, d = qs_t.shape
+def _t1_scratch(nb: int):
+    """The MXU matvec's scratch: the row's block-diagonal planes and the
+    blocks' sums (``_diag_planes_nb``)."""
+    return [pltpu.VMEM((nb // _T1_GROUP, 3 * _T1_GROUP, QK * _T1_GROUP),
+                       jnp.float32),
+            pltpu.VMEM((nb, 1), jnp.float32)]
+
+
+def _vector_planes_nb(x, nb: int):
+    """The vector matvec's x planes: xlo / xhi (NJ, nb, 1), xsum (nb, 1)."""
     xlo, xhi = _split_x(x.astype(jnp.float32), nb)   # (NJ, 1, nb)
     xlo = jnp.transpose(xlo, (0, 2, 1))              # (NJ, nb, 1)
     xhi = jnp.transpose(xhi, (0, 2, 1))
     xsum = jnp.sum(xlo[:, :, 0] + xhi[:, :, 0], axis=0)[:, None]  # (nb, 1)
-    out = pl.pallas_call(
-        _kernel_matvec_nb,
-        grid=(d // block_rows,),
-        in_specs=[
-            pl.BlockSpec((NJ, nb, block_rows), lambda i: (0, 0, i)),
-            pl.BlockSpec((nb, block_rows), lambda i: (0, i)),
-            pl.BlockSpec((NJ, nb, 1), lambda i: (0, 0, 0)),
-            pl.BlockSpec((NJ, nb, 1), lambda i: (0, 0, 0)),
-            pl.BlockSpec((nb, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_rows), lambda i: (0, i)),
+    return xlo, xhi, xsum
+
+
+def _matvec_nb_call(layer, qs_t, scale, x, block_rows, interpret):
+    """The T = 1 nb-major ``pallas_call``: a 2-D leaf (``layer`` None) or
+    one layer of a stack, which the scalar-prefetched index picks; the MXU
+    body or the vector body, which ``_t1_mxu`` picks."""
+    nb, d = qs_t.shape[-2:]
+    pre = () if layer is None else (layer,)
+
+    def tile(*shape):        # a row tile of the leaf (of the picked layer)
+        return pl.BlockSpec(
+            (1,) * len(pre) + shape + (block_rows,),
+            lambda i, *L: (*(l[0] for l in L), *(0,) * len(shape), i))
+
+    def whole(*shape):       # what every row tile reads whole
+        return pl.BlockSpec(shape, lambda i, *L: (0,) * len(shape))
+
+    if _t1_mxu(nb):
+        kernel = _kernel_matvec_nb_mxu_stacked if pre else _matvec_body_nb_mxu
+        planes = (x.astype(jnp.float32).reshape(nb // 4, 128),)
+        specs, scratch = [whole(nb // 4, 128)], _t1_scratch(nb)
+    else:
+        kernel = _kernel_matvec_nb_stacked if pre else _kernel_matvec_nb
+        planes = _vector_planes_nb(x, nb)
+        specs, scratch = [whole(NJ, nb, 1), whole(NJ, nb, 1), whole(nb, 1)], []
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(pre), grid=(d // block_rows,),
+            in_specs=[tile(NJ, nb), tile(nb), *specs],
+            out_specs=pl.BlockSpec((1, block_rows), lambda i, *L: (0, i)),
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
-        compiler_params=_matvec_nb_params(nb),
-        interpret=interpret,
-    )(qs_t, scale, xlo, xhi, xsum)
-    return out                                        # (1, d)
+        compiler_params=_VMEM64_PARAMS, interpret=interpret,
+    )(*pre, qs_t, scale, *planes)                     # (1, d)
+
+
+# the benchmark finds the T = 1 calls by these two names
+# (benchmark/harness/reduce_trace.py: a device operation is Q40's by the
+# jitted function's name), so both bodies run under them
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _q40_matvec_nb_2d(qs_t, scale, x, *, block_rows, interpret):
+    return _matvec_nb_call(None, qs_t, scale, x, block_rows, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _q40_matvec_nb_stacked(layer, qs_t, scale, x, *, block_rows, interpret):
-    _, _, nb, d = qs_t.shape
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)
-    xlo = jnp.transpose(xlo, (0, 2, 1))
-    xhi = jnp.transpose(xhi, (0, 2, 1))
-    xsum = jnp.sum(xlo[:, :, 0] + xhi[:, :, 0], axis=0)[:, None]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(d // block_rows,),
-        in_specs=[
-            pl.BlockSpec((1, NJ, nb, block_rows),
-                         lambda i, L: (L[0], 0, 0, i)),
-            pl.BlockSpec((1, nb, block_rows), lambda i, L: (L[0], 0, i)),
-            pl.BlockSpec((NJ, nb, 1), lambda i, L: (0, 0, 0)),
-            pl.BlockSpec((NJ, nb, 1), lambda i, L: (0, 0, 0)),
-            pl.BlockSpec((nb, 1), lambda i, L: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_rows), lambda i, L: (0, i)),
-    )
-    out = pl.pallas_call(
-        _kernel_matvec_nb_stacked, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
-        compiler_params=_matvec_nb_params(nb),
-        interpret=interpret,
-    )(layer, qs_t, scale, xlo, xhi, xsum)
-    return out
+    return _matvec_nb_call(layer, qs_t, scale, x, block_rows, interpret)
 
 
 def _mxu_nb_planes(x, nb: int, block_t: int, bf16: bool):
@@ -834,15 +1016,22 @@ def _q40_mxu_nb_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
 def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                         interpret: bool | None,
                         layer: jax.Array | None) -> jax.Array:
-    """nb-major dispatch: every T on a kernel, the body picked by T alone.
-    T = 1 the matvec, anything wider the MXU body with the standard
-    (M,K)x(K,N) dot (``_five_pass_dot``: the weight's two bf16 pieces
-    against the rows' three, split once a call, exact on both sides and as
-    close to float64 as HIGHEST; one piece a side where the caller traced
-    under bf16 precision): rows are padded to a multiple of 8, so a 2..8-row
-    decode dispatch is ONE 8-row t-tile of the body a 16-row dispatch and a
-    prefill chunk run. Dequantize-then-dot serves a chunk traced under bf16
-    precision (see q40_matmul) and a ``d`` the row tiler cannot place."""
+    """nb-major dispatch: every T on a kernel, the body picked by T alone
+    and, at T = 1, by whether the leaf's block count is a multiple of 8.
+    T = 1 the matvec: the MXU body (``_matvec_body_nb_mxu``: raw codes
+    pushed once against the row's three bf16 pieces laid block-diagonal,
+    the scale applied a block) where ``_t1_mxu(nb)``, which is every leaf
+    of every model; the vector body for a block count off the 8 grid.
+    Nothing else picks: no leaf timed on the chip lost to the vector body
+    (``_t1_mxu`` says by how much). Anything wider the MXU tile with the
+    standard (M,K)x(K,N) dot (``_five_pass_dot``: the weight's two bf16
+    pieces against the rows' three, split once a call, exact on both sides
+    and as close to float64 as HIGHEST; one piece a side where the caller
+    traced under bf16 precision): rows are padded to a multiple of 8, so a
+    2..8-row decode dispatch is ONE 8-row t-tile of the body a 16-row
+    dispatch and a prefill chunk run. Dequantize-then-dot serves a chunk
+    traced under bf16 precision (see q40_matmul) and a ``d`` the row tiler
+    cannot place."""
     from .linear import matmul_mode
 
     qs_t, scale = w.qs_t, w.scale
@@ -860,7 +1049,10 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
     bf16 = matmul_mode() == "bf16"
     # a chunk under bf16 precision dequantizes once and dots (q40_matmul
     # says why); a decode dispatch of up to MULTI_T_MAX rows never does
-    rows = None if bf16 and t > MULTI_T_MAX else _pick_rows_nb(d, nb)
+    if t == 1:
+        rows = _pick_rows_t1(d, nb)
+    else:
+        rows = None if bf16 and t > MULTI_T_MAX else _pick_rows_nb(d, nb)
     block_t = _pick_block_t(t, nb)
     if rows is not None and t > 1:
         # the MXU body's f32 wlo/whi temporaries obey the same measured
@@ -1009,6 +1201,13 @@ def q40_matmul(w: Q40Kernel | Q40KernelNb | Q40KernelNbI4 | Q40Weight,
     Q40KernelNbI4  _q40_matvec_nb_i4  dequant + dot  dequant + dot
     Q40Kernel      _kernel_matvec     _kernel_multi  _kernel
     ============== ================== ============== ================
+
+    ``_q40_matvec_nb`` is two bodies under one name, picked by the leaf's
+    block count alone (``_t1_mxu``): the MXU matvec (raw codes against the
+    row laid block-diagonal, ``_matvec_body_nb_mxu``) where it is a
+    multiple of 8, as every model's is, with ``_pick_rows_t1``'s row tile;
+    the vector body (``_matvec_body_nb``) else. The other T = 1 bodies
+    multiply and add on the vector unit.
 
     Two exceptions, both dequantize-then-dot in XLA: a ``d`` no tiler
     places, and a chunk (T > 8) traced under bf16 precision

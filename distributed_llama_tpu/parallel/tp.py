@@ -302,8 +302,19 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
         # change would make runs incomparable
         picks = "; ".join(f"{label}: {' '.join(keys)}"
                           for label, keys in layouts.items() if keys)
+        # the one-row body of each shard, as the one-chip line counts it
+        # (ops/linear.t1_bodies): an nb-major leaf whose LOCAL block count
+        # is a multiple of 8 takes the MXU matvec
+        from ..ops.pallas_q40 import _t1_mxu
+
+        sharded_in = (FUSED_INPUT_SHARDED
+                      if scheme in _INPUT_SHARDED_SCHEMES else ())
+        mxu = sum(_t1_mxu(params[k].qs_t.shape[-2]
+                          // (n_tp if k in sharded_in else 1))
+                  for k in layouts["nb-major"])
         print(f"💡 Q40 sharded layout: {picks} (tp={n_tp} {scheme}; a "
-              f"shard-local block count off the 128 grid packs nb-major)",
+              f"shard-local block count off the 128 grid packs nb-major; "
+              f"t1 mxu {mxu}/{sum(map(len, layouts.values()))})",
               file=sys.stderr)
     specs = param_specs(params, scheme)
     threads = min(16, os.cpu_count() or 1)

@@ -266,7 +266,7 @@ def _q40_wide_w2():
 
     d, nb = 5120, 17408 // 32
     fn = functools.partial(pq._q40_matvec_nb_stacked, interpret=False,
-                           block_rows=pq._pick_rows_nb(d, nb))
+                           block_rows=pq._pick_rows_t1(d, nb))
     return fn, (_sd((1,), jnp.int32), _sd((2, 16, nb, d), jnp.uint8),
                 _sd((2, nb, d), jnp.float32), _sd((1, nb * 32), jnp.float32))
 
@@ -474,10 +474,18 @@ CASES = {
     # a raised scoped-VMEM limit), and the chunk's float32 MXU matmuls
     "retention-decode-B16": (functools.partial(_retention, "decode"), True),
     "retention-chunk-T128": (functools.partial(_retention, "chunk"), True),
-    # the T=1 nb-major matvec at Brumby's w2 (544 blocks a row): was
-    # "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem" under
-    # the default scoped limit (ops/pallas_q40._matvec_nb_params)
+    # the T=1 nb-major matvec at Brumby's w2 (544 blocks a row): the vector
+    # body's x planes were "RESOURCE_EXHAUSTED: Ran out of memory in memory
+    # space vmem" under the default scoped limit. Since PR 49 the MXU
+    # matvec (ops/pallas_q40._matvec_body_nb_mxu: the groups walked with
+    # fori_loop, the row's block-diagonal planes in scratch), here and at
+    # the block counts and row counts of the two decode cells that no case
+    # below holds at one row: Mistral's w13 (1024-row tiles) and w2 (448
+    # blocks), Yi's tp-4 wo (56 blocks: a chunk of 32 and a tail of 24), wk
+    # (256 rows: one tile) and classifier (16,000 rows: 25 tiles of 640)
     "q40-nb-w2-nb544-T1": (_q40_wide_w2, True),
+    **{f"q40-nb-{leaf}-T1": (functools.partial(_q40, "nb", leaf, 1), True)
+       for leaf in ("m-w13", "m-w2", "yi-wo", "yi-wk", "yi-wcls")},
     # DeepSeek-V3 (PR 33). The latent plane: was "Slice shape along
     # dimension 2 must be aligned to tiling (128), but is 576" on
     # memref<36873x16x640xf32> (the chip stores 576 values in 640 lanes

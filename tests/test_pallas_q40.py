@@ -820,3 +820,118 @@ def test_dense_and_merged_tiles_agree(nb, rows):
     size = np.abs(want).max()
     np.testing.assert_allclose(merged, dense, rtol=0, atol=1e-6 * size)
     assert np.abs(merged - want).max() <= 1e-6 * size
+
+
+# -- the T = 1 nb-major MXU matvec (PR 49) ----------------------------------
+
+# (d, nb) of every leaf the two decode cells run at one row (Mistral-7B's
+# fused tree, Yi-34B's tp-4 shards), Brumby-14B's ``wo`` and ``w2`` (160 and
+# 544 blocks a row) and a block count off the 8 grid, which keeps the vector
+# body
+T1_LEAVES = {
+    "m-wqkv": (6144, 128), "m-wo": (4096, 128), "m-w13": (28672, 128),
+    "m-w2": (4096, 448), "m-wcls": (32000, 128),
+    "yi-wq": (1792, 224), "yi-wk": (256, 224), "yi-wo": (7168, 56),
+    "yi-w1": (5120, 224), "yi-w2": (7168, 160), "yi-wcls": (16000, 224),
+    "br-wo": (5120, 160), "br-w2": (5120, 544), "off-the-8-grid": (256, 20),
+}
+
+
+def _run_vector_body_nb(qs, scale, x, rows):
+    """The vector matvec body on a 2-D leaf, ``rows`` a tile (interpret)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    _, nb, d = qs.shape
+    xlo, xhi, xsum = pq._vector_planes_nb(jnp.asarray(x), nb)
+    return np.asarray(pl.pallas_call(
+        pq._kernel_matvec_nb, grid=(d // rows,),
+        in_specs=[pl.BlockSpec((16, nb, rows), lambda i: (0, 0, i)),
+                  pl.BlockSpec((nb, rows), lambda i: (0, i)),
+                  pl.BlockSpec((16, nb, 1), lambda i: (0, 0, 0)),
+                  pl.BlockSpec((16, nb, 1), lambda i: (0, 0, 0)),
+                  pl.BlockSpec((nb, 1), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((1, rows), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        interpret=True)(jnp.asarray(qs), jnp.asarray(scale), xlo, xhi, xsum))
+
+
+@pytest.mark.parametrize("leaf", sorted(T1_LEAVES))
+def test_t1_mxu_matvec_is_as_close_to_float64_as_the_vector_body(leaf):
+    """The T = 1 program of every leaf above, at the leaf's own block count
+    and row tile (``d`` cut to two row tiles: the body sees a tile, the
+    grid the rest), stacked as the layer scan runs it and 2-D for a
+    classifier: equal to the dequantize-then-dot float32 product within
+    the vector body's tolerance, and no farther from the float64 product
+    than the vector body on the same seeds (plus 1e-7 of the outputs'
+    size: two float32 summation orders)."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    d, nb = T1_LEAVES[leaf]
+    rows = pq._pick_rows_t1(d, nb)
+    assert pq._t1_mxu(nb) == (nb % 8 == 0)
+    d = min(d, 2 * rows)
+    qs, scale, x, w, want = _tile_inputs(nb, 1, d=d, seed=49)
+    if leaf.endswith("wcls"):
+        got = pq._q40_matvec_nb_2d(jnp.asarray(qs), jnp.asarray(scale),
+                                   jnp.asarray(x), block_rows=rows,
+                                   interpret=True)
+    else:
+        # layer 1 of two: the scalar-prefetch index map picks it
+        other = np.roll(qs, 1, axis=1)
+        got = pq._q40_matvec_nb_stacked(
+            jnp.asarray([1], jnp.int32), jnp.asarray(np.stack([other, qs])),
+            jnp.asarray(np.stack([scale, scale])), jnp.asarray(x),
+            block_rows=rows, interpret=True)
+    got = np.asarray(got)
+    assert got.shape == (1, d)
+    np.testing.assert_allclose(got, x @ w.astype(np.float32).T,
+                               rtol=1e-4, atol=1e-3)
+    size = np.abs(want).max()
+    vector = _run_vector_body_nb(qs, scale, x, rows)
+    assert (np.abs(got - want).max() / size
+            <= np.abs(vector - want).max() / size + 1e-7)
+
+
+@pytest.mark.parametrize("nb", [8, 24, 56, 64, 160])
+def test_block_diagonal_planes_sum_to_the_row_exactly(nb):
+    """``_diag_planes_nb``: in group g, row p 8 + b and column v 8 + b'
+    hold piece p of x[block 8 g + b, v] where b == b' and zero elsewhere;
+    every entry is a bf16 number and the three pieces sum to x EXACTLY;
+    the second plane is 8 x each block's sum. Block counts of one group, a
+    tail alone (24), a chunk and a tail (56), whole chunks."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    x = np.random.default_rng(nb).standard_normal(32 * nb).astype(np.float32)
+    x[:3] = [0.0, -3.0e-20, 65504.0]
+
+    def body(x_ref, l_ref, xs_ref):
+        pq._diag_planes_nb(x_ref, l_ref, xs_ref, nb)
+
+    planes, xs = pl.pallas_call(
+        body, out_shape=(jax.ShapeDtypeStruct((nb // 8, 24, 256),
+                                              jnp.float32),
+                         jax.ShapeDtypeStruct((nb, 1), jnp.float32)),
+        interpret=True)(jnp.asarray(x).reshape(nb // 4, 128))
+    planes = np.asarray(planes).reshape(nb // 8, 3, 8, 32, 8)  # g p b v b'
+    np.testing.assert_array_equal(
+        planes, np.asarray(jnp.asarray(planes).astype(jnp.bfloat16)
+                           ).astype(np.float32))
+    off = planes * (1 - np.eye(8, dtype=np.float32))[None, None, :, None, :]
+    assert not off.any()
+    on = np.einsum("gpbvb->gpbv", planes)
+    np.testing.assert_array_equal((on[:, 0] + on[:, 1]) + on[:, 2],
+                                  x.reshape(nb // 8, 8, 32))
+    assert np.abs(on[:, 2]).max() > 0
+    np.testing.assert_allclose(np.asarray(xs)[:, 0],
+                               8 * x.reshape(nb, 32).sum(axis=1),
+                               rtol=1e-5, atol=1e-5)

@@ -145,6 +145,33 @@ def test_apply_returns_label_notes_and_leaves_env_alone(monkeypatch, capsys):
     assert linear._APPLIED_LAYOUT == q40_body_policy(llama2_7b_spec())
 
 
+@pytest.mark.parametrize("model,want", [("7b", "i4-nb t1 mxu 8/8"),
+                                        ("13b", "d-major t1 mxu 7/8")])
+def test_policy_line_and_stamp_count_the_t1_mxu_leaves(model, want,
+                                                       monkeypatch, capsys):
+    """The one-row body is picked at trace time from shapes, so its record
+    is static: the policy line and every log record's ``q40_body`` count
+    the dense tensors whose T = 1 dispatch takes the MXU matvec (an
+    nb-major leaf, block count a multiple of 8). 13B's ``w2`` (432 blocks a
+    row) stays d-major under the stock picks: a vector body."""
+    from distributed_llama_tpu.ops.linear import t1_bodies
+    from distributed_llama_tpu.utils import fingerprint
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    monkeypatch.setattr(fingerprint, "_Q40_BODY", "unresolved")
+    spec = llama2_7b_spec() if model == "7b" else llama2_13b_spec()
+    label, count = want.split(" ", 1)
+    assert t1_bodies(spec, q40_body_policy(spec)) == count
+    assert apply_q40_body_policy(spec) == label
+    assert f"; {count}; the i4 body" in capsys.readouterr().err
+    assert fingerprint.run_stamp()["q40_body"] == want
+    # off the kernel path there is no body to count
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "xla")
+    apply_q40_body_policy(spec)
+    assert "t1 mxu" not in capsys.readouterr().err
+    assert fingerprint.run_stamp()["q40_body"] == "d-major"
+
+
 def test_apply_twice_the_second_stands(monkeypatch, capsys):
     """Overwritten, not first-wins: a by-hand packer after the second call
     packs the second call's layout (here a model past the headroom gate

@@ -158,6 +158,9 @@ def test_sharded_forward_on_nb_major_leaves(scheme, t, monkeypatch, capfd):
     leaves = ("wq", "wk", "wv", "wo", "w1", "w2", "w3", "wcls")
     assert all(isinstance(sharded[k], Q40KernelNb) for k in leaves)
     assert "Q40 sharded layout: nb-major: " in note and "d-major" not in note
+    # every shard-local block count (8, 16 or 32) is a multiple of 8: the
+    # one-row dispatch of all eight leaves is the MXU matvec
+    assert "t1 mxu 8/8" in note
     in_banded = scheme != "ref"
     assert sharded["w2"].qs_t.sharding.shard_shape(
         sharded["w2"].qs_t.shape) == ((1, 16, 16, 512) if in_banded
