@@ -198,9 +198,11 @@ def run_inference(name: str, model: str, tok: str, seed: int,
                 tokens.append(rec["token"])
                 gen_ms.append(rec["gen_ms"])
                 fps.append(rec.get("env_fingerprint", {}))
-        m = re.match(r"⏩ Loaded model in ([0-9.]+)s", line)
-        if m:
-            load_s = float(m.group(1))
+    for line in p.stderr.splitlines():
+        # the program's own start-up account (obs/spans.log_startup): load,
+        # pack, place, cache and engine, summed
+        if line.startswith("{") and '"startup.summary"' in line:
+            load_s = round(sum(json.loads(line)["phases"].values()), 1)
     dev = _DEV_LINE.search(p.stdout)
     mem = _memory_lines(p.stderr)
     cache_errors = p.stderr.count("💡 cache error [")

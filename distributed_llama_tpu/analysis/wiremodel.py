@@ -629,7 +629,10 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_engine_queued_requests"),
     _m("dllama_engine_active_slots"),
     _m("dllama_engine_batch_occupancy"),
-    _m("dllama_engine_compile_events_total"),
+    _m("dllama_engine_compile_events_total"),   # programs made since start()
+    _m("dllama_startup_seconds", labels=("phase",)),
+    _m("dllama_program_makes_total", labels=("program", "how")),
+    _m("dllama_program_make_seconds_total", labels=("program", "how")),
     _m("dllama_dispatch_tokens_total", labels=("kind",)),
     _m("dllama_generated_tokens_total"),
     _m("dllama_requests_total"),

@@ -588,7 +588,6 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         tp = args.tp or 1
     else:
         tp = args.tp or max(1, n_dev // args.sp)
-    t0 = time.perf_counter()
     q40_layout = None  # mesh runs: the engine's own (the stock value)
     if tp > 1 or args.sp > 1:
         # mesh runs keep the codec tree: tp-aware packing happens in
@@ -722,7 +721,12 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
     engine = Engine(spec, params, mesh=mesh, cache_dtype=cache_dtype,
                     fast_prefill=args.fast_prefill, q40_layout=q40_layout)
     if not quiet:
-        print(f"⏩ Loaded model in {time.perf_counter() - t0:.1f}s")
+        from ..obs.spans import log_startup
+
+        # where the seconds since the load began went, by phase, and every
+        # program made so far by name; the programs made from here on (the
+        # step's, at the first token) are logged as they are made
+        log_startup()
         _print_device_memory("loaded")
 
     tokenizer = Tokenizer(args.tokenizer, spec.vocab_size)

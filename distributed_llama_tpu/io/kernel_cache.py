@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from ..obs.spans import startup_phase
 from ..utils.compile_cache import cache_error
 from .loader import Q40Kernel, Q40KernelNb, Q40Weight
 
@@ -241,6 +242,7 @@ def cache_enabled() -> bool:
     return os.environ.get("DLLAMA_TILED_CACHE", "1") != "0"
 
 
+@startup_phase("load")
 def load_model_packed(path: str, spec=None, weights_float_type=None,
                       buffer_float_type=None, layout=None):
     """load_model + pack_q40_params + fuse_q40_layer_matmuls, with the

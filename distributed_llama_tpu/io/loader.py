@@ -18,6 +18,7 @@ from typing import NamedTuple
 import numpy as np
 
 from ..models.spec import MAX_HEADER_BYTES, TransformerSpec
+from ..obs.spans import startup_phase
 from ..ops.quants import (
     FloatType,
     pack_q40_bytes,
@@ -221,6 +222,7 @@ class _Walker:
         raise ValueError(f"unsupported weights float type {ft}")
 
 
+@startup_phase("load")
 def load_model(path: str, spec: TransformerSpec | None = None,
                weights_float_type=FloatType.F32,
                buffer_float_type=FloatType.F32) -> tuple[TransformerSpec, dict]:

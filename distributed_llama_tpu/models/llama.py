@@ -1706,21 +1706,24 @@ def params_to_device(params: dict[str, Any], dtype=None,
     op per layer.
     """
     from ..io.loader import Q40Kernel, Q40Weight
+    from ..obs.spans import startup_phase, startup_placed
     from ..ops.linear import fuse_q40_layer_matmuls, pack_q40_params
 
-    if "wkv_b" in params:   # a latent spec's absorbed halves (float32)
-        from .latent import prepare_latent_params
+    with startup_phase("pack"):     # every host repack, not the Q40 one alone
+        if "wkv_b" in params:   # a latent spec's absorbed halves (float32)
+            from .latent import prepare_latent_params
 
-        if spec is None:
-            raise ValueError("a latent-attention tree needs its spec to be "
-                             "placed: params_to_device(params, spec=spec)")
-        params = prepare_latent_params(spec, params)
-    params = fuse_q40_layer_matmuls(pack_q40_params(
-        params, allow_nb_major=True, layout=layout))
-    if spec is not None and not spec.planned:
-        from ..ops.pallas_layer import prepare_mega_params
+            if spec is None:
+                raise ValueError(
+                    "a latent-attention tree needs its spec to be placed: "
+                    "params_to_device(params, spec=spec)")
+            params = prepare_latent_params(spec, params)
+        params = fuse_q40_layer_matmuls(pack_q40_params(
+            params, allow_nb_major=True, layout=layout))
+        if spec is not None and not spec.planned:
+            from ..ops.pallas_layer import prepare_mega_params
 
-        params = prepare_mega_params(spec, params)
+            params = prepare_mega_params(spec, params)
 
     def conv(a):
         x = jnp.asarray(a)
@@ -1737,4 +1740,9 @@ def params_to_device(params: dict[str, Any], dtype=None,
                 if isinstance(v, (Q40Weight, Q40Kernel, Q40KernelNb))
                 else conv(v) for k, v in stack.items()}
 
-    return place(params)
+    # the transfers are enqueued, not waited for: ``place`` reads the
+    # host's part (PERF.md section 3)
+    with startup_phase("place"):
+        placed = place(params)
+    startup_placed(placed)
+    return placed

@@ -15,7 +15,12 @@ rails that share ONE naming scheme:
 * **host phases** — ``host_phase("serve.fetch")`` puts what the host is
   doing on the PROFILER's clock, beside the device trace, so a capture's
   idle gaps split by phase. The ring's ``perf_counter`` shares nothing
-  with the device trace; its decode spans have a twin here.
+  with the device trace; its decode spans have a twin here;
+* **the start-up account** — ``startup_phase("pack")`` is a host phase
+  named ``startup.pack`` whose wall seconds are also summed in ONE
+  process-wide dict, beside every program JAX makes (by name, with the
+  seconds of its tracing, lowering and compile or cache read), read with
+  ``startup_account()``.
 
 The scope names are the contract between the forward (which emits them),
 the xprof loader (which buckets by them), and the drift reconciler
@@ -29,6 +34,8 @@ import contextlib
 import dataclasses
 import json
 import os
+import re
+import sys
 import threading
 import time
 from collections import deque
@@ -118,6 +125,231 @@ def named_program(name: str, fn):
 
     program.__name__ = program.__qualname__ = name
     return program
+
+
+# -- the start-up account ---------------------------------------------------
+#
+# What a process spends before it serves, in two halves. PHASES: the wall
+# seconds of each ``startup_phase`` (load, pack, place, cache, engine), a
+# phase's own time only, so nested phases add up to the wall time they
+# cover. PROGRAMS: every program JAX makes, by the name ``named_program``
+# gave it, from ONE pair of ``jax.monitoring`` listeners. What JAX 0.9.0
+# sends (checked against the installed ``jax/_src``: ``pjit.py``,
+# ``interpreters/pxla.py``, ``compiler.py``): the three durations below
+# carry ``fun_name=`` (the bare name for tracing, ``jit(<name>)`` for the
+# other two); a fetch from the persistent cache fires the plain event
+# ``cache_hits`` (no name) on the compiling thread INSIDE that program's
+# ``backend_compile_duration``, whose duration then is the read, not a
+# compile. Tracing, lowering and the compile of one program follow each
+# other on one thread, so what is pending on a thread when a compile ends
+# is that program's. A program traced INSIDE another's tracing (a jitted
+# helper) fires a tracing event of its own and no compile: it is dropped,
+# its seconds being part of the outer program's.
+
+JAX_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+JAX_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+JAX_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+PROGRAM_HOWS = ("compiled", "cache")
+STARTUP_PHASES = ("load", "pack", "place", "cache", "engine")  # as printed
+_JIT_NAME = re.compile(r"^\w+\((.*)\)$")    # ``jit(serve_decode_step)``
+
+
+class _Account:
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.local = threading.local()  # phase stack, pending program
+        self.phases: dict = {}          # name -> own seconds, first-opened
+        self.bytes_placed = 0
+        self.programs: dict = {}        # name -> how -> seconds and count
+        self.listening = False
+        self.closed = False             # the summary is out: log each make
+        self.sinks: list = []           # fn(program, how, seconds)
+
+
+_account = _Account()
+
+
+def _listen() -> None:
+    """Register the ONE pair of listeners, at the account's first use
+    (JAX has no call to take a listener off, so never twice)."""
+    with _account.lock:
+        if _account.listening:
+            return
+        _account.listening = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def _pending() -> dict:
+    p = getattr(_account.local, "pending", None)
+    if p is None:
+        p = _account.local.pending = {"trace": {}, "lower": {}, "hit": False}
+    return p
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == JAX_CACHE_HIT_EVENT:
+        _pending()["hit"] = True
+
+
+def _on_duration(event: str, duration: float, fun_name: str = "",
+                 **_kw) -> None:
+    if event not in (JAX_TRACE_EVENT, JAX_LOWER_EVENT, JAX_COMPILE_EVENT):
+        return
+    m = _JIT_NAME.match(fun_name)
+    name = m.group(1) if m else fun_name
+    p = _pending()
+    if event == JAX_TRACE_EVENT:
+        p["trace"][name] = p["trace"].get(name, 0.0) + duration
+        return
+    if event == JAX_LOWER_EVENT:
+        p["lower"][name] = p["lower"].get(name, 0.0) + duration
+        return
+    how = "cache" if p["hit"] else "compiled"
+    trace_s, lower_s = p["trace"].get(name, 0.0), p["lower"].get(name, 0.0)
+    p["trace"].clear()
+    p["lower"].clear()
+    p["hit"] = False
+    with _account.lock:
+        row = _account.programs.setdefault(name, {}).setdefault(
+            how, {"makes": 0, "trace_s": 0.0, "lower_s": 0.0,
+                  "backend_s": 0.0})
+        row["makes"] += 1
+        row["trace_s"] += trace_s
+        row["lower_s"] += lower_s
+        row["backend_s"] += duration
+        closed, sinks = _account.closed, list(_account.sinks)
+    seconds = trace_s + lower_s + duration
+    for sink in sinks:
+        sink(name, how, seconds)
+    if closed:
+        from .log import log_event
+
+        log_event("program.made",
+                  f"program made: {name} {seconds:.2f} s ({how})",
+                  file=sys.stderr, program=name, seconds=round(seconds, 4),
+                  how=how)
+
+
+@contextlib.contextmanager
+def startup_phase(name: str):
+    """``host_phase("startup." + name)``, with its wall seconds added to
+    the process's start-up account under ``name``: a phase's OWN seconds
+    (less the phases opened inside it), so the account's phases add up to
+    the wall time they cover. It synchronises nothing: a phase measures
+    its call as the call is (a placement that only enqueues reads short).
+    Also usable as a decorator (``Engine.__init__``)."""
+    _listen()
+    stack = getattr(_account.local, "stack", None)
+    if stack is None:
+        stack = _account.local.stack = []
+    with _account.lock:
+        _account.phases.setdefault(name, 0.0)
+    frame = [0.0]                # seconds of the phases opened inside
+    stack.append(frame)
+    t0 = time.perf_counter()
+    try:
+        with host_phase("startup." + name):
+            yield
+    finally:
+        whole = time.perf_counter() - t0
+        stack.pop()
+        if stack:
+            stack[-1][0] += whole
+        with _account.lock:
+            _account.phases[name] += whole - frame[0]
+
+
+def startup_placed(tree) -> None:
+    """``startup.place`` put ``tree``'s arrays on the devices: their bytes
+    go to the account."""
+    import jax
+
+    nbytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
+    with _account.lock:
+        _account.bytes_placed += nbytes
+
+
+def startup_account() -> dict:
+    """The account as a plain dict (``json.dumps`` takes it): ``phases``
+    {name: seconds} in the order first opened, ``bytes_placed``, and
+    ``programs`` {name: {how: {makes, trace_s, lower_s, backend_s}}} with
+    ``how`` one of ``PROGRAM_HOWS``. A copy: the account keeps counting."""
+    with _account.lock:
+        return _snapshot()
+
+
+def _snapshot() -> dict:      # under the account's lock
+    return {"phases": dict(_account.phases),
+            "bytes_placed": _account.bytes_placed,
+            "programs": {n: {h: dict(r) for h, r in hows.items()}
+                         for n, hows in _account.programs.items()}}
+
+
+def program_seconds(row: dict) -> float:
+    """Tracing, lowering and compile or cache read of one account row."""
+    return row["trace_s"] + row["lower_s"] + row["backend_s"]
+
+
+def startup_line(account: dict | None = None) -> str:
+    """The operator's one line: ``startup: load 0.0 pack 6.1 ... | programs
+    7 made, 4.9 s (cache 7, compiled 0): serve_decode_step 1.9, ...``;
+    every program by name, the slowest first, marked ``(compiled)`` where
+    it was not read from the persistent cache; ``place`` with the bytes
+    it put on the devices."""
+    acc = account or startup_account()
+    order = {n: i for i, n in enumerate(STARTUP_PHASES)}
+    phases = " ".join(
+        f"{n} {s:.1f}" + (f" ({acc['bytes_placed'] / 2**30:.2f} GiB)"
+                          if n == "place" else "")
+        for n, s in sorted(acc["phases"].items(),
+                           key=lambda kv: order.get(kv[0], len(order))))
+    count = {h: sum(hows[h]["makes"] for hows in acc["programs"].values()
+                    if h in hows) for h in PROGRAM_HOWS}
+    by_name = sorted(
+        ((sum(program_seconds(r) for r in hows.values()), n,
+          " (compiled)" if "compiled" in hows else "")
+         for n, hows in acc["programs"].items()), reverse=True)
+    names = ", ".join(f"{n} {s:.1f}{how}" for s, n, how in by_name)
+    return (f"startup: {phases or 'no phase'} | programs "
+            f"{sum(count.values())} made, "
+            f"{sum(s for s, _, _ in by_name):.1f} s (cache {count['cache']}, "
+            f"compiled {count['compiled']}){': ' + names if names else ''}")
+
+
+def log_startup() -> dict:
+    """Print ``startup_line`` on stderr (under ``--log-json`` the same
+    fields as one ``startup.summary`` record) and close start-up: every
+    program made from here on is logged as it is made (``program.made``).
+    Returns the account it printed."""
+    from .log import log_event
+
+    acc = startup_account()
+    with _account.lock:
+        _account.closed = True
+    log_event("startup.summary", startup_line(acc), file=sys.stderr, **acc)
+    return acc
+
+
+def on_program_made(sink) -> dict:
+    """Call ``sink(program, how, seconds)`` for every program made from
+    now on (the ``/metrics`` registry's feed; it runs inside JAX's
+    listener and must not raise); ``off_program_made`` takes it away.
+    Returns the account as it stood when the sink went on, so a copy and
+    the feed after it count every program once."""
+    _listen()
+    with _account.lock:
+        _account.sinks.append(sink)
+        return _snapshot()
+
+
+def off_program_made(sink) -> None:
+    with _account.lock:
+        if sink in _account.sinks:
+            _account.sinks.remove(sink)
 
 
 @dataclasses.dataclass(frozen=True)

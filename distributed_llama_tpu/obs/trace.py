@@ -80,7 +80,9 @@ class EngineMetrics:
             "dllama_engine_steps_total", "Device decode steps executed")
         self.compile_events = c(
             "dllama_engine_compile_events_total",
-            "Step-shape cache misses (new fused-chain shapes traced)")
+            "Programs made (compiled, or read from the persistent compile "
+            "cache) since the server started: while serving it should "
+            "stand still (dllama_program_makes_total names them)")
         self.completed = c(
             "dllama_requests_total", "Requests retired normally")
         self.failed = c(
@@ -359,6 +361,7 @@ class EngineMetrics:
         # Σ bytes/chip/step of the bound collective schedule — the
         # ledger's ICI pro-ration numerator (0.0 until bind_collectives)
         self.ici_bytes_per_step = 0.0
+        self._startup_feed = None   # bind_startup's listener, while bound
 
     def bind_kv_pool(self, kv_quant: str, pool_bytes: int,
                      n_pages: int) -> None:
@@ -386,6 +389,55 @@ class EngineMetrics:
             "Logical bytes of ONE physical page across all layers and "
             "tp shards (pool bytes / physical pages)").set(
                 pool_bytes // max(n_pages, 1))
+
+    def bind_startup(self) -> None:
+        """Copy the process's start-up account (obs/spans) into the
+        registry and keep its programs current: the phases as they stood
+        at this call (``dllama_startup_seconds``, by ``phase``), and every
+        program made so far and, through the account's listener, from now
+        on (``dllama_program_makes_total`` and
+        ``dllama_program_make_seconds_total``, by ``program`` and ``how``).
+        Programs made FROM NOW ON also count in
+        ``dllama_engine_compile_events_total``. ``unbind_startup`` ends
+        the feed (``InferenceServer.start`` / ``stop``)."""
+        from .spans import on_program_made, program_seconds
+
+        def made(program: str, how: str, seconds: float,
+                 makes: int = 1) -> None:
+            labels = {"program": program, "how": how}
+            self.registry.labeled_counter(
+                "dllama_program_makes_total", labels,
+                "Programs made, by name (the jitted function's) and by "
+                "how: compiled, or read from the persistent compile "
+                "cache").inc(makes)
+            self.registry.labeled_counter(
+                "dllama_program_make_seconds_total", labels,
+                "Seconds of making them: tracing, lowering and the "
+                "compile or cache read").inc(seconds)
+
+        def feed(program: str, how: str, seconds: float) -> None:
+            made(program, how, seconds)
+            self.compile_events.inc()
+
+        self.unbind_startup()
+        self._startup_feed = feed
+        account = on_program_made(feed)
+        for phase, seconds in account["phases"].items():
+            self.registry.labeled_gauge(
+                "dllama_startup_seconds", {"phase": phase},
+                "Wall seconds of a start-up phase (load, pack, place, "
+                "cache, engine: obs/spans.startup_phase), each less the "
+                "phases inside it").set(seconds)
+        for program, hows in account["programs"].items():
+            for how, row in hows.items():
+                made(program, how, program_seconds(row), row["makes"])
+
+    def unbind_startup(self) -> None:
+        from .spans import off_program_made
+
+        feed, self._startup_feed = self._startup_feed, None
+        if feed is not None:
+            off_program_made(feed)
 
     def set_queue_depth(self, n: int) -> None:
         """Write BOTH queue gauges (legacy + canonical) in one place."""

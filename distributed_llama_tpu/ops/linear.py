@@ -30,6 +30,7 @@ import numpy as np
 from ..io.loader import (Q40Kernel, Q40KernelNb, Q40KernelNbI4, Q40Weight,
                          from_kernel_layout, to_kernel_layout,
                          to_kernel_layout_nb)
+from ..obs.spans import startup_phase
 from .quants import dequantize_q40_jax, dequantize_q80_jax, quantize_q80_jax
 
 RMS_EPS = 1e-5
@@ -265,6 +266,7 @@ def q40_leaf_layout(d: int, nb: int, *, tp: int = 1,
     return "d-major" if kernel_supports(d, nb * 32) else "codec"
 
 
+@startup_phase("pack")
 def pack_q40_params(params: dict, enable: bool | None = None,
                     tp: int = 1, allow_nb_major: bool = False,
                     input_sharded=(),
@@ -283,6 +285,7 @@ def pack_q40_params(params: dict, enable: bool | None = None,
     ``allow_nb_major`` defaults to off: tp == 1 does not imply one chip (an
     sp > 1 mesh packs with tp=1), so the truly-single-chip callers opt in.
     Call this at load time, before device_put; never inside a jitted step.
+    Its seconds are the start-up account's ``pack`` (obs/spans).
     """
     if enable is None:
         enable = q40_kernel_mode() == "pallas"

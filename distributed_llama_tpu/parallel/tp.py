@@ -123,7 +123,7 @@ from ..models.spec import TransformerSpec
 from ..obs.spans import (SCOPE_ATTN, SCOPE_EMBED, SCOPE_FFN, SCOPE_ICI_GATHER,
                          SCOPE_ICI_PPERMUTE, SCOPE_ICI_PSUM,
                          SCOPE_ICI_SCATTER, SCOPE_LAYER, SCOPE_LOGITS,
-                         named_program)
+                         named_program, startup_phase, startup_placed)
 from ..ops.linear import fake_quant_q80, matmul, rmsnorm, silu
 from ..ops.quants import (QK, FloatType, dequantize_q80_jax,
                           quantize_q80_jax)
@@ -289,7 +289,7 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
                     f"{name}: {scheme} tp scheme shards the input dim, but "
                     f"{v.qs.shape[-2]} Q40 blocks do not divide over "
                     f"tp={n_tp} (need input_dim/tp to be a 32-multiple)")
-    params = pack_q40_params(
+    params = pack_q40_params(           # the start-up account's ``pack``
         params, tp=n_tp,
         input_sharded=(FUSED_INPUT_SHARDED
                        if scheme in _INPUT_SHARDED_SCHEMES else ()))
@@ -342,8 +342,13 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
             np.shape(a), NamedSharding(mesh, s),
             lambda idx, a=a: cut(a, idx))
 
-    with concurrent.futures.ThreadPoolExecutor(threads) as pool:
-        return jax.tree_util.tree_map(put, params, specs)
+    # ``make_array_from_callback`` cuts each shard on the host before it
+    # returns: ``place`` holds the copies, the transfers are enqueued
+    with startup_phase("place"), \
+            concurrent.futures.ThreadPoolExecutor(threads) as pool:
+        placed = jax.tree_util.tree_map(put, params, specs)
+    startup_placed(placed)
+    return placed
 
 
 def shard_cache(cache: KVCache, mesh: Mesh) -> KVCache:

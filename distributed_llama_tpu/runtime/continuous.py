@@ -37,7 +37,7 @@ from ..io.tokenizer import BOS
 from ..models.spec import TransformerSpec
 from ..obs import tracectx
 from ..obs.ledger import CensusRing, LedgerBook
-from ..obs.spans import host_phase, named_program
+from ..obs.spans import host_phase, named_program, startup_phase
 from .sampling import Sampler
 
 
@@ -715,6 +715,7 @@ class ContinuousEngine:
     any number of requests stream through the pool.
     """
 
+    @startup_phase("engine")   # less the pack, place and cache inside it
     def __init__(self, spec: TransformerSpec, params: dict[str, Any],
                  slots: int, temperature: float, topp: float, seed: int,
                  cache_dtype=None, mesh=None, prefill_chunk: int = 0,
@@ -968,15 +969,17 @@ class ContinuousEngine:
                         lambda: make_sharded_mixed(
                             spec, mesh, page_size, scheme=scheme,
                             kv_quant=kv_quant))
-                self.cache = shard_cache_paged(
-                    init_cache_paged_q8(spec, self._alloc.n_pages + 1,
-                                        page_size)
-                    if kv_quant == "q8" else
-                    init_cache_paged(spec, self._alloc.n_pages + 1,
-                                     page_size, dtype), mesh)
+                with startup_phase("cache"):
+                    self.cache = shard_cache_paged(
+                        init_cache_paged_q8(spec, self._alloc.n_pages + 1,
+                                            page_size)
+                        if kv_quant == "q8" else
+                        init_cache_paged(spec, self._alloc.n_pages + 1,
+                                         page_size, dtype), mesh)
             else:
-                self.cache = shard_cache_batch(
-                    init_cache_batch(spec, slots, dtype), mesh)
+                with startup_phase("cache"):
+                    self.cache = shard_cache_batch(
+                        init_cache_batch(spec, slots, dtype), mesh)
                 self._step = _shared_program(
                     ("sh_step_batch", spec, mesh, scheme),
                     lambda: make_sharded_forward_batch(spec, mesh,
@@ -1004,12 +1007,14 @@ class ContinuousEngine:
                 params, layout=self.q40_layout,
                 spec=spec if spec.latent else None)
             if self._alloc is not None:
-                self.cache = (
-                    init_cache_paged_q8(spec, self._alloc.n_pages + 1,
-                                        page_size)
-                    if kv_quant == "q8" else
-                    init_cache_paged(spec, self._alloc.n_pages + 1,
-                                     page_size, dtype, slots=slots))
+                # pages and, a slotted spec, the rows' rings and states
+                with startup_phase("cache"):
+                    self.cache = (
+                        init_cache_paged_q8(spec, self._alloc.n_pages + 1,
+                                            page_size)
+                        if kv_quant == "q8" else
+                        init_cache_paged(spec, self._alloc.n_pages + 1,
+                                         page_size, dtype, slots=slots))
                 self._step = _shared_program(
                     ("step_paged", spec, page_size, kv_quant),
                     lambda: jax.jit(
@@ -1051,7 +1056,8 @@ class ContinuousEngine:
                                               kv_quant=kv_quant),
                             donate_argnums=1))
             else:
-                self.cache = init_cache_batch(spec, slots, dtype)
+                with startup_phase("cache"):
+                    self.cache = init_cache_batch(spec, slots, dtype)
                 self._step = _shared_program(
                     ("step_ragged", spec),
                     lambda: jax.jit(
@@ -1403,8 +1409,6 @@ class ContinuousEngine:
         key = (k, greedy_only)
         if key in self._chains:
             return self._chains[key]
-        if self._obs is not None:  # step-shape cache miss: a new trace
-            self._obs.compile_events.inc()
 
         from .decode import sample_device_dynamic
 
@@ -1472,8 +1476,6 @@ class ContinuousEngine:
         key = ("spec", greedy_only)
         if key in self._chains:
             return self._chains[key]
-        if self._obs is not None:  # verify-shape cache miss: a new trace
-            self._obs.compile_events.inc()
         base = self._verify_base
 
         from .decode import greedy_verify_tokens
@@ -1502,8 +1504,6 @@ class ContinuousEngine:
         key = ("mixed", greedy_only)
         if key in self._chains:
             return self._chains[key]
-        if self._obs is not None:  # mixed-shape cache miss: a new trace
-            self._obs.compile_events.inc()
         base = self._mixed_base
 
         from .decode import greedy_verify_tokens

@@ -41,7 +41,7 @@ from ..models.llama import forward, init_cache
 from ..models.spec import TransformerSpec
 from ..obs.log import log_event
 from ..obs.metrics import summarize_values
-from ..obs.spans import host_phase, named_program
+from ..obs.spans import host_phase, named_program, startup_phase
 from ..parallel.comm_stats import (CommStats, ici_all_gather_bytes,
                                    sp_lse_bytes, tp_scheme)
 from .sampling import Sampler
@@ -77,6 +77,7 @@ def _with_pick(step):
 class Engine:
     """Owns params + cache + the jitted step; exposes infer(token, pos)."""
 
+    @startup_phase("engine")   # less the pack, place and cache inside it
     def __init__(self, spec: TransformerSpec, params: dict[str, Any],
                  mesh=None, cache_dtype=None, fast_prefill: bool = False,
                  q40_layout=None):
@@ -128,7 +129,9 @@ class Engine:
             tok_sharding = jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
             self.params = shard_params(params, mesh, scheme=self.tp_scheme)
-            self.cache = shard_cache(init_cache(spec, self.cache_dtype), mesh)
+            with startup_phase("cache"):
+                self.cache = shard_cache(init_cache(spec, self.cache_dtype),
+                                         mesh)
             # shard_map wrapper under a jit of its own; traceable in scan
             # and inside the step program below
             step = self._step_raw = make_sharded_forward(
@@ -138,7 +141,8 @@ class Engine:
 
             self.params = params_to_device(params, spec=spec,
                                            layout=self.q40_layout)
-            self.cache = init_cache(spec, self.cache_dtype)
+            with startup_phase("cache"):
+                self.cache = init_cache(spec, self.cache_dtype)
             self._step_raw = functools.partial(forward, spec)
             # an expert spec's step also hands out the (L, E) count of rows
             # routed to each expert (``infer`` fetches it with the token);
@@ -453,6 +457,10 @@ class GenStats:
     moe_active: int = 0  # pairs / distinct experts, summed over layers+steps
     ahead_used: int = 0     # temperature 0: steps found already in flight
     ahead_dropped: int = 0  # steps enqueued ahead and thrown away (BOS stop)
+    # ``generate``'s entry to the first SAMPLED token's emit (tokenizer,
+    # prefill, the echo and the first step): the one-shot CLI's TTFT.
+    # None where no token was sampled
+    first_token_ms: float | None = None
 
     @property
     def avg(self) -> tuple[float, float, float]:
@@ -485,12 +493,13 @@ def _prefill_prefix(engine: Engine, prompt_tokens: list[int], steps: int,
         return None
     engine.prefill(prompt_tokens[:n_pre], 0, chunk)
     prev = prompt_tokens[0]
-    for t in prompt_tokens[1:n_pre + 1]:
-        out_tokens.append(t)
-        if emit is not None:
-            piece = tokenizer.decode_piece(prev, t)
-            emit(piece.decode("utf-8", errors="replace"))
-        prev = t
+    with host_phase("inference.echo"):   # while the chunks run
+        for t in prompt_tokens[1:n_pre + 1]:
+            out_tokens.append(t)
+            if emit is not None:
+                piece = tokenizer.decode_piece(prev, t)
+                emit(piece.decode("utf-8", errors="replace"))
+            prev = t
     return n_pre
 
 
@@ -519,6 +528,7 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
     stats lines (those positions never run the loop; stats cover the decode
     phase).
     """
+    t_entry = time.perf_counter()
     spec = engine.spec
     out_tokens: list[int] = []
     if resume is not None:
@@ -529,7 +539,9 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
         steps = min(start_pos + steps, spec.seq_len)
     else:
         start_pos, steps = 0, min(steps, spec.seq_len)
-        prompt_tokens = tokenizer.encode(prompt or "", bos=True, eos=False)
+        with host_phase("inference.encode"):
+            prompt_tokens = tokenizer.encode(prompt or "", bos=True,
+                                             eos=False)
         if not prompt_tokens:
             raise ValueError(
                 "something is wrong, expected at least 1 prompt token")
@@ -584,6 +596,8 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
             piece = tokenizer.decode_piece(token, next_token)
             if emit is not None:
                 emit(piece.decode("utf-8", errors="replace"))
+            if not forced and stats.first_token_ms is None:
+                stats.first_token_ms = (time.perf_counter() - t_entry) * 1e3
             if not quiet:
                 # the 🔶 reference stats line, or one NDJSON object per token
                 # with the same fields under DLLAMA_LOG_JSON=1 (obs/log.py)
@@ -623,6 +637,9 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                   f"R {comm.recv_bytes / 1024:.0f} kB /token")
             print(f"Steps run ahead:     {stats.ahead_used} used, "
                   f"{stats.ahead_dropped} dropped")
+            if stats.first_token_ms is not None:
+                print(f"First sampled token: {stats.first_token_ms:.2f} ms "
+                      f"after the call (tokenizer, prefill, first step)")
             if stats.moe_active:
                 print(f"Routed experts:      "
                       f"{stats.moe_pairs / stats.moe_active:.2f} rows per "
@@ -634,7 +651,9 @@ def generate(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
                   sent_bytes_per_token=comm.sent_bytes,
                   recv_bytes_per_token=comm.recv_bytes,
                   ahead_used=stats.ahead_used,
-                  ahead_dropped=stats.ahead_dropped)
+                  ahead_dropped=stats.ahead_dropped,
+                  first_token_ms=(None if stats.first_token_ms is None
+                                  else round(stats.first_token_ms, 3)))
     return out_tokens, stats
 
 
@@ -666,8 +685,9 @@ def generate_batch(spec: TransformerSpec, params: dict[str, Any],
     B = len(prompts)
     steps = min(steps, spec.seq_len)
     dtype = cache_dtype or jnp.float32
-    toks_per_row = [tokenizer.encode(p or "", bos=True, eos=False)
-                    for p in prompts]
+    with host_phase("inference.encode"):
+        toks_per_row = [tokenizer.encode(p or "", bos=True, eos=False)
+                        for p in prompts]
     padded = np.full((B, steps + 1), -1, dtype=np.int32)
     coins = np.zeros((B, steps), dtype=np.float32)
     for b, pt in enumerate(toks_per_row):
@@ -762,7 +782,9 @@ def generate_fast(engine: Engine, tokenizer: Tokenizer, sampler: Sampler,
     else:
         start_pos = 0
         steps = min(steps, spec.seq_len)
-        prompt_tokens = tokenizer.encode(prompt or "", bos=True, eos=False)
+        with host_phase("inference.encode"):
+            prompt_tokens = tokenizer.encode(prompt or "", bos=True,
+                                             eos=False)
         if not prompt_tokens:
             raise ValueError(
                 "something is wrong, expected at least 1 prompt token")

@@ -1154,6 +1154,14 @@ class InferenceServer:
             t = threading.Thread(target=target, daemon=True)
             t.start()
             self._threads.append(t)
+        # where start-up went, by phase and by program made (stderr; one
+        # ``startup.summary`` record under --log-json), the same on
+        # /metrics, and from here on every program made is logged by name
+        from ..obs.spans import log_startup
+
+        log_startup()
+        if self.engine._obs is not None:
+            self.engine._obs.bind_startup()
         self.health.to("serving")
 
     def serve_forever(self):
@@ -1240,6 +1248,8 @@ class InferenceServer:
             return
         self._stopped.set()
         self._shutdown.set()
+        if self.engine._obs is not None:
+            self.engine._obs.unbind_startup()
         # park the watch loop FIRST: a watch tick mid-teardown would
         # scrape a half-closed engine (the event also bounds the
         # _watch_loop thread's lifetime — threadmodel's joined_by)

@@ -401,19 +401,30 @@ def test_server_health_reports_spec_accept_rate(params):
         srv.stop()
 
 
-def test_engine_compile_event_counter(params):
-    """Fused-chain shape-cache misses count as compile events; reusing a
-    chain shape does not."""
+def test_engine_compile_event_counter():
+    """Programs made while the registry is bound to the start-up account
+    (obs/spans; ``InferenceServer.start`` binds it) count as compile
+    events: a new fused-chain shape makes one, reusing it makes none."""
     from distributed_llama_tpu.runtime.continuous import ContinuousEngine
 
+    # a width of its own: no other test has made this engine's programs
+    spec = TransformerSpec(dim=64, hidden_dim=160, n_layers=2, n_heads=4,
+                           n_kv_heads=2, vocab_size=152, seq_len=16)
     reg = Registry()
-    eng = ContinuousEngine(SPEC, params, slots=2, temperature=0.0,
-                           topp=0.9, seed=5, block_steps=3, metrics=reg)
-    eng.run([[1, 5]], steps=6)
-    first = reg.get("dllama_engine_compile_events_total").value
-    assert first >= 1
-    eng.run([[1, 7]], steps=6)  # same chain shape: no new trace
-    assert reg.get("dllama_engine_compile_events_total").value == first
+    eng = ContinuousEngine(spec, synth_params(spec, q40=False, seed=4,
+                                              scale=0.3),
+                           slots=2, temperature=0.0, topp=0.9, seed=5,
+                           block_steps=3, metrics=reg)
+    assert reg.get("dllama_engine_compile_events_total").value == 0
+    eng._obs.bind_startup()
+    try:
+        eng.run([[1, 5]], steps=6)
+        first = reg.get("dllama_engine_compile_events_total").value
+        assert first >= 1
+        eng.run([[1, 7]], steps=6)  # same chain shape: no program made
+        assert reg.get("dllama_engine_compile_events_total").value == first
+    finally:
+        eng._obs.unbind_startup()
 
 
 # ---------------------------------------------------- server round-trip
