@@ -164,7 +164,7 @@ def _load_one_chip(model: str, wft, bft, rows: int):
     if wft == FloatType.Q40:
         spec = read_spec(model, weights_float_type=wft)
         layout = q40_body_policy(spec, rows)
-        announce_q40_layout(layout, spec)
+        announce_q40_layout(layout, spec, rows)
     spec, params = load_model_packed(model, weights_float_type=wft,
                                      buffer_float_type=bft, layout=layout)
     return spec, params, layout

@@ -489,6 +489,16 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
         f"i4 headroom gate"))
 
 
+def _dense_matmul_shapes(spec) -> list[tuple[int, int]]:
+    """(d, n) of a one-chip model's dense matmul tensors: a layer's as the
+    file has them (fusing along ``d`` changes no block count), a leading
+    dense layer's, and the classifier; routed experts run the expert
+    kernels."""
+    return [shape for _, shape in (spec.layer_matmul_shapes()
+                                   + spec.dense_layer_matmul_shapes())
+            ] + [(spec.vocab_size, spec.dim)]
+
+
 def t1_bodies(spec, layout: Q40Layout) -> str:
     """``t1 mxu M/N``: of a one-chip model's N dense matmul tensors (a
     layer's as the file has them, which fusing along ``d`` does not change,
@@ -499,30 +509,53 @@ def t1_bodies(spec, layout: Q40Layout) -> str:
     Static, like the body itself: shapes decide at trace time."""
     from .pallas_q40 import _t1_mxu
 
-    shapes = [shape for _, shape in (spec.layer_matmul_shapes()
-                                     + spec.dense_layer_matmul_shapes())]
-    shapes.append((spec.vocab_size, spec.dim))
+    shapes = _dense_matmul_shapes(spec)
     mxu = sum(q40_leaf_layout(d, n // 32, layout=layout) == "nb-major"
               and _t1_mxu(n // 32) for d, n in shapes)
     return f"t1 mxu {mxu}/{len(shapes)}"
 
 
-def announce_q40_layout(layout: Q40Layout, spec=None) -> None:
+def tile_planes(spec, layout: Q40Layout, rows: int) -> str:
+    """``tile planes-a-dot: 8 x 7 leaves, 4 x 1, 2 x 1, 1 x 0``: of the dense
+    matmul tensors ``t1_bodies`` counts, those that pack nb-major, by how
+    many nibble planes one dot of the T > 1 MXU tile contracts over at a
+    decode dispatch of ``rows`` rows (ops/pallas_q40._pick_planes: a pure
+    function of the leaf's blocks a row and the rows of a t-tile), most
+    planes first and a dot a plane always listed. Static, like the tile
+    itself: shapes decide at trace time."""
+    from .pallas_q40 import _PLANES, _pick_block_t, _pick_planes
+
+    t = -(-rows // 8) * 8            # a dispatch pads to whole sublane tiles
+    count = dict.fromkeys(_PLANES, 0)
+    for d, n in _dense_matmul_shapes(spec):
+        if q40_leaf_layout(d, n // 32, layout=layout) == "nb-major":
+            count[_pick_planes(n // 32, _pick_block_t(t, n // 32))] += 1
+    parts = [f"{g} x {c}" for g, c in sorted(count.items(), reverse=True)
+             if c or g == 1]
+    return "tile planes-a-dot: " + ", ".join(
+        [parts[0] + " leaves", *parts[1:]])
+
+
+def announce_q40_layout(layout: Q40Layout, spec=None, rows: int = 1) -> None:
     """The record of a pick: one stderr line, printed unconditionally even
     for quiet callers (a silent layout change would make runs
     incomparable), and the label on every log record's run stamp. With the
-    ``spec`` both also carry ``t1_bodies``' count (where the Pallas kernels
-    run at all)."""
+    ``spec`` both also carry ``t1_bodies``' count and, for a decode dispatch
+    of ``rows`` > 1 rows, ``tile_planes``' histogram (where the Pallas
+    kernels run at all)."""
     import sys
 
     from ..utils.fingerprint import stamp_q40_body
 
-    t1 = (t1_bodies(spec, layout)
-          if spec is not None and q40_kernel_mode() == "pallas" else "")
-    stamp_q40_body(f"{layout.label} {t1}".rstrip())
+    bodies = ""
+    if spec is not None and q40_kernel_mode() == "pallas":
+        bodies = t1_bodies(spec, layout)
+        if rows > 1:
+            bodies += "; " + tile_planes(spec, layout, rows)
+    stamp_q40_body(f"{layout.label} {bodies}".rstrip())
     print(f"💡 Q40 body policy: {layout.label} ({layout.reason}; "
-          f"{t1 and t1 + '; '}the i4 body engages on fused decode chains)",
-          file=sys.stderr)
+          f"{bodies and bodies + '; '}the i4 body engages on fused decode "
+          f"chains)", file=sys.stderr)
 
 
 # What apply_q40_body_policy last resolved, for packers that are handed no
@@ -542,7 +575,7 @@ def apply_q40_body_policy(spec, rows: int = 1) -> str:
     global _APPLIED_LAYOUT
 
     _APPLIED_LAYOUT = layout = q40_body_policy(spec, rows)
-    announce_q40_layout(layout, spec)
+    announce_q40_layout(layout, spec, rows)
     return layout.label
 
 

@@ -50,8 +50,10 @@ times the routed work of those two chunks.
 The slot's LIVE ROW COUNT (a fourth prefetched list, ``fill``) alone picks
 its body: a slot of one row takes ``_row_body`` (the nb-major matvec's
 arithmetic, 8 vector operations a packed byte; at T == 1 the only body),
-any other the MXU tile ``_mxu_body_merged`` (the body of every dense leaf at
-T > 1) over the smallest of 8 / 16 / 32 / C rows that holds it
+any other the MXU tile ``_mxu_body_merged`` (the dense T > 1 tile's
+arithmetic, ``ops/pallas_q40._planes_dot``, with all 16 nibble planes in one
+contraction; a dense leaf merges as many as its block count asks for, PR 51)
+over the smallest of 8 / 16 / 32 / C rows that holds it
 (``_tile_rows``: a part-filled slot of a wide dispatch pays for its rows,
 not for C). On a v5e the tile streams an expert at 370-390 GB/s at 8 rows
 (270-290 until PR 38) and the one-row body at 520-610 (PERF.md section 7,
@@ -79,7 +81,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
 from .linear import StackedQ40, matmul, matmul_mode, silu
 from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ,
-                         _five_pass_dot, _mask_pieces)
+                         _mask_pieces, _planes_dot)
 
 MOE_SLOT_ROWS = 8                # rows of a narrow dispatch's slot: one sublane tile
 MOE_SLOT_T_MAX = 32              # widest dispatch whose slots are one such tile
@@ -328,37 +330,27 @@ def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
 # -- the MXU tile ---------------------------------------------------------------
 
 def _mxu_body_merged(qs_ref, s, xlo_ref, xhi_ref, out_ref, bf16: bool):
-    """``_matmul_body_nb`` (ops/pallas_q40: dequantize the tile to float32,
-    exact, and multiply by ``_five_pass_dot``: the weight as its TWO bf16
-    pieces against the rows' three, five single bf16 passes; one piece a
-    side, one pass, under fast-prefill's ``bf16``) with the 16 nibble planes
-    MERGED into the contraction: qs_ref (NJ, nb, R) codes, s (nb, R) scales,
-    xlo/xhi (bt, NJ * nb) float32 with value j of block b at column
-    j * nb + b; out (bt, R). The 2-D kernels contract one plane at a time
-    over nb, which is 64 or 32 for an expert: half or a quarter of an MXU
-    pass's rows, 32 passes a tile. One (3 bt, NJ * nb) x (NJ * nb, R) dot
-    per nibble half and weight piece fills them (measured on OLMoE's chunk:
-    PERF.md section 6, PR 26). The rows are split HERE, once a row tile: a
-    slot has one to eight of them, and three pieces a slot gathered outside
-    cost XLA more than these four operations a value (PERF.md section 7,
-    PR 38)."""
-    nj, nb, r = qs_ref.shape
-    wdt = jnp.bfloat16 if bf16 else jnp.float32
-    q = qs_ref[...].astype(jnp.int32)                # (NJ, nb, R)
-    dn = (((1,), (0,)), ((), ()))
-    acc = None
-    for x_ref, codes in ((xlo_ref, q & 0xF), (xhi_ref, q >> 4)):
-        w = ((codes - 8).astype(jnp.float32) * s[None]).astype(wdt)
-        if bf16:
-            a = jax.lax.dot_general(x_ref[...].astype(wdt),
-                                    w.reshape(nj * nb, r), dn,
-                                    preferred_element_type=jnp.float32)
-        else:
-            w = w.reshape(nj * nb, r)
-            x3 = jnp.concatenate(_mask_pieces(x_ref[...], 3), axis=0)
-            a = _five_pass_dot(x3, w, out_ref.shape[0])
-        acc = a if acc is None else acc + a
-    out_ref[...] = acc
+    """The dense tile's arithmetic (ops/pallas_q40._planes_dot: dequantize
+    the tile to float32, exact, and multiply by ``_five_pass_dot``: the
+    weight as its TWO bf16 pieces against the rows' three, five single bf16
+    passes; one piece a side, one pass, under fast-prefill's ``bf16``) with
+    ALL 16 nibble planes merged into the contraction: qs_ref (NJ, nb, R)
+    codes, s (nb, R) scales, xlo/xhi (bt, NJ * nb) float32 with value j of
+    block b at column j * nb + b; out (bt, R). A dot a plane contracts over
+    nb, which is 64 or 32 for an expert: half or a quarter of an MXU pass's
+    rows, 32 passes a tile. One (3 bt, NJ * nb) x (NJ * nb, R) dot per
+    nibble half and weight piece fills them (measured on OLMoE's chunk:
+    PERF.md section 6, PR 26; since PR 51 a dense leaf merges as many planes
+    as ``ops/pallas_q40._pick_planes`` says for its block count and rows).
+    The rows are split HERE, once a row tile: a slot has one to eight of
+    them, and three pieces a slot gathered outside cost XLA more than these
+    four operations a value (PERF.md section 7, PR 38); a dense leaf's are
+    split once a call outside."""
+    xs = [x_ref[...] if bf16 else
+          jnp.concatenate(_mask_pieces(x_ref[...], 3), axis=0)
+          for x_ref in (xlo_ref, xhi_ref)]
+    out_ref[...] = _planes_dot(qs_ref[...].astype(jnp.int32), s, *xs,
+                               out_ref.shape[0], bf16)
 
 
 def _merged_planes(x: jax.Array, nb: int):

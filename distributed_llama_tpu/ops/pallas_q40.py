@@ -43,7 +43,11 @@ DMAs on Mistral-7B's ``w13`` and 9 to 19 % over them on a layer's other
 leaves, 72 % of the HBM roofline over the step where the vector body read
 63), a wider one
 dequantizes the tile and multiplies its two pieces by the rows' three
-(``_five_pass_dot``, PR 38). ``q40_matmul``'s docstring has the dispatch.
+(``_five_pass_dot``, PR 38), G nibble planes to a dot so that the
+contraction is whole 128-deep MXU pushes (``_pick_planes``, PR 51: G from
+the leaf's blocks a row and the rows of a t-tile; a dot a plane where a
+plane is whole pushes already or the tile is bound by the unpack).
+``q40_matmul``'s docstring has the dispatch.
 """
 
 from __future__ import annotations
@@ -386,34 +390,54 @@ def _five_pass_dot(x3: jax.Array, w: jax.Array, rows: int) -> jax.Array:
             + (a[rows:2 * rows] + b[:rows])) + a[:rows]
 
 
+def _planes_dot(q, s, xlo, xhi, rows: int, bf16: bool) -> jax.Array:
+    """(rows, R) float32: G nibble planes of a tile against their columns of
+    the rows, ONE contraction G nb deep a nibble half. ``q`` (G, nb, R) int32
+    holds the planes' bytes, ``s`` (nb, R) the scales; ``xlo`` / ``xhi``
+    (.., G nb) the rows' values under the low / high nibbles, value j of
+    block b at column j nb + b (``_merged_planes``). The planes are
+    dequantized to float32 (exact) and viewed (G nb, R), a renumbering of
+    whole sublane tiles where nb is a multiple of 8. Parity: ``xlo`` / ``xhi``
+    hold the rows' [hi; mid; lo] pieces and each half is ``_five_pass_dot``;
+    ``bf16`` (fast-prefill): (rows, G nb), one piece a side, one pass. The
+    dense tile calls it once a group of planes (``_matmul_body_nb``), the
+    expert slots' once a tile (ops/pallas_moe._mxu_body_merged)."""
+    g, nb, r = q.shape
+    dn = (((1,), (0,)), ((), ()))
+    acc = None
+    for x, codes in ((xlo, q & 0xF), (xhi, q >> 4)):
+        w = ((codes - 8).astype(jnp.float32) * s[None]).reshape(g * nb, r)
+        if bf16:
+            a = jax.lax.dot_general(x.astype(jnp.bfloat16),
+                                    w.astype(jnp.bfloat16), dn,
+                                    preferred_element_type=jnp.float32)
+        else:
+            a = _five_pass_dot(x, w, rows)
+        acc = a if acc is None else acc + a
+    return acc
+
+
 def _matmul_body_nb(qs3, s, xlo_ref, xhi_ref, out_ref, bf16=False):
     """T>1 MXU body, nb-major: qs3 (NJ, nb, R), s (nb, R); out (bt, R). The
-    contraction is a STANDARD (M,K)x(K,N) dot (x rows x nb against weights
-    nb x R), one per nibble plane. Parity mode (``bf16`` False): xlo/xhi
-    (NJ, S, nb) bfloat16 hold the rows' three pieces stacked
-    (``_stack_pieces``) and each plane is ``_five_pass_dot``: the tile is
-    dequantized to float32 (exact), split in TWO bf16 pieces (exact: a Q40
-    weight has 15 bits) and multiplied in five single bf16 passes, as close
-    to float64 as HIGHEST's six and without its three-way split of the
-    weight. ``bf16`` (fast-prefill): xlo/xhi (NJ, bt, nb) float32, one
-    piece a side, one pass. Its cost hardly depends on bt under 128 rows
-    (the unpack and the MXU's weight loads do not; PERF.md section 7)."""
-    dn = (((1,), (0,)), ((), ()))
+    contraction is a STANDARD (M,K)x(K,N) dot (x rows against weights K x R)
+    over G nibble planes at a time, K = G nb (``_planes_dot``), NJ / G
+    groups a nibble half; G is read off the planes, xlo/xhi (NJ / G, .,
+    G nb), which ``_mxu_nb_planes`` lays as ``_pick_planes`` says. G = 1 is
+    a dot a plane (every leaf until PR 51), G = 16 the expert slots' form.
+    Parity mode (``bf16`` False): xlo/xhi (NJ / G, S, G nb) bfloat16 hold
+    the rows' three pieces stacked (``_stack_pieces``) and each group is
+    ``_five_pass_dot``: the planes are dequantized to float32 (exact), split
+    in TWO bf16 pieces (exact: a Q40 weight has 15 bits) and multiplied in
+    five single bf16 passes, as close to float64 as HIGHEST's six and
+    without its three-way split of the weight. ``bf16`` (fast-prefill):
+    xlo/xhi (NJ / G, bt, G nb) float32, one piece a side, one pass, the
+    same G. PERF.md section 7 has what a deeper contraction buys by shape."""
     bt = out_ref.shape[0]
-    wdt = jnp.bfloat16 if bf16 else jnp.float32
-
-    def dot(x, w):
-        if not bf16:
-            return _five_pass_dot(x, w, bt)
-        return jax.lax.dot_general(x.astype(wdt), w, dn,
-                                   preferred_element_type=jnp.float32)
-
+    g = NJ // xlo_ref.shape[0]
     acc = None
-    for j in range(NJ):
-        q = qs3[j].astype(jnp.int32)                 # (nb, R)
-        wlo = (((q & 0xF) - 8).astype(jnp.float32) * s).astype(wdt)
-        whi = (((q >> 4) - 8).astype(jnp.float32) * s).astype(wdt)
-        a = dot(xlo_ref[j], wlo) + dot(xhi_ref[j], whi)
+    for k in range(NJ // g):
+        q = qs3[k * g:(k + 1) * g].astype(jnp.int32)     # (G, nb, R)
+        a = _planes_dot(q, s, xlo_ref[k], xhi_ref[k], bt, bf16)
         acc = a if acc is None else acc + a
     out_ref[...] = acc
 
@@ -946,71 +970,147 @@ def _q40_matvec_nb_stacked(layer, qs_t, scale, x, *, block_rows, interpret):
     return _matvec_nb_call(layer, qs_t, scale, x, block_rows, interpret)
 
 
-def _mxu_nb_planes(x, nb: int, block_t: int, bf16: bool):
-    """The nb-major MXU body's x planes and the rows of one t-tile in them:
-    (NJ, t, nb) float32 under ``bf16`` (fast-prefill: one piece, cast in the
-    kernel), else the three bf16 pieces of every row, stacked a t-tile
-    (``_stack_pieces``), split ONCE a call here and not once a row tile in
-    the kernel."""
-    xlo, xhi = _split_x(x.astype(jnp.float32), nb)   # (NJ, t, nb) — natural
+# Nibble planes one dot of the T > 1 tile may contract over: divisors of NJ.
+_PLANES = (1, 2, 4, 8, 16)
+
+# Most float32 words one merged group's planes may hold, G nb R: the expert
+# slots' measured boundary at 32 rows a slot (ops/pallas_moe._slot_block_rows:
+# all 16 planes of rows x nb = _MATMUL_ROWSXNB_CAP / 2 words). No leaf of
+# the nine benchmark configurations comes within a quarter of it at today's
+# row tiles; the chip's compiler wants 1 to 7 MiB of scoped VMEM for them.
+_MERGED_WORDS_CAP = NJ * _MATMUL_ROWSXNB_CAP // 2
+
+
+def _pick_planes(nb: int, block_t: int) -> int:
+    """Nibble planes G one dot of the T > 1 nb-major tile contracts over
+    (``_matmul_body_nb``), from what a call observes: the leaf's blocks a
+    row and the rows of a t-tile. Nothing else picks.
+
+    A dot a plane (G = 1) contracts ``nb`` deep, and the MXU pays whole
+    128-deep pushes: a plane of 80 blocks pushes 1.6 times its weights, one
+    of 16 eight times. The candidate is the SMALLEST G that makes ``G nb``
+    a whole number of pushes (1 where nb is on the 128 grid, 16 at nb 24 or
+    56): on the chip it won or tied at every (leaf, rows) read, and more
+    planes a dot than that read level or up to 3 % behind, with G times the
+    float32 planes live. It is taken
+
+    * at a t-tile of 32 rows or more, where the MXU's pushes show: x 1.06
+      to 1.38 at 32 rows on nb 80 / 160 / 192 / 224 / 288 / 320 / 448 / 544
+      / 576 (nb 112: level), x 1.8 to 4.5 up to 64; x 1.1 to 1.6 and x 2 to
+      7 at a chunk's 128, by the padding a plane had;
+    * under 32 rows only where a plane fills at most HALF a push (nb up to
+      64: x 1.0 to 1.6 at 8 rows): there the tile is bound by the unpack,
+      which merging does not touch, and on every wider leaf a deeper
+      contraction read 1 to 8 % SLOWER at 8 and 16 rows.
+
+    A block count off the 8 grid (no model's) keeps G = 1: the merged view
+    renumbers whole sublane tiles. So does a leaf whose merged group would
+    not fit a 256-row tile under ``_MERGED_WORDS_CAP`` (344 blocks a row, G
+    16: no benchmark leaf): a smaller row tile was not measured against the
+    deeper dot. PERF.md section 7 has the table."""
+    if nb % 8:
+        return 1
+    fill = next(g for g in _PLANES if g * nb % 128 == 0)
+    if block_t < 32 and 2 * nb > 128:
+        return 1
+    return fill if fill * nb * 256 <= _MERGED_WORDS_CAP else 1
+
+
+def _pick_rows_mxu(d: int, nb: int, block_t: int, planes: int) -> int | None:
+    """Row tile of the T > 1 nb-major tile: rows ride the lanes (a multiple
+    of 128 dividing ``d``, else None and the caller dequantizes and dots).
+    The body's float32 temporaries obey the measured rows x nb boundary of
+    the d-major path (``_MATMUL_ROWSXNB_CAP``; ``_pick_rows_nb``'s matvec
+    budget is looser), and at a t-tile short of the full 128 rows Mosaic
+    keeps more of them live: 256 rows at most there (see
+    ``_pick_block_rows``; on the chip 512 rows read 8 to 13 % slower than
+    256 at 8 and 32 rows on 128 blocks a row). A merged group of ``planes``
+    planes keeps its words under ``_MERGED_WORDS_CAP`` too, which no leaf
+    ``_pick_planes`` merges comes near at these tiles."""
+    rows = _pick_rows_nb(d, nb)
+    if rows is None:
+        return None
+    cap = min(_MATMUL_ROWSXNB_CAP // nb, _MERGED_WORDS_CAP // (planes * nb))
+    if block_t < 128:
+        cap = min(cap, 256)
+    return next((r for r in range(min(rows, cap - cap % 128), 0, -128)
+                 if d % r == 0), None)
+
+
+def _mxu_nb_planes(x, nb: int, block_t: int, bf16: bool, planes: int = 1):
+    """The nb-major MXU body's x planes and the rows of one t-tile in them,
+    ``planes`` (G) nibble planes merged into a dot's contraction:
+    (NJ / G, t, G nb) float32 under ``bf16`` (fast-prefill: one piece, cast
+    in the kernel), value j' of a group's block b at column j' nb + b
+    (ops/pallas_moe._merged_planes' order within the group), else the three
+    bf16 pieces of every row, stacked a t-tile (``_stack_pieces``), split
+    ONCE a call here and not once a row tile in the kernel. The same bytes
+    at every G; in VMEM fewer the larger G where nb is under 128, since a
+    block pads its minor dim to 128 lanes."""
+    x = x.astype(jnp.float32)
+    if planes == 1:
+        xlo, xhi = _split_x(x, nb)                   # (NJ, t, nb) — natural
+    else:
+        # ONE transpose from the rows: cutting the planes first and merging
+        # them after cost XLA up to 50 us a call more (PERF.md section 7)
+        t, groups = x.shape[0], NJ // planes
+        x5 = x.reshape(t, nb, 2, groups, planes)
+        xlo, xhi = (jnp.transpose(x5[:, :, half], (2, 0, 3, 1))
+                    .reshape(groups, t, planes * nb) for half in (0, 1))
     if bf16:
         return xlo, xhi, block_t
     return (_stack_pieces(xlo, block_t), _stack_pieces(xhi, block_t),
             _stack_rows(block_t))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16"))
-def _q40_mxu_nb_2d(qs_t, scale, x, *, block_rows, block_t, interpret,
-                   bf16=False):
-    _, nb, d = qs_t.shape
+def _mxu_nb_call(layer, qs_t, scale, x, block_rows, block_t, planes,
+                 interpret, bf16):
+    """The T > 1 nb-major ``pallas_call``: a 2-D leaf (``layer`` None) or
+    one layer of a stack, which the scalar-prefetched index picks."""
+    nb, d = qs_t.shape[-2:]
     t = x.shape[0]
-    xlo, xhi, x_rows = _mxu_nb_planes(x, nb, block_t, bf16)
-    out = pl.pallas_call(
-        functools.partial(_kernel_mxu_nb, bf16=bf16),
-        compiler_params=_VMEM64_PARAMS,
-        grid=(t // block_t, d // block_rows),
-        in_specs=[
-            pl.BlockSpec((NJ, nb, block_rows), lambda ti, i: (0, 0, i)),
-            pl.BlockSpec((nb, block_rows), lambda ti, i: (0, i)),
-            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i: (0, ti, 0)),
-            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i: (0, ti, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_rows), lambda ti, i: (ti, i)),
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        interpret=interpret,
-    )(qs_t, scale, xlo, xhi)
-    return out
+    pre = () if layer is None else (layer,)
+    xlo, xhi, x_rows = _mxu_nb_planes(x, nb, block_t, bf16, planes)
 
+    def tile(*shape):        # a row tile of the leaf (of the picked layer)
+        return pl.BlockSpec(
+            (1,) * len(pre) + shape + (block_rows,),
+            lambda ti, i, *L: (*(l[0] for l in L), *(0,) * len(shape), i))
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "block_t", "interpret",
-                                    "bf16"))
-def _q40_mxu_nb_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
-                        interpret, bf16=False):
-    _, _, nb, d = qs_t.shape
-    t = x.shape[0]
-    xlo, xhi, x_rows = _mxu_nb_planes(x, nb, block_t, bf16)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(t // block_t, d // block_rows),
-        in_specs=[
-            pl.BlockSpec((1, NJ, nb, block_rows),
-                         lambda ti, i, L: (L[0], 0, 0, i)),
-            pl.BlockSpec((1, nb, block_rows), lambda ti, i, L: (L[0], 0, i)),
-            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i, L: (0, ti, 0)),
-            pl.BlockSpec((NJ, x_rows, nb), lambda ti, i, L: (0, ti, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_rows),
-                               lambda ti, i, L: (ti, i)),
-    )
+    x_spec = pl.BlockSpec((NJ // planes, x_rows, planes * nb),
+                          lambda ti, i, *L: (0, ti, 0))
     return pl.pallas_call(
-        functools.partial(_kernel_mxu_nb_stacked, bf16=bf16),
-        grid_spec=grid_spec,
+        functools.partial(_kernel_mxu_nb_stacked if pre else _kernel_mxu_nb,
+                          bf16=bf16),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(pre),
+            grid=(t // block_t, d // block_rows),
+            in_specs=[tile(NJ, nb), tile(nb), x_spec, x_spec],
+            out_specs=pl.BlockSpec((block_t, block_rows),
+                                   lambda ti, i, *L: (ti, i))),
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
         compiler_params=_VMEM64_PARAMS, interpret=interpret,
-    )(layer, qs_t, scale, xlo, xhi)
+    )(*pre, qs_t, scale, xlo, xhi)
+
+
+# the benchmark finds the T > 1 tile by these two names, as it does the
+# matvec by the two above
+@functools.partial(jax.jit,
+                   static_argnames=("block_rows", "block_t", "interpret",
+                                    "bf16", "planes"))
+def _q40_mxu_nb_2d(qs_t, scale, x, *, block_rows, block_t, interpret,
+                   bf16=False, planes=1):
+    return _mxu_nb_call(None, qs_t, scale, x, block_rows, block_t, planes,
+                        interpret, bf16)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_rows", "block_t", "interpret",
+                                    "bf16", "planes"))
+def _q40_mxu_nb_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
+                        interpret, bf16=False, planes=1):
+    return _mxu_nb_call(layer, qs_t, scale, x, block_rows, block_t, planes,
+                        interpret, bf16)
 
 
 def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
@@ -1027,11 +1127,13 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
     standard (M,K)x(K,N) dot (``_five_pass_dot``: the weight's two bf16
     pieces against the rows' three, split once a call, exact on both sides
     and as close to float64 as HIGHEST; one piece a side where the caller
-    traced under bf16 precision): rows are padded to a multiple of 8, so a
-    2..8-row decode dispatch is ONE 8-row t-tile of the body a 16-row
-    dispatch and a prefill chunk run. Dequantize-then-dot serves a chunk
-    traced under bf16 precision (see q40_matmul) and a ``d`` the row tiler
-    cannot place."""
+    traced under bf16 precision, the same planes a dot): rows are padded to
+    a multiple of 8, so a 2..8-row decode dispatch is ONE 8-row t-tile of
+    the body a 16-row dispatch and a prefill chunk run. How many nibble
+    planes one dot contracts over is ``_pick_planes(nb, block_t)``'s, the
+    row tile ``_pick_rows_mxu``'s: still ONE Pallas call a leaf, under the
+    same two names. Dequantize-then-dot serves a chunk traced under bf16
+    precision (see q40_matmul) and a ``d`` the row tiler cannot place."""
     from .linear import matmul_mode
 
     qs_t, scale = w.qs_t, w.scale
@@ -1047,24 +1149,17 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                                   interpret, layer)
         return out[:t].reshape(*lead, d)
     bf16 = matmul_mode() == "bf16"
-    # a chunk under bf16 precision dequantizes once and dots (q40_matmul
-    # says why); a decode dispatch of up to MULTI_T_MAX rows never does
+    block_t = _pick_block_t(t, nb)
+    planes = 1
     if t == 1:
         rows = _pick_rows_t1(d, nb)
+    elif bf16 and t > MULTI_T_MAX:
+        # a chunk under bf16 precision dequantizes once and dots (q40_matmul
+        # says why); a decode dispatch of up to MULTI_T_MAX rows never does
+        rows = None
     else:
-        rows = None if bf16 and t > MULTI_T_MAX else _pick_rows_nb(d, nb)
-    block_t = _pick_block_t(t, nb)
-    if rows is not None and t > 1:
-        # the MXU body's f32 wlo/whi temporaries obey the same measured
-        # rows*nb boundary as the d-major path (_MATMUL_ROWSXNB_CAP);
-        # _pick_rows_nb's matvec budget is looser, so re-cap here
-        cap = _MATMUL_ROWSXNB_CAP // nb
-        rows = next((r for r in range(min(rows, cap - cap % 128), 0, -128)
-                     if d % r == 0), None)
-        if rows is not None and block_t < 128 and rows > 256:
-            # same Mosaic small-t-tile VMEM behavior as the d-major MXU
-            # path: shrink the row tile (see _pick_block_rows)
-            rows = 256 if d % 256 == 0 else (128 if d % 128 == 0 else None)
+        planes = _pick_planes(nb, block_t)
+        rows = _pick_rows_mxu(d, nb, block_t, planes)
     if rows is not None:
         if layer is not None:
             lidx = jnp.asarray(layer, dtype=jnp.int32).reshape(1)
@@ -1075,7 +1170,8 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
             else:
                 out = _q40_mxu_nb_stacked(lidx, qs_t, scale, x2,
                                           block_rows=rows, block_t=block_t,
-                                          interpret=interpret, bf16=bf16)
+                                          interpret=interpret, bf16=bf16,
+                                          planes=planes)
         else:
             if t == 1:
                 out = _q40_matvec_nb_2d(qs_t, scale, x2, block_rows=rows,
@@ -1083,7 +1179,7 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
             else:
                 out = _q40_mxu_nb_2d(qs_t, scale, x2, block_rows=rows,
                                      block_t=block_t, interpret=interpret,
-                                     bf16=bf16)
+                                     bf16=bf16, planes=planes)
         return out.reshape(*lead, d)
     if layer is not None:
         qs_t = qs_t[layer]

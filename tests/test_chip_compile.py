@@ -595,6 +595,32 @@ CASES = {
 }
 
 
+def _q40_tile(d: int, nb: int, t: int):
+    """One stacked nb-major leaf of ``nb`` blocks a row at a ``t``-row
+    dispatch, through ``q40_matmul``: the tile as the rule lays it."""
+    from distributed_llama_tpu.io.loader import Q40KernelNb
+    from distributed_llama_tpu.ops.pallas_q40 import q40_matmul
+
+    w = Q40KernelNb(_sd((2, 16, nb, d), jnp.uint8),
+                    _sd((2, nb, d), jnp.float32))
+    return (lambda w, x, layer: q40_matmul(w, x, interpret=False,
+                                           layer=layer),
+            (w, _sd((t, nb * 32), jnp.float32), _sd((), jnp.int32)))
+
+
+# PR 51: the T > 1 tile at every (blocks a row, rows, planes a dot) triple
+# ``ops/pallas_q40._pick_planes`` returns over the dense leaves of the nine
+# benchmark configurations and the widths their cells dispatch (shapes from
+# the configuration files, tests/q40_cell_leaves.py; the smallest leaf of a
+# triple), so that the chip's compiler has taken every tile a cell runs
+# before a cell runs it: PR 36 and PR 38 each met a scoped-VMEM refusal late
+from q40_cell_leaves import rule_triples  # noqa: E402
+
+CASES.update({f"q40-tile-nb{nb}-T{t}-G{g}":
+              (functools.partial(_q40_tile, d, nb, t), True)
+              for (nb, t, g), d in rule_triples().items()})
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(chip, case):
     build, kernel = CASES[case]

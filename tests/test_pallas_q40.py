@@ -726,9 +726,10 @@ def _tile_inputs(nb, rows, d=128, seed=0):
     return qs, scale, x, w, x.astype(np.float64) @ w.T
 
 
-def _run_body_nb(qs, scale, x, highest: bool):
-    """One tile through ``_matmul_body_nb`` (interpret mode); ``highest``:
-    through the body it replaced, float32 dots at Precision.HIGHEST."""
+def _run_body_nb(qs, scale, x, highest: bool, planes: int = 1):
+    """One tile through ``_matmul_body_nb`` (interpret mode), ``planes``
+    nibble planes a dot; ``highest``: through the body it replaced, float32
+    dots at Precision.HIGHEST."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -755,7 +756,8 @@ def _run_body_nb(qs, scale, x, highest: bool):
 
     # the HIGHEST body takes the rows as they are, the new one their pieces
     xlo, xhi = (pq._split_x(jnp.asarray(x), nb) if highest else
-                pq._mxu_nb_planes(jnp.asarray(x), nb, rows, False)[:2])
+                pq._mxu_nb_planes(jnp.asarray(x), nb, rows, False,
+                                  planes)[:2])
     return np.asarray(pl.pallas_call(
         highest_body if highest else new_body,
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
@@ -793,13 +795,15 @@ def test_five_passes_are_as_close_to_float64_as_highest(nb, rows):
     assert dist(xp[0] @ wp[0].T) > bound               # one pass
 
 
+@pytest.mark.parametrize("planes", [1, 2, 4, 8, 16])
 @pytest.mark.parametrize("rows", [8, 32])
 @pytest.mark.parametrize("nb", [32, 64, 224])
-def test_dense_and_merged_tiles_agree(nb, rows):
-    """``_matmul_body_nb`` (one dot a nibble plane) and the expert slots'
-    ``_mxu_body_merged`` (the planes merged into the contraction) are the
-    same five-product arithmetic: the same array on the same tile, to the
-    two summation orders' float32 rounding."""
+def test_dense_and_merged_tiles_agree(nb, rows, planes):
+    """``_matmul_body_nb`` at every count of nibble planes a dot (1: a dot
+    a plane; 16: the slots' form) and the expert slots' ``_mxu_body_merged``
+    (all 16 planes merged into the contraction) are the same five-product
+    arithmetic: the same array on the same tile, to the summation orders'
+    float32 rounding."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -807,7 +811,7 @@ def test_dense_and_merged_tiles_agree(nb, rows):
     from distributed_llama_tpu.ops import pallas_moe as pm
 
     qs, scale, x, _, want = _tile_inputs(nb, rows, seed=7)
-    dense = _run_body_nb(qs, scale, x, highest=False)
+    dense = _run_body_nb(qs, scale, x, highest=False, planes=planes)
 
     def merged_body(qs_ref, s_ref, xlo_ref, xhi_ref, out_ref):
         pm._mxu_body_merged(qs_ref, s_ref[...], xlo_ref, xhi_ref, out_ref,

@@ -172,6 +172,49 @@ def test_policy_line_and_stamp_count_the_t1_mxu_leaves(model, want,
     assert fingerprint.run_stamp()["q40_body"] == "d-major"
 
 
+@pytest.mark.parametrize("rows,want", [
+    (8, "tile planes-a-dot: 1 x 8 leaves"),
+    (32, "tile planes-a-dot: 1 x 8 leaves"),
+    (1, None)])
+def test_policy_line_and_stamp_carry_the_tile_planes_histogram(
+        rows, want, monkeypatch, capsys):
+    """For a decode dispatch of more than one row the policy line and every
+    log record's ``q40_body`` count the nb-major dense tensors by the
+    nibble planes one dot of the T > 1 tile contracts over
+    (ops/pallas_q40._pick_planes), from the rule and nothing else; a
+    one-row engine's steps never run the tile and its line says nothing.
+    7B: seven tensors on the 128 grid, and ``w2`` (344 blocks a row) whose
+    16-plane group would not fit a 256-row tile: a dot a plane all."""
+    from distributed_llama_tpu.utils import fingerprint
+
+    monkeypatch.setenv("DLLAMA_Q40_KERNEL", "pallas")
+    monkeypatch.setattr(fingerprint, "_Q40_BODY", "unresolved")
+    apply_q40_body_policy(llama2_7b_spec(), rows=rows)
+    err = capsys.readouterr().err
+    stamp = fingerprint.run_stamp()["q40_body"]
+    if want is None:
+        assert "planes-a-dot" not in err and "planes-a-dot" not in stamp
+        return
+    assert f"t1 mxu 8/8; {want}; the i4 body" in err
+    assert stamp == f"i4-nb t1 mxu 8/8; {want}"
+
+
+def test_tile_planes_counts_leaves_by_the_rule():
+    """``tile_planes`` is a pure function of (spec, layout, rows): a spec
+    with leaves off the 128 grid (dim 2560: 80 blocks a row; FFN 10240:
+    320) merges 8 and 2 planes a dot at 32 rows and none at 8."""
+    from distributed_llama_tpu.ops.linear import Q40Layout, tile_planes
+
+    spec = TransformerSpec(dim=2560, hidden_dim=10240, n_layers=2,
+                           n_heads=20, n_kv_heads=20, vocab_size=32000,
+                           seq_len=64, weights_float_type=FloatType.Q40)
+    layout = Q40Layout("nb-major", "test")
+    assert tile_planes(spec, layout, 32) == (
+        "tile planes-a-dot: 8 x 7 leaves, 2 x 1, 1 x 0")
+    assert tile_planes(spec, layout, 8) == "tile planes-a-dot: 1 x 8 leaves"
+    assert tile_planes(spec, layout, 5) == tile_planes(spec, layout, 8)
+
+
 def test_apply_twice_the_second_stands(monkeypatch, capsys):
     """Overwritten, not first-wins: a by-hand packer after the second call
     packs the second call's layout (here a model past the headroom gate
