@@ -79,7 +79,7 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
         from ..ops.retention import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)
-    if spec.slotted:
+    if spec.slotted and not spec.latent:
         _refuse_sharded_slotted(spec, n_slices)
         # each layer its kind's tensors (a held expert's counted once
         # each), and the classifier (a hybrid spec's: the tied copy)
@@ -93,7 +93,7 @@ def weight_values_per_device(spec: TransformerSpec, n_slices: int) -> int:
         # (``latent_absorbed_bytes``), not in the weights' float type
         dense = sum(d * n for _, (d, n) in spec.dense_layer_matmul_shapes())
         la = spec.latent
-        kvb = spec.n_heads * (la.nope_dim + la.v_dim) * la.kv_rank
+        kvb = spec.latent_groups * (la.nope_dim + la.v_dim) * la.kv_rank
         return (spec.n_expert_layers * per_layer
                 + spec.n_dense_layers * dense - spec.n_layers * kvb
                 + spec.vocab_size * spec.dim)
@@ -117,8 +117,8 @@ def latent_absorbed_bytes(spec: TransformerSpec) -> int:
     if not spec.latent:
         return 0
     la = spec.latent
-    return (4 * spec.n_layers * spec.n_heads * (la.nope_dim + la.v_dim)
-            * la.kv_rank)
+    return (4 * spec.n_layers * spec.latent_groups
+            * (la.nope_dim + la.v_dim) * la.kv_rank)
 
 
 def hyper_bytes(spec: TransformerSpec) -> int:
@@ -171,6 +171,12 @@ def state_slot_bytes(spec: TransformerSpec) -> int:
     context). What ``kv_position_bytes`` x positions is to a softmax spec."""
     from ..ops.retention import state_bytes
 
+    if spec.latent and spec.slotted:
+        # a latent spec's slot: each sliding layer's ring of latent rows,
+        # float32, in whole lane tiles (models/latent.plane_width)
+        la = spec.latent
+        return (4 * la.count("sliding") * la.window
+                * -(-la.width // 128) * 128)
     if spec.mixers:
         # a mixer-kinds spec's slot: each sliding layer's ring of K and V,
         # float32 (models/laguna.py); its full layers' K / V are pages
@@ -203,9 +209,10 @@ def kv_cache_device_bytes(spec: TransformerSpec, n_slices: int,
 
             raise ValueError(TP_REFUSAL)
         return batch * state_slot_bytes(spec)
-    if spec.latent:
-        return batch * spec.seq_len * kv_position_bytes(spec, n_slices,
-                                                        cache_itemsize)
+    if spec.latent:     # its full layers' plane, and its rings where any
+        return batch * (spec.seq_len * kv_position_bytes(
+            spec, n_slices, cache_itemsize)
+            + (state_slot_bytes(spec) if spec.slotted else 0))
     if spec.slotted:    # ``batch`` slots, and the full layers' K / V
         _refuse_sharded_slotted(spec, n_slices, n_sp)
         return batch * (state_slot_bytes(spec) + spec.seq_len
@@ -247,8 +254,8 @@ def kv_position_bytes(spec: TransformerSpec, n_slices: int,
         if n_slices > 1 or kv_quant != "f32":
             raise ValueError("a latent-attention spec's plane is float32 on "
                              "one chip (runtime/continuous.latent_refusals)")
-        return (spec.n_layers * -(-spec.latent.width // 128) * 128
-                * cache_itemsize)
+        return (spec.latent_kinds.count("full")
+                * -(-spec.latent.width // 128) * 128 * cache_itemsize)
     if spec.slotted:    # the full layers' K and V alone (a hybrid spec
         #                     has ONE), float32, one chip
         _refuse_sharded_slotted(spec, n_slices)

@@ -52,6 +52,12 @@ Extensions beyond the reference:
   6, with six float32 tensors a layer (``HYPER_TENSORS``). Their names in
   the checkpoint are a GUESS (``HYPER_TENSORS_NOTE``): no checkpoint was
   seen, and one that names them otherwise is refused by a ``KeyError``.
+* ``model_type: Motif`` (Motif-3-Beta, ``attention_cls: gdla``: grouped
+  differential attention on a latent plane, sliding and full layers,
+  PolyNorm, ``mhc_expansion_rate`` streams): ``motif_spec`` reads its
+  ``config.json`` into header extension 9; the tensors are
+  ``LATENT_TENSORS`` and ``HYPER_TENSORS`` with ``MOTIF_TENSORS``' three,
+  their names a GUESS as those (``MOTIF_TENSORS_NOTE``).
 * ``model_type: phi4flash`` (Phi-4-mini-flash-reasoning, SambaY): its
   ``config.json`` gives the spec (``hybrid_spec``: the per-layer list of
   kinds from ``mb_per_layer`` and the depth, header extension 5; the
@@ -238,6 +244,80 @@ HYPER_TENSORS_NOTE = (
 (``TransformerSpec.hyper_shapes``); the rest are ``LATENT_TENSORS``."""
 
 
+MOTIF_TENSORS = {"w_lambda": _L + "self_attn.lambda_proj.weight",
+                 "wg": _L + "self_attn.g_proj.weight",
+                 "pn_w": _L + "mlp.act_fn.weight"}
+MOTIF_TENSORS_NOTE = (
+    "a guess: no Motif checkpoint was seen. The map takes self_attn."
+    "lambda_proj.weight (signal heads x hidden) for the per-token lambda, "
+    "self_attn.g_proj.weight for the elementwise gate and mlp.act_fn.weight "
+    "as PolyNorm's (w0, w1, w2, b) of a layer; the hyper-connection modules "
+    "as xing4_0's. A checkpoint that names or splits them otherwise fails "
+    "with a KeyError on the first such tensor, and nothing is guessed "
+    "further")
+"""What a ``Motif`` checkpoint has beside ``LATENT_TENSORS`` and
+``HYPER_TENSORS``."""
+
+
+def motif_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
+    """The spec of a ``Motif`` config object ``c`` (``attention_cls``
+    "gdla"): every layer and every routed expert, header extension 9. The
+    readings the config does not settle are stated in
+    ``models/reference_motif.py``."""
+    import math
+
+    from .models.spec import (Activation, ExpertLayout, HyperConnections,
+                              LatentAttn, Router)
+
+    if (c.attention_cls, c.score_func, c.hidden_act, bool(c.diff_v2),
+            bool(c.headwise_attn_output_gate),
+            getattr(c, "interleave_moe_layer_step", 1),
+            bool(c.score_before_experts)) != (
+            "gdla", "sigmoid", "poly_norm", True, False, 1, False):
+        raise ValueError("Motif: grouped differential latent attention "
+                         "(gdla, diff_v2), sigmoid scores applied after the "
+                         "experts, an expert layer after every leading dense "
+                         "one and PolyNorm are what the program runs")
+    rs = getattr(c, "rope_scaling", None) or {}
+    if rs.get("apply_yarn_scaling", False) or c.swa_rope_theta != c.rope_theta:
+        raise ValueError("Motif: plain RoPE at one base in both layer kinds "
+                         "is what the program runs (apply_yarn_scaling "
+                         "false)")
+    n_layers, groups = c.num_hidden_layers, c.num_key_value_heads
+    if c.num_noise_heads not in (0, groups):
+        raise ValueError("Motif: one noise head a KV group, or none")
+    period = c.sliding_window_period
+    sliding = bool(c.use_sliding_window)
+    if sliding and c.sliding_window_pattern != "interleave":
+        raise ValueError("Motif: the interleaved window pattern alone")
+    kinds = tuple("full" if (i + 1) % period == 0 else "sliding"
+                  for i in range(n_layers)) if sliding else ()
+    streams = c.mhc_expansion_rate if c.mhc_enabled else 0
+    return TransformerSpec(
+        dim=c.hidden_size, hidden_dim=c.moe_intermediate_size,
+        n_layers=n_layers, n_heads=c.num_attention_heads,
+        n_kv_heads=c.num_attention_heads, vocab_size=c.vocab_size,
+        seq_len=seq_len, weights_float_type=target,
+        n_experts=c.num_experts, n_active_experts=c.experts_top_k,
+        rope_theta=float(c.rope_theta), norm_eps=float(c.rms_norm_eps),
+        latent=LatentAttn(
+            c.q_lora_rank, c.kv_lora_rank, c.head_dim - c.qk_rope_head_dim,
+            c.qk_rope_head_dim, c.v_head_dim, kv_groups=groups,
+            noise_heads=c.num_noise_heads // groups,
+            gate=bool(c.elementwise_attn_output_gate), kinds=kinds,
+            window=c.sliding_window if sliding else 0),
+        layout=ExpertLayout(min(c.n_dense_first_layers, n_layers - 1),
+                            c.intermediate_size, c.num_shared_experts),
+        router=Router("sigmoid", 1, 1, bool(c.route_norm),
+                      float(c.route_scale), False),
+        hyper=HyperConnections(streams, int(c.mhc_sinkhorn_iters), 1e-6,
+                               -math.inf, math.inf,
+                               float(getattr(c, "hidden_clamp", 0) or 0))
+        if streams else None,
+        activation=Activation("polynorm", float(c.polynorm_output_scale),
+                              float(c.polynorm_bias_clamp)))
+
+
 def latent_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
     """The spec of a ``deepseek_v3`` config object ``c``: every layer and
     every routed expert."""
@@ -333,6 +413,9 @@ class HFCheckpoint:
                     int(c.hc_mult), int(c.hc_sinkhorn_iters),
                     float(c.hc_eps), float(c.mhc_h_res_clamp_min),
                     float(c.mhc_h_res_clamp_max)))
+        if getattr(c, "model_type", "") == "Motif":
+            print(f"🔶 Motif tensors: {MOTIF_TENSORS_NOTE}")
+            return motif_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "phi4flash":
             return hybrid_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "laguna":
@@ -390,7 +473,8 @@ class HFCheckpoint:
                                      spec.mixers.rotary(kind))
             return w
         if spec.latent:     # rows as they are: see the module docstring
-            key = LATENT_TENSORS.get(name) or HYPER_TENSORS.get(name) or {
+            key = (LATENT_TENSORS.get(name) or HYPER_TENSORS.get(name)
+                   or MOTIF_TENSORS.get(name)) or {
                 "tok_embedding": "model.embed_tokens.weight",
                 "rms_final": "model.norm.weight",
                 "wcls": "lm_head.weight"}[name]

@@ -38,7 +38,8 @@ from ..io.loader import Q40Kernel, Q40KernelNb, Q40KernelNbI4
 from ..obs.spans import (SCOPE_ATTN, SCOPE_ATTN_SINK, SCOPE_EMBED, SCOPE_FFN,
                          SCOPE_LOGITS)
 from ..ops.hyper import residual_in, residual_out
-from ..ops.linear import StackedQ40, fake_quant_q80, matmul, rmsnorm, silu
+from ..ops.linear import (StackedQ40, fake_quant_q80, ffn_activation, matmul,
+                          rmsnorm)
 from ..ops.quants import FloatType
 from .spec import TransformerSpec
 
@@ -67,14 +68,19 @@ def init_state(spec: TransformerSpec, batch: int | None = None) -> StateCache:
 
 def slot_model(spec: TransformerSpec):
     """The module that runs a spec whose sequences keep a slot of fixed
-    size AND pages (``spec.slotted``): ``models/sambay`` (a hybrid spec) or
-    ``models/laguna`` (a mixer-kinds spec). Both give ``init_cache``,
+    size AND pages (``spec.slotted``): ``models/sambay`` (a hybrid spec),
+    ``models/laguna`` (a mixer-kinds spec) or ``models/latent`` (a latent
+    spec with sliding layers). Each gives ``init_cache``,
     ``init_cache_paged``, ``insert_sequence``, ``state_bytes``,
     ``forward_batch`` and ``forward_chunk``."""
     if spec.mixers:
         from . import laguna
 
         return laguna
+    if spec.latent:
+        from . import latent
+
+        return latent
     from . import sambay
 
     return sambay
@@ -82,9 +88,11 @@ def slot_model(spec: TransformerSpec):
 
 def slot_counts(spec: TransformerSpec) -> dict:
     """The keyword with which a slotted spec's forwards also hand out an
-    expert spec's (L_e, E) routed-rows counts (a mixer-kinds spec's alone:
-    a hybrid spec has a dense FFN), for the engines' ``functools.partial``."""
-    return {"moe_counts": True} if spec.mixers and spec.n_experts else {}
+    expert spec's (L_e, E) routed-rows counts (a mixer-kinds or a latent
+    spec's: a hybrid spec has a dense FFN), for the engines'
+    ``functools.partial``."""
+    return {"moe_counts": True} if (spec.mixers or spec.latent) and \
+        spec.n_experts else {}
 
 
 def init_cache(spec: TransformerSpec, dtype=jnp.float32):
@@ -92,7 +100,7 @@ def init_cache(spec: TransformerSpec, dtype=jnp.float32):
         return slot_model(spec).init_cache(spec, dtype=dtype)
     if spec.retention:  # float32 whatever ``dtype``: nothing scales with S
         return init_state(spec)
-    if spec.latent:     # one plane [c_kv | k_rope] in place of K and V
+    if spec.latent:     # planes of [c_kv | k_rope] rows in place of K and V
         from .latent import init_cache as init_latent
 
         return init_latent(spec, dtype)
@@ -341,15 +349,17 @@ def _qkv_proj(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
 
 def _swiglu(spec: TransformerSpec, lw: dict[str, Any], xb: jax.Array,
             prefix: str = "") -> jax.Array:
-    """w2(silu(w1 xb) * w3 xb) of the leaves ``prefix + w1 | w2 | w3`` (or
+    """w2(act(w1 xb) * w3 xb), act the spec's activation (SiLU unless it
+    states another: ops/linear.ffn_activation), of the leaves ``prefix + w1 | w2 | w3`` (or
     their load-time fusion ``prefix + w13``: linear.fuse_q40_layer_matmuls)."""
+    act = ffn_activation(spec, lw)
     if prefix + "w13" in lw:
         h13 = matmul(lw[prefix + "w13"], xb)
         hid = h13.shape[-1] // 2
-        hb = silu(h13[..., :hid]) * h13[..., hid:]
+        hb = act(h13[..., :hid]) * h13[..., hid:]
     else:
-        hb = silu(matmul(lw[prefix + "w1"], xb)) * matmul(lw[prefix + "w3"],
-                                                          xb)
+        hb = act(matmul(lw[prefix + "w1"], xb)) * matmul(lw[prefix + "w3"],
+                                                         xb)
     return matmul(lw[prefix + "w2"], _maybe_q80(spec, hb))
 
 
@@ -364,9 +374,10 @@ def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
     ops/hyper's ``residual_in`` / ``residual_out``: the plain add, unless
     the spec carries several streams (x is then (n, R, dim) and ``coef``
     what ``residual_in`` gave the caller for the attention sub-layer)."""
+    clamp = spec.hyper.stream_clamp if spec.hyper else 0.0
     with jax.named_scope(SCOPE_ATTN):
         ao = _maybe_q80(spec, ao)
-        x = residual_out(coef, x, matmul(lw["wo"], ao))
+        x = residual_out(coef, x, matmul(lw["wo"], ao), clamp)
     h, coef = residual_in(spec, lw, "ffn", x)
     with jax.named_scope(SCOPE_FFN):
         xb = rmsnorm(h, lw["rms_ffn"], spec.norm_eps)
@@ -377,9 +388,9 @@ def _post_attention(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
             y, counts = moe_ffn(spec, lw, xb)
             if "sh_w2" in lw:
                 y = y + _swiglu(spec, lw, xb, "sh_")
-            x = residual_out(coef, x, y)
+            x = residual_out(coef, x, y, clamp)
             return (x, counts) if moe_counts else x
-        return residual_out(coef, x, _swiglu(spec, lw, xb))
+        return residual_out(coef, x, _swiglu(spec, lw, xb), clamp)
 
 
 def _layer(spec: TransformerSpec, x: jax.Array, lw: dict[str, Any],
@@ -772,13 +783,12 @@ def init_cache_paged(spec: TransformerSpec, n_pages: int, page_size: int,
     physical pages through an int32 page-table row, so the pool can be
     sized far below slots * seq_len (the HBM lever of vLLM's
     PagedAttention)."""
-    if spec.latent:     # ONE plane a page: models/latent.py
-        from .latent import init_cache_paged as init_latent_paged
-
-        return init_latent_paged(spec, n_pages, page_size, dtype)
-    if spec.slotted:    # ``slots`` rows of state and ring beside the pool
-        return slot_model(spec).init_cache_paged(spec, slots, n_pages,
-                                                 page_size, dtype)
+    if spec.slotted or spec.latent:
+        # ``slots`` rows of state and ring beside the pool; a latent
+        # spec's ONE plane a page (models/latent.py: no rows without
+        # sliding layers)
+        model = slot_model(spec)
+        return model.init_cache_paged(spec, slots, n_pages, page_size, dtype)
     if spec.seq_len % page_size:
         raise ValueError(f"page_size={page_size} must divide "
                          f"seq_len={spec.seq_len}")
@@ -1088,11 +1098,11 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
     documented quantization contract.
     """
     if spec.latent:
-        from .latent import forward_batch_latent_paged
+        from .latent import forward_batch as forward_batch_latent
 
-        return forward_batch_latent_paged(spec, page_size, params, cache,
-                                          tokens, pos_vec, table,
-                                          moe_counts=moe_counts)
+        return forward_batch_latent(spec, params, cache, tokens, pos_vec,
+                                    table, page_size=page_size,
+                                    moe_counts=moe_counts)
     if spec.hybrid:
         from .sambay import forward_batch_sambay
 

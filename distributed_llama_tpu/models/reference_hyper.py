@@ -88,8 +88,10 @@ def sublayer(spec, lw, sub: str, x, fn, low: bool = False):
     pre, post, res = coefficients(spec, lw, sub, x, low)
     h = jnp.einsum("ti,tic->tc", pre, x)
     y, *more = fn(h)
-    return (jnp.einsum("tij,tjc->tic", res, x)
-            + post[:, :, None] * y[:, None, :], *more)
+    out = jnp.einsum("tij,tjc->tic", res, x) + post[:, :, None] * y[:, None, :]
+    if spec.hyper.stream_clamp:     # the streams written back, clipped
+        out = jnp.clip(out, -spec.hyper.stream_clamp, spec.hyper.stream_clamp)
+    return (out, *more)
 
 
 def _dense_ffn(spec, lw, h):

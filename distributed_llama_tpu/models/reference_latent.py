@@ -130,9 +130,11 @@ def attention(spec, lw, x):
     return x + attention_out(spec, lw, x)
 
 
-def attention_out(spec, lw, x):
-    """The latent-attention sub-block of input x (which it norms), expanded,
-    without the residual (models/reference_hyper.py mixes its own)."""
+def projections(spec, lw, x):
+    """(h the normed input (T, dim), q_nope (T, H, nope), q_rope (T, H,
+    rope) rotated, c_kv (T, kv_rank) normed, k_rope (T, rope) rotated, the
+    attention scale) of input x: the low-rank q and the latent row, before
+    ``wkv_b`` expands it (models/reference_motif.py expands it by group)."""
     la, nh, eps = spec.latent, spec.n_heads, spec.norm_eps
     t = x.shape[0]
     freq, factor, scale = rope_frequencies(spec)
@@ -144,6 +146,15 @@ def attention_out(spec, lw, x):
     kv = h @ _dense(lw["wkv_a"]).T
     c_kv = _rmsnorm(kv[:, :la.kv_rank], lw["rms_kv_a"], eps)
     k_rope = _rope(kv[:, la.kv_rank:], freq, factor)
+    return h, q_nope, q_rope, c_kv, k_rope, scale
+
+
+def attention_out(spec, lw, x):
+    """The latent-attention sub-block of input x (which it norms), expanded,
+    without the residual (models/reference_hyper.py mixes its own)."""
+    la, nh = spec.latent, spec.n_heads
+    t = x.shape[0]
+    _, q_nope, q_rope, c_kv, k_rope, scale = projections(spec, lw, x)
     kvb = (c_kv @ _dense(lw["wkv_b"]).T).reshape(t, nh,
                                                  la.nope_dim + la.v_dim)
     k_nope, v = kvb[..., :la.nope_dim], kvb[..., la.nope_dim:]
@@ -155,9 +166,10 @@ def attention_out(spec, lw, x):
     return ao @ _dense(lw["wo"]).T
 
 
-def _swiglu(h, w1, w2, w3):
-    return (jax.nn.silu(h @ _dense(w1).T) * (h @ _dense(w3).T)) \
-        @ _dense(w2).T
+def _swiglu(h, w1, w2, w3, act=jax.nn.silu):
+    """``act`` on the gate projection: SiLU, unless the spec states another
+    (models/reference_motif.py's PolyNorm)."""
+    return (act(h @ _dense(w1).T) * (h @ _dense(w3).T)) @ _dense(w2).T
 
 
 def route(spec, gate, bias, h):
@@ -195,8 +207,9 @@ def experts(spec, lw, x, shared: bool = True):
     return x + y, margin, ids
 
 
-def experts_out(spec, lw, x, shared: bool = True):
-    """``experts`` without the residual: the sub-block's output."""
+def experts_out(spec, lw, x, shared: bool = True, act=jax.nn.silu):
+    """``experts`` without the residual: the sub-block's output (``act``
+    as in ``_swiglu``)."""
     h = _rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
     w, ids, margin = route(spec, lw["moe_gate"], lw.get("moe_bias"), h)
     held, off = spec.n_experts_held, spec.layout.offset
@@ -208,10 +221,10 @@ def experts_out(spec, lw, x, shared: bool = True):
         e = jnp.clip(local, 0, held - 1)
         g = jnp.einsum("thd,td->th", w1[e], h)
         u = jnp.einsum("thd,td->th", w3[e], h)
-        out = jnp.einsum("tdh,th->td", w2[e], jax.nn.silu(g) * u)
+        out = jnp.einsum("tdh,th->td", w2[e], act(g) * u)
         y = y + jnp.where(here, w[:, j], 0.0)[:, None] * out
     if shared and spec.layout.shared:
-        y = y + _swiglu(h, lw["sh_w1"], lw["sh_w2"], lw["sh_w3"])
+        y = y + _swiglu(h, lw["sh_w1"], lw["sh_w2"], lw["sh_w3"], act)
     return y, margin, ids
 
 

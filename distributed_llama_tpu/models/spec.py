@@ -141,6 +141,26 @@ scaled), so a version-7 file reads and writes byte for byte. A kind's ``wk``
 is (kvHeads_k head x dim), ``wv`` (kvHeads_k valueHead x dim), ``wo`` (dim x
 heads_k valueHead), and a kind with a sink has, after ``wo`` (and
 ``w_hgate``), ``sink`` (F32, heads_k).
+
+Extension VERSION 9 (version 6's values, ``HyperConnections`` all zero
+where the spec has one stream, then eight ints {kvGroups, noiseHeads, gate,
+window, activation (0 silu, 1 polynorm), three reserved}, three float64
+{activationScale, activationClamp, streamClamp} and 128 bytes, a layer's
+kind each (``MIXER_KINDS``; all 255: every layer "full")) is written only
+by a latent spec that sets one of them (Motif-3-Beta's layout: ``wkv_b``
+expands the plane to ``kvGroups`` heads that ``nHeads / kvGroups`` query
+heads share, the last ``noiseHeads`` of a group are subtracted from its
+others after the softmax, an elementwise gate on the attention output, a
+ring of ``window`` latent rows a "sliding" layer, PolyNorm in the FFN), so
+files of every earlier version read and write byte for byte. A layer then
+carries, after its norms (and the residual path's tensors):
+
+  w_lambda (F32, signalHeads x dim)   where noiseHeads > 0
+  pn_w     (F32, 4: w0, w1, w2, b)    where the activation is PolyNorm
+  wq_a, wq_b, wkv_a, wkv_b (kvGroups (nope + v) x kvRank),
+  wg (signalHeads v x dim)            where gate, wo (dim x signalHeads v)
+
+with signalHeads = nHeads - kvGroups noiseHeads.
 """
 
 from __future__ import annotations
@@ -168,7 +188,10 @@ EXT7_VERSION = 7
 EXT7_STRUCT = struct.Struct("<14i2d16i7d9i14d128B")
 EXT8_VERSION = 8
 EXT8_STRUCT = struct.Struct("<14i2d16i7d9i14d128B5i1d")
-MAX_HEADER_BYTES = EXT8_STRUCT.size
+EXT9_VERSION = 9
+EXT9_STRUCT = struct.Struct("<14i2d16i7d4i3d8i3d128B")
+MAX_HEADER_BYTES = max(EXT8_STRUCT.size, EXT9_STRUCT.size)
+ACTIVATIONS = ("silu", "polynorm")
 HC_SUBLAYERS = ("att", "ffn")
 ATTN_KINDS = ("softmax", "retention")
 # what a layer of a ``HybridLayers`` spec mixes with, and what it caches for
@@ -195,6 +218,22 @@ class LatentAttn:
     nope_dim: int    # a head's q / k part that carries no position
     rope_dim: int    # ... and the part RoPE rotates (k's is shared by heads)
     v_dim: int
+    # header version 9 (the defaults: versions 4 and 6 as they were).
+    # ``wkv_b`` expands the plane to ``kv_groups`` heads of [k_nope | v]
+    # that n_heads / kv_groups query heads share (0: a head its own); of a
+    # group's heads the LAST ``noise_heads`` are noise heads: finished
+    # softmax heads that are subtracted, times a per-token lambda
+    # (``w_lambda``), from each of the group's other (signal) heads, which
+    # alone reach ``wo``; ``gate``: the signal heads' output times an
+    # elementwise sigmoid of the normed layer input (``wg``) before ``wo``;
+    # ``kinds[i]`` is layer i's kind, "full" or "sliding" (``MIXER_KINDS``;
+    # empty: every layer "full"): a sliding layer sees the last ``window``
+    # positions and keeps a RING of their latent rows in place of a plane
+    kv_groups: int = 0
+    noise_heads: int = 0
+    gate: bool = False
+    kinds: tuple = ()
+    window: int = 0
 
     @property
     def qk_dim(self) -> int:
@@ -204,6 +243,28 @@ class LatentAttn:
     def width(self) -> int:
         """Values cached a position and layer: [c_kv | k_rope]."""
         return self.kv_rank + self.rope_dim
+
+    @property
+    def widened(self) -> bool:
+        """Whether the record states what a version-4 header has no field
+        for."""
+        return bool(self.kv_groups or self.noise_heads or self.gate
+                    or self.kinds or self.window)
+
+    def count(self, kind: str) -> int:
+        return sum(k == kind for k in self.kinds)
+
+
+@dataclasses.dataclass(frozen=True)
+class Activation:
+    """What an FFN applies to its gate projection before the product with
+    the up projection: "silu", or "polynorm" (arXiv:2411.03884):
+    ``scale * (w0 n(z^3) + w1 n(z^2) + w2 n(z) + clip(b, -clamp, clamp))``
+    with ``n(u) = u / sqrt(mean(u^2) + eps)`` over the FFN's own width and
+    the four numbers a layer's ``pn_w`` (``clamp`` 0: the bias as it is)."""
+    kind: str = "silu"
+    scale: float = 1.0
+    clamp: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -290,12 +351,21 @@ class HyperConnections:
     mix of the streams and writes back through a per-token matrix that
     ``sinkhorn_iters`` alternating normalisations (each sum taken with
     ``eps`` added) project onto the doubly stochastic ones, from logits
-    clamped to [clamp_min, clamp_max]."""
+    clamped to [clamp_min, clamp_max] (both infinite: no clamp, and none is
+    computed). ``stream_clamp`` (header version 9; 0: none): the streams a
+    sub-layer writes back are clipped to +- that."""
     streams: int
     sinkhorn_iters: int = 20
     eps: float = 1e-6
     clamp_min: float = -30.0
     clamp_max: float = 30.0
+    stream_clamp: float = 0.0
+
+    @property
+    def clamped(self) -> bool:
+        import math
+
+        return not (math.isinf(self.clamp_min) and math.isinf(self.clamp_max))
 
     @property
     def coefficients(self) -> int:
@@ -422,8 +492,16 @@ class TransformerSpec:
     # each with a head count and RoPE of its own, beside ``layout`` and
     # ``router`` (None: every layer is what the fields above say)
     mixers: MixerKinds | None = None
+    # header version 9: what an FFN applies to its gate projection
+    activation: Activation = Activation()
 
     def __post_init__(self):
+        if self.latent is not None and (
+                self.latent.widened or self.activation != Activation()):
+            self._check_latent_kinds()
+        elif self.activation != Activation():
+            raise ValueError("an activation other than SiLU is carried by "
+                             "header version 9, a latent spec's: set latent")
         if self.hybrid is not None:
             self._check_hybrid()
         if self.mixers is not None:
@@ -483,6 +561,31 @@ class TransformerSpec:
                 f"n_experts={self.n_experts} / n_active_experts="
                 f"{self.n_active_experts}: both 0 (dense FFN) or "
                 f"0 < active <= experts")
+
+    def _check_latent_kinds(self) -> None:
+        la, act = self.latent, self.activation
+        groups = la.kv_groups or self.n_heads
+        kinds = tuple(la.kinds)
+        if (self.n_heads % groups or la.noise_heads not in (0, 1)
+                or la.noise_heads >= self.n_heads // groups
+                or min(la.kv_groups, la.window) < 0):
+            raise ValueError(
+                f"latent: n_heads={self.n_heads} in kv_groups={groups} "
+                f"groups of more than one head, of which the last may be a "
+                f"noise head (noise_heads={la.noise_heads}: 0 or 1; which "
+                f"signal heads a second one would be subtracted from is "
+                f"not stated)")
+        if kinds and (len(kinds) != self.n_layers or len(kinds) > 128
+                      or any(k not in MIXER_KINDS for k in kinds)):
+            raise ValueError(f"latent.kinds: one of {MIXER_KINDS} for each "
+                             f"of n_layers={self.n_layers} (at most 128)")
+        if ("sliding" in kinds) != bool(la.window):
+            raise ValueError("latent: a window where a layer is \"sliding\", "
+                             "and there alone")
+        if (act.kind not in ACTIVATIONS or act.clamp < 0
+                or (act.kind == "silu" and act != Activation())):
+            raise ValueError(f"activation {act}: one of {ACTIVATIONS}, a "
+                             f"scale and a clamp >= 0 a PolyNorm's alone")
 
     def _check_mixers(self) -> None:
         mx = self.mixers
@@ -554,21 +657,44 @@ class TransformerSpec:
     def stateful(self) -> bool:
         """Whether a sequence keeps something that a step rewrites and that
         cannot be rewound (a recurrent state, a window ring)."""
-        return bool(self.retention or self.hybrid or self.mixers)
+        return bool(self.retention or self.slotted)
 
     @property
     def slotted(self) -> bool:
         """Whether a sequence keeps a slot of fixed size AND pages (a
-        hybrid spec's, a mixer-kinds spec's): what ``models/llama.
-        slot_model`` runs."""
-        return bool(self.hybrid or self.mixers)
+        hybrid spec's, a mixer-kinds spec's, a latent spec's with sliding
+        layers: rings of latent rows beside the full layers' plane): what
+        ``models/llama.slot_model`` runs."""
+        return bool(self.hybrid or self.mixers
+                    or (self.latent and self.latent.window))
+
+    @property
+    def latent_kinds(self) -> tuple:
+        """A latent spec's kind a layer (``LatentAttn.kinds``; an empty
+        list is every layer "full")."""
+        return tuple(self.latent.kinds) or ("full",) * self.n_layers
+
+    @property
+    def latent_groups(self) -> int:
+        """KV groups ``wkv_b`` expands a latent spec's plane to."""
+        return self.latent.kv_groups or self.n_heads
+
+    @property
+    def latent_signal_heads(self) -> int:
+        """A latent spec's heads that reach ``wo``: all but the noise
+        heads."""
+        return self.n_heads - self.latent_groups * self.latent.noise_heads
 
     @property
     def header_version(self) -> int:
-        """0 (the 28-byte header), 2, 3, 4, 5, 6, 7 or 8: the lowest that
-        holds the spec."""
+        """0 (the 28-byte header), 2, 3, 4, 5, 6, 7, 8 or 9: the lowest
+        that holds the spec."""
         if self.mixers:
             return EXT8_VERSION if self.mixers.widened else EXT7_VERSION
+        if self.latent and (self.latent.widened
+                            or self.activation != Activation()
+                            or (self.hyper and self.hyper.stream_clamp)):
+            return EXT9_VERSION
         if self.hyper:
             return EXT6_VERSION
         if self.hybrid:
@@ -594,7 +720,8 @@ class TransformerSpec:
                 EXT5_VERSION: EXT5_STRUCT.size,
                 EXT6_VERSION: EXT6_STRUCT.size,
                 EXT7_VERSION: EXT7_STRUCT.size,
-                EXT8_VERSION: EXT8_STRUCT.size}[self.header_version]
+                EXT8_VERSION: EXT8_STRUCT.size,
+                EXT9_VERSION: EXT9_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
@@ -664,7 +791,8 @@ class TransformerSpec:
                       (EXT5_VERSION, 165): EXT5_STRUCT,
                       (EXT6_VERSION, 36): EXT6_STRUCT,
                       (EXT7_VERSION, 187): EXT7_STRUCT,
-                      (EXT8_VERSION, 193): EXT8_STRUCT}.get((version, count))
+                      (EXT8_VERSION, 193): EXT8_STRUCT,
+                      (EXT9_VERSION, 175): EXT9_STRUCT}.get((version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
@@ -678,13 +806,19 @@ class TransformerSpec:
             if version in (EXT7_VERSION, EXT8_VERSION):
                 more = _read_ext7(ints[36:], base[2])
                 ints = ints[:36]
-            if version == EXT6_VERSION:
+            nine = None
+            if version == EXT9_VERSION:
+                nine, ints = ints[43:], ints[:43]
+            if version in (EXT6_VERSION, EXT9_VERSION):
                 streams, iters, _, _, eps, lo, hi = ints[36:]
                 more = dict(hyper=HyperConnections(
-                    streams, iters, float(eps), float(lo), float(hi)))
+                    streams, iters, float(eps), float(lo), float(hi),
+                    float(nine[10]) if nine else 0.0)) if streams else {}
                 ints = ints[:36]
             if version >= EXT4_VERSION:
                 more = dict(_read_ext4(ints[13:]), **more)
+            if nine:
+                more.update(_read_ext9(nine, more["latent"], base[2]))
             if version >= EXT3_VERSION:
                 kind, theta, eps = ints[10:13]
                 if not 0 <= kind < len(ATTN_KINDS):
@@ -730,11 +864,19 @@ class TransformerSpec:
             rs.beta_slow, rs.mscale, rs.mscale_all_dim)
         if self.header_version == EXT4_VERSION:
             return EXT4_STRUCT.pack(EXT_MAGIC, EXT4_VERSION, 29, *v3, *v4)
-        if self.header_version == EXT6_VERSION:
-            hc = self.hyper
-            return EXT6_STRUCT.pack(
-                EXT_MAGIC, EXT6_VERSION, 36, *v3, *v4, hc.streams,
-                hc.sinkhorn_iters, 0, 0, hc.eps, hc.clamp_min, hc.clamp_max)
+        if self.header_version in (EXT6_VERSION, EXT9_VERSION):
+            hc = self.hyper or HyperConnections(0, 0, 0.0, 0.0, 0.0)
+            v6 = (*v3, *v4, hc.streams, hc.sinkhorn_iters, 0, 0, hc.eps,
+                  hc.clamp_min, hc.clamp_max)
+            if self.header_version == EXT6_VERSION:
+                return EXT6_STRUCT.pack(EXT_MAGIC, EXT6_VERSION, 36, *v6)
+            act = self.activation
+            kinds = [MIXER_KINDS.index(k) for k in la.kinds]
+            return EXT9_STRUCT.pack(
+                EXT_MAGIC, EXT9_VERSION, 175, *v6, la.kv_groups,
+                la.noise_heads, int(la.gate), la.window,
+                ACTIVATIONS.index(act.kind), 0, 0, 0, act.scale, act.clamp,
+                hc.stream_clamp, *kinds, *([255] * (128 - len(kinds))))
         if self.mixers:
             mx = self.mixers
             kinds = [MIXER_KINDS.index(k) for k in mx.kinds]
@@ -778,20 +920,29 @@ class TransformerSpec:
                 seen.update({(e[1], e[2]): None for e in entries
                              if e[0] == "mm" and len(e) == 3})
             return list(seen)
-        attn = [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
-                ("wo", (d, d))]
-        if self.latent:
-            la, nh = self.latent, self.n_heads
-            attn = [("wq_a", (la.q_rank, d)),
-                    ("wq_b", (nh * la.qk_dim, la.q_rank)),
-                    ("wkv_a", (la.width, d)),
-                    ("wkv_b", (nh * (la.nope_dim + la.v_dim), la.kv_rank)),
-                    ("wo", (d, nh * la.v_dim))]
+        attn = self.attn_matmul_shapes()
         if self.n_experts:
             sh = self.layout.shared * h   # the shared experts: ONE SwiGLU
             return attn + ([("sh_w1", (sh, d)), ("sh_w2", (d, sh)),
                             ("sh_w3", (sh, d))] if sh else [])
         return attn + [("w1", (h, d)), ("w2", (d, h)), ("w3", (h, d))]
+
+    def attn_matmul_shapes(self) -> list[tuple[str, tuple[int, int]]]:
+        """The attention's matmul tensors of a layer, in file order (a
+        latent spec's: ``wkv_b`` a KV group's rows, ``wg`` and ``wo`` over
+        the signal heads)."""
+        d, kv = self.dim, self.kv_dim
+        if not self.latent:
+            return [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
+                    ("wo", (d, d))]
+        la, out = self.latent, self.latent_signal_heads * self.latent.v_dim
+        return [("wq_a", (la.q_rank, d)),
+                ("wq_b", (self.n_heads * la.qk_dim, la.q_rank)),
+                ("wkv_a", (la.width, d)),
+                ("wkv_b", (self.latent_groups * (la.nope_dim + la.v_dim),
+                           la.kv_rank)),
+                *([("wg", (out, d))] if la.gate else []),
+                ("wo", (d, out))]
 
     def dense_layer_matmul_shapes(self) -> list[tuple[str, tuple[int, int]]]:
         """A LEADING DENSE layer's matmul tensors of an expert spec whose
@@ -800,8 +951,7 @@ class TransformerSpec:
         if not self.layout.dense_layers or self.mixers:
             return []   # a mixer-kinds spec's are among its distinct ones
         d, h = self.dim, self.layout.dense_hidden
-        n_attn = 5 if self.latent else 4
-        return self.layer_matmul_shapes()[:n_attn] + [
+        return self.attn_matmul_shapes() + [
             ("w1", (h, d)), ("w2", (d, h)), ("w3", (h, d))]
 
     def expert_matmul_shapes(self) -> list[tuple[str, tuple[int, int]]]:
@@ -862,6 +1012,11 @@ class TransformerSpec:
             return self._mixer_plans()
         norms = [("f32", n, (w,)) for n, w in self.layer_norm_shapes()]
         norms += [("f32", n, s) for n, s in self.hyper_shapes()]
+        if self.latent.noise_heads:
+            norms.append(("f32", "w_lambda",
+                          (self.latent_signal_heads, self.dim)))
+        if self.activation.kind == "polynorm":
+            norms.append(("f32", "pn_w", (4,)))
         dense = norms + [("mm", n, s)
                          for n, s in self.dense_layer_matmul_shapes()]
         shared = self.layer_matmul_shapes()
@@ -1052,6 +1207,22 @@ def _read_ext7(vals, n_layers: int) -> dict:
         MixerKind(s_heads, float(s_theta), s_rot,
                   scaling(s_scaled, yarn[6:]), s_kv, bool(s_sink)),
         bool(gate), v_head, float(v_scale)))
+
+
+def _read_ext9(vals, la: LatentAttn, n_layers: int) -> dict:
+    """What a version-9 header adds to ``latent`` (and ``activation``)
+    from its eight ints, three float64 and 128 bytes."""
+    groups, noise, gate, window, act, _, _, _, scale, clamp, _, *kinds = vals
+    kinds = [k for k in kinds[:n_layers] if k != 255]
+    if not 0 <= act < len(ACTIVATIONS) or any(
+            k >= len(MIXER_KINDS) for k in kinds):
+        raise ValueError("unknown activation or layer kind in a version-9 "
+                         "header")
+    return dict(
+        latent=dataclasses.replace(
+            la, kv_groups=groups, noise_heads=noise, gate=bool(gate),
+            window=window, kinds=tuple(MIXER_KINDS[k] for k in kinds)),
+        activation=Activation(ACTIVATIONS[act], float(scale), float(clamp)))
 
 
 def _read_ext5(vals, n_layers: int) -> dict:

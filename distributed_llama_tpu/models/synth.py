@@ -93,6 +93,16 @@ def hyper_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
     return x
 
 
+def polynorm_leaf(shape, unit) -> np.ndarray:
+    """A layer's ``pn_w`` (..., 4) from ``unit(*shape)`` ~ N(0, 1): the three
+    weights 1/3 + N(0, 0.1), the bias N(0, 0.3), so that a clamp at 0.5
+    bites in some layers and not in others."""
+    x = unit(*shape).astype(np.float32)
+    x[..., :3] = np.float32(1 / 3) + np.float32(0.1) * x[..., :3]
+    x[..., 3] *= np.float32(0.3)
+    return x
+
+
 def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
     """``_build_tree`` for a spec with several stacks of layers (an expert
     spec's leading dense ones under ``p["dense"]``, a hybrid spec's kinds
@@ -117,9 +127,13 @@ def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
         elif name.startswith("hc_"):
             dst[name] = hyper_leaf(spec, name, shape,
                                    lambda *s: t(*s) * np.float32(20.0))
-        elif name in ("moe_gate", "w_hgate"):
-            # rows ~N(0, 1/sqrt(dim)): a head's gate sigmoid(.) then
-            # spreads around 0.5 and no seeded head is shut or idle
+        elif name == "pn_w":
+            dst[name] = polynorm_leaf(shape,
+                                      lambda *s: t(*s) * np.float32(20.0))
+        elif name in ("moe_gate", "w_hgate", "w_lambda"):
+            # rows ~N(0, 1/sqrt(dim)): a head's gate sigmoid(.) (a signal
+            # head's lambda) then spreads around 0.5 and no seeded head is
+            # shut or idle
             dst[name] = t(*shape) * np.float32(20.0 / np.sqrt(spec.dim))
         elif name == "moe_bias":
             dst[name] = t(*shape)
@@ -343,7 +357,11 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
                     f.write(memoryview(np.ascontiguousarray(hyper_leaf(
                         spec, name, shape, lambda *s: rng.standard_normal(
                             s, dtype=np.float32)))).cast("B"))
-                elif name in ("moe_gate", "w_hgate"):
+                elif name == "pn_w":
+                    f.write(memoryview(polynorm_leaf(
+                        shape, lambda *s: rng.standard_normal(
+                            s, dtype=np.float32))).cast("B"))
+                elif name in ("moe_gate", "w_hgate", "w_lambda"):
                     f.write(f32(*shape, scale=1.0 / np.sqrt(spec.dim)))
                 elif name == "moe_bias":
                     f.write(f32(*shape, scale=0.05))

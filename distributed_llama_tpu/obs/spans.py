@@ -64,6 +64,14 @@ SCOPE_HC_MIX = "hc.mix"     # the sub-layer's input and the streams' update
 SCOPE_ATTN_GATE = "attn.gate"
 SCOPE_ATTN_SCALE = "attn.scale"
 SCOPE_ATTN_SINK = "attn.sink"
+# a latent spec's with noise heads, rings and PolyNorm (models/latent.py
+# opens the first two inside SCOPE_ATTN beside SCOPE_ATTN_GATE, its
+# elementwise gate; ops/linear.polynorm the third inside SCOPE_FFN): the
+# noise heads' subtraction with its per-token lambda and the one W_UV
+# product after it, a sliding layer's write of its ring, the activation
+SCOPE_ATTN_DIFF = "attn.diff"
+SCOPE_RING_WRITE = "ring.write"
+SCOPE_POLYNORM = "ffn.polynorm"
 
 
 def scope_rope(kind: str) -> str:

@@ -73,8 +73,10 @@ def _from_logits(hc, n: int, z: jax.Array):
     """z (2 n + n^2, rows), gated and biased -> (pre, post, [res rows])."""
     pre = jax.nn.sigmoid(z[:n])
     post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
-    rows = [jnp.exp(jnp.clip(z[(2 + i) * n:(3 + i) * n], hc.clamp_min,
-                             hc.clamp_max)) for i in range(n)]
+    # a spec that states no clamp (both bounds infinite) computes none
+    clip = ((lambda a: jnp.clip(a, hc.clamp_min, hc.clamp_max))
+            if hc.clamped else (lambda a: a))
+    rows = [jnp.exp(clip(z[(2 + i) * n:(3 + i) * n])) for i in range(n)]
     return pre, post, _sinkhorn_rows(rows, hc.sinkhorn_iters, hc.eps)
 
 
@@ -136,12 +138,15 @@ def residual_in(spec, lw: dict[str, Any], sub: str, x: jax.Array):
 
 
 def residual_out(coef: Coefficients | None, x: jax.Array,
-                 y: jax.Array) -> jax.Array:
-    """The carry after a sub-layer whose output is y (R, C)."""
+                 y: jax.Array, clamp: float = 0.0) -> jax.Array:
+    """The carry after a sub-layer whose output is y (R, C); the streams
+    written back clipped to +- ``clamp`` where the spec states one
+    (``HyperConnections.stream_clamp``)."""
     if coef is None:
         return x + y
     n = x.shape[0]
     with jax.named_scope(SCOPE_HC_MIX):
-        return jnp.stack(
+        out = jnp.stack(
             [sum(coef.res[i, j][:, None] * x[j] for j in range(n))
              + coef.post[i][:, None] * y for i in range(n)], axis=0)
+        return jnp.clip(out, -clamp, clamp) if clamp else out

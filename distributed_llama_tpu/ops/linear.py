@@ -18,6 +18,8 @@ Semantics contract (BASELINE.md logit parity):
 
 from __future__ import annotations
 
+import functools
+
 import contextlib
 import contextvars
 import os
@@ -101,6 +103,36 @@ def rmsnorm(x: jax.Array, weight: jax.Array,
 
 def silu(x: jax.Array) -> jax.Array:
     return x / (1.0 + jnp.exp(-x))
+
+
+def polynorm(z: jax.Array, pn_w: jax.Array, scale: float, clamp: float,
+             eps: float) -> jax.Array:
+    """``scale * (w0 n(z^3) + w1 n(z^2) + w2 n(z) + clip(b, -clamp,
+    clamp))``, ``n(u) = u / sqrt(mean(u^2) + eps)`` over z's last dim (an
+    FFN's own width: a (token, expert) pair's needs nothing of another
+    expert) and ``pn_w`` = (w0, w1, w2, b) (arXiv:2411.03884)."""
+    from ..obs.spans import SCOPE_POLYNORM
+
+    with jax.named_scope(SCOPE_POLYNORM):
+        z = z.astype(jnp.float32)
+        z2 = z * z
+        bias = jnp.clip(pn_w[3], -clamp, clamp) if clamp else pn_w[3]
+        out = sum(pn_w[i] * u * rms_inv(u, eps)
+                  for i, u in enumerate((z2 * z, z2, z)))
+        return jnp.float32(scale) * (out + bias)
+
+
+def ffn_activation(spec, lw):
+    """What a layer's FFN applies to its gate projection: ``silu``, or the
+    spec's PolyNorm over the layer's ``pn_w`` (``TransformerSpec.
+    activation``). The ONE place the four gated-FFN sites (models/llama.
+    _swiglu's two, ops/pallas_moe's slot and XLA expert paths) take it
+    from."""
+    act = spec.activation
+    if act.kind == "silu":
+        return silu
+    return functools.partial(polynorm, pn_w=lw["pn_w"], scale=act.scale,
+                             clamp=act.clamp, eps=spec.norm_eps)
 
 
 def dequantize_weight(w) -> jax.Array:

@@ -195,6 +195,8 @@ def supports(spec, params) -> bool:
         return False
     if spec.norm_eps != 1e-5 or spec.qk_norm:  # the kernels' own RMSNorm
         return False
+    if spec.activation.kind != "silu":  # the tail kernels hard-code SiLU: a
+        return False                    # PolyNorm spec takes the unfused path
     for key in ("wqkv", "wo", "w13", "w2"):
         w = params.get(key)
         if not (isinstance(w, Q40Kernel) and w.qs_t.ndim == 4):

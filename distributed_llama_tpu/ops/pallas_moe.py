@@ -79,7 +79,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
-from .linear import StackedQ40, matmul, matmul_mode, silu
+from .linear import StackedQ40, ffn_activation, matmul, matmul_mode, silu
 from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ,
                          _mask_pieces, _planes_dot)
 
@@ -391,7 +391,7 @@ def shape_places(d: int, nb: int) -> bool:
 
 
 def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret,
-                   bf16=False):
+                   bf16=False, act=silu):
     t, k = topi.shape
     cap = slot_cap(t, k, n_experts)
     (slot_expert, n_slots, fill, slot_rows, pair_slot, pair_lane,
@@ -406,7 +406,9 @@ def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret,
 
     h13 = call(w13, xb, slot_rows)                       # (A, C, 2 hidden)
     hid = h13.shape[-1] // 2
-    out = call(w2, silu(h13[..., :hid]) * h13[..., hid:])   # (A, C, dim)
+    # the activation between the two calls: a PolyNorm's mean runs over a
+    # slot row's own hidden width (a lane no pair fills reads 0)
+    out = call(w2, act(h13[..., :hid]) * h13[..., hid:])    # (A, C, dim)
     picked = out[pair_slot, pair_lane]                   # (T, k, dim)
     # a pair that took no slot reads whatever lies at the clamped index
     picked = jnp.where((topi >= 0)[..., None], picked, 0.0)
@@ -421,7 +423,7 @@ def _routing_mask(topw, topi, n_experts):
             jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32))
 
 
-def _experts_xla(lw, xb, topw, topi, n_experts):
+def _experts_xla(lw, xb, topw, topi, n_experts, act=silu):
     """One expert at a time through ``ops/linear.matmul`` (codec Q40 or
     dense leaves): every row through every expert, weighted 0 where it was
     not routed. Holds one dequantized expert at a time."""
@@ -429,7 +431,7 @@ def _experts_xla(lw, xb, topw, topi, n_experts):
 
     def body(acc, ws):
         w1, w2, w3, m = ws
-        h = silu(matmul(w1, xb)) * matmul(w3, xb)
+        h = act(matmul(w1, xb)) * matmul(w3, xb)
         return acc + matmul(w2, h * m[:, None]), None
 
     acc, _ = jax.lax.scan(body, jnp.zeros_like(xb, dtype=jnp.float32),
@@ -467,11 +469,13 @@ def moe_ffn(spec, lw: dict, xb: jax.Array):
             layer = jnp.asarray(w13.layer, dtype=jnp.int32).reshape(1)
             y, counts = _experts_slots(layer, w13.w, w2.w, x2, topw, topi,
                                        n_exp, interpret,
-                                       matmul_mode() == "bf16")
+                                       matmul_mode() == "bf16",
+                                       ffn_activation(spec, lw))
         elif "moe_w1" not in lw or isinstance(lw["moe_w1"], StackedQ40):
             raise NotImplementedError(
                 "expert stacks packed for the kernels without their fused "
                 "moe_w13 (ops/linear.fuse_q40_layer_matmuls)")
         else:
-            y, counts = _experts_xla(lw, x2, topw, topi, n_exp)
+            y, counts = _experts_xla(lw, x2, topw, topi, n_exp,
+                                     ffn_activation(spec, lw))
     return y.reshape(*lead, -1), counts if routed is None else routed

@@ -61,6 +61,11 @@ class Request:
     index: int = -1  # submission order; assigned by submit()
     error: str | None = None  # set (before done) if the engine failed it
     cancelled: bool = False  # consumer gone: retire at the next step
+    # () -> bool, asked once where the request leaves the queue: False and
+    # the consumer has gone while it waited, so it is completed as cancelled
+    # and no admission prefill is spent on it (runtime/server.py sets it to
+    # a look at the client's socket). None: nobody to ask.
+    alive: Any = None
     # SLO priority class (obs/slo.py): None = the policy's default class;
     # the tracker resolves it at retire. Ignored on engines without a
     # policy.
@@ -192,16 +197,23 @@ def _warn_q8_xla_fallback(spec: TransformerSpec, page_size: int,
 
 def sequence_caches(spec) -> frozenset:
     """What one sequence of ``spec`` caches, by kind: "pages" (K and V of
-    every position, which page), "plane" (a latent spec's one plane a
-    position, behind the same page tables) and "state" (a slot of fixed
-    size that a step rewrites: a recurrent state, a window ring). A hybrid
-    spec keeps a state AND one layer's pages, a mixer-kinds spec rings AND
-    its full layers' pages ("rings" rides along: its slot is window rings
-    alone). "streams" rides along where the residual path is several
-    streams (``spec.hyper``): nothing a sequence caches, but the list below
-    names it in its reasons."""
+    every position, which page), "plane" (a latent spec's one row
+    [c_kv | k_rope] a position and full layer, behind the same page tables:
+    a spec whose layers are all "full" caches that plane a layer and
+    nothing else, which is what "a latent spec caches one plane" means
+    wherever this module and models/latent.py say it) and "state" (a slot
+    of fixed size that a step rewrites: a recurrent state, a window ring).
+    A hybrid spec keeps a state AND one layer's pages, a mixer-kinds spec
+    rings AND its full layers' pages ("rings" rides along: its slot is
+    window rings alone), a latent spec with sliding layers rings of latent
+    rows AND its full layers' plane. "streams" rides along where the
+    residual path is several streams (``spec.hyper``): nothing a sequence
+    caches, but the list below names it in its reasons."""
     if spec.mixers:
         return frozenset({"state", "pages", "rings"})
+    if spec.latent and spec.slotted:
+        return frozenset({"state", "plane", "rings"}
+                         | ({"streams"} if spec.hyper else set()))
     if spec.hybrid:
         return frozenset({"state", "pages"})
     if spec.retention:
@@ -227,6 +239,14 @@ _WHY = {
                                             "sliding layer beside its "
                                             "full layers' KV pages",
 }
+_WHY[frozenset({"state", "plane", "rings"})] = (
+    "a latent-attention model with sliding layers keeps a ring of latent "
+    "rows of fixed size a sliding layer beside its full layers' plane "
+    "[c_kv | k_rope], not K and V")
+_WHY[frozenset({"state", "plane", "rings", "streams"})] = (
+    "a latent-attention model with sliding layers and several residual "
+    "streams keeps a ring of latent rows of fixed size a sliding layer "
+    "beside its full layers' plane [c_kv | k_rope], not K and V")
 
 
 def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
@@ -257,15 +277,20 @@ def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
     out = []
 
     def refuse(flag: str, for_state: str | None, for_plane: str | None):
-        reason = for_state if state else for_plane if plane else None
+        # rings of latent rows beside a plane: what a state refuses, and
+        # what a plane refuses where a state has nothing to say
+        reason = (for_state if state and for_state else
+                  for_plane if plane else None)
         if reason is not None:
             out.append(f"{flag}: {why}; {reason}")
 
     if tp > 1:
         if "rings" in caches:
             out.append(f"--tp {tp}: {why}; neither the rings, the kinds' "
-                       f"head counts nor the experts held here are placed "
-                       f"over tensor-parallel ranks")
+                       f"head counts"
+                       + (", the latent plane" if plane else "")
+                       + " nor the experts held here are placed "
+                       "over tensor-parallel ranks")
         elif state:
             from ..ops import mamba, retention
 
@@ -282,7 +307,8 @@ def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
                "slot of fixed size, with no positions to page", None)
     if serve and not page_size and paged:
         refuse("serve without --kv-page-size", "serve reads the full "
-               "layer's K / V through pages only (pass --kv-page-size)",
+               + ("layers' plane" if plane else "layer's K / V")
+               + " through pages only (pass --kv-page-size)",
                "serve reads it through pages only (pass --kv-page-size)")
     if prefix_share:
         refuse("prefix sharing (prefix_share)", "a state cannot be shared "
@@ -1145,7 +1171,7 @@ class ContinuousEngine:
                                             page_size=page_size)
             self._insert = _shared_program(
                 ("insert", self._state, self._hybrid and page_size,
-                 bool(spec.mixers)),
+                 bool(spec.mixers), bool(spec.latent)),
                 lambda: jax.jit(
                     named_program("serve_admit_state_insert" if self._state
                                   else "serve_admit_insert", _insert),
@@ -2740,7 +2766,8 @@ class ContinuousEngine:
                 depth = [int(blk[b, 1]) + 1 for b, s in enumerate(rows)
                          if s is not None]
                 if self._hybrid:
-                    w = (self.spec.hybrid or self.spec.mixers).window
+                    w = (self.spec.hybrid or self.spec.mixers
+                         or self.spec.latent).window
                     self.stats.shared_kv_positions += sum(depth)
                     self.stats.window_kv_positions += sum(
                         min(d, w) for d in depth)
@@ -2828,7 +2855,8 @@ class ContinuousEngine:
             if flight.norm_min is not None:  # (L,) floats
                 health = np.asarray(flight.norm_min)  # dlint: allow[D001] normaliser counter
                 low = float(health.min())
-                if self.spec.mixers:    # (2,): smallest and mean gate
+                if self.spec.mixers or self.spec.latent:
+                    # (2,): smallest and mean gate
                     self.stats.count_gate(low, float(health[1]))
                 elif self._hybrid:
                     self.stats.ssm_min_decay = min(self.stats.ssm_min_decay,
@@ -2994,6 +3022,11 @@ class ContinuousEngine:
                 req = self._queue.pop(at)
                 if self._obs is not None:
                     self._obs.set_queue_depth(len(self._queue))
+            if not req.cancelled and req.alive is not None \
+                    and not req.alive():
+                req.on_token, req.cancelled = None, True
+                if self._obs is not None:
+                    self._obs.cancelled.inc()
             if not req.cancelled:
                 return req
             if self._journal is not None:

@@ -255,7 +255,8 @@ class Engine:
         with host_phase("inference.fetch"):  # the wait and the transfer
             if step.norm_min:  # (L,) floats beside the rest
                 low = float(np.asarray(step.norm_min[0]).min())  # dlint: allow[D001] normaliser counter
-                if self.spec.mixers:    # (smallest gate, mean gate)
+                if self.spec.mixers or self.spec.latent:
+                    # (smallest gate, mean gate)
                     self.gate_min = min(self.gate_min, low)
                 elif self.spec.hybrid:
                     self.ssm_min_decay = min(self.ssm_min_decay, low)

@@ -62,7 +62,9 @@ and the ``dllama_health_state`` gauge.
 from __future__ import annotations
 
 import json
+import select
 import signal
+import socket
 import sys
 import threading
 import time
@@ -92,6 +94,19 @@ class OversizedRequest(ValueError):
     """A request the model literally cannot serve (prompt or steps beyond
     seq_len) — its own 400 + ``admission_rejected{reason="oversized"}``
     series, distinct from malformed-payload bad_request."""
+
+
+def _peer_open(conn: socket.socket) -> bool:
+    """False once the peer has closed its end of ``conn`` (an EOF or a
+    reset is waiting to be read); never blocks, consumes nothing. A client
+    that half-closes after its request and still reads is taken for gone,
+    as by most HTTP servers."""
+    try:
+        ready = select.poll()
+        ready.register(conn, select.POLLIN)
+        return not ready.poll(0) or conn.recv(1, socket.MSG_PEEK) != b""
+    except (OSError, ValueError):
+        return False
 
 
 class _BurstHTTPServer(ThreadingHTTPServer):
@@ -443,6 +458,9 @@ class InferenceServer:
                     req, submit = server.remote_prefill(req)
                 else:
                     submit = lambda r=req: server.engine.submit(r)  # noqa: E731
+                # asked where the request leaves the queue: a client that
+                # hung up while it waited gets no admission prefill
+                req.alive = lambda c=self.connection: _peer_open(c)
                 if stream:
                     return self._stream(req, submit)
                 if submit is not None:

@@ -750,3 +750,79 @@ def test_listener_holds_a_burst_of_connections(params):
         srv.httpd.server_close()
         srv.engine.close()
     assert len(socks) == 64
+
+
+@pytest.mark.parametrize("hangs_up", [True, False])
+def test_a_client_gone_while_queued_gets_no_admission(params, hangs_up):
+    """A request is asked once, where it leaves the queue, whether its
+    client still listens (``Request.alive``): one that hung up while it
+    waited is completed as cancelled and never placed in a slot; one that
+    waits is served. (A closed loop cut by its clients left 32 such
+    requests behind, and their admission prefills, 38 s of device work,
+    outlasted ``stop()``.)"""
+    import http.client
+    import time
+
+    from distributed_llama_tpu.runtime.server import InferenceServer
+
+    srv = InferenceServer(SPEC, params, _IdTokenizer(), "127.0.0.1", 0,
+                          slots=1, steps=8, temperature=0.0, topp=0.9,
+                          seed=5, quiet=True)
+    gate = threading.Event()
+    step_many = srv.engine.step_many
+
+    def held(*a, **kw):         # the scheduler admits nothing until opened
+        gate.wait(30)
+        return step_many(*a, **kw)
+
+    srv.engine.step_many = held
+    srv.start()
+    try:
+        conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=30)
+        conn.request("POST", "/generate",
+                     body=json.dumps({"prompt": "hello", "steps": 8,
+                                      "stream": True}))
+        deadline = time.time() + 30
+        while not srv.engine._queue and time.time() < deadline:
+            time.sleep(0.01)
+        req = srv.engine._queue[0]
+        if hangs_up:
+            conn.close()
+        gate.set()
+        assert req.done.wait(30)
+        if not hangs_up:
+            lines = [json.loads(ln) for ln in conn.getresponse()
+                     if ln.strip()]
+            conn.close()
+    finally:
+        gate.set()
+        srv.stop()
+    if hangs_up:
+        assert req.cancelled and req.t_admit == 0.0 and not req.out
+        assert srv.engine.stats.tokens == 0
+    else:
+        assert not req.cancelled and req.t_admit > 0.0
+        assert lines[-1]["done"] and lines[-1]["steps"] == len(req.out) > 0
+
+
+@pytest.mark.parametrize("peer, open_", [("waits", True), ("sent", True),
+                                         ("closed", False)])
+def test_peer_open_reads_nothing_and_never_blocks(peer, open_):
+    import socket
+
+    from distributed_llama_tpu.runtime.server import _peer_open
+
+    ours, theirs = socket.socketpair()
+    try:
+        if peer == "sent":
+            theirs.sendall(b"x")
+        if peer == "closed":
+            theirs.close()
+        assert _peer_open(ours) is open_
+        assert _peer_open(ours) is open_
+        if peer == "sent":
+            assert ours.recv(1) == b"x"
+    finally:
+        ours.close()
+        theirs.close()
+    assert _peer_open(ours) is False     # a closed socket of our own
