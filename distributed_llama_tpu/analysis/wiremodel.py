@@ -690,9 +690,11 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_moe_expert_rows_total", labels=("expert",)),
     # ... of which on experts held here; a latent spec's pages in use
     _m("dllama_moe_local_pairs_total"),
-    # how the slot kernel engaged: live slots, and those of one row
+    # how the slot kernel engaged: live slots, those of one row, and those
+    # that took the block-diagonal body
     _m("dllama_moe_slots_total"),
     _m("dllama_moe_single_row_slots_total"),
+    _m("dllama_moe_diag_slots_total"),
     _m("dllama_moe_chunk_pairs_total"),
     _m("dllama_moe_chunk_slots_total"),
     _m("dllama_latent_pages_in_use"),

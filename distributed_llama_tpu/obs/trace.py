@@ -205,7 +205,12 @@ class EngineMetrics:
             "held expert's rows in slots of up to 8), summed over layers")
         self.moe_single_row_slots = c(
             "dllama_moe_single_row_slots_total",
-            "Of those, slots that held one row (the kernel's one-row body)")
+            "Of those, slots that held one row")
+        self.moe_diag_slots = c(
+            "dllama_moe_diag_slots_total",
+            "Of those, slots that took the kernel's block-diagonal body (1 "
+            "or 2 live rows on leaves whose block count is a multiple of 8; "
+            "fuller slots run the MXU tile)")
         self.moe_chunk_pairs = c(
             "dllama_moe_chunk_pairs_total",
             "Routed pairs that landed on held experts in admission prefill "
@@ -596,15 +601,17 @@ class EngineMetrics:
             self.fetch_wait_behind_admit.inc(wait_s)
 
     def record_moe(self, counts, held: slice = slice(None),
-                   slots: tuple = (0, 0)) -> None:
+                   slots: tuple = (0, 0, 0)) -> None:
         """One decode dispatch's (L, E) rows-per-expert counts; ``held``
         the columns of the experts the engine holds; ``slots`` its (live
-        slots, one-row slots) as the slot kernel saw them."""
+        slots, one-row slots, block-diagonal slots) as the slot kernel saw
+        them."""
         self.moe_pairs.inc(int(counts.sum()))
         self.moe_local_pairs.inc(int(counts[:, held].sum()))
         self.moe_active.inc(int((counts[:, held] > 0).sum()))
         self.moe_slots.inc(slots[0])
         self.moe_single_row_slots.inc(slots[1])
+        self.moe_diag_slots.inc(slots[2])
         if not self._moe_rows:
             self._moe_rows = [
                 self.registry.labeled_counter(

@@ -29,7 +29,9 @@ the router chose are computed. The dispatch's pairs are grouped into SLOTS
 of one expert and up to C rows; the grid walks the slots from a
 scalar-prefetched list, experts ascending, so a distinct expert's tile is
 fetched ONCE (a second slot of the same expert repeats the block index and
-Pallas skips the copy), never all E and never once per pair. The slot count
+Pallas skips the copy; in the decode slots' kernel, whose grid walks a
+slot's row tiles before the next slot, once a slot: ``moe_q40_slots``),
+never all E and never once per pair. The slot count
 is a static bound (every expert active, every capacity overflowing); the
 live count is data, and the steps past it are skipped and repeat the last
 live slot's block indices, so they move nothing. A full slot opens another:
@@ -47,20 +49,37 @@ fact). Until PR 36 a dispatch wider than 32 rows ran EVERY held expert over
 EVERY row and weighted the rows an expert was not routed by 0: 8 and 32
 times the routed work of those two chunks.
 
-The slot's LIVE ROW COUNT (a fourth prefetched list, ``fill``) alone picks
-its body: a slot of one row takes ``_row_body`` (the nb-major matvec's
-arithmetic, 8 vector operations a packed byte; at T == 1 the only body),
-any other the MXU tile ``_mxu_body_merged`` (the dense T > 1 tile's
+The slot's LIVE ROW COUNT (a fourth prefetched list, ``fill``) and the
+leaf's block count alone pick its body (``diag_rows``). In the decode slots'
+kernel (up to 8 rows a slot, and the one row of T == 1) on a leaf whose
+block count is a multiple of 8 (every model's), a slot of 1 or 2 live rows
+takes ``_diag_body``: the dense T = 1 matvec's block-diagonal MXU product
+(ops/pallas_q40, PR 49: raw codes pushed to the MXU against a row's three
+bf16 pieces laid block-diagonal over groups of 8 blocks, the ``- 8`` fold and
+the scale applied to a block's (8, R) product and not to a weight), ONE
+static body of two stacked rows, 48 left-hand rows a group; the live rows'
+planes are built in the kernel by a loop over them. (PR 53 unrolled a body a
+row count up to 3: faster at 3 rows, within 2 to 5 % at 1 and 2, and refused
+for what three bodies cost every program's tracing at start-up; one body
+whose row count is a loop bound, PR 54's first form, ran at half the speed:
+PERF.md sections 6 and 7.) On any other leaf a slot of one row takes
+``_row_body`` (the nb-major vector matvec's arithmetic, 8 vector operations a
+packed byte), picked statically, so only one of the two is traced. Any
+fuller slot, and every slot of over one row in a wider dispatch's kernel (a
+chunk's, whose slots are mostly full: it is the kernel it was, body for
+body), takes the MXU tile ``_mxu_body_merged`` (the dense T > 1 tile's
 arithmetic, ``ops/pallas_q40._planes_dot``, with all 16 nibble planes in one
 contraction; a dense leaf merges as many as its block count asks for, PR 51)
-over the smallest of 8 / 16 / 32 / C rows that holds it
-(``_tile_rows``: a part-filled slot of a wide dispatch pays for its rows,
-not for C). On a v5e the tile streams an expert at 370-390 GB/s at 8 rows
-(270-290 until PR 38) and the one-row body at 520-610 (PERF.md section 7,
-PRs 34, 36 and 38).
+over the smallest of 8 / 16 / 32 / C rows that holds it (``_tile_rows``: a
+part-filled slot of a wide dispatch pays for its rows, not for C). On a v5e
+the tile streams an expert at 370-390 GB/s whatever it holds (it multiplies
+the scale onto every weight before the dot and is bound by that unpack),
+``_row_body`` at 520-610 and the block-diagonal body at 630-840 (PERF.md
+section 7 has it by leaf and fill: PRs 53 and 54).
 
-All are the float32 arithmetic of the dense Q40 kernels: exact on the VPU,
-and on the MXU the tile's five bf16 passes (``ops/pallas_q40._five_pass_dot``:
+All are the float32 arithmetic of the dense Q40 kernels: exact on the VPU
+and in the block-diagonal product (a code times a bf16 piece is exact and
+the MXU adds in float32), and the tile's five bf16 passes (``ops/pallas_q40._five_pass_dot``:
 a Q40 weight, code x scale, has 15 significant bits and IS two bf16 numbers,
 so it is split in two exactly and multiplied by the row's three pieces: what
 ``Precision.HIGHEST``'s six passes add, without its three-way split of the
@@ -80,13 +99,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
 from .linear import StackedQ40, ffn_activation, matmul, matmul_mode, silu
-from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _VMEM64_PARAMS, NJ,
-                         _mask_pieces, _planes_dot)
+from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _T1_CHUNK, _T1_GROUP,
+                         _VMEM64_PARAMS, NJ, _diag_planes_nb, _mask_pieces,
+                         _planes_dot)
 
 MOE_SLOT_ROWS = 8                # rows of a narrow dispatch's slot: one sublane tile
 MOE_SLOT_T_MAX = 32              # widest dispatch whose slots are one such tile
 MOE_WIDE_ROWS = 32               # most rows a wider one's slot is worth
 MOE_SLOT_TILE_MAX = 2048         # largest row tile measured (PERF.md section 7)
+MOE_DIAG_ROWS = 2                # fullest slot of the block-diagonal body
 
 
 def route(gate: jax.Array, xb: jax.Array, k: int, router=None, bias=None):
@@ -147,12 +168,38 @@ def slot_cap(t: int, k: int, n_experts: int) -> int:
     return min(max(-(-2 * expect // 8) * 8, 16), MOE_WIDE_ROWS)
 
 
-def slot_census(counts, cap: int) -> tuple[int, int]:
-    """(live slots, slots of one row) of dispatches whose routed rows per
-    held expert are ``counts`` (any shape; numpy, on the host) at ``cap``
-    rows a slot: what ``build_slots`` builds from them, for the counters."""
-    single = counts if cap == 1 else counts % cap == 1
-    return int((-(-counts // cap)).sum()), int(single.sum())
+def diag_rows(cap: int, *nbs: int) -> int:
+    """Most live rows of a slot that takes the block-diagonal body
+    (``_diag_body``) at ``cap`` rows a slot on a leaf of ``nb`` blocks a
+    row (on every one of several: the engine's counter asks both of an
+    expert's); 0: no slot does. What the kernel sees decides: the block
+    count (the body walks groups of 8 blocks: ``ops/pallas_q40._t1_mxu``'s
+    rule; any other leaf keeps ``_row_body``) and the capacity (the decode slots'
+    kernel only: a wider dispatch's slots, ``moe_q40_grouped``, are mostly
+    full and every chunk program would pay the body's tracing). Up to
+    ``MOE_DIAG_ROWS`` = 2 rows, which the ONE body stacks: at 1 and 2 rows
+    it is 1.4 to 2 times ahead of ``_row_body`` and the tile on every expert
+    leaf; a body of its own for 3 and for 4 rows was ahead of the tile by
+    a third and a tenth and cost every program that holds the kernel its
+    tracing (PERF.md section 7, PRs 53 and 54)."""
+    if any(nb % _T1_GROUP for nb in nbs) or cap > MOE_SLOT_ROWS:
+        return 0
+    return min(MOE_DIAG_ROWS, cap)
+
+
+def slot_census(counts, cap: int, top: int = 0) -> tuple[int, int, int]:
+    """(live slots, slots of one row, slots of 1 to ``top`` rows) of
+    dispatches whose routed rows per held expert are ``counts`` (any shape;
+    numpy, on the host) at ``cap`` rows a slot: what ``build_slots`` builds
+    from them, for the counters. With ``top`` the ``diag_rows`` of the
+    expert leaves, the third is the slots that took the block-diagonal
+    body."""
+    live = int((-(-counts // cap)).sum())
+    if cap == 1:                     # a row a slot
+        return live, live, live if top else 0
+    last = counts % cap              # an expert's last slot, if part filled
+    return (live, int((last == 1).sum()),
+            int(((last >= 1) & (last <= top)).sum()))
 
 
 def max_slots(t: int, k: int, n_experts: int, cap: int) -> int:
@@ -202,7 +249,74 @@ def build_slots(topi: jax.Array, n_experts: int, cap: int):
             slot_rows, slot.reshape(t, k), lane.reshape(t, k), counts)
 
 
-# -- the slot kernel (the MXU tile; a one-row body beside it) ------------------
+# -- the slot kernel (the MXU tile; a body for part-filled slots beside it) -----
+
+def _diag_body(qs_ref, s_ref, out_ref, l_scr, xs_scr):
+    """A slot's first ``top`` rows against one row tile: the dense T = 1
+    matvec's block-diagonal MXU product (ops/pallas_q40's section comment,
+    PR 49) with the rows STACKED in the left-hand side. qs_ref (NJ, nb, R)
+    uint8 codes, s_ref (nb, R) f32 scales; l_scr (nb / 8, 24 top, 256) the
+    rows' block-diagonal planes, 24 left-hand rows a row of the slot, and
+    xs_scr (top, nb, 1) their block sums (``_diag_planes``). In a group's
+    turn the 32 blocks' codes are unpacked once and a group of 8 blocks
+    meets ONE dot of 24 ``top`` left-hand rows; the ``- 8`` fold and the
+    scale are applied to a block's (8, R) product. Exact in float32, as
+    ``_row_body``. Writes rows 0 to ``top`` of ``out_ref``; a row past the
+    slot's live ones reads the planes an earlier slot left and is never
+    read back (a row of the product depends on its own planes alone)."""
+    nb, r = s_ref.shape
+    top = xs_scr.shape[0]
+    f32 = jnp.float32
+    dn = (((1,), (0,)), ((), ()))
+    g = _T1_GROUP
+
+    def turn(start, blocks, g0, acc):
+        # whole planes at a time: an operation traced is set-up time on
+        # every run (ops/pallas_q40._matvec_body_nb_mxu's turn, the rows'
+        # accumulators as one (top, 8, R) value for the same reason)
+        q = qs_ref[:, pl.ds(start, blocks), :].astype(jnp.int32)
+        codes = jnp.concatenate([(q & 0xF).astype(f32),
+                                 (q >> 4).astype(f32)])  # (32, blocks, R)
+        for k in range(blocks // g):
+            rhs = jax.lax.slice_in_dim(codes, k * g, (k + 1) * g, axis=1)
+            p = jax.lax.dot_general(
+                l_scr[g0 + k], rhs.reshape(2 * NJ * g, r), dn,
+                preferred_element_type=f32).reshape(top, 3, g, r)
+            b = pl.ds(start + k * g, g)
+            blk = (p[:, 2] + p[:, 1]) + p[:, 0]         # small pieces first
+            acc = acc + (blk - xs_scr[:, b, :]) * s_ref[b, :]
+        return acc
+
+    full, tail = divmod(nb, _T1_CHUNK)
+    acc = jnp.zeros((top, g, r), f32)
+    if full:
+        acc = jax.lax.fori_loop(
+            0, full, lambda c, acc: turn(
+                pl.multiple_of(c * _T1_CHUNK, _T1_CHUNK), _T1_CHUNK, c * 4,
+                acc), acc)
+    if tail:
+        acc = turn(full * _T1_CHUNK, tail, full * 4, acc)
+    out_ref[0:top, :] = jnp.sum(acc, axis=1)
+
+
+def _diag_planes(x_ref, l_scr, xs_scr, sum_scr, rows):
+    """Build the block-diagonal planes and block sums of a slot's ``rows``
+    live rows (data) from the rows as they are, x_ref (top, n / 128, 128),
+    into ``l_scr`` / ``xs_scr`` (``_diag_body``), a row a turn of ONE loop
+    (``ops/pallas_q40._diag_planes_nb``, as the dense matvec builds its
+    own: traced once whatever the slot holds)."""
+    nb = xs_scr.shape[1]
+
+    def build(t, carry):
+        lhs = l_scr.at[:, pl.ds(pl.multiple_of(24 * t, 8), 24), :]
+        _diag_planes_nb(x_ref.at[t], lhs, sum_scr, nb)
+        # (the chip's compiler refuses a VIEW of a one-lane buffer at a row
+        # that is data: the sums land in a row's worth and are copied)
+        xs_scr[t] = sum_scr[...]
+        return carry
+
+    jax.lax.fori_loop(0, rows, build, 0)
+
 
 def _row_body(qs_ref, s, xp_ref, out_ref):
     """ONE row against the tile: ``_matvec_body_nb``'s arithmetic
@@ -243,23 +357,43 @@ def _tile_rows(c: int) -> tuple[int, ...]:
 
 
 def _kernel_moe_slots(layer_ref, sexp_ref, n_ref, fill_ref, qs_ref,
-                      scale_ref, *refs, bf16):
-    """One slot, its body picked by its live rows (``fill_ref``; 0 past the
-    live count: nothing runs). ``refs``: at C > 1 rows a slot the merged
-    planes xlo, xhi (C, NJ * nb) of the slot's rows; always the packed
-    planes (nb, 128) of its FIRST row; out."""
+                      scale_ref, *refs, bf16, top):
+    """One row tile of one slot, its body picked by the slot's live rows
+    (``fill_ref``; 0 past the live count: nothing runs). ``top`` 0: a slot
+    of one row takes ``_row_body``, the grid is (row tiles, slots) and
+    ``refs`` are, at C > 1 rows a slot, the merged planes xlo, xhi
+    (C, NJ * nb) of the slot's rows; always the packed planes (nb, 128) of
+    its FIRST row; out. ``top`` > 0 (``diag_rows``): a slot of up to ``top``
+    rows takes ``_diag_body``, the grid is (slots, row tiles) and in place
+    of the packed planes come the slot's first ``top`` rows as they are
+    (top, n / 128, 128), and after out the body's three scratch buffers.
+    A fuller slot runs the MXU tile either way."""
     del layer_ref, sexp_ref, n_ref  # consumed by the index maps
-    *tile_refs, row_ref, out_ref = refs
-    rows = fill_ref[pl.program_id(1)]
+    if top:
+        *tile_refs, x_ref, out_ref, l_scr, xs_scr, sum_scr = refs
+        rows = fill_ref[pl.program_id(0)]
+        part = (rows >= 1) & (rows <= top)
 
-    @pl.when(rows == 1)
-    def _():
-        _row_body(qs_ref, scale_ref[...], row_ref, out_ref)
+        # at the slot's first row tile, for its later ones to read
+        @pl.when(part & (pl.program_id(1) == 0))
+        def _():
+            _diag_planes(x_ref, l_scr, xs_scr, sum_scr, rows)
+
+        @pl.when(part)
+        def _():
+            _diag_body(qs_ref, scale_ref, out_ref, l_scr, xs_scr)
+    else:
+        *tile_refs, row_ref, out_ref = refs
+        rows = fill_ref[pl.program_id(1)]
+
+        @pl.when(rows == 1)
+        def _():
+            _row_body(qs_ref, scale_ref[...], row_ref, out_ref)
 
     if not tile_refs:  # at T == 1 a slot never holds a second row
         return
     sizes = _tile_rows(out_ref.shape[0])
-    for lo, hi in zip((1,) + sizes, sizes):
+    for lo, hi in zip((max(top, 1),) + sizes, sizes):
         whole = hi == sizes[-1]
 
         @pl.when(rows > lo if whole else (rows > lo) & (rows <= hi))
@@ -284,42 +418,71 @@ def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
     then built once a row and gathered, not once a slot lane); C is one row
     (T == 1) or a multiple of 8 (``slot_cap``). The tile multiplies the
     rows' three bf16 pieces by the weight's two (``_mxu_body_merged``);
-    ``bf16``: one piece a side (fast-prefill); the one-row body stays
-    exact. In a capture the call is ``moe_q40_slots`` up to one sublane
-    tile a slot (the decode steps' kernel, which the benchmark's roofline
-    shares find by that name) and ``moe_q40_grouped`` beyond (a chunk's)."""
+    ``bf16``: one piece a side (fast-prefill); the part-filled slots'
+    bodies stay exact. In a capture the call is ``moe_q40_slots`` up to one
+    sublane tile a slot (the decode steps' kernel, which the benchmark's
+    roofline shares find by that name) and ``moe_q40_grouped`` beyond (a
+    chunk's). Where ``diag_rows`` admits the leaf the grid walks (slots,
+    row tiles), so that a slot's rows are fetched and its block-diagonal
+    planes built once a slot and not once a tile (DeepSeek-V3's ``w13`` is
+    eight tiles a slot); a second slot of one expert, rare at 8 rows a
+    slot, then fetches the expert's tiles again. Elsewhere (row tiles,
+    slots): the slots of one expert follow each other with the same weight
+    block index, which skips the re-fetch."""
     nb, d = qs_t.shape[-2], qs_t.shape[-1]
     a, c = xs.shape[:2] if rows is None else rows.shape
+    n_tiles, top = d // block_rows, diag_rows(c, nb)
 
     def at(g, N):
         # a dead slot repeats the last live one's block indices: nothing
         # is fetched for it and nothing written back
         return jnp.maximum(jnp.minimum(g, N[0] - 1), 0)
 
+    def tile(g, i, N):
+        # ... slots outermost, that is the last live slot's LAST tile
+        return jnp.where(g < N[0], i, n_tiles - 1) if top else i
+
+    def spec(shape, index):
+        """``index`` (slot g, row tile i, N) under the grid's order."""
+        if top:
+            return pl.BlockSpec(shape, lambda g, i, L, S, N, F:
+                                index(g, i, L, S, N))
+        return pl.BlockSpec(shape, lambda i, g, L, S, N, F:
+                            index(g, i, L, S, N))
+
     planes = [] if c == 1 else [p if rows is None else p[rows]
                                 for p in _merged_planes(xs, nb)]
-    planes.append(_row_planes(xs[:, 0], nb) if rows is None
-                  else _row_planes(xs, nb)[rows[:, 0]])
+    scratch = []
+    if top:
+        raw = xs.astype(jnp.float32).reshape(*xs.shape[:-1], nb // 4, 128)
+        planes.append(raw if rows is None else raw[rows[:, :top]])
+        scratch = [pltpu.VMEM((nb // 8, 24 * top, 256), jnp.float32),
+                   pltpu.VMEM((top, nb, 1), jnp.float32),
+                   pltpu.VMEM((nb, 1), jnp.float32)]
+    else:
+        planes.append(_row_planes(xs[:, 0], nb) if rows is None
+                      else _row_planes(xs, nb)[rows[:, 0]])
+    blocks = [p.shape[1:] for p in planes]
+    if top:   # of all C rows where ``xs`` holds them the first ``top``: no copy
+        blocks[-1] = (top, nb // 4, 128)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        # slots innermost: the slots of one expert follow each other with
-        # the same weight block index, which is what skips the re-fetch
-        grid=(d // block_rows, a),
+        grid=(a, n_tiles) if top else (n_tiles, a),
         # leading dims are squeezed (None): the body sees (NJ, nb, rows)
         # codes, (nb, rows) scales and one slot's planes
         in_specs=[
-            pl.BlockSpec((None, None, NJ, nb, block_rows),
-                         lambda i, g, L, S, N, F: (L[0], S[g], 0, 0, i)),
-            pl.BlockSpec((None, None, nb, block_rows),
-                         lambda i, g, L, S, N, F: (L[0], S[g], 0, i)),
-        ] + [pl.BlockSpec((None,) + p.shape[1:],
-                          lambda i, g, L, S, N, F: (at(g, N), 0, 0))
-             for p in planes],
-        out_specs=pl.BlockSpec((None, c, block_rows),
-                               lambda i, g, L, S, N, F: (at(g, N), 0, i)),
+            spec((None, None, NJ, nb, block_rows),
+                 lambda g, i, L, S, N: (L[0], S[g], 0, 0, tile(g, i, N))),
+            spec((None, None, nb, block_rows),
+                 lambda g, i, L, S, N: (L[0], S[g], 0, tile(g, i, N))),
+        ] + [spec((None,) + block, lambda g, i, L, S, N, z=(0,) * len(block):
+                  (at(g, N),) + z) for block in blocks],
+        out_specs=spec((None, c, block_rows),
+                       lambda g, i, L, S, N: (at(g, N), 0, tile(g, i, N))),
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        functools.partial(_kernel_moe_slots, bf16=bf16),
+        functools.partial(_kernel_moe_slots, bf16=bf16, top=top),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((a, c, d), jnp.float32),
         compiler_params=_VMEM64_PARAMS, interpret=interpret,

@@ -495,9 +495,11 @@ class ContinuousStats:
     # how the slot kernel engaged (ops/pallas_moe): live slots of the
     # counted dispatches (a held expert's rows in slots of ``slot_cap``
     # rows: ceil(count / cap), summed over layers and steps), and those of
-    # them that held ONE row, which take the one-row body
+    # them that held ONE row, and those that took the block-diagonal body
+    # (1 to ``ops/pallas_moe.diag_rows`` live rows; the fuller ran the tile)
     moe_slots: int = 0
     moe_single_row_slots: int = 0
+    moe_diag_slots: int = 0
     # the same of admission prefill chunks, which the counters above leave
     # out: pairs that landed on held experts, and the live slots they
     # filled at the chunk's capacity (``slot_cap`` of its rows). Pairs over
@@ -630,7 +632,7 @@ class ContinuousStats:
         return behind
 
     def count_moe(self, counts, held: slice = slice(None),
-                  slots: tuple = (0, 0)) -> None:
+                  slots: tuple = (0, 0, 0)) -> None:
         """One dispatch's (L, E) rows-per-expert counts; ``held`` the
         columns of the experts held here; ``slots`` the dispatch's
         ``ops/pallas_moe.slot_census``."""
@@ -639,6 +641,7 @@ class ContinuousStats:
         self.moe_active += int((counts[:, held] > 0).sum())
         self.moe_slots += slots[0]
         self.moe_single_row_slots += slots[1]
+        self.moe_diag_slots += slots[2]
         load = counts.sum(axis=0, dtype=np.int64)
         self.moe_load = load if self.moe_load is None else self.moe_load + load
 
@@ -2809,14 +2812,17 @@ class ContinuousEngine:
         taken, self._chunk_moe = tuple(self._chunk_moe), []
         return taken
 
-    def _slot_census(self, local, rows: int) -> tuple[int, int]:
-        """(live slots, slots of one row) of a ``rows``-row dispatch whose
-        (L, E held) routed-rows counts are ``local``, at the rows a slot
-        its shape gives (``ops/pallas_moe.slot_cap``)."""
-        from ..ops.pallas_moe import slot_cap, slot_census
+    def _slot_census(self, local, rows: int) -> tuple[int, int, int]:
+        """(live slots, slots of one row, slots that took the
+        block-diagonal body) of a ``rows``-row dispatch whose (L, E held)
+        routed-rows counts are ``local``, at the rows a slot its shape gives
+        (``ops/pallas_moe.slot_cap``) and the fill the body takes on BOTH
+        of an expert's leaves (``diag_rows`` of their block counts)."""
+        from ..ops.pallas_moe import diag_rows, slot_cap, slot_census
 
-        return slot_census(local, slot_cap(
-            rows, self.spec.n_active_experts, local.shape[1]))
+        cap = slot_cap(rows, self.spec.n_active_experts, local.shape[1])
+        return slot_census(local, cap, diag_rows(
+            cap, self.spec.dim // 32, self.spec.hidden_dim // 32))
 
     def _count_chunk_moe(self, chunk_moe) -> None:
         """Count chunks whose programs are known to have run (a dispatch

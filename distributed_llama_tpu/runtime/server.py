@@ -1346,7 +1346,11 @@ class InferenceServer:
                      f"layer of the KV page pool"
                      if st.paged_kv_positions else "")
                   + (f"; chunks walked {st.chunk_walk_share:.1%} of the "
-                     f"plane" if st.chunk_plane_positions else ""),
+                     f"plane" if st.chunk_plane_positions else "")
+                  + (f"; {st.moe_diag_slots} of {st.moe_slots} expert "
+                     f"slots took the block-diagonal body "
+                     f"({st.moe_single_row_slots} held one row)"
+                     if st.moe_slots else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
                   sum_active=st.sum_active, steps_ahead=st.steps_ahead,
                   rows_dropped_ahead=st.rows_dropped_ahead,
@@ -1374,6 +1378,9 @@ class InferenceServer:
                   moe_pairs=st.moe_pairs,
                   moe_local_pairs=st.moe_local_pairs,
                   moe_active=st.moe_active,
+                  moe_slots=st.moe_slots,
+                  moe_single_row_slots=st.moe_single_row_slots,
+                  moe_diag_slots=st.moe_diag_slots,
                   state_bytes=st.state_bytes,
                   window_bytes=st.window_bytes,
                   shared_kv_pages=st.shared_kv_pages,

@@ -634,6 +634,7 @@ def test_engine_serves_with_prefix_sharing_and_counts_its_share(kernel_mode,
     assert reg.get("dllama_moe_slots_total").value == st.moe_slots
     assert reg.get("dllama_moe_single_row_slots_total").value == \
         st.moe_single_row_slots
+    assert "dllama_moe_diag_slots_total 0" in reg.expose()
     assert "dllama_latent_pages_in_use" in reg.expose()
 
 
@@ -724,7 +725,14 @@ def test_the_servers_summary_says_what_share_of_the_plane_chunks_walked(
         srv.stop()
     # 36 tokens: 35 rows in chunks at 0, 16 and 32 of 64 positions
     assert srv.engine.stats.chunk_walked_positions == 16 + 32 + 48
-    assert "chunks walked 50.0% of the plane" in capsys.readouterr().err
+    err = capsys.readouterr().err
+    assert "chunks walked 50.0% of the plane" in err
+    # ... and how the expert slot kernel engaged (the toy ``w2`` is 4 blocks
+    # a row, off the block-diagonal body's grid: none took it)
+    st = srv.engine.stats
+    assert 0 < st.moe_single_row_slots <= st.moe_slots
+    assert (f"; 0 of {st.moe_slots} expert slots took the block-diagonal "
+            f"body ({st.moe_single_row_slots} held one row)") in err
 
 
 # -- the converter, on a toy dict of the published names ---------------------

@@ -17,6 +17,8 @@ asserts that this is most of the sequence. The logit tolerance is never
 widened for a flipped expert.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -292,6 +294,8 @@ def test_slot_counters_over_a_served_run(kind, tree):
         assert st.moe_chunk_pairs == st.moe_chunk_slots == 0
         assert reg.get("dllama_moe_slots_total").value == 0
         assert reg.get("dllama_moe_single_row_slots_total").value == 0
+        assert st.moe_diag_slots == 0 == reg.get(
+            "dllama_moe_diag_slots_total").value
         return
     eng, _, seen = _served_rows(tree, 12, prompts, 10, metrics=reg)
     st = eng.stats
@@ -449,30 +453,175 @@ def test_slot_kernel_matches_pair_loop(rows, case):
                                               minlength=8)).all()
 
 
-@pytest.mark.parametrize("fill", [1, 2, 8])
-@pytest.mark.parametrize("nb", [224, 64])     # DeepSeek-V3's w13 and w2
-def test_slot_call_matches_dense_at_deepseeks_block_counts(nb, fill):
-    """One call of the slot kernel on a leaf of ``nb`` blocks a row at a toy
-    ``d``: a one-row slot (its own body), a part-filled and a full tile, a
-    dead slot behind them."""
+def _q40_leaf(rng, n_exp, d, nb):
+    """(nb-major leaf, float64 dense (E, d, n)) of a random expert stack."""
     from distributed_llama_tpu.ops.quants import quantize_q40
 
-    rng = np.random.default_rng(nb + fill)
-    d, n, n_exp = 128, nb * 32, 3
+    n = nb * 32
     w = Q40Weight(*quantize_q40((rng.standard_normal((1, n_exp, d, n))
                                  / np.sqrt(n)).astype(np.float32)))
-    dense = dequantize_q40(w.qs, w.d16).astype(np.float64)[0]
-    leaf = to_kernel_layout_nb(w)
-    xs = rng.standard_normal((3, 8, n)).astype(np.float32)
-    slot_expert = jnp.asarray([0, 2, 2], jnp.int32)     # slot 2 is dead
-    fills = jnp.asarray([fill, 8, 0], jnp.int32)
-    got = np.asarray(pallas_moe.moe_q40_slots(
-        jnp.zeros((1,), jnp.int32), slot_expert, jnp.int32(2), fills,
-        leaf.qs_t, leaf.scale, jnp.asarray(xs), block_rows=128,
-        interpret=True))
+    return (to_kernel_layout_nb(w),
+            dequantize_q40(w.qs, w.d16).astype(np.float64)[0])
+
+
+def _slot_call(leaf, slot_expert, live, fills, xs, block_rows=128):
+    """One call of the slot kernel, traced anew (a test may have changed
+    ``MOE_DIAG_ROWS``, which the jitted entry's cache does not see)."""
+    return np.asarray(jax.jit(functools.partial(
+        pallas_moe.moe_q40_slots.__wrapped__, block_rows=block_rows,
+        interpret=True))(
+        jnp.zeros((1,), jnp.int32), jnp.asarray(slot_expert, jnp.int32),
+        jnp.int32(live), jnp.asarray(fills, jnp.int32), leaf.qs_t,
+        leaf.scale, jnp.asarray(xs)))
+
+
+@pytest.mark.parametrize("fill", [1, 2, 3, 4, 5, 6, 7, 8])
+# DeepSeek-V3's w13 and w2, MiMo's w13, OLMoE's w2
+@pytest.mark.parametrize("nb", [224, 64, 128, 32])
+def test_slot_call_matches_dense_at_deepseeks_block_counts(nb, fill):
+    """One call of the slot kernel on a leaf of ``nb`` blocks a row at a toy
+    ``d``: a slot of ``fill`` rows (1 or 2: the block-diagonal body; 3 to 8
+    the tile), a full tile, a dead slot behind them."""
+    rng = np.random.default_rng(nb + fill)
+    leaf, dense = _q40_leaf(rng, 3, 128, nb)
+    xs = rng.standard_normal((3, 8, nb * 32)).astype(np.float32)
+    # slot 2 is dead
+    got = _slot_call(leaf, [0, 2, 2], 2, [fill, 8, 0], xs)
     for a, (e, live) in enumerate(((0, fill), (2, 8))):
         want = xs[a, :live].astype(np.float64) @ dense[e].T
         assert np.abs(got[a, :live] - want).max() < 1e-5
+
+
+MIXED_FILLS = [1, 5, 3, 8, 2, 4, 7, 6, 0, 0]          # the last two dead
+MIXED_EXPERTS = [0, 0, 1, 1, 1, 2, 2, 2, 2, 2]
+
+
+@pytest.mark.parametrize("nb", [32, 64, 128, 224])
+def test_mixed_fills_in_one_grid_and_their_distance(nb, monkeypatch):
+    """Every live-row count in ONE grid of two row tiles a slot (the planes
+    are built at a slot's first tile and read at its second), against the
+    float64 product: the block-diagonal body (1 or 2 rows) is no further
+    from it than ``_row_body`` is at one row, the body's own class (both
+    fold the ``- 8`` out of raw codes; the tile multiplies code - 8 and
+    reads closer still, and no fill is past ITS distance by more than that
+    class allows); the fuller slots are the parent's tile bit for bit; dead
+    slots write nothing (interpret mode leaves NaN where nothing was
+    written)."""
+    rng = np.random.default_rng(nb)
+    leaf, dense = _q40_leaf(rng, 3, 256, nb)
+    xs = rng.standard_normal((len(MIXED_FILLS), 8, nb * 32)
+                             ).astype(np.float32)
+
+    def distances(got):
+        out = {}
+        for a, (e, fill) in enumerate(zip(MIXED_EXPERTS, MIXED_FILLS)):
+            if fill:
+                want = xs[a, :fill].astype(np.float64) @ dense[e].T
+                out[fill] = (np.abs(got[a, :fill] - want).max()
+                             / np.abs(want).max())
+        return out
+
+    got = _slot_call(leaf, MIXED_EXPERTS, 8, MIXED_FILLS, xs)
+    assert np.isnan(got[8:]).all()
+    new = distances(got)
+    monkeypatch.setattr(pallas_moe, "MOE_DIAG_ROWS", 0)   # the parent's
+    old_got = _slot_call(leaf, MIXED_EXPERTS, 8, MIXED_FILLS, xs)
+    old = distances(old_got)
+    assert np.isnan(old_got[8:]).all()
+    assert new[1] <= 1.1 * old[1] < 1e-6
+    assert new[2] <= 1.1 * max(old[2], old[1])
+    for a, fill in enumerate(MIXED_FILLS):
+        if fill > 2:
+            assert (got[a, :fill] == old_got[a, :fill]).all()
+
+
+def test_a_leaf_off_the_8_grid_keeps_the_row_body(monkeypatch):
+    """The body is picked statically by the leaf's block count, so only one
+    of the two part-filled bodies is traced: at 12 blocks a row
+    ``_diag_body`` is never reached, at 16 ``_row_body`` is not."""
+    def never(*a, **k):
+        raise AssertionError("traced")
+
+    rng = np.random.default_rng(12)
+    for nb, body in ((12, "_diag_body"), (16, "_row_body")):
+        assert (pallas_moe.diag_rows(8, nb) == 0) == (nb == 12)
+        leaf, dense = _q40_leaf(rng, 2, 128, nb)
+        xs = rng.standard_normal((3, 8, nb * 32)).astype(np.float32)
+        with monkeypatch.context() as m:
+            m.setattr(pallas_moe, body, never)
+            got = _slot_call(leaf, [0, 1, 1], 2, [1, 3, 0], xs)
+        for a, (e, live) in enumerate(((0, 1), (1, 3))):
+            want = xs[a, :live].astype(np.float64) @ dense[e].T
+            assert np.abs(got[a, :live] - want).max() < 1e-5
+    # a wide dispatch's slots (a chunk's) keep the parent's bodies
+    assert pallas_moe.diag_rows(32, 64) == 0 == pallas_moe.diag_rows(16, 64)
+    assert pallas_moe.diag_rows(8, 64) == 2 and pallas_moe.diag_rows(1, 64) == 1
+
+
+def _count_equations(jaxpr) -> tuple[int, int]:
+    """(equations, those that hold a jaxpr of their own: a branch, a loop, a
+    jitted call) of a jaxpr, through every sub-jaxpr its equations hold (a
+    ``pallas_call``'s kernel, a branch, a loop's body)."""
+    def subs(v):
+        if hasattr(v, "eqns"):
+            yield v
+        elif hasattr(v, "jaxpr") and hasattr(v.jaxpr, "eqns"):
+            yield v.jaxpr
+        elif isinstance(v, (tuple, list)):
+            for x in v:
+                yield from subs(x)
+
+    total = nested = 0
+    for e in jaxpr.eqns:
+        inner = [j for v in e.params.values() for j in subs(v)]
+        counts = [_count_equations(j) for j in inner]
+        total += 1 + sum(c[0] for c in counts)
+        nested += bool(inner) + sum(c[1] for c in counts)
+    return total, nested
+
+
+def _kernel_equations(cap: int) -> tuple[int, int, str]:
+    """(equations in the slot kernel's body, its nested jaxprs, the call's
+    name) at OLMoE's ``w13`` shape (64 blocks a row, 2048 rows, one
+    row tile up to 16 rows a slot) and ``cap`` rows a slot."""
+    nb, d, a = 64, 2048, 16
+    sd = jax.ShapeDtypeStruct
+    closed = jax.make_jaxpr(functools.partial(
+        pallas_moe.moe_q40_slots.__wrapped__,
+        block_rows=pallas_moe._slot_block_rows(d, nb, cap),
+        interpret=False))(
+        sd((1,), jnp.int32), sd((a,), jnp.int32), sd((), jnp.int32),
+        sd((a,), jnp.int32), sd((1, 4, 16, nb, d), jnp.uint8),
+        sd((1, 4, nb, d), jnp.float32), sd((a, cap, nb * 32), jnp.float32))
+    call, = (e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call")
+    return (*_count_equations(call.params["jaxpr"]), call.params["name"])
+
+
+# What a run pays at start-up for every program that holds the kernel is the
+# tracing and lowering of its body, which only the chip's set-up showed (PR
+# 53: a body a row count up to 3, ``setup_s`` +5.5 s in OLMoE's cell:
+# refused). What costs is what is traced on its own (a branch, a loop, a
+# jitted ``jnp`` call: each a jaxpr of its own) more than the equations: PR
+# 54's first form, one body whose row count was a loop bound, held as many
+# equations as this one and traced as slowly as PR 53's (PERF.md section 7);
+# 18 of this kernel's 24 are ``ops/pallas_q40._diag_planes_nb``'s ``//``,
+# ``%`` and ``jnp.where`` (ROADMAP S9 c). (equations, nested jaxprs) by rows
+# a slot: the parent's (commit 9a7a6d5) beside this tree's; PR 53's as
+# refused: (1045, 67) and (1193, 69).
+PARENT_SLOT_KERNEL = {8: (309, 2), 32: (457, 4)}
+SLOT_KERNEL = {8: (340, 24), 32: (457, 4)}
+
+
+@pytest.mark.parametrize("cap", [8, 32])
+def test_the_slot_kernels_traced_size_is_pinned(cap, monkeypatch):
+    *count, name = _kernel_equations(cap)
+    assert name == ("moe_q40_slots" if cap == 8 else "moe_q40_grouped")
+    assert tuple(count) == SLOT_KERNEL[cap]
+    assert count[0] <= 1.5 * PARENT_SLOT_KERNEL[cap][0]
+    if cap == 32:        # a chunk's kernel is the parent's, body for body
+        assert tuple(count) == PARENT_SLOT_KERNEL[cap]
+    monkeypatch.setattr(pallas_moe, "MOE_DIAG_ROWS", 0)
+    assert _kernel_equations(cap)[:2] == PARENT_SLOT_KERNEL[cap]
 
 
 @pytest.mark.parametrize("rows", [40, 13])   # a wide dispatch, a narrow one
@@ -578,19 +727,42 @@ def test_slot_cap_of_a_narrow_dispatch_is_pinned():
             assert cap % 8 == 0 and 8 < cap <= -(-t // 8) * 8
 
 
+@pytest.mark.parametrize("nb", [64, 20])   # a leaf the body takes; one off the grid
 @pytest.mark.parametrize("rows,slots", [(1, 1), (16, 8), (32, 8),
                                         (40, 24), (128, 32)])
-def test_slot_census_counts_what_build_slots_builds(rows, slots):
+def test_slot_census_counts_what_build_slots_builds(rows, slots, nb):
     """The host's arithmetic for the counters against the device's slots."""
     assert pallas_moe.slot_cap(rows, 4, 16) == slots
     rng = np.random.default_rng(rows)
     topi = np.stack([rng.choice(16, 4, replace=False) for _ in range(rows)])
     counts = np.bincount(topi.ravel(), minlength=16)
-    live, single = pallas_moe.slot_census(counts, slots)
+    top = pallas_moe.diag_rows(slots, nb)
+    assert top == (min(slots, 2) if nb == 64 and slots <= 8 else 0)
+    live, single, diag = pallas_moe.slot_census(counts, slots, top)
     _, n_slots, fill, *_ = pallas_moe.build_slots(
         jnp.asarray(topi, jnp.int32), 16, slots)
+    fill = np.asarray(fill)
     assert live == int(n_slots) == sum(-(-c // slots) for c in counts)
-    assert single == int((np.asarray(fill) == 1).sum())
+    assert single == int((fill == 1).sum())
+    assert diag == int(((fill >= 1) & (fill <= top)).sum())
+    assert (diag > 0) == (top > 0) and diag <= live
+
+
+@pytest.mark.parametrize("hidden,took", [(128, False), (256, True)])
+def test_the_engines_census_asks_both_leaves(hidden, took):
+    """A slot counts as one that took the block-diagonal body where BOTH of
+    an expert's leaves admit it (``w13`` has dim / 32 blocks a row, ``w2``
+    hidden / 32): the toy spec's hidden 128 is 4 blocks, off the grid."""
+    from types import SimpleNamespace
+
+    from distributed_llama_tpu.runtime.continuous import ContinuousEngine
+
+    eng = SimpleNamespace(spec=SimpleNamespace(
+        n_active_experts=2, dim=256, hidden_dim=hidden))
+    local = np.array([[0, 1, 4, 5, 9, 12, 8, 3]])      # rows an expert, 1 layer
+    live, single, diag = ContinuousEngine._slot_census(eng, local, 16)
+    assert (live, single) == (9, 2)          # 9 and 12 open a second slot
+    assert diag == (2 if took else 0)        # last fills 1, 4, 5, 1, 4, 3
 
 
 # -- (vii): what refuses an expert spec --------------------------------------
