@@ -183,6 +183,13 @@ def state_slot_bytes(spec: TransformerSpec) -> int:
         mx = spec.mixers
         return (4 * mx.count("sliding") * mx.window
                 * spec.kv_cached("sliding"))
+    if spec.ssd:
+        # an ssd spec's slot: each Mamba-2 layer's state (heads, head_dim,
+        # d_state) and its conv rows, float32 (models/nemotron.py); its
+        # attention layers' K / V are pages (``kv_position_bytes``)
+        sd = spec.ssd
+        return 4 * sd.count("mamba2") * (
+            sd.d_inner * sd.d_state + (sd.d_conv - 1) * sd.conv_dim)
     if spec.hybrid:
         # a hybrid spec's slot: each Mamba layer's conv inputs and state,
         # each window layer's ring of K and V, float32 (models/sambay.py);
@@ -264,6 +271,9 @@ def kv_position_bytes(spec: TransformerSpec, n_slices: int,
                              "float32 (runtime/continuous.cache_refusals)")
         if spec.mixers:     # the kind's KV heads, K's and V's head sizes
             return (spec.mixers.count("full") * spec.kv_cached("full")
+                    * cache_itemsize)
+        if spec.ssd:        # K and V of every attention layer
+            return (spec.ssd.count("full") * 2 * spec.kv_dim
                     * cache_itemsize)
         return 2 * spec.kv_dim * cache_itemsize
     kv_dim = (spec.n_kv_heads // n_slices) * spec.head_size

@@ -576,7 +576,7 @@ def check_config(entry: MatrixEntry, device: str = "v5e",
         from ..ops.retention import TP_REFUSAL
 
         raise ValueError(f"shardcheck {config}: {TP_REFUSAL}")
-    if spec.hybrid:
+    if spec.hybrid or spec.ssd:
         # and a hybrid spec: slots of fixed size and one layer's pages
         from ..ops.mamba import TP_REFUSAL
 

@@ -712,6 +712,8 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_prompt_positions_total"),
     _m("dllama_xdec_positions_total"),
     _m("dllama_ssm_min_decay"),
+    # an ssd spec's layers a landed decode step ran, by kind
+    _m("dllama_layers_run_total", labels=("kind",)),
     # a mixer-kinds spec's per-head output gate (its rings, pages and
     # positions are the hybrid series above)
     _m("dllama_attn_gate_min"),

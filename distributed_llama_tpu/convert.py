@@ -83,6 +83,16 @@ Extensions beyond the reference:
   ``moe_layer_freq`` and the router's keys; header extension 8). The tensor
   names are ``LAGUNA_TENSORS`` with ``MIMO_TENSORS``' two, a GUESS as
   theirs; the multi-token-prediction layers are not read.
+* ``model_type: nemotron_h`` (NVIDIA-Nemotron-3-Nano-30B-A3B: a layer is ONE
+  mixer, Mamba-2, attention without positional encoding or non-gated relu2
+  experts): ``nemotron_spec`` reads the config (``hybrid_override_pattern``,
+  the ``mamba_*`` / ``ssm_state_size`` / ``n_groups`` / ``conv_kernel`` /
+  ``chunk_size`` keys, the router's; header extension 10) and
+  ``nemotron_tensor`` the published names (``NEMOTRON_TENSORS``):
+  ``in_proj``'s rows [z | xBC | dt] are cut into ``in_zx`` and ``in_dt``,
+  ``conv1d.weight`` (channels, 1, taps) is laid (taps, channels); q / k rows
+  stay as they are (there is no rotary embedding to permute for). Tested on
+  seeded tensors of those names; the published weights were not run.
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -418,6 +428,8 @@ class HFCheckpoint:
             return motif_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "phi4flash":
             return hybrid_spec(c, target, seq_len)
+        if getattr(c, "model_type", "") == "nemotron_h":
+            return nemotron_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "laguna":
             print(f"🔶 laguna tensors: {LAGUNA_TENSORS_NOTE}")
             return laguna_spec(c, target, seq_len)
@@ -460,6 +472,10 @@ class HFCheckpoint:
                 "against the checkpoint, so no tensor is converted (the "
                 "module docstring says why); models/synth.py writes a "
                 "seeded file of this spec")
+        if spec.ssd:
+            return nemotron_tensor(
+                lambda key: self.state[key].to(self.torch.float32).numpy(),
+                name, layer, spec, expert)
         if spec.mixers:
             key = LAGUNA_TENSORS.get(name) or MIMO_TENSORS.get(name) or {
                 "tok_embedding": "model.embed_tokens.weight",
@@ -668,6 +684,101 @@ def mimo_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
                       bool(c.add_swa_attention_sink_bias)),
             False, 0 if c.v_head_dim == c.head_dim else int(c.v_head_dim),
             float(c.attention_value_scale)))
+
+
+NEMOTRON_TENSORS = {
+    "tok_embedding": "backbone.embeddings.weight",
+    "rms_final": "backbone.norm_f.weight",
+    "wcls": "lm_head.weight",
+    "rms_att": "backbone.layers.{layer}.norm.weight",
+    "in_zx": "backbone.layers.{layer}.mixer.in_proj.weight",
+    "in_dt": "backbone.layers.{layer}.mixer.in_proj.weight",
+    "conv_w": "backbone.layers.{layer}.mixer.conv1d.weight",
+    "conv_b": "backbone.layers.{layer}.mixer.conv1d.bias",
+    "dt_bias": "backbone.layers.{layer}.mixer.dt_bias",
+    "a_log": "backbone.layers.{layer}.mixer.A_log",
+    "d_skip": "backbone.layers.{layer}.mixer.D",
+    "norm_g": "backbone.layers.{layer}.mixer.norm.weight",
+    "out_proj": "backbone.layers.{layer}.mixer.out_proj.weight",
+    "wq": "backbone.layers.{layer}.mixer.q_proj.weight",
+    "wk": "backbone.layers.{layer}.mixer.k_proj.weight",
+    "wv": "backbone.layers.{layer}.mixer.v_proj.weight",
+    "wo": "backbone.layers.{layer}.mixer.o_proj.weight",
+    "moe_gate": "backbone.layers.{layer}.mixer.gate.weight",
+    "moe_bias":
+        "backbone.layers.{layer}.mixer.gate.e_score_correction_bias",
+    "moe_w1": "backbone.layers.{layer}.mixer.experts.{expert}.up_proj.weight",
+    "moe_w2":
+        "backbone.layers.{layer}.mixer.experts.{expert}.down_proj.weight",
+    "sh_w1": "backbone.layers.{layer}.mixer.shared_experts.up_proj.weight",
+    "sh_w2": "backbone.layers.{layer}.mixer.shared_experts.down_proj.weight",
+}
+NEMOTRON_LETTERS = {"M": "mamba2", "*": "full", "E": "experts"}
+
+
+def nemotron_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
+    """The spec of a ``nemotron_h`` config. ``d_inner`` is
+    ``mamba_num_heads x mamba_head_dim`` (not ``expand x hidden_size``: the
+    published parameter count says which), the router's groups are
+    ``n_group`` (1) and Mamba-2's ``n_groups``; a pattern with a dense-MLP
+    layer ('-'), a bias, a gated or another activation, or routing groups
+    is refused."""
+    from .models.spec import Activation, ExpertLayout, Router, SsdLayers
+
+    pattern = c.hybrid_override_pattern
+    if len(pattern) != c.num_hidden_layers or set(pattern) - set(
+            NEMOTRON_LETTERS):
+        raise ValueError("nemotron_h: hybrid_override_pattern has one of "
+                         "M, * and E a layer (a dense-MLP layer '-' is not "
+                         "run)")
+    if (getattr(c, "mlp_hidden_act", "relu2") != "relu2"
+            or getattr(c, "mamba_hidden_act", "silu") != "silu"
+            or (getattr(c, "n_group", 1), getattr(c, "topk_group", 1))
+            != (1, 1) or getattr(c, "n_shared_experts", 1) != 1
+            or any(getattr(c, k, False) for k in (
+                "attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias",
+                "tie_word_embeddings"))
+            or not getattr(c, "use_conv_bias", True)):
+        raise ValueError("nemotron_h: relu2 experts with one shared, silu "
+                         "in the mixer, no routing groups, no projection "
+                         "bias, a conv bias and an untied head are what "
+                         "the program runs")
+    return TransformerSpec(
+        dim=c.hidden_size, hidden_dim=c.moe_intermediate_size,
+        n_layers=c.num_hidden_layers, n_heads=c.num_attention_heads,
+        n_kv_heads=c.num_key_value_heads, vocab_size=c.vocab_size,
+        seq_len=seq_len, weights_float_type=target,
+        n_experts=c.n_routed_experts,
+        n_active_experts=c.num_experts_per_tok,
+        norm_eps=float(getattr(c, "norm_eps", 1e-5)),
+        layout=ExpertLayout(shared=1),
+        router=Router("sigmoid", 1, 1, bool(c.norm_topk_prob),
+                      float(c.routed_scaling_factor), bias=True),
+        activation=Activation("relu2", gated=False),
+        ssd=SsdLayers(tuple(NEMOTRON_LETTERS[x] for x in pattern),
+                      int(c.mamba_num_heads), int(c.mamba_head_dim),
+                      int(c.n_groups), int(c.ssm_state_size),
+                      int(c.head_dim), int(c.conv_kernel),
+                      int(c.chunk_size),
+                      int(c.moe_shared_expert_intermediate_size)))
+
+
+def nemotron_tensor(read, name: str, layer: int | None,
+                    spec: TransformerSpec, expert: int | None = None):
+    """The loader's tensor ``name`` of layer ``layer`` (held expert
+    ``expert``) from a ``nemotron_h`` checkpoint, ``read(key)`` giving a
+    published tensor as float32 numpy."""
+    if expert is not None:
+        expert += spec.layout.offset
+    w = read(NEMOTRON_TENSORS[name].format(layer=layer, expert=expert))
+    cut = spec.ssd.d_inner + spec.ssd.conv_dim
+    if name == "in_zx":
+        return w[:cut]
+    if name == "in_dt":
+        return w[cut:]
+    if name == "conv_w":
+        return np.ascontiguousarray(w.reshape(w.shape[0], -1).T)
+    return w
 
 
 def hybrid_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:

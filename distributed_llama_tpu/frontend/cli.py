@@ -82,6 +82,25 @@ def _hybrid_line(spec, slots: int) -> str:
             f"{2 * spec.kv_dim * 4} B a position")
 
 
+def _ssd_line(spec, slots: int) -> str:
+    """What an ssd spec keeps a sequence: a startup line."""
+    from ..analysis.memory_model import kv_position_bytes, state_slot_bytes
+
+    sd = spec.ssd
+    return (f"💡 layers, ONE mixer each: {sd.count('mamba2')} mamba-2 "
+            f"({sd.heads} heads of {sd.head_dim}, state {sd.d_state}, "
+            f"{sd.groups} groups), {sd.count('full')} attention "
+            f"({spec.n_heads} heads over {spec.n_kv_heads} KV heads, no "
+            f"positional encoding), {sd.count('experts')} expert "
+            f"({spec.n_experts_held} of {spec.n_experts} experts held, "
+            f"{spec.n_active_experts} a token, {spec.activation.kind}"
+            f"{'' if spec.activation.gated else ', not gated'}); a sequence "
+            f"keeps {state_slot_bytes(spec) / 2**20:.1f} MiB of state "
+            f"({slots} slot{'s' if slots != 1 else ''}, fixed) and the "
+            f"attention layers' K / V: {kv_position_bytes(spec, 1)} B a "
+            f"position")
+
+
 def _mixers_line(spec, slots: int) -> str:
     """What a mixer-kinds spec keeps a sequence: a startup line."""
     mx, lay = spec.mixers, spec.layout
@@ -635,6 +654,8 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         print(_hybrid_line(spec, rows))
     if spec.mixers and not quiet:
         print(_mixers_line(spec, rows))
+    if spec.ssd and not quiet:
+        print(_ssd_line(spec, rows))
     mesh = (make_mesh(sp=args.sp, tp=tp)
             if tp > 1 or args.sp > 1 else None)
     assumed = getattr(args, "_slice_tp_ranks", None)
@@ -1153,6 +1174,8 @@ def cmd_serve(argv: list[str]) -> int:
         print(_hybrid_line(spec, args.slots))
     if spec.mixers:
         print(_mixers_line(spec, args.slots))
+    if spec.ssd:
+        print(_ssd_line(spec, args.slots))
     mesh = make_mesh(tp=args.tp) if args.tp and args.tp > 1 else None
     seed = args.seed if args.seed is not None else int(time.time())
     if journal is not None:

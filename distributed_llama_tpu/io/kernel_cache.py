@@ -81,8 +81,10 @@ def layout_key(model_path: str | None = None, tp: int = 1,
     nbm = "force" if layout is not None and layout.force_nb_major else "auto"
     wf = getattr(weights_float_type, "name", weights_float_type) or "Q40"
     bf = getattr(buffer_float_type, "name", buffer_float_type) or "F32"
+    pad = getattr(layout, "pad_blocks", 0)
     return (f"v1|{q40_kernel_mode()}|{_TILE_ROWS_CAP}|{fusion_cache_key()}"
-            f"|nb={nbm}|tp={tp}|wf={wf}|bf={bf}{src}")
+            f"|nb={nbm}|tp={tp}|wf={wf}|bf={bf}{src}"
+            + (f"|pad={pad}" if pad else ""))
 
 
 def sidecar_path(model_path: str) -> str:

@@ -77,6 +77,10 @@ def slot_model(spec: TransformerSpec):
         from . import laguna
 
         return laguna
+    if spec.ssd:
+        from . import nemotron
+
+        return nemotron
     if spec.latent:
         from . import latent
 
@@ -91,8 +95,8 @@ def slot_counts(spec: TransformerSpec) -> dict:
     expert spec's (L_e, E) routed-rows counts (a mixer-kinds or a latent
     spec's: a hybrid spec has a dense FFN), for the engines'
     ``functools.partial``."""
-    return {"moe_counts": True} if (spec.mixers or spec.latent) and \
-        spec.n_experts else {}
+    return {"moe_counts": True} if (spec.mixers or spec.latent
+                                    or spec.ssd) and spec.n_experts else {}
 
 
 def init_cache(spec: TransformerSpec, dtype=jnp.float32):
@@ -349,10 +353,14 @@ def _qkv_proj(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
 
 def _swiglu(spec: TransformerSpec, lw: dict[str, Any], xb: jax.Array,
             prefix: str = "") -> jax.Array:
-    """w2(act(w1 xb) * w3 xb), act the spec's activation (SiLU unless it
+    """w2(act(w1 xb) * w3 xb) (w2(act(w1 xb)) where the spec's FFN is not
+    gated), act the spec's activation (SiLU unless it
     states another: ops/linear.ffn_activation), of the leaves ``prefix + w1 | w2 | w3`` (or
     their load-time fusion ``prefix + w13``: linear.fuse_q40_layer_matmuls)."""
     act = ffn_activation(spec, lw)
+    if not spec.activation.gated:   # one up matrix, no product
+        return matmul(lw[prefix + "w2"], _maybe_q80(
+            spec, act(matmul(lw[prefix + "w1"], xb))))
     if prefix + "w13" in lw:
         h13 = matmul(lw[prefix + "w13"], xb)
         hid = h13.shape[-1] // 2
@@ -662,11 +670,9 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
         from .sambay import forward_sambay
 
         return forward_sambay(spec, params, cache, tokens, pos)
-    if spec.mixers:
-        from .laguna import forward_chunk
-
-        return forward_chunk(spec, params, cache, tokens, pos,
-                             moe_counts=moe_counts)
+    if spec.mixers or spec.ssd:
+        return slot_model(spec).forward_chunk(spec, params, cache, tokens,
+                                              pos, moe_counts=moe_counts)
     if spec.retention:
         return forward_retention(spec, params, cache, tokens, pos)
     if spec.latent:
@@ -1108,12 +1114,10 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
 
         return forward_batch_sambay(spec, params, cache, tokens, pos_vec,
                                     table, page_size=page_size)
-    if spec.mixers:
-        from .laguna import forward_batch as forward_batch_mixers
-
-        return forward_batch_mixers(spec, params, cache, tokens, pos_vec,
-                                    table, page_size=page_size,
-                                    moe_counts=moe_counts)
+    if spec.mixers or spec.ssd:
+        return slot_model(spec).forward_batch(
+            spec, params, cache, tokens, pos_vec, table, page_size=page_size,
+            moe_counts=moe_counts)
     B = tokens.shape[0]
     x = params["tok_embedding"][tokens].astype(jnp.float32)  # (B, dim)
     positions = pos_vec if jnp.ndim(pos_vec) == 1 else jnp.full((B,),

@@ -161,6 +161,33 @@ carries, after its norms (and the residual path's tensors):
   wg (signalHeads v x dim)            where gate, wo (dim x signalHeads v)
 
 with signalHeads = nHeads - kvGroups noiseHeads.
+
+Extension VERSION 10 (version 4's values, then twelve ints {ssmHeads,
+ssmHeadDim, ssmGroups, ssmState, ssmConv, ssmChunk, headSize, sharedHidden,
+activation (``ACTIVATIONS``), gated, two reserved} and 128 bytes, a layer's
+kind each: its index into ``SSD_KINDS``, 255 past the last layer) is written
+only by a spec that sets ``ssd`` (``SsdLayers``: a layer is ONE mixer under
+one norm and one residual add: a Mamba-2 (SSD) mixer, grouped-query
+attention with no positional encoding, or routed experts with version 4's
+``ExpertLayout`` / ``Router``), so files of every earlier version read and
+write byte for byte. Such a file has no rope gap, and a layer is one run of
+tensors in its kind's stack (``params[kind]``):
+
+  "mamba2":  norm (F32 dim), in_zx ((dInner + convDim) x dim: rows [z | x |
+             B | C]) [weightsFloatType], in_dt (F32, ssmHeads x dim),
+             conv_w (F32, ssmConv x convDim), conv_b (F32 convDim),
+             dt_bias, a_log, d_skip (F32 ssmHeads), norm_g (F32 dInner),
+             out_proj (dim x dInner) [weightsFloatType]
+  "full":    norm, wq (nHeads headSize x dim), wk, wv (nKvHeads headSize x
+             dim), wo (dim x nHeads headSize)   [weightsFloatType]
+  "experts": norm, router (F32, nExperts x dim), [router_bias (F32
+             nExperts)], sh_w1 (sharedHidden x dim), sh_w2 (dim x
+             sharedHidden) where sharedHidden > 0, per HELD expert e: w1_e
+             (hidden x dim), w2_e (dim x hidden)   [weightsFloatType]: an
+             expert that is not gated has no w3
+
+with dInner = ssmHeads ssmHeadDim and convDim = dInner + 2 ssmGroups
+ssmState; the norm's gain is ``rms_att`` in every kind's stack.
 """
 
 from __future__ import annotations
@@ -190,8 +217,11 @@ EXT8_VERSION = 8
 EXT8_STRUCT = struct.Struct("<14i2d16i7d9i14d128B5i1d")
 EXT9_VERSION = 9
 EXT9_STRUCT = struct.Struct("<14i2d16i7d4i3d8i3d128B")
-MAX_HEADER_BYTES = max(EXT8_STRUCT.size, EXT9_STRUCT.size)
-ACTIVATIONS = ("silu", "polynorm")
+EXT10_VERSION = 10
+EXT10_STRUCT = struct.Struct("<14i2d16i7d12i128B")
+MAX_HEADER_BYTES = max(EXT8_STRUCT.size, EXT9_STRUCT.size,
+                       EXT10_STRUCT.size)
+ACTIVATIONS = ("silu", "polynorm", "relu2")
 HC_SUBLAYERS = ("att", "ffn")
 ATTN_KINDS = ("softmax", "retention")
 # what a layer of a ``HybridLayers`` spec mixes with, and what it caches for
@@ -205,6 +235,9 @@ ROUTER_SCORINGS = ("softmax", "sigmoid")
 # what a layer of a ``MixerKinds`` spec attends over: every position (K / V
 # of its own, in pages under ``serve``) or the last ``window`` (a ring)
 MIXER_KINDS = ("full", "sliding")
+# what a layer of an ``SsdLayers`` spec IS (one mixer, no FFN beside it),
+# and what it caches for one sequence
+SSD_KINDS = ("mamba2", "full", "experts")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -257,22 +290,29 @@ class LatentAttn:
 
 @dataclasses.dataclass(frozen=True)
 class Activation:
-    """What an FFN applies to its gate projection before the product with
-    the up projection: "silu", or "polynorm" (arXiv:2411.03884):
+    """What an FFN applies between its input and output matrices. Gated
+    (the default): ``w2(act(w1 x) * w3 x)``, ``act`` on the gate projection
+    before the product with the up projection: "silu", or "polynorm"
+    (arXiv:2411.03884):
     ``scale * (w0 n(z^3) + w1 n(z^2) + w2 n(z) + clip(b, -clamp, clamp))``
     with ``n(u) = u / sqrt(mean(u^2) + eps)`` over the FFN's own width and
-    the four numbers a layer's ``pn_w`` (``clamp`` 0: the bias as it is)."""
+    the four numbers a layer's ``pn_w`` (``clamp`` 0: the bias as it is).
+    ``gated=False`` (header version 10): ONE up matrix and no product,
+    ``w2(act(w1 x))``, with "relu2": ``relu(z)^2``."""
     kind: str = "silu"
     scale: float = 1.0
     clamp: float = 0.0
+    gated: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
 class ExpertLayout:
     """Which layers are dense and which experts live here: ``dense_layers``
     leading layers have a SwiGLU of ``dense_hidden``; the rest route over
-    ``n_experts`` and add ``shared`` always-on experts (one SwiGLU of
-    ``shared * hidden_dim``); this file holds routed experts
+    ``n_experts`` and add ``shared`` always-on experts (one FFN of
+    ``shared * hidden_dim``, of the spec's activation: a SwiGLU unless it
+    says otherwise; an ``SsdLayers`` spec states that width itself,
+    ``shared_hidden``); this file holds routed experts
     ``offset .. offset + held - 1`` of the router's width (held 0 = all)."""
     dense_layers: int = 0
     dense_hidden: int = 0
@@ -433,6 +473,45 @@ class MixerKinds:
         return self.of(kind).rotary_dim or self.head_size
 
 
+@dataclasses.dataclass(frozen=True)
+class SsdLayers:
+    """A per-layer list in which a layer is ONE mixer under one pre-norm
+    and one residual add (Nemotron-H's layout; models/nemotron.py runs it,
+    models/reference_nemotron.py states it). ``kinds[i]`` is layer i:
+    "mamba2" (a Mamba-2 / SSD mixer, arXiv:2405.21060: ``heads`` heads of
+    ``head_dim`` channels, a state (heads, head_dim, d_state) float32 a
+    sequence, a SCALAR decay a head, B and C shared by the heads of one of
+    ``groups`` groups, a causal depthwise convolution ``d_conv`` wide over
+    [x | B | C] together, a gated RMSNorm in groups of d_inner / groups;
+    a prompt runs the chunked form in chunks of ``chunk``), "full"
+    (grouped-query softmax attention, causal, NO positional encoding, K / V
+    of its own: ``n_heads`` heads of ``head_size`` over ``n_kv_heads``) or
+    "experts" (``TransformerSpec.layout``'s and ``router``'s routed
+    experts of ``hidden_dim`` and one shared expert of ``shared_hidden``,
+    0: none). The list need not be periodic."""
+    kinds: tuple
+    heads: int
+    head_dim: int
+    groups: int
+    d_state: int
+    head_size: int
+    d_conv: int = 4
+    chunk: int = 128
+    shared_hidden: int = 0
+
+    @property
+    def d_inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: [x | B | C]."""
+        return self.d_inner + 2 * self.groups * self.d_state
+
+    def count(self, kind: str) -> int:
+        return sum(k == kind for k in self.kinds)
+
+
 def cache_lanes(head: int) -> int:
     """The last dim a mixer-kinds spec's cache gives a K or V head of
     ``head`` values: itself up to one 128-lane tile, whole tiles past it
@@ -494,14 +573,20 @@ class TransformerSpec:
     mixers: MixerKinds | None = None
     # header version 9: what an FFN applies to its gate projection
     activation: Activation = Activation()
+    # header version 10: a per-layer list whose layer is ONE mixer (Mamba-2,
+    # attention or experts), beside ``layout`` and ``router``
+    ssd: SsdLayers | None = None
 
     def __post_init__(self):
-        if self.latent is not None and (
+        if self.ssd is not None:
+            self._check_ssd()
+        elif self.latent is not None and (
                 self.latent.widened or self.activation != Activation()):
             self._check_latent_kinds()
         elif self.activation != Activation():
             raise ValueError("an activation other than SiLU is carried by "
-                             "header version 9, a latent spec's: set latent")
+                             "header version 9, a latent spec's, or 10, an "
+                             "ssd spec's: set latent or ssd")
         if self.hybrid is not None:
             self._check_hybrid()
         if self.mixers is not None:
@@ -544,14 +629,15 @@ class TransformerSpec:
                             or self.latent.rope_dim % 2):
             raise ValueError("a latent-attention spec is softmax attention "
                              "without q/k-norm, with an even rope_dim")
-        if not (self.latent or self.mixers) and (
+        if not (self.latent or self.mixers or self.ssd) and (
                 lay != ExpertLayout() or self.rope_scaling
                 or self.router != Router()):
             raise ValueError("an expert layout, a router kind and a RoPE "
                              "scaling are run by the latent-attention "
-                             "forward (models/latent.py) and the mixer-"
-                             "kinds forward (models/laguna.py) only: set "
-                             "latent or mixers")
+                             "forward (models/latent.py), the mixer-kinds "
+                             "forward (models/laguna.py) and the ssd "
+                             "forward (models/nemotron.py) only: set "
+                             "latent or mixers (or ssd)")
         if self.latent and not self.n_experts:
             raise ValueError("a latent-attention spec has expert layers "
                              "after its leading dense ones: set n_experts")
@@ -586,6 +672,40 @@ class TransformerSpec:
                 or (act.kind == "silu" and act != Activation())):
             raise ValueError(f"activation {act}: one of {ACTIVATIONS}, a "
                              f"scale and a clamp >= 0 a PolyNorm's alone")
+
+    def _check_ssd(self) -> None:
+        sd, act = self.ssd, self.activation
+        kinds = tuple(sd.kinds)
+        if (len(kinds) != self.n_layers or len(kinds) > 128
+                or any(k not in SSD_KINDS for k in kinds)):
+            raise ValueError(f"ssd.kinds: one of {SSD_KINDS} for each of "
+                             f"n_layers={self.n_layers} (at most 128)")
+        if (self.hybrid or self.latent or self.hyper or self.mixers
+                or self.retention or self.qk_norm or self.rope_scaling
+                or self.layout.dense_layers):
+            raise ValueError("an ssd spec's layer is one mixer (Mamba-2, "
+                             "attention without positional encoding, or "
+                             "experts): it combines with layout (no leading "
+                             "dense layers) and router only")
+        if (min(sd.heads, sd.head_dim, sd.groups, sd.d_state, sd.d_conv - 1,
+                sd.chunk, sd.head_size) < 1 or sd.heads % sd.groups
+                or sd.d_inner % sd.groups or sd.shared_hidden < 0
+                or sd.shared_hidden % 32 or self.n_heads % self.n_kv_heads):
+            raise ValueError(
+                f"ssd: positive sizes, heads={sd.heads} and d_inner="
+                f"{sd.d_inner} multiples of groups={sd.groups}, a shared "
+                f"width of whole Q40 blocks, n_heads a multiple of "
+                f"n_kv_heads")
+        if ("experts" in kinds) != bool(self.n_experts) or (
+                sd.shared_hidden and not self.layout.shared):
+            raise ValueError("ssd: n_experts where a layer is \"experts\", "
+                             "and there alone; layout.shared 1 with a "
+                             "shared_hidden")
+        if (act.kind not in ACTIVATIONS or act.kind == "polynorm"
+                or act.gated != (act.kind == "silu")
+                or (act.scale, act.clamp) != (1.0, 0.0)):
+            raise ValueError(f"activation {act}: an ssd spec's experts are "
+                             f"gated SiLU or non-gated relu2")
 
     def _check_mixers(self) -> None:
         mx = self.mixers
@@ -646,7 +766,7 @@ class TransformerSpec:
     def planned(self) -> bool:
         """Whether ``layer_plans`` (and not the one-kind walk) says the
         file's layers."""
-        return bool(self.latent or self.hybrid or self.mixers)
+        return bool(self.latent or self.hybrid or self.mixers or self.ssd)
 
     @property
     def retention(self) -> bool:
@@ -665,8 +785,14 @@ class TransformerSpec:
         hybrid spec's, a mixer-kinds spec's, a latent spec's with sliding
         layers: rings of latent rows beside the full layers' plane): what
         ``models/llama.slot_model`` runs."""
-        return bool(self.hybrid or self.mixers
+        return bool(self.hybrid or self.mixers or self.ssd
                     or (self.latent and self.latent.window))
+
+    @property
+    def window(self) -> int:
+        """Positions a slotted spec's window layers see (0: it has none)."""
+        rec = self.hybrid or self.mixers or self.latent
+        return rec.window if rec else 0
 
     @property
     def latent_kinds(self) -> tuple:
@@ -687,8 +813,10 @@ class TransformerSpec:
 
     @property
     def header_version(self) -> int:
-        """0 (the 28-byte header), 2, 3, 4, 5, 6, 7, 8 or 9: the lowest
+        """0 (the 28-byte header), 2, 3, 4, 5, 6, 7, 8, 9 or 10: the lowest
         that holds the spec."""
+        if self.ssd:
+            return EXT10_VERSION
         if self.mixers:
             return EXT8_VERSION if self.mixers.widened else EXT7_VERSION
         if self.latent and (self.latent.widened
@@ -721,14 +849,15 @@ class TransformerSpec:
                 EXT6_VERSION: EXT6_STRUCT.size,
                 EXT7_VERSION: EXT7_STRUCT.size,
                 EXT8_VERSION: EXT8_STRUCT.size,
-                EXT9_VERSION: EXT9_STRUCT.size}[self.header_version]
+                EXT9_VERSION: EXT9_STRUCT.size,
+                EXT10_VERSION: EXT10_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
         """A q / k head: derived, unless a latent or a mixer-kinds spec
         states it."""
-        if self.mixers:
-            return self.mixers.head_size
+        if self.mixers or self.ssd:
+            return (self.mixers or self.ssd).head_size
         return self.latent.qk_dim if self.latent else self.dim // self.n_heads
 
     @property
@@ -750,13 +879,15 @@ class TransformerSpec:
 
     @property
     def n_expert_layers(self) -> int:
+        if self.ssd:
+            return self.ssd.count("experts")
         return self.n_layers - self.layout.dense_layers if self.n_experts \
             else 0
 
     @property
     def kv_dim(self) -> int:
-        if self.mixers:
-            return self.n_kv_heads * self.mixers.head_size
+        if self.mixers or self.ssd:
+            return self.n_kv_heads * self.head_size
         return (self.dim * self.n_kv_heads) // self.n_heads
 
     def kv_shape(self, kind: str) -> tuple[int, int, int]:
@@ -792,7 +923,9 @@ class TransformerSpec:
                       (EXT6_VERSION, 36): EXT6_STRUCT,
                       (EXT7_VERSION, 187): EXT7_STRUCT,
                       (EXT8_VERSION, 193): EXT8_STRUCT,
-                      (EXT9_VERSION, 175): EXT9_STRUCT}.get((version, count))
+                      (EXT9_VERSION, 175): EXT9_STRUCT,
+                      (EXT10_VERSION, 176): EXT10_STRUCT}.get(
+                          (version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
                                  f"{version} ({count} ints)")
@@ -805,6 +938,9 @@ class TransformerSpec:
                 ints = ints[:36]
             if version in (EXT7_VERSION, EXT8_VERSION):
                 more = _read_ext7(ints[36:], base[2])
+                ints = ints[:36]
+            if version == EXT10_VERSION:
+                more = _read_ext10(ints[36:], base[2])
                 ints = ints[:36]
             nine = None
             if version == EXT9_VERSION:
@@ -877,6 +1013,14 @@ class TransformerSpec:
                 la.noise_heads, int(la.gate), la.window,
                 ACTIVATIONS.index(act.kind), 0, 0, 0, act.scale, act.clamp,
                 hc.stream_clamp, *kinds, *([255] * (128 - len(kinds))))
+        if self.ssd:
+            sd, act = self.ssd, self.activation
+            kinds = [SSD_KINDS.index(k) for k in sd.kinds]
+            return EXT10_STRUCT.pack(
+                EXT_MAGIC, EXT10_VERSION, 176, *v3, *v4, sd.heads,
+                sd.head_dim, sd.groups, sd.d_state, sd.d_conv, sd.chunk,
+                sd.head_size, sd.shared_hidden, ACTIVATIONS.index(act.kind),
+                int(act.gated), 0, 0, *kinds, *([255] * (128 - len(kinds))))
         if self.mixers:
             mx = self.mixers
             kinds = [MIXER_KINDS.index(k) for k in mx.kinds]
@@ -912,7 +1056,7 @@ class TransformerSpec:
         order: an expert spec has the four attention tensors here and its
         FFN under ``expert_matmul_shapes``."""
         d, h, kv = self.dim, self.hidden_dim, self.kv_dim
-        if self.hybrid or self.mixers:
+        if self.hybrid or self.mixers or self.ssd:
             # every distinct matmul tensor of any kind, once (a routed
             # expert's are ``expert_matmul_shapes``)
             seen = {}
@@ -961,6 +1105,8 @@ class TransformerSpec:
         if not self.n_experts:
             return []
         d, h = self.dim, self.hidden_dim
+        if not self.activation.gated:       # one up matrix, no product
+            return [("moe_w1", (h, d)), ("moe_w2", (d, h))]
         return [("moe_w1", (h, d)), ("moe_w2", (d, h)), ("moe_w3", (h, d))]
 
     def matmul_shape_counts(self) -> list[tuple[tuple[int, int], int]]:
@@ -1010,6 +1156,8 @@ class TransformerSpec:
             return self._hybrid_plans()
         if self.mixers:
             return self._mixer_plans()
+        if self.ssd:
+            return self._ssd_plans()
         norms = [("f32", n, (w,)) for n, w in self.layer_norm_shapes()]
         norms += [("f32", n, s) for n, s in self.hyper_shapes()]
         if self.latent.noise_heads:
@@ -1115,6 +1263,43 @@ class TransformerSpec:
                          else ("", i - k, ffn))
         return plans
 
+    def _ssd_plans(self):
+        """``layer_plans`` of an ssd spec: ONE entry a layer, ``stack`` its
+        kind and ``index`` its place among the layers of that kind (the
+        module docstring has each kind's tensors)."""
+        sd, d, h = self.ssd, self.dim, self.hidden_dim
+        f, m = (lambda n, *s: ("f32", n, s)), (lambda n, *s: ("mm", n, s))
+        hs, di = sd.head_size, sd.d_inner
+        experts = [f("rms_att", d), f("moe_gate", self.n_experts, d)]
+        if self.router.bias:
+            experts.append(f("moe_bias", self.n_experts))
+        if sd.shared_hidden:
+            experts += [m("sh_w1", sd.shared_hidden, d),
+                        m("sh_w2", d, sd.shared_hidden)]
+            if self.activation.gated:
+                experts.append(m("sh_w3", sd.shared_hidden, d))
+        experts += [("mm", n, s, e) for e in range(self.n_experts_held)
+                    for n, s in self.expert_matmul_shapes()]
+        mixer = {
+            "mamba2": [f("rms_att", d), m("in_zx", di + sd.conv_dim, d),
+                       f("in_dt", sd.heads, d),
+                       f("conv_w", sd.d_conv, sd.conv_dim),
+                       f("conv_b", sd.conv_dim), f("dt_bias", sd.heads),
+                       f("a_log", sd.heads), f("d_skip", sd.heads),
+                       f("norm_g", di), m("out_proj", d, di)],
+            "full": [f("rms_att", d), m("wq", self.n_heads * hs, d),
+                     m("wk", self.n_kv_heads * hs, d),
+                     m("wv", self.n_kv_heads * hs, d),
+                     m("wo", d, self.n_heads * hs)],
+            "experts": experts,
+        }
+        seen = {k: 0 for k in SSD_KINDS}
+        plans = []
+        for kind in sd.kinds:
+            plans.append((kind, seen[kind], mixer[kind]))
+            seen[kind] += 1
+        return plans
+
     def stack_leaves(self):
         """(stack, name, kind, stacked shape) of every leaf of the two layer
         stacks ``layer_plans`` walks, once each: the leading axis counts the
@@ -1126,6 +1311,8 @@ class TransformerSpec:
             depth = {"dense": self.n_dense_layers,
                      "": self.n_layers - self.n_dense_layers,
                      **{k: self.mixers.count(k) for k in MIXER_KINDS}}
+        if self.ssd:
+            depth = {k: self.ssd.count(k) for k in SSD_KINDS}
         seen, out = set(), []
         for stack, _, entries in self.layer_plans():
             for kind, name, shape, *e in entries:
@@ -1207,6 +1394,22 @@ def _read_ext7(vals, n_layers: int) -> dict:
         MixerKind(s_heads, float(s_theta), s_rot,
                   scaling(s_scaled, yarn[6:]), s_kv, bool(s_sink)),
         bool(gate), v_head, float(v_scale)))
+
+
+def _read_ext10(vals, n_layers: int) -> dict:
+    """``ssd`` and ``activation`` from a version-10 header's twelve ints
+    and 128 bytes."""
+    (heads, head_dim, groups, d_state, d_conv, chunk, head_size, shared,
+     act, gated, _, _, *kinds) = vals
+    kinds = kinds[:n_layers]
+    if (not 0 < n_layers <= 128 or not 0 <= act < len(ACTIVATIONS)
+            or any(k >= len(SSD_KINDS) for k in kinds)):
+        raise ValueError("unknown activation or layer kind in a version-10 "
+                         "header")
+    return dict(
+        ssd=SsdLayers(tuple(SSD_KINDS[k] for k in kinds), heads, head_dim,
+                      groups, d_state, head_size, d_conv, chunk, shared),
+        activation=Activation(ACTIVATIONS[act], gated=bool(gated)))
 
 
 def _read_ext9(vals, la: LatentAttn, n_layers: int) -> dict:

@@ -75,6 +75,32 @@ def hybrid_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
     return x + np.float32(1) if name in ("ln1_g", "ln2_g", "subln") else x
 
 
+SSD_LEAVES = ("in_dt", "conv_w", "conv_b", "dt_bias", "a_log", "d_skip",
+              "norm_g")
+
+
+def ssd_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
+    """A Mamba-2 layer's float32 leaf ``name`` of an ssd spec (any leading
+    layer axes in ``shape``) from ``unit(*shape)`` ~ N(0, 1), as the family
+    initialises it: ``a_log`` = log of 1 .. 16 spread evenly over the heads
+    (a head's scalar decay); ``dt_bias`` such that softplus gives 1e-3 to
+    1e-1, spread log-evenly over the heads; ``d_skip`` = 1; ``in_dt`` rows
+    ~N(0, 1/sqrt(dim)); conv taps ~N(0, 1/2), their bias +- 0.05; the
+    gated norm's gain 1 +- 0.05."""
+    if name == "a_log":
+        a = np.log(np.linspace(1.0, 16.0, shape[-1], dtype=np.float32))
+        return np.broadcast_to(a, shape).copy()
+    if name == "dt_bias":
+        dt = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), shape[-1]))
+        return np.broadcast_to((dt + np.log(-np.expm1(-dt))).astype(
+            np.float32), shape).copy()
+    if name == "d_skip":
+        return np.ones(shape, np.float32)
+    x = unit(*shape) * np.float32(
+        {"in_dt": spec.dim ** -0.5, "conv_w": 0.5}.get(name, 0.05))
+    return x + np.float32(1) if name == "norm_g" else x
+
+
 def hyper_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
     """A float32 leaf ``hc_<sub>_<phi|gate|bias>`` of a spec with several
     residual streams (any leading layer axes in ``shape``) from
@@ -124,6 +150,9 @@ def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
         elif spec.hybrid:
             dst[name] = hybrid_leaf(spec, name, shape,
                                     lambda *s: t(*s) * np.float32(20.0))
+        elif spec.ssd and name in SSD_LEAVES:
+            dst[name] = ssd_leaf(spec, name, shape,
+                                 lambda *s: t(*s) * np.float32(20.0))
         elif name.startswith("hc_"):
             dst[name] = hyper_leaf(spec, name, shape,
                                    lambda *s: t(*s) * np.float32(20.0))
@@ -351,6 +380,10 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
                     f.write(q40(*shape))
                 elif spec.hybrid:
                     f.write(memoryview(np.ascontiguousarray(hybrid_leaf(
+                        spec, name, shape, lambda *s: rng.standard_normal(
+                            s, dtype=np.float32)))).cast("B"))
+                elif spec.ssd and name in SSD_LEAVES:
+                    f.write(memoryview(np.ascontiguousarray(ssd_leaf(
                         spec, name, shape, lambda *s: rng.standard_normal(
                             s, dtype=np.float32)))).cast("B"))
                 elif name.startswith("hc_"):

@@ -72,6 +72,15 @@ SCOPE_ATTN_SINK = "attn.sink"
 SCOPE_ATTN_DIFF = "attn.diff"
 SCOPE_RING_WRITE = "ring.write"
 SCOPE_POLYNORM = "ffn.polynorm"
+# an ssd spec's Mamba-2 layers (models/nemotron.py opens them inside
+# SCOPE_ATTN; an expert layer's moe.router / moe.experts lie in SCOPE_FFN):
+# the projections in and out, the convolution and its activation, the
+# state's update and read (the decode kernel ``ops/mamba2.DECODE_KERNEL`` or
+# a chunk's matrix products), the gate and the grouped norm
+SCOPE_SSD_PROJ = "ssd.proj"
+SCOPE_SSD_CONV = "ssd.conv"
+SCOPE_SSD_SCAN = "ssd.scan"
+SCOPE_SSD_GATE_NORM = "ssd.gate_norm"
 
 
 def scope_rope(kind: str) -> str:

@@ -320,6 +320,8 @@ class EngineMetrics:
             "Mamba layer took in a decode step: near zero, a state forgets "
             "everything in one token")
         self._hybrid_seen = [0, 0, 0, 0]
+        # an ssd spec's (ContinuousStats.layers_run): a series a layer kind
+        self._layers_run: dict = {}
         # a mixer-kinds spec's (ContinuousStats.gate_min and below): its
         # rings, full layers' pages and the positions its decode steps
         # read are the hybrid gauges and counters above
@@ -644,6 +646,18 @@ class EngineMetrics:
         if st.gate_steps:
             self.attn_gate_min.set(st.gate_min)
             self.attn_gate_mean.set(st.gate_mean)
+
+    def record_layers_run(self, kinds: tuple) -> None:
+        """One landed decode step of a model whose layer is ONE mixer: its
+        layers by kind (``dllama_layers_run_total{kind=...}``)."""
+        for kind in kinds:
+            ctr = self._layers_run.get(kind)
+            if ctr is None:
+                ctr = self._layers_run[kind] = self.registry.labeled_counter(
+                    "dllama_layers_run_total", {"kind": kind},
+                    "Layers the landed decode steps ran, by kind (a model "
+                    "whose layer is ONE mixer: mamba2, full or experts)")
+            ctr.inc()
 
     def record_retire(self, req, now: float) -> None:
         """Derive the lifecycle histograms at retirement. Cancelled and

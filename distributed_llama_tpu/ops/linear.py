@@ -122,15 +122,24 @@ def polynorm(z: jax.Array, pn_w: jax.Array, scale: float, clamp: float,
         return jnp.float32(scale) * (out + bias)
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """``relu(x)^2``."""
+    r = jnp.maximum(x, 0.0)
+    return r * r
+
+
 def ffn_activation(spec, lw):
-    """What a layer's FFN applies to its gate projection: ``silu``, or the
-    spec's PolyNorm over the layer's ``pn_w`` (``TransformerSpec.
-    activation``). The ONE place the four gated-FFN sites (models/llama.
-    _swiglu's two, ops/pallas_moe's slot and XLA expert paths) take it
-    from."""
+    """What a layer's FFN applies to its gate projection (a gated FFN's:
+    before the product with the up projection) or to its one up projection
+    (``spec.activation.gated`` false): ``silu``, ``relu2``, or the spec's
+    PolyNorm over the layer's ``pn_w`` (``TransformerSpec.activation``).
+    The ONE place the four FFN sites (models/llama._swiglu's two,
+    ops/pallas_moe's slot and XLA expert paths) take it from."""
     act = spec.activation
     if act.kind == "silu":
         return silu
+    if act.kind == "relu2":
+        return relu2
     return functools.partial(polynorm, pn_w=lw["pn_w"], scale=act.scale,
                              clamp=act.clamp, eps=spec.norm_eps)
 
@@ -214,14 +223,27 @@ class Q40Layout(NamedTuple):
     and hands it down as an argument (loader, sidecar key, packer, chain
     builder); nothing reads it back from the process."""
 
-    label: str    # "i4-nb" | "nb-major" | "d-major"
+    label: str    # "i4-nb" | "nb-major" | "nb-major-pad8" | "d-major"
     reason: str
+
+    @property
+    def pad_blocks(self) -> int:
+        """> 0 ("nb-major-pad8": 8): every nb-major Q40 leaf's blocks a row
+        are padded to a multiple of it with zero blocks when it is packed
+        (``pack_q40_params``), and a matmul pads its input with zeros to
+        match (ops/pallas_q40._q40_matmul_nbmajor, ops/pallas_moe.
+        _experts_slots): the chip stores a uint8 array whose second-minor
+        dim is off the 8 grid with ANOTHER dim there, and every step then
+        copies the leaf row-major before its kernel call (6.3 GiB of
+        temporaries in an ssd spec's step at hidden 2,688 = 84 blocks:
+        benchmark/tools/rehearse_nemotron.py, PR 55)."""
+        return 8 if self.label == "nb-major-pad8" else 0
 
     @property
     def force_nb_major(self) -> bool:
         """Every leaf the nb-major row tiler places packs nb-major (the i4
         body exists only there, so pad-free 7B-class shapes need it)."""
-        return self.label in ("i4-nb", "nb-major")
+        return self.label in ("i4-nb", "nb-major", "nb-major-pad8")
 
     @property
     def i4_chain(self) -> bool:
@@ -261,7 +283,8 @@ def q40_leaf_layout(d: int, nb: int, *, tp: int = 1,
     * A routed-expert stack (``key`` ``moe_*``): nb-major where both
       grouped kernels (ops/pallas_moe) place it, else codec (the XLA scan);
       ``moe_w1`` / ``moe_w3`` are fused along d afterwards, so twice their
-      width must place too.
+      width must place too (a non-gated stack has no ``moe_w3`` and is
+      never fused; twice a width that places, places).
     * Sharded (``tp > 1``): nb-major iff the local ``nb`` is off the 128
       grid. The chip stores an
       array whose minor dim is not a multiple of 128 with the second-minor
@@ -298,6 +321,45 @@ def q40_leaf_layout(d: int, nb: int, *, tp: int = 1,
     return "d-major" if kernel_supports(d, nb * 32) else "codec"
 
 
+def _pad_blocks(w: Q40Weight, multiple: int) -> Q40Weight:
+    """``w`` with its blocks a row padded to a multiple of ``multiple`` by
+    zero blocks (codes 0 under a delta of 0: they add 0 whatever the input
+    holds there)."""
+    pad = -w.qs.shape[-2] % multiple
+    if not pad:
+        return w
+    lead = [(0, 0)] * (w.qs.ndim - 2)
+    return Q40Weight(np.pad(w.qs, lead + [(0, pad), (0, 0)]),
+                     np.pad(w.d16, lead + [(0, pad)]))
+
+
+def _pad_plain_experts(params: dict, blocks: int) -> dict:
+    """A NON-GATED expert stack (``moe_w1`` and ``moe_w2`` with no
+    ``moe_w3``) on the grid of ``blocks`` blocks: its hidden width padded
+    to whole 128-lane tiles of ``moe_w1``'s rows that are whole groups of
+    ``blocks`` blocks of ``moe_w2``'s (256 at 8), and ``moe_w1``'s own
+    blocks a row to a multiple of ``blocks`` in the same copy: zero rows
+    and zero blocks, so that the slot kernels tile it (ops/pallas_moe.
+    shape_places). Exact: the padded hidden values are act(0) = 0 for
+    ``relu2`` and meet zero weights. A gated stack is left as it is (its
+    widths are on the grid in every model here, and PolyNorm's mean runs
+    over the width)."""
+    w1, w2 = params.get("moe_w1"), params.get("moe_w2")
+    if ("moe_w3" in params or not isinstance(w1, Q40Weight)
+            or not isinstance(w2, Q40Weight)):
+        return params
+    rows = -w1.qs.shape[-3] % max(128, 32 * blocks)
+    if not rows:
+        return params
+    lead = [(0, 0)] * (w1.qs.ndim - 3)
+    nb = (0, -w1.qs.shape[-2] % blocks)
+    return dict(
+        params,
+        moe_w1=Q40Weight(np.pad(w1.qs, lead + [(0, rows), nb, (0, 0)]),
+                         np.pad(w1.d16, lead + [(0, rows), nb])),
+        moe_w2=_pad_blocks(w2, blocks))
+
+
 @startup_phase("pack")
 def pack_q40_params(params: dict, enable: bool | None = None,
                     tp: int = 1, allow_nb_major: bool = False,
@@ -325,6 +387,10 @@ def pack_q40_params(params: dict, enable: bool | None = None,
         return params
     if layout is None:
         layout = _APPLIED_LAYOUT or Q40_STOCK  # the shim's ONE reader
+    pad = layout.pad_blocks if tp == 1 else 0
+    given = params
+    if pad:
+        params = _pad_plain_experts(params, pad)
 
     def pick(k, v):
         if isinstance(v, dict):     # a second stack of layers ("dense")
@@ -349,13 +415,28 @@ def pack_q40_params(params: dict, enable: bool | None = None,
             return v
         else:
             d_loc, n_loc = d // tp, n
-        kind = q40_leaf_layout(d_loc, n_loc // 32, tp=tp, layout=layout,
-                               key=k, allow_nb_major=allow_nb_major)
+        judge = functools.partial(q40_leaf_layout, d_loc, tp=tp,
+                                  layout=layout, key=k,
+                                  allow_nb_major=allow_nb_major)
+        nb = n_loc // 32
+        if pad and nb % pad and judge(nb + -nb % pad) == "nb-major":
+            # zero blocks up to the grid (nb-major leaves alone: their
+            # matmul pads its input to match)
+            return to_kernel_layout_nb(_pad_blocks(v, pad))
+        kind = judge(nb)
         if kind == "nb-major":
             return to_kernel_layout_nb(v)
         return to_kernel_layout(v) if kind == "d-major" else v
 
-    return {k: pick(k, v) for k, v in params.items()}
+    out = {k: pick(k, v) for k, v in params.items()}
+    moe = [k for k, v in given.items()
+           if k.startswith("moe_w") and isinstance(v, Q40Weight)]
+    if pad and not all(isinstance(out[k], Q40KernelNb) for k in moe):
+        # an expert's leaves pack for the slot kernels together or stay
+        # codec (and unpadded) together: ops/pallas_moe.moe_ffn takes one
+        # path for both
+        out.update({k: given[k] for k in moe})
+    return out
 
 
 def fuse_q40_layer_matmuls(params: dict) -> dict:
@@ -468,6 +549,15 @@ def q40_body_policy(spec, rows: int = 1, sharded: bool = False) -> Q40Layout:
         # a hybrid spec's published widths; 16, 64 and 192 beside 256 at a
         # mixer-kinds spec's
         off = sorted({n // 32 for _, n in shapes if (n // 32) % 128})
+        if off and any(nb % 8 for nb in off):
+            # ... and a uint8 plane whose blocks a row are off the 8 grid
+            # is stored with another dim second-minor and copied every step
+            # all the same: such leaves pack with zero blocks up to it
+            return Q40Layout("nb-major-pad8", (
+                f"slot-and-pages spec with leaves of {off} blocks a row, "
+                f"off the 128 grid and some off the 8 grid: every leaf the "
+                f"row tiler places packs nb-major, u8 bodies, its blocks a "
+                f"row padded to a multiple of 8 with zero blocks"))
         if off:
             return Q40Layout("nb-major", (
                 f"slot-and-pages spec with leaves of {off} blocks a row, "

@@ -1,4 +1,6 @@
-"""Selective state-space scan (Mamba-1, arXiv:2312.00752) on a state of
+"""Selective state-space scan (Mamba-1, arXiv:2312.00752; Mamba-2 / SSD,
+a scalar decay a head on a state (heads, head_dim, d_state), is
+``ops/mamba2.py``) on a state of
 fixed size, one (d_state, d_inner) float32 plane a layer and sequence:
 
     s_t = exp(delta_t A) * s_{t-1} + B_t (delta_t x_t)^T,   A = -exp(A_log)
@@ -43,7 +45,8 @@ _PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 TP_REFUSAL = (
     "a hybrid (state-space and attention) model runs on one chip only: "
-    "neither the recurrent state nor the window ring is sharded over "
+    "neither the recurrent state (Mamba-1's a channel, Mamba-2's a head) "
+    "nor the window ring is sharded over "
     "tensor-parallel ranks, so --tp > 1 (or any sharded mesh) refuses it")
 
 

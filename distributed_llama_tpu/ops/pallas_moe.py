@@ -22,7 +22,14 @@ Expert weights stay Q40, stacked (L, E, ...) in the nb-major kernel layout
 counts 64 and 32 pad nothing and the chip stores the stack as it is packed;
 a d-major leaf of that shape would be copied at the top of every step,
 ops/linear.q40_leaf_layout). ``w1`` and ``w3`` are fused at load into
-``moe_w13`` (ops/linear.fuse_q40_layer_matmuls).
+``moe_w13`` (ops/linear.fuse_q40_layer_matmuls). An expert that is NOT
+gated (``spec.activation.gated`` false: ``w2_e(act(w1_e x))``, one up matrix
+and no product) has no ``w3``: its two calls run over ``moe_w1`` and
+``moe_w2`` as they are, and where the model's layout pads block counts
+(ops/linear.Q40Layout.pad_blocks) a hidden width off the grid is packed with
+zero rows / zero blocks up to it (ops/linear.pack_q40_params: exact, act(0)
+= 0 meets zero rows of ``w2``) and the rows are padded with zeros to the
+up stack's blocks.
 
 ONE grouped matmul at every dispatch width: only the (row, expert) pairs
 the router chose are computed. The dispatch's pairs are grouped into SLOTS
@@ -554,11 +561,16 @@ def shape_places(d: int, nb: int) -> bool:
 
 
 def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret,
-                   bf16=False, act=silu):
+                   bf16=False, act=silu, gated=True):
+    """``w13``: the fused [gate | up] stack, or, not ``gated``, the one up
+    stack ``moe_w1``."""
     t, k = topi.shape
     cap = slot_cap(t, k, n_experts)
     (slot_expert, n_slots, fill, slot_rows, pair_slot, pair_lane,
      counts) = build_slots(topi, n_experts, cap)
+    n_in = w13.qs_t.shape[-2] * 2 * NJ
+    if xb.shape[-1] < n_in:   # zero blocks past the input width (ops/linear.
+        xb = jnp.pad(xb, ((0, 0), (0, n_in - xb.shape[-1])))  # Q40Layout)
 
     def call(w, xs, rows=None):
         nb, d = w.qs_t.shape[-2:]
@@ -571,7 +583,8 @@ def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret,
     hid = h13.shape[-1] // 2
     # the activation between the two calls: a PolyNorm's mean runs over a
     # slot row's own hidden width (a lane no pair fills reads 0)
-    out = call(w2, act(h13[..., :hid]) * h13[..., hid:])    # (A, C, dim)
+    out = call(w2, act(h13[..., :hid]) * h13[..., hid:] if gated
+               else act(h13))                                # (A, C, dim)
     picked = out[pair_slot, pair_lane]                   # (T, k, dim)
     # a pair that took no slot reads whatever lies at the clamped index
     picked = jnp.where((topi >= 0)[..., None], picked, 0.0)
@@ -586,19 +599,22 @@ def _routing_mask(topw, topi, n_experts):
             jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32))
 
 
-def _experts_xla(lw, xb, topw, topi, n_experts, act=silu):
+def _experts_xla(lw, xb, topw, topi, n_experts, act=silu, gated=True):
     """One expert at a time through ``ops/linear.matmul`` (codec Q40 or
     dense leaves): every row through every expert, weighted 0 where it was
     not routed. Holds one dequantized expert at a time."""
     wmask, counts = _routing_mask(topw, topi, n_experts)
 
     def body(acc, ws):
-        w1, w2, w3, m = ws
-        h = act(matmul(w1, xb)) * matmul(w3, xb)
+        w1, w2, *w3, m = ws
+        h = act(matmul(w1, xb))
+        if gated:
+            h = h * matmul(w3[0], xb)
         return acc + matmul(w2, h * m[:, None]), None
 
     acc, _ = jax.lax.scan(body, jnp.zeros_like(xb, dtype=jnp.float32),
-                          (lw["moe_w1"], lw["moe_w2"], lw["moe_w3"], wmask.T))
+                          (lw["moe_w1"], lw["moe_w2"],
+                           *([lw["moe_w3"]] if gated else []), wmask.T))
     return acc, counts
 
 
@@ -625,20 +641,22 @@ def moe_ffn(spec, lw: dict, xb: jax.Array):
             topi = jnp.where(here, local, -1)
             topw = jnp.where(here, topw, 0.0)
     n_exp = held
+    gated = spec.activation.gated
     with jax.named_scope(SCOPE_MOE_EXPERTS):
-        w13, w2 = lw.get("moe_w13"), lw.get("moe_w2")
+        w13 = lw.get("moe_w13" if gated else "moe_w1")
+        w2 = lw.get("moe_w2")
         if isinstance(w13, StackedQ40) and isinstance(w2, StackedQ40):
             interpret = jax.default_backend() != "tpu"
             layer = jnp.asarray(w13.layer, dtype=jnp.int32).reshape(1)
             y, counts = _experts_slots(layer, w13.w, w2.w, x2, topw, topi,
                                        n_exp, interpret,
                                        matmul_mode() == "bf16",
-                                       ffn_activation(spec, lw))
+                                       ffn_activation(spec, lw), gated)
         elif "moe_w1" not in lw or isinstance(lw["moe_w1"], StackedQ40):
             raise NotImplementedError(
                 "expert stacks packed for the kernels without their fused "
                 "moe_w13 (ops/linear.fuse_q40_layer_matmuls)")
         else:
             y, counts = _experts_xla(lw, x2, topw, topi, n_exp,
-                                     ffn_activation(spec, lw))
+                                     ffn_activation(spec, lw), gated)
     return y.reshape(*lead, -1), counts if routed is None else routed

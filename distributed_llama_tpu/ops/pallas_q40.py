@@ -1142,6 +1142,10 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
         interpret = jax.default_backend() != "tpu"
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
+    if x2.shape[-1] < nb * QK:
+        # a leaf packed with zero blocks past its input width
+        # (ops/linear.Q40Layout.pad_blocks): zeros meet them
+        x2 = jnp.pad(x2, ((0, 0), (0, nb * QK - x2.shape[-1])))
     t = x2.shape[0]
     if t > 1 and t % 8 != 0:
         pad = (-t) % 8

@@ -766,7 +766,7 @@ def validate_sharding(spec: TransformerSpec, mesh: Mesh,
         from ..ops.retention import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)
-    if spec.hybrid:
+    if spec.hybrid or spec.ssd:
         from ..ops.mamba import TP_REFUSAL
 
         raise ValueError(TP_REFUSAL)

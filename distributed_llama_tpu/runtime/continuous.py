@@ -203,7 +203,8 @@ def sequence_caches(spec) -> frozenset:
     nothing else, which is what "a latent spec caches one plane" means
     wherever this module and models/latent.py say it) and "state" (a slot
     of fixed size that a step rewrites: a recurrent state, a window ring).
-    A hybrid spec keeps a state AND one layer's pages, a mixer-kinds spec
+    A hybrid spec keeps a state AND one layer's pages (an ssd spec: a
+    Mamba-2 state AND each attention layer's pages), a mixer-kinds spec
     rings AND its full layers' pages ("rings" rides along: its slot is
     window rings alone), a latent spec with sliding layers rings of latent
     rows AND its full layers' plane. "streams" rides along where the
@@ -214,7 +215,7 @@ def sequence_caches(spec) -> frozenset:
     if spec.latent and spec.slotted:
         return frozenset({"state", "plane", "rings"}
                          | ({"streams"} if spec.hyper else set()))
-    if spec.hybrid:
+    if spec.hybrid or spec.ssd:
         return frozenset({"state", "pages"})
     if spec.retention:
         return frozenset({"state"})
@@ -232,8 +233,9 @@ _WHY = {
                                      "residual streams caches one plane "
                                      "[c_kv | k_rope] a layer, not K and V",
     frozenset({"state", "pages"}): "a hybrid model keeps a recurrent state "
-                                   "and a window ring of fixed size beside "
-                                   "one layer's KV pages",
+                                   "(and, where it has window layers, a "
+                                   "ring) of fixed size beside its "
+                                   "attention layers' KV pages",
     frozenset({"state", "pages", "rings"}): "a mixer-kinds model keeps a "
                                             "window ring of fixed size a "
                                             "sliding layer beside its "
@@ -307,7 +309,7 @@ def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
                "slot of fixed size, with no positions to page", None)
     if serve and not page_size and paged:
         refuse("serve without --kv-page-size", "serve reads the full "
-               + ("layers' plane" if plane else "layer's K / V")
+               + ("layers' plane" if plane else "layers' K / V")
                + " through pages only (pass --kv-page-size)",
                "serve reads it through pages only (pass --kv-page-size)")
     if prefix_share:
@@ -558,6 +560,11 @@ class ContinuousStats:
     prompt_positions: int = 0
     xdec_positions: int = 0
     ssm_min_decay: float = 1.0
+    # an ssd spec (its Mamba-2 states and conv rows are ``state_bytes``,
+    # its attention layers' pool pages ``shared_kv_pages``, and
+    # ``shared_kv_positions`` counts ONE attention layer's reads): layers
+    # the landed decode steps ran, by kind (a layer there is ONE mixer)
+    layers_run: dict = dataclasses.field(default_factory=dict)
     # a mixer-kinds spec (its rings are ``window_bytes``, its full layers'
     # pool pages ``shared_kv_pages``, and ``window_kv_positions`` /
     # ``shared_kv_positions`` count ONE sliding / ONE full layer's reads):
@@ -2769,8 +2776,7 @@ class ContinuousEngine:
                 depth = [int(blk[b, 1]) + 1 for b, s in enumerate(rows)
                          if s is not None]
                 if self._hybrid:
-                    w = (self.spec.hybrid or self.spec.mixers
-                         or self.spec.latent).window
+                    w = self.spec.window
                     self.stats.shared_kv_positions += sum(depth)
                     self.stats.window_kv_positions += sum(
                         min(d, w) for d in depth)
@@ -2795,6 +2801,14 @@ class ContinuousEngine:
         return _Flight(rows, reqs, paused, logits, picked, moe, norm_min,
                        t0, prev is not None, *self._queued_ahead(),
                        self._take_chunk_moe())
+
+    def _count_layers_run(self) -> None:
+        """An ssd spec's layers a landed decode step ran, by kind."""
+        sd, run = self.spec.ssd, self.stats.layers_run
+        for kind in sd.kinds:
+            run[kind] = run.get(kind, 0) + 1
+        if self._obs is not None:
+            self._obs.record_layers_run(sd.kinds)
 
     def _queued_ahead(self) -> tuple[int, int]:
         """(admission prefill chunks, admissions with device work) enqueued
@@ -2934,6 +2948,8 @@ class ContinuousEngine:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
             with host_phase("serve.census"):
                 self.stats.steps += 1
+                if self.spec.ssd:
+                    self._count_layers_run()
                 self.stats.sum_active += active0
                 self.stats.max_active = max(self.stats.max_active, active0)
                 self._census_dispatch("decode", 1, flight.paused, active0,

@@ -258,7 +258,7 @@ class Engine:
                 if self.spec.mixers or self.spec.latent:
                     # (smallest gate, mean gate)
                     self.gate_min = min(self.gate_min, low)
-                elif self.spec.hybrid:
+                elif self.spec.hybrid or self.spec.ssd:
                     self.ssm_min_decay = min(self.ssm_min_decay, low)
                 else:
                     self.min_normaliser = min(self.min_normaliser, low)

@@ -643,9 +643,11 @@ def test_the_six_readers_and_their_entries(family, name):
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
         doc = json.load(fh)
     entry = next(m for m in doc["per_layer"] if m["name"] == family + name)
-    assert entry == {
+    listed = ["mistral7b.serve-chat"] if family == "chat_" else SAT_CELLS
+    # a later cell whose window holds admissions appends its name
+    assert entry["workloads"][:len(listed)] == listed
+    assert dict(entry, workloads=listed) == {
         "name": family + name, "unit": unit, "better": "lower",
         "source": "program_span", "layer": "scheduler", "moves": moves,
-        "workloads": (["mistral7b.serve-chat"] if family == "chat_"
-                      else SAT_CELLS)}
+        "workloads": listed}
     assert entry in doc["per_layer"]     # later PRs append after them
