@@ -439,47 +439,135 @@ def pack_q40_params(params: dict, enable: bool | None = None,
     return out
 
 
-def fuse_q40_layer_matmuls(params: dict) -> dict:
-    """Concatenate the stacked Q40 qkv (and w1/w3) weights along the output
-    dim into single kernel tensors ``wqkv`` / ``w13``, host-side, at load.
+class RankMajor:
+    """One plane (``qs_t`` or ``scale``) of a fused leaf laid RANK-MAJOR
+    over ``ranks`` tensor-parallel ranks: rank r's contiguous band of
+    ``axis`` (the output rows) is ``[member 0's r-th band | member 1's |
+    ...]``, so a contiguous ``P("tp")`` cut hands every rank the rows
+    ``parallel/tp._tp_qkv`` / ``_swiglu_local`` split (``[q_r | k_r |
+    v_r]``, ``[w1_r | w3_r]``). The concat is taken a rank, never over the
+    whole leaf: the whole plane exists only as a ``shape``, and ``band``
+    copies a rank's members' bands ONCE, into the buffer that is placed
+    (``parallel/tp.shard_params``'s one copy a shard). With one rank the
+    only band is the plain concat."""
+
+    def __init__(self, parts, axis: int, ranks: int):
+        self.parts, self.ranks = tuple(parts), ranks
+        first = self.parts[0]
+        self.axis = axis % first.ndim
+        rest = {p.shape[:self.axis] + p.shape[self.axis + 1:]
+                for p in self.parts}
+        if len(rest) != 1 or any(p.shape[self.axis] % ranks
+                                 for p in self.parts):
+            raise ValueError(
+                f"members of shapes {[p.shape for p in self.parts]} do not "
+                f"fuse along axis {axis} over {ranks} ranks")
+        self.dtype, self.ndim = first.dtype, first.ndim
+        self.widths = [p.shape[self.axis] // ranks for p in self.parts]
+        self.band_shape, self.shape = (
+            (*first.shape[:self.axis], bands * sum(self.widths),
+             *first.shape[self.axis + 1:]) for bands in (1, ranks))
+
+    def rank_of(self, idx) -> int:
+        """The rank whose band the index ``idx`` of the whole plane is (a
+        shard's, as ``jax.make_array_from_callback`` hands it over)."""
+        band = sum(self.widths)
+        cut = idx[self.axis]
+        if (cut.start is None or cut.start % band
+                or cut.stop - cut.start != band):
+            raise ValueError(
+                f"a rank-major plane {self.shape} of {self.ranks} bands of "
+                f"{band} on axis {self.axis} is cut a rank's band at a "
+                f"time, not {idx}")
+        return cut.start // band
+
+    def band(self, rank: int, rows: slice = slice(None), out=None):
+        """Rank ``rank``'s band, rows ``rows`` of its leading axis (whole
+        by default; ``parallel/tp.shard_params`` threads over them), into
+        ``out`` where given."""
+        at = [slice(None)] * self.ndim
+        at[0] = rows
+        pieces = []
+        for part, width in zip(self.parts, self.widths):
+            at[self.axis] = slice(rank * width, (rank + 1) * width)
+            pieces.append(part[tuple(at)])
+        return np.concatenate(pieces, axis=self.axis, out=out)
+
+
+def _row_tiles(leaf, d: int) -> tuple:
+    """Which dispatch widths of a kernel leaf of ``d`` (shard-local) rows
+    have a row tile, in its layout and at its blocks a row: one row, a
+    decode dispatch's 8, a chunk's 128. The rest dequantize and dot
+    (ops/pallas_q40.q40_matmul)."""
+    from .pallas_q40 import (_pick_block_rows, _pick_block_t, _pick_planes,
+                             _pick_rows_mxu, _pick_rows_t1)
+
+    if isinstance(leaf, Q40KernelNb):
+        nb = leaf.qs_t.shape[-2]
+        tiles = [_pick_rows_t1(d, nb)]
+        for t in (8, 128):
+            block_t = _pick_block_t(t, nb)
+            tiles.append(_pick_rows_mxu(d, nb, block_t,
+                                        _pick_planes(nb, block_t)))
+    else:
+        nb = leaf.qs_t.shape[-1]
+        tiles = [_pick_block_rows(d, t, nb, _pick_block_t(t, nb))
+                 for t in (1, 8, 128)]
+    return tuple(rows is not None for rows in tiles)
+
+
+def fuse_q40_layer_matmuls(params: dict, ranks: int = 1) -> dict:
+    """Fuse the stacked Q40 qkv (and w1/w3) kernel leaves along the output
+    dim into single leaves ``wqkv`` / ``w13``, host-side, at load: with one
+    rank (one chip; a rank's own band tree, parallel/shard_sim) the plain
+    concat, over ``ranks`` tensor-parallel ranks the RANK-MAJOR one, whose
+    planes are ``RankMajor`` values that ``parallel/tp.shard_params``
+    assembles shard by shard as it places them.
 
     The three qkv matmuls (and the two SwiGLU input matmuls) share the same
     input vector; one wide kernel call replaces three (two) narrow ones,
-    which matters for single-token decode where the d=4096 matvec runs at
-    roughly half the bytes/s of the d>=11008 ones (grid too short to hide
-    pipeline ramp). Row-wise the math is unchanged — outputs are split back
-    by models/llama (the reference computes the same three matmuls back to
-    back, transformer-tasks.cpp:167-179).
+    which matters for single-token decode: a call pays about 3.8 us of
+    pipeline ends whatever it streams (PERF.md section 7), and the narrow
+    ones stream little else. Row-wise the math is unchanged: outputs are
+    split back by models/llama and parallel/tp (the reference computes the
+    same three matmuls back to back, transformer-tasks.cpp:167-179).
 
-    Only fires on stacked Q40Kernel entries (i.e. after pack_q40_params on
-    the single-chip path); dense/TP trees pass through untouched.
+    A group fuses only where every member is the same kernel layout
+    (``Q40Kernel`` or ``Q40KernelNb``, i.e. after ``pack_q40_params``; a
+    codec leaf or a dense array passes through), every member's rows divide
+    over the ranks, the fused shard-local width has a row tile for the
+    one-row body, and it has one at a decode dispatch's and a chunk's rows
+    wherever every member has (``_row_tiles``): a fused leaf never falls to
+    dequantize-then-dot where its members did not. Else the group stays as
+    it is and the forwards take their unfused branch.
     """
-    from .pallas_q40 import _pick_rows_nb, kernel_supports
-
-    out = {k: fuse_q40_layer_matmuls(v) if isinstance(v, dict) else v
+    out = {k: fuse_q40_layer_matmuls(v, ranks) if isinstance(v, dict) else v
            for k, v in params.items()}
 
     def fuse(dst, keys):
-        # host numpy tree by contract (runs after pack_q40_params, before
-        # device placement) — np.concatenate takes the leaves directly
         ws = [out.get(k) for k in keys]
         if all(isinstance(w, Q40Kernel) and w.qs_t.ndim == 4 for w in ws):
-            qs_t = np.concatenate([w.qs_t for w in ws], axis=2)
-            scale = np.concatenate([w.scale for w in ws], axis=1)
-            if not kernel_supports(qs_t.shape[2], qs_t.shape[3] * 32):
-                return
-            out[dst] = Q40Kernel(qs_t, scale)
+            axis = -2       # d-major: qs_t (L, 16, d, nb), scale (L, d, nb)
         elif all(isinstance(w, Q40KernelNb) and w.qs_t.ndim in (4, 5)
                  for w in ws):
-            # nb-major: the output dim d is MINOR — concat along it (an
-            # expert stack has one more leading axis)
-            qs_t = np.concatenate([w.qs_t for w in ws], axis=-1)
-            scale = np.concatenate([w.scale for w in ws], axis=-1)
-            if _pick_rows_nb(qs_t.shape[-1], qs_t.shape[-2]) is None:
-                return
-            out[dst] = Q40KernelNb(qs_t, scale)
+            axis = -1       # nb-major: the output dim d is MINOR (an
+            #                 expert stack has one more leading axis)
         else:
             return
+        local = [w.scale.shape[axis] // ranks for w in ws]
+        if any(w.scale.shape[axis] % ranks for w in ws):
+            return
+        fused = _row_tiles(ws[0], sum(local))
+        members = [_row_tiles(w, d) for w, d in zip(ws, local)]
+        if not fused[0] or any(all(m) and not f
+                               for f, *m in zip(fused, *members)):
+            return
+        # host numpy tree by contract (runs after pack_q40_params, before
+        # device placement)
+        planes = (RankMajor([w.qs_t for w in ws], axis, ranks),
+                  RankMajor([w.scale for w in ws], axis, ranks))
+        out[dst] = type(ws[0])(*(p.band(0) if ranks == 1 else p
+                                 for p in planes))
         for k in keys:
             del out[k]
 

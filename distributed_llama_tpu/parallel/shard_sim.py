@@ -189,14 +189,18 @@ def init_rank_cache(spec: TransformerSpec, n_slices: int, dtype=None):
 
 
 def rank_params_to_device(params: dict[str, Any]) -> dict[str, Any]:
-    """Kernel-pack + fuse + device_put the band tree (shapes are already
-    local, so pack with tp=1 — identical layout to the band a real
-    shard_params device_puts to each chip: packing is band-local in both
+    """Kernel-pack + fuse + device_put ONE rank's band tree (shapes are
+    already local, so pack with tp=1 — identical layout to the band a real
+    shard_params places on each chip: packing is band-local in both
     schemes, whichever dim the band slices).
-    Fusing the rank's wq/wk/wv (and w1/w3) bands into wqkv/w13 is valid
-    per-rank by construction (the bands are this rank's contiguous rows)
-    and cuts per-token kernel launches from 7 to 4 per layer — at 80
-    layers the launch overhead is a measurable slice of the rank step."""
+    The rank's wq/wk/wv (and w1/w3) bands are fused into wqkv/w13 by the
+    plain concat, which is safe HERE because the tree holds one rank's rows
+    and nothing is cut after it: it is the band [q_r | k_r | v_r] that
+    shard_params assembles for rank r when it fuses the whole model's tree
+    rank-major (ops/linear.fuse_q40_layer_matmuls over n_tp ranks), so the
+    sim and the mesh run the same 4 kernel calls a layer. The concat that is
+    refused is the whole leaf's ([q; k; v] of the full model, cut in
+    contiguous tp bands afterwards): shard_params raises on such a tree."""
     import jax
     import jax.numpy as jnp
 

@@ -140,13 +140,19 @@ _MATMUL_SPECS = {
     "wv": P(None, "tp", None), "wo": P(None, "tp", None),
     "w1": P(None, "tp", None), "w2": P(None, "tp", None),
     "w3": P(None, "tp", None),
-    # NOTE: fused wqkv/w13 (ops/linear.fuse_q40_layer_matmuls) are
-    # deliberately ABSENT: contiguous P-sharding of a [q;k;v] concat would
-    # hand rank 0 only q rows while _tp_qkv splits each local chunk as
-    # [q|k|v] — silently wrong. Fused trees are per-rank-local only
-    # (shard_sim); a fused tree reaching shard_params fails loudly here.
+    # NOTE: the fused leaves are output bands like their members, which is
+    # right for ONE concat only: the rank-major one, where rank r's
+    # contiguous band is [q_r | k_r | v_r] ([w1_r | w3_r]), the rows _tp_qkv
+    # / _swiglu_local split. shard_params makes it, after the tp-aware pack
+    # (ops/linear.fuse_q40_layer_matmuls over n_tp ranks), a shard at a time.
+    # A concat over the WHOLE leaf ([q; k; v], what one chip and shard_sim's
+    # rank-local tree hold) would hand rank 0 only q rows, silently wrong:
+    # a tree that reaches shard_params already fused is refused there.
+    "wqkv": P(None, "tp", None), "w13": P(None, "tp", None),
     "wcls": P("tp", None),
 }
+# what shard_params fuses a layer's wq/wk/wv and w1/w3 into, a rank
+FUSED_GROUPS = ("wqkv", "w13")
 _REPL_SPECS = {
     "tok_embedding": P(), "rms_att": P(), "rms_ffn": P(), "rms_final": P(),
 }
@@ -262,23 +268,30 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
 
     Q40 weights are re-tiled to the Pallas kernel layout first (host side,
     once) when the Q40 fast path is active, each leaf laid out by
-    ops/linear.q40_leaf_layout on its shard-local shape. Placement goes
+    ops/linear.q40_leaf_layout on its shard-local shape, and a layer's
+    wq/wk/wv (w1/w3) kernel leaves are then fused RANK-MAJOR into wqkv (w13)
+    where ops/linear.fuse_q40_layer_matmuls's rule lets them: one kernel
+    call a group, the fused shard assembled in the one host copy a shard
+    gets anyway. Placement goes
     through ``make_array_from_callback``, not ``device_put``: each process
     materializes ONLY its addressable shards (a multi-host device_put both
     asserts bitwise-equal full values on every host — which slice-streamed
     weights deliberately violate, their unfetched bands being zeros — and
     would ship n_hosts copies of every tensor across the wire).
     """
-    import concurrent.futures
-    import os
     import sys
 
-    import numpy as np
-
-    from ..ops.linear import pack_q40_params
+    from ..ops.linear import fuse_q40_layer_matmuls, pack_q40_params
 
     scheme = scheme or tp_scheme()
     n_tp = mesh.shape["tp"]
+    whole = [k for k in FUSED_GROUPS if k in params]
+    if whole:
+        raise ValueError(
+            f"{' '.join(whole)}: a tree fused over the WHOLE leaf cannot be "
+            f"sharded (a contiguous tp band of a [q; k; v] concat is not a "
+            f"rank's [q_r | k_r | v_r]): hand shard_params the members, it "
+            f"fuses them a rank")
     if scheme in _INPUT_SHARDED_SCHEMES and n_tp > 1:
         # quantized wo/w2 shard along their nb block axis: fail with the
         # clear constraint here, not a sharding traceback mid-device_put
@@ -293,6 +306,7 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
         params, tp=n_tp,
         input_sharded=(FUSED_INPUT_SHARDED
                        if scheme in _INPUT_SHARDED_SCHEMES else ()))
+    params = fuse_q40_layer_matmuls(params, ranks=n_tp)
     layouts = {label: [k for k, v in params.items() if isinstance(v, kind)]
                for label, kind in (("nb-major", Q40KernelNb),
                                    ("d-major", Q40Kernel),
@@ -312,29 +326,59 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
         mxu = sum(_t1_mxu(params[k].qs_t.shape[-2]
                           // (n_tp if k in sharded_in else 1))
                   for k in layouts["nb-major"])
+        groups = [k for k in FUSED_GROUPS if k in params]
         print(f"💡 Q40 sharded layout: {picks} (tp={n_tp} {scheme}; a "
               f"shard-local block count off the 128 grid packs nb-major; "
-              f"t1 mxu {mxu}/{sum(map(len, layouts.values()))})",
-              file=sys.stderr)
+              f"t1 mxu {mxu}/{sum(map(len, layouts.values()))}; fused: "
+              f"{' '.join(groups) or 'none'}; "
+              f"{sum(k in params for k in LAYER_KEYS[2:])} Q40 calls a "
+              f"layer)", file=sys.stderr)
+    return place_params(params, mesh, scheme)
+
+
+def place_params(params: dict[str, Any], mesh: Mesh,
+                 scheme: str) -> dict[str, Any]:
+    """``shard_params``'s placement alone: the tree as it is handed over
+    (packed, fused or neither) onto the mesh by ``param_specs``, a shard a
+    host copy."""
+    import concurrent.futures
+    import os
+
+    import numpy as np
+
+    from ..ops.linear import RankMajor
+
     specs = param_specs(params, scheme)
     threads = min(16, os.cpu_count() or 1)
 
     def cut(a, idx):
         # host tree by contract (loader/synth/pack all emit numpy): this
-        # contiguous copy of one shard is the one conversion point. It is
-        # bound by first-touch page faults, not bandwidth (0.75 GB/s on one
-        # thread: 28 of Yi-34B's 38 s of placement), so bands of a large
-        # shard's leading axis go to threads
-        view = a[idx]
-        if view.ndim < 2 or view.nbytes < _CUT_THREAD_BYTES:
-            return np.ascontiguousarray(view)
-        out = np.empty(view.shape, view.dtype)
-        bands = np.linspace(0, len(view), threads + 1).astype(int)
+        # contiguous copy of one shard is the one conversion point, and
+        # where a rank-major leaf's shard is assembled from its members'
+        # bands. It is bound by first-touch page faults, not bandwidth
+        # (0.75 GB/s on one thread: 28 of Yi-34B's 38 s of placement), so
+        # bands of a large shard's leading axis go to threads
+        if isinstance(a, RankMajor):
+            rank, shape = a.rank_of(idx), a.band_shape
 
-        def copy(lo, hi):
-            out[lo:hi] = view[lo:hi]
+            def copy(out, lo, hi):
+                a.band(rank, slice(lo, hi), out[lo:hi])
+        else:
+            view = a[idx]
+            shape = view.shape
+            if view.ndim < 2 or view.nbytes < _CUT_THREAD_BYTES:
+                return np.ascontiguousarray(view)
 
-        list(pool.map(copy, bands[:-1], bands[1:]))
+            def copy(out, lo, hi):
+                out[lo:hi] = view[lo:hi]
+
+        out = np.empty(shape, a.dtype)
+        if out.nbytes < _CUT_THREAD_BYTES:
+            copy(out, 0, len(out))
+        else:
+            bands = np.linspace(0, len(out), threads + 1).astype(int)
+            list(pool.map(functools.partial(copy, out), bands[:-1],
+                          bands[1:]))
         return out
 
     def put(a, s):
@@ -343,7 +387,9 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
             lambda idx, a=a: cut(a, idx))
 
     # ``make_array_from_callback`` cuts each shard on the host before it
-    # returns: ``place`` holds the copies, the transfers are enqueued
+    # returns: ``place`` holds the copies, the transfers are enqueued and
+    # run behind the next leaf's copies (the tree's order: the largest
+    # leaves first read 3.3 s MORE of Yi-34B's placement, PERF.md PR 56)
     with startup_phase("place"), \
             concurrent.futures.ThreadPoolExecutor(threads) as pool:
         placed = jax.tree_util.tree_map(put, params, specs)
@@ -739,7 +785,9 @@ def _local_layer(spec: TransformerSpec, n_slices: int, n_sp: int, x, lw,
     return x, k_all, v_all, None
 
 
-LAYER_KEYS = ("rms_att", "rms_ffn", "wq", "wk", "wv", "wo", "w1", "w2", "w3")
+# a layer's leaves, its matmuls from [2:]
+LAYER_KEYS = ("rms_att", "rms_ffn", "wq", "wk", "wv", "wo", "w1", "w2",
+              "w3") + FUSED_GROUPS
 
 
 def validate_sharding(spec: TransformerSpec, mesh: Mesh,

@@ -68,6 +68,10 @@ LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
           "yi-wq": (1792, 7168), "yi-wk": (256, 7168), "yi-wo": (7168, 1792),
           "yi-w1": (5120, 7168), "yi-w2": (7168, 5120),
           "yi-wcls": (16000, 7168),
+          # ... and what shard_params fuses a rank since PR 56: [q_r | k_r |
+          # v_r] (three tiles of 768 at one row) and [w1_r | w3_r] (ten of
+          # 1,024)
+          "yi-wqkv": (2304, 7168), "yi-w13": (10240, 7168),
           "br-wcls": (151936, 5120),
           # Laguna-XS.2's (dim 2048: 64 blocks a row; a full layer's 48
           # heads and a sliding layer's 64 over 8 KV heads of 128: wqkv of
@@ -485,7 +489,8 @@ CASES = {
     # (256 rows: one tile) and classifier (16,000 rows: 25 tiles of 640)
     "q40-nb-w2-nb544-T1": (_q40_wide_w2, True),
     **{f"q40-nb-{leaf}-T1": (functools.partial(_q40, "nb", leaf, 1), True)
-       for leaf in ("m-w13", "m-w2", "yi-wo", "yi-wk", "yi-wcls")},
+       for leaf in ("m-w13", "m-w2", "yi-wo", "yi-wk", "yi-wcls",
+                    "yi-wqkv", "yi-w13")},
     # DeepSeek-V3 (PR 33). The latent plane: was "Slice shape along
     # dimension 2 must be aligned to tiling (128), but is 576" on
     # memref<36873x16x640xf32> (the chip stores 576 values in 640 lanes
@@ -528,7 +533,8 @@ CASES = {
                         ("wcls", (128,)), ("yi-wq", (128,)),
                         ("yi-wk", (128,)), ("yi-wo", (128,)),
                         ("yi-w1", (128,)), ("yi-w2", (128,)),
-                        ("yi-wcls", (128,)), ("br-wcls", (16,)))
+                        ("yi-wcls", (128,)), ("yi-wqkv", (128,)),
+                        ("yi-w13", (128,)), ("br-wcls", (16,)))
        for t in ts},
     **{f"moe-ds-{kind}-{leaf}-T{rows}":
        (functools.partial(_moe, leaf, rows, "ds"), True)

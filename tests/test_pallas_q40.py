@@ -145,7 +145,8 @@ def test_tp_sharded_forward_with_kernel_layout(monkeypatch):
     monkeypatch.setenv("DLLAMA_ATTN_KERNEL", "pallas")
     mesh = make_mesh(tp=2)
     sharded = shard_params(params, mesh)
-    assert isinstance(sharded["wq"], Q40Kernel)  # packed + sharded
+    # packed, fused a rank (64 + 2 x 32 local rows, d-major) + sharded
+    assert isinstance(sharded["wqkv"], Q40Kernel) and "wq" not in sharded
     fwd = make_sharded_forward(spec, mesh)
     got_logits, _ = fwd(sharded, shard_cache(init_cache(spec), mesh), tok,
                         jnp.int32(0))

@@ -155,12 +155,14 @@ def test_sharded_forward_on_nb_major_leaves(scheme, t, monkeypatch, capfd):
     capfd.readouterr()
     sharded = shard_params(params, mesh, scheme=scheme)
     note = capfd.readouterr().err
-    leaves = ("wq", "wk", "wv", "wo", "w1", "w2", "w3", "wcls")
+    # the members of wqkv (256 + 2 x 128 local rows) and w13 (2 x 512)
+    # are fused a rank since PR 56 (tests/test_tp_fused_leaves.py)
+    leaves = ("wqkv", "wo", "w13", "w2", "wcls")
     assert all(isinstance(sharded[k], Q40KernelNb) for k in leaves)
     assert "Q40 sharded layout: nb-major: " in note and "d-major" not in note
     # every shard-local block count (8, 16 or 32) is a multiple of 8: the
-    # one-row dispatch of all eight leaves is the MXU matvec
-    assert "t1 mxu 8/8" in note
+    # one-row dispatch of all five leaves is the MXU matvec
+    assert "t1 mxu 5/5; fused: wqkv w13; 4 Q40 calls a layer" in note
     in_banded = scheme != "ref"
     assert sharded["w2"].qs_t.sharding.shard_shape(
         sharded["w2"].qs_t.shape) == ((1, 16, 16, 512) if in_banded
