@@ -10,10 +10,9 @@ BEST published figures per model: 7B 494.00 ms (4x RasPi), 13B 848.19 ms
 
 Configs (--config):
   all      (default) run 7b + 13b + 70b-tp8 + the six scaling rows below,
-           each in its own subprocess (one extra profiled chain per row
-           carries the I/T split), write the FULL table to BENCH_FULL.json,
-           and emit ONE COMPACT JSON line (headline + per-row ms/x/I/T +
-           "scaling_x_vs_same_n" pairs — the driver command; VERDICT
+           each in its own subprocess, write the FULL table to
+           BENCH_FULL.json, and emit ONE COMPACT JSON line (headline +
+           per-row ms/x, I/T on the tp rows, "scaling_x_vs_same_n" pairs — the driver command; VERDICT
            r2 #1/r3 #2/r4 #1 — every claim driver-verifiable and the
            stdout line sized for the driver's capture).
   7b       whole model on one chip — the headline row.
@@ -376,56 +375,6 @@ def _bench(spec, params, samples: int, per_step: bool = False,
     # elapsed/samples would then understate the true per-token cost
     from distributed_llama_tpu.io.tokenizer import BOS
 
-    prof_dir = os.environ.get("DLLAMA_BENCH_PROFILE")
-    if prof_dir:
-        # op-time attribution of ONE timed chain: per-token device op ms
-        # by kernel family, printed to stderr next to the wall number.
-        # Also derives the reference-shaped I/T split (utils.cpp:104-106,
-        # README.md:50): I = device compute op time, T = collective op
-        # time — and carries both into the row JSON (VERDICT r4 #8).
-        from distributed_llama_tpu.utils.it_split import (
-            bucket_ops_from_splits, parse_trace, summarize)
-
-        try:
-            with jax.profiler.trace(prof_dir):
-                toks, _ = run(*args())
-                toks = np.asarray(toks)
-            # divide by the steps the chain actually RAN (a --model chain
-            # can BOS-terminate early), mirroring the timed loop below
-            bos = np.flatnonzero(toks[:samples] == BOS)
-            ran = int(bos[0]) + 1 if len(bos) else samples
-            splits = parse_trace(prof_dir)  # parse the big xplane ONCE
-            per_tok = bucket_ops_from_splits(splits, ran)
-            print(f"op-time per token (ms, {ran}-step chain): {per_tok} "
-                  f"total {round(sum(per_tok.values()), 3)}", file=sys.stderr)
-            i_ms, t_ms = summarize(splits, tokens=ran, out=sys.stderr)
-            _STARTUP["it_split"] = {
-                "I_ms_per_token": round(i_ms, 3),
-                "T_ms_per_token": round(t_ms, 3),
-                "basis": "profiler device op time over one timed chain; "
-                         "I=compute ops, T=collective ops (0 on one chip; "
-                         "tp rows carry modeled ICI separately)"}
-            _STARTUP["op_ms_per_token"] = per_tok
-            # drift columns (ISSUE 5): phase attribution + the measured-
-            # vs-modeled collective verdict from the SAME parsed trace
-            from distributed_llama_tpu.obs.drift import bench_drift_fields
-
-            _STARTUP["drift"] = bench_drift_fields(splits, spec, rank_tp,
-                                                   tokens=ran)
-            print(f"drift: {_STARTUP['drift']['verdict']} "
-                  f"(phase coverage "
-                  f"{_STARTUP['drift']['phase_coverage']:.0%}, collective "
-                  f"ms/token measured "
-                  f"{_STARTUP['drift']['collectives']['measured_ms_per_token']}"
-                  f" vs modeled "
-                  f"{_STARTUP['drift']['collectives']['modeled_ms_per_token']})",
-                  file=sys.stderr)
-        except Exception as e:  # noqa: BLE001 - attribution is best-effort
-            # the profiled chain is an EXTRA run: a trace hiccup (a full
-            # disk, a malformed xplane) must not take down the timed rows
-            print(f"profile attribution failed ({type(e).__name__}: {e}); "
-                  f"timing continues unprofiled", file=sys.stderr)
-
     times = []
     executed = samples
     n_trials = _bench_trials()
@@ -568,8 +517,8 @@ def _project_tp(spec, rank_tp: int, ms: float, baseline: float) -> dict:
                                   "4-gather MatmulSlice schedule")
     schemes_out["overlap"]["note"] = (
         "ring-decomposed combines (bitwise == fused); total subtracts the "
-        "modeled hidden collective time — the tracecheck overlap gate "
-        "holds a real capture to it")
+        "modeled hidden collective time — no capture has been held "
+        "to it")
     if scheme != "ref":
         # APPEND: the overlap caveat above is load-bearing in archived
         # rows and must survive being the active scheme
@@ -603,17 +552,13 @@ def _compact_summary(configs, rows, curve) -> dict:
     """The driver-parseable stdout line (VERDICT r4 #1): round 4's full
     table outgrew the driver protocol's capture (BENCH_r04 recorded a
     2000-char truncation -> parsed=null), so the stdout line now carries
-    only the headline per row (ms, x-vs-reference, I/T when profiled) and
+    only the headline per row (ms, x-vs-reference, I/T on the tp rows) and
     the scaling table as [ms, x-vs-same-n] pairs; everything else lives in
     BENCH_FULL.json. A guard test pins the line length (test_bench_smoke)."""
     def brief(r):
         if "value" not in r:
             return {"error": r.get("error", "?")}
         b = {"ms": r["value"], "x": r["vs_baseline"]}
-        it = r.get("it_split")
-        if it:
-            b["I"] = it["I_ms_per_token"]
-            b["T"] = it["T_ms_per_token"]
         if "shard_ms_measured" in r:  # tp rows: modeled ICI is the T analog
             b["I"] = r["shard_ms_measured"]
             b["T"] = round(r["ici_bandwidth_ms_modeled"]
@@ -626,8 +571,8 @@ def _compact_summary(configs, rows, curve) -> dict:
                for m, pts in curve.items()} if curve else None
     head = rows.get(configs[0], {})
     out = {
-        "metric": "llama2 q40 single-token decode (7b headline; "
-                  "I/T=compute/collective ms/token; full table: "
+        "metric": "llama2 q40 single-token decode (7b headline; tp rows: "
+                  "I/T=measured rank/modeled ICI ms/token; full table: "
                   "BENCH_FULL.json)",
         "value": head["value"],
         "unit": "ms/token",
@@ -643,19 +588,14 @@ def _run_all(args) -> int:
     """Default driver protocol (VERDICT r2 #1 + r3 #2): run the 7b, 13b,
     70b-tp8 configs plus the six {7b,13b}-tp{2,4,8} scaling rows — each in
     its OWN subprocess, so a 16 GB chip never holds two models' weights at
-    once and a crash in one row cannot take down the others. Each row runs
-    one extra profiled chain so its JSON carries the reference-shaped I/T
-    split (VERDICT r4 #8). The FULL table (every row field + the assembled
-    scaling_curve) is written to BENCH_FULL.json in the repo; stdout gets
-    ONE COMPACT line (VERDICT r4 #1 — round 4's full-table line overflowed
+    once and a crash in one row cannot take down the others. The FULL
+    table (every row field + the assembled scaling_curve) is written to
+    BENCH_FULL.json in the repo; stdout gets ONE COMPACT line (VERDICT r4 #1 — round 4's full-table line overflowed
     the driver's capture and the round recorded parsed=null). The headline
     value/vs_baseline stay the 7B row, the chart the driver has tracked
     since round 1. DLLAMA_BENCH_CONFIGS overrides the config list (test
     hook; CI smokes the aggregation with 'small')."""
-    import shutil
     import subprocess
-
-    from distributed_llama_tpu.utils.compile_cache import default_cache_dir
 
     configs = [c for c in os.environ.get(
         "DLLAMA_BENCH_CONFIGS",
@@ -668,22 +608,9 @@ def _run_all(args) -> int:
         cmd = [sys.executable, os.path.abspath(__file__),
                "--config", cfg, "--samples", str(args.samples)]
         print(f"=== bench --config {cfg} ===", file=sys.stderr)
-        env = dict(os.environ)
-        prof = None
-        if env.get("DLLAMA_BENCH_NO_PROFILE") != "1" \
-                and "DLLAMA_BENCH_PROFILE" not in env:
-            # a fixed per-row path under the cache directory, emptied
-            # before and after the row (traces are ~100s of MB)
-            prof = os.path.join(default_cache_dir(), "bench-prof", cfg)
-            shutil.rmtree(prof, ignore_errors=True)
-            os.makedirs(prof, exist_ok=True)
-            env["DLLAMA_BENCH_PROFILE"] = prof
         t0 = time.perf_counter()
-        proc = subprocess.run(cmd, stdout=subprocess.PIPE, text=True,
-                              env=env)
+        proc = subprocess.run(cmd, stdout=subprocess.PIPE, text=True)
         dt = time.perf_counter() - t0
-        if prof:
-            shutil.rmtree(prof, ignore_errors=True)
         line = proc.stdout.strip().splitlines()[-1] if proc.stdout.strip() \
             else ""
         if proc.returncode != 0 or not line.startswith("{"):
@@ -692,11 +619,8 @@ def _run_all(args) -> int:
             rows[cfg] = {"error": f"rc={proc.returncode}"}
             continue
         rows[cfg] = json.loads(line)
-        it = rows[cfg].get("it_split", {})
-        it_note = (f"  I {it['I_ms_per_token']} T {it['T_ms_per_token']}"
-                   if it else "")
         print(f"--config {cfg}: {rows[cfg]['value']} ms/token "
-              f"(x{rows[cfg]['vs_baseline']} vs reference;{it_note} "
+              f"(x{rows[cfg]['vs_baseline']} vs reference; "
               f"{dt:.0f}s wall)", file=sys.stderr)
     head = rows.get(configs[0], {})
     failed = [cfg for cfg, r in rows.items() if "value" not in r]

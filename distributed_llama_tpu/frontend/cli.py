@@ -493,10 +493,10 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
                          "program). Needs --prefill-chunk > 1")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the "
-                         "generation into DIR (xprof/tensorboard format — "
-                         "the TPU-native equivalent of the reference's "
-                         "per-task I/T timing split). DLLAMA_PROFILE_DIR "
-                         "sets the same thing without flag plumbing")
+                         "generation into DIR (tensorboard's format; "
+                         "benchmark/harness/reduce_trace.py reads it). "
+                         "DLLAMA_PROFILE_DIR sets the same thing without "
+                         "flag plumbing")
     ap.add_argument("--metrics", action="store_true",
                     help="collect run telemetry (obs registry: per-token "
                          "latency histogram, generated-token counters) and "
@@ -795,25 +795,6 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         _print_device_memory("end")
     if args.profile and not quiet:
         print(f"⏩ Profiler trace written to {args.profile}")
-        # the reference-shaped I/T split, profiler-derived (tools/it_split
-        # has the standalone CLI; reference utils.cpp:101-109 semantics)
-        try:
-            from ..utils.it_split import parse_trace, summarize
-
-            # the trace wraps the WHOLE generate() call — a prefilled prompt's
-            # chunked forwards are inside it, so dividing by generated tokens
-            # overstates the decode-only per-token split; say so in the line
-            # (a resumed run prefills only the unconsumed prompt tail)
-            n_prompt = (len(rest0) if resume
-                        else len(tokenizer.encode(args.prompt or "",
-                                                  bos=True, eos=False)))
-            note = (f"; trace includes ~{n_prompt}-token prompt prefill"
-                    if n_prompt > 1 else "")
-            summarize(parse_trace(args.profile),
-                      tokens=max(stats.tokens, 1), note=note)
-        except Exception as e:  # a malformed trace must not fail the run
-            print(f"💡 I/T split unavailable ({type(e).__name__}: {e}); "
-                  f"run tools/it_split.py on the trace dir", file=sys.stderr)
     if args.metrics:
         # one-shot runs have no /metrics endpoint: expose the run's
         # telemetry as a Prometheus text dump on stderr (same metric

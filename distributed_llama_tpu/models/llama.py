@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from ..io.loader import Q40Kernel, Q40KernelNb, Q40KernelNbI4
 # the single-chip forward emits the SAME canonical trace scopes as the tp
 # forward (parallel/tp.py), so a --profile capture of either program
-# attributes through one obs/xprof.py vocabulary
+# attributes through one vocabulary of scope names (obs/spans.py)
 from ..obs.spans import (SCOPE_ATTN, SCOPE_ATTN_SINK, SCOPE_EMBED, SCOPE_FFN,
                          SCOPE_LOGITS)
 from ..ops.hyper import residual_in, residual_out

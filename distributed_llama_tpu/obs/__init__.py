@@ -17,10 +17,6 @@ instrument is one end-of-run benchmark line, tokenizer.cpp:381):
   emits; Chrome-trace/Perfetto + NDJSON exports (``GET /debug/timeline``);
   ``host_phase`` puts the host's phases on the profiler's clock and
   ``named_program`` names the programs a capture shows;
-* ``obs.xprof`` — profiler-capture loader: device events bucketed by
-  named scope into per-phase ms/token and per-collective time/bytes;
-* ``obs.drift`` — the model-vs-measured reconciler behind
-  ``tools/tracecheck.py``, the bench drift columns, and CI's DRIFT gate;
 * ``obs.slo`` — declarative SLO policies (priority classes with TTFT +
   per-token budgets) and the per-request verdict tracker behind
   ``dllama_slo_requests_total{class,verdict}`` / goodput accounting and

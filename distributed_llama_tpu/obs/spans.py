@@ -11,7 +11,7 @@ rails that share ONE naming scheme:
 * **device scopes** — ``jax.named_scope`` annotations threaded through the
   tp forward (parallel/tp.py) using the canonical names below, so a
   jax.profiler capture carries per-phase and per-collective labels that
-  obs/xprof.py can bucket without guessing;
+  a reader of captures can bucket without guessing;
 * **host phases** — ``host_phase("serve.fetch")`` puts what the host is
   doing on the PROFILER's clock, beside the device trace, so a capture's
   idle gaps split by phase. The ring's ``perf_counter`` shares nothing
@@ -22,9 +22,9 @@ rails that share ONE naming scheme:
   seconds of its tracing, lowering and compile or cache read), read with
   ``startup_account()``.
 
-The scope names are the contract between the forward (which emits them),
-the xprof loader (which buckets by them), and the drift reconciler
-(obs/drift.py, which joins collective scopes against the analytic budget).
+The scope names are the contract between the forward (which emits them)
+and whatever reads a capture by them (benchmark/harness/reduce_trace.py
+is the one reader of captures; ROADMAP D11 lists the names it takes).
 Change them here or nowhere.
 """
 

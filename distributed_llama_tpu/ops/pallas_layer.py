@@ -103,7 +103,7 @@ def fusion_enabled() -> bool:
 
     'auto' currently resolves to OFF: at real 7B footprint the megakernel's
     multi-window DMA streams at ~550 GB/s vs the standalone kernels'
-    ~670 GB/s (same bytes; measured tools/layer_kernel_bench +
+    ~670 GB/s (same bytes; measured by a layer-kernel probe, since gone, +
     mega bisections, r3), so fusion does not yet beat the unfused path
     end-to-end. Opt in with DLLAMA_LAYER_FUSION=on (whole-layer megakernel
     when the spec supports it) or =headtail (the two-pallas_call pair with

@@ -119,7 +119,7 @@ class CollectiveBudget:
 
     def bytes_by_kind(self) -> dict[str, int]:
         """kind -> moved bytes/chip/token — the per-kind join key the
-        drift reconciler (obs/drift.py reconcile) reads; same rows as
+        a measured census is reconciled on; same rows as
         ``entries``, keyed like ``kind_counts``."""
         return {k: b for k, _, b in self.entries}
 

@@ -230,8 +230,8 @@ def modeled_ici_ms(spec: TransformerSpec, n_slices: int,
                    ) -> tuple[float, float]:
     """(bandwidth_ms, latency_ms) per token for the scheme's collective
     schedule — the ONE formula behind project_full_system's ICI columns
-    and the obs/drift time check, so the projection the bench prints and
-    the band the drift gate holds measurements to cannot diverge. This is
+    and any check of measured collective time, so the projection the bench
+    prints and the band a measurement is held to cannot diverge. This is
     TOTAL collective time (what a profiler capture measures); the overlap
     scheme's hidden share is modeled separately
     (modeled_overlap_hidden_ms) and only project_full_system subtracts it.

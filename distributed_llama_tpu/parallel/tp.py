@@ -70,7 +70,7 @@ scheme's; only the combines change shape:
 Collective census per layer: 2*(tp-1) ppermutes + 2 all_gathers (vs the
 fused scheme's 2 psums f32 / 2 scatter+gather pairs Q80) — MORE launches,
 but each ppermute is one ring hop hidden behind compute, which is what
-obs/drift's overlap-coverage gate verifies on captures. Requires dim/tp to
+a capture has to show (no cell measures this scheme). Requires dim/tp to
 divide (the ring chunks the residual width) and sp == 1.
 
 In both schemes the reference's syncRmsAtt broadcast (:161) disappears: x is
@@ -118,7 +118,7 @@ from ..models.llama import (KVCache, PagedKVQ8, attention_core,
                             spec_verify_attention, split_layer_weights)
 from ..models.spec import TransformerSpec
 # canonical trace-scope names (obs/spans.py): every phase and collective
-# scope this forward emits is a name the xprof loader buckets by — the
+# scope this forward emits is a name a reader of captures buckets by — the
 # attribution contract lives THERE, the emission lives HERE
 from ..obs.spans import (SCOPE_ATTN, SCOPE_EMBED, SCOPE_FFN, SCOPE_ICI_GATHER,
                          SCOPE_ICI_PPERMUTE, SCOPE_ICI_PSUM,

@@ -1224,7 +1224,7 @@ def run_drills(make_engine, which=None, inject=None) -> list[DrillResult]:
 
 
 def render_drill_table(results) -> str:
-    """The human verdict table (tracecheck-style)."""
+    """The human verdict table."""
     lines = [f"{'drill':<24} {'verdict':<8} detail"]
     for r in results:
         detail = ("; ".join(r.violations) if r.violations

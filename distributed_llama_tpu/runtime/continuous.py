@@ -1360,8 +1360,8 @@ class ContinuousEngine:
             self._spans = SpanTracer(on_drop=self._obs.spans_dropped.inc)
             if mesh is not None and mesh.shape["tp"] > 1:
                 # export the analytic collective schedule as labeled
-                # /metrics series — the budget the drift gate (obs/drift)
-                # reconciles measurements against. Bytes scale by the slot
+                # /metrics series — the budget a measured collective census
+                # is reconciled against. Bytes scale by the slot
                 # count: every batched collective moves B rows.
                 from ..parallel.comm_stats import tp_collective_budget
 

@@ -29,6 +29,38 @@ assert jax.default_backend() == "cpu"
 
 import pytest  # noqa: E402
 
+_CACHE_OPTIONS = ("jax_compilation_cache_dir",
+                  "jax_persistent_cache_min_compile_time_secs",
+                  "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture(autouse=True)
+def persistent_cache_off(monkeypatch):
+    """The rule above, held against ``frontend/cli.main`` (and ``bench.py``)
+    run IN this process: their ``enable_persistent_cache()`` would point
+    every later test of the worker at the checkout's ``.jax_cache/``, which
+    the other workers and every earlier run share (ROADMAP D22: a
+    deserialized executable from there crashed a worker, and filed
+    programs as ``cache`` in ``test_startup_account``). So the name they
+    import is a no-op here, and a test that sets jax's three cache options
+    itself has them put back. Yields the real function for the one test
+    that calls it on purpose."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from distributed_llama_tpu.utils import compile_cache
+
+    real = compile_cache.enable_persistent_cache
+    was = {name: getattr(jax.config, name) for name in _CACHE_OPTIONS}
+    monkeypatch.setattr(compile_cache, "enable_persistent_cache",
+                        lambda: None)
+    yield real
+    changed = [name for name in _CACHE_OPTIONS
+               if getattr(jax.config, name) != was[name]]
+    for name in changed:
+        jax.config.update(name, was[name])
+    if changed:
+        compilation_cache.reset_cache()   # jax opens its cache object once
+
 # Tests marked slow and deselected from the default run (pytest.ini). One
 # tunable place, chosen from measured -n 8 durations: multi-process
 # jax.distributed spawns, training soaks, deep-position/randomized parity

@@ -37,14 +37,19 @@ def params():
 
 @pytest.fixture()
 def make_engine(params):
+    made = []
+
     def factory(chaos=None, **overrides):
         kw = dict(slots=4, temperature=0.0, topp=0.9, seed=7,
                   metrics=Registry(), prefill_chunk=4, page_size=4,
                   kv_pages=20)
         kw.update(overrides)
-        return ContinuousEngine(SPEC, params, chaos=chaos, **kw)
+        made.append(ContinuousEngine(SPEC, params, chaos=chaos, **kw))
+        return made[-1]
 
-    return factory
+    yield factory
+    for eng in made:    # a tiered one owns a PageUploader thread
+        eng.close()
 
 
 # -------------------------------------------------------------- audit

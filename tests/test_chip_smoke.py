@@ -28,10 +28,13 @@ def _load_smoke():
     return mod
 
 
-def _one_cpu_device(monkeypatch, tmp_path):
+def _one_cpu_device(monkeypatch, tmp_path, smoke):
     """The children inherit this process's environment: one CPU device (the
     suite's 8-device flag would make ``inference`` build a tp=8 mesh) and a
-    compile cache of the test's own."""
+    compile cache of the test's own. The smoke's work directory is the
+    test's own too: ``main`` removes it at its end, under another worker's
+    smoke if they share the checkout's."""
+    monkeypatch.setattr(smoke, "WORK", str(tmp_path / "work"))
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.setenv("XLA_FLAGS",
                        "--xla_force_host_platform_device_count=1")
@@ -60,7 +63,7 @@ def test_smoke_phases_pass_with_platform_stubbed(monkeypatch, tmp_path,
                                                  capsys):
     smoke = _load_smoke()
     monkeypatch.setattr(smoke, "EXPECT_PLATFORM", "cpu")
-    _one_cpu_device(monkeypatch, tmp_path)
+    _one_cpu_device(monkeypatch, tmp_path, smoke)
     rc = smoke.main(["--size", "tiny"])
     out = capsys.readouterr().out
     notes = [json.loads(ln) for ln in out.strip().splitlines()]
@@ -82,7 +85,7 @@ def test_smoke_phases_pass_with_platform_stubbed(monkeypatch, tmp_path,
 def test_smoke_fails_when_a_child_fails(monkeypatch, tmp_path, capsys):
     smoke = _load_smoke()
     monkeypatch.setattr(smoke, "EXPECT_PLATFORM", "cpu")
-    _one_cpu_device(monkeypatch, tmp_path)
+    _one_cpu_device(monkeypatch, tmp_path, smoke)
     missing = str(tmp_path / "no-such-model.bin")
     monkeypatch.setattr(smoke, "write_model",
                         lambda size, seed: (missing, missing))
@@ -175,7 +178,6 @@ def test_bench_all_exits_nonzero_when_a_row_failed(monkeypatch, tmp_path,
 
     monkeypatch.setattr(subprocess, "run", fake_run)
     monkeypatch.setenv("DLLAMA_BENCH_CONFIGS", "7b,13b")
-    monkeypatch.setenv("DLLAMA_BENCH_NO_PROFILE", "1")
     monkeypatch.setenv("DLLAMA_BENCH_FULL_PATH", str(tmp_path / "full.json"))
 
     class Args:

@@ -30,7 +30,21 @@ def _make(params, journal=None, **overrides):
               metrics=Registry(), prefill_chunk=4, page_size=4,
               kv_pages=24)
     kw.update(overrides)
-    return ContinuousEngine(SPEC, params, journal=journal, **kw)
+    eng = ContinuousEngine(SPEC, params, journal=journal, **kw)
+    _ENGINES.append(eng)
+    return eng
+
+
+_ENGINES = []
+
+
+@pytest.fixture(autouse=True)
+def _engines_closed():
+    """A tiered engine owns a PageUploader thread: stop each one this test
+    made, so none outlives it in the worker."""
+    yield
+    while _ENGINES:
+        _ENGINES.pop().close()
 
 
 def _reqs():

@@ -3,8 +3,8 @@
 Covers obs/spans.py (nesting, thread safety, Chrome/NDJSON exports, the
 schema validator), the engine's span wiring (/debug/timeline round trip,
 dark-engine silence), and the contract that parallel/tp.py's traced
-forward actually CARRIES the canonical phase/collective scope names the
-xprof loader buckets by."""
+forward actually CARRIES the canonical phase/collective scope names a
+reader of captures buckets by."""
 
 import json
 import threading
@@ -156,7 +156,7 @@ def _name_stacks(jaxpr, out=None):
 @pytest.mark.parametrize("scheme", ["ref", "fused", "overlap"])
 def test_tp_forward_carries_phase_and_collective_scopes(scheme):
     """The traced tp forward must label every phase and every collective
-    at source — the attribution contract obs/xprof.py buckets by."""
+    at source — the attribution contract of obs/spans.py."""
     import jax
     import jax.numpy as jnp
 

@@ -58,25 +58,14 @@ class _IdTokenizer:
 def account(monkeypatch):
     """An empty account of this test's own; the ONE pair of listeners (the
     module's functions) then files into it. JAX's persistent compile cache
-    is off for the test: a test of another file that ran ``cli.main`` in
-    this process leaves it on (``utils/compile_cache``: the checkout's
-    ``.jax_cache/``, every threshold zero), and a program an earlier run of
-    the suite wrote there is then filed as ``cache``, not ``compiled``
-    (ROADMAP D22: eight of these tests failed so under ``--dist
-    loadfile``)."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
+    is off in every test (``conftest.persistent_cache_off``), so a program
+    made here is ``compiled``, never read from the checkout's
+    ``.jax_cache/``."""
     spans._listen()
     fresh = spans._Account()
     fresh.listening = True
     monkeypatch.setattr(spans, "_account", fresh)
-    yield fresh
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    return fresh
 
 
 def _made(program):

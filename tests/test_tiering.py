@@ -47,7 +47,21 @@ def _engine(params, **kw):
     base = dict(slots=2, temperature=0.0, topp=0.9, seed=3,
                 prefill_chunk=PS, page_size=PS)
     base.update(kw)
-    return ContinuousEngine(SPEC, params, **base)
+    eng = ContinuousEngine(SPEC, params, **base)
+    _ENGINES.append(eng)
+    return eng
+
+
+_ENGINES = []
+
+
+@pytest.fixture(autouse=True)
+def _engines_closed():
+    """A tiered engine owns a PageUploader thread: stop each one this test
+    made, so none outlives it in the worker."""
+    yield
+    while _ENGINES:
+        _ENGINES.pop().close()
 
 
 def _waves(n_prefix, tails=(3, 9)):

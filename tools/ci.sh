@@ -159,47 +159,6 @@ fi
 # without its comm_stats t_len term fails there.
 python -m pytest tests/test_speculative.py -q -p no:cacheprovider \
     -k "bitwise or streams or rollback"
-# drift observatory gate (ISSUE 5 + 10): tracecheck reconciles the
-# checked-in synthetic capture fixtures — ALL THREE tp schemes — against
-# the analytic collective model and fails the build on any DRIFT verdict;
-# the attribution Chrome traces are archived under tools/ci_artifacts/
-# (gitignored) — load them in Perfetto
-mkdir -p tools/ci_artifacts
-for fixture in trace_7b_tp8_ref trace_7b_tp8_fused trace_7b_tp8_overlap \
-               trace_13b_tp8_ref trace_13b_tp8_fused \
-               trace_13b_tp8_overlap; do
-    python tools/tracecheck.py "tests/fixtures/traces/$fixture.json" \
-        --chrome-out "tools/ci_artifacts/$fixture.chrome.json"
-done
-# and the gate must still CATCH drift: the mutated fixture must exit with
-# status 1 EXACTLY (the DRIFT verdict) — status 2 is a usage error (e.g. a
-# renamed fixture) and would pass a naive non-zero check vacuously
-set +e
-python tools/tracecheck.py \
-    tests/fixtures/traces/trace_7b_tp8_ref_extra_collective.json \
-    > /dev/null 2>&1
-tracecheck_rc=$?
-set -e
-if [ "$tracecheck_rc" -ne 1 ]; then
-    echo "ci: tracecheck did not flag the mutated drift fixture" \
-         "(exit $tracecheck_rc, expected 1)" >&2
-    exit 1
-fi
-# ... and the overlap-scheme gate must still catch a SERIALIZED schedule:
-# the mutated fixture (ppermute hops with zero concurrent-compute
-# coverage) must exit 1 EXACTLY — latency hiding is the overlap scheme's
-# whole claim, and a capture that shows none of it is a DRIFT, not noise
-set +e
-python tools/tracecheck.py \
-    tests/fixtures/traces/trace_7b_tp8_overlap_serialized.json \
-    > /dev/null 2>&1
-overlap_rc=$?
-set -e
-if [ "$overlap_rc" -ne 1 ]; then
-    echo "ci: tracecheck did not flag the serialized-overlap fixture" \
-         "(exit $overlap_rc, expected 1)" >&2
-    exit 1
-fi
 # KV-tiering gate (ISSUE 12): the continuous_bench tiering section on the
 # CPU smoke model — prefix-hit prefill savings at a working set 10x the
 # HBM page pool must hold within 20% of the all-HBM ceiling through the
@@ -398,8 +357,8 @@ fi
 # weight-stream disconnect+resume with CRC repair). Every drill asserts
 # no leaked pages/slots, scrapeable metrics, and a still-admitting
 # engine; the baseline's recovery_drills list makes a silently-skipped
-# recovery drill a gate failure. The row is archived next to the
-# tracecheck artifacts.
+# recovery drill a gate failure. The row is archived under
+# tools/ci_artifacts/.
 python tools/loadcheck.py --json > tools/ci_artifacts/loadcheck.json
 # and the gate must still CATCH a fault: with the seeded
 # leak-on-cancel mutation armed (a page deliberately dropped on every

@@ -415,10 +415,6 @@ def main():
     ap.add_argument("--tiering-prefixes", type=int, default=40,
                     help="distinct shared prefixes in the tiering "
                          "section's working set (2 full pages each)")
-    ap.add_argument("--profile", default=None, metavar="DIR",
-                    help="trace the timed pass and print the per-step "
-                         "op-time split by kernel family (the VERDICT r3 "
-                         "#8 attribution)")
     args = ap.parse_args()
 
     import jax
@@ -506,24 +502,6 @@ def main():
                                                      dtype)
     if args.tiering_compare:
         row["kv_tiering"] = tiering_compare(spec, params, args, dtype)
-
-    if args.profile:
-        from distributed_llama_tpu.utils.it_split import bucket_ops
-
-        with jax.profiler.trace(args.profile):
-            # time eng.run alone: trace start/stop + export would inflate
-            # the host-gap attribution this tool exists to pin
-            t0 = time.perf_counter()
-            outs3, st3 = eng.run(reqs, steps=args.steps)
-            dt3 = time.perf_counter() - t0
-        assert outs3 == outs
-        per_step = bucket_ops(args.profile, st3.steps)
-        op_total = sum(per_step.values())
-        print(f"profiled pass: {dt3:.2f}s, {st3.steps} steps -> op-time "
-              f"per step (ms): {per_step} total {op_total:.2f}; wall "
-              f"{dt3 * 1000 / st3.steps:.2f} ms/step -> "
-              f"{dt3 * 1000 / st3.steps - op_total:.2f} ms/step of "
-              f"dispatch/host gaps")
 
     # the machine-readable row, fingerprint-stamped like bench.py's
     row["env_fingerprint"] = env_fingerprint()

@@ -1,4 +1,4 @@
-"""loadcheck: offered-load sweep + chaos drills, gated like tracecheck.
+"""loadcheck: offered-load sweep + chaos drills, held to a baseline band.
 
 The SLO observatory's CLI (ISSUE 8). Builds a small synthetic-weight
 engine on the current backend, replays a seeded loadgen workload at each
@@ -12,8 +12,7 @@ still admitting).
 The sweep runs on loadgen's VIRTUAL clock (one device step = one time
 unit), so the curve is a pure function of the scheduler + model stream —
 deterministic on any box — and can be held to the checked-in CPU baseline
-band (tools/loadcheck_baseline.json) the way tracecheck holds collective
-drift. Exit 0 = curve within band and every drill passed; 1 = regression
+band (tools/loadcheck_baseline.json). Exit 0 = curve within band and every drill passed; 1 = regression
 or drill failure; 2 = usage/baseline error.
 
 The final stdout line is one JSON row stamped with

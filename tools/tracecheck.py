@@ -1,25 +1,16 @@
-"""tracecheck: attribute a profiler capture and reconcile it vs the model.
+"""tracecheck: validate and summarize a flight-recorder bundle.
 
-The drift observatory's CLI (ISSUE 5). Loads a capture — a real
-``jax.profiler`` directory (``POST /profile`` / ``DLLAMA_PROFILE_DIR``
-output) or a ``dllama-trace/1`` synthetic fixture — buckets device events
-by the named scopes the tp forward emits (obs/spans.py via obs/xprof.py),
-and joins the measured collective census against
-``comm_stats.tp_collective_budget`` + ``shard_sim.modeled_ici_ms`` for the
-named (model, tp, scheme) config (obs/drift.py). Prints the verdict table;
-exit 0 = every check OK, 1 = DRIFT, 2 = usage error.
+A bundle is what ``obs/flightrec`` dumps on a watchdog trip, a SIGTERM
+drain, a crash loop or a watchtower incident (``--flightrec DIR``). Exit 0 =
+loadable and schema-clean, 1 = damaged (a postmortem artifact discovered
+malformed mid-incident is worse than none), 2 = unreadable or not a bundle.
 
-Fixtures carry their config in the header; real captures need
-``--model/--tp/--scheme`` (and ``--tokens``, which an xplane cannot know).
-
-``--chrome-out`` additionally writes the attribution as a Chrome-trace/
-Perfetto JSON artifact (per-phase and per-collective lanes laid out
-sequentially per token) — CI archives it next to the gate run.
+A profiler capture is NOT read here: ``benchmark/harness/reduce_trace.py``
+is the repository's one reader of captures (``benchmark/layer_metrics/``
+hold what is computed from it).
 
 Usage:
-  python tools/tracecheck.py CAPTURE [--model 7b|13b|70b|small] [--tp N]
-      [--scheme ref|fused|overlap] [--buffer f32|q80] [--tokens N]
-      [--chrome-out PATH] [--json]
+  python tools/tracecheck.py BUNDLE.json [--json]
 """
 
 from __future__ import annotations
@@ -30,34 +21,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-
-def attribution_chrome_trace(att, report) -> dict:
-    """The attribution as a Chrome-trace object: one 'X' lane per phase
-    (per-token ms, laid out sequentially) and one per collective kind,
-    plus a metadata event carrying the verdict."""
-    from distributed_llama_tpu.obs.spans import Span, spans_to_chrome
-
-    spans, t = [], 0.0
-    for phase, ms in sorted(att.phase_ms.items()):
-        per_tok = ms / max(att.tokens, 1) / 1e3  # seconds/token
-        spans.append(Span(phase, "phase", t, per_tok, 0, 0,
-                          {"ms_per_token": round(per_tok * 1e3, 6)}))
-        t += per_tok
-    for kind, m in sorted(att.collectives.items()):
-        per_tok = m.ms / max(att.tokens, 1) / 1e3
-        spans.append(Span(kind, "collective", 0.0, per_tok, 1, 0,
-                          {"count_per_token": m.count / max(att.tokens, 1),
-                           "bytes_per_token":
-                               (m.bytes or 0) / max(att.tokens, 1)}))
-    doc = spans_to_chrome(spans)
-    doc["traceEvents"].append({
-        "name": "tracecheck", "ph": "M", "ts": 0, "pid": os.getpid(),
-        "args": {"verdict": "OK" if report.ok else "DRIFT",
-                 "label": report.label, "scheme": report.scheme,
-                 "tp": report.n_slices,
-                 "coverage": round(report.coverage, 4)}})
-    return doc
 
 
 def _check_bundle(path: str, emit_json: bool = False) -> int:
@@ -111,64 +74,21 @@ def _check_bundle(path: str, emit_json: bool = False) -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="tracecheck",
-        description="per-step cost attribution + model-vs-measured drift "
-                    "verdict over a profiler capture or trace fixture")
-    ap.add_argument("capture", help="jax.profiler capture dir / .xplane.pb "
-                                    "/ dllama-trace fixture .json")
-    ap.add_argument("--model", default=None,
-                    choices=("7b", "13b", "70b", "small"))
-    ap.add_argument("--tp", type=int, default=None)
-    ap.add_argument("--scheme", default=None,
-                    choices=("ref", "fused", "overlap"))
-    ap.add_argument("--buffer", default=None, choices=("f32", "q80"))
-    ap.add_argument("--tokens", type=int, default=0,
-                    help="tokens decoded under the capture (fixtures "
-                         "carry their own count)")
-    ap.add_argument("--chrome-out", default=None,
-                    help="write the attribution as Chrome-trace JSON here")
+        description="validate + summarize a flight-recorder bundle")
+    ap.add_argument("bundle", help="flight-recorder bundle .json")
     ap.add_argument("--json", action="store_true",
                     help="emit one machine-readable JSON object instead "
-                         "of the table")
+                         "of the summary line")
     args = ap.parse_args(argv)
 
     from distributed_llama_tpu.obs.flightrec import is_bundle_file
 
-    if is_bundle_file(args.capture):
-        # a crash-forensics flight-recorder bundle (ISSUE 15): validate
-        # it and summarize — exit 1 on schema damage (a postmortem
-        # artifact discovered malformed mid-incident is worse than none)
-        return _check_bundle(args.capture, emit_json=args.json)
-
-    from distributed_llama_tpu.obs.drift import reconcile_capture
-    from distributed_llama_tpu.obs.spans import validate_chrome_trace
-
-    try:
-        att, report = reconcile_capture(
-            args.capture, model=args.model, tp=args.tp, scheme=args.scheme,
-            buffer=args.buffer, tokens=args.tokens)
-    except (OSError, ValueError) as e:
-        print(f"tracecheck: {e}", file=sys.stderr)
+    if not is_bundle_file(args.bundle):
+        print(f"tracecheck: {args.bundle} is not a flight-recorder bundle; "
+              f"a profiler capture is read by "
+              f"benchmark/harness/reduce_trace.py", file=sys.stderr)
         return 2
-
-    if args.chrome_out:
-        doc = attribution_chrome_trace(att, report)
-        validate_chrome_trace(doc)  # never archive a malformed artifact
-        os.makedirs(os.path.dirname(os.path.abspath(args.chrome_out)),
-                    exist_ok=True)
-        with open(args.chrome_out, "w", encoding="utf-8") as fh:
-            json.dump(doc, fh)
-            fh.write("\n")
-        print(f"tracecheck: chrome trace -> {args.chrome_out}",
-              file=sys.stderr)
-
-    if args.json:
-        out = report.to_json()
-        out["phase_ms_per_token"] = att.phase_ms_per_token()
-        print(json.dumps(out))
-    else:
-        print(report.render())
-        print("phase ms/token: " + json.dumps(att.phase_ms_per_token()))
-    return 0 if report.ok else 1
+    return _check_bundle(args.bundle, emit_json=args.json)
 
 
 if __name__ == "__main__":
