@@ -12,14 +12,24 @@ per head in VMEM. Attention cost becomes pos-proportional — the shape of the
 reference's own per-position attention loop (transformer-tasks.cpp:246-276),
 which scans exactly 0..pos, not 0..seqLen.
 
-Numerics: f32 throughout, max-subtracted softmax, GQA via a static python
-loop over the kv_mul query heads per kv head — same math as
+Numerics: f32 throughout, max-subtracted softmax, every position 0..pos
+attended and a masked position's weight exactly 0 — same math as
 models/llama.attention_core (the parity anchor; the interpret-mode test
-checks element-level agreement).
+checks element-level agreement, and the distance from a float64 attention is
+pinned beside the vector-unit fold's this kernel had until PR 59).
 
-Scores/weighted sums are computed on the VPU (broadcast-multiply-reduce over
-the head dim): per-head matvecs are too thin for the MXU, and the kernel is
-DMA-bound at decode shapes anyway.
+A landed chunk (C, n_kv, hs) is folded by the head-major kernels' ``_fold``
+(ops/pallas_head_major_attention.py, whose docstring has the argument): both
+contractions on the MXU, K and V read once for all ``kv_mul`` query heads of
+a group (padded to a sublane tile), every product exact (each operand cut in
+its three bf16 pieces, the nine piece products summed in float32, the small
+ones first). ``_heads`` is the bridge from the cache's layout, heads
+second-minor, to the fold's (n_kv, C, hs): one strided read a KV head where
+the heads are whole sublane tiles, one relayout of the loaded slot otherwise
+(a tp rank's 2 or 10 heads). A bf16 cache's chunk is widened into one
+float32 slot a side first. Until PR 59 the fold multiplied and lane-reduced
+the chunk on the vector unit a query head at a time and ran 8 (Mistral) to
+35 (a Yi-34B tp-4 rank) times over its bytes at the decode cells' depths.
 """
 
 from __future__ import annotations
@@ -31,124 +41,105 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = float("-inf")
+from .pallas_head_major_attention import (_TILE, _VMEM_BUDGET,  # noqa: F401
+                                          NEG_INF, _flash_walk, _fold, _heads,
+                                          _stacked_queries)
+# The raised scoped-VMEM limit (v5e has 128 MB physical): with the DEFAULT
+# 16 MB limit, shapes whose scratch sits near the 12 MB budget can exceed the
+# limit once the compiler's own temporaries stack on top — measured: 13B tp=4
+# rank (n_kv=10, hs=128, f32 cache, chunk 512) needed 16.07 MB and fell back
+# to the XLA attention path, costing ~4 ms/token rank time. ONE shared
+# constant with the matmul kernels: a missed copy reintroduces exactly this
+# silent-fallback class of bug.
+from .pallas_q40 import _VMEM64_PARAMS
 
 
-def _flash_walk(n_chunks, start_dma, wait_dma, update, init):
-    """THE double-buffered flash DMA loop, shared by the contiguous kernels
-    here and the paged kernels (ops/pallas_paged_attention.py): start chunk
-    0, then per iteration prefetch chunk i+1 into the other slot while
-    chunk i is reduced into the carry. ``start_dma(slot, i)`` issues the
-    copies for chunk i, ``wait_dma(slot, i)`` blocks on them, and
-    ``update(i, slot, carry)`` folds the landed chunk into the running
-    (m, l, o) state."""
-    start_dma(0, 0)
+def _kernel(layer_ref, pos_ref, q3_ref, k_hbm, v_hbm, out_ref,
+            k_buf, v_buf, sems, *wide, chunk: int, batch: int):
+    """Per-row flash decode over the rank-4 (L*B, S, n_kv, hs) cache (the
+    single sequence's stacked (L, S, n_kv, hs) cache is B = 1).
 
-    def body(i, carry):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_chunks)
-        def _():
-            start_dma(jax.lax.rem(i + 1, 2), i + 1)
-
-        wait_dma(slot, i)
-        return update(i, slot, carry)
-
-    return jax.lax.fori_loop(0, n_chunks, body, init)
-
-
-def _flash_over_row(row, pos, q, k_hbm, v_hbm, k_buf, v_buf, sems, *,
-                    chunk: int, kv_mul: int):
-    """Shared flash loop: walk the live chunks of cache row ``row`` (an index
-    into the leading dim of the (R, S, n_kv, hs) HBM caches), double-buffered
-    DMA, running (m, l, o) per query-head-in-group carried as flat tuples
-    (static kv_mul unroll; functional .at-column updates don't lower well).
-    q: (n_kv, kv_mul, hs). Returns the kv_mul final (m, l, o) tuples."""
-    n_kv = q.shape[0]
-    hs = q.shape[2]
-    n_chunks = pos // chunk + 1  # live chunks only
-
-    def k_dma(slot, i):
-        return pltpu.make_async_copy(
-            k_hbm.at[row, pl.ds(i * chunk, chunk)], k_buf.at[slot],
-            sems.at[slot, 0])
-
-    def v_dma(slot, i):
-        return pltpu.make_async_copy(
-            v_hbm.at[row, pl.ds(i * chunk, chunk)], v_buf.at[slot],
-            sems.at[slot, 1])
-
-    def start_dma(slot, i):
-        k_dma(slot, i).start()
-        v_dma(slot, i).start()
-
-    def wait_dma(slot, i):
-        k_dma(slot, i).wait()
-        v_dma(slot, i).wait()
-
-    scale = 1.0 / jnp.sqrt(jnp.float32(hs))
-
-    def update(i, slot, carry):
-        k = k_buf[slot]                              # (chunk, n_kv, hs)
-        v = v_buf[slot]
-
-        key_pos = i * chunk + jax.lax.broadcasted_iota(
-            jnp.int32, (chunk, n_kv), 0)
-        valid = key_pos <= pos                       # (chunk, n_kv)
-
-        out = []
-        for mqi in range(kv_mul):
-            m_old, l_old, o_old = carry[mqi]         # (1,n_kv),(1,n_kv),(n_kv,hs)
-            qm = q[:, mqi, :]                        # (n_kv, hs)
-            s = jnp.sum(k * qm[None, :, :], axis=-1) * scale  # (chunk, n_kv)
-            s = jnp.where(valid, s, NEG_INF)
-            m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
-            p = jnp.exp(s - m_new)                   # (chunk, n_kv)
-            corr = jnp.exp(m_old - m_new)            # (1, n_kv)
-            l_new = l_old * corr + jnp.sum(p, axis=0, keepdims=True)
-            po = jnp.sum(p[:, :, None] * v, axis=0)  # (n_kv, hs)
-            o_new = o_old * jnp.transpose(corr) + po
-            out.append((m_new, l_new, o_new))
-        return tuple(out)
-
-    init = tuple((jnp.full((1, n_kv), NEG_INF, jnp.float32),
-                  jnp.zeros((1, n_kv), jnp.float32),
-                  jnp.zeros((n_kv, hs), jnp.float32))
-                 for _ in range(kv_mul))
-    return _flash_walk(n_chunks, start_dma, wait_dma, update, init)
-
-
-def _kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, out_ref,
-            k_buf, v_buf, sems, *, chunk: int, kv_mul: int):
-    """q_ref (n_kv, kv_mul, hs) VMEM; k/v_hbm (L, S, n_kv, hs) in HBM;
-    out_ref (n_kv, kv_mul, hs); k/v_buf (2, chunk, n_kv, hs) VMEM scratch;
-    sems (2, 2) DMA semaphores (slot x {k, v})."""
-    final = _flash_over_row(layer_ref[0], pos_ref[0], q_ref[...], k_hbm,
-                            v_hbm, k_buf, v_buf, sems, chunk=chunk,
-                            kv_mul=kv_mul)
-    for mqi in range(kv_mul):
-        _, l_i, o_i = final[mqi]
-        out_ref[:, mqi, :] = o_i / jnp.transpose(l_i)
-
-
-def _kernel_batch(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, out_ref,
-                  k_buf, v_buf, sems, *, chunk: int, kv_mul: int,
-                  batch: int):
-    """Per-row flash decode over the rank-4 (L*B, S, n_kv, hs) batched cache.
-
-    grid=(B,): program b walks row layer*batch+b's live chunks via the same
-    shared flash loop as the single-sequence kernel (prefix-indexed DMAs).
-    pos_ref is (B,) — each row has its own position clock (identical values
-    in the lockstep case; ragged for continuous batching).
-    q_ref/out_ref get per-b blocks (1, n_kv, kv_mul, hs).
-    """
+    grid=(B,): program b walks the live chunks of row layer*batch+b
+    (prefix-indexed DMAs, double-buffered, one copy a side a turn) at ITS
+    position pos_ref[b] (identical values in the lockstep case; ragged for
+    continuous batching); each landed slot (chunk, n_kv, hs) is read
+    head-major (``_heads``) into the head-major kernels' exact MXU ``_fold``.
+    q3_ref (1, n_kv, 3 R, hs): a KV head's query heads, padded to R rows, in
+    stacked pieces; out_ref (1, n_kv, kv_mul, hs); k/v_buf (2, chunk, n_kv,
+    hs) VMEM scratch; sems (2, 2) DMA semaphores (slot x {k, v}); ``wide``:
+    nothing for a float32 cache, else the two float32 slots (chunk, n_kv, hs)
+    a landed bf16 slot is widened into."""
     b = pl.program_id(0)
-    row = layer_ref[0] * batch + b
-    final = _flash_over_row(row, pos_ref[b], q_ref[0], k_hbm, v_hbm,
-                            k_buf, v_buf, sems, chunk=chunk, kv_mul=kv_mul)
-    for mqi in range(kv_mul):
-        _, l_i, o_i = final[mqi]
-        out_ref[0, :, mqi, :] = o_i / jnp.transpose(l_i)
+    row, pos = layer_ref[0] * batch + b, pos_ref[b]
+    q3 = q3_ref[0]
+    n_kv, rows3, hs = q3.shape
+    rows = rows3 // 3
+
+    def copies(slot, i):
+        at = pl.ds(i * chunk, chunk)
+        return (pltpu.make_async_copy(k_hbm.at[row, at], k_buf.at[slot],
+                                      sems.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[row, at], v_buf.at[slot],
+                                      sems.at[slot, 1]))
+
+    def landed(slot):
+        if not wide:
+            return (_heads(w.at[slot], n_kv) for w in (k_buf, v_buf))
+        for w, buf in zip(wide, (k_buf, v_buf)):
+            w[...] = buf[slot].astype(jnp.float32)
+        return (_heads(w, n_kv) for w in wide)
+
+    key = jax.lax.broadcasted_iota(jnp.int32, (1, 1, chunk), 2)
+    init = (jnp.full((n_kv, rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((n_kv, rows, 1), jnp.float32),
+            jnp.zeros((n_kv, rows, hs), jnp.float32))
+    _, l_fin, o_fin = _flash_walk(
+        pos // chunk + 1,                              # live chunks only
+        lambda slot, i: [c.start() for c in copies(slot, i)],
+        lambda slot, i: [c.wait() for c in copies(slot, i)],
+        lambda i, slot, carry: _fold(q3, *landed(slot),
+                                     i * chunk + key <= pos, carry),
+        init)
+    out_ref[0] = (o_fin / l_fin)[:, :out_ref.shape[2]]
+
+
+def _call(q, k4, v4, layer, pos, kv_mul: int, interpret):
+    """The ``pallas_call`` both decode entries share: q (B, n_q, hs) over
+    rows of the (R, S, n_kv, hs) caches, grid=(B,); layer and clocks in
+    SMEM, a row's stacked queries and its output a block, the caches left in
+    HBM. Returns (B, n_q * hs) float32."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    _, S, n_kv, hs = k4.shape
+    B = q.shape[0]
+    chunk = _chunk(S, n_kv, hs, k4.dtype.itemsize)
+    if chunk is None:
+        raise ValueError(
+            f"no cache chunking fits VMEM for seq_len={S}, n_kv={n_kv}, "
+            f"hs={hs} (gate with supports())")
+    q3 = _stacked_queries(q, n_kv, kv_mul, hs)
+    # scratch matches the cache dtype (bf16 caches halve the DMA); a landed
+    # bf16 slot is widened into one float32 slot a side for the fold
+    slot = pltpu.VMEM((2, chunk, n_kv, hs), k4.dtype)
+    wide = [] if k4.dtype == jnp.float32 else [
+        pltpu.VMEM((chunk, n_kv, hs), jnp.float32)] * 2
+    out = pl.pallas_call(
+        functools.partial(_kernel, chunk=chunk, batch=B),
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, *q3.shape[1:]), lambda b: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, n_kv, kv_mul, hs), lambda b: (b, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, kv_mul, hs), jnp.float32),
+        scratch_shapes=[slot, slot, pltpu.SemaphoreType.DMA((2, 2)), *wide],
+        compiler_params=_VMEM64_PARAMS,
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), pos, q3, k4, v4)
+    return out.reshape(B, n_kv * kv_mul * hs)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_mul", "interpret"))
@@ -161,39 +152,10 @@ def decode_attention_batch(q, k4, v4, layer, pos, *, kv_mul: int,
     (per-row clocks, continuous batching). Returns (B, n_q * hs) f32.
     Live-chunk walking per row, like decode_attention.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    LB, S, n_kv, hs = k4.shape
     B = q.shape[0]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-    chunk = _chunk(S, n_kv, hs, k4.dtype.itemsize)
-    if chunk is None:
-        raise ValueError(
-            f"no cache chunking fits VMEM for seq_len={S}, n_kv={n_kv}, "
-            f"hs={hs} (gate with supports())")
-    qg = q.reshape(B, n_kv, kv_mul, hs).astype(jnp.float32)
-    out = pl.pallas_call(
-        functools.partial(_kernel_batch, chunk=chunk, kv_mul=kv_mul,
-                          batch=B),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, n_kv, kv_mul, hs), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, n_kv, kv_mul, hs), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, n_kv, kv_mul, hs), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk, n_kv, hs), k4.dtype),
-            pltpu.VMEM((2, chunk, n_kv, hs), k4.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), pos, qg, k4, v4)
-    return out.reshape(B, n_kv * kv_mul * hs)
+    return _call(q, k4, v4, layer,
+                 jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)), kv_mul,
+                 interpret)
 
 
 def maybe_flash_decode(q2, k_all, v_all, idx, pos, *, seq_len: int,
@@ -236,31 +198,45 @@ def attn_kernel_mode() -> str:
     return env
 
 
-_VMEM_BUDGET = 12 * 1024 * 1024  # scratch budget: bounds the DMA chunk size
-
-# Raised scoped-VMEM limit (v5e has 128 MB physical): with the DEFAULT
-# 16 MB limit, shapes whose scratch sits near the 12 MB budget can exceed
-# the limit once the compiler's own temporaries stack on top — measured:
-# 13B tp=4 rank (n_kv=10, hs=128, f32 cache, chunk 512) needs 16.07 MB and
-# fell back to the XLA attention path (or compiled a pessimized marginal
-# kernel), costing ~4 ms/token rank time — the r4 scaling curve's tp=4
-# anomaly. ONE shared constant with the matmul kernels: a missed copy
-# reintroduces exactly this silent-fallback class of bug.
-from .pallas_q40 import _VMEM64_PARAMS  # noqa: E402
-
-
 def _scratch_bytes(chunk: int, n_kv: int, hs: int, itemsize: int) -> int:
-    # 2 slots x {K,V} x (chunk, n_kv, hs) in the cache dtype
-    return 2 * 2 * chunk * n_kv * hs * itemsize
+    """2 slots x {K, V} x (chunk, n_kv, hs) in the cache dtype, plus, under
+    a cache that is not float32, the float32 slot a side a landed chunk is
+    widened into."""
+    wide = 2 * chunk * n_kv * hs * 4 if itemsize != 4 else 0
+    return 2 * 2 * chunk * n_kv * hs * itemsize + wide
+
+
+_TURN_BYTES = 512 * 1024  # a side's slot up to which a turn of 256 pays
 
 
 def _chunk(seq_len: int, n_kv: int, hs: int, itemsize: int = 4) -> int | None:
-    """Largest cache chunk that divides seq_len within the VMEM budget
-    (bf16 caches fit chunks twice as long as f32)."""
-    for c in (512, 256, 128, 64, 32, 16, 8):
+    """Positions a turn lands: the fold's tile of 128, or 256 where a side's
+    slot of 256 stays within ``_TURN_BYTES`` (4 KV heads of 128 in float32,
+    8 in bf16), cut to what divides ``seq_len`` and fits the VMEM budget;
+    None where nothing does.
+
+    From the call's shapes alone: no flag, no argument. Alone on the chip
+    (us a call at 130 / 255 / 1,024 / 4,000 positions of 4,096; PERF.md
+    section 7) a turn of few heads is its fixed cost, so fewer and longer
+    ones win at depth and lose nothing where the plane is shallow (a tp
+    rank's 2 KV heads: 5.3 / 5.6 / 9.1 / 24.8 in turns of 128, 5.6 / 5.5 /
+    8.0 / 19.7 in 256; 4 heads: 6.6 / 6.1 / 11.4 / 32.4 and 6.5 / 6.6 / 11.1
+    / 28.5), while a turn of 8 heads is its copy (7.9 / 7.9 / 16.4 / 49.8 and
+    8.8 / 8.0 / 16.8 / 50.8, the copies alone 7.2 to 9.0 / 49.1 to 53.1).
+    Turns of 512, the chunk until PR 59, land two to four times the live
+    bytes at the decode cells' 129 to 256 positions with nothing to hide the
+    copy behind (8 heads 13.0 / 12.9, 2 heads 7.4 / 7.1, one head 10.4 / 10.3
+    for 7.2 / 6.8) and are ahead of 256 at 4,000 positions of 2 heads alone
+    (17.5 for 19.7)."""
+    if itemsize == 2 and n_kv % 8 and n_kv not in (2, 4):
+        # the chip tiles a bf16 cache 16 heads (or 2, or 4) a tile and
+        # refuses a copy of any other head count (tests/test_chip_compile.py)
+        return None
+    for c in (256, 128, 64, 32, 16, 8):
         if (seq_len % c == 0
+                and (c <= _TILE or c * n_kv * hs * itemsize <= _TURN_BYTES)
                 and _scratch_bytes(c, n_kv, hs, itemsize) <= _VMEM_BUDGET):
-            return min(c, seq_len)
+            return c
     if (seq_len <= 8
             and _scratch_bytes(seq_len, n_kv, hs, itemsize) <= _VMEM_BUDGET):
         return seq_len
@@ -292,39 +268,8 @@ def decode_attention(q, k_all, v_all, layer, pos, *, kv_mul: int,
     ``interpret=None`` auto-selects interpret mode off-TPU (like q40_matmul),
     so DLLAMA_ATTN_KERNEL=pallas works everywhere.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    L, S, n_kv, hs = k_all.shape
-    chunk = _chunk(S, n_kv, hs, k_all.dtype.itemsize)
-    if chunk is None:
-        raise ValueError(
-            f"no cache chunking fits VMEM for seq_len={S}, n_kv={n_kv}, "
-            f"hs={hs} (gate with supports())")
-    qg = q.reshape(n_kv, kv_mul, hs).astype(jnp.float32)
-    out = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, kv_mul=kv_mul),
-        grid=(),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_kv, kv_mul, hs), jnp.float32),
-        scratch_shapes=[
-            # scratch matches the cache dtype (bf16 caches halve the DMA);
-            # score/softmax math promotes to f32 in the kernel body
-            pltpu.VMEM((2, chunk, n_kv, hs), k_all.dtype),
-            pltpu.VMEM((2, chunk, n_kv, hs), k_all.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-        compiler_params=_VMEM64_PARAMS,
-        interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.asarray(pos, jnp.int32).reshape(1), qg, k_all, v_all)
-    return out.reshape(1, n_kv * kv_mul * hs)
+    return _call(q[None], k_all, v_all, layer,
+                 jnp.asarray(pos, jnp.int32).reshape(1), kv_mul, interpret)
 
 
 # --------------------------------------------------------------------------
@@ -337,8 +282,8 @@ def decode_attention(q, k_all, v_all, layer, pos, *, kv_mul: int,
 # bucket (~38% of chunk-1920 op time is attention + glue + layout; probe
 # since deleted; runtime of round 5). This kernel runs the whole
 # online-softmax walk in VMEM: grid over (kv head, q block), and per invocation an in-kernel
-# double-buffered DMA loop (the decode kernel's machinery, _flash_over_row's
-# pattern) walks ONLY the live KV blocks. Scores never touch HBM; the causal
+# double-buffered DMA loop (the decode kernel's machinery) walks ONLY the
+# live KV blocks. Scores never touch HBM; the causal
 # bound clamps the walk exactly like blockwise_chunk_partials' n_live.
 #
 # Layout: Mosaic blocks the LAST TWO dims of an operand, so q/out are

@@ -24,7 +24,9 @@ page is one contiguous block, and the scores of a chunk lie (group, chunk)
 a KV head with the positions along lanes.
 
 Both kernels walk a row's live positions a CHUNK at a time with
-``pallas_attention._flash_walk``'s double-buffered DMA loop and keep the
+``_flash_walk``'s double-buffered DMA loop (it, ``_fold`` and ``_heads``
+lie here for every flash-decode kernel to import: the contiguous kernels
+of ``pallas_attention.py``, the paged ones, the latent ones) and keep the
 running (m, l, o) of every query head; scores are scaled by 1 / sqrt(head
 size). Every position up to a row's ``last`` is attended.
 
@@ -83,12 +85,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import _VMEM_BUDGET, NEG_INF, _flash_walk
 from .pallas_q40 import _VMEM64_PARAMS, _mask_pieces
 
 ROWS_KERNEL = "hm_attn_rows_decode"
 PAGED_KERNEL = "hm_attn_paged_decode"
 _TILE = 128         # positions the MXU holds still at once: the fold's tile
+NEG_INF = float("-inf")
+_VMEM_BUDGET = 12 * 1024 * 1024  # scratch budget: bounds the DMA chunk size
+
+
+def _flash_walk(n_chunks, start_dma, wait_dma, update, init):
+    """THE double-buffered flash DMA loop, shared by the contiguous kernels
+    (ops/pallas_attention.py), the paged kernels
+    (ops/pallas_paged_attention.py) and the ones here: start chunk
+    0, then per iteration prefetch chunk i+1 into the other slot while
+    chunk i is reduced into the carry. ``start_dma(slot, i)`` issues the
+    copies for chunk i, ``wait_dma(slot, i)`` blocks on them, and
+    ``update(i, slot, carry)`` folds the landed chunk into the running
+    (m, l, o) state."""
+    start_dma(0, 0)
+
+    def body(i, carry):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_chunks)
+        def _():
+            start_dma(jax.lax.rem(i + 1, 2), i + 1)
+
+        wait_dma(slot, i)
+        return update(i, slot, carry)
+
+    return jax.lax.fori_loop(0, n_chunks, body, init)
 
 
 def _group_rows(kv_mul: int) -> int:
@@ -136,6 +163,26 @@ def _fold(q3, k, v, valid, carry, head_size=None):
     l_new = l_old * corr + jnp.sum(p, axis=2, keepdims=True)
     o_new = o_old * corr + _dot9(_stack3(p), v, 1)
     return m_new, l_new, o_new
+
+
+def _heads(w, n_kv: int):
+    """A landed slot whose heads are second-minor, a float32 ref (C, n_kv,
+    hs), as the value (n_kv, C, hs) ``_fold`` takes.
+
+    Where a position's heads are whole sublane tiles (n_kv % 8 == 0: every
+    single-chip pool or cache) the slot is (C x n_kv, hs) as it lies, a
+    position's heads on consecutive rows, so a head's K or V is ONE strided
+    read of it (every n_kv-th row) and nothing is shuffled. Any other head
+    count (a tp rank's 2 or 10) is padded to a tile in the slot, which is then
+    NOT those rows (the chip compiles the view and reads wrong rows at 10
+    heads: distance 0.59, PERF.md section 7): one relayout of the loaded
+    slot."""
+    chunk, _, hs = w.shape
+    if n_kv % 8:
+        return jnp.swapaxes(w[...], 0, 1)
+    rows = w.reshape(chunk * n_kv, hs)
+    return jnp.stack([rows[pl.ds(h, chunk, stride=n_kv), :]
+                      for h in range(n_kv)])
 
 
 def _walk(n_chunks, copies, last, q3_ref, sink_ref, k_buf, v_buf, out_ref,

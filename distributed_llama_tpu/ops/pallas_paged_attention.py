@@ -86,8 +86,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..ops.quants import QK
 from .pallas_attention import (_VMEM64_PARAMS, _VMEM_BUDGET, NEG_INF,
                                _flash_walk, attn_kernel_mode)
-from .pallas_head_major_attention import (_fold, _group_rows, _pages_a_turn,
-                                          _stack3)
+from .pallas_head_major_attention import (_fold, _group_rows, _heads,
+                                          _pages_a_turn, _stack3)
 
 KV_QUANTS = ("f32", "q8")  # the --kv-quant vocabulary (f32 = cache dtype)
 
@@ -135,25 +135,6 @@ def supports_paged(page_size: int, n_kv: int, head_size: int, t_len: int,
     return (1 <= t_len <= 8 and head_size % 128 == 0
             and _paged_scratch_bytes(page_size, n_kv, head_size, itemsize,
                                      q8) <= _VMEM_BUDGET)
-
-
-def _heads(w, n_kv: int):
-    """A landed slot, a float32 ref (C, n_kv, hs), as the value (n_kv, C, hs)
-    the head-major fold takes.
-
-    Where a position's heads are whole sublane tiles (n_kv % 8 == 0: every
-    single-chip pool) the slot is (C x n_kv, hs) as it lies, a position's
-    heads on consecutive rows, so a head's K or V is ONE strided read of it
-    (every n_kv-th row) and nothing is shuffled. Any other head count (a tp
-    rank's 2 or 10) is padded to a tile in the slot, which is then NOT those
-    rows (the chip compiles the view and reads wrong rows at 10 heads:
-    distance 0.59, PERF.md section 7): one relayout of the loaded slot."""
-    chunk, _, hs = w.shape
-    if n_kv % 8:
-        return jnp.swapaxes(w[...], 0, 1)
-    rows = w.reshape(chunk * n_kv, hs)
-    return jnp.stack([rows[pl.ds(h, chunk, stride=n_kv), :]
-                      for h in range(n_kv)])
 
 
 def _flash_pages(pos_ref, table_ref, layer_ref, q3_ref, out_ref, reader, *,

@@ -144,13 +144,14 @@ def _q40(layout: str, leaf: str, t: int):
     return fn, (w, _sd((t, n), jnp.float32), _sd((), jnp.int32))
 
 
-def _decode(dtype):
+def _decode(dtype, n_kv: int = N_KV, kv_mul: int = 1, seq: int = SEQ):
     from distributed_llama_tpu.ops.pallas_attention import decode_attention
 
-    cache = _sd((2, SEQ, N_KV, HS), dtype)
-    return (functools.partial(decode_attention, kv_mul=1, interpret=False),
-            (_sd((N_KV, HS), jnp.float32), cache, cache, _sd((), jnp.int32),
-             _sd((), jnp.int32)))
+    cache = _sd((2, seq, n_kv, HS), dtype)
+    return (functools.partial(decode_attention, kv_mul=kv_mul,
+                              interpret=False),
+            (_sd((n_kv * kv_mul, HS), jnp.float32), cache, cache,
+             _sd((), jnp.int32), _sd((), jnp.int32)))
 
 
 def _decode_batch():
@@ -424,6 +425,16 @@ CASES = {
     "decode-f32": (functools.partial(_decode, jnp.float32), True),
     "decode-bf16": (functools.partial(_decode, jnp.bfloat16), True),
     "decode-batch-f32": (_decode_batch, True),
+    # PR 59: a landed chunk (chunk, n_kv, hs) is read head-major into the MXU
+    # fold: a strided read a head at Mistral's 8 KV heads x 4, one relayout
+    # of the slot at a Yi-34B tp-4 rank's 2 x 7 and a 13B rank's 10 x 1 (a
+    # ``ref.reshape`` of such a slot compiles and reads wrong rows: ROADMAP
+    # S4 e), at the decode cells' 4,096 positions; a bf16 slot is widened
+    **{f"decode-{name}-kv{n}x{m}":
+       (functools.partial(_decode, dt, n, m, 4096), True)
+       for name, dt in (("f32", jnp.float32), ("bf16", jnp.bfloat16))
+       for n, m in ((8, 4), (2, 7), (10, 1))
+       if (name, n) != ("bf16", 10)},
     "prefill-f32": (functools.partial(_prefill, jnp.float32), True),
     # was: "Slice shape along dimension 1 must be aligned to tiling (8),
     # but is 1" on memref<2048x32x128xbf16> -> 512x1x128
@@ -636,6 +647,25 @@ def test_kernel_compiles_for_v5e(chip, case):
         shapes)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert ("tpu_custom_call" in text) == kernel, case
+
+
+@pytest.mark.parametrize("n_kv", [1, 3, 6, 10, 20])
+def test_bf16_cache_head_counts_the_chip_cannot_land_are_gated(chip, n_kv):
+    """A bf16 cache is tiled (8, 128)(2, 1) in HBM (16 heads a tile, or 2 or
+    4), and the chip's compiler refuses the decode kernel's copy of any
+    other head count ("Slice shape along dimension 2 must be aligned to
+    tiling (8), but is 10": a 13B tp-4 rank). ``supports`` sends those to
+    the XLA path; the float32 cache of the same heads has a kernel."""
+    from distributed_llama_tpu.ops import pallas_attention as pa
+
+    assert not pa.supports(4096, HS, 1, n_kv, 2)
+    assert pa.supports(4096, HS, 1, n_kv, 4)
+    fn, shapes = _decode(jnp.bfloat16, n_kv, 1, 4096)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        shapes)
+    with pytest.raises(ValueError, match="no cache chunking"):
+        jax.jit(fn).lower(*args)
 
 
 @pytest.mark.parametrize("n_kv,kv_mul,t_len", [(8, 4, 1), (8, 4, 4),
