@@ -171,6 +171,13 @@ def state_slot_bytes(spec: TransformerSpec) -> int:
     context). What ``kv_position_bytes`` x positions is to a softmax spec."""
     from ..ops.retention import state_bytes
 
+    if spec.kda:
+        # a kda spec's slot: each KDA layer's state (heads, head_dim,
+        # head_dim) and its conv rows, float32 (models/kda.py); its latent
+        # layers' plane is pages (``kv_position_bytes``)
+        kd = spec.kda
+        return 4 * spec.latent.count("kda") * (
+            kd.width * kd.head_dim + (kd.d_conv - 1) * 3 * kd.width)
     if spec.latent and spec.slotted:
         # a latent spec's slot: each sliding layer's ring of latent rows,
         # float32, in whole lane tiles (models/latent.plane_width)

@@ -93,6 +93,20 @@ Extensions beyond the reference:
   ``conv1d.weight`` (channels, 1, taps) is laid (taps, channels); q / k rows
   stay as they are (there is no rotary embedding to permute for). Tested on
   seeded tensors of those names; the published weights were not run.
+* ``model_type: bailing_hybrid`` (Ling-3.0-flash: Kimi-Delta-Attention
+  layers beside latent attention with no query rank and a head-wise gate,
+  DeepSeek-V3's router, a SwiGLU clamp a layer): ``ling_spec`` reads the
+  config (``layer_group_size``: every such layer is the latent one;
+  ``kda_lower_bound``, ``short_conv_kernel_size``, the two
+  ``*_swiglu_limit_list``; header extension 11) and ``ling_tensor`` the
+  names (``LING_TENSORS``: a GUESS from the family's published hybrid code
+  and Kimi Linear's, not read off a checkpoint): a KDA layer's q / k / v /
+  f (the decay) / g projections are stacked into ``in_qkvag``, its three
+  ``*_conv1d.weight`` (channels, 1, taps) laid (taps, 3 channels), and a
+  layer's two limits, which are the CONFIG's, written as the tensor
+  ``ffn_limit``. ``rope_interleave`` true: q / k rows stay as they are. The
+  multi-token-prediction layer is not read. Tested on seeded tensors of
+  those names; the published weights were not run.
 * tokenizer export: ``--export-tokenizer`` writes the llama2.c tokenizer.bin
   from a sentencepiece tokenizer.model.
 
@@ -430,6 +444,9 @@ class HFCheckpoint:
             return hybrid_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "nemotron_h":
             return nemotron_spec(c, target, seq_len)
+        if getattr(c, "model_type", "") == "bailing_hybrid":
+            print(f"🔶 bailing_hybrid tensors: {LING_TENSORS_NOTE}")
+            return ling_spec(c, target, seq_len)
         if getattr(c, "model_type", "") == "laguna":
             print(f"🔶 laguna tensors: {LAGUNA_TENSORS_NOTE}")
             return laguna_spec(c, target, seq_len)
@@ -476,6 +493,13 @@ class HFCheckpoint:
             return nemotron_tensor(
                 lambda key: self.state[key].to(self.torch.float32).numpy(),
                 name, layer, spec, expert)
+        if spec.kda:
+            c = self.config
+            return ling_tensor(
+                lambda key: self.state[key].to(self.torch.float32).numpy(),
+                name, layer, spec, expert,
+                (c.expert_swiglu_limit_list,
+                 c.share_expert_swiglu_limit_list))
         if spec.mixers:
             key = LAGUNA_TENSORS.get(name) or MIMO_TENSORS.get(name) or {
                 "tok_embedding": "model.embed_tokens.weight",
@@ -781,6 +805,131 @@ def nemotron_tensor(read, name: str, layer: int | None,
     return w
 
 
+LING_TENSORS_NOTE = (
+    "the names are a guess from the family's published hybrid code and Kimi "
+    "Linear's (no checkpoint was read): check them against the checkpoint's "
+    "index before trusting a converted file")
+_LING_ATT = "model.layers.{layer}.attention."
+_LING_MLP = "model.layers.{layer}.mlp."
+LING_TENSORS = {
+    "tok_embedding": "model.word_embeddings.weight",
+    "rms_final": "model.norm.weight",
+    "wcls": "lm_head.weight",
+    "rms_att": "model.layers.{layer}.input_layernorm.weight",
+    "rms_ffn": "model.layers.{layer}.post_attention_layernorm.weight",
+    # a KDA layer: in_qkvag stacks the five projections in this order
+    "in_qkvag": tuple(_LING_ATT + n + "_proj.weight" for n in "qkvfg"),
+    "conv_w": tuple(_LING_ATT + n + "_conv1d.weight" for n in "qkv"),
+    "a_log": _LING_ATT + "A_log",
+    "dt_bias": _LING_ATT + "dt_bias",
+    "w_beta": _LING_ATT + "b_proj.weight",
+    "norm_g": _LING_ATT + "o_norm.weight",
+    "wo": _LING_ATT + "o_proj.weight",
+    # a latent layer
+    "wq": _LING_ATT + "q_proj.weight",
+    "rms_kv_a": _LING_ATT + "kv_a_layernorm.weight",
+    "wkv_a": _LING_ATT + "kv_a_proj_with_mqa.weight",
+    "wkv_b": _LING_ATT + "kv_b_proj.weight",
+    "w_hgate": _LING_ATT + "g_proj.weight",
+    # the FFN
+    "w1": _LING_MLP + "gate_proj.weight",
+    "w2": _LING_MLP + "down_proj.weight",
+    "w3": _LING_MLP + "up_proj.weight",
+    "moe_gate": _LING_MLP + "gate.weight",
+    "moe_bias": _LING_MLP + "gate.expert_bias",
+    "sh_w1": _LING_MLP + "shared_experts.gate_proj.weight",
+    "sh_w2": _LING_MLP + "shared_experts.down_proj.weight",
+    "sh_w3": _LING_MLP + "shared_experts.up_proj.weight",
+    "moe_w1": _LING_MLP + "experts.{expert}.gate_proj.weight",
+    "moe_w2": _LING_MLP + "experts.{expert}.down_proj.weight",
+    "moe_w3": _LING_MLP + "experts.{expert}.up_proj.weight",
+}
+
+
+def ling_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
+    """The spec of a ``bailing_hybrid`` config: every ``layer_group_size``-th
+    layer is the latent one and the others are KDA layers; the decay and
+    the output gate of a KDA layer full rank (``no_kda_lora``), the
+    head-wise gate the latent layers'. A query rank, a RoPE scaling, a
+    bias, another activation, a norm this program does not compute
+    (``value_norm``, ``up_proj_norm``, ``use_nGPT``, ``scale_router_input``)
+    or a KDA gate that is not the lower-bound ("safe") one is refused."""
+    from .models.spec import (Activation, ExpertLayout, KdaLayers,
+                              LatentAttn, Router)
+
+    period = int(c.layer_group_size)
+    if (getattr(c, "q_lora_rank", None) or getattr(c, "rope_scaling", None)
+            or getattr(c, "hidden_act", "silu") != "silu"
+            or not getattr(c, "kda_safe_gate", True)
+            or not getattr(c, "no_kda_lora", True)
+            or not getattr(c, "linear_silu", True)
+            or not getattr(c, "rope_interleave", True)
+            or not getattr(c, "use_qk_norm", True)
+            or getattr(c, "group_norm_size", 1) != 1
+            or getattr(c, "num_shared_experts", 1) != 1
+            or getattr(c, "moe_shared_expert_intermediate_size",
+                       c.moe_intermediate_size) != c.moe_intermediate_size
+            or getattr(c, "gated_attention_proj_granularity_type",
+                       "head_wise") != "head_wise"
+            or any(getattr(c, k, False) for k in (
+                "use_bias", "use_qkv_bias", "tie_word_embeddings",
+                "value_norm", "up_proj_norm", "use_nGPT",
+                "scale_router_input", "use_kda_lora", "use_mla_nope"))):
+        raise ValueError(
+            "bailing_hybrid: KDA layers with the lower-bound gate, full-rank "
+            "decay and gate projections, SiLU after the convolutions and an "
+            "L2 q / k norm, one output norm group; latent attention with no "
+            "query rank, plain interleaved RoPE and a head-wise gate; SiLU "
+            "experts with one shared expert of their width, no bias and an "
+            "untied head are what the program runs")
+    kinds = tuple("full" if (i + 1) % period == 0 else "kda"
+                  for i in range(c.num_hidden_layers))
+    limits = any(c.expert_swiglu_limit_list) or any(
+        c.share_expert_swiglu_limit_list)
+    return TransformerSpec(
+        dim=c.hidden_size, hidden_dim=c.moe_intermediate_size,
+        n_layers=c.num_hidden_layers, n_heads=c.num_attention_heads,
+        n_kv_heads=c.num_key_value_heads, vocab_size=c.vocab_size,
+        seq_len=seq_len, weights_float_type=target,
+        n_experts=c.num_experts, n_active_experts=c.num_experts_per_tok,
+        rope_theta=float(c.rope_theta), norm_eps=float(c.rms_norm_eps),
+        latent=LatentAttn(0, int(c.kv_lora_rank), int(c.qk_nope_head_dim),
+                          int(c.qk_rope_head_dim), int(c.v_head_dim),
+                          kinds=kinds, head_gate=True),
+        layout=ExpertLayout(int(c.first_k_dense_replace),
+                            int(c.intermediate_size), 1),
+        router=Router(getattr(c, "score_function", "sigmoid"),
+                      int(c.n_group), int(c.topk_group),
+                      bool(c.norm_topk_prob),
+                      float(c.routed_scaling_factor),
+                      bool(c.moe_router_enable_expert_bias)),
+        activation=Activation(limits=bool(limits)),
+        kda=KdaLayers(int(c.num_attention_heads), int(c.head_dim),
+                      int(c.short_conv_kernel_size),
+                      lower_bound=float(c.kda_lower_bound)))
+
+
+def ling_tensor(read, name: str, layer: int | None, spec: TransformerSpec,
+                expert: int | None = None, limits=((), ())):
+    """The loader's tensor ``name`` of layer ``layer`` (held expert
+    ``expert``) from a ``bailing_hybrid`` checkpoint, ``read(key)`` giving
+    a tensor as float32 numpy; ``limits``: the config's two per-layer
+    lists, whose entries of ``layer`` are the tensor ``ffn_limit``."""
+    if name == "ffn_limit":
+        return np.asarray([lst[layer] if layer < len(lst) else 0.0
+                           for lst in limits], np.float32)
+    if expert is not None:
+        expert += spec.layout.offset
+    key = LING_TENSORS[name]
+    if name == "conv_w":    # (channels, 1, taps) each -> (taps, 3 channels)
+        return np.ascontiguousarray(np.concatenate(
+            [read(k.format(layer=layer)).reshape(spec.kda.width, -1)
+             for k in key]).T)
+    if isinstance(key, tuple):
+        return np.concatenate([read(k.format(layer=layer)) for k in key])
+    return read(key.format(layer=layer, expert=expert))
+
+
 def hybrid_spec(c, target: FloatType, seq_len: int) -> TransformerSpec:
     """The spec of a ``phi4flash`` config: Mamba at every ``mb_per_layer``-th
     layer up to the middle, window attention between, the full layer after
@@ -820,7 +969,7 @@ def convert_hf(model_path: str, target: str, out: str | None = None,
         for stack, _, entries in (spec.layer_plans() if spec.planned
                                   else ()):
             # a mixer-kinds spec has two runs a layer: its FFN's follows
-            i += not (spec.mixers and stack in ("", "dense"))
+            i += not ((spec.mixers or spec.kda) and stack in ("", "dense"))
             for kind, name_, _, *e in entries:
                 arr = ckpt.tensor_by_name(name_, i, spec,
                                           e[0] if e else None)

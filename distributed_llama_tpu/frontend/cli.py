@@ -101,6 +101,21 @@ def _ssd_line(spec, slots: int) -> str:
             f"position")
 
 
+def _kda_line(spec, slots: int) -> str:
+    """What a kda spec keeps a sequence: a startup line."""
+    from ..analysis.memory_model import kv_position_bytes, state_slot_bytes
+
+    kd, la = spec.kda, spec.latent
+    return (f"💡 mixers: {la.count('kda')} delta-rule (KDA: {kd.heads} heads "
+            f"of {kd.head_dim}, a decay a key channel down to "
+            f"exp({kd.lower_bound:g}), conv {kd.d_conv}) beside "
+            f"{la.count('full')} latent; a sequence keeps "
+            f"{state_slot_bytes(spec) / 2**20:.1f} MiB of state and conv "
+            f"rows ({slots} slot{'s' if slots != 1 else ''}, fixed) and the "
+            f"latent layers' plane: {kv_position_bytes(spec, 1)} B a "
+            f"position")
+
+
 def _mixers_line(spec, slots: int) -> str:
     """What a mixer-kinds spec keeps a sequence: a startup line."""
     mx, lay = spec.mixers, spec.layout
@@ -656,6 +671,8 @@ def cmd_inference(argv: list[str], quiet: bool = False) -> int:
         print(_mixers_line(spec, rows))
     if spec.ssd and not quiet:
         print(_ssd_line(spec, rows))
+    if spec.kda and not quiet:
+        print(_kda_line(spec, rows))
     mesh = (make_mesh(sp=args.sp, tp=tp)
             if tp > 1 or args.sp > 1 else None)
     assumed = getattr(args, "_slice_tp_ranks", None)
@@ -1157,6 +1174,8 @@ def cmd_serve(argv: list[str]) -> int:
         print(_mixers_line(spec, args.slots))
     if spec.ssd:
         print(_ssd_line(spec, args.slots))
+    if spec.kda:
+        print(_kda_line(spec, args.slots))
     mesh = make_mesh(tp=args.tp) if args.tp and args.tp > 1 else None
     seed = args.seed if args.seed is not None else int(time.time())
     if journal is not None:

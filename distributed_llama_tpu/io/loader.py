@@ -377,7 +377,7 @@ def tensor_byte_ranges(spec: TransformerSpec) -> list[TensorRange]:
     layer = -1
     for stack, _, entries in (spec.layer_plans() if spec.planned else ()):
         # a mixer-kinds spec has two runs a layer: its FFN's follows
-        layer += not (spec.mixers and stack in ("", "dense"))
+        layer += not ((spec.mixers or spec.kda) and stack in ("", "dense"))
         for kind, name, shape, *_ in entries:
             if kind == "f32":
                 add(name, layer, 4 * int(np.prod(shape)))

@@ -260,8 +260,12 @@ def latent_qkv(spec: TransformerSpec, lw: dict[str, Any], x: jax.Array,
     freq, factor, scale = rope_frequencies(spec)
     if h is None:
         h = rmsnorm(x, lw["rms_att"], eps)
-    c_q = rmsnorm(matmul(lw["wq_a"], h), lw["rms_q_a"], eps)
-    q = matmul(lw["wq_b"], c_q).reshape(-1, nh, la.qk_dim)
+    if "wq" in lw:      # no query rank (a kda spec's): ONE matrix
+        q = matmul(lw["wq"], h)
+    else:
+        c_q = rmsnorm(matmul(lw["wq_a"], h), lw["rms_q_a"], eps)
+        q = matmul(lw["wq_b"], c_q)
+    q = q.reshape(-1, nh, la.qk_dim)
     # wkv_a's outputs past ``width`` are zero rows (prepare_latent_params)
     kv = (matmul(lw["wkv_a"], h) if kv is None else kv)[:, :la.width]
     c_kv = rmsnorm(kv[:, :la.kv_rank], lw["rms_kv_a"], eps)

@@ -38,7 +38,8 @@ from ..io.loader import Q40Kernel, Q40KernelNb, Q40KernelNbI4
 from ..obs.spans import (SCOPE_ATTN, SCOPE_ATTN_SINK, SCOPE_EMBED, SCOPE_FFN,
                          SCOPE_LOGITS)
 from ..ops.hyper import residual_in, residual_out
-from ..ops.linear import (StackedQ40, fake_quant_q80, ffn_activation, matmul,
+from ..ops.linear import (StackedQ40, fake_quant_q80, ffn_activation,
+                          gated_product, matmul,
                           rmsnorm)
 from ..ops.quants import FloatType
 from .spec import TransformerSpec
@@ -69,8 +70,9 @@ def init_state(spec: TransformerSpec, batch: int | None = None) -> StateCache:
 def slot_model(spec: TransformerSpec):
     """The module that runs a spec whose sequences keep a slot of fixed
     size AND pages (``spec.slotted``): ``models/sambay`` (a hybrid spec),
-    ``models/laguna`` (a mixer-kinds spec) or ``models/latent`` (a latent
-    spec with sliding layers). Each gives ``init_cache``,
+    ``models/laguna`` (a mixer-kinds spec), ``models/nemotron`` (an ssd
+    spec), ``models/kda`` (a kda spec) or ``models/latent`` (a latent spec
+    with sliding layers). Each gives ``init_cache``,
     ``init_cache_paged``, ``insert_sequence``, ``state_bytes``,
     ``forward_batch`` and ``forward_chunk``."""
     if spec.mixers:
@@ -81,6 +83,10 @@ def slot_model(spec: TransformerSpec):
         from . import nemotron
 
         return nemotron
+    if spec.kda:
+        from . import kda
+
+        return kda
     if spec.latent:
         from . import latent
 
@@ -357,17 +363,18 @@ def _swiglu(spec: TransformerSpec, lw: dict[str, Any], xb: jax.Array,
     gated), act the spec's activation (SiLU unless it
     states another: ops/linear.ffn_activation), of the leaves ``prefix + w1 | w2 | w3`` (or
     their load-time fusion ``prefix + w13``: linear.fuse_q40_layer_matmuls)."""
-    act = ffn_activation(spec, lw)
     if not spec.activation.gated:   # one up matrix, no product
+        act = ffn_activation(spec, lw)
         return matmul(lw[prefix + "w2"], _maybe_q80(
             spec, act(matmul(lw[prefix + "w1"], xb))))
+    product = gated_product(spec, lw, prefix)
     if prefix + "w13" in lw:
         h13 = matmul(lw[prefix + "w13"], xb)
         hid = h13.shape[-1] // 2
-        hb = act(h13[..., :hid]) * h13[..., hid:]
+        hb = product(h13[..., :hid], h13[..., hid:])
     else:
-        hb = act(matmul(lw[prefix + "w1"], xb)) * matmul(lw[prefix + "w3"],
-                                                         xb)
+        hb = product(matmul(lw[prefix + "w1"], xb),
+                     matmul(lw[prefix + "w3"], xb))
     return matmul(lw[prefix + "w2"], _maybe_q80(spec, hb))
 
 
@@ -670,7 +677,7 @@ def forward(spec: TransformerSpec, params: dict[str, Any], cache: KVCache,
         from .sambay import forward_sambay
 
         return forward_sambay(spec, params, cache, tokens, pos)
-    if spec.mixers or spec.ssd:
+    if spec.mixers or spec.ssd or spec.kda:
         return slot_model(spec).forward_chunk(spec, params, cache, tokens,
                                               pos, moe_counts=moe_counts)
     if spec.retention:
@@ -1103,6 +1110,11 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
     parity against f32 moves to distribution-pinned tolerance gates, the
     documented quantization contract.
     """
+    if spec.mixers or spec.ssd or spec.kda:   # before the latent branch: a
+        #                   kda spec's kinds are in the latent list
+        return slot_model(spec).forward_batch(
+            spec, params, cache, tokens, pos_vec, table, page_size=page_size,
+            moe_counts=moe_counts)
     if spec.latent:
         from .latent import forward_batch as forward_batch_latent
 
@@ -1114,10 +1126,6 @@ def forward_batch_paged(spec: TransformerSpec, page_size: int,
 
         return forward_batch_sambay(spec, params, cache, tokens, pos_vec,
                                     table, page_size=page_size)
-    if spec.mixers or spec.ssd:
-        return slot_model(spec).forward_batch(
-            spec, params, cache, tokens, pos_vec, table, page_size=page_size,
-            moe_counts=moe_counts)
     B = tokens.shape[0]
     x = params["tok_embedding"][tokens].astype(jnp.float32)  # (B, dim)
     positions = pos_vec if jnp.ndim(pos_vec) == 1 else jnp.full((B,),
@@ -1724,7 +1732,9 @@ def params_to_device(params: dict[str, Any], dtype=None,
     from ..ops.linear import fuse_q40_layer_matmuls, pack_q40_params
 
     with startup_phase("pack"):     # every host repack, not the Q40 one alone
-        if "wkv_b" in params:   # a latent spec's absorbed halves (float32)
+        if "wkv_b" in params or "wkv_b" in params.get("full", ()):
+            # a latent spec's absorbed halves (float32; a kda spec's lie
+            # in its latent layers' stack)
             from .latent import prepare_latent_params
 
             if spec is None:

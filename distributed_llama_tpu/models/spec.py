@@ -188,6 +188,35 @@ tensors in its kind's stack (``params[kind]``):
 
 with dInner = ssmHeads ssmHeadDim and convDim = dInner + 2 ssmGroups
 ssmState; the norm's gain is ``rms_att`` in every kind's stack.
+
+Extension VERSION 11 (version 9's values, its 128 bytes now an index into
+``LATENT_KINDS``, then eight ints {kdaHeads, kdaHeadDim, kdaConv, headGate,
+ffnLimits, three reserved} and one float64 {kdaLowerBound}) is
+written only by a latent spec that sets ``kda`` (``KdaLayers``:
+Ling-3.0-flash's layout, Kimi-Delta-Attention layers beside latent
+attention), so files of every earlier version read and write byte for byte.
+A layer's kind is then "kda" (a delta-rule state of kdaHeads x kdaHeadDim x
+kdaHeadDim float32 a sequence, ``ops/kda.py``) or "full" (latent attention,
+where ``qRank`` may be 0: ONE matrix ``wq`` and no ``q_a_norm``, and with
+``headGate`` a sigmoid gate a HEAD on the heads' outputs). Such a file lays
+a layer as two runs of tensors, as version 7 does: its mixer's
+(``params[kind]``) and then its FFN's (``params["dense"]`` or the top-level
+stacks), with kd = kdaHeads kdaHeadDim:
+
+  "kda":   attention_norm (F32 dim), in_qkvag (5 kd x dim: rows [q | k | v |
+           a | g]) [weightsFloatType], conv_w (F32, kdaConv x 3 kd: the taps
+           of q, k and v's depthwise causal convolutions), a_log (F32
+           kdaHeads), dt_bias (F32 kd), w_beta (F32, kdaHeads x dim), norm_g
+           (F32 kd), wo (dim x kd) [weightsFloatType]
+  "full":  attention_norm, kv_a_norm (F32 kvRank), [q_a_norm (F32 qRank),
+           wq_a, wq_b | wq (nHeads (nope + rope) x dim) where qRank is 0],
+           wkv_a, wkv_b, [wg], wo [weightsFloatType], [w_hgate (F32, nHeads x
+           dim) where headGate]
+  FFN:     ffn_norm (F32 dim), then a dense layer's w1, w2, w3 at
+           denseHidden, or an expert layer's [ffn_limit (F32, 2: the clamp
+           of the routed experts' and of the shared expert's gate and up
+           projections, 0: none) where ffnLimits], router ... experts as in
+           version 4
 """
 
 from __future__ import annotations
@@ -219,8 +248,10 @@ EXT9_VERSION = 9
 EXT9_STRUCT = struct.Struct("<14i2d16i7d4i3d8i3d128B")
 EXT10_VERSION = 10
 EXT10_STRUCT = struct.Struct("<14i2d16i7d12i128B")
+EXT11_VERSION = 11
+EXT11_STRUCT = struct.Struct("<14i2d16i7d4i3d8i3d128B8i1d")
 MAX_HEADER_BYTES = max(EXT8_STRUCT.size, EXT9_STRUCT.size,
-                       EXT10_STRUCT.size)
+                       EXT10_STRUCT.size, EXT11_STRUCT.size)
 ACTIVATIONS = ("silu", "polynorm", "relu2")
 HC_SUBLAYERS = ("att", "ffn")
 ATTN_KINDS = ("softmax", "retention")
@@ -235,6 +266,9 @@ ROUTER_SCORINGS = ("softmax", "sigmoid")
 # what a layer of a ``MixerKinds`` spec attends over: every position (K / V
 # of its own, in pages under ``serve``) or the last ``window`` (a ring)
 MIXER_KINDS = ("full", "sliding")
+# ... of a latent spec: those two, or a Kimi-Delta-Attention layer (a
+# recurrent state of fixed size; header version 11)
+LATENT_KINDS = (*MIXER_KINDS, "kda")
 # what a layer of an ``SsdLayers`` spec IS (one mixer, no FFN beside it),
 # and what it caches for one sequence
 SSD_KINDS = ("mamba2", "full", "experts")
@@ -267,6 +301,11 @@ class LatentAttn:
     gate: bool = False
     kinds: tuple = ()
     window: int = 0
+    # header version 11 (a spec with ``kda``): ``q_rank`` may be 0 (q is ONE
+    # matrix ``wq``, no rank and no norm between), a kind may be "kda"
+    # (``LATENT_KINDS``), and ``head_gate``: the heads' outputs times a
+    # sigmoid of the normed layer input a HEAD (``w_hgate``, float32)
+    head_gate: bool = False
 
     @property
     def qk_dim(self) -> int:
@@ -282,7 +321,7 @@ class LatentAttn:
         """Whether the record states what a version-4 header has no field
         for."""
         return bool(self.kv_groups or self.noise_heads or self.gate
-                    or self.kinds or self.window)
+                    or self.kinds or self.window or self.head_gate)
 
     def count(self, kind: str) -> int:
         return sum(k == kind for k in self.kinds)
@@ -298,11 +337,15 @@ class Activation:
     with ``n(u) = u / sqrt(mean(u^2) + eps)`` over the FFN's own width and
     the four numbers a layer's ``pn_w`` (``clamp`` 0: the bias as it is).
     ``gated=False`` (header version 10): ONE up matrix and no product,
-    ``w2(act(w1 x))``, with "relu2": ``relu(z)^2``."""
+    ``w2(act(w1 x))``, with "relu2": ``relu(z)^2``. ``limits`` (header
+    version 11): an expert layer carries ``ffn_limit`` (2,), the clamp L of
+    its routed experts and of its shared expert, ``w2(act(min(w1 x, L)) *
+    clip(w3 x, -L, L))``; L = 0 is no clamp (ops/linear.gated_product)."""
     kind: str = "silu"
     scale: float = 1.0
     clamp: float = 0.0
     gated: bool = True
+    limits: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -512,6 +555,30 @@ class SsdLayers:
         return sum(k == kind for k in self.kinds)
 
 
+@dataclasses.dataclass(frozen=True)
+class KdaLayers:
+    """The Kimi-Delta-Attention layers of a latent spec whose
+    ``latent.kinds`` name some "kda" (arXiv:2510.26692; ops/kda.py has the
+    recurrence, models/kda.py runs it, models/reference_kda.py states it):
+    ``heads`` heads of ``head_dim`` key AND value channels, a state (heads,
+    head_dim, head_dim) float32 a sequence and layer, depthwise causal
+    convolutions ``d_conv`` wide over q, k and v (their last d_conv - 1
+    inputs are state too), a per-channel decay exp(g) with ``g =
+    lower_bound * sigmoid(exp(a_log) * (W_a h + dt_bias))`` in
+    [exp(lower_bound), 1). How a prompt's chunked form is tiled
+    (``ops/kda.CHUNK``) is the program's business and no field here."""
+    heads: int
+    head_dim: int
+    d_conv: int = 4
+    lower_bound: float = -5.0
+
+    @property
+    def width(self) -> int:
+        """heads x head_dim: what each of q, k, v, the decay and the
+        output gate is wide."""
+        return self.heads * self.head_dim
+
+
 def cache_lanes(head: int) -> int:
     """The last dim a mixer-kinds spec's cache gives a K or V head of
     ``head`` values: itself up to one 128-lane tile, whole tiles past it
@@ -576,10 +643,15 @@ class TransformerSpec:
     # header version 10: a per-layer list whose layer is ONE mixer (Mamba-2,
     # attention or experts), beside ``layout`` and ``router``
     ssd: SsdLayers | None = None
+    # header version 11: the Kimi-Delta-Attention layers of a latent spec
+    # (``latent.kinds`` says which layers they are)
+    kda: KdaLayers | None = None
 
     def __post_init__(self):
         if self.ssd is not None:
             self._check_ssd()
+        elif self.kda is not None:
+            self._check_kda()
         elif self.latent is not None and (
                 self.latent.widened or self.activation != Activation()):
             self._check_latent_kinds()
@@ -648,8 +720,33 @@ class TransformerSpec:
                 f"{self.n_active_experts}: both 0 (dense FFN) or "
                 f"0 < active <= experts")
 
+    def _check_kda(self) -> None:
+        kd, la, act = self.kda, self.latent, self.activation
+        kinds = tuple(la.kinds) if la else ()
+        if (la is None or len(kinds) != self.n_layers or len(kinds) > 128
+                or set(kinds) != {"kda", "full"}):
+            raise ValueError("kda: a latent spec whose latent.kinds name "
+                             "\"kda\" and \"full\" layers (some of each, no "
+                             "\"sliding\"), one for each of n_layers (at most "
+                             "128)")
+        if (self.hybrid or self.hyper or self.mixers or la.kv_groups
+                or la.noise_heads or la.window or la.q_rank < 0
+                or min(kd.heads, kd.head_dim, kd.d_conv - 1) < 1
+                or not kd.lower_bound < 0):
+            raise ValueError("kda: positive sizes and a negative lower "
+                             "bound, beside plain latent attention (a head "
+                             "its own KV group, no noise heads, no rings, "
+                             "one residual stream)")
+        if act != Activation(limits=act.limits):
+            raise ValueError(f"activation {act}: a kda spec's FFN is a gated "
+                             f"SiLU, with per-layer limits or without")
+
     def _check_latent_kinds(self) -> None:
         la, act = self.latent, self.activation
+        if la.head_gate or la.q_rank < 1 or act.limits:
+            raise ValueError("latent: a head-wise gate, q_rank 0 and "
+                             "per-layer FFN limits are header version 11's: "
+                             "set kda")
         groups = la.kv_groups or self.n_heads
         kinds = tuple(la.kinds)
         if (self.n_heads % groups or la.noise_heads not in (0, 1)
@@ -785,7 +882,7 @@ class TransformerSpec:
         hybrid spec's, a mixer-kinds spec's, a latent spec's with sliding
         layers: rings of latent rows beside the full layers' plane): what
         ``models/llama.slot_model`` runs."""
-        return bool(self.hybrid or self.mixers or self.ssd
+        return bool(self.hybrid or self.mixers or self.ssd or self.kda
                     or (self.latent and self.latent.window))
 
     @property
@@ -813,10 +910,12 @@ class TransformerSpec:
 
     @property
     def header_version(self) -> int:
-        """0 (the 28-byte header), 2, 3, 4, 5, 6, 7, 8, 9 or 10: the lowest
+        """0 (the 28-byte header), 2, 3, 4, 5, 6, 7, 8, 9, 10 or 11: the lowest
         that holds the spec."""
         if self.ssd:
             return EXT10_VERSION
+        if self.kda:
+            return EXT11_VERSION
         if self.mixers:
             return EXT8_VERSION if self.mixers.widened else EXT7_VERSION
         if self.latent and (self.latent.widened
@@ -850,7 +949,8 @@ class TransformerSpec:
                 EXT7_VERSION: EXT7_STRUCT.size,
                 EXT8_VERSION: EXT8_STRUCT.size,
                 EXT9_VERSION: EXT9_STRUCT.size,
-                EXT10_VERSION: EXT10_STRUCT.size}[self.header_version]
+                EXT10_VERSION: EXT10_STRUCT.size,
+                EXT11_VERSION: EXT11_STRUCT.size}[self.header_version]
 
     @property
     def head_size(self) -> int:
@@ -924,7 +1024,8 @@ class TransformerSpec:
                       (EXT7_VERSION, 187): EXT7_STRUCT,
                       (EXT8_VERSION, 193): EXT8_STRUCT,
                       (EXT9_VERSION, 175): EXT9_STRUCT,
-                      (EXT10_VERSION, 176): EXT10_STRUCT}.get(
+                      (EXT10_VERSION, 176): EXT10_STRUCT,
+                      (EXT11_VERSION, 184): EXT11_STRUCT}.get(
                           (version, count))
             if layout is None:
                 raise ValueError(f"unknown header extension version "
@@ -942,10 +1043,12 @@ class TransformerSpec:
             if version == EXT10_VERSION:
                 more = _read_ext10(ints[36:], base[2])
                 ints = ints[:36]
-            nine = None
-            if version == EXT9_VERSION:
+            nine = eleven = None
+            if version == EXT11_VERSION:
+                eleven, ints = ints[-9:], ints[:-9]
+            if version in (EXT9_VERSION, EXT11_VERSION):
                 nine, ints = ints[43:], ints[:43]
-            if version in (EXT6_VERSION, EXT9_VERSION):
+            if version in (EXT6_VERSION, EXT9_VERSION, EXT11_VERSION):
                 streams, iters, _, _, eps, lo, hi = ints[36:]
                 more = dict(hyper=HyperConnections(
                     streams, iters, float(eps), float(lo), float(hi),
@@ -955,6 +1058,8 @@ class TransformerSpec:
                 more = dict(_read_ext4(ints[13:]), **more)
             if nine:
                 more.update(_read_ext9(nine, more["latent"], base[2]))
+            if eleven:
+                more.update(_read_ext11(eleven, more))
             if version >= EXT3_VERSION:
                 kind, theta, eps = ints[10:13]
                 if not 0 <= kind < len(ATTN_KINDS):
@@ -1000,19 +1105,26 @@ class TransformerSpec:
             rs.beta_slow, rs.mscale, rs.mscale_all_dim)
         if self.header_version == EXT4_VERSION:
             return EXT4_STRUCT.pack(EXT_MAGIC, EXT4_VERSION, 29, *v3, *v4)
-        if self.header_version in (EXT6_VERSION, EXT9_VERSION):
+        if self.header_version in (EXT6_VERSION, EXT9_VERSION,
+                                   EXT11_VERSION):
             hc = self.hyper or HyperConnections(0, 0, 0.0, 0.0, 0.0)
             v6 = (*v3, *v4, hc.streams, hc.sinkhorn_iters, 0, 0, hc.eps,
                   hc.clamp_min, hc.clamp_max)
             if self.header_version == EXT6_VERSION:
                 return EXT6_STRUCT.pack(EXT_MAGIC, EXT6_VERSION, 36, *v6)
             act = self.activation
-            kinds = [MIXER_KINDS.index(k) for k in la.kinds]
-            return EXT9_STRUCT.pack(
-                EXT_MAGIC, EXT9_VERSION, 175, *v6, la.kv_groups,
-                la.noise_heads, int(la.gate), la.window,
-                ACTIVATIONS.index(act.kind), 0, 0, 0, act.scale, act.clamp,
-                hc.stream_clamp, *kinds, *([255] * (128 - len(kinds))))
+            kinds = [LATENT_KINDS.index(k) for k in la.kinds]
+            v9 = (*v6, la.kv_groups,
+                  la.noise_heads, int(la.gate), la.window,
+                  ACTIVATIONS.index(act.kind), 0, 0, 0, act.scale, act.clamp,
+                  hc.stream_clamp, *kinds, *([255] * (128 - len(kinds))))
+            if self.header_version == EXT9_VERSION:
+                return EXT9_STRUCT.pack(EXT_MAGIC, EXT9_VERSION, 175, *v9)
+            kd = self.kda
+            return EXT11_STRUCT.pack(
+                EXT_MAGIC, EXT11_VERSION, 184, *v9, kd.heads, kd.head_dim,
+                kd.d_conv, int(la.head_gate), int(act.limits), 0, 0, 0,
+                kd.lower_bound)
         if self.ssd:
             sd, act = self.ssd, self.activation
             kinds = [SSD_KINDS.index(k) for k in sd.kinds]
@@ -1056,7 +1168,7 @@ class TransformerSpec:
         order: an expert spec has the four attention tensors here and its
         FFN under ``expert_matmul_shapes``."""
         d, h, kv = self.dim, self.hidden_dim, self.kv_dim
-        if self.hybrid or self.mixers or self.ssd:
+        if self.hybrid or self.mixers or self.ssd or self.kda:
             # every distinct matmul tensor of any kind, once (a routed
             # expert's are ``expert_matmul_shapes``)
             seen = {}
@@ -1080,8 +1192,10 @@ class TransformerSpec:
             return [("wq", (d, d)), ("wk", (kv, d)), ("wv", (kv, d)),
                     ("wo", (d, d))]
         la, out = self.latent, self.latent_signal_heads * self.latent.v_dim
-        return [("wq_a", (la.q_rank, d)),
-                ("wq_b", (self.n_heads * la.qk_dim, la.q_rank)),
+        wq = ([("wq_a", (la.q_rank, d)),
+               ("wq_b", (self.n_heads * la.qk_dim, la.q_rank))]
+              if la.q_rank else [("wq", (self.n_heads * la.qk_dim, d))])
+        return [*wq,
                 ("wkv_a", (la.width, d)),
                 ("wkv_b", (self.latent_groups * (la.nope_dim + la.v_dim),
                            la.kv_rank)),
@@ -1092,7 +1206,7 @@ class TransformerSpec:
         """A LEADING DENSE layer's matmul tensors of an expert spec whose
         layout has some (empty otherwise): the attention tensors and a
         SwiGLU of ``layout.dense_hidden``."""
-        if not self.layout.dense_layers or self.mixers:
+        if not self.layout.dense_layers or self.mixers or self.kda:
             return []   # a mixer-kinds spec's are among its distinct ones
         d, h = self.dim, self.layout.dense_hidden
         return self.attn_matmul_shapes() + [
@@ -1158,6 +1272,8 @@ class TransformerSpec:
             return self._mixer_plans()
         if self.ssd:
             return self._ssd_plans()
+        if self.kda:
+            return self._kda_plans()
         norms = [("f32", n, (w,)) for n, w in self.layer_norm_shapes()]
         norms += [("f32", n, s) for n, s in self.hyper_shapes()]
         if self.latent.noise_heads:
@@ -1263,6 +1379,50 @@ class TransformerSpec:
                          else ("", i - k, ffn))
         return plans
 
+    def _kda_plans(self):
+        """``layer_plans`` of a kda spec: TWO entries a layer, as a
+        mixer-kinds spec's: its mixer's (``stack`` "kda" or "full",
+        ``index`` its place among the layers of that kind) and then its
+        FFN's ("dense" or ""). The module docstring has each run's
+        tensors."""
+        kd, la, lay = self.kda, self.latent, self.layout
+        d, h, w = self.dim, self.hidden_dim, self.kda.width
+        f, m = (lambda n, *s: ("f32", n, s)), (lambda n, *s: ("mm", n, s))
+        mixer = {
+            "kda": [f("rms_att", d), m("in_qkvag", 5 * w, d),
+                    f("conv_w", kd.d_conv, 3 * w), f("a_log", kd.heads),
+                    f("dt_bias", w), f("w_beta", kd.heads, d),
+                    f("norm_g", w), m("wo", d, w)],
+            "full": [f("rms_att", d), f("rms_kv_a", la.kv_rank),
+                     *([f("rms_q_a", la.q_rank)] if la.q_rank else []),
+                     *(("mm", n, s) for n, s in self.attn_matmul_shapes()),
+                     *([f("w_hgate", self.n_heads, d)] if la.head_gate
+                       else [])],
+        }
+        ffn_dense = [f("rms_ffn", d), m("w1", lay.dense_hidden, d),
+                     m("w2", d, lay.dense_hidden),
+                     m("w3", lay.dense_hidden, d)]
+        ffn = [f("rms_ffn", d)]
+        if self.activation.limits:
+            ffn.append(f("ffn_limit", 2))
+        ffn.append(f("moe_gate", self.n_experts, d))
+        if self.router.bias:
+            ffn.append(f("moe_bias", self.n_experts))
+        sh = lay.shared * h
+        if sh:
+            ffn += [m("sh_w1", sh, d), m("sh_w2", d, sh), m("sh_w3", sh, d)]
+        ffn += [("mm", n, s, e) for e in range(self.n_experts_held)
+                for n, s in self.expert_matmul_shapes()]
+        seen = {"kda": 0, "full": 0}
+        plans = []
+        for i, kind in enumerate(la.kinds):
+            plans.append((kind, seen[kind], mixer[kind]))
+            seen[kind] += 1
+            k = lay.dense_layers
+            plans.append(("dense", i, ffn_dense) if i < k
+                         else ("", i - k, ffn))
+        return plans
+
     def _ssd_plans(self):
         """``layer_plans`` of an ssd spec: ONE entry a layer, ``stack`` its
         kind and ``index`` its place among the layers of that kind (the
@@ -1313,6 +1473,10 @@ class TransformerSpec:
                      **{k: self.mixers.count(k) for k in MIXER_KINDS}}
         if self.ssd:
             depth = {k: self.ssd.count(k) for k in SSD_KINDS}
+        if self.kda:
+            depth = {"dense": self.n_dense_layers,
+                     "": self.n_layers - self.n_dense_layers,
+                     **{k: self.latent.count(k) for k in ("kda", "full")}}
         seen, out = set(), []
         for stack, _, entries in self.layer_plans():
             for kind, name, shape, *e in entries:
@@ -1412,19 +1576,32 @@ def _read_ext10(vals, n_layers: int) -> dict:
         activation=Activation(ACTIVATIONS[act], gated=bool(gated)))
 
 
+def _read_ext11(vals, more: dict) -> dict:
+    """``kda`` from a version-11 header's eight ints and one float64, and
+    what it adds to ``latent`` and ``activation`` (as ``_read_ext9`` left
+    them)."""
+    heads, head_dim, d_conv, head_gate, limits, _, _, _, bound = vals
+    return dict(
+        kda=KdaLayers(heads, head_dim, d_conv, float(bound)),
+        latent=dataclasses.replace(more["latent"],
+                                   head_gate=bool(head_gate)),
+        activation=dataclasses.replace(more["activation"],
+                                       limits=bool(limits)))
+
+
 def _read_ext9(vals, la: LatentAttn, n_layers: int) -> dict:
     """What a version-9 header adds to ``latent`` (and ``activation``)
     from its eight ints, three float64 and 128 bytes."""
     groups, noise, gate, window, act, _, _, _, scale, clamp, _, *kinds = vals
     kinds = [k for k in kinds[:n_layers] if k != 255]
     if not 0 <= act < len(ACTIVATIONS) or any(
-            k >= len(MIXER_KINDS) for k in kinds):
+            k >= len(LATENT_KINDS) for k in kinds):
         raise ValueError("unknown activation or layer kind in a version-9 "
                          "header")
     return dict(
         latent=dataclasses.replace(
             la, kv_groups=groups, noise_heads=noise, gate=bool(gate),
-            window=window, kinds=tuple(MIXER_KINDS[k] for k in kinds)),
+            window=window, kinds=tuple(LATENT_KINDS[k] for k in kinds)),
         activation=Activation(ACTIVATIONS[act], float(scale), float(clamp)))
 
 

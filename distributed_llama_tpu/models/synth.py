@@ -101,6 +101,36 @@ def ssd_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
     return x + np.float32(1) if name == "norm_g" else x
 
 
+KDA_LEAVES = ("conv_w", "a_log", "dt_bias", "w_beta", "ffn_limit")
+
+
+def kda_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
+    """A float32 leaf of a kda spec (any leading layer axes in ``shape``)
+    from ``unit(*shape)`` ~ N(0, 1): ``a_log`` = log of 0.5 .. 2 spread
+    evenly over the heads and ``dt_bias`` -3 .. 3 spread evenly over the
+    heads' channels, so that the decay's exponent ``lower_bound *
+    sigmoid(exp(a_log) (W_a h + dt_bias))`` covers [lower_bound, 0) over
+    the heads (the first forget slowly, the last at once); conv taps ~N(0,
+    1/2); ``w_beta`` rows ~N(0, 1/sqrt(dim)) (the write strength spreads
+    around 0.5); ``ffn_limit``: 0 (no clamp) in the first two thirds of the
+    expert layers, then (0.5, 0.75) for the routed and the shared experts,
+    where seeded projections ~N(0, 1) meet them (ONE layer's, where the
+    caller has no stack: no clamp)."""
+    if name == "a_log":
+        a = np.log(np.linspace(0.5, 2.0, shape[-1], dtype=np.float32))
+        return np.broadcast_to(a, shape).copy()
+    if name == "dt_bias":
+        return np.broadcast_to(np.linspace(-3.0, 3.0, shape[-1],
+                                           dtype=np.float32), shape).copy()
+    if name == "ffn_limit":
+        out = np.zeros(shape, np.float32)
+        if len(shape) > 1:
+            out[-(-2 * shape[0] // 3):] = (0.5, 0.75)
+        return out
+    return unit(*shape) * np.float32(
+        {"w_beta": spec.dim ** -0.5, "conv_w": 0.5}[name])
+
+
 def hyper_leaf(spec: TransformerSpec, name: str, shape, unit) -> np.ndarray:
     """A float32 leaf ``hc_<sub>_<phi|gate|bias>`` of a spec with several
     residual streams (any leading layer axes in ``shape``) from
@@ -152,6 +182,9 @@ def _build_planned_tree(spec: TransformerSpec, t, mm, tie=None) -> dict:
                                     lambda *s: t(*s) * np.float32(20.0))
         elif spec.ssd and name in SSD_LEAVES:
             dst[name] = ssd_leaf(spec, name, shape,
+                                 lambda *s: t(*s) * np.float32(20.0))
+        elif spec.kda and name in KDA_LEAVES:
+            dst[name] = kda_leaf(spec, name, shape,
                                  lambda *s: t(*s) * np.float32(20.0))
         elif name.startswith("hc_"):
             dst[name] = hyper_leaf(spec, name, shape,
@@ -384,6 +417,10 @@ def write_synth_q40_model(path: str, spec: TransformerSpec,
                             s, dtype=np.float32)))).cast("B"))
                 elif spec.ssd and name in SSD_LEAVES:
                     f.write(memoryview(np.ascontiguousarray(ssd_leaf(
+                        spec, name, shape, lambda *s: rng.standard_normal(
+                            s, dtype=np.float32)))).cast("B"))
+                elif spec.kda and name in KDA_LEAVES:
+                    f.write(memoryview(np.ascontiguousarray(kda_leaf(
                         spec, name, shape, lambda *s: rng.standard_normal(
                             s, dtype=np.float32)))).cast("B"))
                 elif name.startswith("hc_"):

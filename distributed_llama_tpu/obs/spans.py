@@ -81,6 +81,17 @@ SCOPE_SSD_PROJ = "ssd.proj"
 SCOPE_SSD_CONV = "ssd.conv"
 SCOPE_SSD_SCAN = "ssd.scan"
 SCOPE_SSD_GATE_NORM = "ssd.gate_norm"
+# inside SCOPE_ATTN of a kda spec's Kimi-Delta-Attention layer
+# (models/kda.py): the projection in, the three convolutions and their
+# activation, the q / k norms with the decay and the write strength, the
+# state's update and read (the decode kernel ``ops/kda.DECODE_KERNEL`` or a
+# chunk's matrix products and its triangular solve), the output's norm and
+# gate
+SCOPE_KDA_PROJ = "kda.proj"
+SCOPE_KDA_CONV = "kda.conv"
+SCOPE_KDA_GATE = "kda.gate"
+SCOPE_KDA_SCAN = "kda.scan"
+SCOPE_KDA_OUT_NORM = "kda.out_norm"
 
 
 def scope_rope(kind: str) -> str:

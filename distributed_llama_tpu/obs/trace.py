@@ -655,8 +655,10 @@ class EngineMetrics:
             if ctr is None:
                 ctr = self._layers_run[kind] = self.registry.labeled_counter(
                     "dllama_layers_run_total", {"kind": kind},
-                    "Layers the landed decode steps ran, by kind (a model "
-                    "whose layer is ONE mixer: mamba2, full or experts)")
+                    "Layers the landed decode steps ran, by kind (mamba2, "
+                    "full or experts where a layer is ONE mixer; kda or "
+                    "latent where delta-rule layers stand beside latent "
+                    "attention)")
             ctr.inc()
 
     def record_retire(self, req, now: float) -> None:

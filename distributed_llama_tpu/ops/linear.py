@@ -144,6 +144,24 @@ def ffn_activation(spec, lw):
                              clamp=act.clamp, eps=spec.norm_eps)
 
 
+def gated_product(spec, lw, prefix: str = ""):
+    """``(gate, up) -> act(gate) * up`` of a gated FFN's two projections,
+    ``act`` the layer's ``ffn_activation``. Where the layer carries
+    ``ffn_limit`` (2,) (``spec.activation.limits``: a kda spec's expert
+    layers), the gate is clamped above and the up projection both ways at
+    L = ffn_limit[0] (the routed experts) or ffn_limit[1] (the shared
+    expert, ``prefix`` "sh_") first; L = 0 is no clamp. The ONE place the
+    gated FFN sites take the product from."""
+    act = ffn_activation(spec, lw)
+    limit = lw.get("ffn_limit")
+    if limit is None:
+        return lambda gate, up: act(gate) * up
+    cap = limit[int(prefix == "sh_")]
+    cap = jnp.where(cap > 0, cap, jnp.inf)
+    return lambda gate, up: act(jnp.minimum(gate, cap)) * jnp.clip(
+        up, -cap, cap)
+
+
 def dequantize_weight(w) -> jax.Array:
     """Materialize any weight representation as f32 (d, n)."""
     if isinstance(w, StackedQ40):

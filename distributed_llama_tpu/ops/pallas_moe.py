@@ -105,7 +105,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
-from .linear import StackedQ40, ffn_activation, matmul, matmul_mode, silu
+from .linear import (StackedQ40, ffn_activation, gated_product, matmul,
+                     matmul_mode, silu)
 from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _T1_CHUNK, _T1_GROUP,
                          _VMEM64_PARAMS, NJ, _diag_planes_nb, _mask_pieces,
                          _planes_dot)
@@ -561,9 +562,11 @@ def shape_places(d: int, nb: int) -> bool:
 
 
 def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret,
-                   bf16=False, act=silu, gated=True):
+                   bf16=False, act=silu, gated=True, product=None):
     """``w13``: the fused [gate | up] stack, or, not ``gated``, the one up
-    stack ``moe_w1``."""
+    stack ``moe_w1``. ``product``: a gated expert's ``(gate, up) ->
+    hidden`` (ops/linear.gated_product; default ``act(gate) * up``)."""
+    product = product or (lambda gate, up: act(gate) * up)
     t, k = topi.shape
     cap = slot_cap(t, k, n_experts)
     (slot_expert, n_slots, fill, slot_rows, pair_slot, pair_lane,
@@ -583,7 +586,7 @@ def _experts_slots(layer, w13, w2, xb, topw, topi, n_experts, interpret,
     hid = h13.shape[-1] // 2
     # the activation between the two calls: a PolyNorm's mean runs over a
     # slot row's own hidden width (a lane no pair fills reads 0)
-    out = call(w2, act(h13[..., :hid]) * h13[..., hid:] if gated
+    out = call(w2, product(h13[..., :hid], h13[..., hid:]) if gated
                else act(h13))                                # (A, C, dim)
     picked = out[pair_slot, pair_lane]                   # (T, k, dim)
     # a pair that took no slot reads whatever lies at the clamped index
@@ -599,17 +602,19 @@ def _routing_mask(topw, topi, n_experts):
             jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32))
 
 
-def _experts_xla(lw, xb, topw, topi, n_experts, act=silu, gated=True):
+def _experts_xla(lw, xb, topw, topi, n_experts, act=silu, gated=True,
+                 product=None):
     """One expert at a time through ``ops/linear.matmul`` (codec Q40 or
     dense leaves): every row through every expert, weighted 0 where it was
-    not routed. Holds one dequantized expert at a time."""
+    not routed. Holds one dequantized expert at a time. ``product`` as
+    ``_experts_slots``'s."""
     wmask, counts = _routing_mask(topw, topi, n_experts)
+    product = product or (lambda gate, up: act(gate) * up)
 
     def body(acc, ws):
         w1, w2, *w3, m = ws
-        h = act(matmul(w1, xb))
-        if gated:
-            h = h * matmul(w3[0], xb)
+        h = matmul(w1, xb)
+        h = product(h, matmul(w3[0], xb)) if gated else act(h)
         return acc + matmul(w2, h * m[:, None]), None
 
     acc, _ = jax.lax.scan(body, jnp.zeros_like(xb, dtype=jnp.float32),
@@ -642,6 +647,8 @@ def moe_ffn(spec, lw: dict, xb: jax.Array):
             topw = jnp.where(here, topw, 0.0)
     n_exp = held
     gated = spec.activation.gated
+    act = ffn_activation(spec, lw)
+    product = gated_product(spec, lw) if gated else None
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         w13 = lw.get("moe_w13" if gated else "moe_w1")
         w2 = lw.get("moe_w2")
@@ -650,13 +657,13 @@ def moe_ffn(spec, lw: dict, xb: jax.Array):
             layer = jnp.asarray(w13.layer, dtype=jnp.int32).reshape(1)
             y, counts = _experts_slots(layer, w13.w, w2.w, x2, topw, topi,
                                        n_exp, interpret,
-                                       matmul_mode() == "bf16",
-                                       ffn_activation(spec, lw), gated)
+                                       matmul_mode() == "bf16", act, gated,
+                                       product)
         elif "moe_w1" not in lw or isinstance(lw["moe_w1"], StackedQ40):
             raise NotImplementedError(
                 "expert stacks packed for the kernels without their fused "
                 "moe_w13 (ops/linear.fuse_q40_layer_matmuls)")
         else:
-            y, counts = _experts_xla(lw, x2, topw, topi, n_exp,
-                                     ffn_activation(spec, lw), gated)
+            y, counts = _experts_xla(lw, x2, topw, topi, n_exp, act, gated,
+                                     product)
     return y.reshape(*lead, -1), counts if routed is None else routed

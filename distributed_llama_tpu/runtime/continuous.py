@@ -207,11 +207,15 @@ def sequence_caches(spec) -> frozenset:
     Mamba-2 state AND each attention layer's pages), a mixer-kinds spec
     rings AND its full layers' pages ("rings" rides along: its slot is
     window rings alone), a latent spec with sliding layers rings of latent
-    rows AND its full layers' plane. "streams" rides along where the
+    rows AND its full layers' plane, a kda spec a delta-rule state and
+    conv rows a KDA layer AND its latent layers' plane. "streams" rides
+    along where the
     residual path is several streams (``spec.hyper``): nothing a sequence
     caches, but the list below names it in its reasons."""
     if spec.mixers:
         return frozenset({"state", "pages", "rings"})
+    if spec.kda:
+        return frozenset({"state", "plane"})
     if spec.latent and spec.slotted:
         return frozenset({"state", "plane", "rings"}
                          | ({"streams"} if spec.hyper else set()))
@@ -241,6 +245,10 @@ _WHY = {
                                             "sliding layer beside its "
                                             "full layers' KV pages",
 }
+_WHY[frozenset({"state", "plane"})] = (
+    "a delta-rule model keeps a recurrent state and conv rows of fixed size "
+    "a KDA layer beside its latent layers' plane [c_kv | k_rope], not K "
+    "and V")
 _WHY[frozenset({"state", "plane", "rings"})] = (
     "a latent-attention model with sliding layers keeps a ring of latent "
     "rows of fixed size a sliding layer beside its full layers' plane "
@@ -294,10 +302,10 @@ def cache_refusals(caches: frozenset, *, tp: int = 1, page_size: int = 0,
                        + " nor the experts held here are placed "
                        "over tensor-parallel ranks")
         elif state:
-            from ..ops import mamba, retention
+            from ..ops import kda, mamba, retention
 
-            out.append(f"--tp {tp}: "
-                       f"{(mamba if paged else retention).TP_REFUSAL}")
+            out.append(f"--tp {tp}: " + (
+                kda if plane else mamba if paged else retention).TP_REFUSAL)
         else:
             refuse(f"--tp {tp}", None, (
                 "neither the streams' carry and per-token mixes, "
@@ -1181,7 +1189,7 @@ class ContinuousEngine:
                                             page_size=page_size)
             self._insert = _shared_program(
                 ("insert", self._state, self._hybrid and page_size,
-                 bool(spec.mixers), bool(spec.latent)),
+                 bool(spec.mixers), bool(spec.latent), bool(spec.kda)),
                 lambda: jax.jit(
                     named_program("serve_admit_state_insert" if self._state
                                   else "serve_admit_insert", _insert),
@@ -2803,12 +2811,15 @@ class ContinuousEngine:
                        self._take_chunk_moe())
 
     def _count_layers_run(self) -> None:
-        """An ssd spec's layers a landed decode step ran, by kind."""
-        sd, run = self.spec.ssd, self.stats.layers_run
-        for kind in sd.kinds:
+        """An ssd or a kda spec's layers a landed decode step ran, by kind
+        (a kda spec's latent layers count as "latent")."""
+        kinds = (self.spec.ssd.kinds if self.spec.ssd else tuple(
+            "latent" if k == "full" else k for k in self.spec.latent.kinds))
+        run = self.stats.layers_run
+        for kind in kinds:
             run[kind] = run.get(kind, 0) + 1
         if self._obs is not None:
-            self._obs.record_layers_run(sd.kinds)
+            self._obs.record_layers_run(kinds)
 
     def _queued_ahead(self) -> tuple[int, int]:
         """(admission prefill chunks, admissions with device work) enqueued
@@ -2876,8 +2887,12 @@ class ContinuousEngine:
                 health = np.asarray(flight.norm_min)  # dlint: allow[D001] normaliser counter
                 low = float(health.min())
                 if self.spec.mixers or self.spec.latent:
-                    # (2,): smallest and mean gate
-                    self.stats.count_gate(low, float(health[1]))
+                    # smallest and mean gate (the smallest comes first)
+                    self.stats.count_gate(float(health[0]),
+                                          float(health[1]))
+                    if self.spec.kda:   # and a state's smallest decay
+                        self.stats.ssm_min_decay = min(
+                            self.stats.ssm_min_decay, float(health[2]))
                 elif self._hybrid:
                     self.stats.ssm_min_decay = min(self.stats.ssm_min_decay,
                                                    low)
@@ -2948,7 +2963,7 @@ class ContinuousEngine:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
             with host_phase("serve.census"):
                 self.stats.steps += 1
-                if self.spec.ssd:
+                if self.spec.ssd or self.spec.kda:
                     self._count_layers_run()
                 self.stats.sum_active += active0
                 self.stats.max_active = max(self.stats.max_active, active0)

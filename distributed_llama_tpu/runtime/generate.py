@@ -254,10 +254,15 @@ class Engine:
                 self._ahead = self._launch(step.picked, pos + 1)
         with host_phase("inference.fetch"):  # the wait and the transfer
             if step.norm_min:  # (L,) floats beside the rest
-                low = float(np.asarray(step.norm_min[0]).min())  # dlint: allow[D001] normaliser counter
+                health = np.asarray(step.norm_min[0])  # dlint: allow[D001] normaliser counter
+                low = float(health.min())
                 if self.spec.mixers or self.spec.latent:
-                    # (smallest gate, mean gate)
-                    self.gate_min = min(self.gate_min, low)
+                    # (smallest gate, mean gate[, a kda spec's smallest
+                    # decay])
+                    self.gate_min = min(self.gate_min, float(health[0]))
+                    if self.spec.kda:
+                        self.ssm_min_decay = min(self.ssm_min_decay,
+                                                 float(health[2]))
                 elif self.spec.hybrid or self.spec.ssd:
                     self.ssm_min_decay = min(self.ssm_min_decay, low)
                 else:

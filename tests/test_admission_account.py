@@ -646,6 +646,9 @@ def test_the_six_readers_and_their_entries(family, name):
     listed = ["mistral7b.serve-chat"] if family == "chat_" else SAT_CELLS
     # a later cell whose window holds admissions appends its name
     assert entry["workloads"][:len(listed)] == listed
+    if family == "sat_":    # PR 55's and PR 60's cells, in that order
+        assert entry["workloads"][len(listed):] == [
+            "nemotron3.reason-sat32", "ling3flash.reason-sat32"]
     assert dict(entry, workloads=listed) == {
         "name": family + name, "unit": unit, "better": "lower",
         "source": "program_span", "layer": "scheduler", "moves": moves,
