@@ -74,6 +74,10 @@ small ones first. The pieces stay float32 and the dots run at DEFAULT
 precision, Mosaic's one bf16 pass, which rounds an operand that is a bf16
 number already to itself (PERF.md section 7 has the candidates' times).
 Max, exp and the sums stay on the vector unit, on (n_kv, group, chunk).
+``_dot6`` is the same stack against pieces cut by the caller, in HIGHEST's
+six products: the latent kernels' fold (``pallas_latent_attention.py``: 32
+to 128 query heads over ONE plane, where the nine would load no tile less
+and stream half as many rows again).
 """
 
 from __future__ import annotations
@@ -147,6 +151,25 @@ def _dot9(x3, w, contract: int):
     return ((((at(lo, 2) + (at(lo, 1) + at(mid, 2)))
               + (at(lo, 0) + at(mid, 1) + at(hi, 2)))
              + (at(mid, 0) + at(hi, 1))) + at(hi, 0))
+
+
+def _dot6(x3, pieces, contract: int):
+    """(R, N) float32: the product of x (R, K), handed over as its stacked
+    pieces ``x3`` (3 R, K), and w, handed over as its three ``pieces`` (hi,
+    mid, lo), over their dim ``contract`` (the other is N), in the SIX piece
+    products ``Precision.HIGHEST`` keeps, each piece of w pushed to the MXU
+    ONCE: x3 . hi (hi hi, mid hi, lo hi), [hi; mid] . mid (hi mid, mid
+    mid) and hi . lo. What is left out (mid lo, lo mid, lo lo: 2^-24 of a
+    product and under) is what HIGHEST's six passes leave out too. Each
+    product is of two bf16 numbers, summed over K in float32; the slabs are
+    added in the order of their size, the smallest first."""
+    r = x3.shape[0] // 3
+    dn = (((1,), (contract,)), ((), ()))
+    hi, mid, lo = (jax.lax.dot_general(x3[:n * r], p, dn,
+                                       preferred_element_type=jnp.float32)
+                   for n, p in zip((3, 2, 1), pieces))
+    return (((hi[2 * r:] + mid[r:] + lo) + (hi[r:2 * r] + mid[:r]))
+            + hi[:r])
 
 
 def _fold(q3, k, v, valid, carry, head_size=None):

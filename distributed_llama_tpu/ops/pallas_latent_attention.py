@@ -8,11 +8,40 @@ values (the absorbed schedule: models/latent.py). The paged kernel of
 ops/pallas_paged_attention.py reads a K page and a V page of ``n_kv x hs``
 and folds them a query head at a time on the VPU; at 128 heads over one key
 head that is 128 passes over every page. Here a row's H queries are ONE
-(H, width) matrix: a block of pages lands once, the scores are one MXU dot
-(H, width) x (width, positions), the values another, float32 at HIGHEST.
+(H, width) matrix: a block of pages lands once, the scores are an MXU
+product (H, width) x (width, positions), the values another.
+
+What the fold multiplies (``_fold``). The configuration's precision is
+float32: six bf16 piece products an operation, which HIGHEST precision
+gave until PR 61. The fold forms the SAME six itself: each operand is cut in
+three pieces that ARE bf16 numbers (``pallas_q40._mask_pieces``: a float32
+is their sum exactly; the queries once a grid program, the landed block once
+a turn for both products, the block's weights p once a turn), the small
+operand's pieces are stacked along the rows, and each piece of the block is
+pushed to the MXU once: [hi; mid; lo] . page_hi, [hi; mid] . page_mid,
+hi . page_lo (``pallas_head_major_attention._dot6``), everything HIGHEST's
+six passes add (mid lo, lo mid and lo lo, 2^-24 of a product and under, are
+what it drops too). The pieces stay FLOAT32 and the dots run at DEFAULT
+precision, Mosaic's one bf16 pass, whose rounding of an operand that is a
+bf16 number already is exact; every product is exact in the MXU's float32
+accumulator and the six slabs are added the small ones first. Max, exp, the
+masks and the carry stay on the vector unit in float32. What it bought,
+alone on the chip (PERF.md section 7, PR 61): 9 % at 32 heads, nothing at 80
+and 128. ISSUE 61 reckoned that HIGHEST loads every 128 x 128 tile of the
+block six times and that the loads set the time; the times say its lowering
+loads a tile no oftener than this does (the kernel's time follows the rows
+STREAMED, products x heads, at the four MXUs' full rate: nine products cost
+half as much again as six), and that what is left beside the stream is the
+vector unit's work a TURN whatever the turn holds: the values' six slabs of
+(heads, kv_rank) popped and added, the carry rescaled. So a turn is 256
+positions, two MXU tiles (``BLOCK_POSITIONS``): 12 % off the 128-position
+turn at 80 heads 4,900 deep, 24 % at 32 heads 1,000 deep, 6 to 8 % at 128
+heads, level at 32 heads 300 deep; 512 gives 18 % at depth and LOSES 15 % at
+300 positions, where most of a turn is masked and no copy hides behind a
+fold.
 
 grid = (B,): program b walks row b's live pages through its page-table row,
-``group`` pages a block (128 positions) so that a dot has an MXU's rows,
+``group`` pages a block (256 positions) so that a dot has two MXU tiles,
 double-buffered on ``pallas_attention._flash_walk``. Table entries past a
 row's live pages point at the scrap page; their positions are masked.
 
@@ -34,30 +63,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_attention import _VMEM64_PARAMS, NEG_INF, _flash_walk
+from .pallas_head_major_attention import _dot6, _stack3
+from .pallas_q40 import _mask_pieces
 
-BLOCK_POSITIONS = 128     # positions a block of pages holds
+BLOCK_POSITIONS = 256     # positions a block of pages holds: two MXU tiles
 KERNEL_NAME = "mla_paged_attn_decode"
 RING_KERNEL_NAME = "mla_ring_attn_decode"
 
 
-def _fold(q, page, key_pos, last, carry, kv_rank: int):
-    """One landed block into the running (m, l, o): q (H, W) scaled, page
-    (blk, W) latent rows, key_pos (1, blk) what each row is compared by
-    (a plane's: its position; a ring's: its slot) against ``last``."""
+def _fold(q3, page, key_pos, last, carry, kv_rank: int):
+    """One landed block into the running (m, l, o): q3 (3 H, W) the scaled
+    queries' bf16 pieces [hi; mid; lo], page (blk, W) latent rows, key_pos
+    (1, blk) what each row is compared by (a plane's: its position; a
+    ring's: its slot) against ``last``. Both products are ``_dot6``'s six
+    exact piece products against ONE cut of the block in three (the module
+    docstring)."""
     m_old, l_old, o_old = carry
-    s = jax.lax.dot_general(
-        q, page, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)                # (H, blk)
+    pieces = _mask_pieces(page, 3)
+    s = _dot6(q3, pieces, 1)                                # (H, blk)
     s = jnp.where(key_pos <= last, s, NEG_INF)
     m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_old - m_new)
     l_new = l_old * corr + jnp.sum(p, axis=1, keepdims=True)
-    o_new = o_old * corr + jax.lax.dot_general(
-        p, page[:, :kv_rank], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)                # (H, kv_rank)
+    o_new = o_old * corr + _dot6(
+        _stack3(p), [w[:, :kv_rank] for w in pieces], 0)    # (H, kv_rank)
     return m_new, l_new, o_new
 
 
@@ -76,8 +106,8 @@ def _kernel(layer_ref, pos_ref, table_ref, q_ref, c_hbm, out_ref, buf, sems,
     max_pages = table_ref.shape[1]
     blk = group * page_size
     n_blocks = (pos // page_size) // group + 1
-    q = q_ref[0]
-    n_heads = q.shape[0]
+    n_heads = q_ref.shape[1]
+    q3 = _stack3(q_ref[0])
 
     def copies(slot, i):
         out = []
@@ -99,7 +129,7 @@ def _kernel(layer_ref, pos_ref, table_ref, q_ref, c_hbm, out_ref, buf, sems,
 
     def update(i, slot, carry):
         key_pos = i * blk + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
-        return _fold(q, buf[slot], key_pos, pos, carry, kv_rank)
+        return _fold(q3, buf[slot], key_pos, pos, carry, kv_rank)
 
     _, l_fin, o_fin = _flash_walk(n_blocks, start_dma, wait_dma, update,
                                   _empty(n_heads, kv_rank))
@@ -155,7 +185,7 @@ def _ring_kernel(layer_ref, pos_ref, q_ref, w_ref, out_ref, *, kv_rank: int):
     window = w_ref.shape[1]
     last = jnp.minimum(pos_ref[pl.program_id(0)], window - 1)
     slot = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
-    _, l_fin, o_fin = _fold(q_ref[0], w_ref[0], slot, last,
+    _, l_fin, o_fin = _fold(_stack3(q_ref[0]), w_ref[0], slot, last,
                             _empty(q_ref.shape[1], kv_rank), kv_rank)
     out_ref[0] = o_fin / l_fin
 

@@ -221,6 +221,21 @@ def _latent_paged(ps: int = 16):
              _sd((b,), jnp.int32), _sd((b, SEQ // ps), jnp.int32)))
 
 
+def _latent_ring():
+    """The latent ring kernel at Motif-3-Beta's shape: 32 rows of 80 heads
+    over nine sliding layers' rings of 128 slots of 640 lanes (the fold's
+    stacked pieces are 240, 160 and 80 rows)."""
+    from distributed_llama_tpu.ops.pallas_latent_attention import (
+        latent_ring_decode)
+
+    b, heads, plane = 32, 80, 640
+    return (functools.partial(latent_ring_decode, kv_rank=512,
+                              interpret=False),
+            (_sd((b, heads, plane), jnp.float32),
+             _sd((9 * b, 128, plane), jnp.float32), _sd((), jnp.int32),
+             _sd((b,), jnp.int32)))
+
+
 def _moe(leaf: str, rows: int, model: str = "olmoe"):
     """The routed-expert kernels (ops/pallas_moe) at OLMoE-1B-7B's widths:
     64 experts a layer, ``w13`` (2 x 1024, 2048) and ``w2`` (2048, 1024),
@@ -507,6 +522,8 @@ CASES = {
     # memref<36873x16x640xf32> (the chip stores 576 values in 640 lanes
     # whatever it is told: models/latent.plane_width pads the plane itself)
     "latent-paged-ps16-B32": (_latent_paged, True),
+    # Motif-3-Beta's ring (PR 52) under the fold of exact pieces (PR 61)
+    "latent-ring-w128-H80-B32": (_latent_ring, True),
     # its new leaves at the cell's 32 rows, one row and the 128-row chunk;
     # nb 576 runs under the raised scoped-VMEM limit at T = 1
     **{f"q40-nb-{leaf}-T{t}": (functools.partial(_q40, "nb", leaf, t), True)

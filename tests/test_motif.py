@@ -12,6 +12,7 @@ differs by op order alone (the largest reading here is 3e-6).
 """
 
 import dataclasses
+import functools
 import math
 import types
 
@@ -409,6 +410,106 @@ def test_the_paged_kernel_still_agrees_after_sharing_its_fold(monkeypatch):
         outs.append(latent.paged_decode_attention(spec, ps, P, q, row, c3, 1,
                                                   pos, table)[0])
     assert np.abs(np.asarray(outs[0]) - np.asarray(outs[1])).max() < 2e-5
+
+
+# -- the fold's six exact piece products (PR 61) --------------------------------
+
+def _softmax64(q, rows):
+    """softmax(q . rows^T) rows[:, :rank] in float64; rank from LATENT."""
+    s = q.astype(np.float64) @ rows.astype(np.float64).T
+    p = np.exp(s - s.max(axis=1, keepdims=True))
+    return (p / p.sum(axis=1, keepdims=True)) @ rows[:, :LATENT.kv_rank]
+
+
+def _six_products(heads):
+    """``_dot6`` in both of the fold's contractions (the scores' over the
+    block's last dim, the values' over its first), operands with full
+    24-bit mantissas: level with a float32 product at HIGHEST, far from
+    one bf16 pass, and farther with a product left out."""
+    from distributed_llama_tpu.ops.pallas_head_major_attention import (
+        _dot6, _stack3)
+    from distributed_llama_tpu.ops.pallas_q40 import _mask_pieces
+
+    @functools.partial(jax.jit, static_argnums=2)
+    def products(x, w, contract):
+        x3, pieces = _stack3(x), _mask_pieces(w, 3)
+        dn = (((1,), (contract,)), ((), ()))
+        bf16 = [p.astype(jnp.bfloat16) for p in pieces]
+        return (_dot6(x3, pieces, contract),
+                # without hi . lo
+                _dot6(x3, (*pieces[:2], jnp.zeros_like(w)), contract),
+                jax.lax.dot_general(x, w, dn,
+                                    precision=jax.lax.Precision.HIGHEST),
+                jax.lax.dot_general(x.astype(jnp.bfloat16),
+                                    w.astype(jnp.bfloat16), dn,
+                                    preferred_element_type=jnp.float32),
+                jnp.stack(pieces), jnp.stack(bf16).astype(jnp.float32))
+
+    rng = np.random.default_rng(heads)
+    for contract, k, n in ((1, 40, 24), (0, 24, 32)):
+        x = rng.standard_normal((heads, k)).astype(np.float32)
+        w = rng.standard_normal((n, k) if contract else (k, n)).astype(
+            np.float32)
+        assert (x.view(np.uint32) & 0xFF).any() and (
+            w.view(np.uint32) & 0xFF).any()
+        exact = x.astype(np.float64) @ (w.T if contract else w).astype(
+            np.float64)
+        six, five, highest, one_pass, pieces, as_bf16 = (
+            np.asarray(a) for a in products(x, w, contract))
+        assert np.array_equal(pieces, as_bf16)      # each IS a bf16 number
+        assert np.array_equal(pieces.sum(axis=0), w)
+        far = lambda got: np.abs(got - exact).max()         # noqa: E731
+        assert six.shape == exact.shape
+        # the CPU's HIGHEST is one float32 product (all nine piece products)
+        assert far(six) <= 2.5 * far(highest), (far(six), far(highest))
+        assert far(one_pass) > 100 * far(six)
+        assert far(five) > 10 * far(six)            # 2^-16 of a product off
+
+
+def _paged_blocks():
+    """Rows that end a block's last position, begin the next, lie in a
+    part-filled third block, and in the first alone: pages of 8 positions,
+    32 a block."""
+    rng = np.random.default_rng(61)
+    B, ps, width, rank = 4, 8, latent.plane_width(SPEC), LATENT.kv_rank
+    pages = 80
+    n_pages = B * pages + 1
+    q = rng.standard_normal((B, 10, width)).astype(np.float32)
+    c3 = rng.standard_normal((2 * n_pages, ps, width)).astype(np.float32)
+    table = rng.permutation(np.arange(1, n_pages)).reshape(B, pages).astype(
+        np.int32)
+    blk = pla.BLOCK_POSITIONS
+    pos = np.asarray([blk - 1, blk, 2 * blk + 88, 5], np.int32)
+    got = np.asarray(pla.latent_paged_decode(
+        q, c3, 1, pos, table, page_size=ps, n_pages=n_pages, kv_rank=rank,
+        interpret=True))
+    for b in range(B):
+        plane = c3[n_pages + table[b]].reshape(-1, width)[:pos[b] + 1]
+        assert np.abs(got[b] - _softmax64(q[b], plane)).max() < 1e-5, b
+
+
+def _ring_wraps():
+    """Rings of 8 slots before they wrap (slots past ``pos`` unseen), at
+    the wrap and far past it."""
+    rng = np.random.default_rng(62)
+    B, window, width = 4, LATENT.window, latent.plane_width(SPEC)
+    q = rng.standard_normal((B, 10, width)).astype(np.float32)
+    w3 = rng.standard_normal((3 * B, window, width)).astype(np.float32)
+    pos = np.asarray([0, 3, window, 5 * window + 3], np.int32)
+    got = np.asarray(pla.latent_ring_decode(q, w3, 1, pos,
+                                            kv_rank=LATENT.kv_rank,
+                                            interpret=True))
+    for b in range(B):
+        seen = w3[B + b, :min(pos[b], window - 1) + 1]
+        assert np.abs(got[b] - _softmax64(q[b], seen)).max() < 1e-5, b
+
+
+@pytest.mark.parametrize("check", [
+    *(functools.partial(_six_products, h) for h in (32, 80, 128)),
+    _paged_blocks, _ring_wraps],
+    ids=["six-H32", "six-H80", "six-H128", "paged-blocks", "ring-wraps"])
+def test_the_fold_keeps_highests_six_products(check):
+    check()
 
 
 # -- the shares add up -------------------------------------------------------------
