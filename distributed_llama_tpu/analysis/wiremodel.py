@@ -695,6 +695,8 @@ METRIC_FAMILIES: dict[str, MetricFamily] = {m.name: m for m in (
     _m("dllama_moe_slots_total"),
     _m("dllama_moe_single_row_slots_total"),
     _m("dllama_moe_diag_slots_total"),
+    # landed steps whose dense Q40 leaves took the stacked body
+    _m("dllama_dense_diag_steps_total"),
     _m("dllama_moe_chunk_pairs_total"),
     _m("dllama_moe_chunk_slots_total"),
     _m("dllama_latent_pages_in_use"),

@@ -211,6 +211,11 @@ class EngineMetrics:
             "Of those, slots that took the kernel's block-diagonal body (1 "
             "or 2 live rows on leaves whose block count is a multiple of 8; "
             "fuller slots run the MXU tile)")
+        self.dense_diag_steps = c(
+            "dllama_dense_diag_steps_total",
+            "Landed decode steps whose dense Q40 matmuls took the stacked "
+            "block-diagonal body (1 or 2 live rows of a dispatch of up to "
+            "8) and not the 8-row MXU tile")
         self.moe_chunk_pairs = c(
             "dllama_moe_chunk_pairs_total",
             "Routed pairs that landed on held experts in admission prefill "

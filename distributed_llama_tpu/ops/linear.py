@@ -64,6 +64,38 @@ def matmul_mode() -> str:
     return _MATMUL_MODE.get()
 
 
+# Which rows of the decode dispatch being TRACED are live (a trace-time
+# context like the mode above, but it holds data of the program: the rows a
+# step's staged block marks). The step program sets it around its forward
+# (runtime/continuous._with_pick); a Q40 call on exactly those rows reads it
+# (ops/pallas_q40._q40_matmul_nbmajor picks its body by the live count). A
+# forward traced outside it (the chain, verify, a chunk, ``inference``, a
+# mesh's program) traces what it always did.
+_LIVE_ROWS = contextvars.ContextVar("dllama_live_rows", default=None)
+
+
+@contextlib.contextmanager
+def live_rows(mask: jax.Array):
+    """Trace the forward of a decode dispatch whose rows ``mask`` (B,)
+    marks live (> 0) or dead: a free, paused or cancelled row, whose result
+    nobody reads. The census is taken once, here."""
+    from .pallas_q40 import live_census
+
+    token = _LIVE_ROWS.set((mask.shape[0], live_census(mask)))
+    try:
+        yield
+    finally:
+        _LIVE_ROWS.reset(token)
+
+
+def dispatch_live_rows(t: int):
+    """``live_census`` of the dispatch being traced if its forward gave one
+    and it has ``t`` rows (a call on anything else is not on those rows);
+    else None."""
+    got = _LIVE_ROWS.get()
+    return got[1] if got is not None and got[0] == t else None
+
+
 def bf16_prefill(fn):
     """Wrap a forward so it TRACES under bf16 matmul precision — THE one
     fast-prefill wrapper (Engine and ContinuousEngine both build their

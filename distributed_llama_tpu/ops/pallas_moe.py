@@ -107,9 +107,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..obs.spans import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTER
 from .linear import (StackedQ40, ffn_activation, gated_product, matmul,
                      matmul_mode, silu)
-from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _T1_CHUNK, _T1_GROUP,
-                         _VMEM64_PARAMS, NJ, _diag_planes_nb, _mask_pieces,
-                         _planes_dot)
+from .pallas_q40 import (_MATMUL_ROWSXNB_CAP, _T1_GROUP, _VMEM64_PARAMS, NJ,
+                         _diag_planes, _diag_product, _diag_scratch,
+                         _mask_pieces, _planes_dot)
 
 MOE_SLOT_ROWS = 8                # rows of a narrow dispatch's slot: one sublane tile
 MOE_SLOT_T_MAX = 32              # widest dispatch whose slots are one such tile
@@ -261,69 +261,15 @@ def build_slots(topi: jax.Array, n_experts: int, cap: int):
 
 def _diag_body(qs_ref, s_ref, out_ref, l_scr, xs_scr):
     """A slot's first ``top`` rows against one row tile: the dense T = 1
-    matvec's block-diagonal MXU product (ops/pallas_q40's section comment,
-    PR 49) with the rows STACKED in the left-hand side. qs_ref (NJ, nb, R)
-    uint8 codes, s_ref (nb, R) f32 scales; l_scr (nb / 8, 24 top, 256) the
-    rows' block-diagonal planes, 24 left-hand rows a row of the slot, and
-    xs_scr (top, nb, 1) their block sums (``_diag_planes``). In a group's
-    turn the 32 blocks' codes are unpacked once and a group of 8 blocks
-    meets ONE dot of 24 ``top`` left-hand rows; the ``- 8`` fold and the
-    scale are applied to a block's (8, R) product. Exact in float32, as
-    ``_row_body``. Writes rows 0 to ``top`` of ``out_ref``; a row past the
-    slot's live ones reads the planes an earlier slot left and is never
-    read back (a row of the product depends on its own planes alone)."""
-    nb, r = s_ref.shape
-    top = xs_scr.shape[0]
-    f32 = jnp.float32
-    dn = (((1,), (0,)), ((), ()))
-    g = _T1_GROUP
-
-    def turn(start, blocks, g0, acc):
-        # whole planes at a time: an operation traced is set-up time on
-        # every run (ops/pallas_q40._matvec_body_nb_mxu's turn, the rows'
-        # accumulators as one (top, 8, R) value for the same reason)
-        q = qs_ref[:, pl.ds(start, blocks), :].astype(jnp.int32)
-        codes = jnp.concatenate([(q & 0xF).astype(f32),
-                                 (q >> 4).astype(f32)])  # (32, blocks, R)
-        for k in range(blocks // g):
-            rhs = jax.lax.slice_in_dim(codes, k * g, (k + 1) * g, axis=1)
-            p = jax.lax.dot_general(
-                l_scr[g0 + k], rhs.reshape(2 * NJ * g, r), dn,
-                preferred_element_type=f32).reshape(top, 3, g, r)
-            b = pl.ds(start + k * g, g)
-            blk = (p[:, 2] + p[:, 1]) + p[:, 0]         # small pieces first
-            acc = acc + (blk - xs_scr[:, b, :]) * s_ref[b, :]
-        return acc
-
-    full, tail = divmod(nb, _T1_CHUNK)
-    acc = jnp.zeros((top, g, r), f32)
-    if full:
-        acc = jax.lax.fori_loop(
-            0, full, lambda c, acc: turn(
-                pl.multiple_of(c * _T1_CHUNK, _T1_CHUNK), _T1_CHUNK, c * 4,
-                acc), acc)
-    if tail:
-        acc = turn(full * _T1_CHUNK, tail, full * 4, acc)
-    out_ref[0:top, :] = jnp.sum(acc, axis=1)
-
-
-def _diag_planes(x_ref, l_scr, xs_scr, sum_scr, rows):
-    """Build the block-diagonal planes and block sums of a slot's ``rows``
-    live rows (data) from the rows as they are, x_ref (top, n / 128, 128),
-    into ``l_scr`` / ``xs_scr`` (``_diag_body``), a row a turn of ONE loop
-    (``ops/pallas_q40._diag_planes_nb``, as the dense matvec builds its
-    own: traced once whatever the slot holds)."""
-    nb = xs_scr.shape[1]
-
-    def build(t, carry):
-        lhs = l_scr.at[:, pl.ds(pl.multiple_of(24 * t, 8), 24), :]
-        _diag_planes_nb(x_ref.at[t], lhs, sum_scr, nb)
-        # (the chip's compiler refuses a VIEW of a one-lane buffer at a row
-        # that is data: the sums land in a row's worth and are copied)
-        xs_scr[t] = sum_scr[...]
-        return carry
-
-    jax.lax.fori_loop(0, rows, build, 0)
+    matvec's block-diagonal MXU product (ops/pallas_q40's section comments,
+    PR 49) with the rows STACKED in the left-hand side
+    (``ops/pallas_q40._diag_product``, which a part-filled dense dispatch
+    runs too), its planes and block sums built a slot by
+    ``ops/pallas_q40._diag_planes``. Exact in float32, as ``_row_body``.
+    Writes rows 0 to ``top`` of ``out_ref``; a row past the slot's live
+    ones reads the planes an earlier slot left and is never read back."""
+    out_ref[0:xs_scr.shape[0], :] = _diag_product(qs_ref, s_ref, l_scr,
+                                                  xs_scr)
 
 
 def _row_body(qs_ref, s, xp_ref, out_ref):
@@ -464,9 +410,7 @@ def moe_q40_slots(layer, slot_expert, n_slots, fill, qs_t, scale, xs,
     if top:
         raw = xs.astype(jnp.float32).reshape(*xs.shape[:-1], nb // 4, 128)
         planes.append(raw if rows is None else raw[rows[:, :top]])
-        scratch = [pltpu.VMEM((nb // 8, 24 * top, 256), jnp.float32),
-                   pltpu.VMEM((top, nb, 1), jnp.float32),
-                   pltpu.VMEM((nb, 1), jnp.float32)]
+        scratch = _diag_scratch(nb, top)
     else:
         planes.append(_row_planes(xs[:, 0], nb) if rows is None
                       else _row_planes(xs, nb)[rows[:, 0]])

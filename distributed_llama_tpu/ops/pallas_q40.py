@@ -41,7 +41,8 @@ code once against the row's three pieces laid block-diagonal and scales a
 block's product (``_matvec_body_nb_mxu``, PR 49: level with its tile's
 DMAs on Mistral-7B's ``w13`` and 9 to 19 % over them on a layer's other
 leaves, 72 % of the HBM roofline over the step where the vector body read
-63), a wider one
+63); a dispatch of up to 8 rows of which one or two are LIVE runs the same
+product with those rows stacked (``_q40_live_nb``, PR 63); a wider one
 dequantizes the tile and multiplies its two pieces by the rows' three
 (``_five_pass_dot``, PR 38), G nibble planes to a dot so that the
 contraction is whole 128-deep MXU pushes (``_pick_planes``, PR 51: G from
@@ -310,6 +311,90 @@ def _kernel_matvec_nb_mxu_stacked(layer_ref, qs_ref, scale_ref, x_ref,
                         xs_scr)
 
 
+# -- the same product with a few rows STACKED in the left-hand side -------------
+#
+# A dispatch that is compiled for 8 rows and carries one or two (an expert's
+# part-filled slot, ops/pallas_moe, PR 54; a part-filled dense decode
+# dispatch, ``_live_nb_call`` below, PR 63) pays the T > 1 tile for all 8:
+# the tile is bound by its unpack and its time does not depend on the rows
+# it carries. The block-diagonal product above serves them: each row's
+# planes take 24 left-hand rows of a group's ONE dot, the codes are
+# unpacked once for all of them, and the fold and the scale are applied to
+# a block's (8, R) product a row.
+
+def _diag_product(qs_ref, s_ref, l_scr, xs_scr):
+    """(top, R) float32: ``top`` stacked rows against one row tile.
+    qs_ref (NJ, nb, R) uint8 codes, s_ref (nb, R) f32 scales; l_scr
+    (nb / 8, 24 top, 256) the rows' block-diagonal planes, 24 left-hand
+    rows a stacked row, and xs_scr (top, nb, 1) their block sums
+    (``_diag_planes``). In a group's turn the 32 blocks' codes are unpacked
+    once and a group of 8 blocks meets ONE dot of 24 ``top`` left-hand rows;
+    the ``- 8`` fold and the scale are applied to a block's (8, R) product.
+    Exact in float32. A stacked row past the live ones reads the planes an
+    earlier call or slot left: a row of the product depends on its own
+    planes alone, and the caller does not read it back."""
+    nb, r = s_ref.shape
+    top = xs_scr.shape[0]
+    f32 = jnp.float32
+    dn = (((1,), (0,)), ((), ()))
+    g = _T1_GROUP
+
+    def turn(start, blocks, g0, acc):
+        # whole planes at a time: an operation traced is set-up time on
+        # every run (``_matvec_body_nb_mxu``'s turn; the rows' accumulators
+        # as one (top, 8, R) value for the same reason)
+        q = qs_ref[:, pl.ds(start, blocks), :].astype(jnp.int32)
+        codes = jnp.concatenate([(q & 0xF).astype(f32),
+                                 (q >> 4).astype(f32)])  # (32, blocks, R)
+        for k in range(blocks // g):
+            rhs = jax.lax.slice_in_dim(codes, k * g, (k + 1) * g, axis=1)
+            p = jax.lax.dot_general(
+                l_scr[g0 + k], rhs.reshape(2 * NJ * g, r), dn,
+                preferred_element_type=f32).reshape(top, 3, g, r)
+            b = pl.ds(start + k * g, g)
+            blk = (p[:, 2] + p[:, 1]) + p[:, 0]         # small pieces first
+            acc = acc + (blk - xs_scr[:, b, :]) * s_ref[b, :]
+        return acc
+
+    full, tail = divmod(nb, _T1_CHUNK)
+    acc = jnp.zeros((top, g, r), f32)
+    if full:
+        acc = jax.lax.fori_loop(
+            0, full, lambda c, acc: turn(
+                pl.multiple_of(c * _T1_CHUNK, _T1_CHUNK), _T1_CHUNK, c * 4,
+                acc), acc)
+    if tail:
+        acc = turn(full * _T1_CHUNK, tail, full * 4, acc)
+    return jnp.sum(acc, axis=1)
+
+
+def _diag_planes(x_ref, l_scr, xs_scr, sum_scr, rows, row_of=lambda k: k):
+    """Build the block-diagonal planes and block sums of the first ``rows``
+    stacked rows (data) into ``l_scr`` / ``xs_scr`` (``_diag_product``),
+    stacked row k from row ``row_of(k)`` of x_ref (.., n / 128, 128) as it
+    is; a row a turn of ONE loop (``_diag_planes_nb``, as the T = 1 matvec
+    builds its own: traced once whatever rides)."""
+    nb = xs_scr.shape[1]
+
+    def build(k, carry):
+        lhs = l_scr.at[:, pl.ds(pl.multiple_of(24 * k, 8), 24), :]
+        _diag_planes_nb(x_ref.at[row_of(k)], lhs, sum_scr, nb)
+        # (the chip's compiler refuses a VIEW of a one-lane buffer at a row
+        # that is data: the sums land in a row's worth and are copied)
+        xs_scr[k] = sum_scr[...]
+        return carry
+
+    jax.lax.fori_loop(0, rows, build, 0)
+
+
+def _diag_scratch(nb: int, top: int):
+    """Scratch of ``top`` stacked rows: planes, block sums, one row's sums."""
+    return [pltpu.VMEM((nb // _T1_GROUP, 3 * _T1_GROUP * top,
+                        QK * _T1_GROUP), jnp.float32),
+            pltpu.VMEM((top, nb, 1), jnp.float32),
+            pltpu.VMEM((nb, 1), jnp.float32)]
+
+
 # -- the T>1 tile's dot: five bf16 passes, exact on what a Q40 weight holds ---
 #
 # A Q40 weight is code x scale, code an integer in [-8, 7] and scale a
@@ -349,8 +434,8 @@ def _mask_pieces(x: jax.Array, n: int):
     for _ in range(n - 1):
         bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
         pieces.append(jax.lax.bitcast_convert_type(
-            bits & jnp.uint32(0xFFFF0000), jnp.float32))
-        x = x - pieces[-1]
+            jax.lax.bitwise_and(bits, jnp.uint32(0xFFFF0000)), jnp.float32))
+        x = jax.lax.sub(x, pieces[-1])
     return (*pieces, x)
 
 
@@ -383,11 +468,17 @@ def _five_pass_dot(x3: jax.Array, w: jax.Array, rows: int) -> jax.Array:
     terms first."""
     dn = (((1,), (0,)), ((), ()))
     w_hi, w_lo = _mask_pieces(w, 2)
+    add = jax.lax.add
+
+    def cut(m, lo, hi):      # rows lo..hi (lax: an operator or an index is
+        return jax.lax.slice(m, (lo, 0), (hi, m.shape[1]))   # a jitted call)
+
     a = jax.lax.dot_general(x3, w_hi, dn, preferred_element_type=jnp.float32)
-    b = jax.lax.dot_general(x3[:2 * rows], w_lo, dn,
+    b = jax.lax.dot_general(cut(x3, 0, 2 * rows), w_lo, dn,
                             preferred_element_type=jnp.float32)
-    return ((a[2 * rows:3 * rows] + b[rows:])
-            + (a[rows:2 * rows] + b[:rows])) + a[:rows]
+    return add(add(add(cut(a, 2 * rows, 3 * rows), cut(b, rows, 2 * rows)),
+                   add(cut(a, rows, 2 * rows), cut(b, 0, rows))),
+               cut(a, 0, rows))
 
 
 def _planes_dot(q, s, xlo, xhi, rows: int, bf16: bool) -> jax.Array:
@@ -405,15 +496,19 @@ def _planes_dot(q, s, xlo, xhi, rows: int, bf16: bool) -> jax.Array:
     g, nb, r = q.shape
     dn = (((1,), (0,)), ((), ()))
     acc = None
-    for x, codes in ((xlo, q & 0xF), (xhi, q >> 4)):
-        w = ((codes - 8).astype(jnp.float32) * s[None]).reshape(g * nb, r)
+    lax = jax.lax   # (primitives: an operator on a tracer is a jitted call,
+    #                  traced on every run; the jaxpr is the operators')
+    for x, codes in ((xlo, lax.bitwise_and(q, 0xF)),
+                     (xhi, lax.shift_right_arithmetic(q, 4))):
+        w = lax.mul(lax.convert_element_type(lax.sub(codes, 8), jnp.float32),
+                    s[None]).reshape(g * nb, r)
         if bf16:
             a = jax.lax.dot_general(x.astype(jnp.bfloat16),
                                     w.astype(jnp.bfloat16), dn,
                                     preferred_element_type=jnp.float32)
         else:
             a = _five_pass_dot(x, w, rows)
-        acc = a if acc is None else acc + a
+        acc = a if acc is None else lax.add(acc, a)
     return acc
 
 
@@ -438,7 +533,7 @@ def _matmul_body_nb(qs3, s, xlo_ref, xhi_ref, out_ref, bf16=False):
     for k in range(NJ // g):
         q = qs3[k * g:(k + 1) * g].astype(jnp.int32)     # (G, nb, R)
         a = _planes_dot(q, s, xlo_ref[k], xhi_ref[k], bt, bf16)
-        acc = a if acc is None else acc + a
+        acc = a if acc is None else jax.lax.add(acc, a)
     out_ref[...] = acc
 
 
@@ -453,7 +548,9 @@ def _kernel_mxu_nb_stacked(layer_ref, qs_ref, scale_ref, xlo_ref, xhi_ref,
     _matmul_body_nb(qs_ref[0], scale_ref[0], xlo_ref, xhi_ref, out_ref, bf16)
 
 
-# Where the bodies meet, chosen by T alone (what a dispatch observes).
+# Where the bodies meet, chosen by T (what a dispatch observes; an nb-major
+# dispatch of 2..8 rows that is told its live rows picks by them as well:
+# ``_q40_matmul_nbmajor``).
 # d-major leaves: the VPU multi body (one accumulator a row) up to
 # MULTI_T_MAX rows, the MXU body beyond, where the per-row accumulators
 # crowd VMEM. nb-major leaves: the matvec at T = 1 and the MXU body
@@ -970,6 +1067,114 @@ def _q40_matvec_nb_stacked(layer, qs_t, scale, x, *, block_rows, interpret):
     return _matvec_nb_call(layer, qs_t, scale, x, block_rows, interpret)
 
 
+# -- a part-filled decode dispatch: the body picked by its LIVE rows -----------
+#
+# A decode dispatch of 2 to MULTI_T_MAX rows is compiled for all of them and
+# often carries one or two (a chat server's pool: 1.65 rows a dispatch in
+# ``mistral7b.serve-chat``). Which rows ride is data the step program holds
+# (``ops/linear.live_rows``: the staged block's last column), so where the
+# forward says so the dispatch below picks by it, as the expert slot kernel
+# picks by a slot's fill: up to ``LIVE_ROWS_MAX`` live rows run the stacked
+# block-diagonal product above on THOSE rows (``_q40_live_nb``), a fuller
+# dispatch the tile as ever. One ``lax.cond`` a call, so that each body
+# keeps its own row tile and the tile's x planes are cut only where it runs
+# (PERF.md section 7 has the one-leaf table that chose it).
+
+LIVE_ROWS_MAX = 2   # fullest dispatch of the stacked body: ONE body stacks two
+
+
+def live_census(mask: jax.Array, top: int = LIVE_ROWS_MAX) -> jax.Array:
+    """(1 + top,) int32 of a dispatch whose rows ``mask`` (B,) marks live:
+    how many are, and the indices of the first ``top`` of them in row order
+    (past the live count: the first live row's again, a row that exists)."""
+    alive = mask.reshape(-1) > 0
+    count = jnp.sum(alive)
+    rank = jnp.cumsum(alive) * alive                 # 1.. on the live rows
+    idx = [jnp.argmax(rank == k + 1) for k in range(top)]
+    return jnp.stack([count] + [jnp.where(k < count, i, idx[0])
+                                for k, i in enumerate(idx)]).astype(jnp.int32)
+
+
+def live_rows_top(t: int, d: int, nb: int) -> int:
+    """Most live rows of a ``t``-row dispatch that take the stacked body on
+    an nb-major leaf (d, nb blocks a row); 0: the dispatch never does (one
+    row, more than ``MULTI_T_MAX``, a block count off the 8 grid or a ``d``
+    no row tile places). What the call itself sees decides, and the engine's
+    counter asks the same question (runtime/continuous)."""
+    if not 1 < t <= MULTI_T_MAX or not _t1_mxu(nb):
+        return 0
+    return LIVE_ROWS_MAX if _pick_rows_t1(d, nb) else 0
+
+
+def _kernel_live_nb(*refs, stacked: bool):
+    """One row tile of a part-filled dispatch: live_ref (1 + top,) = [live
+    count | the live rows' indices]; x_ref (t, n / 128, 128) ALL the
+    dispatch's rows as they are, of which the live ones are read by index;
+    out (t, R): a live row's product at its index, zeros everywhere else
+    (nothing unspecified leaves the call)."""
+    if stacked:
+        _, live_ref, qs_ref, s_ref, x_ref, out_ref, l_scr, xs_scr, sum_scr = \
+            refs
+        qs_ref, s_ref = qs_ref.at[0], s_ref.at[0]
+    else:
+        live_ref, qs_ref, s_ref, x_ref, out_ref, l_scr, xs_scr, sum_scr = refs
+    count = live_ref[0]
+
+    # at the call's first row tile, for its later ones to read
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        _diag_planes(x_ref, l_scr, xs_scr, sum_scr, count,
+                     lambda k: live_ref[1 + k])
+
+    rows = _diag_product(qs_ref, s_ref, l_scr, xs_scr)           # (top, R)
+    at = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    out = jnp.zeros(out_ref.shape, jnp.float32)
+    for k in range(rows.shape[0]):
+        out = jnp.where((at == live_ref[1 + k]) & (count > k),
+                        rows[k:k + 1], out)
+    out_ref[...] = out
+
+
+def _live_nb_call(live, layer, qs_t, scale, x, block_rows, interpret):
+    """The part-filled dispatch's ``pallas_call``: ``live`` (1 + top,)
+    ``live_census`` of the rows of ``x`` (t, n), ``top`` read off its
+    length; a 2-D leaf (``layer`` None) or one layer of a stack."""
+    nb, d = qs_t.shape[-2:]
+    t, top = x.shape[0], live.shape[0] - 1
+    pre = (live,) if layer is None else (layer, live)
+
+    def tile(*shape):        # a row tile of the leaf (of the picked layer)
+        return pl.BlockSpec(
+            (1,) * (len(pre) - 1) + shape + (block_rows,),
+            lambda i, *S: (*(l[0] for l in S[:-1]), *(0,) * len(shape), i))
+
+    return pl.pallas_call(
+        functools.partial(_kernel_live_nb, stacked=layer is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(pre), grid=(d // block_rows,),
+            in_specs=[tile(NJ, nb), tile(nb),
+                      pl.BlockSpec((t, nb // 4, 128),
+                                   lambda i, *S: (0, 0, 0))],
+            out_specs=pl.BlockSpec((t, block_rows), lambda i, *S: (0, i)),
+            scratch_shapes=_diag_scratch(nb, top)),
+        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
+        compiler_params=_VMEM64_PARAMS, interpret=interpret,
+    )(*pre, qs_t, scale, x.astype(jnp.float32).reshape(t, nb // 4, 128))
+
+
+# a capture finds these two by ``q40`` in their names, as it does the tile
+# and the matvec (benchmark/harness/reduce_trace.classify)
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _q40_live_nb_2d(live, qs_t, scale, x, *, block_rows, interpret):
+    return _live_nb_call(live, None, qs_t, scale, x, block_rows, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _q40_live_nb_stacked(live, layer, qs_t, scale, x, *, block_rows,
+                         interpret):
+    return _live_nb_call(live, layer, qs_t, scale, x, block_rows, interpret)
+
+
 # Nibble planes one dot of the T > 1 tile may contract over: divisors of NJ.
 _PLANES = (1, 2, 4, 8, 16)
 
@@ -1116,8 +1321,10 @@ def _q40_mxu_nb_stacked(layer, qs_t, scale, x, *, block_rows, block_t,
 def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
                         interpret: bool | None,
                         layer: jax.Array | None) -> jax.Array:
-    """nb-major dispatch: every T on a kernel, the body picked by T alone
-    and, at T = 1, by whether the leaf's block count is a multiple of 8.
+    """nb-major dispatch: every T on a kernel, the body picked by T, at
+    T = 1 by whether the leaf's block count is a multiple of 8, and at 2 to
+    ``MULTI_T_MAX`` rows by the dispatch's LIVE rows where the forward being
+    traced told them (``ops/linear.live_rows``; since PR 63).
     T = 1 the matvec: the MXU body (``_matvec_body_nb_mxu``: raw codes
     pushed once against the row's three bf16 pieces laid block-diagonal,
     the scale applied a block) where ``_t1_mxu(nb)``, which is every leaf
@@ -1132,9 +1339,18 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
     the body a 16-row dispatch and a prefill chunk run. How many nibble
     planes one dot contracts over is ``_pick_planes(nb, block_t)``'s, the
     row tile ``_pick_rows_mxu``'s: still ONE Pallas call a leaf, under the
-    same two names. Dequantize-then-dot serves a chunk traced under bf16
-    precision (see q40_matmul) and a ``d`` the row tiler cannot place."""
-    from .linear import matmul_mode
+    same two names. That tile's time does not depend on the rows it
+    carries, so a dispatch of 2 to ``MULTI_T_MAX`` rows whose forward said
+    which of them ride (the serving step program: a pool is seldom full)
+    holds a second call beside it under one ``lax.cond`` on the live count,
+    which is data: 1 to ``LIVE_ROWS_MAX`` live rows run the T = 1 product
+    with those rows stacked (``_q40_live_nb``: the live rows read by index,
+    every dead row written as zeros), anything fuller the tile. A call
+    that is told nothing, or told of other rows than it is given, traces
+    what it always traced. Dequantize-then-dot serves a chunk traced under
+    bf16 precision (see q40_matmul) and a ``d`` the row tiler cannot
+    place."""
+    from .linear import dispatch_live_rows, matmul_mode
 
     qs_t, scale = w.qs_t, w.scale
     nb, d = qs_t.shape[-2], qs_t.shape[-1]
@@ -1146,12 +1362,16 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
         # a leaf packed with zero blocks past its input width
         # (ops/linear.Q40Layout.pad_blocks): zeros meet them
         x2 = jnp.pad(x2, ((0, 0), (0, nb * QK - x2.shape[-1])))
+    given = x2.shape[0]
+    # what the forward being traced said of THESE rows, if it said anything
+    live = dispatch_live_rows(given) if live_rows_top(given, d, nb) else None
+    if given > 1 and given % 8 != 0:
+        x2 = jnp.pad(x2, ((0, (-given) % 8), (0, 0)))
     t = x2.shape[0]
-    if t > 1 and t % 8 != 0:
-        pad = (-t) % 8
-        out = _q40_matmul_nbmajor(w, jnp.pad(x2, ((0, pad), (0, 0))),
-                                  interpret, layer)
-        return out[:t].reshape(*lead, d)
+
+    def rows_of(out):        # the rows the caller gave, in its shape
+        return (out if t == given else out[:given]).reshape(*lead, d)
+
     bf16 = matmul_mode() == "bf16"
     block_t = _pick_block_t(t, nb)
     planes = 1
@@ -1165,31 +1385,30 @@ def _q40_matmul_nbmajor(w: Q40KernelNb, x: jax.Array,
         planes = _pick_planes(nb, block_t)
         rows = _pick_rows_mxu(d, nb, block_t, planes)
     if rows is not None:
-        if layer is not None:
-            lidx = jnp.asarray(layer, dtype=jnp.int32).reshape(1)
-            if t == 1:
-                out = _q40_matvec_nb_stacked(lidx, qs_t, scale, x2,
-                                             block_rows=rows,
-                                             interpret=interpret)
-            else:
-                out = _q40_mxu_nb_stacked(lidx, qs_t, scale, x2,
-                                          block_rows=rows, block_t=block_t,
-                                          interpret=interpret, bf16=bf16,
-                                          planes=planes)
+        pre = () if layer is None else (
+            jnp.asarray(layer, dtype=jnp.int32).reshape(1),)
+        if t == 1:
+            call = _q40_matvec_nb_stacked if pre else _q40_matvec_nb_2d
+            return rows_of(call(*pre, qs_t, scale, x2, block_rows=rows,
+                                interpret=interpret))
+        tile = functools.partial(
+            _q40_mxu_nb_stacked if pre else _q40_mxu_nb_2d, block_rows=rows,
+            block_t=block_t, interpret=interpret, bf16=bf16, planes=planes)
+        if live is None:
+            out = tile(*pre, qs_t, scale, x2)
         else:
-            if t == 1:
-                out = _q40_matvec_nb_2d(qs_t, scale, x2, block_rows=rows,
-                                        interpret=interpret)
-            else:
-                out = _q40_mxu_nb_2d(qs_t, scale, x2, block_rows=rows,
-                                     block_t=block_t, interpret=interpret,
-                                     bf16=bf16, planes=planes)
-        return out.reshape(*lead, d)
+            few = functools.partial(
+                _q40_live_nb_stacked if pre else _q40_live_nb_2d,
+                block_rows=_pick_rows_t1(d, nb), interpret=interpret)
+            out = jax.lax.cond(
+                (live[0] >= 1) & (live[0] < live.shape[0]), few,
+                lambda lv, *ops: tile(*ops), live, *pre, qs_t, scale, x2)
+        return rows_of(out)
     if layer is not None:
         qs_t = qs_t[layer]
         scale = scale[layer]
     wf = _dequant_nb(qs_t, scale)
-    return _precision_dot(wf, x2).reshape(*lead, d)
+    return rows_of(_precision_dot(wf, x2))
 
 
 def _dequant_i4(w: Q40KernelNbI4) -> jax.Array:
@@ -1291,16 +1510,21 @@ def q40_matmul(w: Q40Kernel | Q40KernelNb | Q40KernelNbI4 | Q40Weight,
     (L, 16, d, nb)) and the kernel DMAs layer ``layer`` directly out of the
     stack via scalar prefetch — the zero-copy path for lax.scan over layers.
 
-    The body is picked by the leaf's layout and T alone, each with a
-    ``_2d`` and a ``_stacked`` wrapper; the row tile by the pickers:
+    The body is picked by the leaf's layout and T, each with a ``_2d`` and
+    a ``_stacked`` wrapper; the row tile by the pickers:
 
     ============== ================== ============== ================
     leaf           T = 1              2..8 rows      more rows
     ============== ================== ============== ================
-    Q40KernelNb    _q40_matvec_nb     _q40_mxu_nb    _q40_mxu_nb
+    Q40KernelNb    _q40_matvec_nb     _q40_mxu_nb(*) _q40_mxu_nb
     Q40KernelNbI4  _q40_matvec_nb_i4  dequant + dot  dequant + dot
     Q40Kernel      _kernel_matvec     _kernel_multi  _kernel
     ============== ================== ============== ================
+
+    (*) where the forward being traced told which of the rows are live
+    (``ops/linear.live_rows``: the serving step program), ``_q40_live_nb``
+    on 1 or 2 live rows and the tile on more, picked on the device by the
+    live count (``_q40_matmul_nbmajor``).
 
     ``_q40_matvec_nb`` is two bodies under one name, picked by the leaf's
     block count alone (``_t1_mxu``): the MXU matvec (raw codes against the

@@ -431,29 +431,58 @@ class _Flight:
                 if s is not None and s.req is r]
 
 
-def _with_pick(step, paged: bool, vocab: int, state: bool = False):
+def _with_pick(step, paged: bool, vocab: int, state: bool = False,
+               tell_live: bool = True):
     """``step_once``'s program around a decode forward ``step`` (logits,
     cache[, moe counts]): the row inputs arrive as ONE staged int32 block
-    (B, 2[ + pages]) = [override | pos | page table], split here, and a
-    row's input token is its override or, where that is -1, the previous
-    step's pick, which never left the device. Beside the forward's results
-    it returns ``picked``, the argmax of each row's logits (lowest index
-    on a tie, as the host's ``sample_argmax``). A ``state`` engine's block
-    ends in one more column, [override | pos | (page table) | takes part]:
-    a row that does not leaves its state as it is."""
+    (B, 3[ + pages]) = [override | pos | page table | live], split here,
+    and a row's input token is its override or, where that is -1, the
+    previous step's pick, which never left the device. Beside the forward's
+    results it returns ``picked``, the argmax of each row's logits (lowest
+    index on a tie, as the host's ``sample_argmax``). The last column says
+    which rows ride (a free, paused or cancelled row is dead: nobody reads
+    its result): a ``state`` engine's forward takes it as an argument (a
+    row that does not take part leaves its state as it is), and every
+    forward is traced knowing it (``ops/linear.live_rows``: a Q40 call on a
+    part-filled dispatch picks its body by the live rows) unless
+    ``tell_live`` is False (a mesh's program, whose forward is traced
+    inside ``shard_map``)."""
     def run(params, cache, prev_picked, blk):
         import jax.numpy as jnp
 
-        override = blk[:, 0]
+        from ..ops.linear import live_rows
+
+        override, live = blk[:, 0], blk[:, -1]
         tokens = jnp.where(override >= 0, override, prev_picked)
-        table = ((blk[:, 2:-1], blk[:, -1]) if paged and state
-                 else (blk[:, 2:],) if paged else (blk[:, 2],) if state
-                 else ())
-        logits, cache, *moe = step(params, cache, tokens, blk[:, 1], *table)
+        table = ((blk[:, 2:-1],) if paged else ()) + ((live,) if state
+                                                      else ())
+        with live_rows(live) if tell_live else contextlib.nullcontext():
+            logits, cache, *moe = step(params, cache, tokens, blk[:, 1],
+                                       *table)
         picked = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
         return (logits, picked, cache, *moe)
 
     return run
+
+
+def _dense_diag_rows(params, rows: int) -> int:
+    """Most live rows of a ``rows``-row decode dispatch at which EVERY dense
+    nb-major Q40 leaf of ``params`` takes the stacked block-diagonal body
+    (``ops/pallas_q40.live_rows_top``: the question each call asks of its
+    own shapes); 0 where a leaf never does, or the tree holds none."""
+    import jax
+
+    from ..io.loader import Q40KernelNb
+    from ..ops.pallas_q40 import live_rows_top
+
+    def nb_major(v):
+        return isinstance(v, Q40KernelNb)
+
+    # (an expert stack, (L, E, ...), goes to the slot kernel: not counted)
+    tops = [live_rows_top(rows, w.qs_t.shape[-1], w.qs_t.shape[-2])
+            for w in jax.tree_util.tree_leaves(params, is_leaf=nb_major)
+            if nb_major(w) and w.qs_t.ndim <= 4]
+    return min(tops, default=0)
 
 
 @dataclasses.dataclass
@@ -510,6 +539,11 @@ class ContinuousStats:
     moe_slots: int = 0
     moe_single_row_slots: int = 0
     moe_diag_slots: int = 0
+    # landed decode steps whose dense Q40 leaves took the stacked
+    # block-diagonal body and not the 8-row tile (ops/pallas_q40: a
+    # dispatch of 1 to ``LIVE_ROWS_MAX`` live rows), counted from the rows
+    # the step's block was staged from; over ``steps`` the hit share
+    dense_diag_steps: int = 0
     # the same of admission prefill chunks, which the counters above leave
     # out: pairs that landed on held experts, and the live slots they
     # filled at the chunk's capacity (``slot_cap`` of its rows). Pairs over
@@ -1149,11 +1183,16 @@ class ContinuousEngine:
         self._decode = _shared_program(
             decode_key, lambda: jax.jit(
                 named_program("serve_decode_step", _with_pick(
-                    decode_fwd, paged, spec.vocab_size, self._state)),
+                    decode_fwd, paged, spec.vocab_size, self._state,
+                    tell_live=not sharded)),
                 donate_argnums=1))
-        # columns of a launch's staged block: [override | pos | page table]
-        # (a retention engine's: [override | pos | takes part])
-        self._blk_cols = 2 + (self._max_pages if paged else 0) + self._state
+        # columns of a launch's staged block:
+        # [override | pos | page table | live]
+        self._blk_cols = 3 + (self._max_pages if paged else 0)
+        # most live rows of a dispatch whose dense Q40 leaves take the
+        # stacked block-diagonal body instead of the tile (0: none does)
+        self._dense_diag_rows = 0 if sharded else _dense_diag_rows(
+            self.params, slots)
         # the newest launch's picks, the next launch's ``prev_picked``; the
         # first is placed as a step's result is, or a mesh's program would
         # compile once for each of the two placements
@@ -2773,9 +2812,8 @@ class ContinuousEngine:
                 row = blk[b]
                 row[0], row[1] = token, pos
                 row[2:2 + len(pages)] = pages
-                row[2 + len(pages):] = SCRAP_PAGE
-                if self._state:     # the block's last column
-                    row[-1] = rows[b] is not None
+                row[2 + len(pages):-1] = SCRAP_PAGE
+                row[-1] = rows[b] is not None   # live: the last column
             if prev is not None and not any(r is not None for r in rows):
                 return None
             if (self.spec.latent or self._hybrid
@@ -2963,6 +3001,11 @@ class ContinuousEngine:
                     self._obs.kv_pages_free.set(self._alloc.n_free)
             with host_phase("serve.census"):
                 self.stats.steps += 1
+                if 1 <= sum(r is not None for r in flight.rows) \
+                        <= self._dense_diag_rows:
+                    self.stats.dense_diag_steps += 1
+                    if self._obs is not None:
+                        self._obs.dense_diag_steps.inc()
                 if self.spec.ssd or self.spec.kda:
                     self._count_layers_run()
                 self.stats.sum_active += active0

@@ -1350,7 +1350,11 @@ class InferenceServer:
                   + (f"; {st.moe_diag_slots} of {st.moe_slots} expert "
                      f"slots took the block-diagonal body "
                      f"({st.moe_single_row_slots} held one row)"
-                     if st.moe_slots else ""),
+                     if st.moe_slots else "")
+                  + (f"; {st.dense_diag_steps} of {st.steps} steps ran "
+                     f"their dense Q40 leaves through the stacked "
+                     f"block-diagonal body"
+                     if st.dense_diag_steps else ""),
                   file=sys.stderr, tokens=st.tokens, steps=st.steps,
                   sum_active=st.sum_active, steps_ahead=st.steps_ahead,
                   rows_dropped_ahead=st.rows_dropped_ahead,
@@ -1381,6 +1385,7 @@ class InferenceServer:
                   moe_slots=st.moe_slots,
                   moe_single_row_slots=st.moe_single_row_slots,
                   moe_diag_slots=st.moe_diag_slots,
+                  dense_diag_steps=st.dense_diag_steps,
                   state_bytes=st.state_bytes,
                   window_bytes=st.window_bytes,
                   shared_kv_pages=st.shared_kv_pages,

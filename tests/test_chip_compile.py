@@ -24,6 +24,7 @@ the entry computation.
 
 import functools
 import os
+import re
 
 import pytest
 
@@ -73,6 +74,8 @@ LEAVES = {"wq": (DIM, DIM), "w13": (2 * HIDDEN, DIM), "w2": (DIM, HIDDEN),
           # 1,024)
           "yi-wqkv": (2304, 7168), "yi-w13": (10240, 7168),
           "br-wcls": (151936, 5120),
+          # ... and its ``w2`` (FFN 17408: 544 blocks a row, 17 turns of 32)
+          "br-w2": (5120, 17408),
           # Laguna-XS.2's (dim 2048: 64 blocks a row; a full layer's 48
           # heads and a sliding layer's 64 over 8 KV heads of 128: wqkv of
           # 8192 and 10240 rows, wo of 192 and 256 blocks; the dense FFN
@@ -664,6 +667,32 @@ def test_kernel_compiles_for_v5e(chip, case):
         shapes)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert ("tpu_custom_call" in text) == kernel, case
+
+
+@pytest.mark.parametrize("leaf", ["m-wqkv", "wq", "m-w13", "m-w2", "wcls",
+                                  "br-w2"])
+def test_part_filled_dispatch_compiles_both_bodies_for_v5e(chip, leaf):
+    """PR 63: an 8-row decode dispatch that is told its live rows
+    (``ops/linear.live_rows``) holds the tile and the stacked
+    block-diagonal body under one conditional, each under a name a capture
+    classes as Q40: Mistral-7B's five leaves (``wq`` has ``wo``'s shape) and
+    Brumby's ``w2``, whose 544 blocks a row are 17 turns of 32."""
+    from distributed_llama_tpu.ops.linear import live_rows
+
+    fn, (w, x, layer) = _q40("nb", leaf, 8)
+
+    def told(w, x, layer, mask):
+        with live_rows(mask):
+            return fn(w, x, layer)
+
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        (w, x, layer, _sd((8,), jnp.int32)))
+    text = jax.jit(told).lower(*args).compile().as_text()
+    kind = "2d" if leaf.endswith("wcls") else "stacked"
+    for name in (f"_q40_mxu_nb_{kind}", f"_q40_live_nb_{kind}"):
+        assert re.search(rf"%{name}[.\d]* = [^\n]*custom-call\(", text), name
+    assert " conditional(" in text
 
 
 @pytest.mark.parametrize("n_kv", [1, 3, 6, 10, 20])
